@@ -1,0 +1,356 @@
+#!/usr/bin/env python3
+"""chip_smoke.py — the quickest proof that the system still starts on
+the chip.
+
+    python3 chip_smoke.py [CHIPS]        # CHIPS = 1 (default) or 4
+
+One process drives every chip it expects.  It takes the two normal entry
+points at the full published width and depth of GPT-2 350M, with random
+weights from a seed:
+
+  train  ``ds.initialize`` -> ``engine.train_step`` for a few steps on
+         one seeded batch (ZeRO-2 on one chip; ``mesh {"data": N}`` and
+         ZeRO-3 on N): loss finite and lower at the end, one compile and
+         none after the first step, the step program holds compiled
+         Pallas kernels (``tpu_custom_call``), and on N chips every
+         parameter / optimizer leaf has shards on N distinct devices.
+  serve  ``ds.init_inference`` (the trained weights) ->
+         ``serving_engine()`` -> staggered ``submit``/``step``/``run``
+         through the paged pool, chunked prefill and the mixed decode
+         program (``serving.mesh {"model": N}``): every greedy stream
+         completes with finite logits, ``decode_builds == 1``, no
+         compile after warm-up, the pool drains, the step program holds
+         ``tpu_custom_call``.
+  kernel the paged decode and prefill kernel against the float32
+         ``jax.numpy`` reference, on the chip, at the serving shapes.
+
+It fails — non-zero exit, no result line — when JAX shows anything but
+CHIPS TPU devices; it never adapts downward, and nothing on the path is
+caught.  Compile seconds are reported apart from run seconds, so a
+second run on the same compile-cache directory shows the hit.  The last
+line of stdout is one JSON object, ``{"ok": true, "device": {...}}``.
+"""
+from __future__ import annotations
+
+import json
+import sys
+import time
+
+import numpy as np
+
+SEED = 0
+TRAIN_STEPS = 6
+MICRO_BATCH = 16                       # sequences per chip per step
+SEQ_LEN = 1024
+#: (prompt tokens, new tokens) per request — greedy, staggered arrivals
+REQUESTS = ((64, 32), (200, 48), (512, 64), (128, 40), (333, 56),
+            (96, 32), (480, 64), (256, 36))
+SERVING = {"enabled": True, "kv_block_size": 16, "num_kv_blocks": 512,
+           "max_batch_slots": 8, "prefill_chunk_tokens": 256}
+#: bf16 pool and queries against a float32 reference
+KERNEL_ATOL = 2e-2
+
+
+class SmokeFailure(RuntimeError):
+    """A check of the smoke did not hold."""
+
+
+def check(ok: bool, what: str) -> None:
+    if not ok:
+        raise SmokeFailure(what)
+
+
+def require_tpu(chips: int) -> dict:
+    """The devices JAX reports, or exit: nothing below runs — and no
+    result is printed — on another platform or chip count."""
+    import jax
+    devices = jax.devices()
+    info = {"platform": devices[0].platform,
+            "kind": devices[0].device_kind, "count": len(devices)}
+    if info["platform"] != "tpu" or info["count"] != chips:
+        sys.stderr.write(
+            f"chip_smoke: needs exactly {chips} TPU device(s); JAX "
+            f"reports {info['count']} x {info['platform']} "
+            f"({info['kind']}). No result.\n")
+        raise SystemExit(2)
+    return info
+
+
+class CompileLog:
+    """XLA compilations from JAX's own monitoring events: how many, the
+    seconds in the backend compiler (or in fetching the program from the
+    persistent cache — what a warm cache shrinks), and the seconds spent
+    tracing and lowering, which no cache saves."""
+
+    def __init__(self):
+        from jax import monitoring
+        self.compiles = 0
+        self.cache_hits = 0
+        self.compile_s = 0.0
+        self.trace_s = 0.0
+        monitoring.register_event_duration_secs_listener(self._duration)
+        monitoring.register_event_listener(self._event)
+
+    def _duration(self, name, secs, **_):
+        if name == "/jax/core/compile/backend_compile_duration":
+            self.compiles += 1
+            self.compile_s += secs
+        elif name.startswith("/jax/core/compile/"):
+            self.trace_s += secs
+
+    def _event(self, name, **_):
+        self.cache_hits += name == "/jax/compilation_cache/cache_hits"
+
+    def mark(self):
+        return (self.compiles, self.cache_hits, self.compile_s,
+                self.trace_s)
+
+    def since(self, mark):
+        return {"compiles": self.compiles - mark[0],
+                "cache_hits": self.cache_hits - mark[1],
+                "compile_s": round(self.compile_s - mark[2], 2),
+                "trace_lower_s": round(self.trace_s - mark[3], 2)}
+
+
+def distinct_shard_devices(tree) -> dict:
+    """Over every array leaf: the fewest distinct devices any leaf has
+    shards on, and the share of bytes held in leaves that are really
+    partitioned (a shard smaller than the array)."""
+    import jax
+    fewest, split, total = None, 0, 0
+    for leaf in jax.tree_util.tree_leaves(tree):
+        shards = leaf.addressable_shards
+        n = len({s.device for s in shards})
+        fewest = n if fewest is None else min(fewest, n)
+        total += leaf.nbytes
+        if shards[0].data.shape != leaf.shape:
+            split += leaf.nbytes
+    return {"min_devices": fewest,
+            "partitioned_byte_share": round(split / max(total, 1), 3)}
+
+
+def train_phase(chips: int, model_config, log: CompileLog, device: dict,
+                micro_batch: int = MICRO_BATCH, steps: int = TRAIN_STEPS):
+    """A few optimizer steps through ``ds.initialize``; returns the
+    phase record and the trained parameters."""
+    import jax
+    import deepspeed_tpu as ds
+    from deepspeed_tpu.models import TransformerLM
+
+    config = {
+        "train_micro_batch_size_per_gpu": micro_batch,
+        "gradient_accumulation_steps": 1,
+        "steps_per_print": 0,
+        "bf16": {"enabled": True},
+        "optimizer": {"type": "AdamW",
+                      "params": {"lr": 3e-4, "weight_decay": 0.01}},
+        "gradient_clipping": 1.0,
+        "zero_optimization": {"stage": 2 if chips == 1 else 3},
+        "mesh": {"data": chips},
+    }
+    engine, *_ = ds.initialize(model=TransformerLM(model_config),
+                               config=config, rng=jax.random.PRNGKey(SEED))
+    rng = np.random.default_rng(SEED)
+    batch = {"input_ids": rng.integers(
+        0, model_config.vocab_size,
+        (micro_batch * chips, model_config.max_seq_len), dtype=np.int32)}
+
+    mark = log.mark()
+    losses, step_s = [], []
+    for i in range(steps):
+        t0 = time.perf_counter()
+        metrics = engine.train_step(batch)
+        losses.append(float(jax.block_until_ready(metrics["loss"])))
+        step_s.append(time.perf_counter() - t0)
+        if i == 0:
+            first = log.since(mark)
+            steady = log.mark()
+    after = log.since(steady)
+
+    check(all(np.isfinite(losses)), f"train: non-finite loss {losses}")
+    check(losses[-1] < losses[0],
+          f"train: loss did not fall on a repeated batch: {losses}")
+    check(after["compiles"] == 0,
+          f"train: {after['compiles']} compile(s) after the first step")
+    text = engine._train_step_fn.lower(
+        engine.state, engine.shard_batch(batch)).as_text()
+    kernels = text.count("tpu_custom_call")
+    check(kernels > 0, "train: no tpu_custom_call in the step program")
+    placement = distinct_shard_devices(
+        {k: engine.state[k] for k in ("params", "opt")})
+    check(placement["min_devices"] == chips,
+          f"train: a state leaf lives on {placement['min_devices']} "
+          f"device(s), expected {chips}")
+    if chips > 1:
+        check(placement["partitioned_byte_share"] > 0.9,
+              f"train: ZeRO-3 left the state replicated: {placement}")
+
+    record = {"phase": "train", **device,
+              "zero_stage": config["zero_optimization"]["stage"],
+              "steps": steps, "tokens_per_step":
+                  micro_batch * chips * model_config.max_seq_len,
+              "loss_first": round(losses[0], 4),
+              "loss_last": round(losses[-1], 4),
+              "first_step": first, "compiles_after_first_step":
+                  after["compiles"],
+              "first_step_wall_s": round(step_s[0], 2),
+              "steady_step_s": round(float(np.median(step_s[1:])), 4),
+              "tpu_custom_calls_in_program": kernels, **placement}
+    return record, engine.state["params"]
+
+
+def serve_phase(chips: int, model_config, params, log: CompileLog,
+                device: dict, requests=REQUESTS, serving=None,
+                max_out_tokens: int = SEQ_LEN):
+    """A handful of staggered greedy requests through
+    ``init_inference(...).serving_engine()``."""
+    import jax
+    import deepspeed_tpu as ds
+    from deepspeed_tpu.inference.serving import RequestStatus
+    from deepspeed_tpu.models import TransformerLM
+
+    serving = dict(serving or SERVING, mesh={"data": 1, "model": chips})
+    eng = ds.init_inference(
+        TransformerLM(model_config),
+        {"dtype": "bfloat16", "max_out_tokens": max_out_tokens,
+         "temperature": 0.0, "serving": serving}, params=params)
+    srv = eng.serving_engine()
+
+    rng = np.random.default_rng(SEED + 1)
+    prompts = [rng.integers(0, model_config.vocab_size, n).tolist()
+               for n, _ in requests]
+    mark = log.mark()
+    t0 = time.perf_counter()
+    submitted = []
+    # staggered arrivals: three requests, a few iterations, three more,
+    # a few more iterations, then the rest and a drain
+    for i, (prompt, (_, new)) in enumerate(zip(prompts, requests)):
+        submitted.append(srv.submit(prompt, max_new_tokens=new))
+        if i == 0:
+            srv.step()                      # warm-up: the one compile
+            warm_s = time.perf_counter() - t0
+            first = log.since(mark)
+            steady = log.mark()
+        elif i % 3 == 2:
+            for _ in range(4):
+                srv.step()
+    srv.run()
+    run_s = time.perf_counter() - t0 - warm_s
+    after = log.since(steady)
+
+    for req, (_, new) in zip(submitted, requests):
+        check(req.status is RequestStatus.OK,
+              f"serve: {req.req_id} ended {req.status} ({req.error})")
+        check(len(req.output) == new,
+              f"serve: {req.req_id} produced {len(req.output)} of {new} "
+              f"tokens")
+    check(srv.decode_builds == 1,
+          f"serve: mixed program built {srv.decode_builds} times")
+    check(after["compiles"] == 0,
+          f"serve: {after['compiles']} compile(s) after warm-up")
+    check(srv.allocator.num_used == 0,
+          f"serve: {srv.allocator.num_used} KV blocks held after drain")
+    builds, held = srv.decode_builds, srv.allocator.num_used
+    pool = distinct_shard_devices([srv._pool_k, srv._pool_v])
+    check(pool["min_devices"] == chips,
+          f"serve: KV pool on {pool['min_devices']} device(s), expected "
+          f"{chips}")
+    text = srv._step_fn.lower(*srv._step_operands((), None)).as_text()
+    kernels = text.count("tpu_custom_call")
+    check(kernels > 0, "serve: no tpu_custom_call in the mixed program")
+
+    tokens = sum(new for _, new in requests)
+    record = {"phase": "serve", **device, "requests": len(requests),
+              "prompt_tokens": sum(n for n, _ in requests),
+              "new_tokens": tokens, "warmup": first,
+              "warmup_wall_s": round(warm_s, 2),
+              "compiles_after_warmup": after["compiles"],
+              "run_s": round(run_s, 2),
+              "decode_builds": builds, "kv_blocks_held_after_drain": held,
+              "tpu_custom_calls_in_program": kernels,
+              "kv_pool_min_devices": pool["min_devices"],
+              "kv_pool_partitioned_byte_share":
+                  pool["partitioned_byte_share"]}
+    return record
+
+
+def kernel_phase(heads: int, head_dim: int, device: dict,
+                 block: int = SERVING["kv_block_size"],
+                 chunk: int = SERVING["prefill_chunk_tokens"]):
+    """Paged decode and prefill kernel vs the float32 jnp reference, on
+    the chip, at the shapes one serving shard runs (bf16 pool)."""
+    import jax
+    import jax.numpy as jnp
+    from deepspeed_tpu.ops import resolve_interpret
+    from deepspeed_tpu.ops.transformer.paged_decode_attention import (
+        paged_attention_reference, paged_decode_attention,
+        paged_prefill_attention, paged_prefill_reference)
+
+    check(resolve_interpret(None) is False,
+          "kernel: the package would interpret its Pallas kernels")
+    rng = np.random.default_rng(SEED + 2)
+    pages = SEQ_LEN // block
+    lens = np.array([1, 64, 97, 200, 333, 512, 575, 0], np.int32)
+    nb = 1 + len(lens) * pages
+    tables = np.arange(1, nb, dtype=np.int32).reshape(len(lens), pages)
+    pool_k, pool_v = (jnp.asarray(
+        rng.standard_normal((nb, block, heads * head_dim)), jnp.bfloat16)
+        for _ in range(2))
+    f32 = lambda a: a.astype(jnp.float32)               # noqa: E731
+
+    q = jnp.asarray(rng.standard_normal((len(lens), heads, head_dim)),
+                    jnp.bfloat16)
+    out = jax.jit(paged_decode_attention)(q, pool_k, pool_v, lens, tables)
+    ref = paged_attention_reference(f32(q), f32(pool_k), f32(pool_v), lens,
+                                    tables)
+    decode_err = float(jnp.max(jnp.abs(f32(out) - ref)))
+    check(bool(jnp.all(jnp.isfinite(f32(out)))), "kernel: decode not finite")
+    check(decode_err < KERNEL_ATOL,
+          f"kernel: paged decode off the f32 reference by {decode_err}")
+
+    # a full chunk after `chunk` rows of context, then a ragged tail
+    prefill_err = 0.0
+    qc = jnp.asarray(rng.standard_normal((chunk, heads, head_dim)),
+                     jnp.bfloat16)
+    for base, n in ((chunk, chunk), (2 * chunk, chunk // 2 + 3)):
+        out = jax.jit(paged_prefill_attention)(
+            qc, pool_k, pool_v, base, n, tables[5])
+        ref = paged_prefill_reference(f32(qc), f32(pool_k), f32(pool_v),
+                                      base, n, tables[5])
+        check(bool(jnp.all(jnp.isfinite(f32(out)[:n]))),
+              "kernel: prefill not finite")
+        prefill_err = max(prefill_err, float(jnp.max(jnp.abs(
+            f32(out)[:n] - ref[:n]))))
+    check(prefill_err < KERNEL_ATOL,
+          f"kernel: paged prefill off the f32 reference by {prefill_err}")
+    return {"phase": "kernel", **device, "heads": heads,
+            "head_dim": head_dim, "kv_block_size": block,
+            "decode_max_abs_err": round(decode_err, 5),
+            "prefill_max_abs_err": round(prefill_err, 5),
+            "atol": KERNEL_ATOL}
+
+
+def main(argv) -> int:
+    chips = int(argv[1]) if len(argv) > 1 else 1
+    device = require_tpu(chips)
+    log = CompileLog()
+
+    from deepspeed_tpu.models import gpt2_config
+    model_config = gpt2_config("350m", max_seq_len=SEQ_LEN, remat="full",
+                               attn_impl="flash", loss_chunk=256)
+    t0 = time.perf_counter()
+    record, params = train_phase(chips, model_config, log, device)
+    print(json.dumps(record), flush=True)
+    print(json.dumps(serve_phase(chips, model_config, params, log, device)),
+          flush=True)
+    print(json.dumps(kernel_phase(model_config.num_heads // chips,
+                                  model_config.hdim, device)), flush=True)
+    print(json.dumps({"phase": "total", **device,
+                      "wall_s": round(time.perf_counter() - t0, 1),
+                      **log.since((0, 0, 0.0, 0.0))}), flush=True)
+    print(json.dumps({"ok": True, "device": device}), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main(sys.argv))

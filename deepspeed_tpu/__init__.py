@@ -10,6 +10,7 @@ Top-level API mirrors the reference `deepspeed/__init__.py`:
 """
 from __future__ import annotations
 
+import os
 from typing import Any, Optional, Tuple
 
 __version__ = "0.1.0"
@@ -21,6 +22,31 @@ from .runtime.config import DeepSpeedConfig  # noqa: F401
 from .runtime.engine import DeepSpeedEngine  # noqa: F401
 from .runtime.dataloader import DeepSpeedDataLoader, RepeatingLoader  # noqa: F401
 from .parallel.topology import build_mesh  # noqa: F401
+
+
+def enable_compile_cache() -> Optional[str]:
+    """Keep compiled programs across processes in JAX's persistent
+    compilation cache; returns its directory.  Called by
+    :func:`initialize` and :func:`init_inference` before their first
+    compile, so every entry point shares one cache.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set JAX reads it itself and
+    no path is set here.  Otherwise the cache lives at the fixed,
+    git-ignored ``<checkout>/.jax_cache`` — the path is part of the
+    cache key, so it is never derived from a temp name, pid or time.
+    CPU runs (the test suite) compile nothing worth keeping and are
+    left alone."""
+    import jax
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    if jax.default_backend() == "cpu":
+        return None
+    path = os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def initialize(args: Any = None,
@@ -54,6 +80,7 @@ def initialize(args: Any = None,
         raise ValueError("deepspeed_tpu.initialize requires a model")
     if dist_init_required:
         init_distributed()
+    enable_compile_cache()
 
     # Engine dispatch rides the topology: a mesh whose ``pipe`` axis is
     # >= 2 — passed in or declared by the config's mesh block (e.g. an
@@ -90,6 +117,7 @@ def init_inference(model: Any = None, config: Any = None,
     restored TP-sliced, else fresh weights."""
     from .inference.engine import InferenceEngine
     from .inference.config import DeepSpeedInferenceConfig
+    enable_compile_cache()
     if isinstance(config, DeepSpeedInferenceConfig):
         cfg = (config.model_copy(update=kwargs) if kwargs else config)
     else:

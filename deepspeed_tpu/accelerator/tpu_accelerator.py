@@ -113,8 +113,7 @@ class TPUAccelerator:
         callback()
 
     def synchronize(self, device_index: Optional[int] = None) -> None:
-        (jax.effects_barrier if hasattr(jax, "effects_barrier")
-         else lambda: None)()
+        jax.effects_barrier()
 
 
 _accel: Optional[TPUAccelerator] = None

@@ -32,8 +32,27 @@ from typing import Any, Dict, List, Optional
 from ..utils.logging import logger
 
 
+def initialized_accelerator() -> Optional[str]:
+    """The accelerator platform this process's JAX has ALREADY
+    initialized, else None — asked without initializing anything (a
+    process that merely imported jax holds no chip)."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return None
+    from jax._src import xla_bridge
+    if not xla_bridge.backends_are_initialized():
+        return None
+    platform = jax.default_backend()
+    return None if platform == "cpu" else platform
+
+
 class ResourceManager:
-    """Run job specs over a bounded pool of subprocess slots."""
+    """Run job specs over a bounded pool of subprocess slots.
+
+    A chip belongs to one process at a time, and every experiment needs
+    the whole accelerator: the scheduling process must not have touched
+    JAX on it (``run`` raises rather than let the children hang), and
+    more than one slot is for CPU runs (``JAX_PLATFORMS=cpu``) only."""
 
     def __init__(self, slots: int = 1, timeout_s: float = 600.0,
                  env: Optional[Dict[str, str]] = None,
@@ -42,6 +61,13 @@ class ResourceManager:
         self.timeout_s = float(timeout_s)
         self.env = dict(env or {})
         self.poll_s = poll_s
+        platforms = self.env.get("JAX_PLATFORMS",
+                                 os.environ.get("JAX_PLATFORMS", ""))
+        if self.slots > 1 and platforms != "cpu":
+            raise ValueError(
+                f"autotune scheduler: {self.slots} slots would run that "
+                f"many experiments at once, each needing the whole "
+                f"accelerator — use slots=1, or JAX_PLATFORMS=cpu")
 
     def _launch(self, spec_path: str,
                 log_path: str) -> subprocess.Popen:
@@ -67,6 +93,15 @@ class ResourceManager:
         """Execute all specs; returns one result dict per spec (same
         order): {"status": ok|oom|error|crash|timeout, "samples_per_sec",
         "detail"}."""
+        held = initialized_accelerator()
+        if held is not None and self.env.get("JAX_PLATFORMS") != "cpu":
+            raise RuntimeError(
+                f"autotune scheduler: this process has already "
+                f"initialized JAX on {held!r} and so holds the chip its "
+                f"experiment subprocesses need — they would fail or "
+                f"hang.  Schedule from a process that has not touched "
+                f"JAX (build the specs there, or pass them in), or tune "
+                f"in-process with Autotuner.tune()")
         os.makedirs(workdir, exist_ok=True)
         results: List[Optional[Dict[str, Any]]] = [None] * len(specs)
         pending = deque()

@@ -276,12 +276,8 @@ def axis_index(axis_name: str):
 
 
 def axis_size(axis_name: str):
-    """Participant count on ``axis_name``. ``lax.axis_size`` only exists
-    on newer jax; psum of the constant 1 is the version-portable form —
-    it folds to the axis size at trace time (no collective emitted)."""
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis_name)
-    return lax.psum(1, axis_name)
+    """Participant count on ``axis_name``."""
+    return lax.axis_size(axis_name)
 
 
 def log_summary() -> str:

@@ -192,38 +192,36 @@ class ServingEngine:
         self._ovl = get_overlap_profiler()
         # -- (data, model) serving submesh (docs/serving.md
         # "Tensor-parallel serving"): model shards heads + KV pool +
-        # MLP, data shards the decode slots; 1x1 keeps the legacy
-        # single-device program byte-identical --------------------------
+        # MLP, data shards the decode slots.  The step ALWAYS runs under
+        # shard_map over this submesh, 1x1 included: the paged kernel
+        # is a Mosaic call XLA cannot partition, and the engine's own
+        # mesh spans every device of the host ----------------------------
         self.tp_data_size = cfg.mesh.data
         self.tp_model_size = cfg.mesh.model
-        self._tp = self.tp_data_size > 1 or self.tp_model_size > 1
-        self.tp_mesh = None
-        self._tp_model = model
-        if self._tp:
-            self._init_tp_mesh()
+        self._init_tp_mesh()
         with trace_span("serving/kv_quantize", bits=self.kv_bits,
                         blocks=cfg.num_kv_blocks):
             pools = model.init_paged_cache(cfg.num_kv_blocks,
                                            self.block_size,
                                            dtype=engine.dtype,
                                            kv_bits=self.kv_bits)
-        self._pool_k, self._pool_v = pools["k"], pools["v"]
-        self._pool_ks = pools.get("k_scale")
-        self._pool_vs = pools.get("v_scale")
-        if self._tp:
-            # pools shard on the kv_heads axis over `model` (scale
-            # planes ride the same axis) and REPLICATE over `data`: each
-            # chip holds kv_heads/model of every block — per-chip pool
-            # HBM is 1/model of the unsharded pool (kv_pool_bytes)
-            self._pool_k = jax.device_put(
-                self._pool_k, NamedSharding(self.tp_mesh, self._pool_spec))
-            self._pool_v = jax.device_put(
-                self._pool_v, NamedSharding(self.tp_mesh, self._pool_spec))
-            if self.kv_bits:
-                sh = NamedSharding(self.tp_mesh, self._pscale_spec)
-                self._pool_ks = jax.device_put(self._pool_ks, sh)
-                self._pool_vs = jax.device_put(self._pool_vs, sh)
-            self._prep_tp_params()
+        # the pools live in the sharding the step returns them in —
+        # sharding is part of the jit cache key, so anything else would
+        # retrace the program on the second dispatch.  They shard on
+        # the kv-head lanes over `model` (scale planes on their kv-head
+        # axis) and REPLICATE over `data`: each chip holds
+        # kv_heads/model of every block (kv_pool_bytes)
+        self._pool_sh = NamedSharding(self.tp_mesh, self._pool_spec)
+        self._pscale_sh = NamedSharding(self.tp_mesh, self._pscale_spec)
+        self._pool_k = jax.device_put(pools["k"], self._pool_sh)
+        self._pool_v = jax.device_put(pools["v"], self._pool_sh)
+        self._pool_ks = self._pool_vs = None
+        if self.kv_bits:
+            self._pool_ks = jax.device_put(pools["k_scale"],
+                                           self._pscale_sh)
+            self._pool_vs = jax.device_put(pools["v_scale"],
+                                           self._pscale_sh)
+        self._prep_tp_params()
         logger.info(
             f"serving: paged KV pool {cfg.num_kv_blocks} x "
             f"{self.block_size}-token blocks "
@@ -236,7 +234,7 @@ class ServingEngine:
 
         # donation keeps the pools in-place on TPU; the CPU backend
         # does not implement donation and would warn every dispatch
-        self._donate = jax.default_backend() == "tpu"
+        self._donate = jax.default_backend() != "cpu"
 
         # -- tiered host prefix cache (docs/serving.md "Tiered prefix
         # cache"): LRU-evicted registered blocks demote into host
@@ -352,8 +350,7 @@ class ServingEngine:
             ).set(self.tp_model_size)
         # per-token per-layer model-axis psum payload (bytes): one psum
         # on attention+MLP outputs for parallel-residual blocks, two for
-        # serial/post-norm — the `serving/tp_psum` span and
-        # tp_decode_bench report this
+        # serial/post-norm — the `serving/tp_psum` span reports this
         mc = model.config
         npsums = 1 if mc.parallel_residual else 2
         self.tp_psum_bytes_per_token_layer = (
@@ -488,16 +485,16 @@ class ServingEngine:
     # ------------------------------------------------------------------
     @property
     def _pool_spec(self) -> P:
-        """KV pools [L, blocks, block, kv_heads, d]: kv_heads over
-        `model`, replicated over `data` (every data shard applies every
-        slot's writes — see the model's gather_rows)."""
-        return P(None, None, None, topo.MODEL_AXIS, None)
+        """KV pools [L, blocks, block, kv_heads * d]: the kv-head lanes
+        over `model`, replicated over `data` (every data shard applies
+        every slot's writes — see the model's gather_rows)."""
+        return P(None, None, None, topo.MODEL_AXIS)
 
     @property
     def _pscale_spec(self) -> P:
-        """Quant scale planes [L, blocks, block, kv_heads] ride the
+        """Quant scale planes [L, blocks, kv_heads, 1, block] ride the
         pools' kv_heads sharding."""
-        return P(None, None, None, topo.MODEL_AXIS)
+        return P(None, None, topo.MODEL_AXIS, None, None)
 
     def _init_tp_mesh(self) -> None:
         """Validate the (data, model) request against the model shapes,
@@ -666,22 +663,42 @@ class ServingEngine:
     def _build_block_dma(self) -> None:
         # block-granular DMA helpers: tiny jitted gather/scatter over
         # the pools (NOT the mixed step — these run in the admission
-        # window, never per decode token)
+        # window, never per decode token).  They translate between the
+        # pool layout ([L, nb, blk, kvh * De] values, [L, nb, kvh, 1,
+        # blk] scales) and the codec's per-block [L, blk, kvh, De] /
+        # [L, blk, kvh]; the scatter returns the pools in their own
+        # shardings so the mixed step never sees a new input layout
+        kvh = self.model.config.kv_heads
+
+        def take(pool, b):
+            blk = pool[:, b]
+            return blk.reshape(*blk.shape[:2], kvh, -1)
+
+        def put(pool, b, blk):
+            return pool.at[:, b].set(blk.reshape(*blk.shape[:2], -1))
+
+        def take_s(scale, b):
+            return scale[:, b, :, 0].swapaxes(1, 2)
+
+        def put_s(scale, b, rows):
+            return scale.at[:, b, :, 0].set(rows.swapaxes(1, 2))
         if self.kv_bits:
             self._gather_block = jax.jit(
                 lambda pk, pv, pks, pvs, b:
-                (pk[:, b], pv[:, b], pks[:, b], pvs[:, b]))
+                (take(pk, b), take(pv, b), take_s(pks, b), take_s(pvs, b)))
             self._scatter_block = jax.jit(
                 lambda pk, pv, pks, pvs, b, k, v, ks, vs:
-                (pk.at[:, b].set(k), pv.at[:, b].set(v),
-                 pks.at[:, b].set(ks), pvs.at[:, b].set(vs)),
+                (put(pk, b, k), put(pv, b, v), put_s(pks, b, ks),
+                 put_s(pvs, b, vs)),
+                out_shardings=(self._pool_sh, self._pool_sh,
+                               self._pscale_sh, self._pscale_sh),
                 donate_argnums=(0, 1, 2, 3) if self._donate else ())
         else:
             self._gather_block = jax.jit(
-                lambda pk, pv, b: (pk[:, b], pv[:, b]))
+                lambda pk, pv, b: (take(pk, b), take(pv, b)))
             self._scatter_block = jax.jit(
-                lambda pk, pv, b, k, v:
-                (pk.at[:, b].set(k), pv.at[:, b].set(v)),
+                lambda pk, pv, b, k, v: (put(pk, b, k), put(pv, b, v)),
+                out_shardings=(self._pool_sh, self._pool_sh),
                 donate_argnums=(0, 1) if self._donate else ())
         # compile warmup: scatter the null block's own content back into
         # itself — a semantic no-op that traces both programs now
@@ -942,19 +959,15 @@ class ServingEngine:
             dpools = draft.init_paged_cache(
                 cfg.num_kv_blocks, self.block_size,
                 dtype=self.engine.dtype, kv_bits=0)
-        self._dpool_k, self._dpool_v = dpools["k"], dpools["v"]
-        self._tp_draft = draft
-        if self._tp:
-            # the draft replicates over BOTH mesh axes (it is small);
-            # its view arms only the data axis so the slot-sharded
-            # lens/tables it shares with the target stay correct
-            self._tp_draft = draft.tp_serving_view(
-                1, None,
-                topo.DATA_AXIS if self.tp_data_size > 1 else None)
-            rep = NamedSharding(self.tp_mesh, P())
-            self._dpool_k = jax.device_put(self._dpool_k, rep)
-            self._dpool_v = jax.device_put(self._dpool_v, rep)
-            self._draft_params = jax.device_put(self._draft_params, rep)
+        # the draft replicates over BOTH mesh axes (it is small); its
+        # view arms only the data axis so the slot-sharded lens/tables
+        # it shares with the target stay correct
+        self._tp_draft = draft.tp_serving_view(
+            1, None, topo.DATA_AXIS if self.tp_data_size > 1 else None)
+        rep = NamedSharding(self.tp_mesh, P())
+        self._dpool_k = jax.device_put(dpools["k"], rep)
+        self._dpool_v = jax.device_put(dpools["v"], rep)
+        self._draft_params = jax.device_put(self._draft_params, rep)
         logger.info(
             f"serving: speculative decoding armed — draft "
             f"{draft.config.num_layers}L/{draft.config.d_model}d, "
@@ -1292,19 +1305,15 @@ class ServingEngine:
         else:
             fn = step
             donate = (2, 3) + ((4, 5) if self.kv_bits else ())
-        if not self._tp:
-            with self.engine.mesh:
-                return jax.jit(
-                    fn, donate_argnums=donate if self._donate else ())
-        # TP: the same body, shard_mapped over the (data, model) serving
-        # submesh.  Pools/params shard over 'model' (kv_head axis /
+        # the body runs shard_mapped over the (data, model) serving
+        # submesh.  Pools/params shard over 'model' (kv-head lanes /
         # column-row tiles); slot-shaped inputs — including the per-slot
         # sampling params, keys, and output indices — over 'data'; the
         # chunk and its sampling scalars stay replicated, and the draft
         # (params + pools) replicates over both axes, so every shard
         # traces the one identical program (decode_builds == 1
         # regardless of mesh)
-        d, m = topo.DATA_AXIS, topo.MODEL_AXIS
+        d = topo.DATA_AXIS
         pool_sp = self._pool_spec
         pscale_sp = self._pscale_spec if self.kv_bits else P()
         scale_sp = (self._tp_scale_specs
@@ -1327,8 +1336,10 @@ class ServingEngine:
                         P(), P(), P(), P()) + samp_in
             out_specs = (P(d), P(), P(d), P(),
                          pool_sp, pool_sp, pscale_sp, pscale_sp)
+        # manual over EVERY axis of the submesh (the rest are size 1):
+        # a Mosaic call refuses to lower while any mesh axis is auto
         sharded = shard_map(fn, mesh=self.tp_mesh, in_specs=in_specs,
-                            out_specs=out_specs, axis_names={d, m})
+                            out_specs=out_specs)
         with self.tp_mesh:
             return jax.jit(
                 sharded, donate_argnums=donate if self._donate else ())
@@ -1352,6 +1363,65 @@ class ServingEngine:
         self._m_quarantined.inc()
         self.lifecycle_counts["quarantined"] += 1
         logger.error(f"serving: {req.req_id}: {msg}")
+
+    def _step_operands(self, dec: List[Tuple[int, Request]],
+                       chunk: Optional[Tuple[int, Request, int, int]],
+                       spec: List[Tuple[int, Request]] = ()) -> tuple:
+        """The mixed program's positional operands for one dispatch
+        (see ``_build_step``): weights, the pools, then the slot-shaped
+        host arrays — block tables, lens, decode/spec tokens and masks,
+        the prompt chunk, and the per-slot / per-chunk sampling state —
+        filled from the scheduler's request records."""
+        tables = np.zeros((self.num_slots, self.max_pages), np.int32)
+        lens = np.zeros((self.num_slots,), np.int32)
+        dec_tokens = np.zeros((self.num_slots,), np.int32)
+        dec_active = np.zeros((self.num_slots,), np.int32)
+        spec_active = np.zeros((self.num_slots,), np.int32)
+        temp = np.zeros((self.num_slots,), np.float32)
+        top_k = np.zeros((self.num_slots,), np.int32)
+        top_p = np.ones((self.num_slots,), np.float32)
+        keys = np.zeros((self.num_slots, 2), np.uint32)
+        out_idx = np.zeros((self.num_slots,), np.int32)
+        for slot, req in self.scheduler.running.items():
+            table = self.allocator.block_table(req.req_id)
+            tables[slot, :len(table)] = table
+            lens[slot] = req.cached_tokens
+        for slot, req in list(dec) + list(spec):
+            dec_tokens[slot] = req.output[-1]
+            temp[slot] = req.temperature
+            top_k[slot] = req.top_k
+            top_p[slot] = req.top_p
+            keys[slot] = req.prng_key
+            out_idx[slot] = len(req.output)
+        for slot, _req in dec:
+            dec_active[slot] = 1
+        for slot, _req in spec:
+            spec_active[slot] = 1
+        chunk_ids = np.zeros((self.chunk_tokens,), np.int32)
+        c_slot = c_start = c_len = 0
+        c_temp, c_top_k, c_top_p = 0.0, 0, 1.0
+        c_key = np.zeros((2,), np.uint32)
+        c_oidx = 0
+        if chunk is not None:
+            c_slot, req, c_start, c_len = chunk
+            chunk_ids[:c_len] = req.prefix[c_start:c_start + c_len]
+            c_temp, c_top_k, c_top_p = req.temperature, req.top_k, \
+                req.top_p
+            c_key = np.asarray(req.prng_key, np.uint32)
+            c_oidx = len(req.output)
+        i32 = lambda x: jnp.asarray(x, jnp.int32)          # noqa: E731
+        f32 = lambda x: jnp.asarray(x, jnp.float32)        # noqa: E731
+        pools = (self._pool_k, self._pool_v, self._pool_ks, self._pool_vs)
+        slots = (tables, lens, dec_tokens, dec_active)
+        if self._draft_model is not None:
+            pools = (self._draft_params,) + pools + (self._dpool_k,
+                                                     self._dpool_v)
+            slots += (spec_active,)
+        return ((self._tp_params, self._tp_scales) + pools + slots
+                + (chunk_ids, i32(c_slot), i32(c_start), i32(c_len),
+                   temp, top_k, top_p, keys, out_idx,
+                   f32(c_temp), i32(c_top_k), f32(c_top_p), c_key,
+                   i32(c_oidx)))
 
     def _dispatch(self, dec: List[Tuple[int, Request]],
                   chunk: Optional[Tuple[int, Request, int, int]],
@@ -1378,44 +1448,9 @@ class ServingEngine:
                 f"fatal fault at serving dispatch: {e}") from e
         sched = self.scheduler
         spec_on = self._draft_model is not None
-        tables = np.zeros((self.num_slots, self.max_pages), np.int32)
-        lens = np.zeros((self.num_slots,), np.int32)
-        dec_tokens = np.zeros((self.num_slots,), np.int32)
-        dec_active = np.zeros((self.num_slots,), np.int32)
-        spec_active = np.zeros((self.num_slots,), np.int32)
-        temp = np.zeros((self.num_slots,), np.float32)
-        top_k = np.zeros((self.num_slots,), np.int32)
-        top_p = np.ones((self.num_slots,), np.float32)
-        keys = np.zeros((self.num_slots, 2), np.uint32)
-        out_idx = np.zeros((self.num_slots,), np.int32)
-        for slot, req in sched.running.items():
-            table = self.allocator.block_table(req.req_id)
-            tables[slot, :len(table)] = table
-            lens[slot] = req.cached_tokens
-        for slot, req in list(dec) + list(spec):
-            dec_tokens[slot] = req.output[-1]
-            temp[slot] = req.temperature
-            top_k[slot] = req.top_k
-            top_p[slot] = req.top_p
-            keys[slot] = req.prng_key
-            out_idx[slot] = len(req.output)
-        for slot, _req in dec:
-            dec_active[slot] = 1
-        for slot, _req in spec:
-            spec_active[slot] = 1
-        chunk_ids = np.zeros((self.chunk_tokens,), np.int32)
-        c_slot = c_start = c_len = 0
-        c_temp, c_top_k, c_top_p = 0.0, 0, 1.0
-        c_key = np.zeros((2,), np.uint32)
-        c_oidx = 0
-        if chunk is not None:
-            c_slot, req, c_start, c_len = chunk[0], chunk[1], chunk[2], \
-                chunk[3]
-            chunk_ids[:c_len] = req.prefix[c_start:c_start + c_len]
-            c_temp, c_top_k, c_top_p = req.temperature, req.top_k, \
-                req.top_p
-            c_key = np.asarray(req.prng_key, np.uint32)
-            c_oidx = len(req.output)
+        c_slot, c_start, c_len = ((chunk[0], chunk[2], chunk[3])
+                                  if chunk is not None else (0, 0, 0))
+        operands = self._step_operands(dec, chunk, spec)
         if self._step_fn is None:
             self._step_fn = self._build_step()
         ovl_on = self._ovl.enabled
@@ -1433,55 +1468,27 @@ class ServingEngine:
                 spans.enter_context(
                     trace_span("serving/prefill_chunk", slot=c_slot,
                                start=c_start, tokens=c_len))
-            if self._tp:
+            if self.tp_mesh.size > 1:
                 spans.enter_context(trace_span(
                     "serving/tp_psum", model=self.tp_model_size,
                     data=self.tp_data_size,
                     bytes_per_token_layer=self.tp_psum_bytes_per_token_layer,
                     layers=self.model.config.num_layers))
-                params = self._tp_params
-                scales = self._tp_scales
-            else:
-                params = self.engine.params
-                scales = getattr(self.engine, "_scales", None)
-            samp_args = (temp, top_k, top_p, keys, out_idx,
-                         jnp.asarray(c_temp, jnp.float32),
-                         jnp.asarray(c_top_k, jnp.int32),
-                         jnp.asarray(c_top_p, jnp.float32),
-                         c_key, jnp.asarray(c_oidx, jnp.int32))
+            outs = self._step_fn(*operands)
+            if ovl_on:
+                # dispatch returned, nothing materialized yet: the
+                # enqueue/device-wait boundary for the overlap split
+                t_enq = time.perf_counter()
             if spec_on:
                 (nxt, first, emitted, n_emit, dec_fin, spec_fin,
                  chunk_fin, self._pool_k, self._pool_v, self._pool_ks,
-                 self._pool_vs, self._dpool_k, self._dpool_v) = \
-                    self._step_fn(
-                        params, scales, self._draft_params,
-                        self._pool_k, self._pool_v, self._pool_ks,
-                        self._pool_vs, self._dpool_k, self._dpool_v,
-                        tables, lens, dec_tokens, dec_active,
-                        spec_active, chunk_ids,
-                        jnp.asarray(c_slot, jnp.int32),
-                        jnp.asarray(c_start, jnp.int32),
-                        jnp.asarray(c_len, jnp.int32), *samp_args)
-                if ovl_on:
-                    # dispatch returned, nothing materialized yet: the
-                    # enqueue/device-wait boundary for the overlap split
-                    t_enq = time.perf_counter()
+                 self._pool_vs, self._dpool_k, self._dpool_v) = outs
                 emitted = np.asarray(emitted)
                 n_emit = np.asarray(n_emit)
                 spec_fin = np.asarray(spec_fin)
             else:
                 (nxt, first, dec_fin, chunk_fin, self._pool_k,
-                 self._pool_v, self._pool_ks, self._pool_vs) = \
-                    self._step_fn(
-                        params, scales,
-                        self._pool_k, self._pool_v, self._pool_ks,
-                        self._pool_vs, tables, lens, dec_tokens,
-                        dec_active, chunk_ids,
-                        jnp.asarray(c_slot, jnp.int32),
-                        jnp.asarray(c_start, jnp.int32),
-                        jnp.asarray(c_len, jnp.int32), *samp_args)
-                if ovl_on:
-                    t_enq = time.perf_counter()
+                 self._pool_v, self._pool_ks, self._pool_vs) = outs
             nxt = np.asarray(nxt)
             dec_fin = np.asarray(dec_fin)
         # ITL = dispatch wall time only, captured BEFORE the host-side

@@ -34,40 +34,20 @@ from jax.sharding import PartitionSpec as P
 from . import layers as L
 
 
-class PagedKVCache(NamedTuple):
-    """One transformer layer's slice of the paged serving KV state —
-    the marker type ``_attention`` dispatches on for the
-    continuous-batching decode path (``inference/serving/``).
-
-      k_pool / v_pool  [num_blocks, block, kv_heads, head_dim] — or,
-                       with a quantized KV cache
-                       (``serving.kv_cache_bits``), int8 pools at
-                       ``head_dim`` (8-bit) / ``head_dim // 2``
-                       (packed 4-bit) width
-      block_tables     [B, pages] int32 (pool block ids; tail entries
-                       hold the reserved null block 0)
-      lens             [B] int32 — tokens ALREADY in the cache per slot
-                       (the new token writes at position ``lens``;
-                       0 = inactive slot)
-      k_scale / v_scale  [num_blocks, block, kv_heads] f32 per-row
-                       per-head dequant scales (None = bf16/f32 pools)
-    """
-    k_pool: Any
-    v_pool: Any
-    block_tables: Any
-    lens: Any
-    k_scale: Any = None
-    v_scale: Any = None
-
-
 class PagedMixedState(NamedTuple):
     """Paged serving state for the MIXED decode+chunked-prefill step
     (Sarathi-Serve style) — ``_attention`` dispatches on it when the
     serving engine coalesces one prompt chunk with the live decode
     slots into a single compiled program.
 
-    On top of :class:`PagedKVCache`'s pool/tables/lens:
-
+      k_pool / v_pool  [num_blocks, block, kv_heads * De] — one
+                   layer's slice of the pool, token-major with every kv
+                   head's row side by side (``De`` = head_dim, or
+                   head_dim // 2 for packed int4; layout rationale in
+                   ``ops/transformer/paged_decode_attention``)
+      block_tables [B, pages] int32 (pool block ids; tail entries hold
+                   the reserved null block 0)
+      lens         [B] int32 — rows ALREADY in the pool per slot
       dec_active   [B] int32 — 1 for slots decoding this iteration
                    (prefilling and empty slots are 0: their row of the
                    token batch is ignored and their KV write re-routes
@@ -92,8 +72,9 @@ class PagedMixedState(NamedTuple):
       spec_width   static Python int — rows per slot in the spec lane
                    (draft length k + 1); 0 = no spec lane, the
                    pre-speculation program byte-identical
-      k_scale / v_scale  per-row per-head dequant scales (see
-                   :class:`PagedKVCache`; None = unquantized pools)
+      k_scale / v_scale  [num_blocks, kv_heads, 1, block] f32 per-row
+                   per-head dequant scales of an int8 pool
+                   (``serving.kv_cache_bits``); None = unquantized
     """
     k_pool: Any
     v_pool: Any
@@ -536,8 +517,6 @@ class TransformerLM:
         paths like ring attention). The engine calls this at init."""
         self.mesh = mesh
 
-    _flash_fallback_warned = False
-
     def _sparse_decode_mask(self, idx, t: int, tk: int):
         """[1, H|1, t, tk] bool: the training layout's block rows gathered
         at the query positions — cached decode sees exactly the pattern
@@ -571,18 +550,6 @@ class TransformerLM:
         rows = jnp.take(layout_j, qpos // blk, axis=1)    # [H?, t, nbk]
         kmask = jnp.repeat(rows, blk, axis=-1)[..., :tk]  # [H?, t, tk]
         return kmask[None]                                # [1, H|1, t, tk]
-
-    def _warn_flash_fallback(self, tq: int, tk: int) -> None:
-        """Loud (once) on the flash→XLA perf cliff — a silent fallback hides
-        an O(T²)-HBM regression (VERDICT weak #6)."""
-        if not TransformerLM._flash_fallback_warned:
-            from ..utils.logging import logger
-            logger.warning(
-                f"flash attention unsupported for seq {tq}/{tk} (block-size "
-                f"divisibility) — falling back to XLA attention, which "
-                f"materializes the [B,H,T,T] score matrix. Pad the sequence "
-                f"to a multiple of the flash block for the fast path.")
-            TransformerLM._flash_fallback_warned = True
 
     def _norm_fn(self):
         """The configured norm apply with eps bound (single source for the
@@ -672,10 +639,6 @@ class TransformerLM:
             # chunk in a single program (chunked prefill)
             return self._paged_mixed_attention(p, q, k, v, cache_kv, t, nh,
                                                hd)
-        if isinstance(cache_kv, PagedKVCache):
-            # continuous-batching decode: per-slot write into the shared
-            # block pool + batched paged-attention kernel
-            return self._paged_attention(p, q, k, v, cache_kv, b, t, nh, hd)
         if cache_kv is None and c.attn_impl in ("ring", "ulysses",
                                                 "blocksparse"):
             # the flash kernel folds GQA via its k/v index maps and is NOT
@@ -722,15 +685,15 @@ class TransformerLM:
         if cache_kv is None and c.attn_impl == "flash" and \
                 c.pos_embedding != "alibi" and window is None:
             from ..ops.transformer.flash_attention import (
-                flash_attention_bthd, supports)
-            if supports(q.shape[1], k.shape[1]):
-                # k/v go in at kv-head width; ragged lengths are masked
-                # in-kernel (ceil grid), so mid-sized odd sequences no
-                # longer fall back to the O(T²) XLA path
-                o = flash_attention_bthd(q, k, v, causal=c.causal)
-                o = o.reshape(b, t, nh * hd)
-                return L.dense_apply(p["out"], o), None
-            self._warn_flash_fallback(q.shape[1], k.shape[1])
+                flash_attention_bthd)
+            # k/v go in at kv-head width; ragged lengths are masked
+            # in-kernel (ceil grid).  Over a multi-device mesh the
+            # kernel runs per shard (a Mosaic call cannot be
+            # auto-partitioned) — the engine binds the mesh
+            o = flash_attention_bthd(q, k, v, causal=c.causal,
+                                     mesh=self.mesh)
+            o = o.reshape(b, t, nh * hd)
+            return L.dense_apply(p["out"], o), None
         if cache_kv is not None:
             ck, cv, idx = cache_kv
             ck = jax.lax.dynamic_update_slice(ck, k.astype(ck.dtype),
@@ -744,17 +707,6 @@ class TransformerLM:
                 raise NotImplementedError(
                     "KV-cache decode on a non-causal (encoder) model is "
                     "meaningless — encoders have no autoregressive order")
-            if t == 1 and c.attn_impl == "flash" and \
-                    c.pos_embedding != "alibi":
-                # token-at-a-time hot path → fused Pallas decode kernel
-                # (reference softmax_context, csrc/.../softmax.cu)
-                from ..ops.transformer import decode_attention as DA
-                if DA.supports(hd, tk):
-                    o = DA.decode_attention(
-                        q[:, 0], expand_kv(ck).astype(q.dtype),
-                        expand_kv(cv).astype(q.dtype), idx + 1)[:, None]
-                    o = o.reshape(b, t, nh * hd)
-                    return L.dense_apply(p["out"], o), new_cache
             bias = None
             if c.pos_embedding == "alibi":
                 qpos = (positions[0] if positions is not None
@@ -818,69 +770,6 @@ class TransformerLM:
         o = o.reshape(b, t, nh * hd)
         return L.dense_apply(p["out"], o), new_cache
 
-    def _paged_attention(self, p, q, k, v, paged: PagedKVCache, b, t, nh,
-                         hd):
-        """Ragged-batch decode against a paged KV pool (one layer).
-
-        q/k/v [B, 1, nh|kvh, hd] — the new token per slot, rotary
-        already applied with per-slot positions.  The new k/v scatter
-        into each slot's current block (slots own disjoint blocks, so
-        the write indices never collide; inactive slots write into the
-        reserved null block 0), then the batched Pallas kernel attends
-        over the block tables with per-slot lengths — no per-step cache
-        copy, no ``jnp.pad``.  With a quantized pool
-        (``paged.k_scale is not None``) the new rows are encoded at the
-        scatter (``ops/quantizer/kv_quantize`` — one scale per row per
-        kv head, written alongside) and the kernel dequantizes in its
-        inner loop, so the pool never holds a full-precision copy."""
-        if t != 1:
-            raise NotImplementedError(
-                f"paged decode is token-at-a-time (t=1), got t={t} — "
-                f"prompts prefill through the dense cache path")
-        pool_k, pool_v = paged.k_pool, paged.v_pool
-        tables, lens = paged.block_tables, paged.lens
-        kscale, vscale = paged.k_scale, paged.v_scale
-        kv_bits = self._paged_kv_bits(pool_k, kscale, hd)
-        nb, blk = pool_k.shape[0], pool_k.shape[1]
-        slot = jnp.arange(b)
-        # write position of the new token: block_table[len // blk]
-        # offset len % blk, flattened over [nb * blk] rows
-        write = tables[slot, lens // blk] * blk + lens % blk
-        flat = (nb * blk,) + pool_k.shape[2:]
-        if kv_bits:
-            from ..ops.quantizer.quantizer import kv_quantize
-            kq, ks = kv_quantize(k[:, 0], kv_bits)    # [B,kvh,De],[B,kvh]
-            vq, vs = kv_quantize(v[:, 0], kv_bits)
-            sflat = (nb * blk,) + kscale.shape[2:]
-            pool_k = pool_k.reshape(flat).at[write].set(
-                kq).reshape(pool_k.shape)
-            pool_v = pool_v.reshape(flat).at[write].set(
-                vq).reshape(pool_v.shape)
-            kscale = kscale.reshape(sflat).at[write].set(
-                ks).reshape(paged.k_scale.shape)
-            vscale = vscale.reshape(sflat).at[write].set(
-                vs).reshape(paged.v_scale.shape)
-            kern_k, kern_v = pool_k, pool_v
-        else:
-            pool_k = pool_k.reshape(flat).at[write].set(
-                k[:, 0].astype(pool_k.dtype)).reshape(pool_k.shape)
-            pool_v = pool_v.reshape(flat).at[write].set(
-                v[:, 0].astype(pool_v.dtype)).reshape(pool_v.shape)
-            kern_k, kern_v = pool_k.astype(q.dtype), pool_v.astype(q.dtype)
-        from ..ops.transformer.paged_decode_attention import (
-            paged_decode_attention)
-        o = paged_decode_attention(
-            q[:, 0], kern_k, kern_v,
-            # inactive slots (lens 0) must stay 0 so the kernel's
-            # null-block page is masked off, not attended
-            jnp.where(lens > 0, lens + 1, 0), tables,
-            sm_scale=self._attn_scale,
-            k_scale=kscale, v_scale=vscale, kv_bits=kv_bits)
-        o = o.reshape(b, t, nh * hd)
-        pools = (pool_k, pool_v) if not kv_bits else \
-            (pool_k, pool_v, kscale, vscale)
-        return L.dense_apply(p["out"], o), pools
-
     def _paged_mixed_attention(self, p, q, k, v, st: PagedMixedState, t,
                                nh, hd):
         """One layer of the mixed decode+spec-verify+chunked-prefill step.
@@ -901,8 +790,10 @@ class TransformerLM:
         chunk kernel over the chunk slot's pages — and the outputs
         concatenate back into the shared projection.  A quantized pool
         (``st.k_scale is not None``) encodes every row at the combined
-        scatter and all kernels dequantize in-loop (see
-        :meth:`_paged_attention`).  ``S == 0`` and ``C == 0`` are
+        scatter (``ops/quantizer/kv_quantize`` — one scale per row per
+        kv head, written alongside, so the pool never holds a
+        full-precision copy) and the kernel dequantizes in its page
+        loop.  ``S == 0`` and ``C == 0`` are
         STATIC widths: the corresponding lane compiles away entirely,
         so the plain decode program is byte-identical to pre-spec
         builds."""
@@ -913,7 +804,7 @@ class TransformerLM:
         bsl = lens.shape[0]                   # decode slots
         sw = st.spec_width                    # spec rows per slot
         c = t - bsl - bsl * sw                # chunk width
-        nb, blk = pool_k.shape[0], pool_k.shape[1]
+        blk = pool_k.shape[1]
         npages = tables.shape[1]
         act = st.dec_active > 0
         slot = jnp.arange(bsl)
@@ -985,32 +876,25 @@ class TransformerLM:
                 out.append(a[bsl + bsl * sw:])
             return out
         write = shard_cat(writes)
-        flat = (nb * blk,) + pool_k.shape[2:]
+
+        def put(pool, rows):
+            # token rows [T, kvh, De] -> flat [nb * blk, kvh * De] rows
+            rows = shard_cat(seg(rows.astype(pool.dtype)))
+            return pool.reshape(-1, pool.shape[2]).at[write].set(
+                rows.reshape(rows.shape[0], -1)).reshape(pool.shape)
+
+        def put_scale(scale, rows):
+            # per-row per-head scales [T, kvh] -> [nb, kvh, 1, blk]
+            return scale.at[write // blk, :, 0, write % blk].set(
+                shard_cat(seg(rows)))
         if kv_bits:
             from ..ops.quantizer.quantizer import kv_quantize
             kq, ks = kv_quantize(k[0], kv_bits)   # [T,kvh,De],[T,kvh]
             vq, vs = kv_quantize(v[0], kv_bits)
-            kq, vq = shard_cat(seg(kq)), shard_cat(seg(vq))
-            ks, vs = shard_cat(seg(ks)), shard_cat(seg(vs))
-            sflat = (nb * blk,) + kscale.shape[2:]
-            pool_k = pool_k.reshape(flat).at[write].set(
-                kq).reshape(pool_k.shape)
-            pool_v = pool_v.reshape(flat).at[write].set(
-                vq).reshape(pool_v.shape)
-            kscale = kscale.reshape(sflat).at[write].set(
-                ks).reshape(st.k_scale.shape)
-            vscale = vscale.reshape(sflat).at[write].set(
-                vs).reshape(st.v_scale.shape)
-            pk, pv = pool_k, pool_v
+            pk, pv = put(pool_k, kq), put(pool_v, vq)
+            kscale, vscale = put_scale(kscale, ks), put_scale(vscale, vs)
         else:
-            kw = shard_cat(seg(k[0].astype(pool_k.dtype)))
-            vw = shard_cat(seg(v[0].astype(pool_v.dtype)))
-            pool_k = pool_k.reshape(flat).at[write].set(
-                kw).reshape(pool_k.shape)
-            pool_v = pool_v.reshape(flat).at[write].set(
-                vw).reshape(pool_v.shape)
-            pk = pool_k.astype(q.dtype)
-            pv = pool_v.astype(q.dtype)
+            pk, pv = put(pool_k, k[0]), put(pool_v, v[0])
         from ..ops.transformer.paged_decode_attention import (
             paged_decode_attention, paged_prefill_attention)
         o_parts = [paged_decode_attention(
@@ -1043,8 +927,7 @@ class TransformerLM:
         o = (o_parts[0] if len(o_parts) == 1
              else jnp.concatenate(o_parts, axis=0))[None]
         o = o.reshape(1, t, nh * hd)
-        pools = (pool_k, pool_v) if not kv_bits else \
-            (pool_k, pool_v, kscale, vscale)
+        pools = (pk, pv) if not kv_bits else (pk, pv, kscale, vscale)
         return L.dense_apply(p["out"], o), pools
 
     def _mlp(self, p, x):
@@ -1195,9 +1078,6 @@ class TransformerLM:
                 params, input_ids, train=False,
                 token_type_ids=token_type_ids)
             return self._project(params, x)
-
-        if "block_tables" in cache:
-            return self._apply_paged_decode(params, input_ids, cache)
 
         idx = cache["index"]
         if positions is None:
@@ -1374,54 +1254,12 @@ class TransformerLM:
     @staticmethod
     def _paged_kv_bits(pool_k, k_scale, hd: int) -> int:
         """Static kv-cache width from the pool's (trace-time) shape: 0
-        when unquantized, else 8 (int8 at full head_dim) or 4 (packed
-        nibbles at head_dim // 2)."""
+        when unquantized, else 8 (int8 rows at full head_dim) or 4
+        (packed nibbles at head_dim // 2) — ``pool_k [.., kvh * De]``
+        against the ``kvh`` of ``k_scale [nb, kvh, 1, blk]``."""
         if k_scale is None:
             return 0
-        return 8 if pool_k.shape[-1] == hd else 4
-
-    def _apply_paged_decode(self, params, input_ids, cache):
-        """Continuous-batching decode step: one new token per slot
-        against the paged KV pool.
-
-        ``cache``: {"k"/"v": [L, num_blocks, block, kv_heads, hd] pools
-        (int8 at hd | hd//2 width plus "k_scale"/"v_scale"
-        [L, num_blocks, block, kv_heads] f32 when quantized),
-        "block_tables": [B, pages] int32, "lens": [B] int32 (tokens
-        already cached per slot; 0 = inactive)}.  Returns
-        ``(logits [B, 1, V], cache with updated pools and lens + 1)``.
-        Slots advance independently — this is the program the serving
-        scheduler re-dispatches every iteration without retracing."""
-        reason = self._paged_supported()
-        if reason is not None:
-            raise NotImplementedError(reason)
-        if input_ids.shape[1] != 1:
-            raise NotImplementedError(
-                "paged decode consumes one token per slot per step")
-        tables, lens = cache["block_tables"], cache["lens"]
-        quant = cache.get("k_scale") is not None
-        positions = lens[:, None]          # each slot decodes at its own pos
-        x = self._embed_tokens(params, input_ids, positions=positions)
-
-        def scan_fn(carry, xs):
-            bp, *pools = xs
-            bp = self.block_transform(bp)
-            y, new_pools = self._block(
-                bp, carry, PagedKVCache(*pools[:2], tables, lens,
-                                        *pools[2:]), positions)
-            return y, new_pools
-
-        xs = (params["blocks"], cache["k"], cache["v"])
-        if quant:
-            xs += (cache["k_scale"], cache["v_scale"])
-        x, pools = jax.lax.scan(scan_fn, x, xs)
-        if self.config.final_layernorm:
-            x = self._norm_fn()(params["ln_f"], x)
-        new_cache = {"k": pools[0], "v": pools[1], "block_tables": tables,
-                     "lens": jnp.where(lens > 0, lens + 1, 0)}
-        if quant:
-            new_cache["k_scale"], new_cache["v_scale"] = pools[2], pools[3]
-        return self._project(params, x), new_cache
+        return 8 if pool_k.shape[-1] == k_scale.shape[1] * hd else 4
 
     def _apply_paged_mixed(self, params, cache, dec_tokens, dec_active,
                            chunk_ids, chunk_slot, chunk_start, chunk_len,
@@ -1434,8 +1272,8 @@ class TransformerLM:
         of the prompt-length distribution; the spec lane is Leviathan
         et al.'s verify step batched over slots).
 
-        ``cache``: {"k"/"v": [L, num_blocks, block, kv_heads, hd] pools,
-        "block_tables": [B, pages] int32, "lens": [B] int32 (rows
+        ``cache``: {"k"/"v" (+ "k_scale"/"v_scale"): the
+        :meth:`init_paged_cache` pools, "block_tables": [B, pages] int32, "lens": [B] int32 (rows
         already in the pool per slot)}.  ``dec_tokens``/``dec_active``
         [B] int32; ``chunk_ids`` [C] int32 (padded with anything past
         ``chunk_len``; C may be STATICALLY 0 — the chunk lane then
@@ -1540,13 +1378,18 @@ class TransformerLM:
         ``num_blocks`` fixed-size blocks of ``block_size`` tokens shared
         by every sequence through per-slot block tables (block 0 is the
         allocator's reserved null block).  Pools are per layer; tables
-        and lens start empty — the serving engine owns them.
+        and lens start empty — the serving engine owns them.  Layout
+        (what the TPU kernel's DMAs need; see
+        ``ops/transformer/paged_decode_attention``): ``k``/``v``
+        [layers, num_blocks, block, kv_heads * De], token-major with
+        the heads' rows side by side.
 
         ``kv_bits`` 8 or 4 stores the pool COMPRESSED: int8 values at
-        head_dim (8-bit) or packed-nibble head_dim // 2 (4-bit) width,
-        with per-row per-head f32 scales in ``k_scale``/``v_scale`` —
-        2x / ~3.8x more tokens per HBM byte, and the attention kernels
-        dequantize in their inner loop (``serving.kv_cache_bits``)."""
+        ``De`` = head_dim (8-bit) or packed-nibble head_dim // 2 (4-bit)
+        width, with per-row per-head f32 scales in ``k_scale``/
+        ``v_scale`` [layers, num_blocks, kv_heads, 1, block] — 2x /
+        ~3.8x more tokens per HBM byte, and the attention kernel
+        dequantizes in its page loop (``serving.kv_cache_bits``)."""
         reason = self._paged_supported()
         if reason is not None:
             raise NotImplementedError(reason)
@@ -1557,17 +1400,16 @@ class TransformerLM:
         if kv_bits == 4 and c.hdim % 2:
             raise ValueError(
                 f"packed int4 KV needs an even head_dim, got {c.hdim}")
-        if kv_bits:
-            d_eff = c.hdim if kv_bits == 8 else c.hdim // 2
-            shape = (c.num_layers, num_blocks, block_size, c.kv_heads,
-                     d_eff)
-            sshape = shape[:-1]
-            return {"k": jnp.zeros(shape, jnp.int8),
-                    "v": jnp.zeros(shape, jnp.int8),
-                    "k_scale": jnp.zeros(sshape, jnp.float32),
-                    "v_scale": jnp.zeros(sshape, jnp.float32)}
-        shape = (c.num_layers, num_blocks, block_size, c.kv_heads, c.hdim)
-        return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+        d_eff = c.hdim // 2 if kv_bits == 4 else c.hdim
+        shape = (c.num_layers, num_blocks, block_size, c.kv_heads * d_eff)
+        if not kv_bits:
+            return {"k": jnp.zeros(shape, dtype),
+                    "v": jnp.zeros(shape, dtype)}
+        sshape = (c.num_layers, num_blocks, c.kv_heads, 1, block_size)
+        return {"k": jnp.zeros(shape, jnp.int8),
+                "v": jnp.zeros(shape, jnp.int8),
+                "k_scale": jnp.zeros(sshape, jnp.float32),
+                "v_scale": jnp.zeros(sshape, jnp.float32)}
 
     def init_cache(self, batch: int, max_len: int, dtype=None) -> Dict:
         c = self.config

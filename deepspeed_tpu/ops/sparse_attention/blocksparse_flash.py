@@ -30,14 +30,11 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..pallas_compat import compiler_params
+from .. import resolve_interpret
 
 MASK_VALUE = -0.7 * float(np.finfo(np.float32).max)
 LANES = 128
 
-
-def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def compress_layout(layout: np.ndarray) -> Tuple[np.ndarray, np.ndarray,
@@ -152,7 +149,7 @@ def _fwd(q, k, v, idx, cnt, causal, sm_scale, block, nheads, interpret):
             jax.ShapeDtypeStruct(q.shape, q.dtype),
             jax.ShapeDtypeStruct((bh, 8, tq), jnp.float32),
         ],
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(idx, cnt, q, k, v)
@@ -279,7 +276,7 @@ def _bwd(causal, sm_scale, block, nheads, layout_c, interpret, res, do):
             scratch_shapes=[pltpu.VMEM((block, d), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(idx, cnt, q, k, v, do, lse, delta)
@@ -319,7 +316,7 @@ def _bwd(causal, sm_scale, block, nheads, layout_c, interpret, res, do):
             jax.ShapeDtypeStruct(k.shape, k.dtype),
             jax.ShapeDtypeStruct(v.shape, v.dtype),
         ],
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(idxT, cntT, q, k, v, do, lse, delta)
@@ -344,8 +341,7 @@ def blocksparse_attention(q, k, v, layout_c, block: int, nheads: int,
 def _bsa_fwd(q, k, v, layout_c, block, nheads, causal, sm_scale, interpret):
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
-    if interpret is None:
-        interpret = _interpret_default()
+    interpret = resolve_interpret(interpret)
     idx, cnt, _, _ = layout_c
     if q.shape[1] % block or k.shape[1] % block:
         raise ValueError(
@@ -359,8 +355,7 @@ def _bsa_fwd(q, k, v, layout_c, block, nheads, causal, sm_scale, interpret):
 def _bsa_bwd(layout_c, block, nheads, causal, sm_scale, interpret, res, do):
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(res[0].shape[-1])
-    if interpret is None:
-        interpret = _interpret_default()
+    interpret = resolve_interpret(interpret)
     return _bwd(causal, sm_scale, block, nheads, layout_c, interpret, res,
                 do)
 

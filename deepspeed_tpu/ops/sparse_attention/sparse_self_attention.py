@@ -26,7 +26,8 @@ from .sparsity_config import SparsityConfig
 
 MASK_VALUE = -1e30
 
-#: cost-based routing defaults, motivated by BENCH_ALL_r04 on the v5e:
+#: cost-based routing defaults, motivated by a pre-round v5e record
+#: (BENCH_ALL_r04, deleted in PR 21; in git at 95bdfc0; not re-measured):
 #: the sliding-window blocksparse path ran 101.31 ms at seq 8k (layout
 #: density 0.121) where dense flash took 17.02 ms, but won 2.58x at seq
 #: 16k (density 0.062: 103.07 vs 266.19 ms) — block sparsity only wins
@@ -144,11 +145,8 @@ class SparseSelfAttention:
         kind = self.mask_kind
         default_scale = abs(sm_scale - 1.0 / math.sqrt(q.shape[-1])) < 1e-12
         if kind in ("full", "causal") and default_scale and t >= 1024:
-            from ..transformer.flash_attention import (flash_attention_bthd,
-                                                       supports)
-            if supports(t, t):
-                return flash_attention_bthd(q, k, v,
-                                            causal=(kind == "causal"))
+            from ..transformer.flash_attention import flash_attention_bthd
+            return flash_attention_bthd(q, k, v, causal=(kind == "causal"))
         s = jnp.einsum("bqhd,bkhd->bhqk", q, k,
                        preferred_element_type=jnp.float32) * sm_scale
         if kind == "causal":

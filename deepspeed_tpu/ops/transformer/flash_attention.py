@@ -52,15 +52,15 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import PartitionSpec as P
 
-from ..pallas_compat import compiler_params
+from .. import resolve_interpret
+from ...parallel import topology as topo
+from ...parallel.shard_map_compat import shard_map
 
 MASK_VALUE = -0.7 * float(np.finfo(np.float32).max)
 LANES = 128
 
-
-def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def _ceil_div(a: int, b: int) -> int:
@@ -183,7 +183,7 @@ def _fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret):
         # accumulation dim. Mosaic needs this to double-buffer block DMAs
         # across grid steps — without it the kernel runs DMA-serial and
         # sits at <10% of the MXU.
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v)
@@ -353,7 +353,7 @@ def _bwd_fused(causal, sm_scale, interpret, q, k, v, do, lse, delta):
             jax.ShapeDtypeStruct(k.shape, k.dtype),
             jax.ShapeDtypeStruct(v.shape, v.dtype),
         ],
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(q, k, v, do, lse, delta)
@@ -389,7 +389,7 @@ def _bwd(causal, sm_scale, block_q, block_k, interpret, res, do):
         out_specs=pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v, do, lse, delta)
@@ -425,7 +425,7 @@ def _bwd(causal, sm_scale, block_q, block_k, interpret, res, do):
             pltpu.VMEM((block_k, d), jnp.float32),
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v, do, lse, delta)
@@ -451,8 +451,7 @@ def flash_attention(q, k, v, causal: bool = True,
 def _fa_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret):
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
-    if interpret is None:
-        interpret = _interpret_default()
+    interpret = resolve_interpret(interpret)
     if q.shape[0] % k.shape[0]:
         raise ValueError(
             f"flash_attention GQA needs query heads divisible by kv heads: "
@@ -466,8 +465,7 @@ def _fa_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret):
 def _fa_bwd(causal, sm_scale, block_q, block_k, interpret, res, do):
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(res[0].shape[-1])
-    if interpret is None:
-        interpret = _interpret_default()
+    interpret = resolve_interpret(interpret)
     block_q = min(block_q, res[0].shape[1])
     block_k = min(block_k, res[1].shape[1])
     return _bwd(causal, sm_scale, block_q, block_k, interpret, res, do)
@@ -479,22 +477,44 @@ flash_attention.defvjp(_fa_fwd, _fa_bwd)
 def flash_attention_bthd(q, k, v, causal: bool = True,
                          sm_scale: Optional[float] = None,
                          block_q: int = 1024, block_k: int = 1024,
-                         interpret: Optional[bool] = None):
+                         interpret: Optional[bool] = None, mesh=None):
     """Model-layout adapter: q [B, T, H, D], k/v [B, T, KVH, D] →
     [B, T, H, D]. KVH < H (grouped-query attention) streams k/v at
-    kv-head width through the kernel — no head-expansion copy."""
-    b, t, h, d = q.shape
-    def pack(x):
-        return x.transpose(0, 2, 1, 3).reshape(
-            b * x.shape[2], x.shape[1], d)
-    o = flash_attention(pack(q), pack(k), pack(v), causal, sm_scale,
-                        block_q, block_k, interpret)
-    return o.reshape(b, h, t, d).transpose(0, 2, 1, 3)
+    kv-head width through the kernel — no head-expansion copy.
+
+    ``mesh``: the device mesh the caller's jitted program spans.  XLA
+    cannot partition a Mosaic kernel automatically, so over more than
+    one device the kernel runs per shard under ``shard_map`` — batch
+    over the data axes, heads over ``model`` — manual on every mesh
+    axis an enclosing ``shard_map`` has not already made so.  A batch
+    or head count the axes do not divide stays replicated on them."""
+    def local(q, k, v):
+        b, t, h, d = q.shape
+
+        def pack(x):
+            return x.transpose(0, 2, 1, 3).reshape(
+                b * x.shape[2], x.shape[1], d)
+        o = flash_attention(pack(q), pack(k), pack(v), causal, sm_scale,
+                            block_q, block_k, interpret)
+        return o.reshape(b, h, t, d).transpose(0, 2, 1, 3)
+
+    manual = set(jax.sharding.get_abstract_mesh().manual_axes)
+    # Mosaic wants EVERY mesh axis manual, size-1 axes included
+    free = [] if mesh is None or (mesh.size == 1 and not manual) else [
+        a for a in mesh.axis_names if a not in manual]
+    if not free:
+        return local(q, k, v)
+    batch_axes, shards = [], 1
+    for a in (topo.DCN_DATA_AXIS, topo.DATA_AXIS, topo.EXPERT_AXIS):
+        if a in free and q.shape[0] % (shards * mesh.shape[a]) == 0:
+            batch_axes.append(a)
+            shards *= mesh.shape[a]
+    heads = (topo.MODEL_AXIS if topo.MODEL_AXIS in free
+             and k.shape[2] % mesh.shape[topo.MODEL_AXIS] == 0 else None)
+    spec = P(tuple(batch_axes) or None, None, heads, None)
+    # nested inside a manual region the context mesh is the only legal one
+    return shard_map(local, None if manual else mesh,
+                     in_specs=(spec, spec, spec), out_specs=spec,
+                     axis_names=free)(q, k, v)
 
 
-def supports(t_q: int, t_k: int, block_q: int = 1024,
-             block_k: int = 1024) -> bool:
-    """Ragged lengths are handled in-kernel (ceil grid + masking), so the
-    old block-divisibility gate is gone; kept as the models' capability
-    probe for any future constraint."""
-    return t_q > 0 and t_k > 0

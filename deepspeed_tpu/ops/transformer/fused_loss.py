@@ -2,7 +2,8 @@
 
 The loss head — hidden states [N, D] × vocab projection [D, V] →
 softmax-cross-entropy — is the last large phase of the training step
-(BENCH_r05: 62.7 ms at 0.505 efficiency). The autodiff formulation costs
+(62.7 ms at 0.505 efficiency in the pre-round record BENCH_r05,
+deleted in PR 21; in git at 95bdfc0). The autodiff formulation costs
 what this op avoids: ``jax.grad`` through ``logsumexp ∘ project``
 materializes a full [N, V] logit COTANGENT in HBM (at vocab 50k that is
 the biggest tensor of the whole backward), writes it, then immediately

@@ -2,7 +2,7 @@
 
 The ``(pipe, model, data)`` product lives here — topology (the only
 Mesh() owner in the tree, enforced by dstpu-lint MESH003), the
-version-compat ``shard_map`` wrapper every manual region goes through,
+in-tree ``shard_map`` wrapper every manual region goes through,
 and the exact-gradient collective pair (Megatron's f/g operators) the
 3D training region is built from. The composition invariant: each
 collective family owns one axis — ``ppermute`` moves stage-boundary

@@ -1,21 +1,10 @@
-"""Version portability for ``shard_map`` (the ``pallas_compat`` of SPMD).
+"""The in-tree ``shard_map`` call.
 
-jax moved ``shard_map`` from ``jax.experimental.shard_map`` to a
-top-level ``jax.shard_map`` and, in the same arc, replaced the
-``auto=frozenset(...)`` parameter (axes NOT handled manually) with
-``axis_names={...}`` (axes that ARE manual) and ``check_rep`` with
-``check_vma``.  Depending on the pinned jax exactly one spelling works:
-0.4.x ships only the experimental module, current jax only the
-top-level form.  Calling ``jax.shard_map(...)`` directly therefore
-raises ``AttributeError`` on 0.4.x — the same failure mode as the
-``pltpu.TPUCompilerParams`` rename, and the one that silently broke
-``ring_attention``/``ulysses_attention`` under the repo's CI jax.
-
-All in-tree shard_map call sites route through :func:`shard_map` below
-(``dstpu-lint`` MESH004 enforces this); new ones should too.  The
-wrapper speaks the NEW vocabulary (``axis_names`` = manual axes,
-``check`` = the rep/vma consistency check) and translates down when
-needed.
+Every shard_map call site goes through :func:`shard_map` below
+(``dstpu-lint`` MESH004 enforces it), so the two choices the package
+makes everywhere live in one place: regions name the mesh axes they are
+MANUAL over (``axis_names``; the rest stay GSPMD-auto), and the
+replication/VMA check is off unless a call site asks for it.
 """
 from __future__ import annotations
 
@@ -27,32 +16,17 @@ import jax
 def shard_map(f, mesh, in_specs, out_specs,
               axis_names: Optional[Iterable[str]] = None,
               check: bool = False):
-    """``jax.shard_map`` under whichever API this jax exports.
+    """``jax.shard_map`` with the package defaults.
 
-    ``axis_names``: the mesh axes the function is MANUAL over (the rest
-    stay GSPMD-auto); ``None`` — the ``jax.shard_map`` default — means
-    manual over every mesh axis.  ``check``: the replication/VMA
-    consistency check (``check_vma`` / ``check_rep`` depending on the
-    jax generation) — off by default, matching every in-tree call site.
-
-    Legacy degradation: when ``axis_names`` is a strict subset, 0.4.x
-    is asked for the partial-manual form it cannot fully deliver —
-    ``auto=`` regions there cannot lower ``axis_index``/``ppermute``
-    ("PartitionId ... is not supported for SPMD partitioning"), which
-    every in-tree partial-manual body uses.  So the legacy path always
-    goes FULLY manual: axes the specs do not mention are replicated
-    inside the region (same math, replicated compute over those axes).
-    Sharding-constraint hints over those axes are dropped inside the
-    region by ``zero/sharding.py constrain`` for the same reason.
-    Current jax keeps the efficient partial-manual form.
+    ``mesh``: the device mesh, or ``None`` inside an enclosing
+    ``shard_map`` — there the context mesh is the only legal one.
+    ``axis_names``: the mesh axes the function is MANUAL over; ``None``
+    — the ``jax.shard_map`` default — means every mesh axis.  A spec may
+    only name manual axes.  ``check``: the replication/VMA consistency
+    check (``check_vma``), off by default.
     """
-    manual = frozenset(mesh.axis_names if axis_names is None
-                       else axis_names)
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, axis_names=set(manual),
-                             check_vma=check)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=check,
-                      auto=frozenset())
+    kwargs = {} if mesh is None else {"mesh": mesh}
+    if axis_names is not None:
+        kwargs["axis_names"] = set(axis_names)
+    return jax.shard_map(f, in_specs=in_specs, out_specs=out_specs,
+                         check_vma=check, **kwargs)

@@ -20,24 +20,31 @@ import jax
 
 from ...utils.logging import logger
 
-# bf16 dense peak FLOPS per chip by TPU generation (public spec sheets).
+# bf16 dense peak FLOPS per chip by TPU generation (Google Cloud TPU
+# documentation, per-generation system architecture pages), keyed by a
+# substring of ``device_kind``.
 PEAK_FLOPS = {
     "v4": 275e12,
     "v5 lite": 197e12, "v5e": 197e12,
     "v5": 459e12, "v5p": 459e12,
     "v6 lite": 918e12, "v6e": 918e12,
-    "cpu": 1e12,  # nominal, so CPU runs still produce a number
 }
 
 
 def chip_peak_flops(device=None) -> float:
+    """Published bf16 peak of ``device`` (default: the first device).  A
+    device that is not in the table is an error, not a default — a
+    utilization against a made-up peak describes nothing."""
     if device is None:
         device = jax.devices()[0]
-    kind = getattr(device, "device_kind", "cpu").lower()
+    kind = device.device_kind.lower()
     for key, val in sorted(PEAK_FLOPS.items(), key=lambda kv: -len(kv[0])):
         if key in kind:
             return val
-    return 197e12
+    raise ValueError(
+        f"no published peak FLOP/s for device_kind "
+        f"{device.device_kind!r} (platform {device.platform!r}); known: "
+        f"{sorted(PEAK_FLOPS)}")
 
 
 def compiled_cost(fn: Callable, *args, **kwargs) -> Dict[str, float]:
@@ -47,10 +54,7 @@ def compiled_cost(fn: Callable, *args, **kwargs) -> Dict[str, float]:
     lowered = jax.jit(fn).lower(*args, **kwargs) if not hasattr(
         fn, "lower") else fn.lower(*args, **kwargs)
     compiled = lowered.compile()
-    cost = compiled.cost_analysis()
-    if isinstance(cost, list):  # older jax returns [dict]
-        cost = cost[0] if cost else {}
-    return dict(cost or {})
+    return dict(compiled.cost_analysis() or {})
 
 
 def transformer_flops_per_token(num_params: int, num_layers: int,

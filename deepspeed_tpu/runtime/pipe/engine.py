@@ -804,10 +804,8 @@ class PipelineEngine(DeepSpeedEngine):
             return self._build_train_step_traced()
 
     def _pipeline_gpipe_value_and_grad(self, params, ids, scale):
-        """Autodiff runs INSIDE the region: legacy jax (0.4.x) cannot
-        transpose the shard_map primitive itself (scalar residuals trip
-        ``_SpecError`` in the partial-eval / transpose pipeline), so the
-        gpipe path mirrors 1F1B's structure — grads are taken per stage
+        """Autodiff runs INSIDE the region, mirroring 1F1B's structure
+        — grads are taken per stage
         and the cross-stage contributions of the replicated leaves
         (embed/head/ln_f) psummed here, while block grads stay
         pipe-local like the params themselves. Dense models run the 3D
@@ -851,9 +849,9 @@ class PipelineEngine(DeepSpeedEngine):
                                             daxes)
         ids_spec = (P(None, daxes if len(daxes) > 1 else daxes[0])
                     if daxes else P())
-        names = {topo.PIPE_AXIS} | set(daxes)
-        if self._mp > 1:
-            names.add(topo.MODEL_AXIS)
+        # the region's param specs name `model` whatever its size, and a
+        # spec may only name manual axes
+        names = {topo.PIPE_AXIS, topo.MODEL_AXIS} | set(daxes)
         if self.schedule == "1f1b":
             fn = self._pipeline_value_and_grad
             # 1F1B assembles exactly the head/embed/blocks grads; subset

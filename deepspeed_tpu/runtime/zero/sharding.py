@@ -283,32 +283,8 @@ def to_named(mesh: Mesh, spec_tree):
         spec_tree, is_leaf=lambda x: isinstance(x, P))
 
 
-def _bound_axis_names():
-    """Mesh axes currently bound as MANUAL by an enclosing shard_map
-    trace (empty outside one, or when the internal API is absent)."""
-    try:
-        from jax._src.core import get_axis_env
-        return set(getattr(get_axis_env(), "axis_sizes", {}) or {})
-    except Exception:
-        return set()
-
-
 def constrain(tree, mesh: Mesh, spec_tree):
-    """with_sharding_constraint over a tree (inside jit).
-
-    Inside a fully-manual ``shard_map`` region (the legacy-jax
-    degradation of ``parallel/shard_map_compat.py``) a constraint
-    naming a manual axis is rejected at lowering; the constraint is a
-    layout HINT, so specs touching a manual axis are dropped there
-    rather than failing the compile.
-    """
-    manual = _bound_axis_names()
-
-    def one(x, s):
-        if manual:
-            named = {a for part in s if part is not None
-                     for a in ((part,) if isinstance(part, str) else part)}
-            if named & manual:
-                return x
-        return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, s))
-    return jax.tree_util.tree_map(one, tree, spec_tree)
+    """with_sharding_constraint over a tree (inside jit)."""
+    return jax.tree_util.tree_map(
+        lambda x, s: jax.lax.with_sharding_constraint(
+            x, NamedSharding(mesh, s)), tree, spec_tree)

@@ -35,8 +35,8 @@ RULE_CATALOG = {
     "CFG002": "*_DEFAULT constant consumed nowhere",
     "CFG003": "raw string config key not declared in constants.py",
     "TEST001": "pytest marker not registered in pytest.ini",
-    "PALLAS001": "direct pltpu.CompilerParams construction bypassing "
-                 "pallas_compat.compiler_params()",
+    "PALLAS001": "Pallas TPU name the installed jax retired "
+                 "(TPUCompilerParams, pltpu.ANY)",
     "PALLAS002": "select-by-multiply on a mask in a kernel (0*NaN "
                  "poison) — use jnp.where(mask, v, 0)",
     "PALLAS003": "non-f32 scratch accumulator in a pallas_call kernel",
@@ -47,7 +47,7 @@ RULE_CATALOG = {
     "MESH002": "collective over an axis name topology.py does not "
                "declare",
     "MESH003": "Mesh(...) constructed outside parallel/topology.py",
-    "MESH004": "jax.shard_map spelling bypassing "
+    "MESH004": "shard_map call site bypassing "
                "parallel/shard_map_compat",
     "LIFE001": "allocator allocate/fork with no reachable free",
     "LIFE002": "terminal RequestStatus stamped outside _terminalize()",

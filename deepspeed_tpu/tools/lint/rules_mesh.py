@@ -16,12 +16,11 @@ machine-checked BEFORE it lands:
            — device order IS the topology contract (model innermost
            rides ICI); route through ``build_mesh``.  Hard-coded
            device-list literals upgrade the finding to error.
-  MESH004  ``jax.shard_map`` attribute use or
-           ``jax.experimental.shard_map`` import outside
-           ``parallel/shard_map_compat.py`` — exactly one spelling
-           exists per jax version (the rename that broke
-           ring/ulysses attention under the CI jax); route through the
-           compat wrapper
+  MESH004  ``jax.shard_map`` attribute use or a ``shard_map`` import
+           from jax outside ``parallel/shard_map_compat.py`` — the
+           wrapper there holds the package's defaults (named manual
+           axes, ``check_vma`` off), and ``jax.experimental.shard_map``
+           is gone from the installed jax
 
 MESH002's declared-axis set is parsed from the project's
 ``parallel/topology.py`` (``AXIS_ORDER`` elements + ``*_AXIS`` string
@@ -159,7 +158,7 @@ def _check_mesh_ctor(mod: SourceModule, call: ast.Call,
 
 
 # ---------------------------------------------------------------------------
-# MESH004 — shard_map spelling bypassing the compat wrapper
+# MESH004 — shard_map call sites bypassing the in-tree wrapper
 # ---------------------------------------------------------------------------
 def _check_shard_map_compat(mod: SourceModule, symtab,
                             findings: List[Finding]) -> None:
@@ -170,10 +169,9 @@ def _check_shard_map_compat(mod: SourceModule, symtab,
             findings.append(Finding(
                 rule="MESH004", severity=Severity.ERROR, path=mod.rel,
                 line=node.lineno, col=node.col_offset,
-                message="`jax.shard_map` does not exist on every "
-                        "supported jax (0.4.x ships only the "
-                        "experimental module) — route through "
-                        "parallel/shard_map_compat.shard_map",
+                message="direct `jax.shard_map` — route through "
+                        "parallel/shard_map_compat.shard_map, which "
+                        "holds the package's defaults",
                 scope=enclosing_scope(node), detail="jax.shard_map"))
     idx = symtab.index(mod)
     seen: Set[str] = set()
@@ -187,8 +185,7 @@ def _check_shard_map_compat(mod: SourceModule, symtab,
         findings.append(Finding(
             rule="MESH004", severity=Severity.ERROR, path=mod.rel,
             line=1, col=0,
-            message=f"importing shard_map from `{src}` — exactly "
-                    f"one spelling exists per jax version; route "
+            message=f"importing shard_map from `{src}` — route "
                     f"through parallel/shard_map_compat.shard_map",
             detail=f"import:{src}"))
 

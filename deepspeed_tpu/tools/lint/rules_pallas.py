@@ -1,16 +1,14 @@
 """PALLAS — TPU kernel hazards in ``pallas_call`` kernels and wrappers.
 
 The serving stack's worst bugs were kernel-shaped and mechanically
-detectable: the ``pltpu.CompilerParams`` rename broke 20 tests until the
-compat shim (PR 5), and a masked ``0 × NaN`` v-row re-poisoned recycled
-KV blocks until the zeroing convention (PR 6).  These rules pin both
-conventions, plus the accumulator/DMA disciplines the in-tree kernels
-follow:
+detectable: a Pallas name the installed jax had renamed broke 20 tests
+(PR 5), and a masked ``0 × NaN`` v-row re-poisoned recycled KV blocks
+until the zeroing convention (PR 6).  These rules pin both, plus the
+accumulator/DMA disciplines the in-tree kernels follow:
 
-  PALLAS001  direct ``pltpu.CompilerParams``/``TPUCompilerParams``
-             construction — bypasses ``ops/pallas_compat.py``'s
-             ``compiler_params()`` (exactly one of the two names exists
-             per jax version; direct use breaks on the other)
+  PALLAS001  a Pallas TPU name the installed jax has removed or
+             deprecated: ``pltpu.TPUCompilerParams`` (now
+             ``pltpu.CompilerParams``), ``pltpu.ANY`` (now ``pl.ANY``)
   PALLAS002  select-by-multiply on a boolean mask inside a kernel
              (``mask * v``) — masked rows give probability ~0 but
              ``0 * NaN = NaN``, so recycled-pool garbage poisons the
@@ -38,9 +36,8 @@ from .core import (Finding, Project, Severity, SourceModule,
                    callee_name as _callee_attr, enclosing_function,
                    get_symtab, src_of as _src)
 
-COMPAT_REL = "ops/pallas_compat.py"
-
-_CP_NAMES = {"CompilerParams", "TPUCompilerParams"}
+#: retired ``pltpu`` attribute -> its replacement
+_RETIRED = {"TPUCompilerParams": "pltpu.CompilerParams", "ANY": "pl.ANY"}
 _ACC_BAD_DTYPES = {"bfloat16", "float16", "float8_e4m3fn", "float8_e5m2"}
 #: call roots an index_map may use (pure, trace-safe index math)
 _INDEX_OK_ROOTS = {"jnp", "jax", "lax", "pl", "pltpu"}
@@ -79,34 +76,29 @@ def _is_kernel_fn(fn: ast.AST, kernel_names: Set[str]) -> bool:
 
 
 # ---------------------------------------------------------------------------
-# PALLAS001 — CompilerParams bypass
+# PALLAS001 — retired Pallas TPU names
 # ---------------------------------------------------------------------------
-def _check_compiler_params(mod: SourceModule, symtab,
-                           findings: List[Finding]) -> None:
-    if mod.rel.endswith(COMPAT_REL):
-        return  # the shim itself is the one blessed construction site
+def _check_retired_names(mod: SourceModule, symtab,
+                         findings: List[Finding]) -> None:
     for node in symtab.attributes[mod.rel]:
-        if node.attr in _CP_NAMES:
-            findings.append(Finding(
-                rule="PALLAS001", severity=Severity.ERROR, path=mod.rel,
-                line=node.lineno, col=node.col_offset,
-                message=f"direct `{_src(node)}` use — exactly one of "
-                        f"CompilerParams/TPUCompilerParams exists per "
-                        f"jax version; route through "
-                        f"ops/pallas_compat.compiler_params()",
-                scope=_scope_of(node), detail=node.attr))
-    idx = symtab.index(mod)
-    # sorted: both hits land at line 1 col 0, so emission order is the
-    # only tiebreak between them (DET002 applied to our own source)
-    for name in sorted(_CP_NAMES):
-        tgt = idx.from_imports.get(name)
-        if tgt is not None:
-            findings.append(Finding(
-                rule="PALLAS001", severity=Severity.ERROR, path=mod.rel,
-                line=1, col=0,
-                message=f"importing `{name}` from {tgt[0]} — route "
-                        f"through ops/pallas_compat.compiler_params()",
-                detail=f"import:{name}"))
+        new = _RETIRED.get(node.attr)
+        if new is None or not (isinstance(node.value, ast.Name)
+                               and node.value.id == "pltpu"):
+            continue
+        findings.append(Finding(
+            rule="PALLAS001", severity=Severity.ERROR, path=mod.rel,
+            line=node.lineno, col=node.col_offset,
+            message=f"`{_src(node)}` is retired in the installed jax "
+                    f"— use `{new}`",
+            scope=_scope_of(node), detail=node.attr))
+    tgt = symtab.index(mod).from_imports.get("TPUCompilerParams")
+    if tgt is not None:
+        findings.append(Finding(
+            rule="PALLAS001", severity=Severity.ERROR, path=mod.rel,
+            line=1, col=0,
+            message=f"importing `TPUCompilerParams` from {tgt[0]} — the "
+                    f"installed jax exports `CompilerParams`",
+            detail="import:TPUCompilerParams"))
 
 
 def _scope_of(node: ast.AST) -> str:
@@ -294,7 +286,7 @@ def run(project: Project) -> List[Finding]:
     symtab = get_symtab(project)
     findings: List[Finding] = []
     for mod in project.modules:
-        _check_compiler_params(mod, symtab, findings)
+        _check_retired_names(mod, symtab, findings)
         kernel_names = _kernel_names_for(symtab.calls[mod.rel])
         for fn in symtab.functions[mod.rel]:
             if _is_kernel_fn(fn, kernel_names):

@@ -91,3 +91,42 @@ class TestScheduledTune:
         model, cfg = Autotuner.apply_best(tiny_model(), best)
         assert model.config.remat == "full"
         assert "_model_overrides" not in cfg
+
+
+class TestOneProcessPerChip:
+    """A chip belongs to one process: a parent that holds it must not
+    spawn children that need it (they fail or hang)."""
+
+    def test_parent_holding_the_chip_refuses_to_schedule(self, tmp_path,
+                                                         monkeypatch):
+        from deepspeed_tpu.autotuning import scheduler
+        monkeypatch.setattr(scheduler, "initialized_accelerator",
+                            lambda: "tpu")
+        rm = ResourceManager(slots=1)
+        with pytest.raises(RuntimeError, match="holds the chip"):
+            rm.run([{"cfg": {}}], str(tmp_path))
+
+    def test_parallel_slots_are_cpu_only(self, monkeypatch):
+        monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+        with pytest.raises(ValueError, match="whole accelerator"):
+            ResourceManager(slots=2)
+        assert ResourceManager(slots=2, env=CPU_ENV).slots == 2
+
+    def test_spawning_parents_stay_off_jax(self):
+        """Importing the launcher, the elastic agents and the scheduler
+        initializes no JAX backend — the property that lets them spawn
+        workers that own the chip."""
+        import subprocess
+        import sys
+        code = (
+            "import deepspeed_tpu.launcher.runner, "
+            "deepspeed_tpu.elasticity.elastic_agent, "
+            "deepspeed_tpu.elasticity.rendezvous, "
+            "deepspeed_tpu.autotuning.scheduler as s\n"
+            "from jax._src import xla_bridge\n"
+            "assert not xla_bridge.backends_are_initialized()\n"
+            "assert s.initialized_accelerator() is None\n")
+        subprocess.run([sys.executable, "-c", code], check=True,
+                       timeout=120,
+                       cwd=os.path.dirname(os.path.dirname(os.path.dirname(
+                           os.path.abspath(__file__)))))

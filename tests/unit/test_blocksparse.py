@@ -212,7 +212,7 @@ class TestSparseDecode:
 
 class TestCostRouting:
     """PR-4 satellite: SparseSelfAttention routes to a dense path when
-    the layout cannot beat it (BENCH_ALL_r04 motivation: sliding-window
+    the layout cannot beat it (pre-round chip record: sliding-window
     blocksparse 101.31 ms vs 17.02 ms dense flash at seq 8k, a 2.58x
     WIN at 16k — sparsity only pays once it prunes most of the work).
     Semantics are identical either route; the masked dense fallback is

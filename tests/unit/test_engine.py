@@ -223,14 +223,3 @@ class TestBatchReconciliation:
         with pytest.raises(ValueError):
             ds.initialize(model=tiny_model(), config=base_config(
                 train_batch_size=17))
-
-
-class TestGraftEntry:
-    @pytest.mark.slow
-    def test_dryrun_multichip(self):
-        import importlib.util
-        spec = importlib.util.spec_from_file_location(
-            "graft_entry", "/root/repo/__graft_entry__.py")
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        mod.dryrun_multichip(8)

@@ -655,36 +655,28 @@ def test_slot_store_close_waits_for_pins(tmp_path):
 # ---------------------------------------------------------------------------
 # PALLAS family — kernel hazards (PR 7)
 # ---------------------------------------------------------------------------
-def test_pallas_compiler_params_bypass(tmp_path):
+def test_pallas_retired_names(tmp_path):
+    """Names the installed jax removed or deprecated are flagged; their
+    replacements are clean."""
     fs = run_lint(tmp_path, {"ops/kern.py": """\
+        from jax.experimental import pallas as pl
         from jax.experimental.pallas import tpu as pltpu
 
         def build():
             return pltpu.CompilerParams(dimension_semantics=("parallel",))
 
+        def spec():
+            return pl.BlockSpec(memory_space=pl.ANY)
+
         def build_old():
             return pltpu.TPUCompilerParams()
+
+        def spec_old():
+            return pl.BlockSpec(memory_space=pltpu.ANY)
         """})
-    assert [f.rule for f in fs].count("PALLAS001") == 2
-    assert all(f.severity == "error" for f in fs)
-
-
-def test_pallas_compiler_params_shim_exempt(tmp_path):
-    """The shim module itself (and compiler_params() users) stay clean."""
-    fs = run_lint(tmp_path, {"ops/pallas_compat.py": """\
-        from jax.experimental.pallas import tpu as pltpu
-        _CLS = getattr(pltpu, "CompilerParams", None) or \\
-            getattr(pltpu, "TPUCompilerParams")
-
-        def compiler_params(**kw):
-            return _CLS(**kw)
-        """, "ops/kern.py": """\
-        from .pallas_compat import compiler_params
-
-        def build():
-            return compiler_params(dimension_semantics=("parallel",))
-        """})
-    assert [f for f in fs if f.rule == "PALLAS001"] == []
+    hits = [f for f in fs if f.rule == "PALLAS001"]
+    assert sorted(f.detail for f in hits) == ["ANY", "TPUCompilerParams"]
+    assert all(f.severity == "error" for f in hits)
 
 
 def test_pallas_select_by_multiply(tmp_path):
@@ -882,9 +874,8 @@ def test_mesh_ctor_outside_topology(tmp_path):
 
 
 def test_mesh_shard_map_compat_bypass(tmp_path):
-    """The rename class that killed ring/ulysses on the pinned jax:
-    jax.shard_map attribute use AND experimental imports are flagged;
-    the compat wrapper import is the fix."""
+    """Direct jax.shard_map use AND shard_map imports from jax are
+    flagged; the in-tree wrapper import is the fix."""
     fs = run_lint(tmp_path, {
         "parallel/topology.py": _TOPO_FIXTURE,
         "a.py": """\

@@ -92,8 +92,21 @@ class TestTimers:
         assert "fwd" in line and "missing" not in line
 
 
+@pytest.fixture
+def cpu_peak(monkeypatch):
+    """The peak table has no row for the CPU mesh (chip_peak_flops
+    raises there); tests of the MFU plumbing bring their own."""
+    from deepspeed_tpu.profiling.flops_profiler import profiler
+    monkeypatch.setitem(profiler.PEAK_FLOPS, "cpu", 1e12)
+
+
 class TestFlopsProfiler:
-    def test_profile_and_mfu(self):
+    def test_unknown_device_has_no_peak(self):
+        from deepspeed_tpu.profiling.flops_profiler import chip_peak_flops
+        with pytest.raises(ValueError, match="no published peak"):
+            chip_peak_flops()
+
+    def test_profile_and_mfu(self, cpu_peak):
         from deepspeed_tpu.profiling.flops_profiler import FlopsProfiler
         engine, _, _, _ = ds.initialize(model=tiny_model(), config={
             "train_batch_size": 16, "gradient_accumulation_steps": 2,
@@ -111,7 +124,7 @@ class TestFlopsProfiler:
         mfu = prof.mfu(step_time_s=1.0)
         assert 0 < mfu < 1
 
-    def test_engine_reports_mfu_in_monitor(self, tmp_path):
+    def test_engine_reports_mfu_in_monitor(self, tmp_path, cpu_peak):
         config = {
             "train_batch_size": 16, "gradient_accumulation_steps": 2,
             "optimizer": {"type": "AdamW", "params": {"lr": 1e-3}},

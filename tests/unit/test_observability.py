@@ -868,11 +868,10 @@ class TestCommsBw:
     def test_axis_size_captured_at_trace_time(self, mesh8):
         """The WIRING, not just the formula: tracing a collective through
         deepspeed_tpu.comm records the axis size, so log_summary's wire
-        volume is non-zero in production (jax 0.4.x has no
-        lax.axis_size — the psum(1) fallback must carry it)."""
+        volume is non-zero in production."""
         import jax
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
+        from deepspeed_tpu.parallel.shard_map_compat import shard_map
         from deepspeed_tpu.comm import comm
         from deepspeed_tpu.comm.comms_logging import (configure,
                                                       get_comms_logger)
@@ -883,7 +882,7 @@ class TestCommsBw:
         def f(x):
             return comm.all_reduce(x, axis_name="data")
         with mesh8:
-            jax.jit(shard_map(f, mesh=mesh8, in_specs=P("data"),
+            jax.jit(shard_map(f, mesh8, in_specs=P("data"),
                               out_specs=P()))(
                 np.arange(8, dtype=np.float32))
         recs = cl.comms_dict["all_reduce"]

@@ -75,8 +75,20 @@ def make_case(lens, bs, nb, h=4, hkv=4, d=32, seed=0, garbage=None):
         if garbage is not None and ln % bs:
             pk[bt[i, -(-ln // bs) - 1], ln % bs:] = garbage
             pv[bt[i, -(-ln // bs) - 1], ln % bs:] = garbage
-    return (jnp.asarray(q), jnp.asarray(pk), jnp.asarray(pv),
+    return (jnp.asarray(q), pool_layout(pk), pool_layout(pv),
             jnp.asarray(lens, jnp.int32), jnp.asarray(bt))
+
+
+def pool_layout(rows):
+    """[nb, bs, hkv, De] token rows -> the kernel's token-major pool
+    [nb, bs, hkv * De] (heads side by side in the lane dim)."""
+    return jnp.asarray(rows).reshape(*rows.shape[:2], -1)
+
+
+def scale_layout(scale):
+    """kv_quantize's [nb, bs, hkv] row scales -> the kernel's
+    [nb, hkv, 1, bs] lane-major scale planes."""
+    return jnp.asarray(scale).transpose(0, 2, 1)[:, :, None]
 
 
 class TestPagedDecodeKernel:
@@ -137,8 +149,8 @@ class TestPagedDecodeKernel:
         b, h, d = 2, 2, 64
         lens = [16384, 700]
         q = jnp.asarray(rng.standard_normal((b, h, d)), jnp.bfloat16)
-        pk = jnp.asarray(rng.standard_normal((nb, bs, h, d)), jnp.bfloat16)
-        pv = jnp.asarray(rng.standard_normal((nb, bs, h, d)), jnp.bfloat16)
+        pk = jnp.asarray(rng.standard_normal((nb, bs, h * d)), jnp.bfloat16)
+        pv = jnp.asarray(rng.standard_normal((nb, bs, h * d)), jnp.bfloat16)
         maxp = 32
         bt = np.zeros((b, maxp), np.int32)
         bt[0] = np.arange(1, 33)
@@ -182,7 +194,7 @@ def make_prefill_case(base, chunk_len, c, bs, nb, h=4, hkv=4, d=32,
     if garbage is not None and total % bs:
         pk[bt[npages - 1], total % bs:] = garbage
         pv[bt[npages - 1], total % bs:] = garbage
-    return (jnp.asarray(q), jnp.asarray(pk), jnp.asarray(pv),
+    return (jnp.asarray(q), pool_layout(pk), pool_layout(pv),
             jnp.asarray(base, jnp.int32), jnp.asarray(chunk_len, jnp.int32),
             jnp.asarray(bt))
 
@@ -248,9 +260,11 @@ def quantize_case(q, pk, pv, bits):
     """Quantize a make_case pool to ``bits`` (NaN rows quantize to NaN
     scales — exactly what a recycled quarantine-discarded block holds)."""
     from deepspeed_tpu.ops.quantizer import kv_quantize
-    kq, ks = kv_quantize(pk, bits)
-    vq, vs = kv_quantize(pv, bits)
-    return kq, vq, ks, vs
+    rows = lambda p: p.reshape(*p.shape[:2], -1, q.shape[-1])  # noqa: E731
+    kq, ks = kv_quantize(rows(pk), bits)
+    vq, vs = kv_quantize(rows(pv), bits)
+    return (pool_layout(kq), pool_layout(vq), scale_layout(ks),
+            scale_layout(vs))
 
 
 class TestMultiPageQuantizedKernels:
@@ -327,7 +341,10 @@ class TestMultiPageQuantizedKernels:
             out = paged_decode_attention(q, kq, vq, ln, bt,
                                          interpret=True, k_scale=ks,
                                          v_scale=vs, kv_bits=bits)
-            want = kv_dequantize(vq, vs, bits)[np.asarray(bt)[0, 0], 0]
+            # block bt[0, 0], token row 0, as [hkv, d]
+            blk = int(np.asarray(bt)[0, 0])
+            want = kv_dequantize(vq[blk, 0].reshape(2, -1),
+                                 vs[blk, :, 0, 0], bits)
             np.testing.assert_allclose(np.asarray(out)[0],
                                        np.asarray(want), atol=1e-6)
 
@@ -1052,7 +1069,8 @@ class TestServingEngine:
         engine must drain leak-free with finite full-length streams
         from one compiled program."""
         _, srv = serving_engine(serving={"kv_cache_bits": 4})
-        assert srv._pool_k.shape[-1] == 4            # hdim 8, packed
+        # 4 kv heads x hdim 8, packed two features a byte
+        assert srv._pool_k.shape[-1] == 4 * 4
         rs = np.random.RandomState(19)
         reqs = [srv.submit(rs.randint(0, 64, (n,)).tolist(),
                            max_new_tokens=5) for n in (9, 6)]
@@ -1389,6 +1407,16 @@ class TestLifecycleEngine:
         assert srv.allocator.num_used == 0
         assert srv.lifecycle_counts["cancelled"] == 1
         assert srv.lifecycle_counts["timed_out"] == 1
+        # a second run() re-dispatches with the pools the first one
+        # returned: their sharding is part of the jit cache key, so a
+        # pool created in any other placement retraces here (the jax 0.9
+        # regression PR 21 repaired through pool placement)
+        r_again = srv.submit(p_cancel, max_new_tokens=8)
+        srv.run()
+        assert r_again.status is RequestStatus.OK
+        np.testing.assert_array_equal(np.asarray(r_again.output),
+                                      _generate(eng, p_cancel, 8))
+        assert srv.decode_builds == 1
 
     def test_shed_on_overload(self):
         """Bounded backpressure: beyond max_queue_depth, submit()

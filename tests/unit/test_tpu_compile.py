@@ -1,0 +1,260 @@
+"""Chip-less TPU v5e compile check for every Pallas kernel the package
+ships and for the flagship train step's ``value_and_grad`` on a
+four-device mesh, plus a TPU lowering of the programs the two engines
+really build.
+
+The CPU suite runs the kernels in the Pallas interpreter, which inlines
+them into ordinary HLO — so it can never see what the Mosaic compiler
+refuses (unaligned DMA slabs, dot shapes) or that XLA will not
+partition a Mosaic call over a mesh.  libtpu accepts a COMPILE-ONLY
+topology without a chip: the functions below are traced on abstract
+arguments placed on that topology and compiled for ``tpu``; nothing
+executes.  What a kernel computes is pinned by the interpret-mode
+parity tests; that it runs is pinned by ``chip_smoke.py`` on the chip.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+from deepspeed_tpu import ops
+from deepspeed_tpu.models import TransformerLM, gpt2_config
+from deepspeed_tpu.ops.sparse_attention import FixedSparsityConfig
+from deepspeed_tpu.ops.sparse_attention.blocksparse_flash import (
+    blocksparse_attention_bthd)
+from deepspeed_tpu.ops.transformer.flash_attention import (
+    flash_attention_bthd)
+from deepspeed_tpu.ops.transformer.paged_decode_attention import (
+    paged_decode_attention, paged_prefill_attention)
+from deepspeed_tpu.parallel.topology import build_mesh
+from deepspeed_tpu.runtime.config import MeshConfig
+
+_TOPOLOGY_ENV = {"TPU_SKIP_MDS_QUERY": "1",
+                 "TPU_ACCELERATOR_TYPE": "v5litepod-4",
+                 "TPU_WORKER_HOSTNAMES": "localhost"}
+
+
+@pytest.fixture(scope="module")
+def v5e_devices():
+    """The four devices of a compile-only v5e 2x2 topology."""
+    saved = {k: os.environ.get(k) for k in _TOPOLOGY_ENV}
+    os.environ.update(_TOPOLOGY_ENV)
+    try:
+        from jax.experimental import topologies
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:   # noqa: BLE001 — no libtpu / no topology: skip
+        pytest.skip(f"compile-only v5e topology cannot be built: {e!r}")
+    finally:
+        for k, v in saved.items():
+            if v is None:
+                os.environ.pop(k, None)
+            else:
+                os.environ[k] = v
+    return topo.devices
+
+
+@pytest.fixture
+def compiled_kernels():
+    """Inside the test the package's kernels are built COMPILED (the
+    suite-wide interpret switch is what this module must not use)."""
+    ops.interpret_kernels(False)
+    yield
+    ops.interpret_kernels(True)
+
+
+def compile_for_tpu(fn, *args):
+    """Lower ``fn`` for the TPU platform on abstract args and compile;
+    returns the optimized HLO text."""
+    return jax.jit(fn).trace(*args).lower(
+        lowering_platforms=("tpu",)).compile().as_text()
+
+
+def one_chip(devices):
+    mesh = Mesh(np.array(devices[:1]), ("x",))
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=NamedSharding(mesh, P()))
+    return sds
+
+
+@pytest.mark.parametrize("d", [64, 128])
+@pytest.mark.parametrize("t", [1000, 2048])
+def test_flash_fwd_bwd_compiles(v5e_devices, compiled_kernels, d, t):
+    """Ragged (1000) and multi-block (2048) lengths; the single-block
+    fused backward (T <= 1024) holds its 1024 x 1024 f32 score blocks
+    in VMEM, which the compiler checks against the scoped limit."""
+    sds = one_chip(v5e_devices)
+    qkv = [sds((2, t, 8, d), jnp.bfloat16)] * 3
+
+    def loss(q, k, v):
+        return flash_attention_bthd(q, k, v).astype(jnp.float32).sum()
+    text = compile_for_tpu(jax.value_and_grad(loss, argnums=(0, 1, 2)),
+                           *qkv)
+    assert text.count("tpu_custom_call") >= 2       # fwd + bwd kernels
+
+
+@pytest.mark.parametrize("d", [64, 128])
+def test_flash_gqa_compiles(v5e_devices, compiled_kernels, d):
+    sds = one_chip(v5e_devices)
+    q = sds((2, 2048, 8, d), jnp.bfloat16)
+    kv = sds((2, 2048, 2, d), jnp.bfloat16)
+
+    def loss(q, k, v):
+        return flash_attention_bthd(q, k, v).astype(jnp.float32).sum()
+    text = compile_for_tpu(jax.value_and_grad(loss, argnums=(0, 1, 2)),
+                           q, kv, kv)
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("d", [64, 128])
+def test_blocksparse_fwd_bwd_compiles(v5e_devices, compiled_kernels, d):
+    sds = one_chip(v5e_devices)
+    cfg = FixedSparsityConfig(num_heads=8, block=128, num_local_blocks=4,
+                              attention="unidirectional")
+    qkv = [sds((2, 2048, 8, d), jnp.bfloat16)] * 3
+
+    def loss(q, k, v):
+        return blocksparse_attention_bthd(q, k, v, cfg).astype(
+            jnp.float32).sum()
+    text = compile_for_tpu(jax.value_and_grad(loss, argnums=(0, 1, 2)),
+                           *qkv)
+    assert text.count("tpu_custom_call") >= 2
+
+
+def paged_args(sds, d, kv_bits, hkv, block, nb=64):
+    d_eff = d // 2 if kv_bits == 4 else d
+    pool = sds((nb, block, hkv * d_eff),
+               jnp.int8 if kv_bits else jnp.bfloat16)
+    scale = sds((nb, hkv, 1, block), jnp.float32)
+    return pool, (scale if kv_bits else None)
+
+
+@pytest.mark.parametrize("d", [64, 128])
+@pytest.mark.parametrize("kv_bits", [0, 8, 4])
+@pytest.mark.parametrize("h,hkv", [(16, 16), (16, 4)])
+def test_paged_decode_compiles(v5e_devices, d, kv_bits, h, hkv):
+    sds = one_chip(v5e_devices)
+    block = 128 if kv_bits else 16       # scale rows are DMA'd [1, block]
+    pool, scale = paged_args(sds, d, kv_bits, hkv, block)
+    q = sds((8, h, d), jnp.bfloat16)
+    lens = sds((8,), jnp.int32)
+    tables = sds((8, 1024 // block), jnp.int32)
+
+    def fn(q, pk, pv, lens, tables, ks, vs):
+        return paged_decode_attention(q, pk, pv, lens, tables, k_scale=ks,
+                                      v_scale=vs, kv_bits=kv_bits,
+                                      interpret=False)
+    assert "tpu_custom_call" in compile_for_tpu(
+        fn, q, pool, pool, lens, tables, scale, scale)
+
+
+@pytest.mark.parametrize("d", [64, 128])
+@pytest.mark.parametrize("kv_bits", [0, 8, 4])
+def test_paged_prefill_compiles(v5e_devices, d, kv_bits):
+    """The serving default chunk (256 tokens) at 16 MHA heads."""
+    sds = one_chip(v5e_devices)
+    block = 128 if kv_bits else 16
+    pool, scale = paged_args(sds, d, kv_bits, 16, block)
+    q = sds((256, 16, d), jnp.bfloat16)
+    scalar = sds((), jnp.int32)
+    table = sds((1024 // block,), jnp.int32)
+
+    def fn(q, pk, pv, base, n, table, ks, vs):
+        return paged_prefill_attention(q, pk, pv, base, n, table,
+                                       k_scale=ks, v_scale=vs,
+                                       kv_bits=kv_bits, interpret=False)
+    assert "tpu_custom_call" in compile_for_tpu(
+        fn, q, pool, pool, scalar, scalar, table, scale, scale)
+
+
+def test_paged_rejects_shapes_the_tpu_cannot_tile():
+    """A quantized pool at kv_block_size 16, or kv heads that do not
+    fill a 128-lane chunk, fail with a message — at trace time, before
+    Mosaic would."""
+    q = jnp.zeros((2, 4, 64), jnp.bfloat16)
+    lens, tables = jnp.ones((2,), jnp.int32), jnp.zeros((2, 4), jnp.int32)
+    pool = jnp.zeros((8, 16, 4 * 64), jnp.int8)
+    scale = jnp.zeros((8, 4, 1, 16), jnp.float32)
+    with pytest.raises(ValueError, match="kv_block_size % 128"):
+        paged_decode_attention(q, pool, pool, lens, tables, k_scale=scale,
+                               v_scale=scale, kv_bits=8, interpret=False)
+    one_head = jnp.zeros((8, 16, 64), jnp.bfloat16)
+    with pytest.raises(ValueError, match="128-lane"):
+        paged_decode_attention(q[:, :1], one_head, one_head, lens, tables,
+                               interpret=False)
+
+
+def test_train_grad_compiles_on_four_chips(v5e_devices, compiled_kernels):
+    """GPT-2 350M ``value_and_grad(model.loss)`` with the batch sharded
+    over a data=4 mesh: the flash kernel must sit inside a shard_map or
+    lowering raises "Mosaic kernels cannot be automatically
+    partitioned"."""
+    mesh = build_mesh(MeshConfig(data=4), devices=v5e_devices)
+    model = TransformerLM(gpt2_config(
+        "350m", max_seq_len=1024, remat="full", attn_impl="flash",
+        loss_chunk=256))
+    model.bind_mesh(mesh)
+    shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    params = jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(
+            s.shape, jnp.bfloat16,
+            sharding=NamedSharding(mesh, P(*([None] * len(s.shape))))),
+        shapes)
+    batch = {"input_ids": jax.ShapeDtypeStruct(
+        (16, 1024), jnp.int32,
+        sharding=NamedSharding(mesh, P("data", None)))}
+    text = compile_for_tpu(jax.value_and_grad(model.loss), params, batch)
+    assert text.count("tpu_custom_call") >= 2
+
+
+# ---------------------------------------------------------------------------
+# the engines' own programs, lowered for the TPU from the CPU mesh
+# ---------------------------------------------------------------------------
+# Lowering (not compiling) for another platform needs no device of it,
+# and it is where XLA refuses a Mosaic call that is not manual over every
+# mesh axis — so the programs ``ds.initialize`` and ``serving_engine()``
+# really build are checked here on the 8-device CPU mesh.
+def lower_for_tpu(jitted, *args) -> str:
+    jax.clear_caches()          # drop traces made with interpreted kernels
+    return jitted.trace(*args).lower(
+        lowering_platforms=("tpu",)).as_text()
+
+
+def test_engine_train_step_lowers_for_tpu(compiled_kernels):
+    import deepspeed_tpu as ds
+    model = TransformerLM(gpt2_config(
+        "125m", num_layers=2, d_model=256, num_heads=4, vocab_size=512,
+        max_seq_len=256, remat="full", attn_impl="flash", loss_chunk=64))
+    engine, *_ = ds.initialize(model=model, config={
+        "train_micro_batch_size_per_gpu": 1, "bf16": {"enabled": True},
+        "optimizer": {"type": "AdamW", "params": {"lr": 1e-4}},
+        "zero_optimization": {"stage": 3}, "mesh": {"data": 8}})
+    batch = engine.shard_batch(
+        {"input_ids": np.zeros((8, 256), np.int32)})
+    text = lower_for_tpu(engine._build_train_step(), engine.state, batch)
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("mesh", [{"data": 1, "model": 1},
+                                  {"data": 2, "model": 2}])
+def test_serving_step_lowers_for_tpu(compiled_kernels, mesh):
+    """The engine mesh spans all 8 devices whatever ``serving.mesh``
+    says; the mixed step must still lower (it runs under shard_map over
+    its own submesh, 1x1 included)."""
+    import deepspeed_tpu as ds
+    model = TransformerLM(gpt2_config(
+        "125m", num_layers=2, d_model=256, num_heads=8, vocab_size=512,
+        max_seq_len=128))
+    eng = ds.init_inference(model, {
+        "dtype": "bfloat16", "max_out_tokens": 128,
+        "serving": {"enabled": True, "kv_block_size": 16,
+                    "num_kv_blocks": 32, "max_batch_slots": 4,
+                    "prefill_chunk_tokens": 32, "mesh": mesh}})
+    srv = eng.serving_engine()
+    text = lower_for_tpu(srv._build_step(), *srv._step_operands((), None))
+    assert "tpu_custom_call" in text
