@@ -44,11 +44,9 @@ def _overlap_columns(kind: str = "serving") -> dict:
     reg = get_registry()
     h_plan = reg.histogram(f"dstpu_{kind}_host_plan_seconds")
     h_wait = reg.histogram(f"dstpu_{kind}_device_wait_seconds")
-    h_frac = reg.histogram(f"dstpu_{kind}_overlap_frac_dist")
     return {"host_plan_ms_p50": round(h_plan.quantile(0.5) * 1e3, 3),
             "device_wait_ms_p50": round(h_wait.quantile(0.5) * 1e3, 3),
-            "overlap_frac_p50": round(h_frac.quantile(0.5), 4),
-            "iterations": h_frac.count}
+            "iterations": h_wait.count}
 
 
 def train_bench(size: str, micro: int, seq: int, zero_stage: int,

@@ -41,7 +41,6 @@ paths tested in CI.
 """
 from __future__ import annotations
 
-import contextlib
 import time
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
@@ -51,7 +50,8 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ...observability import (get_flight_recorder, get_overlap_profiler,
-                              get_registry, get_request_tracer, trace_span)
+                              get_registry, get_request_tracer, overlap,
+                              trace_span)
 from ...parallel import topology as topo
 from ...parallel.shard_map_compat import shard_map
 from ...runtime.resilience.errors import (FatalIOError, ServingError,
@@ -187,8 +187,9 @@ class ServingEngine:
         self._rt = get_request_tracer()
         self._fr = get_flight_recorder()
         # host/device overlap profiler (observability/overlap.py): the
-        # iteration bracket + per-dispatch enqueue/wait split below all
-        # guard on ``.enabled`` — disabled is one attribute check
+        # iteration bracket, the phase marks and the dispatch counters
+        # below all guard on ``.enabled`` — disabled is one attribute
+        # check
         self._ovl = get_overlap_profiler()
         # -- (data, model) serving submesh (docs/serving.md
         # "Tensor-parallel serving"): model shards heads + KV pool +
@@ -290,6 +291,13 @@ class ServingEngine:
         self._dpool_k = self._dpool_v = None
         if draft_model is not None:
             self._init_draft(draft_model, draft_params)
+        #: rows the target runs in one dispatch of the mixed program,
+        #: whatever rides: a decode row per slot (and, with a draft, its
+        #: spec_k + 1 verify rows) plus the whole chunk lane
+        self._rows_per_dispatch = (
+            self.num_slots * (1 + (self.spec_k + 1 if draft_model
+                                   is not None else 0))
+            + self.chunk_tokens)
 
         #: incremented at TRACE time inside the mixed program — the
         #: "the serving loop compiles exactly one program, whatever the
@@ -350,7 +358,7 @@ class ServingEngine:
             ).set(self.tp_model_size)
         # per-token per-layer model-axis psum payload (bytes): one psum
         # on attention+MLP outputs for parallel-residual blocks, two for
-        # serial/post-norm — the `serving/tp_psum` span reports this
+        # serial/post-norm (docs/serving.md "Tensor-parallel serving")
         mc = model.config
         npsums = 1 if mc.parallel_residual else 2
         self.tp_psum_bytes_per_token_layer = (
@@ -1448,37 +1456,26 @@ class ServingEngine:
                 f"fatal fault at serving dispatch: {e}") from e
         sched = self.scheduler
         spec_on = self._draft_model is not None
-        c_slot, c_start, c_len = ((chunk[0], chunk[2], chunk[3])
-                                  if chunk is not None else (0, 0, 0))
+        c_start, c_len = ((chunk[2], chunk[3]) if chunk is not None
+                          else (0, 0))
+        ovl = self._ovl
+        ovl_on = ovl.enabled
+        if ovl_on:
+            ovl.mark(overlap.OPERANDS)
         operands = self._step_operands(dec, chunk, spec)
         if self._step_fn is None:
             self._step_fn = self._build_step()
-        ovl_on = self._ovl.enabled
+        if ovl_on:
+            ovl.mark(overlap.ENQUEUE)
         t0 = time.perf_counter()
-        t_enq = t0
-        with contextlib.ExitStack() as spans:
-            if dec:
-                spans.enter_context(
-                    trace_span("serving/decode", batch=len(dec)))
-            if spec:
-                spans.enter_context(trace_span(
-                    "serving/spec_decode", batch=len(spec),
-                    k=self.spec_k))
-            if chunk is not None:
-                spans.enter_context(
-                    trace_span("serving/prefill_chunk", slot=c_slot,
-                               start=c_start, tokens=c_len))
-            if self.tp_mesh.size > 1:
-                spans.enter_context(trace_span(
-                    "serving/tp_psum", model=self.tp_model_size,
-                    data=self.tp_data_size,
-                    bytes_per_token_layer=self.tp_psum_bytes_per_token_layer,
-                    layers=self.model.config.num_layers))
+        with trace_span("serving/dispatch", decode=len(dec),
+                        chunk_tokens=c_len, spec=len(spec),
+                        tp=self.tp_mesh.size):
             outs = self._step_fn(*operands)
             if ovl_on:
-                # dispatch returned, nothing materialized yet: the
-                # enqueue/device-wait boundary for the overlap split
-                t_enq = time.perf_counter()
+                # dispatch returned, nothing materialized yet: from here
+                # to the last np.asarray the host waits on the device
+                ovl.mark(overlap.DEVICE_WAIT)
             if spec_on:
                 (nxt, first, emitted, n_emit, dec_fin, spec_fin,
                  chunk_fin, self._pool_k, self._pool_v, self._pool_ks,
@@ -1495,12 +1492,14 @@ class ServingEngine:
         # bookkeeping below (commit hashing, finishes, quarantines) so
         # the histogram stays comparable across PRs
         dispatch_dt = time.perf_counter() - t0
+        if chunk is not None:
+            # the last result to come back: still the device's time
+            chunk_fin = np.asarray(chunk_fin)
         if ovl_on:
-            # enqueue = t0 -> step_fn return; device-wait = step_fn
-            # return -> np.asarray join — both reusing the dispatch_dt
-            # clock reads, no extra syncs
-            self._ovl.note_dispatch(t_enq - t0,
-                                    dispatch_dt - (t_enq - t0))
+            ovl.mark(overlap.APPLY)
+            ovl.count_dispatch(
+                len(dec) + len(spec) * (self.spec_k + 1), c_len,
+                self._rows_per_dispatch)
         if self._rt.enabled and dec:
             # request-track segments reuse t0/dispatch_dt — no extra
             # clock reads on the hot path
@@ -1571,7 +1570,7 @@ class ServingEngine:
                 self._m_tokens.inc(progress)
         if chunk is not None:
             req = chunk[1]
-            if not bool(np.asarray(chunk_fin)):
+            if not bool(chunk_fin):
                 self._quarantine(chunk[0], req, "prefill chunk")
             else:
                 req.cached_tokens += c_len
@@ -1645,8 +1644,9 @@ class ServingEngine:
 
     def _step_impl(self) -> bool:
         sched = self.scheduler
-        if self._ovl.enabled:
-            self._ovl.begin()
+        ovl = self._ovl
+        if ovl.enabled:
+            ovl.begin()
         finished_before = len(sched.finished)
         sched.sweep_deadlines()
         # capacity BEFORE admission: running sequences claim their next
@@ -1712,6 +1712,10 @@ class ServingEngine:
             budget -= chunk[3]
             if budget <= 0:
                 break
+            if ovl.enabled:
+                ovl.mark(overlap.PLAN)       # a second dispatch follows
+        if ovl.enabled:
+            ovl.mark(overlap.APPLY)
         self._drain_terminal_events()
         self._update_gauges()
         # one flush per iteration boundary: every token emitted above
@@ -1738,8 +1742,8 @@ class ServingEngine:
                     f"consecutive iterations (zero tokens, zero prefill, "
                     f"zero terminal transitions) — scheduler wedged or "
                     f"every dispatch faulted"))
-        if self._ovl.enabled:
-            self._ovl.end("serving")
+        if ovl.enabled:
+            ovl.end("serving")
         return sched.has_work
 
     def _update_drain_rate(self, n_finished: int) -> None:

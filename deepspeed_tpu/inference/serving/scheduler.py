@@ -75,6 +75,7 @@ from dataclasses import dataclass, field
 from typing import Callable, Deque, Dict, List, Optional, Tuple
 
 from ...observability.flight_recorder import get_flight_recorder
+from ...observability.overlap import get_overlap_profiler
 from ...observability.request_trace import get_request_tracer
 from ...runtime.resilience.errors import FatalIOError, TransientIOError
 from ...runtime.resilience.fault_injection import get_fault_injector
@@ -85,6 +86,7 @@ from .block_allocator import BlockPoolError, PagedBlockAllocator
 # check per lifecycle event with no allocation or clock read
 _REQ_TRACE = get_request_tracer()
 _FLIGHT = get_flight_recorder()
+_OVERLAP = get_overlap_profiler()
 
 
 class RequestState(enum.Enum):
@@ -135,6 +137,9 @@ class Request:
     #: human-readable reason for a non-OK terminal status
     error: Optional[str] = None
     submit_time: float = field(default_factory=time.perf_counter)
+    #: first WAITING -> RUNNING (a re-admission after a preemption
+    #: leaves it): queue wait is ``admit_time - submit_time``
+    admit_time: Optional[float] = None
     first_token_time: Optional[float] = None
     finish_time: Optional[float] = None
     #: owning tenant (frontend multi-tenancy; "default" = untenanted —
@@ -353,6 +358,8 @@ class ContinuousBatchingScheduler:
             self.terminal_events.append(req)
         if _REQ_TRACE.enabled:
             _REQ_TRACE.on_terminal(req)
+        if _OVERLAP.enabled:
+            _OVERLAP.note_request(req)
         if _FLIGHT.enabled:
             _FLIGHT.note_terminal({
                 "req_id": req.req_id, "trace_id": req.trace_id,
@@ -470,6 +477,8 @@ class ContinuousBatchingScheduler:
                 continue
             self.waiting.popleft()
             req.state = RequestState.RUNNING
+            if req.admit_time is None:
+                req.admit_time = time.perf_counter()
             req.prefill_target = len(req.prefix)
             req.cached_tokens = cached     # hit blocks skip prefill
             req.cache_hit_tokens += cached

@@ -1,29 +1,45 @@
 """Host/device overlap profiler: where does an iteration's wall time go?
 
-ROADMAP item 4 (async multi-step scheduling) needs an instrument before
-it needs a scheduler: you cannot pipeline a bubble you cannot measure.
-This module splits every engine iteration's wall time into
+One instrument, two engines.  A **serving iteration** (one
+``ServingEngine.step()``) is one record — the span ``serving/iteration``
+with its number, begin and end — split into five consecutive, exclusive
+phases, marked where the work happens in ``_step_impl`` / ``_dispatch``:
 
-  - **host-plan** — scheduler/allocator/promote planning and bookkeeping
-    between dispatches (wall minus everything below);
-  - **dispatch-enqueue** — from calling the jitted step function to its
-    return (tracing/dispatch of the async computation);
-  - **device-wait** — from dispatch return to the host-side
-    materialization the engine already performs (``np.asarray`` on the
-    sampled ids), i.e. the host blocked on the device.
+  - ``plan`` — deadline sweep, decode capacity, admissions, promotions,
+    terminal drain, gauges, ``next_prefill_chunk`` / ``decoding_slots``;
+  - ``operands`` — ``_step_operands``: the mixed program's host arrays;
+  - ``enqueue`` — ``self._step_fn(*operands)`` until it returns;
+  - ``device_wait`` — from that return until the last ``np.asarray`` of
+    the results: the host blocked on the device;
+  - ``apply`` — results into the request records, commit hashing,
+    finishes, terminal drain, event flush, flight record: everything
+    until ``step`` returns.
 
-and derives ``overlap_frac = 1 - device_wait / wall`` — the fraction of
-the iteration the host spent doing useful work rather than blocked on
-the device. Today's synchronous engines sit near their floor; the async
-scheduler's acceptance test is this number going UP.
+A second dispatch in one iteration (a chunk remainder) re-enters
+``plan`` .. ``apply`` and the times add up, so the five always sum to the
+iteration.  The record also carries what the dispatches did:
+``dispatches``, ``decode_rows``, ``chunk_rows`` (rows that carried a
+token) and ``rows_computed`` (rows the program ran whatever rode).
+
+A **training step** is recorded one-shot (``observe``) from the
+timestamps the step path already takes: enqueue, device wait, and the
+rest as ``plan``.
+
+Terminal **requests** go into a second ring (``note_request``) with
+their own stamps: submit, admit, first token, finish.
 
 Contract (same as every observability hook in this repo):
-  - the timestamps reuse instants the engines already capture for their
-    latency histograms — **no new device syncs** in any path;
+  - **no new device syncs** in any path;
   - disabled (default), every engine call site is ONE attribute check
-    (``if ovl.enabled:``) — no allocation, no clock read;
-  - enabled, the serving iteration adds two ``perf_counter`` reads
-    (iteration bracket) and one per dispatch (enqueue/wait split);
+    (``if ovl.enabled:``) — no allocation, no clock read, no annotation;
+  - enabled, a phase mark is one ``perf_counter_ns`` read, and every
+    phase is also a ``jax.profiler.TraceAnnotation("serving/<phase>")``
+    inside a ``TraceAnnotation("serving/iteration", n=<number>)``, so a
+    profiler trace shows them on the host line, on the device trace's
+    clock;
+  - both rings are preallocated at enable; ``iterations(t0_s, t1_s)`` /
+    ``requests(t0_s, t1_s)`` read them back on ``time.perf_counter()``'s
+    clock (the one ``perf_counter_ns`` shares);
   - export rides the existing flush boundary: gauges + histograms into
     the metrics registry, a per-iteration track into the Chrome trace
     via the tracer's event-source hook.
@@ -32,74 +48,135 @@ from __future__ import annotations
 
 import threading
 import time
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, Optional, Tuple
+
+import numpy as np
 
 #: overlap iteration tracks render as their own Perfetto process group
 OVERLAP_TRACK_PID_OFFSET = 2000
 
-#: buckets for the dimensionless overlap fraction
-FRAC_BUCKETS = (0.05, 0.1, 0.2, 0.3, 0.4, 0.5, 0.6, 0.7, 0.8,
-                0.9, 0.95, 1.0)
+#: the five phases of a serving iteration, in the order they run
+PHASES = ("plan", "operands", "enqueue", "device_wait", "apply")
+PLAN, OPERANDS, ENQUEUE, DEVICE_WAIT, APPLY = range(len(PHASES))
+ITERATION_SPAN = "serving/iteration"
+PHASE_SPANS = tuple(f"serving/{p}" for p in PHASES)
+
+#: one iteration (or training step).  Times are seconds on
+#: ``time.perf_counter()``'s clock; ``<phase>_s`` is the phase's summed
+#: time.
+ITERATION_DTYPE = np.dtype(
+    [("n", np.int64), ("kind", "U8"), ("begin_s", np.float64),
+     ("end_s", np.float64)]
+    + [(f"{p}_s", np.float64) for p in PHASES]
+    + [("dispatches", np.int64), ("decode_rows", np.int64),
+       ("chunk_rows", np.int64), ("rows_computed", np.int64)])
+
+#: one terminal request: its own stamps.  One it never reached is NaN.
+REQUEST_DTYPE = np.dtype(
+    [("submit_time", np.float64), ("admit_time", np.float64),
+     ("first_token_time", np.float64), ("finish_time", np.float64)])
+
+NAN = float("nan")
 
 
-class _Rec:
-    __slots__ = ("kind", "t0_ns", "total_ns", "plan_ns", "enq_ns",
-                 "wait_ns", "frac", "dispatches")
+def _or_nan(stamp: Optional[float]) -> float:
+    return NAN if stamp is None else stamp
 
-    def __init__(self):
-        self.kind = ""
-        self.t0_ns = 0
-        self.total_ns = 0
-        self.plan_ns = 0
-        self.enq_ns = 0
-        self.wait_ns = 0
-        self.frac = 0.0
-        self.dispatches = 0
+
+class _Ring:
+    """A preallocated ring of records of one dtype; ``n`` counts every
+    record ever written.  The caller holds the profiler's lock."""
+
+    def __init__(self, capacity: int, dtype: np.dtype):
+        self.capacity, self.dtype = capacity, dtype
+        self.rows: Optional[np.ndarray] = None     # allocated at enable
+        self.n = 0
+
+    def allocate(self) -> None:
+        if self.rows is None:
+            self.rows = np.zeros(self.capacity, self.dtype)
+
+    def write(self, row: tuple) -> None:
+        if self.rows is not None:
+            self.rows[self.n % self.capacity] = row
+            self.n += 1
+
+    def held(self) -> np.ndarray:
+        """The records still held, oldest first (a copy)."""
+        if self.rows is None or not self.n:
+            return np.zeros(0, self.dtype)
+        if self.n <= self.capacity:
+            return self.rows[:self.n].copy()
+        at = self.n % self.capacity
+        return np.concatenate((self.rows[at:], self.rows[:at]))
 
 
 class OverlapProfiler:
-    """Per-iteration host/device overlap accounting (module singleton).
+    """Per-iteration phase accounting and request stamps (module
+    singleton).
 
-    Serving protocol (``ServingEngine._step_impl``)::
+    Serving protocol (``ServingEngine._step_impl`` / ``_dispatch``)::
 
-        if ovl.enabled: ovl.begin()
-        ...                                # per dispatch:
-        if ovl.enabled: ovl.note_dispatch(enqueue_s, wait_s)
+        if ovl.enabled: ovl.begin()                 # opens ``plan``
+        ...                                         # per dispatch:
+        if ovl.enabled: ovl.mark(OPERANDS)
+        if ovl.enabled: ovl.mark(ENQUEUE)
+        if ovl.enabled: ovl.mark(DEVICE_WAIT)
+        if ovl.enabled:
+            ovl.mark(APPLY); ovl.count_dispatch(decode, chunk, computed)
+        ...                                         # another dispatch:
+        if ovl.enabled: ovl.mark(PLAN)
         ...
-        if ovl.enabled: ovl.end("serving")
+        if ovl.enabled: ovl.end()
 
     Training records one-shot (``ovl.observe("train", ...)``) from the
-    timestamps the step path already takes.
+    timestamps the step path already takes.  The engine step loop is
+    single-threaded; the lock only guards the rings against a reader.
     """
 
-    def __init__(self, capacity: int = 2048):
+    def __init__(self, capacity: int = 16384):
         self.enabled = False
-        self._capacity = int(capacity)
-        self._ring: List[_Rec] = []
-        self._n = 0
+        self._its = _Ring(int(capacity), ITERATION_DTYPE)
+        self._reqs = _Ring(int(capacity), REQUEST_DTYPE)
         self._lock = threading.Lock()
         self.rank = 0
         self._metrics: Dict[str, tuple] = {}
-        # open-iteration accumulators (engine step loop is single-threaded)
-        self._it_t0_ns = 0
-        self._it_enq_s = 0.0
-        self._it_wait_s = 0.0
-        self._it_dispatches = 0
+        #: number of the open serving iteration (the last one, between
+        #: iterations)
+        self.iteration = -1
+        # open-iteration state
+        self._open = False
+        self._t0_ns = 0
+        self._mark_ns = 0
+        self._phase = PLAN
+        self._acc_ns = [0] * len(PHASES)
+        self._counts = [0, 0, 0, 0]
+        self._annotation = None           # jax.profiler.TraceAnnotation
+        self._it_span = None
+        self._phase_span = None
 
     # -- configuration -----------------------------------------------------
     def configure(self, enabled: bool, capacity: Optional[int] = None,
                   rank: Optional[int] = None) -> None:
         with self._lock:
-            if capacity is not None and int(capacity) > 0:
-                if int(capacity) != self._capacity or not self._ring:
-                    self._capacity = int(capacity)
-                    self._ring = []
-                    self._n = 0
+            if capacity is not None and int(capacity) > 0 \
+                    and int(capacity) != self._its.capacity:
+                self._its = _Ring(int(capacity), ITERATION_DTYPE)
+                self._reqs = _Ring(int(capacity), REQUEST_DTYPE)
             if rank is not None:
                 self.rank = int(rank)
-            if enabled and not self._ring:
-                self._ring = [_Rec() for _ in range(self._capacity)]
+            if enabled:
+                self._its.allocate()
+                self._reqs.allocate()
+                if self._annotation is None:
+                    from jax.profiler import TraceAnnotation
+                    self._annotation = TraceAnnotation
             self.enabled = bool(enabled)
+        if not enabled:
+            # the engine's own thread configures: an iteration that was
+            # open when the profiler went off is dropped
+            self._close_spans()
+            self._open = False
 
     def _metrics_for(self, kind: str) -> tuple:
         m = self._metrics.get(kind)
@@ -112,93 +189,122 @@ class OverlapProfiler:
         # table verifiably in sync
         if kind == "serving":
             m = (reg.gauge("dstpu_serving_host_plan_ms",
-                           "host planning time in the last serving "
-                           "iteration"),
+                           "host time (plan + operands + apply) in the "
+                           "last serving iteration"),
                  reg.gauge("dstpu_serving_device_wait_ms",
                            "host blocked on device in the last serving "
                            "iteration"),
-                 reg.gauge("dstpu_serving_overlap_frac",
-                           "1 - device_wait/wall for the last serving "
-                           "iteration"),
                  reg.histogram("dstpu_serving_host_plan_seconds",
-                               "serving per-iteration host planning time"),
+                               "serving per-iteration host time (plan + "
+                               "operands + apply)"),
                  reg.histogram("dstpu_serving_device_wait_seconds",
-                               "serving per-iteration device wait"),
-                 reg.histogram("dstpu_serving_overlap_frac_dist",
-                               "serving per-iteration overlap fraction",
-                               buckets=FRAC_BUCKETS))
+                               "serving per-iteration device wait"))
         else:
             m = (reg.gauge("dstpu_train_host_plan_ms",
                            "host planning time in the last training step"),
                  reg.gauge("dstpu_train_device_wait_ms",
                            "host blocked on device in the last training "
                            "step"),
-                 reg.gauge("dstpu_train_overlap_frac",
-                           "1 - device_wait/wall for the last training "
-                           "step"),
                  reg.histogram("dstpu_train_host_plan_seconds",
                                "training per-step host planning time"),
                  reg.histogram("dstpu_train_device_wait_seconds",
-                               "training per-step device wait"),
-                 reg.histogram("dstpu_train_overlap_frac_dist",
-                               "training per-step overlap fraction",
-                               buckets=FRAC_BUCKETS))
+                               "training per-step device wait"))
         self._metrics[kind] = m
         return m
 
     # -- serving iteration protocol ----------------------------------------
-    def begin(self) -> None:
-        self._it_t0_ns = time.perf_counter_ns()
-        self._it_enq_s = 0.0
-        self._it_wait_s = 0.0
-        self._it_dispatches = 0
+    def _close_spans(self) -> None:
+        for span in (self._phase_span, self._it_span):
+            if span is not None:
+                span.__exit__(None, None, None)
+        self._phase_span = self._it_span = None
 
-    def note_dispatch(self, enqueue_s: float, wait_s: float) -> None:
-        self._it_enq_s += max(0.0, enqueue_s)
-        self._it_wait_s += max(0.0, wait_s)
-        self._it_dispatches += 1
+    def begin(self) -> None:
+        """Open iteration ``self.iteration + 1`` in its ``plan`` phase."""
+        self._close_spans()                 # left open if step() raised
+        self.iteration += 1
+        self._it_span = self._annotation(ITERATION_SPAN, n=self.iteration)
+        self._it_span.__enter__()
+        self._phase_span = self._annotation(PHASE_SPANS[PLAN])
+        self._phase_span.__enter__()
+        now = time.perf_counter_ns()
+        self._t0_ns = self._mark_ns = now
+        self._phase = PLAN
+        self._acc_ns = [0] * len(PHASES)
+        self._counts = [0, 0, 0, 0]
+        self._open = True
+
+    def mark(self, phase: int) -> None:
+        """Close the running phase and open ``phase``: one clock read.
+        Marking the phase that already runs is a no-op."""
+        if phase == self._phase or not self._open:
+            return
+        self._phase_span.__exit__(None, None, None)
+        now = time.perf_counter_ns()
+        self._acc_ns[self._phase] += now - self._mark_ns
+        self._mark_ns = now
+        self._phase = phase
+        self._phase_span = self._annotation(PHASE_SPANS[phase])
+        self._phase_span.__enter__()
+
+    def count_dispatch(self, decode_rows: int, chunk_rows: int,
+                       rows_computed: int) -> None:
+        """One dispatch of the mixed program: the rows that carried a
+        token (decoding slots, prompt-chunk tokens) and the rows the
+        program ran whatever rode."""
+        c = self._counts
+        c[0] += 1
+        c[1] += decode_rows
+        c[2] += chunk_rows
+        c[3] += rows_computed
 
     def end(self, kind: str = "serving") -> None:
-        t0 = self._it_t0_ns
-        if not t0:
+        if not self._open:
             return
-        self._it_t0_ns = 0
-        total_s = (time.perf_counter_ns() - t0) / 1e9
-        self.observe(kind, total_s=total_s, enqueue_s=self._it_enq_s,
-                     wait_s=self._it_wait_s, t0_ns=t0,
-                     dispatches=self._it_dispatches)
+        self._open = False
+        now = time.perf_counter_ns()
+        self._acc_ns[self._phase] += now - self._mark_ns
+        self._close_spans()
+        self._record(self.iteration, kind, self._t0_ns * 1e-9, now * 1e-9,
+                     [a * 1e-9 for a in self._acc_ns], self._counts)
+
+    def _record(self, n: int, kind: str, begin_s: float, end_s: float,
+                phase_s: List[float], counts: List[int]) -> None:
+        """Export the host / wait split and write one iteration record
+        (``ITERATION_DTYPE``'s field order)."""
+        g_plan, g_wait, h_plan, h_wait = self._metrics_for(kind)
+        host_s = phase_s[PLAN] + phase_s[OPERANDS] + phase_s[APPLY]
+        g_plan.set(host_s * 1e3)
+        g_wait.set(phase_s[DEVICE_WAIT] * 1e3)
+        h_plan.observe(host_s)
+        h_wait.observe(phase_s[DEVICE_WAIT])
+        with self._lock:
+            self._its.write((n, kind, begin_s, end_s, *phase_s, *counts))
 
     # -- one-shot (training) ----------------------------------------------
     def observe(self, kind: str, total_s: float, enqueue_s: float,
                 wait_s: float, t0_ns: Optional[int] = None,
                 dispatches: int = 1) -> None:
+        """One step from three durations the caller already has: enqueue,
+        device wait, and the rest of ``total_s`` as ``plan``."""
         total_s = max(0.0, total_s)
         enqueue_s = max(0.0, min(enqueue_s, total_s))
         wait_s = max(0.0, min(wait_s, total_s - enqueue_s))
         plan_s = max(0.0, total_s - enqueue_s - wait_s)
-        frac = 1.0 - (wait_s / total_s) if total_s > 0 else 1.0
-        g_plan, g_wait, g_frac, h_plan, h_wait, h_frac = \
-            self._metrics_for(kind)
-        g_plan.set(plan_s * 1e3)
-        g_wait.set(wait_s * 1e3)
-        g_frac.set(frac)
-        h_plan.observe(plan_s)
-        h_wait.observe(wait_s)
-        h_frac.observe(frac)
+        t0_s = (t0_ns if t0_ns is not None
+                else time.perf_counter_ns()) * 1e-9
+        self._record(self._its.n, kind, t0_s, t0_s + total_s,
+                     [plan_s, 0.0, enqueue_s, wait_s, 0.0],
+                     [dispatches, 0, 0, 0])
+
+    # -- terminal requests -------------------------------------------------
+    def note_request(self, req) -> None:
+        """A request reached its terminal state (``_terminalize``'s one
+        path, OK included): keep its stamps."""
+        row = (req.submit_time, _or_nan(req.admit_time),
+               _or_nan(req.first_token_time), _or_nan(req.finish_time))
         with self._lock:
-            if not self._ring:
-                return
-            rec = self._ring[self._n % self._capacity]
-            rec.kind = kind
-            rec.t0_ns = t0_ns if t0_ns is not None else \
-                time.perf_counter_ns()
-            rec.total_ns = int(total_s * 1e9)
-            rec.plan_ns = int(plan_s * 1e9)
-            rec.enq_ns = int(enqueue_s * 1e9)
-            rec.wait_ns = int(wait_s * 1e9)
-            rec.frac = frac
-            rec.dispatches = dispatches
-            self._n += 1
+            self._reqs.write(row)
 
     # -- one-shot (pipeline bubble probe) ----------------------------------
     def record_bubble(self, frac: float) -> None:
@@ -219,23 +325,63 @@ class OverlapProfiler:
     # -- introspection -----------------------------------------------------
     @property
     def recorded(self) -> int:
-        return min(self._n, self._capacity)
+        return min(self._its.n, self._its.capacity)
+
+    def _window(self, ring: _Ring, key: str, order_key: str,
+                t0_s: float, t1_s: float) -> Tuple[np.ndarray, bool]:
+        """The held records with ``key`` in ``(t0_s, t1_s]``, oldest
+        first, and whether none that could belong there was overwritten:
+        records are written in ``order_key`` order, so the ring is
+        complete unless it wrapped and its oldest record is already past
+        ``t0_s``."""
+        with self._lock:
+            held, wrapped = ring.held(), ring.n > ring.capacity
+        complete = not (wrapped and held[order_key][0] > t0_s)
+        return held[(held[key] > t0_s) & (held[key] <= t1_s)], complete
+
+    def iterations(self, t0_s: float, t1_s: float
+                   ) -> Tuple[np.ndarray, bool]:
+        """``(records, complete)``: the iteration records
+        (``ITERATION_DTYPE``) whose end lies in ``(t0_s, t1_s]`` on
+        ``time.perf_counter()``'s clock, oldest first, and whether the
+        ring still holds every one of them (False once it has wrapped
+        past ``t0_s``)."""
+        return self._window(self._its, "end_s", "end_s", t0_s, t1_s)
+
+    def requests(self, t0_s: float, t1_s: float
+                 ) -> Tuple[np.ndarray, bool]:
+        """``(records, complete)``: the terminal requests
+        (``REQUEST_DTYPE``) submitted in ``(t0_s, t1_s]``, in the order
+        they ended, and whether the ring still holds every one of them
+        (False once it has wrapped past a request that ended after
+        ``t0_s``)."""
+        return self._window(self._reqs, "submit_time", "finish_time",
+                            t0_s, t1_s)
 
     def last(self) -> Optional[Dict[str, Any]]:
+        """The newest iteration record.  ``host_plan_s`` is all the host
+        time that is neither enqueue nor device wait: plan + operands +
+        apply."""
         with self._lock:
-            if not self._n or not self._ring:
+            ring = self._its
+            if not ring.n or ring.rows is None:
                 return None
-            rec = self._ring[(self._n - 1) % self._capacity]
-            return {"kind": rec.kind, "total_s": rec.total_ns / 1e9,
-                    "host_plan_s": rec.plan_ns / 1e9,
-                    "enqueue_s": rec.enq_ns / 1e9,
-                    "device_wait_s": rec.wait_ns / 1e9,
-                    "overlap_frac": rec.frac,
-                    "dispatches": rec.dispatches}
+            rec = ring.rows[(ring.n - 1) % ring.capacity]
+            out = {"kind": str(rec["kind"]), "n": int(rec["n"]),
+                   "total_s": float(rec["end_s"] - rec["begin_s"]),
+                   "host_plan_s": float(rec["plan_s"] + rec["operands_s"]
+                                        + rec["apply_s"])}
+            for p in PHASES:
+                out[f"{p}_s"] = float(rec[f"{p}_s"])
+            for k in ("dispatches", "decode_rows", "chunk_rows",
+                      "rows_computed"):
+                out[k] = int(rec[k])
+            return out
 
     def reset(self) -> None:
         with self._lock:
-            self._n = 0
+            self._its.n = self._reqs.n = 0
+        self.iteration = -1
 
     # -- export (tracer event source) --------------------------------------
     def chrome_events(self, epoch_ns: int, rank: int
@@ -244,13 +390,10 @@ class OverlapProfiler:
         'C' counter series Perfetto renders as a graph."""
         pid = OVERLAP_TRACK_PID_OFFSET + rank
         with self._lock:
-            n = min(self._n, self._capacity)
-            start = self._n - n
-            recs = [self._ring[i % self._capacity]
-                    for i in range(start, self._n)]
-        if not recs:
+            recs = self._its.held()
+        if not len(recs):
             return []
-        kinds = sorted({r.kind for r in recs})
+        kinds = sorted({str(k) for k in recs["kind"]})
         tids = {k: i + 1 for i, k in enumerate(kinds)}
         out: List[Dict[str, Any]] = [
             {"ph": "M", "pid": pid, "tid": 0, "name": "process_name",
@@ -263,19 +406,28 @@ class OverlapProfiler:
                         "name": "thread_name",
                         "args": {"name": f"{k} iterations"}})
         for rec in recs:
-            ts = (rec.t0_ns - epoch_ns) / 1000.0
-            out.append({"ph": "X", "pid": pid, "tid": tids[rec.kind],
-                        "name": f"{rec.kind}_iteration", "cat": "overlap",
-                        "ts": ts, "dur": rec.total_ns / 1000.0,
-                        "args": {"host_plan_ms": rec.plan_ns / 1e6,
-                                 "enqueue_ms": rec.enq_ns / 1e6,
-                                 "device_wait_ms": rec.wait_ns / 1e6,
-                                 "overlap_frac": round(rec.frac, 4),
-                                 "dispatches": rec.dispatches}})
-            out.append({"ph": "C", "pid": pid, "tid": tids[rec.kind],
-                        "name": f"{rec.kind}_overlap", "ts": ts,
-                        "args": {"host_plan_ms": rec.plan_ns / 1e6,
-                                 "device_wait_ms": rec.wait_ns / 1e6}})
+            kind = str(rec["kind"])
+            ts = (rec["begin_s"] * 1e9 - epoch_ns) / 1000.0
+            phases_ms = {f"{p}_ms": float(rec[f"{p}_s"]) * 1e3
+                         for p in PHASES}
+            host_ms = (phases_ms["plan_ms"] + phases_ms["operands_ms"]
+                       + phases_ms["apply_ms"])
+            out.append({"ph": "X", "pid": pid, "tid": tids[kind],
+                        "name": f"{kind}_iteration", "cat": "overlap",
+                        "ts": ts,
+                        "dur": float(rec["end_s"] - rec["begin_s"]) * 1e6,
+                        "args": dict(phases_ms, n=int(rec["n"]),
+                                     host_plan_ms=host_ms,
+                                     dispatches=int(rec["dispatches"]),
+                                     decode_rows=int(rec["decode_rows"]),
+                                     chunk_rows=int(rec["chunk_rows"]),
+                                     rows_computed=int(
+                                         rec["rows_computed"]))})
+            out.append({"ph": "C", "pid": pid, "tid": tids[kind],
+                        "name": f"{kind}_overlap", "ts": ts,
+                        "args": {"host_plan_ms": host_ms,
+                                 "device_wait_ms":
+                                     phases_ms["device_wait_ms"]}})
         return out
 
 

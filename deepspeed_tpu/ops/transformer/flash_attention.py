@@ -186,6 +186,7 @@ def _fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_fwd",
     )(q, k, v)
     return o, lse
 
@@ -356,6 +357,7 @@ def _bwd_fused(causal, sm_scale, interpret, q, k, v, do, lse, delta):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
+        name="flash_bwd",
     )(q, k, v, do, lse, delta)
     return dq, dk, dv
 
@@ -392,6 +394,7 @@ def _bwd(causal, sm_scale, block_q, block_k, interpret, res, do):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_bwd_dq",
     )(q, k, v, do, lse, delta)
 
     # dk/dv at kv-head width: grid batch dim is B·KVH and the innermost
@@ -428,6 +431,7 @@ def _bwd(causal, sm_scale, block_q, block_k, interpret, res, do):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(q, k, v, do, lse, delta)
     return dq, dk, dv
 

@@ -552,13 +552,13 @@ class FlightRecorderConfig(ConfigModel):
 class OverlapConfig(ConfigModel):
     """``observability.overlap`` — host/device overlap profiler
     (deepspeed_tpu/observability/overlap.py): splits each serving
-    iteration / training step into host-plan, dispatch-enqueue and
-    device-wait from timestamps the engines already take (no new device
-    syncs), exporting overlap gauges+histograms and a per-iteration
-    trace track. The acceptance instrument for the async multi-step
-    scheduler (ROADMAP item 4)."""
+    iteration into its five phases (plan, operands, enqueue, device
+    wait, apply) and each synced training step into host-plan, enqueue
+    and device-wait (no new device syncs), counts dispatches and rows,
+    keeps terminal requests' stamps, and exports gauges+histograms and a
+    per-iteration trace track."""
     enabled: bool = C.OBSERVABILITY_OVERLAP_ENABLED_DEFAULT
-    # per-iteration records retained for the trace track
+    # records retained in each ring (iterations, terminal requests)
     capacity: int = C.OBSERVABILITY_OVERLAP_CAPACITY_DEFAULT
 
     @model_validator(mode="after")

@@ -146,7 +146,7 @@ OBSERVABILITY_FLIGHT_MAX_BUNDLES_DEFAULT = 4    # bundles kept per rank
 # host-plan / dispatch-enqueue / device-wait split — the acceptance
 # instrument for the async multi-step scheduler (ROADMAP item 4)
 OBSERVABILITY_OVERLAP_ENABLED_DEFAULT = False
-OBSERVABILITY_OVERLAP_CAPACITY_DEFAULT = 2048   # iteration ring slots
+OBSERVABILITY_OVERLAP_CAPACITY_DEFAULT = 16384   # slots of each ring
 
 # Serving (continuous batching) block defaults — the ``serving`` block
 # of the INFERENCE config (inference/config.py ServingConfig,

@@ -51,6 +51,7 @@ from deepspeed_tpu.observability import (FleetMetricsAggregator,
 from deepspeed_tpu.observability.fleet_metrics import hist_snapshot
 from deepspeed_tpu.observability.fleet_trace import FLOW_CAT
 from deepspeed_tpu.observability.metrics import decumulate
+from deepspeed_tpu.observability import overlap
 from deepspeed_tpu.observability.overlap import OverlapProfiler
 from deepspeed_tpu.runtime.config import ObservabilityConfig
 
@@ -401,34 +402,60 @@ def test_overlap_profiler_accounting_and_metrics(obs_reset):
         pytest.approx(3.0)
     assert reg.gauge("dstpu_serving_device_wait_ms").value == \
         pytest.approx(5.0)
-    assert reg.gauge("dstpu_serving_overlap_frac").value == \
-        pytest.approx(0.5)
-    assert reg.histogram("dstpu_serving_overlap_frac_dist").count >= 1
+    assert reg.histogram("dstpu_serving_device_wait_seconds").count >= 1
     last = ovl.last()
     assert last["kind"] == "serving" and last["dispatches"] == 1
     assert last["host_plan_s"] == pytest.approx(0.003)
+    # a one-shot record has three of the five phases: the rest of the
+    # wall time is plan
+    assert (last["plan_s"], last["enqueue_s"], last["device_wait_s"]) == \
+        pytest.approx((0.003, 0.002, 0.005))
+    assert last["operands_s"] == last["apply_s"] == 0.0
     # inconsistent inputs clamp (never a negative plan or wait > wall)
     ovl.observe("train", total_s=0.001, enqueue_s=0.005, wait_s=0.005)
     last = ovl.last()
     assert last["kind"] == "train"
     assert last["device_wait_s"] == 0.0
-    assert last["overlap_frac"] == 1.0
-    assert reg.gauge("dstpu_train_overlap_frac").value == 1.0
-    # the serving begin/note/end protocol records a real iteration
+    assert (last["enqueue_s"], last["total_s"]) == \
+        pytest.approx((0.001, 0.001))
+    assert reg.gauge("dstpu_train_device_wait_ms").value == 0.0
+    # the serving begin/mark/end protocol records a real iteration whose
+    # phases add up over both dispatches
     ovl.begin()
-    ovl.note_dispatch(0.001, 0.002)
-    ovl.note_dispatch(0.001, 0.002)
+    for _ in range(2):
+        ovl.mark(overlap.OPERANDS)
+        ovl.mark(overlap.ENQUEUE)
+        ovl.mark(overlap.DEVICE_WAIT)
+        ovl.mark(overlap.APPLY)
+        ovl.count_dispatch(3, 5, 12)
+        ovl.mark(overlap.PLAN)
+    ovl.mark(overlap.APPLY)
     ovl.end("serving")
-    assert ovl.last()["dispatches"] == 2
+    last = ovl.last()
+    assert last["dispatches"] == 2 and last["n"] == 0
+    assert (last["decode_rows"], last["chunk_rows"],
+            last["rows_computed"]) == (6, 10, 24)
+    assert sum(last[f"{p}_s"] for p in overlap.PHASES) == \
+        pytest.approx(last["total_s"], abs=1e-7)
+    assert all(last[f"{p}_s"] > 0 for p in overlap.PHASES)
     assert ovl.recorded == 3
 
 
 def test_overlap_profiler_disabled_is_inert():
     ovl = OverlapProfiler()
     assert not ovl.enabled
-    # the ring is not even allocated until enable — the engines' guard
-    # (`if ovl.enabled:`) is the entire disabled-path cost
-    assert ovl._ring == [] and ovl.recorded == 0
+    # neither ring is allocated and no annotation class is bound until
+    # enable — the engines' guard (`if ovl.enabled:`) is the entire
+    # disabled-path cost (tests/unit/test_overlap_phases.py counts the
+    # clock reads and annotations of a disabled serving step: none)
+    assert ovl._its.rows is None and ovl._reqs.rows is None
+    assert ovl._annotation is None and ovl.recorded == 0
+    assert ovl.last() is None
+    recs, complete = ovl.iterations(0.0, float("inf"))
+    assert len(recs) == 0 and complete
+    # disabling again drops nothing and allocates nothing
+    ovl.configure(enabled=False)
+    assert ovl._its.rows is None and not ovl.enabled
 
 
 def test_overlap_chrome_events_render_iteration_track(obs_reset):
@@ -440,7 +467,11 @@ def test_overlap_chrome_events_render_iteration_track(obs_reset):
     assert {e["pid"] for e in evs} == {2000}
     x = next(e for e in evs if e["ph"] == "X")
     assert x["name"] == "serving_iteration"
-    assert x["args"]["overlap_frac"] == pytest.approx(0.5)
+    assert x["dur"] == pytest.approx(10_000.0)
+    assert x["args"]["device_wait_ms"] == pytest.approx(5.0)
+    assert x["args"]["host_plan_ms"] == pytest.approx(3.0)
+    assert {f"{p}_ms" for p in overlap.PHASES} <= set(x["args"])
+    assert "overlap_frac" not in x["args"]
     assert any(e["ph"] == "C" and e["name"] == "serving_overlap"
                for e in evs)
     assert any(e["ph"] == "M" and e["args"].get("name")
@@ -595,7 +626,7 @@ def test_disagg_fleet_merged_trace_with_failover(tmp_path, obs_reset):
     reg = obs.get_registry()
     assert reg.histogram("dstpu_serving_host_plan_seconds").count > 0
     assert reg.histogram("dstpu_serving_device_wait_seconds").count > 0
-    assert 0.0 <= reg.gauge("dstpu_serving_overlap_frac").value <= 1.0
+    assert reg.gauge("dstpu_serving_device_wait_ms").value > 0.0
     assert get_overlap_profiler().recorded > 0
 
 
@@ -634,12 +665,12 @@ def test_train_overlap_records_on_synced_steps(tmp_path, obs_reset):
     reg = obs.get_registry()
     assert reg.histogram("dstpu_train_device_wait_seconds").count >= 2
     assert reg.histogram("dstpu_train_host_plan_seconds").count >= 2
-    assert 0.0 <= reg.gauge("dstpu_train_overlap_frac").value <= 1.0
+    assert reg.gauge("dstpu_train_device_wait_ms").value >= 0.0
 
     # disabled path: the same loop records NOTHING new
     engine2 = tiny_engine(overlap=False)
     assert not ovl.enabled
-    before = ovl._n
+    before = ovl._its.n
     for i in range(2):
         engine2.train_step(batch(i))
-    assert ovl._n == before
+    assert ovl._its.n == before

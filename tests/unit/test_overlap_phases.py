@@ -1,0 +1,329 @@
+"""The serving iteration measured from inside (ISSUE 24): the overlap
+profiler's five phases, its dispatch and row counters, the request ring
+and the ``iterations`` / ``requests`` accessors, on a tiny CPU engine.
+docs/observability.md "Host/device overlap profiler"."""
+import glob
+import time
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+import deepspeed_tpu as ds
+from deepspeed_tpu.inference.serving import RequestStatus
+from deepspeed_tpu.models import TransformerLM, gpt2_config
+from deepspeed_tpu.observability import get_overlap_profiler, overlap
+from deepspeed_tpu.observability.overlap import (ITERATION_DTYPE, PHASES,
+                                                 REQUEST_DTYPE,
+                                                 OverlapProfiler)
+
+pytestmark = [pytest.mark.observability]
+
+SLOTS, CHUNK = 4, 16
+
+
+@pytest.fixture(scope="module")
+def srv():
+    cfg = gpt2_config("125m", num_layers=2, d_model=32, num_heads=4,
+                      vocab_size=256, max_seq_len=64, dtype=jnp.float32)
+    eng = ds.init_inference(
+        TransformerLM(cfg),
+        config={"dtype": "float32", "max_out_tokens": 64,
+                "temperature": 0.0, "replace_with_kernel_inject": False,
+                "serving": {"enabled": True, "kv_block_size": 8,
+                            "num_kv_blocks": 48,
+                            "max_batch_slots": SLOTS,
+                            "prefill_chunk_tokens": CHUNK,
+                            "prefix_cache": False}})
+    engine = eng.serving_engine()
+    engine.submit([1, 2, 3], max_new_tokens=2)       # build the program
+    engine.run()
+    return engine
+
+
+@pytest.fixture
+def ovl():
+    prof = get_overlap_profiler()
+    prof.reset()
+    prof.configure(enabled=True)
+    yield prof
+    prof.configure(enabled=False)
+    prof.reset()
+
+
+def drain(engine):
+    while engine.step():
+        pass
+
+
+def prompt(n, base=1):
+    return [base + i for i in range(n)]
+
+
+def test_five_phases_partition_every_iteration(srv, ovl):
+    t0 = time.perf_counter()
+    srv.submit(prompt(20), max_new_tokens=4)
+    srv.submit(prompt(37, 50), max_new_tokens=4)
+    lasts = []
+    while True:
+        more = srv.step()
+        lasts.append(ovl.last())
+        if not more:
+            break
+    its, complete = ovl.iterations(t0, time.perf_counter())
+    assert complete and len(its) == len(lasts) >= 4
+    assert its.dtype == ITERATION_DTYPE
+    assert list(its["n"]) == list(range(len(its)))
+    for rec, last in zip(its, lasts):
+        total = rec["end_s"] - rec["begin_s"]
+        assert sum(rec[f"{p}_s"] for p in PHASES) == pytest.approx(
+            total, abs=1e-7)
+        # what the benchmark's runner reads for sat.host_plan_share
+        assert last["n"] == rec["n"]
+        assert last["total_s"] == pytest.approx(total)
+        assert last["host_plan_s"] == pytest.approx(
+            rec["plan_s"] + rec["operands_s"] + rec["apply_s"])
+        assert last["device_wait_s"] == rec["device_wait_s"] > 0
+    # iterations do not overlap: the caller's time lies between them
+    assert np.all(its["begin_s"][1:] >= its["end_s"][:-1])
+
+
+def test_chunk_remainder_records_both_dispatches(srv, ovl):
+    """A 20-token prompt leaves a 4-token remainder; with another request
+    prefilling, the iteration that takes the remainder dispatches the
+    mixed program a second time for the 12 tokens left of its budget."""
+    t0 = time.perf_counter()
+    srv.submit(prompt(20), max_new_tokens=2)
+    srv.submit(prompt(30, 100), max_new_tokens=2)
+    drain(srv)
+    its, _ = ovl.iterations(t0, time.perf_counter())
+    assert list(its["dispatches"][:2]) == [1, 2]
+    first, second = its[0], its[1]
+    assert (first["chunk_rows"], first["decode_rows"]) == (CHUNK, 0)
+    assert first["rows_computed"] == SLOTS + CHUNK
+    # 4 (the remainder) + 12 (the next prompt's head), no decode yet
+    assert second["chunk_rows"] == 4 + 12 and second["decode_rows"] == 0
+    assert second["rows_computed"] == 2 * (SLOTS + CHUNK)
+    # the second dispatch re-entered plan .. apply: still a partition
+    assert sum(second[f"{p}_s"] for p in PHASES) == pytest.approx(
+        second["end_s"] - second["begin_s"], abs=1e-7)
+
+
+def test_useful_and_computed_rows_of_a_known_batch(srv, ovl):
+    """Three requests of one whole 16-token chunk each and 3 new tokens:
+    a prefill iteration each (the first token rides the chunk), the later
+    ones with the earlier requests decoding beside the chunk."""
+    t0 = time.perf_counter()
+    for k in range(3):
+        srv.submit(prompt(CHUNK, 10 + 40 * k), max_new_tokens=3)
+    drain(srv)
+    its, _ = ovl.iterations(t0, time.perf_counter())
+    assert list(its["dispatches"]) == [1] * 5
+    assert list(its["chunk_rows"]) == [16, 16, 16, 0, 0]
+    assert list(its["decode_rows"]) == [0, 1, 2, 2, 1]
+    assert np.all(its["rows_computed"] == SLOTS + CHUNK)
+    useful = (its["decode_rows"] + its["chunk_rows"]).sum()
+    assert useful / its["rows_computed"].sum() == pytest.approx(54 / 100)
+
+
+@pytest.mark.parametrize("cancel", (False, True), ids=("ok", "cancelled"))
+def test_request_ring_holds_the_requests_own_stamps(srv, ovl, cancel):
+    t0 = time.perf_counter()
+    req = srv.submit(prompt(20, 7), max_new_tokens=6)
+    srv.step()
+    srv.step()                      # second chunk: the first token
+    if cancel:
+        assert srv.cancel(req)
+    drain(srv)
+    recs, complete = ovl.requests(t0, time.perf_counter())
+    assert complete and recs.dtype == REQUEST_DTYPE and len(recs) == 1
+    rec = recs[0]
+    assert req.status == (RequestStatus.CANCELLED if cancel
+                          else RequestStatus.OK)
+    for key in ("submit_time", "admit_time", "first_token_time",
+                "finish_time"):
+        assert rec[key] == getattr(req, key)
+    assert (rec["submit_time"] <= rec["admit_time"]
+            <= rec["first_token_time"] <= rec["finish_time"])
+    assert len(req.output) == (1 if cancel else 6)
+    # the stamps fall in the iterations that made them: admission in the
+    # first, the first token (20 tokens, two chunks) in the second
+    its, _ = ovl.iterations(t0, time.perf_counter())
+    assert its[0]["begin_s"] <= rec["admit_time"] <= its[0]["end_s"]
+    assert its[1]["begin_s"] <= rec["first_token_time"] <= its[1]["end_s"]
+
+
+def test_a_request_cancelled_while_waiting_has_no_admit_stamp(srv, ovl):
+    t0 = time.perf_counter()
+    req = srv.submit(prompt(5, 3), max_new_tokens=2)
+    assert srv.cancel(req)
+    drain(srv)
+    (rec,), _ = ovl.requests(t0, time.perf_counter())
+    assert req.admit_time is None and np.isnan(rec["admit_time"])
+    assert np.isnan(rec["first_token_time"])
+    assert rec["finish_time"] == req.finish_time >= rec["submit_time"]
+
+
+def test_admit_time_is_stamped_with_the_profiler_off(srv):
+    assert not get_overlap_profiler().enabled
+    req = srv.submit(prompt(6, 9), max_new_tokens=2)
+    drain(srv)
+    assert req.submit_time <= req.admit_time <= req.first_token_time
+
+
+@pytest.mark.parametrize("ring", ("iterations", "requests"))
+def test_a_wrapped_ring_reports_incomplete(ring):
+    prof = OverlapProfiler(capacity=4)
+    prof.configure(enabled=True)
+
+    class Req:
+        admit_time = first_token_time = None
+
+    def write(k):
+        if ring == "iterations":
+            prof.observe("serving", total_s=0.5, enqueue_s=0.1,
+                         wait_s=0.3, t0_ns=int(k * 1e9))
+        else:
+            req = Req()
+            req.submit_time, req.finish_time = k + 0.25, k + 0.5
+            prof.note_request(req)
+
+    read = getattr(prof, ring)
+    for k in range(4):
+        write(k)
+    recs, complete = read(0.0, 10.0)          # ends at 0.5 .. 3.5
+    assert complete and len(recs) == 4
+    write(4)                                   # overwrites the first
+    recs, complete = read(0.0, 10.0)
+    assert not complete and len(recs) == 4
+    # the oldest record still held ended at 1.5: a window that opens at
+    # or after it lost nothing
+    recs, complete = read(1.5, 10.0)
+    assert complete and len(recs) == 3
+    recs, complete = read(1.0, 10.0)
+    assert complete is False
+    # clipping: (t0, t1] on the record's end (a request's submit)
+    recs, complete = read(2.0, 3.6)
+    assert complete and len(recs) == 2
+    assert prof.recorded == (4 if ring == "iterations" else 0)
+
+
+def test_accessors_before_anything_was_recorded():
+    prof = OverlapProfiler(capacity=4)
+    for read, dtype in ((prof.iterations, ITERATION_DTYPE),
+                        (prof.requests, REQUEST_DTYPE)):
+        recs, complete = read(0.0, 1e9)
+        assert complete and len(recs) == 0 and recs.dtype == dtype
+    assert prof.last() is None
+
+
+def test_train_observe_maps_onto_the_phases():
+    prof = OverlapProfiler(capacity=4)
+    prof.configure(enabled=True)
+    prof.observe("train", total_s=0.010, enqueue_s=0.002, wait_s=0.005,
+                 t0_ns=1_000_000_000)
+    (rec,), _ = prof.iterations(0.0, 10.0)
+    assert rec["kind"] == "train" and rec["dispatches"] == 1
+    assert (rec["begin_s"], rec["end_s"]) == pytest.approx((1.0, 1.010))
+    assert [rec[f"{p}_s"] for p in PHASES] == pytest.approx(
+        [0.003, 0.0, 0.002, 0.005, 0.0])
+
+
+def test_disabled_step_touches_no_profiler_clock_or_annotation(
+        srv, monkeypatch):
+    """With the profiler off a serving step reads no clock and builds no
+    annotation for it: the same traffic reads ``perf_counter`` equally
+    often off and on, and everything the profiler adds when on is
+    ``perf_counter_ns`` reads and ``TraceAnnotation``s of its own."""
+    prof = get_overlap_profiler()
+    counts = {"pc": 0, "ns": 0, "ann": 0}
+    real_pc, real_ns = time.perf_counter, time.perf_counter_ns
+
+    def pc():
+        counts["pc"] += 1
+        return real_pc()
+
+    def ns():
+        counts["ns"] += 1
+        return real_ns()
+
+    class Annotation(jax.profiler.TraceAnnotation):
+        def __init__(self, *a, **kw):
+            counts["ann"] += 1
+            super().__init__(*a, **kw)
+
+    monkeypatch.setattr(time, "perf_counter", pc)
+    monkeypatch.setattr(time, "perf_counter_ns", ns)
+    monkeypatch.setattr(prof, "_annotation", Annotation)
+
+    def traffic(base):
+        counts.update(pc=0, ns=0, ann=0)
+        srv.submit(prompt(20, base), max_new_tokens=3)
+        srv.submit(prompt(9, base + 30), max_new_tokens=3)
+        drain(srv)
+        return dict(counts)
+
+    assert not prof.enabled
+    off = traffic(1)
+    assert off["ns"] == 0 and off["ann"] == 0 and off["pc"] > 0
+    prof.reset()
+    prof.enabled = True          # rings not allocated: nothing is kept
+    try:
+        on = traffic(101)
+    finally:
+        prof.enabled = False
+        prof.reset()
+    assert on["pc"] == off["pc"]
+    steps = prof.iteration + 1
+    assert on["ann"] >= 6 * steps and on["ns"] >= 6 * steps
+
+
+def test_phase_annotations_lie_inside_the_callers_span(srv, ovl, tmp_path):
+    """Under ``jax.profiler.trace`` the iteration and its five phases are
+    ``TraceAnnotation``s on the caller's host line, inside the caller's
+    own annotation (the benchmark client's ``serve_step``)."""
+    from jax.profiler import ProfileData
+    srv.submit(prompt(20, 60), max_new_tokens=3)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        more = True
+        while more:
+            with jax.profiler.TraceAnnotation("outer_step"):
+                more = srv.step()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "plugins/profile/*/*.xplane.pb"))
+    wanted = {"outer_step", overlap.ITERATION_SPAN, *overlap.PHASE_SPANS}
+    lines = [[e for e in line.events if e.name in wanted]
+             for plane in ProfileData.from_file(path).planes
+             for line in plane.lines]
+    (events,) = [evs for evs in lines if evs]      # one host thread
+    spans = {name: [(e.start_ns, e.start_ns + e.duration_ns, e)
+                    for e in events if e.name == name] for name in wanted}
+    n_it = ovl.iteration + 1
+    assert len(spans["outer_step"]) == len(
+        spans[overlap.ITERATION_SPAN]) == n_it >= 3
+    numbers = [dict(e.stats)["n"] for _, _, e in
+               spans[overlap.ITERATION_SPAN]]
+    assert numbers == list(range(n_it))
+    for (o0, o1, _), (i0, i1, _) in zip(spans["outer_step"],
+                                        spans[overlap.ITERATION_SPAN]):
+        assert o0 <= i0 and i1 <= o1
+        inside = [(s, e) for name in overlap.PHASE_SPANS
+                  for s, e, _ in spans[name] if i0 <= s and e <= i1]
+        # consecutive and exclusive: sorted by start, none overlaps
+        inside.sort()
+        assert all(a[1] <= b[0] for a, b in zip(inside, inside[1:]))
+        assert len(inside) >= 2
+    # a dispatching iteration shows all five, in order
+    i0, i1, _ = spans[overlap.ITERATION_SPAN][0]
+    firsts = [min(s for s, e, _ in spans[name] if i0 <= s and e <= i1)
+              for name in overlap.PHASE_SPANS]
+    assert firsts == sorted(firsts)
+    assert sum(len(spans[name]) for name in overlap.PHASE_SPANS) >= \
+        5 * (n_it - 1)

@@ -212,6 +212,42 @@ def test_train_grad_compiles_on_four_chips(v5e_devices, compiled_kernels):
     assert text.count("tpu_custom_call") >= 2
 
 
+@pytest.mark.parametrize("chips,seq,want", [
+    (1, 1024, {"flash_fwd", "flash_bwd"}),
+    (4, 2048, {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"})])
+def test_flash_kernels_keep_their_names(v5e_devices, compiled_kernels,
+                                        chips, seq, want):
+    """On the TPU a device event is named after its HLO instruction, and
+    the benchmark finds a kernel by that name (``^%flash_fwd[.\\d]* = ``).
+    Under the layer scan with ``remat="full"`` the forward runs as
+    ``closed_call`` and again as ``checkpoint/rematted_computation``, on
+    several chips inside ``shard_map``: the instruction must be named
+    after the kernel, not after whatever wraps it."""
+    import re
+    mesh = build_mesh(MeshConfig(data=chips), devices=v5e_devices[:chips])
+    model = TransformerLM(gpt2_config(
+        "350m", num_layers=2, max_seq_len=seq, remat="full",
+        attn_impl="flash", loss_chunk=256))
+    model.bind_mesh(mesh)
+    params = jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(
+            s.shape, jnp.bfloat16,
+            sharding=NamedSharding(mesh, P(*([None] * len(s.shape))))),
+        jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    batch = {"input_ids": jax.ShapeDtypeStruct(
+        (2 * chips, seq), jnp.int32,
+        sharding=NamedSharding(mesh, P("data", None)))}
+    text = compile_for_tpu(jax.value_and_grad(model.loss), params, batch)
+    calls = [ln.strip() for ln in text.splitlines()
+             if "tpu_custom_call" in ln and " custom-call(" in ln]
+    names = [re.match(r"%([a-z_]+)[.\d]* = ", ln).group(1) for ln in calls]
+    assert set(names) == want and names.count("flash_fwd") == 2
+    wrappers = " ".join(re.search(r'op_name="([^"]*)"', ln).group(1)
+                        for ln in calls)
+    assert "rematted_computation" in wrappers and "checkpoint" in wrappers
+    assert ("shard_map" in wrappers) == (chips > 1)
+
+
 # ---------------------------------------------------------------------------
 # the engines' own programs, lowered for the TPU from the CPU mesh
 # ---------------------------------------------------------------------------
