@@ -40,13 +40,17 @@ class PagedMixedState(NamedTuple):
     serving engine coalesces one prompt chunk with the live decode
     slots into a single compiled program.
 
-      k_pool / v_pool  [num_blocks, block, kv_heads * De] — one
-                   layer's slice of the pool, token-major with every kv
-                   head's row side by side (``De`` = head_dim, or
-                   head_dim // 2 for packed int4; layout rationale in
-                   ``ops/transformer/paged_decode_attention``)
-      block_tables [B, pages] int32 (pool block ids; tail entries hold
-                   the reserved null block 0)
+      k_pool / v_pool  [layers * num_blocks, block, kv_heads * De] — the
+                   WHOLE pool, every layer's blocks end to end (layer l
+                   owns blocks l * num_blocks ..), token-major with
+                   every kv head's row side by side (``De`` = head_dim,
+                   or head_dim // 2 for packed int4; layout rationale in
+                   ``ops/transformer/paged_decode_attention``).  The
+                   layer scan carries it and each layer scatters its
+                   rows into it in place: no layer ever holds a slice
+      block_tables [B, pages] int32 — pool block ids ALREADY offset to
+                   this layer's blocks (``table + null_block``; tail
+                   entries hold the layer's null block)
       lens         [B] int32 — rows ALREADY in the pool per slot
       dec_active   [B] int32 — 1 for slots decoding this iteration
                    (prefilling and empty slots are 0: their row of the
@@ -62,7 +66,8 @@ class PagedMixedState(NamedTuple):
                    decode slots are sharded over the ``data`` mesh axis
                    (``block_tables``/``lens`` then hold this shard's
                    slot rows only, while ``chunk_slot`` stays a global
-                   slot id); None on the single-shard path
+                   slot id), offset like ``block_tables``; None on the
+                   single-shard path
       spec_active  [B] int32 — 1 for slots VERIFYING a speculative
                    draft run this iteration (the third lane of the
                    mixed step, docs/serving.md "Speculative decoding");
@@ -72,9 +77,15 @@ class PagedMixedState(NamedTuple):
       spec_width   static Python int — rows per slot in the spec lane
                    (draft length k + 1); 0 = no spec lane, the
                    pre-speculation program byte-identical
-      k_scale / v_scale  [num_blocks, kv_heads, 1, block] f32 per-row
-                   per-head dequant scales of an int8 pool
-                   (``serving.kv_cache_bits``); None = unquantized
+      k_scale / v_scale  [layers * num_blocks, kv_heads, 1, block] f32
+                   per-row per-head dequant scales of an int8 pool
+                   (``serving.kv_cache_bits``), laid out like the
+                   pools; None = unquantized
+      null_block   int32 scalar — this layer's block offset
+                   ``l * num_blocks``, which is also its reserved null
+                   block: every masked row (inactive slot, padding)
+                   writes there, so each layer's null block is written
+                   by that layer alone
     """
     k_pool: Any
     v_pool: Any
@@ -89,6 +100,7 @@ class PagedMixedState(NamedTuple):
     spec_width: int = 0
     k_scale: Any = None
     v_scale: Any = None
+    null_block: Any = 0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -783,7 +795,11 @@ class TransformerLM:
         in one combined write (decode rows at ``table[len // blk]``,
         spec row i of slot b at position ``lens[b] + i``, chunk rows at
         ``base + i`` of the chunk slot's table; inactive/padded rows
-        re-route to the reserved null block), then the kernels attend —
+        re-route to the layer's null block ``st.null_block``).  The pool
+        is the whole carried ``[layers * num_blocks, ..]`` buffer and
+        the tables already point into this layer's blocks, so the write
+        is an in-place update of a few rows and the pool goes on to the
+        next layer untouched otherwise.  Then the kernels attend —
         the batched decode kernel over all slots, one decode-kernel
         call per spec depth (row i sees the slot's prefix plus draft
         tokens 0..i: causality via the length vector), and the causal
@@ -808,10 +824,12 @@ class TransformerLM:
         npages = tables.shape[1]
         act = st.dec_active > 0
         slot = jnp.arange(bsl)
-        # decode rows: write position of each slot's new token (null
-        # block row 0 for slots not decoding this iteration)
+        # row 0 of this layer's null block: where every masked row lands
+        null = st.null_block * blk
+        # decode rows: write position of each slot's new token (the
+        # null row for slots not decoding this iteration)
         wd = jnp.where(act, tables[slot, lens // blk] * blk + lens % blk,
-                       0)
+                       null)
         writes = [wd]
         if sw:
             # spec rows: slot b's draft token i lands at position
@@ -827,7 +845,7 @@ class TransformerLM:
             spage = jnp.minimum(spos // blk, npages - 1)
             ws = jnp.where(sact[:, None],
                            jnp.take_along_axis(tables, spage, axis=1)
-                           * blk + spos % blk, 0)
+                           * blk + spos % blk, null)
             writes.append(ws.reshape(-1))
         if c:
             # chunk rows: absolute rows base..base+C-1 of the chunk
@@ -842,7 +860,7 @@ class TransformerLM:
                       else st.tables_g)[st.chunk_slot]
             cpage = jnp.minimum(cpos // blk, npages - 1)
             wc = jnp.where(ci < st.chunk_len,
-                           ctable[cpage] * blk + cpos % blk, 0)
+                           ctable[cpage] * blk + cpos % blk, null)
             writes.append(wc)
         dp = self._dp_axis
 
@@ -878,13 +896,13 @@ class TransformerLM:
         write = shard_cat(writes)
 
         def put(pool, rows):
-            # token rows [T, kvh, De] -> flat [nb * blk, kvh * De] rows
+            # token rows [T, kvh, De] -> flat [L * nb * blk, kvh * De] rows
             rows = shard_cat(seg(rows.astype(pool.dtype)))
             return pool.reshape(-1, pool.shape[2]).at[write].set(
                 rows.reshape(rows.shape[0], -1)).reshape(pool.shape)
 
         def put_scale(scale, rows):
-            # per-row per-head scales [T, kvh] -> [nb, kvh, 1, blk]
+            # per-row per-head scales [T, kvh] -> [L * nb, kvh, 1, blk]
             return scale.at[write // blk, :, 0, write % blk].set(
                 shard_cat(seg(rows)))
         if kv_bits:
@@ -1273,8 +1291,13 @@ class TransformerLM:
         et al.'s verify step batched over slots).
 
         ``cache``: {"k"/"v" (+ "k_scale"/"v_scale"): the
-        :meth:`init_paged_cache` pools, "block_tables": [B, pages] int32, "lens": [B] int32 (rows
-        already in the pool per slot)}.  ``dec_tokens``/``dec_active``
+        :meth:`init_paged_cache` pools ``[layers, num_blocks, ...]``,
+        "block_tables": [B, pages] int32, "lens": [B] int32 (rows
+        already in the pool per slot)}.  Each pool is ONE buffer that
+        the layer scan carries and updates in place (donate it and the
+        step moves only the rows it writes): layer l is the block
+        offset ``l * num_blocks`` into it, and block 0 of every layer
+        stays that layer's own null block.  ``dec_tokens``/``dec_active``
         [B] int32; ``chunk_ids`` [C] int32 (padded with anything past
         ``chunk_len``; C may be STATICALLY 0 — the chunk lane then
         compiles away); ``chunk_slot``/``chunk_start``/``chunk_len``
@@ -1322,21 +1345,35 @@ class TransformerLM:
         tables_g = (None if self._dp_axis is None else
                     jax.lax.all_gather(tables, self._dp_axis, axis=0,
                                        tiled=True))
-        st_args = (tables, lens, dec_active, chunk_slot, chunk_start,
-                   chunk_len, tables_g, spec_active, sw)
+        # the pools are loop STATE: each is one [L * nb, ...] buffer
+        # (merging the two leading dimensions is a bitcast) that the
+        # scan carries, layer l addressing its blocks at offset l * nb
+        # through the tables.  Scanned as xs / ys they would be sliced
+        # per layer, restacked and copied whole every step, and exist
+        # twice in HBM.
+        names = ("k", "v") + (("k_scale", "v_scale") if quant else ())
+        nl, nb = cache["k"].shape[:2]
+        pools = tuple(cache[n].reshape(nl * nb, *cache[n].shape[2:])
+                      for n in names)
 
         def scan_fn(carry, xs):
-            bp, *pools = xs
-            bp = self.block_transform(bp)
-            y, new_pools = self._block(
-                bp, carry, PagedMixedState(*pools[:2], *st_args,
-                                           *pools[2:]), positions)
-            return y, new_pools
+            y, pools = carry
+            bp, off = xs
+            y, pools = self._block(
+                self.block_transform(bp), y,
+                PagedMixedState(
+                    *pools[:2], tables + off, lens, dec_active,
+                    chunk_slot, chunk_start, chunk_len,
+                    None if tables_g is None else tables_g + off,
+                    spec_active, sw, *pools[2:], null_block=off),
+                positions)
+            return (y, pools), None
 
-        xs = (params["blocks"], cache["k"], cache["v"])
-        if quant:
-            xs += (cache["k_scale"], cache["v_scale"])
-        x, pools = jax.lax.scan(scan_fn, x, xs)
+        offs = jnp.arange(nl, dtype=tables.dtype) * nb
+        (x, pools), _ = jax.lax.scan(scan_fn, (x, pools),
+                                     (params["blocks"], offs))
+        pools = dict(zip(names, (
+            p.reshape(nl, nb, *p.shape[1:]) for p in pools)))
         if self.config.final_layernorm:
             x = self._norm_fn()(params["ln_f"], x)
         # project only the rows anything samples from: the B decode
@@ -1362,10 +1399,7 @@ class TransformerLM:
         cs = (chunk_slot if self._dp_axis is None else
               chunk_slot - jax.lax.axis_index(self._dp_axis) * bsl)
         new_lens = new_lens.at[cs].add(chunk_len, mode="drop")
-        new_cache = {"k": pools[0], "v": pools[1], "block_tables": tables,
-                     "lens": new_lens}
-        if quant:
-            new_cache["k_scale"], new_cache["v_scale"] = pools[2], pools[3]
+        new_cache = dict(pools, block_tables=tables, lens=new_lens)
         if sw:
             spec_logits = logits[0, bsl:nsample].reshape(
                 bsl, sw, logits.shape[-1])
@@ -1377,12 +1411,14 @@ class TransformerLM:
         """Preallocated paged KV pool for continuous-batching serving:
         ``num_blocks`` fixed-size blocks of ``block_size`` tokens shared
         by every sequence through per-slot block tables (block 0 is the
-        allocator's reserved null block).  Pools are per layer; tables
-        and lens start empty — the serving engine owns them.  Layout
-        (what the TPU kernel's DMAs need; see
-        ``ops/transformer/paged_decode_attention``): ``k``/``v``
-        [layers, num_blocks, block, kv_heads * De], token-major with
-        the heads' rows side by side.
+        allocator's reserved null block).  ``k`` and ``v`` are ONE
+        buffer each, [layers, num_blocks, block, kv_heads * De],
+        token-major with the heads' rows side by side (what the TPU
+        kernel's DMAs need; see
+        ``ops/transformer/paged_decode_attention``).  The mixed step
+        updates it in place and addresses layer l as the block offset
+        ``l * num_blocks``; every layer keeps its own null block there.
+        Tables and lens start empty — the serving engine owns them.
 
         ``kv_bits`` 8 or 4 stores the pool COMPRESSED: int8 values at
         ``De`` = head_dim (8-bit) or packed-nibble head_dim // 2 (4-bit)

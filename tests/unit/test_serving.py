@@ -802,6 +802,142 @@ def tiny_cfg(**kw):
                        **kw)
 
 
+# ---------------------------------------------------------------------------
+# mixed step: the carried pool against per-layer slices
+# ---------------------------------------------------------------------------
+def mixed_step_by_layer_slices(model, params, cache, dec_tokens,
+                               dec_active, chunk_ids, chunk_slot,
+                               chunk_start, chunk_len, spec_tokens=None,
+                               spec_active=None):
+    """``_apply_paged_mixed`` as it was first written, kept here as the
+    reference: a Python loop over the layers, each calling ``_block``
+    on ITS OWN ``[nb, block, kvh * De]`` slice of the pools with the
+    tables as the allocator gives them (null block 0 of that slice)."""
+    from deepspeed_tpu.models.transformer import PagedMixedState
+    tables, lens = cache["block_tables"], cache["lens"]
+    quant = "k_scale" in cache
+    bsl = dec_tokens.shape[0]
+    sw = 0 if spec_tokens is None else spec_tokens.shape[1]
+    ci = jnp.arange(chunk_ids.shape[0])
+    pos, ids = [lens], [dec_tokens]
+    if sw:
+        pos.append(jnp.where((spec_active > 0)[:, None],
+                             lens[:, None] + jnp.arange(sw)[None, :],
+                             0).reshape(-1))
+        ids.append(spec_tokens.reshape(-1))
+    pos.append(jnp.where(ci < chunk_len, chunk_start + ci, 0))
+    ids.append(chunk_ids)
+    positions = jnp.concatenate(pos)[None]
+    x = model._embed_tokens(params, jnp.concatenate(ids)[None],
+                            positions=positions)
+    names = ("k", "v") + (("k_scale", "v_scale") if quant else ())
+
+    @jax.jit                     # traced once, called once a layer
+    def layer(bp, x, *pools):
+        return model._block(
+            model.block_transform(bp), x,
+            PagedMixedState(*pools[:2], tables, lens, dec_active,
+                            chunk_slot, chunk_start, chunk_len, None,
+                            spec_active, sw, *pools[2:]), positions)
+    layers = []
+    for l in range(model.config.num_layers):
+        x, new = layer(
+            jax.tree_util.tree_map(lambda a: a[l], params["blocks"]), x,
+            *(cache[n][l] for n in names))
+        layers.append(new)
+    x = model._norm_fn()(params["ln_f"], x)
+    n = bsl + bsl * sw
+    last = x[0, n + jnp.maximum(chunk_len - 1, 0)]
+    logits = model._project(
+        params, jnp.concatenate([x[0, :n], last[None]])[None])[0]
+    return logits, {nm: jnp.stack([lay[i] for lay in layers])
+                    for i, nm in enumerate(names)}
+
+
+@pytest.mark.parametrize("spec", [False, True], ids=["nospec", "spec"])
+@pytest.mark.parametrize("kv_bits", [0, 8], ids=["kv16", "kv8"])
+def test_mixed_step_confines_each_layer_to_its_own_blocks(kv_bits, spec):
+    """One mixed step on a seeded non-zero pool — two decode slots (or
+    one decoding and one verifying a draft run), an inactive slot, and
+    a prefilling slot whose 8-row chunk holds 5 tokens and 3 of padding
+    — against the per-layer-slice reference: the pool is ONE buffer
+    that the scan carries, layer l addressing it at block offset
+    ``l * nb``, and must leave every layer exactly what the slices
+    did, null block included, touching nothing but the rows layer l
+    wrote."""
+    nb, blk, pages, sw = 12, 8, 2, 2
+    model = TransformerLM(gpt2_config(
+        "125m", num_layers=3, d_model=32, num_heads=4, vocab_size=64,
+        max_seq_len=64, dtype=jnp.float32))
+    nl = model.config.num_layers
+    params = model.init(jax.random.PRNGKey(3))
+    rng = np.random.default_rng(11)
+    cache = dict(model.init_paged_cache(nb, blk, kv_bits=kv_bits))
+    for name, a in cache.items():
+        fill = (rng.integers(-127, 128, a.shape) if a.dtype == jnp.int8
+                else rng.uniform(0.01, 0.05, a.shape) if "scale" in name
+                else rng.standard_normal(a.shape))
+        cache[name] = jnp.asarray(fill, a.dtype)
+    old = {name: np.asarray(a) for name, a in cache.items()}
+    # slot 0 decodes at row 5, slot 1 at row 11 (second page), slot 2
+    # is empty, slot 3 prefills rows 8..12 (its second page)
+    tables = np.zeros((4, pages), np.int32)
+    tables[0, :1], tables[1, :2], tables[3, :2] = [7], [3, 9], [5, 2]
+    lens = np.array([5, 11, 0, 8], np.int32)
+    dec_active = np.array([1, 0 if spec else 1, 0, 0], np.int32)
+    cache["block_tables"], cache["lens"] = (jnp.asarray(tables),
+                                            jnp.asarray(lens))
+    args = [jnp.asarray(rng.integers(0, 64, 4), jnp.int32),
+            jnp.asarray(dec_active),
+            jnp.asarray(rng.integers(0, 64, 8), jnp.int32),
+            jnp.int32(3), jnp.int32(8), jnp.int32(5)]
+    kw = {}
+    wrote = {7 * blk + 5, 0} | {2 * blk + i for i in range(5)}
+    if spec:
+        kw = {"spec_tokens": jnp.asarray(rng.integers(0, 64, (4, sw)),
+                                         jnp.int32),
+              "spec_active": jnp.asarray([0, 1, 0, 0], jnp.int32)}
+        wrote |= {9 * blk + 3 + i for i in range(sw)}     # rows 11, 12
+    else:
+        wrote.add(9 * blk + 3)
+    *logits, got = jax.jit(model._apply_paged_mixed)(
+        params, cache, *args, **kw)
+    want_logits, want = jax.jit(
+        lambda *a, **k: mixed_step_by_layer_slices(model, *a, **k))(
+            params, cache, *args, **kw)
+    # decode rows, spec rows (slot-major), the chunk's last valid row
+    np.testing.assert_allclose(
+        np.concatenate([np.asarray(g).reshape(-1, 64) for g in logits]),
+        np.asarray(want_logits), atol=2e-5)
+    rows = sorted(wrote)
+    untouched = np.setdiff1d(np.arange(nb * blk), rows)
+
+    def flat(name, a):             # -> [L, nb * blk rows, ...]
+        if "scale" in name:        # [L, nb, kvh, 1, blk]
+            a = a[:, :, :, 0].transpose(0, 1, 3, 2)
+        return a.reshape(nl, nb * blk, -1)
+    for name, before in old.items():
+        after = np.asarray(got[name])
+        assert after.shape == before.shape and after.dtype == before.dtype
+        if before.dtype == np.int8:
+            # a code may round the other way where the two programs'
+            # activations differ in the last bit
+            assert np.abs(after.astype(np.int32)
+                          - np.asarray(want[name])).max() <= 1
+        else:
+            np.testing.assert_allclose(after, np.asarray(want[name]),
+                                       atol=2e-5)
+        before, after = flat(name, before), flat(name, after)
+        np.testing.assert_array_equal(after[:, untouched],
+                                      before[:, untouched])
+        # every layer wrote every one of its rows — its own null row
+        # (row 0 of ITS block 0) among them, each layer with its own k
+        assert (after[:, rows] != before[:, rows]).any(axis=-1).all()
+        null = after[:, 0]
+        assert all((null[a] != null[b]).any()
+                   for a in range(nl) for b in range(a))
+
+
 def serving_engine(serving=None, model_cfg=None, **cfg):
     eng = ds.init_inference(
         TransformerLM(model_cfg or tiny_cfg()),

@@ -189,6 +189,72 @@ def test_paged_rejects_shapes_the_tpu_cannot_tile():
                                interpret=False)
 
 
+@pytest.mark.parametrize("d", [64, 128])
+@pytest.mark.parametrize("kv_bits,block,nb", [(0, 16, 1920),
+                                              (8, 128, 4800)])
+def test_mixed_step_keeps_the_pool_in_place(v5e_devices, compiled_kernels,
+                                            d, kv_bits, block, nb):
+    """``_apply_paged_mixed`` with donated pools at 16 heads of head
+    dim 128 (the Pythia row) and 64 (the gpt2-medium row): the layer
+    scan carries each pool as one buffer, so the compiled step holds no
+    second pool, no layer slice and no whole-pool copy — only bitcasts
+    of the donated argument and the in-place scatter.  Scanned as xs /
+    ys the same step compiled with two ``AllocateBuffer``, two
+    ``constant_dynamic-update-slice_fusion`` and two ``copy`` of the
+    pool and 1,511 MB of temporaries at ``[4, 1920, 16, 2048]`` bf16.
+    The pools are far too large for any on-chip placement; the int8
+    case takes 4,800 blocks so that its scale planes (157 MB) are too —
+    a plane that fits the compiler prefetches into VMEM whole."""
+    import re
+    layers, heads, slots, chunk = 4, 16, 24, 256
+    sds = one_chip(v5e_devices)
+    model = TransformerLM(gpt2_config(
+        "125m", num_layers=layers, d_model=heads * d, num_heads=heads,
+        vocab_size=512, max_seq_len=2048))
+
+    def abstract(tree, dtype=None):
+        return jax.tree_util.tree_map(
+            lambda a: sds(a.shape, dtype or a.dtype), tree)
+    params = abstract(jax.eval_shape(model.init, jax.random.PRNGKey(0)),
+                      jnp.bfloat16)
+    cache = abstract(jax.eval_shape(
+        lambda: model.init_paged_cache(nb, block, jnp.bfloat16, kv_bits)))
+    pools = dict(cache)
+    cache["block_tables"] = sds((slots, 2048 // block), jnp.int32)
+    cache["lens"] = sds((slots,), jnp.int32)
+    scalar = sds((), jnp.int32)
+    compiled = jax.jit(model._apply_paged_mixed, donate_argnums=1).trace(
+        params, cache, sds((slots,), jnp.int32), sds((slots,), jnp.int32),
+        sds((chunk,), jnp.int32), scalar, scalar, scalar).lower(
+            lowering_platforms=("tpu",)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 2     # decode + chunk kernels
+    # pool-shaped: a whole pool, one layer's slice of it, or either as
+    # flat rows — in the [layers, nb, ..] or the carried [layers * nb, ..]
+    # view
+    shaped = set()
+    for a in pools.values():
+        for lead in ((layers, nb), (nb,), (layers * nb,)):
+            shaped.add(lead + a.shape[2:])
+            shaped.add((int(np.prod(lead)) * a.shape[2],) + a.shape[3:])
+    moved = []
+    for ln in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%(\S+) = \w+\[([\d,]+)\]\S* ([\w-]+)\(",
+                     ln)
+        if m is None:
+            continue
+        name, dims, op = m.groups()
+        if tuple(int(n) for n in dims.split(",")) in shaped and (
+                op in ("copy", "dynamic-slice", "dynamic-update-slice")
+                or "AllocateBuffer" in ln
+                or (op == "fusion" and "dynamic" in name)):
+            moved.append(ln.strip()[:160])
+    assert not moved, moved
+    slice_bytes = min(int(np.prod(a.shape[1:])) * a.dtype.itemsize
+                      for a in pools.values())
+    assert compiled.memory_analysis().temp_size_in_bytes < slice_bytes
+
+
 def test_train_grad_compiles_on_four_chips(v5e_devices, compiled_kernels):
     """GPT-2 350M ``value_and_grad(model.loss)`` with the batch sharded
     over a data=4 mesh: the flash kernel must sit inside a shard_map or
