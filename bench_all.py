@@ -545,8 +545,8 @@ def paged_decode_roofline_sweep(hbm_gbps: float, slots: int = 8,
             # IS the per-row rule (pinned against init_paged_cache)
             gb = float(lens.sum()) * kv_block_bytes(1, heads, d,
                                                     bits) / 2**30
-            for pp in (1, 4, 8):
-                if pp > pages:
+            for pp in (1, 2, None):   # None: the kernel's own VMEM budget
+                if pp is not None and pp > pages:
                     continue
                 # pools AND scales ride as arguments (closing over them
                 # would bake them into the executable as constants)

@@ -16,41 +16,61 @@ kv head's features side by side in the lane dimension:
     k_scale / v_scale [num_blocks, Hkv, 1, block]   f32 (quantized only)
 
 ``De`` is the stored width of one head's row: ``D`` (bf16/f32/int8) or
-``D // 2`` (packed int4).  The kernel walks the lane dimension in
-chunks of ``W = lcm(De, 128)`` lanes — a PACK of ``W // De`` kv heads
-(2 at head_dim 64, 1 at 128, 4 at 96) — and fetches one ``[block, W]``
-slab per (page, pack).  Scale rows are stored lane-major, one
-``[1, block]`` row per (page, head), so they broadcast over score
-columns.  Compiled for the TPU this needs ``Hkv * De`` to be a multiple
-of ``W`` and, for a quantized pool, ``block % 128 == 0``; the
-interpreter (CPU tests) takes any shape.
+``D // 2`` (packed int4).  A pool block — a PAGE — is contiguous in HBM
+(64 KB at 16 tokens x 2,048 bf16 lanes) and is always fetched whole.
+The smallest lane chunk of whole heads is a PACK of ``lcm(De, 128) //
+De`` kv heads (2 at head_dim 64, 1 at 128, 4 at 96).  Scale rows are
+stored lane-major, one ``[1, block]`` row per (page, head), so they
+broadcast over score columns.  Compiled for the TPU this needs ``Hkv *
+De`` to be a multiple of the pack's lanes and, for a quantized pool,
+``block % 128 == 0``; the interpreter (CPU tests) takes any shape.
 
 Kernel design:
 
-  * grid ``(slot, pack, page_group)`` with MULTIPLE pages per program;
-    pages past a slot's valid prefix are never fetched (their DMA is
-    predicated off), so a short sequence's ragged tail costs no HBM
-    traffic.
-  * DOUBLE-BUFFERED manual block fetches: the pools stay in HBM
-    (``memory_space=ANY``); while page group *g* is consumed, the next
-    grid position's group (next group, next pack, next slot) is already
-    in flight into the other half of the VMEM scratch.
-  * the heads of a pack share one MXU contraction: the wrapper lays the
-    pack's queries out BLOCK-DIAGONALLY (head j's rows are non-zero only
-    in head j's lane window), so ``Q x slab^T`` yields every head's
-    scores with no in-kernel lane slicing; the off-diagonal output
-    windows are discarded by the wrapper.  GQA query groups and prefill
-    chunk rows are simply more rows.
+  * grid ``(slot, page_group)``: one step fetches a group of
+    ``pages_per_program`` pages and contracts it for EVERY head.  A
+    step at or past its slot's length (an inactive slot, the idle
+    prefill lane, the tail of a short sequence) is DEAD: one predicate,
+    no descriptor, no table look-up.  The wrapper computes from the
+    lengths what a step needs to know of the walk — live groups per
+    slot, live steps before it, the next live slot — and hands it over
+    as scalar prefetch.
+  * a page is ONE DMA per operand, ``pool[bid]`` into slot ``j`` of a
+    ``[2, pp, block, Hkv * De]`` VMEM scratch (the scale rows of a
+    quantized page likewise, ``[Hkv, 1, block]`` in one copy), issued by
+    a loop over the group's live pages only.  DOUBLE-BUFFERED across
+    steps: the pools stay in HBM (``memory_space=ANY``); while one group
+    is contracted, the next LIVE step's group — of this slot or of the
+    next live one, however many dead steps lie between — is already in
+    flight into the other half.
+  * heads are walked in WINDOWS of whole packs, 128-lane-aligned slices
+    of the slab in VMEM, under a ``fori_loop`` (never unrolled: the
+    mixed step is traced and lowered in every serving set-up).  A
+    window's heads share one MXU contraction over the group's ``pp x
+    block`` keys: the wrapper lays their queries out BLOCK-DIAGONALLY
+    (head j's rows are non-zero only in head j's lanes), so ``Q x
+    slab^T`` yields every head's scores with no in-kernel lane slicing;
+    the off-diagonal output windows are discarded by the wrapper.  The
+    window grows while its rows fit ``_WINDOW_ROWS``: a decode call (one
+    row a head) stacks ALL heads into one contraction a step, a 256-row
+    chunk walks one pack at a time.  GQA query groups and chunk rows
+    are simply more rows.  Online-softmax state (m, l, acc) is kept per
+    window.
+  * ``pages_per_program`` follows from a stated VMEM budget
+    (``_VMEM_GROUP_BYTES`` of the ``_VMEM_LIMIT_BYTES`` the kernel asks
+    for): what a page costs in both buffer halves plus what the
+    contraction keeps per key.
   * FUSED DEQUANT: an int8 / packed-int4 pool crosses HBM compressed;
     the per-row scales are applied in the SCORE domain (``(q . k_int) *
     k_scale`` and ``(p * v_scale) . v_int``), which is the same product
     as ``ops/quantizer/kv_dequantize`` re-associated.  int4 is
     feature-split packed (byte ``j`` = features ``j`` and ``j + D//2``),
     so the wrapper splits q in halves and the kernel never concatenates.
-  * inactive slots (length 0) fetch nothing and return zero rows; masked
-    v rows and scales are ZEROED, not just down-weighted — ``0 x NaN``
-    from a recycled quarantined block must never reach the accumulator
-    (the PR 6 invariant, pinned by the NaN-garbage parity tests).
+  * dead slots return zero rows; masked v rows and scales are ZEROED,
+    not just down-weighted — ``0 x NaN`` from a recycled quarantined
+    block, or from whatever an unfetched page left in the buffer, must
+    never reach the accumulator (the PR 6 invariant, pinned by the
+    NaN-garbage parity tests).
 """
 from __future__ import annotations
 
@@ -68,27 +88,15 @@ from .. import resolve_interpret
 
 MASK_VALUE = -0.7 * float(np.finfo(np.float32).max)
 LANES = 128
-#: rows per page group the wrapper aims for: enough work per grid step
-#: to hide its overhead
-_TARGET_GROUP_ROWS = 1024
-#: cap on concurrently in-flight page DMAs per buffer half
-_MAX_PAGES_PER_PROGRAM = 16
-#: f32 score tiles a program keeps live between its max and exp passes
-_SCORE_BYTES = 2 << 20
-
-
-def _pages_per_program(block: int, npages: int, rows: int,
-                       override: Optional[int]) -> int:
-    if override is not None:
-        if override < 1:
-            raise ValueError(
-                f"pages_per_program must be >= 1, got {override}")
-        return min(override, npages)
-    pp = max(1, _TARGET_GROUP_ROWS // block)
-    # a [rows, block] f32 score tile occupies whole (8, 128) vregs
-    tile = (-(-rows // 8) * 8) * (-(-block // LANES) * LANES) * 4
-    return max(1, min(pp, _MAX_PAGES_PER_PROGRAM, npages,
-                      _SCORE_BYTES // tile))
+#: scoped VMEM the kernel asks the compiler for ...
+_VMEM_LIMIT_BYTES = 32 << 20
+#: ... and the part of it a step's page buffers and per-group
+#: temporaries may plan to fill (the rest: the q / o blocks, the
+#: accumulators, what the compiler keeps for itself)
+_VMEM_GROUP_BYTES = 12 << 20
+#: query rows one head window may stack block-diagonally: a bf16 operand
+#: tile has 16 sublanes, so an MXU pass costs the same for 1 row as for 16
+_WINDOW_ROWS = 16
 
 
 def _head_pack(kv_heads: int, d_eff: int) -> int:
@@ -99,35 +107,67 @@ def _head_pack(kv_heads: int, d_eff: int) -> int:
     return math.gcd(kv_heads, math.lcm(d_eff, LANES) // d_eff)
 
 
-def _page_group_dma(start, hbm, bufs, sem, bt_ref, row, pack, total, group,
-                    buf, *, block, pp, width, hp):
-    """Start (or wait on) the DMAs of one page group: for each valid
-    page ``group * pp + j`` of slot ``row``, the pack's ``[block, W]``
-    k and v slabs — and, for a quantized pool, each of its heads'
-    ``[1, block]`` scale rows — from pool block ``bt[row, page]`` into
-    slot ``j`` of buffer half ``buf``.  Start and wait MUST evaluate the
-    same predicates, so both go through here."""
-    npages = bt_ref.shape[1]
-    lane0 = pl.multiple_of(pack * width, width)
-    for j in range(pp):
-        p = group * pp + j
-        bid = bt_ref[row, jnp.minimum(p, npages - 1)]
-        copies = [pltpu.make_async_copy(
-            hbm[op].at[bid, :, pl.ds(lane0, width)], bufs[op].at[buf, j],
-            sem.at[buf, op]) for op in (0, 1)]
-        for op in range(2, len(hbm)):
-            copies += [pltpu.make_async_copy(
-                hbm[op].at[bid, pack * hp + t], bufs[op].at[buf, j, t],
-                sem.at[buf, op]) for t in range(hp)]
+def _window_heads(kv_heads: int, hp: int, rows_per_head: int) -> int:
+    """kv heads whose queries one contraction stacks block-diagonally:
+    whole packs, doubled while they divide the heads and the stacked
+    rows stay within ``_WINDOW_ROWS`` — every head of a decode call
+    (one row each), one pack of a 256-row chunk."""
+    heads = hp
+    while (kv_heads % (2 * heads) == 0
+           and 2 * heads * rows_per_head <= _WINDOW_ROWS):
+        heads *= 2
+    return heads
 
-        @pl.when((p < npages) & (p * block < total))
-        def _():
-            for c in copies:
-                c.start() if start else c.wait()
+
+def _pages_per_program(pool, kv_heads: int, kv_bits: int, rows: int,
+                       width: int, npages: int,
+                       override: Optional[int]) -> int:
+    """Pages one grid step fetches and contracts: as many as
+    ``_VMEM_GROUP_BYTES`` holds of what a page costs in VMEM — its k and
+    v rows in both buffer halves, its scale rows (a ``[1, block]`` row
+    occupies whole (8, 128) tiles), and per key the f32 score and
+    probability rows of ``rows`` queries plus the operands one window
+    pass makes of the slab (the zeroed v rows; both f32 copies, per q
+    split, of a quantized pool's k and v) — rounded down to a power of
+    two."""
+    if override is not None:
+        if override < 1:
+            raise ValueError(
+                f"pages_per_program must be >= 1, got {override}")
+        return min(override, npages)
+    _, block, lanes = pool.shape
+    page = 4 * block * lanes * pool.dtype.itemsize
+    per_key = 2 * 4 * (-(-rows // 8) * 8) + width * pool.dtype.itemsize
+    if kv_bits:
+        nsplit = 2 if kv_bits == 4 else 1
+        page += 4 * kv_heads * 8 * (-(-block // LANES) * LANES) * 4
+        per_key += 2 * nsplit * width * 4
+    pp = max(1, _VMEM_GROUP_BYTES // (page + block * per_key))
+    return min(1 << (pp.bit_length() - 1), npages)
+
+
+def _page_group_dma(start, hbm, bufs, sem, bt_ref, row, total, group, half,
+                    *, block, pp):
+    """Start (or wait on) the DMAs of one live page group: for each page
+    ``group * pp + j`` of slot ``row`` that holds keys below ``total``,
+    the WHOLE pool block ``bt[row, page]`` — ``[block, Hkv * De]`` of k
+    and of v, and for a quantized pool its ``[Hkv, 1, block]`` scale
+    rows — into slot ``j`` of buffer half ``half``, one copy per operand.
+    The loop runs over the live pages only, so start and wait count
+    alike; a wait needs the copy's shape and semaphore, not its source."""
+    def page(j, carry):
+        bid = bt_ref[row, group * pp + j] if start else 0
+        for op, (src, dst) in enumerate(zip(hbm, bufs)):
+            copy = pltpu.make_async_copy(src.at[bid], dst.at[half, j],
+                                         sem.at[half, op])
+            copy.start() if start else copy.wait()
+        return carry
+    live = jnp.clip(-(-(total - group * pp * block) // block), 0, pp)
+    jax.lax.fori_loop(0, live, page, 0)
 
 
 def _unpack(x, kv_bits):
-    """One pool slab ``[block, W]`` → the matmul operand per q split:
+    """One pool slab ``[keys, W]`` → the matmul operand per q split:
     the slab itself, its int8 values, or its (low, high) nibbles."""
     if kv_bits == 0:
         return [x]
@@ -139,126 +179,134 @@ def _unpack(x, kv_bits):
 
 
 def _kernel(meta_ref, bt_ref, coff_ref, rhead_ref, q_ref, *refs, sm_scale,
-            block, pp, kv_bits, width, hp):
-    """Online-softmax walk over one (slot, pack)'s page groups.
+            block, pp, kv_bits, width, heads):
+    """Online-softmax walk over one slot's live page groups, every head
+    window inside the step.
 
-    ``meta_ref [B, 2]`` = (base, total) per slot: query row ``c`` sits
-    at absolute position ``base + c``, sees keys ``<=`` its own
-    position, and nothing at or past ``total`` is attended.  q_ref
-    ``[nsplit, R, W]`` block-diagonal queries (``R = hp * G * C`` rows;
-    ``coff_ref``/``rhead_ref`` ``[R, 1]`` give each row's chunk offset
-    and head-within-pack); VMEM slabs kbuf/vbuf ``[2, pp, block, W]`` in
-    the pool dtype (+ ksbuf/vsbuf ``[2, pp, hp, 1, block]`` f32 when
-    quantized); scratch m/l ``[R, 1]``, acc ``[nsplit, R, W]`` f32; one
-    DMA semaphore per (buffer half, operand)."""
+    ``meta_ref [5, B]`` (scalar prefetch), per slot: (base, total, live
+    groups, live steps before this slot, next live slot after this one
+    or B).  Query row ``c`` sits at absolute position ``base +
+    c``, sees keys ``<=`` its own position, and nothing at or past
+    ``total`` is attended.  q_ref ``[nwin, nsplit, R, W]``: per head
+    window the block-diagonal queries of its ``heads`` kv heads (``R =
+    heads * G * C`` rows; ``coff_ref``/``rhead_ref`` ``[R, 1]`` give each
+    row's chunk offset and head-within-window).  VMEM slabs kbuf/vbuf
+    ``[2, pp, block, Hkv * De]`` in the pool dtype (+ ksbuf/vsbuf ``[2,
+    pp, Hkv, 1, block]`` f32 when quantized); scratch m/l ``[nwin, R,
+    1]``, acc ``[nwin, nsplit, R, W]`` f32; one DMA semaphore per
+    (buffer half, operand)."""
     nops = 2 if kv_bits == 0 else 4
     hbm = refs[:nops]
     o_ref = refs[nops]
     bufs = refs[nops + 1:nops + 1 + nops]
     m_scr, l_scr, acc_scr, sem = refs[nops + 1 + nops:]
 
-    i, hh, g = pl.program_id(0), pl.program_id(1), pl.program_id(2)
-    nh, ng = pl.num_programs(1), pl.num_programs(2)
-    rows = pp * block
-    base, total = meta_ref[i, 0], meta_ref[i, 1]
-    step = (i * nh + hh) * ng + g
-    buf = jax.lax.rem(step, 2)
+    i, g = pl.program_id(0), pl.program_id(1)
+    nslots, ng = pl.num_programs(0), pl.num_programs(1)
+    nwin = q_ref.shape[0]
+    keys = pp * block
+    base, total, live_groups = meta_ref[0, i], meta_ref[1, i], meta_ref[2, i]
 
-    def fetch(row, pack, group, into_buf, start):
-        _page_group_dma(start, hbm, bufs, sem, bt_ref, row, pack,
-                        meta_ref[row, 1], group, into_buf, block=block,
-                        pp=pp, width=width, hp=hp)
+    def fetch(row, group, half, start):
+        _page_group_dma(start, hbm, bufs, sem, bt_ref, row,
+                        meta_ref[1, row], group, half, block=block, pp=pp)
 
-    @pl.when(step == 0)
-    def _cold_start():
-        fetch(i, hh, g, buf, start=True)
+    # a step at or past its slot's length runs none of this
+    @pl.when(g < live_groups)
+    def _live():
+        step = meta_ref[3, i] + g          # ordinal among the live steps
+        half = jax.lax.rem(step, 2)
 
-    # issue the NEXT grid position's fetch before waiting on ours: the
-    # pipeline stays full across page-group, pack, and slot boundaries
-    g1 = g + 1
-    h1 = hh + g1 // ng
-    i1 = i + h1 // nh
-    g1, h1 = jax.lax.rem(g1, ng), jax.lax.rem(h1, nh)
+        @pl.when(step == 0)
+        def _cold_start():
+            fetch(i, g, half, start=True)
 
-    @pl.when(i1 < pl.num_programs(0))
-    def _prefetch_next():
-        fetch(i1, h1, g1, jax.lax.rem(step + 1, 2), start=True)
+        # issue the NEXT LIVE step's fetch before waiting on ours: the
+        # pipeline stays full across page groups, slots and dead steps
+        more = g + 1 < live_groups
+        row1 = jnp.where(more, i, meta_ref[4, i])
+        g1 = jnp.where(more, g + 1, 0)
 
-    fetch(i, hh, g, buf, start=False)
+        @pl.when(row1 < nslots)
+        def _prefetch_next():
+            fetch(row1, g1, 1 - half, start=True)
 
-    @pl.when(g == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, -jnp.inf)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
+        fetch(i, g, half, start=False)
 
-    @pl.when(g * rows < total)
-    def _body():
-        nsplit = q_ref.shape[0]
-        qs = [q_ref[s] if kv_bits == 0 else q_ref[s].astype(jnp.float32)
-              for s in range(nsplit)]
-        qpos = base + coff_ref[...]                       # [R, 1]
+        @pl.when(g == 0)
+        def _init():
+            m_scr[...] = jnp.full_like(m_scr, -jnp.inf)
+            l_scr[...] = jnp.zeros_like(l_scr)
+            acc_scr[...] = jnp.zeros_like(acc_scr)
 
-        def row_scale(sbuf, j):
-            # the pack's per-head [1, block] scale rows -> [R, block],
-            # each query row taking its own head's
-            s = sbuf[buf, j, 0]
-            for t in range(1, hp):
-                s = jnp.where(rhead_ref[...] == t, sbuf[buf, j, t], s)
-            return s
+        qpos = base + coff_ref[...]                            # [R, 1]
+        pos = g * keys + jax.lax.broadcasted_iota(jnp.int32, (1, keys), 1)
+        in_range = pos < total                                 # [1, keys]
+        visible = (pos <= qpos) & in_range                     # [R, keys]
+        # masked rows get probability ~0, but 0 * NaN = NaN: zero the v
+        # rows (and scales) past the valid length so a recycled pool
+        # block holding a quarantined request's non-finite KV cannot
+        # re-poison its next owner — unfetched pages also leave stale
+        # garbage in the buffer
+        v_valid = g * keys + jax.lax.broadcasted_iota(
+            jnp.int32, (keys, 1), 0) < total                   # [keys, 1]
 
-        # pass 1: every page's masked scores, and the group's row max
-        scores, in_range = [], []
-        m_prev = m_scr[...]                               # [R, 1]
-        m_new = m_prev
-        for j in range(pp):
+        def window(w, carry):
+            lanes = pl.ds(pl.multiple_of(w * width, width), width)
+
+            def row_scale(sbuf):
+                # the window's per-head [1, block] scale rows of every
+                # page -> [R, keys], each query row taking its own head's
+                def of_head(t):
+                    return jnp.concatenate(
+                        [sbuf[half, j, w * heads + t] for j in range(pp)],
+                        axis=1)
+                s = of_head(0)
+                for t in range(1, heads):
+                    s = jnp.where(rhead_ref[...] == t, of_head(t), s)
+                return s
+
+            k = bufs[0][half, :, :, lanes].reshape(keys, width)
             s = sum(jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
+                q_ref[w, c] if kv_bits == 0
+                else q_ref[w, c].astype(jnp.float32), kk,
+                (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32)
-                for q, k in zip(qs, _unpack(bufs[0][buf, j], kv_bits)))
+                for c, kk in enumerate(_unpack(k, kv_bits)))
             if kv_bits:
-                s = s * row_scale(bufs[2], j)
-            pos = (g * pp + j) * block + jax.lax.broadcasted_iota(
-                jnp.int32, (1, block), 1)
-            in_range.append(pos < total)
-            s = jnp.where((pos <= qpos) & in_range[j], s * sm_scale,
-                          MASK_VALUE)                     # [R, block]
-            scores.append(s)
-            m_new = jnp.maximum(m_new, jnp.max(s, axis=1, keepdims=True))
-        # pass 2: one rescale of the running state, then accumulate
-        alpha = jnp.exp(m_prev - m_new)                   # [R, 1]
-        l_new = alpha * l_scr[...]
-        acc = [alpha * acc_scr[s] for s in range(nsplit)]
-        for j in range(pp):
-            p = jnp.exp(scores[j] - m_new)                # [R, block]
-            l_new = l_new + jnp.sum(p, axis=1, keepdims=True)
-            # masked rows get probability ~0, but 0 * NaN = NaN: zero
-            # the v rows (and scales) past the valid length so a
-            # recycled pool block holding a quarantined request's
-            # non-finite KV cannot re-poison its next owner — unfetched
-            # pages also leave stale garbage in the buffer
-            rowpos = (g * pp + j) * block + jax.lax.broadcasted_iota(
-                jnp.int32, (block, 1), 0)
-            v = bufs[1][buf, j]
-            v = jnp.where(rowpos < total, v, jnp.zeros_like(v))
+                s = s * row_scale(bufs[2])
+            s = jnp.where(visible, s * sm_scale, MASK_VALUE)   # [R, keys]
+            m_prev = m_scr[w]                                  # [R, 1]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - m_new)
+            l_scr[w] = alpha * l_scr[w] + jnp.sum(p, axis=1, keepdims=True)
+            m_scr[w] = m_new
+            v = bufs[1][half, :, :, lanes].reshape(keys, width)
+            v = jnp.where(v_valid, v, jnp.zeros_like(v))
             if kv_bits:
-                p = p * jnp.where(in_range[j], row_scale(bufs[3], j), 0.0)
-            for s, vv in enumerate(_unpack(v, kv_bits)):
-                acc[s] = acc[s] + jax.lax.dot_general(
+                p = p * jnp.where(in_range, row_scale(bufs[3]), 0.0)
+            for c, vv in enumerate(_unpack(v, kv_bits)):
+                acc_scr[w, c] = alpha * acc_scr[w, c] + jax.lax.dot_general(
                     p.astype(vv.dtype), vv, (((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32)   # [R, W]
-        l_scr[...] = l_new
-        m_scr[...] = m_new
-        for s in range(nsplit):
-            acc_scr[s] = acc[s]
+                    preferred_element_type=jnp.float32)        # [R, W]
+            return carry
+
+        # the windows are looped, never unrolled: the body is traced and
+        # lowered once whatever the number of heads
+        if nwin == 1:
+            window(0, 0)
+        else:
+            jax.lax.fori_loop(0, nwin, window, 0)
 
     @pl.when(g == ng - 1)
     def _out():
-        # a slot that never ran a group (length 0, idle prefill lane):
-        # l stays 0 and the clamp yields zero rows instead of 0/0
-        inv = 1.0 / jnp.maximum(l_scr[...], 1e-30)        # [R, 1]
-        for s in range(q_ref.shape[0]):
-            o_ref[s] = (inv * acc_scr[s]).astype(o_ref.dtype)
+        # a slot that never ran a group (length 0, idle prefill lane)
+        # left the accumulators to its neighbours: zero rows
+        alive = live_groups > 0
+        inv = 1.0 / jnp.maximum(l_scr[...], 1e-30)             # [nwin,R,1]
+        o_ref[...] = jnp.where(alive, inv[:, None] * acc_scr[...],
+                               0.0).astype(o_ref.dtype)
 
 
 def _check_args(q_heads, d, pool_k, pool_v, k_scale, v_scale, kv_bits,
@@ -310,12 +358,12 @@ def _paged_attention(q, pool_k, pool_v, base, total, block_tables, *,
     b, c, h, d = q.shape
     hkv, d_eff = _check_args(h, d, pool_k, pool_v, k_scale, v_scale,
                              kv_bits, what)
-    block = pool_k.shape[1]
+    block, lanes = pool_k.shape[1:]
     nsplit = d // d_eff                   # 2 for packed int4, else 1
     groups = h // hkv
-    hp = _head_pack(hkv, d_eff)
-    width = hp * d_eff
-    npacks = hkv // hp
+    heads = _window_heads(hkv, _head_pack(hkv, d_eff), groups * c)
+    width = heads * d_eff
+    nwin = hkv // heads
     interpret = resolve_interpret(interpret)
     if not interpret:
         if width % LANES:
@@ -330,52 +378,60 @@ def _paged_attention(q, pool_k, pool_v, base, total, block_tables, *,
                 f"kv_block_size % {LANES} == 0 (scale rows are DMA'd "
                 f"[1, block]), got {block}")
     npages = block_tables.shape[1]
-    rows = hp * groups * c
-    pp = _pages_per_program(block, npages, rows, pages_per_program)
+    rows = heads * groups * c
+    pp = _pages_per_program(pool_k, hkv, kv_bits, rows, width, npages,
+                            pages_per_program)
     ngroups = -(-npages // pp)
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(d)
-    meta = jnp.stack([jnp.asarray(base, jnp.int32).reshape(b),
-                      jnp.asarray(total, jnp.int32).reshape(b)], axis=1)
+    # what a step needs to know of the walk, so that a dead step tests
+    # one number and a live one finds the next live step without
+    # looking at its neighbours' pages
+    base = jnp.asarray(base, jnp.int32).reshape(b)
+    total = jnp.asarray(total, jnp.int32).reshape(b)
+    live = jnp.clip(-(-total // (pp * block)), 0, ngroups)
+    slot = jnp.where(live > 0, jnp.arange(b, dtype=jnp.int32), b)
+    later = jnp.append(jax.lax.cummin(slot, reverse=True)[1:], b)
+    meta = jnp.stack([base, total, live, jnp.cumsum(live) - live, later])
     block_tables = jnp.asarray(block_tables, jnp.int32)
     if kv_bits == 0:
         q = q.astype(pool_k.dtype)
-    # [B, C, H, D] -> [B, pack, split, (head-in-pack, group, c), W] with
-    # head j's rows non-zero only in lane window j (block-diagonal).
-    # Query head (pack * hp + j) * G + g reads kv head pack * hp + j.
-    qg = q.reshape(b, c, npacks, hp, groups, nsplit, d_eff)
-    qg = qg.transpose(0, 2, 5, 3, 4, 1, 6)              # b P s j g c e
-    eye = jnp.eye(hp, dtype=q.dtype)
-    qg = jnp.einsum("bpsjgce,jk->bpsjgcke", qg, eye)
-    qg = qg.reshape(b, npacks, nsplit, rows, width)
+    # [B, C, H, D] -> [B, window, split, (head-in-window, group, c), W]
+    # with head j's rows non-zero only in lane window j (block-diagonal).
+    # Query head (win * heads + j) * G + g reads kv head win * heads + j.
+    qg = q.reshape(b, c, nwin, heads, groups, nsplit, d_eff)
+    qg = qg.transpose(0, 2, 5, 3, 4, 1, 6)              # b w s j g c e
+    eye = jnp.eye(heads, dtype=q.dtype)
+    qg = jnp.einsum("bwsjgce,jk->bwsjgcke", qg, eye)
+    qg = qg.reshape(b, nwin, nsplit, rows, width)
     coff = jnp.tile(jnp.arange(c, dtype=jnp.int32),
-                    hp * groups).reshape(rows, 1)
-    rhead = jnp.repeat(jnp.arange(hp, dtype=jnp.int32),
+                    heads * groups).reshape(rows, 1)
+    rhead = jnp.repeat(jnp.arange(heads, dtype=jnp.int32),
                        groups * c).reshape(rows, 1)
 
     nops = 2 if kv_bits == 0 else 4
     operands = [coff, rhead, qg, pool_k, pool_v]
-    scratch = [pltpu.VMEM((2, pp, block, width), pool_k.dtype),
-               pltpu.VMEM((2, pp, block, width), pool_v.dtype)]
+    scratch = [pltpu.VMEM((2, pp, block, lanes), pool_k.dtype),
+               pltpu.VMEM((2, pp, block, lanes), pool_v.dtype)]
     if kv_bits:
         operands += [k_scale.astype(jnp.float32),
                      v_scale.astype(jnp.float32)]
-        scratch += [pltpu.VMEM((2, pp, hp, 1, block), jnp.float32),
-                    pltpu.VMEM((2, pp, hp, 1, block), jnp.float32)]
-    scratch += [pltpu.VMEM((rows, 1), jnp.float32),
-                pltpu.VMEM((rows, 1), jnp.float32),
-                pltpu.VMEM((nsplit, rows, width), jnp.float32),
+        scratch += [pltpu.VMEM((2, pp, hkv, 1, block), jnp.float32),
+                    pltpu.VMEM((2, pp, hkv, 1, block), jnp.float32)]
+    scratch += [pltpu.VMEM((nwin, rows, 1), jnp.float32),
+                pltpu.VMEM((nwin, rows, 1), jnp.float32),
+                pltpu.VMEM((nwin, nsplit, rows, width), jnp.float32),
                 pltpu.SemaphoreType.DMA((2, nops))]
-    qspec = pl.BlockSpec((None, None, nsplit, rows, width),
-                         lambda i, hh, g, *_: (i, hh, 0, 0, 0))
-    rspec = pl.BlockSpec((rows, 1), lambda i, hh, g, *_: (0, 0))
+    qspec = pl.BlockSpec((None, nwin, nsplit, rows, width),
+                         lambda i, g, *_: (i, 0, 0, 0, 0))
+    rspec = pl.BlockSpec((rows, 1), lambda i, g, *_: (0, 0))
 
     out = pl.pallas_call(
         functools.partial(_kernel, sm_scale=sm_scale, block=block, pp=pp,
-                          kv_bits=kv_bits, width=width, hp=hp),
+                          kv_bits=kv_bits, width=width, heads=heads),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(b, npacks, ngroups),
+            grid=(b, ngroups),
             in_specs=[rspec, rspec, qspec]
             + [pl.BlockSpec(memory_space=pl.ANY)] * nops,
             out_specs=qspec,
@@ -383,13 +439,14 @@ def _paged_attention(q, pool_k, pool_v, base, total, block_tables, *,
         ),
         out_shape=jax.ShapeDtypeStruct(qg.shape, q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT_BYTES),
         interpret=interpret,
         name="paged_attention",
     )(meta, block_tables, *operands)
     # keep each head's own lane window of its rows
-    out = out.reshape(b, npacks, nsplit, hp, groups, c, hp, d_eff)
-    out = jnp.einsum("bpsjgcke,jk->bpsjgce", out, eye)
+    out = out.reshape(b, nwin, nsplit, heads, groups, c, heads, d_eff)
+    out = jnp.einsum("bwsjgcke,jk->bwsjgce", out, eye)
     return out.transpose(0, 5, 1, 3, 4, 2, 6).reshape(b, c, h, d)
 
 

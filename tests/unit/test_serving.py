@@ -364,6 +364,134 @@ class TestMultiPageQuantizedKernels:
 
 
 # ---------------------------------------------------------------------------
+# the page walk: dead steps, the prefetch chain, windows of heads
+# ---------------------------------------------------------------------------
+#: (query heads, kv heads, head dim): 16 one-head packs, 8 two-head
+#: packs, one four-head pack of 384 lanes, GQA over two-head packs
+WALK_SHAPES = {"16packs": (16, 16, 128), "8packs2h": (16, 16, 64),
+               "pack4h": (4, 4, 96), "gqa": (8, 4, 64)}
+WALK_BLOCK, WALK_PAGES = 4, 7
+
+
+def nan_pools(slots, bs, npages, hkv, d, rng):
+    """Pools in which EVERY row is NaN — the null block, the spare
+    blocks the tables' padding points at, the rows past a slot's length
+    in its last page — except the rows ``slots`` (a list of (rows held,
+    finite?)) validly hold.  A slot that is not finite holds NaN in its
+    valid rows too: a request whose KV went non-finite, still in the
+    batch in the step that finds it out.  Returns pool_k, pool_v (token
+    rows [nb, bs, hkv, d]) and the tables [len(slots), npages], padded
+    with NaN-full blocks."""
+    nb = 3 + len(slots) * npages
+    pk = np.full((nb, bs, hkv, d), np.nan, np.float32)
+    pv = np.full((nb, bs, hkv, d), np.nan, np.float32)
+    avail = list(rng.permutation(np.arange(1, nb - 2)))
+    bt = np.tile(np.array([0, nb - 1, nb - 2], np.int32),
+                 (len(slots), npages))[:, :npages]
+    for i, (held, finite) in enumerate(slots):
+        for p in range(-(-held // bs)):
+            bt[i, p] = avail.pop()
+            if finite:
+                n = min(bs, held - p * bs)
+                pk[bt[i, p], :n] = rng.standard_normal((n, hkv, d))
+                pv[bt[i, p], :n] = rng.standard_normal((n, hkv, d))
+    return pk, pv, bt
+
+
+def walk_pools(pk, pv, q, kv):
+    """Token-row pools -> the kernel's operands for pool kind ``kv``
+    (f32 / bf16 / int8 / int4): pools, extra kwargs, tolerance."""
+    pk, pv = pool_layout(pk), pool_layout(pv)
+    if kv in ("f32", "bf16"):
+        dt = jnp.float32 if kv == "f32" else jnp.bfloat16
+        return pk.astype(dt), pv.astype(dt), {}, 3e-5 if kv == "f32" else 3e-2
+    bits = int(kv[3:])
+    kq, vq, ks, vs = quantize_case(q, pk, pv, bits)
+    return kq, vq, dict(k_scale=ks, v_scale=vs, kv_bits=bits), 3e-5
+
+
+class TestPagedWalk:
+    """One grid step per (slot, page group) with every head inside it:
+    lengths on every side of a group boundary, dead slots before,
+    between and after the live ones (the prefetch chain skips them), a
+    poisoned neighbour whose NaN pages are what the buffers hold when a
+    short slot leaves pages unfetched, NaN behind every masked row."""
+
+    @pytest.mark.parametrize("kv", ["f32", "bf16", "int8", "int4"])
+    @pytest.mark.parametrize("pp", [1, 3, None])
+    @pytest.mark.parametrize("shape", list(WALK_SHAPES))
+    def test_decode_walk(self, shape, pp, kv):
+        h, hkv, d = WALK_SHAPES[shape]
+        bs, npages = WALK_BLOCK, WALK_PAGES
+        group = (pp or npages) * bs
+        width = npages * bs
+        # a poisoned full-width slot first and in the middle: both buffer
+        # halves hold its NaN pages when the short slots after it run
+        lens = [width, 0, 1, min(group, width), min(group + 1, width),
+                width, 2, width, 0]
+        poisoned = (0, 5)
+        rng = np.random.default_rng(len(shape) + 7 * (pp or 0))
+        pk, pv, bt = nan_pools(
+            [(ln, i not in poisoned) for i, ln in enumerate(lens)],
+            bs, npages, hkv, d, rng)
+        q = jnp.asarray(rng.standard_normal((len(lens), h, d)), jnp.float32)
+        pk, pv, kw, atol = walk_pools(pk, pv, q, kv)
+        ln, bt = jnp.asarray(lens, jnp.int32), jnp.asarray(bt)
+        out = np.asarray(paged_decode_attention(
+            q, pk, pv, ln, bt, interpret=True, pages_per_program=pp, **kw),
+            np.float32)
+        ref = np.asarray(paged_attention_reference(q, pk, pv, ln, bt, **kw))
+        keep = [i for i in range(len(lens)) if i not in poisoned]
+        assert np.isfinite(out[keep]).all()
+        assert (out[[1, 8]] == 0).all()                # dead slots
+        np.testing.assert_allclose(out[keep], ref[keep], atol=atol)
+
+    @pytest.mark.parametrize("pp", [1, 3, None])
+    @pytest.mark.parametrize("lens", [[0, 0, 0, 0, 0], [0, 0, 0, 0, 9],
+                                      [9, 0, 0, 0, 0], [0, 28, 0, 0, 5]],
+                             ids=["all_dead", "last_live", "first_live",
+                                  "gaps"])
+    def test_prefetch_chain_skips_dead_slots(self, lens, pp):
+        rng = np.random.default_rng(sum(lens))
+        pk, pv, bt = nan_pools([(ln, True) for ln in lens], WALK_BLOCK,
+                               WALK_PAGES, 4, 64, rng)
+        q = jnp.asarray(rng.standard_normal((len(lens), 4, 64)),
+                        jnp.float32)
+        pk, pv = pool_layout(pk), pool_layout(pv)
+        ln, bt = jnp.asarray(lens, jnp.int32), jnp.asarray(bt)
+        out = np.asarray(paged_decode_attention(
+            q, pk, pv, ln, bt, interpret=True, pages_per_program=pp))
+        ref = np.asarray(paged_attention_reference(q, pk, pv, ln, bt))
+        assert (out[np.asarray(lens) == 0] == 0).all()
+        np.testing.assert_allclose(out, ref, atol=3e-5)
+
+    @pytest.mark.parametrize("kv", ["f32", "bf16", "int8", "int4"])
+    @pytest.mark.parametrize("pp", [3, None])
+    @pytest.mark.parametrize("shape", list(WALK_SHAPES))
+    def test_chunk_walk(self, shape, pp, kv):
+        """A chunk whose base is no multiple of the group (3 pages of 4
+        = 12 keys), ending inside a page, in a table wider than it
+        needs: NaN behind the tail rows and behind the padding."""
+        from deepspeed_tpu.ops.transformer.paged_decode_attention import (
+            paged_prefill_attention, paged_prefill_reference)
+        h, hkv, d = WALK_SHAPES[shape]
+        base, n, c = 7, 11, 16
+        rng = np.random.default_rng(len(shape) + (pp or 0))
+        pk, pv, bt = nan_pools([(base + n, True)], WALK_BLOCK, WALK_PAGES,
+                               hkv, d, rng)
+        q = jnp.asarray(rng.standard_normal((c, h, d)), jnp.float32)
+        pk, pv, kw, atol = walk_pools(pk, pv, q, kv)
+        b, cl, bt = jnp.int32(base), jnp.int32(n), jnp.asarray(bt[0])
+        out = np.asarray(paged_prefill_attention(
+            q, pk, pv, b, cl, bt, interpret=True, pages_per_program=pp,
+            **kw), np.float32)[:n]
+        ref = np.asarray(paged_prefill_reference(q, pk, pv, b, cl, bt,
+                                                 **kw))[:n]
+        assert np.isfinite(out).all()
+        np.testing.assert_allclose(out, ref, atol=atol)
+
+
+# ---------------------------------------------------------------------------
 # block allocator
 # ---------------------------------------------------------------------------
 class TestBlockAllocator:

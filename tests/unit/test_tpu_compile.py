@@ -134,42 +134,133 @@ def paged_args(sds, d, kv_bits, hkv, block, nb=64):
     return pool, (scale if kv_bits else None)
 
 
-@pytest.mark.parametrize("d", [64, 128])
-@pytest.mark.parametrize("kv_bits", [0, 8, 4])
-@pytest.mark.parametrize("h,hkv", [(16, 16), (16, 4)])
-def test_paged_decode_compiles(v5e_devices, d, kv_bits, h, hkv):
-    sds = one_chip(v5e_devices)
-    block = 128 if kv_bits else 16       # scale rows are DMA'd [1, block]
+#: the serving cells' own decode calls: (slots, pages of 16, heads, head
+#: dim) of `pythia-1.4b.serve-*`, of the would-be
+#: `gpt2-medium.serve-decode-sat`, and of one tensor-parallel shard of each
+CELL_SHAPES = {"pythia": (24, 128, 16, 128), "gpt2-medium": (48, 64, 16, 64),
+               "pythia-tp4": (24, 128, 4, 128),
+               "gpt2-medium-tp4": (48, 64, 4, 64)}
+
+
+def paged_decode_case(sds, slots, pages, h, hkv, d, kv_bits, block, **kw):
+    """(fn, abstract args) of one decode call."""
     pool, scale = paged_args(sds, d, kv_bits, hkv, block)
-    q = sds((8, h, d), jnp.bfloat16)
-    lens = sds((8,), jnp.int32)
-    tables = sds((8, 1024 // block), jnp.int32)
+    args = (sds((slots, h, d), jnp.bfloat16), pool, pool,
+            sds((slots,), jnp.int32), sds((slots, pages), jnp.int32),
+            scale, scale)
 
     def fn(q, pk, pv, lens, tables, ks, vs):
         return paged_decode_attention(q, pk, pv, lens, tables, k_scale=ks,
                                       v_scale=vs, kv_bits=kv_bits,
-                                      interpret=False)
-    assert "tpu_custom_call" in compile_for_tpu(
-        fn, q, pool, pool, lens, tables, scale, scale)
+                                      interpret=False, **kw)
+    return fn, args
+
+
+def paged_prefill_case(sds, pages, h, d, kv_bits, block, **kw):
+    """(fn, abstract args) of one 256-row chunk call (the serving
+    default) at ``h`` MHA heads."""
+    pool, scale = paged_args(sds, d, kv_bits, h, block)
+    scalar = sds((), jnp.int32)
+    args = (sds((256, h, d), jnp.bfloat16), pool, pool, scalar, scalar,
+            sds((pages,), jnp.int32), scale, scale)
+
+    def fn(q, pk, pv, base, n, table, ks, vs):
+        return paged_prefill_attention(q, pk, pv, base, n, table,
+                                       k_scale=ks, v_scale=vs,
+                                       kv_bits=kv_bits, interpret=False,
+                                       **kw)
+    return fn, args
+
+
+@pytest.mark.parametrize("d", [64, 128])
+@pytest.mark.parametrize("kv_bits", [0, 8, 4])
+@pytest.mark.parametrize("h,hkv", [(16, 16), (16, 4)])
+def test_paged_decode_compiles(v5e_devices, d, kv_bits, h, hkv):
+    block = 128 if kv_bits else 16       # scale rows are DMA'd [1, block]
+    fn, args = paged_decode_case(one_chip(v5e_devices), 8, 1024 // block,
+                                 h, hkv, d, kv_bits, block)
+    assert "tpu_custom_call" in compile_for_tpu(fn, *args)
+
+
+@pytest.mark.parametrize("cell", list(CELL_SHAPES))
+def test_paged_decode_compiles_at_the_cells_shapes(v5e_devices, cell):
+    slots, pages, h, d = CELL_SHAPES[cell]
+    fn, args = paged_decode_case(one_chip(v5e_devices), slots, pages, h, h,
+                                 d, 0, 16)
+    assert "tpu_custom_call" in compile_for_tpu(fn, *args)
 
 
 @pytest.mark.parametrize("d", [64, 128])
 @pytest.mark.parametrize("kv_bits", [0, 8, 4])
 def test_paged_prefill_compiles(v5e_devices, d, kv_bits):
     """The serving default chunk (256 tokens) at 16 MHA heads."""
-    sds = one_chip(v5e_devices)
     block = 128 if kv_bits else 16
-    pool, scale = paged_args(sds, d, kv_bits, 16, block)
-    q = sds((256, 16, d), jnp.bfloat16)
-    scalar = sds((), jnp.int32)
-    table = sds((1024 // block,), jnp.int32)
+    fn, args = paged_prefill_case(one_chip(v5e_devices), 1024 // block, 16,
+                                  d, kv_bits, block)
+    assert "tpu_custom_call" in compile_for_tpu(fn, *args)
 
-    def fn(q, pk, pv, base, n, table, ks, vs):
-        return paged_prefill_attention(q, pk, pv, base, n, table,
-                                       k_scale=ks, v_scale=vs,
-                                       kv_bits=kv_bits, interpret=False)
-    assert "tpu_custom_call" in compile_for_tpu(
-        fn, q, pool, pool, scalar, scalar, table, scale, scale)
+
+@pytest.mark.parametrize("cell", list(CELL_SHAPES))
+def test_paged_prefill_compiles_at_the_cells_shapes(v5e_devices, cell):
+    _, pages, h, d = CELL_SHAPES[cell]
+    fn, args = paged_prefill_case(one_chip(v5e_devices), pages, h, d, 0, 16)
+    assert "tpu_custom_call" in compile_for_tpu(fn, *args)
+
+
+def kernel_eqns(fn, *args):
+    """Every equation of the Pallas kernel ``fn`` calls, loops and
+    branches included."""
+    def walk(jaxpr, out):
+        for eqn in jaxpr.eqns:
+            out.append(eqn)
+            for v in eqn.params.values():
+                for sub in (v if isinstance(v, (list, tuple)) else [v]):
+                    sub = getattr(sub, "jaxpr", sub)
+                    if hasattr(sub, "eqns"):
+                        walk(sub, out)
+        return out
+    call, = (e for e in walk(jax.make_jaxpr(fn)(*args).jaxpr, [])
+             if e.primitive.name == "pallas_call")
+    return walk(call.params["jaxpr"], [])
+
+
+@pytest.mark.parametrize("chunk", [False, True], ids=["decode", "chunk"])
+def test_paged_kernel_fetches_whole_pages_and_loops_heads(chunk):
+    """What the kernel's speed and the serving set-up's trace + lower
+    time rest on, read off the kernel's jaxpr (no clock): a page is ONE
+    copy per operand of the whole ``[block, Hkv * De]`` block — issued
+    from two sites (cold start, prefetch), each a loop over the group's
+    live pages, so a step starts at most ``pp`` fetches per operand
+    whatever the number of packs — and the body holds one QK^T and one
+    PV however many packs and pages a step covers."""
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype)
+
+    def counts(h, d, pp):
+        if chunk:
+            fn, args = paged_prefill_case(sds, 128, h, d, 0, 16,
+                                          pages_per_program=pp)
+        else:
+            fn, args = paged_decode_case(sds, 24, 128, h, h, d, 0, 16,
+                                         pages_per_program=pp)
+        eqns = kernel_eqns(fn, *args)
+        starts = [e for e in eqns if e.primitive.name == "dma_start"]
+        for e in starts:
+            src, src_index, dst, dst_index = jax.tree_util.tree_unflatten(
+                e.params["tree"], e.invars)[:4]
+            assert src_index[0].get_indexer_shape() == (16, h * d)
+            assert dst.aval.shape == (2, pp, 16, h * d)
+            assert dst_index[0].get_indexer_shape() == (16, h * d)
+        names = [e.primitive.name for e in eqns]
+        return {n: names.count(n) for n in ("dma_start", "dma_wait",
+                                            "dot_general", "while", "scan")}
+    few = counts(4, 128, 16)                  # 4 packs x 16 pages
+    assert few["dma_start"] == 4 and few["dma_wait"] == 2     # k and v
+    assert few["dot_general"] == 2
+    assert few["while"] == 3                  # over the live pages
+    assert few["scan"] == chunk               # over the head windows
+    assert counts(16, 128, 32) == few         # 16 packs x 32 pages
+    assert counts(16, 64, 32) == few          # 8 two-head packs
 
 
 def test_paged_rejects_shapes_the_tpu_cannot_tile():
