@@ -351,8 +351,7 @@ class Autotuner:
 
     # -- scheduled (subprocess) tuning -------------------------------------
     def _make_specs(self, seq: Optional[int] = None,
-                    steps: Optional[int] = None,
-                    profile_phases: bool = False) -> List[Dict[str, Any]]:
+                    steps: Optional[int] = None) -> List[Dict[str, Any]]:
         """Job specs for the experiment scheduler: the in-process
         model-based pruner stays the PROPOSAL stage; measurement moves to
         isolated subprocesses."""
@@ -373,7 +372,6 @@ class Autotuner:
                 "cfg": exp["cfg"], "model_config": mc,
                 "steps": steps or self.steps_per_trial,
                 "seq": seq,
-                "profile_phases": bool(profile_phases),
                 "meta": {"mb": exp["mb"],
                          "zero_stage": exp["cfg"]["zero_optimization"]
                          ["stage"],
@@ -389,8 +387,7 @@ class Autotuner:
                        timeout_s: float = 600.0,
                        env: Optional[Dict[str, str]] = None,
                        seq: Optional[int] = None,
-                       specs: Optional[List[Dict[str, Any]]] = None,
-                       profile_phases: bool = False
+                       specs: Optional[List[Dict[str, Any]]] = None
                        ) -> Dict[str, Any]:
         """Reference `Autotuner.tune` (`autotuner.py:421`) semantics:
         experiments run as scheduler jobs with crash/timeout isolation
@@ -400,8 +397,7 @@ class Autotuner:
         import json
         import os
         from .scheduler import ResourceManager
-        specs = specs if specs is not None else self._make_specs(
-            seq=seq, profile_phases=profile_phases)
+        specs = specs if specs is not None else self._make_specs(seq=seq)
         # smallest micro-batches first: cheap failures surface early
         order = sorted(range(len(specs)),
                        key=lambda i: specs[i]["meta"]["mb"])
@@ -419,8 +415,6 @@ class Autotuner:
                    "status": res["status"],
                    "samples_per_sec": res.get("samples_per_sec"),
                    "detail": res.get("detail", "")}
-            if res.get("phases"):   # optional per-phase profile
-                row["phases"] = res["phases"]
             self.results.append(row)
         ranked = sorted((r for r in self.results
                          if r["samples_per_sec"] is not None),
@@ -442,7 +436,7 @@ class Autotuner:
         kw = {k: v for k, v in best_meta.items()
               if k not in ("mb", "zero_stage", "offload", "wire_bits",
                            "mesh", "status", "samples_per_sec", "detail",
-                           "spec_index", "phases")}
+                           "spec_index")}
         if kw:
             best["_model_overrides"] = kw
         logger.info(f"scheduled autotune best: {best_meta}")

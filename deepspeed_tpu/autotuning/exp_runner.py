@@ -64,25 +64,9 @@ def run(spec: Dict[str, Any]) -> Dict[str, Any]:
             m = engine.train_step(batch)
         float(m["loss"])
         dt = (time.perf_counter() - t0) / steps
-        out = {"status": "ok",
-               "samples_per_sec": engine.train_batch_size / dt,
-               "step_seconds": dt, "detail": ""}
-        if spec.get("profile_phases"):
-            # per-phase attribution of THIS trial via the shared roofline
-            # engine (profiling/phase_bench.py). Timing-only unless the
-            # spec carries probed ceilings — re-probing the roofline per
-            # experiment would dominate the trial. Best-effort: a profile
-            # failure must not fail a measured experiment.
-            try:
-                from ..profiling.phase_bench import phase_breakdown
-                out["phases"] = phase_breakdown(
-                    engine, model, batch, seq, dt,
-                    spec.get("gemm_tflops"), spec.get("hbm_gbps"),
-                    inner=2, reps=1, do_feed_registry=False)
-            except Exception as e:
-                out["phases"] = {"error":
-                                 f"{type(e).__name__}: {str(e)[:200]}"}
-        return out
+        return {"status": "ok",
+                "samples_per_sec": engine.train_batch_size / dt,
+                "step_seconds": dt, "detail": ""}
     except Exception as e:  # classified, not propagated
         status = ("oom" if any(s in str(e) for s in _OOM_MARKERS)
                   else "error")

@@ -245,19 +245,17 @@ class ServingEngine:
         self._hc_codec: Optional[BlockCodec] = None
         self._gather_block = self._scatter_block = None
         self._promote_k = cfg.host_cache.promote_parallelism
-        #: plain-int mirrors for bench_all / callers without the registry
+        #: plain-int mirrors of the host-tier counters, read by
+        #: tests/unit/test_host_cache.py without the registry
         self.host_counts = {"promoted_blocks": 0, "promote_failures": 0,
                             "spill_failures": 0}
         #: KV-fabric mirrors (disaggregated fleet): chain blocks this
         #: engine published, publishes degraded to decode-side
-        #: recompute, and prefill-only requests completed
+        #: recompute, and prefill-only requests completed; read by
+        #: fleet/replica.py's snapshot and tests/unit/test_disagg_fleet.py
         self.fabric_counts = {"published_blocks": 0,
                               "publish_failures": 0,
                               "prefill_only_completed": 0}
-        #: wall seconds inside _service_promotions — with
-        #: ``promoted_blocks * codec.nbytes`` this is the promote
-        #: bandwidth the tiered-cache bench reports
-        self.promote_seconds = 0.0
         if cfg.host_cache.enabled:
             if not cfg.prefix_cache:
                 raise ValueError(
@@ -412,8 +410,8 @@ class ServingEngine:
             "dstpu_serving_quarantined_total",
             "requests quarantined on non-finite logits (KV discarded, "
             "batch unaffected)")
-        #: plain-int mirror of the lifecycle counters for bench_all /
-        #: callers without the metrics registry
+        #: plain-int mirror of the lifecycle counters, read by
+        #: fleet/replica.py's snapshot and the serving tests
         self.lifecycle_counts = {"cancelled": 0, "timed_out": 0,
                                  "shed": 0, "failed": 0, "quarantined": 0}
         # speculative-decoding acceptance (docs/serving.md "Speculative
@@ -429,8 +427,8 @@ class ServingEngine:
             "draft proposals per slot per iteration (0 = speculative "
             "decoding off)").set(self.spec_k if draft_model is not None
                                  else 0)
-        #: plain-int mirror for bench_all (acceptance_rate =
-        #: accepted / proposed)
+        #: plain-int mirror read by tests/unit/test_frontend.py and
+        #: test_serving_chaos.py (acceptance rate = accepted / proposed)
         self.spec_counts = {"proposed": 0, "accepted": 0}
         # tiered host cache metrics (docs/serving.md "Tiered prefix
         # cache"): per-tier hit/spill/evict counters, resident-bytes and
@@ -907,7 +905,6 @@ class ServingEngine:
             self.host_counts["promoted_blocks"] += 1
             self._m_promoted.inc()
         dur = time.perf_counter() - t0
-        self.promote_seconds += dur
         if landed and self._rt.enabled:
             self._rt.on_promote(promoting, t0, dur, landed)
         return landed

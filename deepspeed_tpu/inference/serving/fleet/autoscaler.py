@@ -99,7 +99,7 @@ class FleetAutoscaler:
         self.queue_low = queue_low
         self.quiet_s = quiet_s
         #: scale decisions, in order: dicts with t/action/role/replica/
-        #: reason — the bench correlates these with breach timestamps
+        #: reason — tests correlate these with breach timestamps
         self.events: List[Dict] = []
         self.counts = {"scale_ups": 0, "scale_downs": 0,
                        "budget_denials": 0, "actuator_failures": 0}

@@ -215,7 +215,7 @@ class FleetRouter:
             "dstpu_fleet_fabric_orphans_reaped_total",
             "published-never-claimed fabric entries swept after a "
             "publisher died or drained")
-        #: plain-int mirrors for the bench / callers without the registry
+        #: plain-int mirrors for the autoscaler and the fleet tests
         self.fleet_counts = {"failovers": 0, "replayed_tokens": 0,
                              "dead_replicas": 0, "shed_retries": 0,
                              "drains": 0, "joins": 0, "handoffs": 0,

@@ -89,8 +89,7 @@ class StreamDeduper:
 
 class StreamCollector:
     """Minimal ``on_token`` sink: records tokens and events in arrival
-    order (tests and the replay bench read ``tokens`` / ``events``
-    after the drain)."""
+    order (tests read ``tokens`` / ``events`` after the drain)."""
 
     def __init__(self) -> None:
         self.tokens: List[int] = []

@@ -1491,8 +1491,7 @@ class TransformerLM:
 
     def nll_from_hidden(self, params, x, labels, mask=None) -> jnp.ndarray:
         """Mean masked NLL from final hidden states ([B,T,D]) — the loss
-        HEAD alone, exposed so it can be timed/attributed separately from
-        the trunk (bench.py phase breakdown)."""
+        HEAD alone, separate from the trunk."""
         c = self.config
         chunk = c.loss_chunk
         t = labels.shape[1]

@@ -88,13 +88,6 @@ _CORE_METRICS = (
      "in-flight NVMe slot-store aio operations"),
     ("gauge", "dstpu_device_peak_memory_bytes",
      "device memory high-water mark (memory_stats)"),
-    # training-phase roofline gauges, fed whenever a phase breakdown
-    # runs (profiling/phase_bench.py feed_registry; bench.py, autotuner
-    # trials with profiling on) — docs/training_perf.md
-    ("gauge", "dstpu_train_backward_ms",
-     "measured backward phase time per train step"),
-    ("gauge", "dstpu_train_backward_efficiency",
-     "backward roofline efficiency (ideal/measured, binding resource)"),
 )
 
 

@@ -230,7 +230,7 @@ class SloMonitor:
                 or self.firing(tenant, KIND_ITL))
 
     def snapshot(self) -> Dict[str, Dict[str, Any]]:
-        """Host-side state dump (flight-recorder / bench friendly)."""
+        """Host-side state dump (for the flight recorder)."""
         out: Dict[str, Dict[str, Any]] = {}
         with self._lock:
             for (tenant, kind), ks in self._keys.items():

@@ -1,8 +1,3 @@
 """Profiling — counterpart of `/root/reference/deepspeed/profiling/`.
 
-``flops_profiler`` mirrors the reference module; ``phase_bench`` is the
-shared per-phase train-step roofline used by ``bench.py``, the
-autotuner's experiment runner, and the observability gauges
-(docs/training_perf.md)."""
-
-from .phase_bench import feed_registry, phase_breakdown  # noqa: F401
+``flops_profiler`` mirrors the reference module."""

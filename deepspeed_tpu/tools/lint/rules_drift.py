@@ -4,8 +4,8 @@ The repo's contract surfaces live in three kinds of artifact that
 nothing ties together: metric names registered in code vs the docs
 tables operators grep, fault-injection sites vs the chaos matrices that
 sweep them, and config keys vs the constants and reference tables that
-declare them.  Each pair drifts silently — ``dstpu_train_backward_ms``
-was registered for two PRs before any docs table mentioned it.  These
+declare them.  Each pair drifts silently — a gauge can stay registered
+for two PRs before any docs table mentions it.  These
 rules generalize LIFE003's doc-catalog check into a reconciler driven
 by the PR 7 symbol table:
 
@@ -23,9 +23,10 @@ by the PR 7 symbol table:
             documented key no dataclass consumes
 
 Templated names use ``*`` for dynamic segments on both sides: code
-``f"dstpu_train_{name}_ms"`` becomes ``dstpu_train_*_ms`` and the docs
-placeholder ``dstpu_train_<phase>_ms`` becomes the same; either side's
-wildcard matches one-or-more characters of the other.
+``f"dstpu_comm_volume_bytes_{op}"`` becomes ``dstpu_comm_volume_bytes_*``
+and the docs placeholder ``dstpu_comm_volume_bytes_<op>`` becomes the
+same; either side's wildcard matches one-or-more characters of the
+other.
 
 The family is assembly-shaped: per-module extraction (cached by the
 incremental engine) plus a cheap global pass over docs/ and

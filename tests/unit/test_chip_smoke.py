@@ -3,6 +3,7 @@ phase fails, and share one compile cache.  (That ``chip_smoke.py``
 passes is only ever shown on the chip; here the sandbox has none, which
 is exactly the case these tests need.)"""
 import importlib.util
+import json
 import os
 import subprocess
 import sys
@@ -16,13 +17,21 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
 
-@pytest.mark.parametrize("script", ["chip_smoke.py", "bench.py",
-                                    "bench_all.py", "kernel_diag.py"])
-def test_script_fails_without_a_tpu(script):
+with open(os.path.join(ROOT, "BENCHMARK.json")) as _f:
+    _CELLS = [w["name"] for w in json.load(_f)["workloads"]]
+
+
+# the smoke, and the driver's own command for every cell of the benchmark
+@pytest.mark.parametrize("command", [
+    pytest.param(["chip_smoke.py"], id="chip_smoke.py"),
+    *(pytest.param(["benchmark/run.py", "--workload", cell, "--seed", "1",
+                    "--seconds", "1", "--trace", "0"], id=cell)
+      for cell in _CELLS)])
+def test_script_fails_without_a_tpu(command):
     """Non-zero exit, a message naming the missing TPU, and no metric or
     result line on stdout — never a CPU number under a chip's name."""
     env = dict(os.environ, JAX_PLATFORMS="cpu")
-    proc = subprocess.run([sys.executable, os.path.join(ROOT, script)],
+    proc = subprocess.run([sys.executable, *command],
                           cwd=ROOT, env=env, capture_output=True,
                           text=True, timeout=300)
     assert proc.returncode != 0

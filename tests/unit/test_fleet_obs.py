@@ -479,9 +479,9 @@ def test_overlap_chrome_events_render_iteration_track(obs_reset):
 
 
 def test_inference_config_accepts_observability_block():
-    """``init_inference`` takes the SAME observability block as training
-    (bench_all's serving benches pass one); None (the default) must
-    leave the process-global singletons untouched."""
+    """``init_inference`` takes the SAME observability block as
+    training; None (the default) must leave the process-global
+    singletons untouched."""
     from deepspeed_tpu.inference.config import DeepSpeedInferenceConfig
     cfg = DeepSpeedInferenceConfig(
         observability={"metrics": {"enabled": True},

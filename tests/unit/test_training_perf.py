@@ -1,14 +1,11 @@
-"""Training-perf suite: remat overrides, fused loss head, phase
-roofline, and the autotuner feedback loop (docs/training_perf.md).
+"""Training-perf suite: remat overrides, fused loss head, and the
+autotuner feedback loop (docs/training_perf.md).
 
 Pins the PR-11 acceptance contracts:
   * the ``training`` config block rebuilds the model per-engine and the
     step is numerically identical across remat policies;
   * the fused loss head (analytic custom-VJP cross-entropy) matches the
     autodiff path in value AND gradient for tied and untied heads;
-  * ``phase_breakdown`` (the shared engine behind bench.py, the
-    autotuner and the observability gauges) telescopes to the step with
-    a non-negative residual and feeds the ``dstpu_train_*`` gauges;
   * a 2-point CPU smoke search emits a best-config JSON that the master
     ``DeepSpeedConfig`` parses round-trip and ``ds.initialize`` applies.
 """
@@ -134,47 +131,6 @@ class TestFusedLossHead:
         assert engine.model.config.loss_chunk == 4
         m = engine.train_step(make_batch(engine.train_batch_size))
         assert np.isfinite(float(m["loss"]))
-
-
-class TestPhaseBench:
-    @pytest.mark.slow
-    def test_timing_only_breakdown_and_gauges(self):
-        from deepspeed_tpu.observability import get_registry
-        from deepspeed_tpu.profiling.phase_bench import (PHASES,
-                                                         phase_breakdown)
-        engine, _, _, _ = ds.initialize(model=tiny_model(),
-                                        config=base_cfg())
-        batch = make_batch(engine.train_batch_size)
-        m = engine.train_step(batch)
-        float(m["loss"])
-        out = phase_breakdown(engine, engine.model, batch, 16,
-                              t_step=5e-3, inner=2, reps=1)
-        for name in PHASES:
-            assert out[name]["ms"] >= 0.0
-            # timing-only mode: no roofline columns without ceilings
-            assert "efficiency" not in out[name]
-        # the residual clamps at 0; overlap is reported, not a negative
-        # phase (satellite: the -3.8 ms dispatch_residual read as a bug)
-        assert out["dispatch_residual"]["ms"] >= 0.0
-        assert out["dispatch_residual"]["overlap_ms"] >= 0.0
-        g = get_registry().get("dstpu_train_backward_ms")
-        assert g is not None and g.value == out["backward"]["ms"]
-
-    @pytest.mark.slow
-    def test_roofline_mode_bounds_efficiency(self):
-        from deepspeed_tpu.profiling.phase_bench import phase_breakdown
-        engine, _, _, _ = ds.initialize(model=tiny_model(),
-                                        config=base_cfg())
-        batch = make_batch(engine.train_batch_size)
-        m = engine.train_step(batch)
-        float(m["loss"])
-        out = phase_breakdown(engine, engine.model, batch, 16,
-                              t_step=5e-3, gemm_tf=1.0, hbm_gbps=10.0,
-                              inner=2, reps=1)
-        for name in ("fwd", "loss_head", "backward"):
-            if "efficiency" in out[name]:
-                # the normalization makes >1.0 impossible by construction
-                assert out[name]["efficiency"] <= 1.0 + 1e-9
 
 
 class TestAutotuneSmoke:
