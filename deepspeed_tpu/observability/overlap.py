@@ -7,10 +7,14 @@ phases, marked where the work happens in ``_step_impl`` / ``_dispatch``:
 
   - ``plan`` — deadline sweep, decode capacity, admissions, promotions,
     terminal drain, gauges, ``next_prefill_chunk`` / ``decoding_slots``;
-  - ``operands`` — ``_step_operands``: the mixed program's host arrays;
-  - ``enqueue`` — ``self._step_fn(*operands)`` until it returns;
-  - ``device_wait`` — from that return until the last ``np.asarray`` of
-    the results: the host blocked on the device;
+  - ``operands`` — ``_step_operands``: NumPy fills of the mixed
+    program's two host arrays (per-slot state with the block tables,
+    the chunk vector); no device program is launched in it;
+  - ``enqueue`` — ``self._step_fn(*operands)`` until it returns: the two
+    host-to-device transfers and the launch;
+  - ``device_wait`` — from that return, where the one result array's
+    copy to the host is queued behind the program, until the one
+    ``np.asarray`` that reads it: the host blocked on the device;
   - ``apply`` — results into the request records, commit hashing,
     finishes, terminal drain, event flush, flight record: everything
     until ``step`` returns.
@@ -19,7 +23,11 @@ A second dispatch in one iteration (a chunk remainder) re-enters
 ``plan`` .. ``apply`` and the times add up, so the five always sum to the
 iteration.  The record also carries what the dispatches did:
 ``dispatches``, ``decode_rows``, ``chunk_rows`` (rows that carried a
-token) and ``rows_computed`` (rows the program ran whatever rode).
+token), ``rows_computed`` (rows the program ran whatever rode), and what
+crossed between host and device: ``host_arrays_in`` (host arrays passed
+to the program: 2 a dispatch) and ``host_reads_out`` (device arrays
+materialised on the host: 1 a dispatch), counted by ``_dispatch`` from
+what it passed and read.
 
 A **training step** is recorded one-shot (``observe``) from the
 timestamps the step path already takes: enqueue, device wait, and the
@@ -61,6 +69,10 @@ PLAN, OPERANDS, ENQUEUE, DEVICE_WAIT, APPLY = range(len(PHASES))
 ITERATION_SPAN = "serving/iteration"
 PHASE_SPANS = tuple(f"serving/{p}" for p in PHASES)
 
+#: what an iteration's dispatches did (``count_dispatch``)
+COUNTERS = ("dispatches", "decode_rows", "chunk_rows", "rows_computed",
+            "host_arrays_in", "host_reads_out")
+
 #: one iteration (or training step).  Times are seconds on
 #: ``time.perf_counter()``'s clock; ``<phase>_s`` is the phase's summed
 #: time.
@@ -68,8 +80,7 @@ ITERATION_DTYPE = np.dtype(
     [("n", np.int64), ("kind", "U8"), ("begin_s", np.float64),
      ("end_s", np.float64)]
     + [(f"{p}_s", np.float64) for p in PHASES]
-    + [("dispatches", np.int64), ("decode_rows", np.int64),
-       ("chunk_rows", np.int64), ("rows_computed", np.int64)])
+    + [(c, np.int64) for c in COUNTERS])
 
 #: one terminal request: its own stamps.  One it never reached is NaN.
 REQUEST_DTYPE = np.dtype(
@@ -123,7 +134,8 @@ class OverlapProfiler:
         if ovl.enabled: ovl.mark(ENQUEUE)
         if ovl.enabled: ovl.mark(DEVICE_WAIT)
         if ovl.enabled:
-            ovl.mark(APPLY); ovl.count_dispatch(decode, chunk, computed)
+            ovl.mark(APPLY)
+            ovl.count_dispatch(decode, chunk, computed, n_in, n_out)
         ...                                         # another dispatch:
         if ovl.enabled: ovl.mark(PLAN)
         ...
@@ -150,7 +162,7 @@ class OverlapProfiler:
         self._mark_ns = 0
         self._phase = PLAN
         self._acc_ns = [0] * len(PHASES)
-        self._counts = [0, 0, 0, 0]
+        self._counts = [0] * len(COUNTERS)
         self._annotation = None           # jax.profiler.TraceAnnotation
         self._it_span = None
         self._phase_span = None
@@ -231,7 +243,7 @@ class OverlapProfiler:
         self._t0_ns = self._mark_ns = now
         self._phase = PLAN
         self._acc_ns = [0] * len(PHASES)
-        self._counts = [0, 0, 0, 0]
+        self._counts = [0] * len(COUNTERS)
         self._open = True
 
     def mark(self, phase: int) -> None:
@@ -248,15 +260,15 @@ class OverlapProfiler:
         self._phase_span.__enter__()
 
     def count_dispatch(self, decode_rows: int, chunk_rows: int,
-                       rows_computed: int) -> None:
+                       rows_computed: int, host_arrays_in: int = 0,
+                       host_reads_out: int = 0) -> None:
         """One dispatch of the mixed program: the rows that carried a
-        token (decoding slots, prompt-chunk tokens) and the rows the
-        program ran whatever rode."""
-        c = self._counts
-        c[0] += 1
-        c[1] += decode_rows
-        c[2] += chunk_rows
-        c[3] += rows_computed
+        token (decoding slots, prompt-chunk tokens), the rows the
+        program ran whatever rode, the host arrays passed to it and the
+        device arrays read back from it."""
+        for k, add in enumerate((1, decode_rows, chunk_rows, rows_computed,
+                                 host_arrays_in, host_reads_out)):
+            self._counts[k] += add
 
     def end(self, kind: str = "serving") -> None:
         if not self._open:
@@ -295,7 +307,7 @@ class OverlapProfiler:
                 else time.perf_counter_ns()) * 1e-9
         self._record(self._its.n, kind, t0_s, t0_s + total_s,
                      [plan_s, 0.0, enqueue_s, wait_s, 0.0],
-                     [dispatches, 0, 0, 0])
+                     [dispatches] + [0] * (len(COUNTERS) - 1))
 
     # -- terminal requests -------------------------------------------------
     def note_request(self, req) -> None:
@@ -373,8 +385,7 @@ class OverlapProfiler:
                                         + rec["apply_s"])}
             for p in PHASES:
                 out[f"{p}_s"] = float(rec[f"{p}_s"])
-            for k in ("dispatches", "decode_rows", "chunk_rows",
-                      "rows_computed"):
+            for k in COUNTERS:
                 out[k] = int(rec[k])
             return out
 
@@ -418,11 +429,7 @@ class OverlapProfiler:
                         "dur": float(rec["end_s"] - rec["begin_s"]) * 1e6,
                         "args": dict(phases_ms, n=int(rec["n"]),
                                      host_plan_ms=host_ms,
-                                     dispatches=int(rec["dispatches"]),
-                                     decode_rows=int(rec["decode_rows"]),
-                                     chunk_rows=int(rec["chunk_rows"]),
-                                     rows_computed=int(
-                                         rec["rows_computed"]))})
+                                     **{c: int(rec[c]) for c in COUNTERS})})
             out.append({"ph": "C", "pid": pid, "tid": tids[kind],
                         "name": f"{kind}_overlap", "ts": ts,
                         "args": {"host_plan_ms": host_ms,

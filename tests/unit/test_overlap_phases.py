@@ -106,6 +106,9 @@ def test_chunk_remainder_records_both_dispatches(srv, ovl):
     # 4 (the remainder) + 12 (the next prompt's head), no decode yet
     assert second["chunk_rows"] == 4 + 12 and second["decode_rows"] == 0
     assert second["rows_computed"] == 2 * (SLOTS + CHUNK)
+    # what crossed: two host arrays in and one result array out a dispatch
+    assert (first["host_arrays_in"], first["host_reads_out"]) == (2, 1)
+    assert (second["host_arrays_in"], second["host_reads_out"]) == (4, 2)
     # the second dispatch re-entered plan .. apply: still a partition
     assert sum(second[f"{p}_s"] for p in PHASES) == pytest.approx(
         second["end_s"] - second["begin_s"], abs=1e-7)
@@ -124,6 +127,8 @@ def test_useful_and_computed_rows_of_a_known_batch(srv, ovl):
     assert list(its["chunk_rows"]) == [16, 16, 16, 0, 0]
     assert list(its["decode_rows"]) == [0, 1, 2, 2, 1]
     assert np.all(its["rows_computed"] == SLOTS + CHUNK)
+    assert np.all(its["host_arrays_in"] == 2)
+    assert np.all(its["host_reads_out"] == 1)
     useful = (its["decode_rows"] + its["chunk_rows"]).sum()
     assert useful / its["rows_computed"].sum() == pytest.approx(54 / 100)
 
@@ -229,6 +234,33 @@ def test_train_observe_maps_onto_the_phases():
     assert (rec["begin_s"], rec["end_s"]) == pytest.approx((1.0, 1.010))
     assert [rec[f"{p}_s"] for p in PHASES] == pytest.approx(
         [0.003, 0.0, 0.002, 0.005, 0.0])
+
+
+@pytest.mark.parametrize("crossed", ((), (2, 1)), ids=("rows", "crossed"))
+def test_count_dispatch_adds_up_over_an_iteration(crossed):
+    """Two dispatches in one iteration: every counter is their sum, in
+    the record, in ``last()`` and on the Chrome track; a caller that
+    counts rows alone (the benchmark's reader tests) leaves the two
+    host-traffic counters at 0, as a training step does."""
+    prof = OverlapProfiler(capacity=4)
+    prof.configure(enabled=True)
+    prof.begin()
+    for rows in ((3, 16, 20), (0, 4, 20)):
+        prof.mark(overlap.OPERANDS)
+        prof.mark(overlap.APPLY)
+        prof.count_dispatch(*rows, *crossed)
+        prof.mark(overlap.PLAN)
+    prof.end()
+    want = dict(zip(overlap.COUNTERS, (2, 3, 20, 40) + tuple(
+        2 * c for c in crossed or (0, 0))))
+    (rec,), _ = prof.iterations(0.0, time.perf_counter())
+    last = prof.last()
+    (track,) = [e for e in prof.chrome_events(0, 0) if e["ph"] == "X"]
+    for name, value in want.items():
+        assert rec[name] == last[name] == track["args"][name] == value
+    prof.observe("train", total_s=0.01, enqueue_s=0.002, wait_s=0.005)
+    assert prof.last()["host_arrays_in"] == 0
+    assert prof.last()["host_reads_out"] == 0
 
 
 def test_disabled_step_touches_no_profiler_clock_or_annotation(

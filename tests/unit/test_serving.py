@@ -21,6 +21,8 @@ Coverage model:
     sites (transient = delay, fatal = one request FAILED).  The
     randomized chaos suite lives in ``test_serving_chaos.py``.
 """
+import time
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -1066,8 +1068,8 @@ def test_mixed_step_confines_each_layer_to_its_own_blocks(kv_bits, spec):
                    for a in range(nl) for b in range(a))
 
 
-def serving_engine(serving=None, model_cfg=None, **cfg):
-    eng = ds.init_inference(
+def inference_engine(serving=None, model_cfg=None, **cfg):
+    return ds.init_inference(
         TransformerLM(model_cfg or tiny_cfg()),
         # kernel injection off: the sequential-generate BASELINE must
         # run the xla decode path on every backend; the serving side
@@ -1081,6 +1083,10 @@ def serving_engine(serving=None, model_cfg=None, **cfg):
                             "prefill_chunk_tokens": 16,
                             **(serving or {})},
                 **cfg})
+
+
+def serving_engine(serving=None, model_cfg=None, **cfg):
+    eng = inference_engine(serving, model_cfg, **cfg)
     return eng, eng.serving_engine()
 
 
@@ -1877,3 +1883,185 @@ class TestFaultSites:
             np.testing.assert_array_equal(np.asarray(r.output),
                                           _generate(eng, p, 6))
         assert srv.allocator.num_used == 0
+
+
+# ---------------------------------------------------------------------------
+# what a dispatch carries (ISSUE 30): two host arrays in, one result array
+# out, float and key lanes by their bits — on every kind of step there is
+# ---------------------------------------------------------------------------
+PACKED_VARIANTS = {
+    "plain": {},
+    "draft": {"spec_k": 1},
+    "kv8": {"kv_cache_bits": 8},
+    # data=2 shards the per-slot array by rows, model=2 is the 2-way
+    # tensor-parallel step (float32: XLA:CPU aborts on it at bf16)
+    "tp2": {"mesh": {"data": 2, "model": 2}},
+}
+# every request of the mix has its own sampling lanes: greedy beside
+# temperature / top_k / top_p draws, each with an explicit seed
+PACKED_MIX = [
+    ([3, 1, 4, 1, 5, 9, 2, 6, 5], dict(temperature=0.0)),
+    ([2, 7, 1, 8, 2, 8], dict(temperature=0.8, seed=7)),
+    ([1, 6, 1, 8, 0, 3, 3, 9, 8, 8, 7], dict(temperature=0.6, top_k=12,
+                                          seed=9)),
+    ([1, 4, 1, 4, 2], dict(temperature=1.3, top_p=0.7, seed=2 ** 31 + 5)),
+    (list(range(20, 40)), dict(temperature=0.9, top_k=20, top_p=0.85,
+                               seed=123456789)),
+]
+
+
+def packed_cfg(layers):
+    return gpt2_config("125m", num_layers=layers, d_model=32, num_heads=4,
+                       vocab_size=64, max_seq_len=64, dtype=jnp.float32)
+
+
+@pytest.fixture(scope="module", params=list(PACKED_VARIANTS))
+def packed(request):
+    """One engine a variant, shared by the tests below: whatever they
+    send, the mixed program is built once."""
+    eng = inference_engine(PACKED_VARIANTS[request.param], packed_cfg(2))
+    if request.param == "draft":
+        draft = TransformerLM(packed_cfg(1))
+        srv = eng.serving_engine(
+            draft_model=draft,
+            draft_params=draft.init(jax.random.PRNGKey(1)))
+    else:
+        srv = eng.serving_engine()
+    yield eng, srv
+    assert srv.decode_builds == 1
+    assert srv.allocator.num_used == 0
+
+
+def sampled_generate(eng, prompt, n, temperature, seed=None, **samp):
+    rng = None if seed is None else jax.random.PRNGKey(seed)
+    return np.asarray(eng.generate(
+        np.asarray(prompt, np.int32)[None], max_new_tokens=n,
+        temperature=temperature, rng=rng, **samp))[0].tolist()
+
+
+def submit_mix(srv, n=6):
+    return [srv.submit(p, max_new_tokens=n, **samp)
+            for p, samp in PACKED_MIX]
+
+
+def test_packed_lanes_are_bit_exact(packed):
+    """A mixed batch of greedy and sampled requests emits the tokens of
+    ``generate()`` and of a one-request-at-a-time run: the temperature,
+    ``top_p`` and key lanes crossed as int32 without losing a bit."""
+    eng, srv = packed
+    batch = submit_mix(srv)
+    srv.run()
+    assert all(r.status is RequestStatus.OK for r in batch)
+    alone = []
+    for p, samp in PACKED_MIX:
+        alone.append(srv.submit(p, max_new_tokens=6, **samp))
+        srv.run()
+    for (p, samp), together, single in zip(PACKED_MIX, batch, alone):
+        assert together.output == single.output, (p, samp)
+        assert together.output == sampled_generate(eng, p, 6, **samp), \
+            (p, samp)
+    if srv._draft_model is not None:
+        assert srv.spec_counts["proposed"] > 0       # the lane ran
+    # the mix is not greedy in disguise: a sampled stream left the argmax
+    assert any(r.output != sampled_generate(eng, p, 6, 0.0)
+               for (p, _), r in zip(PACKED_MIX, batch))
+
+
+def test_dispatch_passes_two_host_arrays_and_reads_one(packed, monkeypatch):
+    """Beside the device's own state (weights, pools) the operands are
+    two NumPy arrays and nothing that lives on the device; one dispatch
+    materialises one device array on the host."""
+    from deepspeed_tpu.observability import get_overlap_profiler
+    _eng, srv = packed
+    submit_mix(srv, n=3)
+    srv.step()
+    dec = srv.scheduler.decoding_slots()
+    chunk = srv.scheduler.next_prefill_chunk(srv.chunk_tokens)
+    assert dec and chunk is not None
+    operands = srv._step_operands(dec, chunk)
+    resident = {id(x) for x in jax.tree_util.tree_leaves(
+        [srv._tp_params, srv._tp_scales, srv._pool_k, srv._pool_v,
+         srv._pool_ks, srv._pool_vs, getattr(srv, "_draft_params", None),
+         getattr(srv, "_dpool_k", None), getattr(srv, "_dpool_v", None)])}
+    host = [x for x in jax.tree_util.tree_leaves(operands)
+            if id(x) not in resident]
+    assert [type(x) for x in host] == [np.ndarray, np.ndarray]
+    assert all(x.dtype == np.int32 for x in host)
+    slots, chunk_vec = host
+    assert slots.shape[0] == srv.num_slots and chunk_vec.ndim == 1
+    # fresh buffers every dispatch: the last ones may still be read
+    again = srv._step_operands(dec, chunk)
+    assert not np.shares_memory(again[-2], slots)
+    assert not np.shares_memory(again[-1], chunk_vec)
+
+    reads = []
+    real = np.asarray
+
+    def counting(a, *args, **kw):
+        if isinstance(a, jax.Array):
+            reads.append(a.shape)
+        return real(a, *args, **kw)
+
+    prof = get_overlap_profiler()
+    prof.reset()
+    prof.configure(enabled=True)
+    monkeypatch.setattr(np, "asarray", counting)
+    try:
+        srv.step()
+        last = prof.last()
+    finally:
+        monkeypatch.undo()
+        prof.configure(enabled=False)
+        prof.reset()
+    assert len(reads) == last["dispatches"] == last["host_reads_out"] >= 1
+    assert last["host_arrays_in"] == 2 * last["dispatches"]
+    assert all(shape[0] == srv.num_slots for shape in reads)
+    srv.run()
+
+
+def test_second_dispatch_and_quarantine_through_packed_results(packed):
+    """The chunk's two scalars and the finite flags ride in the one
+    result array: a chunk remainder's second dispatch still lands its
+    first token, and a poisoned slot still fails alone."""
+    from deepspeed_tpu.observability import get_overlap_profiler
+    eng, srv = packed
+    rs = np.random.RandomState(83)
+    # 20 tokens leave a 4-token remainder of the 16-token budget, which
+    # the next prompt's head shares: two dispatches in one iteration
+    prompts = [rs.randint(0, 64, (n,)).tolist() for n in (20, 30, 7)]
+    prof = get_overlap_profiler()
+    prof.reset()
+    prof.configure(enabled=True)
+    try:
+        t0 = time.perf_counter()
+        reqs = [srv.submit(p, max_new_tokens=8) for p in prompts]
+        srv.step()
+        srv.step()
+        its, _ = prof.iterations(t0, time.perf_counter())
+    finally:
+        prof.configure(enabled=False)
+        prof.reset()
+    assert list(its["dispatches"]) == [1, 2]
+    assert list(its["host_arrays_in"]) == [2, 4]
+    assert list(its["host_reads_out"]) == [1, 2]
+    assert len(reqs[0].output) >= 1          # the remainder's first token
+    for _ in range(3):
+        srv.step()
+    victim = reqs[1]
+    assert victim.state is RequestState.RUNNING
+    block = srv.allocator.block_table(victim.req_id)[0]
+    name = "_pool_ks" if srv.kv_bits else "_pool_k"
+    pool = getattr(srv, name)
+    # an int8 pool cannot hold NaN: its scale plane can.  Same placement,
+    # or the program would be traced again for the new sharding
+    setattr(srv, name, jax.device_put(pool.at[:, block].set(jnp.nan),
+                                      pool.sharding))
+    srv.run()
+    assert victim.status is RequestStatus.FAILED
+    assert "quarantined" in victim.error
+    for p, r in zip(prompts, reqs):
+        if r is not victim:
+            assert r.status is RequestStatus.OK
+            np.testing.assert_array_equal(np.asarray(r.output),
+                                          _generate(eng, p, 8))
+    srv.allocator.assert_consistent()
