@@ -23,6 +23,9 @@ weights from a seed:
          ``tpu_custom_call``.
   kernel the paged decode and prefill kernel against the float32
          ``jax.numpy`` reference, on the chip, at the serving shapes.
+  latent (one chip) the latent paged kernel and the experts' grouped
+         product at ``longcat-flash-omni``'s widths against float32
+         ``jax.numpy``.
 
 It fails — non-zero exit, no result line — when JAX shows anything but
 CHIPS TPU devices; it never adapts downward, and nothing on the path is
@@ -330,6 +333,83 @@ def kernel_phase(heads: int, head_dim: int, device: dict,
             "atol": KERNEL_ATOL}
 
 
+def latent_phase(device: dict, block: int = SERVING["kv_block_size"]):
+    """The latent (MLA) paged kernel and the experts' grouped product at
+    the widths of ``longcat-flash-omni.serve-longdoc-sat`` (64 heads over
+    one [512 | 64 | 0] row of 640 lanes a token, 512-row chunks; 16 held
+    experts 6144 x 2048 at about 9 rows each), against float32
+    ``jax.numpy``, on the chip."""
+    import jax
+    import jax.numpy as jnp
+    from deepspeed_tpu.moe import dropless
+    from deepspeed_tpu.ops.transformer.paged_decode_attention import (
+        mla_paged_decode_attention, mla_paged_prefill_attention,
+        mla_paged_reference)
+
+    rng = np.random.default_rng(SEED + 3)
+    f32 = lambda a: a.astype(jnp.float32)               # noqa: E731
+    bf = lambda a: jnp.asarray(a, jnp.bfloat16)         # noqa: E731
+    heads, lat, rope, lanes, chunk = 64, 512, 64, 640, 512
+    scale = 1.0 / np.sqrt(192.0)
+    lens = np.array([1, 640, 1097, 2048, 3333, 4096, 6655, 0], np.int32)
+    pages = 8192 // block
+    nb = 1 + len(lens) * pages
+    tables = np.arange(1, nb, dtype=np.int32).reshape(len(lens), pages)
+    pool = bf(np.pad(rng.standard_normal((nb, block, lat + rope)),
+                     ((0, 0), (0, 0), (0, lanes - lat - rope))))
+    ql = bf(rng.standard_normal((len(lens), heads, lat)) * 0.3)
+    qr = bf(rng.standard_normal((len(lens), heads, rope)) * 0.3)
+    decode = jax.jit(lambda *a: mla_paged_decode_attention(*a, scale))
+    out = decode(ql, qr, pool, lens, tables)
+    ref = mla_paged_reference(f32(ql)[:, None], f32(qr)[:, None], pool,
+                              lens - 1, lens, tables, scale)[:, 0]
+    decode_err = float(jnp.max(jnp.abs(f32(out) - ref)))
+    check(bool(jnp.all(jnp.isfinite(f32(out)))), "latent: decode not finite")
+    check(decode_err < KERNEL_ATOL,
+          f"latent: decode off the f32 reference by {decode_err}")
+
+    # a full chunk deep in a context, then a ragged one that ends mid-page
+    qlc = bf(rng.standard_normal((chunk, heads, lat)) * 0.3)
+    qrc = bf(rng.standard_normal((chunk, heads, rope)) * 0.3)
+    prefill = jax.jit(lambda *a: mla_paged_prefill_attention(*a, scale))
+    prefill_err = 0.0
+    for base, n in ((2560, chunk), (3072, chunk // 2 + 3)):
+        out = prefill(qlc, qrc, pool, base, n, tables[6])
+        ref = mla_paged_reference(f32(qlc)[None], f32(qrc)[None], pool,
+                                  np.array([base]), np.array([base + n]),
+                                  tables[6][None], scale)[0]
+        check(bool(jnp.all(jnp.isfinite(f32(out)[:n]))),
+              "latent: prefill not finite")
+        prefill_err = max(prefill_err, float(jnp.max(jnp.abs(
+            f32(out)[:n] - ref[:n]))))
+    check(prefill_err < KERNEL_ATOL,
+          f"latent: prefill off the f32 reference by {prefill_err}")
+
+    # the grouped product: 16 held experts, ~9 rows each, one pass
+    held, h, f, rows_each = 16, 6144, 2048, 9
+    w = bf(rng.standard_normal((held, h, f)) * 0.02)
+    tiles = 1024 // dropless.TILE_ROWS
+    x = np.zeros((1024, h), np.float32)
+    for e in range(held):
+        x[e * dropless.TILE_ROWS:e * dropless.TILE_ROWS + rows_each] = \
+            rng.standard_normal((rows_each, h))
+    x = bf(x)
+    te = jnp.asarray(np.minimum(np.arange(tiles), held - 1), jnp.int32)
+    live = jnp.asarray(held, jnp.int32)
+    pallas = jax.jit(dropless.grouped_matmul)
+    got = f32(pallas(x, w, te, live))[:held * dropless.TILE_ROWS]
+    want = jnp.einsum(
+        "erk,ekn->ern", f32(x)[:held * dropless.TILE_ROWS].reshape(
+            held, dropless.TILE_ROWS, h), f32(w),
+        precision="highest").reshape(-1, f)
+    gmm_err = float(jnp.max(jnp.abs(got - want)))
+    check(gmm_err < 5e-2, f"latent: grouped product off by {gmm_err}")
+    return {"phase": "latent", **device,
+            "decode_max_abs_err": round(decode_err, 5),
+            "prefill_max_abs_err": round(prefill_err, 5),
+            "grouped_max_abs_err": round(gmm_err, 5), "atol": KERNEL_ATOL}
+
+
 def main(argv) -> int:
     chips = int(argv[1]) if len(argv) > 1 else 1
     device = require_tpu(chips)
@@ -345,6 +425,8 @@ def main(argv) -> int:
           flush=True)
     print(json.dumps(kernel_phase(model_config.num_heads // chips,
                                   model_config.hdim, device)), flush=True)
+    if chips == 1:
+        print(json.dumps(latent_phase(device)), flush=True)
     print(json.dumps({"phase": "total", **device,
                       "wall_s": round(time.perf_counter() - t0, 1),
                       **log.since((0, 0, 0.0, 0.0))}), flush=True)

@@ -78,6 +78,10 @@ def initialize(args: Any = None,
         config = getattr(args, "deepspeed_config", None)
     if model is None:
         raise ValueError("deepspeed_tpu.initialize requires a model")
+    reason = getattr(model, "training_refusal", lambda: None)()
+    if reason is not None:
+        raise NotImplementedError(
+            f"deepspeed_tpu.initialize cannot train this model: {reason}")
     if dist_init_required:
         init_distributed()
     enable_compile_cache()

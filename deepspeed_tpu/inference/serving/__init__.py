@@ -23,7 +23,7 @@ from ...observability.slo import SloAlert, SloMonitor  # noqa: F401
 from ...runtime.resilience.errors import ServingError  # noqa: F401
 from .block_allocator import (BlockPoolError, NULL_BLOCK,  # noqa: F401
                               PagedBlockAllocator, blocks_for_budget,
-                              kv_block_bytes)
+                              kv_block_bytes, latent_block_bytes)
 from .engine import ServingEngine  # noqa: F401
 from .fleet import (FleetAutoscaler, FleetRequest,  # noqa: F401
                     FleetRouter, ReplicaHandle, ReplicaState,
@@ -45,5 +45,6 @@ __all__ = ["BlockCodec", "BlockPoolError", "NULL_BLOCK",
            "ServingError", "ServingFrontend", "SloAlert", "SloMonitor",
            "StreamCollector", "StreamDeduper", "TokenEvent",
            "TenantRegistry", "TenantSpec",
-           "host_block_bytes", "kv_block_bytes", "blocks_for_budget",
+           "host_block_bytes", "kv_block_bytes", "latent_block_bytes",
+           "blocks_for_budget",
            "placement_score", "tiered_blocks_for_budget"]

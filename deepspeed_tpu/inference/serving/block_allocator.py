@@ -94,6 +94,18 @@ def kv_block_bytes(block_size: int, kv_heads: int, head_dim: int,
     return 2 * block_size * per_row          # k + v
 
 
+def latent_block_bytes(block_size: int, latent_width: int,
+                       rope_width: int, cache_itemsize: int = 2) -> int:
+    """Device HBM bytes one pool block costs in ONE attention sublayer of
+    a LATENT pool (``models/shortcut_moe.py init_paged_cache``): a token's
+    row is its latent (``latent_width`` values: key and value of every
+    head) and its rotary key side by side, padded to whole 128-lane
+    tiles.  A model holds ``2 * num_layers`` sublayers.  Pure ints, pinned
+    against the model by test, like :func:`kv_block_bytes`."""
+    lanes = -(-(latent_width + rope_width) // 128) * 128
+    return block_size * lanes * cache_itemsize
+
+
 def blocks_for_budget(budget_bytes: int, block_size: int, kv_heads: int,
                       head_dim: int, kv_bits: int = 0,
                       cache_itemsize: int = 2,

@@ -149,15 +149,21 @@ class _ChunkState(NamedTuple):
 
 
 def _pack_results(nxt, dec_finite, first, chunk_finite, *spec_cols,
-                  samples=None) -> jax.Array:
+                  samples=None, counters=None) -> jax.Array:
     """Everything the host reads after a dispatch as one int32 array,
-    a row a slot (the ``_R_*`` columns)."""
+    a row a slot (the ``_R_*`` columns).  ``counters`` — what the model's
+    program counted this dispatch (``TransformerLM.PAGED_COUNTERS``) —
+    ride as the LAST columns, the same in every row."""
     rows = nxt.shape[0]
     cols = [nxt, dec_finite, jnp.broadcast_to(first, (rows,)),
             jnp.broadcast_to(chunk_finite, (rows,)), *spec_cols]
     out = jnp.stack([c.astype(jnp.int32) for c in cols], axis=1)
     if samples is not None:
         out = jnp.concatenate([out, samples.astype(jnp.int32)], axis=1)
+    if counters is not None:
+        out = jnp.concatenate([out, jnp.broadcast_to(
+            counters.astype(jnp.int32)[None], (rows, counters.shape[0]))],
+            axis=1)
     return out
 
 
@@ -244,6 +250,15 @@ class ServingEngine:
             raise NotImplementedError(
                 f"continuous-batching serving cannot run this model: "
                 f"{reason}")
+        reason = model.paged_refusal(
+            kv_bits=cfg.kv_cache_bits, spec=draft_model is not None,
+            mesh_model=cfg.mesh.model, mesh_data=cfg.mesh.data,
+            host_cache=cfg.host_cache.enabled,
+            weight_quant=getattr(engine, "_quantized", False))
+        if reason is not None:
+            raise NotImplementedError(
+                f"continuous-batching serving cannot be built this way "
+                f"for this model: {reason}")
         self.engine = engine
         self.model = model
         self.block_size = cfg.kv_block_size
@@ -306,8 +321,12 @@ class ServingEngine:
         # kv_heads/model of every block (kv_pool_bytes)
         self._pool_sh = NamedSharding(self.tp_mesh, self._pool_spec)
         self._pscale_sh = NamedSharding(self.tp_mesh, self._pscale_spec)
+        # a pool of ONE buffer (a latent pool: key and value are one
+        # row) has no "v": the operand is None, like the scale planes of
+        # an unquantized pool
         self._pool_k = jax.device_put(pools["k"], self._pool_sh)
-        self._pool_v = jax.device_put(pools["v"], self._pool_sh)
+        self._pool_v = (None if pools["v"] is None else
+                        jax.device_put(pools["v"], self._pool_sh))
         self._pool_ks = self._pool_vs = None
         if self.kv_bits:
             self._pool_ks = jax.device_put(pools["k_scale"],
@@ -699,10 +718,18 @@ class ServingEngine:
         mesh each chip holds ``kv_heads / model`` of every block, so
         this is 1/model of the global pool (data shards replicate the
         pool; they add capacity in SLOTS, not bytes)."""
-        total = self._pool_k.nbytes + self._pool_v.nbytes
+        total = self._pool_k.nbytes
+        if self._pool_v is not None:
+            total += self._pool_v.nbytes
         if self._pool_ks is not None:
             total += self._pool_ks.nbytes + self._pool_vs.nbytes
         return total // self.tp_model_size
+
+    @property
+    def kv_row_width(self) -> int:
+        """Lanes of one token's row in a pool buffer (``kv_heads x
+        head_dim``; a latent pool's padded row)."""
+        return int(self._pool_k.shape[-1])
 
     # ------------------------------------------------------------------
     # tiered host prefix cache (docs/serving.md "Tiered prefix cache")
@@ -1307,7 +1334,8 @@ class ServingEngine:
             # prefix cache
             dec_finite = jnp.all(jnp.isfinite(dec_logits), axis=-1)
             chunk_finite = jnp.all(jnp.isfinite(chunk_logits))
-            return (_pack_results(nxt, dec_finite, first, chunk_finite),
+            return (_pack_results(nxt, dec_finite, first, chunk_finite,
+                                  counters=cache.get("counters")),
                     cache["k"], cache["v"],
                     cache.get("k_scale"), cache.get("v_scale"))
 
@@ -1409,7 +1437,8 @@ class ServingEngine:
         pscale_sp = self._pscale_spec if self.kv_bits else P()
         scale_sp = (self._tp_scale_specs
                     if self._tp_scales is not None else P())
-        pools_sp = (pool_sp, pool_sp, pscale_sp, pscale_sp)
+        pools_sp = (pool_sp, pool_sp if self._pool_v is not None else P(),
+                    pscale_sp, pscale_sp)
         host_in = (P(d, None), P())
         if spec_on:
             in_specs = ((self._tp_param_specs, scale_sp, P()) + pools_sp
@@ -1534,9 +1563,10 @@ class ServingEngine:
         if ovl_on:
             ovl.mark(overlap.ENQUEUE)
         t0 = time.perf_counter()
+        counted = self.model.PAGED_COUNTERS
         with trace_span("serving/dispatch", decode=len(dec),
                         chunk_tokens=c_len, spec=len(spec),
-                        tp=self.tp_mesh.size):
+                        tp=self.tp_mesh.size, moe=int(bool(counted))):
             outs = self._step_fn(*operands)
             if ovl_on:
                 # dispatch returned, nothing materialized yet: from here
@@ -1565,7 +1595,10 @@ class ServingEngine:
                 self._rows_per_dispatch,
                 host_arrays_in=sum(isinstance(a, np.ndarray)
                                    for a in operands),
-                host_reads_out=len(results))
+                host_reads_out=len(results),
+                # what the program counted: the row's last columns
+                **(dict(zip(counted, map(int, res[0, -len(counted):])))
+                   if counted else {}))
         nxt, dec_fin = res[:, _R_NEXT], res[:, _R_DEC_FINITE]
         first, chunk_fin = res[0, _R_FIRST], res[0, _R_CHUNK_FINITE]
         if spec_on:

@@ -1,2 +1,3 @@
 from .transformer import (TransformerConfig, TransformerLM,  # noqa: F401
-                          gpt2_config, neox_config)
+                          build_model, gpt2_config, longcat_flash_config,
+                          neox_config)

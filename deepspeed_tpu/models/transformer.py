@@ -200,6 +200,14 @@ class TransformerConfig:
     moe_aux_loss_coef: float = 0.01
     moe_d_ff: int = 0                  # 0 → ff_dim
 
+    @classmethod
+    def model_class(cls):
+        """The class that runs this configuration's block;
+        :func:`build_model` builds it.  A block that is not ``x + attn +
+        mlp`` brings its own config subclass and model subclass
+        (``models/shortcut_moe.py``) instead of more flags here."""
+        return TransformerLM
+
     @property
     def ff_dim(self) -> int:
         return self.d_ff or 4 * self.d_model
@@ -295,6 +303,38 @@ def neox_config(size: str = "1.3b", **kw) -> TransformerConfig:
                                 **NEOX_SIZES[size], **kw})
 
 
+LONGCAT_FLASH_SIZES = {
+    # https://huggingface.co/meituan-longcat/LongCat-Flash-Omni config.json
+    "omni": dict(num_layers=28, num_heads=64, d_model=6144, d_ff=12288,
+                 head_dim=192, vocab_size=131072, max_seq_len=131072,
+                 q_lora_rank=1536, kv_lora_rank=512, qk_nope_head_dim=128,
+                 qk_rope_head_dim=64, v_head_dim=128, rope_theta=1e7,
+                 expert_d_ff=2048, n_routed_experts=512,
+                 zero_expert_num=256, moe_topk=12,
+                 routed_scaling_factor=6.0),
+}
+
+
+def longcat_flash_config(size: str = "omni", **kw) -> TransformerConfig:
+    """LongCat-Flash's language model: the shortcut-connected block of
+    two latent-attention sublayers, two dense FFNs and one MoE
+    (``models/shortcut_moe.py``).  ``size`` names a published set of
+    widths; depth, vocabulary, served positions and ``experts_held`` (the
+    chip's share of a deployment) come as keywords."""
+    from .shortcut_moe import ShortcutMoEConfig
+    return ShortcutMoEConfig(**{
+        "pos_embedding": "none", "norm_type": "rmsnorm", "gated_mlp": True,
+        "activation": "silu", "use_bias": False, "tie_embeddings": False,
+        "layernorm_eps": 1e-5, **LONGCAT_FLASH_SIZES[size], **kw})
+
+
+def build_model(config: TransformerConfig, **kw) -> "TransformerLM":
+    """The model that runs ``config``'s block: ``TransformerLM`` for the
+    standard block, the config's own class (``config.model_class()``) for
+    a block that brings one."""
+    return config.model_class()(config, **kw)
+
+
 class TransformerLM:
     """Pure-functional LM: ``init`` → params pytree, ``apply`` → logits.
 
@@ -303,9 +343,18 @@ class TransformerLM:
     the model stays mesh-agnostic.
     """
 
+    #: names of the int32 counters ``_apply_paged_mixed`` returns under
+    #: ``new_cache["counters"]`` (none for the standard block)
+    PAGED_COUNTERS: Tuple[str, ...] = ()
+
     def __init__(self, config: TransformerConfig,
                  constrain: Optional[Callable] = None,
                  block_transform: Optional[Callable] = None):
+        if not isinstance(self, config.model_class()):
+            raise TypeError(
+                f"{type(config).__name__} is run by "
+                f"{config.model_class().__name__}, not {type(self).__name__}"
+                f": build it with models.build_model(config)")
         self.config = config
         self.constrain = constrain or (lambda x: x)
         # per-layer param hook applied INSIDE the scan body to each
@@ -1258,7 +1307,10 @@ class TransformerLM:
         if not c.causal:
             return "paged decode needs a causal (decoder) model"
         if c.moe_enabled:
-            return "paged decode does not cover MoE block stacks yet"
+            return ("paged decode does not cover the capacity-gated "
+                    "top-1 / top-2 MoE superblock (moe_num_experts > 0); "
+                    "the MoE it serves is the dropless top-k block of "
+                    "models/shortcut_moe.py (longcat_flash_config)")
         if c.attention_layers:
             return ("paged decode does not apply per-layer local windows "
                     "(GPT-Neo family)")
@@ -1267,6 +1319,19 @@ class TransformerLM:
         from ..ops.transformer.paged_decode_attention import supports
         if not supports(c.hdim):
             return f"head_dim {c.hdim} is not lane-aligned (multiple of 8)"
+        return None
+
+    def training_refusal(self) -> Optional[str]:
+        """Why ``ds.initialize`` cannot train this block, or None."""
+        return None
+
+    def paged_refusal(self, kv_bits: int = 0, spec: bool = False,
+                      mesh_model: int = 1, mesh_data: int = 1,
+                      host_cache: bool = False,
+                      weight_quant: bool = False) -> Optional[str]:
+        """Why the serving engine cannot be built with these options
+        around this block's pool, or None (the standard block takes them
+        all)."""
         return None
 
     @staticmethod

@@ -197,4 +197,7 @@ def gate(logits: jnp.ndarray, k: int, capacity_factor: float = 1.0,
                            use_rts=use_rts, rng=rng)
     if k == 2:
         return top2_gating(logits, capacity_factor, min_capacity, rng=rng)
-    raise ValueError(f"Only top-1 and top-2 gating supported, got k={k}")
+    raise ValueError(
+        f"the capacity-gated path takes top-1 and top-2 only, got k={k}; "
+        f"top-k without capacity or drops is the dropless router, "
+        f"moe/dropless.py (route, expert_share)")

@@ -71,7 +71,14 @@ PHASE_SPANS = tuple(f"serving/{p}" for p in PHASES)
 
 #: what an iteration's dispatches did (``count_dispatch``)
 COUNTERS = ("dispatches", "decode_rows", "chunk_rows", "rows_computed",
-            "host_arrays_in", "host_reads_out")
+            "host_arrays_in", "host_reads_out",
+            # counted IN the mixed program by a block that routes experts
+            # and reads a latent pool (models/shortcut_moe.py), carried
+            # out on the dispatch's one result array; 0 for other blocks
+            "moe_picks", "moe_picks_held", "moe_picks_zero",
+            "moe_rows_max_expert", "moe_experts_touched",
+            "latent_tokens_read")
+_COUNTER_AT = {name: k for k, name in enumerate(COUNTERS)}
 
 #: one iteration (or training step).  Times are seconds on
 #: ``time.perf_counter()``'s clock; ``<phase>_s`` is the phase's summed
@@ -261,14 +268,19 @@ class OverlapProfiler:
 
     def count_dispatch(self, decode_rows: int, chunk_rows: int,
                        rows_computed: int, host_arrays_in: int = 0,
-                       host_reads_out: int = 0) -> None:
+                       host_reads_out: int = 0, **program_counts: int
+                       ) -> None:
         """One dispatch of the mixed program: the rows that carried a
         token (decoding slots, prompt-chunk tokens), the rows the
         program ran whatever rode, the host arrays passed to it and the
-        device arrays read back from it."""
+        device arrays read back from it; ``program_counts`` — what the
+        program counted itself (``moe_picks`` .. ``latent_tokens_read``
+        of ``COUNTERS``), by name."""
         for k, add in enumerate((1, decode_rows, chunk_rows, rows_computed,
                                  host_arrays_in, host_reads_out)):
             self._counts[k] += add
+        for name, add in program_counts.items():
+            self._counts[_COUNTER_AT[name]] += add
 
     def end(self, kind: str = "serving") -> None:
         if not self._open:

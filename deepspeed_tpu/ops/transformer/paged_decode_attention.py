@@ -529,6 +529,252 @@ def paged_prefill_attention(q: jnp.ndarray, pool_k: jnp.ndarray,
     return out[0].astype(q.dtype)
 
 
+# ---------------------------------------------------------------------------
+# the latent case (multi-head latent attention, absorbed form)
+# ---------------------------------------------------------------------------
+#: query rows one latent grid step contracts: whole positions of every
+#: head (a decode slot is one position; a chunk walks tiles of this many
+#: rows, each tile only as far as its own last position sees)
+_MLA_TILE_ROWS = 1024
+
+
+def latent_pool_lanes(latent: int, rope: int) -> int:
+    """Lanes of one latent pool row ``[c | k_rope | 0 ..]``: the latent and
+    the rotary key side by side, padded to whole 128-lane tiles (a page is
+    ONE DMA: at 16 tokens a page the walk is bound by descriptors, not
+    bytes, so a second operand would cost as much again)."""
+    return -(-(latent + rope) // LANES) * LANES
+
+
+def _mla_kernel(meta_ref, bt_ref, coff_ref, q_ref, pool_hbm, o_ref, buf,
+                m_scr, l_scr, acc_scr, sem, *, sm_scale, block, pp, lat):
+    """The page walk of :func:`_kernel` for one WALKER — a tile of query
+    rows of one slot — over a latent pool: a page is ``[block, W]`` rows
+    ``[c | k_rope | 0]``, key AND value of every head; all heads' rows of
+    the tile are plain rows of one contraction, ``score = [q_lat | q_rope]
+    . row``, ``o_lat = sum p c`` over the row's first ``lat`` lanes: one
+    page fetch serves both.
+
+    ``meta_ref [6, W]``: (base, total, live groups, live steps before,
+    next live walker or W, the slot whose table this walker reads)."""
+    i, g = pl.program_id(0), pl.program_id(1)
+    nwalk, ng = pl.num_programs(0), pl.num_programs(1)
+    keys = pp * block
+    base, total, live_groups = meta_ref[0, i], meta_ref[1, i], meta_ref[2, i]
+
+    def fetch(w, group, half, start):
+        _page_group_dma(start, (pool_hbm,), (buf,), sem, bt_ref,
+                        meta_ref[5, w], meta_ref[1, w], group, half,
+                        block=block, pp=pp)
+
+    @pl.when(g < live_groups)
+    def _live():
+        step = meta_ref[3, i] + g
+        half = jax.lax.rem(step, 2)
+
+        @pl.when(step == 0)
+        def _cold_start():
+            fetch(i, g, half, start=True)
+
+        more = g + 1 < live_groups
+        w1 = jnp.where(more, i, meta_ref[4, i])
+        g1 = jnp.where(more, g + 1, 0)
+
+        @pl.when(w1 < nwalk)
+        def _prefetch_next():
+            fetch(w1, g1, 1 - half, start=True)
+
+        fetch(i, g, half, start=False)
+
+        @pl.when(g == 0)
+        def _init():
+            m_scr[...] = jnp.full_like(m_scr, -jnp.inf)
+            l_scr[...] = jnp.zeros_like(l_scr)
+            acc_scr[...] = jnp.zeros_like(acc_scr)
+
+        qpos = base + coff_ref[...]                            # [R, 1]
+        pos = g * keys + jax.lax.broadcasted_iota(jnp.int32, (1, keys), 1)
+        visible = (pos <= qpos) & (pos < total)                # [R, keys]
+        v_valid = g * keys + jax.lax.broadcasted_iota(
+            jnp.int32, (keys, 1), 0) < total                   # [keys, 1]
+        rows = buf[half].reshape(keys, buf.shape[-1])
+        s = jax.lax.dot_general(q_ref[...], rows, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+        s = jnp.where(visible, s * sm_scale, MASK_VALUE)
+        m_prev = m_scr[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new)
+        l_scr[...] = alpha * l_scr[...] + jnp.sum(p, axis=1, keepdims=True)
+        m_scr[...] = m_new
+        # stale or recycled rows past the length: zeroed, not down-weighted
+        c = rows[:, :lat]
+        c = jnp.where(v_valid, c, jnp.zeros_like(c))
+        acc_scr[...] = alpha * acc_scr[...] + jax.lax.dot_general(
+            p.astype(c.dtype), c, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+    @pl.when(g == ng - 1)
+    def _out():
+        inv = 1.0 / jnp.maximum(l_scr[...], 1e-30)
+        o_ref[...] = jnp.where(live_groups > 0, inv * acc_scr[...],
+                               0.0).astype(o_ref.dtype)
+
+
+def _mla_pages_per_program(block: int, lanes: int, lat: int, itemsize: int,
+                           rows: int, npages: int,
+                           override: Optional[int]) -> int:
+    """Pages to a grid step, from the same budget as the k/v kernel: a
+    page in both buffer halves, and per key the f32 score and probability
+    rows plus the zeroed copy of the latent part."""
+    if override is not None:
+        if override < 1:
+            raise ValueError(
+                f"pages_per_program must be >= 1, got {override}")
+        return min(override, npages)
+    per_key = (2 * lanes + lat) * itemsize + 2 * 4 * rows
+    pp = max(1, _VMEM_GROUP_BYTES // (block * per_key))
+    return min(1 << (pp.bit_length() - 1), npages)
+
+
+def _mla_paged_attention(q_lat, q_rope, pool, base, total, block_tables, *,
+                         sm_scale, interpret, pages_per_program, what):
+    """q_lat [B, C, H, R], q_rope [B, C, H, Dr] — C query positions a
+    slot at absolute positions ``base[b] ..``; ``pool [num_blocks, block,
+    W]`` with ``W >= R + Dr`` lanes a row, ``[c | k_rope | 0]``.  Returns
+    o_lat [B, C, H, R]."""
+    b, c, h, lat = q_lat.shape
+    rope = q_rope.shape[-1]
+    if pool.ndim != 3 or pool.shape[2] < lat + rope:
+        raise ValueError(
+            f"{what}: the latent pool must be [num_blocks, block, >= "
+            f"{lat} + {rope}], got {pool.shape}")
+    block, lanes = pool.shape[1:]
+    interpret = resolve_interpret(interpret)
+    if not interpret and (lat % LANES or lanes % LANES):
+        raise ValueError(
+            f"{what}: compiled for the TPU, the latent part ({lat}) and "
+            f"the whole pool row ({lanes}) must be whole {LANES}-lane "
+            f"tiles")
+    # walkers: tiles of whole positions, rows position-major
+    tp = max(1, min(c, _MLA_TILE_ROWS // h))
+    while c % tp:
+        tp -= 1
+    ntile, rows = c // tp, tp * h
+    nwalk = b * ntile
+    npages = block_tables.shape[1]
+    pp = _mla_pages_per_program(block, lanes, lat, pool.dtype.itemsize, rows,
+                                npages, pages_per_program)
+    ngroups = -(-npages // pp)
+    base = jnp.asarray(base, jnp.int32).reshape(b)
+    total = jnp.asarray(total, jnp.int32).reshape(b)
+    first = (base[:, None]
+             + tp * jnp.arange(ntile, dtype=jnp.int32)[None, :]).reshape(-1)
+    upto = jnp.repeat(total, ntile)
+    upto = jnp.where(first < upto, jnp.minimum(upto, first + tp), 0)
+    live = jnp.clip(-(-upto // (pp * block)), 0, ngroups)
+    walker = jnp.where(live > 0, jnp.arange(nwalk, dtype=jnp.int32), nwalk)
+    later = jnp.append(jax.lax.cummin(walker, reverse=True)[1:], nwalk)
+    slot = jnp.repeat(jnp.arange(b, dtype=jnp.int32), ntile)
+    meta = jnp.stack([first, upto, live, jnp.cumsum(live) - live, later,
+                      slot])
+    dtype = pool.dtype
+    # the query laid out like a pool row: [q_lat | q_rope | 0]
+    q = jnp.concatenate(
+        [q_lat.astype(dtype), q_rope.astype(dtype),
+         jnp.zeros((b, c, h, lanes - lat - rope), dtype)], axis=-1
+    ).reshape(nwalk, rows, lanes)
+    coff = (jnp.arange(rows, dtype=jnp.int32) // h).reshape(rows, 1)
+
+    def qspec(width):
+        return pl.BlockSpec((None, rows, width), lambda i, g, *_: (i, 0, 0))
+
+    out = pl.pallas_call(
+        functools.partial(_mla_kernel, sm_scale=sm_scale, block=block,
+                          pp=pp, lat=lat),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(nwalk, ngroups),
+            in_specs=[pl.BlockSpec((rows, 1), lambda i, g, *_: (0, 0)),
+                      qspec(lanes), pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=qspec(lat),
+            scratch_shapes=[
+                pltpu.VMEM((2, pp, block, lanes), dtype),
+                pltpu.VMEM((rows, 1), jnp.float32),
+                pltpu.VMEM((rows, 1), jnp.float32),
+                pltpu.VMEM((rows, lat), jnp.float32),
+                pltpu.SemaphoreType.DMA((2, 1))],
+        ),
+        out_shape=jax.ShapeDtypeStruct((nwalk, rows, lat), dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT_BYTES),
+        interpret=interpret,
+        name="mla_paged_attention",
+    )(meta, jnp.asarray(block_tables, jnp.int32), coff, q, pool)
+    return out.reshape(b, c, h, lat)
+
+
+def mla_paged_decode_attention(q_lat, q_rope, pool, lengths, block_tables,
+                               sm_scale: float,
+                               interpret: Optional[bool] = None,
+                               pages_per_program: Optional[int] = None):
+    """Latent decode: q_lat [B, H, R] (the no-rope query already through
+    ``W_UK``), q_rope [B, H, Dr]; one token per slot at position
+    ``lengths - 1``; 0 = inactive (zero rows back).  Returns the latent
+    output [B, H, R] — the caller applies ``W_UV``."""
+    lengths = jnp.asarray(lengths, jnp.int32).reshape(q_lat.shape[0])
+    return _mla_paged_attention(
+        q_lat[:, None], q_rope[:, None], pool, lengths - 1, lengths,
+        block_tables, sm_scale=sm_scale, interpret=interpret,
+        pages_per_program=pages_per_program,
+        what="mla_paged_decode_attention")[:, 0].astype(q_lat.dtype)
+
+
+def mla_paged_prefill_attention(q_lat, q_rope, pool, base, chunk_len,
+                                block_table, sm_scale: float,
+                                interpret: Optional[bool] = None,
+                                pages_per_program: Optional[int] = None):
+    """Latent causal chunk of ONE slot: q_lat [C, H, R], q_rope
+    [C, H, Dr] at positions ``base ..``; the chunk's own rows are already
+    in the pool.  Rows at or past ``chunk_len`` come back as finite
+    garbage or zeros; callers ignore them."""
+    base = jnp.asarray(base, jnp.int32)
+    return _mla_paged_attention(
+        q_lat[None], q_rope[None], pool, base,
+        base + jnp.asarray(chunk_len, jnp.int32), block_table[None],
+        sm_scale=sm_scale, interpret=interpret,
+        pages_per_program=pages_per_program,
+        what="mla_paged_prefill_attention")[0].astype(q_lat.dtype)
+
+
+def mla_paged_reference(q_lat, q_rope, pool, base, total, block_tables,
+                        sm_scale: float):
+    """float32 jnp reference of the latent kernel: q_lat [B, C, H, R],
+    q_rope [B, C, H, Dr]; gathers each slot's pages and runs masked dense
+    attention in the absorbed form.  Dead slots give zero rows."""
+    b, c, h, lat = q_lat.shape
+    dr = q_rope.shape[-1]
+    block, npages = pool.shape[1], block_tables.shape[1]
+
+    def one(ql, qr, table, bs, tot):
+        rows = pool[table].reshape(npages * block, -1).astype(jnp.float32)
+        cc, rr = rows[:, :lat], rows[:, lat:lat + dr]
+        s = (jnp.einsum("chr,sr->chs", ql.astype(jnp.float32), cc)
+             + jnp.einsum("chd,sd->chs", qr.astype(jnp.float32), rr)
+             ) * sm_scale
+        pos = jnp.arange(npages * block)
+        qpos = bs + jnp.arange(c)[:, None, None]
+        s = jnp.where((pos <= qpos) & (pos < tot), s, -1e30)
+        cc = jnp.where((pos < tot)[:, None], cc, 0.0)
+        o = jnp.einsum("chs,sr->chr", jax.nn.softmax(s, axis=-1), cc)
+        return jnp.where(tot > 0, o, 0.0)
+
+    return jax.vmap(one)(q_lat, q_rope, block_tables,
+                         jnp.asarray(base, jnp.int32),
+                         jnp.asarray(total, jnp.int32))
+
+
 def _reference_cache(pool_k, pool_v, k_scale, v_scale, kv_bits, d):
     """The pools as plain ``[num_blocks, block, Hkv, D]`` arrays for the
     jnp references — ``kv_dequantize`` is the math the kernel fuses."""

@@ -541,7 +541,7 @@ class DeepSpeedEngine:
                 # ONE flat vector, split back to leaves on device, in place
                 # of ~n_leaves per-leaf H2D uploads; multi-chip keeps the
                 # pipelined per-bucket path.  Whether the split still pays
-                # on the chip's host link is ROADMAP A6/C6 — unmeasured.
+                # on the chip's host link is ROADMAP C6 — unmeasured.
                 n_leaves = len(self._host_opt.opt.master)
                 if wcb:
                     self.timers("offload/sweep").start()

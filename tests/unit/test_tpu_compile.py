@@ -346,6 +346,98 @@ def test_mixed_step_keeps_the_pool_in_place(v5e_devices, compiled_kernels,
     assert compiled.memory_analysis().temp_size_in_bytes < slice_bytes
 
 
+def test_latent_kernel_and_grouped_product_compile_at_the_cells_shapes(
+        v5e_devices):
+    """``longcat-flash-omni.serve-longdoc-sat``: 48 decode slots and one
+    512-row chunk of 64 heads over one ``[512 | 64 | 0]`` row of 640
+    lanes a token, 512 pages of 16; 16 held experts of 6144 x 2048 in
+    passes of 1,024 rows."""
+    from deepspeed_tpu.moe import dropless
+    from deepspeed_tpu.ops.transformer.paged_decode_attention import (
+        mla_paged_decode_attention, mla_paged_prefill_attention)
+    sds = one_chip(v5e_devices)
+    bf, i32 = jnp.bfloat16, jnp.int32
+    pool = sds((4096, 16, 640), bf)
+
+    def decode(ql, qr, pool, lens, tables):
+        return mla_paged_decode_attention(ql, qr, pool, lens, tables, 0.072,
+                                          interpret=False)
+
+    def chunk(ql, qr, pool, base, n, table):
+        return mla_paged_prefill_attention(ql, qr, pool, base, n, table,
+                                           0.072, interpret=False)
+    assert "tpu_custom_call" in compile_for_tpu(
+        decode, sds((48, 64, 512), bf), sds((48, 64, 64), bf), pool,
+        sds((48,), i32), sds((48, 512), i32))
+    assert "tpu_custom_call" in compile_for_tpu(
+        chunk, sds((512, 64, 512), bf), sds((512, 64, 64), bf), pool,
+        sds((), i32), sds((), i32), sds((512,), i32))
+    for k_dim, n in ((6144, 2048), (2048, 6144)):
+        text = compile_for_tpu(
+            lambda x, w, te, live: dropless.grouped_matmul(
+                x, w, te, live, interpret=False),
+            sds((1024, k_dim), bf), sds((64, k_dim, n), bf),
+            sds((64,), i32), sds((), i32))
+        assert "tpu_custom_call" in text
+
+
+def test_latent_mixed_step_keeps_pool_and_experts_in_place(
+        v5e_devices, compiled_kernels):
+    """The shortcut block's mixed step at the cell's widths (2 layers,
+    4 held experts): the latent pool is the scan's carry and the expert
+    stack is read where it lies, so the compiled step holds no pool-shaped
+    and no expert-stack-shaped copy, slice or second buffer — either would
+    be more than a GB moved every step at the cell's depth."""
+    import re
+    from deepspeed_tpu.models import build_model, longcat_flash_config
+    layers, held, nb, slots, chunk = 2, 4, 4096, 48, 512
+    sds = one_chip(v5e_devices)
+    model = build_model(longcat_flash_config(
+        "omni", num_layers=layers, vocab_size=1024, max_seq_len=8192,
+        experts_held=(0, held)))
+
+    def abstract(tree, dtype=None):
+        return jax.tree_util.tree_map(
+            lambda a: sds(a.shape, dtype or a.dtype), tree)
+    params = abstract(jax.eval_shape(model.init, jax.random.PRNGKey(0)),
+                      jnp.bfloat16)
+    cache = abstract(jax.eval_shape(
+        lambda: model.init_paged_cache(nb, 16, jnp.bfloat16)))
+    pools = {"k": cache["k"]}              # one buffer: "v" is None
+    cache["block_tables"] = sds((slots, 512), jnp.int32)
+    cache["lens"] = sds((slots,), jnp.int32)
+    scalar = sds((), jnp.int32)
+    compiled = jax.jit(model._apply_paged_mixed, donate_argnums=1).trace(
+        params, cache, sds((slots,), jnp.int32), sds((slots,), jnp.int32),
+        sds((chunk,), jnp.int32), scalar, scalar, scalar).lower(
+            lowering_platforms=("tpu",)).compile()
+    text = compiled.as_text()
+    # two attention sublayers x (decode + chunk) and three grouped products
+    assert text.count("tpu_custom_call") >= 7
+    shaped = set()
+    for a in pools.values():
+        for lead in ((2 * layers, nb), (nb,), (2 * layers * nb,)):
+            shaped.add(lead + a.shape[2:])
+    for a in params["blocks"]["moe"]["experts"].values():
+        shaped.update({a.shape, a.shape[1:], (1,) + a.shape[1:],
+                       (layers * held,) + a.shape[2:]})
+    moved = []
+    for ln in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%(\S+) = \w+\[([\d,]+)\]\S* ([\w-]+)\(",
+                     ln)
+        if m is None:
+            continue
+        name, dims, op = m.groups()
+        if tuple(int(n) for n in dims.split(",")) in shaped and (
+                op in ("copy", "dynamic-slice", "dynamic-update-slice")
+                or "AllocateBuffer" in ln
+                or (op == "fusion" and "dynamic" in name)):
+            moved.append(ln.strip()[:160])
+    assert not moved, moved
+    one_expert_stack = held * 6144 * 2048 * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < one_expert_stack
+
+
 def test_train_grad_compiles_on_four_chips(v5e_devices, compiled_kernels):
     """GPT-2 350M ``value_and_grad(model.loss)`` with the batch sharded
     over a data=4 mesh: the flash kernel must sit inside a shard_map or
