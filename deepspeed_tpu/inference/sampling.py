@@ -22,6 +22,33 @@ keep all) mask nothing and leave the logits bytes untouched, and both
 paths feed the identical filtered array to the identical categorical
 draw.
 
+What runs when (the per-row path).  Everything is data, but not
+everything is always computed: each stage runs only when some row of
+THE CALL needs it, decided inside the program by a ``lax.cond`` on a
+scalar of the rows' own state — no second program, no host choice.
+
+  ==========================================  =========================
+  the call's rows                             what runs
+  ==========================================  =========================
+  none samples (every ``temperature`` 0)      ``argmax`` — no division,
+                                              no sort, no softmax, no
+                                              random bits
+  some sample, none of those sets a filter    + the division and one
+                                              categorical draw a row
+  some sampling row sets ``top_k > 0``        + the top-k stage: one
+                                              full sort
+  some sampling row sets ``top_p < 1``        + the top-p stage: one
+                                              full sort, softmax, cumsum
+  ==========================================  =========================
+
+A stage that runs is the unconditional arithmetic over the whole array
+and a stage that is skipped would have masked nothing in any row whose
+draw is kept, so the tokens are the same bytes whichever side a call
+takes (``test_serving.py`` holds them to the every-stage-always body).
+The serving engine counts the same predicates on the host, from the
+operands it fills: ``sampled_rows`` / ``filtered_rows`` of the overlap
+record (docs/observability.md).
+
 Key schedule (`fold_in`, not a split chain): the token at OUTPUT index
 ``j`` of a request is always sampled with ``fold_in(request_key, j)``.
 The key depends only on (request key, position) — never on batch
@@ -80,37 +107,62 @@ def sample_tokens_per_row(logits, keys, temperature, top_k, top_p):
     logits (bit-exact vs the static path).
 
     Everything is data, nothing is shape: one trace covers every
-    per-slot sampling mix (the ``decode_builds == 1`` contract).  The
-    top-k threshold comes from a sort + rank compare instead of
+    per-slot sampling mix (the ``decode_builds == 1`` contract).  What
+    no row asks for is not computed: the draw and each filter sit
+    behind a ``lax.cond`` on a scalar of THIS call's rows (module
+    docstring, "What runs when").  Call it plainly: under ``vmap`` a
+    ``cond`` becomes a ``select`` and every call sorts again
+    (``test_tpu_compile.py`` holds the compiled step to it).
+
+    The top-k threshold comes from a sort + rank compare instead of
     ``lax.top_k`` (whose k must be static); the selected threshold
     VALUE is identical, so the masked array matches the static path
     byte-for-byte."""
     v = logits.shape[-1]
     logits = logits.astype(jnp.float32)
-    greedy = jnp.argmax(logits, axis=-1)
     t = jnp.asarray(temperature, jnp.float32)
-    scaled = logits / jnp.maximum(t, 1e-8)[..., None]
-    # -- top-k: k-th largest value as the keep threshold (k = V keeps
-    # everything and leaves the bytes untouched) --
     k = jnp.asarray(top_k, jnp.int32)
-    k_eff = jnp.where(k > 0, jnp.clip(k, 1, v), v)
-    sorted_desc = jnp.sort(scaled, axis=-1)[..., ::-1]
-    kth = jnp.take_along_axis(sorted_desc, (k_eff - 1)[..., None], axis=-1)
-    filt = jnp.where(scaled < kth, -jnp.inf, scaled)
-    # -- top-p (nucleus) over the top-k-filtered logits, matching the
-    # static path's filter order; p >= 1 pins the cutoff to the minimum
-    # so nothing masks (cumsum rounding must not shave the tail) --
     p = jnp.asarray(top_p, jnp.float32)
-    s2 = jnp.sort(filt, axis=-1)[..., ::-1]
-    probs = jax.nn.softmax(s2, axis=-1)
-    cum = jnp.cumsum(probs, axis=-1)
-    cutoff_idx = jnp.sum((cum < p[..., None]).astype(jnp.int32), axis=-1)
-    cutoff_idx = jnp.where(p >= 1.0, v - 1, cutoff_idx)
-    cutoff = jnp.take_along_axis(s2, cutoff_idx[..., None], axis=-1)
-    filt = jnp.where(filt < cutoff, -jnp.inf, filt)
+    samples = t > 0.0
 
-    def draw(kk, row):
-        return jax.random.categorical(kk, row)
-    sampled = jax.vmap(draw)(keys.reshape(-1, 2),
-                             filt.reshape(-1, v)).reshape(greedy.shape)
-    return jnp.where(t <= 0.0, greedy, sampled).astype(jnp.int32)
+    def greedy():
+        return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+    def top_k_filter(scaled):
+        # k-th largest value as the keep threshold (k = V keeps
+        # everything and leaves the bytes untouched)
+        k_eff = jnp.where(k > 0, jnp.clip(k, 1, v), v)
+        sorted_desc = jnp.sort(scaled, axis=-1)[..., ::-1]
+        kth = jnp.take_along_axis(sorted_desc, (k_eff - 1)[..., None],
+                                  axis=-1)
+        return jnp.where(scaled < kth, -jnp.inf, scaled)
+
+    def top_p_filter(filt):
+        # nucleus over the top-k-filtered logits, matching the static
+        # path's filter order; p >= 1 pins the cutoff to the minimum so
+        # nothing masks (cumsum rounding must not shave the tail)
+        s2 = jnp.sort(filt, axis=-1)[..., ::-1]
+        probs = jax.nn.softmax(s2, axis=-1)
+        cum = jnp.cumsum(probs, axis=-1)
+        cutoff_idx = jnp.sum((cum < p[..., None]).astype(jnp.int32),
+                             axis=-1)
+        cutoff_idx = jnp.where(p >= 1.0, v - 1, cutoff_idx)
+        cutoff = jnp.take_along_axis(s2, cutoff_idx[..., None], axis=-1)
+        return jnp.where(filt < cutoff, -jnp.inf, filt)
+
+    def sampled():
+        filt = logits / jnp.maximum(t, 1e-8)[..., None]
+        # a filter that no SAMPLING row set masks nothing in the rows
+        # whose draw is kept: skipping its sort hands those draws the
+        # identical bytes
+        for asked, stage in ((k > 0, top_k_filter), (p < 1.0, top_p_filter)):
+            filt = jax.lax.cond(jnp.any(samples & asked), stage,
+                                lambda x: x, filt)
+
+        def draw(kk, row):
+            return jax.random.categorical(kk, row)
+        drawn = jax.vmap(draw)(keys.reshape(-1, 2),
+                               filt.reshape(-1, v)).reshape(t.shape)
+        return jnp.where(t <= 0.0, greedy(), drawn).astype(jnp.int32)
+
+    return jax.lax.cond(jnp.any(samples), sampled, greedy)

@@ -1526,6 +1526,26 @@ class ServingEngine:
         return (self._tp_params, self._tp_scales) + pools + (slots,
                                                              chunk_vec)
 
+    def _sampler_rows(self, slots: np.ndarray,
+                      chunk_vec: np.ndarray) -> Dict[str, int]:
+        """What the rows of one dispatch ask the in-program sampler for,
+        from the operands its own predicates read
+        (``sample_tokens_per_row``): rows at temperature > 0, and those
+        of them with a top-k or a top-p set.  A speculating slot is
+        ``spec_k + 1`` rows, a riding chunk one."""
+        slots_f, chunk_f = slots.view(np.float32), chunk_vec.view(np.float32)
+        samples = slots_f[:, _TEMP] > 0
+        chunk_samples = bool(chunk_f[_C_TEMP] > 0)
+        if not (chunk_samples or samples.any()):
+            return {}           # every row greedy: both counters add 0
+        filters = samples & ((slots[:, _TOP_K] > 0)
+                             | (slots_f[:, _TOP_P] < 1))
+        chunk_filters = chunk_samples and bool(
+            chunk_vec[_C_TOP_K] > 0 or chunk_f[_C_TOP_P] < 1)
+        rows = 1 + self.spec_k * slots[:, _SPEC_ACTIVE]
+        return {"sampled_rows": int(rows[samples].sum()) + chunk_samples,
+                "filtered_rows": int(rows[filters].sum()) + chunk_filters}
+
     def _dispatch(self, dec: List[Tuple[int, Request]],
                   chunk: Optional[Tuple[int, Request, int, int]],
                   spec: List[Tuple[int, Request]] = ()
@@ -1596,6 +1616,7 @@ class ServingEngine:
                 host_arrays_in=sum(isinstance(a, np.ndarray)
                                    for a in operands),
                 host_reads_out=len(results),
+                **self._sampler_rows(*operands[-2:]),
                 # what the program counted: the row's last columns
                 **(dict(zip(counted, map(int, res[0, -len(counted):])))
                    if counted else {}))

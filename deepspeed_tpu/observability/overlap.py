@@ -27,7 +27,10 @@ token), ``rows_computed`` (rows the program ran whatever rode), and what
 crossed between host and device: ``host_arrays_in`` (host arrays passed
 to the program: 2 a dispatch) and ``host_reads_out`` (device arrays
 materialised on the host: 1 a dispatch), counted by ``_dispatch`` from
-what it passed and read.
+what it passed and read; and what the sampler was asked for:
+``sampled_rows`` (rows at temperature > 0) and ``filtered_rows`` (those
+that also set ``top_k > 0`` or ``top_p < 1``), counted from the operands
+the program's own predicates read.
 
 A **training step** is recorded one-shot (``observe``) from the
 timestamps the step path already takes: enqueue, device wait, and the
@@ -72,6 +75,7 @@ PHASE_SPANS = tuple(f"serving/{p}" for p in PHASES)
 #: what an iteration's dispatches did (``count_dispatch``)
 COUNTERS = ("dispatches", "decode_rows", "chunk_rows", "rows_computed",
             "host_arrays_in", "host_reads_out",
+            "sampled_rows", "filtered_rows",
             # counted IN the mixed program by a block that routes experts
             # and reads a latent pool (models/shortcut_moe.py), carried
             # out on the dispatch's one result array; 0 for other blocks
@@ -268,16 +272,21 @@ class OverlapProfiler:
 
     def count_dispatch(self, decode_rows: int, chunk_rows: int,
                        rows_computed: int, host_arrays_in: int = 0,
-                       host_reads_out: int = 0, **program_counts: int
+                       host_reads_out: int = 0, sampled_rows: int = 0,
+                       filtered_rows: int = 0, **program_counts: int
                        ) -> None:
         """One dispatch of the mixed program: the rows that carried a
         token (decoding slots, prompt-chunk tokens), the rows the
         program ran whatever rode, the host arrays passed to it and the
-        device arrays read back from it; ``program_counts`` — what the
-        program counted itself (``moe_picks`` .. ``latent_tokens_read``
-        of ``COUNTERS``), by name."""
+        device arrays read back from it, and the rows that asked the
+        sampler for a draw and for a filter (0 sampled: the dispatch
+        took the ``argmax``-only side; 0 filtered: it sorted nothing);
+        ``program_counts`` — what the program counted itself
+        (``moe_picks`` .. ``latent_tokens_read`` of ``COUNTERS``), by
+        name."""
         for k, add in enumerate((1, decode_rows, chunk_rows, rows_computed,
-                                 host_arrays_in, host_reads_out)):
+                                 host_arrays_in, host_reads_out,
+                                 sampled_rows, filtered_rows)):
             self._counts[k] += add
         for name, add in program_counts.items():
             self._counts[_COUNTER_AT[name]] += add

@@ -129,8 +129,27 @@ def test_useful_and_computed_rows_of_a_known_batch(srv, ovl):
     assert np.all(its["rows_computed"] == SLOTS + CHUNK)
     assert np.all(its["host_arrays_in"] == 2)
     assert np.all(its["host_reads_out"] == 1)
+    # every request greedy: the sampler's argmax-only side, every dispatch
+    assert not its["sampled_rows"].any() and not its["filtered_rows"].any()
     useful = (its["decode_rows"] + its["chunk_rows"]).sum()
     assert useful / its["rows_computed"].sum() == pytest.approx(54 / 100)
+
+
+def test_sampler_rows_of_a_known_mix(srv, ovl):
+    """The same three requests, but the second samples and the third
+    samples through a nucleus filter: the chunk's row counts in the
+    iteration that prefills it (its first token is drawn there), a
+    decode row in every iteration after."""
+    t0 = time.perf_counter()
+    for k, samp in enumerate((dict(), dict(temperature=0.8, seed=3),
+                              dict(temperature=0.7, top_p=0.9, seed=4))):
+        srv.submit(prompt(CHUNK, 10 + 40 * k), max_new_tokens=3, **samp)
+    drain(srv)
+    its, _ = ovl.iterations(t0, time.perf_counter())
+    assert list(its["decode_rows"]) == [0, 1, 2, 2, 1]
+    assert list(its["sampled_rows"]) == [0, 1, 2, 2, 1]
+    assert list(its["filtered_rows"]) == [0, 0, 1, 1, 1]
+    assert srv.decode_builds == 1
 
 
 @pytest.mark.parametrize("cancel", (False, True), ids=("ok", "cancelled"))
@@ -236,12 +255,14 @@ def test_train_observe_maps_onto_the_phases():
         [0.003, 0.0, 0.002, 0.005, 0.0])
 
 
-@pytest.mark.parametrize("crossed", ((), (2, 1)), ids=("rows", "crossed"))
+@pytest.mark.parametrize("crossed", ((), (2, 1), (2, 1, 5, 3)),
+                         ids=("rows", "crossed", "sampler"))
 def test_count_dispatch_adds_up_over_an_iteration(crossed):
     """Two dispatches in one iteration: every counter is their sum, in
     the record, in ``last()`` and on the Chrome track; a caller that
     counts rows alone (the benchmark's reader tests) leaves the two
-    host-traffic counters at 0, as a training step does."""
+    host-traffic counters and the sampler's two at 0, as a training step
+    does."""
     prof = OverlapProfiler(capacity=4)
     prof.configure(enabled=True)
     prof.begin()
@@ -252,15 +273,15 @@ def test_count_dispatch_adds_up_over_an_iteration(crossed):
         prof.mark(overlap.PLAN)
     prof.end()
     want = dict(zip(overlap.COUNTERS, (2, 3, 20, 40) + tuple(
-        2 * c for c in crossed or (0, 0))))
+        2 * c for c in crossed + (0, 0, 0, 0)[len(crossed):])))
+    assert list(want)[-2:] == ["sampled_rows", "filtered_rows"]
     (rec,), _ = prof.iterations(0.0, time.perf_counter())
     last = prof.last()
     (track,) = [e for e in prof.chrome_events(0, 0) if e["ph"] == "X"]
     for name, value in want.items():
         assert rec[name] == last[name] == track["args"][name] == value
     prof.observe("train", total_s=0.01, enqueue_s=0.002, wait_s=0.005)
-    assert prof.last()["host_arrays_in"] == 0
-    assert prof.last()["host_reads_out"] == 0
+    assert not any(prof.last()[name] for name in list(want)[4:])
 
 
 def test_disabled_step_touches_no_profiler_clock_or_annotation(

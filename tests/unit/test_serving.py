@@ -1967,6 +1967,181 @@ def test_packed_lanes_are_bit_exact(packed):
                for (p, _), r in zip(PACKED_MIX, batch))
 
 
+# ---------------------------------------------------------------------------
+# the sampler does what the rows ask for (ISSUE 33): stages behind
+# ``lax.cond``s, the same bytes out whichever side a dispatch takes
+# ---------------------------------------------------------------------------
+def unconditional_sampler(logits, keys, temperature, top_k, top_p):
+    """``sample_tokens_per_row`` as it stood before ISSUE 33, verbatim:
+    every stage computed for every row, then thrown away by the last
+    ``where`` for a greedy one.  The reference the conditional one is
+    held to, byte for byte."""
+    v = logits.shape[-1]
+    logits = logits.astype(jnp.float32)
+    greedy = jnp.argmax(logits, axis=-1)
+    t = jnp.asarray(temperature, jnp.float32)
+    scaled = logits / jnp.maximum(t, 1e-8)[..., None]
+    k = jnp.asarray(top_k, jnp.int32)
+    k_eff = jnp.where(k > 0, jnp.clip(k, 1, v), v)
+    sorted_desc = jnp.sort(scaled, axis=-1)[..., ::-1]
+    kth = jnp.take_along_axis(sorted_desc, (k_eff - 1)[..., None], axis=-1)
+    filt = jnp.where(scaled < kth, -jnp.inf, scaled)
+    p = jnp.asarray(top_p, jnp.float32)
+    s2 = jnp.sort(filt, axis=-1)[..., ::-1]
+    probs = jax.nn.softmax(s2, axis=-1)
+    cum = jnp.cumsum(probs, axis=-1)
+    cutoff_idx = jnp.sum((cum < p[..., None]).astype(jnp.int32), axis=-1)
+    cutoff_idx = jnp.where(p >= 1.0, v - 1, cutoff_idx)
+    cutoff = jnp.take_along_axis(s2, cutoff_idx[..., None], axis=-1)
+    filt = jnp.where(filt < cutoff, -jnp.inf, filt)
+
+    def draw(kk, row):
+        return jax.random.categorical(kk, row)
+    sampled = jax.vmap(draw)(keys.reshape(-1, 2),
+                             filt.reshape(-1, v)).reshape(greedy.shape)
+    return jnp.where(t <= 0.0, greedy, sampled).astype(jnp.int32)
+
+
+# the cells' 25 rows (24 slots + the chunk's) x 50,304, cut to CPU size
+SAMPLER_ROWS, SAMPLER_VOCAB = 25, 1024
+
+
+def _rows(temperature=0.0, top_k=0, top_p=1.0, **one_row):
+    """Per-row sampling state: every row alike, but row 7 as ``one_row``
+    says."""
+    t = np.full(SAMPLER_ROWS, temperature, np.float32)
+    k = np.full(SAMPLER_ROWS, top_k, np.int32)
+    p = np.full(SAMPLER_ROWS, top_p, np.float32)
+    for name, value in one_row.items():
+        {"t": t, "k": k, "p": p}[name][7] = value
+    return t, k, p
+
+
+SAMPLER_MIXES = {
+    "all_greedy": _rows(),
+    "all_temperature": _rows(0.7),
+    "all_top_k": _rows(0.7, top_k=40),
+    "all_top_p": _rows(0.7, top_p=0.9),
+    "top_k_and_top_p": _rows(0.7, top_k=40, top_p=0.9),
+    "one_filtered_among_greedy": _rows(t=0.7, k=40, p=0.9),
+    "one_unfiltered_among_greedy": _rows(t=1.3),
+    "one_filtered_among_unfiltered": _rows(0.7, p=0.5),
+    "one_top_k_among_top_p": _rows(0.7, top_p=0.9, k=40, p=1.0),
+    # filters on greedy rows beside a row that samples without one: the
+    # greedy rows' draws are thrown away, so their filters sort nothing
+    "greedy_filters_beside_unfiltered": _rows(0.0, top_k=5, top_p=0.5,
+                                              t=0.9, k=0, p=1.0),
+    # filters set on greedy rows only: nobody samples, nothing sorts
+    "greedy_rows_with_filters": _rows(0.0, top_k=5, top_p=0.5),
+    # neutral by value, not by absence
+    "top_p_exactly_one": _rows(0.7, top_p=1.0),
+    "top_k_equal_to_vocab": _rows(0.7, top_k=SAMPLER_VOCAB),
+}
+
+
+@pytest.fixture(scope="module")
+def sampler_pair():
+    """Both samplers jitted once: every mix below is DATA to the one
+    program, as in the serving step."""
+    from deepspeed_tpu.inference.sampling import sample_tokens_per_row
+    return jax.jit(sample_tokens_per_row), jax.jit(unconditional_sampler)
+
+
+@pytest.mark.parametrize("mix", list(SAMPLER_MIXES))
+def test_conditional_sampler_is_byte_equal_to_the_unconditional(
+        sampler_pair, mix):
+    """Whichever stages a dispatch skips, its tokens are the ones the
+    every-stage-always sampler drew."""
+    new, old = sampler_pair
+    t, k, p = SAMPLER_MIXES[mix]
+    for seed in range(3):
+        rs = np.random.RandomState(1000 * seed + len(mix))
+        # bf16-rounded values: ties near the top, as the chip's head gives
+        logits = jnp.asarray(
+            rs.randn(SAMPLER_ROWS, SAMPLER_VOCAB) * 3.0,
+            jnp.bfloat16).astype(jnp.float32)
+        keys = jnp.asarray(
+            rs.randint(0, 2 ** 32, (SAMPLER_ROWS, 2), np.uint64), jnp.uint32)
+        got = np.asarray(new(logits, keys, t, k, p))
+        want = np.asarray(old(logits, keys, t, k, p))
+        np.testing.assert_array_equal(got, want)
+        assert got.dtype == np.int32
+        if not (t > 0).any():
+            np.testing.assert_array_equal(got, np.argmax(logits, axis=-1))
+
+
+def test_batch_moves_between_greedy_and_mixed_on_one_program(packed):
+    """All-greedy dispatches, then greedy beside sampled and filtered
+    rows, then all-greedy again: each side of the sampler's conditionals
+    in one run of ONE program (the fixture holds ``decode_builds`` to 1),
+    every stream the tokens of ``generate()`` under its key, and the
+    overlap record's ``sampled_rows`` / ``filtered_rows`` the hand count
+    of every dispatch: decode rows, a chunk's row, speculative rows."""
+    from deepspeed_tpu.observability import get_overlap_profiler
+    eng, srv = packed
+    greedy = dict(temperature=0.0)
+    greedy_first = [([5, 3, 5, 8, 9, 7, 9], greedy), ([2, 7, 1, 8], greedy)]
+    greedy_last = [([1, 1, 2, 3, 5, 8, 13], greedy),
+                   ([6, 2, 8, 3, 1], greedy)]
+    n = 6
+
+    def submit(mix):
+        return [srv.submit(p, max_new_tokens=n, **samp) for p, samp in mix]
+
+    want = {"sampled_rows": [], "filtered_rows": []}
+    real = srv._step_operands
+
+    def hand_count(dec, chunk, spec=()):
+        rows = ([r for _, r in dec] + [r for _, r in spec] * (srv.spec_k + 1)
+                + ([chunk[1]] if chunk is not None else []))
+        sampled = [r for r in rows if r.temperature > 0]
+        want["sampled_rows"].append(len(sampled))
+        want["filtered_rows"].append(
+            sum(r.top_k > 0 or r.top_p < 1 for r in sampled))
+        return real(dec, chunk, spec)
+
+    prof = get_overlap_profiler()
+    prof.reset()
+    prof.configure(enabled=True)
+    srv._step_operands = hand_count
+    try:
+        t0 = time.perf_counter()
+        reqs = submit(greedy_first)
+        srv.run()
+        greedy_dispatches = len(want["sampled_rows"])
+        reqs += submit(PACKED_MIX)
+        srv.run()
+        mixed_dispatches = len(want["sampled_rows"])
+        reqs += submit(greedy_last)
+        srv.run()
+        its, complete = prof.iterations(t0, time.perf_counter())
+        events = prof.chrome_events(0, 0)
+    finally:
+        del srv._step_operands
+        prof.configure(enabled=False)
+        prof.reset()
+    assert complete
+    for (p, samp), r in zip(greedy_first + PACKED_MIX + greedy_last, reqs):
+        assert r.status is RequestStatus.OK
+        assert r.output == sampled_generate(eng, p, n, **samp), (p, samp)
+    ends = np.cumsum(its["dispatches"])
+    slices = [e["args"] for e in events if e["ph"] == "X"]
+    for name, counts in want.items():
+        # an iteration's record is the sum over its dispatches
+        assert [sum(counts[a:b]) for a, b in zip(
+            ends - its["dispatches"], ends)] == list(its[name]), name
+        # the greedy stretches took the argmax-only side in every dispatch
+        assert not any(counts[:greedy_dispatches]), name
+        assert not any(counts[mixed_dispatches:]), name
+        # on the Chrome track beside ``host_arrays_in``
+        assert sum(a[name] for a in slices) == sum(counts), name
+    mixed = slice(greedy_dispatches, mixed_dispatches)
+    assert sum(want["sampled_rows"][mixed]) \
+        > sum(want["filtered_rows"][mixed]) > 0
+    # a greedy row rode beside a sampled one: today's path whole
+    assert any(0 < s < srv.num_slots for s in want["sampled_rows"][mixed])
+
+
 def test_dispatch_passes_two_host_arrays_and_reads_one(packed, monkeypatch):
     """Beside the device's own state (weights, pools) the operands are
     two NumPy arrays and nothing that lives on the device; one dispatch

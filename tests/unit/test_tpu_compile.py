@@ -543,3 +543,75 @@ def test_serving_step_lowers_for_tpu(compiled_kernels, mesh):
     srv = eng.serving_engine()
     text = lower_for_tpu(srv._build_step(), *srv._step_operands((), None))
     assert "tpu_custom_call" in text
+
+
+def hlo_computations(text):
+    """Optimized HLO text -> ``({computation: its instruction lines},
+    the entry computation's name)``."""
+    import re
+    comps, entry, body = {}, None, None
+    for ln in text.splitlines():
+        m = re.match(r"(ENTRY )?%(\S+) \(.*\{$", ln)
+        if m is not None:
+            body = comps[m.group(2)] = []
+            entry = m.group(2) if m.group(1) else entry
+        elif ln.startswith("}"):
+            body = None
+        elif body is not None:
+            body.append(ln)
+    return comps, entry
+
+
+def test_serving_step_sorts_only_inside_a_conditional(v5e_devices,
+                                                      compiled_kernels):
+    """The 1x1 serving step COMPILED for a v5e chip at the Pythia cells'
+    sampler shape (24 slots and the chunk's row over 50,304): the
+    sampler's stages are still ``conditional`` instructions, and every
+    ``sort`` lies in a computation that is reached only through a
+    conditional's branch.  A ``vmap`` over the sampler (``cond`` ->
+    ``select``) or a compiler that hoists a branch's work makes every
+    all-greedy dispatch sort again, and fails here, not in a benchmark."""
+    import re
+    import deepspeed_tpu as ds
+    model = TransformerLM(gpt2_config(
+        "125m", num_layers=2, d_model=256, num_heads=2, vocab_size=50304,
+        max_seq_len=128))
+    eng = ds.init_inference(model, {
+        "dtype": "bfloat16", "max_out_tokens": 128,
+        "serving": {"enabled": True, "kv_block_size": 16,
+                    "num_kv_blocks": 64, "max_batch_slots": 24,
+                    "prefill_chunk_tokens": 32,
+                    "mesh": {"data": 1, "model": 1}}})
+    srv = eng.serving_engine()
+    operands = srv._step_operands((), None)
+    # the engine's own program, built over one described chip instead of
+    # the CPU device the engine found
+    srv.tp_mesh = Mesh(
+        np.array(v5e_devices[:1]).reshape(srv.tp_mesh.devices.shape),
+        srv.tp_mesh.axis_names)
+    on_chip = NamedSharding(srv.tp_mesh, P())
+    jax.clear_caches()          # drop traces made with interpreted kernels
+    text = srv._build_step().trace(*jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=on_chip),
+        operands)).lower(lowering_platforms=("tpu",)).compile().as_text()
+    assert "tpu_custom_call" in text
+    comps, entry = hlo_computations(text)
+    # three stages a sampler call (the draw, top-k, top-p: a sort in each
+    # filter), two calls a dispatch (decode rows, the chunk's row)
+    assert text.count(" conditional(") >= 6
+    assert text.count(" sort(") >= 4
+    # everything the entry reaches WITHOUT entering a conditional's branch
+    always, todo = set(), [entry]
+    while todo:
+        name = todo.pop()
+        if name in always:
+            continue
+        always.add(name)
+        for ln in comps[name]:
+            if " conditional(" not in ln:
+                todo += [c for c in re.findall(r"%([\w.-]+)", ln)
+                         if c in comps]
+    assert len(always) > 1
+    unconditional_sorts = [ln.strip()[:120] for name in always
+                           for ln in comps[name] if " sort(" in ln]
+    assert not unconditional_sorts, unconditional_sorts
