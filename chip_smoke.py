@@ -23,9 +23,10 @@ weights from a seed:
          ``tpu_custom_call``.
   kernel the paged decode and prefill kernel against the float32
          ``jax.numpy`` reference, on the chip, at the serving shapes.
-  latent (one chip) the latent paged kernel and the experts' grouped
-         product at ``longcat-flash-omni``'s widths against float32
-         ``jax.numpy``.
+  latent (one chip) the latent paged kernel at 64 and at 128 heads, the
+         experts' grouped product, and an expert layer with a sigmoid
+         gate and a shared expert, at ``longcat-flash-omni``'s and
+         ``openpangu-ultra-moe``'s widths against float32 ``jax.numpy``.
 
 It fails — non-zero exit, no result line — when JAX shows anything but
 CHIPS TPU devices; it never adapts downward, and nothing on the path is
@@ -334,11 +335,14 @@ def kernel_phase(heads: int, head_dim: int, device: dict,
 
 
 def latent_phase(device: dict, block: int = SERVING["kv_block_size"]):
-    """The latent (MLA) paged kernel and the experts' grouped product at
-    the widths of ``longcat-flash-omni.serve-longdoc-sat`` (64 heads over
-    one [512 | 64 | 0] row of 640 lanes a token, 512-row chunks; 16 held
-    experts 6144 x 2048 at about 9 rows each), against float32
-    ``jax.numpy``, on the chip."""
+    """The latent (MLA) paged kernel at 64 heads
+    (``longcat-flash-omni.serve-longdoc-sat``) and at 128
+    (``openpangu-ultra-moe.serve-reason-sat``: a decode walker of 128 rows,
+    a chunk tile of 8 positions) over one [512 | 64 | 0] row of 640 lanes a
+    token, 512-row chunks; the experts' grouped product (16 held experts
+    6144 x 2048 at about 9 rows each); and an expert layer with a sigmoid
+    gate and a shared expert (7680 x 2048, 640 rows), against float32
+    ``jax.numpy``, on the chip.  Parity only: nothing here is timed."""
     import jax
     import jax.numpy as jnp
     from deepspeed_tpu.moe import dropless
@@ -349,7 +353,7 @@ def latent_phase(device: dict, block: int = SERVING["kv_block_size"]):
     rng = np.random.default_rng(SEED + 3)
     f32 = lambda a: a.astype(jnp.float32)               # noqa: E731
     bf = lambda a: jnp.asarray(a, jnp.bfloat16)         # noqa: E731
-    heads, lat, rope, lanes, chunk = 64, 512, 64, 640, 512
+    lat, rope, lanes, chunk = 512, 64, 640, 512
     scale = 1.0 / np.sqrt(192.0)
     lens = np.array([1, 640, 1097, 2048, 3333, 4096, 6655, 0], np.int32)
     pages = 8192 // block
@@ -357,33 +361,44 @@ def latent_phase(device: dict, block: int = SERVING["kv_block_size"]):
     tables = np.arange(1, nb, dtype=np.int32).reshape(len(lens), pages)
     pool = bf(np.pad(rng.standard_normal((nb, block, lat + rope)),
                      ((0, 0), (0, 0), (0, lanes - lat - rope))))
-    ql = bf(rng.standard_normal((len(lens), heads, lat)) * 0.3)
-    qr = bf(rng.standard_normal((len(lens), heads, rope)) * 0.3)
     decode = jax.jit(lambda *a: mla_paged_decode_attention(*a, scale))
-    out = decode(ql, qr, pool, lens, tables)
-    ref = mla_paged_reference(f32(ql)[:, None], f32(qr)[:, None], pool,
-                              lens - 1, lens, tables, scale)[:, 0]
-    decode_err = float(jnp.max(jnp.abs(f32(out) - ref)))
-    check(bool(jnp.all(jnp.isfinite(f32(out)))), "latent: decode not finite")
-    check(decode_err < KERNEL_ATOL,
-          f"latent: decode off the f32 reference by {decode_err}")
-
-    # a full chunk deep in a context, then a ragged one that ends mid-page
-    qlc = bf(rng.standard_normal((chunk, heads, lat)) * 0.3)
-    qrc = bf(rng.standard_normal((chunk, heads, rope)) * 0.3)
     prefill = jax.jit(lambda *a: mla_paged_prefill_attention(*a, scale))
-    prefill_err = 0.0
-    for base, n in ((2560, chunk), (3072, chunk // 2 + 3)):
-        out = prefill(qlc, qrc, pool, base, n, tables[6])
-        ref = mla_paged_reference(f32(qlc)[None], f32(qrc)[None], pool,
-                                  np.array([base]), np.array([base + n]),
-                                  tables[6][None], scale)[0]
-        check(bool(jnp.all(jnp.isfinite(f32(out)[:n]))),
-              "latent: prefill not finite")
-        prefill_err = max(prefill_err, float(jnp.max(jnp.abs(
-            f32(out)[:n] - ref[:n]))))
-    check(prefill_err < KERNEL_ATOL,
-          f"latent: prefill off the f32 reference by {prefill_err}")
+    decode_err, prefill_err = {}, {}
+    # the float32 reference holds [chunk, heads, context] scores: at 128
+    # heads the chunks sit half as deep, in a table of half the pages
+    for heads, view, chunks in (
+            (64, pages, ((2560, chunk), (3072, chunk // 2 + 3))),
+            (128, pages // 2, ((1536, chunk), (2048, chunk // 2 + 3)))):
+        ql = bf(rng.standard_normal((len(lens), heads, lat)) * 0.3)
+        qr = bf(rng.standard_normal((len(lens), heads, rope)) * 0.3)
+        out = decode(ql, qr, pool, lens, tables)
+        ref = mla_paged_reference(f32(ql)[:, None], f32(qr)[:, None], pool,
+                                  lens - 1, lens, tables, scale)[:, 0]
+        decode_err[heads] = float(jnp.max(jnp.abs(f32(out) - ref)))
+        check(bool(jnp.all(jnp.isfinite(f32(out)))),
+              f"latent: decode at {heads} heads not finite")
+        check(decode_err[heads] < KERNEL_ATOL,
+              f"latent: decode at {heads} heads off the f32 reference by "
+              f"{decode_err[heads]}")
+
+        # a full chunk deep in a context, then a ragged one that ends
+        # mid-page
+        qlc = bf(rng.standard_normal((chunk, heads, lat)) * 0.3)
+        qrc = bf(rng.standard_normal((chunk, heads, rope)) * 0.3)
+        table = tables[6][:view]
+        prefill_err[heads] = 0.0
+        for base, n in chunks:
+            out = prefill(qlc, qrc, pool, base, n, table)
+            ref = mla_paged_reference(f32(qlc)[None], f32(qrc)[None], pool,
+                                      np.array([base]), np.array([base + n]),
+                                      table[None], scale)[0]
+            check(bool(jnp.all(jnp.isfinite(f32(out)[:n]))),
+                  f"latent: prefill at {heads} heads not finite")
+            prefill_err[heads] = max(prefill_err[heads], float(jnp.max(
+                jnp.abs(f32(out)[:n] - ref[:n]))))
+        check(prefill_err[heads] < KERNEL_ATOL,
+              f"latent: prefill at {heads} heads off the f32 reference by "
+              f"{prefill_err[heads]}")
 
     # the grouped product: 16 held experts, ~9 rows each, one pass
     held, h, f, rows_each = 16, 6144, 2048, 9
@@ -404,10 +419,53 @@ def latent_phase(device: dict, block: int = SERVING["kv_block_size"]):
         precision="highest").reshape(-1, f)
     gmm_err = float(jnp.max(jnp.abs(got - want)))
     check(gmm_err < 5e-2, f"latent: grouped product off by {gmm_err}")
+    gate_err = _shared_expert_layer_error()
+    # the same measure and limit as the cell's `expert_rel_err`
+    check(gate_err < 5e-2,
+          f"latent: sigmoid gate + shared expert off by {gate_err}")
     return {"phase": "latent", **device,
-            "decode_max_abs_err": round(decode_err, 5),
-            "prefill_max_abs_err": round(prefill_err, 5),
-            "grouped_max_abs_err": round(gmm_err, 5), "atol": KERNEL_ATOL}
+            "decode_max_abs_err": {str(h): round(e, 5)
+                                   for h, e in decode_err.items()},
+            "prefill_max_abs_err": {str(h): round(e, 5)
+                                    for h, e in prefill_err.items()},
+            "grouped_max_abs_err": round(gmm_err, 5),
+            "shared_expert_layer_rel_err": round(gate_err, 5),
+            "atol": KERNEL_ATOL}
+
+
+def _shared_expert_layer_error(rows: int = 640) -> float:
+    """One expert layer of the sandwich block at its published widths in
+    bfloat16 — sigmoid top-8 gate over 256 outputs, renormalised and scaled
+    by 2.5, 16 held experts through the grouped product, the shared expert
+    over every row — against the benchmark's float32 reference of the
+    same layer: the norm of the difference over the norm of the held
+    experts' own part."""
+    import jax
+    import jax.numpy as jnp
+    from benchmark.lib import reference_openpangu_ultra_moe as reference
+    from deepspeed_tpu.models import build_model, openpangu_ultra_moe_config
+    held = (0, 16)
+    model = build_model(openpangu_ultra_moe_config(
+        "718b", num_layers=2, first_k_dense=1, vocab_size=256,
+        max_seq_len=256, experts_held=held))
+    c = model.config
+    cfg = {"n_routed_experts": c.n_routed_experts, "moe_topk": c.moe_topk,
+           "scale": c.routed_scaling_factor}
+    key = jax.random.PRNGKey(SEED + 4)
+    p = jax.jit(lambda key: jax.tree_util.tree_map(
+        lambda a: a.astype(jnp.bfloat16), model.init_superblock(key)))(key)
+    u = jax.random.normal(jax.random.fold_in(key, 1),
+                          (1, rows, c.d_model)).astype(jnp.bfloat16)
+    got = jax.jit(lambda p, u: model.expert_layer(p, u)[0])(p, u)
+
+    def plain(p, u):
+        with jax.default_matmul_precision("highest"):
+            u = u.astype(jnp.float32)
+            return (reference.moe(p, u, cfg, held),
+                    reference.routed(p["moe"], u, cfg, held))
+    want, own = jax.jit(plain)(p, u)
+    return float(jnp.linalg.norm(got.astype(jnp.float32) - want)
+                 / jnp.linalg.norm(own))
 
 
 def main(argv) -> int:

@@ -97,10 +97,11 @@ def kv_block_bytes(block_size: int, kv_heads: int, head_dim: int,
 def latent_block_bytes(block_size: int, latent_width: int,
                        rope_width: int, cache_itemsize: int = 2) -> int:
     """Device HBM bytes one pool block costs in ONE attention sublayer of
-    a LATENT pool (``models/shortcut_moe.py init_paged_cache``): a token's
+    a LATENT pool (``models/latent_moe.py init_paged_cache``): a token's
     row is its latent (``latent_width`` values: key and value of every
     head) and its rotary key side by side, padded to whole 128-lane
-    tiles.  A model holds ``2 * num_layers`` sublayers.  Pure ints, pinned
+    tiles.  A model holds ``ATTN_SUBLAYERS * num_layers`` sublayers (2 a
+    layer in the shortcut block, 1 in the sandwich block).  Pure ints, pinned
     against the model by test, like :func:`kv_block_bytes`."""
     lanes = -(-(latent_width + rope_width) // 128) * 128
     return block_size * lanes * cache_itemsize

@@ -205,7 +205,8 @@ class TransformerConfig:
         """The class that runs this configuration's block;
         :func:`build_model` builds it.  A block that is not ``x + attn +
         mlp`` brings its own config subclass and model subclass
-        (``models/shortcut_moe.py``) instead of more flags here."""
+        (``models/shortcut_moe.py``, ``models/sandwich_moe.py``) instead
+        of more flags here."""
         return TransformerLM
 
     @property
@@ -326,6 +327,34 @@ def longcat_flash_config(size: str = "omni", **kw) -> TransformerConfig:
         "pos_embedding": "none", "norm_type": "rmsnorm", "gated_mlp": True,
         "activation": "silu", "use_bias": False, "tie_embeddings": False,
         "layernorm_eps": 1e-5, **LONGCAT_FLASH_SIZES[size], **kw})
+
+
+OPENPANGU_ULTRA_MOE_SIZES = {
+    # https://huggingface.co/FreedomIntelligence/openPangu-Ultra-MoE-718B
+    # config.json
+    "718b": dict(num_layers=61, first_k_dense=3, num_heads=128,
+                 d_model=7680, d_ff=18432, head_dim=192, vocab_size=153600,
+                 max_seq_len=131072, q_lora_rank=1536, kv_lora_rank=512,
+                 qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+                 rope_theta=25.6e6, expert_d_ff=2048, n_routed_experts=256,
+                 n_shared_experts=1, moe_topk=8, routed_scaling_factor=2.5,
+                 norm_topk_prob=True),
+}
+
+
+def openpangu_ultra_moe_config(size: str = "718b", **kw) -> TransformerConfig:
+    """openPangu-Ultra-MoE's language model: the sandwich-norm
+    latent-attention block, ``first_k_dense`` dense layers before the
+    expert layers, a sigmoid top-8 router beside an always-on shared
+    expert (``models/sandwich_moe.py``).  ``size`` names a published set
+    of widths; depth, leading dense layers, vocabulary, served positions
+    and ``experts_held`` (the chip's share of a deployment) come as
+    keywords."""
+    from .sandwich_moe import SandwichMoEConfig
+    return SandwichMoEConfig(**{
+        "pos_embedding": "none", "norm_type": "rmsnorm", "gated_mlp": True,
+        "activation": "silu", "use_bias": False, "tie_embeddings": False,
+        "layernorm_eps": 1e-5, **OPENPANGU_ULTRA_MOE_SIZES[size], **kw})
 
 
 def build_model(config: TransformerConfig, **kw) -> "TransformerLM":

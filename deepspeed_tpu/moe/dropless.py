@@ -1,11 +1,13 @@
 """Dropless top-k routing over routed and zero-compute experts, and the
 expert layer of ONE CHIP'S SHARE of the routed experts.
 
-The router keeps its published width: softmax scores over ``E`` routed
-experts and ``Z`` identity ("zero-compute") experts, the ``k`` chosen are
-the top ``k`` of ``score + bias`` (the bias moves the choice and never the
-weight), and a pick's weight is ``scale * score`` — not renormalised.  No
-capacity, no drops, no auxiliary loss.
+The router keeps its published width: scores over ``E`` routed experts
+and ``Z`` identity ("zero-compute") experts — a softmax over the outputs
+or each output's sigmoid — the ``k`` chosen are the top ``k`` of ``score +
+bias`` (the bias, where the gate has one, moves the choice and never the
+weight), and a pick's weight is ``scale * score``, renormalised over the
+row's picks where the configuration says so (:func:`route`).  No capacity,
+no drops, no auxiliary loss.
 
 The expert layer is told which contiguous range of the routed experts it
 holds (``experts_held``).  It computes, for the rows routed to a held
@@ -23,7 +25,7 @@ trip count follows the load: a balanced batch takes one pass, a batch
 routed wholly to one expert takes as many as its rows need, and no pick
 is ever dropped.  The product is :func:`grouped_matmul` (device trace name
 ``moe_grouped_matmul``), a forward-only kernel: the block that uses it
-serves and does not train (``ShortcutMoELM.training_refusal``).
+serves and does not train (``LatentMoELM.training_refusal``).
 """
 from __future__ import annotations
 
@@ -52,20 +54,35 @@ COUNTERS = ("moe_picks", "moe_picks_held", "moe_picks_zero",
 
 class Routing(NamedTuple):
     index: jax.Array       # [T, k] int32 in [0, E + Z)
-    weight: jax.Array      # [T, k] float32 = scale * softmax score
+    weight: jax.Array      # [T, k] float32 = scale * (renormalised) score
 
 
-def route(u: jax.Array, router_kernel: jax.Array, bias: jax.Array, k: int,
-          scale: float) -> Routing:
-    """``u [T, h]`` -> the ``k`` picks of every row.  Scores are a
-    float32 softmax over all ``E + Z`` outputs (the operands keep their
-    stored type; products accumulate in float32)."""
+def route(u: jax.Array, router_kernel: jax.Array,
+          bias: Optional[jax.Array], k: int, scale: float,
+          scoring: str = "softmax", renormalize: bool = False) -> Routing:
+    """``u [T, h]`` -> the ``k`` picks of every row, in the gate's form
+    the model's configuration names.  Scores are float32 over all
+    ``E + Z`` outputs (the operands keep their stored type; products
+    accumulate in float32): a ``softmax`` over the outputs, or each
+    output's own ``sigmoid``.  The ``k`` chosen are the top ``k`` of
+    ``score + bias`` (``bias`` None: of the score).  A pick's weight is
+    ``scale * score``, or with ``renormalize`` ``scale * score / (the
+    row's chosen scores' sum + 1e-20)``."""
     logits = jnp.einsum("th,he->te", u, router_kernel.astype(u.dtype),
                         preferred_element_type=jnp.float32)
-    p = jax.nn.softmax(logits, axis=-1)
-    _, index = jax.lax.top_k(p + bias.astype(jnp.float32), k)
-    weight = scale * jnp.take_along_axis(p, index, axis=-1)
-    return Routing(index.astype(jnp.int32), weight)
+    if scoring == "softmax":
+        p = jax.nn.softmax(logits, axis=-1)
+    elif scoring == "sigmoid":
+        p = jax.nn.sigmoid(logits)
+    else:
+        raise ValueError(f"route: scoring {scoring!r} is neither 'softmax' "
+                         f"nor 'sigmoid'")
+    _, index = jax.lax.top_k(
+        p if bias is None else p + bias.astype(jnp.float32), k)
+    weight = jnp.take_along_axis(p, index, axis=-1)
+    if renormalize:
+        weight = weight / (jnp.sum(weight, axis=-1, keepdims=True) + 1e-20)
+    return Routing(index.astype(jnp.int32), scale * weight)
 
 
 def _tile_n(k_dim: int, n: int, itemsize: int) -> int:

@@ -77,11 +77,14 @@ COUNTERS = ("dispatches", "decode_rows", "chunk_rows", "rows_computed",
             "host_arrays_in", "host_reads_out",
             "sampled_rows", "filtered_rows",
             # counted IN the mixed program by a block that routes experts
-            # and reads a latent pool (models/shortcut_moe.py), carried
-            # out on the dispatch's one result array; 0 for other blocks
+            # and reads a latent pool (models/latent_moe.py), carried out
+            # on the dispatch's one result array; 0 for other blocks
             "moe_picks", "moe_picks_held", "moe_picks_zero",
             "moe_rows_max_expert", "moe_experts_touched",
-            "latent_tokens_read")
+            "latent_tokens_read",
+            # rows x expert layers through an always-on shared expert
+            # (models/sandwich_moe.py); 0 for blocks that have none
+            "moe_rows_shared")
 _COUNTER_AT = {name: k for k, name in enumerate(COUNTERS)}
 
 #: one iteration (or training step).  Times are seconds on
@@ -282,7 +285,7 @@ class OverlapProfiler:
         sampler for a draw and for a filter (0 sampled: the dispatch
         took the ``argmax``-only side; 0 filtered: it sorted nothing);
         ``program_counts`` — what the program counted itself
-        (``moe_picks`` .. ``latent_tokens_read`` of ``COUNTERS``), by
+        (``moe_picks`` .. ``moe_rows_shared`` of ``COUNTERS``), by
         name."""
         for k, add in enumerate((1, decode_rows, chunk_rows, rows_computed,
                                  host_arrays_in, host_reads_out,

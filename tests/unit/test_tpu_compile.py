@@ -346,12 +346,17 @@ def test_mixed_step_keeps_the_pool_in_place(v5e_devices, compiled_kernels,
     assert compiled.memory_analysis().temp_size_in_bytes < slice_bytes
 
 
+@pytest.mark.parametrize("slots,heads,pages,hidden", [
+    (48, 64, 512, 6144), (128, 128, 256, 7680)],
+    ids=["longcat-flash-omni", "openpangu-ultra-moe"])
 def test_latent_kernel_and_grouped_product_compile_at_the_cells_shapes(
-        v5e_devices):
+        v5e_devices, slots, heads, pages, hidden):
     """``longcat-flash-omni.serve-longdoc-sat``: 48 decode slots and one
     512-row chunk of 64 heads over one ``[512 | 64 | 0]`` row of 640
     lanes a token, 512 pages of 16; 16 held experts of 6144 x 2048 in
-    passes of 1,024 rows."""
+    passes of 1,024 rows.  ``openpangu-ultra-moe.serve-reason-sat``: 128
+    slots, 128 heads (a decode walker of 128 rows, a chunk tile of 8
+    positions), 256 pages; 16 held experts of 7680 x 2048."""
     from deepspeed_tpu.moe import dropless
     from deepspeed_tpu.ops.transformer.paged_decode_attention import (
         mla_paged_decode_attention, mla_paged_prefill_attention)
@@ -367,12 +372,12 @@ def test_latent_kernel_and_grouped_product_compile_at_the_cells_shapes(
         return mla_paged_prefill_attention(ql, qr, pool, base, n, table,
                                            0.072, interpret=False)
     assert "tpu_custom_call" in compile_for_tpu(
-        decode, sds((48, 64, 512), bf), sds((48, 64, 64), bf), pool,
-        sds((48,), i32), sds((48, 512), i32))
+        decode, sds((slots, heads, 512), bf), sds((slots, heads, 64), bf),
+        pool, sds((slots,), i32), sds((slots, pages), i32))
     assert "tpu_custom_call" in compile_for_tpu(
-        chunk, sds((512, 64, 512), bf), sds((512, 64, 64), bf), pool,
-        sds((), i32), sds((), i32), sds((512,), i32))
-    for k_dim, n in ((6144, 2048), (2048, 6144)):
+        chunk, sds((512, heads, 512), bf), sds((512, heads, 64), bf), pool,
+        sds((), i32), sds((), i32), sds((pages,), i32))
+    for k_dim, n in ((hidden, 2048), (2048, hidden)):
         text = compile_for_tpu(
             lambda x, w, te, live: dropless.grouped_matmul(
                 x, w, te, live, interpret=False),
@@ -381,20 +386,42 @@ def test_latent_kernel_and_grouped_product_compile_at_the_cells_shapes(
         assert "tpu_custom_call" in text
 
 
+def _shortcut_case():
+    from deepspeed_tpu.models import longcat_flash_config
+    return longcat_flash_config(
+        "omni", num_layers=2, vocab_size=1024, max_seq_len=8192,
+        experts_held=(0, 4)), 4, 48, 512, 7
+
+
+def _sandwich_case():
+    from deepspeed_tpu.models import openpangu_ultra_moe_config
+    return openpangu_ultra_moe_config(
+        "718b", num_layers=3, first_k_dense=1, vocab_size=1024,
+        max_seq_len=4096, experts_held=(0, 8)), 8, 128, 256, 7
+
+
+@pytest.mark.parametrize("case", [_shortcut_case, _sandwich_case],
+                         ids=["shortcut", "sandwich"])
 def test_latent_mixed_step_keeps_pool_and_experts_in_place(
-        v5e_devices, compiled_kernels):
-    """The shortcut block's mixed step at the cell's widths (2 layers,
-    4 held experts): the latent pool is the scan's carry and the expert
-    stack is read where it lies, so the compiled step holds no pool-shaped
-    and no expert-stack-shaped copy, slice or second buffer — either would
-    be more than a GB moved every step at the cell's depth."""
+        v5e_devices, compiled_kernels, case):
+    """A latent block's mixed step at its cell's widths — the shortcut
+    block (2 layers of two attention sublayers, 4 held experts) and the
+    sandwich block (one dense layer before two expert layers: two kinds
+    of layer through one pool, the expert stack indexed by expert-layer
+    number; 8 held experts, so that one layer's slice of one expert
+    matrix is more than the 128-head attention's own temporaries): the
+    latent pool is the scans' carry and the expert stack is
+    read where it lies, so the compiled step holds no pool-shaped and no
+    expert-stack-shaped copy, slice or second buffer — either would be
+    more than a GB moved every step at the cell's depth."""
     import re
-    from deepspeed_tpu.models import build_model, longcat_flash_config
-    layers, held, nb, slots, chunk = 2, 4, 4096, 48, 512
+    from deepspeed_tpu.models import build_model
+    config, held, slots, pages, kernels = case()
+    nb, chunk = 4096, 512
     sds = one_chip(v5e_devices)
-    model = build_model(longcat_flash_config(
-        "omni", num_layers=layers, vocab_size=1024, max_seq_len=8192,
-        experts_held=(0, held)))
+    model = build_model(config)
+    sublayers = model.ATTN_SUBLAYERS * config.num_layers
+    stacked = config.scan_length
 
     def abstract(tree, dtype=None):
         return jax.tree_util.tree_map(
@@ -404,7 +431,8 @@ def test_latent_mixed_step_keeps_pool_and_experts_in_place(
     cache = abstract(jax.eval_shape(
         lambda: model.init_paged_cache(nb, 16, jnp.bfloat16)))
     pools = {"k": cache["k"]}              # one buffer: "v" is None
-    cache["block_tables"] = sds((slots, 512), jnp.int32)
+    assert cache["k"].shape[0] == sublayers
+    cache["block_tables"] = sds((slots, pages), jnp.int32)
     cache["lens"] = sds((slots,), jnp.int32)
     scalar = sds((), jnp.int32)
     compiled = jax.jit(model._apply_paged_mixed, donate_argnums=1).trace(
@@ -412,15 +440,17 @@ def test_latent_mixed_step_keeps_pool_and_experts_in_place(
         sds((chunk,), jnp.int32), scalar, scalar, scalar).lower(
             lowering_platforms=("tpu",)).compile()
     text = compiled.as_text()
-    # two attention sublayers x (decode + chunk) and three grouped products
-    assert text.count("tpu_custom_call") >= 7
+    # the scanned layer's attention sublayers x (decode + chunk) and its
+    # three grouped products; a leading layer's decode + chunk
+    assert text.count("tpu_custom_call") >= kernels
     shaped = set()
     for a in pools.values():
-        for lead in ((2 * layers, nb), (nb,), (2 * layers * nb,)):
+        for lead in ((sublayers, nb), (nb,), (sublayers * nb,)):
             shaped.add(lead + a.shape[2:])
     for a in params["blocks"]["moe"]["experts"].values():
+        assert a.shape[:2] == (stacked, held)
         shaped.update({a.shape, a.shape[1:], (1,) + a.shape[1:],
-                       (layers * held,) + a.shape[2:]})
+                       (stacked * held,) + a.shape[2:]})
     moved = []
     for ln in text.splitlines():
         m = re.match(r"\s*(?:ROOT )?%(\S+) = \w+\[([\d,]+)\]\S* ([\w-]+)\(",
@@ -434,7 +464,7 @@ def test_latent_mixed_step_keeps_pool_and_experts_in_place(
                 or (op == "fusion" and "dynamic" in name)):
             moved.append(ln.strip()[:160])
     assert not moved, moved
-    one_expert_stack = held * 6144 * 2048 * 2
+    one_expert_stack = held * config.d_model * 2048 * 2
     assert compiled.memory_analysis().temp_size_in_bytes < one_expert_stack
 
 
