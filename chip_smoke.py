@@ -18,9 +18,10 @@ weights from a seed:
          ``serving_engine()`` -> staggered ``submit``/``step``/``run``
          through the paged pool, chunked prefill and the mixed decode
          program (``serving.mesh {"model": N}``): every greedy stream
-         completes with finite logits, ``decode_builds == 1``, no
-         compile after warm-up, the pool drains, the step program holds
-         ``tpu_custom_call``.
+         completes with finite logits, the step's two shapes are built
+         by the first dispatch and never again (``decode_builds == 2``)
+         and each runs at least one dispatch, no compile after warm-up,
+         the pool drains, the step program holds ``tpu_custom_call``.
   kernel the paged decode and prefill kernel against the float32
          ``jax.numpy`` reference, on the chip, at the serving shapes.
   latent (one chip) the latent paged kernel at 64 and at 128 heads, the
@@ -212,6 +213,7 @@ def serve_phase(chips: int, model_config, params, log: CompileLog,
     import deepspeed_tpu as ds
     from deepspeed_tpu.inference.serving import RequestStatus
     from deepspeed_tpu.models import TransformerLM
+    from deepspeed_tpu.observability import get_overlap_profiler
 
     serving = dict(serving or SERVING, mesh={"data": 1, "model": chips})
     eng = ds.init_inference(
@@ -223,6 +225,11 @@ def serve_phase(chips: int, model_config, params, log: CompileLog,
     rng = np.random.default_rng(SEED + 1)
     prompts = [rng.integers(0, model_config.vocab_size, n).tolist()
                for n, _ in requests]
+    # the engine's own record of every dispatch: which shape of the step
+    # each iteration ran (rows_computed), read after the drain
+    profiler = get_overlap_profiler()
+    profiler.reset()
+    profiler.configure(enabled=True)
     mark = log.mark()
     t0 = time.perf_counter()
     submitted = []
@@ -231,7 +238,10 @@ def serve_phase(chips: int, model_config, params, log: CompileLog,
     for i, (prompt, (_, new)) in enumerate(zip(prompts, requests)):
         submitted.append(srv.submit(prompt, max_new_tokens=new))
         if i == 0:
-            srv.step()                      # warm-up: the one compile
+            srv.step()                      # warm-up: both shapes compile
+            check(srv.decode_builds == 2,
+                  f"serve: the first dispatch built {srv.decode_builds} "
+                  f"of the step's 2 shapes")
             warm_s = time.perf_counter() - t0
             first = log.since(mark)
             steady = log.mark()
@@ -241,6 +251,17 @@ def serve_phase(chips: int, model_config, params, log: CompileLog,
     srv.run()
     run_s = time.perf_counter() - t0 - warm_s
     after = log.since(steady)
+    its, complete = profiler.iterations(t0, time.perf_counter())
+    profiler.configure(enabled=False)
+    profiler.reset()
+    check(complete, "serve: the overlap profiler's ring wrapped")
+    one = its[its["dispatches"] == 1]
+    rows = {"decode_only": srv.num_slots,
+            "mixed": srv.num_slots + srv.chunk_tokens}
+    ran = {shape: int(np.sum(one["rows_computed"] == n))
+           for shape, n in rows.items()}
+    check(all(ran.values()) and sum(ran.values()) == len(one),
+          f"serve: dispatches a shape of the step {ran}, rows {rows}")
 
     for req, (_, new) in zip(submitted, requests):
         check(req.status is RequestStatus.OK,
@@ -248,8 +269,9 @@ def serve_phase(chips: int, model_config, params, log: CompileLog,
         check(len(req.output) == new,
               f"serve: {req.req_id} produced {len(req.output)} of {new} "
               f"tokens")
-    check(srv.decode_builds == 1,
-          f"serve: mixed program built {srv.decode_builds} times")
+    check(srv.decode_builds == 2,
+          f"serve: the step's two shapes were built {srv.decode_builds} "
+          f"times")
     check(after["compiles"] == 0,
           f"serve: {after['compiles']} compile(s) after warm-up")
     check(srv.allocator.num_used == 0,
@@ -259,9 +281,12 @@ def serve_phase(chips: int, model_config, params, log: CompileLog,
     check(pool["min_devices"] == chips,
           f"serve: KV pool on {pool['min_devices']} device(s), expected "
           f"{chips}")
-    text = srv._step_fn.lower(*srv._step_operands((), None)).as_text()
-    kernels = text.count("tpu_custom_call")
-    check(kernels > 0, "serve: no tpu_custom_call in the mixed program")
+    # the decode-only shape holds the decode kernel; the mixed one the
+    # chunk kernel besides
+    kernels = min(
+        srv._step_fn.lower(*srv._idle_operands(chunk_lane)).as_text()
+        .count("tpu_custom_call") for chunk_lane in (False, True))
+    check(kernels > 0, "serve: a shape of the step without tpu_custom_call")
 
     tokens = sum(new for _, new in requests)
     record = {"phase": "serve", **device, "requests": len(requests),
@@ -270,7 +295,8 @@ def serve_phase(chips: int, model_config, params, log: CompileLog,
               "warmup_wall_s": round(warm_s, 2),
               "compiles_after_warmup": after["compiles"],
               "run_s": round(run_s, 2),
-              "decode_builds": builds, "kv_blocks_held_after_drain": held,
+              "decode_builds": builds, "dispatches_by_shape": ran,
+              "kv_blocks_held_after_drain": held,
               "tpu_custom_calls_in_program": kernels,
               "kv_pool_min_devices": pool["min_devices"],
               "kv_pool_partitioned_byte_share":

@@ -13,8 +13,9 @@ Two call shapes over the same math:
     neutral, and ``temperature == 0`` is a plain argmax.
   * :func:`sample_tokens_per_row` — PER-ROW traced arrays (the serving
     path): every decode slot carries its own temperature/top-k/top-p/
-    key as step *inputs*, so one compiled program serves any mix of
-    sampling configs without retracing (``decode_builds == 1``).
+    key as step *inputs*, so the serving step (its two shapes, with and
+    without the chunk lane) serves any mix of sampling configs without
+    retracing (``decode_builds == 2``).
 
 The two paths are bit-identical for the same logits + key: the dynamic
 path's neutral filters (``top_k == 0`` → keep all, ``top_p >= 1`` →
@@ -106,8 +107,9 @@ def sample_tokens_per_row(logits, keys, temperature, top_k, top_p):
     Rows with ``temperature == 0`` take the greedy argmax of the raw
     logits (bit-exact vs the static path).
 
-    Everything is data, nothing is shape: one trace covers every
-    per-slot sampling mix (the ``decode_builds == 1`` contract).  What
+    Everything is data, nothing is shape: one trace a shape of the
+    serving step covers every per-slot sampling mix (the
+    ``decode_builds == 2`` contract).  What
     no row asks for is not computed: the draw and each filter sit
     behind a ``lax.cond`` on a scalar of THIS call's rows (module
     docstring, "What runs when").  Call it plainly: under ``vmap`` a

@@ -1,10 +1,9 @@
 """Continuous-batching serving engine: device half of the subsystem.
 
 Couples the host-side policy (``scheduler.py`` + ``block_allocator.py``)
-to ONE compiled program:
+to ONE step compiled in TWO shapes:
 
-  * **mixed step** (compiled exactly ONCE — the acceptance test pins the
-    build counter): every iteration it takes one decode token for each
+  * **mixed step**: every iteration it takes one decode token for each
     live slot AND up to ``prefill_chunk_tokens`` tokens of a single
     prompt chunk, scattering the chunk's KV into the slot's pool blocks
     and sampling a first token when the chunk completes a prefix
@@ -13,6 +12,15 @@ to ONE compiled program:
     so the program shape is independent of the prompt-length
     distribution — no per-padded-length prefill family, no retrace as
     requests join and leave.
+  * **decode-only shape** of the same step: a dispatch whose plan has
+    no chunk (``next_prefill_chunk`` answered ``None``) sends the chunk
+    vector's head alone, so the program it runs has no chunk lane — no
+    chunk rows through the matmuls, no chunk kernel call, no first-token
+    sample.  The plan chooses, nothing else does.  Both shapes are built
+    by the FIRST dispatch, whichever it needs (the other runs once with
+    every slot inactive), so ``decode_builds`` reads 2 from then on and
+    a server never compiles under traffic — the acceptance test pins
+    the build counter.
   * **prefix caching** (RadixAttention-style): admission takes
     content-hash hits against the paged pool, so shared-prefix and
     preempted-then-resubmitted requests skip straight to their uncached
@@ -78,7 +86,8 @@ from .scheduler import (ContinuousBatchingScheduler, Request,
 # dispatch carries"): a transfer costs the same few hundred microseconds
 # whether it carries 4 bytes or 4 KB, so the per-slot state is ONE
 # ``[num_slots, _SLOT_COLS + max_pages]`` array, the chunk's state ONE
-# ``[_CHUNK_HEAD + chunk_tokens]`` vector and the results ONE
+# ``[_CHUNK_HEAD + chunk_tokens]`` vector (its ``_CHUNK_HEAD`` alone when
+# no chunk rides: the decode-only shape) and the results ONE
 # ``[num_slots, _R_SPEC (+ 2 + spec_k + 1)]`` array.  float32 and uint32
 # lanes travel by their bits (``ndarray.view`` on the host,
 # ``lax.bitcast_convert_type`` in the program): not one bit of a
@@ -207,7 +216,7 @@ class ServingEngine:
     ``temperature``/``top_k``/``top_p``/``seed`` (defaulting to the
     inference config), and every slot's params + PRNG key ride the ONE
     compiled mixed step as data — any mix of sampling configs shares
-    the program (``decode_builds == 1``).  Output token j of a request
+    its two shapes (``decode_builds == 2``).  Output token j of a request
     is always drawn with ``fold_in(request_key, j)``, so a stream is
     reproducible across batch composition, admission order, preemption,
     and mesh shape, and token-identical to ``generate()`` under the
@@ -400,17 +409,16 @@ class ServingEngine:
         self._dpool_k = self._dpool_v = None
         if draft_model is not None:
             self._init_draft(draft_model, draft_params)
-        #: rows the target runs in one dispatch of the mixed program,
-        #: whatever rides: a decode row per slot (and, with a draft, its
-        #: spec_k + 1 verify rows) plus the whole chunk lane
-        self._rows_per_dispatch = (
-            self.num_slots * (1 + (self.spec_k + 1 if draft_model
-                                   is not None else 0))
-            + self.chunk_tokens)
+        #: rows the target runs in a decode-only dispatch, whatever
+        #: rides: a decode row per slot and, with a draft, its
+        #: spec_k + 1 verify rows; the mixed shape adds the chunk lane
+        self._decode_rows_per_dispatch = self.num_slots * (
+            1 + (self.spec_k + 1 if draft_model is not None else 0))
 
-        #: incremented at TRACE time inside the mixed program — the
-        #: "the serving loop compiles exactly one program, whatever the
-        #: prompt-length distribution" acceptance pin
+        #: incremented at TRACE time inside the step — the "the serving
+        #: loop compiles the step's two shapes with its first dispatch
+        #: and nothing after, whatever the prompt-length distribution"
+        #: acceptance pin
         self.decode_builds = 0
         self._step_fn = None
         # -- streaming (frontend/streaming.py): token/terminal events
@@ -740,7 +748,7 @@ class ServingEngine:
         and the allocate hit walk extends into the host store.  The
         gather/scatter helper programs are compiled HERE, off the
         serving clock, by round-tripping the null block — the mixed
-        step stays the one program (``decode_builds`` untouched).
+        step stays the one step (``decode_builds`` untouched).
         ``shared`` injects an already-built (fleet-shared) store
         instead: entry geometry must match, budgets were sized by
         whoever built it."""
@@ -1282,7 +1290,7 @@ class ServingEngine:
         return len(events)
 
     # ------------------------------------------------------------------
-    # the one compiled program
+    # the one compiled step, in its two shapes
     # ------------------------------------------------------------------
     def _build_step(self):
         # the TP view shares weights/rotary/block_transform with the
@@ -1293,22 +1301,34 @@ class ServingEngine:
         spec_on = self._draft_model is not None
         S = self.spec_k + 1 if spec_on else 0
 
-        def sample_first(chunk_logits, ch: _ChunkState):
+        def built():
+            # trace-time side effect: counts program BUILDS, not calls —
+            # one a shape, and continuous batching must never retrace
+            # either
+            self.decode_builds += 1
+            get_registry().counter("dstpu_jit_programs_built_total").inc()
+
+        def chunk_results(chunk_logits, ch: _ChunkState):
+            """``first`` and ``chunk_finite`` of the result array."""
+            if not ch.ids.shape[0]:
+                # the decode-only shape: no chunk, so nothing to sample
+                # from and nothing that could be non-finite — constants
+                # in the columns the host reads only when a chunk rode
+                return jnp.zeros((), jnp.int32), jnp.ones((), jnp.bool_)
             # the chunk's first token: output index ch.out_idx of the
             # prefilling request, drawn with ITS key — identical to the
             # token a decode iteration would have produced, which is
             # what makes preempt-recompute and prefix-hit resumes
             # token-exact
-            return sample_tokens_per_row(
+            first = sample_tokens_per_row(
                 chunk_logits[None],
                 fold_in_keys(ch.key[None], ch.out_idx[None]),
                 ch.temp[None], ch.top_k[None], ch.top_p[None])[0]
+            return first, jnp.all(jnp.isfinite(chunk_logits))
 
         def step(params, scales, pool_k, pool_v, pool_ks, pool_vs,
                  slots, chunk):
-            # trace-time side effect: counts program BUILDS, not calls —
-            # continuous batching must never retrace this
-            self.decode_builds += 1
+            built()
             # slices of an operand are free: the two host arrays come
             # apart first thing
             sl, ch = _SlotState.unpack(slots), _ChunkState.unpack(chunk)
@@ -1326,14 +1346,13 @@ class ServingEngine:
             nxt = sample_tokens_per_row(
                 dec_logits, fold_in_keys(sl.keys, sl.out_idx), sl.temp,
                 sl.top_k, sl.top_p)
-            first = sample_first(chunk_logits, ch)
+            first, chunk_finite = chunk_results(chunk_logits, ch)
             # per-slot finite flags, computed IN-PROGRAM (no extra
-            # dispatch, no retrace — decode_builds stays 1): a slot
+            # dispatch, no retrace — decode_builds stays 2): a slot
             # whose logits go non-finite is quarantined host-side
             # instead of silently streaming garbage or poisoning the
             # prefix cache
             dec_finite = jnp.all(jnp.isfinite(dec_logits), axis=-1)
-            chunk_finite = jnp.all(jnp.isfinite(chunk_logits))
             return (_pack_results(nxt, dec_finite, first, chunk_finite,
                                   counters=cache.get("counters")),
                     cache["k"], cache["v"],
@@ -1341,7 +1360,7 @@ class ServingEngine:
 
         def spec_step(params, scales, dparams, pool_k, pool_v, pool_ks,
                       pool_vs, dpool_k, dpool_v, slots, chunk):
-            self.decode_builds += 1
+            built()
             (tables, lens, dec_tokens, dec_active, spec_active, temp,
              top_k, top_p, keys, out_idx) = _SlotState.unpack(slots)
             ch = _ChunkState.unpack(chunk)
@@ -1354,14 +1373,16 @@ class ServingEngine:
             # program.  The draft pool moves in LOCKSTEP with the
             # target pool: feed 0 also writes every PLAIN-decoding
             # slot's token, and the chunk mirror replays the prefill
-            # chunk — so every committed / prefix-cached block is valid
+            # chunk (it goes with the chunk: the decode-only shape has
+            # none) — so every committed / prefix-cached block is valid
             # in BOTH pools and speculation survives preemption, prefix
             # hits, and slot churn.
             dcache = {"k": dpool_k, "v": dpool_v,
                       "block_tables": tables, "lens": lens}
-            _dl, _cl, dcache = draft._apply_paged_mixed(
-                dparams, dcache, zeros_b, zeros_b, ch.ids, ch.slot,
-                ch.start, ch.len)
+            if ch.ids.shape[0]:
+                _dl, _cl, dcache = draft._apply_paged_mixed(
+                    dparams, dcache, zeros_b, zeros_b, ch.ids, ch.slot,
+                    ch.start, ch.len)
             any_active = ((dec_active > 0)
                           | (spec_active > 0)).astype(jnp.int32)
             cur = dec_tokens
@@ -1405,17 +1426,15 @@ class ServingEngine:
                     temp, top_k, top_p) for i in range(S)], axis=1)
             matches = (spec_tokens[:, 1:] == s[:, :-1]).astype(jnp.int32)
             n_emit = 1 + jnp.sum(jnp.cumprod(matches, axis=1), axis=1)
-            first = sample_first(chunk_logits, ch)
+            first, chunk_finite = chunk_results(chunk_logits, ch)
             dec_finite = jnp.all(jnp.isfinite(dec_logits), axis=-1)
             spec_finite = jnp.all(jnp.isfinite(spec_logits),
                                   axis=(-2, -1))
-            chunk_finite = jnp.all(jnp.isfinite(chunk_logits))
             return (_pack_results(nxt, dec_finite, first, chunk_finite,
                                   n_emit, spec_finite, samples=s),
                     cache["k"], cache["v"], cache.get("k_scale"),
                     cache.get("v_scale"), dcache["k"], dcache["v"])
 
-        get_registry().counter("dstpu_jit_programs_built_total").inc()
         # the quantized pool's scale planes are donated with it (they
         # are rewritten at every scatter, exactly like the values); the
         # draft pools donate alongside the target's
@@ -1430,8 +1449,8 @@ class ServingEngine:
         # column-row tiles); the per-slot operands and results — a row a
         # slot — over 'data'; the chunk vector stays replicated, and the
         # draft (params + pools) replicates over both axes, so every
-        # shard traces the one identical program (decode_builds == 1
-        # regardless of mesh)
+        # shard traces the one identical program a shape
+        # (decode_builds == 2 regardless of mesh)
         d = topo.DATA_AXIS
         pool_sp = self._pool_spec
         pscale_sp = self._pscale_spec if self.kv_bits else P()
@@ -1478,15 +1497,17 @@ class ServingEngine:
     def _step_operands(self, dec: List[Tuple[int, Request]],
                        chunk: Optional[Tuple[int, Request, int, int]],
                        spec: List[Tuple[int, Request]] = ()) -> tuple:
-        """The mixed program's positional operands for one dispatch
-        (see ``_build_step``): weights and the pools, which live on the
+        """The step's positional operands for one dispatch (see
+        ``_build_step``): weights and the pools, which live on the
         device, then the TWO host arrays of the ``_SLOT_COLS`` /
         ``_CHUNK_HEAD`` layout — per-slot state and block tables, and
         the prompt chunk with its sampling state — filled from the
-        scheduler's request records.  Both are built fresh: the program
-        reads them asynchronously on the chip, and the CPU backend may
-        alias a host buffer outright.  No device program is launched
-        here."""
+        scheduler's request records.  ``chunk is None`` IS the choice of
+        shape: the chunk vector is then its head alone, and the program
+        that takes it has no chunk lane.  Both are built fresh: the
+        program reads them asynchronously on the chip, and the CPU
+        backend may alias a host buffer outright.  No device program is
+        launched here."""
         slots = np.zeros((self.num_slots, _SLOT_COLS + self.max_pages),
                          np.int32)
         slots_f, slots_u = slots.view(np.float32), slots.view(np.uint32)
@@ -1506,7 +1527,9 @@ class ServingEngine:
             slots[slot, _DEC_ACTIVE] = 1
         for slot, _req in spec:
             slots[slot, _SPEC_ACTIVE] = 1
-        chunk_vec = np.zeros((_CHUNK_HEAD + self.chunk_tokens,), np.int32)
+        chunk_vec = np.zeros(
+            (_CHUNK_HEAD + (0 if chunk is None else self.chunk_tokens),),
+            np.int32)
         chunk_f, chunk_u = (chunk_vec.view(np.float32),
                             chunk_vec.view(np.uint32))
         chunk_f[_C_TOP_P] = 1.0
@@ -1519,12 +1542,48 @@ class ServingEngine:
             chunk_f[_C_TOP_P] = req.top_p
             chunk_vec[_CHUNK_HEAD:_CHUNK_HEAD + c_len] = \
                 req.prefix[c_start:c_start + c_len]
+        return self._device_operands() + (slots, chunk_vec)
+
+    def _device_operands(self) -> tuple:
+        """The operands that live on the device: weights, then the pools
+        as the last dispatch returned them."""
         pools = (self._pool_k, self._pool_v, self._pool_ks, self._pool_vs)
         if self._draft_model is not None:
             pools = (self._draft_params,) + pools + (self._dpool_k,
                                                      self._dpool_v)
-        return (self._tp_params, self._tp_scales) + pools + (slots,
-                                                             chunk_vec)
+        return (self._tp_params, self._tp_scales) + pools
+
+    def _launch(self, operands: tuple) -> jax.Array:
+        """Enqueue the step on ``operands`` — their chunk vector's
+        length selects the shape — keep the pools it returns (the ones
+        passed in are donated) and hand back the one array the host
+        reads."""
+        result, *pools = self._step_fn(*operands)
+        (self._pool_k, self._pool_v, self._pool_ks,
+         self._pool_vs) = pools[:4]
+        if self._draft_model is not None:
+            self._dpool_k, self._dpool_v = pools[4:]
+        return result
+
+    def _build_both_shapes(self, chunk) -> None:
+        """The first dispatch builds BOTH shapes of the step, so that a
+        server never compiles under traffic however late its first
+        chunk-less (or first chunk-carrying) dispatch comes: the shape
+        ``chunk`` does NOT take runs here, once, with every slot
+        inactive — it writes only null-block rows — and the dispatch
+        that called builds its own by running."""
+        self._step_fn = self._build_step()
+        self._launch(self._idle_operands(chunk_lane=chunk is None))
+
+    def _idle_operands(self, chunk_lane: bool) -> tuple:
+        """The step's operands with nothing riding — every slot
+        inactive, no chunk — in either shape: with the (empty) chunk
+        lane or without it."""
+        return self._device_operands() + (
+            np.zeros((self.num_slots, _SLOT_COLS + self.max_pages),
+                     np.int32),
+            np.zeros((_CHUNK_HEAD + (self.chunk_tokens if chunk_lane
+                                     else 0),), np.int32))
 
     def _sampler_rows(self, slots: np.ndarray,
                       chunk_vec: np.ndarray) -> Dict[str, int]:
@@ -1577,33 +1636,27 @@ class ServingEngine:
         ovl_on = ovl.enabled
         if ovl_on:
             ovl.mark(overlap.OPERANDS)
-        operands = self._step_operands(dec, chunk, spec)
         if self._step_fn is None:
-            self._step_fn = self._build_step()
+            self._build_both_shapes(chunk)
+        operands = self._step_operands(dec, chunk, spec)
+        rows = self._decode_rows_per_dispatch + (
+            0 if chunk is None else self.chunk_tokens)
         if ovl_on:
             ovl.mark(overlap.ENQUEUE)
         t0 = time.perf_counter()
         counted = self.model.PAGED_COUNTERS
         with trace_span("serving/dispatch", decode=len(dec),
-                        chunk_tokens=c_len, spec=len(spec),
+                        chunk_tokens=c_len, spec=len(spec), rows=rows,
                         tp=self.tp_mesh.size, moe=int(bool(counted))):
-            outs = self._step_fn(*operands)
+            result = self._launch(operands)
             if ovl_on:
                 # dispatch returned, nothing materialized yet: from here
                 # to the one read below the host waits on the device
                 ovl.mark(overlap.DEVICE_WAIT)
-            # what the host reads comes first, the pools after it
-            n_pools = 6 if spec_on else 4
-            results, pools = outs[:-n_pools], outs[-n_pools:]
-            for r in results:
-                # queued behind the program now, not requested once the
-                # host has noticed that it ended
-                r.copy_to_host_async()
-            (self._pool_k, self._pool_v, self._pool_ks,
-             self._pool_vs) = pools[:4]
-            if spec_on:
-                self._dpool_k, self._dpool_v = pools[4:]
-            (res,) = [np.asarray(r) for r in results]
+            # queued behind the program now, not requested once the host
+            # has noticed that it ended
+            result.copy_to_host_async()
+            res = np.asarray(result)
         # ITL = dispatch wall time only, captured BEFORE the host-side
         # bookkeeping below (commit hashing, finishes, quarantines) so
         # the histogram stays comparable across PRs
@@ -1611,11 +1664,10 @@ class ServingEngine:
         if ovl_on:
             ovl.mark(overlap.APPLY)
             ovl.count_dispatch(
-                len(dec) + len(spec) * (self.spec_k + 1), c_len,
-                self._rows_per_dispatch,
+                len(dec) + len(spec) * (self.spec_k + 1), c_len, rows,
                 host_arrays_in=sum(isinstance(a, np.ndarray)
                                    for a in operands),
-                host_reads_out=len(results),
+                host_reads_out=1,
                 **self._sampler_rows(*operands[-2:]),
                 # what the program counted: the row's last columns
                 **(dict(zip(counted, map(int, res[0, -len(counted):])))

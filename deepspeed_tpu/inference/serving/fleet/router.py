@@ -237,7 +237,7 @@ class FleetRouter:
                     ) -> "FleetRouter":
         """Build ``serving.fleet.replicas`` independent ``ServingEngine``
         replicas over one inference engine (shared weights, per-replica
-        pools/scheduler/compiled program — ``decode_builds == 1`` each)
+        pools/scheduler/compiled step — ``decode_builds == 2`` each)
         and route over them.  All replicas share one host tier when
         ``serving.host_cache`` is on, and share the same base key, so a
         seedless submit replays exactly wherever it lands.  With

@@ -23,7 +23,8 @@ A second dispatch in one iteration (a chunk remainder) re-enters
 ``plan`` .. ``apply`` and the times add up, so the five always sum to the
 iteration.  The record also carries what the dispatches did:
 ``dispatches``, ``decode_rows``, ``chunk_rows`` (rows that carried a
-token), ``rows_computed`` (rows the program ran whatever rode), and what
+token), ``rows_computed`` (rows of the shape of the step that ran,
+whatever rode: the chunk lane's only when the plan had a chunk), and what
 crossed between host and device: ``host_arrays_in`` (host arrays passed
 to the program: 2 a dispatch) and ``host_reads_out`` (device arrays
 materialised on the host: 1 a dispatch), counted by ``_dispatch`` from
@@ -278,9 +279,9 @@ class OverlapProfiler:
                        host_reads_out: int = 0, sampled_rows: int = 0,
                        filtered_rows: int = 0, **program_counts: int
                        ) -> None:
-        """One dispatch of the mixed program: the rows that carried a
-        token (decoding slots, prompt-chunk tokens), the rows the
-        program ran whatever rode, the host arrays passed to it and the
+        """One dispatch of the serving step: the rows that carried a
+        token (decoding slots, prompt-chunk tokens), the rows of the
+        shape that ran whatever rode, the host arrays passed to it and the
         device arrays read back from it, and the rows that asked the
         sampler for a draw and for a filter (0 sampled: the dispatch
         took the ``argmax``-only side; 0 filtered: it sorted nothing);

@@ -403,7 +403,7 @@ def assert_wave_exact(eng, fleet, wave, reqs, sinks, n=8):
         assert sink.finished
     for r in fleet.replicas:
         if r.state is not ReplicaState.DEAD:
-            assert r.srv.decode_builds == 1
+            assert r.srv.decode_builds == 2
             r.srv.allocator.assert_consistent()
             assert r.srv.allocator.num_used == 0
 

@@ -578,7 +578,7 @@ def test_disagg_fleet_merged_trace_with_failover(tmp_path, obs_reset):
         assert np.array_equal(f.output, ref), f.req_id
         assert sink.tokens == list(ref)
     for r in fleet.replicas:
-        assert r.srv.decode_builds <= 1
+        assert r.srv.decode_builds in (0, 2)
 
     # ---- ONE merged Perfetto trace, single trace id, flow arrows ----
     outdir = os.environ.get("DSTPU_FLEET_OBS_DIR") or str(tmp_path)

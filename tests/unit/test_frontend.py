@@ -7,7 +7,7 @@ Coverage model:
     top-k / top-p / seed, mixed in ONE batch) token-identical to the
     same prompt through seeded ``generate()`` — the shared
     ``inference/sampling.py`` fold_in schedule — with
-    ``decode_builds == 1`` across every sampling mix (params are step
+    ``decode_builds == 2`` across every sampling mix (params are step
     inputs, never shapes);
   * token streaming: per-token events at iteration boundaries carrying
     lifecycle status, a final tokenless terminal event for requests
@@ -19,7 +19,7 @@ Coverage model:
     TOKEN-EXACT vs the non-speculative engine under the same keys
     (exactness by construction: target samples at every draft position
     with that position's own fold_in key), acceptance counters move,
-    and the step still traces once;
+    and the step still traces once a shape;
   * weighted-fair multi-tenancy: virtual-token-counter unit math
     (charge / idle-lift / share), the admission policy's priority +
     at-risk + VTC ordering, the starvation bound under a bursty hog
@@ -76,7 +76,7 @@ def seeded_generate(eng, prompt, n, seed, **samp):
 @pytest.fixture(scope="module")
 def shared():
     """One engine + frontend shared by the single-device tests; the
-    cumulative ``decode_builds == 1`` assertions across them prove that
+    cumulative ``decode_builds == 2`` assertions across them prove that
     no sampling mix, stream, or tenant behavior ever retraces."""
     eng = build_engine()
     srv = eng.serving_engine()
@@ -107,7 +107,7 @@ def test_greedy_stream_matches_generate(shared):
         assert c.finished
         assert c.events[-1].status is RequestStatus.OK
         assert [e.index for e in c.events] == list(range(8))
-    assert srv.decode_builds == 1
+    assert srv.decode_builds == 2
 
 
 def test_mixed_seeded_sampling_matches_generate_one_trace(shared):
@@ -125,7 +125,7 @@ def test_mixed_seeded_sampling_matches_generate_one_trace(shared):
     for i, (p, r) in enumerate(zip(PROMPTS, reqs)):
         gen = seeded_generate(eng, p, 8, 100 + i, **samp[i])
         assert r.output == list(gen), (i, r.output, list(gen))
-    assert srv.decode_builds == 1, "sampling mix retraced the step"
+    assert srv.decode_builds == 2, "sampling mix retraced the step"
 
 
 def test_terminal_events_and_callback_isolation(shared):
@@ -164,7 +164,7 @@ def test_terminal_events_and_callback_isolation(shared):
     gen = np.asarray(eng.generate(jnp.asarray([PROMPTS[1]]),
                                   max_new_tokens=8, temperature=0.0))[0]
     np.testing.assert_array_equal(np.asarray(noisy.output), gen)
-    assert srv.decode_builds == 1
+    assert srv.decode_builds == 2
 
 
 # ---------------------------------------------------------------------------
@@ -244,7 +244,7 @@ def test_fair_queue_starvation_bound(shared):
     for r in prem:
         assert first_tok[id(r)] < worst_hog, \
             "premium starved behind the hog's backlog"
-    assert srv.decode_builds == 1
+    assert srv.decode_builds == 2
 
 
 def test_shed_policy_victimizes_queue_hog(shared):
@@ -274,7 +274,7 @@ def test_shed_policy_victimizes_queue_hog(shared):
     assert prem.status is RequestStatus.OK
     assert all(r.status is RequestStatus.OK
                for r in running + waiting_before[:1])
-    assert srv.decode_builds == 1
+    assert srv.decode_builds == 2
 
 
 # ---------------------------------------------------------------------------
@@ -459,7 +459,7 @@ def test_spec_streams_token_exact_vs_plain():
                 for i, p in enumerate(PROMPTS)]
         srv.run()
         assert all(r.status is RequestStatus.OK for r in reqs)
-        assert srv.decode_builds == 1
+        assert srv.decode_builds == 2
         outs.append([r.output for r in reqs])
     assert outs[0] == outs[1], "speculative lane changed the tokens"
     assert spec_srv.spec_counts["proposed"] > 0
@@ -479,7 +479,7 @@ def _mesh_run(mesh, params, draft=None, dparams=None):
     reqs = [srv.submit(p, max_new_tokens=6, temperature=0.8, top_k=16,
                        seed=200 + i) for i, p in enumerate(PROMPTS)]
     srv.run()
-    assert srv.decode_builds == 1, (mesh, srv.decode_builds)
+    assert srv.decode_builds == 2, (mesh, srv.decode_builds)
     assert all(r.status is RequestStatus.OK for r in reqs)
     return eng.params, [r.output for r in reqs]
 
@@ -497,7 +497,7 @@ def test_mesh_shape_determinism_sampled():
 @pytest.mark.slow
 def test_mesh_shape_determinism_sampled_spec():
     """Full-feature acceptance: sampling AND the speculative lane on,
-    (1,1) vs (2,2) token-identical, one compiled program each."""
+    (1,1) vs (2,2) token-identical, the step built once a shape on each."""
     draft, dparams = make_draft()
     params, single = _mesh_run((1, 1), None, draft, dparams)
     _, sharded = _mesh_run((2, 2), params, draft, dparams)

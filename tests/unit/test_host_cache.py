@@ -20,7 +20,7 @@ Covers the spill/promote hierarchy bottom-up:
     int8 at-rest, through the NVMe tier, and under injected
     ``serving.spill`` / ``serving.promote`` faults (transient faults
     retry; fatal faults degrade to eviction / recompute — never a
-    wrong token), with ``decode_builds == 1`` throughout.
+    wrong token), with ``decode_builds == 2`` throughout.
 """
 import jax
 import jax.numpy as jnp
@@ -421,7 +421,7 @@ def run_spill_promote_cycle(eng, srv, seed=0):
     srv.run()
     np.testing.assert_array_equal(np.asarray(r2.output), want)
     srv.allocator.assert_consistent()
-    assert srv.decode_builds == 1, \
+    assert srv.decode_builds == 2, \
         f"tiering must not retrace: {srv.decode_builds} builds"
     return r2
 

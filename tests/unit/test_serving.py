@@ -10,8 +10,8 @@ Coverage model:
     LIFO recompute preemption, drain;
   * the acceptance integration test: >= 8 concurrent requests with
     staggered arrivals whose token streams are identical to sequential
-    ``generate()`` per request, while the compiled decode step traces
-    exactly once (build counter pinned);
+    ``generate()`` per request, while the compiled step's two shapes
+    trace once each (build counter pinned at 2);
   * robustness (ISSUE 6, docs/serving.md "Failure handling &
     overload"): terminal statuses + cancel/deadline/shed at scheduler
     and engine level, the preemption-thrash pin-or-fail guard, NaN
@@ -1118,7 +1118,7 @@ class TestServingEngine:
     def test_integration_staggered_8_requests_single_trace(self):
         """The acceptance pin: 8 concurrent requests with staggered
         arrivals, every token stream identical to sequential
-        ``generate()``, the compiled decode step traced exactly once,
+        ``generate()``, the compiled step traced once a shape (2),
         and the pool leak-free after drain."""
         eng, srv = serving_engine()
         rs = np.random.RandomState(7)
@@ -1139,7 +1139,7 @@ class TestServingEngine:
             np.testing.assert_array_equal(np.asarray(r.output), want,
                                           err_msg=f"prompt {p}")
         # continuous batching must never retrace the decode program
-        assert srv.decode_builds == 1
+        assert srv.decode_builds == 2
         srv.allocator.assert_consistent()
         assert srv.allocator.num_used == 0
 
@@ -1147,7 +1147,7 @@ class TestServingEngine:
     def test_preemption_preserves_streams(self):
         """A pool too small for the offered load forces recompute
         preemption; streams still match sequential generate and the
-        decode program still traces once."""
+        step still traces once a shape."""
         cfg = gpt2_config("125m", num_layers=2, d_model=32, num_heads=4,
                           vocab_size=64, max_seq_len=64,
                           dtype=jnp.float32)
@@ -1165,7 +1165,7 @@ class TestServingEngine:
                 eng.generate(np.asarray(p, np.int32)[None],
                              max_new_tokens=10, temperature=0.0))[0]
             np.testing.assert_array_equal(np.asarray(r.output), want)
-        assert srv.decode_builds == 1
+        assert srv.decode_builds == 2
         assert srv.allocator.num_used == 0
 
     def test_eos_retires_slot_early(self):
@@ -1273,7 +1273,7 @@ class TestServingEngine:
                              max_new_tokens=6, temperature=0.0))[0]
             np.testing.assert_array_equal(np.asarray(r.output), want,
                                           err_msg=f"prompt {p}")
-        assert srv.decode_builds == 1
+        assert srv.decode_builds == 2
 
     @pytest.mark.slow
     def test_warm_prefix_hits_and_streams_match(self):
@@ -1305,7 +1305,7 @@ class TestServingEngine:
         """The quantized-KV acceptance pin (ISSUE 8): with
         ``kv_cache_bits=8`` the toy model's greedy streams are
         EXACT-MATCH against sequential bf16-cache ``generate()``, the
-        mixed program still traces once, and a warm shared-prefix
+        step still traces once a shape, and a warm shared-prefix
         resubmission reuses the quantized blocks — their scales ride
         the same block ids, so the hit stream is exact too."""
         eng, srv = serving_engine(serving={"kv_cache_bits": 8})
@@ -1328,7 +1328,7 @@ class TestServingEngine:
                              max_new_tokens=6, temperature=0.0))[0]
             np.testing.assert_array_equal(np.asarray(r.output), want,
                                           err_msg=f"prompt {p}")
-        assert srv.decode_builds == 1
+        assert srv.decode_builds == 2
         srv.allocator.assert_consistent()
         assert srv.allocator.num_used == 0
 
@@ -1347,7 +1347,7 @@ class TestServingEngine:
         done = srv.run(max_steps=200)
         assert len(done) == 2
         assert all(len(r.output) == 5 for r in reqs)
-        assert srv.decode_builds == 1
+        assert srv.decode_builds == 2
         assert srv.allocator.num_used == 0
 
     @pytest.mark.slow
@@ -1378,7 +1378,7 @@ class TestServingEngine:
                 eng.generate(np.asarray(p, np.int32)[None],
                              max_new_tokens=12, temperature=0.0))[0]
             np.testing.assert_array_equal(np.asarray(r.output), want)
-        assert srv.decode_builds == 1
+        assert srv.decode_builds == 2
         assert srv.allocator.num_used == 0
 
     @pytest.mark.slow
@@ -1413,7 +1413,7 @@ class TestServingEngine:
                              max_new_tokens=8, temperature=0.0))[0]
             np.testing.assert_array_equal(np.asarray(r.output), want,
                                           err_msg=f"prompt {p}")
-        assert srv.decode_builds == 1
+        assert srv.decode_builds == 2
         srv.allocator.assert_consistent()
         assert srv.allocator.num_used == 0
 
@@ -1673,7 +1673,7 @@ class TestLifecycleEngine:
         assert r_ok.status is RequestStatus.OK
         np.testing.assert_array_equal(np.asarray(r_ok.output),
                                       _generate(eng, p_ok, 8))
-        assert srv.decode_builds == 1
+        assert srv.decode_builds == 2
         assert srv.allocator.num_used == 0
         assert srv.lifecycle_counts["cancelled"] == 1
         assert srv.lifecycle_counts["timed_out"] == 1
@@ -1686,7 +1686,7 @@ class TestLifecycleEngine:
         assert r_again.status is RequestStatus.OK
         np.testing.assert_array_equal(np.asarray(r_again.output),
                                       _generate(eng, p_cancel, 8))
-        assert srv.decode_builds == 1
+        assert srv.decode_builds == 2
 
     def test_shed_on_overload(self):
         """Bounded backpressure: beyond max_queue_depth, submit()
@@ -1736,7 +1736,7 @@ class TestLifecycleEngine:
             np.testing.assert_array_equal(np.asarray(r.output),
                                           _generate(eng, p, 8),
                                           err_msg=f"prompt {p}")
-        assert srv.decode_builds == 1
+        assert srv.decode_builds == 2
         srv.allocator.assert_consistent()
         assert srv.allocator.num_used == 0
         # discarded means discarded: resubmitting the poisoned prompt
@@ -1795,7 +1795,7 @@ class TestLifecycleEngine:
             assert r.status is RequestStatus.OK, r.error
             np.testing.assert_array_equal(np.asarray(r.output),
                                           _generate(eng, p, 16))
-        assert srv.decode_builds == 1
+        assert srv.decode_builds == 2
         assert srv.allocator.num_used == 0
 
     def test_run_default_bound_is_finite_and_loud(self):
@@ -1860,7 +1860,7 @@ class TestFaultSites:
             np.testing.assert_array_equal(np.asarray(r.output),
                                           _generate(eng, p, 8),
                                           err_msg=f"prompt {p}")
-        assert srv.decode_builds == 1
+        assert srv.decode_builds == 2
         srv.allocator.assert_consistent()
         assert srv.allocator.num_used == 0
 
@@ -1918,7 +1918,7 @@ def packed_cfg(layers):
 @pytest.fixture(scope="module", params=list(PACKED_VARIANTS))
 def packed(request):
     """One engine a variant, shared by the tests below: whatever they
-    send, the mixed program is built once."""
+    send, the step's two shapes are built once."""
     eng = inference_engine(PACKED_VARIANTS[request.param], packed_cfg(2))
     if request.param == "draft":
         draft = TransformerLM(packed_cfg(1))
@@ -1928,7 +1928,7 @@ def packed(request):
     else:
         srv = eng.serving_engine()
     yield eng, srv
-    assert srv.decode_builds == 1
+    assert srv.decode_builds == 2
     assert srv.allocator.num_used == 0
 
 
@@ -1965,6 +1965,120 @@ def test_packed_lanes_are_bit_exact(packed):
     # the mix is not greedy in disguise: a sampled stream left the argmax
     assert any(r.output != sampled_generate(eng, p, 6, 0.0)
                for (p, _), r in zip(PACKED_MIX, batch))
+
+
+# ---------------------------------------------------------------------------
+# a dispatch with no chunk to carry runs a program with no chunk lane
+# (ISSUE 35): two shapes of one step, chosen from the plan
+# ---------------------------------------------------------------------------
+def registry_value(name):
+    from deepspeed_tpu.observability import get_registry
+    return get_registry().counter(name).value
+
+
+def rows_of_shape(srv, chunk_lane):
+    """Rows the step computes in one dispatch of either shape."""
+    spec = srv.spec_k + 1 if srv._draft_model is not None else 0
+    return srv.num_slots * (1 + spec) + (srv.chunk_tokens if chunk_lane
+                                         else 0)
+
+
+# prompts of 1, chunk, chunk + 1 and 3 x chunk tokens (the fixture's chunk
+# is 16), greedy beside sampled, each arriving while the others decode
+TWO_SHAPE_MIX = [
+    ([7], dict(temperature=0.0)),
+    (list(range(1, 17)), dict(temperature=0.8, seed=11)),
+    (list(range(30, 47)), dict(temperature=0.0)),
+    ([(5 * i + 3) % 64 for i in range(48)],
+     dict(temperature=0.9, top_k=20, top_p=0.85, seed=2 ** 31 + 9)),
+]
+
+
+def test_tokens_alternate_between_the_two_shapes(packed):
+    """Requests whose tokens come alternately from the two programs —
+    a first token from a mixed dispatch, the next from a decode-only
+    one, then beside a later arrival's chunk again — are the tokens of
+    ``generate()`` and of a one-at-a-time run, and every dispatch's
+    ``rows_computed`` is the rows of the program its plan chose."""
+    from deepspeed_tpu.inference.serving.engine import _CHUNK_HEAD
+    from deepspeed_tpu.observability import get_overlap_profiler
+    eng, srv = packed
+    n = 7
+    plans = []
+    real = srv._step_operands
+
+    def watch(dec, chunk, spec=()):
+        plans.append(chunk is not None)
+        operands = real(dec, chunk, spec)
+        # the plan IS the shape: the chunk vector is its head alone
+        assert (operands[-1].shape[0] > _CHUNK_HEAD) == (chunk is not None)
+        return operands
+
+    prof = get_overlap_profiler()
+    prof.reset()
+    prof.configure(enabled=True)
+    srv._step_operands = watch
+    try:
+        t0 = time.perf_counter()
+        batch = []
+        for p, samp in TWO_SHAPE_MIX:
+            batch.append(srv.submit(p, max_new_tokens=n, **samp))
+            srv.step()               # prefill (or its first chunk) ...
+            srv.step()               # ... then at least one plain decode
+        srv.run()
+        its, complete = prof.iterations(t0, time.perf_counter())
+    finally:
+        del srv._step_operands
+        prof.configure(enabled=False)
+        prof.reset()
+    assert complete and len(plans) == its["dispatches"].sum()
+    # both programs ran, and changed places more than once
+    assert sum(a != b for a, b in zip(plans, plans[1:])) >= 4
+    ends = np.cumsum(its["dispatches"])
+    assert [sum(rows_of_shape(srv, lane) for lane in plans[a:b])
+            for a, b in zip(ends - its["dispatches"], ends)] \
+        == list(its["rows_computed"])
+    alone = []
+    for p, samp in TWO_SHAPE_MIX:
+        alone.append(srv.submit(p, max_new_tokens=n, **samp))
+        srv.run()
+    for (p, samp), together, single in zip(TWO_SHAPE_MIX, batch, alone):
+        assert together.status is single.status is RequestStatus.OK
+        assert together.output == single.output, (p, samp)
+        assert together.output == sampled_generate(eng, p, n, **samp), \
+            (p, samp)
+    assert srv.decode_builds == 2
+
+
+@pytest.mark.parametrize("first", ["chunk", "no_chunk"])
+def test_first_dispatch_builds_both_shapes_whichever_it_takes(first):
+    """``decode_builds`` reads 2 after the first dispatch, whether its
+    plan had a chunk (the other shape is run once, every slot inactive)
+    or none, and 2 after the traffic that follows: the shape a server
+    meets late is never compiled under traffic."""
+    eng, srv = serving_engine()
+    reg_before = registry_value("dstpu_jit_programs_built_total")
+    assert srv.decode_builds == 0
+    if first == "no_chunk":
+        assert srv._dispatch([], None) == 0          # nothing rode
+    else:
+        srv.submit([3, 1, 4, 1, 5], max_new_tokens=1)
+        srv.step()
+    assert srv.decode_builds == 2
+    assert registry_value("dstpu_jit_programs_built_total") \
+        == reg_before + 2
+    # the idle run of the other shape wrote null-block rows only
+    rs = np.random.RandomState(5)
+    prompts = [rs.randint(0, 64, (k,)).tolist() for k in (1, 16, 17, 40)]
+    reqs = [srv.submit(p, max_new_tokens=6) for p in prompts]
+    srv.run()
+    for p, r in zip(prompts, reqs):
+        np.testing.assert_array_equal(np.asarray(r.output),
+                                      _generate(eng, p, 6))
+    assert srv.decode_builds == 2
+    assert registry_value("dstpu_jit_programs_built_total") \
+        == reg_before + 2
+    assert srv.allocator.num_used == 0
 
 
 # ---------------------------------------------------------------------------
@@ -2073,7 +2187,7 @@ def test_conditional_sampler_is_byte_equal_to_the_unconditional(
 def test_batch_moves_between_greedy_and_mixed_on_one_program(packed):
     """All-greedy dispatches, then greedy beside sampled and filtered
     rows, then all-greedy again: each side of the sampler's conditionals
-    in one run of ONE program (the fixture holds ``decode_builds`` to 1),
+    in one run of ONE step (the fixture holds ``decode_builds`` to 2),
     every stream the tokens of ``generate()`` under its key, and the
     overlap record's ``sampled_rows`` / ``filtered_rows`` the hand count
     of every dispatch: decode rows, a chunk's row, speculative rows."""
@@ -2224,6 +2338,9 @@ def test_second_dispatch_and_quarantine_through_packed_results(packed):
         srv.step()
     victim = reqs[1]
     assert victim.state is RequestState.RUNNING
+    # every prompt is prefilled: the poison is met by a DECODE-ONLY
+    # dispatch, whose chunk columns are constants
+    assert srv.scheduler.next_prefill_chunk(srv.chunk_tokens) is None
     block = srv.allocator.block_table(victim.req_id)[0]
     name = "_pool_ks" if srv.kv_bits else "_pool_k"
     pool = getattr(srv, name)
@@ -2233,7 +2350,7 @@ def test_second_dispatch_and_quarantine_through_packed_results(packed):
                                       pool.sharding))
     srv.run()
     assert victim.status is RequestStatus.FAILED
-    assert "quarantined" in victim.error
+    assert "quarantined" in victim.error and "decode" in victim.error
     for p, r in zip(prompts, reqs):
         if r is not victim:
             assert r.status is RequestStatus.OK

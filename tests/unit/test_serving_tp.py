@@ -5,9 +5,9 @@ on the conftest 8-device virtual CPU mesh:
 
   * the hard pin: on a (data=2, model=2) mesh, greedy serving streams
     are TOKEN-IDENTICAL to single-device ``generate()`` — bf16 AND int8
-    KV — while the mixed decode+prefill step still compiles to exactly
-    ONE program (``decode_builds == 1``) and the measured per-chip KV
-    pool bytes are 1/model of the unsharded pool, pinned against
+    KV — while the mixed decode+prefill step still compiles its two
+    shapes once each (``decode_builds == 2``) and the measured per-chip
+    KV pool bytes are 1/model of the unsharded pool, pinned against
     ``kv_block_bytes(model_shards=...)``;
   * the mesh-shape matrix: model ∈ {1, 2, 4} x kv_cache_bits ∈ {0, 8},
     every shape streaming exact with one trace, including warm
@@ -109,8 +109,8 @@ def _run_parity(mesh, kv_bits, prompts=None, max_new=8,
     for p, r, w in zip(prompts, reqs, want):
         np.testing.assert_array_equal(np.asarray(r.output), w,
                                       err_msg=f"mesh={mesh} prompt={p}")
-    assert srv.decode_builds == 1, \
-        f"mesh {mesh} retraced the mixed program ({srv.decode_builds})"
+    assert srv.decode_builds == 2, \
+        f"mesh {mesh} retraced the step ({srv.decode_builds} builds)"
     srv.allocator.assert_consistent()
     assert srv.allocator.num_used == 0
     return srv
@@ -135,6 +135,33 @@ class TestTpAcceptance:
         full = kv_block_bytes(8, cfg.kv_heads, cfg.hdim, kv_bits,
                               cache_itemsize=4)
         assert 2 * srv.kv_pool_bytes == full * 48 * cfg.num_layers
+
+    def test_two_shapes_on_the_mesh_built_by_a_chunkless_first_dispatch(
+            self):
+        """(data=2, model=2): the first dispatch has no chunk, so it
+        builds the decode-only shape by running and the mixed one by an
+        idle run over the sharded pools; then prompts of 1, chunk,
+        chunk + 1 and 3 x chunk tokens arrive while the others decode —
+        tokens alternately from the two programs, exact against the
+        single-device ``generate()``, and nothing is built again."""
+        rs = np.random.RandomState(17)
+        prompts = [rs.randint(0, 64, (n,)).tolist() for n in (1, 16, 17, 48)]
+        want = ref_streams(prompts, 6)
+        srv = build_engine(mesh={"data": 2, "model": 2}).serving_engine()
+        assert srv._dispatch([], None) == 0
+        assert srv.decode_builds == 2
+        reqs = []
+        for p in prompts:
+            reqs.append(srv.submit(p, max_new_tokens=6))
+            srv.step()
+            srv.step()
+        srv.run(max_steps=200)
+        for p, r, w in zip(prompts, reqs, want):
+            np.testing.assert_array_equal(np.asarray(r.output), w,
+                                          err_msg=f"prompt of {len(p)}")
+        assert srv.decode_builds == 2
+        srv.allocator.assert_consistent()
+        assert srv.allocator.num_used == 0
 
     def test_mesh_gauges_and_psum_accounting(self):
         from deepspeed_tpu.observability import get_registry
@@ -190,13 +217,13 @@ class TestTpMeshMatrix:
         assert r2.cache_hit_tokens == 16               # warm: 2 blocks
         np.testing.assert_array_equal(np.asarray(r1.output), want)
         np.testing.assert_array_equal(np.asarray(r2.output), want)
-        assert srv.decode_builds == 1
+        assert srv.decode_builds == 2
 
     @pytest.mark.slow
     def test_forced_preemption_streams_exact_on_tp_mesh(self):
         """A pool too small for the offered load forces recompute
         preemption while slots are data-sharded; streams still match
-        sequential generate and the program still traces once."""
+        sequential generate and the step still traces once a shape."""
         # 8 usable blocks x 8 tokens; four requests admit at 7 prompt
         # blocks but need 13 once grown to prompt+12 tokens -> growth
         # must evict and recompute mid-decode
@@ -231,7 +258,7 @@ class TestTpMeshMatrix:
         srv.run(max_steps=200)
         for r, w in zip(reqs, want):
             np.testing.assert_array_equal(np.asarray(r.output), w)
-        assert srv.decode_builds == 1
+        assert srv.decode_builds == 2
 
 
 class TestShardedCapacityPlanning:

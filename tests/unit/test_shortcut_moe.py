@@ -123,7 +123,7 @@ def test_chunked_prefill_then_paged_decode_match_the_reference_logits():
         for j, tok in enumerate(r.output):
             at = lg[len(r.prompt) + j - 1]
             assert at.max() - at[tok] < 1e-4
-    assert srv.decode_builds == 1 and srv.allocator.num_used == 0
+    assert srv.decode_builds == 2 and srv.allocator.num_used == 0
     # the pool's bytes are the planning mirror's: 2 L sublayers x blocks
     from deepspeed_tpu.inference.serving import latent_block_bytes
     assert srv.kv_pool_bytes == 2 * 2 * 64 * latent_block_bytes(

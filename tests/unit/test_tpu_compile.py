@@ -207,6 +207,14 @@ def test_paged_prefill_compiles_at_the_cells_shapes(v5e_devices, cell):
     assert "tpu_custom_call" in compile_for_tpu(fn, *args)
 
 
+def custom_calls(hlo_text: str) -> int:
+    """Mosaic kernel calls in optimized HLO: the instructions, not the
+    mentions of their target in metadata or backend configs."""
+    import re
+    return len(re.findall(
+        r' custom-call\([^\n]*custom_call_target="tpu_custom_call"', hlo_text))
+
+
 def kernel_eqns(fn, *args):
     """Every equation of the Pallas kernel ``fn`` calls, loops and
     branches included."""
@@ -280,11 +288,12 @@ def test_paged_rejects_shapes_the_tpu_cannot_tile():
                                interpret=False)
 
 
+@pytest.mark.parametrize("chunk", [256, 0], ids=["mixed", "decode_only"])
 @pytest.mark.parametrize("d", [64, 128])
 @pytest.mark.parametrize("kv_bits,block,nb", [(0, 16, 1920),
                                               (8, 128, 4800)])
 def test_mixed_step_keeps_the_pool_in_place(v5e_devices, compiled_kernels,
-                                            d, kv_bits, block, nb):
+                                            d, kv_bits, block, nb, chunk):
     """``_apply_paged_mixed`` with donated pools at 16 heads of head
     dim 128 (the Pythia row) and 64 (the gpt2-medium row): the layer
     scan carries each pool as one buffer, so the compiled step holds no
@@ -295,9 +304,12 @@ def test_mixed_step_keeps_the_pool_in_place(v5e_devices, compiled_kernels,
     pool and 1,511 MB of temporaries at ``[4, 1920, 16, 2048]`` bf16.
     The pools are far too large for any on-chip placement; the int8
     case takes 4,800 blocks so that its scale planes (157 MB) are too —
-    a plane that fits the compiler prefetches into VMEM whole."""
+    a plane that fits the compiler prefetches into VMEM whole.  The
+    decode-only shape (``chunk`` 0: what the engine runs when its plan
+    has no chunk) keeps the pool in place as well, and holds ONE kernel
+    call a layer: the chunk kernel's is gone."""
     import re
-    layers, heads, slots, chunk = 4, 16, 24, 256
+    layers, heads, slots = 4, 16, 24
     sds = one_chip(v5e_devices)
     model = TransformerLM(gpt2_config(
         "125m", num_layers=layers, d_model=heads * d, num_heads=heads,
@@ -319,7 +331,9 @@ def test_mixed_step_keeps_the_pool_in_place(v5e_devices, compiled_kernels,
         sds((chunk,), jnp.int32), scalar, scalar, scalar).lower(
             lowering_platforms=("tpu",)).compile()
     text = compiled.as_text()
-    assert text.count("tpu_custom_call") >= 2     # decode + chunk kernels
+    # decode + chunk kernels in the scanned layer; the decode kernel alone
+    # in the decode-only shape
+    assert custom_calls(text) == (2 if chunk else 1)
     # pool-shaped: a whole pool, one layer's slice of it, or either as
     # flat rows — in the [layers, nb, ..] or the carried [layers * nb, ..]
     # view
@@ -400,10 +414,11 @@ def _sandwich_case():
         max_seq_len=4096, experts_held=(0, 8)), 8, 128, 256, 7
 
 
+@pytest.mark.parametrize("chunk", [512, 0], ids=["mixed", "decode_only"])
 @pytest.mark.parametrize("case", [_shortcut_case, _sandwich_case],
                          ids=["shortcut", "sandwich"])
 def test_latent_mixed_step_keeps_pool_and_experts_in_place(
-        v5e_devices, compiled_kernels, case):
+        v5e_devices, compiled_kernels, case, chunk):
     """A latent block's mixed step at its cell's widths — the shortcut
     block (2 layers of two attention sublayers, 4 held experts) and the
     sandwich block (one dense layer before two expert layers: two kinds
@@ -413,11 +428,13 @@ def test_latent_mixed_step_keeps_pool_and_experts_in_place(
     latent pool is the scans' carry and the expert stack is
     read where it lies, so the compiled step holds no pool-shaped and no
     expert-stack-shaped copy, slice or second buffer — either would be
-    more than a GB moved every step at the cell's depth."""
+    more than a GB moved every step at the cell's depth.  The same for
+    the decode-only shape (``chunk`` 0), which calls the latent kernel
+    once an attention sublayer: the chunk lane's calls are gone."""
     import re
     from deepspeed_tpu.models import build_model
     config, held, slots, pages, kernels = case()
-    nb, chunk = 4096, 512
+    nb = 4096
     sds = one_chip(v5e_devices)
     model = build_model(config)
     sublayers = model.ATTN_SUBLAYERS * config.num_layers
@@ -441,8 +458,11 @@ def test_latent_mixed_step_keeps_pool_and_experts_in_place(
             lowering_platforms=("tpu",)).compile()
     text = compiled.as_text()
     # the scanned layer's attention sublayers x (decode + chunk) and its
-    # three grouped products; a leading layer's decode + chunk
-    assert text.count("tpu_custom_call") >= kernels
+    # three grouped products; a leading layer's decode + chunk.  Without
+    # a chunk lane each attention sublayer is one call, not two
+    leading = config.scan_length < config.num_layers
+    attn = model.ATTN_SUBLAYERS + (1 if leading else 0)
+    assert custom_calls(text) == (kernels if chunk else kernels - attn)
     shaped = set()
     for a in pools.values():
         for lead in ((sublayers, nb), (nb,), (sublayers * nb,)):
@@ -555,12 +575,14 @@ def test_engine_train_step_lowers_for_tpu(compiled_kernels):
     assert "tpu_custom_call" in text
 
 
+@pytest.mark.parametrize("chunk_lane", [True, False],
+                         ids=["mixed", "decode_only"])
 @pytest.mark.parametrize("mesh", [{"data": 1, "model": 1},
                                   {"data": 2, "model": 2}])
-def test_serving_step_lowers_for_tpu(compiled_kernels, mesh):
+def test_serving_step_lowers_for_tpu(compiled_kernels, mesh, chunk_lane):
     """The engine mesh spans all 8 devices whatever ``serving.mesh``
-    says; the mixed step must still lower (it runs under shard_map over
-    its own submesh, 1x1 included)."""
+    says; the step must still lower in both its shapes (it runs under
+    shard_map over its own submesh, 1x1 included)."""
     import deepspeed_tpu as ds
     model = TransformerLM(gpt2_config(
         "125m", num_layers=2, d_model=256, num_heads=8, vocab_size=512,
@@ -571,8 +593,11 @@ def test_serving_step_lowers_for_tpu(compiled_kernels, mesh):
                     "num_kv_blocks": 32, "max_batch_slots": 4,
                     "prefill_chunk_tokens": 32, "mesh": mesh}})
     srv = eng.serving_engine()
-    text = lower_for_tpu(srv._build_step(), *srv._step_operands((), None))
-    assert "tpu_custom_call" in text
+    text = lower_for_tpu(srv._build_step(),
+                         *srv._idle_operands(chunk_lane))
+    # the decode kernel, and the chunk kernel where there is a chunk lane
+    assert text.count("stablehlo.custom_call @tpu_custom_call") \
+        == (2 if chunk_lane else 1)
 
 
 def hlo_computations(text):
@@ -592,10 +617,14 @@ def hlo_computations(text):
     return comps, entry
 
 
+@pytest.mark.parametrize("chunk_lane", [True, False],
+                         ids=["mixed", "decode_only"])
 def test_serving_step_sorts_only_inside_a_conditional(v5e_devices,
-                                                      compiled_kernels):
+                                                      compiled_kernels,
+                                                      chunk_lane):
     """The 1x1 serving step COMPILED for a v5e chip at the Pythia cells'
-    sampler shape (24 slots and the chunk's row over 50,304): the
+    sampler shape (24 slots and the chunk's row over 50,304; the
+    decode-only shape has the slots' call alone): the
     sampler's stages are still ``conditional`` instructions, and every
     ``sort`` lies in a computation that is reached only through a
     conditional's branch.  A ``vmap`` over the sampler (``cond`` ->
@@ -613,7 +642,7 @@ def test_serving_step_sorts_only_inside_a_conditional(v5e_devices,
                     "prefill_chunk_tokens": 32,
                     "mesh": {"data": 1, "model": 1}}})
     srv = eng.serving_engine()
-    operands = srv._step_operands((), None)
+    operands = srv._idle_operands(chunk_lane)
     # the engine's own program, built over one described chip instead of
     # the CPU device the engine found
     srv.tp_mesh = Mesh(
@@ -627,9 +656,12 @@ def test_serving_step_sorts_only_inside_a_conditional(v5e_devices,
     assert "tpu_custom_call" in text
     comps, entry = hlo_computations(text)
     # three stages a sampler call (the draw, top-k, top-p: a sort in each
-    # filter), two calls a dispatch (decode rows, the chunk's row)
-    assert text.count(" conditional(") >= 6
-    assert text.count(" sort(") >= 4
+    # filter), two calls a dispatch (decode rows, the chunk's row) or,
+    # with no chunk lane, one
+    calls = 2 if chunk_lane else 1
+    assert text.count(" conditional(") >= 3 * calls
+    assert text.count(" sort(") >= 2 * calls
+    assert custom_calls(text) == calls          # one kernel a lane
     # everything the entry reaches WITHOUT entering a conditional's branch
     always, todo = set(), [entry]
     while todo:
