@@ -63,7 +63,10 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from ..observability.overlap import scoped
 
+
+@scoped("sample")
 def fold_in_keys(keys: jax.Array, indices: jax.Array) -> jax.Array:
     """Per-row ``fold_in``: ``keys`` [..., 2] uint32 raw key data,
     ``indices`` [...] int32 → folded raw key data, same shape."""
@@ -73,6 +76,7 @@ def fold_in_keys(keys: jax.Array, indices: jax.Array) -> jax.Array:
     return out.reshape(keys.shape)
 
 
+@scoped("sample")
 def sample_tokens(logits, key, temperature, top_k, top_p):
     """fp32 categorical sampling over ``logits [..., V]`` with ONE key
     and static (Python-scalar) sampling params; temperature 0 = greedy
@@ -100,6 +104,7 @@ def sample_tokens(logits, key, temperature, top_k, top_p):
     return jax.random.categorical(key, logits, axis=-1)
 
 
+@scoped("sample")
 def sample_tokens_per_row(logits, keys, temperature, top_k, top_p):
     """Per-row sampling for the serving step: ``logits [B, V]`` with
     PER-ROW traced params — ``keys [B, 2]`` uint32, ``temperature [B]``

@@ -1324,7 +1324,8 @@ class ServingEngine:
                 chunk_logits[None],
                 fold_in_keys(ch.key[None], ch.out_idx[None]),
                 ch.temp[None], ch.top_k[None], ch.top_p[None])[0]
-            return first, jnp.all(jnp.isfinite(chunk_logits))
+            with jax.named_scope("head"):
+                return first, jnp.all(jnp.isfinite(chunk_logits))
 
         def step(params, scales, pool_k, pool_v, pool_ks, pool_vs,
                  slots, chunk):
@@ -1352,10 +1353,12 @@ class ServingEngine:
             # whose logits go non-finite is quarantined host-side
             # instead of silently streaming garbage or poisoning the
             # prefix cache
-            dec_finite = jnp.all(jnp.isfinite(dec_logits), axis=-1)
-            return (_pack_results(nxt, dec_finite, first, chunk_finite,
-                                  counters=cache.get("counters")),
-                    cache["k"], cache["v"],
+            with jax.named_scope("head"):
+                dec_finite = jnp.all(jnp.isfinite(dec_logits), axis=-1)
+            with jax.named_scope("sample"):
+                packed = _pack_results(nxt, dec_finite, first, chunk_finite,
+                                       counters=cache.get("counters"))
+            return (packed, cache["k"], cache["v"],
                     cache.get("k_scale"), cache.get("v_scale"))
 
         def spec_step(params, scales, dparams, pool_k, pool_v, pool_ks,

@@ -50,6 +50,7 @@ from jax.sharding import PartitionSpec as P
 
 from . import layers as L
 from ..moe import dropless
+from ..observability.overlap import scoped
 from .transformer import TransformerConfig, TransformerLM
 
 #: std of a seeded selection bias, in units of the mean score 1 / outputs:
@@ -274,12 +275,13 @@ class LatentMoELM(TransformerLM):
                                       params)
 
     # -- the sublayers -----------------------------------------------------
+    @scoped("attn_proj")
     def _mla_project(self, p, x, positions):
         """x [B, T, h] -> (q_nope [B,T,H,dn], q_rope [B,T,H,dr] rotated,
         c [B,T,r_kv] normalised, k_rope [B,T,dr] rotated)."""
         c = self.config
         b, t, _ = x.shape
-        norm = self._norm_fn()
+        norm = self._norm_fn("attn_proj")
         cq = norm(p["q_norm"], L.dense_apply(p["q_a"], x))
         if self._q_scale != 1.0:
             cq = (cq * self._q_scale).astype(x.dtype)
@@ -298,6 +300,7 @@ class LatentMoELM(TransformerLM):
                                 interleaved=False)[:, :, 0]
         return q_nope, q_rope, lat, k_rope
 
+    @scoped("attn_proj")
     def _kv_b(self, p, dtype):
         """``kv_b`` as (W_UK [r, H, dn], W_UV [r, H, dv])."""
         c = self.config
@@ -310,18 +313,21 @@ class LatentMoELM(TransformerLM):
         b, t, _ = x.shape
         q_nope, q_rope, lat, k_rope = self._mla_project(p, x, positions)
         w_uk, w_uv = self._kv_b(p, x.dtype)
-        k_nope = jnp.einsum("btr,rhd->bthd", lat, w_uk)
-        v = jnp.einsum("btr,rhd->bthd", lat, w_uv)
-        s = (jnp.einsum("bqhd,bkhd->bhqk", q_nope, k_nope,
-                        preferred_element_type=jnp.float32)
-             + jnp.einsum("bqhd,bkd->bhqk", q_rope, k_rope,
-                          preferred_element_type=jnp.float32)
-             ) * self._sm_scale
-        causal = jnp.tril(jnp.ones((t, t), bool))
-        s = jnp.where(causal[None, None], s, -jnp.inf)
-        o = jnp.einsum("bhqk,bkhd->bqhd",
-                       jax.nn.softmax(s, axis=-1).astype(x.dtype), v)
-        return L.dense_apply(p["out"], o.reshape(b, t, -1))
+        with jax.named_scope("attn_proj"):
+            k_nope = jnp.einsum("btr,rhd->bthd", lat, w_uk)
+            v = jnp.einsum("btr,rhd->bthd", lat, w_uv)
+        with jax.named_scope("attn_kernel"):
+            s = (jnp.einsum("bqhd,bkhd->bhqk", q_nope, k_nope,
+                            preferred_element_type=jnp.float32)
+                 + jnp.einsum("bqhd,bkd->bhqk", q_rope, k_rope,
+                              preferred_element_type=jnp.float32)
+                 ) * self._sm_scale
+            causal = jnp.tril(jnp.ones((t, t), bool))
+            s = jnp.where(causal[None, None], s, -jnp.inf)
+            o = jnp.einsum("bhqk,bkhd->bqhd",
+                           jax.nn.softmax(s, axis=-1).astype(x.dtype), v)
+        with jax.named_scope("attn_proj"):
+            return L.dense_apply(p["out"], o.reshape(b, t, -1))
 
     def _moe_sublayer(self, p, u, row_valid=None, stack=None):
         """u [B, T, h] -> (this chip's part of the routed experts' output,
@@ -360,7 +366,7 @@ class LatentMoELM(TransformerLM):
         if lead is not None:
             x, _ = jax.lax.scan(layer, x, lead)
         x, _ = jax.lax.scan(layer, x, params["blocks"])
-        return (self._norm_fn()(params["ln_f"], x),
+        return (self._norm_fn("head")(params["ln_f"], x),
                 jnp.zeros((), jnp.float32))
 
     # -- paged serving -----------------------------------------------------
@@ -399,37 +405,41 @@ class LatentMoELM(TransformerLM):
         cw = t - bsl
         blk, npages = pool.shape[1], tables.shape[1]
         q_nope, q_rope, lat, k_rope = self._mla_project(p, xn, positions)
-        slot = jnp.arange(bsl)
-        null_row = null * blk
-        write = [jnp.where(act, tables[slot, lens // blk] * blk + lens % blk,
-                           null_row)]
-        if cw:
-            ci = jnp.arange(cw)
-            cpos = chunk_start + ci
-            ctable = tables[chunk_slot]
-            write.append(jnp.where(
-                ci < chunk_len,
-                ctable[jnp.minimum(cpos // blk, npages - 1)] * blk
-                + cpos % blk, null_row))
-        write = jnp.concatenate(write)
-        lanes = pool.shape[2]
-        rows = jnp.concatenate([lat[0], k_rope[0]], axis=-1)
-        rows = jnp.pad(rows.astype(pool.dtype),
-                       ((0, 0), (0, lanes - rows.shape[1])))
-        pool = pool.reshape(-1, lanes).at[write].set(rows).reshape(
-            pool.shape)
+        with jax.named_scope("pool_write"):
+            slot = jnp.arange(bsl)
+            null_row = null * blk
+            write = [jnp.where(
+                act, tables[slot, lens // blk] * blk + lens % blk, null_row)]
+            if cw:
+                ci = jnp.arange(cw)
+                cpos = chunk_start + ci
+                ctable = tables[chunk_slot]
+                write.append(jnp.where(
+                    ci < chunk_len,
+                    ctable[jnp.minimum(cpos // blk, npages - 1)] * blk
+                    + cpos % blk, null_row))
+            write = jnp.concatenate(write)
+            lanes = pool.shape[2]
+            rows = jnp.concatenate([lat[0], k_rope[0]], axis=-1)
+            rows = jnp.pad(rows.astype(pool.dtype),
+                           ((0, 0), (0, lanes - rows.shape[1])))
+            pool = pool.reshape(-1, lanes).at[write].set(rows).reshape(
+                pool.shape)
         w_uk, w_uv = self._kv_b(p, xn.dtype)
-        q_lat = jnp.einsum("thd,rhd->thr", q_nope[0], w_uk)
-        o_parts = [mla_paged_decode_attention(
-            q_lat[:bsl], q_rope[0, :bsl], pool,
-            jnp.where(act, lens + 1, 0), tables, self._sm_scale)]
-        if cw:
-            o_parts.append(mla_paged_prefill_attention(
-                q_lat[bsl:], q_rope[0, bsl:], pool, chunk_start, chunk_len,
-                ctable, self._sm_scale))
-        o_lat = jnp.concatenate(o_parts) if cw else o_parts[0]
-        o = jnp.einsum("thr,rhd->thd", o_lat, w_uv)
-        return L.dense_apply(p["out"], o.reshape(1, t, -1)), pool
+        with jax.named_scope("attn_proj"):
+            q_lat = jnp.einsum("thd,rhd->thr", q_nope[0], w_uk)
+        with jax.named_scope("attn_kernel"):
+            o_parts = [mla_paged_decode_attention(
+                q_lat[:bsl], q_rope[0, :bsl], pool,
+                jnp.where(act, lens + 1, 0), tables, self._sm_scale)]
+            if cw:
+                o_parts.append(mla_paged_prefill_attention(
+                    q_lat[bsl:], q_rope[0, bsl:], pool, chunk_start,
+                    chunk_len, ctable, self._sm_scale))
+            o_lat = jnp.concatenate(o_parts) if cw else o_parts[0]
+        with jax.named_scope("attn_proj"):
+            o = jnp.einsum("thr,rhd->thd", o_lat, w_uv)
+            return L.dense_apply(p["out"], o.reshape(1, t, -1)), pool
 
     def _apply_paged_mixed(self, params, cache, dec_tokens, dec_active,
                            chunk_ids, chunk_slot, chunk_start, chunk_len,
@@ -447,12 +457,13 @@ class LatentMoELM(TransformerLM):
             raise NotImplementedError(self.paged_refusal(kv_bits=8))
         tables, lens = cache["block_tables"], cache["lens"]
         bsl, cw = dec_tokens.shape[0], chunk_ids.shape[0]
-        act = dec_active > 0
-        ci = jnp.arange(cw)
-        positions = jnp.concatenate(
-            [lens, jnp.where(ci < chunk_len, chunk_start + ci, 0)])[None]
-        ids = jnp.concatenate([dec_tokens, chunk_ids])[None]
-        row_valid = jnp.concatenate([act, ci < chunk_len])
+        with jax.named_scope("embed"):
+            act = dec_active > 0
+            ci = jnp.arange(cw)
+            positions = jnp.concatenate(
+                [lens, jnp.where(ci < chunk_len, chunk_start + ci, 0)])[None]
+            ids = jnp.concatenate([dec_tokens, chunk_ids])[None]
+            row_valid = jnp.concatenate([act, ci < chunk_len])
         x = self._embed_tokens(params, ids)
         ns, nb = cache["k"].shape[:2]
         pool = cache["k"].reshape(ns * nb, *cache["k"].shape[2:])
@@ -460,15 +471,18 @@ class LatentMoELM(TransformerLM):
 
         def attend_at(off):
             def attend(j, p, xn, pool):
-                at = off + j * nb
+                with jax.named_scope("pool_write"):
+                    at = off + j * nb
+                    tables_at = tables + at
                 return self._paged_latent_attention(
-                    p, xn, pool, tables + at, lens, act, chunk_slot,
+                    p, xn, pool, tables_at, lens, act, chunk_slot,
                     chunk_start, chunk_len, at, positions)
             return attend
 
         def offsets(first, count):
-            return (first + jnp.arange(count, dtype=tables.dtype)
-                    ) * per_layer
+            with jax.named_scope("pool_write"):
+                return (first + jnp.arange(count, dtype=tables.dtype)
+                        ) * per_layer
 
         lead, leading = self._leading_blocks(params), 0
         if lead is not None:
@@ -497,33 +511,38 @@ class LatentMoELM(TransformerLM):
             y, pool, moe_counts = self._latent_block(
                 self.block_transform(bp), y, attend_at(off), pool,
                 row_valid, (experts, layer))
-            return (y, pool, counts + moe_counts), None
+            with jax.named_scope("expert_layout"):
+                counts = counts + moe_counts
+            return (y, pool, counts), None
 
         zero = jnp.zeros((len(dropless.COUNTERS),), jnp.int32)
         (x, pool, counts), _ = jax.lax.scan(
             scan_fn, (x, pool, zero),
             (blocks, offsets(leading, scanned),
              jnp.arange(scanned, dtype=jnp.int32)))
-        x = self._norm_fn()(params["ln_f"], x)
-        if cw:
-            last = jax.lax.dynamic_slice_in_dim(
-                x[0], bsl + jnp.maximum(chunk_len - 1, 0), 1, axis=0)
-            logits = self._project(
-                params, jnp.concatenate([x[0, :bsl], last])[None])
-            chunk_logits = logits[0, bsl]
-        else:
-            logits = self._project(params, x[0, :bsl][None])
-            chunk_logits = jnp.zeros((logits.shape[-1],), logits.dtype)
-        # the live context the latent kernel walked, once a sublayer
-        read = (jnp.sum(jnp.where(act, lens + 1, 0))
-                + jnp.where(chunk_len > 0, chunk_start + chunk_len, 0))
-        new_lens = (lens + act.astype(lens.dtype)).at[chunk_slot].add(
-            chunk_len, mode="drop")
-        extra = [jnp.asarray(v, jnp.int32)[None]
-                 for v in self._extra_counters(row_valid)]
+        x = self._norm_fn("head")(params["ln_f"], x)
+        with jax.named_scope("head"):
+            if cw:
+                last = jax.lax.dynamic_slice_in_dim(
+                    x[0], bsl + jnp.maximum(chunk_len - 1, 0), 1, axis=0)
+                logits = self._project(
+                    params, jnp.concatenate([x[0, :bsl], last])[None])
+                chunk_logits = logits[0, bsl]
+            else:
+                logits = self._project(params, x[0, :bsl][None])
+                chunk_logits = jnp.zeros((logits.shape[-1],), logits.dtype)
+            dec_logits = logits[0, :bsl]
+        with jax.named_scope("pool_write"):
+            # the live context the latent kernel walked, once a sublayer
+            read = (jnp.sum(jnp.where(act, lens + 1, 0))
+                    + jnp.where(chunk_len > 0, chunk_start + chunk_len, 0))
+            new_lens = (lens + act.astype(lens.dtype)).at[chunk_slot].add(
+                chunk_len, mode="drop")
+            extra = [jnp.asarray(v, jnp.int32)[None]
+                     for v in self._extra_counters(row_valid)]
+            counters = jnp.concatenate(
+                [counts, (read * ns).astype(jnp.int32)[None], *extra])
         new_cache = {
             "k": pool.reshape(ns, nb, *pool.shape[1:]), "v": None,
-            "block_tables": tables, "lens": new_lens,
-            "counters": jnp.concatenate(
-                [counts, (read * ns).astype(jnp.int32)[None], *extra])}
-        return logits[0, :bsl], chunk_logits, new_cache
+            "block_tables": tables, "lens": new_lens, "counters": counters}
+        return dec_logits, chunk_logits, new_cache

@@ -128,7 +128,9 @@ class SandwichMoELM(LatentMoELM):
         router says; ``stack`` as in ``_latent_block``."""
         routed, counters = self._moe_sublayer(bp["moe"], u, row_valid,
                                               stack)
-        return self._mlp(bp["shared"], u) + routed, counters
+        shared = self._mlp(bp["shared"], u, scope="shared_expert")
+        with jax.named_scope("expert_layout"):
+            return shared + routed, counters
 
     def _latent_block(self, bp, x, attend, pools=None, row_valid=None,
                       stack=None):
@@ -138,14 +140,18 @@ class SandwichMoELM(LatentMoELM):
         norm = self._norm_fn()
         x = self.constrain(x)
         o, pools = attend(0, bp["attn"], norm(bp["ln_in"], x), pools)
-        a = x + norm(bp["ln_post_attn"], o)
+        o = norm(bp["ln_post_attn"], o)
+        with jax.named_scope("residual"):
+            a = x + o
         u = norm(bp["ln_pre_mlp"], a)
         if "moe" in bp:
             f, counters = self.expert_layer(bp, u, row_valid, stack)
         else:
             f = self._mlp(bp["mlp"], u)
             counters = jnp.zeros((len(dropless.COUNTERS),), jnp.int32)
-        y = a + norm(bp["ln_post_mlp"], f)
+        f = norm(bp["ln_post_mlp"], f)
+        with jax.named_scope("residual"):
+            y = a + f
         return self.constrain(y), pools, counters
 
     def _extra_counters(self, row_valid) -> list:

@@ -71,11 +71,17 @@ class ShortcutMoELM(LatentMoELM):
         norm = self._norm_fn()
         x = self.constrain(x)
         o, pools = attend(0, bp["attn0"], norm(bp["ln0"], x), pools)
-        a = x + o
+        with jax.named_scope("residual"):
+            a = x + o
         u = norm(bp["ln1"], a)
         m, counters = self._moe_sublayer(bp["moe"], u, row_valid, stack)
-        bb = a + self._mlp(bp["mlp0"], u)
+        f = self._mlp(bp["mlp0"], u)
+        with jax.named_scope("residual"):
+            bb = a + f
         o, pools = attend(1, bp["attn1"], norm(bp["ln2"], bb), pools)
-        cc = bb + o
-        y = cc + self._mlp(bp["mlp1"], norm(bp["ln3"], cc)) + m
+        with jax.named_scope("residual"):
+            cc = bb + o
+        f = self._mlp(bp["mlp1"], norm(bp["ln3"], cc))
+        with jax.named_scope("residual"):
+            y = cc + f + m
         return self.constrain(y), pools, counters

@@ -24,7 +24,6 @@ target family).
 from __future__ import annotations
 
 import dataclasses
-from functools import partial
 from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 
 import jax
@@ -32,6 +31,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from . import layers as L
+from ..observability.overlap import scoped
 
 
 class PagedMixedState(NamedTuple):
@@ -641,13 +641,18 @@ class TransformerLM:
         kmask = jnp.repeat(rows, blk, axis=-1)[..., :tk]  # [H?, t, tk]
         return kmask[None]                                # [1, H|1, t, tk]
 
-    def _norm_fn(self):
+    def _norm_fn(self, scope: str = "norm"):
         """The configured norm apply with eps bound (single source for the
-        six former copies of the layernorm/rmsnorm selector)."""
+        six former copies of the layernorm/rmsnorm selector), under the
+        device scope ``scope`` (the final norm is the ``head``'s)."""
         c = self.config
         base = (L.layernorm_apply if c.norm_type == "layernorm"
                 else L.rmsnorm_apply)
-        return partial(base, eps=c.layernorm_eps)
+
+        def norm(p, x):
+            with jax.named_scope(scope):
+                return base(p, x, eps=c.layernorm_eps)
+        return norm
 
     _ACT_SITES = ("attn_in", "mlp_in")
 
@@ -697,6 +702,22 @@ class TransformerLM:
 
     # -- block -------------------------------------------------------------
     def _attention(self, p, x, cache_kv=None, positions=None, window=None):
+        with jax.named_scope("attn_proj"):
+            q, k, v = self._qkv(p, x, positions)
+        if isinstance(cache_kv, PagedMixedState):
+            # continuous batching, mixed step: decode slots + one prompt
+            # chunk in a single program (chunked prefill)
+            o, new_cache = self._paged_mixed_attention(q, k, v, cache_kv)
+        else:
+            with jax.named_scope("attn_kernel"):
+                o, new_cache = self._attend(q, k, v, cache_kv, positions,
+                                            window)
+        with jax.named_scope("attn_proj"):
+            return L.dense_apply(p["out"], o), new_cache
+
+    def _qkv(self, p, x, positions):
+        """x [B, T, D] -> q [B, T, H, hd], k / v [B, T, Hkv, hd], rotary
+        applied."""
         c = self.config
         nh, hd = c.num_heads, c.hdim
         nkv = c.kv_heads
@@ -716,6 +737,16 @@ class TransformerLM:
                                interleaved=c.rotary_interleaved)
             k = L.apply_rotary(k, cos, sin, positions,
                                interleaved=c.rotary_interleaved)
+        return q, k, v
+
+    def _attend(self, q, k, v, cache_kv, positions, window):
+        """Attention proper, whichever implementation the configuration
+        names: ``(o [B, T, H * hd]`` before the output projection, the
+        updated ``(ck, cv)`` or None)."""
+        c = self.config
+        nh, nkv = c.num_heads, c.kv_heads
+        b, t, _, hd = q.shape
+
         def expand_kv(a):
             # GQA expansion for the Pallas/ring kernels (which assume one
             # kv head per query head); the XLA paths use L.gqa_attention
@@ -724,11 +755,6 @@ class TransformerLM:
 
         new_cache = None
         offset = 0
-        if isinstance(cache_kv, PagedMixedState):
-            # continuous batching, mixed step: decode slots + one prompt
-            # chunk in a single program (chunked prefill)
-            return self._paged_mixed_attention(p, q, k, v, cache_kv, t, nh,
-                                               hd)
         if cache_kv is None and c.attn_impl in ("ring", "ulysses",
                                                 "blocksparse"):
             # the flash kernel folds GQA via its k/v index maps and is NOT
@@ -751,8 +777,7 @@ class TransformerLM:
                     ulysses_attention)
                 o = ulysses_attention(q, k, v, self.mesh, causal=c.causal,
                                       alibi=use_alibi)
-            o = o.reshape(b, t, nh * hd)
-            return L.dense_apply(p["out"], o), None
+            return o.reshape(b, t, nh * hd), None
         if cache_kv is None and c.attn_impl == "blocksparse":
             from ..ops.sparse_attention.blocksparse_flash import (
                 blocksparse_attention_bthd)
@@ -770,8 +795,7 @@ class TransformerLM:
                 mask = self._sparse_decode_mask(jnp.asarray(0, jnp.int32),
                                                 t, t)
                 o = L.causal_attention(q, k, v, mask=mask, causal=c.causal)
-            o = o.reshape(b, t, nh * hd)
-            return L.dense_apply(p["out"], o), None
+            return o.reshape(b, t, nh * hd), None
         if cache_kv is None and c.attn_impl == "flash" and \
                 c.pos_embedding != "alibi" and window is None:
             from ..ops.transformer.flash_attention import (
@@ -782,8 +806,7 @@ class TransformerLM:
             # auto-partitioned) — the engine binds the mesh
             o = flash_attention_bthd(q, k, v, causal=c.causal,
                                      mesh=self.mesh)
-            o = o.reshape(b, t, nh * hd)
-            return L.dense_apply(p["out"], o), None
+            return o.reshape(b, t, nh * hd), None
         if cache_kv is not None:
             ck, cv, idx = cache_kv
             ck = jax.lax.dynamic_update_slice(ck, k.astype(ck.dtype),
@@ -857,11 +880,9 @@ class TransformerLM:
                     q, k, v, causal=c.causal, bias=bias,
                     mask=None if band is None else band[None, None],
                     scale=self._attn_scale)
-        o = o.reshape(b, t, nh * hd)
-        return L.dense_apply(p["out"], o), new_cache
+        return o.reshape(b, t, nh * hd), new_cache
 
-    def _paged_mixed_attention(self, p, q, k, v, st: PagedMixedState, t,
-                               nh, hd):
+    def _paged_mixed_attention(self, q, k, v, st: PagedMixedState):
         """One layer of the mixed decode+spec-verify+chunked-prefill step.
 
         q/k/v arrive as ``[1, B + B*S + C, nh|kvh, hd]`` — the first B
@@ -882,25 +903,39 @@ class TransformerLM:
         call per spec depth (row i sees the slot's prefix plus draft
         tokens 0..i: causality via the length vector), and the causal
         chunk kernel over the chunk slot's pages — and the outputs
-        concatenate back into the shared projection.  A quantized pool
-        (``st.k_scale is not None``) encodes every row at the combined
-        scatter (``ops/quantizer/kv_quantize`` — one scale per row per
-        kv head, written alongside, so the pool never holds a
-        full-precision copy) and the kernel dequantizes in its page
-        loop.  ``S == 0`` and ``C == 0`` are
-        STATIC widths: the corresponding lane compiles away entirely,
-        so the plain decode program is byte-identical to pre-spec
-        builds."""
+        concatenate back for the shared projection (the caller's).  A
+        quantized pool (``st.k_scale is not None``) encodes every row at
+        the combined scatter (``ops/quantizer/kv_quantize`` — one scale
+        per row per kv head, written alongside, so the pool never holds
+        a full-precision copy) and the kernel dequantizes in its page
+        loop.  ``S == 0`` and ``C == 0`` are STATIC widths: the
+        corresponding lane compiles away entirely, so the plain decode
+        program is byte-identical to pre-spec builds."""
+        _, t, nh, hd = q.shape
+        kv_bits = self._paged_kv_bits(st.k_pool, st.k_scale, hd)
+        with jax.named_scope("pool_write"):
+            pk, pv, kscale, vscale, ctable = self._paged_write(
+                k, v, st, kv_bits)
+        with jax.named_scope("attn_kernel"):
+            o = self._paged_attend(q, pk, pv, kscale, vscale, kv_bits, st,
+                                   ctable)
+        pools = (pk, pv) if not kv_bits else (pk, pv, kscale, vscale)
+        return o.reshape(1, t, nh * hd), pools
+
+    def _paged_write(self, k, v, st: PagedMixedState, kv_bits):
+        """Every lane's new k/v rows into the pools in one combined
+        scatter: ``(k pool, v pool, k scales, v scales, the chunk slot's
+        table or None)``."""
         pool_k, pool_v, tables, lens = (st.k_pool, st.v_pool,
                                         st.block_tables, st.lens)
         kscale, vscale = st.k_scale, st.v_scale
-        kv_bits = self._paged_kv_bits(pool_k, kscale, hd)
         bsl = lens.shape[0]                   # decode slots
         sw = st.spec_width                    # spec rows per slot
-        c = t - bsl - bsl * sw                # chunk width
+        c = k.shape[1] - bsl - bsl * sw       # chunk width
         blk = pool_k.shape[1]
         npages = tables.shape[1]
         act = st.dec_active > 0
+        ctable = None
         slot = jnp.arange(bsl)
         # row 0 of this layer's null block: where every masked row lands
         null = st.null_block * blk
@@ -991,8 +1026,19 @@ class TransformerLM:
             kscale, vscale = put_scale(kscale, ks), put_scale(vscale, vs)
         else:
             pk, pv = put(pool_k, k[0]), put(pool_v, v[0])
+        return pk, pv, kscale, vscale, ctable
+
+    def _paged_attend(self, q, pk, pv, kscale, vscale, kv_bits,
+                      st: PagedMixedState, ctable):
+        """The mixed step's kernels over the written pools: every lane's
+        rows attend and concatenate back, ``[B + B*S + C, nh, hd]``."""
         from ..ops.transformer.paged_decode_attention import (
             paged_decode_attention, paged_prefill_attention)
+        tables, lens = st.block_tables, st.lens
+        nh, hd = q.shape[2:]
+        bsl, sw = lens.shape[0], st.spec_width
+        c = q.shape[1] - bsl - bsl * sw
+        act = st.dec_active > 0
         o_parts = [paged_decode_attention(
             q[0, :bsl], pk, pv,
             # only slots decoding THIS iteration attend (their length
@@ -1005,6 +1051,7 @@ class TransformerLM:
             # spec depth i attends the prefix plus draft rows 0..i
             # (all already in the pool from the combined scatter);
             # per-depth lengths give exact causality between draft rows
+            sact = st.spec_active > 0
             qs = q[0, bsl:bsl + bsl * sw].reshape(bsl, sw, nh, hd)
             o_spec = [paged_decode_attention(
                 qs[:, i], pk, pv,
@@ -1020,22 +1067,20 @@ class TransformerLM:
                 st.chunk_len, ctable,
                 sm_scale=self._attn_scale,
                 k_scale=kscale, v_scale=vscale, kv_bits=kv_bits))
-        o = (o_parts[0] if len(o_parts) == 1
-             else jnp.concatenate(o_parts, axis=0))[None]
-        o = o.reshape(1, t, nh * hd)
-        pools = (pk, pv) if not kv_bits else (pk, pv, kscale, vscale)
-        return L.dense_apply(p["out"], o), pools
+        return (o_parts[0] if len(o_parts) == 1
+                else jnp.concatenate(o_parts, axis=0))
 
-    def _mlp(self, p, x):
-        xq = self._maybe_qact(x, "mlp_in")
-        if self.config.gated_mlp:
-            g = L.ACT_FNS[self.config.activation](
-                L.dense_apply(p["fc_gate"], xq))
-            return L.dense_apply(p["fc_out"],
-                                 g * L.dense_apply(p["fc_in"], xq))
-        h = L.dense_apply(p["fc_in"], xq)
-        h = L.ACT_FNS[self.config.activation](h)
-        return L.dense_apply(p["fc_out"], h)
+    def _mlp(self, p, x, scope: str = "mlp"):
+        with jax.named_scope(scope):
+            xq = self._maybe_qact(x, "mlp_in")
+            if self.config.gated_mlp:
+                g = L.ACT_FNS[self.config.activation](
+                    L.dense_apply(p["fc_gate"], xq))
+                return L.dense_apply(p["fc_out"],
+                                     g * L.dense_apply(p["fc_in"], xq))
+            h = L.dense_apply(p["fc_in"], xq)
+            h = L.ACT_FNS[self.config.activation](h)
+            return L.dense_apply(p["fc_out"], h)
 
     def _block(self, bp, x, cache_kv=None, positions=None, window=None):
         c = self.config
@@ -1068,20 +1113,29 @@ class TransformerLM:
             # BERT family: ln(x + f(x)); ln1 after attention, ln2 after FFN
             a, new_cache = self._attention(bp["attn"], fin(x), cache_kv,
                                            positions, window)
-            x = norm(bp["ln1"], x + red(a))
-            x = norm(bp["ln2"], x + red(self._mlp(bp["mlp"], fin(x))))
+            with jax.named_scope("residual"):
+                a = x + red(a)
+            x = norm(bp["ln1"], a)
+            m = self._mlp(bp["mlp"], fin(x))
+            with jax.named_scope("residual"):
+                m = x + red(m)
+            x = norm(bp["ln2"], m)
         elif c.parallel_residual:
             a, new_cache = self._attention(bp["attn"],
                                            fin(norm(bp["ln1"], x)),
                                            cache_kv, positions, window)
             m = self._mlp(bp["mlp"], fin(norm(bp["ln2"], x)))
-            x = x + red(a + m)
+            with jax.named_scope("residual"):
+                x = x + red(a + m)
         else:
             a, new_cache = self._attention(bp["attn"],
                                            fin(norm(bp["ln1"], x)),
                                            cache_kv, positions, window)
-            x = x + red(a)
-            x = x + red(self._mlp(bp["mlp"], fin(norm(bp["ln2"], x))))
+            with jax.named_scope("residual"):
+                x = x + red(a)
+            m = self._mlp(bp["mlp"], fin(norm(bp["ln2"], x)))
+            with jax.named_scope("residual"):
+                x = x + red(m)
         return self.constrain(x), new_cache
 
     def _moe_block(self, bp, x, cache_kv=None, positions=None, rng=None,
@@ -1092,15 +1146,19 @@ class TransformerLM:
         x = self.constrain(x)
         a, new_cache = self._attention(bp["attn"], norm(bp["ln1"], x),
                                        cache_kv, positions)
+        def moe(u):
+            with jax.named_scope("experts"):
+                return self._moe.apply(bp["moe"], u, rng=rng, train=train)
         if c.parallel_residual:
-            m, laux, _ = self._moe.apply(bp["moe"], norm(bp["ln2"], x),
-                                         rng=rng, train=train)
-            x = x + a + m
+            m, laux, _ = moe(norm(bp["ln2"], x))
+            with jax.named_scope("residual"):
+                x = x + a + m
         else:
-            x = x + a
-            m, laux, _ = self._moe.apply(bp["moe"], norm(bp["ln2"], x),
-                                         rng=rng, train=train)
-            x = x + m
+            with jax.named_scope("residual"):
+                x = x + a
+            m, laux, _ = moe(norm(bp["ln2"], x))
+            with jax.named_scope("residual"):
+                x = x + m
         return self.constrain(x), new_cache, laux
 
     def _superblock(self, sp, x, caches=None, positions=None, rng=None,
@@ -1212,9 +1270,10 @@ class TransformerLM:
         x, (nk, nv) = jax.lax.scan(scan_fn, x, xs)
         new_cache = {"k": nk, "v": nv, "index": idx + input_ids.shape[1]}
         if c.final_layernorm:
-            x = self._norm_fn()(params["ln_f"], x)
+            x = self._norm_fn("head")(params["ln_f"], x)
         return self._project(params, x), new_cache
 
+    @scoped("embed")
     def _embed_tokens(self, params, input_ids, positions=None,
                       token_type_ids=None):
         """Shared embedding path: word (+ position, + token-type) embeds,
@@ -1247,9 +1306,10 @@ class TransformerLM:
                   else jnp.zeros_like(input_ids))
             x = x + L.embedding_apply(params["type_embed"], tt, c.dtype)
         if c.embed_layernorm:
-            x = self._norm_fn()(params["ln_embed"], x)
+            x = self._norm_fn("embed")(params["ln_embed"], x)
         return x
 
+    @scoped("head")
     def _project(self, params, x):
         c = self.config
         if c.mlm_head:
@@ -1258,7 +1318,7 @@ class TransformerLM:
             mh = params["mlm_head"]
             h = L.dense_apply(mh["dense"], x)
             h = L.ACT_FNS[c.activation](h)
-            h = self._norm_fn()(mh["ln"], h)
+            h = self._norm_fn("head")(mh["ln"], h)
             logits = L.embedding_attend(params["embed"], h)
             return logits + mh["bias"].astype(logits.dtype)
         if c.tie_embeddings:
@@ -1323,7 +1383,7 @@ class TransformerLM:
             (x, laux), _ = jax.lax.scan(scan_fn, (x, zero), params["blocks"])
         if not c.final_layernorm:
             return x, laux
-        return self._norm_fn()(params["ln_f"], x), laux
+        return self._norm_fn("head")(params["ln_f"], x), laux
 
     def hidden_states(self, params, input_ids):
         """Forward up to the final norm, pre-projection ([B,T,D])."""
@@ -1373,6 +1433,33 @@ class TransformerLM:
             return 0
         return 8 if pool_k.shape[-1] == k_scale.shape[1] * hd else 4
 
+    @staticmethod
+    def _mixed_rows(lens, dec_tokens, chunk_ids, chunk_start, chunk_len,
+                    spec_tokens=None, spec_active=None):
+        """The mixed step's rows: ``(positions, ids)``, each
+        ``[1, B + B*S + C]`` — decode slots, spec runs, the chunk."""
+        sw = 0 if spec_tokens is None else spec_tokens.shape[1]
+        c = chunk_ids.shape[0]
+        pos_parts, id_parts = [lens], [dec_tokens]
+        if sw:
+            # spec positions: lens[b] + i for verifying slots; parked
+            # at 0 for the rest (null-block rows, position clamped away
+            # from the table edge like padded chunk rows)
+            spos = jnp.where((spec_active > 0)[:, None],
+                             lens[:, None] + jnp.arange(sw)[None, :], 0)
+            pos_parts.append(spos.reshape(-1))
+            id_parts.append(spec_tokens.reshape(-1))
+        if c:
+            ci = jnp.arange(c)
+            # clamp padded chunk positions to 0: base + i past chunk_len
+            # can exceed the rotary/learned position tables near
+            # max_seq_len
+            cpos = jnp.where(ci < chunk_len, chunk_start + ci, 0)
+            pos_parts.append(cpos)
+            id_parts.append(chunk_ids)
+        return (jnp.concatenate(pos_parts)[None],      # [1, B+B*S+C]
+                jnp.concatenate(id_parts)[None])
+
     def _apply_paged_mixed(self, params, cache, dec_tokens, dec_active,
                            chunk_ids, chunk_slot, chunk_start, chunk_len,
                            spec_tokens=None, spec_active=None):
@@ -1412,25 +1499,10 @@ class TransformerLM:
         bsl = dec_tokens.shape[0]
         sw = 0 if spec_tokens is None else spec_tokens.shape[1]
         c = chunk_ids.shape[0]
-        pos_parts, id_parts = [lens], [dec_tokens]
-        if sw:
-            # spec positions: lens[b] + i for verifying slots; parked
-            # at 0 for the rest (null-block rows, position clamped away
-            # from the table edge like padded chunk rows)
-            spos = jnp.where((spec_active > 0)[:, None],
-                             lens[:, None] + jnp.arange(sw)[None, :], 0)
-            pos_parts.append(spos.reshape(-1))
-            id_parts.append(spec_tokens.reshape(-1))
-        if c:
-            ci = jnp.arange(c)
-            # clamp padded chunk positions to 0: base + i past chunk_len
-            # can exceed the rotary/learned position tables near
-            # max_seq_len
-            cpos = jnp.where(ci < chunk_len, chunk_start + ci, 0)
-            pos_parts.append(cpos)
-            id_parts.append(chunk_ids)
-        positions = jnp.concatenate(pos_parts)[None]   # [1, B+B*S+C]
-        ids = jnp.concatenate(id_parts)[None]
+        with jax.named_scope("embed"):
+            positions, ids = self._mixed_rows(
+                lens, dec_tokens, chunk_ids, chunk_start, chunk_len,
+                spec_tokens, spec_active)
         x = self._embed_tokens(params, ids, positions=positions)
         # data-sharded decode slots: the chunk indexes a GLOBAL slot, so
         # gather the full block tables ONCE here (they are loop
@@ -1453,52 +1525,57 @@ class TransformerLM:
         def scan_fn(carry, xs):
             y, pools = carry
             bp, off = xs
-            y, pools = self._block(
-                self.block_transform(bp), y,
-                PagedMixedState(
+            with jax.named_scope("pool_write"):
+                st = PagedMixedState(
                     *pools[:2], tables + off, lens, dec_active,
                     chunk_slot, chunk_start, chunk_len,
                     None if tables_g is None else tables_g + off,
-                    spec_active, sw, *pools[2:], null_block=off),
-                positions)
+                    spec_active, sw, *pools[2:], null_block=off)
+            y, pools = self._block(self.block_transform(bp), y, st,
+                                   positions)
             return (y, pools), None
 
-        offs = jnp.arange(nl, dtype=tables.dtype) * nb
+        with jax.named_scope("pool_write"):
+            offs = jnp.arange(nl, dtype=tables.dtype) * nb
         (x, pools), _ = jax.lax.scan(scan_fn, (x, pools),
                                      (params["blocks"], offs))
         pools = dict(zip(names, (
             p.reshape(nl, nb, *p.shape[1:]) for p in pools)))
         if self.config.final_layernorm:
-            x = self._norm_fn()(params["ln_f"], x)
+            x = self._norm_fn("head")(params["ln_f"], x)
         # project only the rows anything samples from: the B decode
         # rows, the B*S spec rows, and the chunk's last valid position
         # (a [B + B*S + 1, V] head instead of [B + B*S + C, V])
         nsample = bsl + bsl * sw
-        if c:
-            last = jax.lax.dynamic_slice_in_dim(
-                x[0], nsample + jnp.maximum(chunk_len - 1, 0), 1, axis=0)
-            logits = self._project(
-                params, jnp.concatenate([x[0, :nsample], last])[None])
-            chunk_logits = logits[0, nsample]
-        else:
-            logits = self._project(params, x[0, :nsample][None])
-            chunk_logits = jnp.zeros((logits.shape[-1],), logits.dtype)
-        new_lens = lens + (dec_active > 0).astype(lens.dtype)
+        with jax.named_scope("head"):
+            if c:
+                last = jax.lax.dynamic_slice_in_dim(
+                    x[0], nsample + jnp.maximum(chunk_len - 1, 0), 1,
+                    axis=0)
+                logits = self._project(
+                    params, jnp.concatenate([x[0, :nsample], last])[None])
+                chunk_logits = logits[0, nsample]
+            else:
+                logits = self._project(params, x[0, :nsample][None])
+                chunk_logits = jnp.zeros((logits.shape[-1],), logits.dtype)
+            dec_logits = logits[0, :bsl]
         # with data-sharded slots `lens` is this shard's rows and
         # chunk_slot is global: translate to the local row, dropping the
         # update on shards that don't own the chunk slot (the serving
         # engine recomputes lens host-side every dispatch either way —
         # including the spec lane's accepted-token advance, which only
         # the host knows after the accept/reject compare)
-        cs = (chunk_slot if self._dp_axis is None else
-              chunk_slot - jax.lax.axis_index(self._dp_axis) * bsl)
-        new_lens = new_lens.at[cs].add(chunk_len, mode="drop")
+        with jax.named_scope("pool_write"):
+            new_lens = lens + (dec_active > 0).astype(lens.dtype)
+            cs = (chunk_slot if self._dp_axis is None else
+                  chunk_slot - jax.lax.axis_index(self._dp_axis) * bsl)
+            new_lens = new_lens.at[cs].add(chunk_len, mode="drop")
         new_cache = dict(pools, block_tables=tables, lens=new_lens)
         if sw:
             spec_logits = logits[0, bsl:nsample].reshape(
                 bsl, sw, logits.shape[-1])
-            return (logits[0, :bsl], spec_logits, chunk_logits, new_cache)
-        return logits[0, :bsl], chunk_logits, new_cache
+            return dec_logits, spec_logits, chunk_logits, new_cache
+        return dec_logits, chunk_logits, new_cache
 
     def init_paged_cache(self, num_blocks: int, block_size: int,
                          dtype=None, kv_bits: int = 0) -> Dict:
@@ -1580,8 +1657,9 @@ class TransformerLM:
                     if self.config.moe_enabled else 0.0)
 
         x, laux = self.hidden_states_and_aux(params, logits_in, rng=moe_rng)
-        return self.nll_from_hidden(params, x, labels, mask) \
-            + aux_coef * laux
+        with jax.named_scope("loss"):
+            return self.nll_from_hidden(params, x, labels, mask) \
+                + aux_coef * laux
 
     def nll_from_hidden(self, params, x, labels, mask=None) -> jnp.ndarray:
         """Mean masked NLL from final hidden states ([B,T,D]) — the loss

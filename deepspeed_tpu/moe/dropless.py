@@ -36,6 +36,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..observability.overlap import scoped
 from ..ops import resolve_interpret
 
 #: rows to a tile of the grouped product: one bf16 operand tile's sublanes
@@ -57,6 +58,7 @@ class Routing(NamedTuple):
     weight: jax.Array      # [T, k] float32 = scale * (renormalised) score
 
 
+@scoped("router")
 def route(u: jax.Array, router_kernel: jax.Array,
           bias: Optional[jax.Array], k: int, scale: float,
           scoring: str = "softmax", renormalize: bool = False) -> Routing:
@@ -96,6 +98,7 @@ def _tile_n(k_dim: int, n: int, itemsize: int) -> int:
     return tn
 
 
+@scoped("experts")
 def grouped_matmul(x: jax.Array, w: jax.Array, tile_expert: jax.Array,
                    live_tiles: jax.Array,
                    interpret: Optional[bool] = None) -> jax.Array:
@@ -192,6 +195,7 @@ def _layout(local: jax.Array, weight: jax.Array, held: int, rows: int
                    counts)
 
 
+@scoped("expert_layout")
 def expert_share(experts: dict, u: jax.Array, routing: Routing,
                  num_routed: int, experts_held: Tuple[int, int],
                  row_valid: Optional[jax.Array] = None,
@@ -246,8 +250,9 @@ def expert_share(experts: dict, u: jax.Array, routing: Routing,
         xs = u_pad[token]
         gate = grouped_matmul(xs, experts["w_gate"], te, live)
         up = grouped_matmul(xs, experts["w_up"], te, live)
-        mid = (jax.nn.silu(gate.astype(jnp.float32))
-               * up.astype(jnp.float32)).astype(u.dtype)
+        with jax.named_scope("experts"):
+            mid = (jax.nn.silu(gate.astype(jnp.float32))
+                   * up.astype(jnp.float32)).astype(u.dtype)
         out = grouped_matmul(mid, experts["w_down"], te, live)
         # a dead tile's rows are undefined, a padding row's are zero rows
         # of a real expert: both are no pick, and 0 * garbage is not 0

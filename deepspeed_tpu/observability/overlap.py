@@ -40,6 +40,15 @@ rest as ``plan``.
 Terminal **requests** go into a second ring (``note_request``) with
 their own stamps: submit, admit, first token, finish.
 
+The device's half of the same question is ``SCOPES``: the layer names
+the model code declares with ``jax.named_scope`` where the work happens.
+A scope is trace-time metadata — the compiled program is the same
+program with or without it — and a TPU trace event carries only its HLO
+instruction, so the join runs through the program's own compiled text:
+``program_scopes()`` reads the optimized HLO of the step programs this
+process has loaded and returns ``{scope_key(instruction): (scope,
+recomputed)}``.  It is built when asked for and at no other time.
+
 Contract (same as every observability hook in this repo):
   - **no new device syncs** in any path;
   - disabled (default), every engine call site is ONE attribute check
@@ -58,9 +67,11 @@ Contract (same as every observability hook in this repo):
 """
 from __future__ import annotations
 
+import functools
+import re
 import threading
 import time
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Dict, Iterable, List, Optional, Tuple
 
 import numpy as np
 
@@ -72,6 +83,41 @@ PHASES = ("plan", "operands", "enqueue", "device_wait", "apply")
 PLAN, OPERANDS, ENQUEUE, DEVICE_WAIT, APPLY = range(len(PHASES))
 ITERATION_SPAN = "serving/iteration"
 PHASE_SPANS = tuple(f"serving/{p}" for p in PHASES)
+
+#: the layers of a step program, as the model code names them with
+#: ``jax.named_scope``: model-agnostic, each used wherever that work
+#: happens in any configuration.  Scopes nest and the innermost declared
+#: one owns an operation.  ``attn_proj``: q/k/v or the latent down/up
+#: projections, rotary, the output projection; ``attn_kernel``: the
+#: attention call and what feeds it; ``pool_write``: new rows into the
+#: paged pool; ``expert_layout``: the sort into tiles, gather and combine
+#: around the grouped product (``experts``); ``head``: final norm, logits,
+#: the finite flag; ``zero_comm``: the casts, gathers and scatters that
+#: move state between its partitioned form and the form compute uses.
+SCOPES = ("embed", "norm", "residual", "attn_proj", "attn_kernel",
+          "pool_write", "mlp", "router", "expert_layout", "experts",
+          "shared_expert", "head", "sample", "loss", "optimizer",
+          "zero_comm")
+#: an instruction under no declared scope / one whose key two loaded
+#: programs map to different scopes
+UNNAMED, AMBIGUOUS = "unnamed", "ambiguous"
+
+
+def scoped(name: str):
+    """Decorator: the whole function runs under the declared device scope
+    ``name`` (``jax.named_scope``, looked up when the function is
+    traced)."""
+    if name not in SCOPES:
+        raise ValueError(f"{name!r} is not one of SCOPES")
+
+    def wrap(fn):
+        @functools.wraps(fn)
+        def inner(*args, **kwargs):
+            import jax
+            with jax.named_scope(name):
+                return fn(*args, **kwargs)
+        return inner
+    return wrap
 
 #: what an iteration's dispatches did (``count_dispatch``)
 COUNTERS = ("dispatches", "decode_rows", "chunk_rows", "rows_computed",
@@ -107,6 +153,112 @@ NAN = float("nan")
 
 def _or_nan(stamp: Optional[float]) -> float:
     return NAN if stamp is None else stamp
+
+
+# -- device scopes: from a program's compiled text to its layer names -----
+_INSTRUCTION = re.compile(r"\s*(?:ROOT )?(%[^\s=]+) = (.+?) [\w-]+\(")
+_LAYOUT = re.compile(r"\{[^{}]*\}")
+_OP_NAME = re.compile(r'metadata=\{[^}]*?op_name="([^"]*)"')
+_FUSED = re.compile(r" fusion\(.*?, calls=(%[^\s,]+)")
+_COMPUTATION = re.compile(r"(?:ENTRY )?(%[^\s=]+) \(.*\{$")
+_JIT_NAME = re.compile(r"\bp?jit\([^()]*\)")
+_NAME = re.compile(r"%[^\s,(){}]+")
+
+
+def scope_key(instruction: str) -> Optional[str]:
+    """What a TPU trace's "XLA Ops" event name and a line of optimized
+    HLO text share: the instruction's name and its result shape, layouts
+    dropped (the two print layouts and operands differently).  None for
+    text that is no instruction."""
+    m = _INSTRUCTION.match(instruction)
+    return None if m is None else _key(m)
+
+
+def _key(m: "re.Match") -> str:
+    return f"{m.group(1)} = {_LAYOUT.sub('', m.group(2))}"
+
+
+def scope_of(op_name: str) -> Tuple[str, bool]:
+    """An ``op_name`` path (``jit(step)/transpose(jvp(head))/dot_general``)
+    -> ``(the innermost declared scope or UNNAMED, whether the operation
+    is a backward pass's recomputation of its forward)``.  ``jvp(..)``,
+    ``transpose(..)`` and the like are looked through; a jitted
+    function's own name is not a scope."""
+    parts = re.split(r"[/()]+", _JIT_NAME.sub("", op_name))
+    scope = next((p for p in reversed(parts) if p in SCOPES), UNNAMED)
+    return scope, "rematted_computation" in parts
+
+
+def _operands(rest: str) -> List[str]:
+    """The operand names of an instruction, from the text that follows
+    its opcode's opening parenthesis (layouts nest parentheses)."""
+    depth = 1
+    for at, ch in enumerate(rest):
+        depth += (ch == "(") - (ch == ")")
+        if not depth:
+            return _NAME.findall(rest[:at])
+    return []
+
+
+def _scopes_of_program(text: str) -> Dict[str, Tuple[str, bool]]:
+    """One program's optimized HLO text -> ``{scope_key: (scope,
+    recomputed)}`` for every instruction that can be a trace event (those
+    inside a fused computation never are).  An instruction the model
+    code gave no scope — the layer scan's slice of a stacked weight, a
+    copy the compiler made to suit a kernel's layout — is what feeds its
+    users: where those are of one declared scope, it takes it."""
+    lines = text.splitlines()
+    fused = {m.group(1) for m in map(_FUSED.search, lines) if m}
+    computation, skip = [], False
+    out: Dict[str, Tuple[str, bool]] = {}
+
+    def close():
+        feeds: Dict[str, set] = {}
+        for key, (scope, remat), operands in reversed(computation):
+            users = feeds.get(key.split(" = ", 1)[0], ())
+            if scope == UNNAMED and len(users) == 1:
+                scope, = users
+            if scope != UNNAMED:
+                for name in operands:
+                    feeds.setdefault(name, set()).add(scope)
+            out[key] = (scope, remat)
+        computation.clear()
+
+    for ln in lines:
+        head = _COMPUTATION.match(ln)
+        if head is not None:
+            close()
+            skip = head.group(1) in fused
+            continue
+        m = None if skip else _INSTRUCTION.match(ln)
+        if m is not None:
+            op_name = _OP_NAME.search(ln)
+            computation.append((
+                _key(m),
+                scope_of(op_name.group(1)) if op_name else (UNNAMED, False),
+                _operands(ln[m.end():])))
+    close()
+    return out
+
+
+def scope_table(texts: Iterable[str]) -> Dict[str, Tuple[str, bool]]:
+    """The programs' tables merged.  A program that declares no scope at
+    all is none of the model's (a cast, a seed) and is left out; a key
+    that two programs map to different scopes is ``AMBIGUOUS``."""
+    table: Dict[str, Tuple[str, bool]] = {}
+    for text in texts:
+        scopes = _scopes_of_program(text)
+        if all(s == UNNAMED for s, _ in scopes.values()):
+            continue
+        for key, (scope, remat) in scopes.items():
+            seen = table.get(key)
+            if seen is None:
+                table[key] = (scope, remat)
+            elif seen[0] != scope:
+                table[key] = (AMBIGUOUS, False)
+            else:
+                table[key] = (scope, seen[1] and remat)
+    return table
 
 
 class _Ring:
@@ -418,6 +570,23 @@ class OverlapProfiler:
         with self._lock:
             self._its.n = self._reqs.n = 0
         self.iteration = -1
+
+    # -- device scopes -----------------------------------------------------
+    def program_scopes(self) -> Dict[str, Tuple[str, bool]]:
+        """``{scope_key(instruction): (scope, recomputed)}`` over the
+        step programs this process has loaded, read from the optimized
+        HLO the runtime's loaded executables carry: nothing is traced,
+        lowered or compiled for it, and nothing is kept between calls.
+        Join it to a trace with ``scope_key(event name)``; a key it lacks
+        is ``UNNAMED``."""
+        import jax.extend
+        texts = []
+        for exe in jax.extend.backend.get_backend().live_executables():
+            try:
+                texts += [m.to_string() for m in exe.hlo_modules()]
+            except jax.errors.JaxRuntimeError:   # it carries no text
+                continue
+        return scope_table(texts)
 
     # -- export (tracer event source) --------------------------------------
     def chrome_events(self, epoch_ns: int, rank: int

@@ -125,4 +125,5 @@ def fused_linear_xent(x, w, labels, mask=None, bias=None, *,
                 db.astype(jnp.result_type(b)))
 
     run.defvjp(run_fwd, run_bwd)
-    return run(x, w, bias)
+    with jax.named_scope("loss"):
+        return run(x, w, bias)

@@ -360,11 +360,12 @@ class DeepSpeedEngine:
             gsum, lsum = carry
             loss, grads = jax.value_and_grad(self._micro_loss)(
                 state["params"], mb, scale)
-            grads = constrain(
-                jax.tree_util.tree_map(lambda g: g.astype(jnp.float32),
-                                       grads),
-                self.mesh, self.grad_specs)
-            gsum = jax.tree_util.tree_map(jnp.add, gsum, grads)
+            with jax.named_scope("optimizer"):
+                grads = constrain(
+                    jax.tree_util.tree_map(lambda g: g.astype(jnp.float32),
+                                           grads),
+                    self.mesh, self.grad_specs)
+                gsum = jax.tree_util.tree_map(jnp.add, gsum, grads)
             return (gsum, lsum + loss), None
 
         zeros = _tree_zeros_f32(state["params"])
@@ -590,9 +591,10 @@ class DeepSpeedEngine:
     def _cast_for_compute(self, params):
         if self.compute_dtype == jnp.float32:
             return params
-        return jax.tree_util.tree_map(
-            lambda p: p.astype(self.compute_dtype)
-            if jnp.issubdtype(p.dtype, jnp.floating) else p, params)
+        with jax.named_scope("zero_comm"):
+            return jax.tree_util.tree_map(
+                lambda p: p.astype(self.compute_dtype)
+                if jnp.issubdtype(p.dtype, jnp.floating) else p, params)
 
     def _current_scale(self, state):
         """The live loss scale as a traced f32 scalar (1.0 when no scaler)."""
@@ -682,7 +684,9 @@ class DeepSpeedEngine:
         def step_fn(state, batch):
             scale = self._current_scale(state)
             gsum, lsum = self._accumulate_micro_grads(state, batch, scale)
-            new_state, metrics = self._apply_grads(state, gsum, float(gas))
+            with jax.named_scope("optimizer"):
+                new_state, metrics = self._apply_grads(state, gsum,
+                                                       float(gas))
             metrics["loss"] = lsum / (scale * gas)
             return new_state, metrics
 

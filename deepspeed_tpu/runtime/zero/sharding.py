@@ -284,7 +284,10 @@ def to_named(mesh: Mesh, spec_tree):
 
 
 def constrain(tree, mesh: Mesh, spec_tree):
-    """with_sharding_constraint over a tree (inside jit)."""
-    return jax.tree_util.tree_map(
-        lambda x, s: jax.lax.with_sharding_constraint(
-            x, NamedSharding(mesh, s)), tree, spec_tree)
+    """with_sharding_constraint over a tree (inside jit): where ZeRO's
+    gathers and scatters are placed, so under the device scope
+    ``zero_comm``."""
+    with jax.named_scope("zero_comm"):
+        return jax.tree_util.tree_map(
+            lambda x, s: jax.lax.with_sharding_constraint(
+                x, NamedSharding(mesh, s)), tree, spec_tree)

@@ -382,3 +382,108 @@ def test_phase_annotations_lie_inside_the_callers_span(srv, ovl, tmp_path):
     assert firsts == sorted(firsts)
     assert sum(len(spans[name]) for name in overlap.PHASE_SPANS) >= \
         5 * (n_it - 1)
+
+
+# -- device scopes: a program's compiled text -> its layer names ------------
+def _program(*instructions, fused=()):
+    """Optimized-HLO-shaped text: an entry computation of
+    ``instructions`` (``name shape opcode(operands) | op_name``) after a
+    fused computation of ``fused``."""
+    def line(spec):
+        text, _, op_name = spec.partition(" | ")
+        meta = f', metadata={{op_name="{op_name}" stack_frame_id=3}}' \
+            if op_name else ""
+        return f"  {text}{meta}"
+    return "\n".join(
+        ["HloModule jit_step, is_scheduled=true", "",
+         "%fused_computation.1 (param_0.1: bf16[8,8]) -> bf16[8,8] {"]
+        + [line(s) for s in fused]
+        + ["}", "", "ENTRY %main.9 (Arg_0.1: bf16[8,8]) -> bf16[8,8] {"]
+        + [line(s) for s in instructions] + ["}", ""])
+
+
+_DOT = "%fusion.7 = bf16[8,8]{1,0:T(8,128)(2,1)} fusion(%p), " \
+       "kind=kOutput, calls=%fused_computation.1"
+
+
+@pytest.mark.parametrize("programs,key,want", [
+    # jvp(..) / transpose(..) are looked through
+    ([_program(_DOT + " | jit(step)/transpose(jvp(head))/dot_general")],
+     "%fusion.7 = bf16[8,8]", ("head", False)),
+    ([_program(_DOT + " | jit(step)/jvp()/while/body/closed_call/mlp/dot")],
+     "%fusion.7 = bf16[8,8]", ("mlp", False)),
+    # nested scopes: the innermost declared one owns the operation
+    ([_program(_DOT + " | jit(step)/optimizer/zero_comm/convert")],
+     "%fusion.7 = bf16[8,8]", ("zero_comm", False)),
+    ([_program(_DOT + " | jit(step)/shared_expert/jit(silu)/mul")],
+     "%fusion.7 = bf16[8,8]", ("shared_expert", False)),
+    # a jitted function's own name is no scope
+    ([_program(_DOT + " | jit(loss)/jit(norm)/mul",
+               "%copy.1 = bf16[8,8]{1,0} copy(%fusion.7) | jit(f)/mlp/x")],
+     "%fusion.7 = bf16[8,8]", ("mlp", False)),     # ... it feeds the mlp
+    # a backward pass's recomputation of its forward
+    ([_program(_DOT + " | jit(step)/transpose(jvp())/while/body/"
+               "closed_call/checkpoint/rematted_computation/mlp/dot")],
+     "%fusion.7 = bf16[8,8]", ("mlp", True)),
+    ([_program(_DOT + " | jit(step)/transpose(jvp())/while/body/"
+               "closed_call/checkpoint/mlp/dot")],
+     "%fusion.7 = bf16[8,8]", ("mlp", False)),
+    # two loaded programs disagree about one key
+    ([_program(_DOT + " | jit(step)/mlp/dot"),
+      _program(_DOT + " | jit(step)/head/dot")],
+     "%fusion.7 = bf16[8,8]", ("ambiguous", False)),
+    ([_program(_DOT + " | jit(step)/mlp/dot"),
+      _program(_DOT + " | jit(step)/mlp/dot")],
+     "%fusion.7 = bf16[8,8]", ("mlp", False)),
+    # a program that declares no scope is none of the model's
+    ([_program(_DOT + " | jit(step)/mlp/dot"),
+      _program(_DOT + " | jit(convert_element_type)/convert")],
+     "%fusion.7 = bf16[8,8]", ("mlp", False)),
+    # no metadata, and feeding nothing that has a scope
+    ([_program(_DOT, "%copy.1 = bf16[8,8]{1,0} copy(%p) | jit(f)/mlp/x")],
+     "%fusion.7 = bf16[8,8]", ("unnamed", False)),
+    # no scope of its own: what it feeds, if that is ONE scope — through
+    # an instruction that is no event, and tuple shapes with layouts
+    ([_program("%slice.3 = (bf16[1,8,8]{2,1,0:T(8,128)(2,1)S(1)}, u32[]"
+               "{:S(2)}) fusion(%w), kind=kLoop, calls=%fused_computation.1"
+               " | jit(step)/while/body/dynamic_slice",
+               "%bitcast.2 = bf16[8,8]{1,0} bitcast(%slice.3)",
+               _DOT.replace("(%p)", "(%p, %bitcast.2)")
+               + " | jit(step)/while/body/closed_call/attn_proj/dot")],
+     "%slice.3 = (bf16[1,8,8], u32[])", ("attn_proj", False)),
+    ([_program("%copy.5 = bf16[8,8]{1,0} copy(%w)",
+               _DOT.replace("(%p)", "(%copy.5)") + " | jit(step)/mlp/dot",
+               "%fusion.8 = bf16[8,8]{1,0} fusion(%copy.5), kind=kLoop, "
+               "calls=%fused_computation.1 | jit(step)/head/dot")],
+     "%copy.5 = bf16[8,8]", ("unnamed", False)),
+    # an instruction inside a fused computation is never an event
+    ([_program(_DOT + " | jit(step)/mlp/dot", fused=[
+        "%convolution.2 = bf16[8,8]{1,0} convolution(%param_0.1, "
+        "%param_0.1), dim_labels=bf_io->bf | jit(step)/mlp/dot"])],
+     "%convolution.2 = bf16[8,8]", None),
+], ids=["transpose_jvp", "jvp_scan", "innermost", "through_jit",
+        "jit_name_is_no_scope", "recompute", "checkpoint_backward",
+        "ambiguous", "programs_agree", "foreign_program", "unnamed",
+        "feeds_one_scope", "feeds_two_scopes", "fused_is_no_event"])
+def test_scope_table_from_compiled_text(programs, key, want):
+    table = overlap.scope_table(programs)
+    assert table.get(key) == want
+    # an event prints operands with their shapes and no metadata: same key
+    event = "%fusion.7 = bf16[8,8]{1,0:T(8,128)(2,1)S(1)} fusion(bf16[8,8]" \
+            "{1,0:T(8,128)(2,1)} %p), kind=kOutput, calls=%fused_computation.1"
+    assert overlap.scope_key(event) == "%fusion.7 = bf16[8,8]"
+    assert overlap.scope_key("jit_step(1234)") is None
+
+
+def test_program_scopes_reads_the_loaded_step_program(srv):
+    """On request, from the executables the process has loaded: the tiny
+    engine's two step shapes name their layers, and the vocabulary is
+    the declared one."""
+    srv.submit(np.arange(3, dtype=np.int32), max_new_tokens=2)
+    srv.run()
+    found = {scope for scope, _ in
+             get_overlap_profiler().program_scopes().values()}
+    assert {"attn_proj", "attn_kernel", "mlp", "head"} <= found
+    assert found <= set(overlap.SCOPES) | {overlap.UNNAMED,
+                                           overlap.AMBIGUOUS}
+    assert len(overlap.SCOPES) <= 16

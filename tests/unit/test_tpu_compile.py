@@ -288,6 +288,56 @@ def test_paged_rejects_shapes_the_tpu_cannot_tile():
                                interpret=False)
 
 
+#: step programs compiled once a process and shared by the tests below
+#: (a test keyed the same way finds its program here)
+_COMPILED = {}
+
+
+def compile_mixed(devices, model, nb, block, kv_bits, slots, pages, chunk):
+    """``model._apply_paged_mixed`` with donated pools, compiled for one
+    v5e chip on abstract bfloat16 arguments: ``(compiled, the pools'
+    abstract arrays)``."""
+    sds = one_chip(devices)
+
+    def abstract(tree, dtype=None):
+        return jax.tree_util.tree_map(
+            lambda a: sds(a.shape, dtype or a.dtype), tree)
+    params = abstract(jax.eval_shape(model.init, jax.random.PRNGKey(0)),
+                      jnp.bfloat16)
+    cache = abstract(jax.eval_shape(
+        lambda: model.init_paged_cache(nb, block, jnp.bfloat16, kv_bits)))
+    pools = {k: v for k, v in cache.items() if v is not None}
+    cache["block_tables"] = sds((slots, pages), jnp.int32)
+    cache["lens"] = sds((slots,), jnp.int32)
+    scalar = sds((), jnp.int32)
+    compiled = jax.jit(model._apply_paged_mixed, donate_argnums=1).trace(
+        params, cache, sds((slots,), jnp.int32), sds((slots,), jnp.int32),
+        sds((chunk,), jnp.int32), scalar, scalar, scalar).lower(
+            lowering_platforms=("tpu",)).compile()
+    return compiled, pools, params
+
+
+def compiled_once(key, build):
+    if key not in _COMPILED:
+        _COMPILED[key] = build()
+    return _COMPILED[key]
+
+
+def build_dense_mixed(devices, d, kv_bits, block, nb, chunk):
+    """The dense mixed step at 4 layers of 16 heads of head dim ``d``,
+    24 slots, 2,048 positions: ``(compiled, pools)``."""
+    model = TransformerLM(gpt2_config(
+        "125m", num_layers=4, d_model=16 * d, num_heads=16,
+        vocab_size=512, max_seq_len=2048))
+    return compile_mixed(devices, model, nb, block, kv_bits, 24,
+                         2048 // block, chunk)[:2]
+
+
+def compile_dense_mixed(devices, *size):
+    return compiled_once(("dense",) + size,
+                         lambda: build_dense_mixed(devices, *size))
+
+
 @pytest.mark.parametrize("chunk", [256, 0], ids=["mixed", "decode_only"])
 @pytest.mark.parametrize("d", [64, 128])
 @pytest.mark.parametrize("kv_bits,block,nb", [(0, 16, 1920),
@@ -309,27 +359,9 @@ def test_mixed_step_keeps_the_pool_in_place(v5e_devices, compiled_kernels,
     has no chunk) keeps the pool in place as well, and holds ONE kernel
     call a layer: the chunk kernel's is gone."""
     import re
-    layers, heads, slots = 4, 16, 24
-    sds = one_chip(v5e_devices)
-    model = TransformerLM(gpt2_config(
-        "125m", num_layers=layers, d_model=heads * d, num_heads=heads,
-        vocab_size=512, max_seq_len=2048))
-
-    def abstract(tree, dtype=None):
-        return jax.tree_util.tree_map(
-            lambda a: sds(a.shape, dtype or a.dtype), tree)
-    params = abstract(jax.eval_shape(model.init, jax.random.PRNGKey(0)),
-                      jnp.bfloat16)
-    cache = abstract(jax.eval_shape(
-        lambda: model.init_paged_cache(nb, block, jnp.bfloat16, kv_bits)))
-    pools = dict(cache)
-    cache["block_tables"] = sds((slots, 2048 // block), jnp.int32)
-    cache["lens"] = sds((slots,), jnp.int32)
-    scalar = sds((), jnp.int32)
-    compiled = jax.jit(model._apply_paged_mixed, donate_argnums=1).trace(
-        params, cache, sds((slots,), jnp.int32), sds((slots,), jnp.int32),
-        sds((chunk,), jnp.int32), scalar, scalar, scalar).lower(
-            lowering_platforms=("tpu",)).compile()
+    layers = 4
+    compiled, pools = compile_dense_mixed(v5e_devices, d, kv_bits, block,
+                                          nb, chunk)
     text = compiled.as_text()
     # decode + chunk kernels in the scanned layer; the decode kernel alone
     # in the decode-only shape
@@ -414,6 +446,21 @@ def _sandwich_case():
         max_seq_len=4096, experts_held=(0, 8)), 8, 128, 256, 7
 
 
+def build_latent_mixed(devices, case, chunk):
+    """A latent block's mixed step at its cell's widths over a pool of
+    4,096 blocks of 16: ``(model, compiled, pools, params)``."""
+    from deepspeed_tpu.models import build_model
+    config, _, slots, pages, _ = case()
+    model = build_model(config)
+    return (model,) + compile_mixed(devices, model, 4096, 16, 0, slots,
+                                    pages, chunk)
+
+
+def compile_latent_mixed(devices, case, chunk):
+    return compiled_once((case.__name__, chunk),
+                         lambda: build_latent_mixed(devices, case, chunk))
+
+
 @pytest.mark.parametrize("chunk", [512, 0], ids=["mixed", "decode_only"])
 @pytest.mark.parametrize("case", [_shortcut_case, _sandwich_case],
                          ids=["shortcut", "sandwich"])
@@ -432,30 +479,14 @@ def test_latent_mixed_step_keeps_pool_and_experts_in_place(
     the decode-only shape (``chunk`` 0), which calls the latent kernel
     once an attention sublayer: the chunk lane's calls are gone."""
     import re
-    from deepspeed_tpu.models import build_model
     config, held, slots, pages, kernels = case()
     nb = 4096
-    sds = one_chip(v5e_devices)
-    model = build_model(config)
+    model, compiled, pools, params = compile_latent_mixed(v5e_devices, case,
+                                                          chunk)
     sublayers = model.ATTN_SUBLAYERS * config.num_layers
     stacked = config.scan_length
-
-    def abstract(tree, dtype=None):
-        return jax.tree_util.tree_map(
-            lambda a: sds(a.shape, dtype or a.dtype), tree)
-    params = abstract(jax.eval_shape(model.init, jax.random.PRNGKey(0)),
-                      jnp.bfloat16)
-    cache = abstract(jax.eval_shape(
-        lambda: model.init_paged_cache(nb, 16, jnp.bfloat16)))
-    pools = {"k": cache["k"]}              # one buffer: "v" is None
-    assert cache["k"].shape[0] == sublayers
-    cache["block_tables"] = sds((slots, pages), jnp.int32)
-    cache["lens"] = sds((slots,), jnp.int32)
-    scalar = sds((), jnp.int32)
-    compiled = jax.jit(model._apply_paged_mixed, donate_argnums=1).trace(
-        params, cache, sds((slots,), jnp.int32), sds((slots,), jnp.int32),
-        sds((chunk,), jnp.int32), scalar, scalar, scalar).lower(
-            lowering_platforms=("tpu",)).compile()
+    assert list(pools) == ["k"]            # one buffer: "v" is None
+    assert pools["k"].shape[0] == sublayers
     text = compiled.as_text()
     # the scanned layer's attention sublayers x (decode + chunk) and its
     # three grouped products; a leading layer's decode + chunk.  Without
@@ -677,3 +708,150 @@ def test_serving_step_sorts_only_inside_a_conditional(v5e_devices,
     unconditional_sorts = [ln.strip()[:120] for name in always
                            for ln in comps[name] if " sort(" in ln]
     assert not unconditional_sorts, unconditional_sorts
+
+
+# ---------------------------------------------------------------------------
+# device scopes: the layer names the model code declares, read back from
+# the compiled step programs' own text (observability/overlap.py)
+# ---------------------------------------------------------------------------
+def build_train_step(devices, chips):
+    """The engine's fused train step, built by the engine itself over
+    ``chips`` described devices (ZeRO-2 on one, ZeRO-3 over ``data: 4``
+    as the two training cells) on abstract state: two layers of GPT-2
+    350M's widths, flash, full remat, the chunked fused loss head."""
+    from deepspeed_tpu.runtime.engine import DeepSpeedEngine
+    mesh = build_mesh(MeshConfig(data=chips), devices=devices[:chips])
+    model = TransformerLM(gpt2_config(
+        "350m", num_layers=2, max_seq_len=1024, vocab_size=8192,
+        remat="full", attn_impl="flash", loss_chunk=256))
+    engine = DeepSpeedEngine(model, {
+        "train_micro_batch_size_per_gpu": 2, "steps_per_print": 0,
+        "bf16": {"enabled": True}, "gradient_clipping": 1.0,
+        "optimizer": {"type": "AdamW", "params": {"lr": 3e-4}},
+        "zero_optimization": {"stage": 3 if chips > 1 else 2},
+        "mesh": {"data": chips}}, mesh=mesh, dont_init=True)
+
+    def placed(shapes, shardings):
+        return jax.tree_util.tree_map(
+            lambda a, sh: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                               sharding=sh),
+            shapes, shardings)
+    state = placed(jax.eval_shape(engine.init_state, jax.random.PRNGKey(0)),
+                   engine.state_shardings())
+    batch = {"input_ids": jax.ShapeDtypeStruct((1, 2 * chips, 1024),
+                                               jnp.int32)}
+    batch = placed(batch, jax.tree_util.tree_map(
+        lambda sp: NamedSharding(mesh, sp), engine._batch_spec_tree(batch)))
+    return engine._build_train_step().trace(state, batch).lower(
+        lowering_platforms=("tpu",)).compile()
+
+
+#: every step program a benchmark cell runs, at this module's sizes:
+#: name -> (the program compiled once a process, the same compiled anew,
+#: the scopes it must show); both take the described devices
+_LAYER = {"embed", "norm", "residual", "attn_proj", "attn_kernel", "head"}
+_SERVE = _LAYER | {"pool_write", "mlp"}
+_EXPERTS = _SERVE | {"router", "expert_layout", "experts"}
+_TRAIN = _LAYER | {"mlp", "loss", "optimizer", "zero_comm"}
+STEP_PROGRAMS = {}
+for _shape, _dense, _latent in (("mixed", 256, 512), ("decode_only", 0, 0)):
+    _size = (128, 0, 16, 1920, _dense)
+    STEP_PROGRAMS[f"dense-{_shape}"] = (
+        lambda dev, size=_size: compile_dense_mixed(dev, *size)[0],
+        lambda dev, size=_size: build_dense_mixed(dev, *size)[0], _SERVE)
+    for _block, _case, _own in (("shortcut", _shortcut_case, set()),
+                                ("sandwich", _sandwich_case,
+                                 {"shared_expert"})):
+        STEP_PROGRAMS[f"{_block}-{_shape}"] = (
+            lambda dev, case=_case, c=_latent: compile_latent_mixed(
+                dev, case, c)[1],
+            lambda dev, case=_case, c=_latent: build_latent_mixed(
+                dev, case, c)[1], _EXPERTS | _own)
+for _cell, _chips in (("1chip", 1), ("zero3-4chip", 4)):
+    STEP_PROGRAMS[f"train-{_cell}"] = (
+        lambda dev, n=_chips: compiled_once(
+            ("train", n), lambda: build_train_step(dev, n)),
+        lambda dev, n=_chips: build_train_step(dev, n), _TRAIN)
+
+
+def step_program_text(devices, name, fresh=False) -> str:
+    return STEP_PROGRAMS[name][fresh](devices).as_text()
+
+
+@pytest.mark.parametrize("name", list(STEP_PROGRAMS))
+def test_step_programs_carry_their_scopes(v5e_devices, compiled_kernels,
+                                          name):
+    """What ``program_scopes()`` will find on the chip, checked on each
+    cell's step program compiled for it: every scope the configuration
+    has is in the program's text (a scope whose operations XLA fused
+    into another's, as it does the residual adds, is in no instruction
+    of its own); each Pallas call resolves to ``attn_kernel`` or
+    ``experts``; and at least nine in ten of the instructions that can
+    be trace events and do work (fusions, convolutions, copies, custom
+    calls) resolve to a declared scope."""
+    import re
+    from deepspeed_tpu.observability.overlap import (
+        SCOPES, UNNAMED, scope_key, scope_of, scope_table)
+    text = step_program_text(v5e_devices, name)
+    table = scope_table([text])
+    declared = {scope_of(op_name)[0]
+                for op_name in re.findall(r'op_name="([^"]*)"', text)}
+    assert STEP_PROGRAMS[name][2] <= declared <= set(SCOPES) | {UNNAMED}
+    assert {scope for scope, _ in table.values()} <= declared
+    kernels = [ln for ln in text.splitlines()
+               if re.search(r' custom-call\(.*"tpu_custom_call"', ln)]
+    assert kernels
+    for ln in kernels:
+        want = "experts" if "%moe_grouped_matmul" in ln else "attn_kernel"
+        assert table[scope_key(ln)][0] == want, ln[:200]
+    work = [k for k in table
+            if re.match(r"%[\w.-]*(fusion|convolution|copy|custom-call)"
+                        r"[.\d]* = ", k)]
+    named = [k for k in work if table[k][0] != UNNAMED]
+    assert len(named) >= 0.9 * len(work), sorted(set(work) - set(named))
+    if name.startswith("train"):
+        assert any(remat for _, remat in table.values())   # remat="full"
+
+
+@pytest.mark.parametrize("name", list(STEP_PROGRAMS))
+def test_scopes_change_no_instruction(v5e_devices, compiled_kernels,
+                                      monkeypatch, name):
+    """A scope is metadata: with ``jax.named_scope`` patched to a null
+    context the same step program compiles to the same optimized HLO,
+    every ``metadata={..}`` aside: the same instructions in the same
+    order over the same operands, the same kernel bodies.  (The number
+    XLA appends to a name, ``%fusion.248``, counts the instructions made
+    while lowering and shifts with the name stack; a name is compared by
+    where it first appears.)"""
+    import base64
+    import contextlib
+    import re
+    from jax._src.lib.mlir import ir
+
+    def kernel_body(match):
+        """A Mosaic call carries its kernel as MLIR bytecode, source
+        locations and name stack included: the same without them."""
+        with ir.Context() as ctx:
+            ctx.allow_unregistered_dialects = True
+            body = ir.Module.parse(base64.b64decode(match.group(1)))
+            return body.operation.get_asm(enable_debug_info=False)
+
+    def stripped(text):
+        """Without the metadata and the source tables it points into
+        (the kernels' own too), every name replaced by its order of
+        first appearance."""
+        text = re.sub(r"\nFileNames\n.*?\nStackFrames\n.*?\n\n", "\n",
+                      text, count=1, flags=re.S)
+        text = re.sub(r",? ?metadata=\{[^}]*\}", "", text)
+        text = re.sub(r'(?<="body":")([^"]+)(?=")', kernel_body, text)
+        seen = {}
+        return re.sub(
+            r"%[\w.-]+",
+            lambda m: seen.setdefault(m.group(0), f"%{len(seen)}"), text)
+    with_scopes = stripped(step_program_text(v5e_devices, name))
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    jax.clear_caches()
+    without = stripped(step_program_text(v5e_devices, name, fresh=True))
+    jax.clear_caches()
+    assert "op_name" not in without and with_scopes == without
