@@ -263,6 +263,16 @@ def serve_phase(chips: int, model_config, params, log: CompileLog,
     check(all(ran.values()) and sum(ran.values()) == len(one),
           f"serve: dispatches a shape of the step {ran}, rows {rows}")
 
+    # the loop kept an iteration in flight: every dispatch but the idle
+    # engine's first iteration was enqueued before its predecessor's
+    # result was read, and greedy traffic with no eos voids no row
+    n_dispatches = int(its["dispatches"].sum())
+    ahead, void = (int(its[c].sum())
+                   for c in ("ahead_dispatches", "void_rows"))
+    check(n_dispatches - ahead == int(its["dispatches"][0]) and void == 0,
+          f"serve: {ahead} of {n_dispatches} dispatches ran ahead, "
+          f"{void} void row(s)")
+
     for req, (_, new) in zip(submitted, requests):
         check(req.status is RequestStatus.OK,
               f"serve: {req.req_id} ended {req.status} ({req.error})")
@@ -296,6 +306,8 @@ def serve_phase(chips: int, model_config, params, log: CompileLog,
               "compiles_after_warmup": after["compiles"],
               "run_s": round(run_s, 2),
               "decode_builds": builds, "dispatches_by_shape": ran,
+              "dispatches": n_dispatches, "ahead_dispatches": ahead,
+              "void_rows": void,
               "kv_blocks_held_after_drain": held,
               "tpu_custom_calls_in_program": kernels,
               "kv_pool_min_devices": pool["min_devices"],

@@ -552,6 +552,13 @@ class PagedBlockAllocator:
             raise BlockPoolError(f"unknown sequence {seq_id!r}")
         return list(table)
 
+    def blocks_held(self, seq_id: str) -> int:
+        """``len(block_table(seq_id))`` without the copy."""
+        table = self._tables.get(seq_id)
+        if table is None:
+            raise BlockPoolError(f"unknown sequence {seq_id!r}")
+        return len(table)
+
     def free(self, seq_id: str, discard: bool = False) -> None:
         """Release a sequence's blocks (finish or preemption). Shared
         blocks (fork / prefix hits) only leave the tables when the last

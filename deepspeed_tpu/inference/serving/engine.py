@@ -30,6 +30,17 @@ to ONE step compiled in TWO shapes:
     loop re-dispatches one compiled program over the same HBM buffers —
     the iteration-level-scheduling analogue of the CUDA-graph replay
     the reference gets from `inference/engine.py:493`.
+  * **one iteration in flight** (docs/serving.md "The dispatch in
+    flight"): ``step()`` plans and enqueues iteration k+1 from the state
+    *as dispatched* while k is on the device, and only then reads and
+    applies k's result, so the host's whole share of an iteration runs
+    under a device program.  The newest token of every slot stays on the
+    device — the step takes the previous dispatch's result array as an
+    operand and a per-slot source column says whether a row's input
+    token is the host's or that array's.  Whatever cannot be planned
+    from counts alone (a draft armed, a preemption, a promotion, a
+    ``prefill_only`` hand-off) lands the dispatch in flight first: the
+    synchronous order is the drained case of the same loop.
 
 Observability (PR-3 layer): queue-depth / batch-occupancy / blocks-in-
 use / cached-blocks gauges, TTFT + inter-token-latency histograms,
@@ -83,9 +94,11 @@ from .scheduler import (ContinuousBatchingScheduler, Request,
 # what crosses between host and device in one dispatch
 # ---------------------------------------------------------------------------
 # Two int32 arrays in, one int32 array out (docs/serving.md "What a
-# dispatch carries"): a transfer costs the same few hundred microseconds
-# whether it carries 4 bytes or 4 KB, so the per-slot state is ONE
-# ``[num_slots, _SLOT_COLS + max_pages]`` array, the chunk's state ONE
+# dispatch carries"), and that array back in as a device operand of the
+# next dispatch, where a decoding row whose ``_TOKEN_SRC`` says so takes
+# its input token from.  A transfer costs the same few hundred
+# microseconds whether it carries 4 bytes or 4 KB, so the per-slot state
+# is ONE ``[num_slots, _SLOT_COLS + max_pages]`` array, the chunk's ONE
 # ``[_CHUNK_HEAD + chunk_tokens]`` vector (its ``_CHUNK_HEAD`` alone when
 # no chunk rides: the decode-only shape) and the results ONE
 # ``[num_slots, _R_SPEC (+ 2 + spec_k + 1)]`` array.  float32 and uint32
@@ -94,11 +107,18 @@ from .scheduler import (ContinuousBatchingScheduler, Request,
 # temperature, a ``top_p`` or a key changes on the way.
 # (a key is two columns wide)
 (_LENS, _DEC_TOKEN, _DEC_ACTIVE, _SPEC_ACTIVE, _TOP_K, _OUT_IDX, _KEY, _,
- _TEMP, _TOP_P, _SLOT_COLS) = range(11)           # then the block table
+ _TEMP, _TOP_P, _TOKEN_SRC, _SLOT_COLS) = range(12)   # then the block table
+#: ``_TOKEN_SRC``: the row's input token is the host's ``_DEC_TOKEN`` /
+#: the slot's newest token in the previous dispatch's result array
+SRC_HOST, SRC_DEVICE = 0, 1
 (_C_SLOT, _C_START, _C_LEN, _C_TOP_K, _C_OUT_IDX, _C_KEY, _, _C_TEMP,
  _C_TOP_P, _CHUNK_HEAD) = range(10)               # then the chunk's ids
-# the chunk's two scalars ride in every row; with the draft armed the
-# row goes on: n_emit, spec_finite, the spec_k + 1 target samples
+# ``_R_NEXT`` is the slot's NEWEST token: the one this dispatch sampled
+# for a decoding row, the chunk's first token in the slot whose prompt it
+# completed, else the one the slot came in with — so the next dispatch
+# finds every slot's input token there, whichever lane produced it.  The
+# chunk's two scalars ride in every row; with the draft armed the row
+# goes on: n_emit, spec_finite, the spec_k + 1 target samples
 (_R_NEXT, _R_DEC_FINITE, _R_FIRST, _R_CHUNK_FINITE, _R_SPEC) = range(5)
 
 
@@ -118,6 +138,7 @@ class _SlotState(NamedTuple):
     top_p: jax.Array
     keys: jax.Array
     out_idx: jax.Array
+    token_src: jax.Array
 
     @classmethod
     def unpack(cls, slots: jax.Array) -> "_SlotState":
@@ -130,7 +151,8 @@ class _SlotState(NamedTuple):
             top_k=slots[:, _TOP_K],
             top_p=_bits(slots[:, _TOP_P], jnp.float32),
             keys=_bits(slots[:, _KEY:_KEY + 2], jnp.uint32),
-            out_idx=slots[:, _OUT_IDX])
+            out_idx=slots[:, _OUT_IDX],
+            token_src=slots[:, _TOKEN_SRC])
 
 
 class _ChunkState(NamedTuple):
@@ -155,6 +177,21 @@ class _ChunkState(NamedTuple):
             top_p=_bits(chunk[_C_TOP_P], jnp.float32),
             key=_bits(chunk[_C_KEY:_C_KEY + 2], jnp.uint32),
             out_idx=chunk[_C_OUT_IDX])
+
+
+class _Flight(NamedTuple):
+    """One dispatch between its enqueue and the read of its result."""
+    result: jax.Array
+    dec: List[Tuple[int, Request]]
+    chunk: Optional[Tuple[int, Request, int, int]]
+    spec: List[Tuple[int, Request]]
+    #: its chunk completes a prompt: the chunk's row samples a token
+    ends_prefill: bool
+    #: enqueued before its predecessor's result was read
+    ahead: bool
+    t0: float
+    #: ``count_dispatch``'s share known at enqueue (profiler on)
+    counts: Optional[Dict[str, int]]
 
 
 def _pack_results(nxt, dec_finite, first, chunk_finite, *spec_cols,
@@ -414,6 +451,26 @@ class ServingEngine:
         #: spec_k + 1 verify rows; the mixed shape adds the chunk lane
         self._decode_rows_per_dispatch = self.num_slots * (
             1 + (self.spec_k + 1 if draft_model is not None else 0))
+        # -- the dispatch in flight (docs/serving.md) ---------------------
+        #: the iteration on the device: its dispatches, oldest first,
+        #: enqueued and not yet read (empty between a drain and the next
+        #: ``step()``)
+        self._flight: List[_Flight] = []
+        #: the last dispatch's result array, the next one's operand: all
+        #: zeros before the first (no row reads it then), in the
+        #: sharding the step returns it in — sharding is part of the jit
+        #: cache key
+        self._prev_result = jax.device_put(
+            np.zeros((self.num_slots, _R_SPEC + len(model.PAGED_COUNTERS)
+                      + (2 + self.spec_k + 1 if draft_model is not None
+                         else 0)), np.int32),
+            NamedSharding(self.tp_mesh, P(topo.DATA_AXIS, None)))
+        #: when the last result reached the host (the ITL histogram's
+        #: clock: the gap between two successive results)
+        self._last_result_t = 0.0
+        #: plain-int mirror of the overlap profiler's two counters
+        self.flight_counts = {"dispatches": 0, "ahead_dispatches": 0,
+                              "void_rows": 0}
 
         #: incremented at TRACE time inside the step — the "the serving
         #: loop compiles the step's two shapes with its first dispatch
@@ -486,8 +543,8 @@ class ServingEngine:
             "submit -> first token (includes queueing + chunked prefill)")
         self._m_itl = reg.histogram(
             "dstpu_serving_inter_token_seconds",
-            "decode-iteration wall time (per-token latency of every "
-            "active stream)")
+            "time between two successive dispatch results reaching the "
+            "host (per-token latency of every active stream)")
         #: extra histograms that mirror every TTFT/ITL observation —
         #: fleet replica handles register their per-replica ground-truth
         #: series here (observability/fleet_metrics.py merges them
@@ -1242,11 +1299,13 @@ class ServingEngine:
     def cancel(self, req: Request) -> bool:
         """Cancel a request; returns True if it transitioned to
         CANCELLED, False if it was already terminal (idempotent).  Safe
-        at any point BETWEEN dispatches (the serving loop is
+        at any point between two ``step()`` calls (the serving loop is
         single-threaded, so caller code always runs at an iteration
-        boundary): a RUNNING request's computed blocks are commit-cached
+        boundary): a RUNNING request's APPLIED blocks are commit-cached
         first — exactly like preemption — then freed, so a cancelled
-        request's prefix stays warm for shared-prefix siblings."""
+        request's prefix stays warm for shared-prefix siblings.  A row
+        the iteration in flight still carries for it is void: its result
+        is ignored when it lands (``_apply``)."""
         with trace_span("serving/cancel", req=req.req_id):
             ok = self.scheduler.cancel(req)
         self._drain_terminal_events()
@@ -1327,18 +1386,42 @@ class ServingEngine:
             with jax.named_scope("head"):
                 return first, jnp.all(jnp.isfinite(chunk_logits))
 
+        def input_tokens(sl: _SlotState, prev):
+            """Every decoding row's input token: the host's, or — for a
+            row planned while the dispatch that sampled it was still on
+            the device — the slot's newest token in that dispatch's
+            result array.  One select over ``[num_slots]`` integers."""
+            return jnp.where(sl.token_src == SRC_DEVICE, prev[:, _R_NEXT],
+                             sl.dec_tokens)
+
+        def newest_tokens(prev, active, nxt, first, ch: _ChunkState):
+            """The ``_R_NEXT`` column: what each slot's next decode row
+            takes as input — sampled by this dispatch's decode lane or
+            its chunk lane, else carried over from the previous
+            dispatch's, so a dispatch that carries no row for a slot (a
+            chunk remainder's) hands its token on."""
+            newest = jnp.where(active > 0, nxt, prev[:, _R_NEXT])
+            if not ch.ids.shape[0]:
+                return newest
+            rows = newest.shape[0]       # this shard's slots, of all
+            row = jnp.arange(rows) + (
+                lax.axis_index(topo.DATA_AXIS) * rows
+                if self.tp_data_size > 1 else 0)
+            return jnp.where((row == ch.slot) & (ch.len > 0), first, newest)
+
         def step(params, scales, pool_k, pool_v, pool_ks, pool_vs,
-                 slots, chunk):
+                 prev, slots, chunk):
             built()
             # slices of an operand are free: the two host arrays come
             # apart first thing
             sl, ch = _SlotState.unpack(slots), _ChunkState.unpack(chunk)
+            dec_tokens = input_tokens(sl, prev)
             mp = engine._model_params(params, scales)
             cache = {"k": pool_k, "v": pool_v, "k_scale": pool_ks,
                      "v_scale": pool_vs, "block_tables": sl.tables,
                      "lens": sl.lens}
             dec_logits, chunk_logits, cache = model._apply_paged_mixed(
-                mp, cache, sl.dec_tokens, sl.dec_active, ch.ids, ch.slot,
+                mp, cache, dec_tokens, sl.dec_active, ch.ids, ch.slot,
                 ch.start, ch.len)
             # in-program per-slot sampling: output token j of a request
             # is ALWAYS drawn with fold_in(request_key, j) — batch-,
@@ -1356,16 +1439,24 @@ class ServingEngine:
             with jax.named_scope("head"):
                 dec_finite = jnp.all(jnp.isfinite(dec_logits), axis=-1)
             with jax.named_scope("sample"):
-                packed = _pack_results(nxt, dec_finite, first, chunk_finite,
-                                       counters=cache.get("counters"))
+                packed = _pack_results(
+                    newest_tokens(prev, sl.dec_active, nxt, first, ch),
+                    dec_finite, first, chunk_finite,
+                    counters=cache.get("counters"))
             return (packed, cache["k"], cache["v"],
                     cache.get("k_scale"), cache.get("v_scale"))
 
         def spec_step(params, scales, dparams, pool_k, pool_v, pool_ks,
-                      pool_vs, dpool_k, dpool_v, slots, chunk):
+                      pool_vs, dpool_k, dpool_v, prev, slots, chunk):
             built()
-            (tables, lens, dec_tokens, dec_active, spec_active, temp,
-             top_k, top_p, keys, out_idx) = _SlotState.unpack(slots)
+            sl = _SlotState.unpack(slots)
+            (tables, lens, _host_tokens, dec_active, spec_active, temp,
+             top_k, top_p, keys, out_idx, _src) = sl
+            # the same operand and the same select as the plain step; a
+            # speculating slot's lengths are the device's to say
+            # (n_emit), so the loop plans nothing past this dispatch and
+            # every row's source is the host
+            dec_tokens = input_tokens(sl, prev)
             ch = _ChunkState.unpack(chunk)
             mp = engine._model_params(params, scales)
             empty = jnp.zeros((0,), jnp.int32)
@@ -1433,8 +1524,10 @@ class ServingEngine:
             dec_finite = jnp.all(jnp.isfinite(dec_logits), axis=-1)
             spec_finite = jnp.all(jnp.isfinite(spec_logits),
                                   axis=(-2, -1))
-            return (_pack_results(nxt, dec_finite, first, chunk_finite,
-                                  n_emit, spec_finite, samples=s),
+            return (_pack_results(
+                        newest_tokens(prev, dec_active, nxt, first, ch),
+                        dec_finite, first, chunk_finite, n_emit,
+                        spec_finite, samples=s),
                     cache["k"], cache["v"], cache.get("k_scale"),
                     cache.get("v_scale"), dcache["k"], dcache["v"])
 
@@ -1449,8 +1542,9 @@ class ServingEngine:
             donate = (2, 3) + ((4, 5) if self.kv_bits else ())
         # the body runs shard_mapped over the (data, model) serving
         # submesh.  Pools/params shard over 'model' (kv-head lanes /
-        # column-row tiles); the per-slot operands and results — a row a
-        # slot — over 'data'; the chunk vector stays replicated, and the
+        # column-row tiles); the per-slot operands and results (this
+        # dispatch's and the previous one's) — a row a slot — over
+        # 'data'; the chunk vector stays replicated, and the
         # draft (params + pools) replicates over both axes, so every
         # shard traces the one identical program a shape
         # (decode_builds == 2 regardless of mesh)
@@ -1461,7 +1555,8 @@ class ServingEngine:
                     if self._tp_scales is not None else P())
         pools_sp = (pool_sp, pool_sp if self._pool_v is not None else P(),
                     pscale_sp, pscale_sp)
-        host_in = (P(d, None), P())
+        # the previous result comes back as it left: a row a slot
+        host_in = (P(d, None), P(d, None), P())
         if spec_on:
             in_specs = ((self._tp_param_specs, scale_sp, P()) + pools_sp
                         + (P(), P()) + host_in)
@@ -1505,7 +1600,10 @@ class ServingEngine:
         device, then the TWO host arrays of the ``_SLOT_COLS`` /
         ``_CHUNK_HEAD`` layout — per-slot state and block tables, and
         the prompt chunk with its sampling state — filled from the
-        scheduler's request records.  ``chunk is None`` IS the choice of
+        scheduler's request records AS DISPATCHED (applied counts plus
+        what the dispatch in flight adds).  A decoding row whose newest
+        token is still on the device says so in ``_TOKEN_SRC`` and
+        carries no token.  ``chunk is None`` IS the choice of
         shape: the chunk vector is then its head alone, and the program
         that takes it has no chunk lane.  Both are built fresh: the
         program reads them asynchronously on the chip, and the CPU
@@ -1518,11 +1616,14 @@ class ServingEngine:
         for slot, req in self.scheduler.running.items():
             table = self.allocator.block_table(req.req_id)
             slots[slot, _SLOT_COLS:_SLOT_COLS + len(table)] = table
-            slots[slot, _LENS] = req.cached_tokens
+            slots[slot, _LENS] = req.planned_cached
         for slot, req in list(dec) + list(spec):
-            slots[slot, _DEC_TOKEN] = req.output[-1]
+            if req.flight_tokens:
+                slots[slot, _TOKEN_SRC] = SRC_DEVICE
+            else:
+                slots[slot, _DEC_TOKEN] = req.output[-1]
             slots[slot, _TOP_K] = req.top_k
-            slots[slot, _OUT_IDX] = len(req.output)
+            slots[slot, _OUT_IDX] = req.planned_output
             slots_u[slot, _KEY:_KEY + 2] = req.prng_key
             slots_f[slot, _TEMP] = req.temperature
             slots_f[slot, _TOP_P] = req.top_p
@@ -1539,7 +1640,7 @@ class ServingEngine:
         if chunk is not None:
             c_slot, req, c_start, c_len = chunk
             chunk_vec[_C_SLOT:_C_KEY] = (c_slot, c_start, c_len, req.top_k,
-                                         len(req.output))
+                                         req.planned_output)
             chunk_u[_C_KEY:_C_KEY + 2] = req.prng_key
             chunk_f[_C_TEMP] = req.temperature
             chunk_f[_C_TOP_P] = req.top_p
@@ -1549,23 +1650,25 @@ class ServingEngine:
 
     def _device_operands(self) -> tuple:
         """The operands that live on the device: weights, then the pools
-        as the last dispatch returned them."""
+        and the result array as the last dispatch returned them."""
         pools = (self._pool_k, self._pool_v, self._pool_ks, self._pool_vs)
         if self._draft_model is not None:
             pools = (self._draft_params,) + pools + (self._dpool_k,
                                                      self._dpool_v)
-        return (self._tp_params, self._tp_scales) + pools
+        return (self._tp_params, self._tp_scales) + pools + (
+            self._prev_result,)
 
     def _launch(self, operands: tuple) -> jax.Array:
         """Enqueue the step on ``operands`` — their chunk vector's
         length selects the shape — keep the pools it returns (the ones
         passed in are donated) and hand back the one array the host
-        reads."""
+        reads, which is also the next dispatch's operand."""
         result, *pools = self._step_fn(*operands)
         (self._pool_k, self._pool_v, self._pool_ks,
          self._pool_vs) = pools[:4]
         if self._draft_model is not None:
             self._dpool_k, self._dpool_v = pools[4:]
+        self._prev_result = result
         return result
 
     def _build_both_shapes(self, chunk) -> None:
@@ -1608,33 +1711,31 @@ class ServingEngine:
         return {"sampled_rows": int(rows[samples].sum()) + chunk_samples,
                 "filtered_rows": int(rows[filters].sum()) + chunk_filters}
 
-    def _dispatch(self, dec: List[Tuple[int, Request]],
-                  chunk: Optional[Tuple[int, Request, int, int]],
-                  spec: List[Tuple[int, Request]] = ()
-                  ) -> Optional[int]:
-        """One dispatch of the mixed program: a decode token for every
-        slot in ``dec``, a draft+verify round for every slot in ``spec``
-        (draft armed only), plus (optionally) one prompt chunk, then
-        apply the results to the scheduler's request records.  Returns
-        the progress made (decode tokens emitted + prefill tokens
-        landed) — the serving watchdog's heartbeat — or ``None`` when a
-        transient fault at the dispatch site skipped the dispatch: the
-        caller abandons the whole iteration (no budget charged, the same
-        work retries NEXT step; streams are delayed, never corrupted).
-        A fatal fault raises :class:`ServingError`."""
+    def _enqueue(self, dec: List[Tuple[int, Request]],
+                 chunk: Optional[Tuple[int, Request, int, int]],
+                 spec: List[Tuple[int, Request]] = (),
+                 ahead: bool = False) -> bool:
+        """Enqueue one dispatch of the mixed program — a decode token
+        for every slot in ``dec``, a draft+verify round for every slot
+        in ``spec`` (draft armed only), plus (optionally) one prompt
+        chunk — and note on the request records what it will add when it
+        lands (``flight_rows`` / ``flight_tokens``: the state as
+        dispatched).  Nothing is read here; :meth:`_apply` reads.
+        Returns False when a transient fault at the dispatch site
+        skipped the dispatch: the caller abandons the iteration (no
+        budget charged, the same work retries NEXT step; streams are
+        delayed, never corrupted).  A fatal fault raises
+        :class:`ServingError`."""
         try:
             get_fault_injector().check("serving.dispatch")
         except TransientIOError as e:
             logger.warning(f"serving: transient dispatch fault — "
                            f"iteration skipped, will retry: {e}")
-            return None
+            return False
         except FatalIOError as e:
             raise ServingError(
                 f"fatal fault at serving dispatch: {e}") from e
-        sched = self.scheduler
-        spec_on = self._draft_model is not None
-        c_start, c_len = ((chunk[2], chunk[3]) if chunk is not None
-                          else (0, 0))
+        c_len = chunk[3] if chunk is not None else 0
         ovl = self._ovl
         ovl_on = ovl.enabled
         if ovl_on:
@@ -1647,46 +1748,87 @@ class ServingEngine:
         if ovl_on:
             ovl.mark(overlap.ENQUEUE)
         t0 = time.perf_counter()
-        counted = self.model.PAGED_COUNTERS
         with trace_span("serving/dispatch", decode=len(dec),
                         chunk_tokens=c_len, spec=len(spec), rows=rows,
-                        tp=self.tp_mesh.size, moe=int(bool(counted))):
+                        tp=self.tp_mesh.size, ahead=int(ahead),
+                        moe=int(bool(self.model.PAGED_COUNTERS))):
             result = self._launch(operands)
-            if ovl_on:
-                # dispatch returned, nothing materialized yet: from here
-                # to the one read below the host waits on the device
-                ovl.mark(overlap.DEVICE_WAIT)
             # queued behind the program now, not requested once the host
             # has noticed that it ended
             result.copy_to_host_async()
-            res = np.asarray(result)
-        # ITL = dispatch wall time only, captured BEFORE the host-side
-        # bookkeeping below (commit hashing, finishes, quarantines) so
-        # the histogram stays comparable across PRs
-        dispatch_dt = time.perf_counter() - t0
+        # the state as dispatched.  A speculating slot's row count is
+        # the device's to say: its dispatch lands before anything else is
+        # planned (_plan_iteration), so it carries none
+        for _slot, req in dec:
+            req.flight_rows += 1
+            req.flight_tokens += 1
+        ends_prefill = False
+        if chunk is not None:
+            req = chunk[1]
+            req.flight_rows += c_len
+            ends_prefill = (req.planned_cached >= req.prefill_target
+                            and not req.prefill_only)
+            req.flight_tokens += ends_prefill
+        self._flight.append(_Flight(
+            result, dec, chunk, spec, ends_prefill, ahead, t0,
+            dict(decode_rows=len(dec) + len(spec) * (self.spec_k + 1),
+                 chunk_rows=c_len, rows_computed=rows,
+                 host_arrays_in=sum(isinstance(a, np.ndarray)
+                                    for a in operands),
+                 host_reads_out=1, ahead_dispatches=int(ahead),
+                 **self._sampler_rows(*operands[-2:]))
+            if ovl_on else None))
+        return True
+
+    def _apply(self, fl: _Flight) -> int:
+        """Read one dispatch's result — the one place the host waits for
+        the device — and apply it to the scheduler's request records.
+        A row whose request ended after the dispatch was enqueued (eos,
+        a quarantine, a cancel, a deadline: news that arrives one
+        dispatch late) is VOID: its result is ignored and nothing of it
+        is committed; the blocks it wrote into were the request's own
+        when it was planned, and whoever holds them next writes them in
+        a later program before reading them.  Returns the progress made
+        (decode tokens emitted + prefill tokens landed) — the serving
+        watchdog's heartbeat."""
+        sched = self.scheduler
+        dec, chunk, spec = fl.dec, fl.chunk, fl.spec
+        ovl = self._ovl
+        ovl_on = ovl.enabled
+        if ovl_on:
+            # from here to the one read below the host waits on the
+            # device — for THIS dispatch, with the next one queued behind
+            # it when the loop ran ahead
+            ovl.mark(overlap.DEVICE_WAIT)
+        res = np.asarray(fl.result)
+        # ITL = the gap between two successive results (this dispatch's
+        # own enqueue-to-read when the device had stood idle before it),
+        # captured BEFORE the host-side bookkeeping below (commit
+        # hashing, finishes, quarantines) so the histogram stays
+        # comparable across PRs
+        now = time.perf_counter()
+        t0 = max(fl.t0, self._last_result_t)
+        dispatch_dt = now - t0
+        self._last_result_t = now
         if ovl_on:
             ovl.mark(overlap.APPLY)
-            ovl.count_dispatch(
-                len(dec) + len(spec) * (self.spec_k + 1), c_len, rows,
-                host_arrays_in=sum(isinstance(a, np.ndarray)
-                                   for a in operands),
-                host_reads_out=1,
-                **self._sampler_rows(*operands[-2:]),
-                # what the program counted: the row's last columns
-                **(dict(zip(counted, map(int, res[0, -len(counted):])))
-                   if counted else {}))
         nxt, dec_fin = res[:, _R_NEXT], res[:, _R_DEC_FINITE]
         first, chunk_fin = res[0, _R_FIRST], res[0, _R_CHUNK_FINITE]
-        if spec_on:
+        if spec:
             n_emit, spec_fin = res[:, _R_SPEC], res[:, _R_SPEC + 1]
             emitted = res[:, _R_SPEC + 2:]
-        if self._rt.enabled and dec:
+        live = [(slot, req) for slot, req in dec
+                if sched.running.get(slot) is req]
+        void = len(dec) - len(live)
+        if self._rt.enabled and live:
             # request-track segments reuse t0/dispatch_dt — no extra
             # clock reads on the hot path
-            self._rt.on_decode([r for _, r in dec], t0, dispatch_dt,
-                               len(dec))
+            self._rt.on_decode([r for _, r in live], t0, dispatch_dt,
+                               len(live))
         progress = 0
-        for slot, req in dec:
+        for slot, req in live:
+            req.flight_rows -= 1
+            req.flight_tokens -= 1
             if not bool(dec_fin[slot]):
                 # quarantine BEFORE any commit: the row(s) this dispatch
                 # wrote are suspect and must not register in the cache
@@ -1704,7 +1846,7 @@ class ServingEngine:
                                              req.cached_tokens)
             if req.done:
                 sched.finish(slot)
-        for slot, req in spec:
+        for slot, req in spec:      # landed at once: never void
             if not bool(spec_fin[slot]):
                 self._quarantine(slot, req, "spec decode")
                 continue
@@ -1749,10 +1891,14 @@ class ServingEngine:
             if progress:
                 self._m_tokens.inc(progress)
         if chunk is not None:
-            req = chunk[1]
-            if not bool(chunk_fin):
-                self._quarantine(chunk[0], req, "prefill chunk")
+            c_slot, req, c_start, c_len = chunk
+            if sched.running.get(c_slot) is not req:
+                void += c_len
+            elif not bool(chunk_fin):
+                self._quarantine(c_slot, req, "prefill chunk")
             else:
+                req.flight_rows -= c_len
+                req.flight_tokens -= fl.ends_prefill
                 req.cached_tokens += c_len
                 progress += c_len
                 self._m_prefill_tokens.inc(c_len)
@@ -1769,7 +1915,7 @@ class ServingEngine:
                     # leg samples output index 0 with the same pinned
                     # key, so the stream is identical to a one-replica
                     # run
-                    self._finish_prefill_only(chunk[0], req)
+                    self._finish_prefill_only(c_slot, req)
                 elif req.cached_tokens >= req.prefill_target:
                     # the chunk that completed the prefix carries the
                     # first token (sampled from its last valid position
@@ -1788,15 +1934,51 @@ class ServingEngine:
                             h.observe(req.first_token_time
                                       - req.submit_time)
                     if req.done:
-                        sched.finish(chunk[0])
+                        sched.finish(c_slot)
+        self.flight_counts["dispatches"] += 1
+        self.flight_counts["ahead_dispatches"] += fl.ahead
+        self.flight_counts["void_rows"] += void
+        if ovl_on and fl.counts is not None:
+            counted = self.model.PAGED_COUNTERS
+            ovl.count_dispatch(
+                **fl.counts, void_rows=void,
+                # what the program counted: the row's last columns
+                **(dict(zip(counted, map(int, res[0, -len(counted):])))
+                   if counted else {}))
         return progress
+
+    def _land(self, n: Optional[int] = None) -> int:
+        """Read and apply the ``n`` oldest dispatches in flight (all of
+        them by default), oldest first; returns the progress they
+        made."""
+        if n is None:
+            n = len(self._flight)
+        landing, self._flight = self._flight[:n], self._flight[n:]
+        return sum(self._apply(fl) for fl in landing)
+
+    def _dispatch(self, dec: List[Tuple[int, Request]],
+                  chunk: Optional[Tuple[int, Request, int, int]],
+                  spec: List[Tuple[int, Request]] = ()
+                  ) -> Optional[int]:
+        """One dispatch in the synchronous order: enqueue it, then read
+        and apply it (and whatever was in flight before it).  Returns the
+        progress made, or ``None`` when a transient fault skipped the
+        dispatch."""
+        if not self._enqueue(dec, chunk, spec):
+            return None
+        return self._land()
 
     def step(self) -> bool:
         """One continuous-batching iteration: sweep deadlines, admit
         (taking prefix-cache hits), guarantee KV capacity, then dispatch
         the mixed program — one decode token for every live slot riding
         alongside up to ``prefill_chunk_tokens`` of prompt chunks.
-        Returns True while work remains.
+        Each call applies exactly ONE iteration's results; the iteration
+        it plans and enqueues is the one AFTER the one it applies
+        whenever that can be planned from counts alone (``_sees_ahead``),
+        so the device always has its next program queued — a call on an
+        idle engine enqueues two iterations and applies the first.
+        Returns True while work remains or an iteration is in flight.
 
         Robustness (docs/serving.md "Failure handling & overload"):
         expired deadlines terminate WAITING and RUNNING requests at this
@@ -1822,12 +2004,46 @@ class ServingEngine:
                     "diagnose": self._diagnose("engine state at failure")})
             raise
 
-    def _step_impl(self) -> bool:
+    def _sees_ahead(self) -> bool:
+        """Whether the iteration after the one on the device can be
+        planned NOW, from counts the host already holds.  Decided by what
+        the engine can observe, never by an option; when it cannot, the
+        dispatch in flight lands first and the loop runs in the
+        synchronous order:
+
+          * a draft armed — a speculating slot grows by ``n_emit`` rows,
+            which only the result says;
+          * a host->pool promotion pending — it lands with nothing in
+            flight (a block SPILL needs no rule: its gather reads the
+            pool as the dispatch in flight returns it, so it waits for
+            that dispatch by itself);
+          * a ``prefill_only`` request aboard — its hand-off publishes
+            the chain from the pool at apply time;
+          * the decoding slots' next rows need more blocks than the pool
+            has free — ``ensure_decode_capacity`` would preempt, and a
+            victim's recompute restarts from applied counts."""
+        sched = self.scheduler
+        return (self._draft_model is None
+                and not self.allocator.num_pending
+                and not any(r.prefill_only for r in sched.running.values())
+                and sched.decode_growth_blocks() <= self.allocator.num_free)
+
+    def _plan_iteration(self, ahead: bool = False) -> int:
+        """Plan one iteration and enqueue its dispatches: sweep
+        deadlines, guarantee KV capacity, admit (taking prefix-cache
+        hits), land promotions, then one dispatch — a decode token for
+        every live slot beside up to ``prefill_chunk_tokens`` of a
+        prompt chunk — and one more for each chunk remainder the budget
+        still covers.  Every count read here is the state AS DISPATCHED,
+        so with ``ahead`` (the previous iteration still on the device)
+        the plan is the one the synchronous loop would make after that
+        iteration landed in full.  Returns the progress made on the way
+        (blocks promoted; a speculative dispatch's tokens — it lands
+        before the next is planned)."""
         sched = self.scheduler
         ovl = self._ovl
         if ovl.enabled:
-            ovl.begin()
-        finished_before = len(sched.finished)
+            ovl.mark(overlap.PLAN)
         sched.sweep_deadlines()
         # capacity BEFORE admission: running sequences claim their next
         # block first, so a fresh admission is never immediately chosen
@@ -1838,6 +2054,10 @@ class ServingEngine:
             logger.info(f"serving: preempted {req.req_id} on KV pressure "
                         f"({req.preemptions} time(s))")
         sched.schedule_admissions()
+        if ahead and not self._sees_ahead():
+            # an admission brought a promotion or a hand-off: they are
+            # served once the iteration on the device has landed
+            return 0
         # land queued host->pool promotions in the admission window:
         # PROMOTING requests are held out of next_prefill_chunk until
         # their claimed blocks carry real KV again
@@ -1879,13 +2099,19 @@ class ServingEngine:
                 dec = kept
             if not dec and not spec and chunk is None:
                 break
-            dispatched = self._dispatch(dec, chunk, spec)
-            if dispatched is None:
+            if spec:
+                # what a speculating slot leaves behind is the device's
+                # to say: it lands before anything is planned past it
+                landed = self._dispatch(dec, chunk, spec)
+                enqueued = landed is not None
+                progress += landed or 0
+            else:
+                enqueued = self._enqueue(dec, chunk, ahead=ahead)
+            if not enqueued:
                 # transient dispatch fault: abandon the iteration — the
                 # chunk budget was NOT charged and the same decode/chunk
                 # work retries next step
                 break
-            progress += dispatched
             include_decode = False
             if chunk is None:
                 break
@@ -1894,6 +2120,27 @@ class ServingEngine:
                 break
             if ovl.enabled:
                 ovl.mark(overlap.PLAN)       # a second dispatch follows
+        return progress
+
+    def _step_impl(self) -> bool:
+        sched = self.scheduler
+        ovl = self._ovl
+        if ovl.enabled:
+            ovl.begin()
+        finished_before = len(sched.finished)
+        progress = 0
+        if not self._flight:
+            # nothing on the device: the synchronous order's first half
+            progress += self._plan_iteration()
+        landing = len(self._flight)
+        if landing and self._sees_ahead():
+            # one iteration ahead: the device finds it queued when the
+            # one it is running ends, and everything below — the read,
+            # the apply, the caller's own work between two step()s —
+            # runs under a device program
+            progress += self._plan_iteration(ahead=True)
+        # each call applies exactly one iteration's results
+        progress += self._land(landing)
         if ovl.enabled:
             ovl.mark(overlap.APPLY)
         self._drain_terminal_events()
@@ -1911,7 +2158,8 @@ class ServingEngine:
         # is exactly the livelock signature the watchdog exists for.
         progress += len(sched.finished) - finished_before
         self._update_drain_rate(len(sched.finished) - finished_before)
-        if progress or not sched.has_work:
+        working = sched.has_work or bool(self._flight)
+        if progress or not working:
             self._no_progress = 0
         else:
             self._no_progress += 1
@@ -1924,7 +2172,7 @@ class ServingEngine:
                     f"every dispatch faulted"))
         if ovl.enabled:
             ovl.end("serving")
-        return sched.has_work
+        return working
 
     def _update_drain_rate(self, n_finished: int) -> None:
         """EMA of wall seconds per FINISHED request, fed by every
@@ -1960,6 +2208,8 @@ class ServingEngine:
             "lifecycle": dict(self.lifecycle_counts),
             "spec": dict(self.spec_counts),
             "decode_builds": self.decode_builds,
+            "in_flight": len(self._flight),
+            "flight": dict(self.flight_counts),
             "host_pending": alloc.num_pending,
             "host": dict(self.host_counts),
         }

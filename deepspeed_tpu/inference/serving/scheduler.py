@@ -122,8 +122,17 @@ class Request:
     #: computed chunks + decoded tokens, minus the newest sampled token,
     #: which writes on the next decode)
     cached_tokens: int = 0
+    #: what the dispatch in flight adds to this request when it is
+    #: applied (docs/serving.md "The dispatch in flight"): KV rows it
+    #: writes (a decode row: 1; a chunk: its tokens) and output tokens it
+    #: samples (0 or 1).  The PLAN of the next dispatch reads
+    #: ``cached_tokens + flight_rows`` / ``len(output) + flight_tokens`` —
+    #: the state as dispatched; commits, streams and every caller read
+    #: the applied counts above.  Both are 0 with nothing in flight.
+    flight_rows: int = 0
+    flight_tokens: int = 0
     #: prefix length frozen at (re-)admission: the slot is prefilling
-    #: while cached_tokens < prefill_target
+    #: while its dispatched rows are short of prefill_target
     prefill_target: int = 0
     #: cumulative prefix-cache hit tokens across (re-)admissions — the
     #: prefill work this request never had to pay
@@ -185,9 +194,26 @@ class Request:
         return list(self.prompt) + list(self.output)
 
     @property
+    def planned_cached(self) -> int:
+        """KV rows in the pool once the dispatch in flight has landed."""
+        return self.cached_tokens + self.flight_rows
+
+    @property
+    def planned_output(self) -> int:
+        """Output tokens once the dispatch in flight has landed."""
+        return len(self.output) + self.flight_tokens
+
+    @property
     def prefilling(self) -> bool:
         return self.state is RequestState.RUNNING and \
-            self.cached_tokens < self.prefill_target
+            self.planned_cached < self.prefill_target
+
+    @property
+    def spent(self) -> bool:
+        """The length side of :attr:`done`, by dispatched counts: every
+        token ``max_new_tokens`` allows is sampled or in flight, so no
+        later dispatch carries a row for this request."""
+        return self.planned_output >= self.max_new_tokens
 
     @property
     def done(self) -> bool:
@@ -282,10 +308,22 @@ class ContinuousBatchingScheduler:
 
     def decoding_slots(self) -> List[Tuple[int, Request]]:
         """Slots that take a decode token this iteration (admitted AND
-        past their prefill, not held by a transient growth fault), in
-        slot order for deterministic batches."""
+        past their prefill, not held by a transient growth fault, with
+        a token still to come by dispatched counts — a request whose
+        last token is in flight keeps its slot until that lands, and
+        takes no row), in slot order for deterministic batches."""
         return [(s, r) for s, r in sorted(self.running.items())
-                if not r.prefilling and r.req_id not in self._growth_held]
+                if not r.prefilling and not r.spent
+                and r.req_id not in self._growth_held]
+
+    def decode_growth_blocks(self) -> int:
+        """Blocks :meth:`ensure_decode_capacity` would append right now
+        — what the pool must hold free for it to preempt nobody."""
+        return sum(
+            max(0, self.alloc.blocks_for_tokens(r.planned_cached + 1)
+                - self.alloc.blocks_held(r.req_id))
+            for r in self.running.values()
+            if not r.prefilling and not r.spent)
 
     # -- lifecycle ---------------------------------------------------------
     def submit(self, req: Request) -> Request:
@@ -345,6 +383,8 @@ class ContinuousBatchingScheduler:
         lifecycle counters (non-OK only — OK is counted by the token
         path)."""
         req.state = RequestState.FINISHED
+        # whatever a dispatch in flight still carries for it is void
+        req.flight_rows = req.flight_tokens = 0
         req.status = req.status or status
         req.error = error
         if (status is RequestStatus.SHED and req.retry_after_s is None
@@ -512,8 +552,8 @@ class ContinuousBatchingScheduler:
         if self.prefill_policy is not None and len(prefilling) > 1:
             prefilling = self.prefill_policy(prefilling)
         for slot, req in prefilling:
-            n = min(budget, req.prefill_target - req.cached_tokens)
-            return slot, req, req.cached_tokens, n
+            n = min(budget, req.prefill_target - req.planned_cached)
+            return slot, req, req.planned_cached, n
         return None
 
     def ensure_decode_capacity(self) -> List[Request]:
@@ -538,11 +578,11 @@ class ContinuousBatchingScheduler:
         self._growth_held.clear()
         for slot in list(self._admit_order):           # oldest first
             req = self.running.get(slot)
-            if req is None or req.prefilling:
+            if req is None or req.prefilling or req.spent:
                 continue
             while req.state is RequestState.RUNNING:
-                need = self.alloc.blocks_for_tokens(req.cached_tokens + 1)
-                have = len(self.alloc.block_table(req.req_id))
+                need = self.alloc.blocks_for_tokens(req.planned_cached + 1)
+                have = self.alloc.blocks_held(req.req_id)
                 if have >= need:
                     break
                 try:
@@ -642,6 +682,9 @@ class ContinuousBatchingScheduler:
         return eligible[-1]
 
     def _preempt(self, slot: int, req: Request) -> None:
+        # recompute restarts from applied counts: the engine lands the
+        # dispatch in flight before a plan that could preempt
+        assert not req.flight_rows, f"{req.req_id} preempted in flight"
         # register what was computed before letting the blocks go: the
         # re-admission (and any shared-prefix sibling) hits them
         self.alloc.commit_cached(req.req_id, req.prefix, req.cached_tokens)

@@ -131,7 +131,12 @@ COUNTERS = ("dispatches", "decode_rows", "chunk_rows", "rows_computed",
             "latent_tokens_read",
             # rows x expert layers through an always-on shared expert
             # (models/sandwich_moe.py); 0 for blocks that have none
-            "moe_rows_shared")
+            "moe_rows_shared",
+            # the dispatch in flight (docs/serving.md): dispatches that
+            # were enqueued before their predecessor's result was read,
+            # and rows whose result was ignored because their request
+            # had ended by the time it arrived
+            "ahead_dispatches", "void_rows")
 _COUNTER_AT = {name: k for k, name in enumerate(COUNTERS)}
 
 #: one iteration (or training step).  Times are seconds on
@@ -429,7 +434,7 @@ class OverlapProfiler:
     def count_dispatch(self, decode_rows: int, chunk_rows: int,
                        rows_computed: int, host_arrays_in: int = 0,
                        host_reads_out: int = 0, sampled_rows: int = 0,
-                       filtered_rows: int = 0, **program_counts: int
+                       filtered_rows: int = 0, **more_counts: int
                        ) -> None:
         """One dispatch of the serving step: the rows that carried a
         token (decoding slots, prompt-chunk tokens), the rows of the
@@ -437,14 +442,16 @@ class OverlapProfiler:
         device arrays read back from it, and the rows that asked the
         sampler for a draw and for a filter (0 sampled: the dispatch
         took the ``argmax``-only side; 0 filtered: it sorted nothing);
-        ``program_counts`` — what the program counted itself
-        (``moe_picks`` .. ``moe_rows_shared`` of ``COUNTERS``), by
-        name."""
+        ``more_counts`` — the rest of ``COUNTERS`` by name: what the
+        program counted itself (``moe_picks`` .. ``moe_rows_shared``),
+        and ``ahead_dispatches`` (1 if this dispatch was enqueued before
+        its predecessor's result was read) and ``void_rows`` (rows of it
+        whose result was ignored)."""
         for k, add in enumerate((1, decode_rows, chunk_rows, rows_computed,
                                  host_arrays_in, host_reads_out,
                                  sampled_rows, filtered_rows)):
             self._counts[k] += add
-        for name, add in program_counts.items():
+        for name, add in more_counts.items():
             self._counts[_COUNTER_AT[name]] += add
 
     def end(self, kind: str = "serving") -> None:

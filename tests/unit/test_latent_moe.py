@@ -22,6 +22,7 @@ otherwise.
 import dataclasses
 import json
 import os
+import time
 
 import jax
 import jax.numpy as jnp
@@ -213,6 +214,58 @@ def test_tokens_alternate_between_the_two_shapes_of_the_step(block):
         for j, tok in enumerate(a.output):
             at = lg[len(a.prompt) + j - 1]
             assert at.max() - at[tok] < 1e-4
+    assert srv.decode_builds == 2 and srv.allocator.num_used == 0
+
+
+@pytest.mark.parametrize("block", ["sandwich", "shortcut"])
+def test_the_loop_keeps_a_dispatch_in_flight_for_the_latent_blocks(block):
+    """Both latent families take the same loop (ISSUE 37): every dispatch
+    but the first is enqueued before its predecessor's result is read,
+    the counters the program counted still ride that result, a request's
+    tokens are those of a one-at-a-time run, and an eos in mid-stream
+    costs one void row and commits nothing of it."""
+    if block == "shortcut":
+        import test_shortcut_moe as other
+        build_ = other.build
+    else:
+        build_ = build
+    model, params = build_(experts_held=(0, 6))
+    srv = serving_engine(model, params)
+    chunk = SERVING["prefill_chunk_tokens"]
+    rng = np.random.default_rng(11)
+    prompts = [rng.integers(0, 128, n) for n in (5, chunk + 3, 2 * chunk)]
+    alone = []
+    for p in prompts:
+        alone.append(srv.submit(p, max_new_tokens=9))
+        srv.run()
+    # a token of the middle stream that does not occur before its place
+    full = alone[1].output
+    at = next(j for j in range(2, 8) if full[j] not in full[:j])
+    before = dict(srv.flight_counts)
+    prof = get_overlap_profiler()
+    prof.configure(enabled=True)
+    try:
+        t0 = time.perf_counter()
+        together = [srv.submit(p, max_new_tokens=9,
+                               eos_token_id=full[at] if k == 1 else None)
+                    for k, p in enumerate(prompts)]
+        srv.run()
+        its, complete = prof.iterations(t0, time.perf_counter())
+    finally:
+        prof.configure(enabled=False)
+    assert together[0].output == alone[0].output
+    assert together[1].output == full[:at + 1]
+    assert together[2].output == alone[2].output
+    d = {k: srv.flight_counts[k] - before[k] for k in before}
+    assert complete and its["ahead_dispatches"].sum() == d["ahead_dispatches"]
+    # all but the idle engine's first iteration (the three prompts' tails
+    # past their cached blocks share one chunk budget: three dispatches)
+    assert d["ahead_dispatches"] == d["dispatches"] - its["dispatches"][0]
+    assert d["void_rows"] == 1
+    assert its["void_rows"].sum() == 1 and its["moe_picks"].sum() > 0
+    again = srv.submit(prompts[1], max_new_tokens=9)
+    srv.run()
+    assert again.output == full
     assert srv.decode_builds == 2 and srv.allocator.num_used == 0
 
 

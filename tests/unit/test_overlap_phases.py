@@ -24,23 +24,35 @@ pytestmark = [pytest.mark.observability]
 SLOTS, CHUNK = 4, 16
 
 
-@pytest.fixture(scope="module")
-def srv():
-    cfg = gpt2_config("125m", num_layers=2, d_model=32, num_heads=4,
-                      vocab_size=256, max_seq_len=64, dtype=jnp.float32)
+def build_engine(draft=False):
+    def cfg(layers):
+        return gpt2_config("125m", num_layers=layers, d_model=32,
+                           num_heads=4, vocab_size=256, max_seq_len=64,
+                           dtype=jnp.float32)
     eng = ds.init_inference(
-        TransformerLM(cfg),
+        TransformerLM(cfg(2)),
         config={"dtype": "float32", "max_out_tokens": 64,
                 "temperature": 0.0, "replace_with_kernel_inject": False,
                 "serving": {"enabled": True, "kv_block_size": 8,
                             "num_kv_blocks": 48,
                             "max_batch_slots": SLOTS,
                             "prefill_chunk_tokens": CHUNK,
-                            "prefix_cache": False}})
-    engine = eng.serving_engine()
+                            "prefix_cache": False,
+                            **({"spec_k": 1} if draft else {})}})
+    if draft:
+        dm = TransformerLM(cfg(1))
+        engine = eng.serving_engine(
+            draft_model=dm, draft_params=dm.init(jax.random.PRNGKey(1)))
+    else:
+        engine = eng.serving_engine()
     engine.submit([1, 2, 3], max_new_tokens=2)       # build the program
     engine.run()
     return engine
+
+
+@pytest.fixture(scope="module")
+def srv():
+    return build_engine()
 
 
 @pytest.fixture
@@ -112,6 +124,78 @@ def test_chunk_remainder_records_both_dispatches(srv, ovl):
     # the second dispatch re-entered plan .. apply: still a partition
     assert sum(second[f"{p}_s"] for p in PHASES) == pytest.approx(
         second["end_s"] - second["begin_s"], abs=1e-7)
+
+
+@pytest.mark.parametrize("draft", [False, True], ids=["ahead", "draft"])
+def test_the_next_dispatch_is_enqueued_before_this_one_is_read(
+        srv, ovl, monkeypatch, draft):
+    """The order of the loop itself (ISSUE 37): in a steady run the
+    launch of dispatch k+1 comes BEFORE the read of k's result, so the
+    device finds its next program queued — every dispatch but the first
+    counts as ``ahead_dispatches`` — and ``device_wait`` is the wait for
+    k with k+1 behind it.  With a draft armed the loop cannot plan from
+    counts: launch, read, launch, read, and the counter stays 0."""
+    engine = build_engine(draft=True) if draft else srv
+    log = []
+    launch, read = engine._launch, np.asarray
+
+    def launching(operands):
+        log.append("launch")
+        return launch(operands)
+
+    def reading(a, *args, **kw):
+        if isinstance(a, jax.Array):
+            log.append("read")
+        return read(a, *args, **kw)
+
+    monkeypatch.setattr(engine, "_launch", launching)
+    monkeypatch.setattr(np, "asarray", reading)
+    t0 = time.perf_counter()
+    req = engine.submit(prompt(9, 30), max_new_tokens=7)
+    drain(engine)
+    monkeypatch.undo()
+    its, _ = ovl.iterations(t0, time.perf_counter())
+    assert req.status is RequestStatus.OK and len(req.output) == 7
+    n = int(its["dispatches"].sum())
+    assert log.count("launch") == log.count("read") == n
+    launched_before_read = [log[:at].count("launch")
+                            for at, what in enumerate(log) if what == "read"]
+    if draft:
+        assert launched_before_read == list(range(1, n + 1))
+        assert its["ahead_dispatches"].sum() == 0
+    else:
+        # k+1 is on the device's queue when k is read; the last has no
+        # successor (``max_new_tokens`` ended the request by count)
+        assert launched_before_read == list(range(2, n + 1)) + [n]
+        assert its["ahead_dispatches"].sum() == n - 1
+        # a record holds the enqueue of one dispatch and the wait for
+        # the one before it
+        assert np.all(its["enqueue_s"][:-1] > 0)
+    assert np.all(its["device_wait_s"] > 0)
+    assert its["void_rows"].sum() == 0
+    assert engine.decode_builds == 2
+
+
+def test_the_benchmarks_reader_of_the_ahead_counter(srv, ovl, monkeypatch):
+    """``*.ahead_dispatch_share`` (benchmark/readers/program_ahead.py) is
+    the in-window ``ahead_dispatches`` over ``dispatches``; on a program
+    whose records have no such counter — the parent the driver lays this
+    benchmark over — it reads nothing and raises nothing."""
+    from benchmark.lib import program
+    from benchmark.readers import program_ahead
+    t0 = time.perf_counter()
+    srv.submit(prompt(9, 60), max_new_tokens=5)
+    drain(srv)
+    obs = {"window": (t0, time.perf_counter())}
+    its, _ = ovl.iterations(*obs["window"])
+    n = int(its["dispatches"].sum())
+    assert n == 5 and program_ahead.read(obs) == pytest.approx(
+        100.0 * (n - 1) / n)
+    assert program_ahead.read({"window": (0.0, t0)}) is None   # no records
+    old = [name for name in its.dtype.names
+           if name not in ("ahead_dispatches", "void_rows")]
+    monkeypatch.setattr(program, "records", lambda obs, what: its[old])
+    assert program_ahead.read(obs) is None
 
 
 def test_useful_and_computed_rows_of_a_known_batch(srv, ovl):

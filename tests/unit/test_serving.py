@@ -2257,12 +2257,32 @@ def test_batch_moves_between_greedy_and_mixed_on_one_program(packed):
 
 
 def test_dispatch_passes_two_host_arrays_and_reads_one(packed, monkeypatch):
-    """Beside the device's own state (weights, pools) the operands are
-    two NumPy arrays and nothing that lives on the device; one dispatch
-    materialises one device array on the host."""
+    """Beside the device's own state (weights, pools, the previous
+    dispatch's result array) the operands are two NumPy arrays and
+    nothing else that lives on the device; one dispatch materialises one
+    device array on the host."""
     from deepspeed_tpu.observability import get_overlap_profiler
     _eng, srv = packed
+    # on before the first step(): a dispatch is counted when its result
+    # is applied, from what its enqueue noted with the profiler on
+    prof = get_overlap_profiler()
+    prof.reset()
+    prof.configure(enabled=True)
+    try:
+        two_arrays_in_one_out(srv, prof, monkeypatch)
+    finally:
+        monkeypatch.undo()
+        prof.configure(enabled=False)
+        prof.reset()
+        srv.run()
+
+
+def two_arrays_in_one_out(srv, prof, monkeypatch):
     submit_mix(srv, n=3)
+    # three chunks of a prompt no cached block covers: the first step()
+    # dispatches two iterations' worth of chunks, so a third remains
+    # beside the slots that already decode
+    srv.submit([(7 * i + 3) % 61 for i in range(45)], max_new_tokens=3)
     srv.step()
     dec = srv.scheduler.decoding_slots()
     chunk = srv.scheduler.next_prefill_chunk(srv.chunk_tokens)
@@ -2271,7 +2291,8 @@ def test_dispatch_passes_two_host_arrays_and_reads_one(packed, monkeypatch):
     resident = {id(x) for x in jax.tree_util.tree_leaves(
         [srv._tp_params, srv._tp_scales, srv._pool_k, srv._pool_v,
          srv._pool_ks, srv._pool_vs, getattr(srv, "_draft_params", None),
-         getattr(srv, "_dpool_k", None), getattr(srv, "_dpool_v", None)])}
+         getattr(srv, "_dpool_k", None), getattr(srv, "_dpool_v", None),
+         srv._prev_result])}
     host = [x for x in jax.tree_util.tree_leaves(operands)
             if id(x) not in resident]
     assert [type(x) for x in host] == [np.ndarray, np.ndarray]
@@ -2291,21 +2312,13 @@ def test_dispatch_passes_two_host_arrays_and_reads_one(packed, monkeypatch):
             reads.append(a.shape)
         return real(a, *args, **kw)
 
-    prof = get_overlap_profiler()
-    prof.reset()
-    prof.configure(enabled=True)
     monkeypatch.setattr(np, "asarray", counting)
-    try:
-        srv.step()
-        last = prof.last()
-    finally:
-        monkeypatch.undo()
-        prof.configure(enabled=False)
-        prof.reset()
+    srv.step()
+    monkeypatch.undo()
+    last = prof.last()
     assert len(reads) == last["dispatches"] == last["host_reads_out"] >= 1
     assert last["host_arrays_in"] == 2 * last["dispatches"]
     assert all(shape[0] == srv.num_slots for shape in reads)
-    srv.run()
 
 
 def test_second_dispatch_and_quarantine_through_packed_results(packed):
@@ -2356,4 +2369,267 @@ def test_second_dispatch_and_quarantine_through_packed_results(packed):
             assert r.status is RequestStatus.OK
             np.testing.assert_array_equal(np.asarray(r.output),
                                           _generate(eng, p, 8))
+    srv.allocator.assert_consistent()
+
+
+# ---------------------------------------------------------------------------
+# the dispatch in flight (ISSUE 37): iteration k+1 is planned from the
+# state as dispatched and enqueued while k is on the device; a decoding
+# row's input token comes from k's result array without a trip through
+# the host; news (eos, a quarantine, a cancel, a deadline) arrives one
+# dispatch late and costs a void row
+# ---------------------------------------------------------------------------
+def runs_ahead(srv):
+    """Whether this engine's loop may keep a dispatch in flight at all."""
+    return srv._draft_model is None
+
+
+def flight_delta(srv, before):
+    """``srv.flight_counts`` less a copy of them taken earlier."""
+    return {k: srv.flight_counts[k] - before[k] for k in before}
+
+
+def watch_sources(srv, seen):
+    """Wrap ``_step_operands``: note, a dispatch, the requests whose
+    decode row took its token from the previous result array and how
+    many tokens the host had of each at that moment."""
+    from deepspeed_tpu.inference.serving.engine import (SRC_DEVICE,
+                                                        _TOKEN_SRC)
+    real = srv._step_operands
+
+    def watch(dec, chunk, spec=()):
+        operands = real(dec, chunk, spec)
+        slots = operands[-2]
+        seen.append([(req.req_id, len(req.output)) for slot, req in dec
+                     if slots[slot, _TOKEN_SRC] == SRC_DEVICE])
+        return operands
+    srv._step_operands = watch
+
+
+def test_first_token_reaches_the_next_dispatch_from_the_device(packed):
+    """A prompt whose last chunk rode dispatch k decodes in k+1 before
+    the host has read its first token: the row says ``SRC_DEVICE`` with
+    nothing in ``req.output`` yet, and the stream is ``generate()``'s.
+    With a draft armed every row's source is the host."""
+    eng, srv = packed
+    seen = []
+    watch_sources(srv, seen)
+    try:
+        batch = submit_mix(srv)
+        srv.run()
+    finally:
+        del srv._step_operands
+    for (p, samp), r in zip(PACKED_MIX, batch):
+        assert r.status is RequestStatus.OK
+        assert r.output == sampled_generate(eng, p, 6, **samp), (p, samp)
+    from_device = [pair for dispatch in seen for pair in dispatch]
+    if not runs_ahead(srv):
+        assert not from_device
+        return
+    # every request's first decode row read the chunk lane's token there
+    assert {rid for rid, have in from_device if have == 0} \
+        == {r.req_id for r in batch}
+    # and its later rows the decode lane's: the host was one token behind
+    assert any(have > 0 for _, have in from_device)
+
+
+def test_steady_run_is_ahead_in_every_dispatch_but_the_first(packed):
+    """One request, one chunk: every dispatch after the first is
+    enqueued before its predecessor's result is read
+    (``ahead_dispatches == dispatches - 1``), ``max_new_tokens`` ends it
+    by count (no void row), and each ``step()`` applies one dispatch:
+    after n calls the host holds n tokens.  With a draft armed nothing
+    runs ahead and the counts are the synchronous loop's."""
+    eng, srv = packed
+    before = dict(srv.flight_counts)
+    p, n = [7, 1, 5, 2, 9, 4], 9
+    req = srv.submit(p, max_new_tokens=n, temperature=0.0)
+    grown = []
+    while srv.step():
+        grown.append(len(req.output))
+    d = flight_delta(srv, before)
+    assert req.output == sampled_generate(eng, p, n, 0.0)
+    assert d["void_rows"] == 0
+    if runs_ahead(srv):
+        assert d["dispatches"] == n
+        assert d["ahead_dispatches"] == d["dispatches"] - 1
+        assert grown == list(range(1, n))      # the last call returned False
+    else:
+        assert d["ahead_dispatches"] == 0
+    assert not srv._flight and srv.allocator.num_used == 0
+
+
+def test_eos_in_mid_stream_costs_one_void_row(packed):
+    """A request with an ``eos_token_id`` DOES run ahead.  When its eos
+    arrives the next dispatch already carries a row for it: that row is
+    void — ignored, counted, never committed — and a resubmission of the
+    same prompt (which hits the blocks the first run committed) streams
+    ``generate()``'s tokens.  Its neighbours never notice."""
+    eng, srv = packed
+    p, samp = PACKED_MIX[1]
+    n = 12
+    full = sampled_generate(eng, p, n, **samp)
+    # the first token value that does not occur before its own position
+    at = next(j for j in range(3, n - 2) if full[j] not in full[:j])
+    others = [(q, s) for q, s in PACKED_MIX if q is not p]
+    before = dict(srv.flight_counts)
+    r_eos = srv.submit(p, max_new_tokens=n, eos_token_id=full[at], **samp)
+    rest = [srv.submit(q, max_new_tokens=n, **s) for q, s in others]
+    srv.run()
+    assert r_eos.status is RequestStatus.OK
+    assert r_eos.output == full[:at + 1]
+    for (q, s), r in zip(others, rest):
+        assert r.output == sampled_generate(eng, q, n, **s), (q, s)
+    assert flight_delta(srv, before)["void_rows"] == (
+        1 if runs_ahead(srv) else 0)
+    again = srv.submit(p, max_new_tokens=n, **samp)
+    srv.run()
+    assert again.output == full
+    assert srv.allocator.num_used == 0
+    srv.allocator.assert_consistent()
+
+
+def test_cancel_and_deadline_with_a_dispatch_in_flight(packed):
+    """``cancel()`` and a deadline meet a request whose row is on the
+    device: it ends at once (its applied blocks commit, the rest are
+    freed), the row in flight is void, the survivors stream on
+    untouched, and the drained pool holds nothing."""
+    eng, srv = packed
+    n = 10
+    before = dict(srv.flight_counts)
+    batch = [srv.submit(p, max_new_tokens=n, **samp)
+             for p, samp in PACKED_MIX]
+    for _ in range(4):
+        srv.step()
+    r_cancel, r_late = batch[0], batch[3]
+    assert r_cancel.state is r_late.state is RequestState.RUNNING
+    assert bool(srv._flight) == runs_ahead(srv)
+    assert srv.cancel(r_cancel)
+    r_late.deadline_s = 1.0
+    r_late.submit_time -= 100.0              # expires at the next sweep
+    srv.run()
+    assert r_cancel.status is RequestStatus.CANCELLED
+    assert r_late.status is RequestStatus.TIMED_OUT
+    for (p, samp), r in zip(PACKED_MIX, batch):
+        want = sampled_generate(eng, p, n, **samp)
+        if r in (r_cancel, r_late):
+            assert r.output == want[:len(r.output)] and len(r.output) < n
+        else:
+            assert r.status is RequestStatus.OK and r.output == want
+    assert flight_delta(srv, before)["void_rows"] == (
+        2 if runs_ahead(srv) else 0)
+    assert srv.allocator.num_used == 0
+    srv.allocator.assert_consistent()
+
+
+def test_poisoned_slot_with_a_dispatch_in_flight(packed):
+    """NaN in one slot's KV while a dispatch is on the device: the
+    request is quarantined alone when the poisoned dispatch's result
+    arrives, the row the next dispatch carries for it is void, and the
+    others stream ``generate()``'s tokens."""
+    eng, srv = packed
+    n = 8
+    before = dict(srv.flight_counts)
+    batch = [srv.submit(p, max_new_tokens=n, temperature=0.0)
+             for p, _ in PACKED_MIX[:3]]
+    for _ in range(3):
+        srv.step()
+    victim = batch[1]
+    assert victim.state is RequestState.RUNNING
+    assert bool(srv._flight) == runs_ahead(srv)
+    block = srv.allocator.block_table(victim.req_id)[0]
+    name = "_pool_ks" if srv.kv_bits else "_pool_k"
+    pool = getattr(srv, name)
+    setattr(srv, name, jax.device_put(pool.at[:, block].set(jnp.nan),
+                                      pool.sharding))
+    srv.run()
+    assert victim.status is RequestStatus.FAILED
+    assert "quarantined" in victim.error
+    for (p, _), r in zip(PACKED_MIX[:3], batch):
+        if r is not victim:
+            assert r.status is RequestStatus.OK
+            assert r.output == sampled_generate(eng, p, n, 0.0)
+    assert flight_delta(srv, before)["void_rows"] == (
+        1 if runs_ahead(srv) else 0)
+    assert srv.allocator.num_used == 0
+    srv.allocator.assert_consistent()
+
+
+def test_chunk_remainder_runs_ahead_like_any_other_dispatch(packed):
+    """A chunk remainder's second dispatch has no decode row: the slots'
+    newest tokens pass through its result array, so the iteration after
+    it still finds every input token on the device, and the dispatch
+    itself is enqueued ahead."""
+    eng, srv = packed
+    rs = np.random.RandomState(83)
+    # 20 tokens leave a 4-token remainder of the 16-token budget
+    prompts = [rs.randint(0, 64, (k,)).tolist() for k in (9, 20, 30, 7)]
+    before = dict(srv.flight_counts)
+    first = srv.submit(prompts[0], max_new_tokens=10)
+    srv.step()
+    srv.step()                               # decoding, a dispatch ahead
+    rest = [srv.submit(p, max_new_tokens=8) for p in prompts[1:]]
+    srv.run()
+    for p, r in zip(prompts, [first] + rest):
+        assert r.status is RequestStatus.OK
+        assert r.output == sampled_generate(
+            eng, p, r.max_new_tokens, srv.temperature), p
+    d = flight_delta(srv, before)
+    if runs_ahead(srv):
+        # nothing drained: only the very first dispatch had no predecessor
+        assert d["ahead_dispatches"] == d["dispatches"] - 1
+    assert d["void_rows"] == 0
+
+
+@pytest.mark.parametrize("news", ["preemption", "promotion", "prefill_only",
+                                  "dispatch_fault"])
+def test_what_cannot_be_planned_from_counts_drains_first(news, injector):
+    """The loop lands the dispatch in flight before a plan it cannot make
+    from counts: a preemption under KV pressure, a pending host->pool
+    promotion, a ``prefill_only`` hand-off; a transient fault at
+    ``serving.dispatch`` skips the dispatch ahead and still applies the
+    one in flight.  Chosen by what the engine observes — every stream is
+    ``generate()``'s and the pool drains clean."""
+    host = {"host_cache": {"enabled": True, "dram_budget_bytes": 1 << 20,
+                           "wire_bits": 0}}
+    serving = {
+        "preemption": {"kv_block_size": 4, "num_kv_blocks": 15,
+                       "max_batch_slots": 3},
+        "promotion": {"kv_block_size": 4, "num_kv_blocks": 14,
+                      "max_batch_slots": 2, **host},
+        "prefill_only": host, "dispatch_fault": {}}[news]
+    eng, srv = serving_engine(serving=serving)
+    rs = np.random.RandomState(29)
+    n = 12 if news == "preemption" else 6
+    prompts = [rs.randint(0, 64, (k,)).tolist() for k in (12, 10, 11, 9)]
+    kwargs = [{}] * len(prompts)
+    if news == "prefill_only":
+        kwargs = [{}, {"prefill_only": True}, {}, {}]
+    if news == "dispatch_fault":
+        injector.add_plan("serving.dispatch", "fail", at=4, count=2)
+    drained = []                   # steps that left nothing in flight
+    reqs = [srv.submit(p, max_new_tokens=n, **kw)
+            for p, kw in zip(prompts, kwargs)]
+    if news == "promotion":
+        # a second pass over the same prompts finds their blocks spilled
+        # to the host tier: admission hits there, promotions pend
+        srv.run()
+        reqs += [srv.submit(p, max_new_tokens=n) for p in prompts]
+    while srv.step():
+        drained.append(not srv._flight)
+    assert any(drained) and not all(drained)
+    if news == "preemption":
+        assert srv.scheduler.preemption_count > 0
+    if news == "promotion":
+        assert srv.host_counts["promoted_blocks"] > 0
+    for r in reqs:
+        assert r.status is RequestStatus.OK
+        if r.prefill_only:
+            assert r.output == []
+        else:
+            np.testing.assert_array_equal(
+                np.asarray(r.output), _generate(eng, r.prompt, n))
+    assert srv.flight_counts["ahead_dispatches"] > 0
+    assert srv.decode_builds == 2
+    assert srv.allocator.num_used == 0
     srv.allocator.assert_consistent()

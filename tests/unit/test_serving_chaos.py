@@ -42,7 +42,8 @@ def env_injector():
 
 
 def chaos_engine(num_kv_blocks=16, slots=3, max_queue_depth=16,
-                 kv_cache_bits=0, spec_k=None, draft=False):
+                 kv_cache_bits=0, spec_k=None, draft=False,
+                 host_tier=True):
     cfg = gpt2_config("125m", num_layers=2, d_model=32, num_heads=4,
                       vocab_size=64, max_seq_len=64, dtype=jnp.float32)
     serving = {"enabled": True, "kv_block_size": 4,
@@ -56,7 +57,7 @@ def chaos_engine(num_kv_blocks=16, slots=3, max_queue_depth=16,
                # serving.promote matrix entries bite; wire_bits 0 keeps
                # the raw-f32 pool's spill/promote LOSSLESS — OK streams
                # must stay token-exact whatever the fault schedule
-               "host_cache": {"enabled": True,
+               "host_cache": {"enabled": host_tier,
                               "dram_budget_bytes": 1 << 20,
                               "wire_bits": 0}}
     if spec_k is not None:
@@ -263,6 +264,67 @@ def test_chaos_randomized_interleaving(env_injector):
                 err_msg=f"prompt {p}")
 
 
+@pytest.mark.parametrize("host_tier", [False, True],
+                         ids=["ahead", "with_promotions"])
+def test_chaos_news_arrives_with_a_dispatch_in_flight(env_injector,
+                                                      host_tier):
+    """The loop keeps an iteration in flight (ISSUE 37), so every piece
+    of news below lands one dispatch late: an eos in mid-stream, a
+    cancel, an expired deadline, a poisoned slot, KV pressure over an
+    undersized pool — in a seeded random order, on top of the env fault
+    schedule.  Void rows are counted and nothing of them is committed:
+    the drain is clean, the step was built once a shape, every OK stream
+    is ``generate()``'s (cut at its eos).  Without the host tier every
+    dispatch but those after a drain runs ahead."""
+    eng, srv = chaos_engine(num_kv_blocks=14, slots=3, max_queue_depth=6,
+                            host_tier=host_tier)
+    rs = np.random.RandomState(3737)
+    new = 8
+    reqs, prompts, eos = [], [], []
+    poisoned = None
+    for i in range(60):
+        op = rs.choice(["submit", "step", "cancel", "step", "submit",
+                        "poison", "step"])
+        if op == "submit" and len(reqs) < 14:
+            p = rs.randint(0, 64, (int(rs.randint(3, 14)),)).tolist()
+            want = _generate(eng, p, new).tolist()
+            # every other request ends on a token of its own stream
+            stop = want[int(rs.randint(2, new - 1))] \
+                if rs.random_sample() < 0.5 else None
+            r = srv.submit(p, max_new_tokens=new, eos_token_id=stop)
+            prompts.append(p)
+            reqs.append(r)
+            eos.append(stop)
+            if rs.random_sample() < 0.15:
+                r.deadline_s = 1.0
+                r.submit_time -= 50.0
+        elif op == "cancel" and reqs:
+            srv.cancel(reqs[int(rs.randint(len(reqs)))])
+        elif op == "poison" and poisoned is None:
+            running = [r for r in reqs if r.state is RequestState.RUNNING
+                       and r.cached_tokens > 0]
+            if running:
+                poisoned = running[0]
+                poison_slot_kv(srv, poisoned)
+        else:
+            srv.step()
+    finished = srv.run()
+
+    assert_drained_clean(srv, reqs, finished)
+    assert not srv._flight
+    counts = srv.flight_counts
+    assert counts["ahead_dispatches"] > 0
+    assert counts["void_rows"] > 0, "no news met a dispatch in flight"
+    assert sum(1 for r in reqs
+               if r.status is RequestStatus.OK) >= 3, "nothing survived"
+    for p, stop, r in zip(prompts, eos, reqs):
+        if r.status is RequestStatus.OK:
+            want = _generate(eng, p, new).tolist()
+            if stop is not None:
+                want = want[:want.index(stop) + 1]
+            assert r.output == want, f"prompt {p} eos {stop}"
+
+
 def test_flight_recorder_dumps_on_serving_error(tmp_path):
     """Black-box flight recorder end-to-end (docs/observability.md
     "Flight recorder"): with the recorder + tracing armed, a fatal
@@ -289,7 +351,9 @@ def test_flight_recorder_dumps_on_serving_error(tmp_path):
     fr, rt, tracer = (get_flight_recorder(), get_request_tracer(),
                       get_tracer())
     fi = install_fault_injector(FaultInjector())
-    fi.add_plan("serving.dispatch", "fatal", at=3)
+    # the first step() enqueues three dispatches (two chunks, then the
+    # iteration it plans ahead); the fault meets the second call's
+    fi.add_plan("serving.dispatch", "fatal", at=4)
     try:
         fr.configure(enabled=True, capacity=32, output_dir=out_dir)
         fr.reset()

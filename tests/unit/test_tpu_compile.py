@@ -631,6 +631,50 @@ def test_serving_step_lowers_for_tpu(compiled_kernels, mesh, chunk_lane):
         == (2 if chunk_lane else 1)
 
 
+@pytest.mark.parametrize("mesh", [{"data": 1, "model": 1},
+                                  {"data": 2, "model": 2}])
+def test_both_shapes_take_the_previous_result(compiled_kernels, mesh):
+    """The loop keeps a dispatch in flight (ISSUE 37): the step takes the
+    previous dispatch's result array as one more device operand, ALWAYS —
+    zeros before the first dispatch — so there is no third shape: one
+    ``_build_step()`` lowers for the TPU in its two shapes, each with
+    that ``[slots, result columns]`` int32 parameter beside the two host
+    arrays, and ``decode_builds`` reads 2."""
+    import re
+    import deepspeed_tpu as ds
+    from deepspeed_tpu.inference.serving.engine import (_CHUNK_HEAD, _R_SPEC,
+                                                        _SLOT_COLS)
+    slots, chunk = 4, 32
+    model = TransformerLM(gpt2_config(
+        "125m", num_layers=2, d_model=256, num_heads=8, vocab_size=512,
+        max_seq_len=128))
+    srv = ds.init_inference(model, {
+        "dtype": "bfloat16", "max_out_tokens": 128,
+        "serving": {"enabled": True, "kv_block_size": 16,
+                    "num_kv_blocks": 32, "max_batch_slots": slots,
+                    "prefill_chunk_tokens": chunk,
+                    "mesh": mesh}}).serving_engine()
+    step = srv._build_step()
+    for chunk_lane in (True, False):
+        operands = srv._idle_operands(chunk_lane)
+        prev, slot_state, chunk_vec = operands[-3:]
+        assert prev is srv._prev_result
+        assert prev.shape == (slots, _R_SPEC) and prev.dtype == jnp.int32
+        text = lower_for_tpu(step, *operands)
+        main = re.search(r"func\.func public @main\((.*?)\) ->", text,
+                         re.S).group(1)
+        ints = re.findall(r"tensor<([\dx]+)xi32>", main)
+        # (the decode-only program reads nothing of the chunk's head,
+        # so jit drops that parameter)
+        assert ints == [
+            f"{slots}x{_R_SPEC}",
+            f"{slots}x{_SLOT_COLS + srv.max_pages}"] + (
+            [str(_CHUNK_HEAD + chunk)] if chunk_lane else [])
+        assert len(re.findall(r"%arg\d+:", main)) == len(
+            jax.tree_util.tree_leaves(operands)) - (not chunk_lane)
+    assert srv.decode_builds == 2
+
+
 def hlo_computations(text):
     """Optimized HLO text -> ``({computation: its instruction lines},
     the entry computation's name)``."""
