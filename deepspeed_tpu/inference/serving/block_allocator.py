@@ -95,16 +95,23 @@ def kv_block_bytes(block_size: int, kv_heads: int, head_dim: int,
 
 
 def latent_block_bytes(block_size: int, latent_width: int,
-                       rope_width: int, cache_itemsize: int = 2) -> int:
-    """Device HBM bytes one pool block costs in ONE attention sublayer of
-    a LATENT pool (``models/latent_moe.py init_paged_cache``): a token's
-    row is its latent (``latent_width`` values: key and value of every
-    head) and its rotary key side by side, padded to whole 128-lane
-    tiles.  A model holds ``ATTN_SUBLAYERS * num_layers`` sublayers (2 a
-    layer in the shortcut block, 1 in the sandwich block).  Pure ints, pinned
-    against the model by test, like :func:`kv_block_bytes`."""
+                       rope_width: int, cache_itemsize: int = 2,
+                       layers: int = 1, index_width: int = 0,
+                       index_layers: int = 0) -> int:
+    """Device HBM bytes one pool block costs in ``layers`` attention
+    sublayers of a LATENT pool (``models/latent_moe.py init_paged_cache``;
+    ONE by default): a token's row is its latent (``latent_width`` values:
+    key and value of every head) and its rotary key side by side, padded
+    to whole 128-lane tiles.  A model holds ``ATTN_SUBLAYERS * num_layers``
+    sublayers (2 a layer in the shortcut block, 1 in the sandwich block).
+    A model with a learned sparse selection holds a second kind of row
+    under the same table (``models/sparse_latent_moe.py``): an indexer key
+    of ``index_width`` values a token in each of its ``index_layers``
+    layers that compute a selection.  Pure ints, pinned against the model
+    by test, like :func:`kv_block_bytes`."""
     lanes = -(-(latent_width + rope_width) // 128) * 128
-    return block_size * lanes * cache_itemsize
+    return block_size * cache_itemsize * (layers * lanes
+                                          + index_layers * index_width)
 
 
 def blocks_for_budget(budget_bytes: int, block_size: int, kv_heads: int,

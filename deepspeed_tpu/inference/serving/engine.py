@@ -1751,7 +1751,9 @@ class ServingEngine:
         with trace_span("serving/dispatch", decode=len(dec),
                         chunk_tokens=c_len, spec=len(spec), rows=rows,
                         tp=self.tp_mesh.size, ahead=int(ahead),
-                        moe=int(bool(self.model.PAGED_COUNTERS))):
+                        moe=int(bool(self.model.PAGED_COUNTERS)),
+                        sparse=int("sparse_tokens_read"
+                                   in self.model.PAGED_COUNTERS)):
             result = self._launch(operands)
             # queued behind the program now, not requested once the host
             # has noticed that it ended

@@ -37,12 +37,20 @@ layers before the expert layers): they run as a short scan of their own
 before the scan over ``params["blocks"]``, through the same pool — layer
 ``l`` of the whole stack at sublayer index ``ATTN_SUBLAYERS * l`` — while
 the expert stack is indexed by the layer's number among ``blocks``.
+:class:`DenseLeadMoELM` is that stack with a shared expert in every
+expert layer, for the block definitions that have both
+(``models/sandwich_moe.py``, ``models/sparse_latent_moe.py``).
+
+What the two scans carry is the block's own (``_paged_state``): the
+latent pool alone, or with it a second pool and whatever one layer hands
+the next; what a layer is told of itself (``_layer_meta``) is its block
+offset into the pool, or more.
 """
 from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Dict, Optional
+from typing import Dict, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -52,6 +60,22 @@ from . import layers as L
 from ..moe import dropless
 from ..observability.overlap import scoped
 from .transformer import TransformerConfig, TransformerLM
+
+class MixedStep(NamedTuple):
+    """One dispatch of the mixed step as every layer sees it: the slots'
+    tables and lengths, which decode rows are live, the chunk's slot,
+    start and length, every row's position, which rows carry a token,
+    and the pool's blocks a sublayer."""
+    tables: jax.Array
+    lens: jax.Array
+    act: jax.Array
+    chunk_slot: jax.Array
+    chunk_start: jax.Array
+    chunk_len: jax.Array
+    positions: jax.Array
+    row_valid: jax.Array
+    num_blocks: int
+
 
 #: std of a seeded selection bias, in units of the mean score 1 / outputs:
 #: large enough to move choices, as a trained bias does
@@ -156,9 +180,10 @@ class LatentMoELM(TransformerLM):
         ``params["blocks"]``, or None."""
         return None
 
-    def _extra_counters(self, row_valid) -> list:
+    def _extra_counters(self, step, state) -> list:
         """What the block counts a dispatch beyond ``PAGED_COUNTERS`` of
-        this class, in its own ``PAGED_COUNTERS``' order."""
+        this class, in its own ``PAGED_COUNTERS``' order, from the step's
+        operands (``MixedStep``) and what the scans carried out."""
         return []
 
     # -- refusals ----------------------------------------------------------
@@ -210,7 +235,7 @@ class LatentMoELM(TransformerLM):
         if host_cache:
             return ("serving.host_cache: the host tier's block codec "
                     "encodes kv_heads x head_dim rows of k and v, not "
-                    "latent rows")
+                    "latent rows nor a selection's indexer keys")
         return None
 
     # -- init --------------------------------------------------------------
@@ -276,15 +301,25 @@ class LatentMoELM(TransformerLM):
 
     # -- the sublayers -----------------------------------------------------
     @scoped("attn_proj")
-    def _mla_project(self, p, x, positions):
+    def _q_latent(self, p, x):
+        """x [B, T, h] -> the normalised (and scaled) query latent
+        [B, T, r_q]."""
+        cq = self._norm_fn("attn_proj")(p["q_norm"],
+                                        L.dense_apply(p["q_a"], x))
+        if self._q_scale != 1.0:
+            cq = (cq * self._q_scale).astype(x.dtype)
+        return cq
+
+    @scoped("attn_proj")
+    def _mla_project(self, p, x, positions, cq=None):
         """x [B, T, h] -> (q_nope [B,T,H,dn], q_rope [B,T,H,dr] rotated,
-        c [B,T,r_kv] normalised, k_rope [B,T,dr] rotated)."""
+        c [B,T,r_kv] normalised, k_rope [B,T,dr] rotated).  ``cq``: the
+        query latent, where the caller already has it."""
         c = self.config
         b, t, _ = x.shape
         norm = self._norm_fn("attn_proj")
-        cq = norm(p["q_norm"], L.dense_apply(p["q_a"], x))
-        if self._q_scale != 1.0:
-            cq = (cq * self._q_scale).astype(x.dtype)
+        if cq is None:
+            cq = self._q_latent(p, x)
         q = L.dense_apply(p["q_b"], cq).reshape(
             b, t, c.num_heads, c.qk_nope_head_dim + c.qk_rope_head_dim)
         q_nope, q_rope = (q[..., :c.qk_nope_head_dim],
@@ -308,10 +343,12 @@ class LatentMoELM(TransformerLM):
             c.kv_lora_rank, c.num_heads, c.qk_nope_head_dim + c.v_head_dim)
         return w[..., :c.qk_nope_head_dim], w[..., c.qk_nope_head_dim:]
 
-    def _mla_expanded(self, p, x, positions):
-        """Full-sequence causal MLA in the expanded form, plain XLA."""
+    def _mla_expanded(self, p, x, positions, cq=None, chosen=None):
+        """Full-sequence causal MLA in the expanded form, plain XLA —
+        over every earlier position, or over ``chosen [B, T, T]`` of
+        them."""
         b, t, _ = x.shape
-        q_nope, q_rope, lat, k_rope = self._mla_project(p, x, positions)
+        q_nope, q_rope, lat, k_rope = self._mla_project(p, x, positions, cq)
         w_uk, w_uv = self._kv_b(p, x.dtype)
         with jax.named_scope("attn_proj"):
             k_nope = jnp.einsum("btr,rhd->bthd", lat, w_uk)
@@ -322,8 +359,9 @@ class LatentMoELM(TransformerLM):
                  + jnp.einsum("bqhd,bkd->bhqk", q_rope, k_rope,
                               preferred_element_type=jnp.float32)
                  ) * self._sm_scale
-            causal = jnp.tril(jnp.ones((t, t), bool))
-            s = jnp.where(causal[None, None], s, -jnp.inf)
+            if chosen is None:
+                chosen = jnp.tril(jnp.ones((t, t), bool))[None]
+            s = jnp.where(chosen[:, None], s, -jnp.inf)
             o = jnp.einsum("bhqk,bkhd->bqhd",
                            jax.nn.softmax(s, axis=-1).astype(x.dtype), v)
         with jax.named_scope("attn_proj"):
@@ -390,6 +428,37 @@ class LatentMoELM(TransformerLM):
                                 num_blocks, block_size, lanes), dtype),
                 "v": None}
 
+    def _pool_rows(self, tables, lens, act, chunk_slot, chunk_start,
+                   chunk_len, cw, blk, null):
+        """The pool row every row of the step writes — a decode slot's
+        at its length, a chunk row's at its position, a masked row's in
+        the null block ``null`` — and the chunk slot's table."""
+        bsl, npages = tables.shape
+        slot = jnp.arange(bsl)
+        null_row = null * blk
+        write = [jnp.where(
+            act, tables[slot, lens // blk] * blk + lens % blk, null_row)]
+        ctable = None
+        if cw:
+            ci = jnp.arange(cw)
+            cpos = chunk_start + ci
+            ctable = tables[chunk_slot]
+            write.append(jnp.where(
+                ci < chunk_len,
+                ctable[jnp.minimum(cpos // blk, npages - 1)] * blk
+                + cpos % blk, null_row))
+        return jnp.concatenate(write), ctable
+
+    @staticmethod
+    def _scatter_rows(pool, write, rows):
+        """``rows [T, <= lanes]`` into ``pool [blocks, block, lanes]`` at
+        the flat rows ``write``, padded to whole pool rows."""
+        lanes = pool.shape[2]
+        rows = jnp.pad(rows.astype(pool.dtype),
+                       ((0, 0), (0, lanes - rows.shape[1])))
+        return pool.reshape(-1, lanes).at[write].set(rows).reshape(
+            pool.shape)
+
     def _paged_latent_attention(self, p, xn, pool, tables, lens, act,
                                 chunk_slot, chunk_start, chunk_len, null,
                                 positions):
@@ -403,28 +472,13 @@ class LatentMoELM(TransformerLM):
         bsl = lens.shape[0]
         t = xn.shape[1]
         cw = t - bsl
-        blk, npages = pool.shape[1], tables.shape[1]
         q_nope, q_rope, lat, k_rope = self._mla_project(p, xn, positions)
         with jax.named_scope("pool_write"):
-            slot = jnp.arange(bsl)
-            null_row = null * blk
-            write = [jnp.where(
-                act, tables[slot, lens // blk] * blk + lens % blk, null_row)]
-            if cw:
-                ci = jnp.arange(cw)
-                cpos = chunk_start + ci
-                ctable = tables[chunk_slot]
-                write.append(jnp.where(
-                    ci < chunk_len,
-                    ctable[jnp.minimum(cpos // blk, npages - 1)] * blk
-                    + cpos % blk, null_row))
-            write = jnp.concatenate(write)
-            lanes = pool.shape[2]
-            rows = jnp.concatenate([lat[0], k_rope[0]], axis=-1)
-            rows = jnp.pad(rows.astype(pool.dtype),
-                           ((0, 0), (0, lanes - rows.shape[1])))
-            pool = pool.reshape(-1, lanes).at[write].set(rows).reshape(
-                pool.shape)
+            write, ctable = self._pool_rows(
+                tables, lens, act, chunk_slot, chunk_start, chunk_len, cw,
+                pool.shape[1], null)
+            pool = self._scatter_rows(
+                pool, write, jnp.concatenate([lat[0], k_rope[0]], axis=-1))
         w_uk, w_uv = self._kv_b(p, xn.dtype)
         with jax.named_scope("attn_proj"):
             q_lat = jnp.einsum("thd,rhd->thr", q_nope[0], w_uk)
@@ -441,16 +495,55 @@ class LatentMoELM(TransformerLM):
             o = jnp.einsum("thr,rhd->thd", o_lat, w_uv)
             return L.dense_apply(p["out"], o.reshape(1, t, -1)), pool
 
+    # -- what the mixed step's two scans carry, and what a layer is told --
+    def _paged_state(self, params, cache, step):
+        """What the scans carry besides the activations: the latent pool
+        as one ``[sublayers * num_blocks, block, lanes]`` buffer."""
+        k = cache["k"]
+        return k.reshape(k.shape[0] * k.shape[1], *k.shape[2:])
+
+    def _paged_pools(self, state, cache) -> Dict:
+        """The pools of the cache the step returns, from the carry."""
+        return {"k": state.reshape(cache["k"].shape), "v": None}
+
+    def _paged_probe(self, state):
+        """What a check may see of a layer's attention in the carry
+        (``_apply_paged_mixed(probe=True)``): nothing, for a block that
+        reads every page."""
+        return None
+
+    def _layer_meta(self, step, first, count):
+        """What layers ``first .. first + count`` of the stack are each
+        told (a scan's ``xs``): the block offset of the layer's first
+        sublayer into the pool."""
+        with jax.named_scope("pool_write"):
+            return (first + jnp.arange(count, dtype=step.tables.dtype)
+                    ) * (self.ATTN_SUBLAYERS * step.num_blocks)
+
+    def _paged_attend(self, params, step, off):
+        """``attend`` (``_latent_block``'s contract) of the layer whose
+        meta is ``off``."""
+        def attend(j, p, xn, pool):
+            with jax.named_scope("pool_write"):
+                at = off + j * step.num_blocks
+                tables_at = step.tables + at
+            return self._paged_latent_attention(
+                p, xn, pool, tables_at, step.lens, step.act,
+                step.chunk_slot, step.chunk_start, step.chunk_len, at,
+                step.positions)
+        return attend
+
     def _apply_paged_mixed(self, params, cache, dec_tokens, dec_active,
                            chunk_ids, chunk_slot, chunk_start, chunk_len,
-                           spec_tokens=None, spec_active=None):
+                           spec_tokens=None, spec_active=None, probe=False):
         """The mixed step of ``TransformerLM._apply_paged_mixed`` for a
         latent block: same operands, same results, the latent pool as the
         scans' carry (sublayer ``j`` of layer ``l`` is the block offset
         ``(ATTN_SUBLAYERS * l + j) * num_blocks`` into the one buffer).
         ``new_cache`` also holds ``counters`` — int32
         ``[len(PAGED_COUNTERS)]``, this dispatch's sums over the
-        layers."""
+        layers — and with ``probe`` (a check's, never the engine's)
+        ``probe``: every layer's ``_paged_probe`` of the carry, stacked."""
         if spec_tokens is not None:
             raise NotImplementedError(self.paged_refusal(spec=True))
         if cache.get("k_scale") is not None:
@@ -466,36 +559,27 @@ class LatentMoELM(TransformerLM):
             row_valid = jnp.concatenate([act, ci < chunk_len])
         x = self._embed_tokens(params, ids)
         ns, nb = cache["k"].shape[:2]
-        pool = cache["k"].reshape(ns * nb, *cache["k"].shape[2:])
-        per_layer = self.ATTN_SUBLAYERS * nb
+        step = MixedStep(tables, lens, act, chunk_slot, chunk_start,
+                         chunk_len, positions, row_valid, nb)
+        state = self._paged_state(params, cache, step)
 
-        def attend_at(off):
-            def attend(j, p, xn, pool):
-                with jax.named_scope("pool_write"):
-                    at = off + j * nb
-                    tables_at = tables + at
-                return self._paged_latent_attention(
-                    p, xn, pool, tables_at, lens, act, chunk_slot,
-                    chunk_start, chunk_len, at, positions)
-            return attend
-
-        def offsets(first, count):
-            with jax.named_scope("pool_write"):
-                return (first + jnp.arange(count, dtype=tables.dtype)
-                        ) * per_layer
+        def attend_at(meta):
+            return self._paged_attend(params, step, meta)
 
         lead, leading = self._leading_blocks(params), 0
         if lead is not None:
             leading = jax.tree_util.tree_leaves(lead)[0].shape[0]
 
             def lead_fn(carry, xs):
-                bp, off = xs
-                y, pool, _ = self._latent_block(
-                    self.block_transform(bp), carry[0], attend_at(off),
+                bp, meta = xs
+                y, state, _ = self._latent_block(
+                    self.block_transform(bp), carry[0], attend_at(meta),
                     carry[1], row_valid)
-                return (y, pool), None
-            (x, pool), _ = jax.lax.scan(lead_fn, (x, pool),
-                                        (lead, offsets(0, leading)))
+                return (y, state), (self._paged_probe(state) if probe
+                                    else None)
+            (x, state), seen_lead = jax.lax.scan(
+                lead_fn, (x, state),
+                (lead, self._layer_meta(step, 0, leading)))
 
         # the expert stack stays out of the scan's xs: sliced per layer
         # it would be copied whole, every step, to reach the kernel
@@ -506,19 +590,20 @@ class LatentMoELM(TransformerLM):
         scanned = experts["w_up"].shape[0]
 
         def scan_fn(carry, xs):
-            y, pool, counts = carry
-            bp, off, layer = xs
-            y, pool, moe_counts = self._latent_block(
-                self.block_transform(bp), y, attend_at(off), pool,
+            y, state, counts = carry
+            bp, meta, layer = xs
+            y, state, moe_counts = self._latent_block(
+                self.block_transform(bp), y, attend_at(meta), state,
                 row_valid, (experts, layer))
             with jax.named_scope("expert_layout"):
                 counts = counts + moe_counts
-            return (y, pool, counts), None
+            return (y, state, counts), (self._paged_probe(state) if probe
+                                        else None)
 
         zero = jnp.zeros((len(dropless.COUNTERS),), jnp.int32)
-        (x, pool, counts), _ = jax.lax.scan(
-            scan_fn, (x, pool, zero),
-            (blocks, offsets(leading, scanned),
+        (x, state, counts), seen = jax.lax.scan(
+            scan_fn, (x, state, zero),
+            (blocks, self._layer_meta(step, leading, scanned),
              jnp.arange(scanned, dtype=jnp.int32)))
         x = self._norm_fn("head")(params["ln_f"], x)
         with jax.named_scope("head"):
@@ -539,10 +624,117 @@ class LatentMoELM(TransformerLM):
             new_lens = (lens + act.astype(lens.dtype)).at[chunk_slot].add(
                 chunk_len, mode="drop")
             extra = [jnp.asarray(v, jnp.int32)[None]
-                     for v in self._extra_counters(row_valid)]
+                     for v in self._extra_counters(step, state)]
             counters = jnp.concatenate(
                 [counts, (read * ns).astype(jnp.int32)[None], *extra])
-        new_cache = {
-            "k": pool.reshape(ns, nb, *pool.shape[1:]), "v": None,
-            "block_tables": tables, "lens": new_lens, "counters": counters}
+        new_cache = dict(self._paged_pools(state, cache),
+                         block_tables=tables, lens=new_lens,
+                         counters=counters)
+        if probe:
+            new_cache["probe"] = seen if lead is None else \
+                jax.tree_util.tree_map(lambda a, b: jnp.concatenate([a, b]),
+                                       seen_lead, seen)
         return dec_logits, chunk_logits, new_cache
+
+
+# ---------------------------------------------------------------------------
+# leading dense layers, then expert layers with a shared expert
+# ---------------------------------------------------------------------------
+@dataclasses.dataclass(frozen=True)
+class DenseLeadMoEConfig(LatentMoEConfig):
+    """``LatentMoEConfig`` for a stack of two kinds of layer:
+    ``first_k_dense`` dense layers, then expert layers in each of which
+    ``n_shared_experts`` shared experts run beside the routed ones."""
+    first_k_dense: int = 3
+    n_shared_experts: int = 1
+
+    @property
+    def scan_length(self) -> int:
+        """The expert layers: what ``params["blocks"]`` stacks."""
+        return self.num_layers - self.first_k_dense
+
+    def stack_params(self, shell: int) -> int:
+        """Parameters of the whole model, from those of one layer outside
+        its FFN (attention and norms)."""
+        d = self.d_model
+        dense = shell + 3 * d * self.ff_dim
+        expert = (shell + self.moe_params()
+                  + 3 * d * self.n_shared_experts * self.expert_d_ff)
+        return (self.first_k_dense * dense + self.scan_length * expert
+                + 2 * self.vocab_size * d + d)
+
+
+class DenseLeadMoELM(LatentMoELM):
+    """``LatentMoELM`` for such a stack: the ``first_k_dense`` leading
+    layers are ``params["dense_blocks"]`` and the expert layers
+    ``params["blocks"]``; a block definition brings ``_shell_init`` (one
+    layer outside its FFN) and ``_latent_block``.  The shared expert is a
+    SwiGLU of ``n_shared_experts * expert_d_ff`` that every row goes
+    through, whatever the router says — whole on every chip of a
+    deployment, so computed here for every row and not part of
+    ``experts_held``'s share."""
+
+    PAGED_COUNTERS = LatentMoELM.PAGED_COUNTERS + ("moe_rows_shared",)
+
+    def __init__(self, config: DenseLeadMoEConfig, constrain=None,
+                 block_transform=None):
+        super().__init__(config, constrain, block_transform)
+        if not 0 <= config.first_k_dense < config.num_layers:
+            raise ValueError(
+                f"first_k_dense {config.first_k_dense} leaves no expert "
+                f"layer among {config.num_layers}")
+
+    # -- init --------------------------------------------------------------
+    def _shell_init(self, k) -> Dict:
+        raise NotImplementedError
+
+    def init_dense_block(self, k) -> Dict:
+        ka, kf = jax.random.split(k)
+        return dict(self._shell_init(ka), mlp=self._ffn_init(kf))
+
+    def init_superblock(self, k) -> Dict:
+        """One expert layer."""
+        c = self.config
+        ka, km, ks = jax.random.split(k, 3)
+        return dict(self._shell_init(ka), moe=self._moe_init(km),
+                    shared=self._ffn_init(
+                        ks, c.n_shared_experts * c.expert_d_ff))
+
+    def init_resident(self, rng) -> Dict:
+        """Embedding, final norm, head — and the leading dense layers,
+        which no scan over ``blocks`` streams."""
+        params = super().init_resident(rng)
+        k = self.config.first_k_dense
+        if k:
+            params["dense_blocks"] = jax.vmap(self.init_dense_block)(
+                jax.random.split(jax.random.split(rng, 8)[6], k))
+        return params
+
+    def _leading_blocks(self, params) -> Optional[Dict]:
+        return params.get("dense_blocks")
+
+    # -- the FFN sublayer --------------------------------------------------
+    def expert_layer(self, bp, u, row_valid=None, stack=None):
+        """An expert layer's ``F_l``: u [B, T, h] -> ``(Shared(u) + this
+        chip's part of the routed experts' output, counters)``.  The
+        shared expert is a plain SwiGLU over every row, whatever the
+        router says; ``stack`` as in ``_latent_block``."""
+        routed, counters = self._moe_sublayer(bp["moe"], u, row_valid,
+                                              stack)
+        shared = self._mlp(bp["shared"], u, scope="shared_expert")
+        with jax.named_scope("expert_layout"):
+            return shared + routed, counters
+
+    def _ffn_sublayer(self, bp, u, row_valid=None, stack=None):
+        """``F_l`` of either kind of layer: a dense layer is one whose
+        parameters hold ``mlp`` and no ``moe``, and counts nothing."""
+        if "moe" in bp:
+            return self.expert_layer(bp, u, row_valid, stack)
+        return (self._mlp(bp["mlp"], u),
+                jnp.zeros((len(dropless.COUNTERS),), jnp.int32))
+
+    def _extra_counters(self, step, state) -> list:
+        """``moe_rows_shared``: every row that carries a token goes
+        through the shared expert of every expert layer."""
+        return [jnp.sum(step.row_valid, dtype=jnp.int32)
+                * self.config.scan_length]

@@ -357,6 +357,40 @@ def openpangu_ultra_moe_config(size: str = "718b", **kw) -> TransformerConfig:
         "layernorm_eps": 1e-5, **OPENPANGU_ULTRA_MOE_SIZES[size], **kw})
 
 
+GLM_MOE_DSA_SIZES = {
+    # https://huggingface.co/zai-org/GLM-5.2/blob/main/config.json
+    "5.2": dict(num_layers=78, first_k_dense=3, num_heads=64, d_model=6144,
+                d_ff=12288, head_dim=256, vocab_size=154880,
+                max_seq_len=1048576, q_lora_rank=2048, kv_lora_rank=512,
+                qk_nope_head_dim=192, qk_rope_head_dim=64, v_head_dim=256,
+                rope_theta=8e6, expert_d_ff=2048, n_routed_experts=256,
+                n_shared_experts=1, moe_topk=8, routed_scaling_factor=2.5,
+                norm_topk_prob=True, index_n_heads=32, index_head_dim=128,
+                index_topk=2048,
+                # three leading ``full`` layers, then one in every four
+                indexer_types=tuple(
+                    "full" if at < 3 or (at - 2) % 4 == 0 else "shared"
+                    for at in range(78))),
+}
+
+
+def glm_moe_dsa_config(size: str = "5.2", **kw) -> TransformerConfig:
+    """GLM-5.2's language model (``glm_moe_dsa``): the pre-norm
+    latent-attention block whose attention reads a learned sparse
+    selection — an indexer in the ``full`` layers, the set handed on to
+    the ``shared`` ones — over ``first_k_dense`` dense layers and expert
+    layers with a sigmoid top-8 router (selection bias) beside a shared
+    expert (``models/sparse_latent_moe.py``).  ``size`` names a published
+    set of widths; depth, leading dense layers, ``indexer_types``,
+    vocabulary, served positions and ``experts_held`` (the chip's share of
+    a deployment) come as keywords."""
+    from .sparse_latent_moe import SparseLatentMoEConfig
+    return SparseLatentMoEConfig(**{
+        "pos_embedding": "none", "norm_type": "rmsnorm", "gated_mlp": True,
+        "activation": "silu", "use_bias": False, "tie_embeddings": False,
+        "layernorm_eps": 1e-5, **GLM_MOE_DSA_SIZES[size], **kw})
+
+
 def build_model(config: TransformerConfig, **kw) -> "TransformerLM":
     """The model that runs ``config``'s block: ``TransformerLM`` for the
     standard block, the config's own class (``config.model_class()``) for

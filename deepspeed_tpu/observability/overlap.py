@@ -89,7 +89,10 @@ PHASE_SPANS = tuple(f"serving/{p}" for p in PHASES)
 #: happens in any configuration.  Scopes nest and the innermost declared
 #: one owns an operation.  ``attn_proj``: q/k/v or the latent down/up
 #: projections, rotary, the output projection; ``attn_kernel``: the
-#: attention call and what feeds it; ``pool_write``: new rows into the
+#: attention call and what feeds it; ``indexer``: a learned sparse
+#: selection's projections and its score kernel; ``select``: the top-k
+#: over the scores and the table arithmetic that turns positions into
+#: pool rows; ``pool_write``: new rows into the
 #: paged pool; ``expert_layout``: the sort into tiles, gather and combine
 #: around the grouped product (``experts``); ``head``: final norm, logits,
 #: the finite flag; ``zero_comm``: the casts, gathers and scatters that
@@ -97,7 +100,7 @@ PHASE_SPANS = tuple(f"serving/{p}" for p in PHASES)
 SCOPES = ("embed", "norm", "residual", "attn_proj", "attn_kernel",
           "pool_write", "mlp", "router", "expert_layout", "experts",
           "shared_expert", "head", "sample", "loss", "optimizer",
-          "zero_comm")
+          "zero_comm", "indexer", "select")
 #: an instruction under no declared scope / one whose key two loaded
 #: programs map to different scopes
 UNNAMED, AMBIGUOUS = "unnamed", "ambiguous"
@@ -132,6 +135,12 @@ COUNTERS = ("dispatches", "decode_rows", "chunk_rows", "rows_computed",
             # rows x expert layers through an always-on shared expert
             # (models/sandwich_moe.py); 0 for blocks that have none
             "moe_rows_shared",
+            # a learned sparse selection (models/sparse_latent_moe.py),
+            # summed over the layers: rows x layers that ran the indexer,
+            # context tokens it scored, selected tokens attended, rows x
+            # layers that took a handed-on selection; 0 for other blocks
+            "index_rows", "index_keys_scored", "sparse_tokens_read",
+            "sparse_rows_reused",
             # the dispatch in flight (docs/serving.md): dispatches that
             # were enqueued before their predecessor's result was read,
             # and rows whose result was ignored because their request

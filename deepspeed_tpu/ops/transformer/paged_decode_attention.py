@@ -637,6 +637,27 @@ def _mla_pages_per_program(block: int, lanes: int, lat: int, itemsize: int,
     return min(1 << (pp.bit_length() - 1), npages)
 
 
+def _walkers(base, total, ntile: int, tp: int, keys: int, ngroups: int):
+    """The walk of ``b * ntile`` walkers — tile ``j`` of slot ``i`` holds
+    positions ``base[i] + j * tp ..`` and sees keys below ``min(total[i],
+    its last position + 1)`` — as a latent kernel's ``meta [6, W]``:
+    (first position, keys seen, live groups of ``keys`` keys, live steps
+    before, next live walker or W, the slot whose table the walker
+    reads)."""
+    b = base.shape[0]
+    nwalk = b * ntile
+    first = (base[:, None]
+             + tp * jnp.arange(ntile, dtype=jnp.int32)[None, :]).reshape(-1)
+    upto = jnp.repeat(total, ntile)
+    upto = jnp.where(first < upto, jnp.minimum(upto, first + tp), 0)
+    live = jnp.clip(-(-upto // keys), 0, ngroups)
+    walker = jnp.where(live > 0, jnp.arange(nwalk, dtype=jnp.int32), nwalk)
+    later = jnp.append(jax.lax.cummin(walker, reverse=True)[1:], nwalk)
+    slot = jnp.repeat(jnp.arange(b, dtype=jnp.int32), ntile)
+    return jnp.stack([first, upto, live, jnp.cumsum(live) - live, later,
+                      slot])
+
+
 def _mla_paged_attention(q_lat, q_rope, pool, base, total, block_tables, *,
                          sm_scale, interpret, pages_per_program, what):
     """q_lat [B, C, H, R], q_rope [B, C, H, Dr] — C query positions a
@@ -668,16 +689,7 @@ def _mla_paged_attention(q_lat, q_rope, pool, base, total, block_tables, *,
     ngroups = -(-npages // pp)
     base = jnp.asarray(base, jnp.int32).reshape(b)
     total = jnp.asarray(total, jnp.int32).reshape(b)
-    first = (base[:, None]
-             + tp * jnp.arange(ntile, dtype=jnp.int32)[None, :]).reshape(-1)
-    upto = jnp.repeat(total, ntile)
-    upto = jnp.where(first < upto, jnp.minimum(upto, first + tp), 0)
-    live = jnp.clip(-(-upto // (pp * block)), 0, ngroups)
-    walker = jnp.where(live > 0, jnp.arange(nwalk, dtype=jnp.int32), nwalk)
-    later = jnp.append(jax.lax.cummin(walker, reverse=True)[1:], nwalk)
-    slot = jnp.repeat(jnp.arange(b, dtype=jnp.int32), ntile)
-    meta = jnp.stack([first, upto, live, jnp.cumsum(live) - live, later,
-                      slot])
+    meta = _walkers(base, total, ntile, tp, pp * block, ngroups)
     dtype = pool.dtype
     # the query laid out like a pool row: [q_lat | q_rope | 0]
     q = jnp.concatenate(

@@ -436,21 +436,36 @@ def _shortcut_case():
     from deepspeed_tpu.models import longcat_flash_config
     return longcat_flash_config(
         "omni", num_layers=2, vocab_size=1024, max_seq_len=8192,
-        experts_held=(0, 4)), 4, 48, 512, 7
+        experts_held=(0, 4)), 4, 48, 512, 7, 5
 
 
 def _sandwich_case():
     from deepspeed_tpu.models import openpangu_ultra_moe_config
     return openpangu_ultra_moe_config(
         "718b", num_layers=3, first_k_dense=1, vocab_size=1024,
-        max_seq_len=4096, experts_held=(0, 8)), 8, 128, 256, 7
+        max_seq_len=4096, experts_held=(0, 8)), 8, 128, 256, 7, 5
+
+
+def _sparse_case():
+    """Three layers of the sparse-selection block: a dense ``full`` layer,
+    then a ``shared`` and a ``full`` expert layer — 9 kernel calls with a
+    chunk lane (a ``full`` layer's two score calls and every layer's
+    sparse chunk call, under the scanned layer's conditional once each;
+    three grouped products), 5 without.  16 held experts, so that one
+    layer's slice of one expert matrix is more than the chunk's score
+    plane and the selection's own temporaries."""
+    from deepspeed_tpu.models import glm_moe_dsa_config
+    return glm_moe_dsa_config(
+        "5.2", num_layers=3, first_k_dense=1,
+        indexer_types=("full", "shared", "full"), vocab_size=1024,
+        max_seq_len=16384, experts_held=(0, 16)), 16, 32, 1024, 9, 5
 
 
 def build_latent_mixed(devices, case, chunk):
     """A latent block's mixed step at its cell's widths over a pool of
     4,096 blocks of 16: ``(model, compiled, pools, params)``."""
     from deepspeed_tpu.models import build_model
-    config, _, slots, pages, _ = case()
+    config, _, slots, pages, _, _ = case()
     model = build_model(config)
     return (model,) + compile_mixed(devices, model, 4096, 16, 0, slots,
                                     pages, chunk)
@@ -462,41 +477,40 @@ def compile_latent_mixed(devices, case, chunk):
 
 
 @pytest.mark.parametrize("chunk", [512, 0], ids=["mixed", "decode_only"])
-@pytest.mark.parametrize("case", [_shortcut_case, _sandwich_case],
-                         ids=["shortcut", "sandwich"])
+@pytest.mark.parametrize("case", [_shortcut_case, _sandwich_case,
+                                  _sparse_case],
+                         ids=["shortcut", "sandwich", "sparse"])
 def test_latent_mixed_step_keeps_pool_and_experts_in_place(
         v5e_devices, compiled_kernels, case, chunk):
     """A latent block's mixed step at its cell's widths — the shortcut
-    block (2 layers of two attention sublayers, 4 held experts) and the
+    block (2 layers of two attention sublayers, 4 held experts), the
     sandwich block (one dense layer before two expert layers: two kinds
     of layer through one pool, the expert stack indexed by expert-layer
     number; 8 held experts, so that one layer's slice of one expert
-    matrix is more than the 128-head attention's own temporaries): the
-    latent pool is the scans' carry and the expert stack is
-    read where it lies, so the compiled step holds no pool-shaped and no
-    expert-stack-shaped copy, slice or second buffer — either would be
-    more than a GB moved every step at the cell's depth.  The same for
-    the decode-only shape (``chunk`` 0), which calls the latent kernel
-    once an attention sublayer: the chunk lane's calls are gone."""
+    matrix is more than the 128-head attention's own temporaries) and the
+    sparse-selection block (BOTH pools — latent rows and indexer keys —
+    through both scans and through the conditional that tells a ``full``
+    layer from a ``shared`` one): the pools are the scans' carry and the
+    expert stack is read where it lies, so the compiled step holds no
+    pool-shaped and no expert-stack-shaped copy, slice or second buffer —
+    either would be more than a GB moved every step at the cell's depth.
+    The same for the decode-only shape (``chunk`` 0), which calls no
+    chunk kernel: the chunk lane's calls are gone."""
     import re
-    config, held, slots, pages, kernels = case()
+    config, held, slots, pages, kernels, kernels_decode_only = case()
     nb = 4096
     model, compiled, pools, params = compile_latent_mixed(v5e_devices, case,
                                                           chunk)
     sublayers = model.ATTN_SUBLAYERS * config.num_layers
     stacked = config.scan_length
-    assert list(pools) == ["k"]            # one buffer: "v" is None
+    # one buffer ("v" is None), or the indexer pool in its place
+    assert list(pools) == (["k", "v"] if case is _sparse_case else ["k"])
     assert pools["k"].shape[0] == sublayers
     text = compiled.as_text()
-    # the scanned layer's attention sublayers x (decode + chunk) and its
-    # three grouped products; a leading layer's decode + chunk.  Without
-    # a chunk lane each attention sublayer is one call, not two
-    leading = config.scan_length < config.num_layers
-    attn = model.ATTN_SUBLAYERS + (1 if leading else 0)
-    assert custom_calls(text) == (kernels if chunk else kernels - attn)
+    assert custom_calls(text) == (kernels if chunk else kernels_decode_only)
     shaped = set()
     for a in pools.values():
-        for lead in ((sublayers, nb), (nb,), (sublayers * nb,)):
+        for lead in ((a.shape[0], nb), (nb,), (a.shape[0] * nb,)):
             shaped.add(lead + a.shape[2:])
     for a in params["blocks"]["moe"]["experts"].values():
         assert a.shape[:2] == (stacked, held)
@@ -805,7 +819,9 @@ for _shape, _dense, _latent in (("mixed", 256, 512), ("decode_only", 0, 0)):
         lambda dev, size=_size: build_dense_mixed(dev, *size)[0], _SERVE)
     for _block, _case, _own in (("shortcut", _shortcut_case, set()),
                                 ("sandwich", _sandwich_case,
-                                 {"shared_expert"})):
+                                 {"shared_expert"}),
+                                ("sparse", _sparse_case,
+                                 {"shared_expert", "indexer", "select"})):
         STEP_PROGRAMS[f"{_block}-{_shape}"] = (
             lambda dev, case=_case, c=_latent: compile_latent_mixed(
                 dev, case, c)[1],
@@ -829,8 +845,9 @@ def test_step_programs_carry_their_scopes(v5e_devices, compiled_kernels,
     cell's step program compiled for it: every scope the configuration
     has is in the program's text (a scope whose operations XLA fused
     into another's, as it does the residual adds, is in no instruction
-    of its own); each Pallas call resolves to ``attn_kernel`` or
-    ``experts``; and at least nine in ten of the instructions that can
+    of its own); each Pallas call resolves to ``attn_kernel``,
+    ``experts`` or (the score kernel of a sparse selection) ``indexer``;
+    and at least nine in ten of the instructions that can
     be trace events and do work (fusions, convolutions, copies, custom
     calls) resolve to a declared scope."""
     import re
@@ -846,7 +863,8 @@ def test_step_programs_carry_their_scopes(v5e_devices, compiled_kernels,
                if re.search(r' custom-call\(.*"tpu_custom_call"', ln)]
     assert kernels
     for ln in kernels:
-        want = "experts" if "%moe_grouped_matmul" in ln else "attn_kernel"
+        want = ("experts" if "%moe_grouped_matmul" in ln else
+                "indexer" if "%dsa_index_scores" in ln else "attn_kernel")
         assert table[scope_key(ln)][0] == want, ln[:200]
     work = [k for k in table
             if re.match(r"%[\w.-]*(fusion|convolution|copy|custom-call)"
