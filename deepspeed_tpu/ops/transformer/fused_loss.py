@@ -1,27 +1,48 @@
 """Fused loss-head forward+backward (analytic custom-VJP cross-entropy).
 
 The loss head — hidden states [N, D] × vocab projection [D, V] →
-softmax-cross-entropy — is the last large phase of the training step
-(62.7 ms at 0.505 efficiency in the pre-round record BENCH_r05,
-deleted in PR 21; in git at 95bdfc0). The autodiff formulation costs
-what this op avoids: ``jax.grad`` through ``logsumexp ∘ project``
-materializes a full [N, V] logit COTANGENT in HBM (at vocab 50k that is
-the biggest tensor of the whole backward), writes it, then immediately
-re-reads it for the two matmuls that produce dx and dw.
+softmax-cross-entropy — is the last large phase of the training step,
+and at vocab 50k its [N, V] float32 logits are the largest tensor of
+the step.  Autodiff through ``logsumexp ∘ project`` keeps that tensor
+(or recomputes it under ``jax.checkpoint``) and writes a second one,
+its cotangent.  This op holds neither across the fwd/bwd boundary:
 
-This op never stores an [N, V] tensor across the fwd/bwd boundary:
+* forward: a ``lax.scan`` over row chunks computes a chunk's logits →
+  (logsumexp, target-logit) → masked NLL sum.  Scalars accumulate; each
+  row's logsumexp (f32 [N], 64 KB at N = 16k) is all that is kept.
+* backward: the same scan recomputes a chunk's logits and forms
+  ``ds = (exp(logits − lse) − onehot(labels)) · mask · ḡ``, which the
+  dx and dw products consume.
 
-* forward: a `lax.scan` over row chunks computes per-chunk logits →
-  (logsumexp, target-logit) → masked NLL sum; only scalars accumulate.
-* backward: the same scan recomputes each chunk's logits in-VJP and forms
-  the analytic gradient ``ds = (softmax(logits) − onehot(labels)) · mask
-  · ḡ`` directly — one [chunk, V] buffer that is consumed by the dx/dw
-  matmuls immediately, never written back to HBM whole.
+What the compiled TPU program does with the [chunk, V] plane (v5e,
+optimized HLO; ``test_tpu_compile.py`` pins it):
 
-Residuals are just (x, w, bias): the logits recompute is one GEMM per
-chunk, which on a bandwidth-limited part is cheaper than round-tripping
-[N, V] f32 through HBM (the same trade the chunked-``jax.checkpoint``
-loss made for the FORWARD residuals; this extends it to the cotangent).
+* forward body: the logits product writes it in f32 with the row max
+  as its epilogue; one more pass reads it for the exp-sum.
+* backward body: ``ds`` is elementwise in the logits — the softmax's
+  normaliser comes from the forward, the one-hot is ``iota == label``
+  — so XLA makes ALL of it the epilogue of the logits product, which
+  writes the plane ONCE, already rounded to the bf16 the two products
+  take as their operand (a f32 product at default precision is one
+  bf16 pass on this chip; dx comes out bf16, dw accumulates in f32).
+  The plane crosses HBM three times a chunk.
+
+That is why the logsumexp is a residual and not recomputed: a row max
+and a row sum are reductions, so with them in the backward the plane
+is written in f32 and read twice more before ``ds`` can be formed.  And
+why the one-hot is a compare: ``ds.at[rows, labels].add`` is a scatter,
+for which XLA flattens the plane to one dimension and back, two
+re-layouts of 824 MB each for 4,096 elements.  Either one alone keeps
+``ds`` out of the product's epilogue: ten crossings a chunk, not three.
+
+Other residuals are (x, w, bias): the logits recompute is one GEMM per
+chunk, cheaper than keeping [N, V] f32 between the passes.
+
+A label outside ``[0, V)`` matches no column, so its row's gradient is
+the softmax's alone; such rows are expected to carry ``mask`` 0, which
+zeroes it.  (The VALUE is NaN for such a label whatever the mask:
+``take_along_axis`` fills out-of-range reads with NaN, on this path and
+on the autodiff one.  Map ignored labels into range.)
 
 Supports both loss-head layouts of ``models/transformer.py::_project``:
 tied embedding table ``[V, D]`` (``transpose_w=True``) and an untied
@@ -29,8 +50,6 @@ tied embedding table ``[V, D]`` (``transpose_w=True``) and an untied
 vocab-sharded TP head keep the autodiff path (transformer.py gates).
 """
 from __future__ import annotations
-
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -72,36 +91,41 @@ def fused_linear_xent(x, w, labels, mask=None, bias=None, *,
                             preferred_element_type=jnp.float32)
         return lg + b if has_bias else lg
 
-    @jax.custom_vjp
-    def run(x, w, b):
+    def forward(x, w, b):
         def body(carry, xs):
             xc, yc, mc = xs
             lg = logits_of(xc, w, b)
             lse = jax.scipy.special.logsumexp(lg, axis=-1)
             tgt = jnp.take_along_axis(lg, yc[:, None], axis=-1)[:, 0]
             return (carry[0] + jnp.sum((lse - tgt) * mc),
-                    carry[1] + jnp.sum(mc)), None
-        (s, cnt), _ = jax.lax.scan(
+                    carry[1] + jnp.sum(mc)), lse
+        return jax.lax.scan(
             body,
             (jnp.zeros((), jnp.float32), jnp.zeros((), jnp.float32)),
             (chunks(x), chunks(labels), chunks(maskf)))
-        return s, cnt
+
+    @jax.custom_vjp
+    def run(x, w, b):
+        return forward(x, w, b)[0]
 
     def run_fwd(x, w, b):
-        return run(x, w, b), (x, w, b)
+        out, lse = forward(x, w, b)       # lse: f32 [nc, csize]
+        return out, (x, w, b, lse)
 
     def run_bwd(res, ct):
-        x, w, b = res
+        x, w, b, lse = res
         gs = ct[0].astype(jnp.float32)   # d(nll_sum); count has no grads
         w32 = w.astype(jnp.float32)
 
         def body(carry, xs):
             dw, db = carry
-            xc, yc, mc = xs
+            xc, yc, mc, lc = xs
             lg = logits_of(xc, w, b)
             coef = mc * gs                               # [c]
-            ds = jax.nn.softmax(lg, axis=-1) * coef[:, None]
-            ds = ds.at[jnp.arange(csize), yc].add(-coef)  # softmax − onehot
+            # elementwise in lg, so XLA makes it the epilogue of the
+            # product above: softmax from the kept lse, onehot by compare
+            onehot = jnp.arange(lg.shape[1]) == yc[:, None]
+            ds = (jnp.exp(lg - lc[:, None]) - onehot) * coef[:, None]
             if transpose_w:          # lg = x·wᵀ, w [V, D]
                 dxc = jnp.einsum("nv,vd->nd", ds, w32,
                                  preferred_element_type=jnp.float32)
@@ -120,7 +144,7 @@ def fused_linear_xent(x, w, labels, mask=None, bias=None, *,
                else jnp.zeros((), jnp.float32))
         (dw, db), dx = jax.lax.scan(
             body, (jnp.zeros(w.shape, jnp.float32), db0),
-            (chunks(x), chunks(labels), chunks(maskf)))
+            (chunks(x), chunks(labels), chunks(maskf), lse))
         return (dx.reshape(n, d).astype(x.dtype), dw.astype(w.dtype),
                 db.astype(jnp.result_type(b)))
 

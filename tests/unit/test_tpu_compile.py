@@ -592,6 +592,37 @@ def test_flash_kernels_keep_their_names(v5e_devices, compiled_kernels,
     assert ("shard_map" in wrappers) == (chips > 1)
 
 
+def test_loss_head_backward_writes_the_plane_once(v5e_devices):
+    """The fused loss head at the one-chip training cell's shapes (16,384
+    rows in four chunks of 4,096, the tied ``[50304, 1024]`` table):
+    the backward's ``ds`` is elementwise in the logits, so XLA makes it
+    the epilogue of the product that computes them.  A scatter for the
+    one-hot brought back two re-layouts of the ``[4096, 50304]`` float32
+    plane, a softmax from the logits two more passes: 10.18 GB a chunk
+    (forward body + backward body, as ``cost_analysis`` counts a loop)
+    against 4.38."""
+    import re
+    from deepspeed_tpu.ops.transformer.fused_loss import fused_linear_xent
+    sds = one_chip(v5e_devices)
+    rows, chunk, vocab, d = 16384, 4096, 50304, 1024
+
+    def loss(x, w, labels, mask):
+        total, count = fused_linear_xent(x, w, labels, mask,
+                                         transpose_w=True, chunk=chunk)
+        return total / jnp.maximum(count, 1.0)
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).trace(
+        sds((rows, d), jnp.bfloat16), sds((vocab, d), jnp.bfloat16),
+        sds((rows,), jnp.int32), sds((rows,), jnp.float32)).lower(
+        lowering_platforms=("tpu",)).compile()
+    text = compiled.as_text()
+    assert not re.search(r"\bscatter\(", text)
+    plane = re.compile(rf"\[({chunk},)?{vocab}\]|\[{chunk * vocab}\]")
+    moved = [ln.strip()[:160] for ln in text.splitlines()
+             if " reshape(" in ln and plane.search(ln.split(" reshape(")[0])]
+    assert not moved, moved
+    assert compiled.cost_analysis()["bytes accessed"] < 5.5e9
+
+
 # ---------------------------------------------------------------------------
 # the engines' own programs, lowered for the TPU from the CPU mesh
 # ---------------------------------------------------------------------------

@@ -92,18 +92,26 @@ class TestRematParity:
 
 
 class TestFusedLossHead:
-    def _loss_and_grads(self, model, batch):
+    def _loss_and_grads(self, model, batch, head_bias=False):
         params = model.init(jax.random.PRNGKey(0))
+        if head_bias:    # init makes none; a GPT-J checkpoint brings one
+            params["lm_head"]["bias"] = 0.5 * jax.random.normal(
+                jax.random.PRNGKey(1), (TINY["vocab_size"],))
         val, grads = jax.value_and_grad(model.loss)(params, batch)
         return float(val), grads
 
-    # the tied-head arm is the heaviest (~12s) of the three parity pins;
-    # the untied + chunked arms keep the contract in tier-1
+    # the tied-head arm is the heaviest (~12s) of the parity pins; the
+    # untied + chunked arms keep the contract in tier-1
     @pytest.mark.parametrize("kw", [
         pytest.param({}, marks=pytest.mark.slow),  # tied embedding head
         {"tie_embeddings": False},        # untied lm_head kernel
         {"loss_chunk": 8},                # chunked scan path
-    ])
+        # db, dw and the logsumexp the forward keeps, across two chunks
+        {"tie_embeddings": False, "loss_chunk": 8, "head_bias": True},
+        # a label no column matches, in a row the mask drops
+        {"loss_chunk": 8, "bad_label": 10 ** 6},
+        {"tie_embeddings": False, "bad_label": -100},
+    ], ids=lambda kw: "-".join(f"{k}={v}" for k, v in kw.items()) or "tied")
     def test_matches_autodiff(self, kw):
         # f32 end to end: the contract is that the analytic VJP computes
         # the same MATH as autodiff. Under bf16 params the fused head is
@@ -111,14 +119,31 @@ class TestFusedLossHead:
         # rounds per-matmul), which is an improvement, not parity.
         import jax.numpy as jnp
         kw = {**kw, "dtype": jnp.float32, "param_dtype": jnp.float32}
-        batch = make_batch(2)
+        head_bias = kw.pop("head_bias", False)
+        bad_label = kw.pop("bad_label", None)
+        batch = dense_batch = make_batch(2)
+        if bad_label is not None:
+            ids = batch["input_ids"]
+            mask = np.ones(ids.shape, np.float32)
+            mask[:, -1] = mask[1, 3] = 0.0
+            labels = np.roll(ids, -1, axis=1)
+            batch = {"input_ids": ids, "labels": labels.copy(),
+                     "loss_mask": mask}
+            batch["labels"][1, 3] = bad_label
+            # autodiff's arm sees the row dropped, its label in range
+            dense_batch = {**batch, "labels": labels}
         v_fused, g_fused = self._loss_and_grads(
-            tiny_model(fused_loss_head=True, **kw), batch)
+            tiny_model(fused_loss_head=True, **kw), batch, head_bias)
         v_dense, g_dense = self._loss_and_grads(
-            tiny_model(fused_loss_head=False, **kw), batch)
-        np.testing.assert_allclose(v_fused, v_dense, rtol=1e-6)
+            tiny_model(fused_loss_head=False, **kw), dense_batch, head_bias)
+        if bad_label is None:
+            # with one, the VALUE is NaN on either path (take_along_axis
+            # fills what is out of range, and NaN * 0 is NaN): the mask
+            # makes the gradients right, not the label valid
+            np.testing.assert_allclose(v_fused, v_dense, rtol=1e-6)
         for a, b in zip(jax.tree_util.tree_leaves(g_fused),
                         jax.tree_util.tree_leaves(g_dense)):
+            assert np.isfinite(np.asarray(a, dtype=np.float32)).all()
             np.testing.assert_allclose(np.asarray(a, dtype=np.float32),
                                        np.asarray(b, dtype=np.float32),
                                        atol=2e-5)
