@@ -22,8 +22,9 @@ identity experts, and this chip's share of the routed experts
 (``experts_held``).
 
 Full sequences (``apply``) run the EXPANDED form in plain XLA.  These
-blocks do not train: the experts' grouped product is a forward-only
-kernel (``training_refusal``).  Serving runs the ABSORBED form through
+blocks do not train: latent attention has no training kernel
+(``training_refusal``; the experts' grouped product differentiates, and
+``models/cca_moe.py`` trains through it).  Serving runs the ABSORBED form through
 the paged path: the pool row of a token is ``[c | k_rope | 0]`` for each
 attention sublayer of each layer — ONE buffer ``k [sublayers, num_blocks,
 block, lanes]`` (there is no second operand: the pool's ``v`` is None) —
@@ -195,9 +196,10 @@ class LatentMoELM(TransformerLM):
 
     def training_refusal(self) -> Optional[str]:
         return ("the latent-attention MoE block serves and does not train "
-                "yet: its experts' grouped product (moe/dropless.py "
-                "grouped_matmul) is a forward-only kernel and its latent "
-                "attention has no training kernel (ROADMAP B8)")
+                "yet: its latent attention has no training kernel (ROADMAP "
+                "B8); its experts' grouped product (moe/dropless.py "
+                "grouped_matmul) differentiates, as models/cca_moe.py "
+                "trains through it")
 
     def tp_serving_view(self, model_shards, tp_axis, dp_axis):
         if model_shards > 1 or dp_axis is not None:

@@ -391,6 +391,32 @@ def glm_moe_dsa_config(size: str = "5.2", **kw) -> TransformerConfig:
         "layernorm_eps": 1e-5, **GLM_MOE_DSA_SIZES[size], **kw})
 
 
+ZAYA_SIZES = {
+    # https://huggingface.co/Zyphra/ZAYA1-8B/blob/main/config.json
+    "8b": dict(num_layers=40, num_heads=8, num_kv_heads=2, head_dim=128,
+               d_model=2048, d_ff=2048, vocab_size=262272,
+               max_seq_len=131072, rotary_pct=0.5, rotary_base=5e6,
+               cca_time0=2, cca_time1=2, expert_d_ff=2048,
+               n_routed_experts=16, router_hidden=256, init_depth=40),
+}
+
+
+def zaya_config(size: str = "8b", **kw) -> TransformerConfig:
+    """ZAYA1's language model (``zaya``): compressed convolutional
+    attention — grouped-query heads in a latent half the model's width,
+    causal convolutions over q and k, a value shift — and a top-1 expert
+    layer behind a router MLP whose state is carried from layer to layer
+    (``models/cca_moe.py``).  ``size`` names a published set of widths;
+    depth, vocabulary, trained positions and ``experts_held`` (the chip's
+    share of a deployment) come as keywords."""
+    from .cca_moe import CCAMoEConfig
+    return CCAMoEConfig(**{
+        "pos_embedding": "rotary", "rotary_interleaved": False,
+        "norm_type": "rmsnorm", "gated_mlp": True, "activation": "silu",
+        "use_bias": False, "tie_embeddings": True, "layernorm_eps": 1e-5,
+        **ZAYA_SIZES[size], **kw})
+
+
 def build_model(config: TransformerConfig, **kw) -> "TransformerLM":
     """The model that runs ``config``'s block: ``TransformerLM`` for the
     standard block, the config's own class (``config.model_class()``) for
@@ -1664,22 +1690,26 @@ class TransformerLM:
                 "index": jnp.array(0, jnp.int32)}
 
     # -- loss --------------------------------------------------------------
-    def loss(self, params, batch) -> jnp.ndarray:
-        """Causal LM loss. batch: {'input_ids' [B,T]} (labels = shifted) or
-        explicit {'input_ids', 'labels', optional 'loss_mask'}."""
+    @staticmethod
+    def _targets(batch):
+        """``(labels, loss mask or None)`` of a loss batch."""
         ids = batch["input_ids"]
         mask = batch.get("loss_mask")
         if "labels" in batch:
-            labels, logits_in = batch["labels"], ids
-        else:
-            # Shift labels, keep the full T through the model (power-of-two
-            # seq lengths keep the flash kernel's block divisibility); the
-            # final position is masked out instead of sliced off.
-            labels = jnp.concatenate(
-                [ids[:, 1:], jnp.zeros_like(ids[:, :1])], axis=1)
-            logits_in = ids
-            last_mask = jnp.ones_like(ids, dtype=jnp.float32).at[:, -1].set(0.0)
-            mask = last_mask if mask is None else mask * last_mask
+            return batch["labels"], mask
+        # Shift labels, keep the full T through the model (power-of-two
+        # seq lengths keep the flash kernel's block divisibility); the
+        # final position is masked out instead of sliced off.
+        labels = jnp.concatenate(
+            [ids[:, 1:], jnp.zeros_like(ids[:, :1])], axis=1)
+        last_mask = jnp.ones_like(ids, dtype=jnp.float32).at[:, -1].set(0.0)
+        return labels, last_mask if mask is None else mask * last_mask
+
+    def loss(self, params, batch) -> jnp.ndarray:
+        """Causal LM loss. batch: {'input_ids' [B,T]} (labels = shifted) or
+        explicit {'input_ids', 'labels', optional 'loss_mask'}."""
+        logits_in = batch["input_ids"]
+        labels, mask = self._targets(batch)
 
         # Optional per-step gate randomness (RTS / noisy gating): pass
         # batch["moe_rng"] = jax.random.PRNGKey(step) to engine.train_step —

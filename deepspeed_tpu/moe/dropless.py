@@ -24,11 +24,20 @@ The buffer is walked in passes of ``pass_rows`` rows under a loop whose
 trip count follows the load: a balanced batch takes one pass, a batch
 routed wholly to one expert takes as many as its rows need, and no pick
 is ever dropped.  The product is :func:`grouped_matmul` (device trace name
-``moe_grouped_matmul``), a forward-only kernel: the block that uses it
-serves and does not train (``LatentMoELM.training_refusal``).
+``moe_grouped_matmul``).
+
+It differentiates (``custom_vjp``): the rows' gradient is the same kernel
+walked against the weights' other axis, the weights' a second kernel
+(``moe_grouped_matmul_dw``) that sums ``x_tile^T dy_tile`` over each
+expert's tiles.  A loop whose trip count follows the load cannot be
+differentiated in reverse, so a training call asks for ONE pass over the
+whole buffer (``pass_rows=None``), in which a dead tile still starts no
+DMA, and for row tiles deep enough to fill the matrix unit
+(``tile_rows``).
 """
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple, Optional, Tuple
 
 import jax
@@ -48,6 +57,8 @@ PASS_ROWS = 1024
 #: block of up to ``_WEIGHT_BLOCK_BYTES``, the row tiles, the result)
 _VMEM_LIMIT_BYTES = 48 << 20
 _WEIGHT_BLOCK_BYTES = 8 << 20
+#: one float32 ``[tk, tn]`` block of the weights' gradient
+_DW_BLOCK_BYTES = 8 << 20
 #: the counters :func:`expert_share` returns, in order
 COUNTERS = ("moe_picks", "moe_picks_held", "moe_picks_zero",
             "moe_rows_max_expert", "moe_experts_touched")
@@ -59,19 +70,15 @@ class Routing(NamedTuple):
 
 
 @scoped("router")
-def route(u: jax.Array, router_kernel: jax.Array,
-          bias: Optional[jax.Array], k: int, scale: float,
-          scoring: str = "softmax", renormalize: bool = False) -> Routing:
-    """``u [T, h]`` -> the ``k`` picks of every row, in the gate's form
-    the model's configuration names.  Scores are float32 over all
-    ``E + Z`` outputs (the operands keep their stored type; products
-    accumulate in float32): a ``softmax`` over the outputs, or each
-    output's own ``sigmoid``.  The ``k`` chosen are the top ``k`` of
-    ``score + bias`` (``bias`` None: of the score).  A pick's weight is
-    ``scale * score``, or with ``renormalize`` ``scale * score / (the
-    row's chosen scores' sum + 1e-20)``."""
-    logits = jnp.einsum("th,he->te", u, router_kernel.astype(u.dtype),
-                        preferred_element_type=jnp.float32)
+def route_logits(logits: jax.Array, bias: Optional[jax.Array], k: int,
+                 scale: float, scoring: str = "softmax",
+                 renormalize: bool = False) -> Routing:
+    """``logits [T, E + Z]`` float32 -> the ``k`` picks of every row, in
+    the gate's form the model's configuration names: a ``softmax`` over
+    the outputs, or each output's own ``sigmoid``.  The ``k`` chosen are
+    the top ``k`` of ``score + bias`` (``bias`` None: of the score).  A
+    pick's weight is ``scale * score``, or with ``renormalize`` ``scale *
+    score / (the row's chosen scores' sum + 1e-20)``."""
     if scoring == "softmax":
         p = jax.nn.softmax(logits, axis=-1)
     elif scoring == "sigmoid":
@@ -87,44 +94,53 @@ def route(u: jax.Array, router_kernel: jax.Array,
     return Routing(index.astype(jnp.int32), scale * weight)
 
 
+@scoped("router")
+def route(u: jax.Array, router_kernel: jax.Array,
+          bias: Optional[jax.Array], k: int, scale: float,
+          scoring: str = "softmax", renormalize: bool = False) -> Routing:
+    """``u [T, h]`` -> :func:`route_logits` of the gate that is one matrix
+    product: float32 logits over all ``E + Z`` outputs (the operands keep
+    their stored type; products accumulate in float32)."""
+    logits = jnp.einsum("th,he->te", u, router_kernel.astype(u.dtype),
+                        preferred_element_type=jnp.float32)
+    return route_logits(logits, bias, k, scale, scoring, renormalize)
+
+
 def _tile_n(k_dim: int, n: int, itemsize: int) -> int:
     """Columns of one weight block ``[K, tn]``: all of them, or the widest
     128-multiple divisor of ``n`` whose block fits the budget."""
-    if k_dim * n * itemsize <= _WEIGHT_BLOCK_BYTES or n % 128:
-        return n
-    tn = n
-    while tn % 256 == 0 and k_dim * tn * itemsize > _WEIGHT_BLOCK_BYTES:
-        tn //= 2
-    return tn
+    return _divisor_block(n, _WEIGHT_BLOCK_BYTES // (k_dim * itemsize))
 
 
-@scoped("experts")
-def grouped_matmul(x: jax.Array, w: jax.Array, tile_expert: jax.Array,
-                   live_tiles: jax.Array,
-                   interpret: Optional[bool] = None) -> jax.Array:
-    """``x [M, K]`` in tiles of ``TILE_ROWS`` rows, tile ``t`` wholly of
-    expert ``tile_expert[t]``; ``w [E, K, N]``.  Returns ``[M, N]`` in
-    ``x``'s type.  Only the first ``live_tiles`` tiles are computed: a
-    dead tile starts no DMA (its blocks are the last live tile's) and
-    leaves its rows of the result UNDEFINED — the caller masks them.
-
-    Grid ``(N blocks, tiles)``, tiles innermost: consecutive tiles of one
-    expert reuse the weight block already in VMEM, so each held expert's
-    weights cross HBM once per call however its rows split over tiles."""
+def _product(x, w, tile_expert, live_tiles, interpret, transpose_w=False):
+    """``x [M, K]`` times each tile's expert's ``w[e] [K, N]`` — or, with
+    ``transpose_w``, ``x [M, N]`` times ``w[e]^T``: the weights are read
+    where they lie, blocked along their other axis by the index map, and
+    contracted on their last (no transposed copy in HBM)."""
     m, k_dim = x.shape
-    n = w.shape[2]
-    if m % TILE_ROWS:
-        raise ValueError(f"grouped_matmul: {m} rows are not whole tiles of "
-                         f"{TILE_ROWS}")
+    n = w.shape[1] if transpose_w else w.shape[2]
+    tiles = tile_expert.shape[0]
+    if m % tiles or (m // tiles) % TILE_ROWS:
+        raise ValueError(f"grouped_matmul: {m} rows are not {tiles} whole "
+                         f"tiles of a multiple of {TILE_ROWS}")
+    tile = m // tiles
     tn = _tile_n(k_dim, n, w.dtype.itemsize)
-    tiles = m // TILE_ROWS
 
     def kernel(te_ref, live_ref, x_ref, w_ref, o_ref):
         @pl.when(pl.program_id(1) < live_ref[0])
         def _live():
-            o_ref[...] = jnp.dot(
-                x_ref[...], w_ref[...],
-                preferred_element_type=jnp.float32).astype(o_ref.dtype)
+            if transpose_w:
+                o_ref[...] = jax.lax.dot_general(
+                    x_ref[...], w_ref[...], (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32).astype(o_ref.dtype)
+            else:
+                o_ref[...] = jnp.dot(
+                    x_ref[...], w_ref[...],
+                    preferred_element_type=jnp.float32).astype(o_ref.dtype)
+
+    def w_block(j, t, te, live):
+        e = te[jnp.minimum(t, jnp.maximum(live[0] - 1, 0))]
+        return (e, j, 0) if transpose_w else (e, 0, j)
 
     return pl.pallas_call(
         kernel,
@@ -134,15 +150,14 @@ def grouped_matmul(x: jax.Array, w: jax.Array, tile_expert: jax.Array,
             in_specs=[
                 # a dead tile's blocks are the last live tile's: no DMA
                 pl.BlockSpec(
-                    (TILE_ROWS, k_dim), lambda j, t, te, live: (
+                    (tile, k_dim), lambda j, t, te, live: (
                         jnp.minimum(t, jnp.maximum(live[0] - 1, 0)), 0)),
                 pl.BlockSpec(
-                    (None, k_dim, tn), lambda j, t, te, live: (
-                        te[jnp.minimum(t, jnp.maximum(live[0] - 1, 0))], 0,
-                        j)),
+                    (None, tn, k_dim) if transpose_w else (None, k_dim, tn),
+                    w_block),
             ],
             out_specs=pl.BlockSpec(
-                (TILE_ROWS, tn), lambda j, t, te, live: (
+                (tile, tn), lambda j, t, te, live: (
                     jnp.minimum(t, jnp.maximum(live[0] - 1, 0)), j)),
         ),
         out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
@@ -155,21 +170,138 @@ def grouped_matmul(x: jax.Array, w: jax.Array, tile_expert: jax.Array,
       jnp.asarray(live_tiles, jnp.int32).reshape(1), x, w.astype(x.dtype))
 
 
+def _divisor_block(dim: int, most: int) -> int:
+    """The widest block of ``dim`` of at most ``most``: all of it, or a
+    128-multiple that divides it."""
+    block = dim
+    while block > most and block % 256 == 0:
+        block //= 2
+    return block
+
+
+def _product_dw(x, dy, tile_expert, live_tiles, experts, interpret):
+    """``dw[e] = sum over e's live tiles of x_tile^T dy_tile``, float32
+    ``[experts, K, N]``.  Grid ``(K blocks, N blocks, tiles)``, tiles
+    innermost: an expert's tiles are consecutive, so its ``[K, N]`` block
+    stays in VMEM while they add to it and crosses to HBM once.  An
+    expert with no live tile is never visited: its block is UNDEFINED —
+    the caller zeroes it."""
+    m, k_dim = x.shape
+    n = dy.shape[1]
+    tiles = tile_expert.shape[0]
+    tile = m // tiles
+    tn = _divisor_block(n, 2048)
+    tk = _divisor_block(k_dim, max(_DW_BLOCK_BYTES // (4 * tn), 128))
+
+    def kernel(te_ref, live_ref, x_ref, dy_ref, o_ref):
+        t = pl.program_id(2)
+        live = t < live_ref[0]
+        first = (t == 0) | (te_ref[t] != te_ref[jnp.maximum(t - 1, 0)])
+
+        @pl.when(live & first)
+        def _zero():
+            o_ref[...] = jnp.zeros_like(o_ref)
+
+        @pl.when(live)
+        def _add():
+            o_ref[...] += jax.lax.dot_general(
+                x_ref[...], dy_ref[...], (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(k_dim // tk, n // tn, tiles),
+            in_specs=[
+                # a dead tile's blocks are the last live tile's: no DMA
+                pl.BlockSpec((tile, tk), lambda i, j, t, te, live: (
+                    jnp.minimum(t, jnp.maximum(live[0] - 1, 0)), i)),
+                pl.BlockSpec((tile, tn), lambda i, j, t, te, live: (
+                    jnp.minimum(t, jnp.maximum(live[0] - 1, 0)), j)),
+            ],
+            out_specs=pl.BlockSpec(
+                (None, tk, tn), lambda i, j, t, te, live: (
+                    te[jnp.minimum(t, jnp.maximum(live[0] - 1, 0))], i,
+                    j)),
+        ),
+        out_shape=jax.ShapeDtypeStruct((experts, k_dim, n), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT_BYTES),
+        interpret=resolve_interpret(interpret),
+        name="moe_grouped_matmul_dw",
+    )(tile_expert.astype(jnp.int32),
+      jnp.asarray(live_tiles, jnp.int32).reshape(1), x, dy.astype(x.dtype))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _grouped(x, w, tile_expert, live_tiles, interpret):
+    return _product(x, w, tile_expert, live_tiles, interpret)
+
+
+def _grouped_fwd(x, w, tile_expert, live_tiles, interpret):
+    return (_product(x, w, tile_expert, live_tiles, interpret),
+            (x, w, tile_expert, live_tiles))
+
+
+def _grouped_bwd(interpret, res, dy):
+    """Rows of dead tiles are undefined going in (``x``, ``dy``) and
+    coming out (``dx``), as the forward's are; ``dw`` is exact: only live
+    tiles add to it, and a live tile's padding rows are zero rows."""
+    x, w, tile_expert, live_tiles = res
+    with jax.named_scope("experts"):
+        dx = _product(dy, w, tile_expert, live_tiles, interpret,
+                      transpose_w=True)
+        dw = _product_dw(x, dy, tile_expert, live_tiles, w.shape[0],
+                         interpret)
+        tiles = tile_expert.shape[0]
+        touched = jnp.zeros((w.shape[0],), bool).at[tile_expert].max(
+            jnp.arange(tiles) < live_tiles)
+        dw = jnp.where(touched[:, None, None], dw, 0.0).astype(w.dtype)
+    return dx, dw, None, None
+
+
+_grouped.defvjp(_grouped_fwd, _grouped_bwd)
+
+
+@scoped("experts")
+def grouped_matmul(x: jax.Array, w: jax.Array, tile_expert: jax.Array,
+                   live_tiles: jax.Array,
+                   interpret: Optional[bool] = None) -> jax.Array:
+    """``x [M, K]`` in as many equal tiles (of a multiple of ``TILE_ROWS``
+    rows) as ``tile_expert`` has entries, tile ``t`` wholly of expert
+    ``tile_expert[t]``; ``w [E, K, N]``.  Returns ``[M, N]`` in ``x``'s
+    type.  Only the first ``live_tiles`` tiles are computed: a dead tile
+    starts no DMA (its blocks are the last live tile's) and leaves its
+    rows of the result UNDEFINED — the caller masks them.
+
+    Grid ``(N blocks, tiles)``, tiles innermost: consecutive tiles of one
+    expert reuse the weight block already in VMEM, so each held expert's
+    weights cross HBM once per call however its rows split over tiles.
+
+    Differentiable in ``x`` and ``w`` (module docstring): ``dx`` is this
+    kernel over ``w``'s other axis, ``dw`` is ``moe_grouped_matmul_dw``
+    with an expert that got no row written as zeros."""
+    return _grouped(x, w, tile_expert, jnp.asarray(live_tiles, jnp.int32),
+                    interpret)
+
+
 class _Layout(NamedTuple):
     """Where every held pick sits in the row buffer."""
     row_token: jax.Array     # [M] int32: the pick's token, T for no pick
     row_weight: jax.Array    # [M] float32
-    tile_expert: jax.Array   # [M / TILE_ROWS] int32 (local expert id)
+    tile_expert: jax.Array   # [M / tile] int32 (local expert id)
     live_tiles: jax.Array    # int32 scalar
     counts: jax.Array        # [held] int32 rows of each held expert
 
 
-def _layout(local: jax.Array, weight: jax.Array, held: int, rows: int
-            ) -> _Layout:
+def _layout(local: jax.Array, weight: jax.Array, held: int, rows: int,
+            tile: int = TILE_ROWS) -> _Layout:
     """``local [T, k]`` — the held experts' local ids, ``held`` for a pick
     that is not dispatched here.  Each expert's picks go to consecutive
-    rows from a tile boundary on, experts in order, picks in token
-    order."""
+    rows from a boundary of the tiles of ``tile`` rows on, experts in
+    order, picks in token order."""
     t, k = local.shape
     flat = local.reshape(-1)
     onehot = flat[:, None] == jnp.arange(held, dtype=flat.dtype)[None, :]
@@ -177,11 +309,11 @@ def _layout(local: jax.Array, weight: jax.Array, held: int, rows: int
     rank = jnp.take_along_axis(
         jnp.cumsum(onehot, axis=0, dtype=jnp.int32) - 1,
         jnp.minimum(flat, held - 1)[:, None], axis=1)[:, 0]
-    tiles_of = -(-counts // TILE_ROWS)
+    tiles_of = -(-counts // tile)
     first_tile = jnp.cumsum(tiles_of) - tiles_of
     dest = jnp.where(
         flat < held,
-        first_tile[jnp.minimum(flat, held - 1)] * TILE_ROWS + rank, rows)
+        first_tile[jnp.minimum(flat, held - 1)] * tile + rank, rows)
     token = jnp.arange(t * k, dtype=jnp.int32) // k
     row_token = jnp.full((rows,), t, jnp.int32).at[dest].set(
         token, mode="drop")
@@ -189,7 +321,7 @@ def _layout(local: jax.Array, weight: jax.Array, held: int, rows: int
         weight.reshape(-1), mode="drop")
     tile_expert = jnp.clip(
         jnp.searchsorted(jnp.cumsum(tiles_of),
-                         jnp.arange(rows // TILE_ROWS, dtype=jnp.int32),
+                         jnp.arange(rows // tile, dtype=jnp.int32),
                          side="right"), 0, held - 1).astype(jnp.int32)
     return _Layout(row_token, row_weight, tile_expert, tiles_of.sum(),
                    counts)
@@ -199,8 +331,9 @@ def _layout(local: jax.Array, weight: jax.Array, held: int, rows: int
 def expert_share(experts: dict, u: jax.Array, routing: Routing,
                  num_routed: int, experts_held: Tuple[int, int],
                  row_valid: Optional[jax.Array] = None,
-                 pass_rows: int = PASS_ROWS,
-                 layer: Optional[jax.Array] = None
+                 pass_rows: Optional[int] = PASS_ROWS,
+                 layer: Optional[jax.Array] = None,
+                 tile_rows: int = TILE_ROWS
                  ) -> Tuple[jax.Array, jax.Array]:
     """This chip's part of the MoE sublayer's output.
 
@@ -213,6 +346,9 @@ def expert_share(experts: dict, u: jax.Array, routing: Routing,
     ``[L, held, ..]``, and the kernel reads layer ``layer``'s experts
     where they lie: a layer scan that sliced the stack would copy a
     whole layer's experts every step to hand them to a kernel.
+    ``pass_rows`` None is ONE pass over the whole buffer, the form that
+    differentiates; ``tile_rows`` (a multiple of ``TILE_ROWS``) is how
+    many rows of one expert a step of the grouped product takes.
     Returns ``(y [T, h] in u's type, counters int32 [len(COUNTERS)])``.
     """
     lo, hi = experts_held
@@ -228,11 +364,12 @@ def expert_share(experts: dict, u: jax.Array, routing: Routing,
 
     # the row buffer: every held pick and each expert's tile padding
     per_token = min(k, held)
-    rows = t * per_token + held * (TILE_ROWS - 1)
-    step = -(-min(int(pass_rows), rows) // TILE_ROWS) * TILE_ROWS
+    tile = int(tile_rows)
+    rows = t * per_token + held * (tile - 1)
+    step = -(-min(int(pass_rows or rows), rows) // tile) * tile
     rows = -(-rows // step) * step
     lay = _layout(jnp.where(is_held, index - lo, held).astype(jnp.int32),
-                  weight, held, rows)
+                  weight, held, rows, tile)
     u_pad = jnp.concatenate([u, jnp.zeros((1, h), u.dtype)])
     first = 0                      # this layer's first expert in the stack
     if layer is not None:
@@ -241,12 +378,12 @@ def expert_share(experts: dict, u: jax.Array, routing: Routing,
         first = layer * held
 
     def one_pass(p, y):
-        at, tile_at = p * step, p * (step // TILE_ROWS)
+        at, tile_at = p * step, p * (step // tile)
         token = jax.lax.dynamic_slice_in_dim(lay.row_token, at, step)
         w_row = jax.lax.dynamic_slice_in_dim(lay.row_weight, at, step)
         te = first + jax.lax.dynamic_slice_in_dim(
-            lay.tile_expert, tile_at, step // TILE_ROWS)
-        live = jnp.clip(lay.live_tiles - tile_at, 0, step // TILE_ROWS)
+            lay.tile_expert, tile_at, step // tile)
+        live = jnp.clip(lay.live_tiles - tile_at, 0, step // tile)
         xs = u_pad[token]
         gate = grouped_matmul(xs, experts["w_gate"], te, live)
         up = grouped_matmul(xs, experts["w_up"], te, live)
@@ -265,7 +402,7 @@ def expert_share(experts: dict, u: jax.Array, routing: Routing,
         y = one_pass(0, y)
     else:
         y = jax.lax.fori_loop(
-            0, -(-(lay.live_tiles * TILE_ROWS) // step), one_pass, y)
+            0, -(-(lay.live_tiles * tile) // step), one_pass, y)
     y = y + u.astype(jnp.float32) * jnp.sum(
         jnp.where(is_zero, weight, 0.0), axis=-1, keepdims=True)
     counters = jnp.stack([

@@ -92,7 +92,9 @@ PHASE_SPANS = tuple(f"serving/{p}" for p in PHASES)
 #: attention call and what feeds it; ``indexer``: a learned sparse
 #: selection's projections and its score kernel; ``select``: the top-k
 #: over the scores and the table arithmetic that turns positions into
-#: pool rows; ``pool_write``: new rows into the
+#: pool rows; ``attn_conv``: what a convolutional attention does to q and k
+#: between projection and kernel (causal convolutions, the q-k mean, the
+#: per-head normalisation, rotary); ``pool_write``: new rows into the
 #: paged pool; ``expert_layout``: the sort into tiles, gather and combine
 #: around the grouped product (``experts``); ``head``: final norm, logits,
 #: the finite flag; ``zero_comm``: the casts, gathers and scatters that
@@ -100,7 +102,7 @@ PHASE_SPANS = tuple(f"serving/{p}" for p in PHASES)
 SCOPES = ("embed", "norm", "residual", "attn_proj", "attn_kernel",
           "pool_write", "mlp", "router", "expert_layout", "experts",
           "shared_expert", "head", "sample", "loss", "optimizer",
-          "zero_comm", "indexer", "select")
+          "zero_comm", "indexer", "select", "attn_conv")
 #: an instruction under no declared scope / one whose key two loaded
 #: programs map to different scopes
 UNNAMED, AMBIGUOUS = "unnamed", "ambiguous"

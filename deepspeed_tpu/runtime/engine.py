@@ -353,29 +353,33 @@ class DeepSpeedEngine:
     def _accumulate_micro_grads(self, state, batch, scale):
         """Shared GAS loop: scan the microbatch axis, sum f32 grads +
         scaled losses. Single source of the accumulation semantics for the
-        fused train step AND the offload grad function."""
+        fused train step AND the offload grad function.  Third result:
+        the counters of a loss that returns ``(loss, counters)``, summed
+        over the microbatches ({} for a loss that returns a scalar)."""
         gas = self.gradient_accumulation_steps
 
         def micro(carry, mb):
             gsum, lsum = carry
-            loss, grads = jax.value_and_grad(self._micro_loss)(
-                state["params"], mb, scale)
+            (loss, counters), grads = jax.value_and_grad(
+                self._micro_loss, has_aux=True)(state["params"], mb, scale)
             with jax.named_scope("optimizer"):
                 grads = constrain(
                     jax.tree_util.tree_map(lambda g: g.astype(jnp.float32),
                                            grads),
                     self.mesh, self.grad_specs)
                 gsum = jax.tree_util.tree_map(jnp.add, gsum, grads)
-            return (gsum, lsum + loss), None
+            return (gsum, lsum + loss), counters
 
         zeros = _tree_zeros_f32(state["params"])
         if gas == 1:
             sq = jax.tree_util.tree_map(lambda x: x[0], batch)
-            (gsum, lsum), _ = micro((zeros, jnp.zeros((), jnp.float32)), sq)
+            (gsum, lsum), counters = micro(
+                (zeros, jnp.zeros((), jnp.float32)), sq)
         else:
-            (gsum, lsum), _ = jax.lax.scan(
+            (gsum, lsum), counters = jax.lax.scan(
                 micro, (zeros, jnp.zeros((), jnp.float32)), batch)
-        return gsum, lsum
+            counters = jax.tree_util.tree_map(lambda c: c.sum(0), counters)
+        return gsum, lsum, counters
 
     def _build_offload_grad_fn(self):
         """The jitted grads-for-offload program. With
@@ -393,7 +397,7 @@ class DeepSpeedEngine:
         bits = self._offload_wire_bits
 
         def grad_fn(state, batch, scale, key):
-            gsum, lsum = self._accumulate_micro_grads(state, batch, scale)
+            gsum, lsum, _ = self._accumulate_micro_grads(state, batch, scale)
             gnorm = global_norm(gsum)
             if not bits:
                 return lsum, gsum, gnorm
@@ -602,9 +606,17 @@ class DeepSpeedEngine:
             return state["scaler"].scale
         return jnp.asarray(1.0, jnp.float32)
 
+    def _loss_and_counters(self, params, micro_batch):
+        """The loss function's scalar, and the counters of one that
+        returns ``(loss, {name: scalar})`` — what the model counted in the
+        program (a block that routes experts: ``models/cca_moe.py``); the
+        fused step hands them back in ``train_step``'s result."""
+        out = self._loss_fn(self._cast_for_compute(params), micro_batch)
+        return out if isinstance(out, tuple) else (out, {})
+
     def _micro_loss(self, params, micro_batch, scale):
-        loss = self._loss_fn(self._cast_for_compute(params), micro_batch)
-        return loss * scale
+        loss, counters = self._loss_and_counters(params, micro_batch)
+        return loss * scale, counters
 
     def _batch_spec_tree(self, batch):
         def spec(path, x):
@@ -683,10 +695,12 @@ class DeepSpeedEngine:
 
         def step_fn(state, batch):
             scale = self._current_scale(state)
-            gsum, lsum = self._accumulate_micro_grads(state, batch, scale)
+            gsum, lsum, counters = self._accumulate_micro_grads(
+                state, batch, scale)
             with jax.named_scope("optimizer"):
                 new_state, metrics = self._apply_grads(state, gsum,
                                                        float(gas))
+            metrics.update(counters)
             metrics["loss"] = lsum / (scale * gas)
             return new_state, metrics
 
@@ -794,7 +808,7 @@ class DeepSpeedEngine:
                 # collective, so overflow anywhere must skip all
                 # replicas), advance the loss-scale state machine.
                 scale = self._current_scale(state)
-                gsum, lsum = self._accumulate_micro_grads(
+                gsum, lsum, _ = self._accumulate_micro_grads(
                     state, batch, scale)
                 grads = jax.tree_util.tree_map(
                     lambda g: g.astype(jnp.float32) / (gas * scale), gsum)
@@ -1233,8 +1247,8 @@ class DeepSpeedEngine:
                                     batch)
         if not hasattr(self, "_eval_fn"):
             with self.mesh:
-                self._eval_fn = jax.jit(lambda p, b: self._loss_fn(
-                    self._cast_for_compute(p), b))
+                self._eval_fn = jax.jit(
+                    lambda p, b: self._loss_and_counters(p, b)[0])
             _count_jit_build()
         return self._eval_fn(self.state["params"], sq)
 
@@ -1254,7 +1268,9 @@ class DeepSpeedEngine:
                     lambda x: P(self._batch_dim_spec,), batch)))
         if self._grad_fn is None:
             def gfn(params, mb, scale):
-                return jax.value_and_grad(self._micro_loss)(params, mb, scale)
+                (loss, _), grads = jax.value_and_grad(
+                    self._micro_loss, has_aux=True)(params, mb, scale)
+                return loss, grads
             with self.mesh:
                 self._grad_fn = jax.jit(gfn)
             _count_jit_build()

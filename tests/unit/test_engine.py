@@ -223,3 +223,36 @@ class TestBatchReconciliation:
         with pytest.raises(ValueError):
             ds.initialize(model=tiny_model(), config=base_config(
                 train_batch_size=17))
+
+
+class TestLossWithCounters:
+    """A loss that returns ``(loss, counters)``: the fused step hands the
+    counters back in ``train_step``'s result, summed over the
+    microbatches; the trajectory is the scalar loss's."""
+
+    @pytest.mark.parametrize("gas", [1, 2])
+    def test_counters_ride_the_fused_step(self, gas):
+        model = tiny_model()
+
+        def counted(params, batch):
+            ids = batch["input_ids"]
+            return model.loss(params, batch), {
+                "rows": jnp.asarray(ids.shape[0], jnp.int32),
+                "even_ids": jnp.sum(ids % 2 == 0).astype(jnp.int32)}
+        config = base_config(train_batch_size=16 * gas,
+                             gradient_accumulation_steps=gas)
+        batch = fixed_batch(n=16 * gas)
+        plain, *_ = ds.initialize(model=model, config=config,
+                                  rng=jax.random.PRNGKey(42))
+        engine, *_ = ds.initialize(model=model, config=config,
+                                   loss_fn=counted,
+                                   rng=jax.random.PRNGKey(42))
+        for _ in range(2):
+            want, got = plain.train_step(batch), engine.train_step(batch)
+            assert float(got["loss"]) == float(want["loss"])
+            assert "rows" not in want
+            assert int(got["rows"]) == 16 * gas
+            assert int(got["even_ids"]) == int(
+                (batch["input_ids"] % 2 == 0).sum())
+        assert float(engine.eval_loss(batch)) == float(
+            plain.eval_loss(batch))

@@ -296,3 +296,115 @@ class TestMoEInference:
             nxt = logits[:, -1].argmax(-1).astype(np.int32)
             np.testing.assert_array_equal(out[:, t], nxt)
             cur = np.concatenate([cur, nxt[:, None]], axis=1)
+
+
+# ---------------------------------------------------------------------------
+# the dropless grouped product's backward (moe/dropless.py): dx is the
+# same kernel over the weights' other axis, dw the kernel
+# ``moe_grouped_matmul_dw`` — against a loop of einsums, in the interpreter
+# ---------------------------------------------------------------------------
+def grouped_case(counts, tile, k_dim=32, n=48, dead=3, seed=0):
+    """A row buffer laid out as ``dropless._layout`` lays it: expert ``e``'s
+    ``counts[e]`` rows from a tile boundary on, padding rows zero, ``dead``
+    tiles after the last live one."""
+    tiles_of = [-(-c // tile) for c in counts]
+    live = sum(tiles_of)
+    rows = (live + dead) * tile
+    te, real = [], np.zeros(rows, bool)
+    at = 0
+    for e, (c, t) in enumerate(zip(counts, tiles_of)):
+        te += [e] * t
+        real[at * tile:at * tile + c] = True
+        at += t
+    te += [len(counts) - 1] * dead
+    kx, kw, kg = jax.random.split(jax.random.PRNGKey(seed), 3)
+    x = jax.random.normal(kx, (rows, k_dim)) * real[:, None]
+    w = jax.random.normal(kw, (len(counts), k_dim, n))
+    g = jax.random.normal(kg, (rows, n)) * real[:, None]
+    return x, w, g, jnp.asarray(te, jnp.int32), live, real
+
+
+class TestGroupedProductBackward:
+    @pytest.mark.parametrize("tile", [16, 32])
+    @pytest.mark.parametrize("counts", [
+        [20, 20, 20, 20], [0, 0, 70, 0], [17, 0, 33, 5]],
+        ids=["balanced", "one_expert", "an_expert_without_rows"])
+    def test_dx_and_dw_against_a_loop_of_einsums(self, counts, tile):
+        from deepspeed_tpu.moe import dropless
+        x, w, g, te, live, real = grouped_case(counts, tile)
+        row_expert = np.repeat(np.asarray(te), tile)
+
+        def through_kernel(x, w):
+            y = dropless.grouped_matmul(x, w, te, live)
+            return jnp.sum(jnp.where(real[:, None], y, 0.0) * g)
+
+        def through_einsums(x, w):
+            y = jnp.zeros((x.shape[0], w.shape[2]))
+            for e in range(w.shape[0]):
+                y = y + jnp.where((row_expert == e)[:, None],
+                                  jnp.einsum("mk,kn->mn", x, w[e]), 0.0)
+            return jnp.sum(jnp.where(real[:, None], y, 0.0) * g)
+        dx, dw = jax.grad(through_kernel, (0, 1))(x, w)
+        want_dx, want_dw = jax.grad(through_einsums, (0, 1))(x, w)
+        live_rows = np.arange(x.shape[0]) < live * tile
+        np.testing.assert_allclose(np.asarray(dx)[live_rows],
+                                   np.asarray(want_dx)[live_rows],
+                                   atol=2e-5)
+        np.testing.assert_allclose(dw, want_dw, atol=5e-5)
+        for e, c in enumerate(counts):
+            if not c:                      # exactly zero, not small
+                assert not np.asarray(dw[e]).any()
+            else:
+                assert np.asarray(dw[e]).any()
+
+    def test_expert_share_differentiates_in_one_pass(self):
+        """``pass_rows=None``: one pass over the whole buffer, whose
+        gradients (rows, weights, pick weights) equal a dense loop's; the
+        load-dependent loop refuses reverse differentiation."""
+        from deepspeed_tpu.moe import dropless
+        t, h, f, held = 96, 32, 48, 4
+        ks = jax.random.split(jax.random.PRNGKey(0), 4)
+        u = jax.random.normal(ks[0], (t, h))
+        experts = dropless.init_experts(ks[1], held, h, f, 0.2, 0.2,
+                                        jnp.float32)
+        index = jax.random.randint(ks[2], (t, 1), 0, 8)     # 4..7 absent
+        weight = jax.random.uniform(ks[3], (t, 1)) + 0.5
+
+        def ours(u, experts, weight, **kw):
+            y, _ = dropless.expert_share(
+                experts, u, dropless.Routing(index, weight), 8, (0, held),
+                **kw)
+            return jnp.sum(y * y)
+
+        def dense(u, experts, weight):
+            y = 0.0
+            for e in range(held):
+                out = (jax.nn.silu(u @ experts["w_gate"][e])
+                       * (u @ experts["w_up"][e])) @ experts["w_down"][e]
+                y = y + jnp.where(index == e, weight, 0.0) * out
+            return jnp.sum(y * y)
+        want = jax.grad(dense, (0, 1, 2))(u, experts, weight)
+        for tile in (16, 32):
+            got = jax.grad(ours, (0, 1, 2))(u, experts, weight,
+                                            pass_rows=None, tile_rows=tile)
+            for a, b in zip(jax.tree_util.tree_leaves(got),
+                            jax.tree_util.tree_leaves(want)):
+                np.testing.assert_allclose(a, b, atol=1e-4, rtol=1e-4)
+        with pytest.raises(ValueError, match="[Rr]everse"):
+            jax.jit(jax.grad(lambda u: ours(u, experts, weight,
+                                            pass_rows=32)))(u)
+
+    def test_route_is_route_logits_of_one_product(self):
+        from deepspeed_tpu.moe import dropless
+        ks = jax.random.split(jax.random.PRNGKey(1), 3)
+        u = jax.random.normal(ks[0], (40, 32))
+        kernel = jax.random.normal(ks[1], (32, 12))
+        bias = 0.1 * jax.random.normal(ks[2], (12,))
+        for scoring, renorm in (("softmax", False), ("sigmoid", True)):
+            a = dropless.route(u, kernel, bias, 3, 2.5, scoring, renorm)
+            b = dropless.route_logits(
+                jnp.einsum("th,he->te", u, kernel,
+                           preferred_element_type=jnp.float32),
+                bias, 3, 2.5, scoring, renorm)
+            assert np.array_equal(a.index, b.index)
+            assert np.array_equal(a.weight, b.weight)

@@ -489,7 +489,7 @@ class TestRefusals:
 
     def test_training_and_the_dense_cache(self):
         model, _ = build()
-        assert "forward-only kernel" in model.training_refusal()
+        assert "no training kernel" in model.training_refusal()
         with pytest.raises(NotImplementedError, match="does not train"):
             ds.initialize(model=model, config={
                 "train_micro_batch_size_per_gpu": 2,

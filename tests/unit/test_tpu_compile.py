@@ -432,6 +432,102 @@ def test_latent_kernel_and_grouped_product_compile_at_the_cells_shapes(
         assert "tpu_custom_call" in text
 
 
+def _parent_grouped_matmul(x, w, tile_expert, live_tiles):
+    """``moe/dropless.py::grouped_matmul`` as it stood before it got a
+    backward (PR 42's tree), verbatim: what the serving programs'
+    forward must still be, instruction for instruction."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    from deepspeed_tpu.moe.dropless import (TILE_ROWS, _VMEM_LIMIT_BYTES,
+                                            _tile_n)
+    m, k_dim = x.shape
+    n = w.shape[2]
+    tn = _tile_n(k_dim, n, w.dtype.itemsize)
+    tiles = m // TILE_ROWS
+
+    def kernel(te_ref, live_ref, x_ref, w_ref, o_ref):
+        @pl.when(pl.program_id(1) < live_ref[0])
+        def _live():
+            o_ref[...] = jnp.dot(
+                x_ref[...], w_ref[...],
+                preferred_element_type=jnp.float32).astype(o_ref.dtype)
+
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(n // tn, tiles),
+            in_specs=[
+                pl.BlockSpec(
+                    (TILE_ROWS, k_dim), lambda j, t, te, live: (
+                        jnp.minimum(t, jnp.maximum(live[0] - 1, 0)), 0)),
+                pl.BlockSpec(
+                    (None, k_dim, tn), lambda j, t, te, live: (
+                        te[jnp.minimum(t, jnp.maximum(live[0] - 1, 0))], 0,
+                        j)),
+            ],
+            out_specs=pl.BlockSpec(
+                (TILE_ROWS, tn), lambda j, t, te, live: (
+                    jnp.minimum(t, jnp.maximum(live[0] - 1, 0)), j)),
+        ),
+        out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT_BYTES),
+        interpret=False,
+        name="moe_grouped_matmul",
+    )(tile_expert.astype(jnp.int32),
+      jnp.asarray(live_tiles, jnp.int32).reshape(1), x, w.astype(x.dtype))
+
+
+@pytest.mark.parametrize("k_dim,n", [(6144, 2048), (2048, 7680)],
+                         ids=["gate_up", "down"])
+def test_grouped_product_forward_is_the_parents(v5e_devices, k_dim, n):
+    """The grouped product got a backward (``custom_vjp``, a transposed
+    walk, a second kernel); the forward the three serving cells run is
+    the same instructions as before, kernel body included, at their
+    shapes (1,024-row passes over 64 stacked experts)."""
+    from deepspeed_tpu.moe import dropless
+    sds = one_chip(v5e_devices)
+    args = (sds((1024, k_dim), jnp.bfloat16),
+            sds((64, k_dim, n), jnp.bfloat16),
+            sds((64,), jnp.int32), sds((), jnp.int32))
+    def program(call):
+        def product(x, w, tile_expert, live_tiles):   # one name, one text
+            return call(x, w, tile_expert, live_tiles)
+        return compile_for_tpu(product, *args)
+    now = program(lambda *a: dropless.grouped_matmul(*a, interpret=False))
+    jax.clear_caches()
+    before = program(_parent_grouped_matmul)
+    assert "tpu_custom_call" in now
+    assert stripped(now) == stripped(before)
+
+
+@pytest.mark.parametrize("tile", [16, 256])
+def test_grouped_product_backward_compiles_at_the_cells_shapes(
+        v5e_devices, tile):
+    """``zaya1-8b.train-moe-1chip``: 16,384 picks over 8 held experts of
+    2048 x 2048 in ONE pass, tiles of 256 rows (and of the serving
+    layout's 16): the forward, dx (the same kernel against the weights'
+    other axis) and dw (``moe_grouped_matmul_dw``) compile for the chip,
+    three Mosaic calls in the gradient's program."""
+    from deepspeed_tpu.moe import dropless
+    sds = one_chip(v5e_devices)
+    rows = -(-(16384 + 8 * (tile - 1)) // tile) * tile
+
+    def grads(x, w, te, live, g):
+        def loss(x, w):
+            y = dropless.grouped_matmul(x, w, te, live, interpret=False)
+            return jnp.sum((y * y).astype(jnp.float32) * g)
+        return jax.grad(loss, (0, 1))(x, w)
+    text = compile_for_tpu(
+        grads, sds((rows, 2048), jnp.bfloat16),
+        sds((8, 2048, 2048), jnp.bfloat16), sds((rows // tile,), jnp.int32),
+        sds((), jnp.int32), sds((rows, 2048), jnp.float32))
+    assert custom_calls(text) == 3
+    assert "moe_grouped_matmul_dw" in text
+
+
 def _shortcut_case():
     from deepspeed_tpu.models import longcat_flash_config
     return longcat_flash_config(
@@ -835,6 +931,40 @@ def build_train_step(devices, chips):
         lowering_platforms=("tpu",)).compile()
 
 
+def build_cca_train_step(devices):
+    """The engine's fused train step of the CCA + top-1 expert block at
+    ZAYA1-8B's published widths: two layers, 8 of 16 experts held, two
+    sequences of 2,048, as ``zaya1-8b.train-moe-1chip`` trains it (flash
+    at 8 / 2 heads, the grouped product and its two backward products,
+    full remat, the chunked fused head, ZeRO-2 on one device)."""
+    from deepspeed_tpu.models import build_model, zaya_config
+    from deepspeed_tpu.runtime.engine import DeepSpeedEngine
+    mesh = build_mesh(MeshConfig(data=1), devices=devices[:1])
+    model = build_model(zaya_config(
+        "8b", num_layers=2, vocab_size=8192, max_seq_len=2048,
+        experts_held=(0, 8), remat="full", attn_impl="flash",
+        loss_chunk=512))
+    engine = DeepSpeedEngine(model, {
+        "train_micro_batch_size_per_gpu": 2, "steps_per_print": 0,
+        "bf16": {"enabled": True}, "gradient_clipping": 1.0,
+        "optimizer": {"type": "AdamW", "params": {"lr": 1e-4}},
+        "zero_optimization": {"stage": 2}, "mesh": {"data": 1}},
+        mesh=mesh, dont_init=True)
+
+    def placed(shapes, shardings):
+        return jax.tree_util.tree_map(
+            lambda a, sh: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                               sharding=sh),
+            shapes, shardings)
+    state = placed(jax.eval_shape(engine.init_state, jax.random.PRNGKey(0)),
+                   engine.state_shardings())
+    batch = {"input_ids": jax.ShapeDtypeStruct((1, 2, 2048), jnp.int32)}
+    batch = placed(batch, jax.tree_util.tree_map(
+        lambda sp: NamedSharding(mesh, sp), engine._batch_spec_tree(batch)))
+    return engine._build_train_step().trace(state, batch).lower(
+        lowering_platforms=("tpu",)).compile()
+
+
 #: every step program a benchmark cell runs, at this module's sizes:
 #: name -> (the program compiled once a process, the same compiled anew,
 #: the scopes it must show); both take the described devices
@@ -863,6 +993,14 @@ for _cell, _chips in (("1chip", 1), ("zero3-4chip", 4)):
         lambda dev, n=_chips: compiled_once(
             ("train", n), lambda: build_train_step(dev, n)),
         lambda dev, n=_chips: build_train_step(dev, n), _TRAIN)
+
+
+STEP_PROGRAMS["train-moe-1chip"] = (
+    lambda dev: compiled_once(("train-moe",),
+                              lambda: build_cca_train_step(dev)),
+    build_cca_train_step,
+    (_LAYER | {"attn_conv", "router", "expert_layout", "experts", "loss",
+               "optimizer", "zero_comm"}))
 
 
 def step_program_text(devices, name, fresh=False) -> str:
@@ -906,6 +1044,30 @@ def test_step_programs_carry_their_scopes(v5e_devices, compiled_kernels,
         assert any(remat for _, remat in table.values())   # remat="full"
 
 
+def stripped(text):
+    """Optimized HLO without its metadata and the source tables it points
+    into (the kernels' own too: a Mosaic call carries its kernel as MLIR
+    bytecode, source locations and name stack included), every name
+    replaced by its order of first appearance."""
+    import base64
+    import re
+    from jax._src.lib.mlir import ir
+
+    def kernel_body(match):
+        with ir.Context() as ctx:
+            ctx.allow_unregistered_dialects = True
+            body = ir.Module.parse(base64.b64decode(match.group(1)))
+            return body.operation.get_asm(enable_debug_info=False)
+    text = re.sub(r"\nFileNames\n.*?\nStackFrames\n.*?\n\n", "\n",
+                  text, count=1, flags=re.S)
+    text = re.sub(r",? ?metadata=\{[^}]*\}", "", text)
+    text = re.sub(r'(?<="body":")([^"]+)(?=")', kernel_body, text)
+    seen = {}
+    return re.sub(
+        r"%[\w.-]+",
+        lambda m: seen.setdefault(m.group(0), f"%{len(seen)}"), text)
+
+
 @pytest.mark.parametrize("name", list(STEP_PROGRAMS))
 def test_scopes_change_no_instruction(v5e_devices, compiled_kernels,
                                       monkeypatch, name):
@@ -916,31 +1078,7 @@ def test_scopes_change_no_instruction(v5e_devices, compiled_kernels,
     XLA appends to a name, ``%fusion.248``, counts the instructions made
     while lowering and shifts with the name stack; a name is compared by
     where it first appears.)"""
-    import base64
     import contextlib
-    import re
-    from jax._src.lib.mlir import ir
-
-    def kernel_body(match):
-        """A Mosaic call carries its kernel as MLIR bytecode, source
-        locations and name stack included: the same without them."""
-        with ir.Context() as ctx:
-            ctx.allow_unregistered_dialects = True
-            body = ir.Module.parse(base64.b64decode(match.group(1)))
-            return body.operation.get_asm(enable_debug_info=False)
-
-    def stripped(text):
-        """Without the metadata and the source tables it points into
-        (the kernels' own too), every name replaced by its order of
-        first appearance."""
-        text = re.sub(r"\nFileNames\n.*?\nStackFrames\n.*?\n\n", "\n",
-                      text, count=1, flags=re.S)
-        text = re.sub(r",? ?metadata=\{[^}]*\}", "", text)
-        text = re.sub(r'(?<="body":")([^"]+)(?=")', kernel_body, text)
-        seen = {}
-        return re.sub(
-            r"%[\w.-]+",
-            lambda m: seen.setdefault(m.group(0), f"%{len(seen)}"), text)
     with_scopes = stripped(step_program_text(v5e_devices, name))
     monkeypatch.setattr(jax, "named_scope",
                         lambda name: contextlib.nullcontext())
