@@ -23,7 +23,13 @@ weights from a seed:
          and each runs at least one dispatch, no compile after warm-up,
          the pool drains, the step program holds ``tpu_custom_call``.
   kernel the paged decode and prefill kernel against the float32
-         ``jax.numpy`` reference, on the chip, at the serving shapes.
+         ``jax.numpy`` reference, on the chip, at the serving shapes; the
+         flash kernels in the packed ``[B, T, H·D]`` form at 16 heads of
+         64 (two heads a 128-lane pack, the single-block backward), of
+         128 (one head a pack, the two-pass backward) and at 8 / 2 heads
+         of 64 (a pack's two heads on one kv head), forward and
+         gradients, against the same (the interpreter cannot see what
+         Mosaic makes of the lane masks and the half swaps).
   latent (one chip) the latent paged kernel at 64 and at 128 heads, the
          experts' grouped product, and an expert layer with a sigmoid
          gate and a shared expert, at ``longcat-flash-omni``'s and
@@ -369,7 +375,57 @@ def kernel_phase(heads: int, head_dim: int, device: dict,
             "head_dim": head_dim, "kv_block_size": block,
             "decode_max_abs_err": round(decode_err, 5),
             "prefill_max_abs_err": round(prefill_err, 5),
+            "flash": [flash_errors(16, 16, 64, SEQ_LEN),
+                      flash_errors(16, 16, 128, 2 * SEQ_LEN),
+                      flash_errors(8, 2, 64, 2 * SEQ_LEN)],
             "atol": KERNEL_ATOL}
+
+
+def flash_errors(heads: int, kv_heads: int, head_dim: int, seq: int,
+                 batch: int = 2):
+    """The flash kernels (packed form: the shapes say so) against float32
+    ``jax.numpy`` causal attention on the same bf16 inputs: the output's
+    largest error, and the three gradients' of ``sum(out * w)``, each as
+    a share of the reference's largest entry."""
+    import jax
+    import jax.numpy as jnp
+    from deepspeed_tpu.models import layers as L
+    from deepspeed_tpu.ops.transformer.flash_attention import (
+        _heads_a_pack, flash_attention_bthd)
+
+    what = f"kernel: flash at {heads} / {kv_heads} heads of {head_dim}"
+    check(_heads_a_pack(heads, kv_heads, head_dim) is not None,
+          f"{what}: the shape does not pack")
+    rng = np.random.default_rng(SEED + 3)
+    q, k, v, w = (jnp.asarray(rng.standard_normal(
+        (batch, seq, n, head_dim)), jnp.bfloat16)
+        for n in (heads, kv_heads, kv_heads, heads))
+    f32 = lambda a: a.astype(jnp.float32)               # noqa: E731
+
+    def plain(q, k, v):
+        return L.gqa_attention(q, k, v, causal=True)
+
+    def run(attend, *operands):
+        return jax.jit(jax.value_and_grad(
+            lambda q, k, v: jnp.sum(f32(attend(q, k, v)) * f32(w)),
+            argnums=(0, 1, 2)))(*operands)
+    out = jax.jit(flash_attention_bthd)(q, k, v)
+    ref = plain(f32(q), f32(k), f32(v))
+    out_err = float(jnp.max(jnp.abs(f32(out) - ref)))
+    _, grads = run(flash_attention_bthd, q, k, v)
+    _, want = run(plain, f32(q), f32(k), f32(v))
+    grad_err = max(float(jnp.max(jnp.abs(f32(g) - r)) / jnp.max(jnp.abs(r)))
+                   for g, r in zip(grads, want))
+    check(bool(jnp.all(jnp.isfinite(f32(out)))), f"{what} not finite")
+    check(out_err < KERNEL_ATOL,
+          f"{what} off the f32 reference by {out_err}")
+    check(grad_err < KERNEL_ATOL,
+          f"{what}: a gradient off the f32 reference by {grad_err} of "
+          f"its largest entry")
+    return {"heads": heads, "kv_heads": kv_heads, "head_dim": head_dim,
+            "seq": seq,
+            "out_max_abs_err": round(out_err, 5),
+            "grad_max_rel_err": round(grad_err, 5)}
 
 
 def latent_phase(device: dict, block: int = SERVING["kv_block_size"]):

@@ -11,6 +11,7 @@ operate on them as pytrees with partition-spec trees.
 """
 from __future__ import annotations
 
+import functools
 import math
 from typing import Optional, Tuple
 
@@ -147,6 +148,55 @@ def apply_rotary(x, cos, sin, positions=None, interleaved=True):
         y2 = x2 * c + x1 * s
         y = jnp.concatenate([y1, y2], axis=-1)
     return jnp.concatenate([y.astype(x.dtype), x_pass], axis=-1)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def apply_rotary_lanes(x, cos, sin, head_dim: int, interleaved=True):
+    """`apply_rotary` at positions 0..T-1 on ``x [B, T, H * head_dim]``,
+    the projection's own layout, for the flash kernels.
+
+    Nothing here splits the lane dimension into heads or narrows it to
+    the rotary width (on the TPU either is a re-layout of the whole array:
+    a ``[.., H, Dh]`` view tiles differently, and a 16-wide operand is
+    laid out time-minor): a lane's partner is the array shifted by the
+    pair distance, picked by the lane's number, and the cosines and
+    signed sines are full-width ``[T, H * head_dim]`` tables (ones and
+    zeros past the rotary width).  The rotation is orthogonal, so the
+    backward is the same pass with the sines negated."""
+    t, width = x.shape[1], x.shape[2]
+    half = cos.shape[-1]
+    cos, sin = cos[:t].astype(jnp.float32), sin[:t].astype(jnp.float32)
+    rest = jnp.zeros((t, head_dim - 2 * half), jnp.float32)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, 1, width), 2) % head_dim
+    if interleaved:
+        shift, first = 1, lane % 2 == 0
+        pair = lambda a, b: jnp.stack([a, b], axis=-1).reshape(t, 2 * half)
+    else:
+        shift, first = half, lane < half
+        pair = lambda a, b: jnp.concatenate([a, b], axis=-1)
+    c, s = (jnp.concatenate([tab, fill] * (width // head_dim), axis=-1)
+            for tab, fill in ((pair(cos, cos), rest + 1.0),
+                              (pair(-sin, sin), rest)))
+    zero = jnp.zeros((), x.dtype)
+    up = jax.lax.pad(x[..., shift:], zero, [(0, 0, 0)] * 2 + [(0, shift, 0)])
+    down = jax.lax.pad(x[..., :-shift], zero,
+                       [(0, 0, 0)] * 2 + [(shift, 0, 0)])
+    # a pair's first lane takes the one `shift` above it, its second the
+    # one below; past the rotary width s is 0
+    return (x * c + jnp.where(first, up, down) * s).astype(x.dtype)
+
+
+def _rotary_lanes_fwd(x, cos, sin, head_dim, interleaved):
+    return apply_rotary_lanes(x, cos, sin, head_dim, interleaved), (cos, sin)
+
+
+def _rotary_lanes_bwd(head_dim, interleaved, res, dy):
+    cos, sin = res
+    return (apply_rotary_lanes(dy, cos, -sin, head_dim, interleaved),
+            jnp.zeros_like(cos), jnp.zeros_like(sin))
+
+
+apply_rotary_lanes.defvjp(_rotary_lanes_fwd, _rotary_lanes_bwd)
 
 
 # ---------------------------------------------------------------------------

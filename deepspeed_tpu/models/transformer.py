@@ -762,6 +762,9 @@ class TransformerLM:
 
     # -- block -------------------------------------------------------------
     def _attention(self, p, x, cache_kv=None, positions=None, window=None):
+        if self._flash_trains(cache_kv, window) and (
+                positions is None or self.config.pos_embedding != "rotary"):
+            return self._flash_attention(p, x), None
         with jax.named_scope("attn_proj"):
             q, k, v = self._qkv(p, x, positions)
         if isinstance(cache_kv, PagedMixedState):
@@ -775,13 +778,53 @@ class TransformerLM:
         with jax.named_scope("attn_proj"):
             return L.dense_apply(p["out"], o), new_cache
 
+    def _flash_attention(self, p, x):
+        """The attention sublayer through the flash kernels, which take
+        the projection's own ``[B, T, heads * hd]`` layout: the fused
+        product goes over as ONE array (q, k and v by block index), or,
+        where rotary stands between, q and k as its outputs and v as the
+        slice.  Nothing is split into heads on the way."""
+        from ..ops.transformer import flash_attention as fa
+        c = self.config
+        nq, nkv = c.num_heads * c.hdim, c.kv_heads * c.hdim
+        with jax.named_scope("attn_proj"):
+            qkv = self._qkv_product(p, x)
+        if c.pos_embedding == "rotary":
+            with jax.named_scope("attn_proj"):
+                q, k = (L.apply_rotary_lanes(a, self._cos, self._sin, c.hdim,
+                                             c.rotary_interleaved)
+                        for a in (qkv[..., :nq], qkv[..., nq:nq + nkv]))
+            with jax.named_scope("attn_kernel"):
+                o = fa.flash_attention_packed(
+                    q, k, qkv[..., nq + nkv:], c.hdim, causal=c.causal,
+                    mesh=self.mesh)
+        else:
+            with jax.named_scope("attn_kernel"):
+                o = fa.flash_attention_qkv(
+                    qkv, c.num_heads, c.kv_heads, causal=c.causal,
+                    mesh=self.mesh)
+        with jax.named_scope("attn_proj"):
+            return L.dense_apply(p["out"], o)
+
+    def _flash_trains(self, cache_kv, window) -> bool:
+        """Whether this attention call is the flash kernel's: a whole
+        sequence, no cache, no bias and no band."""
+        c = self.config
+        return (cache_kv is None and c.attn_impl == "flash"
+                and c.pos_embedding != "alibi" and window is None)
+
+    def _qkv_product(self, p, x):
+        """x [B, T, D] -> the fused projection [B, T, (H + 2 Hkv) hd],
+        sections q | k | v."""
+        return L.dense_apply(p["qkv"], self._maybe_qact(x, "attn_in"))
+
     def _qkv(self, p, x, positions):
         """x [B, T, D] -> q [B, T, H, hd], k / v [B, T, Hkv, hd], rotary
         applied."""
         c = self.config
         nh, hd = c.num_heads, c.hdim
         nkv = c.kv_heads
-        qkv = L.dense_apply(p["qkv"], self._maybe_qact(x, "attn_in"))
+        qkv = self._qkv_product(p, x)
         b, t = qkv.shape[0], qkv.shape[1]
         if nkv == nh:
             qkv3 = qkv.reshape(b, t, 3, nh, hd)
@@ -856,14 +899,15 @@ class TransformerLM:
                                                 t, t)
                 o = L.causal_attention(q, k, v, mask=mask, causal=c.causal)
             return o.reshape(b, t, nh * hd), None
-        if cache_kv is None and c.attn_impl == "flash" and \
-                c.pos_embedding != "alibi" and window is None:
+        if self._flash_trains(cache_kv, window):
             from ..ops.transformer.flash_attention import (
                 flash_attention_bthd)
-            # k/v go in at kv-head width; ragged lengths are masked
-            # in-kernel (ceil grid).  Over a multi-device mesh the
-            # kernel runs per shard (a Mosaic call cannot be
-            # auto-partitioned) — the engine binds the mesh
+            # rotary at explicit positions (every other flash call goes
+            # from `_attention` in the projection's own layout).  k/v go
+            # in at kv-head width; ragged lengths are masked in-kernel
+            # (ceil grid).  Over a multi-device mesh the kernel runs per
+            # shard (a Mosaic call cannot be auto-partitioned) — the
+            # engine binds the mesh
             o = flash_attention_bthd(q, k, v, causal=c.causal,
                                      mesh=self.mesh)
             return o.reshape(b, t, nh * hd), None

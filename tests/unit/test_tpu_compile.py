@@ -21,7 +21,7 @@ import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from deepspeed_tpu import ops
-from deepspeed_tpu.models import TransformerLM, gpt2_config
+from deepspeed_tpu.models import TransformerLM, gpt2_config, neox_config
 from deepspeed_tpu.ops.sparse_attention import FixedSparsityConfig
 from deepspeed_tpu.ops.sparse_attention.blocksparse_flash import (
     blocksparse_attention_bthd)
@@ -98,11 +98,14 @@ def test_flash_fwd_bwd_compiles(v5e_devices, compiled_kernels, d, t):
     assert text.count("tpu_custom_call") >= 2       # fwd + bwd kernels
 
 
-@pytest.mark.parametrize("d", [64, 128])
-def test_flash_gqa_compiles(v5e_devices, compiled_kernels, d):
+@pytest.mark.parametrize("d,t", [(64, 2048), (128, 2048), (128, 8192)])
+def test_flash_gqa_compiles(v5e_devices, compiled_kernels, d, t):
+    """8 / 2 heads: at 64 a pack's two heads on one kv head (its half of
+    the k / v tile swapped into place), at 128 by block index (8,192 is
+    the length ``zaya1-8b.train-moe-1chip`` trains)."""
     sds = one_chip(v5e_devices)
-    q = sds((2, 2048, 8, d), jnp.bfloat16)
-    kv = sds((2, 2048, 2, d), jnp.bfloat16)
+    q = sds((2, t, 8, d), jnp.bfloat16)
+    kv = sds((2, t, 2, d), jnp.bfloat16)
 
     def loss(q, k, v):
         return flash_attention_bthd(q, k, v).astype(jnp.float32).sum()
@@ -686,6 +689,68 @@ def test_flash_kernels_keep_their_names(v5e_devices, compiled_kernels,
                         for ln in calls)
     assert "rematted_computation" in wrappers and "checkpoint" in wrappers
     assert ("shard_map" in wrappers) == (chips > 1)
+
+
+#: the two dense training cells' models and step shapes
+DENSE_TRAIN_CELLS = {
+    "gpt2-medium": (lambda **kw: gpt2_config("350m", **kw),
+                    dict(max_seq_len=1024), (16, 1024)),
+    "pythia-1.4b": (lambda **kw: neox_config("1.3b", **kw),
+                    dict(max_seq_len=2048, vocab_size=50304,
+                         rotary_pct=0.25, tie_embeddings=False,
+                         activation="gelu_exact"), (8, 2048))}
+
+
+@pytest.mark.parametrize("cell", list(DENSE_TRAIN_CELLS))
+def test_flash_operands_are_never_relaid(v5e_devices, compiled_kernels,
+                                         cell):
+    """``value_and_grad(model.loss)`` of two layers at a dense training
+    cell's shapes (16 heads of 64 fed the fused qkv product; 16 heads of
+    128 behind rotary; ``remat="full"``): the flash kernels take the
+    projection's own ``[B, T, H·D]`` layout, so the optimized HLO holds no
+    ``copy`` / ``transpose`` of a q- or qkv-sized array under
+    ``attn_kernel`` or ``attn_proj`` — forward, recomputed or backward
+    (the adapter that transposed to ``[B·H, T, D]`` and back left 12 + 2
+    a layer in gpt2's program and 12 + 3 in Pythia's).
+
+    One re-layout of that size is not the kernels': the residual
+    stream's cotangent ``[B, T, d_model]``, laid time-minor for the
+    output projection's weight gradient (gpt2 only, and the parent's
+    too)."""
+    import re
+    build, sizes, (b, t) = DENSE_TRAIN_CELLS[cell]
+    mesh = build_mesh(MeshConfig(data=1), devices=v5e_devices[:1])
+    model = TransformerLM(build(num_layers=2, remat="full",
+                                attn_impl="flash", loss_chunk=256, **sizes))
+    model.bind_mesh(mesh)
+    params = jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(
+            s.shape, jnp.bfloat16,
+            sharding=NamedSharding(mesh, P(*([None] * len(s.shape))))),
+        jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    batch = {"input_ids": jax.ShapeDtypeStruct(
+        (b, t), jnp.int32, sharding=NamedSharding(mesh, P("data", None)))}
+    text = compile_for_tpu(jax.value_and_grad(model.loss), params, batch)
+    q_size = b * t * model.config.num_heads * model.config.hdim
+    moved = []
+    for ln in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%(\S+) = \w+\[([\d,]+)\]\S* ([\w-]+)\("
+                     r"(\S+)", ln)
+        path = re.search(r'op_name="([^"]*)"', ln)
+        if m is None or path is None or not re.search(
+                r"attn_(kernel|proj)", path.group(1)):
+            continue
+        name, dims, op, operand = m.groups()
+        relaid = op in ("copy", "transpose") or (
+            op == "fusion" and re.match(r"(copy|transpose)", name))
+        if relaid and np.prod([int(n) for n in dims.split(",")]) >= q_size:
+            moved.append((operand, ln.strip()[:200]))
+    residual = [ln for operand, ln in moved
+                if operand.startswith("%get-tuple-element")
+                and "attn_proj" in ln and "{1,2,0" in ln
+                and "rematted_computation" not in ln]
+    assert len(residual) <= 1
+    assert [ln for _, ln in moved if ln not in residual] == []
 
 
 def test_loss_head_backward_writes_the_plane_once(v5e_devices):
