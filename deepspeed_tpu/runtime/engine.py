@@ -157,6 +157,7 @@ class DeepSpeedEngine:
             param_persistence_threshold=(
                 self._config.zero_config.param_persistence_threshold
                 if self.zero_stage >= 3 else 0))
+        self._install_layer_gather(model)
         self.master_specs = self.zero_policy.master_param_specs()
         self.grad_specs = self.zero_policy.grad_specs()
         opt_shapes = jax.eval_shape(self.optimizer.init, self._param_shapes)
@@ -717,6 +718,28 @@ class DeepSpeedEngine:
             self._train_step_fn = jax.jit(step_fn, donate_argnums=donate)
         _count_jit_build()
         return self._train_step_fn
+
+    def _install_layer_gather(self, model):
+        """Stage 3 over more than one data-parallel device: the policy's
+        ``gather_layer`` goes on the model's per-layer seam
+        (``block_transform``, called on a layer's slice inside the
+        rematerialised scan body by every block family), ahead of the
+        transform the model was built with.  Any other engine leaves —
+        or puts back — the model's own."""
+        own = getattr(model, "block_transform", None)
+        if own is None:
+            return
+        own = getattr(own, "model_transform", own)
+        if not self.zero_policy.gathers_layers:
+            model.block_transform = own
+            return
+
+        policy = self.zero_policy    # not self: the model outlives engines
+
+        def gather_then(layer):
+            return own(policy.gather_layer(layer))
+        gather_then.model_transform = own
+        model.block_transform = gather_then
 
     def _apply_training_overrides(self, model):
         """Rebuild ``model`` with the ``training`` block's model-side

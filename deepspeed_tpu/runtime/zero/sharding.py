@@ -7,8 +7,9 @@ DeepSpeedZeroOptimizer_Stage3, `partition_parameters.py:539` zero.Init,
 `partitioned_param_coordinator.py:44` prefetcher). On TPU under GSPMD the
 same dataflow is a *declaration*: we transform the model's tensor-parallel
 PartitionSpecs into specs for gradients, optimizer state, and (stage 3)
-parameters over the ``data`` mesh axis, and XLA emits the reduce-scatters,
-all-gathers, and their overlap schedule that the reference hand-codes:
+parameters over the ``data`` mesh axis.  Where the state LIVES is all a
+spec tree can say; for stages 0-2 that is also enough to fix the
+traffic:
 
   stage 0 — grads psum over data (classic DP; engine.py:1890 allreduce_gradients)
   stage 1 — optimizer state + fp32 master params sharded over data;
@@ -16,9 +17,25 @@ all-gathers, and their overlap schedule that the reference hand-codes:
             updated params all-gathered (reference stage_1_and_2.py step :1750)
   stage 2 — + gradient specs sharded over data → XLA reduce-scatters grads
             instead of all-reducing (reference average_tensor :942 IPG path)
-  stage 3 — + parameter specs sharded over data → just-in-time all-gather
-            per scan block, scheduled by the XLA latency-hiding scheduler
-            (reference fetch_sub_module / prefetch machinery)
+  stage 3 — + parameter specs sharded over data.  A spec on a weight
+            does NOT say that the weight is what travels: left to its
+            cost model the partitioner keeps each shard where it is and
+            moves the activations of the whole global batch to it,
+            tensor-parallel style over ``data`` (an ``all-gather`` of x,
+            ``all-to-all``s of the products: five times ZeRO-3's bytes
+            in the four-chip cell, docs/training_perf.md).  So the policy
+            states the dataflow itself, :meth:`ZeroShardingPolicy.
+            gather_layer`: a scan block's compute-dtype weights are
+            gathered over the data axes inside the block's rematerialised
+            body, immediately before use (the reference's
+            fetch_sub_module), the backward gathers them again (nothing
+            gathered is saved), and their gradients leave the body
+            reduced into ``grad_specs``' layout.  No prefetch: a block's
+            gathers are overlapped only with that block's own compute,
+            as far as XLA's scheduler manages (the reference's prefetch
+            coordinator has no counterpart yet).  What is not in a scan
+            block (embedding, head, final norm) is still the
+            partitioner's to place.
 
 The "partitioning" itself: for each leaf we shard the largest dimension not
 already claimed by another mesh axis and divisible by the data-axis size;
@@ -40,6 +57,10 @@ def _spec_entries(spec: Optional[P], ndim: int) -> list:
     entries = list(spec) if spec is not None else []
     entries += [None] * (ndim - len(entries))
     return entries
+
+
+def _is_spec(x) -> bool:
+    return isinstance(x, P) or x is None
 
 
 def _used_axes(entries) -> set:
@@ -141,7 +162,7 @@ def grad_reduce_plan(region_specs, grad_specs, data_axes: Sequence[str]):
 
     pairs = jax.tree_util.tree_map(
         one, region_specs, grad_specs,
-        is_leaf=lambda x: isinstance(x, P) or x is None)
+        is_leaf=_is_spec)
     plan = jax.tree_util.tree_map(
         lambda pr: pr[0], pairs,
         is_leaf=lambda x: isinstance(x, tuple) and len(x) == 2
@@ -156,7 +177,7 @@ def grad_reduce_plan(region_specs, grad_specs, data_axes: Sequence[str]):
 class ZeroShardingPolicy:
     """Derives all spec trees for a ZeRO stage.
 
-    ``scan_dims`` maps a params-subtree prefix to the dim index that is a
+    ``scan_axis_paths`` names the params subtrees whose leading dim is a
     lax.scan layer axis (excluded from stage-3 param sharding so each scan
     step gathers only its own layer block, not the whole stack).
     """
@@ -184,6 +205,10 @@ class ZeroShardingPolicy:
                                      param_persistence_threshold)
         self.min_partition_size = min_partition_size
         self.param_persistence_threshold = param_persistence_threshold
+        #: stage 3 across data-parallel devices: the engine puts
+        #: :meth:`gather_layer` on the model's per-layer seam
+        self.gathers_layers = stage >= 3 and any(
+            mesh.shape.get(a, 1) > 1 for a in (DCN_DATA_AXIS, DATA_AXIS))
 
     # -- helpers -----------------------------------------------------------
     def _is_scan_path(self, path) -> bool:
@@ -204,7 +229,7 @@ class ZeroShardingPolicy:
                                    min_size=self.min_partition_size)
         return jax.tree_util.tree_map_with_path(
             f, self.param_specs, self.param_shapes,
-            is_leaf=lambda x: isinstance(x, P) or x is None)
+            is_leaf=_is_spec)
 
     # -- public spec trees -------------------------------------------------
     def model_param_specs(self):
@@ -223,6 +248,35 @@ class ZeroShardingPolicy:
         if self.stage >= 2:
             return self._sharded_tree(exclude_scan_dim=True)
         return self.param_specs
+
+    def gather_layer(self, layer):
+        """Stage 3's fetch: ``layer`` — one layer's slice of a scan-path
+        subtree as the state stores it (each stacked leaf less its
+        leading dim, sharded over the data axes) — in ``param_specs``'
+        layout: the model-parallel / expert axes the model declared,
+        the ``(dcn_data, data)`` product gathered away.  Called inside
+        the block's rematerialised body it is an all-gather of the
+        block's compute-dtype weights there and again in the backward,
+        and its transpose reduces the block's weight gradients back
+        into their shards.  A leaf the policy left whole (under
+        ``param_persistence_threshold``) passes through."""
+        structure = jax.tree_util.tree_structure(layer)
+        stacks = self.param_specs if isinstance(self.param_specs, dict) else {}
+        for name in self.scan_axis_paths:
+            stack = stacks.get(name)
+            if stack is not None and jax.tree_util.tree_structure(
+                    stack, is_leaf=_is_spec) == structure:
+                break
+        else:
+            raise ValueError(
+                f"no subtree of {self.scan_axis_paths} has this layer's "
+                f"structure: {structure}")
+        with jax.named_scope("zero_comm"):
+            return jax.tree_util.tree_map(
+                lambda x, spec: jax.lax.with_sharding_constraint(
+                    x, NamedSharding(self.mesh, P(*_spec_entries(
+                        spec, x.ndim + 1)[1:]))),
+                layer, stack)
 
     def opt_state_specs(self, opt_state_shapes):
         """Map every params-shaped subtree inside the optimizer state to

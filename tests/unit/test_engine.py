@@ -256,3 +256,120 @@ class TestLossWithCounters:
                 (batch["input_ids"] % 2 == 0).sum())
         assert float(engine.eval_loss(batch)) == float(
             plain.eval_loss(batch))
+
+
+class TestStage3GathersLayers:
+    """Stage 3 over more than one data-parallel device puts the policy's
+    ``gather_layer`` on the model's per-layer seam; every other engine
+    leaves the model as it was built."""
+
+    SHARD_ALL = {"stage": 3, "param_persistence_threshold": 0}
+
+    @pytest.mark.parametrize("mesh", [{"data": 8}, {"data": 4, "model": 2}],
+                             ids=["data8", "data4_model2"])
+    def test_sharded_stage3_matches_stage0(self, mesh):
+        """Every parameter sharded (threshold 0) and every layer gathered
+        in its body: the trajectory is stage 0's, and the state leaves
+        the step in ``state_shardings()``."""
+        _, l0 = run_steps(base_config(), n=3)
+        engine, l3 = run_steps(base_config(
+            mesh=mesh, zero_optimization=self.SHARD_ALL), n=3)
+        np.testing.assert_allclose(l0, l3, rtol=2e-3 if "model" in mesh
+                                   else 2e-4)
+        assert engine.zero_policy.gathers_layers
+        want = jax.tree_util.tree_leaves(engine.state_shardings())
+        got = jax.tree_util.tree_leaves(engine.state)
+        assert len(want) == len(got)
+        for leaf, sharding in zip(got, want):
+            assert leaf.sharding.is_equivalent_to(sharding, leaf.ndim)
+
+    @pytest.mark.parametrize("stage,mesh", [
+        (0, {"data": 8}), (1, {"data": 8}), (2, {"data": 8}),
+        (2, {"data": 4, "model": 2}), (3, {"data": 1}),
+        (3, {"data": 1, "model": 8})])
+    def test_other_engines_never_see_the_gather(self, monkeypatch, stage,
+                                                mesh):
+        """Stages 0-2, and stage 3 with nothing to gather over: the
+        model's ``block_transform`` is the one it was built with, and
+        tracing the step never reaches ``gather_layer`` — the step's
+        jaxpr is what it was before the policy had the function."""
+        from deepspeed_tpu.parallel.topology import build_mesh
+        from deepspeed_tpu.runtime.config import MeshConfig
+        from deepspeed_tpu.runtime.zero.sharding import ZeroShardingPolicy
+
+        def never(self, layer):
+            raise AssertionError("gather_layer reached")
+        monkeypatch.setattr(ZeroShardingPolicy, "gather_layer", never)
+        model = tiny_model()
+        built_with = model.block_transform
+        devices = jax.devices()[:int(np.prod(list(mesh.values())))]
+        engine, *_ = ds.initialize(
+            model=model, mesh=build_mesh(MeshConfig(**mesh), devices=devices),
+            config=base_config(
+                mesh=mesh, train_batch_size=2 * mesh["data"],
+                zero_optimization={"stage": stage,
+                                   "param_persistence_threshold": 0}))
+        assert not engine.zero_policy.gathers_layers
+        assert engine.model.block_transform is built_with
+        batch = engine.shard_batch(fixed_batch(n=2 * mesh["data"]))
+        jaxpr = engine._build_train_step().trace(engine.state, batch).jaxpr
+        assert "sharding_constraint" in str(jaxpr)   # the engine's own
+
+    def test_gathered_layer_keeps_its_model_axis(self):
+        """On ``{model: 2, data: 4}`` the gathered layout is
+        ``param_specs``': the ``model`` axis stays where the model
+        declared it and only the data axis is gathered away."""
+        from jax.sharding import PartitionSpec as P
+        engine, _ = run_steps(base_config(
+            mesh={"data": 4, "model": 2},
+            zero_optimization=self.SHARD_ALL), n=0)
+        blocks = engine.state["params"]["blocks"]
+        stored = blocks["mlp"]["fc_in"]["kernel"].sharding.spec
+        assert "data" in str(stored) and "model" in str(stored)
+        with engine.mesh:
+            layer = jax.jit(lambda b: engine.zero_policy.gather_layer(
+                jax.tree_util.tree_map(lambda a: a[0], b)))(blocks)
+        declared = engine.zero_policy.param_specs["blocks"]
+        for leaf, spec in zip(jax.tree_util.tree_leaves(layer),
+                              jax.tree_util.tree_leaves(
+                                  declared,
+                                  is_leaf=lambda x: isinstance(x, P))):
+            assert "data" not in str(leaf.sharding.spec)
+            want = jax.sharding.NamedSharding(engine.mesh, P(*spec[1:]))
+            assert leaf.sharding.is_equivalent_to(want, leaf.ndim)
+        assert "model" in str(layer["mlp"]["fc_in"]["kernel"].sharding.spec)
+        np.testing.assert_array_equal(
+            np.asarray(layer["mlp"]["fc_in"]["kernel"]),
+            np.asarray(blocks["mlp"]["fc_in"]["kernel"][0]))
+
+    def test_composes_with_the_models_own_transform(self, monkeypatch):
+        """A model built with a ``block_transform`` keeps it BEHIND the
+        gather: the policy sees the stored slice its spec tree describes,
+        the model's transform what the gather returned.  A later engine
+        with nothing to gather puts the model's own back."""
+        seen = []
+
+        def own(layer):
+            seen.append(layer)
+            return layer
+        cfg = tiny_model().config
+        model = TransformerLM(cfg, block_transform=own)
+        engine, *_ = ds.initialize(model=model, config=base_config(
+            zero_optimization=self.SHARD_ALL))
+        seam = engine.model.block_transform
+        assert seam is not own and seam.model_transform is own
+        stored, gathered = object(), object()
+        monkeypatch.setattr(
+            engine.zero_policy, "gather_layer",
+            lambda layer: gathered if layer is stored else None)
+        assert seam(stored) is gathered and seen == [gathered]
+        monkeypatch.undo()
+        seen.clear()
+        _, losses = run_steps(base_config(zero_optimization=self.SHARD_ALL),
+                              n=2, model=model)
+        assert model.block_transform.model_transform is own   # not nested
+        assert seen and all(np.isfinite(losses))
+        _, l0 = run_steps(base_config(), n=2)
+        np.testing.assert_allclose(l0, losses, rtol=2e-4)
+        ds.initialize(model=model, config=base_config())       # stage 0
+        assert model.block_transform is own

@@ -964,6 +964,25 @@ def test_serving_step_sorts_only_inside_a_conditional(v5e_devices,
 # device scopes: the layer names the model code declares, read back from
 # the compiled step programs' own text (observability/overlap.py)
 # ---------------------------------------------------------------------------
+def compile_engine_step(engine, rows, seq):
+    """``engine``'s fused train step (an engine built with
+    ``dont_init=True`` over described devices) compiled for the TPU on
+    abstract state and one abstract microbatch of ``rows`` x ``seq``."""
+    def placed(shapes, shardings):
+        return jax.tree_util.tree_map(
+            lambda a, sh: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                               sharding=sh),
+            shapes, shardings)
+    state = placed(jax.eval_shape(engine.init_state, jax.random.PRNGKey(0)),
+                   engine.state_shardings())
+    batch = {"input_ids": jax.ShapeDtypeStruct((1, rows, seq), jnp.int32)}
+    batch = placed(batch, jax.tree_util.tree_map(
+        lambda sp: NamedSharding(engine.mesh, sp),
+        engine._batch_spec_tree(batch)))
+    return engine._build_train_step().trace(state, batch).lower(
+        lowering_platforms=("tpu",)).compile()
+
+
 def build_train_step(devices, chips):
     """The engine's fused train step, built by the engine itself over
     ``chips`` described devices (ZeRO-2 on one, ZeRO-3 over ``data: 4``
@@ -980,20 +999,7 @@ def build_train_step(devices, chips):
         "optimizer": {"type": "AdamW", "params": {"lr": 3e-4}},
         "zero_optimization": {"stage": 3 if chips > 1 else 2},
         "mesh": {"data": chips}}, mesh=mesh, dont_init=True)
-
-    def placed(shapes, shardings):
-        return jax.tree_util.tree_map(
-            lambda a, sh: jax.ShapeDtypeStruct(a.shape, a.dtype,
-                                               sharding=sh),
-            shapes, shardings)
-    state = placed(jax.eval_shape(engine.init_state, jax.random.PRNGKey(0)),
-                   engine.state_shardings())
-    batch = {"input_ids": jax.ShapeDtypeStruct((1, 2 * chips, 1024),
-                                               jnp.int32)}
-    batch = placed(batch, jax.tree_util.tree_map(
-        lambda sp: NamedSharding(mesh, sp), engine._batch_spec_tree(batch)))
-    return engine._build_train_step().trace(state, batch).lower(
-        lowering_platforms=("tpu",)).compile()
+    return compile_engine_step(engine, 2 * chips, 1024)
 
 
 def build_cca_train_step(devices):
@@ -1015,19 +1021,92 @@ def build_cca_train_step(devices):
         "optimizer": {"type": "AdamW", "params": {"lr": 1e-4}},
         "zero_optimization": {"stage": 2}, "mesh": {"data": 1}},
         mesh=mesh, dont_init=True)
+    return compile_engine_step(engine, 2, 2048)
 
-    def placed(shapes, shardings):
-        return jax.tree_util.tree_map(
-            lambda a, sh: jax.ShapeDtypeStruct(a.shape, a.dtype,
-                                               sharding=sh),
-            shapes, shardings)
-    state = placed(jax.eval_shape(engine.init_state, jax.random.PRNGKey(0)),
-                   engine.state_shardings())
-    batch = {"input_ids": jax.ShapeDtypeStruct((1, 2, 2048), jnp.int32)}
-    batch = placed(batch, jax.tree_util.tree_map(
-        lambda sp: NamedSharding(mesh, sp), engine._batch_spec_tree(batch)))
-    return engine._build_train_step().trace(state, batch).lower(
-        lowering_platforms=("tpu",)).compile()
+
+def build_zero3_cell_step(devices):
+    """The engine's fused ZeRO-3 step over ``data: 4`` at the four-chip
+    cell's own shapes — Pythia-1.4B's widths, 8 x 2,048 tokens a chip,
+    flash, full remat, the chunked fused head — at two layers, on
+    abstract state."""
+    from deepspeed_tpu.runtime.engine import DeepSpeedEngine
+    build, sizes, (b, t) = DENSE_TRAIN_CELLS["pythia-1.4b"]
+    mesh = build_mesh(MeshConfig(data=4), devices=devices)
+    model = TransformerLM(build(num_layers=2, remat="full",
+                                attn_impl="flash", loss_chunk=256, **sizes))
+    engine = DeepSpeedEngine(model, {
+        "train_micro_batch_size_per_gpu": b, "steps_per_print": 0,
+        "bf16": {"enabled": True}, "gradient_clipping": 1.0,
+        "optimizer": {"type": "AdamW",
+                      "params": {"lr": 3e-4, "weight_decay": 0.01}},
+        "zero_optimization": {"stage": 3}, "mesh": {"data": 4}},
+        mesh=mesh, dont_init=True)
+    return compile_engine_step(engine, 4 * b, t)
+
+
+#: ``temp_size_in_bytes`` of ``build_zero3_cell_step`` on the parent of
+#: PR 46 (the partitioner left to place stage 3's traffic: the global
+#: batch's activations travel to the weight shards); with a layer's
+#: weights gathered in its body the same program compiles to 1,515,195,392
+ZERO3_CELL_TEMP_BYTES_PARENT = 2_497_768_960
+
+
+def test_zero3_gathers_a_layers_weights_not_the_activations(
+        v5e_devices, compiled_kernels):
+    """ZeRO-3's dataflow in the four-chip cell's step program: a layer's
+    bf16 weights are gathered over ``data`` inside the layer's body
+    (forward, and again in the rematerialised backward), its gradients
+    leave the body reduced into their shards, and no activation crosses
+    the data axis on a weight's account.  Left to the partitioner the
+    program kept each weight shard in place and moved the whole global
+    batch's activations to it instead — four ``all-to-all`` and an
+    ``all-gather bf16[32, 2048, 2048]`` a layer body, five times the
+    bytes."""
+    import re
+    compiled = compiled_once(("train-zero3-cell",),
+                             lambda: build_zero3_cell_step(v5e_devices))
+    text = compiled.as_text()
+    assert "all-to-all" not in text
+    comps, _ = hlo_computations(text)
+
+    def reached(name):
+        seen, todo = set(), [name]
+        while todo:
+            name = todo.pop()
+            if name not in seen:
+                seen.add(name)
+                todo += [c for ln in comps[name]
+                         for c in re.findall(r"%([\w.-]+)", ln) if c in comps]
+        return seen
+    bodies = [m.group(1) for lines in comps.values() for ln in lines
+              for m in [re.search(r" while\(.*body=%([\w.-]+)", ln)] if m]
+    layer_bodies = [b for b in bodies if any(
+        "%flash_" in ln for c in reached(b) for ln in comps[c])]
+    assert len(layer_bodies) == 2              # the forward scan, the backward
+    b, t = DENSE_TRAIN_CELLS["pythia-1.4b"][2]
+    d, ffn = 2048, 8192
+    collectives = []
+    for c in set().union(*map(reached, layer_bodies)):
+        for ln in comps[c]:
+            m = re.match(r"\s*(?:ROOT )?%\S+ = (.+?) (all-gather|all-reduce|"
+                         r"reduce-scatter|collective-permute)(?:-start)?\(",
+                         ln)
+            if m is not None:
+                collectives += [
+                    (m.group(2), dtype,
+                     int(np.prod([int(n) for n in dims.split(",") if n])))
+                    for dtype, dims in re.findall(r"(\w+)\[([\d,]*)\]",
+                                                  m.group(1))]
+    gathers = [(dtype, n) for op, dtype, n in collectives
+               if op == "all-gather"]
+    assert len(gathers) >= 8                   # four weights, two bodies
+    assert max(n for _, _, n in collectives) < b * t * d
+    assert {dtype for dtype, _ in gathers} == {"bf16"}
+    assert max(n for _, n in gathers) <= d * ffn
+    stacked = re.findall(r"\[2,2048,(?:8192|6144)\]", text)
+    assert not stacked, stacked[:3]
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < 0.8 * ZERO3_CELL_TEMP_BYTES_PARENT
 
 
 #: every step program a benchmark cell runs, at this module's sizes:
