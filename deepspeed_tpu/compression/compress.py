@@ -557,39 +557,29 @@ def redundancy_clean(params, cfg, step=None):
 def apply_layer_reduction(model, params, lr_cfg: LayerReductionConfig):
     """Teacher → student: keep the stacked-scan rows ``teacher_layer``
     (reference layer_reduction init via module-name remapping; with the
-    stacked layer axis it is one gather). Indices address SCAN rows —
-    superblocks of ``moe_freq`` layers when MoE is on. Returns
+    stacked layer axis it is one gather). Returns
     (student_model, student_params)."""
     import dataclasses as dc
 
     from ..models.transformer import TransformerLM
     c = model.config
-    total = c.scan_length      # the blocks axis length (≠ num_layers w/ MoE)
-    per_block = c.num_layers // total
+    total = c.num_layers
     layers = list(lr_cfg.teacher_layer)
     if not layers:
         n = lr_cfg.keep_number_layer
         if not n:
             raise ValueError("layer_reduction needs teacher_layer or "
                              "keep_number_layer")
-        if n % per_block:
-            raise ValueError(
-                f"keep_number_layer {n} must divide by layers-per-"
-                f"superblock {per_block} (MoE models reduce in superblocks)")
-        n = n // per_block if per_block > 1 else n
-        # evenly spaced, always including the last scan row
+        # evenly spaced, always including the last layer
         layers = [round(i * (total - 1) / max(n - 1, 1)) for i in range(n)]
     if any(i < 0 or i >= total for i in layers):
         raise ValueError(
-            f"teacher_layer {layers} out of scan range 0..{total - 1} "
-            f"(indices address scan rows; this model has {total} rows of "
-            f"{per_block} layer(s) each)")
+            f"teacher_layer {layers} out of range 0..{total - 1}")
     idx = jnp.asarray(layers, jnp.int32)
     new_params = dict(params)
     new_params["blocks"] = jax.tree_util.tree_map(
         lambda l: jnp.take(l, idx, axis=0), params["blocks"])
-    student_cfg = dc.replace(model.config,
-                             num_layers=len(layers) * per_block)
+    student_cfg = dc.replace(model.config, num_layers=len(layers))
     student = TransformerLM(student_cfg, constrain=model.constrain)
     return student, new_params
 
@@ -701,12 +691,11 @@ def calibrate_activation_ranges(model, params, batches) -> tuple:
         ids = jnp.asarray(np.asarray(batch["input_ids"]))
         x = calib_model._embed_tokens(params, ids)
         wins = calib_model._layer_windows()
-        for i in range(c.scan_length):
+        for i in range(c.num_layers):
             lp = jax.tree_util.tree_map(lambda l, i=i: l[i],
                                         params["blocks"])
-            x, _, _ = calib_model._superblock(
-                lp, x, None, None, None, False,
-                wins[i] if wins is not None else None)
+            x, _ = calib_model._block(
+                lp, x, window=wins[i] if wins is not None else None)
     calib = calib_model._act_calib
     del calib_model._act_calib
     return tuple(calib.get(site, 0.0)

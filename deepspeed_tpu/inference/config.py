@@ -2,7 +2,7 @@
 
 Mirrors the reference ``DeepSpeedInferenceConfig``
 (`/root/reference/deepspeed/inference/config.py`, 276 LoC): dtype,
-tensor_parallel, max_out_tokens, kernel injection, quantization and moe
+tensor_parallel, max_out_tokens, kernel injection and quantization
 blocks — minus the CUDA-graph knob (jit + donated buffers give the same
 replay-without-dispatch behavior for free) and plus TPU mesh controls.
 """
@@ -316,11 +316,6 @@ class TensorParallelConfig(ConfigModel):
     tp_size: int = 1
 
 
-class MoEInferenceConfig(ConfigModel):
-    enabled: bool = False
-    ep_size: int = 1
-
-
 class QuantConfig(ConfigModel):
     """Weight quantization for serving (reference quant block: qkv/mlp int8).
     ``bits`` 0 disables. ``quantize_embeddings`` widens the scope to the
@@ -337,7 +332,6 @@ class DeepSpeedInferenceConfig(ConfigModel):
     dtype: str = "bfloat16"              # serving dtype for weights/compute
     tensor_parallel: TensorParallelConfig = Field(
         default_factory=TensorParallelConfig)
-    moe: MoEInferenceConfig = Field(default_factory=MoEInferenceConfig)
     quant: QuantConfig = Field(default_factory=QuantConfig)
     # continuous-batching serving layer (inference/serving/,
     # docs/serving.md): paged KV pool + iteration-level scheduler

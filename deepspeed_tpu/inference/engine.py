@@ -78,15 +78,13 @@ class InferenceEngine:
 
         tp = self.config.tensor_parallel.tp_size \
             if self.config.tensor_parallel.enabled else 1
-        ep = self.config.moe.ep_size if self.config.moe.enabled else 1
         if mesh is None:
             n = len(jax.devices())
-            if n % (tp * ep):
+            if n % tp:
                 raise ValueError(
-                    f"tp_size {tp} x ep_size {ep} does not divide {n} devices")
+                    f"tp_size {tp} does not divide {n} devices")
             from ..runtime.config import MeshConfig
-            mesh = topo.build_mesh(MeshConfig(model=tp, expert=ep,
-                                              data=n // (tp * ep)))
+            mesh = topo.build_mesh(MeshConfig(model=tp, data=n // tp))
         self.mesh = mesh
 
         # -- TP layout: model-provided specs or the auto-TP heuristic ------

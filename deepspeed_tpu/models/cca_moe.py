@@ -362,8 +362,7 @@ class CCAMoELM(TransformerLM):
         counters["router_bias_abs_max"] = bias_max
         return self._norm_fn("head")(params["ln_f"], x), balance, counters
 
-    def hidden_states_and_aux(self, params, input_ids, rng=None, train=True,
-                              token_type_ids=None):
+    def hidden_states_and_aux(self, params, input_ids, token_type_ids=None):
         x, balance, _ = self._forward(params, input_ids)
         return x, balance
 

@@ -388,8 +388,7 @@ class LatentMoELM(TransformerLM):
         return y.reshape(b, t, h), counters
 
     # -- full sequences ----------------------------------------------------
-    def hidden_states_and_aux(self, params, input_ids, rng=None, train=True,
-                              token_type_ids=None):
+    def hidden_states_and_aux(self, params, input_ids, token_type_ids=None):
         """Forward up to the final norm, expanded form, plain XLA: the
         leading layers, then ``params["blocks"]``."""
         x = self._embed_tokens(params, input_ids)

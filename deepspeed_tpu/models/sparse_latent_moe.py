@@ -220,8 +220,8 @@ class SparseLatentMoELM(DenseLeadMoELM):
                               self.config.index_topk).reshape(score.shape[:2])
             return causal & (score >= kth[..., None])
 
-    def hidden_states_and_aux(self, params, input_ids, rng=None, train=True,
-                              token_type_ids=None, return_selection=False):
+    def hidden_states_and_aux(self, params, input_ids, token_type_ids=None,
+                              return_selection=False):
         """Forward up to the final norm, expanded form, plain XLA; the
         selection mask is carried from a ``full`` layer to the ``shared``
         layers after it.  ``return_selection``: also every layer's mask

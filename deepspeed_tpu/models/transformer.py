@@ -187,18 +187,18 @@ class TransformerConfig:
     # Ignored (autodiff path) for the MLM head and the vocab-sharded TP
     # head, which need the logits cotangent plumbing.
     fused_loss_head: bool = True
-    # -- MoE (reference deepspeed/moe/layer.py:15 MoE surface) --------------
-    moe_num_experts: int = 0           # 0 → dense model
-    moe_freq: int = 2                  # 1 = every layer, 2 = every other
-    moe_k: int = 1                     # top-1 or top-2 gating
-    moe_capacity_factor: float = 1.0
-    moe_eval_capacity_factor: float = 1.0
-    moe_min_capacity: int = 4
-    moe_use_residual: bool = False     # PR-MoE
-    moe_noisy_gate_policy: Optional[str] = None
-    moe_use_rts: bool = True
-    moe_aux_loss_coef: float = 0.01
-    moe_d_ff: int = 0                  # 0 → ff_dim
+
+    def __new__(cls, *args, **kw):
+        gone = sorted(k for k in kw if k.startswith("moe_")
+                      and k not in cls.__dataclass_fields__)
+        if gone:
+            raise TypeError(
+                f"{cls.__name__} has no {', '.join(gone)}: the standard "
+                f"block is x + attn + mlp and carries no expert layer. "
+                f"The expert layer is moe/dropless.py, inside a block of "
+                f"its own (models/cca_moe.py trains, models/latent_moe.py "
+                f"serves); experts sharded across chips are ROADMAP B6")
+        return super().__new__(cls)
 
     @classmethod
     def model_class(cls):
@@ -214,26 +214,9 @@ class TransformerConfig:
         return self.d_ff or 4 * self.d_model
 
     @property
-    def moe_enabled(self) -> bool:
-        return self.moe_num_experts > 0
-
-    @property
     def scan_length(self) -> int:
-        """Number of scanned superblocks (layers per superblock =
-        ``moe_freq`` when MoE is on, else 1)."""
-        if not self.moe_enabled:
-            return self.num_layers
-        if self.moe_freq not in (1, 2):
-            raise ValueError("moe_freq must be 1 or 2")
-        if self.num_layers % self.moe_freq:
-            raise ValueError(
-                f"num_layers ({self.num_layers}) must divide by moe_freq "
-                f"({self.moe_freq})")
-        return self.num_layers // self.moe_freq
-
-    @property
-    def attn_per_block(self) -> int:
-        return self.moe_freq if self.moe_enabled else 1
+        """Length of the ``params["blocks"]`` layer scan."""
+        return self.num_layers
 
     @property
     def hdim(self) -> int:
@@ -474,10 +457,6 @@ class TransformerLM:
             if bad:
                 raise ValueError(f"attention_layers entries must be "
                                  f"'global'/'local', got {sorted(bad)}")
-            if config.moe_enabled:
-                raise NotImplementedError(
-                    "attention_layers (per-layer local windows) is not "
-                    "plumbed through the MoE superblock scan")
             if config.attn_impl != "xla":
                 raise NotImplementedError(
                     f"attention_layers needs attn_impl='xla' (the Pallas "
@@ -491,21 +470,6 @@ class TransformerLM:
             self._cos, self._sin = L.rotary_freqs(
                 config.hdim, config.rotary_dim, config.max_seq_len,
                 config.rotary_base)
-        if config.moe_enabled:
-            from ..moe.layer import MoEConfig, MoELayer
-            self._moe = MoELayer(
-                config.d_model,
-                MoEConfig(num_experts=config.moe_num_experts,
-                          k=config.moe_k,
-                          capacity_factor=config.moe_capacity_factor,
-                          eval_capacity_factor=config.moe_eval_capacity_factor,
-                          min_capacity=config.moe_min_capacity,
-                          use_residual=config.moe_use_residual,
-                          noisy_gate_policy=config.moe_noisy_gate_policy,
-                          use_rts=config.moe_use_rts,
-                          aux_loss_coef=config.moe_aux_loss_coef),
-                d_ff=config.moe_d_ff or config.ff_dim,
-                depth_scale=config.num_layers)
 
     def tp_serving_view(self, model_shards: int, tp_axis: Optional[str],
                         dp_axis: Optional[str]) -> "TransformerLM":
@@ -601,23 +565,9 @@ class TransformerLM:
             blk["mlp"]["fc_out"]["bias"] = jnp.zeros((d,), dt)
         return blk
 
-    def _moe_block_init(self, k):
-        dt = self.config.param_dtype
-        ka, km = jax.random.split(k, 2)
-        blk = self._attn_block_init(ka)
-        blk["moe"] = self._moe.init(km, dt)
-        return blk
-
     def init_superblock(self, k) -> Dict:
         """One scanned layer's params (no leading stack axis)."""
-        c = self.config
-        if not c.moe_enabled:
-            return self._block_init(k)
-        if c.moe_freq == 1:
-            return {"moe_blk": self._moe_block_init(k)}
-        kd, km = jax.random.split(k, 2)
-        return {"dense": self._block_init(kd),
-                "moe_blk": self._moe_block_init(km)}
+        return self._block_init(k)
 
     def superblock_keys(self, rng) -> jax.Array:
         """Per-layer init keys; layer i of init() == init_superblock(keys[i])."""
@@ -1242,52 +1192,6 @@ class TransformerLM:
                 x = x + red(m)
         return self.constrain(x), new_cache
 
-    def _moe_block(self, bp, x, cache_kv=None, positions=None, rng=None,
-                   train=True):
-        """Attention + MoE-FFN block. Returns (x, new_cache, l_aux)."""
-        c = self.config
-        norm = self._norm_fn()
-        x = self.constrain(x)
-        a, new_cache = self._attention(bp["attn"], norm(bp["ln1"], x),
-                                       cache_kv, positions)
-        def moe(u):
-            with jax.named_scope("experts"):
-                return self._moe.apply(bp["moe"], u, rng=rng, train=train)
-        if c.parallel_residual:
-            m, laux, _ = moe(norm(bp["ln2"], x))
-            with jax.named_scope("residual"):
-                x = x + a + m
-        else:
-            with jax.named_scope("residual"):
-                x = x + a
-            m, laux, _ = moe(norm(bp["ln2"], x))
-            with jax.named_scope("residual"):
-                x = x + m
-        return self.constrain(x), new_cache, laux
-
-    def _superblock(self, sp, x, caches=None, positions=None, rng=None,
-                    train=True, window=None):
-        """One scanned unit: a dense block (moe_freq=2 only) followed by a
-        MoE block, or just a dense block when MoE is off.
-
-        ``caches`` — tuple of per-attention-layer (ck, cv, idx) or None.
-        Returns (x, new_caches tuple | None, l_aux)."""
-        c = self.config
-        if not c.moe_enabled:
-            y, nc = self._block(sp, x, caches[0] if caches else None,
-                                positions, window)
-            return y, ((nc,) if caches else None), jnp.zeros((), jnp.float32)
-        new_caches = []
-        if c.moe_freq == 2:
-            x, nc = self._block(sp["dense"], x,
-                                caches[0] if caches else None, positions)
-            new_caches.append(nc)
-        x, nc, laux = self._moe_block(
-            sp["moe_blk"], x, caches[-1] if caches else None, positions,
-            rng, train)
-        new_caches.append(nc)
-        return x, (tuple(new_caches) if caches else None), laux
-
     # (no separate _remat_block: callers wrap their scan body with _remat)
     def _remat(self, fn):
         """Wrap fn with the configured rematerialization policy —
@@ -1330,11 +1234,8 @@ class TransformerLM:
         """
         c = self.config
         if cache is None:
-            # inference semantics: eval capacity factor, no gate noise —
-            # same gating mode as the cached decode branch below
             x, _ = self.hidden_states_and_aux(
-                params, input_ids, train=False,
-                token_type_ids=token_type_ids)
+                params, input_ids, token_type_ids=token_type_ids)
             return self._project(params, x)
 
         idx = cache["index"]
@@ -1343,19 +1244,7 @@ class TransformerLM:
             positions = idx + jnp.arange(input_ids.shape[1])[None, :]
         x = self._embed_tokens(params, input_ids, positions=positions)
 
-        if c.moe_enabled:
-            # cache leaves: [scan, A, B, T, H, Dh], A = attns per superblock
-            def scan_fn(carry, xs):
-                sp, ck, cv = xs
-                sp = self.block_transform(sp)
-                caches = tuple((ck[i], cv[i], idx)
-                               for i in range(c.attn_per_block))
-                y, ncs, _ = self._superblock(sp, carry, caches, positions,
-                                             rng=None, train=False)
-                nk = jnp.stack([nc[0] for nc in ncs])
-                nv = jnp.stack([nc[1] for nc in ncs])
-                return y, (nk, nv)
-        elif c.attention_layers:
+        if c.attention_layers:
             def scan_fn(carry, xs):
                 bp, ck, cv, win = xs
                 bp = self.block_transform(bp)
@@ -1369,7 +1258,7 @@ class TransformerLM:
                 y, kv = self._block(bp, carry, (ck, cv, idx), positions)
                 return y, kv
         xs = (params["blocks"], cache["k"], cache["v"])
-        if not c.moe_enabled and c.attention_layers:
+        if c.attention_layers:
             xs = xs + (self._layer_windows(),)
         x, (nk, nv) = jax.lax.scan(scan_fn, x, xs)
         new_cache = {"k": nk, "v": nv, "index": idx + input_ids.shape[1]}
@@ -1442,52 +1331,36 @@ class TransformerLM:
                                         tiled=True)
         return logits
 
-    def hidden_states_and_aux(self, params, input_ids, rng=None, train=True,
-                              token_type_ids=None):
-        """Forward up to the final norm → ([B,T,D], moe_aux_loss scalar)."""
+    def hidden_states_and_aux(self, params, input_ids, token_type_ids=None):
+        """Forward up to the final norm → ([B,T,D], auxiliary loss: 0 for
+        the standard block; a block with experts overrides this)."""
         c = self.config
         x = self._embed_tokens(params, input_ids,
                                token_type_ids=token_type_ids)
 
-        def sb_fn(sp, x, key, window=None):
+        def layer(bp, x, window=None):
             if c.remat == "host_offload":
                 # name the per-layer residual stream so the offload remat
                 # policy can spill it to host DRAM between fwd and bwd
                 from jax.ad_checkpoint import checkpoint_name
                 x = checkpoint_name(x, "block_in")
-            sp = self.block_transform(sp)
-            y, _, la = self._superblock(sp, x, None, None, key, train,
-                                        window)
-            return y, la
-        sb = self._remat(sb_fn)
-        zero = jnp.zeros((), jnp.float32)
+            return self._block(self.block_transform(bp), x, window=window)[0]
+        layer = self._remat(layer)
 
-        if rng is not None and c.moe_enabled:
-            keys = jax.random.split(rng, c.scan_length)
-
-            def scan_fn(carry, xs):
-                sp, key = xs
-                y, la = sb(sp, carry[0], key)
-                return (y, carry[1] + la), None
-            (x, laux), _ = jax.lax.scan(scan_fn, (x, zero),
-                                        (params["blocks"], keys))
-        elif c.attention_layers:
+        if c.attention_layers:
             # per-layer window rides the scan so the block compiles once
-            def scan_fn(carry, xs):
-                sp, win = xs
-                y, la = sb(sp, carry[0], None, win)
-                return (y, carry[1] + la), None
-            (x, laux), _ = jax.lax.scan(
-                scan_fn, (x, zero),
-                (params["blocks"], self._layer_windows()))
+            def scan_fn(x, xs):
+                bp, win = xs
+                return layer(bp, x, win), None
+            xs = (params["blocks"], self._layer_windows())
         else:
-            def scan_fn(carry, sp):
-                y, la = sb(sp, carry[0], None)
-                return (y, carry[1] + la), None
-            (x, laux), _ = jax.lax.scan(scan_fn, (x, zero), params["blocks"])
-        if not c.final_layernorm:
-            return x, laux
-        return self._norm_fn("head")(params["ln_f"], x), laux
+            def scan_fn(x, bp):
+                return layer(bp, x), None
+            xs = params["blocks"]
+        x, _ = jax.lax.scan(scan_fn, x, xs)
+        if c.final_layernorm:
+            x = self._norm_fn("head")(params["ln_f"], x)
+        return x, jnp.zeros((), jnp.float32)
 
     def hidden_states(self, params, input_ids):
         """Forward up to the final norm, pre-projection ([B,T,D])."""
@@ -1499,11 +1372,6 @@ class TransformerLM:
         c = self.config
         if not c.causal:
             return "paged decode needs a causal (decoder) model"
-        if c.moe_enabled:
-            return ("paged decode does not cover the capacity-gated "
-                    "top-1 / top-2 MoE superblock (moe_num_experts > 0); "
-                    "the MoE it serves is the dropless top-k block of "
-                    "models/shortcut_moe.py (longcat_flash_config)")
         if c.attention_layers:
             return ("paged decode does not apply per-layer local windows "
                     "(GPT-Neo family)")
@@ -1725,11 +1593,7 @@ class TransformerLM:
     def init_cache(self, batch: int, max_len: int, dtype=None) -> Dict:
         c = self.config
         dtype = dtype or c.dtype
-        if c.moe_enabled:
-            shape = (c.scan_length, c.attn_per_block, batch, max_len,
-                     c.kv_heads, c.hdim)
-        else:
-            shape = (c.num_layers, batch, max_len, c.kv_heads, c.hdim)
+        shape = (c.num_layers, batch, max_len, c.kv_heads, c.hdim)
         return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype),
                 "index": jnp.array(0, jnp.int32)}
 
@@ -1752,22 +1616,10 @@ class TransformerLM:
     def loss(self, params, batch) -> jnp.ndarray:
         """Causal LM loss. batch: {'input_ids' [B,T]} (labels = shifted) or
         explicit {'input_ids', 'labels', optional 'loss_mask'}."""
-        logits_in = batch["input_ids"]
         labels, mask = self._targets(batch)
-
-        # Optional per-step gate randomness (RTS / noisy gating): pass
-        # batch["moe_rng"] = jax.random.PRNGKey(step) to engine.train_step —
-        # the engine splits it into one key per microbatch (shard_batch) and
-        # the GAS scan delivers a (2,)-shaped key here. Absent = deterministic
-        # routing.
-        moe_rng = batch.get("moe_rng")
-        aux_coef = (self.config.moe_aux_loss_coef
-                    if self.config.moe_enabled else 0.0)
-
-        x, laux = self.hidden_states_and_aux(params, logits_in, rng=moe_rng)
+        x = self.hidden_states(params, batch["input_ids"])
         with jax.named_scope("loss"):
-            return self.nll_from_hidden(params, x, labels, mask) \
-                + aux_coef * laux
+            return self.nll_from_hidden(params, x, labels, mask)
 
     def nll_from_hidden(self, params, x, labels, mask=None) -> jnp.ndarray:
         """Mean masked NLL from final hidden states ([B,T,D]) — the loss
@@ -1841,9 +1693,8 @@ class TransformerLM:
 
     # -- partitioning ------------------------------------------------------
     # TP rules keyed on the TRAILING (module, weight) path pair — depth-
-    # independent so dense blocks, MoE superblocks, and stacked expert trees
-    # all resolve. Specs are for the weight's own dims; leading stack axes
-    # (scan layer axis, expert axis) are prepended in spec_for.
+    # independent. Specs are for the weight's own dims; the leading scan
+    # layer axis is prepended in spec_for.
     _SUFFIX_RULES = {
         ("embed", "embedding"): ("model", None),
         ("pos_embed", "embedding"): (None, None),
@@ -1868,25 +1719,14 @@ class TransformerLM:
         """Params-shaped PartitionSpec tree: tensor-parallel layout over the
         ``model`` mesh axis (Megatron-style column/row split — role of the
         reference's `module_inject/replace_module.py:23` ReplaceWithTensorSlicing,
-        decided here declaratively); MoE expert stacks shard over ``expert``
-        (reference expert groups, `utils/groups.py:109`). Leading axis of
-        ``blocks`` leaves is the scan/layer axis (never sharded)."""
+        decided here declaratively). Leading axis of ``blocks`` leaves is
+        the scan/layer axis (never sharded)."""
         if params is None:
             params = jax.eval_shape(lambda: self.init(jax.random.PRNGKey(0)))
-        # MoE subtrees defer to MoELayer's own spec tree (single source of
-        # truth — pluggable experts bring their own specs); only the leading
-        # scan axis is prepended here.
-        moe_specs = (self._moe.partition_specs()
-                     if self.config.moe_enabled else None)
 
         def spec_for(path, leaf):
             keys = tuple(p.key for p in path)
             ndim = len(leaf.shape)
-            if "moe" in keys:
-                sp = moe_specs
-                for k in keys[keys.index("moe") + 1:]:
-                    sp = sp[k]
-                return P(None, *sp)            # [scan, ...moe spec...]
             if any(k.startswith("ln") for k in keys):  # norms replicate
                 inner = (None,) * (1 if keys[0] != "blocks" else ndim - 1)
             else:

@@ -4,8 +4,8 @@ The training-side tensor-parallel seam. Serving TP (inference/serving)
 runs forward-only and uses raw ``lax.psum`` on block outputs; training
 needs the *pair* of Megatron's conjugate operators so hand-driven
 ``jax.vjp`` chains (the 1F1B pipeline backward) and in-region autodiff
-(the gpipe backward) both produce exact gradients inside a manual
-region (where every shard's loss cotangent is seeded identically and a
+both produce exact gradients inside a manual region (where every
+shard's loss cotangent is seeded identically and a
 raw psum's transpose would over-count replicated compute by the shard
 count):
 

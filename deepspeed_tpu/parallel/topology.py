@@ -12,7 +12,9 @@ Axis names (canonical order, outermost → innermost):
     dcn_data — replicas across slices (DCN); collectives here are expensive
     pipe     — pipeline stages (ppermute ring)
     data     — data parallel / ZeRO sharding axis
-    expert   — MoE expert parallel (usually folded into data)
+    expert   — expert parallel: data-like in the training engine, which
+               shards the batch over it; no engine shards experts over
+               it yet (ROADMAP B6)
     sequence — context parallelism (ring attention axis)
     model    — tensor parallel; innermost so its collectives ride ICI
                neighbors
@@ -35,6 +37,13 @@ PIPE_AXIS = "pipe"
 EXPERT_AXIS = "expert"
 SEQUENCE_AXIS = "sequence"
 DCN_DATA_AXIS = "dcn_data"
+
+#: what the pipeline and the Infinity engines say to an expert axis > 1
+EXPERT_AXIS_REFUSAL = (
+    "expert mesh axis > 1: nothing here shards experts over it. The "
+    "expert layer is moe/dropless.py on one chip's share of the experts; "
+    "its exchange across chips is ROADMAP B6. Put those devices on the "
+    "data axis")
 
 
 @dataclass(frozen=True)

@@ -240,20 +240,24 @@ class PipelineConfig(ConfigModel):
         if isinstance(self.stages, int) and self.stages < 1:
             raise ValueError(
                 f"pipeline.stages must be >= 1, got {self.stages}")
-        if self.schedule not in C.PIPE_SCHEDULES:
-            raise ValueError(
-                f"pipeline.schedule must be one of {C.PIPE_SCHEDULES}, "
-                f"got {self.schedule!r}")
         return self
+
+    @model_validator(mode="before")
+    @classmethod
+    def _one_schedule(cls, data):
+        if isinstance(data, dict) and "schedule" in data:
+            raise ValueError(
+                "pipeline.schedule is not an option: the pipeline engine "
+                "compiles one schedule, 1F1B. The second one carried the "
+                "auxiliary loss of the capacity-gated expert layer, which "
+                "is gone; the expert layer is moe/dropless.py")
+        return data
     partition: str = "parameters"  # parameters | uniform | type:regex
     seed_layers: bool = False
     activation_checkpoint_interval: int = 0
     pipe_partitioned: bool = True
     grad_partitioned: bool = True
     micro_batches: Optional[int] = None
-    # compiled-schedule selection: auto = 1F1B for dense models, gpipe for
-    # MoE (whose aux-loss plumbing lives in the gpipe loss)
-    schedule: str = C.PIPE_SCHEDULE_DEFAULT   # auto | 1f1b | gpipe
 
 
 class SequenceParallelConfig(ConfigModel):

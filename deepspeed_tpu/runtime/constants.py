@@ -92,11 +92,8 @@ FP16_MIN_LOSS_SCALE_DEFAULT = 1.0
 
 # Pipeline block defaults (runtime/pipe/engine.py, docs/training_perf.md
 # "3D parallelism"): stages "auto" = the mesh pipe-axis size (an int is
-# cross-checked against it at engine build); schedule "auto" = 1F1B for
-# dense models, gpipe for MoE.
+# cross-checked against it at engine build).
 PIPE_STAGES_DEFAULT = "auto"
-PIPE_SCHEDULE_DEFAULT = "auto"
-PIPE_SCHEDULES = ("auto", "1f1b", "gpipe")
 
 # Resilience block defaults (runtime/resilience/, docs/resilience.md).
 RESILIENCE_CHECKPOINT_INTEGRITY_DEFAULT = True

@@ -620,15 +620,13 @@ class DeepSpeedEngine:
         return loss * scale, counters
 
     def _batch_spec_tree(self, batch):
-        def spec(path, x):
-            if path and getattr(path[-1], "key", None) == "moe_rng":
-                return P(*([None] * np.ndim(x)))   # rng keys replicate
+        def spec(x):
             nd = np.ndim(x)
             entries = [None] * nd
             if nd >= 2:
                 entries[1] = self._batch_dim_spec
             return P(*entries)
-        return jax.tree_util.tree_map_with_path(spec, batch)
+        return jax.tree_util.tree_map(spec, batch)
 
     def _apply_grads(self, state, grads, n_micro: float, overflow=None):
         """Unscaled summed grads → clipped update → new state.
@@ -937,24 +935,13 @@ class DeepSpeedEngine:
         nproc = jax.process_count()
         local_b = global_b // nproc if nproc > 1 else global_b
 
-        def prep(k, x):
+        def prep(x):
             # deliberate host materialization: batches normally arrive
             # as host arrays (train_step only calls shard_batch when the
             # leaves are NOT jax.Array), so this is a coercion, not a
             # device round trip — and when a caller DOES hand a device
             # leaf, the sync is the documented contract of this helper
             x = host_transfer(x)
-            if k == "moe_rng":
-                # a single PRNG key: split into one key per microbatch so
-                # gate randomness (RTS / RSample) differs across the GAS scan
-                if x.shape == (2,):
-                    x = host_transfer(jax.random.split(
-                        jnp.asarray(x, jnp.uint32), gas))
-                if x.shape != (gas, 2):
-                    raise ValueError(
-                        f"moe_rng must be a PRNG key (2,) or per-microbatch "
-                        f"keys ({gas}, 2); got {x.shape}")
-                return x.astype(np.uint32)
             if x.ndim >= 1 and x.shape[0] == local_b:
                 return x.reshape((gas, local_b // gas) + x.shape[1:])
             if x.ndim >= 2 and x.shape[0] == gas:
@@ -964,7 +951,7 @@ class DeepSpeedEngine:
                 f"process-local batch ({local_b}"
                 f"{f' = {global_b}/{nproc} procs' if nproc > 1 else ''}) "
                 f"nor [gas={gas}, ...] layout")
-        batch = {k: prep(k, v) for k, v in batch.items()}
+        batch = {k: prep(v) for k, v in batch.items()}
         shardings = to_named(self.mesh, self._batch_spec_tree(batch))
         if nproc > 1:
             # assemble global arrays from per-process shards — device_put

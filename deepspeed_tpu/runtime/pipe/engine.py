@@ -11,14 +11,13 @@ TPU-native redesign: the whole schedule is a single compiled program.
   - stages = slices of a stage-stacked param pytree, sharded over the
     ``pipe`` mesh axis (see `pipe/module.py`);
   - activation exchange = `lax.ppermute` shift-by-one inside a `lax.scan`
-    over schedule ticks (fill-drain/GPipe dataflow; the scan carry IS the
-    reference's pipe buffer);
-  - microbatch loop memory = scan residuals, bounded by the model's remat
-    policy (reference couples this to activation checkpointing the same way);
-  - tied-weight grad all-reduce = automatic: tied params enter `shard_map`
-    replicated over ``pipe``, so its transpose emits the psum
-    (reference's _exec_reduce_tied_grads);
-  - 3D composition (dense models): the region is manual over the FULL
+    over schedule ticks (the scan carry IS the reference's pipe buffer);
+  - microbatch loop memory = a ring of S+1 stored stage inputs, whatever
+    the microbatch count (the point of 1F1B);
+  - tied-weight grad all-reduce = one psum over ``pipe`` of the embed and
+    head gradients at the region's exit (reference's
+    _exec_reduce_tied_grads);
+  - 3D composition: the region is manual over the FULL
     ``(pipe, model, data)`` product. Each stage's forward/backward is a
     tensor-parallel program over ``model`` (per-shard head counts via
     ``tp_train_view``, exact gradients via the ``copy_to``/``reduce_from``
@@ -30,11 +29,9 @@ TPU-native redesign: the whole schedule is a single compiled program.
     ``psum_scatter`` straight into the policy's grad layout
     (`zero/sharding.grad_reduce_plan`) — on ``data``. Three collective
     families, three axes, zero contention.
-  - MoE models keep the previous region (manual over ``pipe`` only,
-    gpipe schedule) byte-for-byte — their expert/data axes stay auto.
 
 Bubble math matches TrainSchedule: M microbatches over S stages run
-M + S - 1 ticks (forward); backward retraces the same ticks in reverse.
+2 (M + S - 1) combined forward/backward ticks.
 ``measure_bubble_fraction`` turns that from arithmetic into a measured
 gauge (``dstpu_train_bubble_frac``) via a two-point slope fit.
 """
@@ -59,7 +56,7 @@ from ..zero.sharding import constrain, grad_reduce_plan
 
 def chunked_ce(proj, norm_fn, ln_params, y, tok, chunk, onehot,
                tp_axis=None):
-    """Shared head loss of BOTH pipeline schedules: final norm + chunked
+    """Head loss of the pipeline schedule: final norm + chunked
     cross-entropy over `chunk`-token slices (the [mb, chunk, V] logits
     block is the only live vocab tensor). Returns (sum_nll, token_count).
 
@@ -128,7 +125,7 @@ class PipelinedLM:
 
     def __init__(self, model, num_stages: int):
         cfg = model.config
-        n_scan = getattr(cfg, "scan_length", cfg.num_layers)
+        n_scan = cfg.scan_length
         if n_scan % num_stages != 0:
             raise ValueError(
                 f"scanned blocks ({n_scan}) must divide evenly into "
@@ -176,14 +173,6 @@ class PipelinedLM:
                     is_leaf=lambda x: isinstance(x, P))
         return specs
 
-    def pipe_specs(self):
-        """shard_map in_specs over the manual ``pipe`` axis only."""
-        shapes = jax.eval_shape(lambda: self.init(jax.random.PRNGKey(0)))
-        specs = jax.tree_util.tree_map(lambda x: P(), shapes)
-        specs["blocks"] = jax.tree_util.tree_map(
-            lambda x: P("pipe"), shapes["blocks"])
-        return specs
-
     # engine-protocol loss (single-stage fallback / eval)
     def loss(self, params, batch):
         return self.model.loss(self.unstack(params), batch)
@@ -195,17 +184,13 @@ class PipelineEngine(DeepSpeedEngine):
     ``gradient_accumulation_steps`` is the microbatch count M (same meaning
     as the reference's engine: train_batch = micro * M * dp).
 
-    Two compiled schedules:
-      - ``1f1b`` (default, dense models): the reference TrainSchedule
-        (`schedule.py:182`) as ONE scan over 2(M+S-1) combined ticks —
-        forward at tick 2m+s, backward at tick 2m+2S-1-s (closed forms of
-        the even/odd instruction math, pinned by a validation test).
-        Backward is hand-orchestrated jax.vjp per stage from a ring buffer
-        of ≤ S+1 stored stage inputs, so activation memory is bounded by
-        the in-flight microbatch count — the point of 1F1B — instead of
-        the full schedule length.
-      - ``gpipe``: fill-drain forward scan with autodiff backward (kept
-        for MoE models, whose aux-loss plumbing lives there).
+    One compiled schedule, 1F1B: the reference TrainSchedule
+    (`schedule.py:182`) as ONE scan over 2(M+S-1) combined ticks —
+    forward at tick 2m+s, backward at tick 2m+2S-1-s (closed forms of
+    the even/odd instruction math, pinned by a validation test).
+    Backward is hand-orchestrated jax.vjp per stage from a ring buffer
+    of ≤ S+1 stored stage inputs, so activation memory is bounded by
+    the in-flight microbatch count instead of the full schedule length.
     """
 
     def __init__(self, model, config=None, mesh=None, **kw):
@@ -221,20 +206,6 @@ class PipelineEngine(DeepSpeedEngine):
             model, self.num_stages)
         adapter.use_onehot_embed = topo.mp_world_size(mesh) > 1
         self.adapter = adapter
-        self.schedule = config.pipeline.schedule
-        if self.schedule == "auto":
-            # MoE aux-loss plumbing lives in the gpipe loss; dense → 1F1B
-            self.schedule = ("gpipe" if getattr(adapter.config,
-                                                "moe_enabled", False)
-                             else "1f1b")
-        if self.schedule not in ("1f1b", "gpipe"):
-            raise ValueError(f"pipeline.schedule must be auto|1f1b|gpipe, "
-                             f"got {self.schedule}")
-        if self.schedule == "1f1b" and getattr(adapter.config,
-                                               "moe_enabled", False):
-            raise NotImplementedError(
-                "1f1b schedule does not carry the MoE aux loss yet; use "
-                "pipeline.schedule=gpipe for MoE models")
         mcfg = adapter.config
         if getattr(mcfg, "attn_impl", None) in ("ring", "ulysses"):
             raise NotImplementedError(
@@ -242,12 +213,6 @@ class PipelineEngine(DeepSpeedEngine):
                 "compiled pipeline loop would nest manual collectives over "
                 "pipe+sequence — not supported yet; use sequence "
                 "parallelism without PP")
-        if getattr(mcfg, "moe_enabled", False) and \
-                mcfg.moe_noisy_gate_policy == "RSample":
-            raise NotImplementedError(
-                "RSample noisy gating has no rng path in the compiled "
-                "pipeline loop yet; use deterministic gating under "
-                "PipelineEngine")
         ps = config.pipeline.stages
         if ps != "auto" and int(ps) != self.num_stages:
             raise ValueError(
@@ -270,15 +235,11 @@ class PipelineEngine(DeepSpeedEngine):
             # re-derives from train_batch_size when that is pinned)
             config._user_batch_triple = (
                 tb, None if tb is not None else mb, pmb)
-        # -- 3D region setup (dense models) ----------------------------
+        # -- 3D region setup -------------------------------------------
         self._mp = topo.mp_world_size(mesh)
-        dense = not getattr(mcfg, "moe_enabled", False)
-        if dense and dict(mesh.shape).get(topo.EXPERT_AXIS, 1) > 1:
-            raise NotImplementedError(
-                "expert mesh axis > 1 under a dense pipeline model: the "
-                "3D region reduces gradients over (dcn_data, data) only — "
-                "drop the expert axis or use an MoE model")
-        if dense and self._mp > 1:
+        if dict(mesh.shape).get(topo.EXPERT_AXIS, 1) > 1:
+            raise NotImplementedError(topo.EXPERT_AXIS_REFUSAL)
+        if self._mp > 1:
             if mcfg.vocab_size % self._mp:
                 raise ValueError(
                     f"model mesh axis ({self._mp}) must divide vocab_size "
@@ -310,7 +271,7 @@ class PipelineEngine(DeepSpeedEngine):
     # -- 3D region plumbing ------------------------------------------------
     def _data_axes(self):
         """Size>1 data-parallel mesh axes, in mesh order (the ``data``
-        leg of the 3D product; expert is guarded off for dense models)."""
+        leg of the 3D product; an expert axis is refused at build)."""
         ms = dict(self.mesh.shape)
         return tuple(a for a in (topo.DCN_DATA_AXIS, topo.DATA_AXIS)
                      if ms.get(a, 1) > 1)
@@ -423,201 +384,12 @@ class PipelineEngine(DeepSpeedEngine):
                 grads, plan_sub)
         return grads
 
-    # -- the pipeline loss program (runs inside shard_map over 'pipe') -----
-    def _pipeline_loss(self, params, ids):
-        """ids: [M, mb, T] (replicated over pipe; 'data' handled by GSPMD).
-        Returns global mean token loss."""
-        cfg = self.adapter.config
-        model = self.adapter.model
-        s = self.num_stages
-        sid = jax.lax.axis_index(topo.PIPE_AXIS)
-        m = ids.shape[0]
-        mb, t = ids.shape[1], ids.shape[2]
-        blocks_local = jax.tree_util.tree_map(lambda x: x[0],
-                                              params["blocks"])
-        norm = (L.layernorm_apply if cfg.norm_type == "layernorm"
-                else L.rmsnorm_apply)
-
-        onehot = getattr(self.adapter, "use_onehot_embed", False)
-
-        def embed_fn(tok):
-            embed = (L.embedding_apply_onehot if onehot
-                     else L.embedding_apply)
-            x = embed(params["embed"], tok, cfg.dtype)
-            if cfg.pos_embedding == "learned":
-                pos = jnp.arange(t)[None, :]
-                x = x + L.embedding_apply(params["pos_embed"], pos, cfg.dtype)
-            return x
-
-        chunk = cfg.loss_chunk if (cfg.loss_chunk and
-                                   t % max(cfg.loss_chunk, 1) == 0 and
-                                   t > cfg.loss_chunk) else t
-
-        def head_loss(y, tok):
-            return chunked_ce(lambda xc: model._project(params, xc),
-                              partial(norm, eps=cfg.layernorm_eps),
-                              params["ln_f"], y, tok, chunk, onehot)
-
-        def sb_fn(sp, x, win=None):
-            y, _, la = model._superblock(sp, x, None, None, None, True, win)
-            return y, la
-        sb = model._remat(sb_fn)
-        # per-layer attention windows (GPT-Neo family): this stage's slice
-        # of the window vector rides the stage scan like the params do;
-        # None (the common case) keeps the scan structure window-free
-        win_local = self._stage_windows(model, sid)
-        xs_local = (blocks_local if win_local is None
-                    else (blocks_local, win_local))
-
-        def stage_fn(x):
-            def f(c, xs):
-                sp, win = (xs, None) if win_local is None else xs
-                y, la = sb(sp, c[0], win)
-                return (y, c[1] + la), None
-            (y, laux), _ = jax.lax.scan(
-                f, (x, jnp.zeros((), jnp.float32)), xs_local)
-            return y, laux
-
-        perm = [(i, (i + 1) % s) for i in range(s)]
-
-        def tick(carry, tt):
-            state, lsum, cnt, lauxsum = carry
-            recv = jax.lax.ppermute(state, topo.PIPE_AXIS, perm)
-            tok_in = ids[jnp.clip(tt, 0, m - 1)]
-            x = jnp.where(sid == 0, embed_fn(tok_in), recv)
-            y, laux = stage_fn(x)
-            # this stage holds a real microbatch only for ticks in
-            # [sid, sid + m); outside that window its input is pipeline
-            # bubble garbage and the aux loss must not count
-            valid_data = jnp.logical_and(tt >= sid, tt < sid + m).astype(
-                jnp.float32)
-            lauxsum = lauxsum + laux * valid_data
-            tok_out = ids[jnp.clip(tt - (s - 1), 0, m - 1)]
-            # Only the last stage at ticks >= S-1 holds a real microbatch
-            # output; every other (stage, tick) skips the vocab projection
-            # entirely (cond, not select — the head is the single most
-            # expensive op in the loop). Safe under manual 'pipe': the
-            # predicate is uniform within a stage, so 'model'-axis (auto)
-            # collectives inside the branch stay consistent per stage.
-            valid = jnp.logical_and(sid == s - 1, tt >= s - 1)
-            ls, ct = jax.lax.cond(
-                valid, lambda: head_loss(y, tok_out),
-                lambda: (jnp.zeros((), jnp.float32),
-                         jnp.zeros((), jnp.float32)))
-            return (y, lsum + ls, cnt + ct, lauxsum), None
-
-        state0 = jnp.zeros((mb, t, cfg.d_model), cfg.dtype)
-        zero = jnp.zeros((), jnp.float32)
-        (_, lsum, cnt, lauxsum), _ = jax.lax.scan(
-            tick, (state0, zero, zero, zero), jnp.arange(m + s - 1))
-        lsum = jax.lax.psum(lsum, topo.PIPE_AXIS)
-        cnt = jax.lax.psum(cnt, topo.PIPE_AXIS)
-        loss = lsum / jnp.maximum(cnt, 1.0)
-        if getattr(cfg, "moe_enabled", False):
-            # per-stage aux summed over stages, averaged over microbatches —
-            # same normalization as the DP path (one laux per micro, meaned)
-            laux = jax.lax.psum(lauxsum, topo.PIPE_AXIS) / m
-            loss = loss + cfg.moe_aux_loss_coef * laux
-        return loss
-
-    def _pipeline_loss_3d(self, params, ids):
-        """Dense gpipe loss, manual over the ``(pipe, model, data)``
-        product. ids [M, mb_local, T] — microbatch dim sharded over the
-        data product; params are the region-local views of
-        `_region_param_specs` (TP-sharded kernels, replicated qkv).
-
-        Returns the GLOBAL mean token loss, identical on every shard:
-        the loss-sum / token-count pair reduces via ``reduce_from`` over
-        ``(pipe,) + data`` so in-region autodiff sees an identity
-        backward — each shard's grads come out as its exact partial
-        contribution, and `_grad_exit_reduce` assembles them with one
-        collective per axis family. (The raw-psum transpose would
-        over-count by the shard count — masked by AdamW's scale
-        invariance in the MoE path, exposed by SGD.)"""
-        cfg = self.adapter.config
-        model = self._tview
-        tp = self._mp > 1
-        s = self.num_stages
-        sid = jax.lax.axis_index(topo.PIPE_AXIS)
-        m, mb, t = ids.shape
-        blocks_local = jax.tree_util.tree_map(lambda x: x[0],
-                                              params["blocks"])
-        norm = (L.layernorm_apply if cfg.norm_type == "layernorm"
-                else L.rmsnorm_apply)
-        tied = "lm_head" not in params
-
-        embed_raw = self._tp_embed_fn(cfg, t)
-        embed_fn = lambda tok: embed_raw(params, tok)    # noqa: E731
-        localize = self._tp_localize_fn(self._qkv_cols() if tp else None)
-
-        chunk = cfg.loss_chunk if (cfg.loss_chunk and
-                                   t % max(cfg.loss_chunk, 1) == 0 and
-                                   t > cfg.loss_chunk) else t
-
-        def head_loss(y, tok):
-            if tp:
-                def proj(xc):
-                    if tied:
-                        return L.embedding_attend(params["embed"], xc)
-                    return jnp.einsum(
-                        "...d,dv->...v", xc,
-                        params["lm_head"]["kernel"].astype(xc.dtype),
-                        preferred_element_type=jnp.float32)
-            else:
-                def proj(xc):
-                    return model._project(params, xc)
-            return chunked_ce(proj, partial(norm, eps=cfg.layernorm_eps),
-                              params["ln_f"], y, tok, chunk, False,
-                              tp_axis=topo.MODEL_AXIS if tp else None)
-
-        def sb_fn(sp, x, win=None):
-            y, _, _ = model._superblock(localize(sp), x, None, None, None,
-                                        True, win)
-            return y
-        sb = model._remat(sb_fn)
-        win_local = self._stage_windows(model, sid)
-        xs_local = (blocks_local if win_local is None
-                    else (blocks_local, win_local))
-
-        def stage_fn(x):
-            def f(c, xs):
-                sp, win = (xs, None) if win_local is None else xs
-                return sb(sp, c, win), None
-            y, _ = jax.lax.scan(f, x, xs_local)
-            return y
-
-        perm = [(i, (i + 1) % s) for i in range(s)]
-
-        def tick(carry, tt):
-            state, lsum, cnt = carry
-            recv = jax.lax.ppermute(state, topo.PIPE_AXIS, perm)
-            tok_in = ids[jnp.clip(tt, 0, m - 1)]
-            x = jnp.where(sid == 0, embed_fn(tok_in), recv)
-            y = stage_fn(x)
-            tok_out = ids[jnp.clip(tt - (s - 1), 0, m - 1)]
-            # head only where it's real work (see _pipeline_loss); the
-            # predicate depends on the pipe index alone, so the model-
-            # axis collectives inside the branch stay uniform per stage
-            valid = jnp.logical_and(sid == s - 1, tt >= s - 1)
-            ls, ct = jax.lax.cond(
-                valid, lambda: head_loss(y, tok_out),
-                lambda: (jnp.zeros((), jnp.float32),
-                         jnp.zeros((), jnp.float32)))
-            return (y, lsum + ls, cnt + ct), None
-
-        state0 = jnp.zeros((mb, t, cfg.d_model), cfg.dtype)
-        zero = jnp.zeros((), jnp.float32)
-        (_, lsum, cnt), _ = jax.lax.scan(
-            tick, (state0, zero, zero), jnp.arange(m + s - 1))
-        red = C.reduce_from((topo.PIPE_AXIS,) + self._data_axes())
-        return red(lsum) / jnp.maximum(red(cnt), 1.0)
-
     # ------------------------------------------------------------------
     # 1F1B: one compiled scan over combined fwd/bwd ticks
     # ------------------------------------------------------------------
     def _pipeline_value_and_grad(self, params, ids, scale):
-        """Manual over the full ``(pipe, model, data)`` product (dense
-        models — 1F1B rejects MoE at init). ids [M, mb_local, T] with
+        """Manual over the full ``(pipe, model, data)`` product. ids
+        [M, mb_local, T] with
         the microbatch dim sharded over the data product; params in
         compute dtype, per the region specs (`_region_param_specs`).
         Returns (loss summed over microbatches AND data shards, grads
@@ -673,8 +445,7 @@ class PipelineEngine(DeepSpeedEngine):
                                    t > cfg.loss_chunk) else t
 
         def head_fn(hp, y, tok):
-            """Per-microbatch MEAN CE via the shared chunked_ce head (the
-            gpipe path consumes the same helper as (sum, count)). Under
+            """Per-microbatch MEAN CE via the chunked_ce head. Under
             TP the projection is shard-local ([.., V/mp] logits) and
             chunked_ce runs Megatron's vocab-parallel CE over ``model``."""
             def proj(xc):
@@ -797,52 +568,16 @@ class PipelineEngine(DeepSpeedEngine):
     def _build_train_step(self):
         # the schedule itself runs inside ONE jitted program (per-tick
         # stage work is the device profiler's domain); the host-side span
-        # marks which schedule was compiled, for how many stages/micros
-        with trace_span("pipe/build_schedule", schedule=self.schedule,
-                        stages=self.num_stages,
+        # marks for how many stages/micros it was compiled
+        with trace_span("pipe/build_schedule", stages=self.num_stages,
                         micro_batches=self.micro_batches):
             return self._build_train_step_traced()
 
-    def _pipeline_gpipe_value_and_grad(self, params, ids, scale):
-        """Autodiff runs INSIDE the region, mirroring 1F1B's structure
-        — grads are taken per stage
-        and the cross-stage contributions of the replicated leaves
-        (embed/head/ln_f) psummed here, while block grads stay
-        pipe-local like the params themselves. Dense models run the 3D
-        loss (`_pipeline_loss_3d`, manual over pipe x model x data, exit
-        reductions via `_grad_exit_reduce`); MoE keeps the pipe-only
-        region and loss unchanged.
-        fp16: loss is scaled BEFORE autodiff so small grads survive the
-        half-precision backward (reference FP16_Optimizer.backward,
-        fp16/fused_optimizer.py); the caller divides the loss back out.
-        """
-        moe = getattr(self.adapter.config, "moe_enabled", False)
-
-        def loss_fn(p):
-            inner = (self._pipeline_loss if moe else self._pipeline_loss_3d)
-            return inner(self._cast_for_compute(p), ids) * scale
-        loss, grads = jax.value_and_grad(loss_fn)(params)
-        psum = partial(jax.lax.psum, axis_name=topo.PIPE_AXIS)
-        grads = {k: (v if k == "blocks"
-                     else jax.tree_util.tree_map(psum, v))
-                 for k, v in grads.items()}
-        if not moe:
-            grads = self._grad_exit_reduce(grads)
-        return loss, grads
-
     def _build_loss_grad_region(self):
         """The shard_map'd ``(params, ids, scale) -> (loss, grads)``
-        program — shared by the train-step builders and the bubble
-        probe. Dense models get the 3D region (manual over pipe, model
-        and the data product, ZeRO grad plan precomputed); MoE keeps the
-        pipe-only manual region with every other axis left auto."""
-        if getattr(self.adapter.config, "moe_enabled", False):
-            pipe_specs = self.adapter.pipe_specs()
-            return shard_map(
-                self._pipeline_gpipe_value_and_grad, mesh=self.mesh,
-                in_specs=(pipe_specs, P(), P()),
-                out_specs=(P(), pipe_specs),
-                axis_names={topo.PIPE_AXIS})
+        program — shared by the train-step builder and the bubble
+        probe: the 3D region (manual over pipe, model and the data
+        product, ZeRO grad plan precomputed)."""
         daxes = self._data_axes()
         region_specs = self._region_param_specs()
         self._plan, gout = grad_reduce_plan(region_specs, self.grad_specs,
@@ -852,48 +587,31 @@ class PipelineEngine(DeepSpeedEngine):
         # the region's param specs name `model` whatever its size, and a
         # spec may only name manual axes
         names = {topo.PIPE_AXIS, topo.MODEL_AXIS} | set(daxes)
-        if self.schedule == "1f1b":
-            fn = self._pipeline_value_and_grad
-            # 1F1B assembles exactly the head/embed/blocks grads; subset
-            # the out-spec tree to match (tied embeds have no lm_head key)
-            gout = {k: gout[k] for k in
-                    ("blocks", "ln_f", "embed", "lm_head", "pos_embed")
-                    if k in gout}
-        else:
-            fn = self._pipeline_gpipe_value_and_grad
+        # 1F1B assembles exactly the head/embed/blocks grads; subset
+        # the out-spec tree to match (tied embeds have no lm_head key)
+        gout = {k: gout[k] for k in
+                ("blocks", "ln_f", "embed", "lm_head", "pos_embed")
+                if k in gout}
         return shard_map(
-            fn, mesh=self.mesh,
+            self._pipeline_value_and_grad, mesh=self.mesh,
             in_specs=(region_specs, ids_spec, P()),
             out_specs=(P(), gout),
             axis_names=names)
 
     def _build_train_step_traced(self):
         sharded = self._build_loss_grad_region()
-        if self.schedule == "1f1b":
-            # grads and per-micro mean losses are SUMS over microbatches
-            # and data shards — normalize by both
-            n_eff = float(self.micro_batches * self._dp_prod())
+        # grads and per-micro mean losses are SUMS over microbatches
+        # and data shards — normalize by both
+        n_eff = float(self.micro_batches * self._dp_prod())
 
-            def step_fn(state, batch):
-                ids = batch["input_ids"]        # [M, micro*dp, T]
-                scale = self._current_scale(state)
-                loss_sum, grads = sharded(
-                    self._cast_for_compute(state["params"]), ids, scale)
-                new_state, metrics = self._apply_grads(state, grads, n_eff)
-                metrics["loss"] = loss_sum / n_eff
-                return new_state, metrics
-        else:
-            # gpipe: the loss is already the global mean (normalized
-            # inside the region), so only the fp16 scale divides out
-            def step_fn(state, batch):
-                ids = batch["input_ids"]        # [M, micro*dp, T]
-                scale = self._current_scale(state)
-                loss, grads = sharded(state["params"], ids, scale)
-                grads = jax.tree_util.tree_map(
-                    lambda g: g.astype(jnp.float32), grads)
-                new_state, metrics = self._apply_grads(state, grads, 1.0)
-                metrics["loss"] = loss / scale
-                return new_state, metrics
+        def step_fn(state, batch):
+            ids = batch["input_ids"]        # [M, micro*dp, T]
+            scale = self._current_scale(state)
+            loss_sum, grads = sharded(
+                self._cast_for_compute(state["params"]), ids, scale)
+            new_state, metrics = self._apply_grads(state, grads, n_eff)
+            metrics["loss"] = loss_sum / n_eff
+            return new_state, metrics
 
         with self.mesh:
             self._train_step_fn = jax.jit(step_fn, donate_argnums=(0,))
@@ -914,10 +632,10 @@ class PipelineEngine(DeepSpeedEngine):
 
             bubble = (t(M) - M * slope) / t(M)
 
-        1F1B's ticks cond-skip the bubble slots' compute, so its
-        intercept is small; gpipe's fill-drain loop runs every stage on
-        every tick, so its measured fraction lands near the analytic
-        (S-1)/(M+S-1). Records the ``dstpu_train_bubble_frac`` gauge and
+        1F1B's ticks cond-skip the bubble slots' compute, so the
+        intercept is small, well under the analytic (S-1)/(M+S-1) of a
+        loop that runs every stage on every tick. Records the
+        ``dstpu_train_bubble_frac`` gauge and
         returns the fit. Device-syncing — a profiling call, not a train
         step."""
         m_full = self.micro_batches
@@ -934,8 +652,8 @@ class PipelineEngine(DeepSpeedEngine):
         cfg = self.adapter.config
         t_len = int(seq_len or cfg.max_seq_len)
         mb_global = self.train_batch_size // self.micro_batches
-        with trace_span("pipe/bubble_probe", schedule=self.schedule,
-                        stages=self.num_stages, m_small=m_small,
+        with trace_span("pipe/bubble_probe", stages=self.num_stages,
+                        m_small=m_small,
                         m_full=m_full):
             region = self._build_loss_grad_region()
             with self.mesh:
@@ -959,7 +677,6 @@ class PipelineEngine(DeepSpeedEngine):
                 frac = (times[m_full] - m_full * slope) / times[m_full]
             frac = min(1.0, max(0.0, frac))
         self._ovl.record_bubble(frac)
-        return {"bubble_frac": frac, "schedule": self.schedule,
-                "stages": self.num_stages,
+        return {"bubble_frac": frac, "stages": self.num_stages,
                 "micro_counts": (m_small, m_full),
                 "step_time_s": times[m_full], "per_micro_s": max(slope, 0.0)}

@@ -60,7 +60,7 @@ vector per process).
 
 Restrictions (all raised loudly): data-parallel-only meshes (model/pipe/
 sequence/expert axes must be 1 under offload), bf16 compute (no fp16
-loss scaling), dense blocks (no MoE), Adam/AdamW.
+loss scaling), the standard block, Adam/AdamW.
 """
 from __future__ import annotations
 
@@ -349,7 +349,7 @@ class InfinityStepper:
                 "block_fwd, which cannot carry the per-layer attention "
                 "windows of attention_layers (GPT-Neo family); train this "
                 "model with the in-HBM engine, or drop attention_layers")
-        for attr in ("init_superblock", "init_resident", "_superblock"):
+        for attr in ("init_superblock", "init_resident", "_block"):
             if not hasattr(model, attr):
                 raise NotImplementedError(
                     "ZeRO-Infinity needs a scan-layer model exposing "
@@ -362,21 +362,14 @@ class InfinityStepper:
                 raise NotImplementedError(
                     f"ZeRO-Infinity composes with data-like sharding "
                     f"only; mesh axis '{axis}' has size "
-                    f"{mesh.shape[axis]} — use a data/expert mesh under "
+                    f"{mesh.shape[axis]} — use a data mesh under "
                     f"offload_param, or drop offload for tp/pp/sp")
-        if mesh.shape.get(topo.EXPERT_AXIS, 1) > 1 and \
-                not getattr(model.config, "moe_enabled", False):
-            raise NotImplementedError(
-                "expert mesh axis under offload needs an MoE model (the "
-                "expert axis is data-like only for MoE's all_to_all)")
+        if mesh.shape.get(topo.EXPERT_AXIS, 1) > 1:
+            raise NotImplementedError(topo.EXPERT_AXIS_REFUSAL)
         if engine.fp16_enabled:
             raise NotImplementedError(
                 "ZeRO-Infinity requires bf16 (fp16 loss scaling is not "
                 "wired into the streamed step); set bf16.enabled")
-        # MoE composes: expert params stream inside the superblock's flat
-        # vector like dense params (the reference trains MoE under
-        # ZeRO-Offload the same way); only the expert-parallel MESH axis
-        # is rejected above (dp-only composition).
         oc = cfg.optimizer
         name = (oc.type if oc is not None else "adamw").lower()
         if name not in ("adam", "adamw", "fusedadam", "cpuadam",
@@ -708,17 +701,8 @@ class InfinityStepper:
                 cast_res(res), ids,
                 token_type_ids=(tt if c.token_type_vocab else None))
 
-        # MoE: the load-balance aux loss contributes aux_coef * Σ l_aux
-        # to the training loss; its gradient rides the SAME per-layer vjp
-        # (cotangent aux_coef on the l_aux output) so gating weights
-        # train correctly under streaming
-        aux_coef = (float(getattr(c, "moe_aux_loss_coef", 0.0))
-                    if getattr(c, "moe_enabled", False) else 0.0)
-
         def flat_fwd(flat, x):
-            lp = self._unflatten(flat)
-            y, _, laux = model._superblock(lp, x, None, None, None, True)
-            return y, jnp.asarray(laux, jnp.float32)
+            return model._block(self._unflatten(flat), x)[0]
 
         pb = self.param_bits
 
@@ -736,9 +720,7 @@ class InfinityStepper:
 
             def block_vjp(payload, scales, x, dy):
                 flat = wire_codec.decode_params(payload, scales, pb)
-                (y, laux), vjp = jax.vjp(flat_fwd, flat, x)
-                del y, laux
-                dflat, dx = vjp((dy, jnp.asarray(aux_coef, jnp.float32)))
+                dflat, dx = jax.vjp(flat_fwd, flat, x)[1](dy)
                 sq = jnp.sum(jnp.square(dflat.astype(jnp.float32)))
                 return dflat, dx, sq
         else:
@@ -797,9 +779,7 @@ class InfinityStepper:
 
         if not pb:
             def block_vjp(flat, x, dy):
-                (y, laux), vjp = jax.vjp(flat_fwd, flat, x)
-                del y, laux
-                dflat, dx = vjp((dy, jnp.asarray(aux_coef, jnp.float32)))
+                dflat, dx = jax.vjp(flat_fwd, flat, x)[1](dy)
                 sq = jnp.sum(jnp.square(dflat.astype(jnp.float32)))
                 return dflat, dx, sq
 
@@ -822,8 +802,8 @@ class InfinityStepper:
             progs = dict(
                 embed_fwd=jax.jit(embed_fwd,
                                   out_shardings=self._batch_shard),
-                block_fwd=jax.jit(block_fwd, out_shardings=(
-                    self._batch_shard, self._repl)),
+                block_fwd=jax.jit(block_fwd,
+                                  out_shardings=self._batch_shard),
                 head_vjp=jax.jit(head_vjp, out_shardings=(
                     self._repl, self._repl, self._batch_shard)),
                 block_vjp=jax.jit(block_vjp, out_shardings=(
@@ -874,12 +854,10 @@ class InfinityStepper:
                 reshape_like(tt) if tt is not None else None)
 
     def _forward_stream(self, progs, ids_dev, tt_dev, stash: bool = True):
-        """Streamed forward → (activation stash | None, final hidden,
-        Σ moe aux loss)."""
+        """Streamed forward → (activation stash | None, final hidden)."""
         L = self.L
         x = progs["embed_fwd"](self.resident, ids_dev, tt_dev)
         acts: List[Any] = [None] * L if stash else None
-        aux = jnp.zeros((), jnp.float32)
         self._ensure_layer(0, {0})
         self._prefetch_encode(1)
         for i in range(L):
@@ -888,9 +866,8 @@ class InfinityStepper:
             self._prefetch_encode(i + 2)
             if stash:
                 acts[i] = x
-            x, la = progs["block_fwd"](*self._dev[i], x)
-            aux = aux + la
-        return acts, x, aux
+            x = progs["block_fwd"](*self._dev[i], x)
+        return acts, x
 
     def _tt_dev(self, tt, ids):
         """Token-type ids on device. Models without a type vocab get a
@@ -922,11 +899,9 @@ class InfinityStepper:
                     if mask is not None
                     else jnp.zeros((1, 1), jnp.float32))
         tt_dev = self._tt_dev(tt, ids)
-        acts, xL, aux = self._forward_stream(progs, ids_dev, tt_dev)
+        acts, xL = self._forward_stream(progs, ids_dev, tt_dev)
         loss, d_res_head, dy = progs["head_vjp"](
             self.resident, xL, ids_dev, labels_dev, mask_dev)
-        if getattr(self.model.config, "moe_enabled", False):
-            loss = loss + self.model.config.moe_aux_loss_coef * aux
         sqs = []
         for i in reversed(range(self.L)):
             if i - 1 >= 0:
@@ -1219,8 +1194,7 @@ class InfinityStepper:
         ids_dev = jax.device_put(ids, self._batch_shard)
         zero_i = jnp.zeros((1, 1), jnp.int32)
         tt_dev = self._tt_dev(batch.get("token_type_ids"), ids)
-        _, xL, aux = self._forward_stream(progs, ids_dev, tt_dev,
-                                          stash=False)
+        _, xL = self._forward_stream(progs, ids_dev, tt_dev, stash=False)
         out = float(host_transfer(progs["eval_loss"](
             self.resident, xL, ids_dev,
             # dstpu: ignore[SYNC003] -- host batch data
@@ -1230,8 +1204,6 @@ class InfinityStepper:
             jax.device_put(np.asarray(mask, np.float32), self._batch_shard)
             if mask is not None
             else jnp.zeros((1, 1), jnp.float32))))
-        if getattr(self.model.config, "moe_enabled", False):
-            out += float(self.model.config.moe_aux_loss_coef * aux)
         self._sweep_uploads(block=True)
         return out
 

@@ -237,7 +237,7 @@ fi
 # pipe x model x data grid bookkeeping, joint (pp, tp, dp) search-space
 # pruning by per-chip state bytes, the (2,2,2) multi-hundred-M e2e
 # train with single-device loss parity, bit-exact checkpoint round-trip
-# across the 3D mesh, the measured 1F1B-vs-gpipe bubble at (4,2,1),
+# across the 3D mesh, the measured 1F1B bubble at (4,2,1),
 # and the autotune winner -> DeepSpeedConfig -> ds.initialize
 # round-trip (pytest.ini `parallel3d` marker; docs/training_perf.md
 # "3D parallelism"). The chaos-marked 3D train-step case then replays

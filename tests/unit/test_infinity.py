@@ -621,33 +621,6 @@ class TestInfinityEngine:
         m = inf.train_step({"input_ids": ids, "token_type_ids": tt})
         assert np.isfinite(m["loss"])
 
-    @pytest.mark.parametrize("k", [1, 2])
-    @pytest.mark.slow
-    def test_moe_composition_matches_base(self, k):
-        """MoE x Infinity (VERDICT r3 missing #5): expert params stream
-        inside the superblock flat vector; the load-balance aux loss and
-        its GATING GRADIENT ride the per-layer vjp. Parity vs the in-HBM
-        engine + convergence through the streamed experts."""
-        over = dict(moe_num_experts=4, moe_freq=2, moe_k=k,
-                    moe_use_rts=False, num_layers=4)
-        mk = lambda: TransformerLM(TransformerConfig(**{**TINY, **over}))
-        rng = jax.random.PRNGKey(0)
-        ids = ids_batch()
-        base = DeepSpeedEngine(mk(), config=engine_cfg(), rng=rng,
-                               mesh=single_mesh())
-        inf = DeepSpeedEngine(mk(), config=engine_cfg(zero=infinity_zero()),
-                              rng=rng, mesh=single_mesh())
-        first = None
-        for _ in range(3):
-            r1 = base.train_step({"input_ids": ids})
-            r2 = inf.train_step({"input_ids": ids})
-            first = first if first is not None else float(r2["loss"])
-            assert abs(float(r1["loss"]) - float(r2["loss"])) < 5e-3
-        for _ in range(5):
-            r2 = inf.train_step({"input_ids": ids})
-        # keeps training through the streamed experts
-        assert float(r2["loss"]) < first - 0.3
-
     def test_eval_loss_and_convergence(self):
         rng = jax.random.PRNGKey(0)
         ids = ids_batch()
@@ -929,62 +902,5 @@ class TestInfinityMultiChip:
         cfg = dp_cfg(zero=infinity_zero(), dp=4)
         cfg["mesh"] = {"data": 4, "model": 2}
         with pytest.raises(NotImplementedError, match="data-like"):
-            DeepSpeedEngine(tiny_model(), config=cfg,
-                            rng=jax.random.PRNGKey(0), mesh=mesh)
-
-    def _moe_engine(self, mesh_dict, rng):
-        from deepspeed_tpu.parallel.topology import build_mesh
-        from deepspeed_tpu.runtime.config import MeshConfig
-        over = dict(moe_num_experts=4, moe_freq=2, moe_k=1,
-                    moe_use_rts=False, num_layers=4)
-        mk = TransformerLM(TransformerConfig(**{**TINY, **over}))
-        cfg = dp_cfg(zero=infinity_zero(), dp=8)
-        cfg["mesh"] = mesh_dict
-        return DeepSpeedEngine(mk, config=cfg, rng=rng,
-                               mesh=build_mesh(MeshConfig(**mesh_dict)))
-
-    @pytest.mark.slow
-    def test_expert_axis_matches_dense_dp_composition(self):
-        """EP mesh axis x Infinity (VERDICT r4 missing #4): an MoE model
-        with offload on mesh {data:4, expert:2} walks the same trajectory
-        as the dense-dp {data:8} composition — the flat layer vector
-        shards over BOTH data-like axes, and the MoE all_to_all rides the
-        expert axis inside the streamed block."""
-        rng = jax.random.PRNGKey(0)
-        ids = ids_batch(n=8)
-        dp = self._moe_engine({"data": 8}, rng)
-        ep = self._moe_engine({"data": 4, "expert": 2}, rng)
-        first = None
-        for _ in range(3):
-            r1 = dp.train_step({"input_ids": ids})
-            r2 = ep.train_step({"input_ids": ids})
-            first = first if first is not None else float(r2["loss"])
-            assert abs(float(r1["loss"]) - float(r2["loss"])) < 5e-3
-        for _ in range(5):
-            r2 = ep.train_step({"input_ids": ids})
-        assert float(r2["loss"]) < first - 0.2
-
-    def test_expert_axis_layer_vector_sharded_over_both_axes(self):
-        """Each of the 8 chips (4 data x 2 expert) holds 1/8 of the
-        streamed MoE layer vector — per-host slot stores span only the
-        local range."""
-        e = self._moe_engine({"data": 4, "expert": 2},
-                             jax.random.PRNGKey(0))
-        st = e._infinity
-        assert st.dp == 8 and st.n_pad % 8 == 0
-        # _ensure_layer returns a tuple of device arrays — (bf16 flat,)
-        # uncompressed, (payload, scales) under the quantized param wire
-        arr = st._ensure_layer(0, {0})[0]
-        assert arr.addressable_shards[0].data.shape == (st.n_pad // 8,)
-        assert len({s.device for s in arr.addressable_shards}) == 8
-        st._sweep_uploads(block=True)
-
-    def test_expert_axis_without_moe_rejected(self):
-        from deepspeed_tpu.parallel.topology import build_mesh
-        from deepspeed_tpu.runtime.config import MeshConfig
-        mesh = build_mesh(MeshConfig(data=4, expert=2))
-        cfg = dp_cfg(zero=infinity_zero(), dp=8)
-        cfg["mesh"] = {"data": 4, "expert": 2}
-        with pytest.raises(NotImplementedError, match="MoE"):
             DeepSpeedEngine(tiny_model(), config=cfg,
                             rng=jax.random.PRNGKey(0), mesh=mesh)

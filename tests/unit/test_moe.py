@@ -1,301 +1,216 @@
-"""MoE / expert-parallel tests (8-device CPU mesh).
-
-Reference coverage model: `/root/reference/tests/unit/moe/test_moe.py`
-(EP group construction, top-1/top-2 training steps) plus gating-math unit
-checks against the reference's top1gating/top2gating semantics
-(`deepspeed/moe/sharded_moe.py:177,278`).
-"""
+"""The expert layer (``moe/dropless.py``) by itself: the router in every
+form ``route_logits`` spells and this chip's share of the experts against a
+NumPy loop over experts, the grouped product's backward, and what refuses
+the capacity-gated layer's old options.  The blocks that use the layer have
+their own files (``test_shortcut_moe.py``, ``test_latent_moe.py``,
+``test_sparse_latent_moe.py``, ``test_cca_moe.py``)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 import deepspeed_tpu as ds
-from deepspeed_tpu.moe import (MoEConfig, MoELayer, capacity, top1_gating,
-                               top2_gating)
-from deepspeed_tpu.models import TransformerLM, gpt2_config
+from deepspeed_tpu.models import (TransformerConfig, TransformerLM,
+                                  build_model, gpt2_config, zaya_config)
+from deepspeed_tpu.moe import dropless
+from deepspeed_tpu.parallel.topology import build_mesh
+from deepspeed_tpu.runtime.config import DeepSpeedConfig, MeshConfig
+
+# ---------------------------------------------------------------------------
+# route + expert_share against a float64 loop over experts
+# ---------------------------------------------------------------------------
+T, H, F, E, Z, SCALE = 40, 16, 8, 8, 2, 2.5
+HOT = 1                      # held by both shares below
+SHARES = {"all": [(0, E)], "half": [(0, E // 2), (E // 2, E)]}
+#: the published gates: LongCat's is softmax_bias, openPangu's
+#: sigmoid_renorm, GLM's sigmoid_bias_renorm, ZAYA's softmax_bias at k = 1
+FORMS = {f"{scoring}{'_bias' * biased}{'_renorm' * renorm}":
+         (scoring, bool(biased), bool(renorm))
+         for scoring in ("softmax", "sigmoid") for biased in (0, 1)
+         for renorm in (0, 1)}
+#: pinned already, through the block that uses it: ZAYA's gate over a half
+#: share (test_cca_moe.py::test_the_two_shares_add_up_to_the_uncut_layer,
+#: ::test_held_and_absent_picks_add_up)
+ELSEWHERE = {("softmax_bias", 1, "half")}
 
 
-def moe_model(layers=4, experts=4, **kw):
-    cfg = gpt2_config("125m", num_layers=layers, d_model=32, num_heads=4,
-                      vocab_size=64, max_seq_len=16, dtype=jnp.float32,
-                      moe_num_experts=experts, **kw)
-    return TransformerLM(cfg)
+def layer_case(load, seed=0):
+    """Rows, a gate and all ``E`` experts' weights; under the ``one_expert``
+    load every row's first pick is ``HOT`` (a feature all rows share and
+    only ``HOT``'s gate column reads)."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+    u = np.array(jax.random.normal(ks[0], (T, H)))
+    kernel = np.array(0.5 * jax.random.normal(ks[1], (H, E + Z)))
+    if load == "one_expert":
+        u[:, 0], kernel[0], kernel[0, HOT] = 1.0, 0.0, 12.0
+    bias = np.asarray(0.05 * jax.random.normal(ks[2], (E + Z,)))
+    experts = dropless.init_experts(ks[3], E, H, F, 0.3, 0.3, jnp.float32)
+    return jnp.asarray(u), jnp.asarray(kernel), jnp.asarray(bias), experts
 
 
-def batch(n, seq=16, vocab=64, seed=0):
-    rs = np.random.RandomState(seed)
-    return {"input_ids": rs.randint(0, vocab, (n, seq), dtype=np.int32)}
+def loop_over_experts(u, kernel, bias, k, scoring, renorm, experts, lo, hi):
+    """``route`` then ``expert_share`` of experts ``lo .. hi - 1`` in
+    float64, an expert at a time: ``(index, weight, y)``."""
+    u, kernel = np.asarray(u, np.float64), np.asarray(kernel, np.float64)
+    logits = u @ kernel
+    if scoring == "softmax":
+        p = np.exp(logits - logits.max(1, keepdims=True))
+        p /= p.sum(1, keepdims=True)
+    else:
+        p = 1.0 / (1.0 + np.exp(-logits))
+    chosen_by = p if bias is None else p + np.asarray(bias, np.float64)
+    index = np.argsort(-chosen_by, axis=1, kind="stable")[:, :k]
+    weight = np.take_along_axis(p, index, axis=1)
+    if renorm:
+        weight = weight / (weight.sum(1, keepdims=True) + 1e-20)
+    weight = SCALE * weight
+    w = {n: np.asarray(a, np.float64) for n, a in experts.items()}
+    y = np.zeros_like(u)
+    for e in range(lo, hi):
+        gate = u @ w["w_gate"][e]
+        out = (gate / (1.0 + np.exp(-gate)) * (u @ w["w_up"][e])) \
+            @ w["w_down"][e]
+        y += (weight * (index == e)).sum(1, keepdims=True) * out
+    y += (weight * (index >= E)).sum(1, keepdims=True) * u
+    return index, weight, y
 
 
-class TestGating:
-    def test_capacity_math(self):
-        # reference _capacity: ceil(S/E * factor), floored at min_capacity
-        assert capacity(64, 4, 1.0, 4) == 16
-        assert capacity(64, 4, 1.5, 4) == 24
-        assert capacity(8, 8, 1.0, 4) == 4  # min_capacity wins
-
-    def test_top1_all_tokens_routed_when_capacity_ample(self):
-        rng = jax.random.PRNGKey(0)
-        logits = jax.random.normal(rng, (32, 4))
-        out = top1_gating(logits, capacity_factor=4.0, min_capacity=1)
-        # every token got exactly one slot
-        assert float(jnp.sum(out.dispatch_mask)) == 32
-        # combine weights per token sum to its top gate prob
-        gates = jax.nn.softmax(logits, axis=-1)
-        top = jnp.max(gates, axis=1)
-        np.testing.assert_allclose(
-            np.asarray(jnp.sum(out.combine_weights, axis=(1, 2))),
-            np.asarray(top), rtol=1e-5)
-
-    def test_top1_capacity_drop(self):
-        # all tokens prefer expert 0 → only `capacity` survive
-        logits = jnp.tile(jnp.array([[10.0, 0.0, 0.0, 0.0]]), (16, 1))
-        out = top1_gating(logits, capacity_factor=0.25, min_capacity=1)
-        # capacity = ceil(16/4 * 0.25) = 1
-        assert float(jnp.sum(out.dispatch_mask)) == 1
-        assert int(out.exp_counts[0]) == 16  # pre-drop routing counts
-
-    def test_top1_aux_loss_uniform_vs_skewed(self):
-        """Balanced routing minimizes l_aux (→1.0); skew pushes it up."""
-        rng = jax.random.PRNGKey(1)
-        uniform = 0.01 * jax.random.normal(rng, (256, 4))
-        skewed = uniform.at[:, 0].add(8.0)
-        l_uni = float(top1_gating(uniform, 4.0, 1).l_aux)
-        l_skew = float(top1_gating(skewed, 4.0, 1).l_aux)
-        assert abs(l_uni - 1.0) < 0.1
-        assert l_skew > 3.0
-
-    def test_top1_rts_respects_capacity(self):
-        logits = jnp.tile(jnp.array([[10.0, 0.0, 0.0, 0.0]]), (16, 1))
-        out = top1_gating(logits, capacity_factor=0.5, min_capacity=1,
-                          rng=jax.random.PRNGKey(3), use_rts=True)
-        assert float(jnp.sum(out.dispatch_mask)) == 2  # cap = 2
-        # each surviving token occupies a distinct capacity slot
-        slot_use = jnp.sum(out.dispatch_mask.astype(jnp.int32), axis=0)
-        assert int(jnp.max(slot_use)) == 1
-
-    def test_top2_two_experts_per_token(self):
-        rng = jax.random.PRNGKey(2)
-        logits = jax.random.normal(rng, (32, 4))
-        out = top2_gating(logits, capacity_factor=4.0, min_capacity=1)
-        # ample capacity: every token reaches 2 experts
-        per_token = jnp.sum(out.dispatch_mask.astype(jnp.int32), axis=(1, 2))
-        assert int(jnp.min(per_token)) == 2
-        # combine weights normalized over the two experts
-        np.testing.assert_allclose(
-            np.asarray(jnp.sum(out.combine_weights, axis=(1, 2))),
-            np.ones(32), rtol=1e-5)
-
-    def test_top2_capacity_doubles(self):
-        assert capacity(64, 4, 1.0 * 2, 4) == 32  # reference: factor*2
-
-    def test_drop_tokens_false_rejected(self):
-        with pytest.raises(ValueError):
-            top1_gating(jnp.zeros((8, 2)), drop_tokens=False)
+@pytest.mark.parametrize("form,k,share", [
+    (form, k, share) for form in FORMS for k in (1, 2, 8)
+    for share in SHARES if (form, k, share) not in ELSEWHERE])
+def test_route_and_expert_share_against_a_loop_over_experts(form, k, share):
+    """What the capacity-gated layer's tests held it to, in the form that
+    is true of this one: every row has exactly ``k`` distinct picks and
+    its weights are the gate's; NO row is dropped at any load (under
+    ``one_expert`` all ``T`` rows reach ``HOT``, several passes of the row
+    buffer); a row with no pick held here gets exactly nothing, and the
+    two halves add up to the whole layer; the output is the loop's."""
+    scoring, biased, renorm = FORMS[form]
+    for load in ("balanced", "one_expert"):
+        u, kernel, bias, experts = layer_case(load)
+        bias = bias if biased else None
+        routing = dropless.route(u, kernel, bias, k, SCALE, scoring, renorm)
+        index = np.asarray(routing.index)
+        assert index.shape == (T, k)
+        assert ((0 <= index) & (index < E + Z)).all()
+        assert all(len(set(row)) == k for row in index)
+        total = np.zeros((T, H))
+        for lo, hi in SHARES[share]:
+            held = {n: a[lo:hi] for n, a in experts.items()}
+            y, counted = dropless.expert_share(held, u, routing, E, (lo, hi),
+                                               pass_rows=32)
+            want_index, want_weight, want = loop_over_experts(
+                u, kernel, bias, k, scoring, renorm, experts, lo, hi)
+            np.testing.assert_array_equal(index, want_index)
+            np.testing.assert_allclose(routing.weight, want_weight,
+                                       rtol=2e-5)
+            np.testing.assert_allclose(y, want, rtol=2e-4, atol=2e-5)
+            counted = dict(zip(dropless.COUNTERS, map(int, counted)))
+            here = (index >= lo) & (index < hi)
+            assert counted["moe_picks"] == T * k
+            assert counted["moe_picks_held"] == here.sum()
+            assert counted["moe_rows_max_expert"] == max(
+                (index == e).sum() for e in range(lo, hi))
+            if load == "one_expert" and lo <= HOT < hi:
+                assert (index[:, 0] == HOT).all()
+                assert counted["moe_rows_max_expert"] == T > 32
+            nothing_here = ~(here | (index >= E)).any(1)
+            assert not np.asarray(y)[nothing_here].any()
+            total += np.asarray(y, np.float64)
+        if share == "half":
+            whole = loop_over_experts(u, kernel, bias, k, scoring, renorm,
+                                      experts, 0, E)
+            identity = (whole[1] * (index >= E)).sum(1, keepdims=True) \
+                * np.asarray(u, np.float64)
+            # each share adds the identity experts' part: counted once
+            np.testing.assert_allclose(total - identity, whole[2],
+                                       rtol=2e-4, atol=4e-5)
 
 
-class TestMoELayer:
-    def test_forward_shape_and_identity_combine(self):
-        layer = MoELayer(16, MoEConfig(num_experts=4, k=1,
-                                       capacity_factor=4.0, min_capacity=1))
-        params = layer.init(jax.random.PRNGKey(0))
-        x = jax.random.normal(jax.random.PRNGKey(1), (8, 6, 16))
-        y, laux, counts = layer.apply(params, x)
-        assert y.shape == x.shape
-        assert np.isfinite(float(laux))
-        assert int(jnp.sum(counts)) == 8 * 6
-
-    def test_moe_matches_manual_expert_computation(self):
-        """With 1 expert and ample capacity, MoE == plain FFN (gate prob 1)."""
-        layer = MoELayer(16, MoEConfig(num_experts=1, k=1,
-                                       capacity_factor=1.0, min_capacity=64))
-        params = layer.init(jax.random.PRNGKey(0))
-        x = jax.random.normal(jax.random.PRNGKey(1), (4, 8, 16))
-        y, _, _ = layer.apply(params, x)
-        single = jax.tree_util.tree_map(lambda p: p[0], params["experts"])
-        ref = layer.expert_apply(single, x.reshape(-1, 16)).reshape(x.shape)
-        np.testing.assert_allclose(np.asarray(y), np.asarray(ref), atol=1e-5)
-
-    def test_residual_moe(self):
-        layer = MoELayer(16, MoEConfig(num_experts=2, k=1, use_residual=True,
-                                       capacity_factor=4.0, min_capacity=1))
-        params = layer.init(jax.random.PRNGKey(0))
-        assert "residual_mlp" in params and "coefficient" in params
-        x = jax.random.normal(jax.random.PRNGKey(1), (4, 4, 16))
-        y, laux, _ = layer.apply(params, x)
-        assert y.shape == x.shape and np.isfinite(float(laux))
-
-    def test_partition_specs_shard_experts(self):
-        from jax.sharding import PartitionSpec as P
-        layer = MoELayer(16, MoEConfig(num_experts=4))
-        specs = layer.partition_specs()
-        assert specs["experts"]["fc_in"]["kernel"][0] == "expert"
-        assert specs["gate"]["kernel"] == P(None, None)
+# ---------------------------------------------------------------------------
+# what is gone says where its successor is
+# ---------------------------------------------------------------------------
+OLD_KEYS = ("moe_num_experts", "moe_freq", "moe_k", "moe_capacity_factor",
+            "moe_eval_capacity_factor", "moe_min_capacity",
+            "moe_use_residual", "moe_noisy_gate_policy", "moe_use_rts",
+            "moe_aux_loss_coef", "moe_d_ff")
+TRAIN = {"train_micro_batch_size_per_gpu": 1, "steps_per_print": 0,
+         "optimizer": {"type": "AdamW", "params": {"lr": 1e-3}}}
 
 
-class TestMoETraining:
-    def _train(self, mesh, experts=4, k=1, freq=2, steps=3, seed=0, **cfg_kw):
-        model = moe_model(experts=experts, moe_k=k, moe_freq=freq)
-        config = {
-            "train_batch_size": 32,
-            "gradient_accumulation_steps": 2,
-            "optimizer": {"type": "AdamW", "params": {"lr": 1e-3}},
-            "mesh": mesh,
-            "steps_per_print": 0,
-            **cfg_kw,
-        }
-        engine, _, _, _ = ds.initialize(model=model, config=config,
-                                        rng=jax.random.PRNGKey(seed))
-        return engine, [float(engine.train_step(
-            batch(engine.train_batch_size, seed=i))["loss"])
-            for i in range(steps)]
-
-    @pytest.mark.slow
-    def test_ep_matches_dp(self):
-        """Same model, same data: pure-DP mesh vs expert-parallel mesh must
-        produce identical losses (EP is a layout, not a different program)."""
-        _, dp = self._train({"data": 8})
-        _, ep = self._train({"data": 2, "expert": 4})
-        np.testing.assert_allclose(dp, ep, rtol=2e-4)
-
-    @pytest.mark.slow
-    def test_ep_with_tp(self):
-        _, dp = self._train({"data": 8})
-        _, ep_tp = self._train({"data": 2, "expert": 2, "model": 2})
-        np.testing.assert_allclose(dp, ep_tp, rtol=2e-3)
-
-    @pytest.mark.slow
-    def test_top2_trains(self):
-        _, losses = self._train({"data": 2, "expert": 4}, k=2)
-        assert all(np.isfinite(losses))
-        assert losses[-1] < losses[0] + 0.5
-
-    def test_every_layer_moe(self):
-        _, losses = self._train({"data": 2, "expert": 4}, freq=1)
-        assert all(np.isfinite(losses))
-
-    @pytest.mark.slow
-    def test_moe_with_zero2(self):
-        _, z0 = self._train({"data": 2, "expert": 4})
-        _, z2 = self._train({"data": 2, "expert": 4},
-                            zero_optimization={"stage": 2})
-        np.testing.assert_allclose(z0, z2, rtol=2e-4)
-
-    @pytest.mark.slow
-    def test_expert_params_sharded(self):
-        engine, _ = self._train({"data": 2, "expert": 4}, steps=1)
-        specs = engine.zero_policy.param_specs
-        blk = specs["blocks"]["moe_blk"]["moe"]["experts"]
-        assert blk["fc_in"]["kernel"][1] == "expert"
-
-    @pytest.mark.slow
-    def test_rsample_rts_via_engine_rng(self):
-        """batch['moe_rng'] reaches the gate through shard_batch + GAS scan:
-        RSample/RTS configs train, and the key changes the routing."""
-        model = moe_model(experts=4, moe_noisy_gate_policy="RSample",
-                          moe_capacity_factor=0.5)
-        config = {
-            "train_batch_size": 32, "gradient_accumulation_steps": 2,
-            "optimizer": {"type": "AdamW", "params": {"lr": 1e-3}},
-            "mesh": {"data": 2, "expert": 4}, "steps_per_print": 0,
-        }
-        engine, _, _, _ = ds.initialize(model=model, config=config,
-                                        rng=jax.random.PRNGKey(0))
-        b = batch(32)
-        l1 = float(engine.train_step(
-            {**b, "moe_rng": jax.random.PRNGKey(1)})["loss"])
-        assert np.isfinite(l1)
-        # missing rng with RSample fails loudly at trace time
-        model2 = moe_model(experts=4, moe_noisy_gate_policy="RSample")
-        engine2, _, _, _ = ds.initialize(model=model2, config=dict(config),
-                                         rng=jax.random.PRNGKey(0))
-        with pytest.raises(ValueError, match="rng"):
-            engine2.train_step(batch(32))
-
-    def test_pipeline_rejects_rsample(self):
-        from deepspeed_tpu.parallel.topology import build_mesh
-        from deepspeed_tpu.runtime.config import MeshConfig
-        from deepspeed_tpu.runtime.pipe.engine import PipelineEngine
-        mesh = build_mesh(MeshConfig(pipe=2, data=4))
-        with pytest.raises(NotImplementedError):
-            PipelineEngine(
-                model=moe_model(moe_noisy_gate_policy="RSample"),
-                config={"train_batch_size": 32,
-                        "gradient_accumulation_steps": 2,
-                        "mesh": {"pipe": 2, "data": 4},
-                        "steps_per_print": 0},
-                mesh=mesh, rng=jax.random.PRNGKey(0))
-
-    @pytest.mark.slow
-    def test_moe_under_pipeline(self):
-        """PP(2) × EP(2) × DP(2) matches pure DP — the pipeline loop must
-        accumulate MoE aux loss only on valid (non-bubble) ticks."""
-        from deepspeed_tpu.parallel.topology import build_mesh
-        from deepspeed_tpu.runtime.config import MeshConfig
-        from deepspeed_tpu.runtime.pipe.engine import PipelineEngine
-        _, dp = self._train({"data": 8})
-        mesh_conf = {"pipe": 2, "data": 2, "expert": 2}
-        mesh = build_mesh(MeshConfig(**mesh_conf))
-        cfgd = {
-            "train_batch_size": 32,
-            "gradient_accumulation_steps": 2,
-            "optimizer": {"type": "AdamW", "params": {"lr": 1e-3}},
-            "mesh": mesh_conf,
-            "steps_per_print": 0,
-        }
-        engine = PipelineEngine(model=moe_model(), config=cfgd, mesh=mesh,
-                                rng=jax.random.PRNGKey(0))
-        pp = [float(engine.train_step(
-            batch(engine.train_batch_size, seed=i))["loss"])
-            for i in range(3)]
-        np.testing.assert_allclose(dp, pp, rtol=2e-4)
-
-    @pytest.mark.slow
-    def test_moe_checkpoint_roundtrip(self, tmp_path):
-        engine, losses = self._train({"data": 2, "expert": 4}, steps=2)
-        engine.save_checkpoint(str(tmp_path), tag="m1")
-        engine2, _ = self._train({"data": 2, "expert": 4}, steps=0, seed=1)
-        engine2.load_checkpoint(str(tmp_path), tag="m1")
-        l1 = float(engine.train_step(batch(engine.train_batch_size, seed=9))
-                   ["loss"])
-        l2 = float(engine2.train_step(batch(engine2.train_batch_size, seed=9))
-                   ["loss"])
-        assert abs(l1 - l2) < 1e-5
+def tiny_lm():
+    return TransformerLM(gpt2_config(
+        "125m", num_layers=2, d_model=32, num_heads=2, vocab_size=64,
+        max_seq_len=16, dtype=jnp.float32))
 
 
-class TestMoEInference:
-    """MoE serving (reference ops/transformer/inference/moe_inference.py):
-    the compiled prefill+decode loop over an expert-parallel model."""
+def standard_config_given(key):
+    TransformerConfig(**{key: 2})
 
-    def _moe_model(self):
-        from deepspeed_tpu.models import TransformerLM, gpt2_config
-        return TransformerLM(gpt2_config(
-            "125m", num_layers=2, d_model=32, num_heads=4, vocab_size=64,
-            max_seq_len=64, loss_chunk=0, dtype=jnp.float32,
-            moe_num_experts=4, moe_freq=2, moe_k=1, moe_use_rts=False))
 
-    @pytest.mark.slow
-    def test_generate_runs_and_matches_forward_argmax(self):
-        import deepspeed_tpu as ds
-        model = self._moe_model()
-        params = jax.device_get(model.init(jax.random.PRNGKey(0)))
-        eng = ds.init_inference(self._moe_model(), params=params, config={
-            "dtype": "float32", "max_out_tokens": 64, "prompt_bucket": 0,
-            "moe": {"enabled": True, "ep_size": 2}})
-        rs = np.random.RandomState(0)
-        ids = rs.randint(0, 64, (2, 8)).astype(np.int32)
-        out = np.asarray(eng.generate(ids, max_new_tokens=4,
-                                      temperature=0.0))
-        assert out.shape == (2, 4)
-        # greedy decode must agree with repeated full forwards (the cached
-        # expert-dispatch path vs the scan path)
-        cur = ids
-        for t in range(4):
-            logits = np.asarray(eng.forward(cur))
-            nxt = logits[:, -1].argmax(-1).astype(np.int32)
-            np.testing.assert_array_equal(out[:, t], nxt)
-            cur = np.concatenate([cur, nxt[:, None]], axis=1)
+def block_config_given(key):
+    zaya_config("8b", **{key: 2})
+
+
+def pipeline_schedule(value):
+    DeepSpeedConfig({**TRAIN, "pipeline": {"schedule": value}})
+
+
+def expert_axis_under(engine):
+    mesh = {"pipeline": {"pipe": 2, "data": 2, "expert": 2},
+            "infinity": {"data": 4, "expert": 2}}[engine]
+    zero = {"pipeline": {"stage": 1}, "infinity": {
+        "stage": 3, "offload_param": {"device": "cpu"},
+        "offload_optimizer": {"device": "cpu"}}}[engine]
+    ds.initialize(model=tiny_lm(), mesh=build_mesh(MeshConfig(**mesh)),
+                  config={**TRAIN, "bf16": {"enabled": True}, "mesh": mesh,
+                          "zero_optimization": zero})
+
+
+@pytest.mark.parametrize("refused,given", [
+    *[(standard_config_given, key) for key in OLD_KEYS],
+    (block_config_given, "moe_num_experts"),
+    (pipeline_schedule, "gpipe"), (pipeline_schedule, "1f1b"),
+    (expert_axis_under, "pipeline"), (expert_axis_under, "infinity")],
+    ids=lambda v: v if isinstance(v, str) else v.__name__)
+def test_what_is_gone_names_the_dropless_layer_or_roadmap_b6(refused, given):
+    """The capacity-gated layer's eleven options on the standard block's
+    configuration (and on a block's own), the pipeline's second schedule
+    and an ``expert`` mesh axis under the two engines that took one for
+    that layer alone: each fails, and says where to go."""
+    with pytest.raises((TypeError, ValueError, NotImplementedError),
+                       match=r"moe/dropless\.py|ROADMAP B6"):
+        refused(given)
+
+
+# ---------------------------------------------------------------------------
+# the block that trains, under every ZeRO stage over data = 8
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("stage", [1, 2, 3])
+def test_cca_block_under_zero_matches_one_device(stage):
+    """The CCA + top-1 expert block through ``ds.initialize`` at ZeRO
+    ``stage`` on ``mesh {data: 8}`` walks one device's losses: stage 3's
+    ``gather_layer`` meets a stacked ``[layers, experts, ..]`` leaf."""
+    sizes = dict(num_layers=2, num_heads=4, num_kv_heads=2, head_dim=16,
+                 d_model=64, d_ff=32, expert_d_ff=32, vocab_size=128,
+                 max_seq_len=32, router_hidden=16, n_routed_experts=8,
+                 experts_held=(0, 4), loss_chunk=16, dtype=jnp.float32)
+    tokens = np.random.default_rng(0).integers(0, 128, (8, 32),
+                                               dtype=np.int32)
+
+    def losses(devices, stage):
+        mesh = {"data": devices}
+        engine, *_ = ds.initialize(
+            model=build_model(zaya_config("8b", **sizes)),
+            rng=jax.random.PRNGKey(0),
+            config={"train_batch_size": 8, "steps_per_print": 0,
+                    "optimizer": {"type": "AdamW", "params": {"lr": 3e-3}},
+                    "zero_optimization": {"stage": stage}, "mesh": mesh},
+            mesh=build_mesh(MeshConfig(**mesh),
+                            devices=jax.devices()[:devices]))
+        return [float(engine.train_step({"input_ids": tokens})["loss"])
+                for _ in range(3)]
+    np.testing.assert_allclose(losses(8, stage), losses(1, 0), rtol=2e-5)
 
 
 # ---------------------------------------------------------------------------

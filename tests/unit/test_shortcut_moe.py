@@ -427,12 +427,3 @@ class TestRefusals:
         model, _ = build()
         with pytest.raises(NotImplementedError, match="paged serving path"):
             model.init_cache(1, 16)
-
-    def test_the_old_gate_points_to_the_dropless_router(self):
-        from deepspeed_tpu.moe import gate
-        with pytest.raises(ValueError, match="dropless"):
-            gate(jnp.zeros((4, 8)), k=12)
-        capped = TransformerLM(gpt2_config(
-            "125m", num_layers=2, d_model=32, num_heads=2, vocab_size=64,
-            moe_num_experts=4))
-        assert "longcat_flash_config" in capped._paged_supported()

@@ -12,6 +12,8 @@ arguments placed on that topology and compiled for ``tpu``; nothing
 executes.  What a kernel computes is pinned by the interpret-mode
 parity tests; that it runs is pinned by ``chip_smoke.py`` on the chip.
 """
+import fcntl
+import json
 import os
 
 import jax
@@ -296,10 +298,11 @@ def test_paged_rejects_shapes_the_tpu_cannot_tile():
 _COMPILED = {}
 
 
-def compile_mixed(devices, model, nb, block, kv_bits, slots, pages, chunk):
-    """``model._apply_paged_mixed`` with donated pools, compiled for one
-    v5e chip on abstract bfloat16 arguments: ``(compiled, the pools'
-    abstract arrays)``."""
+def mixed_step_operands(devices, model, nb, block, kv_bits, slots, pages,
+                        chunk):
+    """``model._apply_paged_mixed``'s abstract bfloat16 arguments on one
+    v5e chip: ``(the call's arguments, the pools' abstract arrays, the
+    parameters')``."""
     sds = one_chip(devices)
 
     def abstract(tree, dtype=None):
@@ -313,10 +316,18 @@ def compile_mixed(devices, model, nb, block, kv_bits, slots, pages, chunk):
     cache["block_tables"] = sds((slots, pages), jnp.int32)
     cache["lens"] = sds((slots,), jnp.int32)
     scalar = sds((), jnp.int32)
+    return (params, cache, sds((slots,), jnp.int32),
+            sds((slots,), jnp.int32), sds((chunk,), jnp.int32), scalar,
+            scalar, scalar), pools, params
+
+
+def compile_mixed(devices, model, *size):
+    """``model._apply_paged_mixed`` with donated pools, compiled for one
+    v5e chip on :func:`mixed_step_operands`: ``(compiled, the pools'
+    abstract arrays, the parameters')``."""
+    args, pools, params = mixed_step_operands(devices, model, *size)
     compiled = jax.jit(model._apply_paged_mixed, donate_argnums=1).trace(
-        params, cache, sds((slots,), jnp.int32), sds((slots,), jnp.int32),
-        sds((chunk,), jnp.int32), scalar, scalar, scalar).lower(
-            lowering_platforms=("tpu",)).compile()
+        *args).lower(lowering_platforms=("tpu",)).compile()
     return compiled, pools, params
 
 
@@ -560,27 +571,29 @@ def _sparse_case():
         max_seq_len=16384, experts_held=(0, 16)), 16, 32, 1024, 9, 5
 
 
-def build_latent_mixed(devices, case, chunk):
-    """A latent block's mixed step at its cell's widths over a pool of
-    4,096 blocks of 16: ``(model, compiled, pools, params)``."""
+LATENT_CASES = {"shortcut": _shortcut_case, "sandwich": _sandwich_case,
+                "sparse": _sparse_case}
+#: the chunk lane's rows of a latent step program, by the shape's name
+LATENT_CHUNK = {"mixed": 512, "decode_only": 0}
+
+
+def latent_mixed_size(case, chunk):
+    """A latent block's model and its mixed step's size at its cell's
+    widths over a pool of 4,096 blocks of 16."""
     from deepspeed_tpu.models import build_model
     config, _, slots, pages, _, _ = case()
-    model = build_model(config)
-    return (model,) + compile_mixed(devices, model, 4096, 16, 0, slots,
-                                    pages, chunk)
+    return build_model(config), (4096, 16, 0, slots, pages, chunk)
 
 
-def compile_latent_mixed(devices, case, chunk):
-    return compiled_once((case.__name__, chunk),
-                         lambda: build_latent_mixed(devices, case, chunk))
+def build_latent_mixed(devices, case, chunk):
+    model, size = latent_mixed_size(case, chunk)
+    return compile_mixed(devices, model, *size)[0]
 
 
-@pytest.mark.parametrize("chunk", [512, 0], ids=["mixed", "decode_only"])
-@pytest.mark.parametrize("case", [_shortcut_case, _sandwich_case,
-                                  _sparse_case],
-                         ids=["shortcut", "sandwich", "sparse"])
+@pytest.mark.parametrize("shape", list(LATENT_CHUNK))
+@pytest.mark.parametrize("block", list(LATENT_CASES))
 def test_latent_mixed_step_keeps_pool_and_experts_in_place(
-        v5e_devices, compiled_kernels, case, chunk):
+        v5e_devices, compiled_kernels, step_programs, block, shape):
     """A latent block's mixed step at its cell's widths — the shortcut
     block (2 layers of two attention sublayers, 4 held experts), the
     sandwich block (one dense layer before two expert layers: two kinds
@@ -596,16 +609,17 @@ def test_latent_mixed_step_keeps_pool_and_experts_in_place(
     The same for the decode-only shape (``chunk`` 0), which calls no
     chunk kernel: the chunk lane's calls are gone."""
     import re
+    case, chunk = LATENT_CASES[block], LATENT_CHUNK[shape]
     config, held, slots, pages, kernels, kernels_decode_only = case()
     nb = 4096
-    model, compiled, pools, params = compile_latent_mixed(v5e_devices, case,
-                                                          chunk)
+    model, size = latent_mixed_size(case, chunk)
+    _, pools, params = mixed_step_operands(v5e_devices, model, *size)
+    text, temp_bytes = step_programs(f"{block}-{shape}")
     sublayers = model.ATTN_SUBLAYERS * config.num_layers
     stacked = config.scan_length
     # one buffer ("v" is None), or the indexer pool in its place
     assert list(pools) == (["k", "v"] if case is _sparse_case else ["k"])
     assert pools["k"].shape[0] == sublayers
-    text = compiled.as_text()
     assert custom_calls(text) == (kernels if chunk else kernels_decode_only)
     shaped = set()
     for a in pools.values():
@@ -629,7 +643,7 @@ def test_latent_mixed_step_keeps_pool_and_experts_in_place(
             moved.append(ln.strip()[:160])
     assert not moved, moved
     one_expert_stack = held * config.d_model * 2048 * 2
-    assert compiled.memory_analysis().temp_size_in_bytes < one_expert_stack
+    assert temp_bytes < one_expert_stack
 
 
 def test_train_grad_compiles_on_four_chips(v5e_devices, compiled_kernels):
@@ -1110,8 +1124,8 @@ def test_zero3_gathers_a_layers_weights_not_the_activations(
 
 
 #: every step program a benchmark cell runs, at this module's sizes:
-#: name -> (the program compiled once a process, the same compiled anew,
-#: the scopes it must show); both take the described devices
+#: name -> (what compiles it, given the described devices; the scopes it
+#: must show)
 _LAYER = {"embed", "norm", "residual", "attn_proj", "attn_kernel", "head"}
 _SERVE = _LAYER | {"pool_write", "mlp"}
 _EXPERTS = _SERVE | {"router", "expert_layout", "experts"}
@@ -1120,39 +1134,55 @@ STEP_PROGRAMS = {}
 for _shape, _dense, _latent in (("mixed", 256, 512), ("decode_only", 0, 0)):
     _size = (128, 0, 16, 1920, _dense)
     STEP_PROGRAMS[f"dense-{_shape}"] = (
-        lambda dev, size=_size: compile_dense_mixed(dev, *size)[0],
         lambda dev, size=_size: build_dense_mixed(dev, *size)[0], _SERVE)
-    for _block, _case, _own in (("shortcut", _shortcut_case, set()),
-                                ("sandwich", _sandwich_case,
-                                 {"shared_expert"}),
-                                ("sparse", _sparse_case,
-                                 {"shared_expert", "indexer", "select"})):
+    for _block, _own in (("shortcut", set()),
+                         ("sandwich", {"shared_expert"}),
+                         ("sparse", {"shared_expert", "indexer", "select"})):
         STEP_PROGRAMS[f"{_block}-{_shape}"] = (
-            lambda dev, case=_case, c=_latent: compile_latent_mixed(
-                dev, case, c)[1],
-            lambda dev, case=_case, c=_latent: build_latent_mixed(
-                dev, case, c)[1], _EXPERTS | _own)
+            lambda dev, case=LATENT_CASES[_block], c=LATENT_CHUNK[_shape]:
+            build_latent_mixed(dev, case, c), _EXPERTS | _own)
 for _cell, _chips in (("1chip", 1), ("zero3-4chip", 4)):
     STEP_PROGRAMS[f"train-{_cell}"] = (
-        lambda dev, n=_chips: compiled_once(
-            ("train", n), lambda: build_train_step(dev, n)),
         lambda dev, n=_chips: build_train_step(dev, n), _TRAIN)
 
 
 STEP_PROGRAMS["train-moe-1chip"] = (
-    lambda dev: compiled_once(("train-moe",),
-                              lambda: build_cca_train_step(dev)),
     build_cca_train_step,
     (_LAYER | {"attn_conv", "router", "expert_layout", "experts", "loss",
                "optimizer", "zero_comm"}))
 
 
-def step_program_text(devices, name, fresh=False) -> str:
-    return STEP_PROGRAMS[name][fresh](devices).as_text()
+@pytest.fixture(scope="module")
+def step_programs(v5e_devices, tmp_path_factory):
+    """``name -> (a step program's optimized HLO text with its scopes,
+    its temp_size_in_bytes)``, compiled once a RUN: the first test to ask,
+    on whichever worker, compiles it under the program's lock and leaves
+    the record in the run's base temp, which every xdist worker's own
+    temp lies in; the others read it.  (A test that asks must hold
+    ``compiled_kernels``.)"""
+    base = tmp_path_factory.getbasetemp()
+    if os.environ.get("PYTEST_XDIST_WORKER"):
+        base = base.parent
+    shared = base / "step_programs"
+    shared.mkdir(exist_ok=True)
+
+    def record(name):
+        path = shared / f"{name}.json"
+        with open(shared / f"{name}.lock", "w") as lock:
+            fcntl.flock(lock, fcntl.LOCK_EX)
+            if not path.exists():
+                compiled = STEP_PROGRAMS[name][0](v5e_devices)
+                made = path.with_suffix(".made")
+                made.write_text(json.dumps([
+                    compiled.as_text(),
+                    compiled.memory_analysis().temp_size_in_bytes]))
+                made.rename(path)
+            return tuple(json.loads(path.read_text()))
+    return record
 
 
 @pytest.mark.parametrize("name", list(STEP_PROGRAMS))
-def test_step_programs_carry_their_scopes(v5e_devices, compiled_kernels,
+def test_step_programs_carry_their_scopes(compiled_kernels, step_programs,
                                           name):
     """What ``program_scopes()`` will find on the chip, checked on each
     cell's step program compiled for it: every scope the configuration
@@ -1166,11 +1196,11 @@ def test_step_programs_carry_their_scopes(v5e_devices, compiled_kernels,
     import re
     from deepspeed_tpu.observability.overlap import (
         SCOPES, UNNAMED, scope_key, scope_of, scope_table)
-    text = step_program_text(v5e_devices, name)
+    text, _ = step_programs(name)
     table = scope_table([text])
     declared = {scope_of(op_name)[0]
                 for op_name in re.findall(r'op_name="([^"]*)"', text)}
-    assert STEP_PROGRAMS[name][2] <= declared <= set(SCOPES) | {UNNAMED}
+    assert STEP_PROGRAMS[name][1] <= declared <= set(SCOPES) | {UNNAMED}
     assert {scope for scope, _ in table.values()} <= declared
     kernels = [ln for ln in text.splitlines()
                if re.search(r' custom-call\(.*"tpu_custom_call"', ln)]
@@ -1214,19 +1244,33 @@ def stripped(text):
 
 @pytest.mark.parametrize("name", list(STEP_PROGRAMS))
 def test_scopes_change_no_instruction(v5e_devices, compiled_kernels,
-                                      monkeypatch, name):
+                                      step_programs, monkeypatch, name):
     """A scope is metadata: with ``jax.named_scope`` patched to a null
     context the same step program compiles to the same optimized HLO,
     every ``metadata={..}`` aside: the same instructions in the same
     order over the same operands, the same kernel bodies.  (The number
     XLA appends to a name, ``%fusion.248``, counts the instructions made
     while lowering and shifts with the name stack; a name is compared by
-    where it first appears.)"""
+    where it first appears.)
+
+    The program without scopes is traced in a trace context of its own
+    (``jax_pgle_profiling_runs``, which nothing reads while
+    ``jax_enable_pgle`` is off, is part of every trace cache's key), so
+    that it finds no function traced with scopes and leaves behind none
+    traced without, and the worker keeps what it had cached: that no
+    scope reached it is asserted on its own ``op_name``s."""
     import contextlib
-    with_scopes = stripped(step_program_text(v5e_devices, name))
+    import re
+    from deepspeed_tpu.observability.overlap import UNNAMED, scope_of
+    with_scopes = stripped(step_programs(name)[0])
     monkeypatch.setattr(jax, "named_scope",
                         lambda name: contextlib.nullcontext())
-    jax.clear_caches()
-    without = stripped(step_program_text(v5e_devices, name, fresh=True))
-    jax.clear_caches()
-    assert "op_name" not in without and with_scopes == without
+    runs = jax.config.jax_pgle_profiling_runs
+    jax.config.update("jax_pgle_profiling_runs", runs + 1)
+    try:
+        without = STEP_PROGRAMS[name][0](v5e_devices).as_text()
+    finally:
+        jax.config.update("jax_pgle_profiling_runs", runs)
+    assert {scope_of(op_name)[0] for op_name in re.findall(
+        r'op_name="([^"]*)"', without)} == {UNNAMED}
+    assert with_scopes == stripped(without)
