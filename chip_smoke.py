@@ -35,6 +35,14 @@ weights from a seed:
          gate and a shared expert, at ``longcat-flash-omni``'s and
          ``openpangu-ultra-moe``'s widths against float32 ``jax.numpy``.
 
+  hybrid (one chip) the paged kernel at 40 / 20 heads of 64 with a window
+         of 512 (decode slots, and a chunk cut into walkers of 128 rows,
+         the pages before the window poisoned), the selective scan
+         ``ssm_chunk_scan`` at 5,120 channels x 16 against the loop, and
+         the hybrid state-space block at ``phi-4-mini-flash-reasoning``'s
+         widths and a shorter pattern served through ``serving_engine()``
+         (three kinds of state a slot) against its float32 reference.
+
 It fails — non-zero exit, no result line — when JAX shows anything but
 CHIPS TPU devices; it never adapts downward, and nothing on the path is
 caught.  Compile seconds are reported apart from run seconds, so a
@@ -527,6 +535,120 @@ def latent_phase(device: dict, block: int = SERVING["kv_block_size"]):
             "atol": KERNEL_ATOL}
 
 
+def hybrid_phase(device: dict, block: int = SERVING["kv_block_size"]):
+    """The hybrid state-space block's kernels at its cell's widths and the
+    block itself, served: see the module docstring."""
+    import jax
+    import jax.numpy as jnp
+    import deepspeed_tpu as ds
+    from benchmark.lib import reference_phi4_flash as reference
+    from deepspeed_tpu.models import build_model, phi4_flash_config
+    from deepspeed_tpu.ops.transformer import ssm_scan
+    from deepspeed_tpu.ops.transformer.paged_decode_attention import (
+        paged_attention_reference, paged_decode_attention,
+        paged_prefill_attention, paged_prefill_reference)
+
+    rng = np.random.default_rng(SEED + 5)
+    f32 = lambda a: a.astype(jnp.float32)               # noqa: E731
+    bf = lambda a: jnp.asarray(a, jnp.bfloat16)         # noqa: E731
+    heads, kv_heads, hd, window, chunk = 40, 20, 64, 512, 512
+    lens = np.array([1, 511, 513, 1097, 2048, 3333, 0, 4096], np.int32)
+    pages = 4096 // block
+    nb = 2 + len(lens) * pages
+    tables = np.arange(1, nb - 1, dtype=np.int32).reshape(len(lens), pages)
+    pool_k = bf(rng.standard_normal((nb, block, kv_heads * hd)))
+    pool_v = bf(rng.standard_normal((nb, block, kv_heads * hd)))
+    # the pages a window has left are another slot's by now: poisoned
+    dead = nb - 1
+    poison_k, poison_v = (p.at[dead].set(jnp.nan) for p in (pool_k, pool_v))
+    left = tables.copy()
+    for b, n in enumerate(lens):
+        left[b, :max(0, n - window) // block] = dead
+    q = bf(rng.standard_normal((len(lens), heads, hd)) * 0.3)
+    out = jax.jit(lambda *a: paged_decode_attention(*a, window=window))(
+        q, poison_k, poison_v, lens, left)
+    ref = paged_attention_reference(f32(q), pool_k, pool_v, lens, tables,
+                                    window=window)
+    decode_err = float(jnp.max(jnp.abs(f32(out) - ref)))
+    check(bool(jnp.all(jnp.isfinite(f32(out)))), "hybrid: decode not finite")
+    check(decode_err < KERNEL_ATOL,
+          f"hybrid: window decode off the f32 reference by {decode_err}")
+    qc = bf(rng.standard_normal((chunk, heads, hd)) * 0.3)
+    prefill = jax.jit(lambda *a: paged_prefill_attention(
+        *a, window=window, tile_rows=128))
+    prefill_err = 0.0
+    for base, n in ((0, chunk), (1536, chunk), (2048, chunk // 2 + 3)):
+        table = tables[7].copy()
+        table[:max(0, base - window + 1) // block] = dead
+        out = prefill(qc, poison_k, poison_v, base, n, table)
+        ref = paged_prefill_reference(f32(qc), pool_k, pool_v, base, n,
+                                      tables[7], window=window)
+        check(bool(jnp.all(jnp.isfinite(f32(out)[:n]))),
+              "hybrid: window prefill not finite")
+        prefill_err = max(prefill_err, float(jnp.max(
+            jnp.abs(f32(out)[:n] - f32(ref)[:n]))))
+    check(prefill_err < KERNEL_ATOL,
+          f"hybrid: window prefill off the f32 reference by {prefill_err}")
+
+    # the scan: 512 rows (a ragged 300 valid), from a given state
+    di, n = 5120, 16
+    x = jnp.asarray(rng.standard_normal((chunk, di)), jnp.float32)
+    dt = jax.nn.softplus(jnp.asarray(rng.standard_normal((chunk, di)),
+                                     jnp.float32) - 3.0)
+    bm, cm = (jnp.asarray(rng.standard_normal((chunk, n)), jnp.float32)
+              for _ in range(2))
+    a = -jnp.broadcast_to(jnp.arange(1.0, n + 1), (di, n))
+    d_skip = jnp.ones((di,), jnp.float32)
+    s0 = jnp.asarray(rng.standard_normal((di, n)), jnp.float32)
+    y, s1 = jax.jit(ssm_scan.ssm_chunk_scan)(
+        x, dt, bm, cm, a, d_skip, ssm_scan.state_to_tiles(s0), 300)
+    want_y, want_s = jax.jit(ssm_scan.ssm_scan_reference)(
+        x, dt, bm, cm, a, d_skip, s0, 300)
+    scan_err = max(float(jnp.max(jnp.abs(y[:300] - want_y[:300]))),
+                   float(jnp.max(jnp.abs(
+                       ssm_scan.state_from_tiles(s1) - want_s))))
+    check(scan_err < 1e-3, f"hybrid: scan off the loop by {scan_err}")
+
+    # the block, served: published widths, 1 + 1 + 1 pairs of the pattern
+    model = build_model(phi4_flash_config(
+        "mini", num_layers=6, pairs_self=1, pairs_cross=1, vocab_size=2048,
+        max_seq_len=2048))
+    params = jax.jit(lambda k: jax.tree_util.tree_map(
+        lambda a: a.astype(jnp.bfloat16), model.init(k)))(
+            jax.random.PRNGKey(SEED + 6))
+    srv = ds.init_inference(
+        model, {"dtype": "bfloat16", "max_out_tokens": 2048,
+                "temperature": 0.0, "serving": dict(SERVING,
+                                                    num_kv_blocks=1024)},
+        params=params).serving_engine()
+    reqs = [srv.submit(rng.integers(0, 2048, p), max_new_tokens=m)
+            for p, m in ((700, 12), (333, 12), (1100, 8))]
+    srv.run()
+    cfg = {"heads": heads, "kv_heads": kv_heads, "window": window,
+           "eps": 1e-5, "state": n, "dt_rank": 160, "without": ()}
+    gap = 0.0
+    for r in reqs:
+        check(len(r.output) == r.max_new_tokens, "hybrid: a stream ended "
+              "short")
+        full = jnp.asarray(list(r.prompt) + list(r.output))[None]
+        lg = np.asarray(jax.jit(lambda p, i: reference.logits(
+            p, i, cfg, last=r.max_new_tokens + 1))(params, full))[0]
+        gap = max(gap, max(float(lg[j].max() - lg[j][tok])
+                           for j, tok in enumerate(r.output)))
+    held = srv.allocator.num_used_by_kind()
+    check(gap < 0.25, f"hybrid: a served token {gap} under the reference's "
+          f"best logit")
+    check(not any(held.values()), f"hybrid: state held after drain {held}")
+    check(srv.decode_builds == 2, "hybrid: the step's two shapes, no more")
+    return {"phase": "hybrid", **device,
+            "window_decode_max_abs_err": round(decode_err, 5),
+            "window_prefill_max_abs_err": round(prefill_err, 5),
+            "scan_max_abs_err": float(scan_err),
+            "served_logit_gap_worst": round(gap, 4),
+            "window_blocks_held": dict(srv.allocator.window_held_max),
+            "atol": KERNEL_ATOL}
+
+
 def _shared_expert_layer_error(rows: int = 640) -> float:
     """One expert layer of the sandwich block at its published widths in
     bfloat16 — sigmoid top-8 gate over 256 outputs, renormalised and scaled
@@ -579,6 +701,7 @@ def main(argv) -> int:
                                   model_config.hdim, device)), flush=True)
     if chips == 1:
         print(json.dumps(latent_phase(device)), flush=True)
+        print(json.dumps(hybrid_phase(device)), flush=True)
     print(json.dumps({"phase": "total", **device,
                       "wall_s": round(time.perf_counter() - t0, 1),
                       **log.since((0, 0, 0.0, 0.0))}), flush=True)

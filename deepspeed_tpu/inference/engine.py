@@ -473,6 +473,10 @@ class InferenceEngine:
         top_p = self.config.top_p if top_p is None else top_p
         true_len = ids.shape[1]
         bucket = self.config.prompt_bucket
+        if bucket and self.module.padded_prompt_refusal() is not None:
+            # a model whose state the padding would run through compiles
+            # for the prompt's own length
+            bucket = 0
         if bucket:
             padded = max(bucket, -(-true_len // bucket) * bucket)
             # never let padding spill the KV workspace the exact shape

@@ -33,6 +33,26 @@ Invariants (``assert_consistent`` checks them, tests fuzz them):
     unknown sequence ids, and exhaustion — a serving scheduler bug
     surfaces as a loud error, not a silently corrupted cache.
 
+Layer KINDS (docs/serving.md "The cache manager's kinds of state"): a
+model whose layers do not all keep the same thing a token gives the
+allocator more than one kind to hand out, all under one sequence id and
+released by the one :meth:`free`:
+
+  * ``full`` — the pool above: a block a ``block_size`` tokens for as long
+    as the sequence lives.  Every model has it; the prefix cache, the
+    host tier and the fork are its alone.
+  * ``window`` (:meth:`add_window_kind`) — a pool of its own (its own
+    block ids, its own null block 0) for layers that attend the newest
+    ``window`` tokens only: :meth:`window_reserve` gives a sequence the
+    pages that cover the positions a dispatch reads and writes and hands
+    the pages wholly before them back, so a sequence holds a bounded
+    number of them however long it grows; a page it gave back reads
+    NULL in its table (the kernel never looks there).
+  * ``state`` (:meth:`attach_state`) — not pages at all: what a layer
+    keeps per SEQUENCE (a recurrent state), indexed by the decode slot
+    the scheduler seated it in.  The allocator only records who holds
+    which slot's state, so that a drained server can show it holds none.
+
 Tiered host cache (docs/serving.md &sect;Tiered prefix cache): with
 :meth:`PagedBlockAllocator.attach_host_tier` wired, eviction becomes
 *demotion* — the LRU walk in :meth:`_pop_block` hands the dying
@@ -209,6 +229,20 @@ class PagedBlockAllocator:
         self.hit_tokens_total = 0
         self.evictions_total = 0
         self.host_hit_tokens_total = 0
+        # the other layer kinds (module docstring): the window kind's
+        # pool and per-sequence sparse tables, and who holds which
+        # slot's per-sequence state
+        self.window_tokens = 0
+        self._wfree: List[int] = []
+        self._wtables: Dict[str, List[int]] = {}
+        self._wdead: Dict[str, int] = {}
+        self.window_blocks = 0
+        self.state_slots = 0
+        self._state_slots: Dict[str, int] = {}
+        #: the most window pages one sequence ever held at a reserve, by
+        #: what rode for it, and the pages handed back so far
+        self.window_held_max = {"decode": 0, "chunk": 0}
+        self.window_freed_total = 0
 
     # -- capacity ----------------------------------------------------------
     @property
@@ -398,6 +432,100 @@ class PagedBlockAllocator:
         del self._cached_lru[block]
         self._ref[block] = 1
 
+    # -- the other layer kinds ---------------------------------------------
+    @property
+    def kinds(self) -> Tuple[str, ...]:
+        """The kinds of state this allocator hands out."""
+        return ("full",) + (("window",) if self.window_tokens else ()) + (
+            ("state",) if self.state_slots else ())
+
+    def add_window_kind(self, num_blocks: int, window_tokens: int) -> None:
+        """Give the allocator a ``window`` kind: a pool of ``num_blocks``
+        blocks (block 0 its null block) for layers that attend the newest
+        ``window_tokens`` tokens."""
+        if self.window_tokens:
+            raise BlockPoolError("the window kind is already there")
+        if num_blocks < 2 or window_tokens < 1:
+            raise ValueError(
+                f"a window kind needs >= 2 blocks and a window >= 1, got "
+                f"{num_blocks} and {window_tokens}")
+        self.window_tokens = window_tokens
+        self.window_blocks = num_blocks
+        self._wfree = list(range(num_blocks - 1, 0, -1))
+
+    def add_state_kind(self, num_slots: int) -> None:
+        """Give the allocator a ``state`` kind: ``num_slots`` per-sequence
+        states, one a decode slot."""
+        self.state_slots = num_slots
+
+    def window_reserve(self, seq_id: str, first_row: int, end_row: int,
+                       lane: str = "decode") -> int:
+        """Before a dispatch that writes ``seq_id``'s rows ``first_row ..
+        end_row - 1`` into the window layers: the sequence gets the pages
+        up to ``end_row`` and gives back every page wholly before the
+        first position the dispatch's rows attend, ``first_row -
+        (window - 1)``.  Returns the pages it gave back.  A pool too small
+        raises :class:`BlockPoolError`: the engine sizes the pool so that
+        every slot's bound fits at once."""
+        if seq_id not in self._tables:
+            raise BlockPoolError(f"unknown sequence {seq_id!r}")
+        table = self._wtables.setdefault(seq_id, [])
+        need = -(-end_row // self.block_size)
+        while len(table) < need:
+            if not self._wfree:
+                raise BlockPoolError(
+                    f"window pool exhausted: {seq_id!r} needs page "
+                    f"{len(table)}, 0 free of {self.window_blocks - 1}")
+            table.append(self._wfree.pop())
+        freed = self.window_trim(seq_id, first_row)
+        held = len(table) - self._wdead.get(seq_id, 0)
+        if held > self.window_held_max[lane]:
+            self.window_held_max[lane] = held
+        return freed
+
+    def window_trim(self, seq_id: str, next_row: int) -> int:
+        """Hand back ``seq_id``'s pages wholly before the window of
+        ``next_row``, the first row a later dispatch will write."""
+        table = self._wtables.get(seq_id)
+        if not table:
+            return 0
+        # the pages given back are a prefix of the table: _wdead its length
+        was = self._wdead.get(seq_id, 0)
+        dead = min(max(0, next_row - (self.window_tokens - 1))
+                   // self.block_size, len(table))
+        for page in range(was, dead):
+            self._wfree.append(table[page])
+            table[page] = NULL_BLOCK
+        if dead > was:
+            self._wdead[seq_id] = dead
+            self.window_freed_total += dead - was
+        return max(0, dead - was)
+
+    def window_pages_held(self, seq_id: str) -> Tuple[int, List[int]]:
+        """``(the first page still held, its and the later pages'
+        blocks)``: the window kind's table without the pages given back
+        (what a dispatch's operand needs: at most the chunk bound)."""
+        dead = self._wdead.get(seq_id, 0)
+        return dead, self._wtables.get(seq_id, [])[dead:]
+
+    def attach_state(self, seq_id: str, slot: int) -> None:
+        """``seq_id`` holds decode slot ``slot``'s per-sequence state
+        until :meth:`free`."""
+        if seq_id not in self._tables:
+            raise BlockPoolError(f"unknown sequence {seq_id!r}")
+        if not 0 <= slot < self.state_slots or \
+                slot in self._state_slots.values():
+            raise BlockPoolError(
+                f"slot {slot}'s state is not there to hold, or is held")
+        self._state_slots[seq_id] = slot
+
+    def num_used_by_kind(self) -> Dict[str, int]:
+        """Blocks (``state``: slots) live sequences hold, by kind."""
+        return {"full": self.num_used,
+                "window": (max(0, self.window_blocks - 1) - len(self._wfree)
+                           if self.window_tokens else 0),
+                "state": len(self._state_slots)}
+
     # -- alloc / grow / free ----------------------------------------------
     def allocate(self, seq_id: str, tokens: int,
                  token_ids: Optional[Sequence[int]] = None
@@ -585,6 +713,10 @@ class PagedBlockAllocator:
             raise BlockPoolError(
                 f"free of unknown (or already-freed) sequence {seq_id!r}")
         self._chain.pop(seq_id, None)
+        self._state_slots.pop(seq_id, None)
+        self._wdead.pop(seq_id, None)
+        self._wfree.extend(b for b in self._wtables.pop(seq_id, ())
+                           if b != NULL_BLOCK)
         for b in table:
             if self._ref[b] <= 0:
                 raise BlockPoolError(
@@ -733,6 +865,18 @@ class PagedBlockAllocator:
                     f"table references")
             if not (in_free or in_cache) and refs == 0:
                 raise BlockPoolError(f"block {b} leaked (no refs, not free)")
+        wheld = [b for t in self._wtables.values() for b in t
+                 if b != NULL_BLOCK]
+        if self.window_tokens and sorted(wheld + self._wfree) != list(
+                range(1, self.window_blocks)):
+            raise BlockPoolError(
+                "window blocks leaked or held twice: free list and tables "
+                "do not partition the window pool")
+        for seq in list(self._wtables) + list(self._state_slots):
+            if seq not in self._tables:
+                raise BlockPoolError(
+                    f"{seq!r} holds window pages or a state without a "
+                    f"table of the full kind")
         for h, b in self._hash_to_block.items():
             if self._block_hash[b] != h:
                 raise BlockPoolError(

@@ -312,9 +312,29 @@ class ServingEngine:
         self.chunk_tokens = cfg.prefill_chunk_tokens
         self.max_pages = max(
             1, -(-engine.config.max_out_tokens // self.block_size))
+        # a block whose per-sequence state cannot be found again at a
+        # block boundary serves with the prefix cache off, and says why
+        no_hits = model.prefix_cache_refusal()
+        self.prefix_cache = cfg.prefix_cache and no_hits is None
+        if cfg.prefix_cache and no_hits is not None:
+            logger.info(f"serving: prefix cache off: {no_hits}")
         self.allocator = PagedBlockAllocator(
             cfg.num_kv_blocks, self.block_size,
-            enable_prefix_cache=cfg.prefix_cache)
+            enable_prefix_cache=self.prefix_cache)
+        #: the block tables a slot has (the allocator's layer kinds that
+        #: are pages), side by side in the per-slot operand
+        self.table_kinds = model.TABLE_KINDS
+        self.window_blocks = 0
+        if "window" in self.table_kinds:
+            # every slot's bound at once: all but one decoding, one with
+            # a chunk in flight (it is trimmed as soon as the chunk is
+            # enqueued), and the kind's null block
+            held_decoding, held_chunk = model.window_pages(
+                self.block_size, self.chunk_tokens)
+            self.window_blocks = ((self.num_slots - 1) * held_decoding
+                                  + held_chunk + 1)
+            self.allocator.add_window_kind(self.window_blocks,
+                                           model.config.sliding_window)
         # a prefill worker publishes to the fabric but never claims from
         # it: claiming would steal the very entries the decode class is
         # about to promote
@@ -379,6 +399,16 @@ class ServingEngine:
                                            self._pscale_sh)
             self._pool_vs = jax.device_put(pools["v_scale"],
                                            self._pscale_sh)
+        # what a slot keeps besides the pool's pages (a window kind's
+        # pool, per-slot recurrent state): the model's own tree, carried
+        # by the step like the pools; None for a block that has none
+        self._pool_x = model.init_paged_extra(
+            self.num_slots, self.block_size, self.window_blocks,
+            dtype=engine.dtype)
+        if self._pool_x is not None:
+            self._pool_x = jax.device_put(
+                self._pool_x, NamedSharding(self.tp_mesh, P()))
+            self.allocator.add_state_kind(self.num_slots)
         self._prep_tp_params()
         logger.info(
             f"serving: paged KV pool {cfg.num_kv_blocks} x "
@@ -388,7 +418,7 @@ class ServingEngine:
             f"), {self.num_slots} decode "
             f"slots, {self.max_pages} pages/seq, prefill chunk "
             f"{self.chunk_tokens} tokens, prefix cache "
-            f"{'on' if cfg.prefix_cache else 'off'}")
+            f"{'on' if self.prefix_cache else 'off'}")
 
         # donation keeps the pools in-place on TPU; the CPU backend
         # does not implement donation and would warn every dispatch
@@ -414,7 +444,7 @@ class ServingEngine:
                               "publish_failures": 0,
                               "prefill_only_completed": 0}
         if cfg.host_cache.enabled:
-            if not cfg.prefix_cache:
+            if not self.prefix_cache:
                 raise ValueError(
                     "serving.host_cache.enabled requires "
                     "serving.prefix_cache — the host tier is keyed by "
@@ -788,6 +818,8 @@ class ServingEngine:
             total += self._pool_v.nbytes
         if self._pool_ks is not None:
             total += self._pool_ks.nbytes + self._pool_vs.nbytes
+        total += sum(a.nbytes for a in
+                     jax.tree_util.tree_leaves(self._pool_x))
         return total // self.tp_model_size
 
     @property
@@ -1409,7 +1441,7 @@ class ServingEngine:
                 if self.tp_data_size > 1 else 0)
             return jnp.where((row == ch.slot) & (ch.len > 0), first, newest)
 
-        def step(params, scales, pool_k, pool_v, pool_ks, pool_vs,
+        def step(params, scales, pool_k, pool_v, pool_ks, pool_vs, pool_x,
                  prev, slots, chunk):
             built()
             # slices of an operand are free: the two host arrays come
@@ -1420,6 +1452,8 @@ class ServingEngine:
             cache = {"k": pool_k, "v": pool_v, "k_scale": pool_ks,
                      "v_scale": pool_vs, "block_tables": sl.tables,
                      "lens": sl.lens}
+            if pool_x is not None:
+                cache["extra"] = pool_x
             dec_logits, chunk_logits, cache = model._apply_paged_mixed(
                 mp, cache, dec_tokens, sl.dec_active, ch.ids, ch.slot,
                 ch.start, ch.len)
@@ -1444,10 +1478,12 @@ class ServingEngine:
                     dec_finite, first, chunk_finite,
                     counters=cache.get("counters"))
             return (packed, cache["k"], cache["v"],
-                    cache.get("k_scale"), cache.get("v_scale"))
+                    cache.get("k_scale"), cache.get("v_scale"),
+                    cache.get("extra"))
 
         def spec_step(params, scales, dparams, pool_k, pool_v, pool_ks,
-                      pool_vs, dpool_k, dpool_v, prev, slots, chunk):
+                      pool_vs, pool_x, dpool_k, dpool_v, prev, slots,
+                      chunk):
             built()
             sl = _SlotState.unpack(slots)
             (tables, lens, _host_tokens, dec_active, spec_active, temp,
@@ -1529,17 +1565,19 @@ class ServingEngine:
                         dec_finite, first, chunk_finite, n_emit,
                         spec_finite, samples=s),
                     cache["k"], cache["v"], cache.get("k_scale"),
-                    cache.get("v_scale"), dcache["k"], dcache["v"])
+                    cache.get("v_scale"), pool_x, dcache["k"],
+                    dcache["v"])
 
         # the quantized pool's scale planes are donated with it (they
         # are rewritten at every scatter, exactly like the values); the
         # draft pools donate alongside the target's
         if spec_on:
             fn = spec_step
-            donate = (3, 4, 7, 8) + ((5, 6) if self.kv_bits else ())
+            donate = (3, 4, 8, 9) + ((5, 6) if self.kv_bits else ())
         else:
             fn = step
-            donate = (2, 3) + ((4, 5) if self.kv_bits else ())
+            donate = (2, 3) + ((4, 5) if self.kv_bits else ()) + (
+                (6,) if self._pool_x is not None else ())
         # the body runs shard_mapped over the (data, model) serving
         # submesh.  Pools/params shard over 'model' (kv-head lanes /
         # column-row tiles); the per-slot operands and results (this
@@ -1554,7 +1592,7 @@ class ServingEngine:
         scale_sp = (self._tp_scale_specs
                     if self._tp_scales is not None else P())
         pools_sp = (pool_sp, pool_sp if self._pool_v is not None else P(),
-                    pscale_sp, pscale_sp)
+                    pscale_sp, pscale_sp, P())
         # the previous result comes back as it left: a row a slot
         host_in = (P(d, None), P(d, None), P())
         if spec_on:
@@ -1609,14 +1647,15 @@ class ServingEngine:
         program reads them asynchronously on the chip, and the CPU
         backend may alias a host buffer outright.  No device program is
         launched here."""
-        slots = np.zeros((self.num_slots, _SLOT_COLS + self.max_pages),
-                         np.int32)
+        slots = np.zeros((self.num_slots, self._slot_cols), np.int32)
         slots_f, slots_u = slots.view(np.float32), slots.view(np.uint32)
         slots_f[:, _TOP_P] = 1.0
         for slot, req in self.scheduler.running.items():
             table = self.allocator.block_table(req.req_id)
             slots[slot, _SLOT_COLS:_SLOT_COLS + len(table)] = table
             slots[slot, _LENS] = req.planned_cached
+        if self.window_blocks:
+            self._window_operands(slots, dec, chunk)
         for slot, req in list(dec) + list(spec):
             if req.flight_tokens:
                 slots[slot, _TOKEN_SRC] = SRC_DEVICE
@@ -1648,10 +1687,37 @@ class ServingEngine:
                 req.prefix[c_start:c_start + c_len]
         return self._device_operands() + (slots, chunk_vec)
 
+    @property
+    def _slot_cols(self) -> int:
+        """Columns of the per-slot operand: the ``_SLOT_COLS`` scalars,
+        then a block table a kind of ``table_kinds``."""
+        return _SLOT_COLS + self.max_pages * len(self.table_kinds)
+
+    def _window_operands(self, slots: np.ndarray, dec, chunk) -> None:
+        """The window kind's part of one dispatch's plan: every row the
+        dispatch writes gets its page and gives back the pages its
+        window has left (``window_reserve``), then the tables go in
+        beside the full kind's: the pages a slot still holds alone (the
+        ones it gave back read the null block, which the zeroed operand
+        already says)."""
+        alloc = self.allocator
+        for _slot, req in dec:
+            at = req.planned_cached
+            alloc.window_reserve(req.req_id, at, at + 1)
+        if chunk is not None:
+            _c_slot, req, c_start, c_len = chunk
+            alloc.window_reserve(req.req_id, c_start, c_start + c_len,
+                                 "chunk")
+        at = _SLOT_COLS + self.max_pages
+        for slot, req in self.scheduler.running.items():
+            first, held = alloc.window_pages_held(req.req_id)
+            slots[slot, at + first:at + first + len(held)] = held
+
     def _device_operands(self) -> tuple:
         """The operands that live on the device: weights, then the pools
         and the result array as the last dispatch returned them."""
-        pools = (self._pool_k, self._pool_v, self._pool_ks, self._pool_vs)
+        pools = (self._pool_k, self._pool_v, self._pool_ks, self._pool_vs,
+                 self._pool_x)
         if self._draft_model is not None:
             pools = (self._draft_params,) + pools + (self._dpool_k,
                                                      self._dpool_v)
@@ -1664,10 +1730,10 @@ class ServingEngine:
         passed in are donated) and hand back the one array the host
         reads, which is also the next dispatch's operand."""
         result, *pools = self._step_fn(*operands)
-        (self._pool_k, self._pool_v, self._pool_ks,
-         self._pool_vs) = pools[:4]
+        (self._pool_k, self._pool_v, self._pool_ks, self._pool_vs,
+         self._pool_x) = pools[:5]
         if self._draft_model is not None:
-            self._dpool_k, self._dpool_v = pools[4:]
+            self._dpool_k, self._dpool_v = pools[5:]
         self._prev_result = result
         return result
 
@@ -1686,8 +1752,7 @@ class ServingEngine:
         inactive, no chunk — in either shape: with the (empty) chunk
         lane or without it."""
         return self._device_operands() + (
-            np.zeros((self.num_slots, _SLOT_COLS + self.max_pages),
-                     np.int32),
+            np.zeros((self.num_slots, self._slot_cols), np.int32),
             np.zeros((_CHUNK_HEAD + (self.chunk_tokens if chunk_lane
                                      else 0),), np.int32))
 
@@ -1736,6 +1801,7 @@ class ServingEngine:
             raise ServingError(
                 f"fatal fault at serving dispatch: {e}") from e
         c_len = chunk[3] if chunk is not None else 0
+        window_freed = self.allocator.window_freed_total
         ovl = self._ovl
         ovl_on = ovl.enabled
         if ovl_on:
@@ -1751,13 +1817,23 @@ class ServingEngine:
         with trace_span("serving/dispatch", decode=len(dec),
                         chunk_tokens=c_len, spec=len(spec), rows=rows,
                         tp=self.tp_mesh.size, ahead=int(ahead),
-                        moe=int(bool(self.model.PAGED_COUNTERS)),
+                        moe=int("moe_picks" in self.model.PAGED_COUNTERS),
                         sparse=int("sparse_tokens_read"
-                                   in self.model.PAGED_COUNTERS)):
+                                   in self.model.PAGED_COUNTERS),
+                        kinds=len(self.allocator.kinds)):
             result = self._launch(operands)
             # queued behind the program now, not requested once the host
             # has noticed that it ended
             result.copy_to_host_async()
+        more_counts = {}
+        if self.window_blocks:
+            # the chunk's slot keeps what its NEXT row's window reaches:
+            # whoever is handed the rest writes it in a later program
+            if chunk is not None:
+                self.allocator.window_trim(chunk[1].req_id,
+                                           chunk[2] + c_len)
+            more_counts["window_blocks_freed"] = (
+                self.allocator.window_freed_total - window_freed)
         # the state as dispatched.  A speculating slot's row count is
         # the device's to say: its dispatch lands before anything else is
         # planned (_plan_iteration), so it carries none
@@ -1778,7 +1854,7 @@ class ServingEngine:
                  host_arrays_in=sum(isinstance(a, np.ndarray)
                                     for a in operands),
                  host_reads_out=1, ahead_dispatches=int(ahead),
-                 **self._sampler_rows(*operands[-2:]))
+                 **more_counts, **self._sampler_rows(*operands[-2:]))
             if ovl_on else None))
         return True
 

@@ -516,6 +516,10 @@ class ContinuousBatchingScheduler:
                                   f"fatal fault allocating KV blocks: {e}")
                 continue
             self.waiting.popleft()
+            if self.alloc.state_slots:
+                # the slot's per-sequence state is this request's until
+                # its blocks are freed
+                self.alloc.attach_state(req.req_id, slot)
             req.state = RequestState.RUNNING
             if req.admit_time is None:
                 req.admit_time = time.perf_counter()

@@ -1,4 +1,5 @@
 from .transformer import (TransformerConfig, TransformerLM,  # noqa: F401
                           build_model, glm_moe_dsa_config, gpt2_config,
                           longcat_flash_config, neox_config,
-                          openpangu_ultra_moe_config, zaya_config)
+                          openpangu_ultra_moe_config, phi4_flash_config,
+                          zaya_config)

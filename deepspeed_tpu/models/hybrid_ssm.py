@@ -1,0 +1,862 @@
+"""A decoder-decoder hybrid: state-space layers with per-slot recurrent
+state, window-attention layers, ONE full-attention layer whose keys and
+values later cross-attention layers re-read, and gated memory units
+between those (the ``phi4flash`` family: SambaY).
+
+The layer pattern is data (``HybridSSMConfig.layer_kinds``)::
+
+    pairs_self  x (state space, window attention)      layers 0 .. 2P-1
+    1           x (state space + export, full attn)    the middle pair
+    pairs_cross x (gated memory unit, cross attention) the cross decoder
+
+and every layer is ``x <- x + mixer(LN(x)); x <- x + MLP(LN'(x))`` with a
+gated MLP (``W_down(silu(g) * u)``, ``[g, u] = h W_gate_up``).  No
+positional encoding anywhere.  The mixers:
+
+  * state space (Mamba-1): ``[u, z] = h W_in``; a causal depthwise
+    convolution over ``u`` (``conv_w[j]`` weighs ``u_{t-j}``) and silu
+    give ``c``; ``[r, B, C] = c W_x``; ``D = softplus(r W_dt + b_dt)``;
+    ``S_t = exp(D A) S_{t-1} + (D c) (x) B``; ``y = S C + D_skip c``;
+    out ``= (y * silu(z)) W_out``.  The middle pair's layer also exports
+    ``m = y`` (before the gate): the memory.
+  * window / full attention: grouped-query heads, ``softmax(q k / sqrt
+    hd)``; a window layer's position ``t`` attends ``s`` iff ``0 <= t - s
+    < sliding_window``.
+  * gated memory unit: out ``= (m * silu(h W_1)) W_2``: no state.
+  * cross attention: ``q = h W_q + b`` against the FULL layer's keys and
+    values; no key or value projection of its own.
+
+Serving keeps THREE kinds of state a slot (``TABLE_KINDS``, the
+allocator's layer kinds): the full layer's pages (one layer, read by
+``1 + pairs_cross`` layers), the window layers' pages (a slot holds only
+the pages its window still reaches; ``cache["extra"]["wk" / "wv"]``),
+and per SLOT, not per token, each state-space layer's convolution tail
+and state (``extra["conv"]``, ``extra["ssm"]``; float32 state, tiled as
+``ops/transformer/ssm_scan.py`` wants it).  A chunk whose first row is
+row 0 starts from zero state, so admission resets nothing.  In the mixed
+step the cross decoder — the full layer's query side and everything
+after it — runs on the rows that yield a token only: the decode rows and
+the chunk's last row; every other chunk row is done once the full layer
+has written its key and value.
+"""
+from __future__ import annotations
+
+import dataclasses
+import math
+from typing import Dict, NamedTuple, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
+
+from . import layers as L
+from ..ops.transformer import ssm_scan
+from .transformer import TransformerConfig, TransformerLM
+
+#: rows of a prompt chunk to one walker of the window layers' kernel: at
+#: 2 query heads a key-value head and 2 heads a lane pack, 128 positions
+#: are 512 query rows a head window
+CHUNK_TILE_ROWS = 128
+#: the kinds of layer, in the order ``layer_kinds`` names them
+SSM, WINDOW, FULL, GMU, CROSS = "ssm", "window", "full", "gmu", "cross"
+
+
+@dataclasses.dataclass(frozen=True)
+class HybridSSMConfig(TransformerConfig):
+    """``TransformerConfig``'s sizes plus the state-space layers' and the
+    pattern's.  The flags of the standard block this block does not read
+    are pinned by :func:`models.transformer.phi4_flash_config`."""
+    ssm_state: int = 16
+    ssm_conv: int = 4
+    ssm_expand: int = 2
+    #: 0 = ceil(d_model / 16), the family's default
+    ssm_dt_rank: int = 0
+    sliding_window: int = 512
+    #: (state space, window) pairs before the middle pair, and (memory
+    #: unit, cross) pairs after it
+    pairs_self: int = 8
+    pairs_cross: int = 7
+
+    @classmethod
+    def model_class(cls):
+        return HybridSSMLM
+
+    @property
+    def d_inner(self) -> int:
+        return self.ssm_expand * self.d_model
+
+    @property
+    def dt_rank(self) -> int:
+        return self.ssm_dt_rank or -(-self.d_model // 16)
+
+    @property
+    def ssm_layers(self) -> int:
+        return self.pairs_self + 1
+
+    @property
+    def layer_kinds(self) -> Tuple[str, ...]:
+        return ((SSM, WINDOW) * self.pairs_self + (SSM, FULL)
+                + (GMU, CROSS) * self.pairs_cross)
+
+    def layer_params(self) -> Dict[str, int]:
+        """One layer's parameters by kind of layer (mixer + MLP + its two
+        LayerNorms)."""
+        d, di, f = self.d_model, self.d_inner, self.ff_dim
+        n, r, k = self.ssm_state, self.dt_rank, self.ssm_conv
+        kv = self.kv_heads * self.hdim
+        shell = 3 * d * f + 4 * d
+        return {
+            SSM: shell + d * 2 * di + (k + 1) * di + di * (r + 2 * n)
+            + r * di + di + di * n + di + di * d,
+            WINDOW: shell + d * (d + 2 * kv) + (d + 2 * kv) + d * d + d,
+            GMU: shell + 2 * d * di,
+            CROSS: shell + 2 * (d * d + d)}
+
+    def num_params(self) -> int:
+        part = self.layer_params()
+        part[FULL] = part[WINDOW]
+        return (sum(part[k] for k in self.layer_kinds)
+                + self.vocab_size * self.d_model + 2 * self.d_model)
+
+
+class HybridStep(NamedTuple):
+    """One dispatch of the mixed step as every layer sees it."""
+    tables: jax.Array          # [S, pages] the full layer's
+    wtables: jax.Array         # [S, pages] the window layers'
+    lens: jax.Array
+    act: jax.Array             # [S] bool
+    chunk_slot: jax.Array
+    chunk_start: jax.Array
+    chunk_len: jax.Array
+    slots: int                 # S
+    chunk: int                 # C (static; 0 = the decode-only shape)
+
+
+class HybridSSMLM(TransformerLM):
+    """``TransformerLM``'s surface (``init`` / ``apply`` / ``generate()``'s
+    cache / ``init_paged_cache`` / ``_apply_paged_mixed`` /
+    ``partition_specs``) for the hybrid block."""
+
+    #: the block tables a slot has, in the order the engine lays them
+    #: side by side in its per-slot operand
+    TABLE_KINDS = ("full", "window")
+    #: what ``_apply_paged_mixed`` counts a dispatch, each added up where
+    #: the work is handed to its kernel (a layer that walked more, or a
+    #: row that was not spared, moves it): keys the eight walks over the
+    #: FULL layer's pages were handed, keys the window layers' walks were,
+    #: (row, state-space layer) pairs through the chunk scan and through
+    #: the decode update, live rows that stopped before the cross decoder,
+    #: chunks that started a slot's state from zero
+    PAGED_COUNTERS = ("kv_tokens_read_full", "kv_tokens_read_window",
+                      "ssm_chunk_rows", "ssm_decode_rows",
+                      "cross_rows_spared", "state_slots_started")
+
+    def __init__(self, config: HybridSSMConfig, constrain=None,
+                 block_transform=None):
+        super().__init__(config, constrain, block_transform)
+        c = config
+        if c.num_layers != len(c.layer_kinds):
+            raise ValueError(
+                f"num_layers {c.num_layers} is not 2 x ({c.pairs_self} + 1 "
+                f"+ {c.pairs_cross}) layers of the pattern")
+        if c.pos_embedding != "none" or c.norm_type != "layernorm" \
+                or not c.tie_embeddings:
+            raise ValueError(
+                "the hybrid block has no positional encoding, LayerNorms "
+                "and a tied head (models.transformer.phi4_flash_config)")
+        self._sm_scale = 1.0 / math.sqrt(c.hdim)
+
+    # -- refusals ----------------------------------------------------------
+    def training_refusal(self) -> Optional[str]:
+        return ("the hybrid state-space block serves and does not train: "
+                "the selective scan (ops/transformer/ssm_scan.py) has no "
+                "backward kernel, and its window layers have no flash "
+                "path (ROADMAP B7, B11)")
+
+    def prefix_cache_refusal(self) -> Optional[str]:
+        return ("a prefix-cache hit would resume a prompt at a block "
+                "boundary, and the state-space layers' recurrent state at "
+                "that boundary is not snapshotted anywhere (ROADMAP B18): "
+                "the prefix cache is off for this block")
+
+    def padded_prompt_refusal(self) -> Optional[str]:
+        return ("prompt_bucket pads a prompt on the right, and the padding "
+                "would run through the state-space layers' recurrent state")
+
+    def tp_serving_view(self, model_shards, tp_axis, dp_axis):
+        if model_shards > 1 or dp_axis is not None:
+            raise NotImplementedError(self.paged_refusal(
+                mesh_model=model_shards, mesh_data=2 if dp_axis else 1))
+        return self
+
+    def _paged_supported(self) -> Optional[str]:
+        return None
+
+    def paged_refusal(self, kv_bits: int = 0, spec: bool = False,
+                      mesh_model: int = 1, mesh_data: int = 1,
+                      host_cache: bool = False,
+                      weight_quant: bool = False) -> Optional[str]:
+        if spec:
+            return ("the speculative lane: a rejected draft token would "
+                    "have to roll the state-space layers' recurrent state "
+                    "back, and the state keeps no history")
+        if kv_bits:
+            return (f"serving.kv_cache_bits={kv_bits}: the window layers' "
+                    f"walk starts inside a slot's table, and the quantized "
+                    f"pool's scale rows take no first page")
+        if host_cache:
+            return ("serving.host_cache: the host tier moves pages of k "
+                    "and v; a slot's recurrent state is not a page and has "
+                    "no digest to be found under")
+        if mesh_model > 1 or mesh_data > 1:
+            return ("the hybrid state-space block serves on one chip: its "
+                    "recurrent state is indexed by slot and its scan has "
+                    "no partitioning rule — use serving.mesh data=1, "
+                    "model=1 and replicas behind the router")
+        if weight_quant:
+            return ("int8 weight-only serving (quant.enabled): the block's "
+                    "scans do not dequantize a layer at a time")
+        return None
+
+    # -- init --------------------------------------------------------------
+    def _norm_init(self):
+        return L.layernorm_init(None, self.config.d_model,
+                                self.config.param_dtype)
+
+    def _mlp_init(self, k) -> Dict:
+        c, dt = self.config, self.config.param_dtype
+        k1, k2 = jax.random.split(k)
+        return {"gate_up": L.dense_init(k1, c.d_model, 2 * c.ff_dim, False,
+                                        0.02, dt),
+                "down": L.dense_init(k2, c.ff_dim, c.d_model, False, 0.02,
+                                     dt)}
+
+    def _shell_init(self, k, mixer: Dict) -> Dict:
+        return {"ln1": self._norm_init(), "mixer": mixer,
+                "ln2": self._norm_init(), "mlp": self._mlp_init(k)}
+
+    def init_layer(self, kind: str, k) -> Dict:
+        """One layer of ``kind`` (no leading stack axis)."""
+        c, dt = self.config, self.config.param_dtype
+        d, di, n, r = c.d_model, c.d_inner, c.ssm_state, c.dt_rank
+        ks = jax.random.split(k, 8)
+        if kind == SSM:
+            # steps log-uniform in [1e-3, 1e-1]; b_dt their inverse
+            # softplus, so that softplus(b_dt) is the step at r = 0
+            step = jnp.exp(jax.random.uniform(ks[5], (di,))
+                           * (math.log(1e-1) - math.log(1e-3))
+                           + math.log(1e-3))
+            mixer = {
+                "in_proj": L.dense_init(ks[1], d, 2 * di, False, 0.02, dt),
+                # the family's own default for its depthwise Conv1d:
+                # uniform in +-1 / sqrt(taps)
+                "conv_w": jax.random.uniform(
+                    ks[2], (c.ssm_conv, di), minval=-1.0, maxval=1.0
+                ).astype(dt) / math.sqrt(c.ssm_conv),
+                "conv_b": jnp.zeros((di,), dt),
+                "x_proj": L.dense_init(ks[3], di, r + 2 * n, False, 0.02,
+                                       dt),
+                "dt_proj": {
+                    "kernel": L.normal_init(ks[4], (r, di), r ** -0.5, dt),
+                    "bias": (step + jnp.log(-jnp.expm1(-step))).astype(dt)},
+                "a_log": jnp.broadcast_to(
+                    jnp.log(jnp.arange(1, n + 1, dtype=jnp.float32)),
+                    (di, n)).astype(dt),
+                "d_skip": jnp.ones((di,), dt),
+                "out_proj": L.dense_init(ks[6], di, d, False, 0.02, dt)}
+        elif kind in (WINDOW, FULL):
+            mixer = {"qkv": L.dense_init(ks[1], d, c.qkv_dim, True, 0.02,
+                                         dt),
+                     "out": L.dense_init(ks[2], c.num_heads * c.hdim, d,
+                                         True, 0.02, dt)}
+        elif kind == GMU:
+            mixer = {"w1": L.dense_init(ks[1], d, di, False, 0.02, dt),
+                     "w2": L.dense_init(ks[2], di, d, False, 0.02, dt)}
+        elif kind == CROSS:
+            mixer = {"q": L.dense_init(ks[1], d, c.num_heads * c.hdim, True,
+                                       0.02, dt),
+                     "out": L.dense_init(ks[2], c.num_heads * c.hdim, d,
+                                         True, 0.02, dt)}
+        else:
+            raise ValueError(f"no layer kind {kind!r}")
+        return self._shell_init(ks[0], mixer)
+
+    def init_pair(self, kinds: Tuple[str, str], k) -> Dict:
+        ka, kb = jax.random.split(k)
+        return {"a": self.init_layer(kinds[0], ka),
+                "b": self.init_layer(kinds[1], kb)}
+
+    def pair_keys(self, rng) -> Dict[str, jax.Array]:
+        """Per-pair init keys of the three parts of the stack: part ``p``
+        of ``init()`` is ``init_pair`` of ``keys[p][i]``."""
+        c = self.config
+        ka, km, kb = jax.random.split(jax.random.split(rng, 8)[1], 3)
+        return {"self": jax.random.split(ka, c.pairs_self), "mid": km[None],
+                "cross": jax.random.split(kb, c.pairs_cross)}
+
+    #: the two kinds of layer in a pair of each part of the stack
+    PARTS = {"self": (SSM, WINDOW), "mid": (SSM, FULL),
+             "cross": (GMU, CROSS)}
+
+    def init(self, rng) -> Dict:
+        params = self.init_resident(rng)
+        keys = self.pair_keys(rng)
+        for part, kinds in self.PARTS.items():
+            stack = jax.vmap(lambda k, kinds=kinds: self.init_pair(
+                kinds, k))(keys[part])
+            params[part] = (jax.tree_util.tree_map(lambda a: a[0], stack)
+                            if part == "mid" else stack)
+        return params
+
+    def partition_specs(self, params=None) -> Dict:
+        """Everything replicated: the block serves on one chip."""
+        if params is None:
+            params = jax.eval_shape(lambda: self.init(jax.random.PRNGKey(0)))
+        return jax.tree_util.tree_map(lambda a: P(*([None] * a.ndim)),
+                                      params)
+
+    # -- what every path shares --------------------------------------------
+    def _glu_mlp(self, p, x):
+        with jax.named_scope("mlp"):
+            g, u = jnp.split(L.dense_apply(p["gate_up"], x), 2, axis=-1)
+            return L.dense_apply(p["down"], jax.nn.silu(g) * u)
+
+    def _shell(self, bp, x, mixer):
+        """``x + mixer(LN x)``, then ``+ MLP(LN' ..)``; ``mixer(p, h) ->
+        (out, aux)``; returns ``(x, aux)``."""
+        norm = self._norm_fn()
+        out, aux = mixer(bp["mixer"], norm(bp["ln1"], x))
+        with jax.named_scope("residual"):
+            x = x + out
+        m = self._glu_mlp(bp["mlp"], norm(bp["ln2"], x))
+        with jax.named_scope("residual"):
+            return x + m, aux
+
+    def _ssm_rows(self, p, conv):
+        """The convolved rows ``conv [.., d_inner]`` (before silu) ->
+        ``(c, step, B, C)``: c in the activations' type, the rest
+        float32."""
+        cfg = self.config
+        n, r = cfg.ssm_state, cfg.dt_rank
+        c = jax.nn.silu(conv + p["conv_b"].astype(conv.dtype))
+        xp = jnp.einsum("...i,io->...o", c,
+                        p["x_proj"]["kernel"].astype(c.dtype),
+                        preferred_element_type=jnp.float32)
+        # the step enters exp(step * A) at every row: its small product
+        # (dt_rank wide) keeps float32's own precision on the chip
+        step = jax.nn.softplus(
+            jnp.einsum("...r,ri->...i", xp[..., :r],
+                       p["dt_proj"]["kernel"].astype(jnp.float32),
+                       precision=jax.lax.Precision.HIGHEST)
+            + p["dt_proj"]["bias"].astype(jnp.float32))
+        return c, step, xp[..., r:r + n], xp[..., r + n:]
+
+    def _ssm_consts(self, p):
+        return (-jnp.exp(p["a_log"].astype(jnp.float32)),
+                p["d_skip"].astype(jnp.float32))
+
+    def _ssm_out(self, p, y, z):
+        with jax.named_scope("ssm_proj"):
+            return L.dense_apply(
+                p["out_proj"],
+                (y * jax.nn.silu(z.astype(jnp.float32))).astype(z.dtype))
+
+    def _gmu(self, p, h, m):
+        with jax.named_scope("gmu"):
+            g = jax.nn.silu(L.dense_apply(p["w1"], h))
+            return L.dense_apply(p["w2"], m.astype(h.dtype) * g)
+
+    # -- full sequences and generate()'s dense cache -----------------------
+    def init_cache(self, batch: int, max_len: int, dtype=None) -> Dict:
+        """``generate()``'s cache: k and v of the window layers and the
+        full layer at full length (the window is a mask there), and each
+        state-space layer's convolution tail and state, in the
+        equations' shapes."""
+        c = self.config
+        dtype = dtype or c.dtype
+        kv = (c.ssm_layers, batch, max_len, c.kv_heads, c.hdim)
+        return {"k": jnp.zeros(kv, dtype), "v": jnp.zeros(kv, dtype),
+                "conv": jnp.zeros((c.ssm_layers, batch, c.ssm_conv - 1,
+                                   c.d_inner), dtype),
+                "ssm": jnp.zeros((c.ssm_layers, batch, c.d_inner,
+                                  c.ssm_state), jnp.float32),
+                "index": jnp.array(0, jnp.int32)}
+
+    def _ssm_dense(self, p, h, tail, state):
+        """A state-space mixer over ``h [B, T, d]`` from ``tail [B, k-1,
+        d_inner]`` and ``state [B, d_inner, n]``: ``(out, y, new tail,
+        new state)``.  Plain XLA, a loop over positions."""
+        k = self.config.ssm_conv
+        with jax.named_scope("ssm_proj"):
+            u, z = jnp.split(L.dense_apply(p["in_proj"], h), 2, axis=-1)
+            padded = jnp.concatenate([tail.astype(u.dtype), u], axis=1)
+            t = u.shape[1]
+            w = p["conv_w"].astype(u.dtype)
+            conv = sum(w[j] * padded[:, k - 1 - j:k - 1 - j + t]
+                       for j in range(k))
+            c, step, bm, cm = self._ssm_rows(p, conv)
+        a, d_skip = self._ssm_consts(p)
+        with jax.named_scope("ssm_scan"):
+            y, state = jax.vmap(
+                lambda *xs: ssm_scan.ssm_scan_reference(*xs[:4], a, d_skip,
+                                                        xs[4])
+            )(c, step, bm, cm, state)
+        return self._ssm_out(p, y, z), y, padded[:, t:], state
+
+    def _attend_dense(self, q, k, v, q_pos, window: Optional[int]):
+        """q ``[B, Tq, H, hd]`` at positions ``q_pos [Tq]`` against k, v
+        ``[B, Tk, Hkv, hd]`` at positions ``0 .. Tk - 1``."""
+        k_pos = jnp.arange(k.shape[1])
+        seen = k_pos[None, :] <= q_pos[:, None]
+        if window is not None:
+            seen = seen & (k_pos[None, :] > q_pos[:, None] - window)
+        with jax.named_scope("attn_kernel"):
+            return L.gqa_attention(q, k, v, causal=False, scale=self._sm_scale,
+                                   mask=seen[None, None, None])
+
+    def _forward(self, params, x, cache=None):
+        """Every layer over ``x [B, T, d]``; ``cache`` as
+        :meth:`init_cache` gives it (``None``: a whole sequence from
+        nothing).  Returns ``(x, new cache or None)``."""
+        c = self.config
+        b, t, _ = x.shape
+        nh, nkv, hd = c.num_heads, c.kv_heads, c.hdim
+        idx = 0 if cache is None else cache["index"]
+        q_pos = idx + jnp.arange(t)
+        if cache is None:
+            cache = self.init_cache(b, t, x.dtype)
+        cache = dict(cache)
+
+        def ssm_at(i):
+            def mixer(p, h):
+                out, y, tail, state = self._ssm_dense(
+                    p, h, cache["conv"][i], cache["ssm"][i])
+                cache["conv"] = cache["conv"].at[i].set(
+                    tail.astype(cache["conv"].dtype))
+                cache["ssm"] = cache["ssm"].at[i].set(state)
+                return out, y
+            return mixer
+
+        def attn_at(i, window):
+            def mixer(p, h):
+                with jax.named_scope("attn_proj"):
+                    qkv = L.dense_apply(p["qkv"], h)
+                    q, k, v = jnp.split(
+                        qkv, [nh * hd, (nh + nkv) * hd], axis=-1)
+                cache["k"] = cache["k"].at[i].set(
+                    jax.lax.dynamic_update_slice_in_dim(
+                        cache["k"][i], k.reshape(b, t, nkv, hd).astype(
+                            cache["k"].dtype), idx, 1))
+                cache["v"] = cache["v"].at[i].set(
+                    jax.lax.dynamic_update_slice_in_dim(
+                        cache["v"][i], v.reshape(b, t, nkv, hd).astype(
+                            cache["v"].dtype), idx, 1))
+                o = self._attend_dense(q.reshape(b, t, nh, hd),
+                                       cache["k"][i], cache["v"][i], q_pos,
+                                       window)
+                with jax.named_scope("attn_proj"):
+                    return L.dense_apply(p["out"],
+                                         o.reshape(b, t, nh * hd)), None
+            return mixer
+
+        def cross(p, h):
+            with jax.named_scope("attn_proj"):
+                q = L.dense_apply(p["q"], h).reshape(b, t, nh, hd)
+            o = self._attend_dense(q, cache["k"][-1], cache["v"][-1], q_pos,
+                                   None)
+            with jax.named_scope("attn_proj"):
+                return L.dense_apply(p["out"], o.reshape(b, t, nh * hd)), None
+
+        def pair_at(part, i):
+            return jax.tree_util.tree_map(lambda a: a[i], params[part])
+
+        # an unrolled loop: the dense path is the tests' and generate()'s
+        for i in range(c.pairs_self):
+            bp = self.block_transform(pair_at("self", i))
+            x, _ = self._shell(bp["a"], x, ssm_at(i))
+            x, _ = self._shell(bp["b"], x, attn_at(i, c.sliding_window))
+        mid = self.block_transform(params["mid"])
+        x, m = self._shell(mid["a"], x, ssm_at(c.pairs_self))
+        x, _ = self._shell(mid["b"], x, attn_at(c.pairs_self, None))
+        for i in range(c.pairs_cross):
+            bp = self.block_transform(pair_at("cross", i))
+            x, _ = self._shell(bp["a"], x,
+                               lambda p, h: (self._gmu(p, h, m), None))
+            x, _ = self._shell(bp["b"], x, cross)
+        cache["index"] = idx + t
+        return x, cache
+
+    def hidden_states_and_aux(self, params, input_ids, token_type_ids=None):
+        x, _ = self._forward(params, self._embed_tokens(params, input_ids))
+        return (self._norm_fn("head")(params["ln_f"], x),
+                jnp.zeros((), jnp.float32))
+
+    def apply(self, params, input_ids, cache=None, positions=None,
+              token_type_ids=None):
+        if cache is None:
+            x, _ = self.hidden_states_and_aux(params, input_ids)
+            return self._project(params, x)
+        x, cache = self._forward(
+            params, self._embed_tokens(params, input_ids), cache)
+        return (self._project(params,
+                              self._norm_fn("head")(params["ln_f"], x)),
+                cache)
+
+    # -- paged serving -----------------------------------------------------
+    def window_pages(self, block_size: int, chunk_tokens: int
+                     ) -> Tuple[int, int]:
+        """Pages of a window layer a slot holds at most: while decoding
+        (its window's keys), and while a chunk of ``chunk_tokens`` rows
+        is in flight (the first row's window to the last row)."""
+        w = self.config.sliding_window
+        return ((w - 1) // block_size + 2,
+                (w - 1 + chunk_tokens - 1) // block_size + 2)
+
+    def init_paged_cache(self, num_blocks: int, block_size: int,
+                         dtype=None, kv_bits: int = 0) -> Dict:
+        """The FULL layer's pool: one layer of ``num_blocks`` pages, k and
+        v ``[1, num_blocks, block, kv_heads * head_dim]``; everything else
+        a slot keeps is :meth:`init_paged_extra`'s."""
+        reason = self.paged_refusal(kv_bits=kv_bits)
+        if reason is not None:
+            raise NotImplementedError(reason)
+        c = self.config
+        shape = (1, num_blocks, block_size, c.kv_heads * c.hdim)
+        dtype = dtype or c.dtype
+        return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+
+    def init_paged_extra(self, num_slots: int, block_size: int,
+                         window_blocks: int, dtype=None) -> Dict:
+        """What a slot keeps besides the full layer's pages: the window
+        layers' pool (``wk`` / ``wv``, ``pairs_self`` layers of
+        ``window_blocks`` pages, block 0 the null block) and, by slot,
+        every state-space layer's convolution tail (``conv [taps - 1,
+        layers x slots, d_inner]``, the activations' type) and state
+        (``ssm [layers x slots, ..]``, float32, tiled)."""
+        c = self.config
+        dtype = dtype or c.dtype
+        wshape = (c.pairs_self, window_blocks, block_size,
+                  c.kv_heads * c.hdim)
+        tiles, lanes = ssm_scan.tiling(c.d_inner)
+        return {"wk": jnp.zeros(wshape, dtype),
+                "wv": jnp.zeros(wshape, dtype),
+                "conv": jnp.zeros((c.ssm_conv - 1, c.ssm_layers * num_slots,
+                                   c.d_inner), dtype),
+                "ssm": jnp.zeros((c.ssm_layers * num_slots, tiles,
+                                  c.ssm_state, ssm_scan.SUBLANES, lanes),
+                                 jnp.float32)}
+
+    def slot_state(self, extra: Dict, slot: int, num_slots: int) -> jax.Array:
+        """A slot's states out of ``extra`` in the equations' shape,
+        ``[state-space layers, d_inner, state]`` (a check's read-back)."""
+        s = extra["ssm"].reshape(self.config.ssm_layers, num_slots,
+                                 *extra["ssm"].shape[1:])[:, slot]
+        return ssm_scan.state_from_tiles(s)
+
+    def _ssm_paged(self, p, h, conv_buf, ssm_buf, layer, st: HybridStep):
+        """A state-space mixer in the mixed step: the decode rows each
+        from their slot's tail and state, the chunk from its slot's (zero
+        where the chunk starts a prompt); ``h [1, S + C, d]``.  Returns
+        ``(out, y [S + C, d_inner], conv_buf, ssm_buf, rows)``, ``rows``
+        what the scans were handed: ``[the chunk kernel's valid rows, the
+        decode lane's live rows, chunks begun from zero state]``."""
+        cfg = self.config
+        k = cfg.ssm_conv
+        s, cw = st.slots, st.chunk
+        with jax.named_scope("ssm_proj"):
+            u, z = jnp.split(L.dense_apply(p["in_proj"], h[0]), 2, axis=-1)
+            w = p["conv_w"].astype(u.dtype)
+        with jax.named_scope("state_io"):
+            at = layer * s
+            tails = jax.lax.dynamic_slice_in_dim(conv_buf, at, s, axis=1)
+            states = jax.lax.dynamic_slice_in_dim(ssm_buf, at, s)
+        with jax.named_scope("ssm_proj"):
+            win = jnp.concatenate([tails, u[None, :s]])       # [k, S, Di]
+            conv = [sum(w[j] * win[k - 1 - j] for j in range(k))]
+            if cw:
+                fresh = st.chunk_start == 0
+                ctail = jnp.where(fresh, 0, tails[:, st.chunk_slot])
+                padded = jnp.concatenate([ctail, u[s:]])
+                conv.append(sum(w[j] * padded[k - 1 - j:k - 1 - j + cw]
+                                for j in range(k)))
+            c, step, bm, cm = self._ssm_rows(p, jnp.concatenate(conv))
+        a, d_skip = self._ssm_consts(p)
+        with jax.named_scope("ssm_scan"):
+            y, new = ssm_scan.ssm_decode_update(
+                c[:s], step[:s], bm[:s], cm[:s], a, d_skip, states)
+            act = st.act.reshape(s, 1, 1, 1, 1)
+            new = jnp.where(act, new, states)
+            rows = [jnp.int32(0), jnp.sum(st.act.astype(jnp.int32)),
+                    jnp.int32(0)]
+            if cw:
+                state0 = jnp.where(fresh, 0.0, states[st.chunk_slot])
+                yc, state1 = ssm_scan.ssm_chunk_scan(
+                    c[s:], step[s:], bm[s:], cm[s:], a, d_skip, state0,
+                    st.chunk_len)
+                y = jnp.concatenate([y, yc])
+                rows[0] = st.chunk_len
+                rows[2] = (fresh & (st.chunk_len > 0)).astype(jnp.int32)
+        with jax.named_scope("state_io"):
+            tails = jnp.where(st.act[None, :, None], win[1:], tails)
+            if cw:
+                rides = st.chunk_len > 0
+                tails = tails.at[:, st.chunk_slot].set(jnp.where(
+                    rides, jax.lax.dynamic_slice_in_dim(
+                        padded, st.chunk_len, k - 1),
+                    tails[:, st.chunk_slot]))
+                new = new.at[st.chunk_slot].set(jnp.where(
+                    rides, state1, new[st.chunk_slot]))
+            conv_buf = jax.lax.dynamic_update_slice_in_dim(
+                conv_buf, tails.astype(conv_buf.dtype), at, 1)
+            ssm_buf = jax.lax.dynamic_update_slice_in_dim(ssm_buf, new, at,
+                                                          0)
+        return (self._ssm_out(p, y, z)[None], y, conv_buf, ssm_buf,
+                jnp.stack(rows).astype(jnp.int32))
+
+    @staticmethod
+    def _write_rows(pool_k, pool_v, k, v, tables, st: HybridStep, null):
+        """The step's new k / v rows ``[S + C, lanes]`` into the pools at
+        their slots' pages (``tables`` already offset to the layer; masked
+        rows to the layer's null block ``null``)."""
+        blk = pool_k.shape[1]
+        npages = tables.shape[1]
+        null_row = null * blk
+        slot = jnp.arange(st.slots)
+        write = [jnp.where(st.act, tables[slot, st.lens // blk] * blk
+                           + st.lens % blk, null_row)]
+        if st.chunk:
+            ci = jnp.arange(st.chunk)
+            cpos = st.chunk_start + ci
+            ctable = tables[st.chunk_slot]
+            write.append(jnp.where(
+                ci < st.chunk_len,
+                ctable[jnp.minimum(cpos // blk, npages - 1)] * blk
+                + cpos % blk, null_row))
+        write = jnp.concatenate(write)
+
+        def put(pool, rows):
+            return pool.reshape(-1, pool.shape[2]).at[write].set(
+                rows.astype(pool.dtype)).reshape(pool.shape)
+        return put(pool_k, k), put(pool_v, v)
+
+    def _window_paged(self, p, h, wk, wv, off, st: HybridStep):
+        """A window-attention mixer in the mixed step: every row writes
+        its k / v into the layer's pages (``off``: its block offset into
+        the window pool), then the decode rows and the chunk attend the
+        keys their windows reach.  Returns ``(out, wk, wv, the keys the
+        two walks were handed)``."""
+        from ..ops.transformer.paged_decode_attention import (
+            paged_decode_attention, paged_prefill_attention)
+        c = self.config
+        nh, nkv, hd, s = c.num_heads, c.kv_heads, c.hdim, st.slots
+        with jax.named_scope("attn_proj"):
+            qkv = L.dense_apply(p["qkv"], h[0])
+            q, k, v = jnp.split(qkv, [nh * hd, (nh + nkv) * hd], axis=-1)
+            q = q.reshape(-1, nh, hd)
+        with jax.named_scope("pool_write"):
+            tables = st.wtables + off
+            wk, wv = self._write_rows(wk, wv, k, v, tables, st, off)
+        with jax.named_scope("attn_kernel"):
+            w = c.sliding_window
+            lengths = jnp.where(st.act, st.lens + 1, 0)
+            o = [paged_decode_attention(
+                q[:s], wk, wv, lengths, tables, sm_scale=self._sm_scale,
+                window=w)]
+            read = jnp.sum(jnp.minimum(lengths, w))
+            if st.chunk:
+                o.append(paged_prefill_attention(
+                    q[s:], wk, wv, st.chunk_start, st.chunk_len,
+                    tables[st.chunk_slot], sm_scale=self._sm_scale,
+                    window=w, tile_rows=CHUNK_TILE_ROWS))
+                # the first row's window to the last row
+                read += jnp.where(
+                    st.chunk_len > 0, st.chunk_start + st.chunk_len
+                    - jnp.maximum(st.chunk_start - (w - 1), 0), 0)
+            o = jnp.concatenate(o) if st.chunk else o[0]
+        with jax.named_scope("attn_proj"):
+            return (L.dense_apply(p["out"], o.reshape(1, -1, nh * hd)), wk,
+                    wv, read.astype(jnp.int32))
+
+    def _yield_rows(self, a, st: HybridStep):
+        """The rows of ``a [S + C, ..]`` that yield a token: the decode
+        rows and the chunk's last valid row."""
+        if not st.chunk:
+            return a
+        last = jax.lax.dynamic_slice_in_dim(
+            a, st.slots + jnp.maximum(st.chunk_len - 1, 0), 1, axis=0)
+        return jnp.concatenate([a[:st.slots], last])
+
+    def _full_walk(self, q, pool_k, pool_v, st: HybridStep):
+        """The yield rows' queries ``[S (+ 1), H, hd]`` against the full
+        layer's pages: each is one more one-row walker.  Returns ``(o,
+        the keys the walk was handed)``."""
+        from ..ops.transformer.paged_decode_attention import (
+            paged_decode_attention)
+        lengths = jnp.where(st.act, st.lens + 1, 0)
+        tables = st.tables
+        if st.chunk:
+            lengths = jnp.append(lengths, jnp.where(
+                st.chunk_len > 0, st.chunk_start + st.chunk_len, 0))
+            tables = jnp.concatenate([tables, tables[st.chunk_slot][None]])
+        with jax.named_scope("attn_kernel"):
+            return (paged_decode_attention(q, pool_k, pool_v, lengths, tables,
+                                           sm_scale=self._sm_scale),
+                    jnp.sum(lengths).astype(jnp.int32))
+
+    def _cross_paged(self, p, h, pool_k, pool_v, st: HybridStep):
+        """A cross-attention mixer in the mixed step: the yield rows'
+        queries against the full layer's pages.  Returns ``(out, (the
+        attention's output ``[S (+ 1), H x hd]``, the keys handed))``."""
+        c = self.config
+        with jax.named_scope("attn_proj"):
+            q = L.dense_apply(p["q"], h[0]).reshape(-1, c.num_heads, c.hdim)
+        o, read = self._full_walk(q, pool_k, pool_v, st)
+        o = o.reshape(1, -1, c.num_heads * c.hdim)
+        with jax.named_scope("attn_proj"):
+            return L.dense_apply(p["out"], o), (o[0], read)
+
+    def _apply_paged_mixed(self, params, cache, dec_tokens, dec_active,
+                           chunk_ids, chunk_slot, chunk_start, chunk_len,
+                           spec_tokens=None, spec_active=None, probe=False):
+        """The mixed step of ``TransformerLM._apply_paged_mixed`` for the
+        hybrid block: same operands, same results.  ``cache``: ``k`` /
+        ``v`` the full layer's pool ``[1, nb, block, lanes]``, ``extra``
+        as :meth:`init_paged_extra`, ``block_tables [S, 2 pages]`` (the
+        full layer's table, then the window layers'), ``lens``.  Three
+        scans over one skeleton: the (state space, window) pairs, the
+        middle pair, and — on the rows that yield a token only — the
+        (memory unit, cross) pairs, with the memory ``m`` and the full
+        pool as loop constants.  ``new_cache`` also holds ``counters``
+        (``PAGED_COUNTERS``: added up from what each layer's kernels were
+        handed) and with ``probe`` (a check's, never the engine's)
+        ``probe``: what the eight walks over the full layer's pages gave
+        the yield rows, ``reads [1 + pairs_cross, S (+ 1), H x hd]``."""
+        if spec_tokens is not None:
+            raise NotImplementedError(self.paged_refusal(spec=True))
+        if cache.get("k_scale") is not None:
+            raise NotImplementedError(self.paged_refusal(kv_bits=8))
+        c = self.config
+        nh, hd = c.num_heads, c.hdim
+        extra = cache["extra"]
+        s, cw = dec_tokens.shape[0], chunk_ids.shape[0]
+        pages = cache["block_tables"].shape[1] // 2
+        with jax.named_scope("embed"):
+            st = HybridStep(cache["block_tables"][:, :pages],
+                            cache["block_tables"][:, pages:], cache["lens"],
+                            dec_active > 0, chunk_slot, chunk_start,
+                            chunk_len, s, cw)
+            ids = jnp.concatenate([dec_tokens, chunk_ids])[None]
+        x = self._embed_tokens(params, ids)
+        nbw = extra["wk"].shape[1]
+        wk = extra["wk"].reshape(-1, *extra["wk"].shape[2:])
+        wv = extra["wv"].reshape(-1, *extra["wv"].shape[2:])
+
+        def ssm_mixer(layer, state):
+            def mixer(p, h):
+                out, y, conv_buf, ssm_buf, state["rows"] = self._ssm_paged(
+                    p, h, state["conv"], state["ssm"], layer, st)
+                state["conv"], state["ssm"] = conv_buf, ssm_buf
+                return out, y
+            return mixer
+
+        def self_pair(carry, xs):
+            x, wk, wv, conv_buf, ssm_buf = carry
+            bp, layer = xs
+            bp = self.block_transform(bp)
+            state = {"conv": conv_buf, "ssm": ssm_buf}
+            x, _ = self._shell(bp["a"], x, ssm_mixer(layer, state))
+            pools = {}
+
+            def window(p, h):
+                with jax.named_scope("pool_write"):
+                    off = layer * nbw
+                out, pools["k"], pools["v"], read = self._window_paged(
+                    p, h, wk, wv, off, st)
+                return out, read
+            x, read = self._shell(bp["b"], x, window)
+            return (x, pools["k"], pools["v"], state["conv"],
+                    state["ssm"]), (state["rows"], read)
+
+        (x, wk, wv, conv_buf, ssm_buf), (ssm_rows, window_read) = \
+            jax.lax.scan(
+                self_pair, (x, wk, wv, extra["conv"], extra["ssm"]),
+                (params["self"], jnp.arange(c.pairs_self, dtype=jnp.int32)))
+
+        # the middle pair: the last state-space layer exports the memory;
+        # the full layer writes EVERY row's key and value, and from its
+        # query on only the rows that yield a token go on
+        mid = self.block_transform(params["mid"])
+        state = {"conv": conv_buf, "ssm": ssm_buf}
+        x, m = self._shell(mid["a"], x,
+                           ssm_mixer(jnp.int32(c.pairs_self), state))
+        pool_k, pool_v = cache["k"][0], cache["v"][0]
+        norm = self._norm_fn()
+        fp = mid["b"]
+        h = norm(fp["ln1"], x)
+        kernel = fp["mixer"]["qkv"]["kernel"]
+        bias = fp["mixer"]["qkv"]["bias"]
+        with jax.named_scope("attn_proj"):
+            kv = (jnp.einsum("ti,io->to", h[0],
+                             kernel[:, nh * hd:].astype(h.dtype))
+                  + bias[nh * hd:].astype(h.dtype))
+            k, v = jnp.split(kv, 2, axis=-1)
+        with jax.named_scope("pool_write"):
+            pool_k, pool_v = self._write_rows(pool_k, pool_v, k, v,
+                                              st.tables, st, 0)
+            live = st.act
+            if cw:
+                live = jnp.concatenate([live, jnp.arange(cw) < chunk_len])
+            # the live rows that stop here: every chunk row but the last
+            spared = (jnp.sum(live.astype(jnp.int32))
+                      - jnp.sum(self._yield_rows(live, st).astype(jnp.int32)))
+        x = self._yield_rows(x[0], st)[None]
+        m = self._yield_rows(m, st)
+
+        def full(p, hy):
+            with jax.named_scope("attn_proj"):
+                q = (jnp.einsum("ti,io->to", hy[0],
+                                kernel[:, :nh * hd].astype(hy.dtype))
+                     + bias[:nh * hd].astype(hy.dtype))
+            o, read = self._full_walk(q.reshape(-1, nh, hd), pool_k, pool_v,
+                                      st)
+            o = o.reshape(1, -1, nh * hd)
+            with jax.named_scope("attn_proj"):
+                return L.dense_apply(p["out"], o), (o[0], read)
+        x, (o_full, full_read) = self._shell(fp, x, full)
+
+        def cross_pair(x, bp):
+            bp = self.block_transform(bp)
+            x, _ = self._shell(bp["a"], x,
+                               lambda p, h: (self._gmu(p, h, m[None]), None))
+            x, (o, read) = self._shell(
+                bp["b"], x,
+                lambda p, h: self._cross_paged(p, h, pool_k, pool_v, st))
+            return x, (o if probe else None, read)
+
+        x, (o_cross, cross_read) = jax.lax.scan(cross_pair, x,
+                                                params["cross"])
+        x = self._norm_fn("head")(params["ln_f"], x)
+        with jax.named_scope("head"):
+            logits = self._project(params, x)[0]
+            dec_logits = logits[:s]
+            chunk_logits = (logits[s] if cw else
+                            jnp.zeros((logits.shape[-1],), logits.dtype))
+        with jax.named_scope("pool_write"):
+            rows = jnp.sum(ssm_rows, axis=0) + state["rows"]
+            counters = jnp.stack([
+                full_read + jnp.sum(cross_read), jnp.sum(window_read),
+                rows[0], rows[1], spared, state["rows"][2]]
+            ).astype(jnp.int32)
+            new_lens = (st.lens + st.act.astype(st.lens.dtype)
+                        ).at[chunk_slot].add(chunk_len, mode="drop")
+        new_extra = {"wk": wk.reshape(extra["wk"].shape),
+                     "wv": wv.reshape(extra["wv"].shape),
+                     "conv": state["conv"], "ssm": state["ssm"]}
+        new_cache = {"k": pool_k[None], "v": pool_v[None],
+                     "extra": new_extra,
+                     "block_tables": cache["block_tables"],
+                     "lens": new_lens, "counters": counters}
+        if probe:
+            new_cache["probe"] = {
+                "reads": jnp.concatenate([o_full[None], o_cross])}
+        return dec_logits, chunk_logits, new_cache

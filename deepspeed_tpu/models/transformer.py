@@ -400,6 +400,32 @@ def zaya_config(size: str = "8b", **kw) -> TransformerConfig:
         **ZAYA_SIZES[size], **kw})
 
 
+PHI4_FLASH_SIZES = {
+    # https://huggingface.co/microsoft/Phi-4-mini-flash-reasoning
+    # config.json (phi4flash); the state-space sizes are the family's
+    # defaults (models/hybrid_ssm.py::HybridSSMConfig)
+    "mini": dict(num_layers=32, pairs_self=8, pairs_cross=7, num_heads=40,
+                 num_kv_heads=20, d_model=2560, d_ff=10240,
+                 vocab_size=200064, max_seq_len=262144, sliding_window=512),
+}
+
+
+def phi4_flash_config(size: str = "mini", **kw) -> TransformerConfig:
+    """Phi-4-mini-flash's language model (``phi4flash``): state-space
+    layers and window-attention layers in pairs, one full-attention layer
+    whose keys and values the cross-attention layers after it re-read,
+    gated memory units between those (``models/hybrid_ssm.py``).  ``size``
+    names a published set of widths; a smaller pattern (``pairs_self``,
+    ``pairs_cross`` with ``num_layers`` = 2 x (their sum + 1)), the
+    vocabulary and the served positions come as keywords."""
+    from .hybrid_ssm import HybridSSMConfig
+    return HybridSSMConfig(**{
+        "pos_embedding": "none", "norm_type": "layernorm",
+        "gated_mlp": True, "activation": "silu", "use_bias": True,
+        "tie_embeddings": True, "layernorm_eps": 1e-5,
+        **PHI4_FLASH_SIZES[size], **kw})
+
+
 def build_model(config: TransformerConfig, **kw) -> "TransformerLM":
     """The model that runs ``config``'s block: ``TransformerLM`` for the
     standard block, the config's own class (``config.model_class()``) for
@@ -418,6 +444,10 @@ class TransformerLM:
     #: names of the int32 counters ``_apply_paged_mixed`` returns under
     #: ``new_cache["counters"]`` (none for the standard block)
     PAGED_COUNTERS: Tuple[str, ...] = ()
+    #: the block tables a slot has in the serving engine's per-slot
+    #: operand, one a kind of paged state (``block_allocator``'s layer
+    #: kinds): every layer's pages under one table for the standard block
+    TABLE_KINDS: Tuple[str, ...] = ("full",)
 
     def __init__(self, config: TransformerConfig,
                  constrain: Optional[Callable] = None,
@@ -1384,6 +1414,24 @@ class TransformerLM:
 
     def training_refusal(self) -> Optional[str]:
         """Why ``ds.initialize`` cannot train this block, or None."""
+        return None
+
+    def prefix_cache_refusal(self) -> Optional[str]:
+        """Why a prefix-cache hit cannot resume a prompt of this block
+        (the serving engine then runs with the cache off), or None."""
+        return None
+
+    def padded_prompt_refusal(self) -> Optional[str]:
+        """Why ``generate()`` cannot pad this block's prompts to a
+        bucket (it then compiles for each prompt length), or None."""
+        return None
+
+    def init_paged_extra(self, num_slots: int, block_size: int,
+                         window_blocks: int, dtype=None) -> Optional[Dict]:
+        """What a slot keeps in the serving engine besides the pool's
+        pages, as a tree the mixed step carries in ``cache["extra"]``
+        (``models/hybrid_ssm.py``); None for a block that keeps nothing
+        else."""
         return None
 
     def paged_refusal(self, kv_bits: int = 0, spec: bool = False,

@@ -98,11 +98,17 @@ PHASE_SPANS = tuple(f"serving/{p}" for p in PHASES)
 #: paged pool; ``expert_layout``: the sort into tiles, gather and combine
 #: around the grouped product (``experts``); ``head``: final norm, logits,
 #: the finite flag; ``zero_comm``: the casts, gathers and scatters that
-#: move state between its partitioned form and the form compute uses.
+#: move state between its partitioned form and the form compute uses;
+#: ``ssm_proj``: a state-space layer's four projections and its causal
+#: convolution; ``ssm_scan``: its recurrence (the chunk's scan kernel, the
+#: decode rows' one-step update); ``gmu``: a gated memory unit's two
+#: products and gate; ``state_io``: what moves a slot's per-sequence state
+#: out of and into the buffer the step carries.
 SCOPES = ("embed", "norm", "residual", "attn_proj", "attn_kernel",
           "pool_write", "mlp", "router", "expert_layout", "experts",
           "shared_expert", "head", "sample", "loss", "optimizer",
-          "zero_comm", "indexer", "select", "attn_conv")
+          "zero_comm", "indexer", "select", "attn_conv", "ssm_proj",
+          "ssm_scan", "gmu", "state_io")
 #: an instruction under no declared scope / one whose key two loaded
 #: programs map to different scopes
 UNNAMED, AMBIGUOUS = "unnamed", "ambiguous"
@@ -143,6 +149,17 @@ COUNTERS = ("dispatches", "decode_rows", "chunk_rows", "rows_computed",
             # layers that took a handed-on selection; 0 for other blocks
             "index_rows", "index_keys_scored", "sparse_tokens_read",
             "sparse_rows_reused",
+            # three kinds of state a slot (models/hybrid_ssm.py), counted
+            # in the program: context tokens read by the layers that
+            # walk the one full layer's pages and by the window layers
+            # (x those layers), (row, state-space layer) pairs through
+            # the chunk scan and the decode update, chunk rows that
+            # stopped before the cross decoder, chunks that started a
+            # slot's state from zero; and on the host the window pages
+            # handed back; 0 for other blocks
+            "kv_tokens_read_full", "kv_tokens_read_window",
+            "ssm_chunk_rows", "ssm_decode_rows", "cross_rows_spared",
+            "state_slots_started", "window_blocks_freed",
             # the dispatch in flight (docs/serving.md): dispatches that
             # were enqueued before their predecessor's result was read,
             # and rows whose result was ignored because their request

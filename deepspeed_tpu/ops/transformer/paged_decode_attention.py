@@ -147,14 +147,17 @@ def _pages_per_program(pool, kv_heads: int, kv_bits: int, rows: int,
 
 
 def _page_group_dma(start, hbm, bufs, sem, bt_ref, row, total, group, half,
-                    *, block, pp):
+                    *, block, pp, first=0):
     """Start (or wait on) the DMAs of one live page group: for each page
     ``group * pp + j`` of slot ``row`` that holds keys below ``total``,
     the WHOLE pool block ``bt[row, page]`` — ``[block, Hkv * De]`` of k
     and of v, and for a quantized pool its ``[Hkv, 1, block]`` scale
     rows — into slot ``j`` of buffer half ``half``, one copy per operand.
     The loop runs over the live pages only, so start and wait count
-    alike; a wait needs the copy's shape and semaphore, not its source."""
+    alike; a wait needs the copy's shape and semaphore, not its source.
+    With a WINDOW the walk has a ``first`` position as well: pages that
+    end at or below it are dead too (their blocks may be another slot's
+    by now), and neither started nor waited on."""
     def page(j, carry):
         bid = bt_ref[row, group * pp + j] if start else 0
         for op, (src, dst) in enumerate(zip(hbm, bufs)):
@@ -163,7 +166,9 @@ def _page_group_dma(start, hbm, bufs, sem, bt_ref, row, total, group, half,
             copy.start() if start else copy.wait()
         return carry
     live = jnp.clip(-(-(total - group * pp * block) // block), 0, pp)
-    jax.lax.fori_loop(0, live, page, 0)
+    dead = 0 if isinstance(first, int) and first == 0 else jnp.clip(
+        (first - group * pp * block) // block, 0, pp)
+    jax.lax.fori_loop(dead, live, page, 0)
 
 
 def _unpack(x, kv_bits):
@@ -179,7 +184,7 @@ def _unpack(x, kv_bits):
 
 
 def _kernel(meta_ref, bt_ref, coff_ref, rhead_ref, q_ref, *refs, sm_scale,
-            block, pp, kv_bits, width, heads):
+            block, pp, kv_bits, width, heads, window_keys=None):
     """Online-softmax walk over one slot's live page groups, every head
     window inside the step.
 
@@ -187,7 +192,12 @@ def _kernel(meta_ref, bt_ref, coff_ref, rhead_ref, q_ref, *refs, sm_scale,
     groups, live steps before this slot, next live slot after this one
     or B).  Query row ``c`` sits at absolute position ``base +
     c``, sees keys ``<=`` its own position, and nothing at or past
-    ``total`` is attended.  q_ref ``[nwin, nsplit, R, W]``: per head
+    ``total`` is attended.  With ``window_keys`` (static) a row sees only
+    the ``window_keys`` keys that end at its own position, and
+    ``meta_ref`` has two more rows: the slot's first page GROUP (the
+    grid's ``g`` counts from it) and its first attended position, the
+    first row's window start.
+    q_ref ``[nwin, nsplit, R, W]``: per head
     window the block-diagonal queries of its ``heads`` kv heads (``R =
     heads * G * C`` rows; ``coff_ref``/``rhead_ref`` ``[R, 1]`` give each
     row's chunk offset and head-within-window).  VMEM slabs kbuf/vbuf
@@ -209,29 +219,40 @@ def _kernel(meta_ref, bt_ref, coff_ref, rhead_ref, q_ref, *refs, sm_scale,
 
     def fetch(row, group, half, start):
         _page_group_dma(start, hbm, bufs, sem, bt_ref, row,
-                        meta_ref[1, row], group, half, block=block, pp=pp)
+                        meta_ref[1, row], group, half, block=block, pp=pp,
+                        first=(0 if window_keys is None
+                               else meta_ref[6, row]))
 
     # a step at or past its slot's length runs none of this
     @pl.when(g < live_groups)
     def _live():
         step = meta_ref[3, i] + g          # ordinal among the live steps
         half = jax.lax.rem(step, 2)
+        # this step's page group: the grid's g, counted from the group a
+        # window's walk starts at
+        gi = g if window_keys is None else g + meta_ref[5, i]
 
         @pl.when(step == 0)
         def _cold_start():
-            fetch(i, g, half, start=True)
+            fetch(i, gi, half, start=True)
 
         # issue the NEXT LIVE step's fetch before waiting on ours: the
         # pipeline stays full across page groups, slots and dead steps
         more = g + 1 < live_groups
         row1 = jnp.where(more, i, meta_ref[4, i])
-        g1 = jnp.where(more, g + 1, 0)
+        if window_keys is None:
+            g1 = jnp.where(more, g + 1, 0)
+        else:
+            # (a dead row1 is never fetched: the index is clamped for the
+            # look-up of its first group alone)
+            g1 = jnp.where(more, gi + 1,
+                           meta_ref[5, jnp.minimum(row1, nslots - 1)])
 
         @pl.when(row1 < nslots)
         def _prefetch_next():
             fetch(row1, g1, 1 - half, start=True)
 
-        fetch(i, g, half, start=False)
+        fetch(i, gi, half, start=False)
 
         @pl.when(g == 0)
         def _init():
@@ -240,7 +261,7 @@ def _kernel(meta_ref, bt_ref, coff_ref, rhead_ref, q_ref, *refs, sm_scale,
             acc_scr[...] = jnp.zeros_like(acc_scr)
 
         qpos = base + coff_ref[...]                            # [R, 1]
-        pos = g * keys + jax.lax.broadcasted_iota(jnp.int32, (1, keys), 1)
+        pos = gi * keys + jax.lax.broadcasted_iota(jnp.int32, (1, keys), 1)
         in_range = pos < total                                 # [1, keys]
         visible = (pos <= qpos) & in_range                     # [R, keys]
         # masked rows get probability ~0, but 0 * NaN = NaN: zero the v
@@ -248,8 +269,18 @@ def _kernel(meta_ref, bt_ref, coff_ref, rhead_ref, q_ref, *refs, sm_scale,
         # block holding a quarantined request's non-finite KV cannot
         # re-poison its next owner — unfetched pages also leave stale
         # garbage in the buffer
-        v_valid = g * keys + jax.lax.broadcasted_iota(
-            jnp.int32, (keys, 1), 0) < total                   # [keys, 1]
+        vpos = gi * keys + jax.lax.broadcasted_iota(jnp.int32, (keys, 1), 0)
+        v_valid = vpos < total                                 # [keys, 1]
+        if window_keys is not None:
+            # a row sees the `window_keys` keys ending at its own
+            # position; a late row of a chunk meets whole groups it does
+            # not see BEFORE the ones it does: what they add at weight
+            # exp(MASK - MASK) is finite (the pages before the walk's
+            # first position, never fetched, are zeroed like the tail)
+            # and is wiped by alpha = exp(MASK - m) = 0 at its first
+            # visible key
+            visible = visible & (pos > qpos - window_keys)
+            v_valid = v_valid & (vpos >= meta_ref[6, i])
 
         def window(w, carry):
             lanes = pl.ds(pl.multiple_of(w * width, width), width)
@@ -351,10 +382,14 @@ def _check_args(q_heads, d, pool_k, pool_v, k_scale, v_scale, kv_bits,
 
 def _paged_attention(q, pool_k, pool_v, base, total, block_tables, *,
                      sm_scale, interpret, k_scale, v_scale, kv_bits,
-                     pages_per_program, what):
+                     pages_per_program, what, window=None):
     """q [B, C, H, D] — C query rows per slot at absolute positions
     ``base[b] .. base[b] + C - 1``; ``total[b]`` bounds the attended
-    prefix; block_tables [B, pages].  Returns [B, C, H, D]."""
+    prefix; block_tables [B, pages].  ``window`` (static; None = every
+    earlier key): a row attends the ``window`` keys that end at its own
+    position, and the slot's walk starts at the page that holds position
+    ``max(0, base[b] - window + 1)`` — table entries before it are never
+    read.  Returns [B, C, H, D]."""
     b, c, h, d = q.shape
     hkv, d_eff = _check_args(h, d, pool_k, pool_v, k_scale, v_scale,
                              kv_bits, what)
@@ -390,9 +425,18 @@ def _paged_attention(q, pool_k, pool_v, base, total, block_tables, *,
     base = jnp.asarray(base, jnp.int32).reshape(b)
     total = jnp.asarray(total, jnp.int32).reshape(b)
     live = jnp.clip(-(-total // (pp * block)), 0, ngroups)
+    if window is not None:
+        if kv_bits:
+            raise NotImplementedError(
+                f"{what}: a window over a quantized pool (the scale rows' "
+                f"DMAs take no first page)")
+        first = jnp.maximum(base - (window - 1), 0)
+        group0 = jnp.where(total > 0, first // (pp * block), 0)
+        live = live - jnp.minimum(group0, live)
     slot = jnp.where(live > 0, jnp.arange(b, dtype=jnp.int32), b)
     later = jnp.append(jax.lax.cummin(slot, reverse=True)[1:], b)
-    meta = jnp.stack([base, total, live, jnp.cumsum(live) - live, later])
+    meta = jnp.stack([base, total, live, jnp.cumsum(live) - live, later]
+                     + ([] if window is None else [group0, first]))
     block_tables = jnp.asarray(block_tables, jnp.int32)
     if kv_bits == 0:
         q = q.astype(pool_k.dtype)
@@ -428,7 +472,9 @@ def _paged_attention(q, pool_k, pool_v, base, total, block_tables, *,
 
     out = pl.pallas_call(
         functools.partial(_kernel, sm_scale=sm_scale, block=block, pp=pp,
-                          kv_bits=kv_bits, width=width, heads=heads),
+                          kv_bits=kv_bits, width=width, heads=heads,
+                          **({} if window is None
+                             else {"window_keys": window})),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(b, ngroups),
@@ -458,8 +504,8 @@ def paged_decode_attention(q: jnp.ndarray, pool_k: jnp.ndarray,
                            k_scale: Optional[jnp.ndarray] = None,
                            v_scale: Optional[jnp.ndarray] = None,
                            kv_bits: int = 0,
-                           pages_per_program: Optional[int] = None
-                           ) -> jnp.ndarray:
+                           pages_per_program: Optional[int] = None,
+                           window: Optional[int] = None) -> jnp.ndarray:
     """q [B, H, D] (one new token per slot); pool_k/v [num_blocks,
     block, Hkv * De]; lengths [B] int32 (valid tokens per slot INCLUDING
     the just-written one, 0 = inactive); block_tables [B, pages] int32
@@ -474,7 +520,10 @@ def paged_decode_attention(q: jnp.ndarray, pool_k: jnp.ndarray,
     The caller guarantees ``lengths[i] <= pages * block`` and that every
     table entry below ``ceil(lengths[i]/block)`` points at that slot's
     own blocks.  ``pages_per_program`` overrides the auto-picked group
-    width.
+    width.  ``window`` (static): attend only the newest ``window`` tokens
+    — table entries of pages wholly before them are never read, so a
+    window layer's table may hold anything there (the allocator's
+    ``window`` kind hands those blocks on).
     """
     if block_tables.ndim != 2 or block_tables.shape[0] != q.shape[0]:
         raise ValueError(
@@ -485,7 +534,8 @@ def paged_decode_attention(q: jnp.ndarray, pool_k: jnp.ndarray,
         q[:, None], pool_k, pool_v, lengths - 1, lengths, block_tables,
         sm_scale=sm_scale, interpret=interpret, k_scale=k_scale,
         v_scale=v_scale, kv_bits=kv_bits,
-        pages_per_program=pages_per_program, what="paged_decode_attention")
+        pages_per_program=pages_per_program, what="paged_decode_attention",
+        window=window)
     return out[:, 0].astype(q.dtype)
 
 
@@ -498,8 +548,9 @@ def paged_prefill_attention(q: jnp.ndarray, pool_k: jnp.ndarray,
                             k_scale: Optional[jnp.ndarray] = None,
                             v_scale: Optional[jnp.ndarray] = None,
                             kv_bits: int = 0,
-                            pages_per_program: Optional[int] = None
-                            ) -> jnp.ndarray:
+                            pages_per_program: Optional[int] = None,
+                            window: Optional[int] = None,
+                            tile_rows: Optional[int] = None) -> jnp.ndarray:
     """Causal chunked-prefill attention for ONE slot through its block
     table (the Sarathi-Serve mixed-batch building block).
 
@@ -513,20 +564,33 @@ def paged_prefill_attention(q: jnp.ndarray, pool_k: jnp.ndarray,
     already be scattered into the pool at rows base.. (the model does
     this immediately before the call), so the kernel reads every key —
     prior and in-chunk — through one uniform double-buffered page walk.
+    ``tile_rows`` (static; must divide C) cuts the chunk into walkers of
+    that many rows, each walking only as far as its own last row sees
+    (and, with a ``window``, from where its first row's begins): what a
+    chunk of many rows x grouped heads needs to fit VMEM.
     Returns [C, H, D] in q's dtype.
     """
     if block_table.ndim != 1:
         raise ValueError(
             f"block_table must be [pages], got {block_table.shape}")
     base = jnp.asarray(base, jnp.int32)
+    end = base + jnp.asarray(chunk_len, jnp.int32)
+    c = q.shape[0]
+    tiles = 1 if not tile_rows or tile_rows >= c else c // tile_rows
+    if c % tiles:
+        raise ValueError(f"tile_rows {tile_rows} must divide the chunk's "
+                         f"{c} rows")
+    if tiles > 1:
+        base = base + (c // tiles) * jnp.arange(tiles, dtype=jnp.int32)
+        end = jnp.where(base < end, jnp.minimum(end, base + c // tiles), 0)
     out = _paged_attention(
-        q[None], pool_k, pool_v, base, base + jnp.asarray(chunk_len,
-                                                          jnp.int32),
-        block_table[None], sm_scale=sm_scale, interpret=interpret,
+        q.reshape(tiles, c // tiles, *q.shape[1:]), pool_k, pool_v, base,
+        end, jnp.broadcast_to(block_table, (tiles,) + block_table.shape),
+        sm_scale=sm_scale, interpret=interpret,
         k_scale=k_scale, v_scale=v_scale, kv_bits=kv_bits,
         pages_per_program=pages_per_program,
-        what="paged_prefill_attention")
-    return out[0].astype(q.dtype)
+        what="paged_prefill_attention", window=window)
+    return out.reshape(q.shape).astype(q.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -805,7 +869,7 @@ def _reference_cache(pool_k, pool_v, k_scale, v_scale, kv_bits, d):
 
 
 def _reference(q, pool_k, pool_v, base, total, block_tables, k_scale,
-               v_scale, kv_bits):
+               v_scale, kv_bits, window=None):
     """Readable float32 jnp reference for the kernel (tests and the
     on-chip smoke pin against this): per slot, dequantize if needed,
     gather the table's pages into a contiguous cache and run masked
@@ -827,9 +891,14 @@ def _reference(q, pool_k, pool_v, base, total, block_tables, k_scale,
                        k.astype(jnp.float32)) / math.sqrt(d)
         pos = jnp.arange(npages * block)
         qpos = bs + jnp.arange(c)[:, None, None]
-        s = jnp.where((pos <= qpos) & (pos < tot), s, -1e30)
+        seen = (pos <= qpos) & (pos < tot)
+        live = pos < tot
+        if window is not None:
+            seen = seen & (pos > qpos - window)
+            live = live & (pos > bs - window)
+        s = jnp.where(seen, s, -1e30)
         p = jax.nn.softmax(s, axis=-1)
-        v = jnp.where((pos < tot)[:, None, None], v, 0.0)  # NaN-safe
+        v = jnp.where(live[:, None, None], v, 0.0)  # NaN-safe
         return jnp.einsum("chs,shd->chd", p, v.astype(jnp.float32))
 
     return jax.vmap(one)(q, block_tables, base, total)
@@ -837,24 +906,25 @@ def _reference(q, pool_k, pool_v, base, total, block_tables, k_scale,
 
 def paged_prefill_reference(q, pool_k, pool_v, base, chunk_len,
                             block_table, k_scale=None, v_scale=None,
-                            kv_bits=0):
+                            kv_bits=0, window=None):
     """jnp reference for :func:`paged_prefill_attention`.  Padding
     queries (index >= chunk_len) are returned as zeros."""
     base = jnp.asarray(base, jnp.int32)
     out = _reference(q[None], pool_k, pool_v, base[None],
                      (base + chunk_len)[None], block_table[None], k_scale,
-                     v_scale, kv_bits)[0]
+                     v_scale, kv_bits, window)[0]
     valid = (jnp.arange(q.shape[0]) < chunk_len)[:, None, None]
     return jnp.where(valid, out, 0.0).astype(q.dtype)
 
 
 def paged_attention_reference(q, pool_k, pool_v, lengths, block_tables,
-                              k_scale=None, v_scale=None, kv_bits=0):
+                              k_scale=None, v_scale=None, kv_bits=0,
+                              window=None):
     """jnp reference for :func:`paged_decode_attention`.
     O(B·pages·block) gather — test-scale only."""
     lengths = jnp.asarray(lengths, jnp.int32)
     out = _reference(q[:, None], pool_k, pool_v, lengths - 1, lengths,
-                     block_tables, k_scale, v_scale, kv_bits)[:, 0]
+                     block_tables, k_scale, v_scale, kv_bits, window)[:, 0]
     return jnp.where((lengths > 0)[:, None, None], out, 0.0).astype(q.dtype)
 
 

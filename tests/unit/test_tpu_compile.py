@@ -646,6 +646,188 @@ def test_latent_mixed_step_keeps_pool_and_experts_in_place(
     assert temp_bytes < one_expert_stack
 
 
+#: sha256 of the paged kernel's program at Pythia's cell shapes (24 decode
+#: slots; a chunk of 256 rows; 16 heads of 128; 128 pages of 16), stripped
+#: of metadata and names, as the commit BEFORE the kernel took a window
+#: (PR 47, ba84aa4) compiles it.  A PR that changes the kernel on purpose
+#: measures Pythia's two serving cells and records the new digests here.
+PAGED_PROGRAM_SHA256 = {
+    "decode": ("aa64db95e72bccaba2c36e902c2a22b5"
+               "ea8a2eb7a83161d8d0e37fbcbb5124b1"),
+    "prefill": ("658c953f10c3026e5e1c55470ecae111"
+                "09585a8d288328599f2a2fe6ccc8fb4d")}
+
+
+@pytest.mark.parametrize("lane", list(PAGED_PROGRAM_SHA256))
+def test_paged_kernel_without_a_window_is_the_program_it_was(v5e_devices,
+                                                             lane):
+    """The window (a static operand of the kernel, two more rows of its
+    scalar prefetch, a first page in its DMA loop) costs a call that has
+    none nothing: the same operands and the same instructions, kernel
+    body included, as before the kernel could take one."""
+    import hashlib
+    sds = one_chip(v5e_devices)
+    fn, args = (paged_decode_case(sds, 24, 128, 16, 16, 128, 0, 16)
+                if lane == "decode" else
+                paged_prefill_case(sds, 128, 16, 128, 0, 16))
+
+    def program(*a):            # one name, one text
+        return fn(*a)
+    text = stripped(compile_for_tpu(program, *args))
+    assert hashlib.sha256(text.encode()).hexdigest() == \
+        PAGED_PROGRAM_SHA256[lane]
+
+
+def hybrid_model(pairs_self=2, pairs_cross=2):
+    """The hybrid state-space block at its cell's widths (40 / 20 heads
+    of 64, d_inner 5,120, a window of 512) with a shorter pattern and a
+    small vocabulary."""
+    from deepspeed_tpu.models import build_model, phi4_flash_config
+    return build_model(phi4_flash_config(
+        "mini", num_layers=2 * (pairs_self + 1 + pairs_cross),
+        pairs_self=pairs_self, pairs_cross=pairs_cross, vocab_size=1024,
+        max_seq_len=10240))
+
+
+#: the hybrid cell's engine: slots, pages a slot, the full layer's blocks,
+#: the window layers' blocks (63 x 33 + 65 and the null block)
+HYBRID_SIZE = (64, 640, 4096, 63 * 33 + 65 + 1)
+HYBRID_CHUNK = {"mixed": 512, "decode_only": 0}
+
+
+def hybrid_mixed_operands(devices, model, chunk):
+    """``mixed_step_operands`` for a block with three kinds of state: the
+    full layer's pool, ``extra`` (the window pool and the per-slot
+    state), and a slot's two tables side by side."""
+    slots, pages, nb, wb = HYBRID_SIZE
+    sds = one_chip(devices)
+    args, pools, params = mixed_step_operands(devices, model, nb, 16, 0,
+                                              slots, 2 * pages, chunk)
+    extra = jax.tree_util.tree_map(
+        lambda a: sds(a.shape, a.dtype), jax.eval_shape(
+            lambda: model.init_paged_extra(slots, 16, wb, jnp.bfloat16)))
+    args[1]["extra"] = extra
+    return args, dict(pools, **extra), params
+
+
+def build_hybrid_mixed(devices, chunk):
+    args, _, _ = hybrid_mixed_operands(devices, hybrid_model(), chunk)
+    return jax.jit(hybrid_model()._apply_paged_mixed, donate_argnums=1
+                   ).trace(*args).lower(
+                       lowering_platforms=("tpu",)).compile()
+
+
+def test_window_walk_and_scan_compile_at_the_cells_shapes(v5e_devices):
+    """The hybrid cell's kernels at its widths: the paged kernel at 40 /
+    20 heads of 64 (two query heads a key-value head, the 2-head pack)
+    with a window of 512 — 64 decode slots, and a chunk of 512 rows cut
+    into walkers of 128 — the same without a window for the 65 one-row
+    walkers of the layers that read the full layer's pages, and the
+    selective scan over 512 rows x 5,120 channels x 16."""
+    from deepspeed_tpu.ops.transformer import ssm_scan
+    sds = one_chip(v5e_devices)
+    pool = sds((4096, 16, 1280), jnp.bfloat16)
+    q = sds((65, 40, 64), jnp.bfloat16)
+    lens, tables = sds((65,), jnp.int32), sds((65, 640), jnp.int32)
+    scalar = sds((), jnp.int32)
+    for window in (512, None):
+        text = compile_for_tpu(
+            lambda q, pk, pv, lens, tables, window=window:
+            paged_decode_attention(q, pk, pv, lens, tables,
+                                   interpret=False, window=window),
+            q, pool, pool, lens, tables)
+        assert custom_calls(text) == 1
+    text = compile_for_tpu(
+        lambda q, pk, pv, base, n, table: paged_prefill_attention(
+            q, pk, pv, base, n, table, interpret=False, window=512,
+            tile_rows=128),
+        sds((512, 40, 64), jnp.bfloat16), pool, pool, scalar, scalar,
+        sds((640,), jnp.int32))
+    assert custom_calls(text) == 1
+    f32 = jnp.float32
+    text = compile_for_tpu(
+        lambda x, dt, b, c, a, d, s, n: ssm_scan.ssm_chunk_scan(
+            x, dt, b, c, a, d, s, n, interpret=False),
+        sds((512, 5120), jnp.bfloat16), sds((512, 5120), f32),
+        sds((512, 16), f32), sds((512, 16), f32), sds((5120, 16), f32),
+        sds((5120,), f32), sds((5, 16, 8, 128), f32), scalar)
+    assert custom_calls(text) == 1 and "ssm_chunk_scan" in text
+
+
+def test_hybrid_probe_step_compiles_at_the_checks_shapes(v5e_devices,
+                                                         compiled_kernels):
+    """The cell's check drives the mixed step with ``probe=True`` over a
+    cache of its own (``benchmark/runners/serve_hybrid.py::
+    _served_cross_reads``: 64 slots and a chunk of 512 rows, tables of 86
+    pages for a request of 1,356 tokens, pools of as many blocks): the
+    same six kernel calls as the engine's step, and the eight walks'
+    outputs for the 65 rows that yield a token come out beside it."""
+    import functools
+    model = hybrid_model()
+    slots, pages = 64, 86
+    sds = one_chip(v5e_devices)
+    args, _, _ = mixed_step_operands(v5e_devices, model, pages, 16, 0, slots,
+                                     2 * pages, 512)
+    args[1]["extra"] = jax.tree_util.tree_map(
+        lambda a: sds(a.shape, a.dtype), jax.eval_shape(
+            lambda: model.init_paged_extra(slots, 16, pages, jnp.bfloat16)))
+    step = jax.jit(functools.partial(model._apply_paged_mixed, probe=True),
+                   donate_argnums=1)
+    reads = jax.eval_shape(step, *args)[2]["probe"]["reads"]
+    assert reads.shape == (1 + 2, slots + 1, 40 * 64)
+    text = step.trace(*args).lower(
+        lowering_platforms=("tpu",)).compile().as_text()
+    assert custom_calls(text) == 6
+
+
+@pytest.mark.parametrize("shape", list(HYBRID_CHUNK))
+def test_hybrid_mixed_step_keeps_pools_and_state_in_place(
+        v5e_devices, compiled_kernels, step_programs, shape):
+    """The hybrid block's mixed step at its cell's widths (2 + 1 + 2
+    pairs of its pattern): the window pool and every state-space layer's
+    per-slot state are the first scan's carry, the full layer's pool a
+    loop constant of the cross decoder, so the compiled step holds no
+    copy, slice or second buffer shaped like a pool (1.4 GB of window
+    pages at the cell's depth) or like the recurrent state (190 MB) —
+    only the in-place updates of the donated arguments.  The decode-only
+    shape calls no chunk kernel: one walk a window layer, one a layer
+    that reads the full pages, no scan kernel."""
+    import re
+    chunk = HYBRID_CHUNK[shape]
+    model = hybrid_model()
+    _, pools, _ = hybrid_mixed_operands(v5e_devices, model, chunk)
+    text, temp_bytes = step_programs(f"hybrid-{shape}")
+    # window decode (+ chunk) in the scanned pair, the scan kernel in the
+    # scanned pair and in the middle pair, the full layer's walk and the
+    # scanned cross layer's
+    assert custom_calls(text) == (6 if chunk else 3)
+    # (the convolution tails, 3 rows a slot a layer, 18 MB at the cell's
+    # depth, are re-laid once on the way in: XLA keeps the taps as the
+    # second-minor dimension whatever order they are given in)
+    shaped = set()
+    for name, a in pools.items():
+        if name == "conv":
+            continue
+        shaped.add(a.shape)
+        if name in ("k", "v", "wk", "wv"):
+            shaped.update({a.shape[1:], (a.shape[0] * a.shape[1],)
+                           + a.shape[2:]})
+    moved = []
+    for ln in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%(\S+) = \w+\[([\d,]+)\]\S* ([\w-]+)\(",
+                     ln)
+        if m is None:
+            continue
+        name, dims, op = m.groups()
+        if tuple(int(n) for n in dims.split(",")) in shaped and (
+                op in ("copy", "dynamic-slice") or "AllocateBuffer" in ln):
+            moved.append(ln.strip()[:160])
+    assert not moved, moved
+    # less than ONE window layer's k pages (the MLP's own temporaries at
+    # 576 rows x 20,480 are most of it)
+    assert temp_bytes < int(np.prod(pools["wk"].shape[1:])) * 2
+
+
 def test_train_grad_compiles_on_four_chips(v5e_devices, compiled_kernels):
     """GPT-2 350M ``value_and_grad(model.loss)`` with the batch sharded
     over a data=4 mesh: the flash kernel must sit inside a shard_map or
@@ -1146,6 +1328,12 @@ for _cell, _chips in (("1chip", 1), ("zero3-4chip", 4)):
         lambda dev, n=_chips: build_train_step(dev, n), _TRAIN)
 
 
+for _shape, _chunk in HYBRID_CHUNK.items():
+    STEP_PROGRAMS[f"hybrid-{_shape}"] = (
+        lambda dev, c=_chunk: build_hybrid_mixed(dev, c),
+        _SERVE | {"ssm_proj", "ssm_scan", "gmu", "state_io"})
+
+
 STEP_PROGRAMS["train-moe-1chip"] = (
     build_cca_train_step,
     (_LAYER | {"attn_conv", "router", "expert_layout", "experts", "loss",
@@ -1189,7 +1377,8 @@ def test_step_programs_carry_their_scopes(compiled_kernels, step_programs,
     has is in the program's text (a scope whose operations XLA fused
     into another's, as it does the residual adds, is in no instruction
     of its own); each Pallas call resolves to ``attn_kernel``,
-    ``experts`` or (the score kernel of a sparse selection) ``indexer``;
+    ``experts``, (the score kernel of a sparse selection) ``indexer`` or
+    (a state-space layer's scan) ``ssm_scan``;
     and at least nine in ten of the instructions that can
     be trace events and do work (fusions, convolutions, copies, custom
     calls) resolve to a declared scope."""
@@ -1207,7 +1396,8 @@ def test_step_programs_carry_their_scopes(compiled_kernels, step_programs,
     assert kernels
     for ln in kernels:
         want = ("experts" if "%moe_grouped_matmul" in ln else
-                "indexer" if "%dsa_index_scores" in ln else "attn_kernel")
+                "indexer" if "%dsa_index_scores" in ln else
+                "ssm_scan" if "%ssm_chunk_scan" in ln else "attn_kernel")
         assert table[scope_key(ln)][0] == want, ln[:200]
     work = [k for k in table
             if re.match(r"%[\w.-]*(fusion|convolution|copy|custom-call)"
