@@ -800,19 +800,18 @@ class TransformerLM:
 
     def _qkv(self, p, x, positions):
         """x [B, T, D] -> q [B, T, H, hd], k / v [B, T, Hkv, hd], rotary
-        applied."""
+        applied.  The product's lanes are the sections ``q | k | v``,
+        heads major in each: a section is CUT by its lane range and only
+        then split into heads, for every head count.  Nothing reshapes
+        the whole product first: XLA folds a ``reshape(b, t, 3, H, hd)``
+        into it as a convolution that wants the weight contraction-minor
+        and transposes the scan's slice of it on the chip, every layer."""
         c = self.config
-        nh, hd = c.num_heads, c.hdim
-        nkv = c.kv_heads
+        hd = c.hdim
+        nq, nkv = c.num_heads * hd, c.kv_heads * hd
         qkv = self._qkv_product(p, x)
-        b, t = qkv.shape[0], qkv.shape[1]
-        if nkv == nh:
-            qkv3 = qkv.reshape(b, t, 3, nh, hd)
-            q, k, v = qkv3[:, :, 0], qkv3[:, :, 1], qkv3[:, :, 2]
-        else:
-            q = qkv[..., :nh * hd].reshape(b, t, nh, hd)
-            k = qkv[..., nh * hd:(nh + nkv) * hd].reshape(b, t, nkv, hd)
-            v = qkv[..., (nh + nkv) * hd:].reshape(b, t, nkv, hd)
+        q, k, v = (a.reshape(a.shape[:2] + (-1, hd))
+                   for a in jnp.split(qkv, [nq, nq + nkv], axis=-1))
         if c.pos_embedding == "rotary":
             cos = self._cos.astype(jnp.float32)
             sin = self._sin.astype(jnp.float32)

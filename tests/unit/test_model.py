@@ -103,6 +103,53 @@ class TestTransformerLM:
         assert logits.shape == (1, 8, 64)
         assert np.all(np.isfinite(np.asarray(logits)))
 
+    @pytest.mark.parametrize("kv_heads", [4, 2, 1],
+                             ids=["equal_heads", "grouped", "one_kv_head"])
+    @pytest.mark.parametrize("builder", [gpt2_config, neox_config],
+                             ids=["learned", "rotary"])
+    def test_qkv_heads_are_lane_slices_of_the_product(self, builder,
+                                                      kv_heads):
+        """``_qkv`` has ONE path for every head count: q, k and v are
+        the lane ranges ``q | k | v`` of ``_qkv_product``'s output, bit
+        for bit, and head ``h`` of a section is that section's columns
+        ``[h hd, (h + 1) hd)`` of the stored weight."""
+        nh, hd = 4, 8
+        cfg = builder(num_layers=1, d_model=nh * hd, num_heads=nh,
+                      num_kv_heads=kv_heads, vocab_size=64, max_seq_len=16,
+                      dtype=jnp.float32)
+        model = TransformerLM(cfg)
+        p = jax.tree_util.tree_map(
+            lambda a: a[0],
+            model.init(jax.random.PRNGKey(0))["blocks"]["attn"])
+        # biases off zero, so that a section cut a lane off would show
+        p["qkv"]["bias"] = jax.random.normal(
+            jax.random.PRNGKey(3), p["qkv"]["bias"].shape)
+        x = jax.random.normal(jax.random.PRNGKey(1), (2, 16, nh * hd))
+        positions = jnp.tile(jnp.arange(16)[None, ::-1], (2, 1))
+        got = model._qkv(p, x, positions)
+        product = np.asarray(model._qkv_product(p, x))
+        nq, nkv = nh * hd, kv_heads * hd
+        assert product.shape == (2, 16, nq + 2 * nkv)
+        want = [a.reshape(2, 16, -1, hd) for a in np.split(
+            product, [nq, nq + nkv], axis=-1)]
+        if cfg.pos_embedding == "rotary":
+            want[:2] = [L.apply_rotary(
+                jnp.asarray(a), model._cos.astype(jnp.float32),
+                model._sin.astype(jnp.float32), positions,
+                interleaved=cfg.rotary_interleaved) for a in want[:2]]
+        for name, a, b in zip("qkv", got, want):
+            assert a.shape == b.shape, name
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b), name)
+        # v is never rotated: its head h IS the weight's columns of head h
+        w, bias = (np.asarray(p["qkv"][k], np.float64)
+                   for k in ("kernel", "bias"))
+        for h in range(kv_heads):
+            cols = slice(nq + nkv + h * hd, nq + nkv + (h + 1) * hd)
+            np.testing.assert_allclose(
+                np.asarray(got[2][:, :, h]),
+                np.asarray(x, np.float64) @ w[:, cols] + bias[cols],
+                atol=1e-5)
+
     def test_kv_cache_decode_matches_full_forward(self, tiny_gpt2):
         model, params = tiny_gpt2
         ids = jax.random.randint(jax.random.PRNGKey(5), (2, 8), 0, 64)

@@ -406,6 +406,54 @@ def test_mixed_step_keeps_the_pool_in_place(v5e_devices, compiled_kernels,
     assert compiled.memory_analysis().temp_size_in_bytes < slice_bytes
 
 
+@pytest.mark.parametrize("chunk", [256, 0], ids=["mixed", "decode_only"])
+@pytest.mark.parametrize("d", [64, 128])
+def test_projections_read_their_weights_where_they_lie(
+        v5e_devices, compiled_kernels, d, chunk):
+    """The dense mixed step at 16 heads of head dim 128 (``d_model``
+    2048: Pythia's widths) and 64, both step shapes, the programs
+    :func:`test_mixed_step_keeps_the_pool_in_place` compiles: a
+    projection is a plain ``[rows, in] x [in, out]`` product over the
+    layer's slice of the stacked weight AS STORED.  So (1) no top-level
+    instruction of the layer body has the shape of one layer's slice of
+    ``qkv``, ``out`` or the MLP's two — the slice lives only inside the
+    product's own fused computation, where the weight streams from HBM
+    into the product — and (2) every ``attn_proj`` product is
+    ``dim_labels=bf_io->bf`` with no ``window=``.  While ``_qkv`` wrote
+    ``qkv.reshape(b, t, 3, heads, hd)`` for equal head counts, XLA
+    folded the reshape into the product (a convolution over sections
+    and heads, ``window={size=3x16 ..}``) whose form wants the weight
+    contraction-minor: the layer body then held the slice
+    ``bf16[1,2048,6144]`` as a fusion of its own and a transposing
+    ``copy`` of all 25 MB of it, every layer of every dispatch (PR 49;
+    0.50 ms of Pythia's 5.2 ms paced iteration)."""
+    import re
+    compiled, _ = compile_dense_mixed(v5e_devices, d, 0, 16, 1920, chunk)
+    text = compiled.as_text()
+    comps, _ = hlo_computations(text)
+    bodies = [m.group(1) for m in re.finditer(r" while\(.*body=%([\w.-]+)",
+                                              text)]
+    assert len(bodies) == 1, bodies                     # the layer scan
+    width = 16 * d
+    slices = {(width, 3 * width), (width, width), (width, 4 * width),
+              (4 * width, width)}
+    held = []
+    for ln in comps[bodies[0]]:
+        m = re.match(r"\s*(?:ROOT )?%\S+ = \w+\[([\d,]+)\]", ln)
+        if m is None:
+            continue
+        dims = tuple(int(n) for n in m.group(1).split(","))
+        if dims[-2:] in slices and int(np.prod(dims[:-2])) == 1:
+            held.append(ln.strip()[:160])
+    assert not held, held
+    products = [ln for ln in text.splitlines()
+                if " convolution(" in ln and "/attn_proj/" in ln]
+    assert len(products) == 2, products                 # qkv, out
+    folded = [ln.strip()[:200] for ln in products
+              if "window=" in ln or "dim_labels=bf_io->bf" not in ln]
+    assert not folded, folded
+
+
 @pytest.mark.parametrize("slots,heads,pages,hidden", [
     (48, 64, 512, 6144), (128, 128, 256, 7680)],
     ids=["longcat-flash-omni", "openpangu-ultra-moe"])
