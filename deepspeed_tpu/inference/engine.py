@@ -90,7 +90,7 @@ class InferenceEngine:
         # -- TP layout: model-provided specs or the auto-TP heuristic ------
         shapes = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0)))
         if hasattr(model, "partition_specs"):
-            self.param_specs = model.partition_specs()
+            self.param_specs = model.partition_specs(shapes)
         else:
             from ..module_inject.auto_tp import auto_tp_specs
             self.param_specs = auto_tp_specs(shapes, self.mesh)
@@ -117,6 +117,19 @@ class InferenceEngine:
                 out_shardings=shardings)
             with self.mesh:
                 self.params = init_fn(jax.random.PRNGKey(0))
+
+        # -- the layout the model's step reads its weights in, made ONCE,
+        # here, whichever source they came from (a checkpoint and
+        # ``model.init`` keep the published one).  The engine holds the
+        # serving tree alone; a model that reads its weights as stored
+        # hands back the tree it was given.  ``quant.enabled`` below
+        # quantizes THIS tree: int8 leaves in the layout the step reads.
+        if hasattr(model, "serving_params"):
+            with self.mesh:
+                laid = model.serving_params(self.params)
+            if laid is not self.params:
+                self.params = laid
+                self.param_specs = model.partition_specs(laid)
 
         # -- int8 weight-only serving (reference GroupQuantizer at
         # module_inject/replace_module.py:150: qkv/mlp weights stored int8,

@@ -754,8 +754,8 @@ class ServingEngine:
         engine, model = self.engine, self.model
         c = model.config
         mp_size = self.tp_model_size
-        specs = model.partition_specs()
         params = engine.params
+        specs = model.partition_specs(params)
         scales = getattr(engine, "_scales", None)
         flags = getattr(engine, "_qflags", None)
         if mp_size > 1:

@@ -21,6 +21,13 @@ top-k router in the form the configuration names (``router_scoring``,
 identity experts, and this chip's share of the routed experts
 (``experts_held``).
 
+``init`` and checkpoints hold ``q_b`` and ``kv_b`` as published, the split
+of a head's parts INSIDE the head.  The sublayers read ONE other layout,
+``q_sections`` / ``w_uk`` / ``w_uv`` (``serving_params``): an inference
+engine makes it once as it places the weights, and the model's entry
+points make it in the caller's program for a tree that still has the
+published keys.
+
 Full sequences (``apply``) run the EXPANDED form in plain XLA.  These
 blocks do not train: latent attention has no training kernel
 (``training_refusal``; the experts' grouped product differentiates, and
@@ -50,6 +57,7 @@ offset into the pool, or more.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Dict, NamedTuple, Optional
 
@@ -76,6 +84,39 @@ class MixedStep(NamedTuple):
     positions: jax.Array
     row_valid: jax.Array
     num_blocks: int
+
+
+@functools.partial(jax.jit, static_argnums=(1, 2))
+def head_sections(w, heads: int, cut: int):
+    """A projection's columns ``w [.., heads * width]`` whose every head
+    is used in two parts, ``[cut | width - cut]``, in TWO SECTIONS: ``[the
+    first part of all heads | the second part of all heads]``.  Stored as
+    published, with the split INSIDE a head, the parts are cut after a
+    reshape of the product's output, which XLA folds into the product as
+    a form that wants the weight transposed: a copy of the layer's whole
+    slice, every layer of every dispatch.  Over the sections the parts
+    are lane ranges of a plain product's output, the weight read where
+    it lies."""
+    lead = w.shape[:-1]
+    per_head = w.reshape(*lead, heads, -1)
+    rest = per_head.shape[-1] - cut
+    return jnp.concatenate(
+        [per_head[..., :cut].reshape(*lead, heads * cut),
+         per_head[..., cut:].reshape(*lead, heads * rest)], axis=-1)
+
+
+@functools.partial(jax.jit, static_argnums=(2, 3, 4))
+def mla_serving_layout(q_b, kv_b, heads: int, nope: int, rope: int):
+    """The two up-projections of latent attention, published ``q_b [..,
+    r_q, H * (nope + rope)]`` and ``kv_b [.., r_kv, H * (nope + dv)]``
+    (any leading axes: a stack of layers), as the step reads them:
+    ``(q_b in sections [.., r_q, H * nope | H * rope]``
+    (:func:`head_sections`), ``W_UK [.., H, r_kv, nope], W_UV [.., H,
+    r_kv, dv])``, head-major so that the absorbed products batch over a
+    leading axis of the weight as stored."""
+    kv = jnp.moveaxis(kv_b.reshape(*kv_b.shape[:-1], heads, -1), -2, -3)
+    return (head_sections(q_b, heads, nope), kv[..., :nope],
+            kv[..., nope:])
 
 
 #: std of a seeded selection bias, in units of the mean score 1 / outputs:
@@ -293,11 +334,41 @@ class LatentMoELM(TransformerLM):
                            * jax.random.normal(k2, (outs,))).astype(dt)
         return moe
 
+    def serving_params(self, params) -> Dict:
+        """``params`` with every latent attention's ``q_b`` and ``kv_b``
+        (the published layout: ``init``'s, a checkpoint's) replaced by
+        ``q_sections``, ``w_uk`` and ``w_uv``
+        (:func:`mla_serving_layout`), the one layout the sublayers read.
+        Told by the tree's keys: a tree that holds no ``q_b`` comes back
+        as it is, the same object.  ``init_inference`` lays the weights
+        out once, as it places them; ``hidden_states_and_aux`` and
+        ``_apply_paged_mixed`` pass their tree through here, so a caller
+        that brings the published one pays the transposes in its own
+        program, once a call and outside the layer scans."""
+        c = self.config
+
+        def lay(tree):
+            if not isinstance(tree, dict):
+                return tree
+            if "q_b" in tree:
+                q, w_uk, w_uv = mla_serving_layout(
+                    tree["q_b"]["kernel"], tree["kv_b"]["kernel"],
+                    c.num_heads, c.qk_nope_head_dim, c.qk_rope_head_dim)
+                return dict({k: v for k, v in tree.items()
+                             if k not in ("q_b", "kv_b")},
+                            q_sections={"kernel": q}, w_uk=w_uk, w_uv=w_uv)
+            laid = {k: lay(v) for k, v in tree.items()}
+            same = all(laid[k] is v for k, v in tree.items())
+            return tree if same else laid
+        return lay(params)
+
     def partition_specs(self, params=None) -> Dict:
         """Everything replicated: the block serves on one chip (no
-        tensor-parallel rules yet)."""
+        tensor-parallel rules yet).  Of ``params``, or of the tree
+        ``serving_params`` gives."""
         if params is None:
-            params = jax.eval_shape(lambda: self.init(jax.random.PRNGKey(0)))
+            params = jax.eval_shape(lambda: self.serving_params(
+                self.init(jax.random.PRNGKey(0))))
         return jax.tree_util.tree_map(lambda a: P(*([None] * a.ndim)),
                                       params)
 
@@ -322,10 +393,10 @@ class LatentMoELM(TransformerLM):
         norm = self._norm_fn("attn_proj")
         if cq is None:
             cq = self._q_latent(p, x)
-        q = L.dense_apply(p["q_b"], cq).reshape(
-            b, t, c.num_heads, c.qk_nope_head_dim + c.qk_rope_head_dim)
-        q_nope, q_rope = (q[..., :c.qk_nope_head_dim],
-                          q[..., c.qk_nope_head_dim:])
+        q = L.dense_apply(p["q_sections"], cq)
+        cut = c.num_heads * c.qk_nope_head_dim
+        q_nope = q[..., :cut].reshape(b, t, c.num_heads, c.qk_nope_head_dim)
+        q_rope = q[..., cut:].reshape(b, t, c.num_heads, c.qk_rope_head_dim)
         kv = L.dense_apply(p["kv_a"], x)
         lat = norm(p["kv_norm"], kv[..., :c.kv_lora_rank])
         if self._kv_scale != 1.0:
@@ -339,11 +410,8 @@ class LatentMoELM(TransformerLM):
 
     @scoped("attn_proj")
     def _kv_b(self, p, dtype):
-        """``kv_b`` as (W_UK [r, H, dn], W_UV [r, H, dv])."""
-        c = self.config
-        w = p["kv_b"]["kernel"].astype(dtype).reshape(
-            c.kv_lora_rank, c.num_heads, c.qk_nope_head_dim + c.v_head_dim)
-        return w[..., :c.qk_nope_head_dim], w[..., c.qk_nope_head_dim:]
+        """(W_UK [H, r, dn], W_UV [H, r, dv])."""
+        return p["w_uk"].astype(dtype), p["w_uv"].astype(dtype)
 
     def _mla_expanded(self, p, x, positions, cq=None, chosen=None):
         """Full-sequence causal MLA in the expanded form, plain XLA —
@@ -353,8 +421,8 @@ class LatentMoELM(TransformerLM):
         q_nope, q_rope, lat, k_rope = self._mla_project(p, x, positions, cq)
         w_uk, w_uv = self._kv_b(p, x.dtype)
         with jax.named_scope("attn_proj"):
-            k_nope = jnp.einsum("btr,rhd->bthd", lat, w_uk)
-            v = jnp.einsum("btr,rhd->bthd", lat, w_uv)
+            k_nope = jnp.einsum("btr,hrd->bthd", lat, w_uk)
+            v = jnp.einsum("btr,hrd->bthd", lat, w_uv)
         with jax.named_scope("attn_kernel"):
             s = (jnp.einsum("bqhd,bkhd->bhqk", q_nope, k_nope,
                             preferred_element_type=jnp.float32)
@@ -391,6 +459,7 @@ class LatentMoELM(TransformerLM):
     def hidden_states_and_aux(self, params, input_ids, token_type_ids=None):
         """Forward up to the final norm, expanded form, plain XLA: the
         leading layers, then ``params["blocks"]``."""
+        params = self.serving_params(params)
         x = self._embed_tokens(params, input_ids)
         positions = jnp.broadcast_to(jnp.arange(x.shape[1])[None],
                                      x.shape[:2])
@@ -482,7 +551,7 @@ class LatentMoELM(TransformerLM):
                 pool, write, jnp.concatenate([lat[0], k_rope[0]], axis=-1))
         w_uk, w_uv = self._kv_b(p, xn.dtype)
         with jax.named_scope("attn_proj"):
-            q_lat = jnp.einsum("thd,rhd->thr", q_nope[0], w_uk)
+            q_lat = jnp.einsum("thd,hrd->thr", q_nope[0], w_uk)
         with jax.named_scope("attn_kernel"):
             o_parts = [mla_paged_decode_attention(
                 q_lat[:bsl], q_rope[0, :bsl], pool,
@@ -493,7 +562,7 @@ class LatentMoELM(TransformerLM):
                     chunk_len, ctable, self._sm_scale))
             o_lat = jnp.concatenate(o_parts) if cw else o_parts[0]
         with jax.named_scope("attn_proj"):
-            o = jnp.einsum("thr,rhd->thd", o_lat, w_uv)
+            o = jnp.einsum("thr,hrd->thd", o_lat, w_uv)
             return L.dense_apply(p["out"], o.reshape(1, t, -1)), pool
 
     # -- what the mixed step's two scans carry, and what a layer is told --
@@ -549,6 +618,7 @@ class LatentMoELM(TransformerLM):
             raise NotImplementedError(self.paged_refusal(spec=True))
         if cache.get("k_scale") is not None:
             raise NotImplementedError(self.paged_refusal(kv_bits=8))
+        params = self.serving_params(params)
         tables, lens = cache["block_tables"], cache["lens"]
         bsl, cw = dec_tokens.shape[0], chunk_ids.shape[0]
         with jax.named_scope("embed"):

@@ -50,7 +50,8 @@ import numpy as np
 
 from . import layers as L
 from ..observability.overlap import scoped
-from .latent_moe import DenseLeadMoEConfig, DenseLeadMoELM
+from .latent_moe import (DenseLeadMoEConfig, DenseLeadMoELM,
+                         head_sections)
 
 _NORMS = ("ln_in", "ln_post")
 _KINDS = ("full", "shared")
@@ -178,18 +179,38 @@ class SparseLatentMoELM(DenseLeadMoELM):
         normalised and rotated, w [B, T, J] float32, scaled)``."""
         c = self.config
         b, t, _ = xn.shape
-        q = L.dense_apply(ip["wq"], cq).reshape(
-            b, t, c.index_n_heads, c.index_head_dim)
+        q = L.dense_apply(ip["wq_sections"], cq)
+        cut = c.index_n_heads * c.qk_rope_head_dim
+        q_rope = q[..., :cut].reshape(b, t, c.index_n_heads,
+                                      c.qk_rope_head_dim)
+        q_rest = q[..., cut:].reshape(
+            b, t, c.index_n_heads, c.index_head_dim - c.qk_rope_head_dim)
         k = L.layernorm_apply(ip["k_norm"], L.dense_apply(ip["wk"], xn),
                               eps=c.layernorm_eps)
-        q = L.apply_rotary(q, self._cos, self._sin, positions,
-                           interleaved=False)
+        q = jnp.concatenate(
+            [L.apply_rotary(q_rope, self._cos, self._sin, positions,
+                            interleaved=False), q_rest], axis=-1)
         k = L.apply_rotary(k[:, :, None], self._cos, self._sin, positions,
                            interleaved=False)[:, :, 0]
         w = jnp.einsum("bth,hj->btj", xn,
                        ip["weights"]["kernel"].astype(xn.dtype),
                        preferred_element_type=jnp.float32)
         return q, k, w * self._index_scale
+
+    def serving_params(self, params) -> Dict:
+        """``LatentMoELM.serving_params``, and the indexers' ``wq`` — a
+        head's rotary part and the rest are a split inside the head too —
+        as ``wq_sections`` (``latent_moe.head_sections``)."""
+        params = super().serving_params(params)
+        ip = params["indexer"]
+        if "wq" not in ip:
+            return params
+        c = self.config
+        wq = head_sections(ip["wq"]["kernel"], c.index_n_heads,
+                           c.qk_rope_head_dim)
+        ip = dict({k: v for k, v in ip.items() if k != "wq"},
+                  wq_sections={"kernel": wq})
+        return dict(params, indexer=ip)
 
     def _layer_kinds(self, first, count):
         """``(is full [count] bool, full-layer number [count] int32)`` of
@@ -226,6 +247,7 @@ class SparseLatentMoELM(DenseLeadMoELM):
         selection mask is carried from a ``full`` layer to the ``shared``
         layers after it.  ``return_selection``: also every layer's mask
         ``[layers, B, T, T]`` (the tests')."""
+        params = self.serving_params(params)
         x = self._embed_tokens(params, input_ids)
         b, t = x.shape[:2]
         positions = jnp.broadcast_to(jnp.arange(t)[None], (b, t))
@@ -425,7 +447,7 @@ class SparseLatentMoELM(DenseLeadMoELM):
                 sel = dict(sel, counts=sel["counts"].at[2].add(read))
             w_uk, w_uv = self._kv_b(p, xn.dtype)
             with jax.named_scope("attn_proj"):
-                q_lat = jnp.einsum("thd,rhd->thr", q_nope[0], w_uk)
+                q_lat = jnp.einsum("thd,hrd->thr", q_nope[0], w_uk)
             with jax.named_scope("attn_kernel"):
                 o_parts = [gathered_latent_attention(
                     q_lat[:bsl], q_rope[0, :bsl], pool,
@@ -438,7 +460,7 @@ class SparseLatentMoELM(DenseLeadMoELM):
                         ctable + off, self._sm_scale))
                 o_lat = jnp.concatenate(o_parts) if cw else o_parts[0]
             with jax.named_scope("attn_proj"):
-                o = jnp.einsum("thr,rhd->thd", o_lat, w_uv)
+                o = jnp.einsum("thr,hrd->thd", o_lat, w_uv)
                 return (L.dense_apply(p["out"], o.reshape(1, t, -1)),
                         {"k": pool, "v": ipool, "sel": sel})
         return attend

@@ -642,6 +642,13 @@ class TransformerLM:
             self.superblock_keys(rng))
         return params
 
+    def serving_params(self, params) -> Dict:
+        """``params`` (``init``'s tree, a checkpoint's) laid out as the
+        serving step reads them: ``init_inference`` calls this once on
+        the tree it places and holds what comes back.  This block reads
+        its weights as stored."""
+        return params
+
     def bind_mesh(self, mesh) -> None:
         """Attach the device mesh (needed by manual-collective attention
         paths like ring attention). The engine calls this at init."""

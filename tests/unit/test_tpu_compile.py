@@ -302,14 +302,16 @@ def mixed_step_operands(devices, model, nb, block, kv_bits, slots, pages,
                         chunk):
     """``model._apply_paged_mixed``'s abstract bfloat16 arguments on one
     v5e chip: ``(the call's arguments, the pools' abstract arrays, the
-    parameters')``."""
+    parameters')``.  The parameters are the tree an engine holds:
+    ``model.serving_params`` of ``model.init``'s."""
     sds = one_chip(devices)
 
     def abstract(tree, dtype=None):
         return jax.tree_util.tree_map(
             lambda a: sds(a.shape, dtype or a.dtype), tree)
-    params = abstract(jax.eval_shape(model.init, jax.random.PRNGKey(0)),
-                      jnp.bfloat16)
+    params = abstract(jax.eval_shape(
+        lambda: model.serving_params(model.init(jax.random.PRNGKey(0)))),
+        jnp.bfloat16)
     cache = abstract(jax.eval_shape(
         lambda: model.init_paged_cache(nb, block, jnp.bfloat16, kv_bits)))
     pools = {k: v for k, v in cache.items() if v is not None}
@@ -692,6 +694,62 @@ def test_latent_mixed_step_keeps_pool_and_experts_in_place(
     assert not moved, moved
     one_expert_stack = held * config.d_model * 2048 * 2
     assert temp_bytes < one_expert_stack
+
+
+@pytest.mark.parametrize("shape", list(LATENT_CHUNK))
+@pytest.mark.parametrize("block", list(LATENT_CASES))
+def test_latent_projections_read_their_weights_where_they_lie(
+        v5e_devices, compiled_kernels, step_programs, block, shape):
+    """:func:`test_projections_read_their_weights_where_they_lie` for the
+    latent blocks, on the programs the test above reads: the step is
+    built on the SERVING tree (``model.serving_params``: ``q_b``'s
+    columns as ``[nope of all heads | rope of all heads]``, ``kv_b`` as
+    head-major ``w_uk`` / ``w_uv``), over which a head's two parts are
+    lane ranges of a plain product's output and the absorbed products
+    batch over the weight's leading axis.  So one layer's slice of an
+    up-projection is never re-laid: no instruction of the slice's shape
+    is a ``copy`` or has another layout than the stacked weight's own.
+    On the published tree (the split INSIDE a head) every layer of every
+    dispatch held ``copy bf16[1,1536,24576]{1,2,0..}`` and ``copy
+    bf16[1,512,32768]{1,2,0..}`` — 109 MB transposed on the chip in the
+    sandwich block's shape, 96 MB in the sparse block's, 55 MB an
+    attention sublayer in the shortcut block's; the sparse block's
+    indexers' ``wq`` (rotary part | rest inside a head) as ``copy
+    bf16[2,2048,4096]{1,2,0..}`` once a dispatch and ``copy
+    bf16[4096,2048]`` a ``full`` layer (PR 50).  (An async
+    ``copy-start`` / ``copy-done`` of a slice AS STORED is the weight's
+    one read, prefetched.)"""
+    import re
+    model, size = latent_mixed_size(LATENT_CASES[block], LATENT_CHUNK[shape])
+    _, _, params = mixed_step_operands(v5e_devices, model, *size)
+    text, _ = step_programs(f"{block}-{shape}")
+    entry = re.search(r"entry_computation_layout=\{\((.*?)\)->", text).group(1)
+
+    def stored(shape):
+        """The layout the chip keeps an array of ``shape`` in (the
+        compiler's choice for an entry parameter: row-major unless the
+        minor dimension is no whole number of lanes)."""
+        dims = ",".join(str(n) for n in shape)
+        return re.search(rf"\w+\[{dims}\]\{{([\d,]+)", entry).group(1)
+    slices = {}
+    for path, a in jax.tree_util.tree_leaves_with_path(params):
+        if {"q_sections", "w_uk", "w_uv", "wq_sections"} & {
+                str(getattr(k, "key", "")) for k in path}:
+            # a layer's slice, or (the indexers', which no scan slices)
+            # the whole stack
+            slices[(1,) + a.shape[1:]] = slices[a.shape] = stored(a.shape)
+    assert slices
+    relaid = []
+    for ln in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%\S+ = \w+\[([\d,]+)\]\{([\d,]+)\S* "
+                     r"([\w-]+)\(", ln)
+        if m is None:
+            continue
+        dims, layout, op = m.groups()
+        dims = tuple(int(n) for n in dims.split(","))
+        if dims in slices and (op == "copy" or layout != slices[dims]):
+            relaid.append(ln.strip()[:160])
+    assert not relaid, relaid
 
 
 #: sha256 of the paged kernel's program at Pythia's cell shapes (24 decode
