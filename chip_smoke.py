@@ -42,6 +42,12 @@ weights from a seed:
          the hybrid state-space block at ``phi-4-mini-flash-reasoning``'s
          widths and a shorter pattern served through ``serving_engine()``
          (three kinds of state a slot) against its float32 reference.
+  ssd    (one chip) both lanes of the Mamba-2 recurrence
+         (``ops/transformer/ssd_scan.py``) at ``granite-4.0-h-micro``'s
+         widths (64 heads x 64 x 128) in bfloat16 against the loop over
+         rows, and the Mamba-2 / attention hybrid block at its widths and
+         a shorter pattern served through ``serving_engine()`` against its
+         float32 reference.
 
 It fails — non-zero exit, no result line — when JAX shows anything but
 CHIPS TPU devices; it never adapts downward, and nothing on the path is
@@ -649,6 +655,99 @@ def hybrid_phase(device: dict, block: int = SERVING["kv_block_size"]):
             "atol": KERNEL_ATOL}
 
 
+def ssd_hybrid_phase(device: dict):
+    """The Mamba-2 recurrence's two lanes at their cell's widths and the
+    hybrid block itself, served: see the module docstring."""
+    import jax
+    import jax.numpy as jnp
+    import deepspeed_tpu as ds
+    from benchmark.lib import reference_granite_hybrid as reference
+    from deepspeed_tpu.models import build_model, granite_hybrid_config
+    from deepspeed_tpu.ops.transformer import ssd_scan
+
+    rng = np.random.default_rng(SEED + 7)
+    heads, hp, n, chunk, slots = 64, 64, 128, 512, 64
+
+    def normal(*shape):
+        return jnp.asarray(rng.standard_normal(shape), jnp.float32)
+    a = -jnp.asarray(rng.uniform(1.0, 16.0, heads), jnp.float32)
+    d_skip = jnp.ones((heads,), jnp.float32)
+    # the chunk lane: 512 rows (a ragged 300 valid), from a given state,
+    # its products' inputs in bfloat16
+    x, bm, cm = normal(chunk, heads, hp), normal(chunk, n), normal(chunk, n)
+    dt = jax.nn.softplus(normal(chunk, heads) - 3.0)
+    s0 = normal(heads, hp, n)
+    bf = lambda t: t.astype(jnp.bfloat16)               # noqa: E731
+    y, s1 = jax.jit(ssd_scan.ssd_chunk_scan)(
+        bf(x), dt, bf(bm), bf(cm), a, d_skip, s0, 300)
+    want_y, want_s = jax.jit(ssd_scan.ssd_scan_reference)(
+        bf(x), dt, bf(bm), bf(cm), a, d_skip, s0, 300)
+    chunk_err = max(
+        float(jnp.linalg.norm(y[:300] - want_y[:300])
+              / jnp.linalg.norm(want_y[:300])),
+        float(jnp.linalg.norm(s1 - want_s) / jnp.linalg.norm(want_s)))
+    check(chunk_err < 1e-2, f"ssd: blocked scan off the loop by {chunk_err}")
+    # the decode lane: every slot's row, every other slot idle
+    states = normal(slots, heads, hp, n)
+    active = jnp.arange(slots) % 2 == 0
+    y, new = jax.jit(ssd_scan.ssd_decode_update)(
+        bf(x[:slots]), dt[:slots], bf(bm[:slots]), bf(cm[:slots]), a, d_skip,
+        states, active)
+    want = jax.jit(jax.vmap(
+        lambda *r: ssd_scan.ssd_scan_reference(*(t[None] for t in r[:4]), a,
+                                               d_skip, r[4])))(
+        bf(x[:slots]), dt[:slots], bf(bm[:slots]), bf(cm[:slots]), states)
+    decode_err = max(
+        float(jnp.max(jnp.abs(new[::2] - want[1][::2]))),
+        float(jnp.max(jnp.abs(y[::2] - want[0][::2, 0]))),
+        float(jnp.max(jnp.abs(new[1::2] - states[1::2]))))
+    check(decode_err < 1e-3, f"ssd: decode update off the loop by "
+          f"{decode_err}")
+
+    # the block, served: published widths, a pattern of six layers
+    pattern = ("mamba", "mamba", "attention") * 2
+    model = build_model(granite_hybrid_config(
+        "h-micro", num_layers=6, layer_types=pattern, vocab_size=2048,
+        max_seq_len=2048))
+    params = jax.jit(lambda k: jax.tree_util.tree_map(
+        lambda t: t.astype(jnp.bfloat16), model.init(k)))(
+            jax.random.PRNGKey(SEED + 8))
+    srv = ds.init_inference(
+        model, {"dtype": "bfloat16", "max_out_tokens": 2048,
+                "temperature": 0.0, "serving": dict(SERVING,
+                                                    num_kv_blocks=1024)},
+        params=params).serving_engine()
+    reqs = [srv.submit(rng.integers(0, 2048, p), max_new_tokens=m)
+            for p, m in ((700, 12), (333, 12), (1100, 8))]
+    srv.run()
+    c = model.config
+    cfg = {"layer_types": pattern, "heads": c.num_heads,
+           "kv_heads": c.kv_heads, "eps": 1e-5, "ssm_heads": heads,
+           "ssm_head_dim": hp, "state": n,
+           "attention_multiplier": c.attn_softmax_scale,
+           "embedding_multiplier": c.embedding_multiplier,
+           "residual_multiplier": c.residual_multiplier,
+           "logits_scaling": c.logits_scaling, "rope_theta": 10000,
+           "without": ()}
+    gap = 0.0
+    for r in reqs:
+        check(len(r.output) == r.max_new_tokens, "ssd: a stream ended short")
+        full = jnp.asarray(list(r.prompt) + list(r.output))[None]
+        lg = np.asarray(jax.jit(lambda p, i: reference.logits(
+            p, i, cfg, last=r.max_new_tokens + 1))(params, full))[0]
+        gap = max(gap, max(float(lg[j].max() - lg[j][tok])
+                           for j, tok in enumerate(r.output)))
+    held = srv.allocator.num_used_by_kind()
+    check(gap < 0.25, f"ssd: a served token {gap} under the reference's "
+          f"best logit")
+    check(not any(held.values()), f"ssd: state held after drain {held}")
+    check(srv.decode_builds == 2, "ssd: the step's two shapes, no more")
+    return {"phase": "ssd", **device,
+            "chunk_scan_rel_err": float(chunk_err),
+            "decode_update_max_abs_err": float(decode_err),
+            "served_logit_gap_worst": round(gap, 4)}
+
+
 def _shared_expert_layer_error(rows: int = 640) -> float:
     """One expert layer of the sandwich block at its published widths in
     bfloat16 — sigmoid top-8 gate over 256 outputs, renormalised and scaled
@@ -702,6 +801,7 @@ def main(argv) -> int:
     if chips == 1:
         print(json.dumps(latent_phase(device)), flush=True)
         print(json.dumps(hybrid_phase(device)), flush=True)
+        print(json.dumps(ssd_hybrid_phase(device)), flush=True)
     print(json.dumps({"phase": "total", **device,
                       "wall_s": round(time.perf_counter() - t0, 1),
                       **log.since((0, 0, 0.0, 0.0))}), flush=True)

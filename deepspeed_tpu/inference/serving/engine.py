@@ -402,12 +402,15 @@ class ServingEngine:
         # what a slot keeps besides the pool's pages (a window kind's
         # pool, per-slot recurrent state): the model's own tree, carried
         # by the step like the pools; None for a block that has none
-        self._pool_x = model.init_paged_extra(
-            self.num_slots, self.block_size, self.window_blocks,
-            dtype=engine.dtype)
+        # (made where it will live: at gigabytes of state a copy on the
+        # way in would not fit beside the weights)
+        make_extra = jax.jit(
+            lambda: model.init_paged_extra(
+                self.num_slots, self.block_size, self.window_blocks,
+                dtype=engine.dtype),
+            out_shardings=NamedSharding(self.tp_mesh, P()))
+        self._pool_x = make_extra()
         if self._pool_x is not None:
-            self._pool_x = jax.device_put(
-                self._pool_x, NamedSharding(self.tp_mesh, P()))
             self.allocator.add_state_kind(self.num_slots)
         self._prep_tp_params()
         logger.info(

@@ -132,47 +132,23 @@ class HybridStep(NamedTuple):
     chunk: int                 # C (static; 0 = the decode-only shape)
 
 
-class HybridSSMLM(TransformerLM):
-    """``TransformerLM``'s surface (``init`` / ``apply`` / ``generate()``'s
-    cache / ``init_paged_cache`` / ``_apply_paged_mixed`` /
-    ``partition_specs``) for the hybrid block."""
+class PerSlotStateLM(TransformerLM):
+    """What the blocks that keep recurrent state a SLOT share (this
+    file's, and ``models/ssd_hybrid.py``'s): the refusals and their
+    sentences, the gated MLP, the dense attention of ``generate()``, the
+    scatter of a step's new rows into a paged pool, the convolution
+    tails' life through the mixed step, and ``apply``.  A subclass brings
+    its pattern, its mixers, ``init`` / ``_forward`` / ``init_cache`` and
+    the mixed step.  Its ``init()`` is ``init_resident`` plus, for every
+    part of ``PARTS`` (stack name -> what an element is made from), the
+    stack of ``init_pair(PARTS[part], key)`` over ``pair_keys(rng)[part]``:
+    whoever fills a tree an element at a time (the benchmark, in the
+    served type) goes through those three."""
 
-    #: the block tables a slot has, in the order the engine lays them
-    #: side by side in its per-slot operand
-    TABLE_KINDS = ("full", "window")
-    #: what ``_apply_paged_mixed`` counts a dispatch, each added up where
-    #: the work is handed to its kernel (a layer that walked more, or a
-    #: row that was not spared, moves it): keys the eight walks over the
-    #: FULL layer's pages were handed, keys the window layers' walks were,
-    #: (row, state-space layer) pairs through the chunk scan and through
-    #: the decode update, live rows that stopped before the cross decoder,
-    #: chunks that started a slot's state from zero
-    PAGED_COUNTERS = ("kv_tokens_read_full", "kv_tokens_read_window",
-                      "ssm_chunk_rows", "ssm_decode_rows",
-                      "cross_rows_spared", "state_slots_started")
-
-    def __init__(self, config: HybridSSMConfig, constrain=None,
-                 block_transform=None):
-        super().__init__(config, constrain, block_transform)
-        c = config
-        if c.num_layers != len(c.layer_kinds):
-            raise ValueError(
-                f"num_layers {c.num_layers} is not 2 x ({c.pairs_self} + 1 "
-                f"+ {c.pairs_cross}) layers of the pattern")
-        if c.pos_embedding != "none" or c.norm_type != "layernorm" \
-                or not c.tie_embeddings:
-            raise ValueError(
-                "the hybrid block has no positional encoding, LayerNorms "
-                "and a tied head (models.transformer.phi4_flash_config)")
-        self._sm_scale = 1.0 / math.sqrt(c.hdim)
+    #: why a quantized pool is refused (the block's own reason)
+    KV_BITS_REFUSAL = ""
 
     # -- refusals ----------------------------------------------------------
-    def training_refusal(self) -> Optional[str]:
-        return ("the hybrid state-space block serves and does not train: "
-                "the selective scan (ops/transformer/ssm_scan.py) has no "
-                "backward kernel, and its window layers have no flash "
-                "path (ROADMAP B7, B11)")
-
     def prefix_cache_refusal(self) -> Optional[str]:
         return ("a prefix-cache hit would resume a prompt at a block "
                 "boundary, and the state-space layers' recurrent state at "
@@ -201,9 +177,7 @@ class HybridSSMLM(TransformerLM):
                     "have to roll the state-space layers' recurrent state "
                     "back, and the state keeps no history")
         if kv_bits:
-            return (f"serving.kv_cache_bits={kv_bits}: the window layers' "
-                    f"walk starts inside a slot's table, and the quantized "
-                    f"pool's scale rows take no first page")
+            return f"serving.kv_cache_bits={kv_bits}: {self.KV_BITS_REFUSAL}"
         if host_cache:
             return ("serving.host_cache: the host tier moves pages of k "
                     "and v; a slot's recurrent state is not a page and has "
@@ -219,9 +193,11 @@ class HybridSSMLM(TransformerLM):
         return None
 
     # -- init --------------------------------------------------------------
-    def _norm_init(self):
-        return L.layernorm_init(None, self.config.d_model,
-                                self.config.param_dtype)
+    def _norm_init(self, dim: Optional[int] = None):
+        c = self.config
+        init = (L.layernorm_init if c.norm_type == "layernorm"
+                else L.rmsnorm_init)
+        return init(None, dim or c.d_model, c.param_dtype)
 
     def _mlp_init(self, k) -> Dict:
         c, dt = self.config, self.config.param_dtype
@@ -235,6 +211,162 @@ class HybridSSMLM(TransformerLM):
         return {"ln1": self._norm_init(), "mixer": mixer,
                 "ln2": self._norm_init(), "mlp": self._mlp_init(k)}
 
+    def partition_specs(self, params=None) -> Dict:
+        """Everything replicated: the block serves on one chip."""
+        if params is None:
+            params = jax.eval_shape(lambda: self.init(jax.random.PRNGKey(0)))
+        return jax.tree_util.tree_map(lambda a: P(*([None] * a.ndim)),
+                                      params)
+
+    # -- what every path shares --------------------------------------------
+    def _glu_mlp(self, p, x):
+        with jax.named_scope("mlp"):
+            g, u = jnp.split(L.dense_apply(p["gate_up"], x), 2, axis=-1)
+            return L.dense_apply(p["down"], jax.nn.silu(g) * u)
+
+    def _attend_dense(self, q, k, v, q_pos, window: Optional[int]):
+        """q ``[B, Tq, H, hd]`` at positions ``q_pos [Tq]`` against k, v
+        ``[B, Tk, Hkv, hd]`` at positions ``0 .. Tk - 1``."""
+        k_pos = jnp.arange(k.shape[1])
+        seen = k_pos[None, :] <= q_pos[:, None]
+        if window is not None:
+            seen = seen & (k_pos[None, :] > q_pos[:, None] - window)
+        with jax.named_scope("attn_kernel"):
+            return L.gqa_attention(q, k, v, causal=False, scale=self._sm_scale,
+                                   mask=seen[None, None, None])
+
+    def hidden_states_and_aux(self, params, input_ids, token_type_ids=None):
+        x, _ = self._forward(params, self._embed_tokens(params, input_ids))
+        return (self._norm_fn("head")(params["ln_f"], x),
+                jnp.zeros((), jnp.float32))
+
+    def apply(self, params, input_ids, cache=None, positions=None,
+              token_type_ids=None):
+        if cache is None:
+            x, _ = self.hidden_states_and_aux(params, input_ids)
+            return self._project(params, x)
+        x, cache = self._forward(
+            params, self._embed_tokens(params, input_ids), cache)
+        return (self._project(params,
+                              self._norm_fn("head")(params["ln_f"], x)),
+                cache)
+
+    # -- the mixed step's shared pieces ------------------------------------
+    @staticmethod
+    def _conv_rows(w, u, tails, st: HybridStep):
+        """The causal depthwise convolution of the step's rows ``u [S +
+        C, channels]`` (before its bias and silu): the decode rows each
+        behind their slot's tail ``tails [taps - 1, S, channels]``, the
+        chunk behind its slot's (zero where the chunk starts a prompt).
+        Returns ``(conv [S + C, channels], win, padded)``, the last two
+        for :meth:`_next_tails`."""
+        k = w.shape[0]
+        s, cw = st.slots, st.chunk
+        win = jnp.concatenate([tails, u[None, :s]])           # [k, S, ch]
+        conv = [sum(w[j] * win[k - 1 - j] for j in range(k))]
+        padded = None
+        if cw:
+            ctail = jnp.where(st.chunk_start == 0, 0,
+                              tails[:, st.chunk_slot])
+            padded = jnp.concatenate([ctail, u[s:]])
+            conv.append(sum(w[j] * padded[k - 1 - j:k - 1 - j + cw]
+                            for j in range(k)))
+        return jnp.concatenate(conv), win, padded
+
+    @staticmethod
+    def _next_tails(tails, win, padded, st: HybridStep):
+        """The tails after the step: a decoding slot's moves on a row, the
+        chunk's slot holds the chunk's last valid rows, the rest stay."""
+        k = win.shape[0]
+        tails = jnp.where(st.act[None, :, None], win[1:], tails)
+        if st.chunk:
+            tails = tails.at[:, st.chunk_slot].set(jnp.where(
+                st.chunk_len > 0, jax.lax.dynamic_slice_in_dim(
+                    padded, st.chunk_len, k - 1),
+                tails[:, st.chunk_slot]))
+        return tails
+
+    @staticmethod
+    def _write_rows(pool_k, pool_v, k, v, tables, st: HybridStep, null):
+        """The step's new k / v rows ``[S + C, lanes]`` into the pools at
+        their slots' pages (``tables`` already offset to the layer; masked
+        rows to the layer's null block ``null``)."""
+        blk = pool_k.shape[1]
+        npages = tables.shape[1]
+        null_row = null * blk
+        slot = jnp.arange(st.slots)
+        write = [jnp.where(st.act, tables[slot, st.lens // blk] * blk
+                           + st.lens % blk, null_row)]
+        if st.chunk:
+            ci = jnp.arange(st.chunk)
+            cpos = st.chunk_start + ci
+            ctable = tables[st.chunk_slot]
+            write.append(jnp.where(
+                ci < st.chunk_len,
+                ctable[jnp.minimum(cpos // blk, npages - 1)] * blk
+                + cpos % blk, null_row))
+        write = jnp.concatenate(write)
+
+        def put(pool, rows):
+            return pool.reshape(-1, pool.shape[2]).at[write].set(
+                rows.astype(pool.dtype)).reshape(pool.shape)
+        return put(pool_k, k), put(pool_v, v)
+
+    def _yield_rows(self, a, st: HybridStep):
+        """The rows of ``a [S + C, ..]`` that yield a token: the decode
+        rows and the chunk's last valid row."""
+        if not st.chunk:
+            return a
+        last = jax.lax.dynamic_slice_in_dim(
+            a, st.slots + jnp.maximum(st.chunk_len - 1, 0), 1, axis=0)
+        return jnp.concatenate([a[:st.slots], last])
+
+
+class HybridSSMLM(PerSlotStateLM):
+    """``TransformerLM``'s surface (``init`` / ``apply`` / ``generate()``'s
+    cache / ``init_paged_cache`` / ``_apply_paged_mixed`` /
+    ``partition_specs``) for the hybrid block."""
+
+    #: the block tables a slot has, in the order the engine lays them
+    #: side by side in its per-slot operand
+    TABLE_KINDS = ("full", "window")
+    #: what ``_apply_paged_mixed`` counts a dispatch, each added up where
+    #: the work is handed to its kernel (a layer that walked more, or a
+    #: row that was not spared, moves it): keys the eight walks over the
+    #: FULL layer's pages were handed, keys the window layers' walks were,
+    #: (row, state-space layer) pairs through the chunk scan and through
+    #: the decode update, live rows that stopped before the cross decoder,
+    #: chunks that started a slot's state from zero
+    PAGED_COUNTERS = ("kv_tokens_read_full", "kv_tokens_read_window",
+                      "ssm_chunk_rows", "ssm_decode_rows",
+                      "cross_rows_spared", "state_slots_started")
+    KV_BITS_REFUSAL = ("the window layers' walk starts inside a slot's "
+                       "table, and the quantized pool's scale rows take no "
+                       "first page")
+
+    def __init__(self, config: HybridSSMConfig, constrain=None,
+                 block_transform=None):
+        super().__init__(config, constrain, block_transform)
+        c = config
+        if c.num_layers != len(c.layer_kinds):
+            raise ValueError(
+                f"num_layers {c.num_layers} is not 2 x ({c.pairs_self} + 1 "
+                f"+ {c.pairs_cross}) layers of the pattern")
+        if c.pos_embedding != "none" or c.norm_type != "layernorm" \
+                or not c.tie_embeddings:
+            raise ValueError(
+                "the hybrid block has no positional encoding, LayerNorms "
+                "and a tied head (models.transformer.phi4_flash_config)")
+        self._sm_scale = 1.0 / math.sqrt(c.hdim)
+
+    # -- refusals ----------------------------------------------------------
+    def training_refusal(self) -> Optional[str]:
+        return ("the hybrid state-space block serves and does not train: "
+                "the selective scan (ops/transformer/ssm_scan.py) has no "
+                "backward kernel, and its window layers have no flash "
+                "path (ROADMAP B7, B11)")
+
+    # -- init --------------------------------------------------------------
     def init_layer(self, kind: str, k) -> Dict:
         """One layer of ``kind`` (no leading stack axis)."""
         c, dt = self.config, self.config.param_dtype
@@ -307,19 +439,6 @@ class HybridSSMLM(TransformerLM):
             params[part] = (jax.tree_util.tree_map(lambda a: a[0], stack)
                             if part == "mid" else stack)
         return params
-
-    def partition_specs(self, params=None) -> Dict:
-        """Everything replicated: the block serves on one chip."""
-        if params is None:
-            params = jax.eval_shape(lambda: self.init(jax.random.PRNGKey(0)))
-        return jax.tree_util.tree_map(lambda a: P(*([None] * a.ndim)),
-                                      params)
-
-    # -- what every path shares --------------------------------------------
-    def _glu_mlp(self, p, x):
-        with jax.named_scope("mlp"):
-            g, u = jnp.split(L.dense_apply(p["gate_up"], x), 2, axis=-1)
-            return L.dense_apply(p["down"], jax.nn.silu(g) * u)
 
     def _shell(self, bp, x, mixer):
         """``x + mixer(LN x)``, then ``+ MLP(LN' ..)``; ``mixer(p, h) ->
@@ -403,17 +522,6 @@ class HybridSSMLM(TransformerLM):
             )(c, step, bm, cm, state)
         return self._ssm_out(p, y, z), y, padded[:, t:], state
 
-    def _attend_dense(self, q, k, v, q_pos, window: Optional[int]):
-        """q ``[B, Tq, H, hd]`` at positions ``q_pos [Tq]`` against k, v
-        ``[B, Tk, Hkv, hd]`` at positions ``0 .. Tk - 1``."""
-        k_pos = jnp.arange(k.shape[1])
-        seen = k_pos[None, :] <= q_pos[:, None]
-        if window is not None:
-            seen = seen & (k_pos[None, :] > q_pos[:, None] - window)
-        with jax.named_scope("attn_kernel"):
-            return L.gqa_attention(q, k, v, causal=False, scale=self._sm_scale,
-                                   mask=seen[None, None, None])
-
     def _forward(self, params, x, cache=None):
         """Every layer over ``x [B, T, d]``; ``cache`` as
         :meth:`init_cache` gives it (``None``: a whole sequence from
@@ -486,22 +594,6 @@ class HybridSSMLM(TransformerLM):
         cache["index"] = idx + t
         return x, cache
 
-    def hidden_states_and_aux(self, params, input_ids, token_type_ids=None):
-        x, _ = self._forward(params, self._embed_tokens(params, input_ids))
-        return (self._norm_fn("head")(params["ln_f"], x),
-                jnp.zeros((), jnp.float32))
-
-    def apply(self, params, input_ids, cache=None, positions=None,
-              token_type_ids=None):
-        if cache is None:
-            x, _ = self.hidden_states_and_aux(params, input_ids)
-            return self._project(params, x)
-        x, cache = self._forward(
-            params, self._embed_tokens(params, input_ids), cache)
-        return (self._project(params,
-                              self._norm_fn("head")(params["ln_f"], x)),
-                cache)
-
     # -- paged serving -----------------------------------------------------
     def window_pages(self, block_size: int, chunk_tokens: int
                      ) -> Tuple[int, int]:
@@ -560,8 +652,6 @@ class HybridSSMLM(TransformerLM):
         ``(out, y [S + C, d_inner], conv_buf, ssm_buf, rows)``, ``rows``
         what the scans were handed: ``[the chunk kernel's valid rows, the
         decode lane's live rows, chunks begun from zero state]``."""
-        cfg = self.config
-        k = cfg.ssm_conv
         s, cw = st.slots, st.chunk
         with jax.named_scope("ssm_proj"):
             u, z = jnp.split(L.dense_apply(p["in_proj"], h[0]), 2, axis=-1)
@@ -571,15 +661,8 @@ class HybridSSMLM(TransformerLM):
             tails = jax.lax.dynamic_slice_in_dim(conv_buf, at, s, axis=1)
             states = jax.lax.dynamic_slice_in_dim(ssm_buf, at, s)
         with jax.named_scope("ssm_proj"):
-            win = jnp.concatenate([tails, u[None, :s]])       # [k, S, Di]
-            conv = [sum(w[j] * win[k - 1 - j] for j in range(k))]
-            if cw:
-                fresh = st.chunk_start == 0
-                ctail = jnp.where(fresh, 0, tails[:, st.chunk_slot])
-                padded = jnp.concatenate([ctail, u[s:]])
-                conv.append(sum(w[j] * padded[k - 1 - j:k - 1 - j + cw]
-                                for j in range(k)))
-            c, step, bm, cm = self._ssm_rows(p, jnp.concatenate(conv))
+            conv, win, padded = self._conv_rows(w, u, tails, st)
+            c, step, bm, cm = self._ssm_rows(p, conv)
         a, d_skip = self._ssm_consts(p)
         with jax.named_scope("ssm_scan"):
             y, new = ssm_scan.ssm_decode_update(
@@ -589,6 +672,7 @@ class HybridSSMLM(TransformerLM):
             rows = [jnp.int32(0), jnp.sum(st.act.astype(jnp.int32)),
                     jnp.int32(0)]
             if cw:
+                fresh = st.chunk_start == 0
                 state0 = jnp.where(fresh, 0.0, states[st.chunk_slot])
                 yc, state1 = ssm_scan.ssm_chunk_scan(
                     c[s:], step[s:], bm[s:], cm[s:], a, d_skip, state0,
@@ -597,47 +681,16 @@ class HybridSSMLM(TransformerLM):
                 rows[0] = st.chunk_len
                 rows[2] = (fresh & (st.chunk_len > 0)).astype(jnp.int32)
         with jax.named_scope("state_io"):
-            tails = jnp.where(st.act[None, :, None], win[1:], tails)
+            tails = self._next_tails(tails, win, padded, st)
             if cw:
-                rides = st.chunk_len > 0
-                tails = tails.at[:, st.chunk_slot].set(jnp.where(
-                    rides, jax.lax.dynamic_slice_in_dim(
-                        padded, st.chunk_len, k - 1),
-                    tails[:, st.chunk_slot]))
                 new = new.at[st.chunk_slot].set(jnp.where(
-                    rides, state1, new[st.chunk_slot]))
+                    st.chunk_len > 0, state1, new[st.chunk_slot]))
             conv_buf = jax.lax.dynamic_update_slice_in_dim(
                 conv_buf, tails.astype(conv_buf.dtype), at, 1)
             ssm_buf = jax.lax.dynamic_update_slice_in_dim(ssm_buf, new, at,
                                                           0)
         return (self._ssm_out(p, y, z)[None], y, conv_buf, ssm_buf,
                 jnp.stack(rows).astype(jnp.int32))
-
-    @staticmethod
-    def _write_rows(pool_k, pool_v, k, v, tables, st: HybridStep, null):
-        """The step's new k / v rows ``[S + C, lanes]`` into the pools at
-        their slots' pages (``tables`` already offset to the layer; masked
-        rows to the layer's null block ``null``)."""
-        blk = pool_k.shape[1]
-        npages = tables.shape[1]
-        null_row = null * blk
-        slot = jnp.arange(st.slots)
-        write = [jnp.where(st.act, tables[slot, st.lens // blk] * blk
-                           + st.lens % blk, null_row)]
-        if st.chunk:
-            ci = jnp.arange(st.chunk)
-            cpos = st.chunk_start + ci
-            ctable = tables[st.chunk_slot]
-            write.append(jnp.where(
-                ci < st.chunk_len,
-                ctable[jnp.minimum(cpos // blk, npages - 1)] * blk
-                + cpos % blk, null_row))
-        write = jnp.concatenate(write)
-
-        def put(pool, rows):
-            return pool.reshape(-1, pool.shape[2]).at[write].set(
-                rows.astype(pool.dtype)).reshape(pool.shape)
-        return put(pool_k, k), put(pool_v, v)
 
     def _window_paged(self, p, h, wk, wv, off, st: HybridStep):
         """A window-attention mixer in the mixed step: every row writes
@@ -676,15 +729,6 @@ class HybridSSMLM(TransformerLM):
         with jax.named_scope("attn_proj"):
             return (L.dense_apply(p["out"], o.reshape(1, -1, nh * hd)), wk,
                     wv, read.astype(jnp.int32))
-
-    def _yield_rows(self, a, st: HybridStep):
-        """The rows of ``a [S + C, ..]`` that yield a token: the decode
-        rows and the chunk's last valid row."""
-        if not st.chunk:
-            return a
-        last = jax.lax.dynamic_slice_in_dim(
-            a, st.slots + jnp.maximum(st.chunk_len - 1, 0), 1, axis=0)
-        return jnp.concatenate([a[:st.slots], last])
 
     def _full_walk(self, q, pool_k, pool_v, st: HybridStep):
         """The yield rows' queries ``[S (+ 1), H, hd]`` against the full

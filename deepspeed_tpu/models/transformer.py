@@ -426,6 +426,39 @@ def phi4_flash_config(size: str = "mini", **kw) -> TransformerConfig:
         **PHI4_FLASH_SIZES[size], **kw})
 
 
+GRANITE_HYBRID_SIZES = {
+    # https://huggingface.co/ibm-granite/granite-4.0-h-micro config.json
+    # (granitemoehybrid, dense: num_local_experts 0, the shared MLP is
+    # the only one)
+    "h-micro": dict(
+        num_layers=40,
+        layer_types=(("mamba",) * 5 + ("attention",) + ("mamba",) * 4) * 4,
+        num_heads=32, num_kv_heads=8, d_model=2048, d_ff=8192,
+        vocab_size=100352, max_seq_len=131072, ssm_heads=64,
+        ssm_head_dim=64, ssm_state=128, ssm_conv=4,
+        attn_softmax_scale=0.015625, embedding_multiplier=12.0,
+        residual_multiplier=0.22, logits_scaling=8.0),
+}
+
+
+def granite_hybrid_config(size: str = "h-micro", **kw) -> TransformerConfig:
+    """Granite 4.0-H's dense language model (``granitemoehybrid``):
+    Mamba-2 layers and a few position-free grouped-query attention layers
+    in the order ``layer_types`` lists, a gated MLP in every layer,
+    RMSNorms and the family's four multipliers
+    (``models/ssd_hybrid.py``).  ``size`` names a published set of widths;
+    another pattern (``layer_types`` with ``num_layers`` its length), the
+    vocabulary and the served positions come as keywords."""
+    from .ssd_hybrid import SSDHybridConfig
+    if "layer_types" in kw:
+        kw["layer_types"] = tuple(kw["layer_types"])
+    return SSDHybridConfig(**{
+        "pos_embedding": "none", "norm_type": "rmsnorm",
+        "gated_mlp": True, "activation": "silu", "use_bias": False,
+        "tie_embeddings": True, "layernorm_eps": 1e-5,
+        **GRANITE_HYBRID_SIZES[size], **kw})
+
+
 def build_model(config: TransformerConfig, **kw) -> "TransformerLM":
     """The model that runs ``config``'s block: ``TransformerLM`` for the
     standard block, the config's own class (``config.model_class()``) for

@@ -934,6 +934,104 @@ def test_hybrid_mixed_step_keeps_pools_and_state_in_place(
     assert temp_bytes < int(np.prod(pools["wk"].shape[1:])) * 2
 
 
+def ssd_hybrid_model():
+    """The Mamba-2 hybrid block at its cell's widths (64 heads x 64 x 128
+    of state, 32 / 8 attention heads of 64) with a shorter pattern — two
+    periods of ``mamba, mamba, attention``: a scan over the periods, a
+    scan over the run inside it — and a small vocabulary."""
+    from deepspeed_tpu.models import build_model, granite_hybrid_config
+    return build_model(granite_hybrid_config(
+        "h-micro", num_layers=6,
+        layer_types=("mamba", "mamba", "attention") * 2, vocab_size=1024,
+        max_seq_len=4096))
+
+
+#: the Mamba-2 hybrid cell's engine: slots, pages a slot, blocks a layer
+SSD_HYBRID_SIZE = (64, 256, 10496)
+
+
+def ssd_hybrid_mixed_operands(devices, model, chunk):
+    slots, pages, nb = SSD_HYBRID_SIZE
+    sds = one_chip(devices)
+    args, pools, params = mixed_step_operands(devices, model, nb, 16, 0,
+                                              slots, pages, chunk)
+    extra = jax.tree_util.tree_map(
+        lambda a: sds(a.shape, a.dtype), jax.eval_shape(
+            lambda: model.init_paged_extra(slots, 16, 0, jnp.bfloat16)))
+    args[1]["extra"] = extra
+    return args, dict(pools, **extra), params
+
+
+def build_ssd_hybrid_mixed(devices, chunk):
+    model = ssd_hybrid_model()
+    args, _, _ = ssd_hybrid_mixed_operands(devices, model, chunk)
+    return jax.jit(model._apply_paged_mixed, donate_argnums=1).trace(
+        *args).lower(lowering_platforms=("tpu",)).compile()
+
+
+def unfused_instructions(text):
+    """``(name, result type, opcode, line)`` of the instructions that are
+    buffers of their own: those outside the computations a fusion
+    calls."""
+    import re
+    fused = set(re.findall(r" fusion\(.*?, calls=%([^\s,]+)", text))
+    for name, lines in hlo_computations(text)[0].items():
+        if name in fused:
+            continue
+        for ln in lines:
+            m = re.match(r"\s*(?:ROOT )?(%\S+) = (.+?) ([\w-]+)\(", ln)
+            if m is not None:
+                yield m.group(1), m.group(2), m.group(3), ln.strip()
+
+
+@pytest.mark.parametrize("shape", list(HYBRID_CHUNK))
+def test_ssd_hybrid_step_updates_the_state_where_it_lies(
+        v5e_devices, compiled_kernels, step_programs, shape):
+    """The Mamba-2 hybrid block's step at its cell's widths: a layer's 64
+    slots' states are 134 MB of the donated ``[layers x 64, 64, 64, 128]``
+    float32 buffer, and the decode lane's update reads them there and
+    writes them back there — ONE fusion whose results are the rows'
+    outputs and the whole buffer (``dynamic_slice`` -> update ->
+    ``dynamic_update_slice``, the buffer aliased through both scans).  So
+    outside a fusion the step holds nothing shaped like a layer's states,
+    no copy and no slice of the buffer or of a pool, and its temporaries
+    are less than one layer's states (decode only: a fiftieth).  The
+    decode-only shape calls the paged kernel once (the scanned period's
+    attention layer), the mixed shape once more for the chunk.  (This is
+    the block's step alone; on the chip the ENGINE's mixed program, the
+    sampler around it, rematerialises the rows' reduction as a fusion of
+    its own, one more read of a layer's states: ``PERF.md`` section 5.)"""
+    import re
+    chunk = HYBRID_CHUNK[shape]
+    model = ssd_hybrid_model()
+    _, pools, _ = ssd_hybrid_mixed_operands(v5e_devices, model, chunk)
+    text, temp_bytes = step_programs(f"ssd-hybrid-{shape}")
+    assert custom_calls(text) == (2 if chunk else 1)
+    slots = SSD_HYBRID_SIZE[0]
+    layer_states = (slots,) + pools["ssm"].shape[1:]
+    dims = lambda shape: ",".join(map(str, shape))        # noqa: E731
+    whole = {dims(pools["ssm"].shape)}
+    for name in ("k", "v"):
+        a = pools[name]
+        whole |= {dims(a.shape), dims(a.shape[1:]),
+                  dims((a.shape[0] * a.shape[1],) + a.shape[2:])}
+    moved, updates = [], 0
+    for name, result, op, ln in unfused_instructions(text):
+        shapes = set(re.findall(r"\w+\[([\d,]+)\]", result))
+        if dims(layer_states) in shapes or (
+                shapes & whole and (op in ("copy", "dynamic-slice")
+                                    or "AllocateBuffer" in ln)):
+            moved.append(ln[:160])
+        if op == "fusion" and {dims(layer_states[:-1]),
+                               dims(pools["ssm"].shape)} <= shapes:
+            updates += 1
+    assert not moved, moved
+    assert updates == 1          # the scanned run's one layer body
+    assert re.search(r"input_output_alias=\{[^\n]*may-alias", text)
+    one_layer = int(np.prod(layer_states)) * 4
+    assert temp_bytes < (one_layer if chunk else one_layer // 50)
+
+
 def test_train_grad_compiles_on_four_chips(v5e_devices, compiled_kernels):
     """GPT-2 350M ``value_and_grad(model.loss)`` with the batch sharded
     over a data=4 mesh: the flash kernel must sit inside a shard_map or
@@ -1438,6 +1536,12 @@ for _shape, _chunk in HYBRID_CHUNK.items():
     STEP_PROGRAMS[f"hybrid-{_shape}"] = (
         lambda dev, c=_chunk: build_hybrid_mixed(dev, c),
         _SERVE | {"ssm_proj", "ssm_scan", "gmu", "state_io"})
+
+
+for _shape, _chunk in HYBRID_CHUNK.items():
+    STEP_PROGRAMS[f"ssd-hybrid-{_shape}"] = (
+        lambda dev, c=_chunk: build_ssd_hybrid_mixed(dev, c),
+        _SERVE | {"ssm_proj", "ssm_scan", "state_io"})
 
 
 STEP_PROGRAMS["train-moe-1chip"] = (
