@@ -45,9 +45,10 @@ weights from a seed:
   ssd    (one chip) both lanes of the Mamba-2 recurrence
          (``ops/transformer/ssd_scan.py``) at ``granite-4.0-h-micro``'s
          widths (64 heads x 64 x 128) in bfloat16 against the loop over
-         rows, and the Mamba-2 / attention hybrid block at its widths and
-         a shorter pattern served through ``serving_engine()`` against its
-         float32 reference.
+         rows (the decode kernel in place, at the middle layer's rows of
+         three layers' states), and the Mamba-2 / attention hybrid block
+         at its widths and a shorter pattern served through
+         ``serving_engine()`` against its float32 reference.
 
 It fails — non-zero exit, no result line — when JAX shows anything but
 CHIPS TPU devices; it never adapts downward, and nothing on the path is
@@ -687,22 +688,32 @@ def ssd_hybrid_phase(device: dict):
               / jnp.linalg.norm(want_y[:300])),
         float(jnp.linalg.norm(s1 - want_s) / jnp.linalg.norm(want_s)))
     check(chunk_err < 1e-2, f"ssd: blocked scan off the loop by {chunk_err}")
-    # the decode lane: every slot's row, every other slot idle
-    states = normal(slots, heads, hp, n)
+    # the decode lane: the kernel compiled, over a buffer of three layers'
+    # states at the middle layer's first row (a traced scalar), every
+    # other slot idle; the other layers' rows come back as they were
+    states = normal(3 * slots, heads, hp, n)
     active = jnp.arange(slots) % 2 == 0
-    y, new = jax.jit(ssd_scan.ssd_decode_update)(
-        bf(x[:slots]), dt[:slots], bf(bm[:slots]), bf(cm[:slots]), a, d_skip,
-        states, active)
+    mine = slice(slots, 2 * slots)
     want = jax.jit(jax.vmap(
         lambda *r: ssd_scan.ssd_scan_reference(*(t[None] for t in r[:4]), a,
                                                d_skip, r[4])))(
-        bf(x[:slots]), dt[:slots], bf(bm[:slots]), bf(cm[:slots]), states)
+        bf(x[:slots]), dt[:slots], bf(bm[:slots]), bf(cm[:slots]),
+        states[mine])
+    before = np.asarray(states)
+    y, new = jax.jit(ssd_scan.ssd_decode_update, donate_argnums=6)(
+        bf(x[:slots]), dt[:slots], bf(bm[:slots]), bf(cm[:slots]), a, d_skip,
+        states, active, jnp.int32(slots))
     decode_err = max(
-        float(jnp.max(jnp.abs(new[::2] - want[1][::2]))),
+        float(jnp.max(jnp.abs(new[mine][::2] - want[1][::2]))),
         float(jnp.max(jnp.abs(y[::2] - want[0][::2, 0]))),
-        float(jnp.max(jnp.abs(new[1::2] - states[1::2]))))
+        float(np.max(np.abs(np.asarray(new[mine][1::2])
+                            - before[mine][1::2]))),
+        float(np.max(np.abs(np.asarray(new[:slots]) - before[:slots]))),
+        float(np.max(np.abs(np.asarray(new[2 * slots:])
+                            - before[2 * slots:]))))
     check(decode_err < 1e-3, f"ssd: decode update off the loop by "
           f"{decode_err}")
+    del states, new, before
 
     # the block, served: published widths, a pattern of six layers
     pattern = ("mamba", "mamba", "attention") * 2

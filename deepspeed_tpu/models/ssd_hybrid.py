@@ -449,9 +449,9 @@ class SSDHybridLM(PerSlotStateLM):
         """A ``mamba`` mixer in the mixed step: the decode rows each from
         their slot's tail and state, the chunk from its slot's (zero where
         the chunk starts a prompt); ``h [S + C, d]``, ``layer`` the
-        layer's place among the ``mamba`` layers.  The states of the
-        layer's slots are read out of ``ssm_buf`` and written back inside
-        the update's own fusion.  Returns ``(out, conv_buf, ssm_buf)``."""
+        layer's place among the ``mamba`` layers.  The decode kernel is
+        handed all of ``ssm_buf`` and updates the layer's slots' states
+        where they lie.  Returns ``(out, conv_buf, ssm_buf)``."""
         s, cw = st.slots, st.chunk
         at = layer * s
         f32 = jnp.float32
@@ -465,11 +465,9 @@ class SSDHybridLM(PerSlotStateLM):
             x, step, bm, cm = self._ssm_rows(p, conv, dt)
         a, d_skip = self._ssm_consts(p)
         with jax.named_scope("ssm_scan"):
-            y, new = ssd_scan.ssd_decode_update(
-                x[:s], step[:s], bm[:s], cm[:s], a, d_skip,
-                jax.lax.dynamic_slice_in_dim(ssm_buf, at, s), st.act)
-            ssm_buf = jax.lax.dynamic_update_slice_in_dim(ssm_buf, new, at,
-                                                          0)
+            y, ssm_buf = ssd_scan.ssd_decode_update(
+                x[:s], step[:s], bm[:s], cm[:s], a, d_skip, ssm_buf, st.act,
+                first=at)
             if cw:
                 # after the decode lane: the chunk's slot decodes nothing
                 # this dispatch, so its state is as it was

@@ -1,6 +1,7 @@
 """The recurrence of a state-space layer with a SCALAR decay a head and a
-MATRIX state a head (Mamba-2 / SSD), both lanes of the serving step, in
-plain XLA.
+MATRIX state a head (Mamba-2 / SSD), both lanes of the serving step: the
+chunk's blocked form in plain XLA, the decode rows' update a Pallas TPU
+kernel over the step's state buffer, in place.
 
 Per head ``h`` of ``H``, with ``x_t^h [P]`` the head's slice of the
 convolved input, ``B_t`` / ``C_t [N]`` shared by every head (one group),
@@ -34,27 +35,61 @@ came 6 % nearer the reference for it (``PERF.md`` section 4).  No loop
 over rows.
 
 ``ssd_decode_update`` — every slot's one row: no product worth the matrix
-unit, and the WHOLE state read and written.  It is one elementwise pass
-over ``[slots, H, P, N]`` with ``N`` on the lanes, written so that the
-caller's ``dynamic_slice`` of a layer's slots out of the step's state
-buffer and the ``dynamic_update_slice`` back fuse around it: the buffer
-is read once and written once, in place.
+unit, and the WHOLE state of a layer read and written.  The kernel is
+handed the step's whole buffer ``[layers x slots, H, P, N]`` (aliased to
+its result) and the layer's first row, streams that layer's slots through
+on-chip memory in blocks of ``DECODE_HEADS`` heads and writes each back
+where it lay: one read, one write, ``y`` from the same pass, whatever XLA
+fuses around the call.  Nothing else of the buffer is touched.
+
+The state has ``N`` on the lanes and ``(head, p)`` on the sublanes, while
+``x``, ``D x`` and ``y`` have ``p`` on the LANES; the two places where
+they meet go through the matrix unit, which transposes for nothing:
+
+* the rank-1 term ``(D x) (x) B`` is a transposed-left product over a
+  contraction of 16, ``L[16, hp]^T R[16, N]``.  Both factors are float32,
+  and one bfloat16 pass would round them; so each is cut into three
+  bfloat16 pieces that add up to it (:func:`_bf16_pieces`) and the nine
+  products of a piece with a piece are the nine live rows of the
+  contraction: ONE pass of exact products summed in float32, where
+  ``precision=HIGHEST`` would take six passes over ``hp x N`` and held the
+  kernel a quarter over its bound (``PERF.md`` section 6, PR 52);
+* ``y = S C`` is the ``q k^T`` form ``C[8, N] . S[hp, N]^T -> [8, hp]``
+  (row 0 live) at ``precision=HIGHEST``: hidden behind the block's DMA.
+
+The decay ``exp(D A)`` is a scalar a (slot, head), read from SMEM and
+splat over the head's ``[P, N]``.
 
 ``ssd_scan_reference`` is the loop over rows in the equations' own
 shapes: the tests' yardstick.
 """
 from __future__ import annotations
 
+import functools
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from .. import resolve_interpret
 
 #: rows to a block of the blocked form: the ``[H, Q, Q]`` decay planes are
 #: ``4 H Q^2`` bytes a block (4.2 MB at 128, 16.8 MB at the published 256)
 #: and ``4 H Q T`` a chunk, so a chunk's bytes fall with ``Q`` while its
 #: products stay deep enough for the matrix unit
 BLOCK_ROWS = 128
+#: heads to a grid step of the decode kernel: a block of the state is
+#: ``heads x P x N`` float32 (1 MB at 32 x 64 x 128), double-buffered in
+#: and out: with the products' temporaries 6-8 MB of on-chip memory, under
+#: the 16 MB a kernel gets unasked.  At 16 the grid's steps show beside
+#: the products (0.437 ms a layer against 0.412 at 32 and at 64, which is
+#: what a kernel that only scales the state takes: ``PERF.md`` section 6,
+#: PR 52)
+DECODE_HEADS = 32
+LANES = 128
+SUBLANES = 8
 
 
 def ssd_chunk_scan(x: jax.Array, dt: jax.Array, b: jax.Array, c: jax.Array,
@@ -118,26 +153,115 @@ def ssd_chunk_scan(x: jax.Array, dt: jax.Array, b: jax.Array, c: jax.Array,
     return y.reshape(t, h, p), state
 
 
+def _bf16_pieces(v: jax.Array):
+    """Three float32 arrays, each a bfloat16's value, that add up to
+    ``v`` to 2^-24 of it."""
+    bf, f32 = jnp.bfloat16, jnp.float32
+    hi = v.astype(bf).astype(f32)
+    rest = v - hi
+    mid = rest.astype(bf).astype(f32)
+    return hi, mid, (rest - mid).astype(bf).astype(f32)
+
+
+def _decode_kernel(first_ref, keep_ref, dx_ref, skip_ref, b_ref, c_ref,
+                   s_ref, new_ref, y_ref, *, heads: int, p: int):
+    """One block of ``heads`` heads of one slot.  ``keep_ref`` (SMEM)
+    holds every (slot, head)'s decay, flat; ``dx_ref`` (``D x``) /
+    ``skip_ref`` (``D_skip x``) / ``y_ref`` ``[rows, heads * p]`` and
+    ``b_ref`` / ``c_ref`` ``[rows, N]`` hold the rows of the slot's group
+    of ``rows`` slots (resident while the grid walks the group); ``s_ref``
+    / ``new_ref`` ``[heads, p, N]``, the same rows of the same buffer."""
+    del first_ref                      # the index maps' alone
+    f32, bf = jnp.float32, jnp.bfloat16
+    n = b_ref.shape[-1]
+    m = heads * p
+    blk, slot = pl.program_id(0), pl.program_id(1)
+    head0 = (slot * pl.num_programs(0) + blk) * heads
+    row = pl.ds(slot % dx_ref.shape[0], 1)
+    # row 3 i + j of the contraction: piece i of D x, piece j of B
+    d, b = _bf16_pieces(dx_ref[row, :]), _bf16_pieces(b_ref[row, :])
+    km = jax.lax.broadcasted_iota(jnp.int32, (16, m), 0)
+    kn = jax.lax.broadcasted_iota(jnp.int32, (16, n), 0)
+    left = jnp.where(km < 3, d[0], jnp.where(
+        km < 6, d[1], jnp.where(km < 9, d[2], 0.0)))
+    right = jnp.where(kn >= 9, 0.0, jnp.where(
+        kn % 3 == 0, b[0], jnp.where(kn % 3 == 1, b[1], b[2])))
+    added = jax.lax.dot_general(
+        left.astype(bf), right.astype(bf), (((0,), (0,)), ((), ())),
+        preferred_element_type=f32)                          # [m, N]
+    for h in range(heads):
+        new_ref[h] = (keep_ref[head0 + h] * s_ref[h]
+                      + added[h * p:(h + 1) * p])
+    c8 = jnp.where(kn[:SUBLANES] == 0, c_ref[row, :], 0.0)
+    y8 = jax.lax.dot_general(
+        c8, new_ref[...].reshape(m, n), (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=f32)                          # [8, m]
+    y_ref[row, :] = y8[0:1] + skip_ref[row, :]
+
+
 def ssd_decode_update(x: jax.Array, dt: jax.Array, b: jax.Array,
                       c: jax.Array, a: jax.Array, d_skip: jax.Array,
-                      state: jax.Array, active: Optional[jax.Array] = None
+                      state: jax.Array, active: Optional[jax.Array] = None,
+                      first=0, interpret: Optional[bool] = None
                       ) -> Tuple[jax.Array, jax.Array]:
     """One row a slot: ``x [S, H, P]``, ``dt [S, H]``, ``b``, ``c [S,
-    N]``, ``state [S, H, P, N]`` float32 (``N`` on the lanes); ``active
-    [S]`` bool: a slot that is not gets step 0 and keeps its state.
-    Elementwise XLA: ``(y [S, H, P] float32, the new states)``."""
+    N]`` (any float type; computed in float32); ``state [rows >= S, H, P,
+    N]`` float32 (``N`` on the lanes): the ``S`` slots' states are its
+    rows ``first .. first + S`` (``first`` an int32 scalar, traced or
+    not) and no other row is read or written; ``active [S]`` bool: a slot
+    that is not gets step 0 and keeps its state.  Returns ``(y [S, H, P]
+    float32, state with those rows updated)`` — the kernel's result IS
+    its operand's buffer (``input_output_aliases``): donate it."""
+    s, h, p = x.shape
+    n = b.shape[1]
+    if (state.ndim != 4 or state.shape[1:] != (h, p, n)
+            or state.shape[0] < s):
+        raise ValueError(f"ssd_decode_update: state must be [rows >= {s}, "
+                         f"{h}, {p}, {n}], got {state.shape}")
+    heads = max(k for k in range(1, min(h, DECODE_HEADS) + 1) if h % k == 0)
+    interpret = resolve_interpret(interpret)
+    if not interpret and (n % LANES or p % SUBLANES
+                          or (heads * p) % LANES):
+        raise ValueError(
+            f"ssd_decode_update: compiled for the TPU, the state ({n}) must "
+            f"be whole {LANES}-lane tiles and a block of {heads} heads of "
+            f"{p} whole {LANES}-lane rows of y")
     f32 = jnp.float32
     dt = dt.astype(f32)
     if active is not None:
         dt = jnp.where(active[:, None], dt, 0.0)
     x = x.astype(f32)
     keep = jnp.exp(dt * a.astype(f32))                       # [S, H]
-    new = (keep[:, :, None, None] * state
-           + (dt[:, :, None] * x)[..., None]
-           * b.astype(f32)[:, None, None, :])
-    y = (jnp.sum(new * c.astype(f32)[:, None, None, :], axis=-1)
-         + d_skip.astype(f32)[:, None] * x)
-    return y, new
+    dx = (dt[:, :, None] * x).reshape(s, h * p)
+    skip = (d_skip.astype(f32)[:, None] * x).reshape(s, h * p)
+    # the slots' rows arrive and leave eight slots a block, as they lie in
+    # (8, 128) tiles: the grid walks a group's slots before the next head
+    # block, so a block of rows is fetched (and y's written) once a group
+    rows = SUBLANES if s % SUBLANES == 0 else s
+    hp_spec = pl.BlockSpec((rows, heads * p),
+                           lambda j, i, first: (i // rows, j))
+    n_spec = pl.BlockSpec((rows, n), lambda j, i, first: (i // rows, 0))
+    state_spec = pl.BlockSpec(
+        (None, heads, p, n), lambda j, i, first: (first[0] + i, j, 0, 0))
+    new, y = pl.pallas_call(
+        functools.partial(_decode_kernel, heads=heads, p=p),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(h // heads, s),
+            in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM), hp_spec,
+                      hp_spec, n_spec, n_spec, state_spec],
+            out_specs=[state_spec, hp_spec]),
+        out_shape=[jax.ShapeDtypeStruct(state.shape, f32),
+                   jax.ShapeDtypeStruct((s, h * p), f32)],
+        # operands count from the scalar prefetch: 6 is the state
+        input_output_aliases={6: 0},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
+        interpret=interpret,
+        name="ssd_decode_update",
+    )(jnp.asarray(first, jnp.int32).reshape(1), keep.reshape(-1), dx, skip,
+      b.astype(f32), c.astype(f32), state)
+    return y.reshape(s, h, p), new
 
 
 def ssd_scan_reference(x, dt, b, c, a, d_skip, state, valid_rows=None):

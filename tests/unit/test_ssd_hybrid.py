@@ -165,6 +165,75 @@ def test_the_decode_update_is_one_step_of_the_loop():
             assert bool(jnp.all(new[i] == states[i]))
 
 
+def decode_case(dtype, layers=3, slots=6, seed=7):
+    """Rows for ``slots`` slots (``x``, ``B``, ``C`` in ``dtype``), every
+    third slot idle, and a buffer of ``layers`` layers' states."""
+    x, dt, b, c, a, d, s = scan_inputs(slots, seed=seed)
+    buf = s[None] * jnp.linspace(-1.5, 2.0, layers * slots)[:, None, None,
+                                                            None]
+    return ((x.astype(dtype), dt, b.astype(dtype), c.astype(dtype), a, d),
+            buf, jnp.arange(slots) % 3 != 1)
+
+
+def assert_decode_matches_the_loop(rows, buf, active, first, y, new):
+    """``new`` is ``buf`` with the rows ``first ..`` one step of the loop
+    on (to 1e-5 of the largest), an idle slot's and every other layer's
+    rows bit for bit; ``y`` the loop's for the live slots."""
+    x, dt, b, c, a, d = rows
+    slots = x.shape[0]
+    want_y, want_s = jax.vmap(lambda *r: ssd_scan.ssd_scan_reference(
+        *(t[None] for t in r[:4]), a, d, r[4]))(
+            x, dt, b, c, buf[first:first + slots])
+    live = np.asarray(active)
+    got = np.asarray(new[first:first + slots])
+    assert np.abs(got - want_s)[live].max() < 1e-5 * np.abs(want_s).max()
+    assert np.abs(np.asarray(y) - want_y[:, 0])[live].max() \
+        < 1e-5 * np.abs(want_y).max()
+    assert (got[~live] == np.asarray(buf[first:first + slots])[~live]).all()
+    others = np.ones(buf.shape[0], bool)
+    others[first:first + slots] = False
+    assert (np.asarray(new)[others] == np.asarray(buf)[others]).all()
+    assert float(np.abs(got[live] - np.asarray(
+        buf[first:first + slots])[live]).max()) > 1e-2     # and it moved
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("first", [0, 6, 12])
+def test_the_decode_kernel_updates_one_layers_rows_where_they_lie(dtype,
+                                                                   first):
+    """The kernel is handed three layers' states and one layer's first
+    row: that layer's live slots move one step of the loop, everything
+    else comes back bit for bit."""
+    rows, buf, active = decode_case(dtype)
+    y, new = ssd_scan.ssd_decode_update(*rows, buf, active, first=first)
+    assert new.shape == buf.shape and y.dtype == new.dtype == jnp.float32
+    assert_decode_matches_the_loop(rows, buf, active, first, y, new)
+
+
+@pytest.mark.parametrize("heads,slots", [(1, 6), (2, 16)])
+def test_the_decode_kernel_under_jit_with_a_traced_first_row(monkeypatch,
+                                                             heads, slots):
+    """As the serving step calls it: the first row a traced scalar, the
+    buffer donated, a slot's heads in several blocks; at 16 slots the
+    rows arrive in two groups of eight slots."""
+    monkeypatch.setattr(ssd_scan, "DECODE_HEADS", heads)
+    rows, buf, active = decode_case(jnp.float32, slots=slots, seed=11)
+    step = jax.jit(lambda buf, layer: ssd_scan.ssd_decode_update(
+        *rows, buf, active, first=layer * slots), donate_argnums=0)
+    y, new = step(buf + 0.0, jnp.int32(1))
+    assert_decode_matches_the_loop(rows, buf, active, slots, y, new)
+
+
+def test_the_decode_kernel_refuses_what_it_cannot_tile():
+    rows, buf, active = decode_case(jnp.float32)
+    with pytest.raises(ValueError, match="state must be"):
+        ssd_scan.ssd_decode_update(*rows, buf[:4], active)
+    with pytest.raises(ValueError, match="state must be"):
+        ssd_scan.ssd_decode_update(*rows, buf[:, :2], active)
+    with pytest.raises(ValueError, match="128-lane"):
+        ssd_scan.ssd_decode_update(*rows, buf, active, interpret=False)
+
+
 # -- the block ---------------------------------------------------------------
 def test_the_config_builds_its_own_model_class_and_counts_its_parameters(
         built):

@@ -984,52 +984,159 @@ def unfused_instructions(text):
                 yield m.group(1), m.group(2), m.group(3), ln.strip()
 
 
-@pytest.mark.parametrize("shape", list(HYBRID_CHUNK))
-def test_ssd_hybrid_step_updates_the_state_where_it_lies(
-        v5e_devices, compiled_kernels, step_programs, shape):
-    """The Mamba-2 hybrid block's step at its cell's widths: a layer's 64
-    slots' states are 134 MB of the donated ``[layers x 64, 64, 64, 128]``
-    float32 buffer, and the decode lane's update reads them there and
-    writes them back there — ONE fusion whose results are the rows'
-    outputs and the whole buffer (``dynamic_slice`` -> update ->
-    ``dynamic_update_slice``, the buffer aliased through both scans).  So
-    outside a fusion the step holds nothing shaped like a layer's states,
-    no copy and no slice of the buffer or of a pool, and its temporaries
-    are less than one layer's states (decode only: a fiftieth).  The
-    decode-only shape calls the paged kernel once (the scanned period's
-    attention layer), the mixed shape once more for the chunk.  (This is
-    the block's step alone; on the chip the ENGINE's mixed program, the
-    sampler around it, rematerialises the rows' reduction as a fusion of
-    its own, one more read of a layer's states: ``PERF.md`` section 5.)"""
+def test_ssd_decode_update_compiles_at_the_cells_widths(v5e_devices,
+                                                         compiled_kernels):
+    """The Mamba-2 decode kernel over ``granite-4.0-h-micro``'s whole state
+    buffer (36 layers x 64 slots of 64 heads x 64 x 128 float32, 4.83 GB)
+    at a traced first row: Mosaic takes its two products (a transposed-left
+    bfloat16 one over a contraction of 16, a ``q k^T`` one at the highest
+    precision), the buffer is the call's operand AND its result, and the
+    program around it holds no temporary at all."""
     import re
-    chunk = HYBRID_CHUNK[shape]
-    model = ssd_hybrid_model()
-    _, pools, _ = ssd_hybrid_mixed_operands(v5e_devices, model, chunk)
-    text, temp_bytes = step_programs(f"ssd-hybrid-{shape}")
-    assert custom_calls(text) == (2 if chunk else 1)
+    from deepspeed_tpu.ops.transformer.ssd_scan import ssd_decode_update
+    sds = one_chip(v5e_devices)
+    slots, h, p, n = SSD_HYBRID_SIZE[0], 64, 64, 128
+    f32 = jnp.float32
+    compiled = jax.jit(ssd_decode_update, donate_argnums=6).trace(
+        sds((slots, h, p), f32), sds((slots, h), f32), sds((slots, n), f32),
+        sds((slots, n), f32), sds((h,), f32), sds((h,), f32),
+        sds((36 * slots, h, p, n), f32), sds((slots,), jnp.bool_),
+        sds((), jnp.int32)).lower(lowering_platforms=("tpu",)).compile()
+    text = compiled.as_text()
+    assert custom_calls(text) == 1
+    call, = [ln for ln in text.splitlines()
+             if re.search(r" custom-call\(.*\"tpu_custom_call\"", ln)]
+    assert re.match(r"\s*%ssd_decode_update[.\d]* = \(f32\[2304,64,64,128\]",
+                    call)
+    assert "output_to_operand_aliasing={{0}: (6, {})}" in call
+    # the program's second result (y is its first) is its seventh argument
+    assert re.search(r"input_output_alias=\{ \{1\}: \(6, \{\}, may-alias\) \}",
+                     text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+def assert_ssd_state_updated_in_place(text, pools, chunk):
+    """What both programs below must show of the Mamba-2 decode update:
+    ONE Mosaic call a scanned layer body whose operand and result are the
+    whole state buffer, aliased, beside the paged kernel's one call (and
+    one more for a chunk); no fusion that reads the buffer, or a layer's
+    states, to give the rows' ``f32[64, 64, 64]`` outputs (the second
+    pass over a layer's states that the engine's mixed program paid
+    before PR 52); outside a fusion nothing shaped like a layer's states,
+    and no copy, slice or allocation shaped like the buffer or a pool."""
+    import re
+    kernels = [ln for ln in text.splitlines()
+               if re.search(r' custom-call\(.*"tpu_custom_call"', ln)]
+    updates = [ln for ln in kernels if "%ssd_decode_update" in ln]
+    assert len(updates) == 1     # the scanned run's one layer body
+    assert len(kernels) - 1 == (2 if chunk else 1)
     slots = SSD_HYBRID_SIZE[0]
     layer_states = (slots,) + pools["ssm"].shape[1:]
     dims = lambda shape: ",".join(map(str, shape))        # noqa: E731
-    whole = {dims(pools["ssm"].shape)}
+    buffer = dims(pools["ssm"].shape)
+    result, operands = re.match(
+        r"\s*%\S+ = \((.*?)\) custom-call\((.*?)\), custom_call_target",
+        updates[0]).groups()
+    assert f"f32[{buffer}]" in result
+    assert "output_to_operand_aliasing={{0}: (6, {})}" in updates[0]
+    assert len(operands.split(", ")) == 7
+    comps, _ = hlo_computations(text)
+    whole = {buffer}
     for name in ("k", "v"):
         a = pools[name]
         whole |= {dims(a.shape), dims(a.shape[1:]),
                   dims((a.shape[0] * a.shape[1],) + a.shape[2:])}
-    moved, updates = [], 0
+    moved, second_reads = [], []
     for name, result, op, ln in unfused_instructions(text):
         shapes = set(re.findall(r"\w+\[([\d,]+)\]", result))
         if dims(layer_states) in shapes or (
                 shapes & whole and (op in ("copy", "dynamic-slice")
                                     or "AllocateBuffer" in ln)):
             moved.append(ln[:160])
-        if op == "fusion" and {dims(layer_states[:-1]),
-                               dims(pools["ssm"].shape)} <= shapes:
-            updates += 1
+        called = re.search(r" fusion\(.*?, calls=%([^\s,]+)", ln)
+        if called and dims(layer_states[:-1]) in shapes and any(
+                re.search(rf"f32\[(?:{buffer}|{dims(layer_states)})\]"
+                          r"\S* parameter\(", body_line)
+                for body_line in comps[called.group(1)]):
+            second_reads.append(ln[:160])
     assert not moved, moved
-    assert updates == 1          # the scanned run's one layer body
+    assert not second_reads, second_reads
     assert re.search(r"input_output_alias=\{[^\n]*may-alias", text)
-    one_layer = int(np.prod(layer_states)) * 4
+    return int(np.prod(layer_states)) * 4
+
+
+@pytest.mark.parametrize("shape", list(HYBRID_CHUNK))
+def test_ssd_hybrid_step_updates_the_state_where_it_lies(
+        v5e_devices, compiled_kernels, step_programs, shape):
+    """The Mamba-2 hybrid block's step at its cell's widths: a layer's 64
+    slots' states are 134 MB of the donated ``[layers x 64, 64, 64, 128]``
+    float32 buffer, and the decode lane's update is a kernel that walks
+    the layer's rows of the whole buffer from a prefetched first row
+    (:func:`assert_ssd_state_updated_in_place`; the buffer is aliased
+    through both scans as well).  The step's temporaries are less than
+    one layer's states (decode only: a fiftieth)."""
+    chunk = HYBRID_CHUNK[shape]
+    model = ssd_hybrid_model()
+    _, pools, _ = ssd_hybrid_mixed_operands(v5e_devices, model, chunk)
+    text, temp_bytes = step_programs(f"ssd-hybrid-{shape}")
+    one_layer = assert_ssd_state_updated_in_place(text, pools, chunk)
     assert temp_bytes < (one_layer if chunk else one_layer // 50)
+
+
+def build_ssd_hybrid_engine_step(devices, chunk):
+    """The ENGINE's step for the Mamba-2 hybrid block — the block's step,
+    the sampler behind it, ``shard_map`` over the 1 x 1 serving submesh,
+    the pools and the state donated — compiled for one v5e chip:
+    ``ServingEngine._build_step`` over a stand-in that holds what that
+    method reads of a live engine (a live one places 2 GB of weights on
+    real devices; the method's body needs shapes alone)."""
+    from types import SimpleNamespace
+    from deepspeed_tpu.inference.serving import engine as serving
+    from deepspeed_tpu.parallel import topology as topo
+    model = ssd_hybrid_model()
+    slots, pages, _ = SSD_HYBRID_SIZE
+    args, pools, params = ssd_hybrid_mixed_operands(devices, model, chunk)
+    cache = args[1]
+    mesh = build_mesh(MeshConfig(data=1, model=1), devices=devices[:1])
+    pool_spec = P(None, None, None, topo.MODEL_AXIS)
+    specs = model.partition_specs(params)
+    stand_in = SimpleNamespace(
+        engine=SimpleNamespace(_model_params=lambda params, scales: params),
+        _tp_model=model, _tp_draft=None, _draft_model=None, spec_k=0,
+        decode_builds=0, tp_data_size=1, kv_bits=0, _donate=True,
+        _pool_v=cache["v"], _pool_x=cache["extra"], _pool_spec=pool_spec,
+        _pscale_spec=P(), _tp_scales=None, _tp_scale_specs=None,
+        _tp_param_specs=specs, tp_mesh=mesh)
+    step = serving.ServingEngine._build_step(stand_in)
+
+    def placed(a, spec=P()):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                    sharding=NamedSharding(mesh, spec))
+
+    def ints(*shape):
+        return placed(jax.ShapeDtypeStruct(shape, jnp.int32))
+    operands = (
+        jax.tree_util.tree_map(placed, params, specs), None,
+        placed(cache["k"], pool_spec), placed(cache["v"], pool_spec), None,
+        None, jax.tree_util.tree_map(placed, cache["extra"]),
+        ints(slots, serving._R_SPEC),
+        ints(slots, serving._SLOT_COLS + pages * len(model.TABLE_KINDS)),
+        ints(serving._CHUNK_HEAD + chunk))
+    return step.trace(*operands).lower(
+        lowering_platforms=("tpu",)).compile(), pools
+
+
+def test_the_engines_mixed_program_keeps_the_ssd_update_one_pass(
+        v5e_devices, compiled_kernels):
+    """The program that split the update before PR 52 was not the block's
+    step but the ENGINE's mixed one, the sampler behind ``y``'s consumers
+    (the chip's trace showed it, ``PERF.md`` section 5): the same holds
+    there."""
+    chunk = HYBRID_CHUNK["mixed"]
+    compiled, pools = build_ssd_hybrid_engine_step(v5e_devices, chunk)
+    one_layer = assert_ssd_state_updated_in_place(compiled.as_text(), pools,
+                                                  chunk)
+    assert compiled.memory_analysis().temp_size_in_bytes < one_layer
 
 
 def test_train_grad_compiles_on_four_chips(v5e_devices, compiled_kernels):
@@ -1588,7 +1695,8 @@ def test_step_programs_carry_their_scopes(compiled_kernels, step_programs,
     into another's, as it does the residual adds, is in no instruction
     of its own); each Pallas call resolves to ``attn_kernel``,
     ``experts``, (the score kernel of a sparse selection) ``indexer`` or
-    (a state-space layer's scan) ``ssm_scan``;
+    (a state-space layer's scan, a Mamba-2 layer's decode update)
+    ``ssm_scan``;
     and at least nine in ten of the instructions that can
     be trace events and do work (fusions, convolutions, copies, custom
     calls) resolve to a declared scope."""
@@ -1607,7 +1715,8 @@ def test_step_programs_carry_their_scopes(compiled_kernels, step_programs,
     for ln in kernels:
         want = ("experts" if "%moe_grouped_matmul" in ln else
                 "indexer" if "%dsa_index_scores" in ln else
-                "ssm_scan" if "%ssm_chunk_scan" in ln else "attn_kernel")
+                "ssm_scan" if re.search(r"%ss[md]_(chunk_scan|decode_update)",
+                                        ln) else "attn_kernel")
         assert table[scope_key(ln)][0] == want, ln[:200]
     work = [k for k in table
             if re.match(r"%[\w.-]*(fusion|convolution|copy|custom-call)"
