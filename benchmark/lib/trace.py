@@ -5,6 +5,7 @@ of idle gaps to what the host was doing.  Read with nothing but JAX's
 from __future__ import annotations
 
 import glob
+import math
 import os
 import re
 from collections import defaultdict
@@ -129,26 +130,59 @@ def total(intervals) -> float:
     return float(sum(e - s for s, e in intervals))
 
 
-def reduce(path: str, span_names) -> dict:
+def innermost(spans) -> list:
+    """``spans`` cut into disjoint, sorted ``(start, end, name)``
+    pieces, each instant under the span that covers it and began last: a
+    phase inside the engine's iteration inside the client's ``serve_step``
+    is the phase's, and what the iteration leaves of ``serve_step`` (the
+    client inside its span, outside the engine) is ``serve_step``'s."""
+    out, stack, cur = [], [], 0.0
+    for name, start, end in sorted(spans, key=lambda s: (s[1], -s[2])) + [
+            ("", math.inf, math.inf)]:
+        while stack and stack[-1][2] <= start:      # the top ends first
+            top = stack.pop()
+            if top[2] > cur:
+                out.append((cur, top[2], top[0]))
+                cur = top[2]
+        if stack and start > cur:
+            out.append((cur, start, stack[-1][0]))
+        cur = start
+        stack.append((name, start, end))
+    return out
+
+
+def reduce(path: str, span_names, window=None) -> dict:
     """All the numbers a traced run reports.  The traced window runs from
-    the start of the client's first span in the trace to the end of its
-    last; device events are clipped to it.  Seconds are averaged over the
-    devices."""
-    return reduce_events(read(path, set(span_names)))
+    the start of the first span named in ``window`` (all of ``span_names``
+    unless given: a serving runner names the client's own, since a trace
+    that opens inside a ``step()`` holds that step's later phases and not
+    its ``serve_step``) to the end of the last; device events are clipped
+    to it.  Seconds are averaged over the devices."""
+    return reduce_events(read(path, set(span_names)), window)
 
 
-def reduce_events(raw: dict) -> dict:
+def reduce_events(raw: dict, window=None) -> dict:
     """``reduce`` on events already read: ``{"devices": {name: [(name,
-    start_ns, duration_ns)]}, "spans": [(name, start_ns, end_ns)]}``."""
-    if not raw["spans"] or not raw["devices"]:
+    start_ns, duration_ns)]}, "spans": [(name, start_ns, end_ns)]}``.  Each
+    part of an idle gap is booked once, to the innermost span that covers
+    it (``none``: to no span); a whole gap counts, for ``idle_gap_longest``
+    (``(seconds, seconds into the window at which it opens)``) and
+    ``idle_gaps_over_1ms``, under the name that holds most of it."""
+    bounds = [s for s in raw["spans"] if window is None or s[0] in window]
+    if not bounds or not raw["devices"]:
         return {}
-    lo = raw["spans"][0][1]
-    hi = max(s[2] for s in raw["spans"])
+    lo = min(s[1] for s in bounds)
+    hi = max(s[2] for s in bounds)
+    pieces = [(max(s, lo), min(e, hi), name)
+              for s, e, name in innermost(raw["spans"])
+              if min(e, hi) > max(s, lo)]
     ndev = len(raw["devices"])
     own_by_name = defaultdict(float)
     calls_by_name = defaultdict(int)
     busy_s = exposed_s = 0.0
     gaps_by_span = defaultdict(float)
+    longest = defaultdict(tuple)
+    over_1ms = defaultdict(int)
     for events in raw["devices"].values():
         events, own, leaf = self_times(
             [e for e in events if e[1] + e[2] > lo and e[1] < hi])
@@ -164,14 +198,22 @@ def reduce_events(raw: dict) -> dict:
                            if lf and not COLLECTIVE.search(op_key(n))],
                           lo, hi))
         exposed_s += total(subtract(coll, comp))
+        j = 0
         for gs, ge in subtract([(lo, hi)], busy):
-            left = ge - gs
-            for name, ss, se in raw["spans"]:
-                part = min(ge, se) - max(gs, ss)
-                if part > 0:
-                    gaps_by_span[name] += part
-                    left -= part
-            gaps_by_span["none"] += max(left, 0.0)
+            parts = defaultdict(float)
+            while j < len(pieces) and pieces[j][1] <= gs:
+                j += 1
+            k = j
+            while k < len(pieces) and pieces[k][0] < ge:
+                ps, pe, name = pieces[k]
+                parts[name] += min(ge, pe) - max(gs, ps)
+                k += 1
+            parts["none"] = max(ge - gs - sum(parts.values()), 0.0)
+            for name, part in parts.items():
+                gaps_by_span[name] += part
+            name = max(parts, key=parts.get)
+            longest[name] = max(longest[name], (ge - gs, gs - lo))
+            over_1ms[name] += ge - gs > 1e6
     ns = 1e-9 / ndev
     return {
         "window_s": (hi - lo) * 1e-9,
@@ -180,6 +222,10 @@ def reduce_events(raw: dict) -> dict:
         "op_s": {k: v * ns for k, v in own_by_name.items()},
         "op_calls": {k: v / ndev for k, v in calls_by_name.items()},
         "idle_gap_s": {k: v * ns for k, v in gaps_by_span.items()},
+        "idle_gap_longest": {k: (v[0] * 1e-9, v[1] * 1e-9)
+                             for k, v in longest.items()},
+        "idle_gaps_over_1ms": {k: v / ndev for k, v in over_1ms.items()
+                               if v},
         "devices": ndev,
     }
 

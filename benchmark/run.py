@@ -51,12 +51,15 @@ class Context:
         jax.profiler.start_trace(self.trace_dir, profiler_options=options)
         self.trace_started_at = time.perf_counter()
 
-    def stop_trace(self, span_names) -> dict:
+    def stop_trace(self, span_names, window=None) -> dict:
         """Stop, reduce the trace to numbers, and keep nothing on disk
-        (``keep_trace`` is for recording the tests' small trace)."""
+        (``keep_trace`` is for recording the tests' small trace).  The
+        spans named in ``window`` (all of them unless given) set the traced
+        window."""
         import jax
         jax.profiler.stop_trace()
-        red = trace.reduce(trace.newest_xplane(self.trace_dir), span_names)
+        red = trace.reduce(trace.newest_xplane(self.trace_dir), span_names,
+                           window)
         if not self.keep_trace:
             shutil.rmtree(self.trace_dir, ignore_errors=True)
         return red
@@ -74,7 +77,7 @@ def metrics_of(bench: dict, group: str, cell: str) -> list:
             if "workloads" not in m or cell in m["workloads"]]
 
 
-def declaration(name: str) -> dict:
+def declaration_path(name: str) -> str:
     """A metric's declaration: ``benchmark/metrics/<name>.json``, or, for a
     name that carries a cell prefix (``sat.``, ``paced.``, ``train.``: cells
     that report different end-to-end metrics need different names for one
@@ -82,9 +85,13 @@ def declaration(name: str) -> dict:
     for stem in (name, name.split(".", 1)[-1]):
         path = os.path.join(HERE, "metrics", stem + ".json")
         if os.path.isfile(path):
-            with open(path) as f:
-                return json.load(f)
+            return path
     raise FileNotFoundError(f"no declaration for metric {name!r}")
+
+
+def declaration(name: str) -> dict:
+    with open(declaration_path(name)) as f:
+        return json.load(f)
 
 
 def evaluate(metric: dict, obs: dict):
@@ -127,11 +134,16 @@ def run_cell(bench: dict, workload: str, seed: int, seconds: float,
             metrics[m["name"]] = got
     line = {"correct": obs["correct"], "attempted": obs["attempted"],
             "failed": obs["failed"], "metrics": metrics}
-    if trace_on and obs["trace"]:
-        line["breakdown"] = trace.breakdown(obs["trace"])
     line["diag"] = dict(obs["diag"], setup_s=obs["values"]["setup_s"],
                         memory=memory, cache_hits=compile_log.cache_hits,
                         compiles=compile_log.compiles)
+    if trace_on and obs["trace"]:
+        line["breakdown"] = trace.breakdown(obs["trace"])
+        # the sum cannot say whether it is one stall or a hundred, nor
+        # whether it is the traced window's first iteration
+        line["diag"]["idle_gaps"] = {
+            "longest_s_at_s": obs["trace"]["idle_gap_longest"],
+            "over_1ms": obs["trace"]["idle_gaps_over_1ms"]}
     return line, obs
 
 

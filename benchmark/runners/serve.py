@@ -12,6 +12,8 @@ import time
 
 import numpy as np
 
+from deepspeed_tpu.observability.overlap import ITERATION_SPAN, PHASE_SPANS
+
 from ..lib import (costs, device, model as model_lib, reference, stats,
                    traffic)
 
@@ -25,7 +27,18 @@ from ..lib import (costs, device, model as model_lib, reference, stats,
 #: logits by tenths.
 LOGIT_GAP_ATOL = 0.05
 CHECK_REQUESTS = ((333, 24), (200, 24))      # (prompt, new) tokens
-SPANS = ("serve_step", "plan_submit")
+#: the client's own spans, which set the traced window, and every span an
+#: idle gap can be booked to: the client's, and inside ``serve_step`` the
+#: engine's iteration and its five phases
+CLIENT_SPANS = ("serve_step", "plan_submit")
+SPANS = CLIENT_SPANS + (ITERATION_SPAN,) + PHASE_SPANS
+
+
+def stop_trace(ctx) -> dict:
+    """The traced window's numbers, its bounds set by the client's spans."""
+    return ctx.stop_trace(SPANS, CLIENT_SPANS)
+
+
 CAP_IT = 1 << 16
 CAP_GAPS = 1 << 21
 clock = time.perf_counter
@@ -169,10 +182,6 @@ class Client:
         self.it_blocks[k] = srv.allocator.num_used
         self.it_flops[k] = flops * self.layers
         self.it_bytes[k] = nbytes * self.layers
-        if self.trace_on:
-            rec = self.overlap.last()
-            self.it_plan[k], self.it_total[k] = rec["host_plan_s"], \
-                rec["total_s"]
         self.n_it += 1
         return te, finished
 
@@ -284,7 +293,7 @@ def run(ctx) -> dict:
         w0, w1, setup_s, tracing = _open_loop(ctx, client, shrink)
     else:
         w0, w1, setup_s, tracing = _closed_loop(ctx, client, slots)
-    red = ctx.stop_trace(SPANS) if tracing else {}
+    red = stop_trace(ctx) if tracing else {}
     compiles_in_window = ctx.compile_log.compiles - compiles_before
 
     c = client
@@ -346,9 +355,6 @@ def run(ctx) -> dict:
         "ttft_mean_ms": stats.finite_ms(float(ttft.mean())) if ttft.size
         else math.nan,
     }
-    if ctx.trace and c.it_total[its][in_w].sum() > 0:
-        values["host_plan_share"] = float(
-            100.0 * c.it_plan[its][in_w].sum() / c.it_total[its][in_w].sum())
     stamps = {"it_start": it_start - w0, "it_end": it_end - w0,
               "it_tokens": c.it_tokens[its], "it_running": c.it_running[its],
               "it_queue": queue, "it_blocks": c.it_blocks[its],

@@ -17,7 +17,7 @@ import numpy as np
 
 from ..lib import (costs, costs_hybrid, device, model as model_lib,
                    reference_phi4_flash as reference, stats, trace, traffic)
-from .serve import SPANS, Client, _profile, clock
+from .serve import Client, _profile, clock, stop_trace
 from .serve_sparse_latent import _closed_loop
 
 #: (a) every token the engine chose greedily, through chunked prefill and
@@ -441,7 +441,7 @@ def run(ctx) -> dict:
     gc.collect()
     gc.callbacks.append(on_gc)
     w0, w1, setup_s, tracing = _closed_loop(ctx, client, slots)
-    red = ctx.stop_trace(SPANS) if tracing else {}
+    red = stop_trace(ctx) if tracing else {}
     compiles_in_window = ctx.compile_log.compiles - compiles_before
 
     c = client
@@ -541,9 +541,6 @@ def run(ctx) -> dict:
             100.0 * state_held / (state_held + pages_held)),
         **counted,
     }
-    if ctx.trace and c.it_total[its][in_w].sum() > 0:
-        values["host_plan_share"] = float(
-            100.0 * c.it_plan[its][in_w].sum() / c.it_total[its][in_w].sum())
     stamps = {"it_start": it_start - w0, "it_end": it_end - w0,
               "it_tokens": c.it_tokens[its], "it_running": c.it_running[its],
               "it_queue": queue, "it_blocks": c.it_blocks[its],

@@ -17,8 +17,8 @@ import numpy as np
 from ..lib import (costs, device, model as model_lib,
                    reference_openpangu_ultra_moe as reference, stats,
                    traffic)
-from .serve import (CHECK_REQUESTS, SPANS, _closed_loop, _open_loop,
-                    _profile, clock)
+from .serve import (CHECK_REQUESTS, _closed_loop, _open_loop, _profile,
+                    clock, stop_trace)
 from .serve_latent import LatentClient, _routing_values, serving_weights
 
 #: serving check: each token the engine chose greedily, through chunked
@@ -252,7 +252,7 @@ def run(ctx) -> dict:
         w0, w1, setup_s, tracing = _open_loop(ctx, client, shrink)
     else:
         w0, w1, setup_s, tracing = _closed_loop(ctx, client, slots)
-    red = ctx.stop_trace(SPANS) if tracing else {}
+    red = stop_trace(ctx) if tracing else {}
     compiles_in_window = ctx.compile_log.compiles - compiles_before
 
     c = client
@@ -322,9 +322,6 @@ def run(ctx) -> dict:
         else math.nan,
         **routing,
     }
-    if ctx.trace and c.it_total[its][in_w].sum() > 0:
-        values["host_plan_share"] = float(
-            100.0 * c.it_plan[its][in_w].sum() / c.it_total[its][in_w].sum())
     stamps = {"it_start": it_start - w0, "it_end": it_end - w0,
               "it_tokens": c.it_tokens[its], "it_running": c.it_running[its],
               "it_queue": queue, "it_blocks": c.it_blocks[its],

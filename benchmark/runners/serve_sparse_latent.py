@@ -17,7 +17,7 @@ import numpy as np
 
 from ..lib import (costs, costs_dsa, device, model as model_lib,
                    reference_glm_dsa as reference, stats, traffic)
-from .serve import SPANS, Client, _profile, clock
+from .serve import Client, _profile, clock, stop_trace
 from .serve_latent import _routing_values, serving_weights
 from .serve_latent_sandwich import _expert_layer_values
 
@@ -556,7 +556,7 @@ def run(ctx) -> dict:
     gc.collect()
     gc.callbacks.append(on_gc)
     w0, w1, setup_s, tracing = _closed_loop(ctx, client, slots)
-    red = ctx.stop_trace(SPANS) if tracing else {}
+    red = stop_trace(ctx) if tracing else {}
     compiles_in_window = ctx.compile_log.compiles - compiles_before
 
     c = client
@@ -633,9 +633,6 @@ def run(ctx) -> dict:
     }
     if asked:
         values["prefix_hit_share"] = 100.0 * hit / asked
-    if ctx.trace and c.it_total[its][in_w].sum() > 0:
-        values["host_plan_share"] = float(
-            100.0 * c.it_plan[its][in_w].sum() / c.it_total[its][in_w].sum())
     stamps = {"it_start": it_start - w0, "it_end": it_end - w0,
               "it_tokens": c.it_tokens[its], "it_running": c.it_running[its],
               "it_queue": queue, "it_blocks": c.it_blocks[its],

@@ -15,10 +15,39 @@ sys.path.insert(0, ROOT)
 
 TINY_MODEL = {"num_layers": 2, "d_model": 64, "num_heads": 4, "d_ff": 128,
               "vocab_size": 512, "dtype": "float32"}
+#: the latent blocks at the sizes of ``tests/unit/test_latent_moe.py``,
+#: half of the experts held
+TINY_LATENT = dict(num_heads=4, d_model=64, d_ff=128, head_dim=24,
+                   vocab_size=128, max_seq_len=512, q_lora_rank=32,
+                   kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8,
+                   v_head_dim=16, expert_d_ff=32, n_routed_experts=8,
+                   moe_topk=3, experts_held=[0, 4], dtype="float32")
+MIX = {"rate_rps": 20.0, "trace_seconds": 1.5}
+#: 16 clients on 8 slots of 32-token chunks; documents of 128 tokens
+MIX_LATENT = dict(MIX, clients=16, lead_in_s=1.0, doc_lens=[2048] * 8, engine={
+    "dtype": "float32", "max_out_tokens": 512, "temperature": 0.0,
+    "serving": {"kv_block_size": 8, "prefill_chunk_tokens": 32,
+                "max_batch_slots": 8, "num_kv_blocks": 2048}})
+
+
+def _latent(**sizes):
+    return {"model": dict(TINY_LATENT, **sizes), "num_kv_blocks": 2048,
+            "shrink": 16, "mix": MIX_LATENT}
+
+
+#: by the traffic's ``kind``.  The hybrid cells and the expert block that
+#: trains rehearse in files of their own (``test_serve_hybrid.py``,
+#: ``test_serve_ssd_hybrid.py``, ``test_train_moe.py``)
 TINY = {
     "train": {"model": dict(TINY_MODEL, max_seq_len=128)},
     "serve": {"model": dict(TINY_MODEL, max_seq_len=256, attn_impl="xla"),
               "num_kv_blocks": 512, "shrink": 16},
+    "serve_latent": _latent(num_layers=2, zero_expert_num=4),
+    "serve_latent_sandwich": _latent(num_layers=3, first_k_dense=1),
+    "serve_sparse_latent": _latent(
+        num_layers=5, first_k_dense=1, index_n_heads=4, index_head_dim=16,
+        index_topk=8,
+        indexer_types=["full", "shared", "full", "shared", "shared"]),
 }
 
 
@@ -41,7 +70,7 @@ def main(workload: str, trace_on: str) -> int:
         bench, workload, seed=2**31 + 7, seconds=3.0,
         trace_on=trace_on == "1", peaks=peaks,
         compile_log=device.CompileLog(), tiny=TINY[kind],
-        mix_overrides={"rate_rps": 20.0, "trace_seconds": 1.5})
+        mix_overrides=TINY[kind].get("mix", MIX))
     line["device"] = {"platform": jax.devices()[0].platform,
                       "count": len(jax.devices())}
     print(json.dumps(line))

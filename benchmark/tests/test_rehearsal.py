@@ -1,6 +1,7 @@
-"""Each cell rehearsed on the CPU at a tiny size, through the harness's own
-``run_cell`` (see ``rehearse.py``), and the shape of the line it would
-print.  The four-chip cell runs on 4 virtual CPU devices."""
+"""Each cell that ``rehearse.py`` has sizes for (the others rehearse in
+files of their own, named there) on the CPU at a tiny size, through the
+harness's own ``run_cell``, and the shape of the line it would print.  The
+four-chip cell runs on 4 virtual CPU devices."""
 import json
 import os
 import subprocess
@@ -8,7 +9,10 @@ import sys
 
 import pytest
 
+import rehearse
 from benchmark import run as harness
+from benchmark.lib import traffic
+from benchmark.runners import serve
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 BENCH = harness.load_benchmark()
@@ -26,7 +30,9 @@ def _rehearse(workload, trace_on):
 
 
 @pytest.mark.parametrize("trace_on", (0, 1))
-@pytest.mark.parametrize("workload", [w["name"] for w in BENCH["workloads"]])
+@pytest.mark.parametrize("workload", [
+    w["name"] for w in BENCH["workloads"]
+    if traffic.load(w["traffic"])["kind"] in rehearse.TINY])
 def test_cell_rehearsal_prints_the_contract_line(workload, trace_on):
     line = _rehearse(workload, trace_on)
     cell = next(w for w in BENCH["workloads"] if w["name"] == workload)
@@ -43,8 +49,13 @@ def test_cell_rehearsal_prints_the_contract_line(workload, trace_on):
     if trace_on:
         assert len(line["metrics"]) >= 5
         assert len(line["breakdown"]["device_ops"]) <= 10
-        assert {n for n, _ in line["breakdown"]["idle_gaps"]} <= {
-            "train_step", "serve_step", "plan_submit", "none"}
+        gaps = dict(line["breakdown"]["idle_gaps"])
+        assert set(gaps) <= set(serve.SPANS) | {"train_step", "none"}
+        assert min(gaps.values()) >= 0.0
+        assert set(line["diag"]["idle_gaps"]["longest_s_at_s"]) <= set(gaps)
+        if "serve_step" in gaps:
+            # an idle gap is named by the engine's phase
+            assert set(gaps) & set(serve.SPANS[len(serve.CLIENT_SPANS):])
     else:
         # every end-to-end metric of the cell is there, none of them 0
         assert set(line["metrics"]) == set(declared)

@@ -9,15 +9,20 @@ import pytest
 
 from benchmark import run as harness
 from benchmark.lib import trace
-from benchmark.readers import trace_share
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 SPANS = ("serve_step", "plan_submit")
 
 
-def _pattern(metric, **shapes):
-    return trace_share.fill(harness.declaration(metric)["args"]["pattern"],
-                            shapes)
+#: any compiled Pallas kernel, and what moved pythia-1.4b's pool rows
+#: ([layers, blocks, 16, 2048]) before the step wrote them in place
+PALLAS = r'custom_call_target="tpu_custom_call"'
+POOL_COPY = (r"^%(copy|constant_dynamic-slice_fusion|constant_dynamic-update-"
+             r"slice_fusion)[.\d]* = bf16\[\d+,\d+,16,WIDTH\]")
+
+
+def _pattern(metric):
+    return harness.declaration(metric)["args"]["pattern"]
 
 
 def _reduce_recorded(tmp_path_factory, name, spans):
@@ -47,9 +52,8 @@ def test_four_chips_exposed_collectives(tmp_path_factory):
         red4, r"^%(all-gather|all-to-all|all-reduce|reduce-scatter)") == \
         pytest.approx(red4["exposed_collective_s"], rel=1e-3)
     # 24 layers x (forward, recomputed forward, two backward passes)
-    flash = _pattern("train.flash_time_share")
-    assert trace.matching(red4, flash, "op_calls") == 96
-    assert trace.matching(red4, flash) == pytest.approx(0.20507, abs=1e-4)
+    assert trace.matching(red4, PALLAS, "op_calls") == 96
+    assert trace.matching(red4, PALLAS) == pytest.approx(0.20507, abs=1e-4)
 
 
 def test_busy_union_and_window(red):
@@ -68,18 +72,14 @@ def test_kernel_time_by_pattern(red):
     assert trace.matching(red, paged, "op_calls") == 288
     assert trace.matching(red, paged) == pytest.approx(0.270694, abs=1e-5)
     # 6 x (24 layers x 4 slices of the pool + 2 whole-pool copies)
-    copy = _pattern("sat.pool_copy_share", kv_block_size=16,
-                    kv_row_width=2048)          # pythia-1.4b's pool rows
+    copy = POOL_COPY.replace("WIDTH", "2048")
     assert trace.matching(red, copy, "op_calls") == 588
     assert 0.2 < trace.matching(red, copy) < 0.3
-    # another pool shape matches nothing here; a cell without the sizes
-    # does not report the metric at all
-    assert trace.matching(red, _pattern(
-        "sat.pool_copy_share", kv_block_size=16, kv_row_width=1024)) == 0.0
-    assert _pattern("sat.pool_copy_share") is None
+    # a pattern holds the result's shape: another pool matches nothing
+    assert trace.matching(red, POOL_COPY.replace("WIDTH", "1024")) == 0.0
     # the one Pallas kernel of the serving step is the paged kernel
-    assert trace.matching(red, _pattern("train.flash_time_share")) == \
-        pytest.approx(trace.matching(red, paged))
+    assert trace.matching(red, PALLAS) == pytest.approx(
+        trace.matching(red, paged))
 
 
 def test_idle_gaps_are_attributed_to_the_clients_spans(red):
