@@ -18,6 +18,7 @@ __version__ = "0.1.0"
 from . import comm  # noqa: F401
 from .accelerator.tpu_accelerator import get_accelerator  # noqa: F401
 from .comm.comm import init_distributed  # noqa: F401
+from .observability.overlap import get_overlap_profiler
 from .runtime.config import DeepSpeedConfig  # noqa: F401
 from .runtime.engine import DeepSpeedEngine  # noqa: F401
 from .runtime.dataloader import DeepSpeedDataLoader, RepeatingLoader  # noqa: F401
@@ -35,8 +36,15 @@ def enable_compile_cache() -> Optional[str]:
     git-ignored ``<checkout>/.jax_cache`` — the path is part of the
     cache key, so it is never derived from a temp name, pid or time.
     CPU runs (the test suite) compile nothing worth keeping and are
-    left alone."""
+    left alone.
+
+    Being the first thing both entry points call, it also registers —
+    once a process, whatever the backend — the overlap profiler's pair
+    of ``jax.monitoring`` listeners, so that every program traced,
+    lowered, compiled or fetched from here on leaves a build record
+    (``get_overlap_profiler().builds()``, docs/observability.md)."""
     import jax
+    get_overlap_profiler().listen_for_builds()
     env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env:
         return env
@@ -82,32 +90,33 @@ def initialize(args: Any = None,
     if reason is not None:
         raise NotImplementedError(
             f"deepspeed_tpu.initialize cannot train this model: {reason}")
-    if dist_init_required:
-        init_distributed()
-    enable_compile_cache()
+    with get_overlap_profiler().setup_span("setup/initialize"):
+        if dist_init_required:
+            init_distributed()
+        enable_compile_cache()
 
-    # Engine dispatch rides the topology: a mesh whose ``pipe`` axis is
-    # >= 2 — passed in or declared by the config's mesh block (e.g. an
-    # autotuner-exported 3D winner) — trains under the compiled pipeline
-    # schedule; no separate entry point.
-    ds_config = (config if isinstance(config, DeepSpeedConfig)
-                 else DeepSpeedConfig(config or {}))
-    if mesh is None:
-        mesh = build_mesh(ds_config.mesh)
-    from .parallel.topology import pp_world_size
-    engine_cls = DeepSpeedEngine
-    if pp_world_size(mesh) >= 2:
-        from .runtime.pipe.engine import PipelineEngine
-        engine_cls = PipelineEngine
-    engine = engine_cls(model=model, config=ds_config, mesh=mesh,
-                        optimizer=optimizer, lr_scheduler=lr_scheduler,
-                        loss_fn=loss_fn, param_specs=param_specs,
-                        rng=rng)
-    dataloader = None
-    if training_data is not None:
-        dataloader = DeepSpeedDataLoader(
-            training_data, batch_size=engine.train_batch_size,
-            collate_fn=collate_fn)
+        # Engine dispatch rides the topology: a mesh whose ``pipe`` axis
+        # is >= 2 — passed in or declared by the config's mesh block
+        # (e.g. an autotuner-exported 3D winner) — trains under the
+        # compiled pipeline schedule; no separate entry point.
+        ds_config = (config if isinstance(config, DeepSpeedConfig)
+                     else DeepSpeedConfig(config or {}))
+        if mesh is None:
+            mesh = build_mesh(ds_config.mesh)
+        from .parallel.topology import pp_world_size
+        engine_cls = DeepSpeedEngine
+        if pp_world_size(mesh) >= 2:
+            from .runtime.pipe.engine import PipelineEngine
+            engine_cls = PipelineEngine
+        engine = engine_cls(model=model, config=ds_config, mesh=mesh,
+                            optimizer=optimizer, lr_scheduler=lr_scheduler,
+                            loss_fn=loss_fn, param_specs=param_specs,
+                            rng=rng)
+        dataloader = None
+        if training_data is not None:
+            dataloader = DeepSpeedDataLoader(
+                training_data, batch_size=engine.train_batch_size,
+                collate_fn=collate_fn)
     return engine, engine.optimizer, dataloader, engine.lr_schedule
 
 
@@ -121,14 +130,15 @@ def init_inference(model: Any = None, config: Any = None,
     restored TP-sliced, else fresh weights."""
     from .inference.engine import InferenceEngine
     from .inference.config import DeepSpeedInferenceConfig
-    enable_compile_cache()
-    if isinstance(config, DeepSpeedInferenceConfig):
-        cfg = (config.model_copy(update=kwargs) if kwargs else config)
-    else:
-        cfg_dict = dict(config) if isinstance(config, dict) else {}
-        cfg_dict.update(kwargs)
-        cfg = DeepSpeedInferenceConfig(**cfg_dict)
-    return InferenceEngine(model, cfg, params=params, mesh=mesh)
+    with get_overlap_profiler().setup_span("setup/init_inference"):
+        enable_compile_cache()
+        if isinstance(config, DeepSpeedInferenceConfig):
+            cfg = (config.model_copy(update=kwargs) if kwargs else config)
+        else:
+            cfg_dict = dict(config) if isinstance(config, dict) else {}
+            cfg_dict.update(kwargs)
+            cfg = DeepSpeedInferenceConfig(**cfg_dict)
+        return InferenceEngine(model, cfg, params=params, mesh=mesh)
 
 
 def init_diffusion(unet_config=None, vae_config=None, text_config=None,

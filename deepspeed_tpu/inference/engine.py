@@ -30,6 +30,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..observability import get_overlap_profiler
 from ..parallel import topology as topo
 from ..runtime.resilience import run_with_timeout
 from ..utils.logging import logger
@@ -87,36 +88,40 @@ class InferenceEngine:
             mesh = topo.build_mesh(MeshConfig(model=tp, data=n // tp))
         self.mesh = mesh
 
+        ovl = get_overlap_profiler()
         # -- TP layout: model-provided specs or the auto-TP heuristic ------
-        shapes = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0)))
-        if hasattr(model, "partition_specs"):
-            self.param_specs = model.partition_specs(shapes)
-        else:
-            from ..module_inject.auto_tp import auto_tp_specs
-            self.param_specs = auto_tp_specs(shapes, self.mesh)
-        shardings = jax.tree_util.tree_map(
-            lambda s: NamedSharding(self.mesh, s), self.param_specs,
-            is_leaf=lambda x: isinstance(x, P))
+        with ovl.setup_span("setup/param_specs"):
+            shapes = jax.eval_shape(
+                lambda: model.init(jax.random.PRNGKey(0)))
+            if hasattr(model, "partition_specs"):
+                self.param_specs = model.partition_specs(shapes)
+            else:
+                from ..module_inject.auto_tp import auto_tp_specs
+                self.param_specs = auto_tp_specs(shapes, self.mesh)
+            shardings = jax.tree_util.tree_map(
+                lambda s: NamedSharding(self.mesh, s), self.param_specs,
+                is_leaf=lambda x: isinstance(x, P))
 
         # -- weights: explicit > checkpoint > fresh init --------------------
-        if params is not None:
-            self.params = jax.device_put(
-                jax.tree_util.tree_map(self._cast, params), shardings)
-        elif self.config.checkpoint:
-            self.params = self._load_checkpoint(
-                self.config.checkpoint, self.config.checkpoint_tag,
-                shapes, shardings)
-        else:
-            logger.warning("init_inference without params or checkpoint — "
-                           "using fresh random weights")
-            # bound once, called once — never re-wrapped per call (the
-            # TRACE003 discipline; __init__ runs once per engine)
-            init_fn = jax.jit(
-                lambda r: jax.tree_util.tree_map(
-                    self._cast, model.init(r)),
-                out_shardings=shardings)
-            with self.mesh:
-                self.params = init_fn(jax.random.PRNGKey(0))
+        with ovl.setup_span("setup/place_params"):
+            if params is not None:
+                self.params = jax.device_put(
+                    jax.tree_util.tree_map(self._cast, params), shardings)
+            elif self.config.checkpoint:
+                self.params = self._load_checkpoint(
+                    self.config.checkpoint, self.config.checkpoint_tag,
+                    shapes, shardings)
+            else:
+                logger.warning("init_inference without params or "
+                               "checkpoint — using fresh random weights")
+                # bound once, called once — never re-wrapped per call (the
+                # TRACE003 discipline; __init__ runs once per engine)
+                init_fn = jax.jit(
+                    lambda r: jax.tree_util.tree_map(
+                        self._cast, model.init(r)),
+                    out_shardings=shardings)
+                with self.mesh:
+                    self.params = init_fn(jax.random.PRNGKey(0))
 
         # -- the layout the model's step reads its weights in, made ONCE,
         # here, whichever source they came from (a checkpoint and
@@ -125,7 +130,7 @@ class InferenceEngine:
         # hands back the tree it was given.  ``quant.enabled`` below
         # quantizes THIS tree: int8 leaves in the layout the step reads.
         if hasattr(model, "serving_params"):
-            with self.mesh:
+            with ovl.setup_span("setup/serving_params"), self.mesh:
                 laid = model.serving_params(self.params)
             if laid is not self.params:
                 self.params = laid
@@ -136,7 +141,8 @@ class InferenceEngine:
         # dequantized into the matmul) ---------------------------------
         self._quantized = False
         if self.config.quant.enabled:
-            self._quantize_weights()
+            with ovl.setup_span("setup/quantize"):
+                self._quantize_weights()
 
         self._fwd = None
         self._gen_fns: Dict[Tuple, Any] = {}
@@ -576,9 +582,10 @@ class InferenceEngine:
                 '{"serving": {"enabled": true}} in the inference config')
         if self._serving is None:
             from .serving import ServingEngine
-            self._serving = ServingEngine(self, rng=rng,
-                                          draft_model=draft_model,
-                                          draft_params=draft_params)
+            with get_overlap_profiler().setup_span("setup/serving_engine"):
+                self._serving = ServingEngine(self, rng=rng,
+                                              draft_model=draft_model,
+                                              draft_params=draft_params)
         elif draft_model is not None \
                 and self._serving._draft_model is not draft_model:
             raise ValueError(

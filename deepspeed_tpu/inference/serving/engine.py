@@ -373,45 +373,48 @@ class ServingEngine:
         self.tp_data_size = cfg.mesh.data
         self.tp_model_size = cfg.mesh.model
         self._init_tp_mesh()
-        with trace_span("serving/kv_quantize", bits=self.kv_bits,
-                        blocks=cfg.num_kv_blocks):
-            pools = model.init_paged_cache(cfg.num_kv_blocks,
-                                           self.block_size,
-                                           dtype=engine.dtype,
-                                           kv_bits=self.kv_bits)
-        # the pools live in the sharding the step returns them in —
-        # sharding is part of the jit cache key, so anything else would
-        # retrace the program on the second dispatch.  They shard on
-        # the kv-head lanes over `model` (scale planes on their kv-head
-        # axis) and REPLICATE over `data`: each chip holds
-        # kv_heads/model of every block (kv_pool_bytes)
-        self._pool_sh = NamedSharding(self.tp_mesh, self._pool_spec)
-        self._pscale_sh = NamedSharding(self.tp_mesh, self._pscale_spec)
-        # a pool of ONE buffer (a latent pool: key and value are one
-        # row) has no "v": the operand is None, like the scale planes of
-        # an unquantized pool
-        self._pool_k = jax.device_put(pools["k"], self._pool_sh)
-        self._pool_v = (None if pools["v"] is None else
-                        jax.device_put(pools["v"], self._pool_sh))
-        self._pool_ks = self._pool_vs = None
-        if self.kv_bits:
-            self._pool_ks = jax.device_put(pools["k_scale"],
-                                           self._pscale_sh)
-            self._pool_vs = jax.device_put(pools["v_scale"],
-                                           self._pscale_sh)
-        # what a slot keeps besides the pool's pages (a window kind's
-        # pool, per-slot recurrent state): the model's own tree, carried
-        # by the step like the pools; None for a block that has none
-        # (made where it will live: at gigabytes of state a copy on the
-        # way in would not fit beside the weights)
-        make_extra = jax.jit(
-            lambda: model.init_paged_extra(
-                self.num_slots, self.block_size, self.window_blocks,
-                dtype=engine.dtype),
-            out_shardings=NamedSharding(self.tp_mesh, P()))
-        self._pool_x = make_extra()
-        if self._pool_x is not None:
-            self.allocator.add_state_kind(self.num_slots)
+        # every device buffer the step carries beside the weights: the
+        # pools, their scale planes, the model's own per-slot tree
+        with self._ovl.setup_span("setup/pools"):
+            with trace_span("serving/kv_quantize", bits=self.kv_bits,
+                            blocks=cfg.num_kv_blocks):
+                pools = model.init_paged_cache(cfg.num_kv_blocks,
+                                               self.block_size,
+                                               dtype=engine.dtype,
+                                               kv_bits=self.kv_bits)
+            # the pools live in the sharding the step returns them in —
+            # sharding is part of the jit cache key, so anything else would
+            # retrace the program on the second dispatch.  They shard on
+            # the kv-head lanes over `model` (scale planes on their kv-head
+            # axis) and REPLICATE over `data`: each chip holds
+            # kv_heads/model of every block (kv_pool_bytes)
+            self._pool_sh = NamedSharding(self.tp_mesh, self._pool_spec)
+            self._pscale_sh = NamedSharding(self.tp_mesh, self._pscale_spec)
+            # a pool of ONE buffer (a latent pool: key and value are one
+            # row) has no "v": the operand is None, like the scale planes of
+            # an unquantized pool
+            self._pool_k = jax.device_put(pools["k"], self._pool_sh)
+            self._pool_v = (None if pools["v"] is None else
+                            jax.device_put(pools["v"], self._pool_sh))
+            self._pool_ks = self._pool_vs = None
+            if self.kv_bits:
+                self._pool_ks = jax.device_put(pools["k_scale"],
+                                               self._pscale_sh)
+                self._pool_vs = jax.device_put(pools["v_scale"],
+                                               self._pscale_sh)
+            # what a slot keeps besides the pool's pages (a window kind's
+            # pool, per-slot recurrent state): the model's own tree, carried
+            # by the step like the pools; None for a block that has none
+            # (made where it will live: at gigabytes of state a copy on the
+            # way in would not fit beside the weights)
+            make_extra = jax.jit(
+                lambda: model.init_paged_extra(
+                    self.num_slots, self.block_size, self.window_blocks,
+                    dtype=engine.dtype),
+                out_shardings=NamedSharding(self.tp_mesh, P()))
+            self._pool_x = make_extra()
+            if self._pool_x is not None:
+                self.allocator.add_state_kind(self.num_slots)
         self._prep_tp_params()
         logger.info(
             f"serving: paged KV pool {cfg.num_kv_blocks} x "
@@ -1179,19 +1182,20 @@ class ServingEngine:
                 "draft checkpoint for real speedups)")
             params = draft.init(jax.random.PRNGKey(1))
         self._draft_params = params
-        with trace_span("serving/draft_pool", blocks=cfg.num_kv_blocks):
-            dpools = draft.init_paged_cache(
-                cfg.num_kv_blocks, self.block_size,
-                dtype=self.engine.dtype, kv_bits=0)
-        # the draft replicates over BOTH mesh axes (it is small); its
-        # view arms only the data axis so the slot-sharded lens/tables
-        # it shares with the target stay correct
-        self._tp_draft = draft.tp_serving_view(
-            1, None, topo.DATA_AXIS if self.tp_data_size > 1 else None)
-        rep = NamedSharding(self.tp_mesh, P())
-        self._dpool_k = jax.device_put(dpools["k"], rep)
-        self._dpool_v = jax.device_put(dpools["v"], rep)
-        self._draft_params = jax.device_put(self._draft_params, rep)
+        with self._ovl.setup_span("setup/pools"):
+            with trace_span("serving/draft_pool", blocks=cfg.num_kv_blocks):
+                dpools = draft.init_paged_cache(
+                    cfg.num_kv_blocks, self.block_size,
+                    dtype=self.engine.dtype, kv_bits=0)
+            # the draft replicates over BOTH mesh axes (it is small); its
+            # view arms only the data axis so the slot-sharded lens/tables
+            # it shares with the target stay correct
+            self._tp_draft = draft.tp_serving_view(
+                1, None, topo.DATA_AXIS if self.tp_data_size > 1 else None)
+            rep = NamedSharding(self.tp_mesh, P())
+            self._dpool_k = jax.device_put(dpools["k"], rep)
+            self._dpool_v = jax.device_put(dpools["v"], rep)
+            self._draft_params = jax.device_put(self._draft_params, rep)
         logger.info(
             f"serving: speculative decoding armed — draft "
             f"{draft.config.num_layers}L/{draft.config.d_model}d, "
@@ -1444,8 +1448,8 @@ class ServingEngine:
                 if self.tp_data_size > 1 else 0)
             return jnp.where((row == ch.slot) & (ch.len > 0), first, newest)
 
-        def step(params, scales, pool_k, pool_v, pool_ks, pool_vs, pool_x,
-                 prev, slots, chunk):
+        def serving_step(params, scales, pool_k, pool_v, pool_ks, pool_vs,
+                         pool_x, prev, slots, chunk):
             built()
             # slices of an operand are free: the two host arrays come
             # apart first thing
@@ -1484,9 +1488,9 @@ class ServingEngine:
                     cache.get("k_scale"), cache.get("v_scale"),
                     cache.get("extra"))
 
-        def spec_step(params, scales, dparams, pool_k, pool_v, pool_ks,
-                      pool_vs, pool_x, dpool_k, dpool_v, prev, slots,
-                      chunk):
+        def serving_spec_step(params, scales, dparams, pool_k, pool_v,
+                              pool_ks, pool_vs, pool_x, dpool_k, dpool_v,
+                              prev, slots, chunk):
             built()
             sl = _SlotState.unpack(slots)
             (tables, lens, _host_tokens, dec_active, spec_active, temp,
@@ -1575,10 +1579,10 @@ class ServingEngine:
         # are rewritten at every scatter, exactly like the values); the
         # draft pools donate alongside the target's
         if spec_on:
-            fn = spec_step
+            fn = serving_spec_step
             donate = (3, 4, 8, 9) + ((5, 6) if self.kv_bits else ())
         else:
-            fn = step
+            fn = serving_step
             donate = (2, 3) + ((4, 5) if self.kv_bits else ()) + (
                 (6,) if self._pool_x is not None else ())
         # the body runs shard_mapped over the (data, model) serving
@@ -1609,6 +1613,8 @@ class ServingEngine:
         # a Mosaic call refuses to lower while any mesh axis is auto
         sharded = shard_map(fn, mesh=self.tp_mesh, in_specs=in_specs,
                             out_specs=out_specs)
+        # the profiler's build records under this name read ``own``
+        get_overlap_profiler().own_program(fn.__name__)
         with self.tp_mesh:
             return jax.jit(
                 sharded, donate_argnums=donate if self._donate else ())
@@ -1747,8 +1753,9 @@ class ServingEngine:
         ``chunk`` does NOT take runs here, once, with every slot
         inactive — it writes only null-block rows — and the dispatch
         that called builds its own by running."""
-        self._step_fn = self._build_step()
-        self._launch(self._idle_operands(chunk_lane=chunk is None))
+        with self._ovl.setup_span("setup/build_step"):
+            self._step_fn = self._build_step()
+            self._launch(self._idle_operands(chunk_lane=chunk is None))
 
     def _idle_operands(self, chunk_lane: bool) -> tuple:
         """The step's operands with nothing riding — every slot

@@ -49,10 +49,32 @@ instruction, so the join runs through the program's own compiled text:
 process has loaded and returns ``{scope_key(instruction): (scope,
 recomputed)}``.  It is built when asked for and at no other time.
 
+The **set-up path** — everything before the first iteration — has a log
+of its own: ``setup_span(name)`` records ``(id, parent, name, begin,
+end)`` around the entry points' and the engines' set-up steps
+(``setup/init_inference`` .. ``setup/build_train_step``), and one pair
+of ``jax.monitoring`` listeners (``listen_for_builds``, registered once
+a process by ``ds.enable_compile_cache()``) folds JAX's own compile
+events into one record a program: its name, what tracing, lowering and
+the backend compile (or the fetch from the persistent cache) took,
+whether the cache held it, the set-up span and the serving iteration
+it was built under, and whether an engine declared the program its own
+(``own_program``).  ``setup_spans()`` / ``builds(t0_s, t1_s)`` read
+them back.
+
 Contract (same as every observability hook in this repo):
   - **no new device syncs** in any path;
-  - disabled (default), every engine call site is ONE attribute check
-    (``if ovl.enabled:``) — no allocation, no clock read, no annotation;
+  - the set-up log is the one exception to "off by default": it is
+    ALWAYS on — a server's set-up has ended before anyone could switch
+    it on — and pays for that by being bounded (two Python lists of at
+    most ``SETUP_LOG_CAP`` records each, the oldest kept: set-up comes
+    first), by preallocating nothing, and by never being called from an
+    iteration or a training step: a span wraps a set-up step, and the
+    listeners run only when JAX traces, lowers or compiles, never on a
+    cached call;
+  - disabled (default), every other engine call site is ONE attribute
+    check (``if ovl.enabled:``) — no allocation, no clock read, no
+    annotation;
   - enabled, a phase mark is one ``perf_counter_ns`` read, and every
     phase is also a ``jax.profiler.TraceAnnotation("serving/<phase>")``
     inside a ``TraceAnnotation("serving/iteration", n=<number>)``, so a
@@ -67,7 +89,9 @@ Contract (same as every observability hook in this repo):
 """
 from __future__ import annotations
 
+import contextlib
 import functools
+import math
 import re
 import threading
 import time
@@ -180,6 +204,38 @@ ITERATION_DTYPE = np.dtype(
 REQUEST_DTYPE = np.dtype(
     [("submit_time", np.float64), ("admit_time", np.float64),
      ("first_token_time", np.float64), ("finish_time", np.float64)])
+
+#: one set-up span: ``parent`` is the ``id`` of the span that was open
+#: around it (-1 at the top).  Seconds on ``time.perf_counter()``'s clock.
+SETUP_SPAN_DTYPE = np.dtype(
+    [("id", np.int64), ("parent", np.int64), ("name", "U48"),
+     ("begin_s", np.float64), ("end_s", np.float64)])
+
+#: one program built (traced, lowered, compiled or fetched): ``trace_s``,
+#: ``lower_s``, ``compile_s`` are JAX's own durations (``compile_s``
+#: holds the fetch on a cache hit); ``cache`` is ``hit`` / ``miss`` /
+#: ``off`` (no persistent cache was asked); ``span`` the id of the set-up
+#: span open when the build ended and ``iteration`` the number of the
+#: serving iteration open then (-1: none); ``own``: an engine declared
+#: the program its own (``own_program``).
+BUILD_DTYPE = np.dtype(
+    [("fun_name", "U64"), ("begin_s", np.float64), ("end_s", np.float64),
+     ("trace_s", np.float64), ("lower_s", np.float64),
+     ("compile_s", np.float64), ("cache", "U4"), ("span", np.int64),
+     ("iteration", np.int64), ("own", np.bool_)])
+
+#: records each of the set-up log's two lists may hold; the oldest stay
+SETUP_LOG_CAP = 1024
+
+#: JAX's monitoring events (jax 0.9.0: every ``/jax/core/compile/*``
+#: duration carries ``fun_name``; the cache events arrive between a
+#: program's lowering and the end of its backend compile)
+_EV_TRACE = "/jax/core/compile/jaxpr_trace_duration"
+_EV_LOWER = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+_EV_COMPILE = "/jax/core/compile/backend_compile_duration"
+_EV_CACHE = {"/jax/compilation_cache/cache_hits": "hit",
+             "/jax/compilation_cache/cache_misses": "miss"}
+_WRAPPED = re.compile(r"^\w+\((.*)\)$")     # jit(step) -> step
 
 NAN = float("nan")
 
@@ -366,6 +422,20 @@ class OverlapProfiler:
         self._annotation = None           # jax.profiler.TraceAnnotation
         self._it_span = None
         self._phase_span = None
+        # -- the set-up log: always on, bounded (module docstring) ---------
+        self._spans: List[tuple] = []     # closed spans (SETUP_SPAN_DTYPE)
+        self._span_ids = 0                # ids handed out
+        self._span_open: List[int] = []   # open spans' ids, outermost first
+        self._builds: List[tuple] = []    # BUILD_DTYPE rows
+        self._own: set = set()            # fun_names the engines declared
+        #: fun_name -> (begin_s, trace_s): traced, waiting for a lowering
+        self._traced: Dict[str, Tuple[float, float]] = {}
+        #: [fun_name, begin_s, trace_s, lower_s, cache]: the program
+        #: between its lowering and the end of its backend compile
+        self._lowered: Optional[list] = None
+        self._listening = False
+        #: records the two lists refused because they were full
+        self.setup_log_dropped = 0
 
     # -- configuration -----------------------------------------------------
     def configure(self, enabled: bool, capacity: Optional[int] = None,
@@ -423,6 +493,109 @@ class OverlapProfiler:
                                "training per-step device wait"))
         self._metrics[kind] = m
         return m
+
+    # -- the set-up log ------------------------------------------------------
+    @contextlib.contextmanager
+    def setup_span(self, name: str):
+        """One step of the set-up path: a ``(id, parent, name, begin_s,
+        end_s)`` record in the set-up log (always) and a ``trace_span``
+        of the same name (when the tracer is on).  Never wrap anything
+        that runs once an iteration or once a training step."""
+        from . import trace_span
+        sid, self._span_ids = self._span_ids, self._span_ids + 1
+        parent = self._span_open[-1] if self._span_open else -1
+        self._span_open.append(sid)
+        begin = time.perf_counter()
+        try:
+            with trace_span(name, "setup"):
+                yield
+        finally:
+            end = time.perf_counter()
+            self._span_open.remove(sid)
+            self._log(self._spans, (sid, parent, name, begin, end))
+
+    def _log(self, rows: List[tuple], row: tuple) -> None:
+        with self._lock:
+            if len(rows) < SETUP_LOG_CAP:
+                rows.append(row)
+            else:
+                self.setup_log_dropped += 1
+
+    def own_program(self, fun_name: str) -> None:
+        """An engine declares the program it is about to wrap in
+        ``jax.jit`` its own: build records under this name read
+        ``own``."""
+        self._own.add(fun_name)
+
+    def listen_for_builds(self) -> None:
+        """Register the ONE pair of ``jax.monitoring`` listeners that
+        writes the build records; a second call does nothing.  JAX calls
+        them only when it traces, lowers or compiles."""
+        if self._listening:
+            return
+        from jax import monitoring
+        monitoring.register_event_duration_secs_listener(self._on_duration)
+        monitoring.register_event_listener(self._on_event)
+        self._listening = True
+
+    def _on_duration(self, event: str, secs: float, fun_name: str = "",
+                     **_) -> None:
+        """Fold one program's three durations into one build record.
+        The events of one program arrive in order — its trace (after
+        those of the functions it calls), its lowering, the cache's
+        answer, its backend compile — on the thread that builds it; two
+        threads that build at once may read each other's cache state."""
+        if event == _EV_TRACE:
+            if len(self._traced) >= SETUP_LOG_CAP:     # traced, never lowered
+                self._traced.clear()
+            self._traced[fun_name] = (time.perf_counter() - secs, secs)
+        elif event == _EV_LOWER:
+            fun = _WRAPPED.sub(r"\1", fun_name)
+            begin, trace_s = self._traced.get(
+                fun, (time.perf_counter() - secs, 0.0))
+            self._traced.clear()
+            self._lowered = [fun, begin, trace_s, secs, "off"]
+        elif event == _EV_COMPILE:
+            fun, now = _WRAPPED.sub(r"\1", fun_name), time.perf_counter()
+            low, self._lowered = self._lowered, None
+            if low is None or low[0] != fun:    # lowered before we listened
+                low = [fun, now - secs, 0.0, 0.0, "off"]
+            _, begin, trace_s, lower_s, cache = low
+            self._log(self._builds, (
+                fun, begin, now, trace_s, lower_s, secs, cache,
+                self._span_open[-1] if self._span_open else -1,
+                self.iteration if self._open else -1, fun in self._own))
+
+    def _on_event(self, event: str, **_) -> None:
+        cache = _EV_CACHE.get(event)
+        if cache is not None and self._lowered is not None:
+            self._lowered[4] = cache
+
+    def setup_spans(self) -> np.ndarray:
+        """The closed set-up spans (``SETUP_SPAN_DTYPE``), in the order
+        they were opened."""
+        with self._lock:
+            return np.array(sorted(self._spans), SETUP_SPAN_DTYPE)
+
+    def builds(self, t0_s: float = -math.inf, t1_s: float = math.inf
+               ) -> np.ndarray:
+        """The build records (``BUILD_DTYPE``) whose end lies in
+        ``(t0_s, t1_s]`` on ``time.perf_counter()``'s clock, oldest
+        first; ``-inf`` reads "since the process began"."""
+        with self._lock:
+            held = np.array(self._builds, BUILD_DTYPE)
+        return held[(held["end_s"] > t0_s) & (held["end_s"] <= t1_s)]
+
+    def clear_setup_log(self) -> None:
+        """Forget the spans, the builds and the declared programs (the
+        tests' isolation; the listeners stay)."""
+        with self._lock:
+            self._spans.clear()
+            self._builds.clear()
+            self.setup_log_dropped = 0
+        self._own.clear()
+        self._traced.clear()
+        self._lowered = None
 
     # -- serving iteration protocol ----------------------------------------
     def _close_spans(self) -> None:
@@ -627,40 +800,67 @@ class OverlapProfiler:
     def chrome_events(self, epoch_ns: int, rank: int
                       ) -> List[Dict[str, Any]]:
         """Per-iteration overlap track: one X slice per iteration plus a
-        'C' counter series Perfetto renders as a graph."""
+        'C' counter series Perfetto renders as a graph; and the set-up
+        log on two threads of the same track: one X slice a set-up span,
+        one a program built."""
         pid = OVERLAP_TRACK_PID_OFFSET + rank
         with self._lock:
             recs = self._its.held()
-        if not len(recs):
+        spans, built = self.setup_spans(), self.builds()
+        if not (len(recs) or len(spans) or len(built)):
             return []
         kinds = sorted({str(k) for k in recs["kind"]})
         tids = {k: i + 1 for i, k in enumerate(kinds)}
+        setup_tid, builds_tid = len(tids) + 1, len(tids) + 2
         out: List[Dict[str, Any]] = [
             {"ph": "M", "pid": pid, "tid": 0, "name": "process_name",
              "args": {"name": f"overlap profiler rank {rank}"}},
             {"ph": "M", "pid": pid, "tid": 0, "name": "process_sort_index",
              "args": {"sort_index": pid}},
         ]
-        for k, t in tids.items():
+        threads = [(t, f"{k} iterations") for k, t in tids.items()]
+        if len(spans):
+            threads.append((setup_tid, "set-up spans"))
+        if len(built):
+            threads.append((builds_tid, "programs built"))
+        for t, name in threads:
             out.append({"ph": "M", "pid": pid, "tid": t,
-                        "name": "thread_name",
-                        "args": {"name": f"{k} iterations"}})
+                        "name": "thread_name", "args": {"name": name}})
+
+        def slice_of(rec, tid, name, args, cat="setup"):
+            return {"ph": "X", "pid": pid, "tid": tid, "name": name,
+                    "cat": cat,
+                    "ts": (rec["begin_s"] * 1e9 - epoch_ns) / 1000.0,
+                    "dur": float(rec["end_s"] - rec["begin_s"]) * 1e6,
+                    "args": args}
+
+        for rec in spans:
+            out.append(slice_of(rec, setup_tid, str(rec["name"]),
+                                {"id": int(rec["id"]),
+                                 "parent": int(rec["parent"])}))
+        for rec in built:
+            out.append(slice_of(
+                rec, builds_tid, str(rec["fun_name"]),
+                {"trace_ms": float(rec["trace_s"]) * 1e3,
+                 "lower_ms": float(rec["lower_s"]) * 1e3,
+                 "compile_ms": float(rec["compile_s"]) * 1e3,
+                 "cache": str(rec["cache"]), "span": int(rec["span"]),
+                 "iteration": int(rec["iteration"]),
+                 "own": bool(rec["own"])}))
         for rec in recs:
             kind = str(rec["kind"])
-            ts = (rec["begin_s"] * 1e9 - epoch_ns) / 1000.0
             phases_ms = {f"{p}_ms": float(rec[f"{p}_s"]) * 1e3
                          for p in PHASES}
             host_ms = (phases_ms["plan_ms"] + phases_ms["operands_ms"]
                        + phases_ms["apply_ms"])
-            out.append({"ph": "X", "pid": pid, "tid": tids[kind],
-                        "name": f"{kind}_iteration", "cat": "overlap",
-                        "ts": ts,
-                        "dur": float(rec["end_s"] - rec["begin_s"]) * 1e6,
-                        "args": dict(phases_ms, n=int(rec["n"]),
-                                     host_plan_ms=host_ms,
-                                     **{c: int(rec[c]) for c in COUNTERS})})
+            it = slice_of(rec, tids[kind], f"{kind}_iteration",
+                          dict(phases_ms, n=int(rec["n"]),
+                               host_plan_ms=host_ms,
+                               **{c: int(rec[c]) for c in COUNTERS}),
+                          cat="overlap")
+            out.append(it)
             out.append({"ph": "C", "pid": pid, "tid": tids[kind],
-                        "name": f"{kind}_overlap", "ts": ts,
+                        "name": f"{kind}_overlap", "ts": it["ts"],
                         "args": {"host_plan_ms": host_ms,
                                  "device_wait_ms":
                                      phases_ms["device_wait_ms"]}})

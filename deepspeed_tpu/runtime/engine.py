@@ -36,7 +36,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..observability import get_registry, trace_span
+from ..observability import (get_overlap_profiler, get_registry,
+                             trace_span)
 from ..parallel import topology as topo
 from ..parallel.shard_map_compat import shard_map
 from ..utils.logging import logger
@@ -51,10 +52,14 @@ from .zero.sharding import ZeroShardingPolicy, constrain, to_named
 MEM_EFFICIENT_LINEAR_DEFAULT = True
 
 
-def _count_jit_build() -> None:
+def _count_jit_build(fn: Callable) -> None:
     """Recompile watermark: every jit program the engine constructs bumps
-    this counter — a rising value mid-run means a retrace bomb."""
+    this counter — a rising value mid-run means a retrace bomb.  ``fn``
+    is the function being wrapped in ``jax.jit``: the overlap profiler's
+    build records under its name read ``own`` and say WHICH program was
+    built, when, and what it cost (``builds()``)."""
     get_registry().counter("dstpu_jit_programs_built_total").inc()
+    get_overlap_profiler().own_program(fn.__name__)
 
 
 def _tree_zeros_f32(tree):
@@ -188,7 +193,7 @@ class DeepSpeedEngine:
         self._analytic_flops_per_step = None
         self._tracer, self._obs = _obs_configure(
             self._config.observability, rank=jax.process_index())
-        from ..observability import get_flight_recorder, get_overlap_profiler
+        from ..observability import get_flight_recorder
         self._flight = get_flight_recorder()
         # host/device overlap profiler: splits the fused step into
         # enqueue vs device-wait from timestamps the step path already
@@ -216,8 +221,9 @@ class DeepSpeedEngine:
 
         # -- state init (sharded at materialization) -----------------------
         if not dont_init:
-            self.state = self.init_state(rng if rng is not None
-                                         else jax.random.PRNGKey(0))
+            with self._ovl.setup_span("setup/state_init"):
+                self.state = self.init_state(rng if rng is not None
+                                             else jax.random.PRNGKey(0))
         self._train_step_fn = None
         self._grad_fn = None
         self._apply_fn = None
@@ -397,7 +403,7 @@ class DeepSpeedEngine:
         from .zero import wire_codec
         bits = self._offload_wire_bits
 
-        def grad_fn(state, batch, scale, key):
+        def offload_grad_fn(state, batch, scale, key):
             gsum, lsum, _ = self._accumulate_micro_grads(state, batch, scale)
             gnorm = global_norm(gsum)
             if not bits:
@@ -415,8 +421,8 @@ class DeepSpeedEngine:
             return lsum, wire_codec.encode(flat, bits, key), gnorm
 
         with self.mesh:
-            self._offload_grad_fn = jax.jit(grad_fn)
-        _count_jit_build()
+            self._offload_grad_fn = jax.jit(offload_grad_fn)
+        _count_jit_build(offload_grad_fn)
         return self._offload_grad_fn
 
     @property
@@ -688,11 +694,17 @@ class DeepSpeedEngine:
         return new_state, metrics
 
     def _build_train_step(self):
-        if self.optimizer.hyperparams.get("onebit"):
-            return self._build_onebit_train_step()
+        # the step's construction; its compile comes with the first
+        # ``train_step`` and is found by its ``own`` build record
+        with self._ovl.setup_span("setup/build_train_step"):
+            if self.optimizer.hyperparams.get("onebit"):
+                return self._build_onebit_train_step()
+            return self._build_train_step_traced()
+
+    def _build_train_step_traced(self):
         gas = self.gradient_accumulation_steps
 
-        def step_fn(state, batch):
+        def train_step(state, batch):
             scale = self._current_scale(state)
             gsum, lsum, counters = self._accumulate_micro_grads(
                 state, batch, scale)
@@ -713,8 +725,8 @@ class DeepSpeedEngine:
         # for the host optimizer sweep and its batch is reused.
         donate = (0, 1) if self._config.training.donate_batch else (0,)
         with self.mesh:
-            self._train_step_fn = jax.jit(step_fn, donate_argnums=donate)
-        _count_jit_build()
+            self._train_step_fn = jax.jit(train_step, donate_argnums=donate)
+        _count_jit_build(train_step)
         return self._train_step_fn
 
     def _install_layer_gather(self, model):
@@ -879,7 +891,7 @@ class DeepSpeedEngine:
             err_in = jax.tree_util.tree_map(
                 lambda l: P(axis), self._onebit_errors)
 
-            def step_fn(state, errors, batch):
+            def onebit_train_step(state, errors, batch):
                 bspec = jax.tree_util.tree_map(lambda _: P(None, axis),
                                                batch)
                 sharded = shard_map(
@@ -894,9 +906,9 @@ class DeepSpeedEngine:
                 return sharded(state, errors, batch)
 
             with self.mesh:
-                self._onebit_compiled[key] = jax.jit(step_fn,
+                self._onebit_compiled[key] = jax.jit(onebit_train_step,
                                                      donate_argnums=(0, 1))
-            _count_jit_build()
+            _count_jit_build(onebit_train_step)
 
         # error buffers re-zero when a reset-marked phase first activates
         # (reference reinitial_error_buffer, zoadam.py:324)
@@ -1256,10 +1268,11 @@ class DeepSpeedEngine:
         sq = jax.tree_util.tree_map(lambda x: x.reshape((-1,) + x.shape[2:]),
                                     batch)
         if not hasattr(self, "_eval_fn"):
+            def eval_loss_fn(params, mb):
+                return self._loss_and_counters(params, mb)[0]
             with self.mesh:
-                self._eval_fn = jax.jit(
-                    lambda p, b: self._loss_and_counters(p, b)[0])
-            _count_jit_build()
+                self._eval_fn = jax.jit(eval_loss_fn)
+            _count_jit_build(eval_loss_fn)
         return self._eval_fn(self.state["params"], sq)
 
     # ------------------------------------------------------------------
@@ -1277,13 +1290,13 @@ class DeepSpeedEngine:
                 self.mesh, jax.tree_util.tree_map(
                     lambda x: P(self._batch_dim_spec,), batch)))
         if self._grad_fn is None:
-            def gfn(params, mb, scale):
+            def forward_grads(params, mb, scale):
                 (loss, _), grads = jax.value_and_grad(
                     self._micro_loss, has_aux=True)(params, mb, scale)
                 return loss, grads
             with self.mesh:
-                self._grad_fn = jax.jit(gfn)
-            _count_jit_build()
+                self._grad_fn = jax.jit(forward_grads)
+            _count_jit_build(forward_grads)
         scale = (self.state["scaler"].scale
                  if self.loss_scaler is not None else 1.0)
         with trace_span("engine/forward", micro_step=self.micro_steps):
@@ -1304,12 +1317,12 @@ class DeepSpeedEngine:
                 # the callable object, so a fresh lambda here meant a fresh
                 # trace+compile EVERY microbatch (dstpu-lint TRACE003)
                 if getattr(self, "_grad_acc_add_fn", None) is None:
+                    def grad_acc_add(a, b):
+                        return jax.tree_util.tree_map(jnp.add, a, b)
                     with self.mesh:
                         self._grad_acc_add_fn = jax.jit(
-                            lambda a, b: jax.tree_util.tree_map(jnp.add,
-                                                                a, b),
-                            donate_argnums=(0,))
-                    _count_jit_build()
+                            grad_acc_add, donate_argnums=(0,))
+                    _count_jit_build(grad_acc_add)
                 with self.mesh:
                     self._grad_acc = self._grad_acc_add_fn(self._grad_acc,
                                                            grads)
@@ -1324,11 +1337,12 @@ class DeepSpeedEngine:
         if self._grad_acc is None:
             return
         if self._apply_fn is None:
+            def apply_grads(state, grads, n_micro):
+                return self._apply_grads(state, grads, n_micro)
             with self.mesh:
-                self._apply_fn = jax.jit(
-                    lambda st, g, n: self._apply_grads(st, g, n),
-                    donate_argnums=(0, 1))
-            _count_jit_build()
+                self._apply_fn = jax.jit(apply_grads,
+                                         donate_argnums=(0, 1))
+            _count_jit_build(apply_grads)
         with trace_span("engine/optimizer_step", step=self.global_steps):
             self.state, metrics = self._apply_fn(
                 self.state, self._grad_acc,

@@ -569,8 +569,9 @@ class PipelineEngine(DeepSpeedEngine):
         # the schedule itself runs inside ONE jitted program (per-tick
         # stage work is the device profiler's domain); the host-side span
         # marks for how many stages/micros it was compiled
-        with trace_span("pipe/build_schedule", stages=self.num_stages,
-                        micro_batches=self.micro_batches):
+        with self._ovl.setup_span("setup/build_train_step"), \
+                trace_span("pipe/build_schedule", stages=self.num_stages,
+                           micro_batches=self.micro_batches):
             return self._build_train_step_traced()
 
     def _build_loss_grad_region(self):
@@ -604,7 +605,7 @@ class PipelineEngine(DeepSpeedEngine):
         # and data shards — normalize by both
         n_eff = float(self.micro_batches * self._dp_prod())
 
-        def step_fn(state, batch):
+        def pipe_train_step(state, batch):
             ids = batch["input_ids"]        # [M, micro*dp, T]
             scale = self._current_scale(state)
             loss_sum, grads = sharded(
@@ -614,8 +615,9 @@ class PipelineEngine(DeepSpeedEngine):
             return new_state, metrics
 
         with self.mesh:
-            self._train_step_fn = jax.jit(step_fn, donate_argnums=(0,))
-        _count_jit_build()
+            self._train_step_fn = jax.jit(pipe_train_step,
+                                          donate_argnums=(0,))
+        _count_jit_build(pipe_train_step)
         return self._train_step_fn
 
     # ------------------------------------------------------------------
@@ -658,7 +660,7 @@ class PipelineEngine(DeepSpeedEngine):
             region = self._build_loss_grad_region()
             with self.mesh:
                 probe = jax.jit(region)   # no donation: params are live
-            _count_jit_build()
+            _count_jit_build(region)
             params = self._cast_for_compute(self.state["params"])
             scale = jnp.asarray(1.0, jnp.float32)
             times = {}

@@ -2239,7 +2239,8 @@ def test_batch_moves_between_greedy_and_mixed_on_one_program(packed):
         assert r.status is RequestStatus.OK
         assert r.output == sampled_generate(eng, p, n, **samp), (p, samp)
     ends = np.cumsum(its["dispatches"])
-    slices = [e["args"] for e in events if e["ph"] == "X"]
+    slices = [e["args"] for e in events
+              if e["ph"] == "X" and e["cat"] == "overlap"]
     for name, counts in want.items():
         # an iteration's record is the sum over its dispatches
         assert [sum(counts[a:b]) for a, b in zip(
