@@ -451,7 +451,9 @@ def latent_phase(device: dict, block: int = SERVING["kv_block_size"]):
     token, 512-row chunks; the experts' grouped product (16 held experts
     6144 x 2048 at about 9 rows each); and an expert layer with a sigmoid
     gate and a shared expert (7680 x 2048, 640 rows), against float32
-    ``jax.numpy``, on the chip.  Parity only: nothing here is timed."""
+    ``jax.numpy``, on the chip.  Parity, and one pair of times: the
+    decode walk at each cell's pool over a table of whole runs and over
+    a scattered one (``latent_walk_times``)."""
     import jax
     import jax.numpy as jnp
     from deepspeed_tpu.moe import dropless
@@ -533,6 +535,7 @@ def latent_phase(device: dict, block: int = SERVING["kv_block_size"]):
     check(gate_err < 5e-2,
           f"latent: sigmoid gate + shared expert off by {gate_err}")
     return {"phase": "latent", **device,
+            "decode_walk": latent_walk_times(block),
             "decode_max_abs_err": {str(h): round(e, 5)
                                    for h, e in decode_err.items()},
             "prefill_max_abs_err": {str(h): round(e, 5)
@@ -540,6 +543,74 @@ def latent_phase(device: dict, block: int = SERVING["kv_block_size"]):
             "grouped_max_abs_err": round(gmm_err, 5),
             "shared_expert_layer_rel_err": round(gate_err, 5),
             "atol": KERNEL_ATOL}
+
+
+#: (heads, slots, pages a slot's table, pool blocks, shortest and longest
+#: context) of the two latent cells' decode walks
+LATENT_WALKS = ((128, 128, 256, 17408, 256, 3000),
+                (64, 48, 512, 12288, 1024, 5800))
+
+
+def latent_walk_times(block: int, reps: int = 20) -> dict:
+    """The latent kernel's decode call at the two cells' pools and batches
+    (17,408 blocks, 128 slots x 128 heads, contexts 256-3,000;
+    12,288 blocks, 48 slots x 64 heads, contexts 1,024-5,800: a kernel's
+    time depends on the pool it walks) over a table whose aligned runs of
+    ``PAGE_RUN`` pages are consecutive pool blocks, as the cache manager
+    hands them out, and over a scattered one, as a pool that kept no runs
+    would hold: microseconds a call (the best of three timings of
+    ``reps`` chained calls), nanoseconds a page, and the share of the
+    roofline (2 x heads x 1,088 operations a context token against 197
+    TFLOP/s, 1,152 bytes against 819 GB/s)."""
+    import jax
+    import jax.numpy as jnp
+    from deepspeed_tpu.ops.transformer.paged_decode_attention import (
+        PAGE_RUN, mla_paged_decode_attention)
+    rng = np.random.default_rng(SEED + 4)
+    out = {}
+    for heads, slots, pages, nb, lo, hi in LATENT_WALKS:
+        lens = rng.integers(lo, hi, slots).astype(np.int32)
+        need = -(-lens // block)
+        pool = jnp.asarray(rng.standard_normal((nb, block, 640),
+                                               dtype=np.float32),
+                           jnp.bfloat16)
+        ql = jnp.asarray(rng.standard_normal((slots, heads, 512)) * 0.3,
+                         jnp.bfloat16)
+        qr = jnp.asarray(rng.standard_normal((slots, heads, 64)) * 0.3,
+                         jnp.bfloat16)
+
+        @jax.jit
+        def chained(ql, qr, pool, lens, tables):
+            def one(_, lens):
+                o = mla_paged_decode_attention(ql, qr, pool, lens, tables,
+                                               192 ** -0.5)
+                return lens + (o[0, 0, 0] * 0).astype(jnp.int32)
+            return jax.lax.fori_loop(0, reps, one, lens)
+        floor = max(2.0 * lens.sum() * heads * 1088 / 197e12,
+                    lens.sum() * 1152 / 819e9)
+        cell = {"pages": int(need.sum())}
+        groups = rng.permutation((nb - 1) // PAGE_RUN)
+        spans = np.concatenate([[0], np.cumsum(-(-need // PAGE_RUN))])
+        for kind in ("runs", "scattered"):
+            tables = np.zeros((slots, pages), np.int32)
+            for s in range(slots):
+                ids = (1 + groups[spans[s]:spans[s + 1], None] * PAGE_RUN
+                       + np.arange(PAGE_RUN)).reshape(-1)
+                if kind == "scattered":
+                    ids = rng.permutation(ids)
+                tables[s, :need[s]] = ids[:need[s]]
+            args = (ql, qr, pool, jnp.asarray(lens), jnp.asarray(tables))
+            chained(*args).block_until_ready()
+            best = float("inf")
+            for _ in range(3):
+                t0 = time.perf_counter()
+                chained(*args).block_until_ready()
+                best = min(best, (time.perf_counter() - t0) / reps)
+            cell[kind] = {"us": round(best * 1e6, 1),
+                          "ns_a_page": round(best * 1e9 / need.sum(), 1),
+                          "roofline_pct": round(100 * floor / best, 1)}
+        out[str(heads)] = cell
+    return out
 
 
 def hybrid_phase(device: dict, block: int = SERVING["kv_block_size"]):

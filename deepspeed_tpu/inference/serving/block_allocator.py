@@ -25,13 +25,26 @@ Invariants (``assert_consistent`` checks them, tests fuzz them):
   * block 0 is RESERVED (the null block): padded block-table entries and
     inactive decode slots point at it so the kernel's index_map always
     lands on valid memory; it is never handed out and never freed.
-  * every other block is, at all times, exactly one of: on the free
-    list, parked in the cached-LRU (refcount 0, hash-registered), or
+  * every other block is, at all times, exactly one of: among the free
+    blocks, parked in the cached-LRU (refcount 0, hash-registered), or
     referenced by >= 1 sequences (refcount > 1 through :meth:`fork`'s
     tail sharing or prefix-cache hits).
   * ``free``/``allocate`` raise :class:`BlockPoolError` on double-free,
     unknown sequence ids, and exhaustion — a serving scheduler bug
     surfaces as a loud error, not a silently corrupted cache.
+
+Runs (docs/serving.md "Runs"): a kernel that walks a narrow pool pays a
+descriptor a page, so blocks are handed out such that entries ``j *
+PAGE_RUN .. (j + 1) * PAGE_RUN - 1`` of a sequence's table are
+CONSECUTIVE pool blocks wherever the pool allows, and the kernel fetches
+such a run with one DMA.  The pool, block 0 aside, is groups of
+``PAGE_RUN`` consecutive ids; :meth:`PagedBlockAllocator._take_block`
+prefers the id after a table's last block, then a block of an idle group
+(none of whose blocks is referenced), then what it always did
+(:meth:`PagedBlockAllocator._pop_block`).  Capacity reads as before: a
+group's unused tail is free capacity that the fallback hands to anyone,
+and a registered block is still evicted only when no unregistered one is
+free.
 
 Layer KINDS (docs/serving.md "The cache manager's kinds of state"): a
 model whose layers do not all keep the same thing a token gives the
@@ -73,6 +86,7 @@ import hashlib
 from collections import OrderedDict
 from typing import Dict, List, Optional, Sequence, Tuple
 
+from ...ops.transformer.paged_decode_attention import PAGE_RUN
 from ...runtime.resilience.errors import ServingError
 from ...runtime.resilience.fault_injection import get_fault_injector
 
@@ -196,10 +210,20 @@ class PagedBlockAllocator:
         self.num_blocks = num_blocks
         self.block_size = block_size
         self.enable_prefix_cache = enable_prefix_cache
-        # LIFO free list: recently-freed blocks are re-handed first (their
-        # pool pages are the likeliest still warm in any cache hierarchy)
-        self._free: List[int] = list(range(num_blocks - 1, 0, -1))
+        # the raw free blocks, an ordered set (a dict's keys): LIFO at its
+        # end — recently-freed blocks are re-handed first — and removal
+        # by id in O(1), which taking the NEXT id of a run needs
+        self._free: Dict[int, None] = dict.fromkeys(
+            range(num_blocks - 1, 0, -1))
         self._ref = [0] * num_blocks
+        # runs (module docstring): referenced blocks a group, and the
+        # whole groups that have none — those without a registered block
+        # at the front (a fresh pool's in ascending order), those that
+        # hold cached content behind them, least recently idled first
+        self._group_live = [0] * (-(-(num_blocks - 1) // PAGE_RUN))
+        self._whole_groups = (num_blocks - 1) // PAGE_RUN
+        self._idle: "OrderedDict[int, None]" = OrderedDict.fromkeys(
+            range(self._whole_groups))
         self._tables: Dict[str, List[int]] = {}
         # prefix cache: chained content hash -> block id, and the reverse
         # map used to unregister on eviction/recycle
@@ -286,12 +310,13 @@ class PagedBlockAllocator:
         self._host = host_cache
         self._spill_fn = spill_fn
 
-    def _claim_host_hit(self, h: bytes) -> Optional[int]:
+    def _claim_host_hit(self, h: bytes, table: List[int]) -> Optional[int]:
         """Extend the hit walk into the host tier: claim the encoded
-        payload out of the host cache, claim a pool block for it, and
-        queue the promotion.  Returns the (pending) block id, or None
-        on a genuine miss / no pool capacity (the entry then stays
-        host-resident and warm — a miss, never an error)."""
+        payload out of the host cache, claim a pool block for it (the
+        next of ``table``), and queue the promotion.  Returns the
+        (pending) block id, or None on a genuine miss / no pool capacity
+        (the entry then stays host-resident and warm — a miss, never an
+        error)."""
         if not self.allow_claims:
             return None
         if self._host is None or not self._host.contains(h):
@@ -301,7 +326,7 @@ class PagedBlockAllocator:
         payload = self._host.claim(h)
         if payload is None:
             return None
-        b = self._pop_block()
+        b = self._take_block(table)
         self._ref[b] = 1
         self._block_hash[b] = h
         self._hash_to_block[h] = b
@@ -381,32 +406,91 @@ class PagedBlockAllocator:
         self._unregister(block)
         if job is not None and self._host is not None:
             self._host.release_claim(h, job.payload)
-        self._free.append(block)
+        self._free[block] = None
 
     # -- internal: free-list / LRU plumbing --------------------------------
     def _pop_block(self) -> int:
-        """Claim one block, always unregistered: the raw free list
-        first (never holds registered blocks — `_release_block` parks
+        """Claim one block, always unregistered, wherever it lies: the
+        raw free blocks first (never registered — `_release_block` parks
         those in the LRU), else evict the least-recently-used cached
-        block, dropping its registration — the pool page is about to
-        be overwritten."""
+        block.  What :meth:`_take_block` falls back to."""
         if self._free:
-            return self._free.pop()
+            return self._free.popitem()[0]
         if self._cached_lru:
-            b, _ = self._cached_lru.popitem(last=False)   # LRU end
-            h = self._block_hash[b]
-            if h is not None and self._spill_fn is not None:
-                # demotion instead of amnesia: hand the block's bytes
-                # to the engine's spill path (device gather -> wire
-                # codec -> host tier) while the pool content is still
-                # valid.  The callback handles its own faults — by
-                # contract it never raises, so a failed spill degrades
-                # to the plain eviction below.
-                self._spill_fn(b, h)
-            self._unregister(b)
-            self.evictions_total += 1
-            return b
+            return self._evict(next(iter(self._cached_lru)))   # LRU end
         raise BlockPoolError("pool exhausted")
+
+    def _evict(self, b: int) -> int:
+        """Take cached block ``b`` out of the LRU and drop its
+        registration — the pool page is about to be overwritten."""
+        del self._cached_lru[b]
+        h = self._block_hash[b]
+        if h is not None and self._spill_fn is not None:
+            # demotion instead of amnesia: hand the block's bytes
+            # to the engine's spill path (device gather -> wire
+            # codec -> host tier) while the pool content is still
+            # valid.  The callback handles its own faults — by
+            # contract it never raises, so a failed spill degrades
+            # to the plain eviction below.
+            self._spill_fn(b, h)
+        self._unregister(b)
+        self.evictions_total += 1
+        return b
+
+    # -- runs --------------------------------------------------------------
+    def _take_block(self, table: Sequence[int]) -> int:
+        """Claim the block that becomes entry ``k = len(table)`` of a
+        sequence's table, so that entries ``j * PAGE_RUN .. (j + 1) *
+        PAGE_RUN - 1`` are consecutive pool blocks wherever the pool
+        allows (the latent kernel fetches such a run with one DMA).  In
+        this order: the id after ``table[-1]`` when ``k`` is inside a
+        run and that id lies in the same group and has no reference;
+        else offset ``k % PAGE_RUN`` of the first idle group (one with no
+        registered block before one that holds cached content, least
+        recently idled first); else :meth:`_pop_block`.  A registered
+        block is taken (evicted) only when no unregistered one is free,
+        as :meth:`_pop_block` has it."""
+        k = len(table) % PAGE_RUN
+        after = table[-1] + 1 if k else NULL_BLOCK
+        idle = 1 + next(iter(self._idle)) * PAGE_RUN + k if self._idle \
+            else NULL_BLOCK
+        if after and (after - 1) % PAGE_RUN and self._takes(after):
+            b = after
+        elif idle and self._takes(idle):
+            b = idle
+        else:
+            b = self._pop_block()
+        self._enter(b)
+        return b
+
+    def _takes(self, b: int) -> bool:
+        """Take unreferenced block ``b`` out of where it waits, if that
+        evicts nothing while an unregistered block is free."""
+        if b in self._free:
+            del self._free[b]
+            return True
+        if not self._free and b in self._cached_lru:
+            self._evict(b)
+            return True
+        return False
+
+    def _enter(self, b: int) -> None:
+        """Block ``b`` got its first reference: its group is not idle."""
+        g = (b - 1) // PAGE_RUN
+        if self._group_live[g] == 0:
+            self._idle.pop(g, None)
+        self._group_live[g] += 1
+
+    def _leave(self, b: int) -> None:
+        """Block ``b`` lost its last reference (and is back among the
+        free or the cached): a whole group with none left is idle."""
+        g = (b - 1) // PAGE_RUN
+        self._group_live[g] -= 1
+        if self._group_live[g] == 0 and g < self._whole_groups:
+            first = 1 + g * PAGE_RUN
+            self._idle[g] = None
+            if not any(self._block_hash[first:first + PAGE_RUN]):
+                self._idle.move_to_end(g, last=False)
 
     def _unregister(self, block: int) -> None:
         h = self._block_hash[block]
@@ -424,13 +508,14 @@ class PagedBlockAllocator:
             # already be parked: it was refcounted until this call)
             self._cached_lru[block] = None
         else:
-            self._free.append(block)
+            self._free[block] = None
 
     def _claim_cached(self, block: int) -> None:
         """A cache hit revives a parked block: out of the LRU, refcount
         1, registration kept (it can be hit again while shared)."""
         del self._cached_lru[block]
         self._ref[block] = 1
+        self._enter(block)
 
     # -- the other layer kinds ---------------------------------------------
     @property
@@ -580,7 +665,7 @@ class PagedBlockAllocator:
                     # past the device index: the digest may live in the
                     # host tier — a hit there claims a pool block now
                     # and lands the bytes asynchronously (PromoteJob)
-                    b = self._claim_host_hit(h)
+                    b = self._claim_host_hit(h, blocks)
                     if b is None:
                         break
                     host_tokens += bs
@@ -596,7 +681,7 @@ class PagedBlockAllocator:
             self.hit_tokens_total += cached_tokens - host_tokens
             self.host_hit_tokens_total += host_tokens
         while len(blocks) < need:
-            b = self._pop_block()
+            b = self._take_block(blocks)
             self._ref[b] = 1
             blocks.append(b)
         self._tables[seq_id] = blocks
@@ -676,7 +761,7 @@ class PagedBlockAllocator:
             raise BlockPoolError(
                 f"pool exhausted growing {seq_id!r} "
                 f"({len(table)} blocks held)")
-        b = self._pop_block()
+        b = self._take_block(table)
         self._ref[b] = 1
         table.append(b)
         return b
@@ -729,6 +814,7 @@ class PagedBlockAllocator:
                     self._cancel_pending(b)
                 else:
                     self._release_block(b)
+                self._leave(b)
 
     def commit_cached(self, seq_id: str, token_ids: Sequence[int],
                       upto_tokens: int) -> int:
@@ -812,7 +898,7 @@ class PagedBlockAllocator:
             if not self.can_allocate(1):
                 raise BlockPoolError(
                     f"pool exhausted forking {src_id!r} -> {dst_id!r}")
-            fresh = self._pop_block()
+            fresh = self._take_block(shared)
             self._ref[fresh] = 1
         for b in shared:
             self._ref[b] += 1
@@ -832,8 +918,6 @@ class PagedBlockAllocator:
         BlockPoolError with the exact discrepancy — the tests' (and a
         draining server's) leak check."""
         free_set = set(self._free)
-        if len(free_set) != len(self._free):
-            raise BlockPoolError("free list contains duplicates")
         if NULL_BLOCK in free_set:
             raise BlockPoolError("null block 0 leaked onto the free list")
         cached_set = set(self._cached_lru)
@@ -865,6 +949,19 @@ class PagedBlockAllocator:
                     f"table references")
             if not (in_free or in_cache) and refs == 0:
                 raise BlockPoolError(f"block {b} leaked (no refs, not free)")
+        # runs: a group's count is its referenced blocks, and the idle
+        # groups are the whole groups that have none
+        live = [0] * len(self._group_live)
+        for b in held:
+            live[(b - 1) // PAGE_RUN] += 1
+        if live != self._group_live:
+            raise BlockPoolError(
+                "a group's live count disagrees with the tables")
+        if set(self._idle) != {g for g in range(self._whole_groups)
+                               if not live[g]}:
+            raise BlockPoolError(
+                "the idle groups are not the whole groups without a "
+                "referenced block")
         wheld = [b for t in self._wtables.values() for b in t
                  if b != NULL_BLOCK]
         if self.window_tokens and sorted(wheld + self._wfree) != list(

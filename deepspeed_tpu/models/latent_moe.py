@@ -186,7 +186,8 @@ class LatentMoELM(TransformerLM):
     ATTN_SUBLAYERS = 1
     #: what ``_apply_paged_mixed`` counts in the program, a dispatch (the
     #: serving engine carries them out on its one result array)
-    PAGED_COUNTERS = dropless.COUNTERS + ("latent_tokens_read",)
+    PAGED_COUNTERS = dropless.COUNTERS + (
+        "latent_tokens_read", "latent_pages_read", "latent_pages_in_runs")
 
     def __init__(self, config: LatentMoEConfig, constrain=None,
                  block_transform=None):
@@ -690,14 +691,27 @@ class LatentMoELM(TransformerLM):
             dec_logits = logits[0, :bsl]
         with jax.named_scope("pool_write"):
             # the live context the latent kernel walked, once a sublayer
-            read = (jnp.sum(jnp.where(act, lens + 1, 0))
-                    + jnp.where(chunk_len > 0, chunk_start + chunk_len, 0))
+            dec_read = jnp.where(act, lens + 1, 0)
+            chunk_read = jnp.where(chunk_len > 0, chunk_start + chunk_len, 0)
+            read = jnp.sum(dec_read) + chunk_read
+            # ... in pages, and those of them in runs of consecutive pool
+            # blocks, which the kernel fetches with one DMA a run: the
+            # flags it reads, made from the same tables and lengths
+            from ..ops.transformer.paged_decode_attention import (
+                PAGE_RUN, page_runs)
+            blk = cache["k"].shape[2]
+            pages = jnp.sum(-(-dec_read // blk)) + -(-chunk_read // blk)
+            in_runs = PAGE_RUN * (
+                jnp.sum(page_runs(tables, dec_read, blk))
+                + jnp.sum(page_runs(tables[chunk_slot][None],
+                                    chunk_read[None], blk)))
             new_lens = (lens + act.astype(lens.dtype)).at[chunk_slot].add(
                 chunk_len, mode="drop")
             extra = [jnp.asarray(v, jnp.int32)[None]
                      for v in self._extra_counters(step, state)]
             counters = jnp.concatenate(
-                [counts, (read * ns).astype(jnp.int32)[None], *extra])
+                [counts, *((n * ns).astype(jnp.int32)[None]
+                           for n in (read, pages, in_runs)), *extra])
         new_cache = dict(self._paged_pools(state, cache),
                          block_tables=tables, lens=new_lens,
                          counters=counters)

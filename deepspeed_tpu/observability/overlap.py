@@ -163,7 +163,8 @@ COUNTERS = ("dispatches", "decode_rows", "chunk_rows", "rows_computed",
             # on the dispatch's one result array; 0 for other blocks
             "moe_picks", "moe_picks_held", "moe_picks_zero",
             "moe_rows_max_expert", "moe_experts_touched",
-            "latent_tokens_read",
+            "latent_tokens_read", "latent_pages_read",
+            "latent_pages_in_runs",
             # rows x expert layers through an always-on shared expert
             # (models/sandwich_moe.py); 0 for blocks that have none
             "moe_rows_shared",

@@ -97,6 +97,12 @@ _VMEM_GROUP_BYTES = 12 << 20
 #: query rows one head window may stack block-diagonally: a bf16 operand
 #: tile has 16 sublanes, so an MXU pass costs the same for 1 row as for 16
 _WINDOW_ROWS = 16
+#: pages of a RUN: the cache manager (``inference/serving/
+#: block_allocator.py``) hands a sequence's pages ``k * PAGE_RUN .. (k + 1)
+#: * PAGE_RUN - 1`` out as consecutive pool blocks wherever it can, and a
+#: walk over a narrow pool (the latent kernel's 20 KB a page) fetches such
+#: a run with ONE DMA.  8 pages = 128 tokens = 160 KB of latent rows
+PAGE_RUN = 8
 
 
 def _head_pack(kv_heads: int, d_eff: int) -> int:
@@ -147,7 +153,7 @@ def _pages_per_program(pool, kv_heads: int, kv_bits: int, rows: int,
 
 
 def _page_group_dma(start, hbm, bufs, sem, bt_ref, row, total, group, half,
-                    *, block, pp, first=0):
+                    *, block, pp, first=0, span=None):
     """Start (or wait on) the DMAs of one live page group: for each page
     ``group * pp + j`` of slot ``row`` that holds keys below ``total``,
     the WHOLE pool block ``bt[row, page]`` — ``[block, Hkv * De]`` of k
@@ -157,7 +163,8 @@ def _page_group_dma(start, hbm, bufs, sem, bt_ref, row, total, group, half,
     alike; a wait needs the copy's shape and semaphore, not its source.
     With a WINDOW the walk has a ``first`` position as well: pages that
     end at or below it are dead too (their blocks may be another slot's
-    by now), and neither started nor waited on."""
+    by now), and neither started nor waited on.  ``span = (j0, n)``
+    narrows the loop to the group's pages ``j0 .. j0 + n - 1``."""
     def page(j, carry):
         bid = bt_ref[row, group * pp + j] if start else 0
         for op, (src, dst) in enumerate(zip(hbm, bufs)):
@@ -168,7 +175,108 @@ def _page_group_dma(start, hbm, bufs, sem, bt_ref, row, total, group, half,
     live = jnp.clip(-(-(total - group * pp * block) // block), 0, pp)
     dead = 0 if isinstance(first, int) and first == 0 else jnp.clip(
         (first - group * pp * block) // block, 0, pp)
+    if span is not None:
+        dead = jnp.maximum(dead, span[0])
+        live = jnp.minimum(live, span[0] + span[1])
     jax.lax.fori_loop(dead, live, page, 0)
+
+
+def _grouped_tables(block_tables, total, pp: int, block: int, run: int):
+    """``(tables padded to whole groups of pp pages, runs [B, groups * pp //
+    run])``: a group is ``pp // run`` RUNS of ``run`` pages, and ``runs`` is
+    1 where a run's pages are consecutive pool blocks and all hold keys
+    below the slot's ``total`` — such a run is one DMA.  (A run that is
+    dead or partly dead reads 0, so nothing past the length is fetched by
+    it.)"""
+    b, npages = block_tables.shape
+    ngroups = -(-npages // pp)
+    nruns = ngroups * pp // run
+    tables = jnp.pad(jnp.asarray(block_tables, jnp.int32),
+                     ((0, 0), (0, ngroups * pp - npages)))
+    t = tables.reshape(b, nruns, run)
+    consecutive = jnp.all(t[..., 1:] == t[..., :-1] + 1, axis=-1)
+    whole = ((jnp.arange(nruns, dtype=jnp.int32)[None] + 1) * (run * block)
+             <= total[:, None])
+    return tables, (consecutive & whole).astype(jnp.int32)
+
+
+def page_runs(block_tables, total, block: int):
+    """``[B, ceil(pages / PAGE_RUN)]`` int32: 1 where pages ``j * PAGE_RUN
+    .. (j + 1) * PAGE_RUN - 1`` of slot ``b``'s table are consecutive pool
+    blocks that all hold keys below ``total[b]`` — the runs a latent walk
+    fetches with one DMA each (what a program counts its run share
+    from)."""
+    return _grouped_tables(block_tables, total, PAGE_RUN, block, PAGE_RUN)[1]
+
+
+def _fetch_group(start, pool_hbm, buf, sem, bt_ref, run_ref, slot, total,
+                 group, half, *, block, pp, run):
+    """Start (or wait on) one page group of ``slot`` into buffer half
+    ``half``, run by run: ONE DMA of ``run`` consecutive pool blocks where
+    ``run_ref`` says so, else ``_page_group_dma``'s one DMA a live page of
+    that run.  The choice is made from the table and the length alone
+    (``_grouped_tables``), the same at start and at wait.  Runs past the
+    length are not looked at."""
+    nruns = pp // run
+
+    def one_run(r, carry):
+        whole = run_ref[slot, group * nruns + r] > 0
+
+        @pl.when(whole)
+        def _one():
+            bid = bt_ref[slot, group * pp + r * run] if start else 0
+            dst = buf.at[half] if nruns == 1 else \
+                buf.at[half, pl.ds(r * run, run)]
+            copy = pltpu.make_async_copy(pool_hbm.at[pl.ds(bid, run)], dst,
+                                         sem.at[half, 0])
+            copy.start() if start else copy.wait()
+
+        @pl.when(jnp.logical_not(whole))
+        def _pages():
+            _page_group_dma(start, (pool_hbm,), (buf,), sem, bt_ref, slot,
+                            total, group, half, block=block, pp=pp,
+                            span=None if nruns == 1 else (r * run, run))
+        return carry
+
+    if nruns == 1:
+        one_run(0, 0)
+    else:
+        live = jnp.clip(-(-(total - group * pp * block) // (run * block)),
+                        0, nruns)
+        jax.lax.fori_loop(0, live, one_run, 0)
+
+
+def _walk_step(i, g, nwalk, meta_ref, bt_ref, run_ref, pool_hbm, buf, sem, *,
+               block, pp, run):
+    """The double-buffered fetch of a live grid step ``(walker i, group
+    g)`` of ``nwalk`` walkers over a one-operand pool: cold start,
+    prefetch of the next live step's group (of this walker or the next
+    live one), wait for this step's.  Returns the buffer half that now
+    holds the group."""
+    live_groups = meta_ref[2, i]
+
+    def fetch(w, group, half, start):
+        _fetch_group(start, pool_hbm, buf, sem, bt_ref, run_ref,
+                     meta_ref[5, w], meta_ref[1, w], group, half,
+                     block=block, pp=pp, run=run)
+
+    step = meta_ref[3, i] + g
+    half = jax.lax.rem(step, 2)
+
+    @pl.when(step == 0)
+    def _cold_start():
+        fetch(i, g, half, start=True)
+
+    more = g + 1 < live_groups
+    w1 = jnp.where(more, i, meta_ref[4, i])
+    g1 = jnp.where(more, g + 1, 0)
+
+    @pl.when(w1 < nwalk)
+    def _prefetch_next():
+        fetch(w1, g1, 1 - half, start=True)
+
+    fetch(i, g, half, start=False)
+    return half
 
 
 def _unpack(x, kv_bits):
@@ -600,24 +708,34 @@ def paged_prefill_attention(q: jnp.ndarray, pool_k: jnp.ndarray,
 #: head (a decode slot is one position; a chunk walks tiles of this many
 #: rows, each tile only as far as its own last position sees)
 _MLA_TILE_ROWS = 1024
+#: parts of a page group a latent grid step may stop after: the step
+#: contracts the parts that hold live keys, not the whole group
+_MLA_PARTS = 4
 
 
 def latent_pool_lanes(latent: int, rope: int) -> int:
     """Lanes of one latent pool row ``[c | k_rope | 0 ..]``: the latent and
-    the rotary key side by side, padded to whole 128-lane tiles (a page is
-    ONE DMA: at 16 tokens a page the walk is bound by descriptors, not
-    bytes, so a second operand would cost as much again)."""
+    the rotary key side by side, padded to whole 128-lane tiles: ONE
+    operand, so that a page — 20 KB at 16 tokens, where a descriptor costs
+    more than the bytes — is one DMA and not two, and a run of
+    ``PAGE_RUN`` consecutive pool blocks, which the cache manager makes the
+    rule, is one DMA of 160 KB (``_fetch_group``)."""
     return -(-(latent + rope) // LANES) * LANES
 
 
-def _mla_kernel(meta_ref, bt_ref, coff_ref, q_ref, pool_hbm, o_ref, buf,
-                m_scr, l_scr, acc_scr, sem, *, sm_scale, block, pp, lat):
+def _mla_kernel(meta_ref, bt_ref, run_ref, coff_ref, q_ref, pool_hbm, o_ref,
+                buf, m_scr, l_scr, acc_scr, sem, *, sm_scale, block, pp, run,
+                parts, lat):
     """The page walk of :func:`_kernel` for one WALKER — a tile of query
     rows of one slot — over a latent pool: a page is ``[block, W]`` rows
     ``[c | k_rope | 0]``, key AND value of every head; all heads' rows of
     the tile are plain rows of one contraction, ``score = [q_lat | q_rope]
     . row``, ``o_lat = sum p c`` over the row's first ``lat`` lanes: one
-    page fetch serves both.
+    page fetch serves both.  A page group comes in RUNS of ``run`` pages
+    (:func:`_fetch_group`): one DMA where ``run_ref [slots, runs]`` says
+    the run's pool blocks are consecutive and live, one a page elsewhere.
+    A group is contracted in as many of its ``parts`` equal parts as hold
+    live keys.
 
     ``meta_ref [6, W]``: (base, total, live groups, live steps before,
     next live walker or W, the slot whose table this walker reads)."""
@@ -626,29 +744,10 @@ def _mla_kernel(meta_ref, bt_ref, coff_ref, q_ref, pool_hbm, o_ref, buf,
     keys = pp * block
     base, total, live_groups = meta_ref[0, i], meta_ref[1, i], meta_ref[2, i]
 
-    def fetch(w, group, half, start):
-        _page_group_dma(start, (pool_hbm,), (buf,), sem, bt_ref,
-                        meta_ref[5, w], meta_ref[1, w], group, half,
-                        block=block, pp=pp)
-
     @pl.when(g < live_groups)
     def _live():
-        step = meta_ref[3, i] + g
-        half = jax.lax.rem(step, 2)
-
-        @pl.when(step == 0)
-        def _cold_start():
-            fetch(i, g, half, start=True)
-
-        more = g + 1 < live_groups
-        w1 = jnp.where(more, i, meta_ref[4, i])
-        g1 = jnp.where(more, g + 1, 0)
-
-        @pl.when(w1 < nwalk)
-        def _prefetch_next():
-            fetch(w1, g1, 1 - half, start=True)
-
-        fetch(i, g, half, start=False)
+        half = _walk_step(i, g, nwalk, meta_ref, bt_ref, run_ref, pool_hbm,
+                          buf, sem, block=block, pp=pp, run=run)
 
         @pl.when(g == 0)
         def _init():
@@ -657,26 +756,47 @@ def _mla_kernel(meta_ref, bt_ref, coff_ref, q_ref, pool_hbm, o_ref, buf,
             acc_scr[...] = jnp.zeros_like(acc_scr)
 
         qpos = base + coff_ref[...]                            # [R, 1]
-        pos = g * keys + jax.lax.broadcasted_iota(jnp.int32, (1, keys), 1)
-        visible = (pos <= qpos) & (pos < total)                # [R, keys]
-        v_valid = g * keys + jax.lax.broadcasted_iota(
-            jnp.int32, (keys, 1), 0) < total                   # [keys, 1]
-        rows = buf[half].reshape(keys, buf.shape[-1])
-        s = jax.lax.dot_general(q_ref[...], rows, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        s = jnp.where(visible, s * sm_scale, MASK_VALUE)
-        m_prev = m_scr[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)
-        l_scr[...] = alpha * l_scr[...] + jnp.sum(p, axis=1, keepdims=True)
-        m_scr[...] = m_new
-        # stale or recycled rows past the length: zeroed, not down-weighted
-        c = rows[:, :lat]
-        c = jnp.where(v_valid, c, jnp.zeros_like(c))
-        acc_scr[...] = alpha * acc_scr[...] + jax.lax.dot_general(
-            p.astype(c.dtype), c, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+
+        def contract(npages):
+            """The group's first ``npages`` pages against the tile."""
+            n = npages * block
+            pos = g * keys + jax.lax.broadcasted_iota(jnp.int32, (1, n), 1)
+            visible = (pos <= qpos) & (pos < total)            # [R, n]
+            v_valid = g * keys + jax.lax.broadcasted_iota(
+                jnp.int32, (n, 1), 0) < total                  # [n, 1]
+            rows = buf[half, :npages].reshape(n, buf.shape[-1])
+            s = jax.lax.dot_general(q_ref[...], rows,
+                                    (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+            s = jnp.where(visible, s * sm_scale, MASK_VALUE)
+            m_prev = m_scr[...]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - m_new)
+            l_scr[...] = alpha * l_scr[...] + jnp.sum(p, axis=1,
+                                                      keepdims=True)
+            m_scr[...] = m_new
+            # stale or recycled rows past the length: zeroed, not
+            # down-weighted
+            c = rows[:, :lat]
+            c = jnp.where(v_valid, c, jnp.zeros_like(c))
+            acc_scr[...] = alpha * acc_scr[...] + jax.lax.dot_general(
+                p.astype(c.dtype), c, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+
+        # a walker's last group is as full as its length leaves it, and
+        # every key contracted costs the same, fetched or not: contract
+        # the live quarters of the group only (one copy of the body a
+        # part; keys past the length inside the last live part are
+        # masked and zeroed as before)
+        part = pp // parts
+        live_parts = -(-jnp.minimum(total - g * keys, keys) // (part * block))
+        if parts == 1:
+            contract(pp)
+        else:
+            for k in range(1, parts + 1):
+                pl.when(live_parts == k)(
+                    functools.partial(contract, k * part))
 
     @pl.when(g == ng - 1)
     def _out():
@@ -727,7 +847,9 @@ def _mla_paged_attention(q_lat, q_rope, pool, base, total, block_tables, *,
     """q_lat [B, C, H, R], q_rope [B, C, H, Dr] — C query positions a
     slot at absolute positions ``base[b] ..``; ``pool [num_blocks, block,
     W]`` with ``W >= R + Dr`` lanes a row, ``[c | k_rope | 0]``.  Returns
-    o_lat [B, C, H, R]."""
+    o_lat [B, C, H, R].  Whether a run of ``PAGE_RUN`` pages is fetched
+    with one DMA or page by page is read from the table and the lengths
+    (``_grouped_tables``); the result is the same either way."""
     b, c, h, lat = q_lat.shape
     rope = q_rope.shape[-1]
     if pool.ndim != 3 or pool.shape[2] < lat + rope:
@@ -750,9 +872,11 @@ def _mla_paged_attention(q_lat, q_rope, pool, base, total, block_tables, *,
     npages = block_tables.shape[1]
     pp = _mla_pages_per_program(block, lanes, lat, pool.dtype.itemsize, rows,
                                 npages, pages_per_program)
-    ngroups = -(-npages // pp)
+    run = math.gcd(pp, PAGE_RUN)
     base = jnp.asarray(base, jnp.int32).reshape(b)
     total = jnp.asarray(total, jnp.int32).reshape(b)
+    tables, runs = _grouped_tables(block_tables, total, pp, block, run)
+    ngroups = tables.shape[1] // pp
     meta = _walkers(base, total, ntile, tp, pp * block, ngroups)
     dtype = pool.dtype
     # the query laid out like a pool row: [q_lat | q_rope | 0]
@@ -767,9 +891,10 @@ def _mla_paged_attention(q_lat, q_rope, pool, base, total, block_tables, *,
 
     out = pl.pallas_call(
         functools.partial(_mla_kernel, sm_scale=sm_scale, block=block,
-                          pp=pp, lat=lat),
+                          pp=pp, run=run, parts=math.gcd(pp, _MLA_PARTS),
+                          lat=lat),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=3,
             grid=(nwalk, ngroups),
             in_specs=[pl.BlockSpec((rows, 1), lambda i, g, *_: (0, 0)),
                       qspec(lanes), pl.BlockSpec(memory_space=pl.ANY)],
@@ -787,7 +912,7 @@ def _mla_paged_attention(q_lat, q_rope, pool, base, total, block_tables, *,
             vmem_limit_bytes=_VMEM_LIMIT_BYTES),
         interpret=interpret,
         name="mla_paged_attention",
-    )(meta, jnp.asarray(block_tables, jnp.int32), coff, q, pool)
+    )(meta, tables, runs, coff, q, pool)
     return out.reshape(b, c, h, lat)
 
 

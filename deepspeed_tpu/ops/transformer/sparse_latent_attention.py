@@ -20,8 +20,9 @@ keeps the ``k`` largest, and attends to those tokens' latent rows only.
     start no DMA, so a short context does not pay for the table's width.
     A page group whose pool blocks are CONSECUTIVE — a document prefilled
     into a fresh pool, the shared prefix of every request that follows —
-    is ONE DMA instead of one a page (``_grouped_tables``): at 4 KB a
-    page the walk is bound by descriptors, not bytes.
+    is ONE DMA instead of one a page (``paged_decode_attention.py``'s
+    ``_grouped_tables`` / ``_fetch_group`` with the whole group as the
+    run): at 4 KB a page the walk is bound by descriptors, not bytes.
   * :func:`kth_largest` / :func:`select_positions` — plain XLA, exact:
     the ``k``-th largest score of a row by bisection over the float's
     bits (32 counting passes, no sort), then for decode rows the
@@ -56,8 +57,9 @@ from jax.experimental.pallas import tpu as pltpu
 
 from .. import resolve_interpret
 from .paged_decode_attention import (LANES, MASK_VALUE, _MLA_TILE_ROWS,
-                                     _VMEM_LIMIT_BYTES, _mla_pages_per_program,
-                                     _page_group_dma, _walkers)
+                                     _VMEM_LIMIT_BYTES, _grouped_tables,
+                                     _mla_pages_per_program, _walk_step,
+                                     _walkers)
 
 #: chunk positions one walker of the index kernel scores, and the keys
 #: (pages x block) one of its grid steps covers
@@ -82,74 +84,6 @@ def plane_width(npages: int, block: int,
     return -(-npages // pp) * pp * block
 
 
-def _grouped_tables(block_tables, total, pp: int, block: int):
-    """``(tables padded to whole groups of pp pages, runs [B, groups])``:
-    ``runs`` is 1 where a group's ``pp`` pages are consecutive pool blocks
-    and all hold keys below the slot's ``total`` — such a group is one
-    DMA."""
-    b, npages = block_tables.shape
-    ngroups = -(-npages // pp)
-    tables = jnp.pad(jnp.asarray(block_tables, jnp.int32),
-                     ((0, 0), (0, ngroups * pp - npages)))
-    t = tables.reshape(b, ngroups, pp)
-    consecutive = jnp.all(t[..., 1:] == t[..., :-1] + 1, axis=-1)
-    whole = ((jnp.arange(ngroups, dtype=jnp.int32)[None] + 1) * (pp * block)
-             <= total[:, None])
-    return tables, (consecutive & whole).astype(jnp.int32)
-
-
-def _fetch_group(start, pool_hbm, buf, sem, bt_ref, run_ref, slot, total,
-                 group, half, *, block, pp):
-    """Start (or wait on) one page group of ``slot`` into buffer half
-    ``half``: one DMA of ``pp`` consecutive pool blocks where ``run_ref``
-    says so, else ``_page_group_dma``'s one DMA a live page."""
-    run = run_ref[slot, group] > 0
-
-    @pl.when(run)
-    def _one():
-        bid = bt_ref[slot, group * pp] if start else 0
-        copy = pltpu.make_async_copy(pool_hbm.at[pl.ds(bid, pp)],
-                                     buf.at[half], sem.at[half, 0])
-        copy.start() if start else copy.wait()
-
-    @pl.when(jnp.logical_not(run))
-    def _pages():
-        _page_group_dma(start, (pool_hbm,), (buf,), sem, bt_ref, slot, total,
-                        group, half, block=block, pp=pp)
-
-
-def _walk_step(i, g, nwalk, meta_ref, bt_ref, run_ref, pool_hbm, buf, sem, *,
-               block, pp):
-    """The double-buffered fetch of a live grid step ``(walker i, group
-    g)`` of ``nwalk`` walkers: cold start, prefetch of the next live
-    step's group (of this walker or the next live one), wait for this
-    step's.  Returns the buffer half that now holds the group."""
-    live_groups = meta_ref[2, i]
-
-    def fetch(w, group, half, start):
-        _fetch_group(start, pool_hbm, buf, sem, bt_ref, run_ref,
-                     meta_ref[5, w], meta_ref[1, w], group, half,
-                     block=block, pp=pp)
-
-    step = meta_ref[3, i] + g
-    half = jax.lax.rem(step, 2)
-
-    @pl.when(step == 0)
-    def _cold_start():
-        fetch(i, g, half, start=True)
-
-    more = g + 1 < live_groups
-    w1 = jnp.where(more, i, meta_ref[4, i])
-    g1 = jnp.where(more, g + 1, 0)
-
-    @pl.when(w1 < nwalk)
-    def _prefetch_next():
-        fetch(w1, g1, 1 - half, start=True)
-
-    fetch(i, g, half, start=False)
-    return half
-
-
 # ---------------------------------------------------------------------------
 # the indexer's scores
 # ---------------------------------------------------------------------------
@@ -165,7 +99,7 @@ def _index_kernel(meta_ref, bt_ref, run_ref, q_ref, w_ref, pool_hbm, o_ref,
     @pl.when(g < meta_ref[2, i])
     def _live():
         half = _walk_step(i, g, nwalk, meta_ref, bt_ref, run_ref, pool_hbm,
-                          buf, sem, block=block, pp=pp)
+                          buf, sem, block=block, pp=pp, run=pp)
         keys = buf[half].reshape(pp * block, buf.shape[-1])
         dims = (((1,), (1,)), ((), ()))
         if tp == 1:
@@ -215,7 +149,7 @@ def dsa_index_scores(q_idx, w_idx, ipool, base, total, block_tables, *,
     nwalk = b * ntile
     base = jnp.asarray(base, jnp.int32).reshape(b)
     total = jnp.asarray(total, jnp.int32).reshape(b)
-    tables, runs = _grouped_tables(block_tables, total, pp, block)
+    tables, runs = _grouped_tables(block_tables, total, pp, block, pp)
     ngroups = runs.shape[1]
     meta = _walkers(base, total, ntile, tp, keys, ngroups)
     dtype = ipool.dtype
@@ -442,7 +376,7 @@ def _sparse_kernel(meta_ref, bt_ref, run_ref, q_ref, plane_ref, floor_ref,
     @pl.when(g < live_groups)
     def _live():
         half = _walk_step(i, g, nwalk, meta_ref, bt_ref, run_ref, pool_hbm,
-                          buf, sem, block=block, pp=pp)
+                          buf, sem, block=block, pp=pp, run=pp)
 
         @pl.when(g == 0)
         def _init():
@@ -525,7 +459,7 @@ def dsa_sparse_prefill_attention(q_lat, q_rope, pool, plane, floor, base,
             f"[{c}, >= {npages * block}], got {plane.shape}")
     base = jnp.asarray(base, jnp.int32).reshape(1)
     total = base + jnp.asarray(chunk_len, jnp.int32)
-    tables, runs = _grouped_tables(block_table[None], total, pp, block)
+    tables, runs = _grouped_tables(block_table[None], total, pp, block, pp)
     ngroups = runs.shape[1]
     meta = _walkers(base, total, ntile, tp, keys, ngroups)
     dtype = pool.dtype

@@ -165,6 +165,35 @@ def test_chunked_prefill_then_paged_decode_match_the_reference_logits():
     assert sum(rec["latent_tokens_read"] for rec in seen) > 3 * 58
 
 
+def test_the_program_counts_the_pages_it_walked_and_those_in_runs():
+    """A prompt of 70 tokens at 8 a page on a fresh pool: its first eight
+    pages are one run of consecutive blocks from the chunk that carries
+    the context past 64 tokens on, the ninth page opens the next; three
+    sublayers walk them.  Pages and tokens are counted from the same
+    lengths."""
+    model, params = build(experts_held=(0, 6))
+    srv = serving_engine(model, params)
+    prof = get_overlap_profiler()
+    prof.configure(enabled=True)
+    try:
+        req = srv.submit(np.arange(70) % 128, max_new_tokens=4)
+        table, seen = None, []
+        while srv.step():
+            seen.append(prof.last())
+            if req.req_id in srv.allocator._tables:
+                table = srv.allocator.block_table(req.req_id)
+    finally:
+        prof.configure(enabled=False)
+    assert table[:8] == list(range(1, 9)) and len(table) == 10
+    live = [rec for rec in seen if rec["latent_tokens_read"]]
+    for rec in live:
+        tokens = rec["latent_tokens_read"] // 3
+        assert rec["latent_pages_read"] == 3 * -(-tokens // 8)
+        assert rec["latent_pages_in_runs"] == 3 * 8 * (tokens >= 64)
+    assert [rec["latent_tokens_read"] // 3 for rec in live][:6] == [
+        16, 32, 48, 64, 70, 71]
+
+
 @pytest.mark.parametrize("block", ["sandwich", "shortcut"])
 def test_tokens_alternate_between_the_two_shapes_of_the_step(block):
     """Prompts of 1, chunk, chunk + 1 and 3 x chunk tokens arriving while
@@ -387,6 +416,80 @@ class TestLatentKernelAt128Heads:
         np.testing.assert_allclose(got[:valid], want[:valid], rtol=2e-4,
                                    atol=2e-5)
         assert bool(jnp.all(jnp.isfinite(got)))
+
+
+class TestLatentKernelFetchesRuns:
+    """The latent kernel (interpret mode) over tables whose aligned runs
+    of ``PAGE_RUN`` pages are consecutive pool blocks (one DMA a run),
+    are not (one a page), or are some of each, the last run of a slot
+    partly past its length — against ``mla_paged_reference``, with NaN in
+    every pool block the walk must not read (block 0, every block no
+    table names, every page past a slot's length: PR 6's pattern)."""
+    H, R, DR, LANES, BLOCK, PAGES = 64, 16, 8, 32, 4, 24
+    #: tokens a slot: two runs and 2.25 pages; dead; every page; one page
+    LENGTHS = (73, 0, 96, 3)
+
+    def case(self, kind, lengths):
+        from deepspeed_tpu.ops.transformer.paged_decode_attention import (
+            PAGE_RUN, page_runs)
+        rng = np.random.default_rng(3)
+        slots, nruns = len(lengths), self.PAGES // PAGE_RUN
+        nb = 1 + slots * self.PAGES
+        groups = rng.permutation(slots * nruns).reshape(slots, nruns)
+        tables = 1 + groups[..., None] * PAGE_RUN + np.arange(PAGE_RUN)
+        scattered = {"all_runs": np.zeros_like(groups, bool),
+                     "no_runs": np.ones_like(groups, bool),
+                     "mixed": np.broadcast_to(np.arange(nruns) % 2 == 1,
+                                              groups.shape)}[kind]
+        # a run read backwards holds no two consecutive blocks
+        tables = np.where(scattered[..., None], tables[..., ::-1], tables)
+        tables = tables.reshape(slots, self.PAGES).astype(np.int32)
+        pool = np.full((nb, self.BLOCK, self.LANES), np.nan, np.float32)
+        whole = 0
+        for s, n in enumerate(lengths):
+            live = tables[s, :-(-n // self.BLOCK)]
+            pool[live] = 0.0
+            pool[live, :, :self.R + self.DR] = rng.standard_normal(
+                (len(live), self.BLOCK, self.R + self.DR))
+            whole += int((~scattered[s, :n // (PAGE_RUN * self.BLOCK)]
+                          ).sum())
+        flags = page_runs(jnp.asarray(tables), jnp.asarray(lengths),
+                          self.BLOCK)
+        assert flags.shape == (slots, nruns) and int(flags.sum()) == whole
+        return jnp.asarray(pool), jnp.asarray(tables)
+
+    @pytest.mark.parametrize("kind", ["all_runs", "no_runs", "mixed"])
+    def test_decode_rows(self, kind):
+        lengths = jnp.asarray(self.LENGTHS, jnp.int32)
+        pool, tables = self.case(kind, self.LENGTHS)
+        keys = jax.random.split(jax.random.PRNGKey(5), 2)
+        ql = jax.random.normal(keys[0], (4, self.H, self.R)) * 0.3
+        qr = jax.random.normal(keys[1], (4, self.H, self.DR)) * 0.3
+        got = mla_paged_decode_attention(ql, qr, pool, lengths, tables,
+                                         0.2, interpret=True)
+        want = mla_paged_reference(ql[:, None], qr[:, None], pool,
+                                   lengths - 1, lengths, tables, 0.2)[:, 0]
+        assert bool(jnp.all(jnp.isfinite(got)))
+        np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-5)
+        assert not np.asarray(got[1]).any()
+
+    @pytest.mark.parametrize("kind", ["all_runs", "no_runs", "mixed"])
+    def test_a_chunk_in_two_tiles_that_ends_mid_run(self, kind):
+        """32 positions x 64 heads = two walkers of 1,024 rows: the first
+        stops inside a run the second reads whole."""
+        base, chunk, valid = 41, 32, 29                    # ends at 70
+        pool, tables = self.case(kind, (base + valid,))
+        keys = jax.random.split(jax.random.PRNGKey(6), 2)
+        ql = jax.random.normal(keys[0], (chunk, self.H, self.R)) * 0.3
+        qr = jax.random.normal(keys[1], (chunk, self.H, self.DR)) * 0.3
+        got = mla_paged_prefill_attention(ql, qr, pool, base, valid,
+                                          tables[0], 0.2, interpret=True)
+        want = mla_paged_reference(ql[None], qr[None], pool,
+                                   np.array([base]),
+                                   np.array([base + valid]), tables, 0.2)[0]
+        assert bool(jnp.all(jnp.isfinite(got)))
+        np.testing.assert_allclose(got[:valid], want[:valid], rtol=2e-4,
+                                   atol=2e-5)
 
 
 class TestRefusals:

@@ -496,6 +496,65 @@ def test_latent_kernel_and_grouped_product_compile_at_the_cells_shapes(
         assert "tpu_custom_call" in text
 
 
+def dma_shapes(fn, *args):
+    """``(the source shapes of a kernel's DMA starts, its waits)``."""
+    eqns = kernel_eqns(fn, *args)
+    shapes = []
+    for e in eqns:
+        if e.primitive.name == "dma_start":
+            src_index = jax.tree_util.tree_unflatten(e.params["tree"],
+                                                     e.invars)[1]
+            shapes.append(src_index[0].get_indexer_shape())
+    return sorted(shapes), sum(e.primitive.name == "dma_wait" for e in eqns)
+
+
+@pytest.mark.parametrize("chunk", [False, True], ids=["decode", "chunk"])
+def test_latent_kernel_fetches_a_run_with_one_dma(chunk):
+    """Read off the kernels' jaxprs (no clock): at each of its two sites
+    (cold start, prefetch) the latent kernel starts EITHER one copy of
+    ``PAGE_RUN`` pool blocks ``[8, 16, 640]`` OR one of a page a live
+    page of the run, chosen by the flag of the run; the indexer's and the
+    sparse chunk's walks, through the same ``_fetch_group``, take their
+    whole page group as the run."""
+    from deepspeed_tpu.ops.transformer.paged_decode_attention import (
+        PAGE_RUN, mla_paged_decode_attention, mla_paged_prefill_attention)
+    from deepspeed_tpu.ops.transformer.sparse_latent_attention import (
+        dsa_index_scores, dsa_sparse_prefill_attention)
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype)
+    i32, pool = jnp.int32, sds((4096, 16, 640))
+    if chunk:
+        starts, waits = dma_shapes(
+            lambda *a: mla_paged_prefill_attention(*a, 0.072,
+                                                   interpret=False),
+            sds((512, 128, 512)), sds((512, 128, 64)), pool, sds((), i32),
+            sds((), i32), sds((256,), i32))
+    else:
+        starts, waits = dma_shapes(
+            lambda *a: mla_paged_decode_attention(*a, 0.072,
+                                                  interpret=False),
+            sds((128, 128, 512)), sds((128, 128, 64)), pool,
+            sds((128,), i32), sds((128, 256), i32))
+    assert starts == sorted([(16, 640), (PAGE_RUN, 16, 640)] * 2)
+    assert waits == 2
+    # GLM's walks: 128 pages of indexer keys a group, 64 of latent rows
+    rows = 512 if chunk else 1
+    starts, _ = dma_shapes(
+        lambda *a: dsa_index_scores(*a, interpret=False),
+        sds((8, rows, 32, 128)), sds((8, rows, 32), jnp.float32),
+        sds((4096, 16, 128)), sds((8,), i32), sds((8,), i32),
+        sds((8, 512), i32))
+    assert starts == sorted([(16, 128), (128, 16, 128)] * 2)
+    starts, _ = dma_shapes(
+        lambda *a: dsa_sparse_prefill_attention(*a, 0.072,
+                                                interpret=False),
+        sds((512, 64, 512)), sds((512, 64, 64)), pool,
+        sds((512, 8192), jnp.float32), sds((512,), jnp.float32),
+        sds((), i32), sds((), i32), sds((512,), i32))
+    assert starts == sorted([(16, 640), (64, 16, 640)] * 2)
+
+
 def _parent_grouped_matmul(x, w, tile_expert, live_tiles):
     """``moe/dropless.py::grouped_matmul`` as it stood before it got a
     backward (PR 42's tree), verbatim: what the serving programs'
@@ -752,16 +811,49 @@ def test_latent_projections_read_their_weights_where_they_lie(
     assert not relaid, relaid
 
 
-#: sha256 of the paged kernel's program at Pythia's cell shapes (24 decode
-#: slots; a chunk of 256 rows; 16 heads of 128; 128 pages of 16), stripped
-#: of metadata and names, as the commit BEFORE the kernel took a window
-#: (PR 47, ba84aa4) compiles it.  A PR that changes the kernel on purpose
-#: measures Pythia's two serving cells and records the new digests here.
+#: sha256 of the PLAIN paged kernel's program, stripped of metadata and
+#: names: at Pythia's cell shapes (24 decode slots; a chunk of 256 rows; 16
+#: heads of 128; 128 pages of 16) as the commit BEFORE the kernel took a
+#: window (PR 47, ba84aa4) compiles it, and at the hybrid cells' (phi-4:
+#: 64 slots, 40 / 20 heads of 64, a window of 512, 512 pages, a 512-row
+#: chunk in walkers of 128 rows; Granite: 64 slots, 32 / 8 heads of 64, 256
+#: pages, the same chunk) as the commit before the LATENT kernel learned to
+#: fetch runs (PR 54, 929a1f6) compiles it: the three cells that run this
+#: kernel are that PR's control.  A PR that changes the kernel on purpose
+#: measures those cells and records the new digests here.
 PAGED_PROGRAM_SHA256 = {
     "decode": ("aa64db95e72bccaba2c36e902c2a22b5"
                "ea8a2eb7a83161d8d0e37fbcbb5124b1"),
     "prefill": ("658c953f10c3026e5e1c55470ecae111"
-                "09585a8d288328599f2a2fe6ccc8fb4d")}
+                "09585a8d288328599f2a2fe6ccc8fb4d"),
+    "phi4_window_decode": ("d7ac82c5448bdcaa272f81b003b454a5"
+                           "1cf38825257a5b373cdb0909a945d2a2"),
+    "phi4_window_prefill": ("37b6b69e50648f53a80199d37f9a0aa4"
+                            "51e2828d1ec83573c020d0be7265a02e"),
+    "granite_decode": ("5994823fc31c222fe799b983fd2e77d0"
+                       "c64ea270f9aa14a065718afee1a5fb8d"),
+    "granite_prefill": ("14adfb634c5d9c770f41b376a43de944"
+                        "71431fbd8f70c88d66502560bcf68de5")}
+
+
+def paged_program_case(sds, lane):
+    """(fn, abstract args) of the paged kernel's call ``lane`` of
+    ``PAGED_PROGRAM_SHA256``."""
+    if lane == "decode":
+        return paged_decode_case(sds, 24, 128, 16, 16, 128, 0, 16)
+    if lane == "prefill":
+        return paged_prefill_case(sds, 128, 16, 128, 0, 16)
+    h, hkv, pages, kw = {"phi4": (40, 20, 512, {"window": 512}),
+                         "granite": (32, 8, 256, {})}[lane.split("_")[0]]
+    if lane.endswith("decode"):
+        return paged_decode_case(sds, 64, pages, h, hkv, 64, 0, 16, **kw)
+    pool, scalar = sds((64, 16, hkv * 64), jnp.bfloat16), sds((), jnp.int32)
+
+    def fn(q, pk, pv, base, n, table):
+        return paged_prefill_attention(q, pk, pv, base, n, table,
+                                       interpret=False, tile_rows=128, **kw)
+    return fn, (sds((512, h, 64), jnp.bfloat16), pool, pool, scalar, scalar,
+                sds((pages,), jnp.int32))
 
 
 @pytest.mark.parametrize("lane", list(PAGED_PROGRAM_SHA256))
@@ -770,15 +862,15 @@ def test_paged_kernel_without_a_window_is_the_program_it_was(v5e_devices,
     """The window (a static operand of the kernel, two more rows of its
     scalar prefetch, a first page in its DMA loop) costs a call that has
     none nothing: the same operands and the same instructions, kernel
-    body included, as before the kernel could take one."""
+    body included, as before the kernel could take one.  Nor does what
+    the latent kernel shares with it (``_page_group_dma``'s span of a
+    run) reach the plain kernel's program, with a window or without, at
+    any of the cells that run it."""
     import hashlib
-    sds = one_chip(v5e_devices)
-    fn, args = (paged_decode_case(sds, 24, 128, 16, 16, 128, 0, 16)
-                if lane == "decode" else
-                paged_prefill_case(sds, 128, 16, 128, 0, 16))
 
     def program(*a):            # one name, one text
         return fn(*a)
+    fn, args = paged_program_case(one_chip(v5e_devices), lane)
     text = stripped(compile_for_tpu(program, *args))
     assert hashlib.sha256(text.encode()).hexdigest() == \
         PAGED_PROGRAM_SHA256[lane]
