@@ -50,6 +50,14 @@ weights from a seed:
          at its widths and a shorter pattern served through
          ``serving_engine()`` against its float32 reference.
 
+  kda    (one chip) both lanes of the gated delta rule
+         (``ops/transformer/kda_scan.py``) at ``kimi-linear-48b-a3b``'s
+         widths (32 heads x 128 x 128) against the loop over rows: the
+         blocked form over a ragged chunk under a gate strong enough that
+         ``1 / exp(G)`` over a block would overflow, the decode kernel in
+         place at the middle layer's rows of three layers' states.
+         ``python chip_smoke.py 1 kda`` runs this phase alone.
+
 It fails — non-zero exit, no result line — when JAX shows anything but
 CHIPS TPU devices; it never adapts downward, and nothing on the path is
 caught.  Compile seconds are reported apart from run seconds, so a
@@ -830,6 +838,75 @@ def ssd_hybrid_phase(device: dict):
             "served_logit_gap_worst": round(gap, 4)}
 
 
+def kda_phase(device: dict):
+    """The gated delta rule's two lanes at their cell's widths: see the
+    module docstring."""
+    import jax
+    import jax.numpy as jnp
+    from deepspeed_tpu.ops.transformer import kda_scan
+
+    rng = np.random.default_rng(SEED + 9)
+    heads, hd, chunk, slots = 32, 128, 512, 64
+
+    def normal(*shape):
+        return jnp.asarray(rng.standard_normal(shape), jnp.float32)
+
+    def unit(a):
+        return a / jnp.linalg.norm(a, axis=-1, keepdims=True)
+    q, k = unit(normal(chunk, heads, hd)) / hd ** 0.5, unit(
+        normal(chunk, heads, hd))
+    v = normal(chunk, heads, hd)
+    beta = jax.nn.sigmoid(normal(chunk, heads))
+    s0 = normal(heads, hd, hd)
+
+    def rel(got, want):
+        return float(jnp.linalg.norm(got - want) / jnp.linalg.norm(want))
+    # the chunk lane: 512 rows (a ragged 300 valid), from a given state,
+    # its output's products on bfloat16 inputs; a mild gate and one whose
+    # running sum passes -190 inside a block
+    chunk_err = 0.0
+    for strong in (0.05, 6.0):
+        g = -strong * jnp.asarray(rng.uniform(size=(chunk, heads, hd)),
+                                  jnp.float32)
+        o, s1 = jax.jit(lambda *a: kda_scan.kda_chunk_scan(
+            *a, product_dtype=jnp.bfloat16))(q, k, v, g, beta, s0, 300)
+        want_o, want_s = jax.jit(kda_scan.kda_scan_reference)(
+            q, k, v, g, beta, s0, 300)
+        check(bool(jnp.all(jnp.isfinite(o[:300]))), "kda: blocked form not "
+              f"finite at gate {strong}")
+        chunk_err = max(chunk_err, rel(o[:300], want_o[:300]),
+                        rel(s1, want_s))
+    check(chunk_err < 1e-2, f"kda: blocked form off the loop by {chunk_err}")
+    # the decode lane: the kernel compiled, over a buffer of three layers'
+    # states at the middle layer's first row (a traced scalar), every
+    # other slot idle; the other layers' rows come back as they were
+    g = -0.05 * jnp.asarray(rng.uniform(size=(slots, heads, hd)), jnp.float32)
+    states = normal(3 * slots, heads, hd, hd)
+    active = jnp.arange(slots) % 2 == 0
+    mine = slice(slots, 2 * slots)
+    want = jax.jit(jax.vmap(
+        lambda *r: kda_scan.kda_scan_reference(*(t[None] for t in r[:5]),
+                                               r[5])))(
+        q[:slots], k[:slots], v[:slots], g, beta[:slots], states[mine])
+    before = np.asarray(states)
+    o, new = jax.jit(kda_scan.kda_decode_update, donate_argnums=5)(
+        q[:slots], k[:slots], v[:slots], g, beta[:slots], states, active,
+        jnp.int32(slots))
+    decode_err = max(
+        float(jnp.max(jnp.abs(new[mine][::2] - want[1][::2]))),
+        float(jnp.max(jnp.abs(o[::2] - want[0][::2, 0]))),
+        float(np.max(np.abs(np.asarray(new[mine][1::2])
+                            - before[mine][1::2]))),
+        float(np.max(np.abs(np.asarray(new[:slots]) - before[:slots]))),
+        float(np.max(np.abs(np.asarray(new[2 * slots:])
+                            - before[2 * slots:]))))
+    check(decode_err < 1e-4, f"kda: decode update off the loop by "
+          f"{decode_err}")
+    return {"phase": "kda", **device,
+            "chunk_scan_rel_err": float(chunk_err),
+            "decode_update_max_abs_err": float(decode_err)}
+
+
 def _shared_expert_layer_error(rows: int = 640) -> float:
     """One expert layer of the sandwich block at its published widths in
     bfloat16 — sigmoid top-8 gate over 256 outputs, renormalised and scaled
@@ -869,6 +946,10 @@ def main(argv) -> int:
     chips = int(argv[1]) if len(argv) > 1 else 1
     device = require_tpu(chips)
     log = CompileLog()
+    if argv[2:] == ["kda"]:            # that one-chip phase alone
+        print(json.dumps(kda_phase(device)), flush=True)
+        print(json.dumps({"ok": True, "device": device}), flush=True)
+        return 0
 
     from deepspeed_tpu.models import gpt2_config
     model_config = gpt2_config("350m", max_seq_len=SEQ_LEN, remat="full",
@@ -884,6 +965,7 @@ def main(argv) -> int:
         print(json.dumps(latent_phase(device)), flush=True)
         print(json.dumps(hybrid_phase(device)), flush=True)
         print(json.dumps(ssd_hybrid_phase(device)), flush=True)
+        print(json.dumps(kda_phase(device)), flush=True)
     print(json.dumps({"phase": "total", **device,
                       "wall_s": round(time.perf_counter() - t0, 1),
                       **log.since((0, 0, 0.0, 0.0))}), flush=True)

@@ -132,18 +132,14 @@ class HybridStep(NamedTuple):
     chunk: int                 # C (static; 0 = the decode-only shape)
 
 
-class PerSlotStateLM(TransformerLM):
-    """What the blocks that keep recurrent state a SLOT share (this
-    file's, and ``models/ssd_hybrid.py``'s): the refusals and their
-    sentences, the gated MLP, the dense attention of ``generate()``, the
-    scatter of a step's new rows into a paged pool, the convolution
-    tails' life through the mixed step, and ``apply``.  A subclass brings
-    its pattern, its mixers, ``init`` / ``_forward`` / ``init_cache`` and
-    the mixed step.  Its ``init()`` is ``init_resident`` plus, for every
-    part of ``PARTS`` (stack name -> what an element is made from), the
-    stack of ``init_pair(PARTS[part], key)`` over ``pair_keys(rng)[part]``:
-    whoever fills a tree an element at a time (the benchmark, in the
-    served type) goes through those three."""
+class PerSlotState:
+    """What ANY block that keeps recurrent state a SLOT takes part in,
+    whatever else it is (a mixin beside a ``TransformerLM``: this file's
+    block, ``models/ssd_hybrid.py``'s, and ``models/kda_latent_moe.py``'s,
+    which is a latent block besides): the refusals and their sentences,
+    the convolution tails' life through the mixed step, the scatter of a
+    step's new rows into a paged pool, and the rows that yield a
+    token."""
 
     #: why a quantized pool is refused (the block's own reason)
     KV_BITS_REFUSAL = ""
@@ -191,65 +187,6 @@ class PerSlotStateLM(TransformerLM):
             return ("int8 weight-only serving (quant.enabled): the block's "
                     "scans do not dequantize a layer at a time")
         return None
-
-    # -- init --------------------------------------------------------------
-    def _norm_init(self, dim: Optional[int] = None):
-        c = self.config
-        init = (L.layernorm_init if c.norm_type == "layernorm"
-                else L.rmsnorm_init)
-        return init(None, dim or c.d_model, c.param_dtype)
-
-    def _mlp_init(self, k) -> Dict:
-        c, dt = self.config, self.config.param_dtype
-        k1, k2 = jax.random.split(k)
-        return {"gate_up": L.dense_init(k1, c.d_model, 2 * c.ff_dim, False,
-                                        0.02, dt),
-                "down": L.dense_init(k2, c.ff_dim, c.d_model, False, 0.02,
-                                     dt)}
-
-    def _shell_init(self, k, mixer: Dict) -> Dict:
-        return {"ln1": self._norm_init(), "mixer": mixer,
-                "ln2": self._norm_init(), "mlp": self._mlp_init(k)}
-
-    def partition_specs(self, params=None) -> Dict:
-        """Everything replicated: the block serves on one chip."""
-        if params is None:
-            params = jax.eval_shape(lambda: self.init(jax.random.PRNGKey(0)))
-        return jax.tree_util.tree_map(lambda a: P(*([None] * a.ndim)),
-                                      params)
-
-    # -- what every path shares --------------------------------------------
-    def _glu_mlp(self, p, x):
-        with jax.named_scope("mlp"):
-            g, u = jnp.split(L.dense_apply(p["gate_up"], x), 2, axis=-1)
-            return L.dense_apply(p["down"], jax.nn.silu(g) * u)
-
-    def _attend_dense(self, q, k, v, q_pos, window: Optional[int]):
-        """q ``[B, Tq, H, hd]`` at positions ``q_pos [Tq]`` against k, v
-        ``[B, Tk, Hkv, hd]`` at positions ``0 .. Tk - 1``."""
-        k_pos = jnp.arange(k.shape[1])
-        seen = k_pos[None, :] <= q_pos[:, None]
-        if window is not None:
-            seen = seen & (k_pos[None, :] > q_pos[:, None] - window)
-        with jax.named_scope("attn_kernel"):
-            return L.gqa_attention(q, k, v, causal=False, scale=self._sm_scale,
-                                   mask=seen[None, None, None])
-
-    def hidden_states_and_aux(self, params, input_ids, token_type_ids=None):
-        x, _ = self._forward(params, self._embed_tokens(params, input_ids))
-        return (self._norm_fn("head")(params["ln_f"], x),
-                jnp.zeros((), jnp.float32))
-
-    def apply(self, params, input_ids, cache=None, positions=None,
-              token_type_ids=None):
-        if cache is None:
-            x, _ = self.hidden_states_and_aux(params, input_ids)
-            return self._project(params, x)
-        x, cache = self._forward(
-            params, self._embed_tokens(params, input_ids), cache)
-        return (self._project(params,
-                              self._norm_fn("head")(params["ln_f"], x)),
-                cache)
 
     # -- the mixed step's shared pieces ------------------------------------
     @staticmethod
@@ -320,6 +257,78 @@ class PerSlotStateLM(TransformerLM):
         last = jax.lax.dynamic_slice_in_dim(
             a, st.slots + jnp.maximum(st.chunk_len - 1, 0), 1, axis=0)
         return jnp.concatenate([a[:st.slots], last])
+
+
+class PerSlotStateLM(PerSlotState, TransformerLM):
+    """What the blocks that are NOTHING BUT layers with recurrent state a
+    slot and plain attention share (this file's, and
+    ``models/ssd_hybrid.py``'s) beyond :class:`PerSlotState`: the gated
+    MLP, the dense attention of ``generate()``, and ``apply``.  A subclass brings
+    its pattern, its mixers, ``init`` / ``_forward`` / ``init_cache`` and
+    the mixed step.  Its ``init()`` is ``init_resident`` plus, for every
+    part of ``PARTS`` (stack name -> what an element is made from), the
+    stack of ``init_pair(PARTS[part], key)`` over ``pair_keys(rng)[part]``:
+    whoever fills a tree an element at a time (the benchmark, in the
+    served type) goes through those three."""
+
+    # -- init --------------------------------------------------------------
+    def _norm_init(self, dim: Optional[int] = None):
+        c = self.config
+        init = (L.layernorm_init if c.norm_type == "layernorm"
+                else L.rmsnorm_init)
+        return init(None, dim or c.d_model, c.param_dtype)
+
+    def _mlp_init(self, k) -> Dict:
+        c, dt = self.config, self.config.param_dtype
+        k1, k2 = jax.random.split(k)
+        return {"gate_up": L.dense_init(k1, c.d_model, 2 * c.ff_dim, False,
+                                        0.02, dt),
+                "down": L.dense_init(k2, c.ff_dim, c.d_model, False, 0.02,
+                                     dt)}
+
+    def _shell_init(self, k, mixer: Dict) -> Dict:
+        return {"ln1": self._norm_init(), "mixer": mixer,
+                "ln2": self._norm_init(), "mlp": self._mlp_init(k)}
+
+    def partition_specs(self, params=None) -> Dict:
+        """Everything replicated: the block serves on one chip."""
+        if params is None:
+            params = jax.eval_shape(lambda: self.init(jax.random.PRNGKey(0)))
+        return jax.tree_util.tree_map(lambda a: P(*([None] * a.ndim)),
+                                      params)
+
+    # -- what every path shares --------------------------------------------
+    def _glu_mlp(self, p, x):
+        with jax.named_scope("mlp"):
+            g, u = jnp.split(L.dense_apply(p["gate_up"], x), 2, axis=-1)
+            return L.dense_apply(p["down"], jax.nn.silu(g) * u)
+
+    def _attend_dense(self, q, k, v, q_pos, window: Optional[int]):
+        """q ``[B, Tq, H, hd]`` at positions ``q_pos [Tq]`` against k, v
+        ``[B, Tk, Hkv, hd]`` at positions ``0 .. Tk - 1``."""
+        k_pos = jnp.arange(k.shape[1])
+        seen = k_pos[None, :] <= q_pos[:, None]
+        if window is not None:
+            seen = seen & (k_pos[None, :] > q_pos[:, None] - window)
+        with jax.named_scope("attn_kernel"):
+            return L.gqa_attention(q, k, v, causal=False, scale=self._sm_scale,
+                                   mask=seen[None, None, None])
+
+    def hidden_states_and_aux(self, params, input_ids, token_type_ids=None):
+        x, _ = self._forward(params, self._embed_tokens(params, input_ids))
+        return (self._norm_fn("head")(params["ln_f"], x),
+                jnp.zeros((), jnp.float32))
+
+    def apply(self, params, input_ids, cache=None, positions=None,
+              token_type_ids=None):
+        if cache is None:
+            x, _ = self.hidden_states_and_aux(params, input_ids)
+            return self._project(params, x)
+        x, cache = self._forward(
+            params, self._embed_tokens(params, input_ids), cache)
+        return (self._project(params,
+                              self._norm_fn("head")(params["ln_f"], x)),
+                cache)
 
 
 class HybridSSMLM(PerSlotStateLM):

@@ -131,12 +131,17 @@ class LatentMoEConfig(TransformerConfig):
     ``max_seq_len``) plus latent attention's and the routed experts'.
     The flags of the standard block that these blocks do not read are
     pinned by the builders in ``models/transformer.py``."""
-    q_lora_rank: int = 1536
+    #: None: ONE full query projection (``q_b [d_model, H * (nope + rope)]``
+    #: and no ``q_a`` / ``q_norm``)
+    q_lora_rank: Optional[int] = 1536
     kv_lora_rank: int = 512
     qk_nope_head_dim: int = 128
     qk_rope_head_dim: int = 64
     v_head_dim: int = 128
     rope_theta: float = 1e7
+    #: False: the ``qk_rope_head_dim`` lanes stay in the head and in the
+    #: pool row but are not rotated (a position-free latent attention)
+    mla_rotary: bool = True
     mla_scale_q_lora: bool = False
     mla_scale_kv_lora: bool = False
     expert_d_ff: int = 2048
@@ -165,8 +170,10 @@ class LatentMoEConfig(TransformerConfig):
         rq, rkv = self.q_lora_rank, self.kv_lora_rank
         dn, dr, dv = (self.qk_nope_head_dim, self.qk_rope_head_dim,
                       self.v_head_dim)
-        return (d * rq + rq + rq * h * (dn + dr) + d * (rkv + dr) + rkv
-                + rkv * h * (dn + dv) + h * dv * d)
+        q = (d * h * (dn + dr) if rq is None
+             else d * rq + rq + rq * h * (dn + dr))
+        return (q + d * (rkv + dr) + rkv + rkv * h * (dn + dv)
+                + h * dv * d)
 
     def moe_params(self) -> int:
         """Router (and its bias) and the held experts of one layer."""
@@ -197,13 +204,14 @@ class LatentMoELM(TransformerLM):
         if not 0 <= lo < hi <= c.n_routed_experts:
             raise ValueError(f"experts_held {c.experts_held} is not a "
                              f"range of the {c.n_routed_experts} experts")
-        self._cos, self._sin = L.rotary_freqs(
-            c.qk_rope_head_dim, c.qk_rope_head_dim, c.max_seq_len,
-            c.rope_theta)
+        if c.mla_rotary:
+            self._cos, self._sin = L.rotary_freqs(
+                c.qk_rope_head_dim, c.qk_rope_head_dim, c.max_seq_len,
+                c.rope_theta)
         self._sm_scale = 1.0 / math.sqrt(c.qk_nope_head_dim
                                          + c.qk_rope_head_dim)
         self._q_scale = (math.sqrt(c.d_model / c.q_lora_rank)
-                         if c.mla_scale_q_lora else 1.0)
+                         if c.mla_scale_q_lora and c.q_lora_rank else 1.0)
         self._kv_scale = (math.sqrt(c.d_model / c.kv_lora_rank)
                           if c.mla_scale_kv_lora else 1.0)
 
@@ -292,13 +300,15 @@ class LatentMoELM(TransformerLM):
         c, dt = self.config, self.config.param_dtype
         d, h = c.d_model, c.num_heads
         k1, k2, k3, k4, k5 = jax.random.split(k, 5)
-        return {
+        q_in = {} if c.q_lora_rank is None else {
             "q_a": L.dense_init(k1, d, c.q_lora_rank, False, 0.02, dt),
-            "q_norm": L.rmsnorm_init(None, c.q_lora_rank, dt),
+            "q_norm": L.rmsnorm_init(None, c.q_lora_rank, dt)}
+        return {
+            **q_in,
             "q_b": L.dense_init(
-                k2, c.q_lora_rank,
+                k2, c.q_lora_rank or d,
                 h * (c.qk_nope_head_dim + c.qk_rope_head_dim), False,
-                0.02, dt),
+                self._q_b_std(), dt),
             "kv_a": L.dense_init(k3, d, c.kv_lora_rank + c.qk_rope_head_dim,
                                  False, 0.02, dt),
             "kv_norm": L.rmsnorm_init(None, c.kv_lora_rank, dt),
@@ -308,6 +318,11 @@ class LatentMoELM(TransformerLM):
             "out": {"kernel": L.scaled_init(
                 k5, (h * c.v_head_dim, d), 0.02, self._out_depth(), dt)},
         }
+
+    def _q_b_std(self) -> float:
+        """The seeded std of the query up-projection (a block definition
+        may want another, for logits a check can see)."""
+        return 0.02
 
     def _ffn_init(self, k, width: Optional[int] = None):
         """A SwiGLU FFN of ``width`` (the dense FFNs' by default)."""
@@ -377,7 +392,10 @@ class LatentMoELM(TransformerLM):
     @scoped("attn_proj")
     def _q_latent(self, p, x):
         """x [B, T, h] -> the normalised (and scaled) query latent
-        [B, T, r_q]."""
+        [B, T, r_q]; ``x`` itself where there is no query latent
+        (``q_lora_rank`` None)."""
+        if self.config.q_lora_rank is None:
+            return x
         cq = self._norm_fn("attn_proj")(p["q_norm"],
                                         L.dense_apply(p["q_a"], x))
         if self._q_scale != 1.0:
@@ -387,8 +405,9 @@ class LatentMoELM(TransformerLM):
     @scoped("attn_proj")
     def _mla_project(self, p, x, positions, cq=None):
         """x [B, T, h] -> (q_nope [B,T,H,dn], q_rope [B,T,H,dr] rotated,
-        c [B,T,r_kv] normalised, k_rope [B,T,dr] rotated).  ``cq``: the
-        query latent, where the caller already has it."""
+        c [B,T,r_kv] normalised, k_rope [B,T,dr] rotated; neither rotated
+        where ``mla_rotary`` is off).  ``cq``: the query latent, where the
+        caller already has it."""
         c = self.config
         b, t, _ = x.shape
         norm = self._norm_fn("attn_proj")
@@ -403,6 +422,8 @@ class LatentMoELM(TransformerLM):
         if self._kv_scale != 1.0:
             lat = (lat * self._kv_scale).astype(x.dtype)
         k_rope = kv[..., None, c.kv_lora_rank:]          # one shared head
+        if not c.mla_rotary:
+            return q_nope, q_rope, lat, k_rope[:, :, 0]
         q_rope = L.apply_rotary(q_rope, self._cos, self._sin, positions,
                                 interleaved=False)
         k_rope = L.apply_rotary(k_rope, self._cos, self._sin, positions,
@@ -495,9 +516,13 @@ class LatentMoELM(TransformerLM):
         from ..ops.transformer.paged_decode_attention import (
             latent_pool_lanes)
         lanes = latent_pool_lanes(c.kv_lora_rank, c.qk_rope_head_dim)
-        return {"k": jnp.zeros((self.ATTN_SUBLAYERS * c.num_layers,
-                                num_blocks, block_size, lanes), dtype),
+        return {"k": jnp.zeros((self._pool_sublayers(), num_blocks,
+                                block_size, lanes), dtype),
                 "v": None}
+
+    def _pool_sublayers(self) -> int:
+        """Attention sublayers that write the pool: every layer's."""
+        return self.ATTN_SUBLAYERS * self.config.num_layers
 
     def _pool_rows(self, tables, lens, act, chunk_slot, chunk_start,
                    chunk_len, cw, blk, null):
@@ -604,6 +629,30 @@ class LatentMoELM(TransformerLM):
                 step.positions)
         return attend
 
+    @staticmethod
+    def _latent_walk(step: MixedStep, blk: int):
+        """What a dispatch's latent walks read, ONCE a sublayer — the
+        live context in tokens, in pages, and those of the pages that lie
+        in runs of consecutive pool blocks, which the kernel fetches with
+        one DMA a run (the flags it reads, made from the same tables and
+        lengths) — and every slot's length after the dispatch."""
+        from ..ops.transformer.paged_decode_attention import (
+            PAGE_RUN, page_runs)
+        tables, lens, act = step.tables, step.lens, step.act
+        chunk_slot, chunk_start, chunk_len = (
+            step.chunk_slot, step.chunk_start, step.chunk_len)
+        dec_read = jnp.where(act, lens + 1, 0)
+        chunk_read = jnp.where(chunk_len > 0, chunk_start + chunk_len, 0)
+        read = jnp.sum(dec_read) + chunk_read
+        pages = jnp.sum(-(-dec_read // blk)) + -(-chunk_read // blk)
+        in_runs = PAGE_RUN * (
+            jnp.sum(page_runs(tables, dec_read, blk))
+            + jnp.sum(page_runs(tables[chunk_slot][None],
+                                chunk_read[None], blk)))
+        new_lens = (lens + act.astype(lens.dtype)).at[chunk_slot].add(
+            chunk_len, mode="drop")
+        return read, pages, in_runs, new_lens
+
     def _apply_paged_mixed(self, params, cache, dec_tokens, dec_active,
                            chunk_ids, chunk_slot, chunk_start, chunk_len,
                            spec_tokens=None, spec_active=None, probe=False):
@@ -690,23 +739,8 @@ class LatentMoELM(TransformerLM):
                 chunk_logits = jnp.zeros((logits.shape[-1],), logits.dtype)
             dec_logits = logits[0, :bsl]
         with jax.named_scope("pool_write"):
-            # the live context the latent kernel walked, once a sublayer
-            dec_read = jnp.where(act, lens + 1, 0)
-            chunk_read = jnp.where(chunk_len > 0, chunk_start + chunk_len, 0)
-            read = jnp.sum(dec_read) + chunk_read
-            # ... in pages, and those of them in runs of consecutive pool
-            # blocks, which the kernel fetches with one DMA a run: the
-            # flags it reads, made from the same tables and lengths
-            from ..ops.transformer.paged_decode_attention import (
-                PAGE_RUN, page_runs)
-            blk = cache["k"].shape[2]
-            pages = jnp.sum(-(-dec_read // blk)) + -(-chunk_read // blk)
-            in_runs = PAGE_RUN * (
-                jnp.sum(page_runs(tables, dec_read, blk))
-                + jnp.sum(page_runs(tables[chunk_slot][None],
-                                    chunk_read[None], blk)))
-            new_lens = (lens + act.astype(lens.dtype)).at[chunk_slot].add(
-                chunk_len, mode="drop")
+            read, pages, in_runs, new_lens = self._latent_walk(
+                step, cache["k"].shape[2])
             extra = [jnp.asarray(v, jnp.int32)[None]
                      for v in self._extra_counters(step, state)]
             counters = jnp.concatenate(

@@ -459,6 +459,45 @@ def granite_hybrid_config(size: str = "h-micro", **kw) -> TransformerConfig:
         **GRANITE_HYBRID_SIZES[size], **kw})
 
 
+KIMI_LINEAR_SIZES = {
+    # https://huggingface.co/moonshotai/Kimi-Linear-48B-A3B-Instruct/blob/
+    # main/config.json (linear_attn_config: 1-based kda_layers /
+    # full_attn_layers; head_dim 128, 32 heads, short convolutions of 4)
+    "48b-a3b": dict(num_layers=27, first_k_dense=1, num_heads=32,
+                    d_model=2304, d_ff=9216, head_dim=192,
+                    vocab_size=163840, max_seq_len=1048576,
+                    q_lora_rank=None, kv_lora_rank=512,
+                    qk_nope_head_dim=128, qk_rope_head_dim=64,
+                    v_head_dim=128, rope_theta=1e4, mla_rotary=False,
+                    expert_d_ff=1024, n_routed_experts=256,
+                    n_shared_experts=1, moe_topk=8,
+                    routed_scaling_factor=2.446, norm_topk_prob=True,
+                    kda_heads=32, kda_head_dim=128, kda_conv=4,
+                    layer_types=tuple(
+                        "mla" if at in (4, 8, 12, 16, 20, 24, 27) else "kda"
+                        for at in range(1, 28))),
+}
+
+
+def kimi_linear_config(size: str = "48b-a3b", **kw) -> TransformerConfig:
+    """Kimi Linear's language model (``kimi_linear``): gated delta-rule
+    linear-attention layers (KDA) and position-free latent-attention
+    layers in the order ``layer_types`` lists, one leading dense layer
+    and then expert layers with a sigmoid top-8 router (selection bias)
+    beside a shared expert (``models/kda_latent_moe.py``).  ``size`` names
+    a published set of widths; another pattern (``layer_types`` with
+    ``num_layers`` its length), the vocabulary, the served positions and
+    ``experts_held`` (the chip's share of a deployment) come as
+    keywords."""
+    from .kda_latent_moe import KDALatentMoEConfig
+    if "layer_types" in kw:
+        kw["layer_types"] = tuple(kw["layer_types"])
+    return KDALatentMoEConfig(**{
+        "pos_embedding": "none", "norm_type": "rmsnorm", "gated_mlp": True,
+        "activation": "silu", "use_bias": False, "tie_embeddings": False,
+        "layernorm_eps": 1e-5, **KIMI_LINEAR_SIZES[size], **kw})
+
+
 def build_model(config: TransformerConfig, **kw) -> "TransformerLM":
     """The model that runs ``config``'s block: ``TransformerLM`` for the
     standard block, the config's own class (``config.model_class()``) for

@@ -127,15 +127,23 @@ PHASE_SPANS = tuple(f"serving/{p}" for p in PHASES)
 #: convolution; ``ssm_scan``: its recurrence (the chunk's scan kernel, the
 #: decode rows' one-step update); ``gmu``: a gated memory unit's two
 #: products and gate; ``state_io``: what moves a slot's per-sequence state
-#: out of and into the buffer the step carries.
+#: out of and into the buffer the step carries; ``kda_proj``: a gated
+#: delta-rule layer's projections, convolutions, gates, gated norm and
+#: output projection; ``kda_scan``: its recurrence, which names its two
+#: lanes one level further in (``SCOPE_LANES``).
 SCOPES = ("embed", "norm", "residual", "attn_proj", "attn_kernel",
           "pool_write", "mlp", "router", "expert_layout", "experts",
           "shared_expert", "head", "sample", "loss", "optimizer",
           "zero_comm", "indexer", "select", "attn_conv", "ssm_proj",
-          "ssm_scan", "gmu", "state_io")
+          "ssm_scan", "gmu", "state_io", "kda_proj", "kda_scan")
 #: an instruction under no declared scope / one whose key two loaded
 #: programs map to different scopes
 UNNAMED, AMBIGUOUS = "unnamed", "ambiguous"
+#: what a scope may name directly inside itself, where the step's two
+#: lanes do different work under one scope (``kda_scan/decode``: the rows'
+#: one-step update, ``kda_scan/chunk``: the chunk's blocked form); a
+#: reader that asks for lanes gets ``scope/lane``, every other the scope
+SCOPE_LANES = ("decode", "chunk")
 
 
 def scoped(name: str):
@@ -185,6 +193,11 @@ COUNTERS = ("dispatches", "decode_rows", "chunk_rows", "rows_computed",
             "kv_tokens_read_full", "kv_tokens_read_window",
             "ssm_chunk_rows", "ssm_decode_rows", "cross_rows_spared",
             "state_slots_started", "window_blocks_freed",
+            # a gated delta rule's state a slot beside a latent pool
+            # (models/kda_latent_moe.py), counted in the program: (row,
+            # layer) pairs through its decode update and its chunk's
+            # blocked form; 0 for other blocks
+            "kda_decode_rows", "kda_chunk_rows",
             # the dispatch in flight (docs/serving.md): dispatches that
             # were enqueued before their predecessor's result was read,
             # and rows whose result was ignored because their request
@@ -268,14 +281,20 @@ def _key(m: "re.Match") -> str:
     return f"{m.group(1)} = {_LAYOUT.sub('', m.group(2))}"
 
 
-def scope_of(op_name: str) -> Tuple[str, bool]:
+def scope_of(op_name: str, lanes: bool = False) -> Tuple[str, bool]:
     """An ``op_name`` path (``jit(step)/transpose(jvp(head))/dot_general``)
     -> ``(the innermost declared scope or UNNAMED, whether the operation
     is a backward pass's recomputation of its forward)``.  ``jvp(..)``,
     ``transpose(..)`` and the like are looked through; a jitted
-    function's own name is not a scope."""
+    function's own name is not a scope.  With ``lanes``, a scope whose
+    next part is one of ``SCOPE_LANES`` is given as ``scope/lane``."""
     parts = re.split(r"[/()]+", _JIT_NAME.sub("", op_name))
-    scope = next((p for p in reversed(parts) if p in SCOPES), UNNAMED)
+    at = next((i for i in reversed(range(len(parts)))
+               if parts[i] in SCOPES), None)
+    scope = UNNAMED if at is None else parts[at]
+    if lanes and at is not None and parts[at + 1:at + 2] and \
+            parts[at + 1] in SCOPE_LANES:
+        scope = f"{scope}/{parts[at + 1]}"
     return scope, "rematted_computation" in parts
 
 
@@ -290,7 +309,8 @@ def _operands(rest: str) -> List[str]:
     return []
 
 
-def _scopes_of_program(text: str) -> Dict[str, Tuple[str, bool]]:
+def _scopes_of_program(text: str, lanes: bool = False
+                       ) -> Dict[str, Tuple[str, bool]]:
     """One program's optimized HLO text -> ``{scope_key: (scope,
     recomputed)}`` for every instruction that can be a trace event (those
     inside a fused computation never are).  An instruction the model
@@ -325,19 +345,21 @@ def _scopes_of_program(text: str) -> Dict[str, Tuple[str, bool]]:
             op_name = _OP_NAME.search(ln)
             computation.append((
                 _key(m),
-                scope_of(op_name.group(1)) if op_name else (UNNAMED, False),
+                scope_of(op_name.group(1), lanes) if op_name
+                else (UNNAMED, False),
                 _operands(ln[m.end():])))
     close()
     return out
 
 
-def scope_table(texts: Iterable[str]) -> Dict[str, Tuple[str, bool]]:
+def scope_table(texts: Iterable[str], lanes: bool = False
+                ) -> Dict[str, Tuple[str, bool]]:
     """The programs' tables merged.  A program that declares no scope at
     all is none of the model's (a cast, a seed) and is left out; a key
     that two programs map to different scopes is ``AMBIGUOUS``."""
     table: Dict[str, Tuple[str, bool]] = {}
     for text in texts:
-        scopes = _scopes_of_program(text)
+        scopes = _scopes_of_program(text, lanes)
         if all(s == UNNAMED for s, _ in scopes.values()):
             continue
         for key, (scope, remat) in scopes.items():
@@ -781,13 +803,15 @@ class OverlapProfiler:
         self.iteration = -1
 
     # -- device scopes -----------------------------------------------------
-    def program_scopes(self) -> Dict[str, Tuple[str, bool]]:
+    def program_scopes(self, lanes: bool = False
+                       ) -> Dict[str, Tuple[str, bool]]:
         """``{scope_key(instruction): (scope, recomputed)}`` over the
         step programs this process has loaded, read from the optimized
         HLO the runtime's loaded executables carry: nothing is traced,
         lowered or compiled for it, and nothing is kept between calls.
         Join it to a trace with ``scope_key(event name)``; a key it lacks
-        is ``UNNAMED``."""
+        is ``UNNAMED``.  With ``lanes`` a scope that names its lanes
+        (``SCOPE_LANES``) reads ``scope/lane``."""
         import jax.extend
         texts = []
         for exe in jax.extend.backend.get_backend().live_executables():
@@ -795,7 +819,7 @@ class OverlapProfiler:
                 texts += [m.to_string() for m in exe.hlo_modules()]
             except jax.errors.JaxRuntimeError:   # it carries no text
                 continue
-        return scope_table(texts)
+        return scope_table(texts, lanes)
 
     # -- export (tracer event source) --------------------------------------
     def chrome_events(self, epoch_ns: int, rank: int

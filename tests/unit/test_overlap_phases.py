@@ -559,6 +559,30 @@ def test_scope_table_from_compiled_text(programs, key, want):
     assert overlap.scope_key("jit_step(1234)") is None
 
 
+@pytest.mark.parametrize("path,plain,laned", [
+    ("jit(step)/while/body/kda_scan/decode/pallas_call",
+     "kda_scan", "kda_scan/decode"),
+    ("jit(step)/kda_scan/chunk/jit(_einsum)/dot_general",
+     "kda_scan", "kda_scan/chunk"),
+    # a lane is named directly inside its scope, and only there
+    ("jit(step)/kda_scan/dot_general", "kda_scan", "kda_scan"),
+    ("jit(step)/kda_scan/while/body/chunk/mul", "kda_scan", "kda_scan"),
+    ("jit(step)/kda_scan/jit(f)/chunk/mul", "kda_scan", "kda_scan/chunk"),
+    ("jit(step)/decode/mlp/dot_general", "mlp", "mlp"),
+    ("jit(step)/chunk/add", "unnamed", "unnamed"),
+], ids=["decode", "chunk", "no_lane", "not_directly_inside", "through_jit",
+        "lane_outside", "lane_alone"])
+def test_a_scope_names_its_lanes_for_a_reader_that_asks(path, plain, laned):
+    assert overlap.scope_of(path) == (plain, False)
+    assert overlap.scope_of(path, lanes=True) == (laned, False)
+    text = _program(_DOT + " | " + path)
+    if plain != "unnamed":
+        assert overlap.scope_table([text])["%fusion.7 = bf16[8,8]"] == (
+            plain, False)
+        assert overlap.scope_table([text], lanes=True)[
+            "%fusion.7 = bf16[8,8]"] == (laned, False)
+
+
 def test_program_scopes_reads_the_loaded_step_program(srv):
     """On request, from the executables the process has loaded: the tiny
     engine's two step shapes name their layers, and the vocabulary is
@@ -570,4 +594,5 @@ def test_program_scopes_reads_the_loaded_step_program(srv):
     assert {"attn_proj", "attn_kernel", "mlp", "head"} <= found
     assert found <= set(overlap.SCOPES) | {overlap.UNNAMED,
                                            overlap.AMBIGUOUS}
-    assert len(overlap.SCOPES) <= 23        # PR 48: + four of a hybrid block
+    # PR 48: + four of a hybrid block; PR 56: + two of a delta-rule layer
+    assert len(overlap.SCOPES) <= 25

@@ -1708,6 +1708,120 @@ def test_zero3_gathers_a_layers_weights_not_the_activations(
     assert temp < 0.8 * ZERO3_CELL_TEMP_BYTES_PARENT
 
 
+# -- the linear / latent hybrid over experts (two kinds of state a slot) -----
+def kda_latent_model():
+    """The ``kimi_linear`` block at its cell's widths (32 heads x 128 x
+    128 of delta-rule state, latent attention at 32 heads, 16 of 256
+    experts of 1024) with a shorter pattern — a leading ``kda`` layer over
+    the dense FFN, then ``kda, mla`` twice over experts: a head and a scan
+    over the passes — and a small vocabulary."""
+    from deepspeed_tpu.models import build_model, kimi_linear_config
+    return build_model(kimi_linear_config(
+        "48b-a3b", num_layers=5,
+        layer_types=("kda", "kda", "mla", "kda", "mla"), vocab_size=1024,
+        max_seq_len=8192, experts_held=(0, 16)))
+
+
+#: the cell's engine: slots, pages a slot, blocks a layer
+KDA_LATENT_SIZE = (48, 512, 9216)
+
+
+def kda_latent_mixed_operands(devices, model, chunk):
+    slots, pages, nb = KDA_LATENT_SIZE
+    sds = one_chip(devices)
+    args, pools, params = mixed_step_operands(devices, model, nb, 16, 0,
+                                              slots, pages, chunk)
+    extra = jax.tree_util.tree_map(
+        lambda a: sds(a.shape, a.dtype), jax.eval_shape(
+            lambda: model.init_paged_extra(slots, 16, 0, jnp.bfloat16)))
+    args[1]["extra"] = extra
+    return args, dict(pools, **extra), params
+
+
+def build_kda_latent_mixed(devices, chunk):
+    model = kda_latent_model()
+    args, _, _ = kda_latent_mixed_operands(devices, model, chunk)
+    return jax.jit(model._apply_paged_mixed, donate_argnums=1).trace(
+        *args).lower(lowering_platforms=("tpu",)).compile()
+
+
+def test_kda_decode_update_compiles_at_the_cells_widths(v5e_devices,
+                                                         compiled_kernels):
+    """The delta rule's decode kernel over ``kimi-linear-48b-a3b``'s whole
+    state buffer (20 layers x 48 slots of 32 heads x 128 x 128 float32,
+    2.0 GB) at a traced first row: Mosaic takes its two products (a ``q
+    k^T`` one at the highest precision, a transposed-left bfloat16 one
+    over a contraction of 16) and every static, aligned load of a head's
+    piece of a row; the buffer is the call's operand AND its result, and
+    the program around it holds next to no temporary."""
+    import re
+    from deepspeed_tpu.ops.transformer.kda_scan import kda_decode_update
+    sds = one_chip(v5e_devices)
+    slots, h, d = KDA_LATENT_SIZE[0], 32, 128
+    f32 = jnp.float32
+    row = sds((slots, h, d), f32)
+    compiled = jax.jit(kda_decode_update, donate_argnums=5).trace(
+        row, row, row, row, sds((slots, h), f32),
+        sds((20 * slots, h, d, d), f32), sds((slots,), jnp.bool_),
+        sds((), jnp.int32)).lower(lowering_platforms=("tpu",)).compile()
+    text = compiled.as_text()
+    assert custom_calls(text) == 1
+    call, = [ln for ln in text.splitlines()
+             if re.search(r" custom-call\(.*\"tpu_custom_call\"", ln)]
+    assert re.match(r"\s*%kda_decode_update[.\d]* = \(f32\[960,32,128,128\]",
+                    call)
+    assert "output_to_operand_aliasing={{0}: (7, {})}" in call
+    # the program's second result (o is its first) is its sixth argument
+    assert re.search(r"input_output_alias=\{ \{1\}: \(5, \{\}, may-alias\) \}",
+                     text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 << 20
+
+
+@pytest.mark.parametrize("shape", list(HYBRID_CHUNK))
+def test_kda_latent_step_updates_pool_and_state_where_they_lie(
+        v5e_devices, compiled_kernels, step_programs, shape):
+    """The block's step at its cell's widths, both shapes: every traced
+    ``kda`` layer body holds ONE aliased decode-update call whose operand
+    and result are the whole ``[layers x 48, 32, 128, 128]`` buffer; every
+    ``mla`` body the latent kernel (and one more for a chunk); outside a
+    fusion nothing is a copy, a slice or an allocation shaped like the
+    state buffer, a layer's states or the latent pool.  (The tails — 94 MB
+    at 20 layers, a thirtieth of the state — are re-laid once on the way
+    into the mixed shape's scans and once on the way out.)"""
+    import re
+    chunk = HYBRID_CHUNK[shape]
+    model = kda_latent_model()
+    _, pools, _ = kda_latent_mixed_operands(v5e_devices, model, chunk)
+    text, temp_bytes = step_programs(f"kda-latent-{shape}")
+    kernels = [ln for ln in text.splitlines()
+               if re.search(r' custom-call\(.*"tpu_custom_call"', ln)]
+    updates = [ln for ln in kernels if "%kda_decode_update" in ln]
+    walks = [ln for ln in kernels if "%mla_paged" in ln]
+    # the plan: a head [kda], two passes of [kda, mla]: two kda bodies and
+    # one mla body are traced
+    assert len(updates) == 2 and len(walks) == (2 if chunk else 1)
+    dims = lambda shape: ",".join(map(str, shape))        # noqa: E731
+    buffer = dims(pools["state"].shape)
+    for ln in updates:
+        assert f"f32[{buffer}]" in ln.split(" custom-call(")[0]
+        assert "output_to_operand_aliasing={{0}: (7, {})}" in ln
+    slots = KDA_LATENT_SIZE[0]
+    whole = {buffer, dims((slots,) + pools["state"].shape[1:])}
+    k = pools["k"]
+    whole |= {dims(k.shape), dims(k.shape[1:]),
+              dims((k.shape[0] * k.shape[1],) + k.shape[2:])}
+    moved = []
+    for name, result, op, ln in unfused_instructions(text):
+        shapes = set(re.findall(r"\w+\[([\d,]+)\]", result))
+        if shapes & whole and (op in ("copy", "dynamic-slice")
+                               or "AllocateBuffer" in ln):
+            moved.append(ln[:160])
+    assert not moved, moved
+    assert re.search(r"input_output_alias=\{[^\n]*may-alias", text)
+    one_layer = slots * int(np.prod(pools["state"].shape[1:])) * 4
+    assert temp_bytes < (3 * one_layer if chunk else one_layer // 10)
+
+
 #: every step program a benchmark cell runs, at this module's sizes:
 #: name -> (what compiles it, given the described devices; the scopes it
 #: must show)
@@ -1741,6 +1855,12 @@ for _shape, _chunk in HYBRID_CHUNK.items():
     STEP_PROGRAMS[f"ssd-hybrid-{_shape}"] = (
         lambda dev, c=_chunk: build_ssd_hybrid_mixed(dev, c),
         _SERVE | {"ssm_proj", "ssm_scan", "state_io"})
+
+
+for _shape, _chunk in HYBRID_CHUNK.items():
+    STEP_PROGRAMS[f"kda-latent-{_shape}"] = (
+        lambda dev, c=_chunk: build_kda_latent_mixed(dev, c),
+        _EXPERTS | {"shared_expert", "kda_proj", "kda_scan", "state_io"})
 
 
 STEP_PROGRAMS["train-moe-1chip"] = (
@@ -1788,7 +1908,7 @@ def test_step_programs_carry_their_scopes(compiled_kernels, step_programs,
     of its own); each Pallas call resolves to ``attn_kernel``,
     ``experts``, (the score kernel of a sparse selection) ``indexer`` or
     (a state-space layer's scan, a Mamba-2 layer's decode update)
-    ``ssm_scan``;
+    ``ssm_scan`` or (a gated delta rule's decode update) ``kda_scan``;
     and at least nine in ten of the instructions that can
     be trace events and do work (fusions, convolutions, copies, custom
     calls) resolve to a declared scope."""
@@ -1807,6 +1927,7 @@ def test_step_programs_carry_their_scopes(compiled_kernels, step_programs,
     for ln in kernels:
         want = ("experts" if "%moe_grouped_matmul" in ln else
                 "indexer" if "%dsa_index_scores" in ln else
+                "kda_scan" if "%kda_decode_update" in ln else
                 "ssm_scan" if re.search(r"%ss[md]_(chunk_scan|decode_update)",
                                         ln) else "attn_kernel")
         assert table[scope_key(ln)][0] == want, ln[:200]
@@ -1817,6 +1938,32 @@ def test_step_programs_carry_their_scopes(compiled_kernels, step_programs,
     assert len(named) >= 0.9 * len(work), sorted(set(work) - set(named))
     if name.startswith("train"):
         assert any(remat for _, remat in table.values())   # remat="full"
+
+
+@pytest.mark.parametrize("shape", list(HYBRID_CHUNK))
+def test_a_delta_rule_layer_names_its_lanes(compiled_kernels, step_programs,
+                                            shape):
+    """What the benchmark's reader of the two lanes finds
+    (``program_scopes(lanes=True)``): every instruction of ``kda_scan``
+    that can be a trace event is of one lane by the program's own name,
+    the decode kernel of ``kda_scan/decode``, and the chunk lane is in
+    the mixed shape alone."""
+    import re
+    from deepspeed_tpu.observability.overlap import scope_key, scope_table
+    text, _ = step_programs(f"kda-latent-{shape}")
+    plain, laned = scope_table([text]), scope_table([text], lanes=True)
+    of_scan = {key: laned[key][0] for key, (scope, _) in plain.items()
+               if scope == "kda_scan"}
+    assert set(of_scan.values()) == {"kda_scan/decode"} | (
+        {"kda_scan/chunk"} if HYBRID_CHUNK[shape] else set()), of_scan
+    kernels = [ln for ln in text.splitlines()
+               if re.search(r' custom-call\(.*"tpu_custom_call"', ln)
+               and "%kda_decode_update" in ln]
+    assert kernels and all(
+        of_scan[scope_key(ln)] == "kda_scan/decode" for ln in kernels)
+    # a lane is a refinement: every other key reads as it did
+    assert all(laned[key] == plain[key] for key in plain
+               if key not in of_scan)
 
 
 def stripped(text):
