@@ -890,11 +890,12 @@ def kda_phase(device: dict):
         q[:slots], k[:slots], v[:slots], g, beta[:slots], states[mine])
     before = np.asarray(states)
     o, new = jax.jit(kda_scan.kda_decode_update, donate_argnums=5)(
-        q[:slots], k[:slots], v[:slots], g, beta[:slots], states, active,
-        jnp.int32(slots))
+        *(a[:slots].reshape(slots, -1) for a in (q, k, v, g)), beta[:slots],
+        states, active, jnp.int32(slots))
     decode_err = max(
         float(jnp.max(jnp.abs(new[mine][::2] - want[1][::2]))),
-        float(jnp.max(jnp.abs(o[::2] - want[0][::2, 0]))),
+        float(jnp.max(jnp.abs(o.reshape(slots, heads, hd)[::2]
+                              - want[0][::2, 0]))),
         float(np.max(np.abs(np.asarray(new[mine][1::2])
                             - before[mine][1::2]))),
         float(np.max(np.abs(np.asarray(new[:slots]) - before[:slots]))),

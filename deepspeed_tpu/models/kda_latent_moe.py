@@ -484,9 +484,11 @@ class KDALatentMoELM(PerSlotState, DenseLeadMoELM):
                 st)
             q, k, v = self._kda_rows(conv)
         with jax.named_scope("kda_scan"), jax.named_scope("decode"):
+            # the slots' rows, a head's channels side by side on the lanes
             o, state_buf = kda_scan.kda_decode_update(
-                q[:s], k[:s], v[:s], g[:s], beta[:s], state_buf, st.act,
-                first=at)
+                *(a[:s].reshape(s, -1) for a in (q, k, v, g)), beta[:s],
+                state_buf, st.act, first=at)
+            o = o.reshape(v[:s].shape)
         if cw:
             # after the decode lane: the chunk's slot decodes nothing this
             # dispatch, so its state is as it was

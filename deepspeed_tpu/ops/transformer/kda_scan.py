@@ -1,6 +1,6 @@
 """The recurrence of a gated DELTA rule with a decay a CHANNEL (Kimi Delta
 Attention, KDA), both lanes of the serving step: the chunk's blocked form
-in plain XLA, the decode rows' update a Pallas TPU kernel over the step's
+in plain XLA, the decode rows' update ONE Pallas TPU kernel over the step's
 state buffer, in place.
 
 Per head, with ``q_t``, ``k_t [K]`` (``k_t`` of unit length), ``v_t [V]``,
@@ -46,21 +46,34 @@ their inputs in ``product_dtype`` (the activations' type) and accumulate
 in float32.
 
 ``kda_decode_update`` — every slot's one row: the WHOLE state of a layer
-read and written, 64 KB a head.  The kernel is handed the step's whole
-buffer ``[layers x slots, H, V, K]`` (aliased to its result) and the
-layer's first row (a multiple of the slots), streams that layer's slots
-through on-chip memory in blocks of ``DECODE_STATES`` head-states (8
-slots x 2 heads) and writes each back where it lay.  A
-head's pass: the decay (lanes) times the state; ONE product at precision
-``highest`` of ``[k; q]`` against ``S'^T`` (the ``q k^T`` form: rows of
-the result lie on the lanes as ``v`` and ``o`` do), which gives ``S'^T k``
-and ``S'^T q`` together — ``o = S'^T q + beta (k . q) u`` with ``u = v -
-S'^T k`` needs no second pass over the new state; and the rank-1 term
-``u (x) beta k`` as a transposed-left product over a contraction of 16
-whose factors are cut into three bfloat16 pieces each, the nine products
-of a piece with a piece exact and summed in float32
-(``ssd_scan.py::_bf16_pieces``).  An idle slot gets decay 1 and ``beta``
-0: its state passes through unchanged.
+read and written, 64 KB a head, and nothing of the lane outside the
+kernel.  The kernel is handed the step's whole buffer ``[layers x slots,
+H, V, K]`` (aliased to its result) and the layer's first row (a multiple
+of the slots), streams that layer's slots through on-chip memory in blocks
+of ``DECODE_STATES`` head-states (8 slots x 2 heads) and writes each back
+where it lay; the slots' rows ``q``, ``k``, ``v``, ``g`` come as the
+projections write them, ``[S, H K]``, eight slots a block, ``beta`` and
+the mask from SMEM.  A head of a block, for its eight slots at once on
+whole ``[8, 128]`` registers: the mask, ``exp(g)``, ``k . q`` (one lane
+reduction), and the three bfloat16 pieces that add up to ``k`` and to
+``q`` (``ssd_scan.py::_bf16_pieces``).  Then a head-state's pass: the
+decay (lanes) times the state, cut into ITS three pieces; six rows — the
+pieces of ``k`` and of ``q`` — against each piece of ``S'^T`` in ONE
+bfloat16 pass each (the ``q k^T`` form: rows of the result lie on the
+lanes as ``v`` and ``o`` do), nine exact piece-by-piece products a row
+summed in float32, which give ``S'^T k`` and ``S'^T q`` together — ``o =
+S'^T q + beta (k . q) u`` with ``u = v - S'^T k`` needs no second pass
+over the new state; and the rank-1 term ``u (x) beta k`` as a
+transposed-left product over a contraction of 16 whose nine live rows are
+the products of a piece of ``u`` with a piece of ``beta k``.  Four
+single passes a head-state where a product at precision ``highest`` took
+six for ``[k; q] S'^T`` alone, each with the state as the stationary
+operand — and a head's eight first products are all issued before its
+first rank-1 term: where every head-state's chain closes before the next
+one's opens, the matrix unit's latency, not its work, bounds the kernel
+(509 us a layer against 316, and 315 for a kernel that only decays the
+state in place: PR 57, chip).  An idle slot gets decay 1 and ``beta`` 0:
+its state passes through unchanged.
 
 ``kda_scan_reference`` is the loop over rows: the tests' yardstick.
 """
@@ -211,48 +224,114 @@ def kda_chunk_scan(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
     return o.transpose(0, 2, 1, 3).reshape(t, h, vd), state
 
 
-def _decode_kernel(first_ref, beta_ref, kq_ref, q_ref, k_ref, v_ref, a_ref,
-                   s_ref, new_ref, o_ref, *, heads: int, kd: int, vd: int,
-                   all_heads: int):
-    """One block: ``heads`` heads of each of a group of ``rows`` slots.
-    ``beta_ref`` / ``kq_ref`` (SMEM) hold every (slot, head)'s ``beta``
-    and ``beta (k . q)``, flat; ``q_ref`` / ``k_ref`` / ``a_ref [rows,
-    heads * K]`` and ``v_ref`` / ``o_ref [rows, heads * V]`` the group's
-    rows; ``s_ref`` / ``new_ref [rows, heads, V, K]``, the same rows of
-    the same buffer.  Every index is static: a (slot, head)'s ``[1, K]``
-    piece of a row is an aligned load."""
-    del first_ref                      # the index maps' alone
+def _pieces(x: jax.Array) -> jax.Array:
+    """``x [n, w]`` float32 -> its three bfloat16 pieces one under the
+    other, ``[3 n, w]`` float32 (piece ``j`` of row ``r`` at ``j n +
+    r``)."""
+    return jnp.concatenate(_bf16_pieces(x), axis=0)
+
+
+def _row(a: jax.Array, at: int) -> jax.Array:
+    """Row ``at`` of ``a``, kept a row: ``[1, w]``."""
+    return jax.lax.slice_in_dim(a, at, at + 1, axis=0)
+
+
+# A head-state's two halves are jitted: a kernel's body is traced anew at
+# every call of the layer, fourteen times a step program's two shapes, and
+# a jitted helper is traced once a process and inlined when the kernel is
+# lowered (the same instructions, in the same order).
+@functools.partial(jax.jit, static_argnums=0)
+def _state_products(r: int, decay, kq, state, sk, sq):
+    """Slot ``r``'s ``S'^T k`` and ``S'^T q`` put into row ``r`` of ``sk``
+    / ``sq [rows, V]``: ``decay [rows, K]``, ``kq [6 rows, K]`` the
+    pieces of ``k`` over those of ``q`` (:func:`_pieces`), ``state [V,
+    K]``.  Six rows — the pieces of ``k``, then of ``q`` — against each
+    piece of the decayed state in one bfloat16 pass: nine exact products
+    a row pair, summed in float32."""
     f32, bf = jnp.float32, jnp.bfloat16
+    rows = decay.shape[0]
+    at = jax.lax.broadcasted_iota(jnp.int32, (16, kq.shape[1]), 0)
+    six = jnp.zeros(at.shape, f32)
+    for j in range(6):
+        six = jnp.where(at == j, _row(kq, j * rows + r), six)
+    p0, p1, p2 = (jax.lax.dot_general(
+        six.astype(bf), piece.astype(bf), (((1,), (1,)), ((), ())),
+        preferred_element_type=f32)
+        for piece in _bf16_pieces(_row(decay, r) * state))    # [16, V]
+    both = p0 + p1 + p2
+    mine = jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0) == r
+    return (jnp.where(mine, _row(both, 0) + _row(both, 1) + _row(both, 2), sk),
+            jnp.where(mine, _row(both, 3) + _row(both, 4) + _row(both, 5), sq))
+
+
+@functools.partial(jax.jit, static_argnums=0)
+def _state_update(r: int, decay, state, u, bk):
+    """Slot ``r``'s new state ``S' + u (x) beta k``: ``decay [rows, K]``,
+    ``state [V, K]``, ``u [3 rows, V]`` and ``bk [3 rows, K]`` the pieces
+    of ``u`` and of ``beta k`` (:func:`_pieces`).  The rank-1 term is a
+    transposed-left product over a contraction of 16: row ``3 i + j`` is
+    piece ``i`` of ``u`` against piece ``j`` of ``beta k``."""
+    f32, bf = jnp.float32, jnp.bfloat16
+    rows = decay.shape[0]
+    au = jax.lax.broadcasted_iota(jnp.int32, (16, u.shape[1]), 0)
+    ak = jax.lax.broadcasted_iota(jnp.int32, (16, bk.shape[1]), 0)
+    left, right = jnp.zeros(au.shape, f32), jnp.zeros(ak.shape, f32)
+    for i in range(3):
+        left = jnp.where((au >= 3 * i) & (au < 3 * i + 3),
+                         _row(u, i * rows + r), left)
+        right = jnp.where((ak < 9) & (ak % 3 == i),
+                          _row(bk, i * rows + r), right)
+    # (the decayed state again: 16 products, not a store and a load of 64
+    # KB)
+    return _row(decay, r) * state + jax.lax.dot_general(
+        left.astype(bf), right.astype(bf), (((0,), (0,)), ((), ())),
+        preferred_element_type=f32)                          # [V, K]
+
+
+def _decode_kernel(first_ref, act_ref, beta_ref, q_ref, k_ref, v_ref, g_ref,
+                   s_ref, new_ref, o_ref, *, heads: int, kd: int, vd: int):
+    """One block: ``heads`` heads of each of a group of ``rows`` slots.
+    ``act_ref [S]`` (SMEM, int32: a slot that is live) and ``beta_ref [S,
+    H]`` (SMEM) hold every slot's; ``q_ref`` / ``k_ref`` / ``g_ref [rows,
+    heads * K]`` and ``v_ref`` / ``o_ref [rows, heads * V]`` the group's
+    rows, a slot a sublane; ``s_ref`` / ``new_ref [rows, heads, V, K]``,
+    the same rows of the same buffer.  A head at a time: what is a row a
+    slot (mask, decay, ``k . q``, ``u``, ``o`` and every cut into pieces)
+    is computed once for the group on whole ``[rows, 128]`` registers,
+    and the head's ``rows`` first products are all issued before its
+    first rank-1 term, so that no head-state's matrix-unit latency
+    stands in the next one's way.  Every index into a row or a state is
+    static; only the SMEM scalars sit at traced places."""
+    del first_ref                      # the index maps' alone
+    f32 = jnp.float32
     rows = q_ref.shape[0]
-    group, blk = pl.program_id(0), pl.program_id(1)
-    r8 = jax.lax.broadcasted_iota(jnp.int32, (SUBLANES, kd), 0)
-    ru = jax.lax.broadcasted_iota(jnp.int32, (16, vd), 0)
-    rk = jax.lax.broadcasted_iota(jnp.int32, (16, kd), 0)
-    for r in range(rows):
-        for h in range(heads):
-            row = slice(r, r + 1)
-            kc = slice(h * kd, (h + 1) * kd)
-            vc = slice(h * vd, (h + 1) * vd)
-            at = (group * rows + r) * all_heads + blk * heads + h
-            k_row, q_row = k_ref[row, kc], q_ref[row, kc]
-            decayed = a_ref[row, kc] * s_ref[r, h]           # [V, K]
-            # rows 0 and 1 of the result: S'^T k and S'^T q, on the lanes
-            both = jax.lax.dot_general(
-                jnp.where(r8 == 0, k_row, jnp.where(r8 == 1, q_row, 0.0)),
-                decayed, (((1,), (1,)), ((), ())), precision=_HIGHEST,
-                preferred_element_type=f32)                  # [8, V]
-            u = v_ref[row, vc] - both[0:1]
-            o_ref[row, vc] = both[1:2] + kq_ref[at] * u
-            # u (x) beta k: row 3 i + j of the contraction is piece i of
-            # u against piece j of beta k
-            d, b = _bf16_pieces(u), _bf16_pieces(beta_ref[at] * k_row)
-            left = jnp.where(ru < 3, d[0], jnp.where(
-                ru < 6, d[1], jnp.where(ru < 9, d[2], 0.0)))
-            right = jnp.where(rk >= 9, 0.0, jnp.where(
-                rk % 3 == 0, b[0], jnp.where(rk % 3 == 1, b[1], b[2])))
-            new_ref[r, h] = decayed + jax.lax.dot_general(
-                left.astype(bf), right.astype(bf), (((0,), (0,)), ((), ())),
-                preferred_element_type=f32)                  # [V, K]
+    slot0, head0 = pl.program_id(0) * rows, pl.program_id(1) * heads
+    slot = jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
+
+    def column(scalar_of):
+        """``[rows, 1]``: row ``r`` the SMEM scalar ``scalar_of(r)``."""
+        out = jnp.zeros((rows, 1), f32)
+        for r in range(rows):
+            out = jnp.where(slot == r, scalar_of(r), out)
+        return out
+    live = column(lambda r: act_ref[slot0 + r].astype(f32)) > 0.0
+    for h in range(heads):
+        kc = slice(h * kd, (h + 1) * kd)
+        vc = slice(h * vd, (h + 1) * vd)
+        k, q = k_ref[:, kc], q_ref[:, kc]
+        # an idle slot: decay 1, beta 0
+        decay = jnp.where(live, jnp.exp(g_ref[:, kc]), 1.0)
+        beta = jnp.where(
+            live, column(lambda r: beta_ref[slot0 + r, head0 + h]), 0.0)
+        kq = jnp.concatenate([_pieces(k), _pieces(q)], axis=0)
+        sk = sq = jnp.zeros((rows, vd), f32)
+        for r in range(rows):
+            sk, sq = _state_products(r, decay, kq, s_ref[r, h], sk, sq)
+        u = v_ref[:, vc] - sk                                # [rows, V]
+        o_ref[:, vc] = sq + beta * jnp.sum(k * q, axis=-1, keepdims=True) * u
+        u, bk = _pieces(u), _pieces(beta * k)
+        for r in range(rows):
+            new_ref[r, h] = _state_update(r, decay, s_ref[r, h], u, bk)
 
 
 def kda_decode_update(q: jax.Array, k: jax.Array, v: jax.Array,
@@ -260,21 +339,28 @@ def kda_decode_update(q: jax.Array, k: jax.Array, v: jax.Array,
                       active: Optional[jax.Array] = None, first=0,
                       interpret: Optional[bool] = None
                       ) -> Tuple[jax.Array, jax.Array]:
-    """One row a slot: ``q``, ``k``, ``g [S, H, K]``, ``v [S, H, V]``,
-    ``beta [S, H]`` (any float type; computed in float32); ``state [rows
-    >= S, H, V, K]`` float32 (value-major, ``K`` on the lanes): the ``S``
-    slots' states are its rows ``first .. first + S`` (``first`` an int32
-    scalar, traced or not) and no other row is read or written; ``active
-    [S]`` bool: a slot that is not gets decay 1 and ``beta`` 0 and keeps
-    its state.  Returns ``(o [S, H, V] float32, state with those rows
-    updated)`` — the kernel's result IS its operand's buffer
-    (``input_output_aliases``): donate it."""
-    s, h, kd = q.shape
-    vd = v.shape[-1]
-    if (state.ndim != 4 or state.shape[1:] != (h, vd, kd)
-            or state.shape[0] < s):
-        raise ValueError(f"kda_decode_update: state must be [rows >= {s}, "
-                         f"{h}, {vd}, {kd}] (value-major), got {state.shape}")
+    """One row a slot, the rows as the projections write them — a slot a
+    row, a head's channels side by side on the lanes: ``q``, ``k``, ``g
+    [S, H K]``, ``v [S, H V]``, ``beta [S, H]`` (any float type; computed
+    in float32); ``state [rows >= S, H, V, K]`` float32 (value-major,
+    ``K`` on the lanes): the ``S`` slots' states are its rows ``first ..
+    first + S`` (``first`` an int32 scalar, traced or not) and no other
+    row is read or written; ``active [S]`` bool: a slot that is not gets
+    decay 1 and ``beta`` 0 and keeps its state.  Returns ``(o [S, H V]
+    float32, state with those rows updated)`` — the kernel's result IS
+    its operand's buffer (``input_output_aliases``): donate it.  Nothing
+    is computed outside the kernel: mask, ``exp(g)`` and ``k . q`` are
+    its own."""
+    s, h = beta.shape
+    vd, kd = state.shape[-2:]
+    if (state.ndim != 4 or state.shape[1] != h or state.shape[0] < s
+            or q.shape != (s, h * kd) or k.shape != q.shape
+            or g.shape != q.shape or v.shape != (s, h * vd)):
+        raise ValueError(
+            f"kda_decode_update: {s} slots' rows of {h} heads must be q, k, "
+            f"g [{s}, {h} K], v [{s}, {h} V] beside a state [rows >= {s}, "
+            f"{h}, V, K] (value-major); got {q.shape}, {k.shape}, "
+            f"{g.shape}, {v.shape}, {state.shape}")
     # the slots' rows arrive and leave eight slots a block, as they lie in
     # (8, 128) tiles, and a block of the state is those slots' next few
     # heads: ``DECODE_STATES`` head-states, 1 MB at 128 x 128
@@ -287,27 +373,21 @@ def kda_decode_update(q: jax.Array, k: jax.Array, v: jax.Array,
             f"kda_decode_update: compiled for the TPU, a head's key ({kd}) "
             f"and value ({vd}) widths must be whole {LANES}-lane tiles")
     f32 = jnp.float32
-    q, k, v = q.astype(f32), k.astype(f32), v.astype(f32)
-    g, beta = g.astype(f32), beta.astype(f32)
-    if active is not None:
-        g = jnp.where(active[:, None, None], g, 0.0)
-        beta = jnp.where(active[:, None], beta, 0.0)
-    kq = beta * jnp.sum(k * q, axis=-1)                      # [S, H]
-    k_spec = pl.BlockSpec((rows, heads * kd), lambda i, j, first: (i, j))
-    v_spec = pl.BlockSpec((rows, heads * vd), lambda i, j, first: (i, j))
+    if active is None:
+        active = jnp.ones((s,), jnp.int32)
+    k_spec = pl.BlockSpec((rows, heads * kd), lambda i, j, *_: (i, j))
+    v_spec = pl.BlockSpec((rows, heads * vd), lambda i, j, *_: (i, j))
     # (``first`` is a layer's first row, a multiple of the slots and so of
     # the group)
     state_spec = pl.BlockSpec(
         (rows, heads, vd, kd),
-        lambda i, j, first: (first[0] // rows + i, j, 0, 0))
-    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+        lambda i, j, first, act: (first[0] // rows + i, j, 0, 0))
     new, o = pl.pallas_call(
-        functools.partial(_decode_kernel, heads=heads, kd=kd, vd=vd,
-                          all_heads=h),
+        functools.partial(_decode_kernel, heads=heads, kd=kd, vd=vd),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1, grid=(s // rows, h // heads),
-            in_specs=[smem, smem, k_spec, k_spec, v_spec, k_spec,
-                      state_spec],
+            num_scalar_prefetch=2, grid=(s // rows, h // heads),
+            in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM), k_spec, k_spec,
+                      v_spec, k_spec, state_spec],
             out_specs=[state_spec, v_spec]),
         out_shape=[jax.ShapeDtypeStruct(state.shape, f32),
                    jax.ShapeDtypeStruct((s, h * vd), f32)],
@@ -317,10 +397,10 @@ def kda_decode_update(q: jax.Array, k: jax.Array, v: jax.Array,
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
         name="kda_decode_update",
-    )(jnp.asarray(first, jnp.int32).reshape(1), beta.reshape(-1),
-      kq.reshape(-1), q.reshape(s, h * kd), k.reshape(s, h * kd),
-      v.reshape(s, h * vd), jnp.exp(g).reshape(s, h * kd), state)
-    return o.reshape(s, h, vd), new
+    )(jnp.asarray(first, jnp.int32).reshape(1), active.astype(jnp.int32),
+      beta.astype(f32), q.astype(f32), k.astype(f32), v.astype(f32),
+      g.astype(f32), state)
+    return o, new
 
 
 def kda_scan_reference(q, k, v, g, beta, state, valid_rows=None):

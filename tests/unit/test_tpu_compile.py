@@ -1749,17 +1749,20 @@ def test_kda_decode_update_compiles_at_the_cells_widths(v5e_devices,
                                                          compiled_kernels):
     """The delta rule's decode kernel over ``kimi-linear-48b-a3b``'s whole
     state buffer (20 layers x 48 slots of 32 heads x 128 x 128 float32,
-    2.0 GB) at a traced first row: Mosaic takes its two products (a ``q
-    k^T`` one at the highest precision, a transposed-left bfloat16 one
-    over a contraction of 16) and every static, aligned load of a head's
-    piece of a row; the buffer is the call's operand AND its result, and
-    the program around it holds next to no temporary."""
+    2.0 GB) at a traced first row: Mosaic takes its products (a head-state:
+    three single bfloat16 passes of six rows of pieces against the pieces
+    of the decayed state, a transposed-left one over a contraction of 16),
+    its exponential and lane reduction on a group's ``[8, 128]`` rows, its
+    SMEM scalars at traced places and every static, aligned load; the
+    buffer is the call's operand AND its result; and the lane is the call
+    ALONE — the rows go in as the projections write them, so nothing
+    around it computes, reduces or lays out again."""
     import re
     from deepspeed_tpu.ops.transformer.kda_scan import kda_decode_update
     sds = one_chip(v5e_devices)
     slots, h, d = KDA_LATENT_SIZE[0], 32, 128
     f32 = jnp.float32
-    row = sds((slots, h, d), f32)
+    row = sds((slots, h * d), f32)
     compiled = jax.jit(kda_decode_update, donate_argnums=5).trace(
         row, row, row, row, sds((slots, h), f32),
         sds((20 * slots, h, d, d), f32), sds((slots,), jnp.bool_),
@@ -1775,6 +1778,16 @@ def test_kda_decode_update_compiles_at_the_cells_widths(v5e_devices,
     assert re.search(r"input_output_alias=\{ \{1\}: \(5, \{\}, may-alias\) \}",
                      text)
     assert compiled.memory_analysis().temp_size_in_bytes < 4 << 20
+    # outside the call nothing computes (no fusion at all): the mask's
+    # change of type and XLA's own choice of a layout for two parameters,
+    # ``beta``'s 6 KB and the scalar
+    rest = [(op, result) for _, result, op, ln in unfused_instructions(text)
+            if "tpu_custom_call" not in ln]
+    assert {op for op, _ in rest} <= {
+        "parameter", "bitcast", "convert", "copy", "get-tuple-element",
+        "tuple"}, rest
+    assert all(re.match(r"(f32\[48,32\]|s32\[\])\{", result)
+               for op, result in rest if op == "copy"), rest
 
 
 @pytest.mark.parametrize("shape", list(HYBRID_CHUNK))
@@ -1964,6 +1977,23 @@ def test_a_delta_rule_layer_names_its_lanes(compiled_kernels, step_programs,
     # a lane is a refinement: every other key reads as it did
     assert all(laned[key] == plain[key] for key in plain
                if key not in of_scan)
+    # the decode lane is its kernel: the mask, ``exp(g)`` and ``k . q``
+    # are the kernel's own, so no instruction of the lane computes:
+    # what XLA leaves there lays rows out (``v``'s, which the projection's
+    # fusion wrote a head a tile; in the mixed shape each of q, k, v, g
+    # cut from the ``[slots + chunk]`` rows the chunk lane shares) or
+    # changes the mask's type
+    bodies, _ = hlo_computations(text)
+    calls = dict(re.findall(r"(%\S+) = .*? fusion\(.*?, calls=%([^\s,]+)",
+                            text))
+    for key, lane in of_scan.items():
+        name = key.split(" = ")[0]
+        if lane != "kda_scan/decode" or name.startswith("%kda_decode_update"):
+            continue
+        assert not re.match(r"%(copy|reduce|exponential)[.\d]* ", key), key
+        for ln in bodies.get(calls.get(name), ()):
+            assert not re.search(
+                r" (exponential|reduce|multiply|add|select|dot)\(", ln), ln
 
 
 def stripped(text):
