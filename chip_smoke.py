@@ -58,6 +58,14 @@ weights from a seed:
          place at the middle layer's rows of three layers' states.
          ``python chip_smoke.py 1 kda`` runs this phase alone.
 
+  block_lane (one chip) the builder's probe of generation by diffusion
+         over blocks, no cell runs it: ``paged_block_attention`` at
+         ``sdar-30b-a3b-chat``'s widths (48 slots x 4 rows, 32 / 4 heads
+         of 128) against its float32 reference under the block mask, and
+         its time at the mix's mean and longest contexts beside four
+         calls of the decode kernel over the same pages.
+         ``python chip_smoke.py 1 block`` runs this phase alone.
+
 It fails — non-zero exit, no result line — when JAX shows anything but
 CHIPS TPU devices; it never adapts downward, and nothing on the path is
 caught.  Compile seconds are reported apart from run seconds, so a
@@ -908,6 +916,66 @@ def kda_phase(device: dict):
             "decode_update_max_abs_err": float(decode_err)}
 
 
+def block_lane_phase(device: dict, block: int = SERVING["kv_block_size"]):
+    """Generation by diffusion over blocks, the builder's probe (no cell
+    runs it): ``paged_block_attention`` at its cell's widths — 48 slots, 4
+    rows a slot, 32 query heads over 4 kv heads of 128 — against the
+    float32 reference under the block mask, and its time at the mix's
+    mean context and at its longest, beside four calls of the decode
+    kernel over the same pages (what a lane that walked a slot's pages
+    once a row would cost)."""
+    import jax
+    import jax.numpy as jnp
+    from deepspeed_tpu.ops.transformer import paged_decode_attention as pda
+
+    rng = np.random.default_rng(SEED + 10)
+    slots, rows, heads, kvh, hd, pages, nb = 48, 4, 32, 4, 128, 128, 2560
+    pool_k, pool_v = (jnp.asarray(rng.standard_normal((nb, block, kvh * hd)),
+                                  jnp.bfloat16) for _ in range(2))
+    tables = jnp.asarray(rng.permutation(nb - 1)[:slots * (nb // slots - 1)]
+                         .reshape(slots, -1)[:, :pages] + 1, jnp.int32)
+    tables = jnp.pad(tables, ((0, 0), (0, pages - tables.shape[1])))
+    q = jnp.asarray(rng.standard_normal((slots, rows, heads, hd)),
+                    jnp.bfloat16)
+    lane = jax.jit(pda.paged_block_attention)
+    four = jax.jit(lambda q, pk, pv, base, tables: jnp.stack(
+        [pda.paged_decode_attention(q[:, i], pk, pv, base + rows, tables)
+         for i in range(rows)], axis=1))
+    out = {"phase": "block_lane", **device}
+    for name, context in (("mean", 644), ("longest", 1532)):
+        base = jnp.full((slots,), context // rows * rows - rows, jnp.int32)
+        active = jnp.asarray(np.arange(slots) % 7 != 3, jnp.int32)
+        got = lane(q, pool_k, pool_v, base, active, tables)
+        want = pda._reference(
+            q.astype(jnp.float32), pool_k, pool_v, base,
+            jnp.where(active > 0, base + rows, 0), tables, None, None, 0,
+            block_rows=rows)
+        live = np.asarray(active) > 0
+        err = float(jnp.max(jnp.abs(got.astype(jnp.float32)[live]
+                                    - want[live])))
+        check(err < 2e-2, f"block lane off its reference by {err} at "
+              f"{context}")
+        check(not bool(jnp.any(got[~live] != 0)), "an idle slot's rows "
+              "are not zero")
+        every = jnp.ones_like(active)
+        for fn, args, key in ((lane, (q, pool_k, pool_v, base, every,
+                                      tables), "lane_us"),
+                              (four, (q, pool_k, pool_v, base, tables),
+                               "four_decode_calls_us")):
+            jax.block_until_ready(fn(*args))
+            t0 = time.perf_counter()
+            for _ in range(20):
+                o = fn(*args)
+            jax.block_until_ready(o)
+            out[f"{name}_{key}"] = round(
+                (time.perf_counter() - t0) / 20 * 1e6, 1)
+        out[f"{name}_max_abs_err"] = err
+        # the context's K and V once, at the chip's memory rate
+        out[f"{name}_bytes_floor_us"] = round(
+            slots * 2 * context * kvh * hd * 2 / 819e9 * 1e6, 1)
+    return out
+
+
 def _shared_expert_layer_error(rows: int = 640) -> float:
     """One expert layer of the sandwich block at its published widths in
     bfloat16 — sigmoid top-8 gate over 256 outputs, renormalised and scaled
@@ -947,8 +1015,9 @@ def main(argv) -> int:
     chips = int(argv[1]) if len(argv) > 1 else 1
     device = require_tpu(chips)
     log = CompileLog()
-    if argv[2:] == ["kda"]:            # that one-chip phase alone
-        print(json.dumps(kda_phase(device)), flush=True)
+    alone = {"kda": kda_phase, "block": block_lane_phase}
+    if len(argv) == 3 and argv[2] in alone:    # that one-chip phase alone
+        print(json.dumps(alone[argv[2]](device)), flush=True)
         print(json.dumps({"ok": True, "device": device}), flush=True)
         return 0
 
@@ -967,6 +1036,7 @@ def main(argv) -> int:
         print(json.dumps(hybrid_phase(device)), flush=True)
         print(json.dumps(ssd_hybrid_phase(device)), flush=True)
         print(json.dumps(kda_phase(device)), flush=True)
+        print(json.dumps(block_lane_phase(device)), flush=True)
     print(json.dumps({"phase": "total", **device,
                       "wall_s": round(time.perf_counter() - t0, 1),
                       **log.since((0, 0, 0.0, 0.0))}), flush=True)

@@ -247,6 +247,16 @@ class ServingConfig(ConfigModel):
     # token equivalence to plain decode under the same key
     # (docs/serving.md "Speculative decoding")
     spec_k: int = C.SERVING_SPEC_K_DEFAULT
+    # generation by diffusion over blocks (docs/serving.md; read only for
+    # a model whose blocks are generated that way — block_length and the
+    # mask token are the MODEL's): denoise forwards a block of all-mask
+    # rows takes before its commit (1 .. block_length), which of the
+    # rows that still hold the mask a forward fills, and the confidence
+    # above which the dynamic rule fills a row early.  The published
+    # sampler's defaults.
+    denoising_steps: int = C.SERVING_DENOISING_STEPS_DEFAULT
+    remasking_strategy: str = C.SERVING_REMASKING_STRATEGY_DEFAULT
+    confidence_threshold: float = C.SERVING_CONFIDENCE_THRESHOLD_DEFAULT
     # (data, model) serving submesh — see ServingMeshConfig; shape
     # constraints the model config imposes (model | kv_heads,
     # data | max_batch_slots) are checked at ServingEngine build, where
@@ -298,6 +308,21 @@ class ServingConfig(ConfigModel):
             raise ValueError(
                 f"serving.spec_k must be >= 1 (only read when a draft "
                 f"model is armed), got {self.spec_k}")
+        if self.denoising_steps < 1:
+            raise ValueError(
+                f"serving.denoising_steps must be >= 1, got "
+                f"{self.denoising_steps}")
+        if self.remasking_strategy not in (
+                "low_confidence_static", "low_confidence_dynamic",
+                "sequential"):
+            raise ValueError(
+                f"serving.remasking_strategy must be low_confidence_static"
+                f", low_confidence_dynamic or sequential, got "
+                f"{self.remasking_strategy!r}")
+        if not 0.0 < self.confidence_threshold <= 1.0:
+            raise ValueError(
+                f"serving.confidence_threshold must be in (0, 1], got "
+                f"{self.confidence_threshold}")
         if self.default_deadline_s < 0:
             raise ValueError(
                 f"serving.default_deadline_s must be >= 0 (0 = none), "

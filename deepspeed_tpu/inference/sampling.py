@@ -63,7 +63,14 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+import math
+
 from ..observability.overlap import scoped
+
+#: which of a block's masked rows a denoise forward fills (generation by
+#: diffusion over blocks, docs/serving.md)
+UNMASK_RULES = ("low_confidence_static", "low_confidence_dynamic",
+                "sequential")
 
 
 @scoped("sample")
@@ -173,3 +180,52 @@ def sample_tokens_per_row(logits, keys, temperature, top_k, top_p):
         return jnp.where(t <= 0.0, greedy(), drawn).astype(jnp.int32)
 
     return jax.lax.cond(jnp.any(samples), sampled, greedy)
+
+
+@scoped("block_unmask")
+def block_unmask(logits, block, n_fill, *, mask_id: int, rule: str,
+                 threshold: float = 0.9):
+    """One denoise step of generation by diffusion over blocks, for every
+    slot at once, on the device: ``logits [S, B, V]`` float32 (row ``i``'s
+    predict the token AT row ``i``), ``block [S, B]`` int32 as it stands
+    (``mask_id`` where nothing is filled yet), ``n_fill [S]`` int32 — rows
+    this step may fill (0: none, the slot's forward is a commit or it
+    rides nothing).  Every row draws ``x0`` (greedy; the mask token itself
+    is never drawn) with confidence ``c = softmax(logits)[x0]`` (over the
+    outputs that can be drawn, float32), and among the rows that still
+    hold the mask:
+
+      * ``low_confidence_static`` — the ``n_fill`` of highest ``c`` (ties
+        to the leftmost) take their ``x0``;
+      * ``low_confidence_dynamic`` — every row with ``c > threshold`` if
+        there are at least ``n_fill`` of them, else as static;
+      * ``sequential`` — the ``n_fill`` leftmost.
+
+    A filled row never changes again.  Returns the block after the step,
+    ``[S, B]`` int32."""
+    if rule not in UNMASK_RULES:
+        raise ValueError(f"block_unmask: rule {rule!r} is none of "
+                         f"{UNMASK_RULES}")
+    s, b, v = logits.shape
+    lg = jnp.where(jax.lax.broadcasted_iota(jnp.int32, (1, 1, v), 2)
+                   == mask_id, -jnp.inf, logits.astype(jnp.float32))
+    x0 = jnp.argmax(lg, axis=-1).astype(jnp.int32)
+    top = jnp.max(lg, axis=-1)
+    # log c: the drawn logit under the log-sum-exp (the best, drawn greedily)
+    logc = -jnp.log(jnp.sum(jnp.exp(lg - top[..., None]), axis=-1))
+    masked = block == mask_id
+    n = n_fill[:, None]
+    i, j = jnp.arange(b)[:, None], jnp.arange(b)[None, :]
+    if rule == "sequential":
+        before = (j < i)[None]
+    else:
+        before = ((logc[:, None, :] > logc[:, :, None])
+                  | ((logc[:, None, :] == logc[:, :, None]) & (j < i)[None]))
+    # a masked row's rank among the masked rows of its block
+    rank = jnp.sum(before & masked[:, None, :], axis=-1)
+    fill = masked & (rank < n)
+    if rule == "low_confidence_dynamic":
+        high = masked & (logc > math.log(threshold))
+        enough = jnp.sum(high, axis=-1, keepdims=True) >= n
+        fill = jnp.where(enough & (n > 0), high, fill)
+    return jnp.where(fill, x0, block).astype(jnp.int32)

@@ -81,7 +81,7 @@ from ...runtime.resilience.fault_injection import get_fault_injector
 from ...runtime.resilience.heartbeat import Heartbeat
 from ...runtime.resilience.retry import retry_call
 from ...utils.logging import logger
-from ..sampling import fold_in_keys, sample_tokens_per_row
+from ..sampling import block_unmask, fold_in_keys, sample_tokens_per_row
 from .block_allocator import PagedBlockAllocator
 from .host_cache import BlockCodec, HostTierCache
 from .frontend.streaming import TokenEvent
@@ -120,6 +120,17 @@ SRC_HOST, SRC_DEVICE = 0, 1
 # chunk's two scalars ride in every row; with the draft armed the row
 # goes on: n_emit, spec_finite, the spec_k + 1 target samples
 (_R_NEXT, _R_DEC_FINITE, _R_FIRST, _R_CHUNK_FINITE, _R_SPEC) = range(5)
+# Generation by diffusion over blocks (docs/serving.md; ``block_rows`` > 0,
+# which refuses the draft): a slot rides a dispatch with its whole block of
+# ``block_rows`` tokens.  ``_DEC_ACTIVE`` then names the forward's PHASE,
+# the draft's column carries the rows a denoise forward may fill, the
+# host's copy of a block (one just opened) rides as the LAST ``block_rows``
+# columns of the per-slot operand, after the tables, and the result array
+# carries every slot's block as the forward left it from ``_R_SPEC`` on —
+# where the next dispatch finds it (``_TOKEN_SRC``), as it finds
+# ``_R_NEXT`` for a one-token model.
+PHASE_DENOISE, PHASE_COMMIT = 1, 2
+_BLOCK_FILL = _SPEC_ACTIVE
 
 
 def _bits(x: jax.Array, dtype) -> jax.Array:
@@ -192,6 +203,25 @@ class _Flight(NamedTuple):
     t0: float
     #: ``count_dispatch``'s share known at enqueue (profiler on)
     counts: Optional[Dict[str, int]]
+    #: the block lane's plan, a slot of ``dec``
+    block: Optional[Dict[int, "_BlockPlan"]] = None
+
+
+class _BlockPlan(NamedTuple):
+    """One slot's forward in the block lane (generation by diffusion over
+    blocks), as planned."""
+    phase: int                  # PHASE_DENOISE | PHASE_COMMIT
+    fill: int                   # rows a denoise forward may fill
+    #: the block's tokens if this forward opens it (the host's to send),
+    #: else None: they are on the device
+    opened: Optional[List[int]]
+    start: int                  # the block's first position
+    new_tokens: int             # tokens its commit makes visible
+    #: rows still masked once this forward has landed, by counts (-1:
+    #: the block is closed)
+    masked_after: int
+    #: denoise forwards the block had taken before this one
+    denoised: int
 
 
 def _pack_results(nxt, dec_finite, first, chunk_finite, *spec_cols,
@@ -312,6 +342,11 @@ class ServingEngine:
         self.chunk_tokens = cfg.prefill_chunk_tokens
         self.max_pages = max(
             1, -(-engine.config.max_out_tokens // self.block_size))
+        #: rows a slot rides a dispatch with where generation is by
+        #: diffusion over blocks (docs/serving.md); 0: one token a step
+        self.block_rows = model.block_rows
+        if self.block_rows:
+            self._init_block_lane(cfg, engine.config.max_out_tokens)
         # a block whose per-sequence state cannot be found again at a
         # block boundary serves with the prefix cache off, and says why
         no_hits = model.prefix_cache_refusal()
@@ -346,6 +381,7 @@ class ServingEngine:
         # SHED terminals advertise a drain-rate-derived Retry-After
         # (docs/serving.md "Fleet serving & failover")
         self.scheduler.retry_after_hint = self._estimate_retry_after
+        self.scheduler.step_rows = max(1, self.block_rows)
         self.no_progress_steps = cfg.no_progress_steps
         self.default_deadline_s = cfg.default_deadline_s
         #: KV-cache width: 0 = engine dtype, 8 = int8, 4 = packed int4
@@ -486,7 +522,8 @@ class ServingEngine:
         #: rides: a decode row per slot and, with a draft, its
         #: spec_k + 1 verify rows; the mixed shape adds the chunk lane
         self._decode_rows_per_dispatch = self.num_slots * (
-            1 + (self.spec_k + 1 if draft_model is not None else 0))
+            self.block_rows
+            or 1 + (self.spec_k + 1 if draft_model is not None else 0))
         # -- the dispatch in flight (docs/serving.md) ---------------------
         #: the iteration on the device: its dispatches, oldest first,
         #: enqueued and not yet read (empty between a drain and the next
@@ -498,6 +535,7 @@ class ServingEngine:
         #: cache key
         self._prev_result = jax.device_put(
             np.zeros((self.num_slots, _R_SPEC + len(model.PAGED_COUNTERS)
+                      + self.block_rows
                       + (2 + self.spec_k + 1 if draft_model is not None
                          else 0)), np.int32),
             NamedSharding(self.tp_mesh, P(topo.DATA_AXIS, None)))
@@ -642,6 +680,32 @@ class ServingEngine:
         #: plain-int mirror read by tests/unit/test_frontend.py and
         #: test_serving_chaos.py (acceptance rate = accepted / proposed)
         self.spec_counts = {"proposed": 0, "accepted": 0}
+        # generation by diffusion over blocks (docs/serving.md): slot
+        # forwards of either phase, rows of live slots dispatched, tokens
+        # made visible by commits, and denoise forwards a committed block
+        # took (what the dynamic rule saves)
+        self._m_block_denoise = reg.counter(
+            "dstpu_serving_block_forwards_denoise_total",
+            "block-lane slot forwards that filled masked rows (their k/v "
+            "are overwritten by the next forward)")
+        self._m_block_commit = reg.counter(
+            "dstpu_serving_block_forwards_commit_total",
+            "block-lane slot forwards over a block's final tokens (their "
+            "k/v are kept; the block becomes visible)")
+        self._m_block_rows = reg.counter(
+            "dstpu_serving_block_rows_total",
+            "rows of live slots dispatched in the block lane "
+            "(block_length a slot a forward)")
+        self._m_block_tokens = reg.counter(
+            "dstpu_serving_block_tokens_total",
+            "tokens made visible by block commits")
+        self._m_block_steps = reg.histogram(
+            "dstpu_serving_block_denoise_forwards",
+            "denoise forwards a committed block took",
+            buckets=[1, 2, 3, 4, 6, 8, 12, 16, 24, 32, 48, 64])
+        #: plain-int mirror of the block lane's counters
+        self.block_counts = {"denoise": 0, "commit": 0, "rows": 0,
+                             "tokens": 0}
         # tiered host cache metrics (docs/serving.md "Tiered prefix
         # cache"): per-tier hit/spill/evict counters, resident-bytes and
         # promote-queue-depth gauges
@@ -697,6 +761,42 @@ class ServingEngine:
         self._host_polled = {"spills": 0, "dram_hits": 0, "nvme_hits": 0,
                              "demotions": 0, "evictions": 0,
                              "hit_tokens": 0}
+
+    # ------------------------------------------------------------------
+    # generation by diffusion over blocks (docs/serving.md)
+    # ------------------------------------------------------------------
+    def _init_block_lane(self, cfg, max_out_tokens: int) -> None:
+        """The block lane's settings, each checked against the model's
+        block: ``serving.denoising_steps`` (1 .. block_length), the rule
+        and its threshold; whole blocks in a page, a chunk and the served
+        positions."""
+        model, rows = self.model, self.block_rows
+        reason = model.serving_refusal(self.block_size, self.chunk_tokens)
+        if reason is None and max_out_tokens % rows:
+            reason = (f"max_out_tokens {max_out_tokens} is not a multiple "
+                      f"of block_length {rows}: a sequence's last block "
+                      f"must lie inside the served positions")
+        if reason is None and not 1 <= cfg.denoising_steps <= rows:
+            reason = (f"serving.denoising_steps {cfg.denoising_steps} is "
+                      f"not in 1 .. block_length {rows}: every denoise "
+                      f"forward fills at least one row")
+        if reason is not None:
+            raise NotImplementedError(
+                f"continuous-batching serving cannot be built this way "
+                f"for this model: {reason}")
+        self.denoising_steps = cfg.denoising_steps
+        self.unmask_rule = cfg.remasking_strategy
+        self.confidence_threshold = cfg.confidence_threshold
+        #: rows a denoise forward fills, a step (the published
+        #: ``get_num_transfer_tokens``)
+        base, more = divmod(rows, self.denoising_steps)
+        self._fill_schedule = [base + (s < more)
+                               for s in range(self.denoising_steps)]
+        logger.info(
+            f"serving: block lane: blocks of {rows} rows in "
+            f"{self.denoising_steps} denoise steps + commit, rule "
+            f"{self.unmask_rule}"
+            f"{f' (threshold {self.confidence_threshold})' if self.unmask_rule == 'low_confidence_dynamic' else ''}")
 
     # ------------------------------------------------------------------
     # tensor-parallel serving (docs/serving.md "Tensor-parallel serving")
@@ -1259,7 +1359,8 @@ class ServingEngine:
                on_token: Optional[Callable] = None,
                tenant: str = "default",
                prefill_only: bool = False,
-               trace_id: Optional[str] = None) -> Request:
+               trace_id: Optional[str] = None,
+               record_blocks: bool = False) -> Request:
         """Queue a request.  ``deadline_s`` is a TTL from submit, swept
         every ``step()`` whether the request is still WAITING or already
         RUNNING (defaults to ``serving.default_deadline_s``; 0 = none).
@@ -1287,7 +1388,11 @@ class ServingEngine:
         engine: when set, the request tracer adopts it instead of
         minting a fresh per-process id, so prefill, decode and failover
         legs of one disaggregated request share ONE trace id in the
-        merged fleet trace (observability/fleet_trace.py)."""
+        merged fleet trace (observability/fleet_trace.py).
+
+        ``record_blocks`` (generation by diffusion over blocks) keeps the
+        request's trajectory in ``Request.block_steps``: the block's
+        tokens after every forward it rode."""
         if prefill_only and self.host_cache is None:
             raise ValueError(
                 "prefill_only requires the host-tier KV fabric "
@@ -1312,6 +1417,14 @@ class ServingEngine:
         if temperature < 0:
             raise ValueError(f"temperature must be >= 0, got "
                              f"{temperature}")
+        if self.block_rows and temperature > 0:
+            raise NotImplementedError(
+                f"temperature {temperature}: the block lane draws greedily "
+                f"(a row's token and its confidence come from one argmax "
+                f"over the vocabulary; a per-row categorical draw over "
+                f"block_length x slots rows is not built) — submit with "
+                f"temperature=0, or put \"temperature\": 0.0 in the engine "
+                f"config")
         if top_k < 0:
             raise ValueError(f"top_k must be >= 0 (0 = off), got "
                              f"{top_k}")
@@ -1324,7 +1437,9 @@ class ServingEngine:
                       deadline_s=deadline_s if deadline_s else None,
                       temperature=temperature, top_k=top_k, top_p=top_p,
                       prng_key=key, on_token=on_token, tenant=tenant,
-                      prefill_only=prefill_only)
+                      prefill_only=prefill_only,
+                      block_steps=[] if record_blocks and self.block_rows
+                      else None)
         if trace_id is not None:
             # fleet-minted trace context: set BEFORE scheduler.submit so
             # the request tracer's on_submit adopts it as-is
@@ -1488,6 +1603,50 @@ class ServingEngine:
                     cache.get("k_scale"), cache.get("v_scale"),
                     cache.get("extra"))
 
+        B = self.block_rows
+        pages = self.max_pages * len(self.table_kinds) if B else 0
+
+        def serving_block_step(params, scales, pool_k, pool_v, pool_ks,
+                               pool_vs, pool_x, prev, slots, chunk):
+            """Generation by diffusion over blocks: every live slot's
+            block through one forward — a denoise step that fills some
+            of its masked rows, or the commit of its final tokens; the
+            program is the same, the host's phase column says which —
+            beside one prompt chunk.  The blocks live on the device from
+            one dispatch to the next, in the result array."""
+            built()
+            sl = _SlotState.unpack(slots[:, :_SLOT_COLS + pages])
+            ch = _ChunkState.unpack(chunk)
+            carried = prev[:, _R_SPEC:_R_SPEC + B]
+            block = jnp.where((sl.token_src == SRC_DEVICE)[:, None],
+                              carried, slots[:, _SLOT_COLS + pages:])
+            mp = engine._model_params(params, scales)
+            cache = {"k": pool_k, "v": pool_v, "k_scale": pool_ks,
+                     "v_scale": pool_vs, "block_tables": sl.tables,
+                     "lens": sl.lens}
+            logits, cache = model._apply_paged_block(
+                mp, cache, block, sl.dec_active, ch.ids, ch.slot, ch.start,
+                ch.len)
+            # confidence, ranking, transfer: on the device
+            filled = block_unmask(
+                logits, block,
+                jnp.where(sl.dec_active == PHASE_DENOISE,
+                          slots[:, _BLOCK_FILL], 0),
+                mask_id=model.config.mask_token_id, rule=self.unmask_rule,
+                threshold=self.confidence_threshold)
+            with jax.named_scope("head"):
+                finite = jnp.all(jnp.isfinite(logits), axis=(-2, -1))
+            with jax.named_scope("sample"):
+                # a slot that rode nothing (a chunk remainder's dispatch)
+                # hands its block on
+                block = jnp.where((sl.dec_active > 0)[:, None], filled,
+                                  carried)
+                packed = _pack_results(
+                    block[:, 0], finite, jnp.zeros((), jnp.int32),
+                    jnp.ones((), jnp.bool_), samples=block,
+                    counters=cache.get("counters"))
+            return (packed, cache["k"], cache["v"], None, None, pool_x)
+
         def serving_spec_step(params, scales, dparams, pool_k, pool_v,
                               pool_ks, pool_vs, pool_x, dpool_k, dpool_v,
                               prev, slots, chunk):
@@ -1582,7 +1741,7 @@ class ServingEngine:
             fn = serving_spec_step
             donate = (3, 4, 8, 9) + ((5, 6) if self.kv_bits else ())
         else:
-            fn = serving_step
+            fn = serving_block_step if B else serving_step
             donate = (2, 3) + ((4, 5) if self.kv_bits else ()) + (
                 (6,) if self._pool_x is not None else ())
         # the body runs shard_mapped over the (data, model) serving
@@ -1641,7 +1800,9 @@ class ServingEngine:
 
     def _step_operands(self, dec: List[Tuple[int, Request]],
                        chunk: Optional[Tuple[int, Request, int, int]],
-                       spec: List[Tuple[int, Request]] = ()) -> tuple:
+                       spec: List[Tuple[int, Request]] = (),
+                       block: Optional[Dict[int, "_BlockPlan"]] = None
+                       ) -> tuple:
         """The step's positional operands for one dispatch (see
         ``_build_step``): weights and the pools, which live on the
         device, then the TWO host arrays of the ``_SLOT_COLS`` /
@@ -1665,6 +1826,19 @@ class ServingEngine:
             slots[slot, _LENS] = req.planned_cached
         if self.window_blocks:
             self._window_operands(slots, dec, chunk)
+        if block is not None:
+            # the block lane: a forward's phase, the rows it may fill,
+            # and — for a block just opened — its tokens; an open
+            # block's are on the device
+            for slot, _req in dec:
+                bp = block[slot]
+                slots[slot, _DEC_ACTIVE] = bp.phase
+                slots[slot, _BLOCK_FILL] = bp.fill
+                if bp.opened is None:
+                    slots[slot, _TOKEN_SRC] = SRC_DEVICE
+                else:
+                    slots[slot, -self.block_rows:] = bp.opened
+            dec = ()
         for slot, req in list(dec) + list(spec):
             if req.flight_tokens:
                 slots[slot, _TOKEN_SRC] = SRC_DEVICE
@@ -1700,7 +1874,8 @@ class ServingEngine:
     def _slot_cols(self) -> int:
         """Columns of the per-slot operand: the ``_SLOT_COLS`` scalars,
         then a block table a kind of ``table_kinds``."""
-        return _SLOT_COLS + self.max_pages * len(self.table_kinds)
+        return (_SLOT_COLS + self.max_pages * len(self.table_kinds)
+                + self.block_rows)
 
     def _window_operands(self, slots: np.ndarray, dec, chunk) -> None:
         """The window kind's part of one dispatch's plan: every row the
@@ -1818,7 +1993,9 @@ class ServingEngine:
             ovl.mark(overlap.OPERANDS)
         if self._step_fn is None:
             self._build_both_shapes(chunk)
-        operands = self._step_operands(dec, chunk, spec)
+        block = self._plan_blocks(dec) if self.block_rows else None
+        operands = self._step_operands(
+            dec, chunk, spec, **({} if block is None else {"block": block}))
         rows = self._decode_rows_per_dispatch + (
             0 if chunk is None else self.chunk_tokens)
         if ovl_on:
@@ -1847,26 +2024,86 @@ class ServingEngine:
         # the state as dispatched.  A speculating slot's row count is
         # the device's to say: its dispatch lands before anything else is
         # planned (_plan_iteration), so it carries none
-        for _slot, req in dec:
-            req.flight_rows += 1
-            req.flight_tokens += 1
+        if block is not None:
+            self._dispatched_blocks(dec, block)
+            more_counts.update(
+                block_rows=len(dec) * self.block_rows,
+                block_commits=sum(bp.phase == PHASE_COMMIT
+                                  for bp in block.values()),
+                block_tokens=sum(bp.new_tokens for bp in block.values()))
+        else:
+            for _slot, req in dec:
+                req.flight_rows += 1
+                req.flight_tokens += 1
         ends_prefill = False
         if chunk is not None:
             req = chunk[1]
             req.flight_rows += c_len
+            # (a block model's chunk samples nothing: what is left of the
+            # prompt rides the first block)
             ends_prefill = (req.planned_cached >= req.prefill_target
-                            and not req.prefill_only)
+                            and not req.prefill_only
+                            and not self.block_rows)
             req.flight_tokens += ends_prefill
         self._flight.append(_Flight(
             result, dec, chunk, spec, ends_prefill, ahead, t0,
-            dict(decode_rows=len(dec) + len(spec) * (self.spec_k + 1),
+            dict(decode_rows=(len(dec) * max(1, self.block_rows)
+                              + len(spec) * (self.spec_k + 1)),
                  chunk_rows=c_len, rows_computed=rows,
                  host_arrays_in=sum(isinstance(a, np.ndarray)
                                     for a in operands),
                  host_reads_out=1, ahead_dispatches=int(ahead),
                  **more_counts, **self._sampler_rows(*operands[-2:]))
-            if ovl_on else None))
+            if ovl_on else None, block))
         return True
+
+    def _plan_blocks(self, dec: List[Tuple[int, Request]]
+                     ) -> Dict[int, "_BlockPlan"]:
+        """The block lane's plan for one dispatch, a slot of ``dec``, from
+        counts alone and changing nothing.  A block opens at the slot's
+        committed rows with what is left of its prompt in place and the
+        mask token everywhere else; it is denoised while it holds masked
+        rows — by the static and the sequential rule ``_fill_schedule``
+        says how many remain after each forward; by the dynamic rule only
+        the result does, and ``_apply`` writes the count back before the
+        next plan (``_sees_ahead``) — and committed by the forward
+        after."""
+        rows, mask = self.block_rows, self.model.config.mask_token_id
+        plan = {}
+        for slot, req in dec:
+            start, opened = req.planned_cached, None
+            masked, step = req.block_masked, req.block_step
+            if masked < 0:
+                # (only a request's first block after its admission finds
+                # prompt tokens past its committed rows)
+                left = (req.prefix[start:]
+                        if start < len(req.prompt) + len(req.output) else [])
+                masked, step = rows - len(left), 0
+                opened = left + [mask] * masked
+            if masked:
+                fill = self._fill_schedule[step]
+                plan[slot] = _BlockPlan(PHASE_DENOISE, fill, opened, start,
+                                        0, max(0, masked - fill), step)
+            else:
+                have = req.planned_output
+                new = min(start + rows - len(req.prompt) - have,
+                          req.max_new_tokens - have)
+                plan[slot] = _BlockPlan(PHASE_COMMIT, 0, opened, start, new,
+                                        -1, step)
+        return plan
+
+    def _dispatched_blocks(self, dec, plan: Dict[int, "_BlockPlan"]) -> None:
+        """Note on the request records what the block lane's dispatch
+        will have done when it lands: the state as dispatched."""
+        for slot, req in dec:
+            bp = plan[slot]
+            req.block_masked = bp.masked_after
+            if bp.phase == PHASE_COMMIT:
+                req.block_step = 0
+                req.flight_rows += self.block_rows
+                req.flight_tokens += bp.new_tokens
+            else:
+                req.block_step = bp.denoised + 1
 
     def _apply(self, fl: _Flight) -> int:
         """Read one dispatch's result — the one place the host waits for
@@ -1914,6 +2151,9 @@ class ServingEngine:
             self._rt.on_decode([r for _, r in live], t0, dispatch_dt,
                                len(live))
         progress = 0
+        if fl.block is not None:
+            progress += self._apply_blocks(fl, live, res)
+            live = ()
         for slot, req in live:
             req.flight_rows -= 1
             req.flight_tokens -= 1
@@ -1976,7 +2216,7 @@ class ServingEngine:
                                           else spec[0][1].trace_id))
             for h in self.mirror_hists.get("itl", ()):
                 h.observe(dispatch_dt)
-            if progress:
+            if progress and fl.block is None:
                 self._m_tokens.inc(progress)
         if chunk is not None:
             c_slot, req, c_start, c_len = chunk
@@ -2004,7 +2244,8 @@ class ServingEngine:
                     # key, so the stream is identical to a one-replica
                     # run
                     self._finish_prefill_only(c_slot, req)
-                elif req.cached_tokens >= req.prefill_target:
+                elif (req.cached_tokens >= req.prefill_target
+                      and not self.block_rows):
                     # the chunk that completed the prefix carries the
                     # first token (sampled from its last valid position
                     # with the request's own key at output index 0 —
@@ -2013,14 +2254,7 @@ class ServingEngine:
                     req.output.append(tok)
                     self._emit_token(req, tok)
                     self._m_tokens.inc()
-                    if req.first_token_time is None:
-                        req.first_token_time = time.perf_counter()
-                        self._m_ttft.observe(
-                            req.first_token_time - req.submit_time,
-                            exemplar=req.trace_id)
-                        for h in self.mirror_hists.get("ttft", ()):
-                            h.observe(req.first_token_time
-                                      - req.submit_time)
+                    self._observe_first_token(req)
                     if req.done:
                         sched.finish(c_slot)
         self.flight_counts["dispatches"] += 1
@@ -2034,6 +2268,81 @@ class ServingEngine:
                 **(dict(zip(counted, map(int, res[0, -len(counted):])))
                    if counted else {}))
         return progress
+
+    def _observe_first_token(self, req: Request) -> None:
+        """Stamp a request's first visible token (once) and observe its
+        TTFT."""
+        if req.first_token_time is not None:
+            return
+        req.first_token_time = time.perf_counter()
+        ttft = req.first_token_time - req.submit_time
+        self._m_ttft.observe(ttft, exemplar=req.trace_id)
+        for h in self.mirror_hists.get("ttft", ()):
+            h.observe(ttft)
+
+    def _apply_blocks(self, fl: _Flight, live, res: np.ndarray) -> int:
+        """The block lane's part of :meth:`_apply`: every live slot's
+        block as its forward left it.  A denoise forward moves nothing
+        but the block (its rows' k/v are overwritten by the next
+        forward); a commit makes the block's new tokens visible at once,
+        moves the request's committed rows by a whole block and registers
+        the pages it completed.  Returns the progress made (tokens made
+        visible, and one for each denoise forward: it moved state)."""
+        sched, rows = self.scheduler, self.block_rows
+        mask = self.model.config.mask_token_id
+        finite = res[:, _R_DEC_FINITE]
+        blocks = res[:, _R_SPEC:_R_SPEC + rows]
+        denoise = commits = tokens = 0
+        for slot, req in live:
+            bp = fl.block[slot]
+            commit = bp.phase == PHASE_COMMIT
+            if commit:
+                req.flight_rows -= rows
+                req.flight_tokens -= bp.new_tokens
+            if not bool(finite[slot]):
+                self._quarantine(slot, req, "block forward")
+                continue
+            block = [int(t) for t in blocks[slot]]
+            req.block_forwards += 1
+            if req.block_steps is not None:
+                req.block_steps.append(
+                    (bp.start, "commit" if commit else "denoise", block))
+            if not commit:
+                denoise += 1
+                if self.unmask_rule == "low_confidence_dynamic":
+                    # the device's to say: read before the next plan
+                    req.block_masked = block.count(mask)
+                continue
+            commits += 1
+            self._m_block_steps.observe(bp.denoised)
+            # the block's rows past what the prompt left in it, as far as
+            # the request asked for (the rest of its last block is dropped)
+            at = len(req.prompt) + len(req.output) - bp.start
+            new = block[at:at + bp.new_tokens]
+            if req.eos_token_id is not None and req.eos_token_id in new:
+                new = new[:new.index(req.eos_token_id) + 1]
+            req.cached_tokens += rows
+            for tok in new:
+                req.output.append(tok)
+                self._emit_token(req, tok)
+            tokens += len(new)
+            if new:
+                self._observe_first_token(req)
+            if req.cached_tokens % self.block_size == 0:
+                self.allocator.commit_cached(req.req_id, req.prefix,
+                                             req.cached_tokens)
+            if req.done:
+                sched.finish(slot)
+        # the registry and its plain-int mirror: applied forwards only
+        for key, counter, n in (
+                ("denoise", self._m_block_denoise, denoise),
+                ("commit", self._m_block_commit, commits),
+                ("rows", self._m_block_rows, rows * (denoise + commits)),
+                ("tokens", self._m_block_tokens, tokens)):
+            self.block_counts[key] += n
+            counter.inc(n)
+        self._m_tokens.inc(tokens)
+        return denoise + tokens
 
     def _land(self, n: Optional[int] = None) -> int:
         """Read and apply the ``n`` oldest dispatches in flight (all of
@@ -2111,6 +2420,10 @@ class ServingEngine:
             has free — ``ensure_decode_capacity`` would preempt, and a
             victim's recompute restarts from applied counts."""
         sched = self.scheduler
+        if self.block_rows and self.unmask_rule == "low_confidence_dynamic":
+            # the device decides when a block is full: the host reads it
+            # before it plans that slot's next forward
+            return False
         return (self._draft_model is None
                 and not self.allocator.num_pending
                 and not any(r.prefill_only for r in sched.running.values())
@@ -2379,7 +2692,13 @@ class ServingEngine:
             # worst-case prefix at a late re-admission includes every
             # token the request may ever generate
             prefix = len(r.prompt) + r.max_new_tokens
-            steps += -(-prefix // self.chunk_tokens) + r.max_new_tokens + 2
+            forwards = r.max_new_tokens
+            if self.block_rows:
+                # denoising_steps + 1 forwards a block, the first and the
+                # last block partly filled
+                forwards = ((r.max_new_tokens // self.block_rows + 2)
+                            * (self.denoising_steps + 1))
+            steps += -(-prefix // self.chunk_tokens) + forwards + 2
             if self.host_cache is not None:
                 # a fully host-warm prefix promotes promote_parallelism
                 # blocks per iteration while the request waits PROMOTING

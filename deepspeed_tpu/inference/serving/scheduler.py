@@ -185,6 +185,18 @@ class Request:
     #: prompt's KV, emit NO tokens, and finish OK the moment prefill
     #: completes — the decode leg streams on another replica
     prefill_only: bool = False
+    #: generation by diffusion over blocks (docs/serving.md): rows of the
+    #: open block that still hold the mask token and denoise forwards it
+    #: has taken, both AS DISPATCHED (-1: no block open, the next forward
+    #: opens one at ``planned_cached``) ...
+    block_masked: int = -1
+    block_step: int = 0
+    #: block-lane forwards of either phase applied to this request
+    block_forwards: int = 0
+    #: ... and, where ``submit(record_blocks=True)`` asked for it, the
+    #: trajectory as applied: one ``(block start, "denoise" | "commit",
+    #: the block's tokens after the forward)`` a forward
+    block_steps: Optional[List[tuple]] = None
 
     @property
     def prefix(self) -> List[int]:
@@ -250,6 +262,11 @@ class ContinuousBatchingScheduler:
         self.num_slots = num_slots
         self.alloc = allocator
         self.max_blocks_per_seq = max_blocks_per_seq
+        #: rows a slot's next forward writes past its committed rows: 1
+        #: token a step, or a whole block where generation is by
+        #: diffusion over blocks (the engine sets it) — what growth
+        #: covers, and the unit a prompt's prefill is cut to
+        self.step_rows = 1
         #: submit() sheds beyond this many waiting requests (0 = unbounded)
         self.max_queue_depth = max_queue_depth
         #: preemption cap per request: at the cap the request is pinned
@@ -320,7 +337,8 @@ class ContinuousBatchingScheduler:
         """Blocks :meth:`ensure_decode_capacity` would append right now
         — what the pool must hold free for it to preempt nobody."""
         return sum(
-            max(0, self.alloc.blocks_for_tokens(r.planned_cached + 1)
+            max(0, self.alloc.blocks_for_tokens(r.planned_cached
+                                                + self.step_rows)
                 - self.alloc.blocks_held(r.req_id))
             for r in self.running.values()
             if not r.prefilling and not r.spent)
@@ -523,7 +541,10 @@ class ContinuousBatchingScheduler:
             req.state = RequestState.RUNNING
             if req.admit_time is None:
                 req.admit_time = time.perf_counter()
-            req.prefill_target = len(req.prefix)
+            # (whole blocks of step_rows: what is left of the prompt
+            # rides the first block's forwards)
+            req.prefill_target = (len(req.prefix) // self.step_rows
+                                  * self.step_rows)
             req.cached_tokens = cached     # hit blocks skip prefill
             req.cache_hit_tokens += cached
             self.running[slot] = req
@@ -585,7 +606,8 @@ class ContinuousBatchingScheduler:
             if req is None or req.prefilling or req.spent:
                 continue
             while req.state is RequestState.RUNNING:
-                need = self.alloc.blocks_for_tokens(req.planned_cached + 1)
+                need = self.alloc.blocks_for_tokens(req.planned_cached
+                                                    + self.step_rows)
                 have = self.alloc.blocks_held(req.req_id)
                 if have >= need:
                     break
@@ -698,6 +720,9 @@ class ContinuousBatchingScheduler:
         req.state = RequestState.WAITING
         req.cached_tokens = 0
         req.prefill_target = 0
+        # an open block's denoise progress is not kept: recompute
+        # restarts from whole committed blocks
+        req.block_masked, req.block_step = -1, 0
         req.preemptions += 1
         self.preemption_count += 1
         if _REQ_TRACE.enabled:

@@ -3,4 +3,4 @@ from .transformer import (TransformerConfig, TransformerLM,  # noqa: F401
                           granite_hybrid_config, kimi_linear_config,
                           longcat_flash_config, neox_config,
                           openpangu_ultra_moe_config, phi4_flash_config,
-                          zaya_config)
+                          sdar_moe_config, zaya_config)

@@ -196,8 +196,10 @@ class TransformerConfig:
                 f"{cls.__name__} has no {', '.join(gone)}: the standard "
                 f"block is x + attn + mlp and carries no expert layer. "
                 f"The expert layer is moe/dropless.py, inside a block of "
-                f"its own (models/cca_moe.py trains, models/latent_moe.py "
-                f"serves); experts sharded across chips are ROADMAP B6")
+                f"its own (models/cca_moe.py trains; models/latent_moe.py "
+                f"serves over the latent pool, models/"
+                f"block_diffusion_moe.py over the k/v pool); experts "
+                f"sharded across chips are ROADMAP B6")
         return super().__new__(cls)
 
     @classmethod
@@ -498,6 +500,39 @@ def kimi_linear_config(size: str = "48b-a3b", **kw) -> TransformerConfig:
         "layernorm_eps": 1e-5, **KIMI_LINEAR_SIZES[size], **kw})
 
 
+SDAR_MOE_SIZES = {
+    # https://huggingface.co/JetLM/SDAR-30B-A3B-Chat/blob/main/config.json
+    # (sdar_moe; block_length and mask_token_id are the released Chat
+    # models' generation defaults, config.json names neither)
+    "30b-a3b": dict(num_layers=48, num_heads=32, num_kv_heads=4,
+                    head_dim=128, d_model=2048, d_ff=6144,
+                    vocab_size=151936, max_seq_len=32768,
+                    rotary_base=1e6, expert_d_ff=768,
+                    n_routed_experts=128, moe_topk=8, norm_topk_prob=True,
+                    block_length=4, mask_token_id=151669),
+}
+
+
+def sdar_moe_config(size: str = "30b-a3b", **kw) -> TransformerConfig:
+    """SDAR-MoE's language model (``sdar_moe``): grouped-query attention
+    with an RMSNorm over each head of q and of k before the rotation,
+    every layer a softmax top-8 router over routed experts with
+    renormalised weights, no shared expert — generated by diffusion over
+    blocks of ``block_length`` positions whose rows see each other both
+    ways (``models/block_diffusion_moe.py``).  ``size`` names a published
+    set of widths; depth, vocabulary, served positions, ``block_length``,
+    ``mask_token_id`` and ``experts_held`` (the chip's share of a
+    deployment) come as keywords."""
+    from .block_diffusion_moe import BlockDiffusionMoEConfig
+    if "experts_held" in kw:
+        kw["experts_held"] = tuple(kw["experts_held"])
+    return BlockDiffusionMoEConfig(**{
+        "pos_embedding": "rotary", "rotary_interleaved": False,
+        "norm_type": "rmsnorm", "gated_mlp": True, "activation": "silu",
+        "use_bias": False, "tie_embeddings": False, "layernorm_eps": 1e-6,
+        **SDAR_MOE_SIZES[size], **kw})
+
+
 def build_model(config: TransformerConfig, **kw) -> "TransformerLM":
     """The model that runs ``config``'s block: ``TransformerLM`` for the
     standard block, the config's own class (``config.model_class()``) for
@@ -520,6 +555,10 @@ class TransformerLM:
     #: operand, one a kind of paged state (``block_allocator``'s layer
     #: kinds): every layer's pages under one table for the standard block
     TABLE_KINDS: Tuple[str, ...] = ("full",)
+    #: rows a slot rides a serving dispatch with where generation is by
+    #: diffusion over blocks (the engine's block lane); 0: one token a
+    #: slot a step, left to right
+    block_rows: int = 0
 
     def __init__(self, config: TransformerConfig,
                  constrain: Optional[Callable] = None,
@@ -1193,7 +1232,15 @@ class TransformerLM:
     def _paged_attend(self, q, pk, pv, kscale, vscale, kv_bits,
                       st: PagedMixedState, ctable):
         """The mixed step's kernels over the written pools: every lane's
-        rows attend and concatenate back, ``[B + B*S + C, nh, hd]``."""
+        rows attend and concatenate back, ``[B + B*S + C, nh, hd]``.
+        Which lanes a family has: the standard block all three here
+        (decode; the draft run, one decode call a depth, when a draft is
+        armed; the causal chunk); the per-slot-state blocks
+        (``hybrid_ssm.py``, ``ssd_hybrid.py``) and the latent blocks
+        decode and chunk through attends of their own and refuse the
+        draft; the block-diffusion block (``block_diffusion_moe.py``) has
+        neither decode nor draft lane — a BLOCK lane (every slot's
+        ``block_length`` rows in one call) and a block-causal chunk."""
         from ..ops.transformer.paged_decode_attention import (
             paged_decode_attention, paged_prefill_attention)
         tables, lens = st.block_tables, st.lens

@@ -130,12 +130,15 @@ PHASE_SPANS = tuple(f"serving/{p}" for p in PHASES)
 #: out of and into the buffer the step carries; ``kda_proj``: a gated
 #: delta-rule layer's projections, convolutions, gates, gated norm and
 #: output projection; ``kda_scan``: its recurrence, which names its two
-#: lanes one level further in (``SCOPE_LANES``).
+#: lanes one level further in (``SCOPE_LANES``); ``block_unmask``:
+#: generation by diffusion over blocks — a drawn token's confidence, the
+#: ranking of a block's masked rows by it and the transfer of the best.
 SCOPES = ("embed", "norm", "residual", "attn_proj", "attn_kernel",
           "pool_write", "mlp", "router", "expert_layout", "experts",
           "shared_expert", "head", "sample", "loss", "optimizer",
           "zero_comm", "indexer", "select", "attn_conv", "ssm_proj",
-          "ssm_scan", "gmu", "state_io", "kda_proj", "kda_scan")
+          "ssm_scan", "gmu", "state_io", "kda_proj", "kda_scan",
+          "block_unmask")
 #: an instruction under no declared scope / one whose key two loaded
 #: programs map to different scopes
 UNNAMED, AMBIGUOUS = "unnamed", "ambiguous"
@@ -198,6 +201,11 @@ COUNTERS = ("dispatches", "decode_rows", "chunk_rows", "rows_computed",
             # layer) pairs through its decode update and its chunk's
             # blocked form; 0 for other blocks
             "kda_decode_rows", "kda_chunk_rows",
+            # generation by diffusion over blocks (the engine's block
+            # lane), counted on the host: rows of live slots dispatched
+            # (block_length a slot a forward), slot forwards that were
+            # commits, and tokens those made visible; 0 for other blocks
+            "block_rows", "block_commits", "block_tokens",
             # the dispatch in flight (docs/serving.md): dispatches that
             # were enqueued before their predecessor's result was read,
             # and rows whose result was ignored because their request

@@ -71,6 +71,13 @@ Kernel design:
     block, or from whatever an unfetched page left in the buffer, must
     never reach the accumulator (the PR 6 invariant, pinned by the
     NaN-garbage parity tests).
+  * VISIBILITY BY BLOCKS (``block_rows``, static; generation by diffusion
+    over blocks): a row's offset is rounded up to the end of the block of
+    ``block_rows`` rows it stands in before the kernel sees it, so row
+    ``c`` sees every key up to ``base + (c // block_rows) * block_rows +
+    block_rows - 1`` (bounded by ``total`` as ever) — its own block both
+    ways, every earlier block whole.  The kernel is the same program: only
+    the offsets it is handed differ.  ``base`` must be a block's start.
 """
 from __future__ import annotations
 
@@ -490,15 +497,21 @@ def _check_args(q_heads, d, pool_k, pool_v, k_scale, v_scale, kv_bits,
 
 def _paged_attention(q, pool_k, pool_v, base, total, block_tables, *,
                      sm_scale, interpret, k_scale, v_scale, kv_bits,
-                     pages_per_program, what, window=None):
+                     pages_per_program, what, window=None, block_rows=None):
     """q [B, C, H, D] — C query rows per slot at absolute positions
     ``base[b] .. base[b] + C - 1``; ``total[b]`` bounds the attended
     prefix; block_tables [B, pages].  ``window`` (static; None = every
     earlier key): a row attends the ``window`` keys that end at its own
     position, and the slot's walk starts at the page that holds position
     ``max(0, base[b] - window + 1)`` — table entries before it are never
-    read.  Returns [B, C, H, D]."""
+    read.  ``block_rows`` (static; must divide C, ``base`` a multiple of
+    it): row ``c`` sees the keys up to the END of the block of that many
+    rows it stands in instead of up to itself.  Returns [B, C, H, D]."""
     b, c, h, d = q.shape
+    if block_rows and (c % block_rows or window is not None):
+        raise ValueError(
+            f"{what}: block_rows {block_rows} must divide the {c} query "
+            f"rows a slot, and takes no window")
     hkv, d_eff = _check_args(h, d, pool_k, pool_v, k_scale, v_scale,
                              kv_bits, what)
     block, lanes = pool_k.shape[1:]
@@ -556,8 +569,10 @@ def _paged_attention(q, pool_k, pool_v, base, total, block_tables, *,
     eye = jnp.eye(heads, dtype=q.dtype)
     qg = jnp.einsum("bwsjgce,jk->bwsjgcke", qg, eye)
     qg = qg.reshape(b, nwin, nsplit, rows, width)
-    coff = jnp.tile(jnp.arange(c, dtype=jnp.int32),
-                    heads * groups).reshape(rows, 1)
+    coff = jnp.arange(c, dtype=jnp.int32)
+    if block_rows:
+        coff = coff // block_rows * block_rows + (block_rows - 1)
+    coff = jnp.tile(coff, heads * groups).reshape(rows, 1)
     rhead = jnp.repeat(jnp.arange(heads, dtype=jnp.int32),
                        groups * c).reshape(rows, 1)
 
@@ -658,7 +673,8 @@ def paged_prefill_attention(q: jnp.ndarray, pool_k: jnp.ndarray,
                             kv_bits: int = 0,
                             pages_per_program: Optional[int] = None,
                             window: Optional[int] = None,
-                            tile_rows: Optional[int] = None) -> jnp.ndarray:
+                            tile_rows: Optional[int] = None,
+                            block_rows: Optional[int] = None) -> jnp.ndarray:
     """Causal chunked-prefill attention for ONE slot through its block
     table (the Sarathi-Serve mixed-batch building block).
 
@@ -676,7 +692,9 @@ def paged_prefill_attention(q: jnp.ndarray, pool_k: jnp.ndarray,
     that many rows, each walking only as far as its own last row sees
     (and, with a ``window``, from where its first row's begins): what a
     chunk of many rows x grouped heads needs to fit VMEM.
-    Returns [C, H, D] in q's dtype.
+    ``block_rows`` (static): BLOCK-causal instead — a row sees its whole
+    block of that many rows (``base``, ``chunk_len`` and a tile are whole
+    blocks).  Returns [C, H, D] in q's dtype.
     """
     if block_table.ndim != 1:
         raise ValueError(
@@ -697,8 +715,34 @@ def paged_prefill_attention(q: jnp.ndarray, pool_k: jnp.ndarray,
         sm_scale=sm_scale, interpret=interpret,
         k_scale=k_scale, v_scale=v_scale, kv_bits=kv_bits,
         pages_per_program=pages_per_program,
-        what="paged_prefill_attention", window=window)
+        what="paged_prefill_attention", window=window,
+        block_rows=block_rows)
     return out.reshape(q.shape).astype(q.dtype)
+
+
+def paged_block_attention(q: jnp.ndarray, pool_k: jnp.ndarray,
+                          pool_v: jnp.ndarray, base: jnp.ndarray,
+                          active: jnp.ndarray, block_tables: jnp.ndarray,
+                          sm_scale: Optional[float] = None,
+                          interpret: Optional[bool] = None,
+                          pages_per_program: Optional[int] = None
+                          ) -> jnp.ndarray:
+    """The block lane of generation by diffusion over blocks: q [B, C, H,
+    D] — EVERY slot's current block of C rows at positions ``base[b] ..
+    base[b] + C - 1`` (their k/v already in the pool), each row seeing the
+    whole prefix and its whole block, ``base[b] + C`` keys; ``active``
+    [B] (0: the slot rides no block, zero rows back).  ONE walk of a
+    slot's pages serves its C rows of every head.  Returns [B, C, H, D]
+    in q's dtype."""
+    c = q.shape[1]
+    base = jnp.asarray(base, jnp.int32).reshape(q.shape[0])
+    total = jnp.where(jnp.asarray(active).reshape(q.shape[0]) > 0,
+                      base + c, 0)
+    return _paged_attention(
+        q, pool_k, pool_v, base, total, block_tables, sm_scale=sm_scale,
+        interpret=interpret, k_scale=None, v_scale=None, kv_bits=0,
+        pages_per_program=pages_per_program, what="paged_block_attention",
+        block_rows=c).astype(q.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -994,7 +1038,7 @@ def _reference_cache(pool_k, pool_v, k_scale, v_scale, kv_bits, d):
 
 
 def _reference(q, pool_k, pool_v, base, total, block_tables, k_scale,
-               v_scale, kv_bits, window=None):
+               v_scale, kv_bits, window=None, block_rows=None):
     """Readable float32 jnp reference for the kernel (tests and the
     on-chip smoke pin against this): per slot, dequantize if needed,
     gather the table's pages into a contiguous cache and run masked
@@ -1015,7 +1059,10 @@ def _reference(q, pool_k, pool_v, base, total, block_tables, k_scale,
         s = jnp.einsum("chd,shd->chs", qi.astype(jnp.float32),
                        k.astype(jnp.float32)) / math.sqrt(d)
         pos = jnp.arange(npages * block)
-        qpos = bs + jnp.arange(c)[:, None, None]
+        coff = jnp.arange(c)
+        if block_rows:
+            coff = coff // block_rows * block_rows + (block_rows - 1)
+        qpos = bs + coff[:, None, None]
         seen = (pos <= qpos) & (pos < tot)
         live = pos < tot
         if window is not None:
@@ -1031,13 +1078,13 @@ def _reference(q, pool_k, pool_v, base, total, block_tables, k_scale,
 
 def paged_prefill_reference(q, pool_k, pool_v, base, chunk_len,
                             block_table, k_scale=None, v_scale=None,
-                            kv_bits=0, window=None):
+                            kv_bits=0, window=None, block_rows=None):
     """jnp reference for :func:`paged_prefill_attention`.  Padding
     queries (index >= chunk_len) are returned as zeros."""
     base = jnp.asarray(base, jnp.int32)
     out = _reference(q[None], pool_k, pool_v, base[None],
                      (base + chunk_len)[None], block_table[None], k_scale,
-                     v_scale, kv_bits, window)[0]
+                     v_scale, kv_bits, window, block_rows)[0]
     valid = (jnp.arange(q.shape[0]) < chunk_len)[:, None, None]
     return jnp.where(valid, out, 0.0).astype(q.dtype)
 

@@ -180,6 +180,10 @@ SERVING_NO_PROGRESS_STEPS_DEFAULT = 64
 # 1..spec_k+1 tokens, token-exact vs plain decode under the same key.
 # Only read when serving_engine(draft_model=...) arms a draft.
 SERVING_SPEC_K_DEFAULT = 3
+# generation by diffusion over blocks: the published sampler's defaults
+SERVING_DENOISING_STEPS_DEFAULT = 4
+SERVING_REMASKING_STRATEGY_DEFAULT = "low_confidence_dynamic"
+SERVING_CONFIDENCE_THRESHOLD_DEFAULT = 0.9
 # default per-request TTL (submit -> terminal), swept every step() for
 # WAITING and RUNNING requests; 0 = no deadline. submit(deadline_s=...)
 # overrides per request.

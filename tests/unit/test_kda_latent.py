@@ -20,6 +20,8 @@ seeded weights.
   - the shares add up; every refusal's sentence; the published sizes'
     parameter count and plan.
 """
+import time
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -270,6 +272,9 @@ def test_the_engine_serves_the_block(built):
     model, params = built
     overlap = get_overlap_profiler()
     overlap.configure(enabled=True)
+    # (this test's iterations alone: an earlier test of the same worker
+    # may have left records of its own in the ring)
+    began = time.perf_counter()
     try:
         srv = serving_engine(model, params)
         assert srv.prefix_cache is False
@@ -306,7 +311,7 @@ def test_the_engine_serves_the_block(built):
         assert rel_err(left[0], states[0]) < 1e-4
         assert rel_err(left[1], latents[0]) < 1e-5
         assert not any(srv.allocator.num_used_by_kind().values())
-        recs, _ = overlap.iterations(0.0, float("inf"))
+        recs, _ = overlap.iterations(began, float("inf"))
         recs = recs[recs["kind"] == "serving"]
         tokens = sum(len(r.prompt) + len(r.output) - 1 for r in reqs)
         assert recs["kda_decode_rows"].sum() + recs["kda_chunk_rows"].sum() \

@@ -594,5 +594,6 @@ def test_program_scopes_reads_the_loaded_step_program(srv):
     assert {"attn_proj", "attn_kernel", "mlp", "head"} <= found
     assert found <= set(overlap.SCOPES) | {overlap.UNNAMED,
                                            overlap.AMBIGUOUS}
-    # PR 48: + four of a hybrid block; PR 56: + two of a delta-rule layer
-    assert len(overlap.SCOPES) <= 25
+    # PR 48: + four of a hybrid block; PR 56: + two of a delta-rule layer;
+    # PR 58: + the block lane's unmasking
+    assert len(overlap.SCOPES) <= 26

@@ -224,6 +224,10 @@ def persistent_cache(tmp_path):
 
 def test_cache_reads_off_then_miss_then_hit(persistent_cache):
     prof = get_overlap_profiler()
+    # (the log keeps the OLDEST ``SETUP_LOG_CAP`` records of a process: a
+    # worker that has built a thousand programs in earlier tests would
+    # record none of this one's)
+    prof.clear_setup_log()
     prof.listen_for_builds()
 
     def build():

@@ -1195,6 +1195,7 @@ def build_ssd_hybrid_engine_step(devices, chunk):
     stand_in = SimpleNamespace(
         engine=SimpleNamespace(_model_params=lambda params, scales: params),
         _tp_model=model, _tp_draft=None, _draft_model=None, spec_k=0,
+        block_rows=0,
         decode_builds=0, tp_data_size=1, kv_bits=0, _donate=True,
         _pool_v=cache["v"], _pool_x=cache["extra"], _pool_spec=pool_spec,
         _pscale_spec=P(), _tp_scales=None, _tp_scale_specs=None,
@@ -1229,6 +1230,95 @@ def test_the_engines_mixed_program_keeps_the_ssd_update_one_pass(
     one_layer = assert_ssd_state_updated_in_place(compiled.as_text(), pools,
                                                   chunk)
     assert compiled.memory_analysis().temp_size_in_bytes < one_layer
+
+
+def block_diffusion_model():
+    """The ``sdar_moe`` block at its cell's widths (32 query heads over 4
+    kv heads of 128 with q / k norms, 16 of 128 experts of 768, blocks of
+    4, the whole vocabulary: the block lane's head is over 151,936
+    outputs) at two layers."""
+    from deepspeed_tpu.models import build_model, sdar_moe_config
+    return build_model(sdar_moe_config(
+        "30b-a3b", num_layers=2, max_seq_len=2048, experts_held=(0, 16)))
+
+
+#: the cell's engine: slots, pages a slot, blocks a layer
+BLOCK_DIFFUSION_SIZE = (40, 128, 2176)
+
+
+def build_block_diffusion_engine_step(devices, chunk):
+    """The ENGINE's step for generation by diffusion over blocks — the
+    block lane beside a chunk, ``block_unmask`` behind the head,
+    ``shard_map`` over the 1 x 1 serving submesh, the pools donated —
+    compiled for one v5e chip at the cell's sizes
+    (:func:`build_ssd_hybrid_engine_step`'s stand-in)."""
+    from types import SimpleNamespace
+    from deepspeed_tpu.inference.serving import engine as serving
+    from deepspeed_tpu.parallel import topology as topo
+    model = block_diffusion_model()
+    slots, pages, nb = BLOCK_DIFFUSION_SIZE
+    rows = model.block_rows
+    shapes = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0)))
+    cache = jax.eval_shape(
+        lambda: model.init_paged_cache(nb, 16, jnp.bfloat16))
+    mesh = build_mesh(MeshConfig(data=1, model=1), devices=devices[:1])
+    pool_spec = P(None, None, None, topo.MODEL_AXIS)
+    specs = model.partition_specs(shapes)
+    stand_in = SimpleNamespace(
+        engine=SimpleNamespace(_model_params=lambda params, scales: params),
+        _tp_model=model, _tp_draft=None, _draft_model=None, spec_k=0,
+        block_rows=rows, max_pages=pages, table_kinds=model.TABLE_KINDS,
+        unmask_rule="low_confidence_static", confidence_threshold=0.9,
+        decode_builds=0, tp_data_size=1, kv_bits=0, _donate=True,
+        _pool_v=cache["v"], _pool_x=None, _pool_spec=pool_spec,
+        _pscale_spec=P(), _tp_scales=None, _tp_scale_specs=None,
+        _tp_param_specs=specs, tp_mesh=mesh)
+    step = serving.ServingEngine._build_step(stand_in)
+
+    def placed(a, spec=P(), dtype=None):
+        return jax.ShapeDtypeStruct(a.shape, dtype or a.dtype,
+                                    sharding=NamedSharding(mesh, spec))
+
+    def ints(*shape):
+        return placed(jax.ShapeDtypeStruct(shape, jnp.int32))
+    counters = len(model.PAGED_COUNTERS)
+    operands = (
+        jax.tree_util.tree_map(
+            lambda a, spec: placed(a, spec, jnp.bfloat16), shapes, specs),
+        None, placed(cache["k"], pool_spec), placed(cache["v"], pool_spec),
+        None, None, None, ints(slots, serving._R_SPEC + rows + counters),
+        ints(slots, serving._SLOT_COLS + pages + rows),
+        ints(serving._CHUNK_HEAD + chunk))
+    return step.trace(*operands).lower(
+        lowering_platforms=("tpu",)).compile()
+
+
+@pytest.mark.parametrize("shape", list(HYBRID_CHUNK))
+def test_block_lane_walks_a_slots_pages_once_a_layer(
+        v5e_devices, compiled_kernels, step_programs, shape):
+    """The engine's step at the cell's sizes, compiled without a chip:
+    ONE ``paged_attention`` call a layer for the block lane — every
+    slot's 4 rows x 8 query heads a kv head, 32 rows a kv head, in one
+    walk of the slot's pages, not a call a row — and one more for the
+    chunk where the shape has one; the pools updated in place (nothing
+    shaped like a pool outside a fusion); and the temporaries stated:
+    the block lane's float32 logits over the whole vocabulary (40 x 4 x
+    151,936 x 4 B = 97 MB) and what the sampler keeps beside them stay
+    under 1 GiB — the cell has 2 GiB beside its weights and pool."""
+    import re
+    chunk = HYBRID_CHUNK[shape]
+    text, temp_bytes = step_programs(f"block-diffusion-{shape}")
+    calls = re.findall(r'custom-call\([^\n]*"tpu_custom_call"[^\n]*', text)
+    walks = [c for c in calls if "paged_attention" in c]
+    assert len(walks) == (2 if chunk else 1), [c[:120] for c in calls]
+    # the block lane's queries: [slots, head windows, 1, 32 rows, 128]
+    assert any("bf16[40,4,1,32,128]" in c for c in walks)
+    nb = BLOCK_DIFFUSION_SIZE[2]
+    pool = f"bf16[{2 * nb},16,512]"
+    for _name, result, opcode, line in unfused_instructions(text):
+        if opcode in ("copy", "slice", "dynamic-slice"):
+            assert pool not in result, line[:200]
+    assert temp_bytes < 1 << 30, temp_bytes
 
 
 def test_train_grad_compiles_on_four_chips(v5e_devices, compiled_kernels):
@@ -1874,6 +1964,12 @@ for _shape, _chunk in HYBRID_CHUNK.items():
     STEP_PROGRAMS[f"kda-latent-{_shape}"] = (
         lambda dev, c=_chunk: build_kda_latent_mixed(dev, c),
         _EXPERTS | {"shared_expert", "kda_proj", "kda_scan", "state_io"})
+
+
+for _shape, _chunk in HYBRID_CHUNK.items():
+    STEP_PROGRAMS[f"block-diffusion-{_shape}"] = (
+        lambda dev, c=_chunk: build_block_diffusion_engine_step(dev, c),
+        (_EXPERTS - {"mlp"}) | {"block_unmask", "sample"})
 
 
 STEP_PROGRAMS["train-moe-1chip"] = (
