@@ -567,21 +567,61 @@ LATENT_WALKS = ((128, 128, 256, 17408, 256, 3000),
                 (64, 48, 512, 12288, 1024, 5800))
 
 
-def latent_walk_times(block: int, reps: int = 20) -> dict:
+def walk_tables(rng, need, pages: int, nb: int) -> dict:
+    """Tables ``[slots, pages]`` for slots that hold ``need`` pages each, of
+    a pool of ``nb`` blocks: ``runs`` — every aligned run of ``PAGE_RUN``
+    entries consecutive pool blocks, as the cache manager hands them out —
+    and ``scattered`` — the same blocks permuted, as a pool that kept no
+    runs would hold."""
+    from deepspeed_tpu.ops.transformer.paged_decode_attention import PAGE_RUN
+    groups = rng.permutation((nb - 1) // PAGE_RUN)
+    spans = np.concatenate([[0], np.cumsum(-(-need // PAGE_RUN))])
+    out = {kind: np.zeros((len(need), pages), np.int32)
+           for kind in ("runs", "scattered")}
+    for s, n in enumerate(need):
+        ids = (1 + groups[spans[s]:spans[s + 1], None] * PAGE_RUN
+               + np.arange(PAGE_RUN)).reshape(-1)
+        out["runs"][s, :n] = ids[:n]
+        out["scattered"][s, :n] = rng.permutation(ids)[:n]
+    return out
+
+
+def _call_us(fn, q, *rest, reps: int = 20) -> float:
+    """Microseconds a call of ``fn(q, *rest)``, ``reps`` of them chained
+    on the device (each call's query waits for the one before; the best of
+    three): a dispatch from this host costs some 250 us, more than the
+    calls timed here."""
+    import jax
+    import jax.numpy as jnp
+
+    @jax.jit
+    def chained(q, *rest):
+        def one(_, q):
+            # (every row of the result: no call that made one may go)
+            return q + (jnp.sum(fn(q, *rest)[..., 0]) * 0).astype(q.dtype)
+        return jax.lax.fori_loop(0, reps, one, q)
+    chained(q, *rest).block_until_ready()
+    best = float("inf")
+    for _ in range(3):
+        t0 = time.perf_counter()
+        chained(q, *rest).block_until_ready()
+        best = min(best, (time.perf_counter() - t0) / reps)
+    return round(best * 1e6, 1)
+
+
+def latent_walk_times(block: int) -> dict:
     """The latent kernel's decode call at the two cells' pools and batches
     (17,408 blocks, 128 slots x 128 heads, contexts 256-3,000;
     12,288 blocks, 48 slots x 64 heads, contexts 1,024-5,800: a kernel's
     time depends on the pool it walks) over a table whose aligned runs of
     ``PAGE_RUN`` pages are consecutive pool blocks, as the cache manager
     hands them out, and over a scattered one, as a pool that kept no runs
-    would hold: microseconds a call (the best of three timings of
-    ``reps`` chained calls), nanoseconds a page, and the share of the
-    roofline (2 x heads x 1,088 operations a context token against 197
-    TFLOP/s, 1,152 bytes against 819 GB/s)."""
-    import jax
+    would hold: microseconds a call (``_call_us``), nanoseconds a page,
+    and the share of the roofline (2 x heads x 1,088 operations a context
+    token against 197 TFLOP/s, 1,152 bytes against 819 GB/s)."""
     import jax.numpy as jnp
     from deepspeed_tpu.ops.transformer.paged_decode_attention import (
-        PAGE_RUN, mla_paged_decode_attention)
+        mla_paged_decode_attention)
     rng = np.random.default_rng(SEED + 4)
     out = {}
     for heads, slots, pages, nb, lo, hi in LATENT_WALKS:
@@ -594,34 +634,13 @@ def latent_walk_times(block: int, reps: int = 20) -> dict:
                          jnp.bfloat16)
         qr = jnp.asarray(rng.standard_normal((slots, heads, 64)) * 0.3,
                          jnp.bfloat16)
-
-        @jax.jit
-        def chained(ql, qr, pool, lens, tables):
-            def one(_, lens):
-                o = mla_paged_decode_attention(ql, qr, pool, lens, tables,
-                                               192 ** -0.5)
-                return lens + (o[0, 0, 0] * 0).astype(jnp.int32)
-            return jax.lax.fori_loop(0, reps, one, lens)
         floor = max(2.0 * lens.sum() * heads * 1088 / 197e12,
                     lens.sum() * 1152 / 819e9)
         cell = {"pages": int(need.sum())}
-        groups = rng.permutation((nb - 1) // PAGE_RUN)
-        spans = np.concatenate([[0], np.cumsum(-(-need // PAGE_RUN))])
-        for kind in ("runs", "scattered"):
-            tables = np.zeros((slots, pages), np.int32)
-            for s in range(slots):
-                ids = (1 + groups[spans[s]:spans[s + 1], None] * PAGE_RUN
-                       + np.arange(PAGE_RUN)).reshape(-1)
-                if kind == "scattered":
-                    ids = rng.permutation(ids)
-                tables[s, :need[s]] = ids[:need[s]]
-            args = (ql, qr, pool, jnp.asarray(lens), jnp.asarray(tables))
-            chained(*args).block_until_ready()
-            best = float("inf")
-            for _ in range(3):
-                t0 = time.perf_counter()
-                chained(*args).block_until_ready()
-                best = min(best, (time.perf_counter() - t0) / reps)
+        for kind, tables in walk_tables(rng, need, pages, nb).items():
+            best = 1e-6 * _call_us(
+                lambda *a: mla_paged_decode_attention(*a, 192 ** -0.5), ql,
+                qr, pool, jnp.asarray(lens), jnp.asarray(tables))
             cell[kind] = {"us": round(best * 1e6, 1),
                           "ns_a_page": round(best * 1e9 / need.sum(), 1),
                           "roofline_pct": round(100 * floor / best, 1)}
@@ -916,63 +935,87 @@ def kda_phase(device: dict):
             "decode_update_max_abs_err": float(decode_err)}
 
 
+#: the decode calls of the two guard cells that run the plain paged kernel
+#: beside SDAR's: (slots, query heads, kv heads, head dim, table pages,
+#: pool blocks, shortest and longest context)
+PAGED_WALKS = {"granite": (64, 32, 8, 64, 256, 8192, 200, 1800),
+               "pythia": (24, 16, 16, 128, 128, 3072, 200, 2048)}
+
+
 def block_lane_phase(device: dict, block: int = SERVING["kv_block_size"]):
     """Generation by diffusion over blocks, the builder's probe (no cell
     runs it): ``paged_block_attention`` at its cell's widths — 48 slots, 4
     rows a slot, 32 query heads over 4 kv heads of 128 — against the
     float32 reference under the block mask, and its time at the mix's
-    mean context and at its longest, beside four calls of the decode
-    kernel over the same pages (what a lane that walked a slot's pages
-    once a row would cost)."""
+    mean context and at its longest, on tables of runs (what the cache
+    manager hands out: one DMA an operand a run) and on scattered ones
+    (a DMA a page), beside four calls of the decode kernel over the same
+    pages (what a lane that walked a slot's pages once a row would
+    cost).  ``decode_walk``: the decode call of Granite's and of Pythia's
+    cell on the same two kinds of table, the plain kernel alone."""
     import jax
     import jax.numpy as jnp
     from deepspeed_tpu.ops.transformer import paged_decode_attention as pda
 
     rng = np.random.default_rng(SEED + 10)
-    slots, rows, heads, kvh, hd, pages, nb = 48, 4, 32, 4, 128, 128, 2560
+    slots, rows, heads, kvh, hd, pages, nb = 48, 4, 32, 4, 128, 128, 4864
     pool_k, pool_v = (jnp.asarray(rng.standard_normal((nb, block, kvh * hd)),
                                   jnp.bfloat16) for _ in range(2))
-    tables = jnp.asarray(rng.permutation(nb - 1)[:slots * (nb // slots - 1)]
-                         .reshape(slots, -1)[:, :pages] + 1, jnp.int32)
-    tables = jnp.pad(tables, ((0, 0), (0, pages - tables.shape[1])))
     q = jnp.asarray(rng.standard_normal((slots, rows, heads, hd)),
                     jnp.bfloat16)
     lane = jax.jit(pda.paged_block_attention)
-    four = jax.jit(lambda q, pk, pv, base, tables: jnp.stack(
-        [pda.paged_decode_attention(q[:, i], pk, pv, base + rows, tables)
-         for i in range(rows)], axis=1))
+
+    def four(q, pk, pv, base, tables):
+        return jnp.stack(
+            [pda.paged_decode_attention(q[:, i], pk, pv, base + rows, tables)
+             for i in range(rows)], axis=1)
     out = {"phase": "block_lane", **device}
+    active = jnp.asarray(np.arange(slots) % 7 != 3, jnp.int32)
+    live = np.asarray(active) > 0
+    every = jnp.ones_like(active)
     for name, context in (("mean", 644), ("longest", 1532)):
         base = jnp.full((slots,), context // rows * rows - rows, jnp.int32)
-        active = jnp.asarray(np.arange(slots) % 7 != 3, jnp.int32)
-        got = lane(q, pool_k, pool_v, base, active, tables)
-        want = pda._reference(
-            q.astype(jnp.float32), pool_k, pool_v, base,
-            jnp.where(active > 0, base + rows, 0), tables, None, None, 0,
-            block_rows=rows)
-        live = np.asarray(active) > 0
-        err = float(jnp.max(jnp.abs(got.astype(jnp.float32)[live]
-                                    - want[live])))
-        check(err < 2e-2, f"block lane off its reference by {err} at "
-              f"{context}")
-        check(not bool(jnp.any(got[~live] != 0)), "an idle slot's rows "
-              "are not zero")
-        every = jnp.ones_like(active)
-        for fn, args, key in ((lane, (q, pool_k, pool_v, base, every,
-                                      tables), "lane_us"),
-                              (four, (q, pool_k, pool_v, base, tables),
-                               "four_decode_calls_us")):
-            jax.block_until_ready(fn(*args))
-            t0 = time.perf_counter()
-            for _ in range(20):
-                o = fn(*args)
-            jax.block_until_ready(o)
-            out[f"{name}_{key}"] = round(
-                (time.perf_counter() - t0) / 20 * 1e6, 1)
-        out[f"{name}_max_abs_err"] = err
+        need = np.full((slots,), -(-context // block))
+        for kind, tables in walk_tables(rng, need, pages, nb).items():
+            tables = jnp.asarray(tables)
+            got = lane(q, pool_k, pool_v, base, active, tables)
+            want = pda._reference(
+                q.astype(jnp.float32), pool_k, pool_v, base,
+                jnp.where(active > 0, base + rows, 0), tables, None, None,
+                0, block_rows=rows)
+            err = float(jnp.max(jnp.abs(got.astype(jnp.float32)[live]
+                                        - want[live])))
+            check(err < 2e-2, f"block lane off its reference by {err} at "
+                  f"{context} on {kind} tables")
+            check(not bool(jnp.any(got[~live] != 0)), "an idle slot's rows "
+                  "are not zero")
+            key = name if kind == "runs" else f"{name}_{kind}"
+            out[f"{key}_lane_us"] = _call_us(
+                pda.paged_block_attention, q, pool_k, pool_v, base, every,
+                tables)
+            out[f"{key}_max_abs_err"] = err
+        out[f"{name}_four_decode_calls_us"] = _call_us(
+            four, q, pool_k, pool_v, base, tables)
         # the context's K and V once, at the chip's memory rate
         out[f"{name}_bytes_floor_us"] = round(
             slots * 2 * context * kvh * hd * 2 / 819e9 * 1e6, 1)
+    out["decode_walk"] = {}
+    for cell, (slots, heads, kvh, hd, pages, nb, lo, hi) in \
+            PAGED_WALKS.items():
+        lens = rng.integers(lo, hi, slots).astype(np.int32)
+        pool_k, pool_v = (jnp.asarray(
+            rng.standard_normal((nb, block, kvh * hd), dtype=np.float32),
+            jnp.bfloat16) for _ in range(2))
+        q = jnp.asarray(rng.standard_normal((slots, heads, hd)),
+                        jnp.bfloat16)
+        walk = {"bytes_floor_us": round(
+            2 * int(lens.sum()) * kvh * hd * 2 / 819e9 * 1e6, 1)}
+        for kind, tables in walk_tables(rng, -(-lens // block), pages,
+                                        nb).items():
+            walk[f"{kind}_us"] = _call_us(
+                pda.paged_decode_attention, q, pool_k, pool_v,
+                jnp.asarray(lens), jnp.asarray(tables))
+        out["decode_walk"][cell] = walk
     return out
 
 

@@ -114,8 +114,11 @@ class BlockDiffusionMoELM(TransformerLM):
     whole sequence under the block mask, ``_apply_paged_block`` the
     serving step."""
 
-    #: what ``_apply_paged_block`` counts in the program, a dispatch
-    PAGED_COUNTERS = dropless.COUNTERS
+    #: what ``_apply_paged_block`` counts in the program, a dispatch: the
+    #: expert layers' counters, then the pages the layers' walks (the block
+    #: lane's and the chunk's) were handed and those of them in runs
+    PAGED_COUNTERS = dropless.COUNTERS + ("kv_pages_read",
+                                          "kv_pages_in_runs")
 
     def __init__(self, config: BlockDiffusionMoEConfig, constrain=None,
                  block_transform=None):
@@ -461,6 +464,16 @@ class BlockDiffusionMoELM(TransformerLM):
         x = self._norm_fn("head")(params["ln_f"], x[:, :slots * bk])
         with jax.named_scope("head"):
             logits = self._project(params, x)[0].reshape(slots, bk, -1)
+        with jax.named_scope("pool_write"):
+            from ..ops.transformer.paged_decode_attention import walk_pages
+            read, walked = jnp.where(act, lens + bk, 0), tables
+            if cw:
+                read = jnp.append(read, jnp.where(
+                    chunk_len > 0, chunk_start + chunk_len, 0))
+                walked = jnp.concatenate([tables, tables[chunk_slot][None]])
+            counters = jnp.concatenate([counters, nl * jnp.stack(
+                walk_pages(walked, read, cache["k"].shape[2]))
+                .astype(jnp.int32)])
         new_cache = {"k": pools[0].reshape(nl, nb, *pools[0].shape[1:]),
                      "v": pools[1].reshape(nl, nb, *pools[1].shape[1:]),
                      "block_tables": tables, "lens": lens,

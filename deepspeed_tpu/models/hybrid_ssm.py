@@ -345,10 +345,12 @@ class HybridSSMLM(PerSlotStateLM):
     #: FULL layer's pages were handed, keys the window layers' walks were,
     #: (row, state-space layer) pairs through the chunk scan and through
     #: the decode update, live rows that stopped before the cross decoder,
-    #: chunks that started a slot's state from zero
+    #: chunks that started a slot's state from zero, and the pages all
+    #: those walks were handed with those of them in runs
     PAGED_COUNTERS = ("kv_tokens_read_full", "kv_tokens_read_window",
                       "ssm_chunk_rows", "ssm_decode_rows",
-                      "cross_rows_spared", "state_slots_started")
+                      "cross_rows_spared", "state_slots_started",
+                      "kv_pages_read", "kv_pages_in_runs")
     KV_BITS_REFUSAL = ("the window layers' walk starts inside a slot's "
                        "table, and the quantized pool's scale rows take no "
                        "first page")
@@ -756,6 +758,29 @@ class HybridSSMLM(PerSlotStateLM):
                                            sm_scale=self._sm_scale),
                     jnp.sum(lengths).astype(jnp.int32))
 
+    def _walk_pages(self, st: HybridStep, block: int):
+        """``[pages, pages in runs]`` of a dispatch's walks, from the
+        tables and lengths :meth:`_full_walk` (x the full layer and the
+        cross layers) and :meth:`_window_paged` (x the window layers, from
+        each walk's first attended position) hand the kernel."""
+        from ..ops.transformer.paged_decode_attention import walk_pages
+        c, w = self.config, self.config.sliding_window
+        total = jnp.where(st.act, st.lens + 1, 0)
+        first = jnp.maximum(total - w, 0)
+        tables, wtables = st.tables, st.wtables
+        if st.chunk:
+            total = jnp.append(total, jnp.where(
+                st.chunk_len > 0, st.chunk_start + st.chunk_len, 0))
+            first = jnp.append(first,
+                               jnp.maximum(st.chunk_start - (w - 1), 0))
+            tables = jnp.concatenate([tables, tables[st.chunk_slot][None]])
+            wtables = jnp.concatenate([wtables,
+                                       wtables[st.chunk_slot][None]])
+        return ((1 + c.pairs_cross)
+                * jnp.stack(walk_pages(tables, total, block))
+                + c.pairs_self
+                * jnp.stack(walk_pages(wtables, total, block, first)))
+
     def _cross_paged(self, p, h, pool_k, pool_v, st: HybridStep):
         """A cross-attention mixer in the mixed step: the yield rows'
         queries against the full layer's pages.  Returns ``(out, (the
@@ -898,7 +923,8 @@ class HybridSSMLM(PerSlotStateLM):
             rows = jnp.sum(ssm_rows, axis=0) + state["rows"]
             counters = jnp.stack([
                 full_read + jnp.sum(cross_read), jnp.sum(window_read),
-                rows[0], rows[1], spared, state["rows"][2]]
+                rows[0], rows[1], spared, state["rows"][2],
+                *self._walk_pages(st, pool_k.shape[1])]
             ).astype(jnp.int32)
             new_lens = (st.lens + st.act.astype(st.lens.dtype)
                         ).at[chunk_slot].add(chunk_len, mode="drop")

@@ -157,10 +157,12 @@ class SSDHybridLM(PerSlotStateLM):
     #: what ``_apply_paged_mixed`` counts a dispatch, each where the work
     #: is handed over: context tokens the attention layers' walks were
     #: handed (x those layers), (row, ``mamba`` layer) pairs through the
-    #: chunk's blocked scan and through the decode update, and chunks that
-    #: started a slot's state from zero
+    #: chunk's blocked scan and through the decode update, chunks that
+    #: started a slot's state from zero, and the pages those walks were
+    #: handed with those of them in runs (x the attention layers)
     PAGED_COUNTERS = ("kv_tokens_read_full", "ssm_chunk_rows",
-                      "ssm_decode_rows", "state_slots_started")
+                      "ssm_decode_rows", "state_slots_started",
+                      "kv_pages_read", "kv_pages_in_runs")
     KV_BITS_REFUSAL = ("the block's scatter of a step's new rows writes "
                        "k and v as they are: it quantizes nothing and "
                        "carries no scale planes")
@@ -604,16 +606,23 @@ class SSDHybridLM(PerSlotStateLM):
             chunk_logits = (logits[s] if cw else
                             jnp.zeros((logits.shape[-1],), logits.dtype))
         with jax.named_scope("pool_write"):
+            from ..ops.transformer.paged_decode_attention import walk_pages
             live = jnp.sum(st.act.astype(jnp.int32))
-            read = jnp.sum(jnp.where(st.act, st.lens + 1, 0))
+            read, walked = jnp.where(st.act, st.lens + 1, 0), st.tables
             rides = chunk_len > 0
             if cw:
-                read += jnp.where(rides, chunk_start + chunk_len, 0)
+                read = jnp.append(read, jnp.where(
+                    rides, chunk_start + chunk_len, 0))
+                walked = jnp.concatenate([walked,
+                                          walked[chunk_slot][None]])
+            pages, in_runs = walk_pages(walked, read, pool_k.shape[1])
             counters = jnp.stack([
-                c.attention_layers_count * read,
+                c.attention_layers_count * jnp.sum(read),
                 c.mamba_layers * (chunk_len if cw else 0),
                 c.mamba_layers * live,
                 (rides & (chunk_start == 0)) if cw else False,
+                c.attention_layers_count * pages,
+                c.attention_layers_count * in_runs,
             ]).astype(jnp.int32)
             new_lens = (st.lens + st.act.astype(st.lens.dtype)
                         ).at[chunk_slot].add(chunk_len, mode="drop")

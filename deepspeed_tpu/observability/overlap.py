@@ -176,6 +176,11 @@ COUNTERS = ("dispatches", "decode_rows", "chunk_rows", "rows_computed",
             "moe_rows_max_expert", "moe_experts_touched",
             "latent_tokens_read", "latent_pages_read",
             "latent_pages_in_runs",
+            # the plain paged kernel's walks (the blocks that return
+            # counters and attend through it), summed over the layers:
+            # pages that hold attended keys, and those of them in runs of
+            # PAGE_RUN consecutive pool blocks, one DMA an operand
+            "kv_pages_read", "kv_pages_in_runs",
             # rows x expert layers through an always-on shared expert
             # (models/sandwich_moe.py); 0 for blocks that have none
             "moe_rows_shared",

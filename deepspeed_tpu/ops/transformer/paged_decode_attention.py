@@ -28,24 +28,45 @@ De`` to be a multiple of the pack's lanes and, for a quantized pool,
 Kernel design:
 
   * grid ``(slot, page_group)``: one step fetches a group of
-    ``pages_per_program`` pages and contracts it for EVERY head.  A
-    step at or past its slot's length (an inactive slot, the idle
-    prefill lane, the tail of a short sequence) is DEAD: one predicate,
-    no descriptor, no table look-up.  The wrapper computes from the
+    ``pages_per_program`` pages and contracts its live part for EVERY
+    head.  A step at or past its slot's length (an inactive slot, the
+    idle prefill lane, the tail of a short sequence) is DEAD: one
+    predicate, no descriptor, no table look-up.  The wrapper computes from the
     lengths what a step needs to know of the walk — live groups per
     slot, live steps before it, the next live slot — and hands it over
     as scalar prefetch.
-  * a page is ONE DMA per operand, ``pool[bid]`` into slot ``j`` of a
-    ``[2, pp, block, Hkv * De]`` VMEM scratch (the scale rows of a
-    quantized page likewise, ``[Hkv, 1, block]`` in one copy), issued by
-    a loop over the group's live pages only.  DOUBLE-BUFFERED across
-    steps: the pools stay in HBM (``memory_space=ANY``); while one group
-    is contracted, the next LIVE step's group — of this slot or of the
-    next live one, however many dead steps lie between — is already in
-    flight into the other half.
+  * the unit of the walk is the RUN: ``PAGE_RUN`` consecutive pages of a
+    slot's table.  Where a run's table entries are consecutive pool
+    blocks that all hold attended keys — which the cache manager makes
+    the rule — it is ONE DMA per operand, ``pool[bid : bid + PAGE_RUN]``
+    into slots ``j ..`` of a ``[2, pp, block, Hkv * De]`` VMEM scratch
+    (the scale rows of a quantized pool likewise, ``[PAGE_RUN, Hkv, 1,
+    block]`` in one copy); any other run — a slot's open last run, one a
+    window's first position falls in, one the allocator had to break —
+    is one DMA per operand a live PAGE, ``pool[bid]`` into slot ``j``,
+    issued by a loop over the run's live pages only.  The wrapper makes
+    the choice from the table and the lengths (``_grouped_tables``: flags
+    ``[B, pages / PAGE_RUN]``, a third scalar-prefetch operand), so start
+    and wait agree.  A page of 16 KB costs its descriptor, not its bytes:
+    80 starts and 80 waits a slot a layer at 40 pages where runs take 17.
+    A pool whose page is ``_RUN_PAGE_BYTES`` (64 KB) an operand or more
+    walks page by page and is handed no flags: there the bytes outlast
+    the descriptors.
+    DOUBLE-BUFFERED across steps: the pools stay in HBM
+    (``memory_space=ANY``); while one group is contracted, the next LIVE
+    step's group — of this slot or of the next live one, however many
+    dead steps lie between — is already in flight into the other half.
+  * a step contracts its group in PARTS of whole runs (``_part_pages``)
+    under a loop whose trip count follows the length: the parts that hold
+    keys the slot attends (below ``total``; under a window, at or after
+    the walk's first position) and no others.  A group sized for VMEM is
+    the whole table of a narrow pool (128 pages = 2,048 keys at 512
+    lanes), and a key contracted costs the same fetched or not.
   * heads are walked in WINDOWS of whole packs, 128-lane-aligned slices
-    of the slab in VMEM, under a ``fori_loop`` (never unrolled: the
-    mixed step is traced and lowered in every serving set-up).  A
+    of the slab in VMEM, under a ``fori_loop`` (traced once — the mixed
+    step is traced and lowered in every serving set-up — and unrolled
+    when it is lowered, so that the windows' independent chains
+    overlap).  A
     window's heads share one MXU contraction over the group's ``pp x
     block`` keys: the wrapper lays their queries out BLOCK-DIAGONALLY
     (head j's rows are non-zero only in head j's lanes), so ``Q x
@@ -58,8 +79,9 @@ Kernel design:
     window.
   * ``pages_per_program`` follows from a stated VMEM budget
     (``_VMEM_GROUP_BYTES`` of the ``_VMEM_LIMIT_BYTES`` the kernel asks
-    for): what a page costs in both buffer halves plus what the
-    contraction keeps per key.
+    for): what a page costs in both buffer halves plus what a
+    contraction of the whole group would keep per key (a part keeps
+    less: the budget is an upper bound).
   * FUSED DEQUANT: an int8 / packed-int4 pool crosses HBM compressed;
     the per-row scales are applied in the SCORE domain (``(q . k_int) *
     k_scale`` and ``(p * v_scale) . v_int``), which is the same product
@@ -106,10 +128,24 @@ _VMEM_GROUP_BYTES = 12 << 20
 _WINDOW_ROWS = 16
 #: pages of a RUN: the cache manager (``inference/serving/
 #: block_allocator.py``) hands a sequence's pages ``k * PAGE_RUN .. (k + 1)
-#: * PAGE_RUN - 1`` out as consecutive pool blocks wherever it can, and a
-#: walk over a narrow pool (the latent kernel's 20 KB a page) fetches such
-#: a run with ONE DMA.  8 pages = 128 tokens = 160 KB of latent rows
+#: * PAGE_RUN - 1`` out as consecutive pool blocks wherever it can, and
+#: every walk here — the plain kernel's k and v, the latent kernel's one
+#: operand — fetches such a run with ONE DMA an operand.  8 pages = 128
+#: tokens = 160 KB of latent rows, 128 KB of k at 4 kv heads of 128
 PAGE_RUN = 8
+#: a page this large an operand is fetched page by page whatever the table
+#: holds: its transfer (80 ns at 64 KB) outlasts its descriptor (38 ns,
+#: PERF.md PR 55), the run walk returned nothing there on the chip
+#: (Pythia's decode, PR 59) and its flags cost an XLA fusion a layer; at 40
+#: KB (phi-4's window layers) it still returns 8 %
+_RUN_PAGE_BYTES = 64 << 10
+#: keys one PART of a page group holds at most: a step contracts the
+#: parts that hold attended keys.  A part is one pass of the chain QK ->
+#: max -> exp -> PV whose latency (some 0.4 us a head window on the v5e,
+#: whatever the keys) is paid a part: 1,024 keys halve a 2,048-key group
+#: for a slot that fills a third of it; parts of 128 cost twice the
+#: whole group (PERF.md, PR 59)
+_PART_KEYS = 1024
 
 
 def _head_pack(kv_heads: int, d_eff: int) -> int:
@@ -159,6 +195,16 @@ def _pages_per_program(pool, kv_heads: int, kv_bits: int, rows: int,
     return min(1 << (pp.bit_length() - 1), npages)
 
 
+def _part_pages(pp: int, run: int, block: int) -> int:
+    """Pages of one PART of a page group — what a step contracts at a
+    time: whole runs, doubled while they divide the group and hold no more
+    than ``_PART_KEYS`` keys."""
+    part = run
+    while pp % (2 * part) == 0 and 2 * part * block <= _PART_KEYS:
+        part *= 2
+    return part
+
+
 def _page_group_dma(start, hbm, bufs, sem, bt_ref, row, total, group, half,
                     *, block, pp, first=0, span=None):
     """Start (or wait on) the DMAs of one live page group: for each page
@@ -188,13 +234,16 @@ def _page_group_dma(start, hbm, bufs, sem, bt_ref, row, total, group, half,
     jax.lax.fori_loop(dead, live, page, 0)
 
 
-def _grouped_tables(block_tables, total, pp: int, block: int, run: int):
+def _grouped_tables(block_tables, total, pp: int, block: int, run: int,
+                    first=None):
     """``(tables padded to whole groups of pp pages, runs [B, groups * pp //
     run])``: a group is ``pp // run`` RUNS of ``run`` pages, and ``runs`` is
     1 where a run's pages are consecutive pool blocks and all hold keys
     below the slot's ``total`` — such a run is one DMA.  (A run that is
     dead or partly dead reads 0, so nothing past the length is fetched by
-    it.)"""
+    it.)  Under a window the walk has a ``first [B]`` position too, and a
+    run with a page wholly before it reads 0 as well: what the table holds
+    there is not the slot's any more."""
     b, npages = block_tables.shape
     ngroups = -(-npages // pp)
     nruns = ngroups * pp // run
@@ -202,8 +251,10 @@ def _grouped_tables(block_tables, total, pp: int, block: int, run: int):
                      ((0, 0), (0, ngroups * pp - npages)))
     t = tables.reshape(b, nruns, run)
     consecutive = jnp.all(t[..., 1:] == t[..., :-1] + 1, axis=-1)
-    whole = ((jnp.arange(nruns, dtype=jnp.int32)[None] + 1) * (run * block)
-             <= total[:, None])
+    r = jnp.arange(nruns, dtype=jnp.int32)[None]
+    whole = (r + 1) * (run * block) <= total[:, None]
+    if first is not None:
+        whole = whole & (r * run >= (first // block)[:, None])
     return tables, (consecutive & whole).astype(jnp.int32)
 
 
@@ -216,14 +267,33 @@ def page_runs(block_tables, total, block: int):
     return _grouped_tables(block_tables, total, PAGE_RUN, block, PAGE_RUN)[1]
 
 
-def _fetch_group(start, pool_hbm, buf, sem, bt_ref, run_ref, slot, total,
-                 group, half, *, block, pp, run):
+def walk_pages(block_tables, total, block: int, first=None):
+    """``(pages, pages in runs)`` of the walks over ``block_tables [B,
+    pages]`` up to ``total [B]`` (from ``first [B]`` under a window): the
+    pages that hold attended keys, and those of them in runs the kernel
+    fetches with one DMA an operand (the flags it is handed, ``page_runs``
+    of the same tables and lengths) — what a program counts its
+    ``kv_pages_read`` / ``kv_pages_in_runs`` from."""
+    total = jnp.asarray(total, jnp.int32)
+    pages = -(-total // block)
+    if first is not None:
+        pages = jnp.maximum(pages - first // block, 0)
+    runs = _grouped_tables(block_tables, total, PAGE_RUN, block, PAGE_RUN,
+                           first)[1]
+    return jnp.sum(pages), PAGE_RUN * jnp.sum(runs)
+
+
+def _fetch_group(start, hbm, bufs, sem, bt_ref, run_ref, slot, total,
+                 group, half, *, block, pp, run, first=0):
     """Start (or wait on) one page group of ``slot`` into buffer half
-    ``half``, run by run: ONE DMA of ``run`` consecutive pool blocks where
-    ``run_ref`` says so, else ``_page_group_dma``'s one DMA a live page of
-    that run.  The choice is made from the table and the length alone
+    ``half``, run by run and for every operand of ``hbm`` / ``bufs`` (the
+    latent pool alone; k and v, and a quantized pool's two scale arrays):
+    ONE DMA an operand of ``run`` consecutive pool blocks where ``run_ref``
+    says so, else ``_page_group_dma``'s one DMA a live page of that run.
+    The choice is made from the table and the lengths alone
     (``_grouped_tables``), the same at start and at wait.  Runs past the
-    length are not looked at."""
+    length — and, under a window, before the walk's ``first`` position —
+    are not looked at."""
     nruns = pp // run
 
     def one_run(r, carry):
@@ -232,25 +302,28 @@ def _fetch_group(start, pool_hbm, buf, sem, bt_ref, run_ref, slot, total,
         @pl.when(whole)
         def _one():
             bid = bt_ref[slot, group * pp + r * run] if start else 0
-            dst = buf.at[half] if nruns == 1 else \
-                buf.at[half, pl.ds(r * run, run)]
-            copy = pltpu.make_async_copy(pool_hbm.at[pl.ds(bid, run)], dst,
-                                         sem.at[half, 0])
-            copy.start() if start else copy.wait()
+            for op, (src, buf) in enumerate(zip(hbm, bufs)):
+                dst = buf.at[half] if nruns == 1 else \
+                    buf.at[half, pl.ds(r * run, run)]
+                copy = pltpu.make_async_copy(src.at[pl.ds(bid, run)], dst,
+                                             sem.at[half, op])
+                copy.start() if start else copy.wait()
 
         @pl.when(jnp.logical_not(whole))
         def _pages():
-            _page_group_dma(start, (pool_hbm,), (buf,), sem, bt_ref, slot,
-                            total, group, half, block=block, pp=pp,
+            _page_group_dma(start, hbm, bufs, sem, bt_ref, slot, total,
+                            group, half, block=block, pp=pp, first=first,
                             span=None if nruns == 1 else (r * run, run))
         return carry
 
     if nruns == 1:
         one_run(0, 0)
     else:
-        live = jnp.clip(-(-(total - group * pp * block) // (run * block)),
-                        0, nruns)
-        jax.lax.fori_loop(0, live, one_run, 0)
+        keys0 = group * pp * block
+        live = jnp.clip(-(-(total - keys0) // (run * block)), 0, nruns)
+        dead = 0 if isinstance(first, int) and first == 0 else jnp.clip(
+            (first - keys0) // (run * block), 0, nruns)
+        jax.lax.fori_loop(dead, live, one_run, 0)
 
 
 def _walk_step(i, g, nwalk, meta_ref, bt_ref, run_ref, pool_hbm, buf, sem, *,
@@ -263,7 +336,7 @@ def _walk_step(i, g, nwalk, meta_ref, bt_ref, run_ref, pool_hbm, buf, sem, *,
     live_groups = meta_ref[2, i]
 
     def fetch(w, group, half, start):
-        _fetch_group(start, pool_hbm, buf, sem, bt_ref, run_ref,
+        _fetch_group(start, (pool_hbm,), (buf,), sem, bt_ref, run_ref,
                      meta_ref[5, w], meta_ref[1, w], group, half,
                      block=block, pp=pp, run=run)
 
@@ -298,8 +371,8 @@ def _unpack(x, kv_bits):
             (xi >> 4).astype(jnp.float32)]
 
 
-def _kernel(meta_ref, bt_ref, coff_ref, rhead_ref, q_ref, *refs, sm_scale,
-            block, pp, kv_bits, width, heads, window_keys=None):
+def _kernel(meta_ref, bt_ref, *refs, sm_scale, block, pp, run, part,
+            kv_bits, width, heads, window_keys=None):
     """Online-softmax walk over one slot's live page groups, every head
     window inside the step.
 
@@ -311,15 +384,22 @@ def _kernel(meta_ref, bt_ref, coff_ref, rhead_ref, q_ref, *refs, sm_scale,
     the ``window_keys`` keys that end at its own position, and
     ``meta_ref`` has two more rows: the slot's first page GROUP (the
     grid's ``g`` counts from it) and its first attended position, the
-    first row's window start.
-    q_ref ``[nwin, nsplit, R, W]``: per head
-    window the block-diagonal queries of its ``heads`` kv heads (``R =
-    heads * G * C`` rows; ``coff_ref``/``rhead_ref`` ``[R, 1]`` give each
-    row's chunk offset and head-within-window).  VMEM slabs kbuf/vbuf
+    first row's window start.  With ``run`` (static; None: every page a
+    DMA of its own) a third scalar-prefetch operand leads ``refs``,
+    ``run_ref [B, groups * pp // run]``: 1 where a run of ``run`` pages is
+    one DMA (:func:`_fetch_group`).  A group is contracted in PARTS of
+    ``part`` pages, those that hold keys the slot attends and no others.
+    Then ``coff_ref``, ``rhead_ref``, and q_ref ``[nwin, nsplit, R, W]``:
+    per head window the block-diagonal queries of its ``heads`` kv heads
+    (``R = heads * G * C`` rows; ``coff_ref``/``rhead_ref`` ``[R, 1]``
+    give each row's chunk offset and head-within-window).  VMEM slabs kbuf/vbuf
     ``[2, pp, block, Hkv * De]`` in the pool dtype (+ ksbuf/vsbuf ``[2,
     pp, Hkv, 1, block]`` f32 when quantized); scratch m/l ``[nwin, R,
     1]``, acc ``[nwin, nsplit, R, W]`` f32; one DMA semaphore per
     (buffer half, operand)."""
+    if run is not None:
+        run_ref, *refs = refs
+    coff_ref, rhead_ref, q_ref, *refs = refs
     nops = 2 if kv_bits == 0 else 4
     hbm = refs[:nops]
     o_ref = refs[nops]
@@ -329,14 +409,18 @@ def _kernel(meta_ref, bt_ref, coff_ref, rhead_ref, q_ref, *refs, sm_scale,
     i, g = pl.program_id(0), pl.program_id(1)
     nslots, ng = pl.num_programs(0), pl.num_programs(1)
     nwin = q_ref.shape[0]
-    keys = pp * block
+    keys, n = pp * block, part * block
     base, total, live_groups = meta_ref[0, i], meta_ref[1, i], meta_ref[2, i]
 
     def fetch(row, group, half, start):
-        _page_group_dma(start, hbm, bufs, sem, bt_ref, row,
-                        meta_ref[1, row], group, half, block=block, pp=pp,
-                        first=(0 if window_keys is None
-                               else meta_ref[6, row]))
+        where = (row, meta_ref[1, row], group, half)
+        kw = dict(block=block, pp=pp, first=(
+            0 if window_keys is None else meta_ref[6, row]))
+        if run is None:
+            _page_group_dma(start, hbm, bufs, sem, bt_ref, *where, **kw)
+        else:
+            _fetch_group(start, hbm, bufs, sem, bt_ref, run_ref, *where,
+                         run=run, **kw)
 
     # a step at or past its slot's length runs none of this
     @pl.when(g < live_groups)
@@ -376,74 +460,104 @@ def _kernel(meta_ref, bt_ref, coff_ref, rhead_ref, q_ref, *refs, sm_scale,
             acc_scr[...] = jnp.zeros_like(acc_scr)
 
         qpos = base + coff_ref[...]                            # [R, 1]
-        pos = gi * keys + jax.lax.broadcasted_iota(jnp.int32, (1, keys), 1)
-        in_range = pos < total                                 # [1, keys]
-        visible = (pos <= qpos) & in_range                     # [R, keys]
-        # masked rows get probability ~0, but 0 * NaN = NaN: zero the v
-        # rows (and scales) past the valid length so a recycled pool
-        # block holding a quarantined request's non-finite KV cannot
-        # re-poison its next owner — unfetched pages also leave stale
-        # garbage in the buffer
-        vpos = gi * keys + jax.lax.broadcasted_iota(jnp.int32, (keys, 1), 0)
-        v_valid = vpos < total                                 # [keys, 1]
-        if window_keys is not None:
-            # a row sees the `window_keys` keys ending at its own
-            # position; a late row of a chunk meets whole groups it does
-            # not see BEFORE the ones it does: what they add at weight
-            # exp(MASK - MASK) is finite (the pages before the walk's
-            # first position, never fetched, are zeroed like the tail)
-            # and is wiped by alpha = exp(MASK - m) = 0 at its first
-            # visible key
-            visible = visible & (pos > qpos - window_keys)
-            v_valid = v_valid & (vpos >= meta_ref[6, i])
 
-        def window(w, carry):
-            lanes = pl.ds(pl.multiple_of(w * width, width), width)
+        def contract(p, carry):
+            """Part ``p`` of the group — pages ``p * part ..`` of the
+            buffer half, ``n`` keys — against every head window."""
+            pages = pl.ds(p * part, part)
+            key0 = gi * keys + p * n
+            pos = key0 + jax.lax.broadcasted_iota(jnp.int32, (1, n), 1)
+            in_range = pos < total                             # [1, n]
+            visible = (pos <= qpos) & in_range                 # [R, n]
+            # masked rows get probability ~0, but 0 * NaN = NaN: zero the
+            # v rows (and scales) past the valid length so a recycled pool
+            # block holding a quarantined request's non-finite KV cannot
+            # re-poison its next owner — unfetched pages also leave stale
+            # garbage in the buffer
+            vpos = key0 + jax.lax.broadcasted_iota(jnp.int32, (n, 1), 0)
+            v_valid = vpos < total                             # [n, 1]
+            if window_keys is not None:
+                # a row sees the `window_keys` keys ending at its own
+                # position; a late row of a chunk meets whole parts it
+                # does not see BEFORE the ones it does: what they add at
+                # weight exp(MASK - MASK) is finite (the pages before the
+                # walk's first position, never fetched, are zeroed like
+                # the tail) and is wiped by alpha = exp(MASK - m) = 0 at
+                # its first visible key
+                visible = visible & (pos > qpos - window_keys)
+                v_valid = v_valid & (vpos >= meta_ref[6, i])
 
-            def row_scale(sbuf):
-                # the window's per-head [1, block] scale rows of every
-                # page -> [R, keys], each query row taking its own head's
-                def of_head(t):
-                    return jnp.concatenate(
-                        [sbuf[half, j, w * heads + t] for j in range(pp)],
-                        axis=1)
-                s = of_head(0)
-                for t in range(1, heads):
-                    s = jnp.where(rhead_ref[...] == t, of_head(t), s)
-                return s
+            def window(w, carry):
+                lanes = pl.ds(pl.multiple_of(w * width, width), width)
 
-            k = bufs[0][half, :, :, lanes].reshape(keys, width)
-            s = sum(jax.lax.dot_general(
-                q_ref[w, c] if kv_bits == 0
-                else q_ref[w, c].astype(jnp.float32), kk,
-                (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)
-                for c, kk in enumerate(_unpack(k, kv_bits)))
-            if kv_bits:
-                s = s * row_scale(bufs[2])
-            s = jnp.where(visible, s * sm_scale, MASK_VALUE)   # [R, keys]
-            m_prev = m_scr[w]                                  # [R, 1]
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-            alpha = jnp.exp(m_prev - m_new)
-            p = jnp.exp(s - m_new)
-            l_scr[w] = alpha * l_scr[w] + jnp.sum(p, axis=1, keepdims=True)
-            m_scr[w] = m_new
-            v = bufs[1][half, :, :, lanes].reshape(keys, width)
-            v = jnp.where(v_valid, v, jnp.zeros_like(v))
-            if kv_bits:
-                p = p * jnp.where(in_range, row_scale(bufs[3]), 0.0)
-            for c, vv in enumerate(_unpack(v, kv_bits)):
-                acc_scr[w, c] = alpha * acc_scr[w, c] + jax.lax.dot_general(
-                    p.astype(vv.dtype), vv, (((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32)        # [R, W]
+                def row_scale(sbuf):
+                    # the window's per-head [1, block] scale rows of the
+                    # part's pages -> [R, n], each query row taking its
+                    # own head's
+                    def of_head(t):
+                        return jnp.concatenate(
+                            [sbuf[half, p * part + j, w * heads + t]
+                             for j in range(part)], axis=1)
+                    s = of_head(0)
+                    for t in range(1, heads):
+                        s = jnp.where(rhead_ref[...] == t, of_head(t), s)
+                    return s
+
+                k = bufs[0][half, pages, :, lanes].reshape(n, width)
+                s = sum(jax.lax.dot_general(
+                    q_ref[w, c] if kv_bits == 0
+                    else q_ref[w, c].astype(jnp.float32), kk,
+                    (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+                    for c, kk in enumerate(_unpack(k, kv_bits)))
+                if kv_bits:
+                    s = s * row_scale(bufs[2])
+                s = jnp.where(visible, s * sm_scale, MASK_VALUE)   # [R, n]
+                m_prev = m_scr[w]                                  # [R, 1]
+                m_new = jnp.maximum(m_prev,
+                                    jnp.max(s, axis=1, keepdims=True))
+                alpha = jnp.exp(m_prev - m_new)
+                p_ = jnp.exp(s - m_new)
+                l_scr[w] = alpha * l_scr[w] + jnp.sum(p_, axis=1,
+                                                      keepdims=True)
+                m_scr[w] = m_new
+                v = bufs[1][half, pages, :, lanes].reshape(n, width)
+                v = jnp.where(v_valid, v, jnp.zeros_like(v))
+                if kv_bits:
+                    p_ = p_ * jnp.where(in_range, row_scale(bufs[3]), 0.0)
+                for c, vv in enumerate(_unpack(v, kv_bits)):
+                    acc_scr[w, c] = alpha * acc_scr[w, c] + \
+                        jax.lax.dot_general(
+                            p_.astype(vv.dtype), vv,
+                            (((1,), (0,)), ((), ())),
+                            preferred_element_type=jnp.float32)    # [R, W]
+                return carry
+
+            # the windows are a loop, traced once whatever the number of
+            # heads, and unrolled when it is lowered: their chains QK ->
+            # max -> exp -> PV are independent, and side by side in one
+            # basic block they overlap (Mosaic lowers a loop whole or
+            # not at all)
+            if nwin == 1:
+                window(0, 0)
+            else:
+                jax.lax.fori_loop(0, nwin, window, 0, unroll=True)
             return carry
 
-        # the windows are looped, never unrolled: the body is traced and
-        # lowered once whatever the number of heads
-        if nwin == 1:
-            window(0, 0)
+        # a slot's last group is as full as its length leaves it (a
+        # window's first as empty as its start), and every key contracted
+        # costs the same, fetched or not: the parts that hold attended
+        # keys are contracted, under a loop whose trip count follows the
+        # length — one copy of the body whatever the number of parts
+        nparts = pp // part
+        if nparts == 1:
+            contract(0, 0)
         else:
-            jax.lax.fori_loop(0, nwin, window, 0)
+            key0 = gi * keys
+            last = jnp.clip(-(-(total - key0) // n), 0, nparts)
+            first = 0 if window_keys is None else jnp.clip(
+                (meta_ref[6, i] - key0) // n, 0, nparts)
+            jax.lax.fori_loop(first, last, contract, 0)
 
     @pl.when(g == ng - 1)
     def _out():
@@ -537,7 +651,12 @@ def _paged_attention(q, pool_k, pool_v, base, total, block_tables, *,
     rows = heads * groups * c
     pp = _pages_per_program(pool_k, hkv, kv_bits, rows, width, npages,
                             pages_per_program)
-    ngroups = -(-npages // pp)
+    # runs where a page is small enough for its descriptor to cost more
+    # than its bytes; parts of whole runs either way
+    run = math.gcd(pp, PAGE_RUN)
+    part = _part_pages(pp, run, block)
+    if block * lanes * pool_k.dtype.itemsize >= _RUN_PAGE_BYTES:
+        run = None
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(d)
     # what a step needs to know of the walk, so that a dead step tests
@@ -545,20 +664,25 @@ def _paged_attention(q, pool_k, pool_v, base, total, block_tables, *,
     # looking at its neighbours' pages
     base = jnp.asarray(base, jnp.int32).reshape(b)
     total = jnp.asarray(total, jnp.int32).reshape(b)
-    live = jnp.clip(-(-total // (pp * block)), 0, ngroups)
+    first = None
     if window is not None:
         if kv_bits:
             raise NotImplementedError(
                 f"{what}: a window over a quantized pool (the scale rows' "
                 f"DMAs take no first page)")
         first = jnp.maximum(base - (window - 1), 0)
+    block_tables = jnp.asarray(block_tables, jnp.int32)
+    tables = (block_tables,) if run is None else _grouped_tables(
+        block_tables, total, pp, block, run, first)
+    ngroups = -(-npages // pp)
+    live = jnp.clip(-(-total // (pp * block)), 0, ngroups)
+    if window is not None:
         group0 = jnp.where(total > 0, first // (pp * block), 0)
         live = live - jnp.minimum(group0, live)
     slot = jnp.where(live > 0, jnp.arange(b, dtype=jnp.int32), b)
     later = jnp.append(jax.lax.cummin(slot, reverse=True)[1:], b)
     meta = jnp.stack([base, total, live, jnp.cumsum(live) - live, later]
                      + ([] if window is None else [group0, first]))
-    block_tables = jnp.asarray(block_tables, jnp.int32)
     if kv_bits == 0:
         q = q.astype(pool_k.dtype)
     # [B, C, H, D] -> [B, window, split, (head-in-window, group, c), W]
@@ -595,11 +719,12 @@ def _paged_attention(q, pool_k, pool_v, base, total, block_tables, *,
 
     out = pl.pallas_call(
         functools.partial(_kernel, sm_scale=sm_scale, block=block, pp=pp,
-                          kv_bits=kv_bits, width=width, heads=heads,
+                          run=run, part=part, kv_bits=kv_bits, width=width,
+                          heads=heads,
                           **({} if window is None
                              else {"window_keys": window})),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=1 + len(tables),
             grid=(b, ngroups),
             in_specs=[rspec, rspec, qspec]
             + [pl.BlockSpec(memory_space=pl.ANY)] * nops,
@@ -612,7 +737,7 @@ def _paged_attention(q, pool_k, pool_v, base, total, block_tables, *,
             vmem_limit_bytes=_VMEM_LIMIT_BYTES),
         interpret=interpret,
         name="paged_attention",
-    )(meta, block_tables, *operands)
+    )(meta, *tables, *operands)
     # keep each head's own lane window of its rows
     out = out.reshape(b, nwin, nsplit, heads, groups, c, heads, d_eff)
     out = jnp.einsum("bwsjgcke,jk->bwsjgce", out, eye)

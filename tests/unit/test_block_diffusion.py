@@ -178,6 +178,30 @@ def test_every_forward_of_an_engine_is_the_references(block_length, steps,
         assert rel_err(kept[0], lacking[0][:, :kept[0].shape[1]]) > 0.05
 
 
+def test_the_step_counts_the_pages_its_walks_read_and_those_in_runs():
+    """One dispatch — a block at 68 rows of context in a slot whose table
+    is consecutive pool blocks, one at 12 in a scattered table, an idle
+    slot, a chunk of 8 rows at 16 in the second slot — counts, a layer, the
+    pages the lane's and the chunk's walks are handed and those of them in
+    whole runs of 8 (pages of 8 rows)."""
+    model, params = build()
+    nl = model.config.num_layers
+    tables = np.zeros((3, 16), np.int32)
+    tables[0, :10] = 1 + np.arange(10)
+    tables[1, :3] = (14, 12, 13)
+    cache = model.init_paged_cache(24, 8, jnp.float32)
+    cache.update(block_tables=jnp.asarray(tables),
+                 lens=jnp.asarray([68, 12, 0], jnp.int32))
+    new = model._apply_paged_block(
+        params, cache, jnp.zeros((3, 4), jnp.int32), jnp.asarray([1, 1, 0]),
+        jnp.arange(8), jnp.int32(1), jnp.int32(16), jnp.int32(8))[1]
+    counted = dict(zip(model.PAGED_COUNTERS, map(int, new["counters"])))
+    # 72 rows = 9 pages, the first 8 a run; 16 rows = 2 pages; the chunk's
+    # 24 rows = 3
+    assert counted["kv_pages_read"] == nl * (9 + 2 + 3)
+    assert counted["kv_pages_in_runs"] == nl * 8
+
+
 def test_a_preempted_request_recomputes_to_the_same_tokens():
     """A pool too small for three requests at their full lengths: one is
     preempted mid-generation, its open block's progress dropped, and

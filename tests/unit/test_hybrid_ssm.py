@@ -218,7 +218,7 @@ def test_each_counter_against_the_known_mix(built, served):
     sums made here from the four requests' lengths."""
     model, _ = built
     c = model.config
-    _, reqs, seen, _, chunks = served
+    srv, reqs, seen, _, chunks = served
     total = {k: sum(int(rec[k]) for rec in seen)
              for k in HybridSSMLM.PAGED_COUNTERS + (
                  "window_blocks_freed", "chunk_rows", "decode_rows")}
@@ -246,6 +246,18 @@ def test_each_counter_against_the_known_mix(built, served):
     win += sum(at + n - max(0, at - (w - 1)) for at, n in chunks)
     assert total["kv_tokens_read_window"] == win * c.pairs_self
     assert total["window_blocks_freed"] > 0
+    # the same walks in pages: all of a full walk's, a window walk's from
+    # the page of its first attended position (a row at t reads from t -
+    # w, a chunk from its first row's window)
+    blk = srv.block_size
+    walks = [(0, t) for p, n in zip(prompts, news)
+             for t in range(p + 1, p + n)] + [(0, at + n) for at, n in chunks]
+    wwalks = [(max(0, t - w), t) for _, t in walks[:decoded]] + [
+        (max(0, at - (w - 1)), at + n) for at, n in chunks]
+    pages = lambda ws: sum(-(-t // blk) - f // blk for f, t in ws)  # noqa: E731
+    assert total["kv_pages_read"] == (pages(walks) * (1 + c.pairs_cross)
+                                      + pages(wwalks) * c.pairs_self)
+    assert 0 <= total["kv_pages_in_runs"] <= total["kv_pages_read"]
 
 
 def test_the_counters_count_what_the_kernels_were_handed(built):
@@ -277,6 +289,10 @@ def test_the_counters_count_what_the_kernels_were_handed(built):
     assert sound["cross_rows_spared"] == less["cross_rows_spared"] == 12
     assert (sound["ssm_chunk_rows"], sound["ssm_decode_rows"],
             sound["state_slots_started"]) == (13 * 3, 1 * 3, 1)
+    # pages of 4: 3 + 4 in each of the three full walks and, the window
+    # of 8 starting inside the first page, in the two window walks; a
+    # table of six pages holds no run of eight
+    assert (sound["kv_pages_read"], sound["kv_pages_in_runs"]) == (7 * 5, 0)
 
 
 @pytest.mark.parametrize("fault", (None,) + serve_hybrid.PROGRAM_FAULTS)

@@ -494,6 +494,171 @@ class TestPagedWalk:
 
 
 # ---------------------------------------------------------------------------
+# the walk by runs: one DMA an operand for PAGE_RUN consecutive pool blocks
+# ---------------------------------------------------------------------------
+RUN_BLOCK, RUN_PAGES = 16, 32              # 4 runs of 8 pages = 512 keys
+#: lane -> (query heads, kv heads, head dim, window): what the three
+#: entry points take; a window takes no quantized pool
+RUN_LANES = {"decode": (4, 2, 32, None), "chunk": (4, 2, 32, None),
+             "block": (8, 2, 32, None), "window_decode": (4, 2, 32, 100),
+             "window_chunk": (4, 2, 32, 150)}
+#: every lane on every kind of table in two groups of two parts, on
+#: tables of runs in one group of four too; the quantized pools (scale
+#: rows ride the same DMAs) where a pool can be quantized
+RUN_CASES = [(lane, kind, 16, "f32") for lane in RUN_LANES
+             for kind in ("runs", "scattered", "mixed")
+             ] + [(lane, "runs", None, "f32") for lane in RUN_LANES
+                  ] + [(lane, kind, 16, kv) for lane in ("decode", "chunk")
+                       for kind in ("runs", "scattered", "mixed")
+                       for kv in ("int8", "int4")]
+
+
+def run_tables(slots, kind, rng, hkv=2, d=32):
+    """Pools in which every row is NaN but the rows a walk may attend —
+    ``slots`` is a list of (first attended position, total) — and tables
+    whose aligned runs of ``PAGE_RUN`` entries are consecutive pool blocks
+    (``kind`` "runs": EVERY run of the table, so the run that straddles a
+    slot's total, the runs past it and the ones before a window's first
+    position are consecutive too, and full of NaN where the walk has no
+    business), permuted inside each run ("scattered": no run at all), or
+    one and the other in turn ("mixed")."""
+    from deepspeed_tpu.ops.transformer.paged_decode_attention import PAGE_RUN
+    bs, npages = RUN_BLOCK, RUN_PAGES
+    nruns = npages // PAGE_RUN
+    nb = 1 + len(slots) * npages
+    pk = np.full((nb, bs, hkv, d), np.nan, np.float32)
+    pv = np.full((nb, bs, hkv, d), np.nan, np.float32)
+    groups = rng.permutation(len(slots) * nruns).reshape(len(slots), nruns)
+    bt = 1 + groups[..., None] * PAGE_RUN + np.arange(PAGE_RUN)
+    for i in range(len(slots)):
+        for r in range(nruns):
+            if kind == "scattered" or (kind == "mixed" and (i + r) % 2):
+                while np.all(np.diff(bt[i, r]) == 1):
+                    bt[i, r] = rng.permutation(bt[i, r])
+    bt = bt.reshape(len(slots), npages).astype(np.int32)
+    for i, (first, total) in enumerate(slots):
+        for pos in range(first, total):
+            pk[bt[i, pos // bs], pos % bs] = rng.standard_normal((hkv, d))
+            pv[bt[i, pos // bs], pos % bs] = rng.standard_normal((hkv, d))
+    return pk, pv, bt
+
+
+class TestPagedRuns:
+    """A table's runs are fetched with one DMA an operand, its other pages
+    one by one, and a step contracts the parts of its group that hold
+    attended keys: the result is the reference's whatever the table, with
+    NaN behind every row the walk must not attend — past the total, before
+    a window's first position, in the run that straddles either."""
+
+    @pytest.mark.parametrize("lane,kind,pp,kv", RUN_CASES)
+    def test_walk_by_runs(self, lane, kind, pp, kv, monkeypatch):
+        from deepspeed_tpu.ops.transformer import paged_decode_attention \
+            as pda
+        # a part is one run here: a group of 512 keys is four parts (the
+        # kernel's own 1,024 keys a part would make it one)
+        monkeypatch.setattr(pda, "_PART_KEYS", 128)
+        h, hkv, d, window = RUN_LANES[lane]
+        rng = np.random.default_rng(len(lane) + len(kind) + (pp or 0))
+        bs = RUN_BLOCK
+        if lane.endswith("decode"):
+            # totals on every side of a run's and a group's boundary
+            lens = [512, 0, 1, 128, 129, 300, 511, 261]
+            first = [max(0, ln - (window or ln)) for ln in lens]
+            pk, pv, bt = run_tables(list(zip(first, lens)), kind, rng)
+            q = jnp.asarray(rng.standard_normal((len(lens), h, d)),
+                            jnp.float32)
+            pk, pv, kw, atol = walk_pools(pk, pv, q, kv)
+            ln, bt = jnp.asarray(lens, jnp.int32), jnp.asarray(bt)
+            out = pda.paged_decode_attention(
+                q, pk, pv, ln, bt, interpret=True, pages_per_program=pp,
+                window=window, **kw)
+            ref = pda.paged_attention_reference(q, pk, pv, ln, bt,
+                                                window=window, **kw)
+            assert (np.asarray(out)[1] == 0).all()          # the dead slot
+        elif lane == "block":
+            rows = 4
+            base = [508, 0, 124, 128, 296, 260]
+            active = [1, 1, 1, 0, 1, 1]
+            pk, pv, bt = run_tables(
+                [(0, (b + rows) * a) for b, a in zip(base, active)], kind,
+                rng)
+            q = jnp.asarray(rng.standard_normal((len(base), rows, h, d)),
+                            jnp.float32)
+            pk, pv = pool_layout(pk), pool_layout(pv)
+            base, bt = jnp.asarray(base, jnp.int32), jnp.asarray(bt)
+            active = jnp.asarray(active, jnp.int32)
+            out = pda.paged_block_attention(q, pk, pv, base, active, bt,
+                                            interpret=True,
+                                            pages_per_program=pp)
+            ref = pda._reference(q, pk, pv, base,
+                                 jnp.where(active > 0, base + rows, 0), bt,
+                                 None, None, 0, block_rows=rows)
+            assert (np.asarray(out)[3] == 0).all()          # the idle slot
+            ref = jnp.where((active > 0)[:, None, None, None], ref, 0.0)
+            atol = 3e-5
+        else:
+            # two walkers of 32 rows, the second ending inside a page
+            base, n, c = 290, 61, 64
+            first = max(0, base - (window - 1)) if window else 0
+            pk, pv, bt = run_tables([(first, base + n)], kind, rng)
+            q = jnp.asarray(rng.standard_normal((c, h, d)), jnp.float32)
+            pk, pv, kw, atol = walk_pools(pk, pv, q, kv)
+            out = pda.paged_prefill_attention(
+                q, pk, pv, jnp.int32(base), jnp.int32(n), jnp.asarray(bt[0]),
+                interpret=True, pages_per_program=pp, window=window,
+                tile_rows=32, **kw)[:n]
+            ref = pda.paged_prefill_reference(
+                q, pk, pv, jnp.int32(base), jnp.int32(n), jnp.asarray(bt[0]),
+                window=window, **kw)[:n]
+        out = np.asarray(out, np.float32)
+        assert np.isfinite(out).all()
+        np.testing.assert_allclose(out, np.asarray(ref), atol=atol)
+
+    @pytest.mark.parametrize("kind", ["runs", "scattered"])
+    def test_wide_pages_walk_page_by_page(self, kind):
+        """A pool whose page is 64 KB an operand (16 tokens x 8 heads of
+        128 in float32) takes no flags and fetches every page with a DMA
+        of its own, runs in its table or not: same result."""
+        from deepspeed_tpu.ops.transformer import paged_decode_attention \
+            as pda
+        rng = np.random.default_rng(3)
+        lens = [300, 0, 129]
+        pk, pv, bt = run_tables([(0, ln) for ln in lens], kind, rng,
+                                hkv=8, d=128)
+        assert pk[0].nbytes >= pda._RUN_PAGE_BYTES
+        q = jnp.asarray(rng.standard_normal((len(lens), 8, 128)),
+                        jnp.float32)
+        pk, pv = pool_layout(pk), pool_layout(pv)
+        ln, bt = jnp.asarray(lens, jnp.int32), jnp.asarray(bt)
+        out = np.asarray(pda.paged_decode_attention(q, pk, pv, ln, bt,
+                                                    interpret=True))
+        ref = pda.paged_attention_reference(q, pk, pv, ln, bt)
+        assert np.isfinite(out).all() and (out[1] == 0).all()
+        np.testing.assert_allclose(out, np.asarray(ref), atol=1e-4)
+
+    def test_a_run_is_whole_only_where_the_walk_reads_all_of_it(self):
+        """The flags the kernel is handed: consecutive ids, every page
+        below the total and none wholly before a window's first position;
+        the run that straddles either goes page by page."""
+        from deepspeed_tpu.ops.transformer.paged_decode_attention import (
+            PAGE_RUN, _grouped_tables, page_runs)
+        bs = RUN_BLOCK
+        bt = 1 + np.arange(2 * RUN_PAGES, dtype=np.int32).reshape(2, -1)
+        bt[1, 9], bt[1, 10] = bt[1, 10], bt[1, 9]          # run 1 broken
+        total = jnp.asarray([300, 512], jnp.int32)
+        # 300 keys: pages 0-18, so run 2 straddles the total
+        assert page_runs(jnp.asarray(bt), total, bs).tolist() == \
+            [[1, 1, 0, 0], [1, 0, 1, 1]]
+        # first positions 140 and 128: page 8 is half before 140 (run 1
+        # is read whole), and wholly at 128 or later
+        first = jnp.asarray([140, 128], jnp.int32)
+        tables, runs = _grouped_tables(jnp.asarray(bt), total, 16, bs,
+                                       PAGE_RUN, first)
+        assert tables.shape == (2, RUN_PAGES)
+        assert runs.tolist() == [[0, 1, 0, 0], [0, 0, 1, 1]]
+
+
+# ---------------------------------------------------------------------------
 # block allocator
 # ---------------------------------------------------------------------------
 class TestBlockAllocator:

@@ -395,6 +395,12 @@ def test_each_counter_against_the_known_mix(built, served):
     read = sum(sum(range(p + 1, p + n)) for p, n in zip(prompts, news))
     read += sum(at + n for at, n in chunks)
     assert total["kv_tokens_read_full"] == read * c.attention_layers_count
+    blk = srv.block_size
+    pages = sum(-(-t // blk) for p, n in zip(prompts, news)
+                for t in range(p + 1, p + n))
+    pages += sum(-(-(at + n) // blk) for at, n in chunks)
+    assert total["kv_pages_read"] == pages * c.attention_layers_count
+    assert 0 < total["kv_pages_in_runs"] <= total["kv_pages_read"]
 
 
 def test_the_state_and_the_pages_read_back_are_the_references(built):

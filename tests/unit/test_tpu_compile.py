@@ -30,7 +30,8 @@ from deepspeed_tpu.ops.sparse_attention.blocksparse_flash import (
 from deepspeed_tpu.ops.transformer.flash_attention import (
     flash_attention_bthd)
 from deepspeed_tpu.ops.transformer.paged_decode_attention import (
-    paged_decode_attention, paged_prefill_attention)
+    PAGE_RUN, paged_block_attention, paged_decode_attention,
+    paged_prefill_attention)
 from deepspeed_tpu.parallel.topology import build_mesh
 from deepspeed_tpu.runtime.config import MeshConfig
 
@@ -177,6 +178,20 @@ def paged_prefill_case(sds, pages, h, d, kv_bits, block, **kw):
     return fn, args
 
 
+def paged_block_case(sds, slots=40, rows=4, h=32, hkv=4, d=128, pages=128):
+    """(fn, abstract args) of one block-lane call; the defaults are
+    ``sdar-30b-a3b-chat.serve-blockgen-sat``'s."""
+    pool = sds((64, 16, hkv * d), jnp.bfloat16)
+    args = (sds((slots, rows, h, d), jnp.bfloat16), pool, pool,
+            sds((slots,), jnp.int32), sds((slots,), jnp.int32),
+            sds((slots, pages), jnp.int32))
+
+    def fn(q, pk, pv, base, active, tables):
+        return paged_block_attention(q, pk, pv, base, active, tables,
+                                     interpret=False)
+    return fn, args
+
+
 @pytest.mark.parametrize("d", [64, 128])
 @pytest.mark.parametrize("kv_bits", [0, 8, 4])
 @pytest.mark.parametrize("h,hkv", [(16, 16), (16, 4)])
@@ -240,12 +255,15 @@ def kernel_eqns(fn, *args):
 @pytest.mark.parametrize("chunk", [False, True], ids=["decode", "chunk"])
 def test_paged_kernel_fetches_whole_pages_and_loops_heads(chunk):
     """What the kernel's speed and the serving set-up's trace + lower
-    time rest on, read off the kernel's jaxpr (no clock): a page is ONE
-    copy per operand of the whole ``[block, Hkv * De]`` block — issued
-    from two sites (cold start, prefetch), each a loop over the group's
-    live pages, so a step starts at most ``pp`` fetches per operand
-    whatever the number of packs — and the body holds one QK^T and one
-    PV however many packs and pages a step covers."""
+    time rest on, read off the kernel's jaxpr (no clock): a fetch is ONE
+    copy per operand of a whole run ``[PAGE_RUN, block, Hkv * De]`` or of
+    a whole page ``[block, Hkv * De]`` — issued from two sites (cold
+    start, prefetch), each a loop over the group's live runs around a
+    loop over a run's live pages (a pool of 64 KB pages: one loop over
+    the group's live pages), so a step starts at most ``pp`` fetches
+    per operand whatever the number of packs — and the body, under ONE
+    loop over the group's live parts where it has several, holds one
+    QK^T and one PV however many packs, pages and parts a step covers."""
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype)
 
@@ -261,19 +279,28 @@ def test_paged_kernel_fetches_whole_pages_and_loops_heads(chunk):
         for e in starts:
             src, src_index, dst, dst_index = jax.tree_util.tree_unflatten(
                 e.params["tree"], e.invars)[:4]
-            assert src_index[0].get_indexer_shape() == (16, h * d)
+            assert src_index[0].get_indexer_shape() in (
+                (16, h * d), (PAGE_RUN, 16, h * d))
             assert dst.aval.shape == (2, pp, 16, h * d)
-            assert dst_index[0].get_indexer_shape() == (16, h * d)
+            assert dst_index[0].get_indexer_shape() == \
+                src_index[0].get_indexer_shape()
         names = [e.primitive.name for e in eqns]
         return {n: names.count(n) for n in ("dma_start", "dma_wait",
                                             "dot_general", "while", "scan")}
     few = counts(4, 128, 16)                  # 4 packs x 16 pages
-    assert few["dma_start"] == 4 and few["dma_wait"] == 2     # k and v
+    # k and v: a run, a page of a run, at two sites and at one
+    assert few["dma_start"] == 8 and few["dma_wait"] == 4
     assert few["dot_general"] == 2
-    assert few["while"] == 3                  # over the live pages
+    # at the three sites: over the live runs, and a run's live pages
+    assert few["while"] == 6
     assert few["scan"] == chunk               # over the head windows
-    assert counts(16, 128, 32) == few         # 16 packs x 32 pages
-    assert counts(16, 64, 32) == few          # 8 two-head packs
+    assert counts(16, 64, 32) == few          # 8 two-head packs x 32 pages
+    # a group of 2,048 keys: one loop more, over its live parts
+    assert counts(4, 128, 128) == {**few, "while": 7}
+    # 16 packs x 32 pages of 64 KB: every page a copy of its own, one
+    # loop a site over the group's live pages, as before PR 59
+    assert counts(16, 128, 32) == {**few, "dma_start": 4, "dma_wait": 2,
+                                   "while": 3}
 
 
 def test_paged_rejects_shapes_the_tpu_cannot_tile():
@@ -555,6 +582,34 @@ def test_latent_kernel_fetches_a_run_with_one_dma(chunk):
     assert starts == sorted([(16, 640), (64, 16, 640)] * 2)
 
 
+#: the plain kernel's calls whose fetches the next test reads: SDAR's
+#: block lane (16 KB a page an operand), phi-4's window decode (40 KB, the
+#: walk starting inside the table) and Pythia's decode (64 KB: no runs)
+PAGED_RUN_SITES = {
+    "sdar_block_lane": (lambda sds: paged_block_case(sds), 4 * 128, True),
+    "phi4_window_decode": (lambda sds: paged_decode_case(
+        sds, 64, 512, 40, 20, 64, 0, 16, window=512), 20 * 64, True),
+    "pythia_decode": (lambda sds: paged_decode_case(
+        sds, 24, 128, 16, 16, 128, 0, 16), 16 * 128, False)}
+
+
+@pytest.mark.parametrize("site", list(PAGED_RUN_SITES))
+def test_paged_kernel_fetches_a_run_with_one_dma(site):
+    """Read off the plain kernel's jaxpr (no clock): at each of its two
+    sites (cold start, prefetch) it starts, an operand (k, v), EITHER one
+    copy of ``PAGE_RUN`` pool blocks ``[8, 16, lanes]`` OR one of a page
+    ``[16, lanes]`` a live page of the run, chosen by the flag of the run
+    — with a window or without — and waits at one site for the same; at
+    Pythia's 64 KB a page, where the bytes outlast the descriptors, a
+    page a copy and nothing else."""
+    case, lanes, by_runs = PAGED_RUN_SITES[site]
+    fn, args = case(jax.ShapeDtypeStruct)
+    starts, waits = dma_shapes(fn, *args)
+    assert starts == sorted([(16, lanes)] * 4
+                            + [(PAGE_RUN, 16, lanes)] * 4 * by_runs)
+    assert waits == 2 + 2 * by_runs
+
+
 def _parent_grouped_matmul(x, w, tile_expert, live_tiles):
     """``moe/dropless.py::grouped_matmul`` as it stood before it got a
     backward (PR 42's tree), verbatim: what the serving programs'
@@ -812,28 +867,35 @@ def test_latent_projections_read_their_weights_where_they_lie(
 
 
 #: sha256 of the PLAIN paged kernel's program, stripped of metadata and
-#: names: at Pythia's cell shapes (24 decode slots; a chunk of 256 rows; 16
-#: heads of 128; 128 pages of 16) as the commit BEFORE the kernel took a
-#: window (PR 47, ba84aa4) compiles it, and at the hybrid cells' (phi-4:
-#: 64 slots, 40 / 20 heads of 64, a window of 512, 512 pages, a 512-row
-#: chunk in walkers of 128 rows; Granite: 64 slots, 32 / 8 heads of 64, 256
-#: pages, the same chunk) as the commit before the LATENT kernel learned to
-#: fetch runs (PR 54, 929a1f6) compiles it: the three cells that run this
-#: kernel are that PR's control.  A PR that changes the kernel on purpose
-#: measures those cells and records the new digests here.
+#: names, at the calls of the four cells that run it: Pythia's (24 decode
+#: slots; a chunk of 256 rows; 16 heads of 128; 128 pages of 16), phi-4's
+#: (64 slots, 40 / 20 heads of 64, a window of 512, 512 pages, a 512-row
+#: chunk in walkers of 128 rows), Granite's (64 slots, 32 / 8 heads of 64,
+#: 256 pages, the same chunk) and SDAR's block lane (40 slots, 4 rows, 32 /
+#: 4 heads of 128, 128 pages) — as PR 59 left them, when the kernel learned
+#: to fetch a run with one DMA an operand and to contract its group's live
+#: parts (the first six stood unchanged from PR 47 / PR 54 until then).
+#: What they guard: a PR that does NOT mean to change this kernel — one
+#: that touches what it shares with the latent kernel (``_fetch_group``,
+#: ``_page_group_dma``, ``_grouped_tables``), adds an operand for one lane,
+#: or edits a wrapper — leaves every cell's program as it was.  A PR that
+#: changes the kernel on purpose measures those cells and records the new
+#: digests here.
 PAGED_PROGRAM_SHA256 = {
-    "decode": ("aa64db95e72bccaba2c36e902c2a22b5"
-               "ea8a2eb7a83161d8d0e37fbcbb5124b1"),
-    "prefill": ("658c953f10c3026e5e1c55470ecae111"
-                "09585a8d288328599f2a2fe6ccc8fb4d"),
-    "phi4_window_decode": ("d7ac82c5448bdcaa272f81b003b454a5"
-                           "1cf38825257a5b373cdb0909a945d2a2"),
-    "phi4_window_prefill": ("37b6b69e50648f53a80199d37f9a0aa4"
-                            "51e2828d1ec83573c020d0be7265a02e"),
-    "granite_decode": ("5994823fc31c222fe799b983fd2e77d0"
-                       "c64ea270f9aa14a065718afee1a5fb8d"),
-    "granite_prefill": ("14adfb634c5d9c770f41b376a43de944"
-                        "71431fbd8f70c88d66502560bcf68de5")}
+    "decode": ("a0f0d633ddec58d0d7ddd69a0b3e7d4e"
+               "17f0f674646c137cb8004122c4d09c56"),
+    "prefill": ("6c0fe5c381fd64954dd97739b39226bf"
+                "60f45b085fc84b3014b5adb41eab3727"),
+    "phi4_window_decode": ("a758e145d19dd9050102af02d1fd7bd1"
+                           "63254071955cc3ce6c5043546f7cb8f9"),
+    "phi4_window_prefill": ("41aebff0570a2970a51f049f1009ac6b"
+                            "1ffb6c7fcaf8840c6e0323c2cfc65aa7"),
+    "granite_decode": ("d6228f51086f757e66899a0450b95676"
+                       "690a3a5e274ea21be5df05a715f69fbe"),
+    "granite_prefill": ("85b2350b9a61b4819afb52cb3960466f"
+                        "71554715f87731ec6b06e545a7bc16a1"),
+    "sdar_block_lane": ("4fa07e345abd61882cbd04062f7cb7f1"
+                        "dd244780bb324b02ee342c2ad768df65")}
 
 
 def paged_program_case(sds, lane):
@@ -843,6 +905,8 @@ def paged_program_case(sds, lane):
         return paged_decode_case(sds, 24, 128, 16, 16, 128, 0, 16)
     if lane == "prefill":
         return paged_prefill_case(sds, 128, 16, 128, 0, 16)
+    if lane == "sdar_block_lane":
+        return paged_block_case(sds)
     h, hkv, pages, kw = {"phi4": (40, 20, 512, {"window": 512}),
                          "granite": (32, 8, 256, {})}[lane.split("_")[0]]
     if lane.endswith("decode"):
@@ -859,13 +923,14 @@ def paged_program_case(sds, lane):
 @pytest.mark.parametrize("lane", list(PAGED_PROGRAM_SHA256))
 def test_paged_kernel_without_a_window_is_the_program_it_was(v5e_devices,
                                                              lane):
-    """The window (a static operand of the kernel, two more rows of its
-    scalar prefetch, a first page in its DMA loop) costs a call that has
-    none nothing: the same operands and the same instructions, kernel
-    body included, as before the kernel could take one.  Nor does what
-    the latent kernel shares with it (``_page_group_dma``'s span of a
-    run) reach the plain kernel's program, with a window or without, at
-    any of the cells that run it."""
+    """The plain paged kernel's program at each call the cells make is
+    the one on record: what one lane needs (a window: a static operand,
+    two more rows of scalar prefetch, a first page in the DMA loops; the
+    block lane: other offsets) costs a call without it nothing, and what
+    the kernel shares with the latent kernel's walk (``_fetch_group``,
+    ``_page_group_dma``'s span of a run, ``_grouped_tables``) does not
+    change under it unseen.  Same operands, same instructions, kernel
+    body included."""
     import hashlib
 
     def program(*a):            # one name, one text
