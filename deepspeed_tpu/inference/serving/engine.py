@@ -1624,7 +1624,9 @@ class ServingEngine:
             cache = {"k": pool_k, "v": pool_v, "k_scale": pool_ks,
                      "v_scale": pool_vs, "block_tables": sl.tables,
                      "lens": sl.lens}
-            logits, cache = model._apply_paged_block(
+            # the one step, at B rows a slot (nothing samples from the
+            # chunk of such a model: no head over it)
+            logits, _, cache = model._apply_paged_mixed(
                 mp, cache, block, sl.dec_active, ch.ids, ch.slot, ch.start,
                 ch.len)
             # confidence, ranking, transfer: on the device

@@ -30,13 +30,15 @@ block's ``B`` rows of k / v at the same pool rows, ``lens .. lens + B -
 1``; only a commit moves ``lens``, so whatever a denoise forward wrote is
 overwritten by the next forward before anything later can see it.
 
-The paged step is :meth:`BlockDiffusionMoELM._apply_paged_block`: every
-slot's current block (``B`` rows a slot — the BLOCK LANE, one walk of a
-slot's pages for all its rows, ``ops/transformer/paged_decode_attention.py
-::paged_block_attention``) beside one block-causal prompt chunk.  There
-is no one-token decode lane and no draft lane.  The experts are ONE CHIP'S
-SHARE (``experts_held``): the router keeps its published width, a pick of
-an absent expert adds nothing.
+The paged step is ``TransformerLM._apply_paged_mixed`` at ``B`` rows a slot
+(``dec_tokens [S, B]``): every slot's current block — the BLOCK LANE, one
+walk of a slot's pages for all its rows, ``ops/transformer/
+paged_decode_attention.py::paged_block_attention`` — beside one
+block-causal prompt chunk.  This file brings the layer
+(``_paged_mixed_attention``), its one scan (``_paged_layers``) and its
+counters.  There is no one-token decode lane and no draft lane.  The
+experts are ONE CHIP'S SHARE (``experts_held``): the router keeps its
+published width, a pick of an absent expert adds nothing.
 """
 from __future__ import annotations
 
@@ -50,7 +52,8 @@ from jax.sharding import PartitionSpec as P
 
 from . import layers as L
 from ..moe import dropless
-from .transformer import TransformerConfig, TransformerLM
+from .transformer import (MixedStep, PagedMixedState, TransformerConfig,
+                          TransformerLM, lane_pool_rows, scatter_rows)
 
 #: rows of a walker of the prompt chunk's attention (whole blocks): what a
 #: chunk of 512 rows x 8 query heads a kv head needs to fit VMEM
@@ -64,7 +67,8 @@ QK_LOGIT_STD = 2.5
 _NO_DECODE_LANE = (
     "a block-diffusion model has no one-token decode lane (a forward "
     "carries a slot's whole block): the serving engine drives "
-    "_apply_paged_block, and generate() — a left-to-right loop over a "
+    "_apply_paged_mixed with [slots, block_length] tokens, and generate() "
+    "— a left-to-right loop over a "
     "dense cache — does not apply; serve it through serving_engine()")
 
 
@@ -111,10 +115,9 @@ class BlockDiffusionMoEConfig(TransformerConfig):
 class BlockDiffusionMoELM(TransformerLM):
     """``TransformerLM`` for the block above: same ``init`` / ``apply`` /
     ``init_paged_cache`` / ``partition_specs`` surface; ``apply`` runs a
-    whole sequence under the block mask, ``_apply_paged_block`` the
-    serving step."""
+    whole sequence under the block mask."""
 
-    #: what ``_apply_paged_block`` counts in the program, a dispatch: the
+    #: what the serving step counts in the program, a dispatch: the
     #: expert layers' counters, then the pages the layers' walks (the block
     #: lane's and the chunk's) were handed and those of them in runs
     PAGED_COUNTERS = dropless.COUNTERS + ("kv_pages_read",
@@ -153,12 +156,6 @@ class BlockDiffusionMoELM(TransformerLM):
                 "ops/transformer/flash_attention.py has no mask for "
                 "(ROADMAP B12); its experts' grouped product "
                 "differentiates, as models/cca_moe.py trains through it")
-
-    def tp_serving_view(self, model_shards, tp_axis, dp_axis):
-        if model_shards > 1 or dp_axis is not None:
-            raise NotImplementedError(self.paged_refusal(
-                mesh_model=model_shards, mesh_data=2 if dp_axis else 1))
-        return self
 
     def init_cache(self, batch, max_len, dtype=None):
         raise NotImplementedError(_NO_DECODE_LANE)
@@ -331,126 +328,59 @@ class BlockDiffusionMoELM(TransformerLM):
         return super().apply(params, input_ids)
 
     # -- the paged step ----------------------------------------------------
-    def _apply_paged_mixed(self, *args, **kwargs):
-        raise NotImplementedError(_NO_DECODE_LANE)
-
-    def _paged_mixed_attention(self, q, k, v, st):
-        """One layer of the block step.  q / k / v arrive as ``[1, S * B +
-        C, heads, D]`` — slot-major, every slot's block of ``B`` rows, then
-        one slot's prompt chunk; rotary already applied at each row's
+    def _paged_mixed_attention(self, q, k, v, st: PagedMixedState):
+        """One layer of the serving step.  q / k / v arrive as ``[1, S * B
+        + C, heads, D]`` — slot-major, every slot's block of ``B`` rows,
+        then one slot's prompt chunk; rotary already applied at each row's
         position.  Every row's k / v goes into the pool in one scatter
         (block row ``i`` of slot ``s`` at position ``lens[s] + i``, chunk
         rows at ``chunk_start + i``; an idle slot's and padding rows to
         the layer's null block), then two ``paged_attention`` calls
         attend: the block lane — all slots, ``B`` rows each, one walk of a
         slot's pages, every row seeing ``lens[s] + B`` keys — and the
-        chunk, block-causal.  ``st = (k pool, v pool, tables, lens, block
-        active, chunk slot, chunk start, chunk len, null block)``."""
+        chunk, block-causal."""
         from ..ops.transformer.paged_decode_attention import (
             paged_block_attention, paged_prefill_attention)
-        pool_k, pool_v, tables, lens, act, cslot, cstart, clen, null_blk = st
+        step, tables = st.step, st.block_tables
         _, t, nh, hd = q.shape
-        bk = self.config.block_length
-        slots = lens.shape[0]
+        slots, bk, c = step.slots, step.rows, step.chunk
         nb = slots * bk
-        c = t - nb
-        blk, npages = pool_k.shape[1], tables.shape[1]
         with jax.named_scope("pool_write"):
-            null = null_blk * blk
-            pos = lens[:, None] + jnp.arange(bk)[None, :]          # [S, B]
-            page = jnp.minimum(pos // blk, npages - 1)
-            write = jnp.where(
-                (act > 0)[:, None],
-                jnp.take_along_axis(tables, page, axis=1) * blk + pos % blk,
-                null).reshape(-1)
-            ctable = None
-            if c:
-                ci = jnp.arange(c)
-                cpos = cstart + ci
-                ctable = tables[cslot]
-                cpage = jnp.minimum(cpos // blk, npages - 1)
-                write = jnp.concatenate([write, jnp.where(
-                    ci < clen, ctable[cpage] * blk + cpos % blk, null)])
-
-            def put(pool, rows):
-                return pool.reshape(-1, pool.shape[2]).at[write].set(
-                    rows.astype(pool.dtype).reshape(t, -1)
-                ).reshape(pool.shape)
-            pk, pv = put(pool_k, k[0]), put(pool_v, v[0])
+            write, ctable = lane_pool_rows(step, tables, st.k_pool.shape[1],
+                                           st.null_block)
+            write = jnp.concatenate(write)
+            pk = scatter_rows(st.k_pool, write, k[0])
+            pv = scatter_rows(st.v_pool, write, v[0])
         with jax.named_scope("attn_kernel"):
             o = paged_block_attention(
-                q[0, :nb].reshape(slots, bk, nh, hd), pk, pv, lens, act,
-                tables).reshape(nb, nh, hd)
+                q[0, :nb].reshape(slots, bk, nh, hd), pk, pv, step.lens,
+                step.act, tables).reshape(nb, nh, hd)
             if c:
                 tile = (CHUNK_TILE_ROWS
                         if c > CHUNK_TILE_ROWS and c % CHUNK_TILE_ROWS == 0
                         and CHUNK_TILE_ROWS % bk == 0 else None)
                 o = jnp.concatenate([o, paged_prefill_attention(
-                    q[0, nb:], pk, pv, cstart, clen, ctable,
-                    tile_rows=tile, block_rows=bk)])
+                    q[0, nb:], pk, pv, step.chunk_start, step.chunk_len,
+                    ctable, tile_rows=tile, block_rows=bk)])
         return o.reshape(1, t, nh * hd), (pk, pv)
 
-    def _apply_paged_block(self, params, cache, block_tokens, block_active,
-                           chunk_ids, chunk_slot, chunk_start, chunk_len):
-        """The serving step of generation by diffusion over blocks: every
-        live slot's current block PLUS one chunk of a single slot's
-        prompt, in ONE program.
-
-        ``cache``: {"k" / "v": the :meth:`init_paged_cache` pools,
-        "block_tables" [S, pages], "lens" [S] — rows a slot has COMMITTED,
-        whole blocks}; ``block_tokens`` [S, B] int32 — each slot's block
-        as it stands (mask tokens where nothing is filled yet), fed at
-        positions ``lens[s] .. lens[s] + B - 1``; ``block_active`` [S]
-        (0: the slot rides no block; its rows go to the null block and
-        come back as zeros); ``chunk_ids`` [C] (C may be STATICALLY 0),
-        ``chunk_slot`` / ``chunk_start`` / ``chunk_len`` int32 scalars,
-        whole blocks.  The forward is the same whether the host calls it a
-        denoise or a commit: the block's k / v are written at its rows
-        either way, and ``lens`` is the host's to move.  Nothing samples
-        from the chunk (no head over it).  Returns ``(block_logits [S, B,
-        V] float32, new_cache)``; ``new_cache`` holds the pools and
-        ``counters`` (``PAGED_COUNTERS``, this dispatch's sums over the
-        layers)."""
-        if cache.get("k_scale") is not None:
-            raise NotImplementedError(self.paged_refusal(kv_bits=8))
-        tables, lens = cache["block_tables"], cache["lens"]
-        slots, bk = block_tokens.shape
-        if bk != self.config.block_length:
-            raise ValueError(f"block_tokens has {bk} rows a slot, the "
-                             f"model's block_length is "
-                             f"{self.config.block_length}")
-        cw = chunk_ids.shape[0]
-        with jax.named_scope("embed"):
-            act = block_active > 0
-            ci = jnp.arange(cw)
-            positions = jnp.concatenate([
-                jnp.where(act[:, None],
-                          lens[:, None] + jnp.arange(bk)[None, :],
-                          0).reshape(-1),
-                jnp.where(ci < chunk_len, chunk_start + ci, 0)])[None]
-            ids = jnp.concatenate([block_tokens.reshape(-1), chunk_ids])[None]
-            row_valid = jnp.concatenate([jnp.repeat(act, bk),
-                                         ci < chunk_len])
-        x = self._embed_tokens(params, ids)
-        nl, nb = cache["k"].shape[:2]
-        pools = tuple(cache[n].reshape(nl * nb, *cache[n].shape[2:])
-                      for n in ("k", "v"))
-        # the expert stack stays out of the scan's xs: sliced per layer
-        # it would be copied whole, every step, to reach the kernel
-        blocks = params["blocks"]
-        experts = blocks["moe"]["experts"]
-        blocks = dict(blocks, moe={k: v for k, v in blocks["moe"].items()
-                                   if k != "experts"})
+    def _paged_layers(self, params, x, carry, step: MixedStep, probe):
+        """One scan over the layers, the pools its carry; ``counts`` the
+        expert layers' ``dropless.COUNTERS``."""
+        nb = step.num_blocks
+        tables = step.tables
+        blocks, experts = dropless.split_experts(params["blocks"])
+        nl = experts["w_up"].shape[0]
 
         def scan_fn(carry, xs):
             y, pools, counts = carry
             bp, off, layer = xs
             with jax.named_scope("pool_write"):
-                st = (*pools, tables + off, lens, block_active, chunk_slot,
-                      chunk_start, chunk_len, off)
+                st = PagedMixedState(*pools, tables + off, step,
+                                     null_block=off)
             y, pools, moe_counts = self._block(
-                self.block_transform(bp), y, st, positions,
-                row_valid=row_valid, stack=(experts, layer))
+                self.block_transform(bp), y, st, step.positions,
+                row_valid=step.row_valid, stack=(experts, layer))
             with jax.named_scope("expert_layout"):
                 counts = counts + moe_counts
             return (y, pools, counts), None
@@ -458,24 +388,8 @@ class BlockDiffusionMoELM(TransformerLM):
         with jax.named_scope("pool_write"):
             offs = jnp.arange(nl, dtype=tables.dtype) * nb
         zero = jnp.zeros((len(dropless.COUNTERS),), jnp.int32)
-        (x, pools, counters), _ = jax.lax.scan(
-            scan_fn, (x, pools, zero),
+        (x, pools, counts), _ = jax.lax.scan(
+            scan_fn, (x, (carry["k"], carry["v"]), zero),
             (blocks, offs, jnp.arange(nl, dtype=jnp.int32)))
-        x = self._norm_fn("head")(params["ln_f"], x[:, :slots * bk])
-        with jax.named_scope("head"):
-            logits = self._project(params, x)[0].reshape(slots, bk, -1)
-        with jax.named_scope("pool_write"):
-            from ..ops.transformer.paged_decode_attention import walk_pages
-            read, walked = jnp.where(act, lens + bk, 0), tables
-            if cw:
-                read = jnp.append(read, jnp.where(
-                    chunk_len > 0, chunk_start + chunk_len, 0))
-                walked = jnp.concatenate([tables, tables[chunk_slot][None]])
-            counters = jnp.concatenate([counters, nl * jnp.stack(
-                walk_pages(walked, read, cache["k"].shape[2]))
-                .astype(jnp.int32)])
-        new_cache = {"k": pools[0].reshape(nl, nb, *pools[0].shape[1:]),
-                     "v": pools[1].reshape(nl, nb, *pools[1].shape[1:]),
-                     "block_tables": tables, "lens": lens,
-                     "counters": counters}
-        return logits, new_cache
+        return (x, dict(zip(("k", "v"), pools)),
+                dict(zip(dropless.COUNTERS, counts)), None)

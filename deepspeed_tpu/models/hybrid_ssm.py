@@ -33,17 +33,18 @@ the pages its window still reaches; ``cache["extra"]["wk" / "wv"]``),
 and per SLOT, not per token, each state-space layer's convolution tail
 and state (``extra["conv"]``, ``extra["ssm"]``; float32 state, tiled as
 ``ops/transformer/ssm_scan.py`` wants it).  A chunk whose first row is
-row 0 starts from zero state, so admission resets nothing.  In the mixed
-step the cross decoder — the full layer's query side and everything
-after it — runs on the rows that yield a token only: the decode rows and
-the chunk's last row; every other chunk row is done once the full layer
-has written its key and value.
+row 0 starts from zero state, so admission resets nothing.  In the one
+serving step (``TransformerLM._apply_paged_mixed``; this file brings its
+layers, ``_paged_layers``) the cross decoder — the full layer's query
+side and all after it — runs on the rows that yield a token only: the
+decode rows and the chunk's last row; every other chunk row is done once
+the full layer has written its key and value.
 """
 from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Dict, NamedTuple, Optional, Tuple
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -51,7 +52,8 @@ from jax.sharding import PartitionSpec as P
 
 from . import layers as L
 from ..ops.transformer import ssm_scan
-from .transformer import TransformerConfig, TransformerLM
+from .transformer import (MixedStep, TransformerConfig, TransformerLM,
+                          lane_pool_rows, scatter_rows)
 
 #: rows of a prompt chunk to one walker of the window layers' kernel: at
 #: 2 query heads a key-value head and 2 heads a lane pack, 128 positions
@@ -119,27 +121,13 @@ class HybridSSMConfig(TransformerConfig):
                 + self.vocab_size * self.d_model + 2 * self.d_model)
 
 
-class HybridStep(NamedTuple):
-    """One dispatch of the mixed step as every layer sees it."""
-    tables: jax.Array          # [S, pages] the full layer's
-    wtables: jax.Array         # [S, pages] the window layers'
-    lens: jax.Array
-    act: jax.Array             # [S] bool
-    chunk_slot: jax.Array
-    chunk_start: jax.Array
-    chunk_len: jax.Array
-    slots: int                 # S
-    chunk: int                 # C (static; 0 = the decode-only shape)
-
-
 class PerSlotState:
     """What ANY block that keeps recurrent state a SLOT takes part in,
     whatever else it is (a mixin beside a ``TransformerLM``: this file's
     block, ``models/ssd_hybrid.py``'s, and ``models/kda_latent_moe.py``'s,
     which is a latent block besides): the refusals and their sentences,
     the convolution tails' life through the mixed step, the scatter of a
-    step's new rows into a paged pool, and the rows that yield a
-    token."""
+    step's new rows into a paged pool."""
 
     #: why a quantized pool is refused (the block's own reason)
     KV_BITS_REFUSAL = ""
@@ -154,12 +142,6 @@ class PerSlotState:
     def padded_prompt_refusal(self) -> Optional[str]:
         return ("prompt_bucket pads a prompt on the right, and the padding "
                 "would run through the state-space layers' recurrent state")
-
-    def tp_serving_view(self, model_shards, tp_axis, dp_axis):
-        if model_shards > 1 or dp_axis is not None:
-            raise NotImplementedError(self.paged_refusal(
-                mesh_model=model_shards, mesh_data=2 if dp_axis else 1))
-        return self
 
     def _paged_supported(self) -> Optional[str]:
         return None
@@ -190,7 +172,7 @@ class PerSlotState:
 
     # -- the mixed step's shared pieces ------------------------------------
     @staticmethod
-    def _conv_rows(w, u, tails, st: HybridStep):
+    def _conv_rows(w, u, tails, st: MixedStep):
         """The causal depthwise convolution of the step's rows ``u [S +
         C, channels]`` (before its bias and silu): the decode rows each
         behind their slot's tail ``tails [taps - 1, S, channels]``, the
@@ -211,7 +193,7 @@ class PerSlotState:
         return jnp.concatenate(conv), win, padded
 
     @staticmethod
-    def _next_tails(tails, win, padded, st: HybridStep):
+    def _next_tails(tails, win, padded, st: MixedStep):
         """The tails after the step: a decoding slot's moves on a row, the
         chunk's slot holds the chunk's last valid rows, the rest stay."""
         k = win.shape[0]
@@ -224,39 +206,23 @@ class PerSlotState:
         return tails
 
     @staticmethod
-    def _write_rows(pool_k, pool_v, k, v, tables, st: HybridStep, null):
+    def _state_rows(st: MixedStep, layers: int):
+        """What ``layers`` layers with state a slot are handed: ``((row,
+        layer) pairs through the chunk's blocked form, through the decode
+        update, chunks that start a slot's state from zero)``."""
+        rides = (st.chunk_len > 0) if st.chunk else jnp.bool_(False)
+        return (layers * (st.chunk_len if st.chunk else 0),
+                layers * jnp.sum(st.act, dtype=jnp.int32),
+                rides & (st.chunk_start == 0))
+
+    @staticmethod
+    def _write_rows(pool_k, pool_v, k, v, tables, st: MixedStep, null):
         """The step's new k / v rows ``[S + C, lanes]`` into the pools at
         their slots' pages (``tables`` already offset to the layer; masked
         rows to the layer's null block ``null``)."""
-        blk = pool_k.shape[1]
-        npages = tables.shape[1]
-        null_row = null * blk
-        slot = jnp.arange(st.slots)
-        write = [jnp.where(st.act, tables[slot, st.lens // blk] * blk
-                           + st.lens % blk, null_row)]
-        if st.chunk:
-            ci = jnp.arange(st.chunk)
-            cpos = st.chunk_start + ci
-            ctable = tables[st.chunk_slot]
-            write.append(jnp.where(
-                ci < st.chunk_len,
-                ctable[jnp.minimum(cpos // blk, npages - 1)] * blk
-                + cpos % blk, null_row))
-        write = jnp.concatenate(write)
-
-        def put(pool, rows):
-            return pool.reshape(-1, pool.shape[2]).at[write].set(
-                rows.astype(pool.dtype)).reshape(pool.shape)
-        return put(pool_k, k), put(pool_v, v)
-
-    def _yield_rows(self, a, st: HybridStep):
-        """The rows of ``a [S + C, ..]`` that yield a token: the decode
-        rows and the chunk's last valid row."""
-        if not st.chunk:
-            return a
-        last = jax.lax.dynamic_slice_in_dim(
-            a, st.slots + jnp.maximum(st.chunk_len - 1, 0), 1, axis=0)
-        return jnp.concatenate([a[:st.slots], last])
+        write = jnp.concatenate(
+            lane_pool_rows(st, tables, pool_k.shape[1], null)[0])
+        return scatter_rows(pool_k, write, k), scatter_rows(pool_v, write, v)
 
 
 class PerSlotStateLM(PerSlotState, TransformerLM):
@@ -265,11 +231,12 @@ class PerSlotStateLM(PerSlotState, TransformerLM):
     ``models/ssd_hybrid.py``'s) beyond :class:`PerSlotState`: the gated
     MLP, the dense attention of ``generate()``, and ``apply``.  A subclass brings
     its pattern, its mixers, ``init`` / ``_forward`` / ``init_cache`` and
-    the mixed step.  Its ``init()`` is ``init_resident`` plus, for every
-    part of ``PARTS`` (stack name -> what an element is made from), the
-    stack of ``init_pair(PARTS[part], key)`` over ``pair_keys(rng)[part]``:
-    whoever fills a tree an element at a time (the benchmark, in the
-    served type) goes through those three."""
+    its layers of the serving step (``_paged_layers``).  Its ``init()`` is
+    ``init_resident`` plus, for every part of ``PARTS`` (stack name ->
+    what an element is made from), the stack of ``init_pair(PARTS[part],
+    key)`` over ``pair_keys(rng)[part]``: whoever fills a tree an element
+    at a time (the benchmark, in the served type) goes through those
+    three."""
 
     # -- init --------------------------------------------------------------
     def _norm_init(self, dim: Optional[int] = None):
@@ -333,13 +300,13 @@ class PerSlotStateLM(PerSlotState, TransformerLM):
 
 class HybridSSMLM(PerSlotStateLM):
     """``TransformerLM``'s surface (``init`` / ``apply`` / ``generate()``'s
-    cache / ``init_paged_cache`` / ``_apply_paged_mixed`` /
-    ``partition_specs``) for the hybrid block."""
+    cache / ``init_paged_cache`` / ``partition_specs``) for the hybrid
+    block, and its three scans of the one serving step."""
 
     #: the block tables a slot has, in the order the engine lays them
     #: side by side in its per-slot operand
     TABLE_KINDS = ("full", "window")
-    #: what ``_apply_paged_mixed`` counts a dispatch, each added up where
+    #: what the serving step counts a dispatch, each added up where
     #: the work is handed to its kernel (a layer that walked more, or a
     #: row that was not spared, moves it): keys the eight walks over the
     #: FULL layer's pages were handed, keys the window layers' walks were,
@@ -615,18 +582,10 @@ class HybridSSMLM(PerSlotStateLM):
         return ((w - 1) // block_size + 2,
                 (w - 1 + chunk_tokens - 1) // block_size + 2)
 
-    def init_paged_cache(self, num_blocks: int, block_size: int,
-                         dtype=None, kv_bits: int = 0) -> Dict:
-        """The FULL layer's pool: one layer of ``num_blocks`` pages, k and
-        v ``[1, num_blocks, block, kv_heads * head_dim]``; everything else
-        a slot keeps is :meth:`init_paged_extra`'s."""
-        reason = self.paged_refusal(kv_bits=kv_bits)
-        if reason is not None:
-            raise NotImplementedError(reason)
-        c = self.config
-        shape = (1, num_blocks, block_size, c.kv_heads * c.hdim)
-        dtype = dtype or c.dtype
-        return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+    def _pool_sublayers(self) -> int:
+        """The pool ``k`` / ``v`` is the FULL layer's alone; everything
+        else a slot keeps is :meth:`init_paged_extra`'s."""
+        return 1
 
     def init_paged_extra(self, num_slots: int, block_size: int,
                          window_blocks: int, dtype=None) -> Dict:
@@ -656,7 +615,7 @@ class HybridSSMLM(PerSlotStateLM):
                                  *extra["ssm"].shape[1:])[:, slot]
         return ssm_scan.state_from_tiles(s)
 
-    def _ssm_paged(self, p, h, conv_buf, ssm_buf, layer, st: HybridStep):
+    def _ssm_paged(self, p, h, conv_buf, ssm_buf, layer, st: MixedStep):
         """A state-space mixer in the mixed step: the decode rows each
         from their slot's tail and state, the chunk from its slot's (zero
         where the chunk starts a prompt); ``h [1, S + C, d]``.  Returns
@@ -703,7 +662,7 @@ class HybridSSMLM(PerSlotStateLM):
         return (self._ssm_out(p, y, z)[None], y, conv_buf, ssm_buf,
                 jnp.stack(rows).astype(jnp.int32))
 
-    def _window_paged(self, p, h, wk, wv, off, st: HybridStep):
+    def _window_paged(self, p, h, wk, wv, off, st: MixedStep):
         """A window-attention mixer in the mixed step: every row writes
         its k / v into the layer's pages (``off``: its block offset into
         the window pool), then the decode rows and the chunk attend the
@@ -741,7 +700,7 @@ class HybridSSMLM(PerSlotStateLM):
             return (L.dense_apply(p["out"], o.reshape(1, -1, nh * hd)), wk,
                     wv, read.astype(jnp.int32))
 
-    def _full_walk(self, q, pool_k, pool_v, st: HybridStep):
+    def _full_walk(self, q, pool_k, pool_v, st: MixedStep):
         """The yield rows' queries ``[S (+ 1), H, hd]`` against the full
         layer's pages: each is one more one-row walker.  Returns ``(o,
         the keys the walk was handed)``."""
@@ -758,30 +717,7 @@ class HybridSSMLM(PerSlotStateLM):
                                            sm_scale=self._sm_scale),
                     jnp.sum(lengths).astype(jnp.int32))
 
-    def _walk_pages(self, st: HybridStep, block: int):
-        """``[pages, pages in runs]`` of a dispatch's walks, from the
-        tables and lengths :meth:`_full_walk` (x the full layer and the
-        cross layers) and :meth:`_window_paged` (x the window layers, from
-        each walk's first attended position) hand the kernel."""
-        from ..ops.transformer.paged_decode_attention import walk_pages
-        c, w = self.config, self.config.sliding_window
-        total = jnp.where(st.act, st.lens + 1, 0)
-        first = jnp.maximum(total - w, 0)
-        tables, wtables = st.tables, st.wtables
-        if st.chunk:
-            total = jnp.append(total, jnp.where(
-                st.chunk_len > 0, st.chunk_start + st.chunk_len, 0))
-            first = jnp.append(first,
-                               jnp.maximum(st.chunk_start - (w - 1), 0))
-            tables = jnp.concatenate([tables, tables[st.chunk_slot][None]])
-            wtables = jnp.concatenate([wtables,
-                                       wtables[st.chunk_slot][None]])
-        return ((1 + c.pairs_cross)
-                * jnp.stack(walk_pages(tables, total, block))
-                + c.pairs_self
-                * jnp.stack(walk_pages(wtables, total, block, first)))
-
-    def _cross_paged(self, p, h, pool_k, pool_v, st: HybridStep):
+    def _cross_paged(self, p, h, pool_k, pool_v, st: MixedStep):
         """A cross-attention mixer in the mixed step: the yield rows'
         queries against the full layer's pages.  Returns ``(out, (the
         attention's output ``[S (+ 1), H x hd]``, the keys handed))``."""
@@ -793,38 +729,18 @@ class HybridSSMLM(PerSlotStateLM):
         with jax.named_scope("attn_proj"):
             return L.dense_apply(p["out"], o), (o[0], read)
 
-    def _apply_paged_mixed(self, params, cache, dec_tokens, dec_active,
-                           chunk_ids, chunk_slot, chunk_start, chunk_len,
-                           spec_tokens=None, spec_active=None, probe=False):
-        """The mixed step of ``TransformerLM._apply_paged_mixed`` for the
-        hybrid block: same operands, same results.  ``cache``: ``k`` /
-        ``v`` the full layer's pool ``[1, nb, block, lanes]``, ``extra``
-        as :meth:`init_paged_extra`, ``block_tables [S, 2 pages]`` (the
-        full layer's table, then the window layers'), ``lens``.  Three
-        scans over one skeleton: the (state space, window) pairs, the
-        middle pair, and — on the rows that yield a token only — the
-        (memory unit, cross) pairs, with the memory ``m`` and the full
-        pool as loop constants.  ``new_cache`` also holds ``counters``
-        (``PAGED_COUNTERS``: added up from what each layer's kernels were
-        handed) and with ``probe`` (a check's, never the engine's)
-        ``probe``: what the eight walks over the full layer's pages gave
-        the yield rows, ``reads [1 + pairs_cross, S (+ 1), H x hd]``."""
-        if spec_tokens is not None:
-            raise NotImplementedError(self.paged_refusal(spec=True))
-        if cache.get("k_scale") is not None:
-            raise NotImplementedError(self.paged_refusal(kv_bits=8))
+    def _paged_layers(self, params, x, carry, st: MixedStep, probe):
+        """Three scans: the (state space, window) pairs, the middle pair,
+        and — on the rows that yield a token only, which is what comes
+        back — the (memory unit, cross) pairs, with the memory ``m`` and
+        the full pool as loop constants.  ``counts``: what each layer's
+        kernels were handed; ``seen`` (``probe``): what the eight walks
+        over the full layer's pages gave the yield rows, ``reads [1 +
+        pairs_cross, S (+ 1), H x hd]``."""
         c = self.config
         nh, hd = c.num_heads, c.hdim
-        extra = cache["extra"]
-        s, cw = dec_tokens.shape[0], chunk_ids.shape[0]
-        pages = cache["block_tables"].shape[1] // 2
-        with jax.named_scope("embed"):
-            st = HybridStep(cache["block_tables"][:, :pages],
-                            cache["block_tables"][:, pages:], cache["lens"],
-                            dec_active > 0, chunk_slot, chunk_start,
-                            chunk_len, s, cw)
-            ids = jnp.concatenate([dec_tokens, chunk_ids])[None]
-        x = self._embed_tokens(params, ids)
+        cw, chunk_len = st.chunk, st.chunk_len
+        extra = carry["extra"]
         nbw = extra["wk"].shape[1]
         wk = extra["wk"].reshape(-1, *extra["wk"].shape[2:])
         wv = extra["wv"].reshape(-1, *extra["wv"].shape[2:])
@@ -867,7 +783,7 @@ class HybridSSMLM(PerSlotStateLM):
         state = {"conv": conv_buf, "ssm": ssm_buf}
         x, m = self._shell(mid["a"], x,
                            ssm_mixer(jnp.int32(c.pairs_self), state))
-        pool_k, pool_v = cache["k"][0], cache["v"][0]
+        pool_k, pool_v = carry["k"], carry["v"]
         norm = self._norm_fn()
         fp = mid["b"]
         h = norm(fp["ln1"], x)
@@ -913,29 +829,23 @@ class HybridSSMLM(PerSlotStateLM):
 
         x, (o_cross, cross_read) = jax.lax.scan(cross_pair, x,
                                                 params["cross"])
-        x = self._norm_fn("head")(params["ln_f"], x)
-        with jax.named_scope("head"):
-            logits = self._project(params, x)[0]
-            dec_logits = logits[:s]
-            chunk_logits = (logits[s] if cw else
-                            jnp.zeros((logits.shape[-1],), logits.dtype))
-        with jax.named_scope("pool_write"):
-            rows = jnp.sum(ssm_rows, axis=0) + state["rows"]
-            counters = jnp.stack([
-                full_read + jnp.sum(cross_read), jnp.sum(window_read),
-                rows[0], rows[1], spared, state["rows"][2],
-                *self._walk_pages(st, pool_k.shape[1])]
-            ).astype(jnp.int32)
-            new_lens = (st.lens + st.act.astype(st.lens.dtype)
-                        ).at[chunk_slot].add(chunk_len, mode="drop")
-        new_extra = {"wk": wk.reshape(extra["wk"].shape),
-                     "wv": wv.reshape(extra["wv"].shape),
-                     "conv": state["conv"], "ssm": state["ssm"]}
-        new_cache = {"k": pool_k[None], "v": pool_v[None],
-                     "extra": new_extra,
-                     "block_tables": cache["block_tables"],
-                     "lens": new_lens, "counters": counters}
-        if probe:
-            new_cache["probe"] = {
-                "reads": jnp.concatenate([o_full[None], o_cross])}
-        return dec_logits, chunk_logits, new_cache
+        carry = {"k": pool_k, "v": pool_v, "extra": {
+            "wk": wk.reshape(extra["wk"].shape),
+            "wv": wv.reshape(extra["wv"].shape),
+            "conv": state["conv"], "ssm": state["ssm"]}}
+        rows = jnp.sum(ssm_rows, axis=0) + state["rows"]
+        counts = {"kv_tokens_read_full": full_read + jnp.sum(cross_read),
+                  "kv_tokens_read_window": jnp.sum(window_read),
+                  "ssm_chunk_rows": rows[0], "ssm_decode_rows": rows[1],
+                  "cross_rows_spared": spared,
+                  "state_slots_started": state["rows"][2]}
+        seen = ({"reads": jnp.concatenate([o_full[None], o_cross])}
+                if probe else None)
+        return x, carry, counts, seen
+
+    def _paged_walks(self, st):
+        # the full layer's walk and the cross layers' (the same pages);
+        # the window layers', from each walk's first attended position
+        c = self.config
+        return ((st.tables, None, 1 + c.pairs_cross),
+                (st.wtables, c.sliding_window, c.pairs_self))

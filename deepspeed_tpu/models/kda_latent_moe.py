@@ -39,9 +39,10 @@ None), and a SLOT and not a token, in ``cache["extra"]``, every ``kda``
 layer's convolution tail (``conv [taps - 1, kda layers x slots, 3 H K]``,
 the activations' type) and matrix state (``state [kda layers x slots, H,
 V, K]`` float32, value-major as ``kda_scan.py`` keeps it): at the
-published widths 2 MB a layer a slot, 42 MB a slot.  The mixed step
-carries pool and both state buffers through its scans and updates each
-where it lies; a chunk whose first row is row 0 starts from zero state.
+published widths 2 MB a layer a slot, 42 MB a slot.  The serving step
+(``TransformerLM._apply_paged_mixed``; ``_paged_layers`` here) carries
+pool and both state buffers through the walk and updates each where it
+lies; a chunk whose first row is row 0 starts from zero state.
 
 What is scanned (``layer_plan``): the stack is cut into a head, the
 longest stretch that repeats a period of (mixer, FFN) signatures, and a
@@ -55,7 +56,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Dict, List, Optional, Tuple
+from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -63,8 +64,9 @@ import jax.numpy as jnp
 from . import layers as L
 from ..moe import dropless
 from ..ops.transformer import kda_scan
-from .hybrid_ssm import HybridStep, PerSlotState
-from .latent_moe import DenseLeadMoEConfig, DenseLeadMoELM, MixedStep
+from .hybrid_ssm import PerSlotState
+from .latent_moe import DenseLeadMoEConfig, DenseLeadMoELM
+from .transformer import MixedStep
 
 KDA, MLA = "kda", "mla"
 DENSE, MOE = "dense", "moe"
@@ -174,7 +176,7 @@ class KDALatentMoELM(PerSlotState, DenseLeadMoELM):
     tails' life, the refusals)."""
 
     TABLE_KINDS = ("full",)
-    #: what ``_apply_paged_mixed`` counts a dispatch beyond the latent
+    #: what the serving step counts a dispatch beyond the latent
     #: block's: (row, ``kda`` layer) pairs through the decode update and
     #: through the chunk's blocked form, and chunks that started a slot's
     #: state from zero
@@ -314,12 +316,9 @@ class KDALatentMoELM(PerSlotState, DenseLeadMoELM):
     def _stacks(self, params):
         """``params`` as the layer bodies read them: the four stacks, the
         expert layers' without their experts, and the experts."""
-        moe = params[MOE]
-        rest = dict(moe, moe={k: v for k, v in moe["moe"].items()
-                              if k != "experts"})
+        rest, experts = dropless.split_experts(params[MOE])
         return ({KDA: params[KDA], MLA: params[MLA],
-                 DENSE: params.get(DENSE), MOE: rest},
-                moe["moe"]["experts"])
+                 DENSE: params.get(DENSE), MOE: rest}, experts)
 
     def _walk(self, layer_fn, carry):
         """``layer_fn(carry, mixer kind, FFN kind, at) -> carry`` over
@@ -464,7 +463,7 @@ class KDALatentMoELM(PerSlotState, DenseLeadMoELM):
         rows = jnp.arange(self.config.kda_layers) * num_slots + slot
         return jnp.swapaxes(extra["state"][rows], -1, -2)
 
-    def _kda_paged(self, p, h, conv_buf, state_buf, layer, st: HybridStep):
+    def _kda_paged(self, p, h, conv_buf, state_buf, layer, st: MixedStep):
         """A ``kda`` mixer in the mixed step: the decode rows each from
         their slot's tail and state, the chunk from its slot's (zero where
         the chunk starts a prompt); ``h [S + C, d]``, ``layer`` the
@@ -513,45 +512,12 @@ class KDALatentMoELM(PerSlotState, DenseLeadMoELM):
         with jax.named_scope("kda_proj"):
             return self._kda_out(p, o, gate), conv_buf, state_buf
 
-    def _state_counters(self, st: HybridStep) -> list:
-        """This block's three counters, in ``PAGED_COUNTERS``' order."""
-        c = self.config
-        rides = (st.chunk_len > 0) if st.chunk else jnp.bool_(False)
-        return [c.kda_layers * jnp.sum(st.act, dtype=jnp.int32),
-                c.kda_layers * (st.chunk_len if st.chunk else 0),
-                rides & (st.chunk_start == 0)]
-
-    def _apply_paged_mixed(self, params, cache, dec_tokens, dec_active,
-                           chunk_ids, chunk_slot, chunk_start, chunk_len,
-                           spec_tokens=None, spec_active=None):
-        """The mixed step of ``TransformerLM._apply_paged_mixed`` for this
-        block: same operands, same results.  ``cache``: ``k`` the latent
-        pool (``v`` None), ``extra`` as :meth:`init_paged_extra`,
-        ``block_tables [S, pages]``, ``lens``.  The latent pool and both
-        state buffers are the walk's carry (:meth:`_walk`); ``new_cache``
-        also holds ``counters`` (``PAGED_COUNTERS``)."""
-        if spec_tokens is not None:
-            raise NotImplementedError(self.paged_refusal(spec=True))
-        if cache.get("k_scale") is not None:
-            raise NotImplementedError(self.paged_refusal(kv_bits=8))
-        params = self.serving_params(params)
+    def _paged_layers(self, params, x, carry, st: MixedStep, probe):
+        """:meth:`_walk` over the stack, the latent pool and both state
+        buffers its carry; ``counts`` the expert layers'
+        ``dropless.COUNTERS``."""
         stacks, experts = self._stacks(params)
-        extra = cache["extra"]
-        tables, lens = cache["block_tables"], cache["lens"]
-        s, cw = dec_tokens.shape[0], chunk_ids.shape[0]
-        with jax.named_scope("embed"):
-            act = dec_active > 0
-            ci = jnp.arange(cw)
-            positions = jnp.concatenate(
-                [lens, jnp.where(ci < chunk_len, chunk_start + ci, 0)])[None]
-            ids = jnp.concatenate([dec_tokens, chunk_ids])[None]
-            row_valid = jnp.concatenate([act, ci < chunk_len])
-        x = self._embed_tokens(params, ids)
-        nb = cache["k"].shape[1]
-        step = MixedStep(tables, lens, act, chunk_slot, chunk_start,
-                         chunk_len, positions, row_valid, nb)
-        st = HybridStep(tables, None, lens, act, chunk_slot, chunk_start,
-                        chunk_len, s, cw)
+        nb = st.num_blocks
         norm = self._norm_fn()
 
         def layer(carry, mixer, ffn, at):
@@ -566,42 +532,31 @@ class KDALatentMoELM(PerSlotState, DenseLeadMoELM):
             else:
                 with jax.named_scope("pool_write"):
                     off = at[MLA] * nb
-                    tables_at = tables + off
+                    tables_at = st.tables + off
                 out, pool = self._paged_latent_attention(
-                    mp["attn"], hn, pool, tables_at, lens, act, chunk_slot,
-                    chunk_start, chunk_len, off, positions)
+                    mp["attn"], hn, pool, st, tables_at, off)
             with jax.named_scope("residual"):
                 x = x + out
             fp = self._layer_of(stacks[ffn], at[ffn])
             f, moe_counts = self._ffn_sublayer(
-                fp, norm(fp["ln2"], x), row_valid, (experts, at[ffn]))
+                fp, norm(fp["ln2"], x), st.row_valid, (experts, at[ffn]))
             with jax.named_scope("residual"):
                 x = x + f
             with jax.named_scope("expert_layout"):
                 counts = counts + moe_counts
             return x, pool, conv_buf, state_buf, counts
 
-        k = cache["k"]
+        extra = carry["extra"]
         zero = jnp.zeros((len(dropless.COUNTERS),), jnp.int32)
         x, pool, conv_buf, state_buf, counts = self._walk(
-            layer, (x, k.reshape(k.shape[0] * nb, *k.shape[2:]),
-                    extra["conv"], extra["state"], zero))
-        x = self._norm_fn("head")(params["ln_f"], self._yield_rows(x[0], st))
-        with jax.named_scope("head"):
-            logits = self._project(params, x)
-            dec_logits = logits[:s]
-            chunk_logits = (logits[s] if cw else
-                            jnp.zeros((logits.shape[-1],), logits.dtype))
-        with jax.named_scope("pool_write"):
-            read, pages, in_runs, new_lens = self._latent_walk(
-                step, k.shape[2])
-            more = [jnp.asarray(v, jnp.int32)[None]
-                    for v in (*self._extra_counters(step, None),
-                              *self._state_counters(st))]
-            counters = jnp.concatenate(
-                [counts, *((n * k.shape[0]).astype(jnp.int32)[None]
-                           for n in (read, pages, in_runs)), *more])
-        return dec_logits, chunk_logits, {
-            "k": pool.reshape(k.shape), "v": None,
-            "extra": {"conv": conv_buf, "state": state_buf},
-            "block_tables": tables, "lens": new_lens, "counters": counters}
+            layer, (x, carry["k"], extra["conv"], extra["state"], zero))
+        return x, {"k": pool, "extra": {
+            "conv": conv_buf, "state": state_buf}}, dict(zip(
+                dropless.COUNTERS, counts)), None
+
+    def _paged_counters(self, st, carry, counts, walk) -> Dict[str, Any]:
+        chunk_rows, decode_rows, started = self._state_rows(
+            st, self.config.kda_layers)
+        return dict(super()._paged_counters(st, carry, counts, walk),
+                    kda_decode_rows=decode_rows, kda_chunk_rows=chunk_rows,
+                    state_slots_started=started)

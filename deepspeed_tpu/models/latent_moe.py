@@ -49,17 +49,18 @@ the expert stack is indexed by the layer's number among ``blocks``.
 expert layer, for the block definitions that have both
 (``models/sandwich_moe.py``, ``models/sparse_latent_moe.py``).
 
-What the two scans carry is the block's own (``_paged_state``): the
-latent pool alone, or with it a second pool and whatever one layer hands
-the next; what a layer is told of itself (``_layer_meta``) is its block
-offset into the pool, or more.
+Of the one serving step (``TransformerLM._apply_paged_mixed``) this file
+brings the latent blocks' layers (``_paged_layers``: the two scans), their
+walk and their counters.  The scans carry the cache's pools, or with them
+whatever one layer hands the next (``_paged_carry``); what a layer is told
+of itself (``_layer_meta``) is its block offset into the pool, or more.
 """
 from __future__ import annotations
 
 import dataclasses
 import functools
 import math
-from typing import Dict, NamedTuple, Optional
+from typing import Any, Dict, Optional
 
 import jax
 import jax.numpy as jnp
@@ -68,22 +69,8 @@ from jax.sharding import PartitionSpec as P
 from . import layers as L
 from ..moe import dropless
 from ..observability.overlap import scoped
-from .transformer import TransformerConfig, TransformerLM
-
-class MixedStep(NamedTuple):
-    """One dispatch of the mixed step as every layer sees it: the slots'
-    tables and lengths, which decode rows are live, the chunk's slot,
-    start and length, every row's position, which rows carry a token,
-    and the pool's blocks a sublayer."""
-    tables: jax.Array
-    lens: jax.Array
-    act: jax.Array
-    chunk_slot: jax.Array
-    chunk_start: jax.Array
-    chunk_len: jax.Array
-    positions: jax.Array
-    row_valid: jax.Array
-    num_blocks: int
+from .transformer import (MixedStep, TransformerConfig, TransformerLM,
+                          lane_pool_rows, scatter_rows)
 
 
 @functools.partial(jax.jit, static_argnums=(1, 2))
@@ -186,15 +173,16 @@ class LatentMoEConfig(TransformerConfig):
 class LatentMoELM(TransformerLM):
     """``TransformerLM`` for blocks of latent attention and routed
     experts: same ``init`` / ``apply`` / ``init_paged_cache`` /
-    ``_apply_paged_mixed`` / ``partition_specs`` surface; the block
-    definition brings the scanned unit."""
+    ``partition_specs`` surface, its layers of the one serving step
+    (``_paged_layers``); the block definition brings the scanned unit."""
 
     #: attention sublayers in one layer of the block
     ATTN_SUBLAYERS = 1
-    #: what ``_apply_paged_mixed`` counts in the program, a dispatch (the
+    #: what the serving step counts in the program, a dispatch (the
     #: serving engine carries them out on its one result array)
-    PAGED_COUNTERS = dropless.COUNTERS + (
-        "latent_tokens_read", "latent_pages_read", "latent_pages_in_runs")
+    WALK_COUNTERS = ("latent_tokens_read", "latent_pages_read",
+                     "latent_pages_in_runs")
+    PAGED_COUNTERS = dropless.COUNTERS + WALK_COUNTERS
 
     def __init__(self, config: LatentMoEConfig, constrain=None,
                  block_transform=None):
@@ -231,30 +219,13 @@ class LatentMoELM(TransformerLM):
         ``params["blocks"]``, or None."""
         return None
 
-    def _extra_counters(self, step, state) -> list:
-        """What the block counts a dispatch beyond ``PAGED_COUNTERS`` of
-        this class, in its own ``PAGED_COUNTERS``' order, from the step's
-        operands (``MixedStep``) and what the scans carried out."""
-        return []
-
     # -- refusals ----------------------------------------------------------
-    _refuse_mesh = (
-        "the latent-attention MoE block serves on one chip: its attention "
-        "has one shared latent row a token (nothing to shard over heads "
-        "in the pool) and its experts are not exchanged across chips yet "
-        "(ROADMAP B6) — use serving.mesh data=1, model=1")
-
     def training_refusal(self) -> Optional[str]:
         return ("the latent-attention MoE block serves and does not train "
                 "yet: its latent attention has no training kernel (ROADMAP "
                 "B8); its experts' grouped product (moe/dropless.py "
                 "grouped_matmul) differentiates, as models/cca_moe.py "
                 "trains through it")
-
-    def tp_serving_view(self, model_shards, tp_axis, dp_axis):
-        if model_shards > 1 or dp_axis is not None:
-            raise NotImplementedError(self._refuse_mesh)
-        return self
 
     def init_cache(self, batch, max_len, dtype=None):
         raise NotImplementedError(
@@ -283,7 +254,12 @@ class LatentMoELM(TransformerLM):
                     f"already the compressed cache; int8 / int4 latent "
                     f"rows have no quantizer or kernel path")
         if mesh_model > 1 or mesh_data > 1:
-            return self._refuse_mesh
+            return (
+                "the latent-attention MoE block serves on one chip: its "
+                "attention has one shared latent row a token (nothing to "
+                "shard over heads in the pool) and its experts are not "
+                "exchanged across chips yet (ROADMAP B6) — use "
+                "serving.mesh data=1, model=1")
         if host_cache:
             return ("serving.host_cache: the host tier's block codec "
                     "encodes kv_heads x head_dim rows of k and v, not "
@@ -524,57 +500,26 @@ class LatentMoELM(TransformerLM):
         """Attention sublayers that write the pool: every layer's."""
         return self.ATTN_SUBLAYERS * self.config.num_layers
 
-    def _pool_rows(self, tables, lens, act, chunk_slot, chunk_start,
-                   chunk_len, cw, blk, null):
-        """The pool row every row of the step writes — a decode slot's
-        at its length, a chunk row's at its position, a masked row's in
-        the null block ``null`` — and the chunk slot's table."""
-        bsl, npages = tables.shape
-        slot = jnp.arange(bsl)
-        null_row = null * blk
-        write = [jnp.where(
-            act, tables[slot, lens // blk] * blk + lens % blk, null_row)]
-        ctable = None
-        if cw:
-            ci = jnp.arange(cw)
-            cpos = chunk_start + ci
-            ctable = tables[chunk_slot]
-            write.append(jnp.where(
-                ci < chunk_len,
-                ctable[jnp.minimum(cpos // blk, npages - 1)] * blk
-                + cpos % blk, null_row))
-        return jnp.concatenate(write), ctable
-
-    @staticmethod
-    def _scatter_rows(pool, write, rows):
-        """``rows [T, <= lanes]`` into ``pool [blocks, block, lanes]`` at
-        the flat rows ``write``, padded to whole pool rows."""
-        lanes = pool.shape[2]
-        rows = jnp.pad(rows.astype(pool.dtype),
-                       ((0, 0), (0, lanes - rows.shape[1])))
-        return pool.reshape(-1, lanes).at[write].set(rows).reshape(
-            pool.shape)
-
-    def _paged_latent_attention(self, p, xn, pool, tables, lens, act,
-                                chunk_slot, chunk_start, chunk_len, null,
-                                positions):
-        """One attention sublayer of the mixed step, absorbed form: the
+    def _paged_latent_attention(self, p, xn, pool, step: MixedStep, tables,
+                                null):
+        """One attention sublayer of the serving step, absorbed form: the
         rows' latents and rotary keys scatter into this sublayer's pages
-        (``tables`` already offset; masked rows to its null block), then
-        the decode rows and the chunk rows attend through the latent
-        kernel and come back through ``W_UV`` and the out projection."""
+        (``tables``: the slots', offset to the sublayer's blocks; masked
+        rows to its null block ``null``), then the decode rows and the
+        chunk rows attend through the latent kernel and come back through
+        ``W_UV`` and the out projection."""
         from ..ops.transformer.paged_decode_attention import (
             mla_paged_decode_attention, mla_paged_prefill_attention)
-        bsl = lens.shape[0]
+        lens, act = step.lens, step.act
+        bsl, cw = step.slots, step.chunk
         t = xn.shape[1]
-        cw = t - bsl
-        q_nope, q_rope, lat, k_rope = self._mla_project(p, xn, positions)
+        q_nope, q_rope, lat, k_rope = self._mla_project(p, xn,
+                                                        step.positions)
         with jax.named_scope("pool_write"):
-            write, ctable = self._pool_rows(
-                tables, lens, act, chunk_slot, chunk_start, chunk_len, cw,
-                pool.shape[1], null)
-            pool = self._scatter_rows(
-                pool, write, jnp.concatenate([lat[0], k_rope[0]], axis=-1))
+            write, ctable = lane_pool_rows(step, tables, pool.shape[1], null)
+            pool = scatter_rows(
+                pool, jnp.concatenate(write),
+                jnp.concatenate([lat[0], k_rope[0]], axis=-1))
         w_uk, w_uv = self._kv_b(p, xn.dtype)
         with jax.named_scope("attn_proj"):
             q_lat = jnp.einsum("thd,hrd->thr", q_nope[0], w_uk)
@@ -584,24 +529,14 @@ class LatentMoELM(TransformerLM):
                 jnp.where(act, lens + 1, 0), tables, self._sm_scale)]
             if cw:
                 o_parts.append(mla_paged_prefill_attention(
-                    q_lat[bsl:], q_rope[0, bsl:], pool, chunk_start,
-                    chunk_len, ctable, self._sm_scale))
+                    q_lat[bsl:], q_rope[0, bsl:], pool, step.chunk_start,
+                    step.chunk_len, ctable, self._sm_scale))
             o_lat = jnp.concatenate(o_parts) if cw else o_parts[0]
         with jax.named_scope("attn_proj"):
             o = jnp.einsum("thr,hrd->thd", o_lat, w_uv)
             return L.dense_apply(p["out"], o.reshape(1, t, -1)), pool
 
-    # -- what the mixed step's two scans carry, and what a layer is told --
-    def _paged_state(self, params, cache, step):
-        """What the scans carry besides the activations: the latent pool
-        as one ``[sublayers * num_blocks, block, lanes]`` buffer."""
-        k = cache["k"]
-        return k.reshape(k.shape[0] * k.shape[1], *k.shape[2:])
-
-    def _paged_pools(self, state, cache) -> Dict:
-        """The pools of the cache the step returns, from the carry."""
-        return {"k": state.reshape(cache["k"].shape), "v": None}
-
+    # -- what the step's two scans carry, and what a layer is told ---------
     def _paged_probe(self, state):
         """What a check may see of a layer's attention in the carry
         (``_apply_paged_mixed(probe=True)``): nothing, for a block that
@@ -619,70 +554,22 @@ class LatentMoELM(TransformerLM):
     def _paged_attend(self, params, step, off):
         """``attend`` (``_latent_block``'s contract) of the layer whose
         meta is ``off``."""
-        def attend(j, p, xn, pool):
+        def attend(j, p, xn, state):
             with jax.named_scope("pool_write"):
                 at = off + j * step.num_blocks
                 tables_at = step.tables + at
-            return self._paged_latent_attention(
-                p, xn, pool, tables_at, step.lens, step.act,
-                step.chunk_slot, step.chunk_start, step.chunk_len, at,
-                step.positions)
+            out, pool = self._paged_latent_attention(
+                p, xn, state["k"], step, tables_at, at)
+            return out, dict(state, k=pool)
         return attend
 
-    @staticmethod
-    def _latent_walk(step: MixedStep, blk: int):
-        """What a dispatch's latent walks read, ONCE a sublayer — the
-        live context in tokens, in pages, and those of the pages that lie
-        in runs of consecutive pool blocks, which the kernel fetches with
-        one DMA a run (the flags it reads, made from the same tables and
-        lengths) — and every slot's length after the dispatch."""
-        from ..ops.transformer.paged_decode_attention import (
-            PAGE_RUN, page_runs)
-        tables, lens, act = step.tables, step.lens, step.act
-        chunk_slot, chunk_start, chunk_len = (
-            step.chunk_slot, step.chunk_start, step.chunk_len)
-        dec_read = jnp.where(act, lens + 1, 0)
-        chunk_read = jnp.where(chunk_len > 0, chunk_start + chunk_len, 0)
-        read = jnp.sum(dec_read) + chunk_read
-        pages = jnp.sum(-(-dec_read // blk)) + -(-chunk_read // blk)
-        in_runs = PAGE_RUN * (
-            jnp.sum(page_runs(tables, dec_read, blk))
-            + jnp.sum(page_runs(tables[chunk_slot][None],
-                                chunk_read[None], blk)))
-        new_lens = (lens + act.astype(lens.dtype)).at[chunk_slot].add(
-            chunk_len, mode="drop")
-        return read, pages, in_runs, new_lens
-
-    def _apply_paged_mixed(self, params, cache, dec_tokens, dec_active,
-                           chunk_ids, chunk_slot, chunk_start, chunk_len,
-                           spec_tokens=None, spec_active=None, probe=False):
-        """The mixed step of ``TransformerLM._apply_paged_mixed`` for a
-        latent block: same operands, same results, the latent pool as the
-        scans' carry (sublayer ``j`` of layer ``l`` is the block offset
-        ``(ATTN_SUBLAYERS * l + j) * num_blocks`` into the one buffer).
-        ``new_cache`` also holds ``counters`` — int32
-        ``[len(PAGED_COUNTERS)]``, this dispatch's sums over the
-        layers — and with ``probe`` (a check's, never the engine's)
-        ``probe``: every layer's ``_paged_probe`` of the carry, stacked."""
-        if spec_tokens is not None:
-            raise NotImplementedError(self.paged_refusal(spec=True))
-        if cache.get("k_scale") is not None:
-            raise NotImplementedError(self.paged_refusal(kv_bits=8))
-        params = self.serving_params(params)
-        tables, lens = cache["block_tables"], cache["lens"]
-        bsl, cw = dec_tokens.shape[0], chunk_ids.shape[0]
-        with jax.named_scope("embed"):
-            act = dec_active > 0
-            ci = jnp.arange(cw)
-            positions = jnp.concatenate(
-                [lens, jnp.where(ci < chunk_len, chunk_start + ci, 0)])[None]
-            ids = jnp.concatenate([dec_tokens, chunk_ids])[None]
-            row_valid = jnp.concatenate([act, ci < chunk_len])
-        x = self._embed_tokens(params, ids)
-        ns, nb = cache["k"].shape[:2]
-        step = MixedStep(tables, lens, act, chunk_slot, chunk_start,
-                         chunk_len, positions, row_valid, nb)
-        state = self._paged_state(params, cache, step)
+    def _paged_layers(self, params, x, state, step: MixedStep, probe):
+        """The leading layers' scan, then the expert layers' (sublayer
+        ``j`` of layer ``l`` is the block offset ``(ATTN_SUBLAYERS * l +
+        j) * num_blocks`` into the one buffer): ``counts`` the expert
+        layers' ``dropless.COUNTERS``, ``seen`` every layer's
+        ``_paged_probe`` of the carry, stacked."""
+        row_valid = step.row_valid
 
         def attend_at(meta):
             return self._paged_attend(params, step, meta)
@@ -702,12 +589,7 @@ class LatentMoELM(TransformerLM):
                 lead_fn, (x, state),
                 (lead, self._layer_meta(step, 0, leading)))
 
-        # the expert stack stays out of the scan's xs: sliced per layer
-        # it would be copied whole, every step, to reach the kernel
-        blocks = params["blocks"]
-        experts = blocks["moe"]["experts"]
-        blocks = dict(blocks, moe={k: v for k, v in blocks["moe"].items()
-                                   if k != "experts"})
+        blocks, experts = dropless.split_experts(params["blocks"])
         scanned = experts["w_up"].shape[0]
 
         def scan_fn(carry, xs):
@@ -726,35 +608,10 @@ class LatentMoELM(TransformerLM):
             scan_fn, (x, state, zero),
             (blocks, self._layer_meta(step, leading, scanned),
              jnp.arange(scanned, dtype=jnp.int32)))
-        x = self._norm_fn("head")(params["ln_f"], x)
-        with jax.named_scope("head"):
-            if cw:
-                last = jax.lax.dynamic_slice_in_dim(
-                    x[0], bsl + jnp.maximum(chunk_len - 1, 0), 1, axis=0)
-                logits = self._project(
-                    params, jnp.concatenate([x[0, :bsl], last])[None])
-                chunk_logits = logits[0, bsl]
-            else:
-                logits = self._project(params, x[0, :bsl][None])
-                chunk_logits = jnp.zeros((logits.shape[-1],), logits.dtype)
-            dec_logits = logits[0, :bsl]
-        with jax.named_scope("pool_write"):
-            read, pages, in_runs, new_lens = self._latent_walk(
-                step, cache["k"].shape[2])
-            extra = [jnp.asarray(v, jnp.int32)[None]
-                     for v in self._extra_counters(step, state)]
-            counters = jnp.concatenate(
-                [counts, *((n * ns).astype(jnp.int32)[None]
-                           for n in (read, pages, in_runs)), *extra])
-        new_cache = dict(self._paged_pools(state, cache),
-                         block_tables=tables, lens=new_lens,
-                         counters=counters)
-        if probe:
-            new_cache["probe"] = seen if lead is None else \
-                jax.tree_util.tree_map(lambda a, b: jnp.concatenate([a, b]),
-                                       seen_lead, seen)
-        return dec_logits, chunk_logits, new_cache
-
+        if probe and lead is not None:
+            seen = jax.tree_util.tree_map(
+                lambda a, b: jnp.concatenate([a, b]), seen_lead, seen)
+        return x, state, dict(zip(dropless.COUNTERS, counts)), seen
 
 # ---------------------------------------------------------------------------
 # leading dense layers, then expert layers with a shared expert
@@ -852,8 +709,9 @@ class DenseLeadMoELM(LatentMoELM):
         return (self._mlp(bp["mlp"], u),
                 jnp.zeros((len(dropless.COUNTERS),), jnp.int32))
 
-    def _extra_counters(self, step, state) -> list:
+    def _paged_counters(self, step, carry, counts, walk) -> Dict[str, Any]:
         """``moe_rows_shared``: every row that carries a token goes
         through the shared expert of every expert layer."""
-        return [jnp.sum(step.row_valid, dtype=jnp.int32)
-                * self.config.scan_length]
+        return dict(super()._paged_counters(step, carry, counts, walk),
+                    moe_rows_shared=jnp.sum(step.row_valid, dtype=jnp.int32)
+                    * self.config.scan_length)

@@ -42,7 +42,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Dict
+from typing import Any, Dict
 
 import jax
 import jax.numpy as jnp
@@ -52,6 +52,7 @@ from . import layers as L
 from ..observability.overlap import scoped
 from .latent_moe import (DenseLeadMoEConfig, DenseLeadMoELM,
                          head_sections)
+from .transformer import lane_pool_rows, scatter_rows
 
 _NORMS = ("ln_in", "ln_post")
 _KINDS = ("full", "shared")
@@ -301,34 +302,27 @@ class SparseLatentMoELM(DenseLeadMoELM):
                                 c.index_head_dim), dtype or c.dtype)
         return cache
 
-    def _paged_state(self, params, cache, step):
-        """Both pools, each as one buffer of its layers' blocks, and the
-        selection a ``full`` layer hands on: a decode slot's selected
-        tokens as pool rows ``[slots, k]`` with their count, a chunk
-        row's score plane, threshold and the size of its set; beside them
-        ``counts``, what the layers add up of this dispatch's indexer and
-        selection work (the last four of ``PAGED_COUNTERS``)."""
+    def _paged_carry(self, params, cache, step):
+        """With both pools, the selection a ``full`` layer hands on: a
+        decode slot's selected tokens as pool rows ``[slots, k]`` with
+        their count, a chunk row's score plane, threshold and the size of
+        its set; beside them ``counts``, what the layers add up of this
+        dispatch's indexer and selection work (the last four of
+        ``PAGED_COUNTERS``)."""
         from ..ops.transformer.sparse_latent_attention import plane_width
         c = self.config
         v = cache["v"]
-        bsl = step.lens.shape[0]
-        cw = step.row_valid.shape[0] - bsl
+        bsl, cw = step.slots, step.chunk
         sel = {"rows": jnp.zeros((bsl, c.index_topk), jnp.int32),
                "count": jnp.zeros((bsl,), jnp.int32),
                "counts": jnp.zeros((4,), jnp.int32)}
         if cw:
             sel.update(
-                plane=jnp.zeros((cw, plane_width(step.tables.shape[1],
-                                                 v.shape[2])), jnp.float32),
+                plane=jnp.zeros((cw, plane_width(
+                    step.tables.shape[1], v.shape[2])), jnp.float32),
                 floor=jnp.zeros((cw,), jnp.float32),
                 chunk_count=jnp.zeros((cw,), jnp.int32))
-        return {"k": super()._paged_state(params, cache, step),
-                "v": v.reshape(v.shape[0] * v.shape[1], *v.shape[2:]),
-                "sel": sel}
-
-    def _paged_pools(self, state, cache) -> Dict:
-        return {"k": state["k"].reshape(cache["k"].shape),
-                "v": state["v"].reshape(cache["v"].shape)}
+        return dict(super()._paged_carry(params, cache, step), sel=sel)
 
     def _layer_meta(self, step, first, count):
         """A layer's block offset into the latent pool, whether it
@@ -350,12 +344,11 @@ class SparseLatentMoELM(DenseLeadMoELM):
             dsa_index_scores, kth_largest, narrowed, pool_rows_of,
             select_positions)
         c = self.config
-        bsl = step.lens.shape[0]
-        cw = xn.shape[1] - bsl
+        bsl, cw = step.slots, step.chunk
         blk = ipool.shape[1]
         q, k, w = self._indexer_project(ip, xn, cq, step.positions)
         with jax.named_scope("pool_write"):
-            ipool = self._scatter_rows(ipool, write + ioff * blk, k[0])
+            ipool = scatter_rows(ipool, write + ioff * blk, k[0])
         tables = step.tables + ioff
         total = jnp.where(step.act, step.lens + 1, 0)
         with jax.named_scope("indexer"):
@@ -408,12 +401,11 @@ class SparseLatentMoELM(DenseLeadMoELM):
         from ..ops.transformer.sparse_latent_attention import (
             dsa_sparse_prefill_attention, gathered_latent_attention)
         off, is_full, full_at, ioff = meta
-        bsl = step.lens.shape[0]
+        bsl, cw = step.slots, step.chunk
 
         def attend(j, p, xn, state):
             pool, ipool, sel = state["k"], state["v"], state["sel"]
             t = xn.shape[1]
-            cw = t - bsl
             blk = pool.shape[1]
             cq = self._q_latent(p, xn)
             q_nope, q_rope, lat, k_rope = self._mla_project(
@@ -421,10 +413,10 @@ class SparseLatentMoELM(DenseLeadMoELM):
             with jax.named_scope("pool_write"):
                 # rows before any layer's offset; a masked row's is the
                 # null block's of whichever layer adds its offset
-                write, ctable = self._pool_rows(
-                    step.tables, step.lens, step.act, step.chunk_slot,
-                    step.chunk_start, step.chunk_len, cw, blk, 0)
-                pool = self._scatter_rows(
+                write, ctable = lane_pool_rows(step, step.tables,
+                                               blk, 0)
+                write = jnp.concatenate(write)
+                pool = scatter_rows(
                     pool, write + off * blk,
                     jnp.concatenate([lat[0], k_rope[0]], axis=-1))
             # the layer that ran says so itself: a ``full`` layer adds
@@ -465,15 +457,16 @@ class SparseLatentMoELM(DenseLeadMoELM):
                         {"k": pool, "v": ipool, "sel": sel})
         return attend
 
-    def _extra_counters(self, step, state) -> list:
-        """``moe_rows_shared``, then what the layers themselves added up
+    def _paged_counters(self, step, state, counts, walk) -> Dict[str, Any]:
+        """The base's, then what the layers themselves added up
         (``_paged_select`` in a ``full`` layer, the other branch in a
         ``shared`` one, ``attend`` in both): rows x ``full`` layers that
         ran the indexer, context tokens they scored, selected tokens
         attended (the sets' own sizes, rows x layers), rows x ``shared``
         layers that took a handed-on set."""
-        return super()._extra_counters(step, state) + list(
-            state["sel"]["counts"])
+        return dict(super()._paged_counters(step, state, counts, walk),
+                    **dict(zip(self.PAGED_COUNTERS[-4:],
+                               state["sel"]["counts"])))
 
     def _paged_probe(self, state) -> Dict:
         """What this layer attended: a decode slot's pool rows and their

@@ -33,10 +33,10 @@ Serving keeps, a SLOT and not a token, every ``mamba`` layer's matrix
 state (``extra["ssm"] [mamba layers x slots, heads, head_dim, state]``
 float32, the state index on the lanes) and convolution tail
 (``extra["conv"]``): at the published widths 2 MB a layer a slot, 76 MB a
-slot, far more than the pages of the few attention layers.  The mixed
-step carries the buffer through its scans, and a layer's update reads
-its slots' states where they lie and writes them back in place (one
-fusion: ``dynamic_slice`` -> update -> ``dynamic_update_slice``).  The
+slot, far more than the pages of the few attention layers.  The serving
+step (``TransformerLM._apply_paged_mixed``; ``_paged_layers`` here) hands
+the buffer through this block's scans, and a layer's update reads its
+slots' states where they lie and writes them back in place.  The
 attention layers' pages are one pool ``[attention layers, blocks, block,
 kv_heads x head_dim]`` under ONE table a slot (``TABLE_KINDS``
 ``("full",)``).  A chunk whose first row is row 0 starts from zero state.
@@ -51,15 +51,15 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Dict, List, Optional, Tuple
+from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from . import layers as L
 from ..ops.transformer import ssd_scan
-from .hybrid_ssm import HybridStep, PerSlotStateLM
-from .transformer import TransformerConfig
+from .hybrid_ssm import PerSlotStateLM
+from .transformer import MixedStep, TransformerConfig
 
 MAMBA, ATTENTION = "mamba", "attention"
 #: rows of a prompt chunk to one walker of the attention layers' kernel:
@@ -154,7 +154,9 @@ class SSDHybridLM(PerSlotStateLM):
     """``TransformerLM``'s surface for the Mamba-2 / attention hybrid."""
 
     TABLE_KINDS = ("full",)
-    #: what ``_apply_paged_mixed`` counts a dispatch, each where the work
+    WALK_COUNTERS = ("kv_tokens_read_full", "kv_pages_read",
+                     "kv_pages_in_runs")
+    #: what the serving step counts a dispatch, each where the work
     #: is handed over: context tokens the attention layers' walks were
     #: handed (x those layers), (row, ``mamba`` layer) pairs through the
     #: chunk's blocked scan and through the decode update, chunks that
@@ -414,19 +416,9 @@ class SSDHybridLM(PerSlotStateLM):
         return x, cache
 
     # -- paged serving -----------------------------------------------------
-    def init_paged_cache(self, num_blocks: int, block_size: int,
-                         dtype=None, kv_bits: int = 0) -> Dict:
-        """The attention layers' pool: k and v ``[attention layers,
-        num_blocks, block, kv_heads * head_dim]`` (block 0 of a layer its
-        null block)."""
-        reason = self.paged_refusal(kv_bits=kv_bits)
-        if reason is not None:
-            raise NotImplementedError(reason)
-        c = self.config
-        shape = (c.attention_layers_count, num_blocks, block_size,
-                 c.kv_heads * c.hdim)
-        dtype = dtype or c.dtype
-        return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+    def _pool_sublayers(self) -> int:
+        """The pool holds the attention layers' pages alone."""
+        return self.config.attention_layers_count
 
     def init_paged_extra(self, num_slots: int, block_size: int,
                          window_blocks: int, dtype=None) -> Dict:
@@ -447,7 +439,7 @@ class SSDHybridLM(PerSlotStateLM):
         rows = jnp.arange(self.config.mamba_layers) * num_slots + slot
         return extra["ssm"][rows]
 
-    def _ssm_paged(self, p, h, conv_buf, ssm_buf, layer, st: HybridStep):
+    def _ssm_paged(self, p, h, conv_buf, ssm_buf, layer, st: MixedStep):
         """A ``mamba`` mixer in the mixed step: the decode rows each from
         their slot's tail and state, the chunk from its slot's (zero where
         the chunk starts a prompt); ``h [S + C, d]``, ``layer`` the
@@ -490,7 +482,7 @@ class SSDHybridLM(PerSlotStateLM):
                         conv_buf.dtype), at, 1)
         return self._ssm_out(p, y, z), conv_buf, ssm_buf
 
-    def _attention_paged(self, p, h, pool_k, pool_v, off, st: HybridStep):
+    def _attention_paged(self, p, h, pool_k, pool_v, off, st: MixedStep):
         """An attention mixer in the mixed step: every row writes its k /
         v into the layer's pages (``off``: its block offset into the pool,
         and its null block), then the decode rows and the chunk attend
@@ -518,32 +510,11 @@ class SSDHybridLM(PerSlotStateLM):
             return (L.dense_apply(p["out"], o.reshape(-1, nh * hd)), pool_k,
                     pool_v)
 
-    def _apply_paged_mixed(self, params, cache, dec_tokens, dec_active,
-                           chunk_ids, chunk_slot, chunk_start, chunk_len,
-                           spec_tokens=None, spec_active=None):
-        """The mixed step of ``TransformerLM._apply_paged_mixed`` for this
-        block: same operands, same results.  ``cache``: ``k`` / ``v`` the
-        attention layers' pool, ``extra`` as :meth:`init_paged_extra`,
-        ``block_tables [S, pages]``, ``lens``.  One scan over the
-        pattern's periods, a scan a run of ``mamba`` layers inside it;
-        the pools and both state buffers are the scans' carry.
-        ``new_cache`` also holds ``counters`` (``PAGED_COUNTERS``)."""
-        if spec_tokens is not None:
-            raise NotImplementedError(self.paged_refusal(spec=True))
-        if cache.get("k_scale") is not None:
-            raise NotImplementedError(self.paged_refusal(kv_bits=8))
+    def _paged_layers(self, params, x, carry, st: MixedStep, probe):
+        """One scan over the pattern's periods, a scan a run of ``mamba``
+        layers inside it; the layers count nothing themselves."""
         c = self.config
-        extra = cache["extra"]
-        s, cw = dec_tokens.shape[0], chunk_ids.shape[0]
-        with jax.named_scope("embed"):
-            st = HybridStep(cache["block_tables"], None, cache["lens"],
-                            dec_active > 0, chunk_slot, chunk_start,
-                            chunk_len, s, cw)
-            ids = jnp.concatenate([dec_tokens, chunk_ids])[None]
-        x = self._embed_tokens(params, ids)[0]
-        nb = cache["k"].shape[1]
-        pool_k = cache["k"].reshape(-1, *cache["k"].shape[2:])
-        pool_v = cache["v"].reshape(-1, *cache["v"].shape[2:])
+        nb = st.num_blocks
         runs = c.period_runs
         per = {kind: sum(n for k, n in runs if k == kind)
                for kind in (MAMBA, ATTENTION)}
@@ -591,7 +562,8 @@ class SSDHybridLM(PerSlotStateLM):
                         first + jnp.arange(layers, dtype=jnp.int32))
             return (x, pool_k, pool_v, conv_buf, ssm_buf), None
 
-        carry = (x, pool_k, pool_v, extra["conv"], extra["ssm"])
+        extra = carry["extra"]
+        carry = (x[0], carry["k"], carry["v"], extra["conv"], extra["ssm"])
         periods = c.num_layers // c.period
         if periods == 1:
             carry, _ = period(carry, jnp.int32(0))
@@ -599,36 +571,12 @@ class SSDHybridLM(PerSlotStateLM):
             carry, _ = jax.lax.scan(period, carry,
                                     jnp.arange(periods, dtype=jnp.int32))
         x, pool_k, pool_v, conv_buf, ssm_buf = carry
-        x = self._norm_fn("head")(params["ln_f"], self._yield_rows(x, st))
-        with jax.named_scope("head"):
-            logits = self._project(params, x)
-            dec_logits = logits[:s]
-            chunk_logits = (logits[s] if cw else
-                            jnp.zeros((logits.shape[-1],), logits.dtype))
-        with jax.named_scope("pool_write"):
-            from ..ops.transformer.paged_decode_attention import walk_pages
-            live = jnp.sum(st.act.astype(jnp.int32))
-            read, walked = jnp.where(st.act, st.lens + 1, 0), st.tables
-            rides = chunk_len > 0
-            if cw:
-                read = jnp.append(read, jnp.where(
-                    rides, chunk_start + chunk_len, 0))
-                walked = jnp.concatenate([walked,
-                                          walked[chunk_slot][None]])
-            pages, in_runs = walk_pages(walked, read, pool_k.shape[1])
-            counters = jnp.stack([
-                c.attention_layers_count * jnp.sum(read),
-                c.mamba_layers * (chunk_len if cw else 0),
-                c.mamba_layers * live,
-                (rides & (chunk_start == 0)) if cw else False,
-                c.attention_layers_count * pages,
-                c.attention_layers_count * in_runs,
-            ]).astype(jnp.int32)
-            new_lens = (st.lens + st.act.astype(st.lens.dtype)
-                        ).at[chunk_slot].add(chunk_len, mode="drop")
-        return dec_logits, chunk_logits, {
-            "k": pool_k.reshape(cache["k"].shape),
-            "v": pool_v.reshape(cache["v"].shape),
-            "extra": {"conv": conv_buf, "ssm": ssm_buf},
-            "block_tables": cache["block_tables"], "lens": new_lens,
-            "counters": counters}
+        return x[None], {"k": pool_k, "v": pool_v, "extra": {
+            "conv": conv_buf, "ssm": ssm_buf}}, None, None
+
+    def _paged_counters(self, st, carry, counts, walk) -> Dict[str, Any]:
+        chunk_rows, decode_rows, started = self._state_rows(
+            st, self.config.mamba_layers)
+        return dict(super()._paged_counters(st, carry, counts, walk),
+                    ssm_chunk_rows=chunk_rows, ssm_decode_rows=decode_rows,
+                    state_slots_started=started)

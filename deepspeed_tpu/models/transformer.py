@@ -34,11 +34,130 @@ from . import layers as L
 from ..observability.overlap import scoped
 
 
+class MixedStep(NamedTuple):
+    """One dispatch of the serving step as every layer of every block
+    sees it: whose rows ride, where they stand, which of them carry a
+    token.  Built once, by ``TransformerLM._apply_paged_mixed``.
+
+    The rows, in order: every slot's ``rows`` rows of the first lane (one
+    decode token; a block-diffusion model's whole block), slot-major; the
+    draft runs (``spec`` rows a slot, slot-major; 0: no such lane); one
+    slot's prompt chunk (``chunk`` rows; STATICALLY 0: the decode-only
+    shape, the lane compiles away)."""
+    #: ``[S, pages] int32`` each, NOT offset to any layer's blocks: the
+    #: slots' tables of the ``"full"`` kind of paged layer, and of the
+    #: ``"window"`` kind where the block has one (``TABLE_KINDS``)
+    tables: Any
+    wtables: Any
+    lens: Any                  # [S] int32, rows ALREADY in the pool a slot
+    act: Any                   # [S] bool, slots that ride the first lane
+    chunk_slot: Any            # int32 scalars: the chunk's slot (a GLOBAL
+    chunk_start: Any           # id under data-sharded slots), its first
+    chunk_len: Any             # row's position, its valid rows (0: none)
+    positions: Any             # [1, T] int32, a masked row's parked at 0
+    row_valid: Any             # [T] bool, rows that carry a token
+    slots: int                 # S
+    rows: int                  # rows a slot in the first lane
+    chunk: int                 # C
+    #: the rows that yield a token: the first lane's, the draft runs', and
+    #: the chunk's last valid row where anything samples from it
+    yields: int
+    num_blocks: int            # blocks a layer of the pool ``cache["k"]``
+    spec_act: Any = None       # [S] bool, slots verifying a draft run
+    spec: int = 0              # rows a slot in the draft lane
+    #: the GLOBAL tables ``[all slots, pages]`` where the slots are
+    #: sharded over the ``data`` mesh axis (``tables`` / ``lens`` then
+    #: hold this shard's slots); None on one shard
+    tables_g: Any = None
+
+
+def pool_rows(table, positions, valid, block: int, null):
+    """Where rows land in a paged pool ``[blocks, block, ..]`` taken as
+    flat rows: position ``p`` of a slot at ``table[p // block] * block +
+    p % block``, a masked row at row 0 of the null block ``null`` (a
+    layer's own: every masked row of a layer lands there).  ``table
+    [pages]`` is one slot's for ``positions [C]``; ``table [S, pages]``
+    every slot's for ``positions [S, R]``.  The page is clamped into the
+    table: a parked position past its edge must still index it."""
+    page = jnp.minimum(positions // block, table.shape[-1] - 1)
+    blocks = (table[page] if table.ndim == 1 else
+              table[jnp.arange(table.shape[0])[:, None], page])
+    return jnp.where(valid, blocks * block + positions % block,
+                     null * block)
+
+
+def lane_pool_rows(step: MixedStep, tables, block: int, null,
+                   tables_g=None):
+    """:func:`pool_rows` of every row of the dispatch, a lane at a time
+    (``[the first lane's [S * rows], the draft runs' [S * spec], the
+    chunk's [C]]``, the last two where the step has them), and the chunk
+    slot's table (None without a chunk).  ``tables [S, pages]`` is one
+    kind's, offset to the layer that writes; ``tables_g`` likewise, where
+    the slots are sharded."""
+    def lane(rows, live):
+        # row i of a slot lands at lens + i: for a draft run the cells a
+        # sequential decode would fill, so accepted tokens are already
+        # committed and the rejected tail is rolled back host-side by not
+        # advancing lens past it
+        at = step.lens[:, None] + jnp.arange(rows)[None, :]
+        return pool_rows(tables, at, live[:, None], block, null).reshape(-1)
+    lanes = [lane(step.rows, step.act)]
+    if step.spec:
+        lanes.append(lane(step.spec, step.spec_act))
+    ctable = None
+    if step.chunk:
+        # chunk_slot is a GLOBAL slot id: with data-sharded slots it
+        # indexes the gathered tables, which every shard holds in full —
+        # the chunk work itself is replicated over data
+        ci = jnp.arange(step.chunk)
+        ctable = (tables if tables_g is None else tables_g)[step.chunk_slot]
+        lanes.append(pool_rows(ctable, step.chunk_start + ci,
+                               ci < step.chunk_len, block, null))
+    return lanes, ctable
+
+
+def scatter_rows(pool, write, rows):
+    """``rows [T, .. <= lanes]`` into ``pool [blocks, block, lanes]`` at
+    the flat rows ``write [T]`` (:func:`pool_rows`), padded to whole pool
+    rows."""
+    lanes = pool.shape[2]
+    rows = rows.astype(pool.dtype).reshape(write.shape[0], -1)
+    rows = jnp.pad(rows, ((0, 0), (0, lanes - rows.shape[1])))
+    return pool.reshape(-1, lanes).at[write].set(rows).reshape(pool.shape)
+
+
+def walk_counts(step: MixedStep, table, block: int, window=None):
+    """``[keys, pages, pages in runs]`` int32 that ONE paged layer's walks
+    of a dispatch are handed over ``table [S, pages]`` (a kind's, any
+    layer's offset aside): every riding slot's context with the rows it
+    just wrote, and the chunk slot's up to the chunk's last row; under a
+    ``window``, from each walk's first attended position.  The pages and
+    those of them in runs the kernel fetches with one DMA are
+    ``paged_decode_attention.walk_pages``' — the flags the kernel itself
+    reads.  The chunk slot's walk is counted apart, not as one more row
+    of the slots' tables: a ``[S + 1, pages]`` copy of the tables here
+    took the compiler's fast memory from GLM's selection planes (PR 60)."""
+    from ..ops.transformer.paged_decode_attention import walk_pages
+
+    def count(table, total, first):
+        keys = total if first is None else jnp.maximum(total - first, 0)
+        return jnp.stack([jnp.sum(keys),
+                          *walk_pages(table, total, block, first)])
+    total = jnp.where(step.act, step.lens + step.rows, 0)
+    counts = count(table, total, None if window is None
+                   else jnp.maximum(total - window, 0))
+    if step.chunk:
+        end = jnp.where(step.chunk_len > 0,
+                        step.chunk_start + step.chunk_len, 0)[None]
+        counts = counts + count(
+            table[step.chunk_slot][None], end, None if window is None
+            else jnp.maximum(step.chunk_start - (window - 1), 0)[None])
+    return counts.astype(jnp.int32)
+
+
 class PagedMixedState(NamedTuple):
-    """Paged serving state for the MIXED decode+chunked-prefill step
-    (Sarathi-Serve style) — ``_attention`` dispatches on it when the
-    serving engine coalesces one prompt chunk with the live decode
-    slots into a single compiled program.
+    """ONE LAYER's view of the serving step's paged state —
+    ``_attention`` dispatches on it.
 
       k_pool / v_pool  [layers * num_blocks, block, kv_heads * De] — the
                    WHOLE pool, every layer's blocks end to end (layer l
@@ -51,32 +170,9 @@ class PagedMixedState(NamedTuple):
       block_tables [B, pages] int32 — pool block ids ALREADY offset to
                    this layer's blocks (``table + null_block``; tail
                    entries hold the layer's null block)
-      lens         [B] int32 — rows ALREADY in the pool per slot
-      dec_active   [B] int32 — 1 for slots decoding this iteration
-                   (prefilling and empty slots are 0: their row of the
-                   token batch is ignored and their KV write re-routes
-                   to the null block)
-      chunk_slot   int32 scalar — slot whose prompt chunk rides this
-                   step (any value when chunk_len == 0)
-      chunk_start  int32 scalar — absolute row of the chunk's first
-                   token (== rows already present for that slot)
-      chunk_len    int32 scalar — valid chunk tokens (0 = no prefill
-                   work this dispatch)
-      tables_g     [S, pages] int32 — the GLOBAL block tables when the
-                   decode slots are sharded over the ``data`` mesh axis
-                   (``block_tables``/``lens`` then hold this shard's
-                   slot rows only, while ``chunk_slot`` stays a global
-                   slot id), offset like ``block_tables``; None on the
-                   single-shard path
-      spec_active  [B] int32 — 1 for slots VERIFYING a speculative
-                   draft run this iteration (the third lane of the
-                   mixed step, docs/serving.md "Speculative decoding");
-                   a speculating slot rides the spec rows INSTEAD of
-                   the decode lane (its ``dec_active`` is 0).  None
-                   when ``spec_width`` is 0.
-      spec_width   static Python int — rows per slot in the spec lane
-                   (draft length k + 1); 0 = no spec lane, the
-                   pre-speculation program byte-identical
+      step         the dispatch's rows (:class:`MixedStep`)
+      tables_g     ``step.tables_g`` offset like ``block_tables``; None
+                   on the single-shard path
       k_scale / v_scale  [layers * num_blocks, kv_heads, 1, block] f32
                    per-row per-head dequant scales of an int8 pool
                    (``serving.kv_cache_bits``), laid out like the
@@ -90,14 +186,8 @@ class PagedMixedState(NamedTuple):
     k_pool: Any
     v_pool: Any
     block_tables: Any
-    lens: Any
-    dec_active: Any
-    chunk_slot: Any
-    chunk_start: Any
-    chunk_len: Any
+    step: MixedStep
     tables_g: Any = None
-    spec_active: Any = None
-    spec_width: int = 0
     k_scale: Any = None
     v_scale: Any = None
     null_block: Any = 0
@@ -551,6 +641,11 @@ class TransformerLM:
     #: names of the int32 counters ``_apply_paged_mixed`` returns under
     #: ``new_cache["counters"]`` (none for the standard block)
     PAGED_COUNTERS: Tuple[str, ...] = ()
+    #: the names in ``PAGED_COUNTERS`` of what the paged layers' walks are
+    #: handed a dispatch (``walk_counts``: keys, pages, pages in runs);
+    #: None: not counted under a name
+    WALK_COUNTERS: Tuple[Optional[str], ...] = (
+        None, "kv_pages_read", "kv_pages_in_runs")
     #: the block tables a slot has in the serving engine's per-slot
     #: operand, one a kind of paged state (``block_allocator``'s layer
     #: kinds): every layer's pages under one table for the standard block
@@ -633,6 +728,10 @@ class TransformerLM:
         are shared by reference."""
         import copy
         c = self.config
+        reason = self.paged_refusal(mesh_model=model_shards,
+                                    mesh_data=1 if dp_axis is None else 2)
+        if reason is not None:
+            raise NotImplementedError(reason)
         if model_shards > 1:
             if c.kv_heads % model_shards or c.num_heads % model_shards:
                 raise ValueError(
@@ -1127,55 +1226,14 @@ class TransformerLM:
         """Every lane's new k/v rows into the pools in one combined
         scatter: ``(k pool, v pool, k scales, v scales, the chunk slot's
         table or None)``."""
-        pool_k, pool_v, tables, lens = (st.k_pool, st.v_pool,
-                                        st.block_tables, st.lens)
+        pool_k, pool_v, step = st.k_pool, st.v_pool, st.step
         kscale, vscale = st.k_scale, st.v_scale
-        bsl = lens.shape[0]                   # decode slots
-        sw = st.spec_width                    # spec rows per slot
-        c = k.shape[1] - bsl - bsl * sw       # chunk width
+        bsl = step.slots * step.rows          # rows of the first lane
+        sw = step.spec                        # spec rows per slot
+        c = step.chunk
         blk = pool_k.shape[1]
-        npages = tables.shape[1]
-        act = st.dec_active > 0
-        ctable = None
-        slot = jnp.arange(bsl)
-        # row 0 of this layer's null block: where every masked row lands
-        null = st.null_block * blk
-        # decode rows: write position of each slot's new token (the
-        # null row for slots not decoding this iteration)
-        wd = jnp.where(act, tables[slot, lens // blk] * blk + lens % blk,
-                       null)
-        writes = [wd]
-        if sw:
-            # spec rows: slot b's draft token i lands at position
-            # lens[b] + i — the same cells a sequential decode would
-            # fill, so accepted tokens are already committed and the
-            # rejected tail is rolled back host-side by simply not
-            # advancing lens past it (stale cells are re-written by the
-            # next run before they can be attended).  Inactive slots
-            # re-route to the null block; the page clamp keeps padded
-            # positions in-table.
-            sact = st.spec_active > 0
-            spos = lens[:, None] + jnp.arange(sw)[None, :]     # [B, S]
-            spage = jnp.minimum(spos // blk, npages - 1)
-            ws = jnp.where(sact[:, None],
-                           jnp.take_along_axis(tables, spage, axis=1)
-                           * blk + spos % blk, null)
-            writes.append(ws.reshape(-1))
-        if c:
-            # chunk rows: absolute rows base..base+C-1 of the chunk
-            # slot's table (null block for padding past chunk_len).
-            # chunk_slot is a GLOBAL slot id: with data-sharded slots
-            # it indexes the gathered tables (st.tables_g), which every
-            # shard holds in full — the chunk work itself is replicated
-            # over data.
-            ci = jnp.arange(c)
-            cpos = st.chunk_start + ci
-            ctable = (tables if st.tables_g is None
-                      else st.tables_g)[st.chunk_slot]
-            cpage = jnp.minimum(cpos // blk, npages - 1)
-            wc = jnp.where(ci < st.chunk_len,
-                           ctable[cpage] * blk + cpos % blk, null)
-            writes.append(wc)
+        writes, ctable = lane_pool_rows(step, st.block_tables, blk,
+                                        st.null_block, st.tables_g)
         dp = self._dp_axis
 
         def gather_rows(a):
@@ -1211,9 +1269,8 @@ class TransformerLM:
 
         def put(pool, rows):
             # token rows [T, kvh, De] -> flat [L * nb * blk, kvh * De] rows
-            rows = shard_cat(seg(rows.astype(pool.dtype)))
-            return pool.reshape(-1, pool.shape[2]).at[write].set(
-                rows.reshape(rows.shape[0], -1)).reshape(pool.shape)
+            return scatter_rows(pool, write, shard_cat(seg(
+                rows.astype(pool.dtype))))
 
         def put_scale(scale, rows):
             # per-row per-head scales [T, kvh] -> [L * nb, kvh, 1, blk]
@@ -1232,22 +1289,15 @@ class TransformerLM:
     def _paged_attend(self, q, pk, pv, kscale, vscale, kv_bits,
                       st: PagedMixedState, ctable):
         """The mixed step's kernels over the written pools: every lane's
-        rows attend and concatenate back, ``[B + B*S + C, nh, hd]``.
-        Which lanes a family has: the standard block all three here
-        (decode; the draft run, one decode call a depth, when a draft is
-        armed; the causal chunk); the per-slot-state blocks
-        (``hybrid_ssm.py``, ``ssd_hybrid.py``) and the latent blocks
-        decode and chunk through attends of their own and refuse the
-        draft; the block-diffusion block (``block_diffusion_moe.py``) has
-        neither decode nor draft lane — a BLOCK lane (every slot's
-        ``block_length`` rows in one call) and a block-causal chunk."""
+        rows attend and concatenate back, ``[B + B*S + C, nh, hd]``: the
+        decode rows; the draft run, one decode call a depth, when a draft
+        is armed; the causal chunk."""
         from ..ops.transformer.paged_decode_attention import (
             paged_decode_attention, paged_prefill_attention)
-        tables, lens = st.block_tables, st.lens
+        step = st.step
+        tables, lens, act = st.block_tables, step.lens, step.act
         nh, hd = q.shape[2:]
-        bsl, sw = lens.shape[0], st.spec_width
-        c = q.shape[1] - bsl - bsl * sw
-        act = st.dec_active > 0
+        bsl, sw, c = step.slots, step.spec, step.chunk
         o_parts = [paged_decode_attention(
             q[0, :bsl], pk, pv,
             # only slots decoding THIS iteration attend (their length
@@ -1260,7 +1310,7 @@ class TransformerLM:
             # spec depth i attends the prefix plus draft rows 0..i
             # (all already in the pool from the combined scatter);
             # per-depth lengths give exact causality between draft rows
-            sact = st.spec_active > 0
+            sact = step.spec_act
             qs = q[0, bsl:bsl + bsl * sw].reshape(bsl, sw, nh, hd)
             o_spec = [paged_decode_attention(
                 qs[:, i], pk, pv,
@@ -1272,8 +1322,8 @@ class TransformerLM:
                 bsl * sw, nh, hd))
         if c:
             o_parts.append(paged_prefill_attention(
-                q[0, bsl + bsl * sw:], pk, pv, st.chunk_start,
-                st.chunk_len, ctable,
+                q[0, bsl + bsl * sw:], pk, pv, step.chunk_start,
+                step.chunk_len, ctable,
                 sm_scale=self._attn_scale,
                 k_scale=kscale, v_scale=vscale, kv_bits=kv_bits))
         return (o_parts[0] if len(o_parts) == 1
@@ -1578,148 +1628,249 @@ class TransformerLM:
             return 0
         return 8 if pool_k.shape[-1] == k_scale.shape[1] * hd else 4
 
-    @staticmethod
-    def _mixed_rows(lens, dec_tokens, chunk_ids, chunk_start, chunk_len,
-                    spec_tokens=None, spec_active=None):
-        """The mixed step's rows: ``(positions, ids)``, each
-        ``[1, B + B*S + C]`` — decode slots, spec runs, the chunk."""
-        sw = 0 if spec_tokens is None else spec_tokens.shape[1]
-        c = chunk_ids.shape[0]
-        pos_parts, id_parts = [lens], [dec_tokens]
-        if sw:
-            # spec positions: lens[b] + i for verifying slots; parked
-            # at 0 for the rest (null-block rows, position clamped away
-            # from the table edge like padded chunk rows)
-            spos = jnp.where((spec_active > 0)[:, None],
-                             lens[:, None] + jnp.arange(sw)[None, :], 0)
-            pos_parts.append(spos.reshape(-1))
-            id_parts.append(spec_tokens.reshape(-1))
-        if c:
-            ci = jnp.arange(c)
-            # clamp padded chunk positions to 0: base + i past chunk_len
-            # can exceed the rotary/learned position tables near
-            # max_seq_len
-            cpos = jnp.where(ci < chunk_len, chunk_start + ci, 0)
-            pos_parts.append(cpos)
-            id_parts.append(chunk_ids)
-        return (jnp.concatenate(pos_parts)[None],      # [1, B+B*S+C]
-                jnp.concatenate(id_parts)[None])
-
-    def _apply_paged_mixed(self, params, cache, dec_tokens, dec_active,
-                           chunk_ids, chunk_slot, chunk_start, chunk_len,
-                           spec_tokens=None, spec_active=None):
-        """Mixed continuous-batching step: one decode token per active
-        slot PLUS one ``chunk_ids``-sized chunk of a single slot's
-        prompt PLUS (optionally) a speculative verify run per slot, in
-        ONE program (Sarathi-Serve chunked prefill — the prefill never
-        monopolizes an iteration and the program shape is independent
-        of the prompt-length distribution; the spec lane is Leviathan
-        et al.'s verify step batched over slots).
-
-        ``cache``: {"k"/"v" (+ "k_scale"/"v_scale"): the
-        :meth:`init_paged_cache` pools ``[layers, num_blocks, ...]``,
-        "block_tables": [B, pages] int32, "lens": [B] int32 (rows
-        already in the pool per slot)}.  Each pool is ONE buffer that
-        the layer scan carries and updates in place (donate it and the
-        step moves only the rows it writes): layer l is the block
-        offset ``l * num_blocks`` into it, and block 0 of every layer
-        stays that layer's own null block.  ``dec_tokens``/``dec_active``
-        [B] int32; ``chunk_ids`` [C] int32 (padded with anything past
-        ``chunk_len``; C may be STATICALLY 0 — the chunk lane then
-        compiles away); ``chunk_slot``/``chunk_start``/``chunk_len``
-        int32 scalars.  ``spec_tokens`` [B, S] int32 arms the spec
-        lane: row b holds the slot's last emitted token followed by
-        draft proposals d_1..d_{S-1}, fed at positions lens[b]..
-        lens[b]+S-1; ``spec_active`` [B] selects the verifying slots
-        (their ``dec_active`` must be 0).  Returns ``(dec_logits
-        [B, V], chunk_logits [V] — the chunk's LAST VALID position, the
-        first-token sample point when the chunk completes a prefix,
-        new_cache)``, with ``spec_logits [B, S, V]`` inserted after
-        ``dec_logits`` when the spec lane is armed."""
-        reason = self._paged_supported()
-        if reason is not None:
-            raise NotImplementedError(reason)
+    # -- the serving step ---------------------------------------------------
+    # ONE definition for every block.  A block says what its scans carry
+    # beyond the cache's pools (``_paged_carry``), its layers
+    # (``_paged_layers``), which walks its paged layers make
+    # (``_paged_walks``) and what it counts (``_paged_counters``);
+    # everything else is here.
+    def _mixed_rows(self, cache, dec_tokens, dec_active, chunk_ids,
+                    chunk_slot, chunk_start, chunk_len, spec_tokens,
+                    spec_active):
+        """The dispatch's rows: ``(MixedStep, ids [1, T])``."""
         tables, lens = cache["block_tables"], cache["lens"]
-        quant = cache.get("k_scale") is not None
-        bsl = dec_tokens.shape[0]
+        slots = dec_tokens.shape[0]
+        rows = dec_tokens.shape[1] if dec_tokens.ndim == 2 else 1
         sw = 0 if spec_tokens is None else spec_tokens.shape[1]
-        c = chunk_ids.shape[0]
-        with jax.named_scope("embed"):
-            positions, ids = self._mixed_rows(
-                lens, dec_tokens, chunk_ids, chunk_start, chunk_len,
-                spec_tokens, spec_active)
-        x = self._embed_tokens(params, ids, positions=positions)
+        cw = chunk_ids.shape[0]
         # data-sharded decode slots: the chunk indexes a GLOBAL slot, so
         # gather the full block tables ONCE here (they are loop
-        # constants — the layer scan reuses the gathered copy, it is not
+        # constants — the layer scans reuse the gathered copy, it is not
         # a per-layer collective)
         tables_g = (None if self._dp_axis is None else
                     jax.lax.all_gather(tables, self._dp_axis, axis=0,
                                        tiled=True))
-        # the pools are loop STATE: each is one [L * nb, ...] buffer
-        # (merging the two leading dimensions is a bitcast) that the
-        # scan carries, layer l addressing its blocks at offset l * nb
-        # through the tables.  Scanned as xs / ys they would be sliced
-        # per layer, restacked and copied whole every step, and exist
-        # twice in HBM.
-        names = ("k", "v") + (("k_scale", "v_scale") if quant else ())
-        nl, nb = cache["k"].shape[:2]
-        pools = tuple(cache[n].reshape(nl * nb, *cache[n].shape[2:])
-                      for n in names)
+        with jax.named_scope("embed"):
+            act = dec_active > 0
+            # the kinds' tables lie side by side, the full kind's first
+            wtables = None
+            if len(self.TABLE_KINDS) > 1:
+                pages = tables.shape[1] // 2
+                tables, wtables = tables[:, :pages], tables[:, pages:]
+            pos, valid, ids = [], [], []
+
+            def lane(tokens, live, rows):
+                # a slot's rows stand at lens .. lens + rows - 1; an idle
+                # slot's are parked at 0 like every masked row (away from
+                # the position tables' edge)
+                pos.append(jnp.where(
+                    live[:, None], lens[:, None] + jnp.arange(rows)[None, :],
+                    0).reshape(-1))
+                valid.append(jnp.repeat(live, rows))
+                ids.append(tokens.reshape(-1))
+            lane(dec_tokens, act, rows)
+            spec_act = None
+            if sw:
+                spec_act = spec_active > 0
+                lane(spec_tokens, spec_act, sw)
+            if cw:
+                live = jnp.arange(cw) < chunk_len
+                pos.append(jnp.where(live, chunk_start + jnp.arange(cw), 0))
+                valid.append(live)
+                ids.append(chunk_ids)
+            # nothing samples from the chunk of a model that generates by
+            # diffusion over blocks: its first token is a denoise
+            # forward's, of a block of its own
+            yields = slots * (rows + sw) + (
+                1 if cw and not self.block_rows else 0)
+            step = MixedStep(
+                tables, wtables, lens, act, chunk_slot, chunk_start, chunk_len,
+                jnp.concatenate(pos)[None], jnp.concatenate(valid), slots,
+                rows, cw, yields, cache["k"].shape[1], spec_act, sw,
+                tables_g)
+            return step, jnp.concatenate(ids)[None]
+
+    @staticmethod
+    def _yield_rows(a, step: MixedStep):
+        """The rows of ``a [T, ..]`` that yield a token — the first
+        lane's, the draft runs', the chunk's last valid row — or ``a``
+        itself where it holds those rows already (a block's layers may
+        narrow on their way)."""
+        n = step.slots * (step.rows + step.spec)
+        if a.shape[0] == step.yields:
+            return a
+        if step.yields == n:
+            return a[:n]
+        last = jax.lax.dynamic_slice_in_dim(
+            a, n + jnp.maximum(step.chunk_len - 1, 0), 1, axis=0)
+        return jnp.concatenate([a[:n], last])
+
+    def _paged_carry(self, params, cache, step: MixedStep) -> Dict:
+        """What the block's scans carry besides the activations: of
+        ``cache``, the pools that are there (``k``, ``v``, the scale
+        planes) and ``extra`` as it lies.  The pools are loop STATE: each
+        is one ``[L * nb, ...]`` buffer (merging the two leading
+        dimensions is a bitcast), layer l addressing its blocks at offset
+        ``l * nb`` through the tables.  Scanned as xs / ys they would be
+        sliced per layer, restacked and copied whole every step, and
+        exist twice in HBM.  The step puts back what comes out under
+        these names, in the cache's shapes."""
+        carry = {n: cache[n].reshape(-1, *cache[n].shape[2:])
+                 for n in ("k", "v", "k_scale", "v_scale")
+                 if cache.get(n) is not None}
+        if "extra" in cache:
+            carry["extra"] = cache["extra"]
+        return carry
+
+    def _paged_layers(self, params, x, carry, step: MixedStep, probe):
+        """Every layer over the dispatch's rows ``x [1, T, d]``, the
+        block's scans inside: ``(x — every row, or the rows that yield a
+        token already —, carry, what the layers counted (the block's own
+        form, :meth:`_paged_counters`'), what ``probe`` shows a check or
+        None)``; ``counts`` is a dict by ``PAGED_COUNTERS``' names."""
+        nb, tables = step.num_blocks, step.tables
+        names = tuple(n for n in carry if n != "extra")
 
         def scan_fn(carry, xs):
             y, pools = carry
             bp, off = xs
             with jax.named_scope("pool_write"):
                 st = PagedMixedState(
-                    *pools[:2], tables + off, lens, dec_active,
-                    chunk_slot, chunk_start, chunk_len,
-                    None if tables_g is None else tables_g + off,
-                    spec_active, sw, *pools[2:], null_block=off)
+                    *pools[:2], tables + off, step,
+                    None if step.tables_g is None else step.tables_g + off,
+                    *pools[2:], null_block=off)
             y, pools = self._block(self.block_transform(bp), y, st,
-                                   positions)
+                                   step.positions)
             return (y, pools), None
 
         with jax.named_scope("pool_write"):
-            offs = jnp.arange(nl, dtype=tables.dtype) * nb
-        (x, pools), _ = jax.lax.scan(scan_fn, (x, pools),
-                                     (params["blocks"], offs))
-        pools = dict(zip(names, (
-            p.reshape(nl, nb, *p.shape[1:]) for p in pools)))
+            offs = jnp.arange(carry["k"].shape[0] // nb,
+                              dtype=tables.dtype) * nb
+        (x, pools), _ = jax.lax.scan(
+            scan_fn, (x, tuple(carry[n] for n in names)),
+            (params["blocks"], offs))
+        return x, dict(zip(names, pools)), None, None
+
+    def _paged_walks(self, step: MixedStep):
+        """``(the slots' tables, window or None, layers)`` of every kind
+        of paged layer the block has: what :func:`walk_counts` is summed
+        over."""
+        return ((step.tables, None, self._pool_sublayers()),)
+
+    def _paged_counters(self, step: MixedStep, carry, counts,
+                        walk) -> Dict[str, Any]:
+        """``PAGED_COUNTERS`` by name: what :meth:`_paged_layers` counted
+        (by name), the walks' ``[keys, pages, pages in runs]`` under
+        ``WALK_COUNTERS``; a block adds what follows from the step's rows
+        or from what its scans carried out."""
+        return dict(counts or {}, **{n: w for n, w in zip(
+            self.WALK_COUNTERS, walk) if n is not None})
+
+    def _apply_paged_mixed(self, params, cache, dec_tokens, dec_active,
+                           chunk_ids, chunk_slot, chunk_start, chunk_len,
+                           spec_tokens=None, spec_active=None, probe=False):
+        """The serving step of continuous batching, every block's: one
+        decode token per active slot PLUS one ``chunk_ids``-sized chunk
+        of a single slot's prompt PLUS (optionally) a speculative verify
+        run per slot, in ONE program (Sarathi-Serve chunked prefill — the
+        prefill never monopolizes an iteration and the program shape is
+        independent of the prompt-length distribution; the spec lane is
+        Leviathan et al.'s verify step batched over slots).
+
+        ``cache``: {"k"/"v" (+ "k_scale"/"v_scale", + "extra" where the
+        block keeps more a slot, :meth:`init_paged_extra`): the
+        :meth:`init_paged_cache` pools ``[layers, num_blocks, ...]``,
+        "block_tables": [B, pages a kind of ``TABLE_KINDS``] int32,
+        "lens": [B] int32 (rows already in the pool per slot)}; the
+        pools are updated in place (:meth:`_paged_carry`; donate them and
+        the step moves only the rows it writes).  ``dec_tokens`` /
+        ``dec_active`` [B] int32; ``chunk_ids`` [C] int32 (padded with
+        anything past ``chunk_len``; C may be STATICALLY 0 — the chunk
+        lane then compiles away); ``chunk_slot`` / ``chunk_start`` /
+        ``chunk_len`` int32 scalars.  ``spec_tokens``
+        [B, S] int32 arms the spec lane: row b holds the slot's last
+        emitted token followed by draft proposals d_1..d_{S-1}, fed at
+        positions lens[b]..lens[b]+S-1; ``spec_active`` [B] selects the
+        verifying slots (their ``dec_active`` must be 0).  Returns
+        ``(dec_logits [B, V], chunk_logits [V] — the chunk's LAST VALID
+        position, the first-token sample point when the chunk completes a
+        prefix; zeros at C = 0 —, new_cache)``, with ``spec_logits
+        [B, S, V]`` inserted after ``dec_logits`` when the spec lane is
+        armed.  ``new_cache`` holds the pools, ``lens`` as the dispatch
+        leaves them, ``counters`` (int32 ``[len(PAGED_COUNTERS)]``, this
+        dispatch's sums over the layers; absent for a block that counts
+        nothing) and with ``probe`` (a check's, never the engine's)
+        ``probe``: what the block's layers show of their attention.
+
+        A model that generates by DIFFUSION OVER BLOCKS (``block_rows``
+        > 0) takes ``dec_tokens`` [B, block_rows] — each slot's block as
+        it stands, fed at ``lens[b] .. lens[b] + block_rows - 1``, every
+        row seeing its block both ways — and returns ``dec_logits [B,
+        block_rows, V]``: the same step at ``block_rows`` rows a slot.
+        The forward is the same whether the host calls it a denoise or a
+        commit, so ``lens`` comes back as it came (the host's to move),
+        and nothing samples from the chunk (no head over it)."""
+        reason = self._paged_supported() or self.paged_refusal(
+            spec=spec_tokens is not None,
+            kv_bits=8 if cache.get("k_scale") is not None else 0)
+        if reason is not None:
+            raise NotImplementedError(reason)
+        want = (self.block_rows,) if self.block_rows else ()
+        if dec_tokens.shape[1:] != want:
+            raise ValueError(
+                f"a slot rides a dispatch of {type(self).__name__} with "
+                f"{self.block_rows or 'one'} row(s): dec_tokens must be "
+                f"{('slots',) + want}, got {dec_tokens.shape}")
+        params = self.serving_params(params)
+        step, ids = self._mixed_rows(
+            cache, dec_tokens, dec_active, chunk_ids, chunk_slot,
+            chunk_start, chunk_len, spec_tokens, spec_active)
+        x = self._embed_tokens(params, ids, positions=step.positions)
+        x, carry, counts, seen = self._paged_layers(
+            params, x, self._paged_carry(params, cache, step), step, probe)
+        # norm and project only the rows anything samples from
+        with jax.named_scope("head"):
+            x = self._yield_rows(x[0], step)[None]
         if self.config.final_layernorm:
             x = self._norm_fn("head")(params["ln_f"], x)
-        # project only the rows anything samples from: the B decode
-        # rows, the B*S spec rows, and the chunk's last valid position
-        # (a [B + B*S + 1, V] head instead of [B + B*S + C, V])
-        nsample = bsl + bsl * sw
+        slots, sw = step.slots, step.spec
+        first = slots * step.rows
         with jax.named_scope("head"):
-            if c:
-                last = jax.lax.dynamic_slice_in_dim(
-                    x[0], nsample + jnp.maximum(chunk_len - 1, 0), 1,
-                    axis=0)
-                logits = self._project(
-                    params, jnp.concatenate([x[0, :nsample], last])[None])
-                chunk_logits = logits[0, nsample]
-            else:
-                logits = self._project(params, x[0, :nsample][None])
-                chunk_logits = jnp.zeros((logits.shape[-1],), logits.dtype)
-            dec_logits = logits[0, :bsl]
-        # with data-sharded slots `lens` is this shard's rows and
-        # chunk_slot is global: translate to the local row, dropping the
-        # update on shards that don't own the chunk slot (the serving
-        # engine recomputes lens host-side every dispatch either way —
-        # including the spec lane's accepted-token advance, which only
-        # the host knows after the accept/reject compare)
+            logits = self._project(params, x)[0]
+            dec_logits = logits[:first].reshape(
+                *dec_tokens.shape, logits.shape[-1])
+            chunk_logits = (logits[-1] if step.yields > first + slots * sw
+                            else jnp.zeros(logits.shape[-1:], logits.dtype))
         with jax.named_scope("pool_write"):
-            new_lens = lens + (dec_active > 0).astype(lens.dtype)
-            cs = (chunk_slot if self._dp_axis is None else
-                  chunk_slot - jax.lax.axis_index(self._dp_axis) * bsl)
-            new_lens = new_lens.at[cs].add(chunk_len, mode="drop")
-        new_cache = dict(pools, block_tables=tables, lens=new_lens)
+            lens = step.lens
+            if not self.block_rows:
+                # with data-sharded slots `lens` is this shard's rows and
+                # chunk_slot is global: translate to the local row,
+                # dropping the update on shards that don't own the chunk
+                # slot (the serving engine recomputes lens host-side every
+                # dispatch either way — including the spec lane's
+                # accepted-token advance, which only the host knows after
+                # the accept/reject compare)
+                cs = (chunk_slot if self._dp_axis is None else chunk_slot
+                      - jax.lax.axis_index(self._dp_axis) * slots)
+                lens = (lens + step.act.astype(lens.dtype)).at[cs].add(
+                    chunk_len, mode="drop")
+            new_cache = dict(cache, lens=lens, **{
+                n: a if n == "extra" else a.reshape(cache[n].shape)
+                for n, a in carry.items() if n in cache})
+            if self.PAGED_COUNTERS:
+                walk = sum(layers * walk_counts(
+                    step, tables, cache["k"].shape[2], window)
+                    for tables, window, layers in self._paged_walks(step))
+                named = self._paged_counters(step, carry, counts, walk)
+                new_cache["counters"] = jnp.stack([
+                    jnp.asarray(named[n], jnp.int32)
+                    for n in self.PAGED_COUNTERS])
+        if probe:
+            new_cache["probe"] = seen
         if sw:
-            spec_logits = logits[0, bsl:nsample].reshape(
-                bsl, sw, logits.shape[-1])
-            return dec_logits, spec_logits, chunk_logits, new_cache
+            return (dec_logits, logits[first:first + slots * sw].reshape(
+                slots, sw, logits.shape[-1]), chunk_logits, new_cache)
         return dec_logits, chunk_logits, new_cache
 
     def init_paged_cache(self, num_blocks: int, block_size: int,
@@ -1742,7 +1893,8 @@ class TransformerLM:
         ``v_scale`` [layers, num_blocks, kv_heads, 1, block] — 2x /
         ~3.8x more tokens per HBM byte, and the attention kernel
         dequantizes in its page loop (``serving.kv_cache_bits``)."""
-        reason = self._paged_supported()
+        reason = self._paged_supported() or self.paged_refusal(
+            kv_bits=kv_bits)
         if reason is not None:
             raise NotImplementedError(reason)
         c = self.config
@@ -1753,15 +1905,21 @@ class TransformerLM:
             raise ValueError(
                 f"packed int4 KV needs an even head_dim, got {c.hdim}")
         d_eff = c.hdim // 2 if kv_bits == 4 else c.hdim
-        shape = (c.num_layers, num_blocks, block_size, c.kv_heads * d_eff)
+        layers = self._pool_sublayers()
+        shape = (layers, num_blocks, block_size, c.kv_heads * d_eff)
         if not kv_bits:
             return {"k": jnp.zeros(shape, dtype),
                     "v": jnp.zeros(shape, dtype)}
-        sshape = (c.num_layers, num_blocks, c.kv_heads, 1, block_size)
+        sshape = (layers, num_blocks, c.kv_heads, 1, block_size)
         return {"k": jnp.zeros(shape, jnp.int8),
                 "v": jnp.zeros(shape, jnp.int8),
                 "k_scale": jnp.zeros(sshape, jnp.float32),
                 "v_scale": jnp.zeros(sshape, jnp.float32)}
+
+    def _pool_sublayers(self) -> int:
+        """Layers (attention sublayers) that write the paged pool, each
+        with ``num_blocks`` blocks of its own: every layer's."""
+        return self.config.num_layers
 
     def init_cache(self, batch: int, max_len: int, dtype=None) -> Dict:
         c = self.config

@@ -327,6 +327,17 @@ def _layout(local: jax.Array, weight: jax.Array, held: int, rows: int,
                    counts)
 
 
+def split_experts(layers: dict):
+    """A stack of expert layers ``{.., "moe": {"router", .., "experts"}}``
+    as ``(the stack without its experts, the experts)``.  The expert stack
+    stays out of a layer scan's ``xs``: sliced per layer it would be
+    copied whole, every step, to reach the kernel — :func:`expert_share`
+    takes the whole stack and the layer's index instead (``layer=``)."""
+    moe = layers["moe"]
+    return (dict(layers, moe={k: v for k, v in moe.items()
+                              if k != "experts"}), moe["experts"])
+
+
 @scoped("expert_layout")
 def expert_share(experts: dict, u: jax.Array, routing: Routing,
                  num_routed: int, experts_held: Tuple[int, int],

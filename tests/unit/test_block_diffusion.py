@@ -192,9 +192,9 @@ def test_the_step_counts_the_pages_its_walks_read_and_those_in_runs():
     cache = model.init_paged_cache(24, 8, jnp.float32)
     cache.update(block_tables=jnp.asarray(tables),
                  lens=jnp.asarray([68, 12, 0], jnp.int32))
-    new = model._apply_paged_block(
+    new = model._apply_paged_mixed(
         params, cache, jnp.zeros((3, 4), jnp.int32), jnp.asarray([1, 1, 0]),
-        jnp.arange(8), jnp.int32(1), jnp.int32(16), jnp.int32(8))[1]
+        jnp.arange(8), jnp.int32(1), jnp.int32(16), jnp.int32(8))[2]
     counted = dict(zip(model.PAGED_COUNTERS, map(int, new["counters"])))
     # 72 rows = 9 pages, the first 8 a run; 16 rows = 2 pages; the chunk's
     # 24 rows = 3

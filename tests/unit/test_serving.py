@@ -1108,21 +1108,31 @@ def mixed_step_by_layer_slices(model, params, cache, dec_tokens,
     reference: a Python loop over the layers, each calling ``_block``
     on ITS OWN ``[nb, block, kvh * De]`` slice of the pools with the
     tables as the allocator gives them (null block 0 of that slice)."""
-    from deepspeed_tpu.models.transformer import PagedMixedState
+    from deepspeed_tpu.models.transformer import (MixedStep,
+                                                  PagedMixedState)
     tables, lens = cache["block_tables"], cache["lens"]
     quant = "k_scale" in cache
     bsl = dec_tokens.shape[0]
     sw = 0 if spec_tokens is None else spec_tokens.shape[1]
-    ci = jnp.arange(chunk_ids.shape[0])
-    pos, ids = [lens], [dec_tokens]
+    cw = chunk_ids.shape[0]
+    ci = jnp.arange(cw)
+    pos, ids, valid = ([jnp.where(dec_active > 0, lens, 0)], [dec_tokens],
+                       [dec_active > 0])
     if sw:
         pos.append(jnp.where((spec_active > 0)[:, None],
                              lens[:, None] + jnp.arange(sw)[None, :],
                              0).reshape(-1))
         ids.append(spec_tokens.reshape(-1))
+        valid.append(jnp.repeat(spec_active > 0, sw))
     pos.append(jnp.where(ci < chunk_len, chunk_start + ci, 0))
     ids.append(chunk_ids)
+    valid.append(ci < chunk_len)
     positions = jnp.concatenate(pos)[None]
+    step = MixedStep(
+        tables, None, lens, dec_active > 0, chunk_slot, chunk_start,
+        chunk_len, positions, jnp.concatenate(valid), bsl, 1, cw,
+        bsl * (1 + sw) + 1, cache["k"].shape[1],
+        None if spec_active is None else spec_active > 0, sw)
     x = model._embed_tokens(params, jnp.concatenate(ids)[None],
                             positions=positions)
     names = ("k", "v") + (("k_scale", "v_scale") if quant else ())
@@ -1131,9 +1141,8 @@ def mixed_step_by_layer_slices(model, params, cache, dec_tokens,
     def layer(bp, x, *pools):
         return model._block(
             model.block_transform(bp), x,
-            PagedMixedState(*pools[:2], tables, lens, dec_active,
-                            chunk_slot, chunk_start, chunk_len, None,
-                            spec_active, sw, *pools[2:]), positions)
+            PagedMixedState(*pools[:2], tables, step, None, *pools[2:]),
+            positions)
     layers = []
     for l in range(model.config.num_layers):
         x, new = layer(
