@@ -24,7 +24,14 @@ The buffer is walked in passes of ``pass_rows`` rows under a loop whose
 trip count follows the load: a balanced batch takes one pass, a batch
 routed wholly to one expert takes as many as its rows need, and no pick
 is ever dropped.  The product is :func:`grouped_matmul` (device trace name
-``moe_grouped_matmul``).
+``moe_grouped_matmul``), three calls a pass, each skipping the pass's dead
+tiles.  The row-wise work around them — the gather of the picks' rows,
+the activation between the products, the weighting and the float32
+scatter-add back — follows the live tiles too: it walks the pass in
+blocks of ``BLOCK_ROWS`` rows under loops whose trip count is the blocks
+that hold a live tile, so a dead row of a pass costs nothing: it is
+neither gathered, nor passed over, nor added (counter
+``moe_rows_moved``: the rows those blocks hold).
 
 It differentiates (``custom_vjp``): the rows' gradient is the same kernel
 walked against the weights' other axis, the weights' a second kernel
@@ -50,9 +57,20 @@ from ..ops import resolve_interpret
 
 #: rows to a tile of the grouped product: one bf16 operand tile's sublanes
 TILE_ROWS = 16
-#: rows of the buffer one pass of the grouped product walks (a balanced
-#: batch of the serving cell's 560 rows needs a quarter of it)
+#: rows of the buffer one pass walks: what ONE call of the grouped product
+#: covers, so that an expert's weights cross HBM once however its tiles
+#: lie in the pass.  A pass costs its LIVE rows: a decode-only dispatch
+#: fills a quarter of it and pays for a quarter (``BLOCK_ROWS``)
 PASS_ROWS = 1024
+#: rows of a pass that one step of its row-wise work moves (the gather, the
+#: activation, the weighted scatter-add).  XLA's scatter-add costs more a
+#: row the more rows one call holds, steeply so for wide rows: at 7,680
+#: columns 17 us a block of 64 rows, 23-70 us of 128, 1.6 ms of 256; at
+#: 2,048 columns a full pass is cheapest in large blocks (8 us a block of
+#: 128 against 47 us for the whole pass in one).  128 keeps every served
+#: width on the cheap side and a full pass within a tenth of one call
+#: (PERF.md, PR 61: the chip's readings at 64, 128, 256 and 512)
+BLOCK_ROWS = 128
 #: scoped VMEM the grouped product asks for (two halves of one weight
 #: block of up to ``_WEIGHT_BLOCK_BYTES``, the row tiles, the result)
 _VMEM_LIMIT_BYTES = 48 << 20
@@ -61,7 +79,7 @@ _WEIGHT_BLOCK_BYTES = 8 << 20
 _DW_BLOCK_BYTES = 8 << 20
 #: the counters :func:`expert_share` returns, in order
 COUNTERS = ("moe_picks", "moe_picks_held", "moe_picks_zero",
-            "moe_rows_max_expert", "moe_experts_touched")
+            "moe_rows_max_expert", "moe_experts_touched", "moe_rows_moved")
 
 
 class Routing(NamedTuple):
@@ -378,6 +396,10 @@ def expert_share(experts: dict, u: jax.Array, routing: Routing,
     tile = int(tile_rows)
     rows = t * per_token + held * (tile - 1)
     step = -(-min(int(pass_rows or rows), rows) // tile) * tile
+    block = step                   # ONE pass moves its buffer whole
+    if pass_rows is not None:      # a pass is whole blocks of whole tiles
+        block = min(-(-BLOCK_ROWS // tile) * tile, step)
+        step = -(-step // block) * block
     rows = -(-rows // step) * step
     lay = _layout(jnp.where(is_held, index - lo, held).astype(jnp.int32),
                   weight, held, rows, tile)
@@ -388,38 +410,65 @@ def expert_share(experts: dict, u: jax.Array, routing: Routing,
                    for n, w in experts.items()}
         first = layer * held
 
-    def one_pass(p, y):
-        at, tile_at = p * step, p * (step // tile)
-        token = jax.lax.dynamic_slice_in_dim(lay.row_token, at, step)
-        w_row = jax.lax.dynamic_slice_in_dim(lay.row_weight, at, step)
-        te = first + jax.lax.dynamic_slice_in_dim(
-            lay.tile_expert, tile_at, step // tile)
-        live = jnp.clip(lay.live_tiles - tile_at, 0, step // tile)
-        xs = u_pad[token]
-        gate = grouped_matmul(xs, experts["w_gate"], te, live)
-        up = grouped_matmul(xs, experts["w_up"], te, live)
-        with jax.named_scope("experts"):
-            mid = (jax.nn.silu(gate.astype(jnp.float32))
-                   * up.astype(jnp.float32)).astype(u.dtype)
-        out = grouped_matmul(mid, experts["w_down"], te, live)
+    def activation(gate, up):
+        return (jax.nn.silu(gate.astype(jnp.float32))
+                * up.astype(jnp.float32)).astype(u.dtype)
+
+    def weighted_add(y, token, w_row, out):
         # a dead tile's rows are undefined, a padding row's are zero rows
         # of a real expert: both are no pick, and 0 * garbage is not 0
         out = jnp.where((token < t)[:, None],
                         out.astype(jnp.float32) * w_row[:, None], 0.0)
         return y.at[token].add(out, mode="drop")
 
-    y = jnp.zeros((t, h), jnp.float32)
+    def part(b, a):
+        return jax.lax.dynamic_slice_in_dim(a, b * block, block)
+
+    def put(b, a, rows_b):
+        return jax.lax.dynamic_update_slice_in_dim(a, rows_b, b * block, 0)
+
+    def one_pass(p, carry):
+        y, moved = carry
+        at, tile_at = p * step, p * (step // tile)
+        token = jax.lax.dynamic_slice_in_dim(lay.row_token, at, step)
+        w_row = jax.lax.dynamic_slice_in_dim(lay.row_weight, at, step)
+        te = first + jax.lax.dynamic_slice_in_dim(
+            lay.tile_expert, tile_at, step // tile)
+        live = jnp.clip(lay.live_tiles - tile_at, 0, step // tile)
+        # the row-wise work follows the live tiles as the products do: it
+        # walks the blocks that hold one.  Rows past the last of them are
+        # never written and never read, in any of the pass's buffers.  The
+        # form that differentiates takes its one block in one step
+        blocks = 1 if pass_rows is None else -(-(live * tile) // block)
+
+        def walk(body, init):
+            if pass_rows is None:
+                return body(0, init)
+            return jax.lax.fori_loop(0, blocks, body, init)
+        xs = walk(lambda b, xs: put(b, xs, u_pad[part(b, token)]),
+                  jnp.zeros((step, h), u.dtype))
+        gate = grouped_matmul(xs, experts["w_gate"], te, live)
+        up = grouped_matmul(xs, experts["w_up"], te, live)
+        with jax.named_scope("experts"):
+            mid = walk(lambda b, gate: put(b, gate, activation(
+                part(b, gate), part(b, up))), gate)
+        out = grouped_matmul(mid, experts["w_down"], te, live)
+        y = walk(lambda b, y: weighted_add(
+            y, part(b, token), part(b, w_row), part(b, out)), y)
+        return y, moved + blocks * block
+
+    carry = jnp.zeros((t, h), jnp.float32), jnp.int32(0)
     if rows == step:
-        y = one_pass(0, y)
+        y, moved = one_pass(0, carry)
     else:
-        y = jax.lax.fori_loop(
-            0, -(-(lay.live_tiles * tile) // step), one_pass, y)
+        y, moved = jax.lax.fori_loop(
+            0, -(-(lay.live_tiles * tile) // step), one_pass, carry)
     y = y + u.astype(jnp.float32) * jnp.sum(
         jnp.where(is_zero, weight, 0.0), axis=-1, keepdims=True)
     counters = jnp.stack([
         valid.sum(dtype=jnp.int32) * k, is_held.sum(dtype=jnp.int32),
         is_zero.sum(dtype=jnp.int32), lay.counts.max(),
-        (lay.counts > 0).sum(dtype=jnp.int32)])
+        (lay.counts > 0).sum(dtype=jnp.int32), moved])
     return y.astype(u.dtype), counters
 
 

@@ -215,7 +215,12 @@ COUNTERS = ("dispatches", "decode_rows", "chunk_rows", "rows_computed",
             # were enqueued before their predecessor's result was read,
             # and rows whose result was ignored because their request
             # had ended by the time it arrived
-            "ahead_dispatches", "void_rows")
+            "ahead_dispatches", "void_rows",
+            # rows of the expert layers' row buffers that the gather and
+            # the weighted scatter-add visited (moe/dropless.py: blocks
+            # walked x BLOCK_ROWS, summed over passes and layers); over
+            # moe_picks_held, the rows moved for a pick that needed one
+            "moe_rows_moved")
 _COUNTER_AT = {name: k for k, name in enumerate(COUNTERS)}
 
 #: one iteration (or training step).  Times are seconds on

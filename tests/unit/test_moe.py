@@ -64,6 +64,14 @@ def loop_over_experts(u, kernel, bias, k, scoring, renorm, experts, lo, hi):
     if renorm:
         weight = weight / (weight.sum(1, keepdims=True) + 1e-20)
     weight = SCALE * weight
+    return index, weight, share_of_the_loop(u, index, weight, experts, lo,
+                                            hi)
+
+
+def share_of_the_loop(u, index, weight, experts, lo, hi):
+    """``expert_share`` of experts ``lo .. hi - 1`` (``experts`` holds all
+    ``E``) in float64, an expert at a time, plus the identity experts'."""
+    u = np.asarray(u, np.float64)
     w = {n: np.asarray(a, np.float64) for n, a in experts.items()}
     y = np.zeros_like(u)
     for e in range(lo, hi):
@@ -72,7 +80,7 @@ def loop_over_experts(u, kernel, bias, k, scoring, renorm, experts, lo, hi):
             @ w["w_down"][e]
         y += (weight * (index == e)).sum(1, keepdims=True) * out
     y += (weight * (index >= E)).sum(1, keepdims=True) * u
-    return index, weight, y
+    return y
 
 
 @pytest.mark.parametrize("form,k,share", [
@@ -125,6 +133,80 @@ def test_route_and_expert_share_against_a_loop_over_experts(form, k, share):
             # each share adds the identity experts' part: counted once
             np.testing.assert_allclose(total - identity, whole[2],
                                        rtol=2e-4, atol=4e-5)
+
+
+# ---------------------------------------------------------------------------
+# a pass moves its live rows only: blocks of BLOCK_ROWS under loops whose
+# trip count is the pass's live tiles
+# ---------------------------------------------------------------------------
+ROWS, HELD, PASS, BLOCK = 200, (0, 4), 256, 32
+#: load -> the blocks each pass must walk of its ``PASS // BLOCK`` = 8
+#: (None: whatever the drawn picks need, asserted to be in the middle)
+LOADS = {"no_pick_held": [], "one_pick": [1], "balanced": None,
+         "two_experts": [8, 3]}
+
+
+def picks_under(load):
+    """``index [ROWS, 2]`` over ``E`` routed + ``Z`` identity experts, of
+    which ``HELD`` are here, and ``row_valid`` (every seventh row carries
+    no token)."""
+    rng = np.random.default_rng(3)
+    valid = np.arange(ROWS) % 7 != 3
+    absent = np.arange(HELD[1], E + Z)
+    if load == "balanced":
+        index = np.stack([rng.permutation(E + Z)[:2] for _ in range(ROWS)])
+    else:
+        index = np.stack([rng.permutation(absent)[:2] for _ in range(ROWS)])
+    if load == "one_pick":
+        index[5, 1] = 2
+    if load == "two_experts":      # every first pick to one, second to one
+        index[:] = HOT, 3
+    return index.astype(np.int32), valid
+
+
+@pytest.mark.parametrize("stacked", [False, True], ids=["held", "layer"])
+@pytest.mark.parametrize("load", list(LOADS))
+def test_a_pass_moves_its_live_blocks_only(monkeypatch, load, stacked):
+    """The form with passes at 0, 1, a middle count and ALL blocks of a
+    pass live (and a second pass after the full one), rows without a token
+    masked, the experts read out of a ``[layers, held, ..]`` stack or
+    handed over: the loop's output, no pick dropped, and
+    ``moe_rows_moved`` = blocks walked x ``BLOCK_ROWS`` — the rows past a
+    pass's last live block are neither gathered nor added."""
+    monkeypatch.setattr(dropless, "BLOCK_ROWS", BLOCK)
+    ks = jax.random.split(jax.random.PRNGKey(11), 3)
+    u = jax.random.normal(ks[0], (ROWS, H))
+    index, valid = picks_under(load)
+    weight = np.asarray(jax.random.uniform(ks[1], index.shape)) + 0.5
+    layers = [dropless.init_experts(k, E, H, F, 0.3, 0.3, jnp.float32)
+              for k in jax.random.split(ks[2], 3)]
+    lo, hi = HELD
+    if stacked:
+        held = {n: jnp.stack([w[n][lo:hi] for w in layers])
+                for n in layers[0]}
+        kw = {"layer": jnp.int32(1)}
+    else:
+        held, kw = {n: a[lo:hi] for n, a in layers[1].items()}, {}
+    y, counted = dropless.expert_share(
+        held, u, dropless.Routing(jnp.asarray(index), jnp.asarray(weight)),
+        E, HELD, row_valid=jnp.asarray(valid), pass_rows=PASS, **kw)
+    want = share_of_the_loop(u, index, weight * valid[:, None], layers[1],
+                             lo, hi)
+    np.testing.assert_allclose(y, want, rtol=2e-4, atol=2e-5)
+    assert not np.asarray(y)[~valid].any()
+    here = valid[:, None] & (index >= lo) & (index < hi)
+    tiles = sum(-(-int((here & (index == e)).sum()) // dropless.TILE_ROWS)
+                for e in range(lo, hi))
+    a_pass = PASS // dropless.TILE_ROWS
+    walked = [-(-min(tiles - at, a_pass) * dropless.TILE_ROWS // BLOCK)
+              for at in range(0, tiles, a_pass)]
+    if LOADS[load] is None:
+        assert len(walked) == 1 and 1 < walked[0] < PASS // BLOCK, walked
+    else:
+        assert walked == LOADS[load]
+    counted = dict(zip(dropless.COUNTERS, map(int, counted)))
+    assert counted["moe_picks_held"] == here.sum()
+    assert counted["moe_rows_moved"] == sum(walked) * BLOCK
 
 
 # ---------------------------------------------------------------------------

@@ -246,7 +246,8 @@ class TestRouter:
         np.testing.assert_allclose(y, weight * u, rtol=1e-6)
         assert dict(zip(dropless.COUNTERS, map(int, counts))) == {
             "moe_picks": 4, "moe_picks_held": 0, "moe_picks_zero": 4,
-            "moe_rows_max_expert": 0, "moe_experts_touched": 0}
+            "moe_rows_max_expert": 0, "moe_experts_touched": 0,
+            "moe_rows_moved": 0}
 
     def test_twelve_picks_none_dropped_under_one_hot_routing(self):
         """Every row's first pick is expert 2, so it gets all 40 rows —

@@ -706,6 +706,153 @@ def test_grouped_product_backward_compiles_at_the_cells_shapes(
     assert "moe_grouped_matmul_dw" in text
 
 
+def _parent_expert_share(experts, u, routing, num_routed, experts_held,
+                         row_valid=None, pass_rows=1024, layer=None,
+                         tile_rows=16):
+    """``moe/dropless.py::expert_share`` as it stood before a pass walked
+    blocks (PR 60's tree), verbatim but for the counters: what the form
+    that differentiates (``pass_rows=None``) must still be."""
+    from deepspeed_tpu.moe.dropless import _layout, grouped_matmul
+    lo, hi = experts_held
+    held = hi - lo
+    t, h = u.shape
+    k = routing.index.shape[1]
+    valid = (jnp.ones((t,), bool) if row_valid is None
+             else row_valid.astype(bool))[:, None]
+    index = routing.index
+    weight = jnp.where(valid, routing.weight, 0.0)
+    is_held = valid & (index >= lo) & (index < hi)
+    is_zero = valid & (index >= num_routed)
+    per_token = min(k, held)
+    tile = int(tile_rows)
+    rows = t * per_token + held * (tile - 1)
+    step = -(-min(int(pass_rows or rows), rows) // tile) * tile
+    rows = -(-rows // step) * step
+    lay = _layout(jnp.where(is_held, index - lo, held).astype(jnp.int32),
+                  weight, held, rows, tile)
+    u_pad = jnp.concatenate([u, jnp.zeros((1, h), u.dtype)])
+    first = 0
+    if layer is not None:
+        experts = {n: w.reshape(-1, *w.shape[2:])
+                   for n, w in experts.items()}
+        first = layer * held
+
+    def one_pass(p, y):
+        at, tile_at = p * step, p * (step // tile)
+        token = jax.lax.dynamic_slice_in_dim(lay.row_token, at, step)
+        w_row = jax.lax.dynamic_slice_in_dim(lay.row_weight, at, step)
+        te = first + jax.lax.dynamic_slice_in_dim(
+            lay.tile_expert, tile_at, step // tile)
+        live = jnp.clip(lay.live_tiles - tile_at, 0, step // tile)
+        xs = u_pad[token]
+        gate = grouped_matmul(xs, experts["w_gate"], te, live)
+        up = grouped_matmul(xs, experts["w_up"], te, live)
+        with jax.named_scope("experts"):
+            mid = (jax.nn.silu(gate.astype(jnp.float32))
+                   * up.astype(jnp.float32)).astype(u.dtype)
+        out = grouped_matmul(mid, experts["w_down"], te, live)
+        out = jnp.where((token < t)[:, None],
+                        out.astype(jnp.float32) * w_row[:, None], 0.0)
+        return y.at[token].add(out, mode="drop")
+
+    y = jnp.zeros((t, h), jnp.float32)
+    if rows == step:
+        y = one_pass(0, y)
+    else:
+        y = jax.lax.fori_loop(
+            0, -(-(lay.live_tiles * tile) // step), one_pass, y)
+    y = y + u.astype(jnp.float32) * jnp.sum(
+        jnp.where(is_zero, weight, 0.0), axis=-1, keepdims=True)
+    return y.astype(u.dtype)
+
+
+def test_expert_share_in_one_pass_is_the_parents(v5e_devices,
+                                                 compiled_kernels):
+    """``zaya1-8b.train-moe-1chip``'s call — ``pass_rows=None`` in tiles
+    of 256 rows over 16,384 top-1 picks and 8 held experts of 2048 x 2048
+    — is the program it was, forward and gradient: the walk by blocks is
+    the serving form's alone (its loops' trip counts follow the load, and
+    such a loop has no reverse derivative)."""
+    from deepspeed_tpu.moe import dropless
+    sds = one_chip(v5e_devices)
+    bf = jnp.bfloat16
+    args = ({n: sds((8, *shape), bf) for n, shape in (
+        ("w_gate", (2048, 2048)), ("w_up", (2048, 2048)),
+        ("w_down", (2048, 2048)))}, sds((16384, 2048), bf),
+        sds((16384, 1), jnp.int32), sds((16384, 1), jnp.float32))
+
+    def program(share):
+        def train_call(experts, u, index, weight):    # one name, one text
+            def loss(experts, u, weight):
+                y = share(experts, u, dropless.Routing(index, weight), 16,
+                          (0, 8), pass_rows=None, tile_rows=256)
+                return jnp.sum(y.astype(jnp.float32) ** 2), y
+            return jax.value_and_grad(loss, (0, 1, 2), has_aux=True)(
+                experts, u, weight)
+        return compile_for_tpu(train_call, *args)
+    now = program(lambda *a, **kw: dropless.expert_share(*a, **kw)[0])
+    jax.clear_caches()
+    before = program(_parent_expert_share)
+    assert custom_calls(now) == 9      # three products, their dx and dw
+    assert stripped(now) == stripped(before)
+
+
+#: a cell's expert layer: (d_model, expert d_ff, the router's routed
+#: outputs, rows of its decode-only dispatch); 16 held, top-8, 4 layers
+EXPERT_SHARE_CELLS = {"sdar-30b-a3b-chat": (2048, 768, 128, 160),
+                      "openpangu-ultra-moe": (7680, 2048, 256, 128)}
+
+
+@pytest.mark.parametrize("cell", list(EXPERT_SHARE_CELLS))
+def test_expert_share_moves_rows_by_blocks(v5e_devices, compiled_kernels,
+                                           cell):
+    """The serving form of ``expert_share`` at a cell's widths, its
+    experts read out of a ``[4, 16, ..]`` stack: every row-wide gather and
+    scatter-add moves ``BLOCK_ROWS`` rows inside a loop's body — none
+    moves a pass's ``PASS_ROWS`` — and a pass is still three grouped
+    products."""
+    import re
+    from deepspeed_tpu.moe import dropless
+    h, f, routed, t = EXPERT_SHARE_CELLS[cell]
+    sds = one_chip(v5e_devices)
+    bf = jnp.bfloat16
+
+    def share(w_gate, w_up, w_down, u, index, weight, layer):
+        return dropless.expert_share(
+            {"w_gate": w_gate, "w_up": w_up, "w_down": w_down}, u,
+            dropless.Routing(index, weight), routed, (0, 16), layer=layer)
+    text = compile_for_tpu(
+        share, sds((4, 16, h, f), bf), sds((4, 16, h, f), bf),
+        sds((4, 16, f, h), bf), sds((t, h), bf), sds((t, 8), jnp.int32),
+        sds((t, 8), jnp.float32), sds((), jnp.int32))
+    assert custom_calls(text) == 3
+    assert len(re.findall(r"custom-call\(.*moe_grouped_matmul", text)) == 3
+    comps, _ = hlo_computations(text)
+    called = {name: set(re.findall(r"(?:calls|body|to_apply)=%([\w.-]+)",
+                                   " ".join(lines)))
+              for name, lines in comps.items()}
+    in_a_loop = set(re.findall(r"body=%([\w.-]+)", text))
+    while True:
+        more = set().union(*(called[c] for c in in_a_loop)) - in_a_loop
+        if not more:
+            break
+        in_a_loop |= more
+    moved = []              # (rows, inside a loop's body)
+    for name, lines in comps.items():
+        shape_of = dict(re.findall(r"%([\w.-]+) = \w+\[([\d,]*)\]",
+                                   "\n".join(lines)))
+        for ln in lines:
+            m = re.search(r"= \w+\[(\d+),%d\]\S* (gather|scatter)\("
+                          r"%%[\w.-]+, %%[\w.-]+(?:, %%([\w.-]+))?" % h, ln)
+            if m is None:
+                continue
+            rows = (m.group(1) if m.group(2) == "gather"
+                    else shape_of[m.group(3)].split(",")[0])
+            moved.append((m.group(2), int(rows), name in in_a_loop))
+    assert sorted(moved) == [("gather", dropless.BLOCK_ROWS, True),
+                             ("scatter", dropless.BLOCK_ROWS, True)], moved
+
+
 def _shortcut_case():
     from deepspeed_tpu.models import longcat_flash_config
     return longcat_flash_config(
