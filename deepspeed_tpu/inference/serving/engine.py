@@ -449,7 +449,7 @@ class ServingEngine:
                     dtype=engine.dtype),
                 out_shardings=NamedSharding(self.tp_mesh, P()))
             self._pool_x = make_extra()
-            if self._pool_x is not None:
+            if self._pool_x is not None and model.SLOT_STATE:
                 self.allocator.add_state_kind(self.num_slots)
         self._prep_tp_params()
         logger.info(

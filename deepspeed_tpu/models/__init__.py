@@ -1,5 +1,6 @@
 from .transformer import (TransformerConfig, TransformerLM,  # noqa: F401
-                          build_model, glm_moe_dsa_config, gpt2_config,
+                          afmoe_config, build_model, glm_moe_dsa_config,
+                          gpt2_config,
                           granite_hybrid_config, kimi_linear_config,
                           longcat_flash_config, neox_config,
                           openpangu_ultra_moe_config, phi4_flash_config,

@@ -53,12 +53,9 @@ from jax.sharding import PartitionSpec as P
 from . import layers as L
 from ..ops.transformer import ssm_scan
 from .transformer import (MixedStep, TransformerConfig, TransformerLM,
-                          lane_pool_rows, scatter_rows)
+                          write_kv_rows)
+from .window_kind import WindowKind
 
-#: rows of a prompt chunk to one walker of the window layers' kernel: at
-#: 2 query heads a key-value head and 2 heads a lane pack, 128 positions
-#: are 512 query rows a head window
-CHUNK_TILE_ROWS = 128
 #: the kinds of layer, in the order ``layer_kinds`` names them
 SSM, WINDOW, FULL, GMU, CROSS = "ssm", "window", "full", "gmu", "cross"
 
@@ -131,6 +128,7 @@ class PerSlotState:
 
     #: why a quantized pool is refused (the block's own reason)
     KV_BITS_REFUSAL = ""
+    SLOT_STATE = True
 
     # -- refusals ----------------------------------------------------------
     def prefix_cache_refusal(self) -> Optional[str]:
@@ -215,14 +213,7 @@ class PerSlotState:
                 layers * jnp.sum(st.act, dtype=jnp.int32),
                 rides & (st.chunk_start == 0))
 
-    @staticmethod
-    def _write_rows(pool_k, pool_v, k, v, tables, st: MixedStep, null):
-        """The step's new k / v rows ``[S + C, lanes]`` into the pools at
-        their slots' pages (``tables`` already offset to the layer; masked
-        rows to the layer's null block ``null``)."""
-        write = jnp.concatenate(
-            lane_pool_rows(st, tables, pool_k.shape[1], null)[0])
-        return scatter_rows(pool_k, write, k), scatter_rows(pool_v, write, v)
+    _write_rows = staticmethod(write_kv_rows)
 
 
 class PerSlotStateLM(PerSlotState, TransformerLM):
@@ -273,13 +264,7 @@ class PerSlotStateLM(PerSlotState, TransformerLM):
     def _attend_dense(self, q, k, v, q_pos, window: Optional[int]):
         """q ``[B, Tq, H, hd]`` at positions ``q_pos [Tq]`` against k, v
         ``[B, Tk, Hkv, hd]`` at positions ``0 .. Tk - 1``."""
-        k_pos = jnp.arange(k.shape[1])
-        seen = k_pos[None, :] <= q_pos[:, None]
-        if window is not None:
-            seen = seen & (k_pos[None, :] > q_pos[:, None] - window)
-        with jax.named_scope("attn_kernel"):
-            return L.gqa_attention(q, k, v, causal=False, scale=self._sm_scale,
-                                   mask=seen[None, None, None])
+        return L.gqa_attention_at(q, k, v, q_pos, window, self._sm_scale)
 
     def hidden_states_and_aux(self, params, input_ids, token_type_ids=None):
         x, _ = self._forward(params, self._embed_tokens(params, input_ids))
@@ -298,14 +283,11 @@ class PerSlotStateLM(PerSlotState, TransformerLM):
                 cache)
 
 
-class HybridSSMLM(PerSlotStateLM):
+class HybridSSMLM(WindowKind, PerSlotStateLM):
     """``TransformerLM``'s surface (``init`` / ``apply`` / ``generate()``'s
     cache / ``init_paged_cache`` / ``partition_specs``) for the hybrid
     block, and its three scans of the one serving step."""
 
-    #: the block tables a slot has, in the order the engine lays them
-    #: side by side in its per-slot operand
-    TABLE_KINDS = ("full", "window")
     #: what the serving step counts a dispatch, each added up where
     #: the work is handed to its kernel (a layer that walked more, or a
     #: row that was not spared, moves it): keys the eight walks over the
@@ -318,9 +300,6 @@ class HybridSSMLM(PerSlotStateLM):
                       "ssm_chunk_rows", "ssm_decode_rows",
                       "cross_rows_spared", "state_slots_started",
                       "kv_pages_read", "kv_pages_in_runs")
-    KV_BITS_REFUSAL = ("the window layers' walk starts inside a slot's "
-                       "table, and the quantized pool's scale rows take no "
-                       "first page")
 
     def __init__(self, config: HybridSSMConfig, constrain=None,
                  block_transform=None):
@@ -573,15 +552,6 @@ class HybridSSMLM(PerSlotStateLM):
         return x, cache
 
     # -- paged serving -----------------------------------------------------
-    def window_pages(self, block_size: int, chunk_tokens: int
-                     ) -> Tuple[int, int]:
-        """Pages of a window layer a slot holds at most: while decoding
-        (its window's keys), and while a chunk of ``chunk_tokens`` rows
-        is in flight (the first row's window to the last row)."""
-        w = self.config.sliding_window
-        return ((w - 1) // block_size + 2,
-                (w - 1 + chunk_tokens - 1) // block_size + 2)
-
     def _pool_sublayers(self) -> int:
         """The pool ``k`` / ``v`` is the FULL layer's alone; everything
         else a slot keeps is :meth:`init_paged_extra`'s."""
@@ -597,11 +567,9 @@ class HybridSSMLM(PerSlotStateLM):
         (``ssm [layers x slots, ..]``, float32, tiled)."""
         c = self.config
         dtype = dtype or c.dtype
-        wshape = (c.pairs_self, window_blocks, block_size,
-                  c.kv_heads * c.hdim)
         tiles, lanes = ssm_scan.tiling(c.d_inner)
-        return {"wk": jnp.zeros(wshape, dtype),
-                "wv": jnp.zeros(wshape, dtype),
+        return {**self._window_pool(c.pairs_self, window_blocks, block_size,
+                                    dtype),
                 "conv": jnp.zeros((c.ssm_conv - 1, c.ssm_layers * num_slots,
                                    c.d_inner), dtype),
                 "ssm": jnp.zeros((c.ssm_layers * num_slots, tiles,
@@ -668,37 +636,17 @@ class HybridSSMLM(PerSlotStateLM):
         the window pool), then the decode rows and the chunk attend the
         keys their windows reach.  Returns ``(out, wk, wv, the keys the
         two walks were handed)``."""
-        from ..ops.transformer.paged_decode_attention import (
-            paged_decode_attention, paged_prefill_attention)
         c = self.config
-        nh, nkv, hd, s = c.num_heads, c.kv_heads, c.hdim, st.slots
+        nh, nkv, hd = c.num_heads, c.kv_heads, c.hdim
         with jax.named_scope("attn_proj"):
             qkv = L.dense_apply(p["qkv"], h[0])
             q, k, v = jnp.split(qkv, [nh * hd, (nh + nkv) * hd], axis=-1)
             q = q.reshape(-1, nh, hd)
-        with jax.named_scope("pool_write"):
-            tables = st.wtables + off
-            wk, wv = self._write_rows(wk, wv, k, v, tables, st, off)
-        with jax.named_scope("attn_kernel"):
-            w = c.sliding_window
-            lengths = jnp.where(st.act, st.lens + 1, 0)
-            o = [paged_decode_attention(
-                q[:s], wk, wv, lengths, tables, sm_scale=self._sm_scale,
-                window=w)]
-            read = jnp.sum(jnp.minimum(lengths, w))
-            if st.chunk:
-                o.append(paged_prefill_attention(
-                    q[s:], wk, wv, st.chunk_start, st.chunk_len,
-                    tables[st.chunk_slot], sm_scale=self._sm_scale,
-                    window=w, tile_rows=CHUNK_TILE_ROWS))
-                # the first row's window to the last row
-                read += jnp.where(
-                    st.chunk_len > 0, st.chunk_start + st.chunk_len
-                    - jnp.maximum(st.chunk_start - (w - 1), 0), 0)
-            o = jnp.concatenate(o) if st.chunk else o[0]
+        o, wk, wv, read = self._write_then_walk(
+            q, k, v, wk, wv, st.wtables, off, st, c.sliding_window)
         with jax.named_scope("attn_proj"):
             return (L.dense_apply(p["out"], o.reshape(1, -1, nh * hd)), wk,
-                    wv, read.astype(jnp.int32))
+                    wv, read)
 
     def _full_walk(self, q, pool_k, pool_v, st: MixedStep):
         """The yield rows' queries ``[S (+ 1), H, hd]`` against the full

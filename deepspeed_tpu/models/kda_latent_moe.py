@@ -66,7 +66,8 @@ from ..moe import dropless
 from ..ops.transformer import kda_scan
 from .hybrid_ssm import PerSlotState
 from .latent_moe import DenseLeadMoEConfig, DenseLeadMoELM
-from .transformer import MixedStep
+from .transformer import (MixedStep, find_layer_plan, layer_of,
+                          walk_layer_plan)
 
 KDA, MLA = "kda", "mla"
 DENSE, MOE = "dense", "moe"
@@ -130,24 +131,7 @@ class KDALatentMoEConfig(DenseLeadMoEConfig):
         that repeats a period at least twice and covers the most layers
         (the shortest such period), and a tail; a stack with no repeat is
         one pass over all of it."""
-        sig = tuple(zip(self.layer_types, self.ffn_types))
-        n = len(sig)
-        best = (0, n, 0, 1)            # covered, period, start, passes
-        for period in range(1, n // 2 + 1):
-            for start in range(0, n - 2 * period + 1):
-                body = sig[start:start + period]
-                passes = 1
-                while sig[start + passes * period:
-                          start + (passes + 1) * period] == body:
-                    passes += 1
-                if passes >= 2 and passes * period > best[0]:
-                    best = (passes * period, period, start, passes)
-        covered, period, start, passes = best
-        if not covered:
-            return [(sig, 1)]
-        plan = [(sig[:start], 1), (sig[start:start + period], passes),
-                (sig[start + covered:], 1)]
-        return [(s, p) for s, p in plan if s]
+        return find_layer_plan(tuple(zip(self.layer_types, self.ffn_types)))
 
     def kda_params(self) -> int:
         # (the two low-rank gates' inner width is a head's)
@@ -306,12 +290,7 @@ class KDALatentMoELM(PerSlotState, DenseLeadMoELM):
         return params
 
     # -- what every path shares --------------------------------------------
-    @staticmethod
-    def _layer_of(stack, i):
-        """Layer ``i`` (traced or not) of a stack."""
-        return jax.tree_util.tree_map(
-            lambda a: jax.lax.dynamic_index_in_dim(a, i, 0, keepdims=False),
-            stack)
+    _layer_of = staticmethod(layer_of)
 
     def _stacks(self, params):
         """``params`` as the layer bodies read them: the four stacks, the
@@ -326,26 +305,8 @@ class KDALatentMoELM(PerSlotState, DenseLeadMoELM):
         stacks (``{kind: index}``, traced inside a repeated stretch):
         ``layer_plan``'s head and tail unrolled, its repeated stretch one
         scan over the passes."""
-        done = {KDA: 0, MLA: 0, DENSE: 0, MOE: 0}
-        for sigs, passes in self.config.layer_plan:
-            per = {kind: sum((m == kind) + (f == kind) for m, f in sigs)
-                   for kind in done}
-
-            def one_pass(carry, n, sigs=sigs, per=per, base=dict(done)):
-                at = {kind: base[kind] + n * per[kind] for kind in base}
-                for mixer, ffn in sigs:
-                    carry = layer_fn(carry, mixer, ffn, dict(at))
-                    at[mixer] = at[mixer] + 1
-                    at[ffn] = at[ffn] + 1
-                return carry, None
-            if passes == 1:
-                carry, _ = one_pass(carry, 0)
-            else:
-                carry, _ = jax.lax.scan(
-                    one_pass, carry, jnp.arange(passes, dtype=jnp.int32))
-            for kind in done:
-                done[kind] += passes * per[kind]
-        return carry
+        return walk_layer_plan(self.config.layer_plan,
+                               (KDA, MLA, DENSE, MOE), layer_fn, carry)
 
     def _kda_in(self, p, h):
         """``h [.., d]`` -> ``(qkv before the convolution, the decay's log

@@ -170,7 +170,88 @@ class LatentMoEConfig(TransformerConfig):
                 + (hi - lo) * 3 * self.d_model * self.expert_d_ff)
 
 
-class LatentMoELM(TransformerLM):
+class ExpertFFN:
+    """The FFN sublayers of a block whose stack mixes dense layers and
+    expert layers (``moe/dropless.py``'s share of the routed experts, a
+    shared expert beside them), whatever its attention is — a mixin
+    beside a ``TransformerLM``: the latent blocks here, and
+    ``models/window_moe.py``'s block over a k/v pool.  It reads the
+    config's ``expert_d_ff``, ``n_routed_experts``, ``router_outputs``,
+    ``moe_topk``, ``routed_scaling_factor``, ``router_scoring``,
+    ``router_bias``, ``norm_topk_prob``, ``held`` and, for the shared
+    expert, ``n_shared_experts``; the block says what its out / down
+    projections' init is scaled by (``_out_depth``)."""
+
+    # -- init --------------------------------------------------------------
+    def _out_depth(self) -> int:
+        return self.config.num_layers
+
+    def _ffn_init(self, k, width: Optional[int] = None):
+        """A SwiGLU FFN of ``width`` (the dense FFNs' by default)."""
+        c, dt = self.config, self.config.param_dtype
+        width = width or c.ff_dim
+        k1, k2, k3 = jax.random.split(k, 3)
+        return {"fc_gate": L.dense_init(k1, c.d_model, width, False, 0.02,
+                                        dt),
+                "fc_in": L.dense_init(k2, c.d_model, width, False, 0.02, dt),
+                "fc_out": {"kernel": L.scaled_init(
+                    k3, (width, c.d_model), 0.02, self._out_depth(), dt)}}
+
+    def _moe_init(self, k):
+        c, dt = self.config, self.config.param_dtype
+        lo, hi = c.held
+        outs = c.router_outputs
+        k1, k2, k3 = jax.random.split(k, 3)
+        moe = {
+            "router": L.dense_init(k1, c.d_model, outs, False, 0.02, dt),
+            "experts": dropless.init_experts(
+                k3, hi - lo, c.d_model, c.expert_d_ff, 0.02,
+                0.02 / math.sqrt(2.0 * self._out_depth()), dt)}
+        if c.router_bias:
+            moe["bias"] = (ROUTER_BIAS_SCALE / outs
+                           * jax.random.normal(k2, (outs,))).astype(dt)
+        return moe
+
+    def _moe_sublayer(self, p, u, row_valid=None, stack=None):
+        """u [B, T, h] -> (this chip's part of the routed experts' output,
+        counters).  ``stack = (every layer's experts, this layer's
+        index)`` where the caller kept the expert stack out of its layer
+        scan."""
+        c = self.config
+        b, t, h = u.shape
+        flat = u.reshape(b * t, h)
+        routing = dropless.route(
+            flat, p["router"]["kernel"], p.get("bias"), c.moe_topk,
+            c.routed_scaling_factor, scoring=c.router_scoring,
+            renormalize=c.norm_topk_prob)
+        experts, layer = stack or (p["experts"], None)
+        y, counters = dropless.expert_share(
+            experts, flat, routing, c.n_routed_experts, c.held, row_valid,
+            layer=layer)
+        return y.reshape(b, t, h), counters
+
+    # -- the FFN sublayer of either kind of layer --------------------------
+    def expert_layer(self, bp, u, row_valid=None, stack=None):
+        """An expert layer's ``F_l``: u [B, T, h] -> ``(Shared(u) + this
+        chip's part of the routed experts' output, counters)``.  The
+        shared expert is a plain SwiGLU over every row, whatever the
+        router says; ``stack`` as in ``_latent_block``."""
+        routed, counters = self._moe_sublayer(bp["moe"], u, row_valid,
+                                              stack)
+        shared = self._mlp(bp["shared"], u, scope="shared_expert")
+        with jax.named_scope("expert_layout"):
+            return shared + routed, counters
+
+    def _ffn_sublayer(self, bp, u, row_valid=None, stack=None):
+        """``F_l`` of either kind of layer: a dense layer is one whose
+        parameters hold ``mlp`` and no ``moe``, and counts nothing."""
+        if "moe" in bp:
+            return self.expert_layer(bp, u, row_valid, stack)
+        return (self._mlp(bp["mlp"], u),
+                jnp.zeros((len(dropless.COUNTERS),), jnp.int32))
+
+
+class LatentMoELM(ExpertFFN, TransformerLM):
     """``TransformerLM`` for blocks of latent attention and routed
     experts: same ``init`` / ``apply`` / ``init_paged_cache`` /
     ``partition_specs`` surface, its layers of the one serving step
@@ -300,32 +381,6 @@ class LatentMoELM(TransformerLM):
         may want another, for logits a check can see)."""
         return 0.02
 
-    def _ffn_init(self, k, width: Optional[int] = None):
-        """A SwiGLU FFN of ``width`` (the dense FFNs' by default)."""
-        c, dt = self.config, self.config.param_dtype
-        width = width or c.ff_dim
-        k1, k2, k3 = jax.random.split(k, 3)
-        return {"fc_gate": L.dense_init(k1, c.d_model, width, False, 0.02,
-                                        dt),
-                "fc_in": L.dense_init(k2, c.d_model, width, False, 0.02, dt),
-                "fc_out": {"kernel": L.scaled_init(
-                    k3, (width, c.d_model), 0.02, self._out_depth(), dt)}}
-
-    def _moe_init(self, k):
-        c, dt = self.config, self.config.param_dtype
-        lo, hi = c.held
-        outs = c.router_outputs
-        k1, k2, k3 = jax.random.split(k, 3)
-        moe = {
-            "router": L.dense_init(k1, c.d_model, outs, False, 0.02, dt),
-            "experts": dropless.init_experts(
-                k3, hi - lo, c.d_model, c.expert_d_ff, 0.02,
-                0.02 / math.sqrt(2.0 * self._out_depth()), dt)}
-        if c.router_bias:
-            moe["bias"] = (ROUTER_BIAS_SCALE / outs
-                           * jax.random.normal(k2, (outs,))).astype(dt)
-        return moe
-
     def serving_params(self, params) -> Dict:
         """``params`` with every latent attention's ``q_b`` and ``kv_b``
         (the published layout: ``init``'s, a checkpoint's) replaced by
@@ -434,24 +489,6 @@ class LatentMoELM(TransformerLM):
                            jax.nn.softmax(s, axis=-1).astype(x.dtype), v)
         with jax.named_scope("attn_proj"):
             return L.dense_apply(p["out"], o.reshape(b, t, -1))
-
-    def _moe_sublayer(self, p, u, row_valid=None, stack=None):
-        """u [B, T, h] -> (this chip's part of the routed experts' output,
-        counters).  ``stack = (every layer's experts, this layer's
-        index)`` where the caller kept the expert stack out of its layer
-        scan."""
-        c = self.config
-        b, t, h = u.shape
-        flat = u.reshape(b * t, h)
-        routing = dropless.route(
-            flat, p["router"]["kernel"], p.get("bias"), c.moe_topk,
-            c.routed_scaling_factor, scoring=c.router_scoring,
-            renormalize=c.norm_topk_prob)
-        experts, layer = stack or (p["experts"], None)
-        y, counters = dropless.expert_share(
-            experts, flat, routing, c.n_routed_experts, c.held, row_valid,
-            layer=layer)
-        return y.reshape(b, t, h), counters
 
     # -- full sequences ----------------------------------------------------
     def hidden_states_and_aux(self, params, input_ids, token_type_ids=None):
@@ -688,26 +725,6 @@ class DenseLeadMoELM(LatentMoELM):
 
     def _leading_blocks(self, params) -> Optional[Dict]:
         return params.get("dense_blocks")
-
-    # -- the FFN sublayer --------------------------------------------------
-    def expert_layer(self, bp, u, row_valid=None, stack=None):
-        """An expert layer's ``F_l``: u [B, T, h] -> ``(Shared(u) + this
-        chip's part of the routed experts' output, counters)``.  The
-        shared expert is a plain SwiGLU over every row, whatever the
-        router says; ``stack`` as in ``_latent_block``."""
-        routed, counters = self._moe_sublayer(bp["moe"], u, row_valid,
-                                              stack)
-        shared = self._mlp(bp["shared"], u, scope="shared_expert")
-        with jax.named_scope("expert_layout"):
-            return shared + routed, counters
-
-    def _ffn_sublayer(self, bp, u, row_valid=None, stack=None):
-        """``F_l`` of either kind of layer: a dense layer is one whose
-        parameters hold ``mlp`` and no ``moe``, and counts nothing."""
-        if "moe" in bp:
-            return self.expert_layer(bp, u, row_valid, stack)
-        return (self._mlp(bp["mlp"], u),
-                jnp.zeros((len(dropless.COUNTERS),), jnp.int32))
 
     def _paged_counters(self, step, carry, counts, walk) -> Dict[str, Any]:
         """``moe_rows_shared``: every row that carries a token goes

@@ -266,6 +266,20 @@ def gqa_attention(q, k, v, *, mask: Optional[jnp.ndarray] = None,
     return out.reshape(b, tq, nh, hd)
 
 
+def gqa_attention_at(q, k, v, q_pos, window: Optional[int], scale: float):
+    """:func:`gqa_attention` of q ``[B, Tq, H, hd]`` at positions ``q_pos
+    [Tq]`` against k, v ``[B, Tk, Hkv, hd]`` at positions ``0 .. Tk - 1``:
+    a row sees every key up to its own position, or with a ``window`` the
+    ``window`` keys that end there (``0 <= q_pos - k_pos < window``)."""
+    k_pos = jnp.arange(k.shape[1])
+    seen = k_pos[None, :] <= q_pos[:, None]
+    if window is not None:
+        seen = seen & (k_pos[None, :] > q_pos[:, None] - window)
+    with jax.named_scope("attn_kernel"):
+        return gqa_attention(q, k, v, causal=False, scale=scale,
+                             mask=seen[None, None, None])
+
+
 def alibi_slopes(num_heads: int) -> jnp.ndarray:
     """ALiBi head slopes (Press et al.; BLOOM's build_alibi_tensor,
     HF modeling_bloom.py): powers of 2^(-8/n) with the non-power-of-two

@@ -24,7 +24,8 @@ target family).
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
+from typing import (Any, Callable, Dict, List, NamedTuple, Optional,
+                    Tuple)
 
 import jax
 import jax.numpy as jnp
@@ -124,6 +125,77 @@ def scatter_rows(pool, write, rows):
     rows = rows.astype(pool.dtype).reshape(write.shape[0], -1)
     rows = jnp.pad(rows, ((0, 0), (0, lanes - rows.shape[1])))
     return pool.reshape(-1, lanes).at[write].set(rows).reshape(pool.shape)
+
+
+def write_kv_rows(pool_k, pool_v, k, v, tables, st: MixedStep, null):
+    """The step's new k / v rows ``[S + C, lanes]`` into the pools at
+    their slots' pages (``tables`` already offset to the layer; masked
+    rows to the layer's null block ``null``)."""
+    write = jnp.concatenate(
+        lane_pool_rows(st, tables, pool_k.shape[1], null)[0])
+    return scatter_rows(pool_k, write, k), scatter_rows(pool_v, write, v)
+
+
+def find_layer_plan(sig: Tuple) -> List[Tuple[Tuple, int]]:
+    """A stack of layers, each named by its signature (what kinds of
+    sublayer it is made of), as ``[(signatures of one pass, passes), ..]``:
+    a head, the stretch that repeats a period at least twice and covers
+    the most layers (the shortest such period, the earliest start), and a
+    tail; a stack with no repeat is one pass over all of it.  What a block
+    whose layers differ in kind scans (:func:`walk_layer_plan`)."""
+    n = len(sig)
+    best = (0, n, 0, 1)            # covered, period, start, passes
+    for period in range(1, n // 2 + 1):
+        for start in range(0, n - 2 * period + 1):
+            body = sig[start:start + period]
+            passes = 1
+            while sig[start + passes * period:
+                      start + (passes + 1) * period] == body:
+                passes += 1
+            if passes >= 2 and passes * period > best[0]:
+                best = (passes * period, period, start, passes)
+    covered, period, start, passes = best
+    if not covered:
+        return [(sig, 1)]
+    plan = [(sig[:start], 1), (sig[start:start + period], passes),
+            (sig[start + covered:], 1)]
+    return [(s, p) for s, p in plan if s]
+
+
+def walk_layer_plan(plan, kinds: Tuple[str, ...], layer_fn, carry):
+    """``layer_fn(carry, *signature, at) -> carry`` over every layer of
+    ``plan`` (:func:`find_layer_plan`) in order, ``at`` the layer's index
+    among the layers of each kind of ``kinds`` (``{kind: index}``, traced
+    inside a repeated stretch; a signature is a tuple of kinds): the
+    plan's head and tail unrolled, its repeated stretch one scan over the
+    passes."""
+    done = {kind: 0 for kind in kinds}
+    for sigs, passes in plan:
+        per = {kind: sum(sum(part == kind for part in sig) for sig in sigs)
+               for kind in done}
+
+        def one_pass(carry, n, sigs=sigs, per=per, base=dict(done)):
+            at = {kind: base[kind] + n * per[kind] for kind in base}
+            for sig in sigs:
+                carry = layer_fn(carry, *sig, dict(at))
+                for part in sig:
+                    at[part] = at[part] + 1
+            return carry, None
+        if passes == 1:
+            carry, _ = one_pass(carry, 0)
+        else:
+            carry, _ = jax.lax.scan(
+                one_pass, carry, jnp.arange(passes, dtype=jnp.int32))
+        for kind in done:
+            done[kind] += passes * per[kind]
+    return carry
+
+
+def layer_of(stack, i):
+    """Layer ``i`` (traced or not) of a stack of layers' parameters."""
+    return jax.tree_util.tree_map(
+        lambda a: jax.lax.dynamic_index_in_dim(a, i, 0, keepdims=False),
+        stack)
 
 
 def walk_counts(step: MixedStep, table, block: int, window=None):
@@ -623,6 +695,45 @@ def sdar_moe_config(size: str = "30b-a3b", **kw) -> TransformerConfig:
         **SDAR_MOE_SIZES[size], **kw})
 
 
+AFMOE_SIZES = {
+    # https://huggingface.co/arcee-ai/Trinity-Mini/blob/main/config.json
+    # (afmoe: every fourth layer full attention, the rest a window of
+    # 2,048; two leading dense layers)
+    "trinity-mini": dict(
+        num_layers=32, layer_types=(("window",) * 3 + ("full",)) * 8,
+        first_k_dense=2, num_heads=32, num_kv_heads=4, head_dim=128,
+        d_model=2048, d_ff=6144, vocab_size=200192, max_seq_len=131072,
+        sliding_window=2048, rotary_base=1e4,
+        expert_d_ff=1024, n_routed_experts=128, n_shared_experts=1,
+        moe_topk=8, routed_scaling_factor=2.826, router_scoring="sigmoid",
+        router_bias=True, norm_topk_prob=True),
+}
+
+
+def afmoe_config(size: str = "trinity-mini", **kw) -> TransformerConfig:
+    """The ``afmoe`` family's language model: window-attention layers
+    (rotary) and full-attention layers (no positional encoding) in the
+    order ``layer_types`` lists (``"window"`` / ``"full"``), an
+    RMSNorm over each head of q and of k, a sigmoid gate on the
+    attention's output, four norms a layer, ``first_k_dense`` dense
+    layers and then a sigmoid top-8 router (selection bias, renormalised
+    and scaled weights) over routed experts beside a shared expert
+    (``models/window_moe.py``).  ``size`` names a published set of
+    widths; another pattern (``layer_types`` with ``num_layers`` its
+    length), the vocabulary, the served positions and ``experts_held``
+    (the chip's share of a deployment; ``()``: every expert) come as
+    keywords."""
+    from .window_moe import WindowMoEConfig
+    for name in ("layer_types", "experts_held"):
+        if name in kw:
+            kw[name] = tuple(kw[name])
+    return WindowMoEConfig(**{
+        "pos_embedding": "rotary", "rotary_interleaved": False,
+        "norm_type": "rmsnorm", "gated_mlp": True, "activation": "silu",
+        "use_bias": False, "tie_embeddings": False, "layernorm_eps": 1e-5,
+        **AFMOE_SIZES[size], **kw})
+
+
 def build_model(config: TransformerConfig, **kw) -> "TransformerLM":
     """The model that runs ``config``'s block: ``TransformerLM`` for the
     standard block, the config's own class (``config.model_class()``) for
@@ -654,6 +765,10 @@ class TransformerLM:
     #: diffusion over blocks (the engine's block lane); 0: one token a
     #: slot a step, left to right
     block_rows: int = 0
+    #: whether ``init_paged_extra``'s tree holds state BY SLOT (the
+    #: allocator's ``state`` kind: ``models/hybrid_ssm.py::PerSlotState``),
+    #: and not pages alone
+    SLOT_STATE: bool = False
 
     def __init__(self, config: TransformerConfig,
                  constrain: Optional[Callable] = None,

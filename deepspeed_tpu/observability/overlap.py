@@ -144,9 +144,12 @@ SCOPES = ("embed", "norm", "residual", "attn_proj", "attn_kernel",
 UNNAMED, AMBIGUOUS = "unnamed", "ambiguous"
 #: what a scope may name directly inside itself, where the step's two
 #: lanes do different work under one scope (``kda_scan/decode``: the rows'
-#: one-step update, ``kda_scan/chunk``: the chunk's blocked form); a
-#: reader that asks for lanes gets ``scope/lane``, every other the scope
-SCOPE_LANES = ("decode", "chunk")
+#: one-step update, ``kda_scan/chunk``: the chunk's blocked form), or
+#: where two kinds of layer do (``attn_kernel/window``: the paged walks of
+#: the layers that attend a window, ``attn_kernel/full``: of those that
+#: attend everything, ``models/window_moe.py``); a reader that asks for
+#: lanes gets ``scope/lane``, every other the scope
+SCOPE_LANES = ("decode", "chunk", "window", "full")
 
 
 def scoped(name: str):
@@ -220,7 +223,16 @@ COUNTERS = ("dispatches", "decode_rows", "chunk_rows", "rows_computed",
             # the weighted scatter-add visited (moe/dropless.py: blocks
             # walked x BLOCK_ROWS, summed over passes and layers); over
             # moe_picks_held, the rows moved for a pick that needed one
-            "moe_rows_moved")
+            "moe_rows_moved",
+            # the plain paged kernel's walks BY KIND of page, where full
+            # layers and window layers stand in one stack
+            # (models/window_moe.py, which also counts
+            # kv_tokens_read_full / _window, moe_rows_shared and
+            # window_blocks_freed above): kv_pages_read / _in_runs of the
+            # full layers' walks, and of the window layers' from each
+            # walk's first attended page; 0 for other blocks
+            "kv_pages_read_full", "kv_pages_in_runs_full",
+            "kv_pages_read_window", "kv_pages_in_runs_window")
 _COUNTER_AT = {name: k for k, name in enumerate(COUNTERS)}
 
 #: one iteration (or training step).  Times are seconds on

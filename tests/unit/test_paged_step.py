@@ -13,7 +13,7 @@ import pytest
 
 from deepspeed_tpu.models import TransformerLM, build_model, gpt2_config
 from deepspeed_tpu.models.transformer import (
-    MixedStep, glm_moe_dsa_config, granite_hybrid_config,
+    MixedStep, afmoe_config, glm_moe_dsa_config, granite_hybrid_config,
     kimi_linear_config, longcat_flash_config, openpangu_ultra_moe_config,
     phi4_flash_config, pool_rows, sdar_moe_config, walk_counts, zaya_config)
 
@@ -53,6 +53,12 @@ BLOCKS = {
         "30b-a3b", num_layers=2, num_heads=4, num_kv_heads=2, head_dim=16,
         d_model=32, expert_d_ff=16, n_routed_experts=8, moe_topk=2,
         mask_token_id=96, block_length=4, **F32),
+    "window-moe": lambda: afmoe_config(
+        "trinity-mini", num_layers=4,
+        layer_types=("window", "window", "window", "full"), first_k_dense=1,
+        num_heads=4, num_kv_heads=2, head_dim=8, d_model=32, d_ff=64,
+        sliding_window=8, expert_d_ff=16, n_routed_experts=8, moe_topk=2,
+        **F32),
 }
 #: the blocks that refuse the draft lane and a quantized pool
 REFUSING = [b for b in BLOCKS if b != "plain"]

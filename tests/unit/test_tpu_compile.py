@@ -2140,6 +2140,91 @@ def test_kda_latent_step_updates_pool_and_state_where_they_lie(
 #: every step program a benchmark cell runs, at this module's sizes:
 #: name -> (what compiles it, given the described devices; the scopes it
 #: must show)
+# -- window and full attention in one stack, over experts -------------------
+def window_moe_model():
+    """The ``afmoe`` block at its cell's widths (32 / 4 heads of 128, a
+    window of 2,048, 16 of 128 experts of 1,024 beside a shared one) with
+    a shorter pattern — the dense lead, one whole period and the boundary
+    period: a head of four layers, three passes of ``[window + experts]``
+    and a ``full`` tail — and a small vocabulary."""
+    from deepspeed_tpu.models import afmoe_config, build_model
+    return build_model(afmoe_config(
+        "trinity-mini", num_layers=8,
+        layer_types=("window", "window", "window", "full") * 2,
+        vocab_size=1024, max_seq_len=10240, experts_held=(0, 16)))
+
+
+#: the cell's engine: slots, pages a slot a kind, the full layers' blocks,
+#: the window layers' (19 x 129 + 161 and the null block)
+WINDOW_MOE_SIZE = (20, 640, 8000, 19 * 129 + 161 + 1)
+
+
+def window_moe_mixed_operands(devices, model, chunk):
+    slots, pages, nb, wb = WINDOW_MOE_SIZE
+    sds = one_chip(devices)
+    args, pools, params = mixed_step_operands(devices, model, nb, 16, 0,
+                                              slots, 2 * pages, chunk)
+    extra = jax.tree_util.tree_map(
+        lambda a: sds(a.shape, a.dtype), jax.eval_shape(
+            lambda: model.init_paged_extra(slots, 16, wb, jnp.bfloat16)))
+    args[1]["extra"] = extra
+    return args, dict(pools, **extra), params
+
+
+def build_window_moe_mixed(devices, chunk):
+    model = window_moe_model()
+    args, _, _ = window_moe_mixed_operands(devices, model, chunk)
+    return jax.jit(model._apply_paged_mixed, donate_argnums=1).trace(
+        *args).lower(lowering_platforms=("tpu",)).compile()
+
+
+@pytest.mark.parametrize("shape", list(HYBRID_CHUNK))
+def test_window_moe_step_keeps_both_pools_in_place(
+        v5e_devices, compiled_kernels, step_programs, shape):
+    """The block's step at its cell's widths, both shapes: one traced body
+    a distinct layer of ``layer_plan`` (six of the eight layers), each
+    with ONE walk of its kind's pages for the decode rows and one more
+    for a chunk, with the window where the layer has one; outside a fusion
+    nothing is a copy, a slice or an allocation shaped like either pool
+    (2.05 GB of window pages and 1.74 GB of full pages at the cell's
+    depth); and both lanes are named."""
+    import re
+    from deepspeed_tpu.observability.overlap import scope_key, scope_table
+    chunk = HYBRID_CHUNK[shape]
+    model = window_moe_model()
+    plan = model.config.layer_plan
+    assert [(len(sigs), passes) for sigs, passes in plan] == [
+        (4, 1), (1, 3), (1, 1)]
+    _, pools, _ = window_moe_mixed_operands(v5e_devices, model, chunk)
+    text, temp_bytes = step_programs(f"window-moe-{shape}")
+    kernels = [ln for ln in text.splitlines()
+               if re.search(r' custom-call\(.*"tpu_custom_call"', ln)
+               and "%paged_attention" in ln]
+    bodies = sum(len(sigs) for sigs, _ in plan)
+    assert len(kernels) == bodies * (2 if chunk else 1)
+    laned = scope_table([text], lanes=True)
+    lanes = [laned[scope_key(ln)][0] for ln in kernels]
+    per = 2 if chunk else 1
+    assert lanes.count("attn_kernel/window") == 4 * per
+    assert lanes.count("attn_kernel/full") == 2 * per
+    dims = lambda shape: ",".join(map(str, shape))        # noqa: E731
+    whole = set()
+    for name in ("k", "v", "wk", "wv"):
+        a = pools[name]
+        whole |= {dims(a.shape), dims(a.shape[1:]),
+                  dims((a.shape[0] * a.shape[1],) + a.shape[2:])}
+    moved = []
+    for name, result, op, ln in unfused_instructions(text):
+        shapes = set(re.findall(r"\w+\[([\d,]+)\]", result))
+        if shapes & whole and (op in ("copy", "dynamic-slice")
+                               or "AllocateBuffer" in ln):
+            moved.append(ln[:160])
+    assert not moved, moved
+    assert re.search(r"input_output_alias=\{[^\n]*may-alias", text)
+    # less than ONE window layer's k pages
+    assert temp_bytes < int(np.prod(pools["wk"].shape[1:])) * 2
+
+
 _LAYER = {"embed", "norm", "residual", "attn_proj", "attn_kernel", "head"}
 _SERVE = _LAYER | {"pool_write", "mlp"}
 _EXPERTS = _SERVE | {"router", "expert_layout", "experts"}
@@ -2182,6 +2267,12 @@ for _shape, _chunk in HYBRID_CHUNK.items():
     STEP_PROGRAMS[f"block-diffusion-{_shape}"] = (
         lambda dev, c=_chunk: build_block_diffusion_engine_step(dev, c),
         (_EXPERTS - {"mlp"}) | {"block_unmask", "sample"})
+
+
+for _shape, _chunk in HYBRID_CHUNK.items():
+    STEP_PROGRAMS[f"window-moe-{_shape}"] = (
+        lambda dev, c=_chunk: build_window_moe_mixed(dev, c),
+        _EXPERTS | {"shared_expert"})
 
 
 STEP_PROGRAMS["train-moe-1chip"] = (
