@@ -60,7 +60,17 @@ released by the one :meth:`free`:
     pages that cover the positions a dispatch reads and writes and hands
     the pages wholly before them back, so a sequence holds a bounded
     number of them however long it grows; a page it gave back reads
-    NULL in its table (the kernel never looks there).
+    NULL in its table (the kernel never looks there).  The pool is
+    handed out in GROUPS of ``PAGE_RUN`` consecutive ids, group ``g`` =
+    ``1 + PAGE_RUN * g .. PAGE_RUN * (g + 1)``: a sequence takes a free
+    group when its table reaches a page ``p`` with ``p % PAGE_RUN == 0``,
+    page ``p`` is the group's block ``p % PAGE_RUN`` (the table is indexed
+    by the sequence's absolute page, so every run of the table is
+    consecutive blocks by construction), and the group goes back when
+    its LAST page is handed back, or at :meth:`free`.  What the kind
+    counts (``window_held_max``, ``num_used_by_kind``) is PAGES a window
+    still reaches; a group's other places are the pool's slack, which
+    :func:`window_pool_blocks` sizes in.
   * ``state`` (:meth:`attach_state`) — not pages at all: what a layer
     keeps per SEQUENCE (a recurrent state), indexed by the decode slot
     the scheduler seated it in.  The allocator only records who holds
@@ -198,6 +208,22 @@ def _chain_hash(prev: bytes, token_ids: Tuple[int, ...]) -> bytes:
     return h.digest()
 
 
+def window_groups(pages: int) -> int:
+    """Groups of ``PAGE_RUN`` that ``pages`` consecutive pages of a table
+    touch at most, wherever the first of them falls in its group."""
+    return -(-(pages - 1) // PAGE_RUN) + 1
+
+
+def window_pool_blocks(num_slots: int, held_decoding: int,
+                       held_chunk: int) -> int:
+    """Blocks of a ``window`` pool that holds every slot's bound at once:
+    all but one slot decoding (``held_decoding`` pages), one with a chunk
+    in flight (``held_chunk``), each in whole groups, and the null
+    block."""
+    return 1 + PAGE_RUN * ((num_slots - 1) * window_groups(held_decoding)
+                           + window_groups(held_chunk))
+
+
 class PagedBlockAllocator:
     def __init__(self, num_blocks: int, block_size: int,
                  enable_prefix_cache: bool = True):
@@ -257,8 +283,12 @@ class PagedBlockAllocator:
         # pool and per-sequence sparse tables, and who holds which
         # slot's per-sequence state
         self.window_tokens = 0
+        # free GROUPS, each by its first block; a sequence's table by its
+        # absolute page, the groups it holds by page // PAGE_RUN (NULL
+        # once given back), and how many leading pages it has given back
         self._wfree: List[int] = []
         self._wtables: Dict[str, List[int]] = {}
+        self._wgroups: Dict[str, List[int]] = {}
         self._wdead: Dict[str, int] = {}
         self.window_blocks = 0
         self.state_slots = 0
@@ -526,17 +556,19 @@ class PagedBlockAllocator:
 
     def add_window_kind(self, num_blocks: int, window_tokens: int) -> None:
         """Give the allocator a ``window`` kind: a pool of ``num_blocks``
-        blocks (block 0 its null block) for layers that attend the newest
-        ``window_tokens`` tokens."""
+        blocks (block 0 its null block, then whole groups of ``PAGE_RUN``)
+        for layers that attend the newest ``window_tokens`` tokens."""
         if self.window_tokens:
             raise BlockPoolError("the window kind is already there")
-        if num_blocks < 2 or window_tokens < 1:
+        if num_blocks <= PAGE_RUN or window_tokens < 1:
             raise ValueError(
-                f"a window kind needs >= 2 blocks and a window >= 1, got "
-                f"{num_blocks} and {window_tokens}")
+                f"a window kind needs > {PAGE_RUN} blocks (a group beside "
+                f"the null block) and a window >= 1, got {num_blocks} and "
+                f"{window_tokens}")
         self.window_tokens = window_tokens
         self.window_blocks = num_blocks
-        self._wfree = list(range(num_blocks - 1, 0, -1))
+        groups = (num_blocks - 1) // PAGE_RUN
+        self._wfree = [1 + PAGE_RUN * g for g in reversed(range(groups))]
 
     def add_state_kind(self, num_slots: int) -> None:
         """Give the allocator a ``state`` kind: ``num_slots`` per-sequence
@@ -555,13 +587,18 @@ class PagedBlockAllocator:
         if seq_id not in self._tables:
             raise BlockPoolError(f"unknown sequence {seq_id!r}")
         table = self._wtables.setdefault(seq_id, [])
+        groups = self._wgroups.setdefault(seq_id, [])
         need = -(-end_row // self.block_size)
         while len(table) < need:
-            if not self._wfree:
-                raise BlockPoolError(
-                    f"window pool exhausted: {seq_id!r} needs page "
-                    f"{len(table)}, 0 free of {self.window_blocks - 1}")
-            table.append(self._wfree.pop())
+            at = len(table) % PAGE_RUN
+            if at == 0:
+                if not self._wfree:
+                    raise BlockPoolError(
+                        f"window pool exhausted: {seq_id!r} needs page "
+                        f"{len(table)}, 0 groups free of "
+                        f"{(self.window_blocks - 1) // PAGE_RUN}")
+                groups.append(self._wfree.pop())
+            table.append(groups[-1] + at)
         freed = self.window_trim(seq_id, first_row)
         held = len(table) - self._wdead.get(seq_id, 0)
         if held > self.window_held_max[lane]:
@@ -578,9 +615,12 @@ class PagedBlockAllocator:
         was = self._wdead.get(seq_id, 0)
         dead = min(max(0, next_row - (self.window_tokens - 1))
                    // self.block_size, len(table))
+        groups = self._wgroups[seq_id]
         for page in range(was, dead):
-            self._wfree.append(table[page])
             table[page] = NULL_BLOCK
+            if page % PAGE_RUN == PAGE_RUN - 1:     # its group's last
+                self._wfree.append(groups[page // PAGE_RUN])
+                groups[page // PAGE_RUN] = NULL_BLOCK
         if dead > was:
             self._wdead[seq_id] = dead
             self.window_freed_total += dead - was
@@ -607,8 +647,8 @@ class PagedBlockAllocator:
     def num_used_by_kind(self) -> Dict[str, int]:
         """Blocks (``state``: slots) live sequences hold, by kind."""
         return {"full": self.num_used,
-                "window": (max(0, self.window_blocks - 1) - len(self._wfree)
-                           if self.window_tokens else 0),
+                "window": sum(len(t) - self._wdead.get(seq, 0)
+                              for seq, t in self._wtables.items()),
                 "state": len(self._state_slots)}
 
     # -- alloc / grow / free ----------------------------------------------
@@ -800,8 +840,9 @@ class PagedBlockAllocator:
         self._chain.pop(seq_id, None)
         self._state_slots.pop(seq_id, None)
         self._wdead.pop(seq_id, None)
-        self._wfree.extend(b for b in self._wtables.pop(seq_id, ())
-                           if b != NULL_BLOCK)
+        self._wtables.pop(seq_id, None)
+        self._wfree.extend(g for g in self._wgroups.pop(seq_id, ())
+                           if g != NULL_BLOCK)
         for b in table:
             if self._ref[b] <= 0:
                 raise BlockPoolError(
@@ -962,13 +1003,28 @@ class PagedBlockAllocator:
             raise BlockPoolError(
                 "the idle groups are not the whole groups without a "
                 "referenced block")
-        wheld = [b for t in self._wtables.values() for b in t
-                 if b != NULL_BLOCK]
-        if self.window_tokens and sorted(wheld + self._wfree) != list(
-                range(1, self.window_blocks)):
+        # the window kind: groups held and groups free are all the groups,
+        # a sequence holds the groups its pages from the first live one to
+        # the table's end fall in, and live page p is its group's block
+        # p % PAGE_RUN
+        wheld = [g for gs in self._wgroups.values() for g in gs
+                 if g != NULL_BLOCK]
+        if sorted(wheld + self._wfree) != list(
+                range(1, self.window_blocks - PAGE_RUN + 1, PAGE_RUN)):
             raise BlockPoolError(
-                "window blocks leaked or held twice: free list and tables "
+                "window groups leaked or held twice: free list and tables "
                 "do not partition the window pool")
+        for seq, table in self._wtables.items():
+            dead, groups = self._wdead.get(seq, 0), self._wgroups[seq]
+            pages = [NULL_BLOCK if p < dead else
+                     groups[p // PAGE_RUN] + p % PAGE_RUN
+                     for p in range(len(table))]
+            kept = [j >= dead // PAGE_RUN
+                    for j in range(-(-len(table) // PAGE_RUN))]
+            if table != pages or [g != NULL_BLOCK for g in groups] != kept:
+                raise BlockPoolError(
+                    f"{seq!r}'s window table is not its groups' blocks "
+                    f"from page {dead} on")
         for seq in list(self._wtables) + list(self._state_slots):
             if seq not in self._tables:
                 raise BlockPoolError(

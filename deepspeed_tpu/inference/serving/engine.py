@@ -82,7 +82,7 @@ from ...runtime.resilience.heartbeat import Heartbeat
 from ...runtime.resilience.retry import retry_call
 from ...utils.logging import logger
 from ..sampling import block_unmask, fold_in_keys, sample_tokens_per_row
-from .block_allocator import PagedBlockAllocator
+from .block_allocator import PagedBlockAllocator, window_pool_blocks
 from .host_cache import BlockCodec, HostTierCache
 from .frontend.streaming import TokenEvent
 from .scheduler import (ContinuousBatchingScheduler, Request,
@@ -361,13 +361,12 @@ class ServingEngine:
         self.table_kinds = model.TABLE_KINDS
         self.window_blocks = 0
         if "window" in self.table_kinds:
-            # every slot's bound at once: all but one decoding, one with
-            # a chunk in flight (it is trimmed as soon as the chunk is
-            # enqueued), and the kind's null block
-            held_decoding, held_chunk = model.window_pages(
-                self.block_size, self.chunk_tokens)
-            self.window_blocks = ((self.num_slots - 1) * held_decoding
-                                  + held_chunk + 1)
+            # every slot's bound at once, in the groups the kind hands
+            # out: all but one decoding, one with a chunk in flight (it is
+            # trimmed as soon as the chunk is enqueued), the null block
+            self.window_blocks = window_pool_blocks(
+                self.num_slots, *model.window_pages(self.block_size,
+                                                    self.chunk_tokens))
             self.allocator.add_window_kind(self.window_blocks,
                                            model.config.sliding_window)
         # a prefill worker publishes to the fabric but never claims from

@@ -30,14 +30,14 @@ import deepspeed_tpu as ds
 from benchmark.lib import reference_phi4_flash as reference
 from benchmark.runners import serve_hybrid
 from deepspeed_tpu.inference.serving.block_allocator import (
-    BlockPoolError, PagedBlockAllocator)
+    BlockPoolError, PagedBlockAllocator, window_groups, window_pool_blocks)
 from deepspeed_tpu.models import (TransformerLM, build_model,
                                   phi4_flash_config)
 from deepspeed_tpu.models.hybrid_ssm import HybridSSMLM
 from deepspeed_tpu.observability.overlap import get_overlap_profiler
 from deepspeed_tpu.ops.transformer import ssm_scan
 from deepspeed_tpu.ops.transformer.paged_decode_attention import (
-    paged_attention_reference, paged_decode_attention,
+    PAGE_RUN, page_runs, paged_attention_reference, paged_decode_attention,
     paged_prefill_attention, paged_prefill_reference)
 
 #: 2 x (state space, window) + the middle pair + 2 x (memory unit, cross)
@@ -207,7 +207,9 @@ def test_every_kind_of_state_is_handed_back(served):
     # window 8 over blocks of 4: 3 pages decoding, 7 with 16 rows in flight
     assert srv.model.window_pages(4, 16) == (3, 7)
     assert alloc.window_held_max == {"decode": 3, "chunk": 7}
-    assert srv.window_blocks == 2 * 3 + 7 + 1
+    # the pool in groups of 8: a slot's 3 or 7 pages touch at most 2
+    assert (window_groups(3), window_groups(7)) == (2, 2)
+    assert srv.window_blocks == PAGE_RUN * (2 * 2 + 2) + 1
     assert alloc.window_freed_total > 0
     # the extra state is counted in the pool's bytes
     assert srv.kv_pool_bytes > srv._pool_k.nbytes + srv._pool_v.nbytes
@@ -412,34 +414,125 @@ def window_pool():
             jnp.asarray(rng.normal(size=(16, 4, 8)), jnp.float32))
 
 
-@pytest.mark.parametrize("pages", [None, 1, 2, 4])
+#: the window of the tables the allocator made (``own_window_pool``): 18
+#: pages of 4, so a walk crosses whole runs of 8
+OWN_WINDOW = 70
+
+
+@pytest.fixture(scope="module")
+def own_window_pool():
+    """The allocator's OWN window tables after a churn, over a pool of
+    their size: five slots decoding at contexts of 0 (no sequence), 3, 77,
+    150 and 163, and one that prefilled 96 rows and has a chunk of 16 in
+    flight; sequences that came and went beside them, so a table's
+    consecutive runs are not neighbours in the pool.  Each walk has whole
+    runs in its middle and broken ones at both ends.  Returns the pool
+    (its last block NaN), the decode tables and lengths, the chunk's
+    table, and the queries."""
+    rng = np.random.default_rng(1)
+    block, chunk, window, lens = 4, 16, OWN_WINDOW, [0, 3, 77, 150, 163]
+    alloc = PagedBlockAllocator(512, block, enable_prefix_cache=False)
+    alloc.add_window_kind(window_pool_blocks(6, *HybridSSMLM.window_pages(
+        types.SimpleNamespace(config=types.SimpleNamespace(
+            sliding_window=window)), block, chunk)), window)
+    at = {}
+
+    def rows(seq, n):
+        """``n`` more rows of ``seq``, one a dispatch."""
+        if seq not in at:
+            alloc.allocate(seq, 200)
+        for r in range(at.get(seq, 0), at.get(seq, 0) + n):
+            alloc.window_reserve(seq, r, r + 1)
+        at[seq] = at.get(seq, 0) + n
+
+    for _ in range(10):                 # five sequences side by side ...
+        for seq in "vwxyz":
+            rows(seq, 9)
+    for seq in "wy":                    # ... two go, three stay a while
+        alloc.free(seq)
+    for n in (3, 77, 150, 163):
+        for step in range(0, n, 5):
+            rows(f"s{n}", min(5, n - step))
+            if n == 150 and step == 100:
+                for seq in "vxz":
+                    alloc.free(seq)
+    alloc.allocate("chunk", 200)
+    for start in range(0, 96, chunk):
+        alloc.window_reserve("chunk", start, start + chunk, "chunk")
+        alloc.window_trim("chunk", start + chunk)
+    alloc.window_reserve("chunk", 96, 96 + chunk, "chunk")
+    alloc.assert_consistent()
+    nan = alloc.window_blocks
+
+    def table(seq):
+        """The table a dispatch is handed, the pages handed back NaN."""
+        first, held = alloc.window_pages_held(seq)
+        return [nan] * first + held + [0] * (48 - first - len(held))
+
+    tables = np.asarray([[0] * 48] + [table(f"s{n}") for n in lens[1:]],
+                        np.int32)
+    lens = np.asarray(lens, np.int32)
+    runs = np.asarray(page_runs(np.where(tables == nan, 0, tables), lens,
+                                block))
+    for b in (2, 3, 4):                 # broken, whole .., broken
+        first, last = (lens[b] - window) // block // PAGE_RUN, \
+            (lens[b] - 1) // block // PAGE_RUN
+        assert runs[b, first + 1:last].all() and runs[b, first + 1:last].size
+        assert not runs[b, last] and tables[b, first * PAGE_RUN] == nan
+    pool = [jnp.asarray(rng.normal(size=(nan + 1, block, 16)), jnp.float32)
+            .at[nan].set(jnp.nan) for _ in range(2)]
+    return (*pool, tables, lens, np.asarray(table("chunk"), np.int32),
+            jnp.asarray(rng.normal(size=(16, 4, 8)), jnp.float32))
+
+
+@pytest.mark.parametrize("pages", [None, 1, 2, 4, "own"])
 def test_the_paged_kernel_walks_a_window_from_its_first_page_decode(
-        window_pool, pages):
+        window_pool, own_window_pool, pages):
     """Grouped-query heads (2 a kv head) and a window of 9: the walk
     starts at the page that holds a slot's first attended position, and
-    the pages before it — handed on, so NaN here — start no DMA."""
-    pk, pv, tables, q = window_pool
-    lens = np.array([0, 3, 17, 40, 48], np.int32)
-    dead = tables.copy()
-    for b, n in enumerate(lens):
-        dead[b, :max(0, n - 9) // 4] = 63
+    the pages before it — handed on, so NaN here — start no DMA.  ``own``:
+    a window of 70 over the tables the allocator's window kind made, runs
+    of 8 consecutive blocks fetched whole where the walk covers them."""
+    if pages == "own":
+        pk, pv, dead, lens, _, q = own_window_pool
+        window, pages = OWN_WINDOW, PAGE_RUN
+        tables = np.where(dead == pk.shape[0] - 1, 0, dead)
+    else:
+        pk, pv, tables, q = window_pool
+        window, lens = 9, np.array([0, 3, 17, 40, 48], np.int32)
+        dead = tables.copy()
+        for b, n in enumerate(lens):
+            dead[b, :max(0, n - 9) // 4] = 63
     got = paged_decode_attention(q[:5], pk, pv, lens, jnp.asarray(dead),
-                                 interpret=True, window=9,
+                                 interpret=True, window=window,
                                  pages_per_program=pages)
     want = paged_attention_reference(q[:5], pk, pv, lens,
-                                     jnp.asarray(tables), window=9)
+                                     jnp.asarray(tables), window=window)
     assert float(jnp.abs(got - want).max()) < 1e-6
     assert not np.asarray(got[0]).any()                 # the empty slot
 
 
-@pytest.mark.parametrize("tile_rows", [None, 4, 8])
-@pytest.mark.parametrize("base,rows", [(0, 16), (16, 16), (32, 11),
-                                       (32, 3)])
+@pytest.mark.parametrize("base,rows,tile_rows", [
+    *((base, rows, tile_rows) for base, rows in ((0, 16), (16, 16), (32, 11),
+                                                 (32, 3))
+      for tile_rows in (None, 4, 8)), (96, 16, 8)])
 def test_the_paged_kernel_walks_a_window_from_its_first_page_chunk(
-        window_pool, base, rows, tile_rows):
+        window_pool, own_window_pool, base, rows, tile_rows):
     """A chunk row sees its own window; cut into tiles, each walker
     starts at its own first page.  Without a window the tiles are the
-    whole chunk's walk."""
+    whole chunk's walk.  The chunk from row 96: a window of 70 over the
+    table the allocator's window kind made for it."""
+    if base == 96:
+        pk, pv, _, _, dead, q = own_window_pool
+        got = paged_prefill_attention(q, pk, pv, base, rows,
+                                      jnp.asarray(dead), interpret=True,
+                                      window=OWN_WINDOW, tile_rows=tile_rows)
+        want = paged_prefill_reference(
+            q, pk, pv, base, rows,
+            jnp.asarray(np.where(dead == pk.shape[0] - 1, 0, dead)),
+            window=OWN_WINDOW)
+        assert float(jnp.abs(got[:rows] - want[:rows]).max()) < 1e-6
+        return
     pk, pv, tables, q = window_pool
     dead = tables[4].copy()
     dead[:max(0, base - 8) // 4] = 63

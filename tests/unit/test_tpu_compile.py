@@ -23,6 +23,8 @@ import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from deepspeed_tpu import ops
+from deepspeed_tpu.inference.serving.block_allocator import (
+    window_pool_blocks)
 from deepspeed_tpu.models import TransformerLM, gpt2_config, neox_config
 from deepspeed_tpu.ops.sparse_attention import FixedSparsityConfig
 from deepspeed_tpu.ops.sparse_attention.blocksparse_flash import (
@@ -1100,8 +1102,9 @@ def hybrid_model(pairs_self=2, pairs_cross=2):
 
 
 #: the hybrid cell's engine: slots, pages a slot, the full layer's blocks,
-#: the window layers' blocks (63 x 33 + 65 and the null block)
-HYBRID_SIZE = (64, 640, 4096, 63 * 33 + 65 + 1)
+#: the window layers' blocks (33 and 65 pages in groups of 8: 63 x 5 + 9
+#: groups and the null block)
+HYBRID_SIZE = (64, 640, 4096, window_pool_blocks(64, 33, 65))
 HYBRID_CHUNK = {"mixed": 512, "decode_only": 0}
 
 
@@ -2155,8 +2158,9 @@ def window_moe_model():
 
 
 #: the cell's engine: slots, pages a slot a kind, the full layers' blocks,
-#: the window layers' (19 x 129 + 161 and the null block)
-WINDOW_MOE_SIZE = (20, 640, 8000, 19 * 129 + 161 + 1)
+#: the window layers' (129 and 161 pages in groups of 8: 19 x 17 + 21 groups
+#: and the null block)
+WINDOW_MOE_SIZE = (20, 640, 8000, window_pool_blocks(20, 129, 161))
 
 
 def window_moe_mixed_operands(devices, model, chunk):

@@ -15,9 +15,13 @@ reference_afmoe.py``: tiny sizes, CPU, float32, seeded weights.
     and the shared expert once add up to the uncut layer;
   - the router's picks and weights against a NumPy transcription;
   - the allocator: window pages within ``window_pages``' two bounds under
-    a host-only churn of the cell's mix, both kinds drained;
+    a host-only churn of the cell's mix, both kinds drained; the window
+    kind's groups of ``PAGE_RUN`` (where a page lies, when a group goes
+    back, the one bound in groups, the walks' pages in runs);
   - each counter against a known mix; each refusal's sentence.
 """
+from types import SimpleNamespace
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -26,11 +30,14 @@ import pytest
 import deepspeed_tpu as ds
 from benchmark.lib import reference_afmoe as reference
 from deepspeed_tpu.inference.serving.block_allocator import (
-    PagedBlockAllocator)
+    BlockPoolError, PagedBlockAllocator, window_groups, window_pool_blocks)
 from deepspeed_tpu.models import TransformerLM, afmoe_config, build_model
 from deepspeed_tpu.models.transformer import find_layer_plan
+from deepspeed_tpu.models.window_kind import WindowKind
 from deepspeed_tpu.models.window_moe import WindowMoELM
 from deepspeed_tpu.moe import dropless
+from deepspeed_tpu.ops.transformer.paged_decode_attention import (
+    PAGE_RUN, walk_pages)
 
 #: the lead (two dense layers), one whole period and the boundary period;
 #: a window of two pages
@@ -208,7 +215,9 @@ def test_the_engine_holds_two_kinds_of_page_and_no_state(built, served):
     assert srv._pool_k.shape[0] == 2 and srv._pool_x["wk"].shape[0] == 6
     held_decoding, held_chunk = model.window_pages(4, 16)
     assert (held_decoding, held_chunk) == (3, 7)
-    assert srv.window_blocks == 2 * 3 + 7 + 1
+    # in groups of 8: 3 or 7 pages touch at most 2, and every slot's at once
+    assert (window_groups(3), window_groups(7)) == (2, 2)
+    assert srv.window_blocks == PAGE_RUN * (2 * 2 + 2) + 1
     held = srv.allocator.window_held_max
     assert held["decode"] == held_decoding and 3 < held["chunk"] <= held_chunk
     assert srv.allocator.window_freed_total > 0
@@ -291,29 +300,64 @@ def test_the_router_is_the_numpy_transcription(built):
     assert np.array_equal(np.sort(np.asarray(picked), -1), np.sort(pick, -1))
 
 
+def cell_allocator(slots=20, block=16, chunk=512, window=2048, short=0):
+    """The allocator at a cell's sizes, its window pool as the engine
+    sizes it (``short`` groups fewer), and ``window_pages``' two bounds."""
+    held = WindowKind.window_pages(SimpleNamespace(config=SimpleNamespace(
+        sliding_window=window)), block, chunk)
+    alloc = PagedBlockAllocator(6656, block, enable_prefix_cache=False)
+    alloc.add_window_kind(window_pool_blocks(slots, *held)
+                          - short * PAGE_RUN, window)
+    return alloc, held
+
+
+def prefill(alloc, seq, start, end, chunk):
+    """Rows ``start .. end - 1`` of ``seq`` by chunks, as the engine drives
+    the kind: reserve before the dispatch, trim behind it.  Returns the
+    most groups the sequence held at a reserve."""
+    most = 0
+    for at in range(start, end, chunk):
+        alloc.window_reserve(seq, at, min(at + chunk, end), "chunk")
+        most = max(most, len(groups_held(alloc, seq)))
+        alloc.window_trim(seq, min(at + chunk, end))
+    return most
+
+
+def groups_held(alloc, seq=None):
+    """The groups ``seq`` (or every sequence) holds, each by its first
+    block."""
+    return [g for r, gs in alloc._wgroups.items() for g in gs
+            if g and seq in (None, r)]
+
+
 @pytest.mark.parametrize("seed", [0, 1])
 def test_window_pages_stay_within_their_bounds_under_the_cells_churn(seed):
     """Host only: the cell's engine sizes (20 slots, pages of 16, chunks of
     512, a window of 2,048) and its mix of prompts and outputs through the
     allocator alone, as the engine drives it — reserve before a dispatch's
     rows, trim behind a chunk, free at the end.  No slot ever holds more
-    than ``window_pages``' bounds, the pool sized from them never runs
-    out, and both kinds drain to 0."""
+    than ``window_pages``' bounds, the pool sized from them in groups never
+    runs out, the groups held and the groups free are all the groups at
+    every dispatch, both kinds drain to 0 — and from the 500th dispatch on
+    (the first turnover is long past) the pages the window walks are
+    handed lie in runs, the decode rows' and the chunk's alike."""
     model = build_model(afmoe_config("trinity-mini", num_layers=4,
                                      layer_types=("window",) * 3 + ("full",),
                                      vocab_size=128, experts_held=(0, 16)))
     block, chunk, slots, window = 16, 512, 20, 2048
-    held_decoding, held_chunk = model.window_pages(block, chunk)
-    assert (held_decoding, held_chunk) == (129, 161)
-    alloc = PagedBlockAllocator(6656, block, enable_prefix_cache=False)
-    alloc.add_window_kind((slots - 1) * held_decoding + held_chunk + 1,
-                          window)
+    alloc, (held_decoding, held_chunk) = cell_allocator()
+    assert (held_decoding, held_chunk) == (129, 161) \
+        == model.window_pages(block, chunk)
+    assert alloc.window_blocks == 2753
+    groups = (alloc.window_blocks - 1) // PAGE_RUN
+    count = jax.jit(walk_pages, static_argnums=2)
     rng = np.random.default_rng(seed)
     mix = [(p, o) for p in (512, 2048, 4096, 8192)
            for o in (128, 256, 384, 512)]
     queue = [mix[i] for _ in range(4) for i in rng.permutation(len(mix))]
     live = {}                      # id -> [prompt, output, rows cached]
-    n = 0
+    n = dispatches = 0
+    walked = {"decode": np.zeros(2, np.int64), "chunk": np.zeros(2, np.int64)}
     while queue or live:
         while queue and len(live) < slots:
             p, o = queue.pop()
@@ -323,14 +367,33 @@ def test_window_pages_stay_within_their_bounds_under_the_cells_churn(seed):
         # one dispatch: one chunk (the first request still prefilling) and
         # every decoding slot a row
         chunked = next((r for r, s in live.items() if s[2] < s[0]), None)
-        for r, (p, o, at) in list(live.items()):
+        total = np.zeros(slots, np.int32)
+        for at_slot, (r, (p, o, at)) in enumerate(list(live.items())):
             if at >= p:
                 alloc.window_reserve(r, at, at + 1)
                 live[r][2] += 1
+                total[at_slot] = at + 1
         if chunked is not None:
             p, o, at = live[chunked]
             rows = min(chunk, p - at)
             alloc.window_reserve(chunked, at, at + rows, "chunk")
+        assert len(groups_held(alloc)) + len(alloc._wfree) == groups
+        if dispatches >= 500:
+            # the tables as _window_operands lays them, the walks as
+            # transformer.walk_counts hands them to walk_pages
+            tables = np.zeros((slots, (8192 + 512) // block), np.int32)
+            for at_slot, r in enumerate(live):
+                first, held = alloc.window_pages_held(r)
+                tables[at_slot, first:first + len(held)] = held
+            walked["decode"] += count(tables, total, block,
+                                      np.maximum(total - window, 0))
+            if chunked is not None:
+                row = list(live).index(chunked)
+                walked["chunk"] += count(
+                    tables[row][None], np.int32([at + rows]), block,
+                    np.int32([max(at - (window - 1), 0)]))
+        dispatches += 1
+        if chunked is not None:
             alloc.window_trim(chunked, at + rows)
             live[chunked][2] += rows
         for r, (p, o, at) in list(live.items()):
@@ -345,6 +408,122 @@ def test_window_pages_stay_within_their_bounds_under_the_cells_churn(seed):
     assert alloc.window_held_max["chunk"] <= held_chunk
     assert alloc.window_held_max["decode"] == held_decoding
     assert alloc.num_used_by_kind() == {"full": 0, "window": 0, "state": 0}
+    assert len(alloc._wfree) == groups
+    alloc.assert_consistent()
+    assert dispatches > 1000
+    for lane, (pages, in_runs) in walked.items():
+        assert pages > 0 and in_runs >= 0.85 * pages, (lane, pages, in_runs)
+
+
+def test_a_live_window_page_is_its_groups_block_at_the_tables_own_index():
+    """Entry ``p`` of a window table is block ``p % PAGE_RUN`` of the group
+    the sequence took at page ``p - p % PAGE_RUN``, whatever was handed
+    back before: every run of the table is consecutive blocks."""
+    alloc, _ = cell_allocator(slots=3, block=4, chunk=16, window=40)
+    for seq in "ab":
+        alloc.allocate(seq, 200)
+    for at in range(0, 192, 16):        # chunks side by side: groups interleave
+        for seq in "ab":
+            alloc.window_reserve(seq, at, at + 16, "chunk")
+            alloc.window_trim(seq, at + 16)
+    for seq in "ab":
+        first, held = alloc.window_pages_held(seq)
+        assert first == (192 - 39) // 4 and len(held) == 48 - first
+        groups = alloc._wgroups[seq]
+        assert [g % PAGE_RUN for g in groups if g] == [1] * 2
+        assert held == [groups[p // PAGE_RUN] + p % PAGE_RUN
+                        for p in range(first, 48)]
+    # the two sequences' groups alternate: consecutive runs of one table
+    # are NOT neighbours in the pool, a run's pages are
+    assert groups_held(alloc, "a")[1] != groups_held(alloc, "a")[0] + PAGE_RUN
+    alloc.assert_consistent()
+
+
+@pytest.mark.parametrize("how", ["trim", "free", "cancel"])
+def test_a_window_group_goes_back_exactly_once(how):
+    """A group goes back when its LAST page is handed back, not before;
+    ``free`` gives back what is left — the partly dead first group, the
+    partly filled last one — and nothing twice."""
+    alloc, _ = cell_allocator(slots=3, block=4, chunk=16, window=9)
+    every = (alloc.window_blocks - 1) // PAGE_RUN
+    alloc.allocate("a", 200)
+    alloc.window_reserve("a", 0, 44, "chunk")           # pages 0 .. 10
+    assert len(groups_held(alloc, "a")) == 2 and len(alloc._wfree) == every - 2
+    if how == "cancel":                 # gone before anything was trimmed
+        alloc.free("a")
+    else:
+        # row 36 attends from row 28: pages 0 .. 6 go, the first group stays
+        assert alloc.window_trim("a", 36) == 7
+        assert len(groups_held(alloc, "a")) == 2
+        first, last = groups_held(alloc, "a")
+        assert alloc.window_trim("a", 40) == 1          # page 7, its last
+        assert groups_held(alloc, "a") == [last]
+        assert alloc._wfree[-1] == first and alloc._wfree.count(first) == 1
+        assert alloc.window_trim("a", 40) == 0
+        if how == "free":
+            # a partly dead first group (page 8 gone) and a partly
+            # filled last one
+            alloc.window_reserve("a", 44, 70, "chunk")      # pages .. 17
+            assert alloc.window_pages_held("a")[0] == 9
+            assert len(groups_held(alloc, "a")) == 2
+            alloc.free("a")
+    if how != "trim":
+        assert "a" not in alloc._wgroups and "a" not in alloc._wtables
+    assert len(groups_held(alloc)) + len(alloc._wfree) == every
+    assert len(set(alloc._wfree)) == len(alloc._wfree)
+    alloc.assert_consistent()
+
+
+@pytest.mark.parametrize("cell,slots,window,groups", [
+    ("trinity-mini", 20, 2048, (17, 21)), ("phi-4-mini-flash", 64, 512, (5, 9))])
+def test_a_slots_pages_never_touch_more_groups_than_the_one_bound(
+        cell, slots, window, groups):
+    """``n`` consecutive pages touch at most ``ceil((n - 1) / PAGE_RUN) +
+    1`` groups: both cells' two page bounds in groups, and a sequence that
+    prefills by chunks and then decodes, from every alignment of its
+    window to its groups, never holds more."""
+    alloc, held = cell_allocator(slots=slots, window=window)
+    assert tuple(window_groups(n) for n in held) == groups
+    assert all(window_groups(n) == -(-(n - 1) // PAGE_RUN) + 1 for n in held)
+    assert alloc.window_blocks == 1 + PAGE_RUN * (
+        (slots - 1) * groups[0] + groups[1])
+    alloc.allocate("a", 9000)
+    # chunks that start 5 rows into a page, one start a group's alignment
+    most_chunk = max(prefill(alloc, "a", 0, 5, 512),
+                     prefill(alloc, "a", 5, 5 + 512 * 2 * PAGE_RUN, 512))
+    most_decode = 0
+    for at in range(5 + 512 * 2 * PAGE_RUN, 8600):
+        alloc.window_reserve("a", at, at + 1)
+        most_decode = max(most_decode, len(groups_held(alloc, "a")))
+    assert alloc.window_held_max == {"decode": held[0], "chunk": held[1]}
+    assert (most_decode, most_chunk) == groups
+
+
+@pytest.mark.parametrize("short", [0, 1])
+def test_a_window_pool_one_group_short_runs_out(short):
+    """Every slot at its bound at once fills the pool to its last group:
+    one group fewer and the last slot's reserve raises."""
+    alloc, (held_decoding, held_chunk) = cell_allocator(
+        slots=4, block=4, chunk=16, window=40, short=short)
+    assert (held_decoding, held_chunk) == (11, 15)
+    # row 69's window starts on a group's LAST page (7) and ends in the
+    # group after the next, and so does a chunk's from there: three groups
+    # each, the bound of both
+    for seq in "abcd":
+        alloc.allocate(seq, 400)
+    for seq in "abc":
+        prefill(alloc, seq, 0, 69, 16)
+        alloc.window_reserve(seq, 69, 70)
+        assert len(groups_held(alloc, seq)) == window_groups(held_decoding)
+    if short:
+        with pytest.raises(BlockPoolError, match="window pool exhausted"):
+            prefill(alloc, "d", 0, 85, 16)
+    else:
+        prefill(alloc, "d", 0, 69, 16)
+        alloc.window_reserve("d", 69, 85, "chunk")
+        assert len(groups_held(alloc, "d")) == window_groups(held_chunk)
+        assert not alloc._wfree
+        assert alloc.window_held_max == {"decode": 11, "chunk": 15}
     alloc.assert_consistent()
 
 
