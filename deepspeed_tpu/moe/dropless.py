@@ -33,6 +33,15 @@ that hold a live tile, so a dead row of a pass costs nothing: it is
 neither gathered, nor passed over, nor added (counter
 ``moe_rows_moved``: the rows those blocks hold).
 
+Where every pick sits in that buffer — each row's token and weight, each
+tile's expert, the live tiles, the experts' counts — is ONE device
+operation (:func:`_layout`, kernel ``moe_layout``): a counting pass that
+ranks each expert's picks (running counts as products with triangles of
+ones), adds up the experts' tiles into their first rows, and inverts
+pick -> row by comparing the LIVE rows with the picks' destinations (no
+running sum, search or scatter is left to XLA: PERF.md, PR 64).  It
+differentiates in the picks' weights.
+
 It differentiates (``custom_vjp``): the rows' gradient is the same kernel
 walked against the weights' other axis, the weights' a second kernel
 (``moe_grouped_matmul_dw``) that sums ``x_tile^T dy_tile`` over each
@@ -314,35 +323,186 @@ class _Layout(NamedTuple):
     counts: jax.Array        # [held] int32 rows of each held expert
 
 
+#: picks to a chunk, rows to a block of the layout kernel: a vector's lanes
+_LANES = 128
+
+
+def _running_sum(x, axis):
+    """Inclusive running sum of an int32 value along ``axis`` by doubling
+    rolls (no ``reduce-window``)."""
+    at = jax.lax.broadcasted_iota(jnp.int32, x.shape, axis)
+    shift = 1
+    while shift < x.shape[axis]:
+        x = x + jnp.where(at >= shift, pltpu.roll(x, shift, axis), 0)
+        shift *= 2
+    return x
+
+
+def _ones_where(mask):
+    return jnp.where(mask, 1.0, 0.0).astype(jnp.bfloat16)
+
+
+def _layout_kernel(local_ref, weight_ref, token_ref, row_weight_ref,
+                   tile_expert_ref, dest_ref, counts_ref, live_ref,
+                   rank_ref, ends_ref, *, held, tile, picks, k):
+    """A counting pass over ``local_ref`` / ``weight_ref [C, 128]`` (pick
+    ``i`` at ``[i // 128, i % 128]``; ``held`` and 0.0 in the padding).
+
+    The experts' one-hots lie stacked in ``rank_ref [held C, 128]``; one
+    running count over them (a product with a triangle of ones along the
+    lanes, a second along each expert's chunks) is every pick's rank among
+    its expert's and, in an expert's last chunk, its count.  The counts'
+    tiles, summed over the experts, are where each expert's rows start;
+    rank and start give every pick's destination (``dest_ref``, the
+    buffer's end for a pick not held).  The inverse needs no scatter: a
+    block of 128 rows is compared with the destinations of 128 picks a
+    step, and only the blocks that hold a live tile are visited — a row
+    holds one pick at most, so a hit overwrites.  Every other row keeps
+    the fill."""
+    i32 = jnp.int32
+    chunks = local_ref.shape[0]
+    no_row = token_ref.shape[0] * _LANES
+    loc = local_ref[...]
+
+    def of_expert(e):
+        return pl.ds(pl.multiple_of(e * chunks, 8), chunks)
+
+    def mark(e, _):
+        rank_ref[of_expert(e), :] = jnp.where(loc == e, 1, 0).astype(i32)
+        return 0
+    jax.lax.fori_loop(0, held, mark, 0)
+    # counts of at most 128 a chunk are exact in bfloat16, their sums in
+    # float32: both running counts are products with a triangle of ones
+    mine = rank_ref[...].astype(jnp.float32).astype(jnp.bfloat16)
+    lanes = (_LANES, _LANES)
+    within = jnp.dot(
+        mine, _ones_where(jax.lax.broadcasted_iota(i32, lanes, 0)
+                          <= jax.lax.broadcasted_iota(i32, lanes, 1)),
+        preferred_element_type=jnp.float32)
+    total = jnp.broadcast_to(within[:, _LANES - 1:], within.shape)
+    # ... and over the chunks before it of the same expert
+    row = jax.lax.broadcasted_iota(i32, (held * chunks, 1), 0)
+    col = jax.lax.broadcasted_iota(i32, (1, held * chunks), 1)
+    same = jax.lax.div(row, i32(chunks)) == jax.lax.div(col, i32(chunks))
+    upto = jnp.dot(_ones_where(same & (col <= row)),
+                   total.astype(jnp.bfloat16),
+                   preferred_element_type=jnp.float32)
+    within, total, upto = (a.astype(i32) for a in (within, total, upto))
+    rank_ref[...] = upto - total + within - 1
+    # ``ends_ref``: first the running totals (an expert's last chunk holds
+    # its count), then, in its first ``held`` rows, the experts' first tiles
+    ends_ref[...] = upto
+    counts = ends_ref[pl.ds(chunks - 1, held, stride=chunks), :]
+    counts_ref[...] = counts
+    tiles_of = jax.lax.div(counts + (tile - 1), i32(tile))
+    ends = _running_sum(tiles_of, 0)          # the first tile past each
+    ends_ref[pl.ds(0, held), :] = ends - tiles_of
+    live_tiles = jnp.max(ends)
+    live_ref[0] = live_tiles
+    # a tile's expert: the experts but the last that end at or under it
+    shape = (held, tile_expert_ref.shape[1])
+    ended = ((jax.lax.broadcasted_iota(i32, shape, 1) >= ends[:, :1])
+             & (jax.lax.broadcasted_iota(i32, shape, 0) < held - 1))
+    tile_expert_ref[...] = jnp.sum(jnp.where(ended, 1, 0).astype(i32),
+                                   axis=0, keepdims=True)
+
+    def place(e, dest):
+        return jnp.where(loc == e, ends_ref[pl.ds(e, 1), :] * tile
+                         + rank_ref[of_expert(e), :], dest)
+    dest_ref[...] = jax.lax.fori_loop(0, held, place,
+                                      jnp.full(loc.shape, no_row, i32))
+    token_ref[...] = jnp.full(token_ref.shape, picks // k, i32)
+    row_weight_ref[...] = jnp.zeros(row_weight_ref.shape, jnp.float32)
+
+    row_at = jax.lax.broadcasted_iota(i32, (_LANES, _LANES), 0)
+    pick_at = jax.lax.broadcasted_iota(i32, (_LANES, _LANES), 1)
+
+    def rows_block(b, _):
+        rows_b = row_at + b * _LANES
+
+        def chunk(c, found):
+            pick, weight = found
+            hit = dest_ref[pl.ds(c, 1), :] == rows_b
+            return (jnp.where(hit, pick_at + c * _LANES, pick),
+                    jnp.where(hit, weight_ref[pl.ds(c, 1), :], weight))
+        pick, weight = jax.lax.fori_loop(
+            0, -(-picks // _LANES), chunk,
+            (jnp.full((_LANES, _LANES), picks, i32),
+             jnp.zeros((_LANES, _LANES), jnp.float32)))
+        # [rows, picks] -> a row of 128 rows: one lane of a row is set
+        token_ref[pl.ds(b, 1), :] = jax.lax.div(
+            jnp.min(pick.T, axis=0, keepdims=True), i32(k))
+        row_weight_ref[pl.ds(b, 1), :] = jnp.sum(weight.T, axis=0,
+                                                 keepdims=True)
+        return 0
+    jax.lax.fori_loop(0, (live_tiles * tile + _LANES - 1) // _LANES,
+                      rows_block, 0)
+
+
+@functools.partial(jax.jit, static_argnums=(2, 3, 4, 5))
+def _layout_call(local, weight, held, rows, tile, interpret):
+    """``(row_token, row_weight, tile_expert, live_tiles, counts, dest
+    [T, k])`` of kernel ``moe_layout``.  (Jitted: a call outside any
+    program builds its kernel once a shape, not once a call.)"""
+    t, k = local.shape
+    picks = t * k
+    chunks = -(-picks // (8 * _LANES)) * 8
+    blocks = -(-rows // _LANES)
+
+    def by_chunks(a, fill):
+        # a few KB, padded by the fusion that makes it: no round trip
+        # dstpu: ignore[PALLAS004] -- fused into the operand's producer
+        return jnp.pad(a.reshape(-1), (0, chunks * _LANES - picks),
+                       constant_values=fill).reshape(chunks, _LANES)
+    vmem, smem = (pl.BlockSpec(memory_space=m)
+                  for m in (pltpu.VMEM, pltpu.SMEM))
+    token, row_weight, tile_expert, dest, counts, live = pl.pallas_call(
+        functools.partial(_layout_kernel, held=held, tile=tile,
+                          picks=picks, k=k),
+        in_specs=[vmem, vmem],
+        out_specs=[vmem, vmem, vmem, vmem, vmem, smem],
+        out_shape=[jax.ShapeDtypeStruct((blocks, _LANES), jnp.int32),
+                   jax.ShapeDtypeStruct((blocks, _LANES), jnp.float32),
+                   jax.ShapeDtypeStruct((1, rows // tile), jnp.int32),
+                   jax.ShapeDtypeStruct((chunks, _LANES), jnp.int32),
+                   jax.ShapeDtypeStruct((held, _LANES), jnp.int32),
+                   jax.ShapeDtypeStruct((1,), jnp.int32)],
+        scratch_shapes=[pltpu.VMEM((held * chunks, _LANES), jnp.int32)] * 2,
+        interpret=interpret,
+        name="moe_layout",
+    )(by_chunks(local, held), by_chunks(weight, 0.0))
+    return (token.reshape(-1)[:rows], row_weight.reshape(-1)[:rows],
+            tile_expert[0], live[0], counts[:, 0],
+            dest.reshape(-1)[:picks].reshape(t, k))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4, 5))
+def _laid_out(local, weight, held, rows, tile, interpret):
+    return _layout_call(local, weight, held, rows, tile, interpret)[:5]
+
+
+def _laid_out_fwd(local, weight, held, rows, tile, interpret):
+    *lay, dest = _layout_call(local, weight, held, rows, tile, interpret)
+    return tuple(lay), dest
+
+
+def _laid_out_bwd(held, rows, tile, interpret, dest, given):
+    """A pick's weight went to its row and nowhere else."""
+    return None, given[1].at[dest].get(mode="fill", fill_value=0.0)
+
+
+_laid_out.defvjp(_laid_out_fwd, _laid_out_bwd)
+
+
 def _layout(local: jax.Array, weight: jax.Array, held: int, rows: int,
             tile: int = TILE_ROWS) -> _Layout:
     """``local [T, k]`` — the held experts' local ids, ``held`` for a pick
     that is not dispatched here.  Each expert's picks go to consecutive
     rows from a boundary of the tiles of ``tile`` rows on, experts in
-    order, picks in token order."""
-    t, k = local.shape
-    flat = local.reshape(-1)
-    onehot = flat[:, None] == jnp.arange(held, dtype=flat.dtype)[None, :]
-    counts = onehot.sum(0, dtype=jnp.int32)
-    rank = jnp.take_along_axis(
-        jnp.cumsum(onehot, axis=0, dtype=jnp.int32) - 1,
-        jnp.minimum(flat, held - 1)[:, None], axis=1)[:, 0]
-    tiles_of = -(-counts // tile)
-    first_tile = jnp.cumsum(tiles_of) - tiles_of
-    dest = jnp.where(
-        flat < held,
-        first_tile[jnp.minimum(flat, held - 1)] * tile + rank, rows)
-    token = jnp.arange(t * k, dtype=jnp.int32) // k
-    row_token = jnp.full((rows,), t, jnp.int32).at[dest].set(
-        token, mode="drop")
-    row_weight = jnp.zeros((rows,), jnp.float32).at[dest].set(
-        weight.reshape(-1), mode="drop")
-    tile_expert = jnp.clip(
-        jnp.searchsorted(jnp.cumsum(tiles_of),
-                         jnp.arange(rows // tile, dtype=jnp.int32),
-                         side="right"), 0, held - 1).astype(jnp.int32)
-    return _Layout(row_token, row_weight, tile_expert, tiles_of.sum(),
-                   counts)
+    order, picks in token order.  ONE device operation, kernel
+    ``moe_layout``; differentiable in ``weight``."""
+    return _Layout(*_laid_out(local, weight.astype(jnp.float32), held, rows,
+                              tile, resolve_interpret(None)))
 
 
 def split_experts(layers: dict):

@@ -119,7 +119,7 @@ PHASE_SPANS = tuple(f"serving/{p}" for p in PHASES)
 #: pool rows; ``attn_conv``: what a convolutional attention does to q and k
 #: between projection and kernel (causal convolutions, the q-k mean, the
 #: per-head normalisation, rotary); ``pool_write``: new rows into the
-#: paged pool; ``expert_layout``: the sort into tiles, gather and combine
+#: paged pool; ``expert_layout``: the layout kernel, gather and combine
 #: around the grouped product (``experts``); ``head``: final norm, logits,
 #: the finite flag; ``zero_comm``: the casts, gathers and scatters that
 #: move state between its partitioned form and the form compute uses;

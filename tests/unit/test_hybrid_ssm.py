@@ -133,10 +133,12 @@ def test_generates_through_the_dense_cache_like_one_pass(built):
     ids = jax.random.randint(jax.random.PRNGKey(2), (2, 30), 0, 128)
     want = model.apply(params, ids)
     cache = model.init_cache(2, 30)
-    lg, cache = model.apply(params, ids[:, :19], cache=cache)
+    # (one program a shape: eleven one-token steps share theirs)
+    step = jax.jit(lambda ids, cache: model.apply(params, ids, cache=cache))
+    lg, cache = step(ids[:, :19], cache)
     outs = [lg]
     for t in range(19, 30):
-        lg, cache = model.apply(params, ids[:, t:t + 1], cache=cache)
+        lg, cache = step(ids[:, t:t + 1], cache)
         outs.append(lg)
     assert float(jnp.abs(jnp.concatenate(outs, 1) - want).max()) < ATOL
     eng = ds.init_inference(model, {"dtype": "float32",

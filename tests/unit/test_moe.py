@@ -4,6 +4,8 @@ NumPy loop over experts, the grouped product's backward, and what refuses
 the capacity-gated layer's old options.  The blocks that use the layer have
 their own files (``test_shortcut_moe.py``, ``test_latent_moe.py``,
 ``test_sparse_latent_moe.py``, ``test_cca_moe.py``)."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -207,6 +209,117 @@ def test_a_pass_moves_its_live_blocks_only(monkeypatch, load, stacked):
     counted = dict(zip(dropless.COUNTERS, map(int, counted)))
     assert counted["moe_picks_held"] == here.sum()
     assert counted["moe_rows_moved"] == sum(walked) * BLOCK
+
+
+# ---------------------------------------------------------------------------
+# the layout: where every held pick sits in the row buffer, ONE kernel
+# (``moe_layout``) against a loop over experts
+# ---------------------------------------------------------------------------
+#: (T, k, held, tile, the router's outputs, the rows ``expert_share`` asks
+#: for): SDAR's decode-only dispatch, its mixed one, LongCat's top-12 and
+#: the training call (top-1, 8 held, tiles of 256, ONE pass; 16,384 rows on
+#: the chip, cut in ``T`` for the CPU)
+LAYOUT_SHAPES = {"decode": (160, 8, 16, 16, 128, 2048),
+                 "mixed": (672, 8, 16, 16, 128, 6144),
+                 "top12": (48, 12, 16, 16, 64, 896),
+                 "training": (1536, 1, 8, 256, 16, 3584)}
+LAYOUT_LOADS = ("no_pick_held", "one_pick", "balanced", "one_expert",
+                "every_pick_held", "rows_masked")
+
+
+def layout_case(shape, load):
+    """``local [T, k]`` as ``expert_share`` hands it over (``held`` for a
+    pick that is absent, or whose row carries no token) and the weights."""
+    t, k, held, tile, routed, rows = LAYOUT_SHAPES[shape]
+    rng = np.random.default_rng(LAYOUT_LOADS.index(load))
+    absent = np.arange(held, routed)
+    index = np.stack([rng.permutation(absent)[:k] for _ in range(t)])
+    if load == "one_pick":
+        index[t // 3, k - 1] = 2
+    elif load in ("balanced", "rows_masked"):
+        index = np.stack([rng.permutation(routed)[:k] for _ in range(t)])
+    elif load == "one_expert":          # a row picks an expert once
+        index[:, k // 2] = 3
+    elif load == "every_pick_held":
+        index = np.stack([rng.permutation(held)[:k] for _ in range(t)])
+    valid = np.ones((t, 1), bool)
+    if load == "rows_masked":
+        valid[np.arange(t) % 7 == 3] = False
+    local = np.where(valid & (index < held), index, held).astype(np.int32)
+    weight = (rng.random((t, k)) + 0.5).astype(np.float32) * valid
+    return local, weight, held, rows, tile
+
+
+def layout_by_a_loop(local, weight, held, rows, tile):
+    """Experts in order, an expert's rows from a tile boundary on, picks in
+    token order; ``T`` / 0.0 in every row without a pick.  Returns the
+    ``_Layout`` fields and every pick's row (``rows`` for none)."""
+    t, k = local.shape
+    flat, w = local.reshape(-1), weight.reshape(-1)
+    row_token = np.full(rows, t, np.int32)
+    row_weight = np.zeros(rows, np.float32)
+    tile_expert = np.full(rows // tile, held - 1, np.int32)
+    counts = np.zeros(held, np.int32)
+    dest = np.full(flat.shape, rows, np.int32)
+    at = 0
+    for e in range(held):
+        mine = np.nonzero(flat == e)[0]
+        counts[e] = len(mine)
+        for r, i in enumerate(mine):
+            dest[i] = at * tile + r
+            row_token[dest[i]], row_weight[dest[i]] = i // k, w[i]
+        tiles = -(-len(mine) // tile)
+        tile_expert[at:at + tiles] = e
+        at += tiles
+    return dropless._Layout(row_token, row_weight, tile_expert,
+                            np.int32(at), counts), dest
+
+
+@functools.lru_cache(maxsize=None)
+def compiled_layout(held, rows, tile):
+    """One program a shape: the loads of a shape share it."""
+    return jax.jit(lambda local, weight: dropless._layout(
+        local, weight, held, rows, tile))
+
+
+@pytest.mark.parametrize("load", LAYOUT_LOADS)
+@pytest.mark.parametrize("shape", list(LAYOUT_SHAPES))
+def test_layout_is_the_loops_element_for_element(shape, load):
+    local, weight, held, rows, tile = layout_case(shape, load)
+    want, _ = layout_by_a_loop(local, weight, held, rows, tile)
+    got = compiled_layout(held, rows, tile)(local, weight)
+    for name, a, b in zip(dropless._Layout._fields, got, want):
+        a = np.asarray(a)
+        assert a.shape == b.shape and a.dtype == b.dtype, name
+        assert np.array_equal(a, b), (name, np.nonzero(a != b)[0][:8])
+    held_picks = int((local < held).sum())
+    assert int(got.counts.sum()) == held_picks
+    assert {"no_pick_held": held_picks == 0, "one_pick": held_picks == 1,
+            "one_expert": int(got.counts[3]) == held_picks == len(local),
+            "every_pick_held": held_picks == local.size}.get(load, True)
+
+
+@pytest.mark.parametrize("shape", ["decode", "training"])
+def test_layout_row_weight_differentiates_as_the_scatter_did(shape):
+    """``row_weight`` is a gather of the picks' weights by the pick in each
+    row; the form it replaced scattered them to their rows (``zeros.at[
+    dest].set(weight)``): the same gradient in the router's weights, an
+    absent pick's exactly zero."""
+    local, weight, held, rows, tile = layout_case(shape, "balanced")
+    _, dest = layout_by_a_loop(local, weight, held, rows, tile)
+    g = np.random.default_rng(5).standard_normal(rows).astype(np.float32)
+
+    def ours(weight):
+        return jnp.sum(dropless._layout(local, weight, held, rows,
+                                        tile).row_weight * g)
+
+    def scattered(weight):
+        return jnp.sum(jnp.zeros((rows,), jnp.float32).at[dest].set(
+            weight.reshape(-1), mode="drop") * g)
+    got, want = jax.grad(ours)(weight), jax.grad(scattered)(weight)
+    assert np.array_equal(got, want)
+    assert np.asarray(got)[local < held].all()
+    assert not np.asarray(got)[local == held].any()
 
 
 # ---------------------------------------------------------------------------

@@ -795,7 +795,8 @@ def test_expert_share_in_one_pass_is_the_parents(v5e_devices,
     now = program(lambda *a, **kw: dropless.expert_share(*a, **kw)[0])
     jax.clear_caches()
     before = program(_parent_expert_share)
-    assert custom_calls(now) == 9      # three products, their dx and dw
+    # the layout, three products, their dx and dw
+    assert custom_calls(now) == 10
     assert stripped(now) == stripped(before)
 
 
@@ -811,8 +812,8 @@ def test_expert_share_moves_rows_by_blocks(v5e_devices, compiled_kernels,
     """The serving form of ``expert_share`` at a cell's widths, its
     experts read out of a ``[4, 16, ..]`` stack: every row-wide gather and
     scatter-add moves ``BLOCK_ROWS`` rows inside a loop's body — none
-    moves a pass's ``PASS_ROWS`` — and a pass is still three grouped
-    products."""
+    moves a pass's ``PASS_ROWS`` — a pass is still three grouped
+    products, and the layout before them is one ``moe_layout`` call."""
     import re
     from deepspeed_tpu.moe import dropless
     h, f, routed, t = EXPERT_SHARE_CELLS[cell]
@@ -827,8 +828,13 @@ def test_expert_share_moves_rows_by_blocks(v5e_devices, compiled_kernels,
         share, sds((4, 16, h, f), bf), sds((4, 16, h, f), bf),
         sds((4, 16, f, h), bf), sds((t, h), bf), sds((t, 8), jnp.int32),
         sds((t, 8), jnp.float32), sds((), jnp.int32))
-    assert custom_calls(text) == 3
+    assert custom_calls(text) == 4
     assert len(re.findall(r"custom-call\(.*moe_grouped_matmul", text)) == 3
+    # the layout is ONE kernel: no running sum left to XLA, and no scatter
+    # of a scalar a pick (the only scatter is the rows' float32 add)
+    assert len(re.findall(r"%moe_layout[.\d]* = .* custom-call\(", text)) == 1
+    assert " reduce-window(" not in text
+    assert not re.search(r"= \w+\[\d+\]\S* scatter\(", text)
     comps, _ = hlo_computations(text)
     called = {name: set(re.findall(r"(?:calls|body|to_apply)=%([\w.-]+)",
                                    " ".join(lines)))
@@ -859,29 +865,29 @@ def _shortcut_case():
     from deepspeed_tpu.models import longcat_flash_config
     return longcat_flash_config(
         "omni", num_layers=2, vocab_size=1024, max_seq_len=8192,
-        experts_held=(0, 4)), 4, 48, 512, 7, 5
+        experts_held=(0, 4)), 4, 48, 512, 8, 6
 
 
 def _sandwich_case():
     from deepspeed_tpu.models import openpangu_ultra_moe_config
     return openpangu_ultra_moe_config(
         "718b", num_layers=3, first_k_dense=1, vocab_size=1024,
-        max_seq_len=4096, experts_held=(0, 8)), 8, 128, 256, 7, 5
+        max_seq_len=4096, experts_held=(0, 8)), 8, 128, 256, 8, 6
 
 
 def _sparse_case():
     """Three layers of the sparse-selection block: a dense ``full`` layer,
-    then a ``shared`` and a ``full`` expert layer — 9 kernel calls with a
+    then a ``shared`` and a ``full`` expert layer — 10 kernel calls with a
     chunk lane (a ``full`` layer's two score calls and every layer's
     sparse chunk call, under the scanned layer's conditional once each;
-    three grouped products), 5 without.  16 held experts, so that one
-    layer's slice of one expert matrix is more than the chunk's score
-    plane and the selection's own temporaries."""
+    the layout and three grouped products), 6 without.  16 held experts,
+    so that one layer's slice of one expert matrix is more than the
+    chunk's score plane and the selection's own temporaries."""
     from deepspeed_tpu.models import glm_moe_dsa_config
     return glm_moe_dsa_config(
         "5.2", num_layers=3, first_k_dense=1,
         indexer_types=("full", "shared", "full"), vocab_size=1024,
-        max_seq_len=16384, experts_held=(0, 16)), 16, 32, 1024, 9, 5
+        max_seq_len=16384, experts_held=(0, 16)), 16, 32, 1024, 10, 6
 
 
 LATENT_CASES = {"shortcut": _shortcut_case, "sandwich": _sandwich_case,
@@ -2342,6 +2348,7 @@ def test_step_programs_carry_their_scopes(compiled_kernels, step_programs,
     assert kernels
     for ln in kernels:
         want = ("experts" if "%moe_grouped_matmul" in ln else
+                "expert_layout" if "%moe_layout" in ln else
                 "indexer" if "%dsa_index_scores" in ln else
                 "kda_scan" if "%kda_decode_update" in ln else
                 "ssm_scan" if re.search(r"%ss[md]_(chunk_scan|decode_update)",
